@@ -1,60 +1,6 @@
-"""Shims over jax API renames (and version-specific miscompiles) so the
-framework runs on every jax the fleet actually has installed.
+"""The two jax names the framework imports from one place: ``shard_map``
+and the Pallas TPU ``CompilerParams``. Plain re-exports of the installed
+jax's names, not version forks."""
 
-Two symbols moved between the jax versions we support:
-
-- ``shard_map``: promoted from ``jax.experimental.shard_map`` to
-  top-level ``jax.shard_map`` (jax 0.6).
-- Pallas TPU compiler params: ``pltpu.TPUCompilerParams`` renamed to
-  ``pltpu.CompilerParams`` (jax 0.5).
-
-Import both from here; never from jax directly.
-
-One workaround for a jax 0.4.37 GSPMD bug lives here too: ``pad_tail``
-(see its docstring) — use it instead of ``jnp.concatenate`` whenever a
-possibly-sharded array gets a constant tail appended.
-"""
-
-import functools
-import inspect
-
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.6
-except ImportError:                      # pragma: no cover - version dep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    # older jax spells the replication check `check_rep`
-    @functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax >= 0.5 spelling first; fall back to the long-stable old name.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    _pltpu.TPUCompilerParams
-
-
-def pad_tail(x, n_pad, value):
-    """Append ``n_pad`` rows of ``value`` along axis 0 — via ``jnp.pad``,
-    NEVER ``jnp.concatenate``.
-
-    jax 0.4.37's SPMD partitioner miscompiles
-    ``concatenate([reshape(slice(sharded)), replicated_fill])``: the
-    sharded operand is read back with a strided/garbled element order, so
-    the padded array's REAL values are wrong (measured on the CPU backend
-    with a ``data``-sharded [B, S] batch: element i comes back as 2i).
-    The ``pad`` HLO lowers correctly on every jax we support. This bug
-    corrupted the fused LM-head loss labels on any multi-axis mesh — the
-    TP/SP trajectory-parity failures tracked since PR 1 were exactly this.
-    """
-    import jax.numpy as jnp
-    if n_pad == 0:
-        return x
-    widths = [(0, n_pad)] + [(0, 0)] * (x.ndim - 1)
-    return jnp.pad(x, widths, constant_values=value)
+from jax import shard_map  # noqa: F401
+from jax.experimental.pallas.tpu import CompilerParams  # noqa: F401

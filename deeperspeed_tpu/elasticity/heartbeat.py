@@ -97,48 +97,17 @@ class InMemoryTransport:
 
 class CoordinationTransport:
     """Heartbeats over the jax.distributed coordination-service KV store
-    (the same client `utils.distributed.barrier` uses).
-
-    Newer jax clients allow overwriting a key (`allow_overwrite=True`);
-    older ones are append-only, so each publish falls back to a
-    serial-suffixed key and reads take the highest serial per peer."""
+    (the same client `utils.distributed.barrier` uses): one key per
+    peer, overwritten on every beat."""
 
     def __init__(self, client, prefix=_KV_PREFIX):
         self._client = client
         self._prefix = prefix
-        self._overwrite = True   # optimistic; downgraded on TypeError
-        self._can_delete = True
-        self._warned_growth = False
 
     def publish(self, peer, payload):
-        value = json.dumps(payload)
-        key = f"{self._prefix}/{peer}"
-        if self._overwrite:
-            try:
-                self._client.key_value_set(key, value,
-                                           allow_overwrite=True)
-                return
-            except TypeError:       # old client: append-only store
-                self._overwrite = False
-        serial = payload["serial"]
-        self._client.key_value_set(f"{key}/{serial}", value)
-        # the fallback would otherwise leak one key per beat forever
-        # (and read_all rescans them all every poll): best-effort delete
-        # of the key this one supersedes
-        if self._can_delete and serial > 1:
-            try:
-                self._client.key_value_delete(f"{key}/{serial - 1}")
-            except AttributeError:
-                self._can_delete = False
-                if not self._warned_growth:  # pragma: no cover - old jax
-                    self._warned_growth = True
-                    logger.warning(
-                        "heartbeat transport: this jax client supports "
-                        "neither key overwrite nor delete — the "
-                        "coordination-service heartbeat keys grow by "
-                        "one per peer per interval for the job lifetime")
-            except Exception:        # already gone / service hiccup
-                pass
+        self._client.key_value_set(f"{self._prefix}/{peer}",
+                                   json.dumps(payload),
+                                   allow_overwrite=True)
 
     def read_all(self):
         try:
@@ -152,21 +121,14 @@ class CoordinationTransport:
             except (TypeError, ValueError):  # pragma: no cover
                 continue
             peer = key[len(self._prefix):].strip("/").split("/")[0]
-            prev = beats.get(peer)
-            if prev is None or payload.get("serial", 0) >= \
-                    prev.get("serial", 0):
-                beats[peer] = payload
+            beats[peer] = payload
         return beats
 
     def discard(self, peer):
-        """Best-effort delete of one key (absent / no-delete-support
-        clients are fine) — the handoff channel's slot retirement."""
-        if not self._can_delete:
-            return
+        """Best-effort delete of one key (absent is fine) — the handoff
+        channel's slot retirement."""
         try:
             self._client.key_value_delete(f"{self._prefix}/{peer}")
-        except AttributeError:   # pragma: no cover - old jax client
-            self._can_delete = False
         except Exception:        # already gone / service hiccup
             pass
 

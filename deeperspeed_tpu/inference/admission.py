@@ -38,7 +38,7 @@ The typed request-terminal errors live here too (`DeadlineExceeded`,
 reaches exactly one terminal status — ``ok`` / ``shed`` /
 ``deadline_exceeded`` / ``failed`` — and the non-``ok`` ones carry one
 of these exceptions in ``Request.error`` (docs/inference.md lists the
-taxonomy).
+statuses).
 """
 
 import time

@@ -411,7 +411,7 @@ class InferenceEngine:
                       "schedule_s": 0.0, "prefill_s": 0.0,
                       "decode_s": 0.0, "admission_wait_s": 0.0,
                       "queue_depth": 0.0, "page_pool_util": 0.0,
-                      # terminal-status taxonomy: every request reaches
+                      # terminal-status set: every request reaches
                       # exactly one (docs/inference.md)
                       "requests_ok": 0, "requests_shed": 0,
                       "requests_deadline_exceeded": 0,
@@ -639,7 +639,7 @@ class InferenceEngine:
             scale_specs = ((P(None, MODEL_AXIS, None),) * 2 if scales
                            else ())
             f = shard_map(
-                mapped, self.mesh,
+                mapped, mesh=self.mesh,
                 in_specs=(P(None, MODEL_AXIS, None), pool_spec,
                           pool_spec, P(None, None), P(None)) + scale_specs,
                 out_specs=P(None, MODEL_AXIS, None),

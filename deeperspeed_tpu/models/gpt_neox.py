@@ -10,9 +10,9 @@ TPU-first choices:
 - Tensor-parallel PartitionSpecs over the ``model`` mesh axis following the
   Megatron pattern: QKV/MLP-in column-sharded, attn-out/MLP-out
   row-sharded, embeddings vocab-sharded — collectives ride ICI via GSPMD.
-- Static shapes; attention via a fused Pallas flash-attention kernel when
-  available (`deeperspeed_tpu.ops.pallas.flash_attention`), XLA fallback
-  otherwise.
+- Static shapes; attention via the fused Pallas flash-attention kernel
+  (`deeperspeed_tpu.ops.pallas.flash_attention`) for the shapes it
+  supports, XLA otherwise — the choice is recorded, and logged on a TPU.
 - `jax.checkpoint`-friendly block structure (the engine's activation-
   checkpoint interval remats whole blocks).
 
@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
+from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, per_shard
 
 
 @dataclass(frozen=True)
@@ -338,20 +338,33 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None):
     boundaries on kernel and fallback paths alike."""
     from ..runtime.activation_checkpointing.checkpointing import \
         tag_attn_residual
+    from ..ops.pallas.flash_attention import (
+        _LAST_BACKEND, BLOCK_K, BLOCK_Q, flash_attention,
+        flash_attention_segmented, flash_attention_supported,
+        note_xla_on_tpu)
+    if use_pallas and flash_attention_supported(q.shape):
+        _LAST_BACKEND["attention"] = "pallas"
+
+        def kernel(q, k, v, *seg):
+            # block geometry from the shape the kernel really sees: the
+            # per-device shard when `per_shard` splits batch and heads
+            fwd, bwd = _flash_dispatch(q.shape, q.dtype)
+            bq, bk = fwd if fwd is not None else (BLOCK_Q, BLOCK_K)
+            if seg:
+                return flash_attention_segmented(
+                    q, k, v, seg[0], True, None, bq, bk, bwd)
+            return flash_attention(q, k, v, True, None, bq, bk, bwd)
+
+        seg = () if segment_ids is None else (segment_ids,)
+        return per_shard(kernel, (q, k, v) + seg,
+                         {0: DATA_AXIS, 2: MODEL_AXIS})
+    _LAST_BACKEND["attention"] = "xla"
     if use_pallas:
-        try:
-            from ..ops.pallas.flash_attention import (
-                BLOCK_K, BLOCK_Q, flash_attention,
-                flash_attention_segmented, flash_attention_supported)
-            if flash_attention_supported(q.shape):
-                fwd, bwd = _flash_dispatch(q.shape, q.dtype)
-                bq, bk = fwd if fwd is not None else (BLOCK_Q, BLOCK_K)
-                if segment_ids is not None:
-                    return flash_attention_segmented(
-                        q, k, v, segment_ids, True, None, bq, bk, bwd)
-                return flash_attention(q, k, v, True, None, bq, bk, bwd)
-        except ImportError:
-            pass
+        note_xla_on_tpu(
+            "causal_attention",
+            f"[B, S, H, D] = {tuple(q.shape)}: the flash kernel needs a "
+            f"head dim of 64/128/256 and a sequence some 128-multiple "
+            f"block divides")
     B, S, H, D = q.shape
     scale = 1.0 / math.sqrt(D)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -826,11 +839,8 @@ def fused_lm_head_loss(x, wte, labels, ignore_index=-100, chunk_rows=None):
     n = xs.shape[0]
     n_pad = (-n) % chunk_rows
     if n_pad:
-        # pad_tail, NOT concatenate: jax 0.4.37's partitioner miscompiles
-        # concat-with-replicated-fill on sharded operands (see compat.py)
-        from ..compat import pad_tail
-        xs = pad_tail(xs, n_pad, 0)
-        ts = pad_tail(ts, n_pad, ignore_index)
+        xs = jnp.pad(xs, ((0, n_pad), (0, 0)))
+        ts = jnp.pad(ts, (0, n_pad), constant_values=ignore_index)
     n_chunks = xs.shape[0] // chunk_rows
     xs = xs.reshape(n_chunks, chunk_rows, H)
     ts = ts.reshape(n_chunks, chunk_rows)

@@ -15,17 +15,25 @@ def dispatch_report():
 
     Keys (present once the corresponding kernel has dispatched):
     ``flash``: {"fwd": (bq, bk), "fwd_variant", "dkv", "dq",
-    "bwd_variant"}; ``decode_attention``: {"decode": backend,
-    "decode_kv": pool dtype — "int8" when the paged pools are
-    quantized}; ``quant_matmul``: {"quant_matmul": backend} for the
-    int8 weight-only matmul.
+    "bwd_variant"}; ``attention``: {"attention" / "sparse_attention":
+    backend} of the model-side dispatchers; ``decode_attention``:
+    {"decode": backend, "decode_kv": pool dtype — "int8" when the paged
+    pools are quantized}; ``quant_matmul`` / ``grouped_matmul``:
+    {name: backend}. A backend is "pallas" (the kernel, interpreted off
+    a TPU) or "xla". ``xla_on_tpu`` names every dispatcher that, on a
+    TPU, took XLA where it has a kernel (`note_xla_on_tpu`).
     """
     from .pallas.decode_attention import _LAST_BACKEND
-    from .pallas.flash_attention import _LAST_BLOCKS
+    from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
+    from .pallas.flash_attention import _LAST_BLOCKS, _XLA_NOTED
+    from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
     from .pallas.quant_matmul import _LAST_BACKEND as _QMM_BACKEND
     return {"flash": dict(_LAST_BLOCKS),
+            "attention": dict(_ATTN_BACKEND),
             "decode_attention": dict(_LAST_BACKEND),
-            "quant_matmul": dict(_QMM_BACKEND)}
+            "quant_matmul": dict(_QMM_BACKEND),
+            "grouped_matmul": dict(_GMM_BACKEND),
+            "xla_on_tpu": sorted(_XLA_NOTED)}
 
 
 __all__ = ["adam", "lamb", "op_builder", "pallas", "sparse_attention",

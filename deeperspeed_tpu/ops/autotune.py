@@ -16,7 +16,8 @@ ordinary shapes — EXCEPT long sequences: at or beyond
 `flash_tune_min_seq()` (default 8192, `DS_FLASH_TUNE_MIN_SEQ`) the
 `flash_blocks_for` dispatch always measures, because the one-time probe
 is noise next to a single long-context step and the static default
-geometry was the measured MFU cliff there (BENCH_r05).
+geometry was an MFU cliff there (claimed before PR 1 from a record
+deleted at PR 22; not measured in this round).
 """
 
 import functools
@@ -157,8 +158,9 @@ _MAX_TUNE_BYTES = 1 << 30
 
 # Sequences at or above this always take the measured block pick, even
 # without DS_TPU_AUTOTUNE=1: at 16k-32k the default square geometry was
-# the measured long-context MFU cliff (BENCH_r05 0.21 vs 0.61 at 1k) and
-# a one-time per-process probe is noise next to a single long-seq step.
+# a long-context MFU cliff (an older claim; not measured in this round)
+# and a one-time per-process probe is noise next to a single long-seq
+# step.
 _TUNE_MIN_SEQ_ENV = "DS_FLASH_TUNE_MIN_SEQ"
 
 
@@ -170,12 +172,11 @@ def flash_tune_min_seq():
 # Compile-time memory screening (tentpole: the (remat policy × batch)
 # bench ladder pre-screens rungs with `compiled.memory_analysis()` before
 # spending a timed run — an AOT lower+compile over abstract shapes costs
-# seconds and zero HBM, an OOM'd rung costs a subprocess, a 30 s zombie-
-# buffer grace, and a retry).
+# seconds and zero HBM, an OOM'd rung costs a whole row subprocess).
 # ---------------------------------------------------------------------------
 
 # Per-generation HBM capacities (spec sheet), used when the runtime does
-# not report `bytes_limit` (e.g. tunneled backends).
+# not report `bytes_limit`.
 _HBM_BYTES_BY_KIND = {
     "v5 lite": 16 << 30, "v5e": 16 << 30,
     "v5p": 95 << 30,

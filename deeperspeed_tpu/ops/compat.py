@@ -8,15 +8,6 @@ prints this matrix (reference `env_report.py:23`).
 """
 
 
-def _on_tpu():
-    import jax
-    try:
-        return jax.default_backend() == "tpu" or \
-            "TPU" in str(jax.devices()[0])
-    except Exception:
-        return False
-
-
 def fused_adam_available():
     from .adam.fused_adam import FusedAdam  # noqa: F401
     return True

@@ -33,9 +33,10 @@ mechanism as the compacted causal grids in `flash_attention.py`. Pages
 at or past a sequence's length skip all compute (`pl.when`); the last
 grid step writes ``acc / l``. No backward exists: decode is inference.
 
-On non-TPU backends the kernel runs in interpreter mode (slow,
-test-only); `paged_decode_attention` defaults to the XLA fallback there,
-a gather + masked softmax with identical semantics.
+Off a TPU the kernel runs in interpreter mode (slow, test-only) and
+`paged_decode_attention` defaults to the XLA form there, a gather +
+masked softmax with identical semantics. Which one ran is recorded in
+`_LAST_BACKEND`; XLA chosen on a TPU is logged by name.
 """
 
 import functools
@@ -47,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...compat import CompilerParams
-from .flash_attention import LANES, NEG_INF, _interpret
+from .flash_attention import LANES, NEG_INF, _interpret, note_xla_on_tpu
 
 _DIMSEM = CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -86,13 +87,25 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                    ks_ref=None, vs_ref=None):
     """One (batch row, head, page) step of paged flash decode. With
     int8 pools (`ks_ref`/`vs_ref` scale blocks, resolved through the
-    SAME page-table LUT as the data blocks), the K/V tiles dequantize
-    right after the DMA — the wire moved 1 byte/element, the math runs
-    fp32."""
+    SAME page-table LUT as the data blocks), the per-slot scales fold
+    into the [1, ps] score / probability rows — ``q·(k·s) == (q·k)·s``
+    — so the wire moved 1 byte/element and no [ps, 1] scale column (a
+    lane→sublane relayout) is ever built; the math runs fp32."""
     b = pl.program_id(0)
+    h = pl.program_id(1)
     p = pl.program_id(2)
     n_pages = pl.num_programs(2)
     length = len_ref[b]
+
+    def scale_row(ref):
+        # the block is the page's whole [Hk, ps] scale tile (Mosaic only
+        # takes a block whose last two dims are the array's own or
+        # (8, 128)-multiples); pick this head's row by mask-and-reduce,
+        # which needs no dynamic sublane slice of a packed bf16 tile
+        tile = ref[...].astype(jnp.float32)                    # [Hk, ps]
+        rows = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+        return jnp.sum(jnp.where(rows == h, tile, 0.0), axis=0,
+                       keepdims=True)                          # [1, ps]
 
     @pl.when(p == 0)
     def _init():
@@ -102,15 +115,17 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p * page_size < length)
     def _compute():
-        q = q_ref[0, 0].reshape(1, -1)                         # [1, D]
-        k = k_ref[0, 0]                                        # [ps, D]
+        q = q_ref[...]                                         # [1, D]
+        k = k_ref[...]                                         # [ps, D]
         if ks_ref is not None:
             q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * \
-                ks_ref[0, 0].astype(jnp.float32)[:, None]
+            k = k.astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale     # [1, ps]
+            preferred_element_type=jnp.float32)                # [1, ps]
+        if ks_ref is not None:
+            s = s * scale_row(ks_ref)
+        s = s * sm_scale
         pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + \
             p * page_size
         s = jnp.where(pos < length, s, NEG_INF)
@@ -129,14 +144,13 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
         if vs_ref is not None:
-            v = v_ref[0, 0].astype(jnp.float32) * \
-                vs_ref[0, 0].astype(jnp.float32)[:, None]      # [ps, D]
             pv = jax.lax.dot_general(
-                prob, v, (((1,), (0,)), ((), ())),
+                prob * scale_row(vs_ref), v_ref[...].astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)            # [1, D]
         else:
             pv = jax.lax.dot_general(
-                prob.astype(v_ref.dtype), v_ref[0, 0],
+                prob.astype(v_ref.dtype), v_ref[...],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)            # [1, D]
         acc_scr[:] = acc_scr[:] * alpha + pv
@@ -146,7 +160,7 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         # inactive rows (length 0) never accumulated: acc == 0 → out 0
-        o_ref[0, 0] = (acc_scr[:] / l_safe).reshape(-1).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
 def _decode_kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
@@ -162,25 +176,29 @@ def _decode_kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
 def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
                                   sm_scale, k_scales=None, v_scales=None):
     B, H, D = q.shape
-    page_size = k_pages.shape[2]
+    Hk, page_size = k_pages.shape[1:3]
     NP = page_table.shape[1]
     quant = k_scales is not None
-    pool_spec = pl.BlockSpec((1, 1, page_size, D),
+    # Mosaic takes a block only if its last two dims are (8, 128)-
+    # multiples or the array's own. The query/out rows therefore ride as
+    # [B, H, 1, D] (a [1, D] block over a [1, D] minor plane) and the
+    # scale block is the page's whole [Hk, ps] plane; `None` dims are
+    # squeezed out of the kernel's refs.
+    row_spec = pl.BlockSpec((None, None, 1, D),
+                            lambda b, h, p, pt, ln: (b, h, 0, 0))
+    pool_spec = pl.BlockSpec((None, None, page_size, D),
                              lambda b, h, p, pt, ln: (pt[b, p], h, 0, 0))
     # the scale pool rides the SAME scalar-prefetch LUT that resolves
     # the data pool's page indirection — one page id, two DMAs
-    scale_spec = pl.BlockSpec((1, 1, page_size),
-                              lambda b, h, p, pt, ln: (pt[b, p], h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, D), lambda b, h, p, pt, ln: (b, h, 0)),
-        pool_spec, pool_spec,
-    ]
-    args = [q, k_pages, v_pages]
+    scale_spec = pl.BlockSpec((None, Hk, page_size),
+                              lambda b, h, p, pt, ln: (pt[b, p], 0, 0))
+    in_specs = [row_spec, pool_spec, pool_spec]
+    args = [q[:, :, None, :], k_pages, v_pages]
     kernel_fn = _decode_kernel
     if quant:
         in_specs += [scale_spec, scale_spec]
         # scale pools stay at their storage dtype (bf16) on the wire;
-        # the kernel widens each [ps] tile in VMEM — a whole-pool fp32
+        # the kernel widens each tile in VMEM — a whole-pool fp32
         # cast here would materialize a pool-sized copy every step
         args += [k_scales, v_scales]
         kernel_fn = _decode_kernel_quant
@@ -188,13 +206,12 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
                                page_size=page_size)
     call = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, NP),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, D),
-                                   lambda b, h, p, pt, ln: (b, h, 0)),
+            out_specs=row_spec,
             scratch_shapes=[
                 pltpu.VMEM((1, LANES), jnp.float32),
                 pltpu.VMEM((1, LANES), jnp.float32),
@@ -205,7 +222,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         interpret=_interpret(),
     )
     return call(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-                *args)
+                *args)[:, :, 0, :]
 
 
 def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
@@ -291,6 +308,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         backend = ("pallas" if on_tpu and
                    paged_decode_supported(D, page_size, quantized=quant)
                    else "xla")
+        if backend == "xla":
+            note_xla_on_tpu(
+                "paged_decode_attention",
+                f"head dim {D}, page size {page_size}, int8 pools "
+                f"{quant}: the kernel needs a head dim of 64/128/256 and "
+                f"a page size that is a multiple of "
+                f"{32 if quant else 8}")
     _LAST_BACKEND["decode"] = backend
     _LAST_BACKEND["decode_kv"] = "int8" if quant else str(k_pages.dtype)
     _log_first_dispatch()

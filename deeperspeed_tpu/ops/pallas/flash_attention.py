@@ -34,7 +34,7 @@ blocks, so dead tiles generate no HBM traffic at all. See
 `causal_grid_maps` for the schedule and `docs/long-context.md` for the
 design.
 
-On non-TPU backends the kernels run in interpreter mode (slow, test-only).
+Off a TPU the kernels run in interpreter mode (slow, test-only).
 """
 
 import functools
@@ -61,8 +61,29 @@ _DIMSEM_FLAT = CompilerParams(
 
 
 def _interpret():
-    return jax.default_backend() not in ("tpu",) and \
-        "TPU" not in str(jax.devices()[0])
+    """True off a TPU: the kernels then run in the Pallas interpreter,
+    which exists for the CPU tests and for nothing else."""
+    return jax.default_backend() != "tpu"
+
+
+# Kernel-or-XLA choice of the most recent attention dispatch
+# ({"attention": "pallas" | "xla"}); `decode_attention`, `grouped_matmul`
+# and `quant_matmul` keep the same record for their own dispatchers and
+# `ops.dispatch_report()` reads them all.
+_LAST_BACKEND = {}
+_XLA_NOTED = set()
+
+
+def note_xla_on_tpu(op, why):
+    """A dispatcher on a TPU took XLA where a Pallas kernel exists: say
+    so once per op, by name. Off a TPU XLA is the expected stand-in and
+    nothing is logged."""
+    if _interpret() or op in _XLA_NOTED:
+        return
+    _XLA_NOTED.add(op)
+    from ...utils.logging import logger
+    logger.warning(f"ops.dispatch {op}: running on XLA, not the Pallas "
+                   f"kernel, on a TPU ({why})")
 
 
 def _fit_block(block, s):
@@ -188,8 +209,8 @@ def _tiled_call(kernel, compact, grid, in_specs, out_specs, scratch,
 
 def flash_attention_supported(shape, block_q=BLOCK_Q, block_k=BLOCK_K):
     """Kernel constraints: seq divisible by some 128-multiple block ≤ the
-    requested size, MXU-friendly head dim. Callers fall back to the XLA
-    path otherwise."""
+    requested size, MXU-friendly head dim. Callers take the XLA path
+    otherwise and record it (`_LAST_BACKEND`, `note_xla_on_tpu`)."""
     b, s, h, d = shape
     return _fit_block(block_q, s) > 0 and _fit_block(block_k, s) > 0 and \
         d in (64, 128, 256)
@@ -615,18 +636,10 @@ def _tag_residuals(out, lse):
     `attn_residuals` policy (`save_only_these_names(ds_attn_out,
     ds_attn_lse)`), both survive the remat boundary, so the backward
     kernels consume saved tensors and this forward kernel never re-runs
-    during the backward replay.
-
-    Inside `shard_map` with the replication check on (the SP ring
-    call sites), jax 0.4.37 has no rep rule for the `name` primitive —
-    the tags are dropped there and `attn_residuals` degrades to
-    recompute for that region."""
+    during the backward replay."""
     from jax.ad_checkpoint import checkpoint_name
-    try:
-        return (checkpoint_name(out, "ds_attn_out"),
-                checkpoint_name(lse, "ds_attn_lse"))
-    except NotImplementedError:
-        return out, lse
+    return (checkpoint_name(out, "ds_attn_out"),
+            checkpoint_name(lse, "ds_attn_lse"))
 
 
 def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,

@@ -48,12 +48,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...compat import CompilerParams
-from .flash_attention import _interpret
+from .flash_attention import _interpret, note_xla_on_tpu
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
 
 _DIMSEM = CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+# Backend ("pallas"/"xla") of the most recent grouped_matmul dispatch —
+# the same record `decode_attention._LAST_BACKEND` keeps.
+_LAST_BACKEND = {}
 
 
 class GmmSpec(NamedTuple):
@@ -323,9 +328,21 @@ def grouped_matmul(x, w, group_sizes, span, lut=None, block_m=None,
         raise ValueError(f"group_sizes shape {group_sizes.shape} != ({G},)")
 
     if backend is None:
+        from ...parallel.mesh import ambient_auto_mesh
         on_tpu = not _interpret()
-        backend = ("pallas" if on_tpu and grouped_matmul_supported(K, N, span)
-                   else "xla")
+        # GSPMD cannot partition a Mosaic kernel, and the sorted-row
+        # buffer has no dim to split per shard: under a multi-device
+        # mesh the kernel is for `shard_map` callers (moe.MoELayer)
+        partitioned = ambient_auto_mesh() is not None
+        backend = ("pallas" if on_tpu and not partitioned and
+                   grouped_matmul_supported(K, N, span) else "xla")
+        if backend == "xla":
+            note_xla_on_tpu(
+                "grouped_matmul",
+                f"K={K}, N={N}, span={span}, under a GSPMD-partitioned "
+                f"mesh {partitioned}: the kernel needs 128-aligned K and "
+                f"N, an 8-aligned span, and one device or a shard_map")
+    _LAST_BACKEND["grouped_matmul"] = backend
     if backend == "xla":
         return grouped_matmul_xla(x, w, group_sizes, span, lut_t)
     if backend != "pallas":

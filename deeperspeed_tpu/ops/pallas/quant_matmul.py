@@ -42,7 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...compat import CompilerParams
-from .flash_attention import _interpret
+from .flash_attention import _interpret, note_xla_on_tpu
 
 _DIMSEM = CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -235,6 +235,12 @@ def quant_matmul(x, qw, backend=None, blocks=None):
         fits = quant_matmul_supported(M, K, N, _fit(bm, M, 8),
                                       _fit(bk, K, 32), _fit(bn, N, 128))
         backend = "pallas" if on_tpu and fits else "xla"
+        if backend == "xla":
+            note_xla_on_tpu(
+                "quant_matmul",
+                f"[M, K, N] = {(M, K, N)} with blocks {(bm, bk, bn)}: "
+                f"the fitted blocks must tile the operands at the int8 "
+                f"(32, 128) tile")
     _LAST_BACKEND["quant_matmul"] = backend
     _log_first_dispatch()
     if backend == "xla":

@@ -167,10 +167,20 @@ class SparseSelfAttention:
         use_kernel = (kernel is not None and d in (64, 128, 256)
                       and rpe is None and key_padding_mask is None
                       and attn_mask is None)
+        from ...parallel.mesh import DATA_AXIS, per_shard
+        from ..pallas.flash_attention import _LAST_BACKEND, note_xla_on_tpu
+        _LAST_BACKEND["sparse_attention"] = "pallas" if use_kernel else "xla"
         if use_kernel:
             kernel = self._autotuned_kernel(s, kernel, query)
-            out = kernel(query, key, value)
+            # the layout is per head, so only the batch splits per shard
+            out = per_shard(kernel, (query, key, value), {0: DATA_AXIS})
         else:
+            note_xla_on_tpu(
+                "sparse_self_attention",
+                f"seq {s}, block {self.block}, head dim {d}, rpe/masks "
+                f"{rpe is not None or key_padding_mask is not None or attn_mask is not None}"
+                f": the block-sparse kernels need 128-multiples, a head "
+                f"dim of 64/128/256 and no rpe or mask operand")
             # The reference's own three-op pipeline (sdd → block softmax
             # → dsd, `sparse_self_attention.py:150-170`): compute scales
             # with active blocks and every mask/rpe option applies.

@@ -24,10 +24,17 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..pallas.flash_attention import (flash_attention,
+from ...parallel.mesh import (DATA_AXIS, MODEL_AXIS, ambient_auto_mesh,
+                              per_shard)
+from ..pallas.flash_attention import (_LAST_BACKEND, flash_attention,
                                       flash_attention_kbias,
                                       flash_attention_supported,
-                                      flash_attention_train)
+                                      flash_attention_train,
+                                      note_xla_on_tpu)
+
+
+# [B, S, H, D] attention is independent over batch and heads
+_ATTN_SHARD_DIMS = {0: DATA_AXIS, 2: MODEL_AXIS}
 
 
 class TransformerConfig:
@@ -261,18 +268,24 @@ class DeepSpeedTransformerLayer:
                                         flash_bwd_blocks_for)
                 from ..pallas.flash_attention import (
                     BLOCK_K, BLOCK_Q, flash_attention_segmented)
-                # same tuned geometry + min-seq gating as the dense
-                # branch below: the static square default was the
-                # measured long-context MFU cliff, and packed encoder
-                # batches hit the identical kernels
-                shape = (b, s, heads, hd)
-                blocks = flash_blocks_for(shape, q.dtype, False)
-                bq, bk = blocks if blocks is not None \
-                    else (BLOCK_Q, BLOCK_K)
-                bwd = flash_bwd_blocks_for(shape, q.dtype, False,
-                                           fwd_blocks=blocks)
-                ctx = flash_attention_segmented(q, k, v, segment_ids,
-                                                False, None, bq, bk, bwd)
+                _LAST_BACKEND["attention"] = "pallas"
+
+                def seg_kernel(q, k, v, seg):
+                    # same tuned geometry + min-seq gating as the dense
+                    # branch below: the static square default was the
+                    # measured long-context MFU cliff, and packed
+                    # encoder batches hit the identical kernels. Shapes
+                    # are the shard's when `per_shard` splits the call.
+                    blocks = flash_blocks_for(q.shape, q.dtype, False)
+                    bq, bk = blocks if blocks is not None \
+                        else (BLOCK_Q, BLOCK_K)
+                    bwd = flash_bwd_blocks_for(q.shape, q.dtype, False,
+                                               fwd_blocks=blocks)
+                    return flash_attention_segmented(
+                        q, k, v, seg, False, None, bq, bk, bwd)
+
+                ctx = per_shard(seg_kernel, (q, k, v, segment_ids),
+                                _ATTN_SHARD_DIMS)
                 ctx = ctx.reshape(b, s, h)
                 return ctx @ params["attn_ow"].astype(x.dtype) + \
                     params["attn_ob"].astype(x.dtype)
@@ -281,29 +294,51 @@ class DeepSpeedTransformerLayer:
                 segment_ids[:, None, None, :], 0.0, -1e30)  # [B,1,S,S]
             additive_mask = seg_pen if additive_mask is None else \
                 additive_mask + seg_pen
+        # the in-kernel dropout keys its mask on the kernel-local
+        # batch·head index, so a call split per shard would repeat one
+        # mask on every shard: under a multi-device mesh it takes XLA
+        drop_unsharded = attn_drop_active and \
+            ambient_auto_mesh() is not None
         if segment_ids is None and \
                 (additive_mask is None or kbias is not None) and \
-                s >= _flash_min_seq() and \
+                s >= _flash_min_seq() and not drop_unsharded and \
                 flash_attention_supported((b, s, heads, hd)):
-            # measured block geometry for long sequences (and opt-in
-            # autotune runs); None keeps the static default — the fused
-            # 16k/32k paths previously hard-coded 1024x1024 here
             from ..autotune import flash_blocks_for
             from ..pallas.flash_attention import BLOCK_K, BLOCK_Q
-            blocks = flash_blocks_for((b, s, heads, hd), q.dtype, False)
-            bq, bk = blocks if blocks is not None else (BLOCK_Q, BLOCK_K)
-            if attn_drop_active:
+            _LAST_BACKEND["attention"] = "pallas"
+
+            def blocks_for(q):
+                # measured block geometry for long sequences (and opt-in
+                # autotune runs); None keeps the static default. The
+                # shape is the shard's when `per_shard` splits the call.
+                blocks = flash_blocks_for(q.shape, q.dtype, False)
+                return blocks if blocks is not None else (BLOCK_Q, BLOCK_K)
+
+            def kernel(q, k, v, *kb):
+                if not kb:
+                    return flash_attention(q, k, v, False, None,
+                                           *blocks_for(q))
+                return flash_attention_kbias(q, k, v, kb[0], False, None,
+                                             *blocks_for(q))
+
+            if attn_drop_active:    # one device: see drop_unsharded
+                bq, bk = blocks_for(q)
                 seed = jax.random.randint(rng, (1,), 0, 2**31 - 1,
                                           dtype=jnp.int32)
                 ctx = flash_attention_train(
                     q, k, v, kbias, seed, block_q=bq, block_k=bk,
                     dropout_rate=float(cfg.attn_dropout_ratio))
-            elif kbias is None:
-                ctx = flash_attention(q, k, v, False, None, bq, bk)
             else:
-                ctx = flash_attention_kbias(q, k, v, kbias, False, None,
-                                            bq, bk)
+                kb = () if kbias is None else (kbias,)
+                ctx = per_shard(kernel, (q, k, v) + kb, _ATTN_SHARD_DIMS)
         else:
+            _LAST_BACKEND["attention"] = "xla"
+            note_xla_on_tpu(
+                "transformer_attention",
+                f"[B, S, H, D] = {(b, s, heads, hd)}, min flash seq "
+                f"{_flash_min_seq()}, full-rank mask "
+                f"{additive_mask is not None and kbias is None}, dropout "
+                f"under a multi-device mesh {drop_unsharded}")
             scale = 1.0 / math.sqrt(hd)
             logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                                 preferred_element_type=jnp.float32) * scale

@@ -42,6 +42,49 @@ def build_mesh(topology=None, devices=None, axes=None, dims=None):
     return Mesh(dev_array, axis_names=tuple(topology.get_axis_names()))
 
 
+def ambient_auto_mesh():
+    """The mesh a traced function runs under, if GSPMD is partitioning it
+    over more than one device: the abstract mesh the tracing engine
+    declared (`jax.sharding.use_abstract_mesh`; `DeepSpeedEngine._jit`
+    does), or None — no mesh declared, one device, or already inside a
+    `shard_map` (manual axes), where code runs per shard anyway."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return None
+    return mesh
+
+
+def per_shard(kernel, args, dim_axes):
+    """``kernel(*args)`` for a Pallas kernel call under GSPMD.
+
+    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    a kernel traced under a multi-device mesh runs inside a `shard_map`
+    over the dims along which its work is independent. ``dim_axes`` maps
+    an array dim to the canonical mesh axis that may shard it —
+    ``{0: DATA_AXIS, 2: MODEL_AXIS}`` for [B, S, H, D] attention; every
+    arg (and the one output) is split on those of its dims that exist
+    and divide evenly, and replicated otherwise. With no multi-device
+    mesh ambient (`ambient_auto_mesh`) this is the plain call."""
+    mesh = ambient_auto_mesh()
+    if mesh is None:
+        return kernel(*args)
+    shape = args[0].shape
+    split = {dim: axis for dim, axis in dim_axes.items()
+             if axis in mesh.axis_names and mesh.shape[axis] > 1
+             and shape[dim] % mesh.shape[axis] == 0}
+    if not split:
+        return kernel(*args)
+
+    def spec(x):
+        return PartitionSpec(*[split.get(d) if d < x.ndim and
+                               x.shape[d] == shape[d] else None
+                               for d in range(x.ndim)])
+
+    return jax.shard_map(kernel, in_specs=tuple(spec(a) for a in args),
+                         out_specs=spec(args[0]), check_vma=False)(*args)
+
+
 def data_parallel_sharding(mesh, spec=None):
     """Sharding for a batch: leading dim split over every data-like axis."""
     if spec is None:

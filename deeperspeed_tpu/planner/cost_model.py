@@ -23,8 +23,9 @@ from ..profiling.hardware import (COLLECTIVE_LATENCY_S,
                                   ici_bandwidth_per_chip,
                                   peak_flops_per_chip)
 
-# Achievable fraction of peak for dense bf16 transformer compute — the
-# repo's measured headline MFU band (BENCH_r05: 0.607 at 125m).
+# Achievable fraction of peak for dense bf16 transformer compute — a
+# guess until a four-chip cell calibrates it (not measured in this
+# round).
 BASE_EFFICIENCY = 0.6
 
 # Full-remat recomputes the forward inside the backward: fwd(1) +

@@ -23,7 +23,7 @@ from .plan import PLAN_VERSION, Plan, cached_plan
 # readable/loggable; axes with measured flat spots are thinned.
 # DEFAULT-FIRST ordering on every axis: the analytic ladder's stable
 # sort resolves exact ties (e.g. world=1, where all collective terms
-# are zero) toward the hand-tuned BENCH_r05 defaults, so an
+# are zero) toward the hand-tuned defaults, so an
 # analytic-only plan never regresses the known-good config on axes the
 # model cannot separate — only a measured probe may move off them.
 MODES = ("explicit", "gspmd")

@@ -22,10 +22,6 @@ PEAK_FLOPS_BY_KIND = {
     "v6": 918e12, "v6e": 918e12,
 }
 
-# Conservative default when the kind is unknown (also what CPU test runs
-# resolve to — their MFU scalars are meaningless but well-defined).
-PEAK_FLOPS_DEFAULT = 197e12
-
 
 # Per-chip ICI all-gather/reduce-scatter bandwidth in bytes/s (public
 # spec sheet aggregate link bandwidth, derated to a sustained-collective
@@ -39,33 +35,35 @@ ICI_BANDWIDTH_BY_KIND = {
     "v6": 360e9, "v6e": 360e9,
 }
 
-# CPU/unknown backends: a deliberately low figure so the planner treats
-# collectives as expensive and prefers overlap-friendly schedules there.
-ICI_BANDWIDTH_DEFAULT = 10e9
-
 # Fixed per-collective launch/latency cost (seconds). Prices the
 # many-tiny-buckets failure mode: a 1 MB bucket ladder pays this per
 # bucket and loses to fewer, fatter buckets on the analytic ladder.
 COLLECTIVE_LATENCY_S = 5e-6
 
 
-def _by_kind(device, table, default):
+def _by_kind(device, table, what):
     kind = getattr(device, "device_kind", None)
     if kind is None:
         kind = str(device)
-    kind = (kind or "").lower()
+    low = kind.lower()
     for key, val in table.items():
-        if key in kind:
+        if key in low:
             return val
-    return default
+    raise ValueError(
+        f"no {what} known for device kind {kind!r}: add it to "
+        f"deeperspeed_tpu/profiling/hardware.py (known: "
+        f"{', '.join(sorted(table))}). A device outside the table is an "
+        f"error, never a default.")
 
 
 def peak_flops_per_chip(device):
-    """bf16 peak FLOPS for a jax device (or a device-kind string)."""
-    return _by_kind(device, PEAK_FLOPS_BY_KIND, PEAK_FLOPS_DEFAULT)
+    """bf16 peak FLOPS for a jax device (or a device-kind string);
+    raises ValueError for a kind the table does not hold."""
+    return _by_kind(device, PEAK_FLOPS_BY_KIND, "bf16 peak FLOPS")
 
 
 def ici_bandwidth_per_chip(device):
     """Sustained per-chip collective bandwidth (bytes/s) for a jax
-    device or a device-kind string."""
-    return _by_kind(device, ICI_BANDWIDTH_BY_KIND, ICI_BANDWIDTH_DEFAULT)
+    device or a device-kind string; raises ValueError for a kind the
+    table does not hold."""
+    return _by_kind(device, ICI_BANDWIDTH_BY_KIND, "ICI bandwidth")

@@ -52,17 +52,9 @@ ATTN_LSE_NAME = "ds_attn_lse"
 
 def tag_attn_residual(x, name=ATTN_OUT_NAME):
     """Mark an attention residual for name-based remat policies. A no-op
-    outside `jax.checkpoint` spans (and for policies that ignore names).
-
-    Inside `shard_map` with the replication check on, jax 0.4.37 has no
-    rep rule for the `name` primitive and raises at trace time — the tag
-    is dropped there (name-based policies then degrade to recompute for
-    that region; every other policy is unaffected)."""
+    outside `jax.checkpoint` spans (and for policies that ignore names)."""
     from jax.ad_checkpoint import checkpoint_name
-    try:
-        return checkpoint_name(x, name)
-    except NotImplementedError:
-        return x
+    return checkpoint_name(x, name)
 
 
 def make_remat_policy(name, offload_src="device", offload_dst="pinned_host"):
@@ -94,13 +86,8 @@ def make_remat_policy(name, offload_src="device", offload_dst="pinned_host"):
     if name == "attn_residuals":
         return cp.save_only_these_names(ATTN_OUT_NAME, ATTN_LSE_NAME), True
     if name == "offload_dots":
-        offload = getattr(cp, "offload_dot_with_no_batch_dims", None)
-        if offload is None:  # pragma: no cover - old-jax fallback
-            logger.warning(
-                "offload_dot_with_no_batch_dims unavailable on this jax; "
-                "remat policy 'offload_dots' degrades to on-device 'dots'")
-            return cp.dots_with_no_batch_dims_saveable, True
-        return offload(offload_src, offload_dst), True
+        return cp.offload_dot_with_no_batch_dims(offload_src,
+                                                 offload_dst), True
     raise ValueError(
         f"unknown remat policy {name!r}; valid choices: "
         f"{', '.join(REMAT_POLICY_CHOICES)}")

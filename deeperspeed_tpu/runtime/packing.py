@@ -3,7 +3,7 @@
 Real corpora are document mixtures, not fixed-length sequences: padding
 every document to the attention window burns flash-kernel flops on pad
 tokens and on cross-document attention that contributes nothing to the
-loss (BENCH_r05's longseq rows pay full n² work regardless of content).
+loss (a dense long-sequence row pays full n² work regardless of content).
 This module packs documents into fixed [S]-token rows and emits the
 metadata the segment-aware attention stack consumes:
 

@@ -458,11 +458,19 @@ class Telemetry:
                      ranks=[0])
 
     def _peak(self):
+        """bf16 peak FLOPS of this engine's device, or None when its
+        kind has no row in `profiling.hardware` (the CPU, say): the MFU
+        scalars are then left out — said once in the log — and never
+        priced against some other chip's peak."""
         if self._peak_flops is None:
             from ..profiling.hardware import peak_flops_per_chip
-            dev = self.devices[0] if self.devices else None
-            self._peak_flops = peak_flops_per_chip(dev)
-        return self._peak_flops
+            try:
+                self._peak_flops = peak_flops_per_chip(
+                    self.devices[0] if self.devices else None)
+            except ValueError as e:
+                logger.warning(f"telemetry: MFU scalars left out — {e}")
+                self._peak_flops = 0.0
+        return self._peak_flops or None
 
     # ------------------------------------------------------------------
     # step hooks
@@ -532,7 +540,8 @@ class Telemetry:
         if self.mfu_enabled and flops and dt > 0:
             achieved = flops / dt          # per-device FLOPS/s
             scalars["Train/Samples/achieved_tflops"] = achieved / 1e12
-            scalars["Train/Samples/mfu"] = achieved / self._peak()
+            if self._peak():
+                scalars["Train/Samples/mfu"] = achieved / self._peak()
 
         if tokens is not None and dt > 0:
             eff, total = tokens
@@ -543,7 +552,7 @@ class Telemetry:
             if self._tokens_total:
                 scalars["Train/Goodput/effective_token_fraction"] = (
                     self._tokens_effective / self._tokens_total)
-            if self.mfu_enabled and flops and total:
+            if self.mfu_enabled and flops and total and self._peak():
                 # MFU counting only loss-bearing tokens as productive:
                 # the raw scalar times flops the kernels BURNED; this
                 # one credits only the fraction the loss consumed

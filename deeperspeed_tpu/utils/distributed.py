@@ -118,22 +118,10 @@ def init_distributed(dist_backend="xla", auto_mpi_discovery=True,
     kwargs = {}
     if timeout is not None:
         kwargs["initialization_timeout"] = int(float(timeout))
-    try:
-        jax.distributed.initialize(
-            coordinator_address=f"{addr}:{port}",
-            num_processes=world_size,
-            process_id=rank, **kwargs)
-    except TypeError:
-        # older jax without initialization_timeout: rendezvous is
-        # unbounded, but barrier() deadlines below still apply
-        if kwargs:
-            logger.warning("this jax version does not accept "
-                           "initialization_timeout; rendezvous will not "
-                           "time out")
-        jax.distributed.initialize(
-            coordinator_address=f"{addr}:{port}",
-            num_processes=world_size,
-            process_id=rank)
+    jax.distributed.initialize(
+        coordinator_address=f"{addr}:{port}",
+        num_processes=world_size,
+        process_id=rank, **kwargs)
     _initialized = True
 
 

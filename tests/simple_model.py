@@ -98,3 +98,11 @@ def tied_pipeline_module(dim=16, num_stages=2):
     ]
     return PipelineModule(layers=specs, num_stages=num_stages,
                           loss_fn=mse_loss)
+
+
+def price_mfu_against_v5e(engine):
+    """The CPU has no row in `profiling.hardware`, so telemetry leaves
+    the MFU scalars out there; a test of the MFU arithmetic names the
+    chip it prices against."""
+    from deeperspeed_tpu.profiling.hardware import peak_flops_per_chip
+    engine.telemetry._peak_flops = peak_flops_per_chip("TPU v5 lite")

@@ -750,9 +750,42 @@ class TestDispatchReport:
     def test_accessor_shape(self):
         from deeperspeed_tpu.ops import dispatch_report
         report = dispatch_report()
-        assert set(report) == {"flash", "decode_attention",
-                               "quant_matmul"}
+        assert set(report) == {"flash", "attention", "decode_attention",
+                               "quant_matmul", "grouped_matmul",
+                               "xla_on_tpu"}
         assert isinstance(report["flash"], dict)
+
+    @pytest.mark.parametrize("head_dim,want", [(64, "pallas"), (96, "xla")])
+    def test_attention_records_backend_and_names_xla_on_a_tpu(
+            self, ds_logs, monkeypatch, head_dim, want):
+        """Every dispatch records kernel-or-XLA; an unsupported shape
+        (head dim 96, the NeoX-20B preset) takes XLA, and on a TPU that
+        is logged once, by name — never silently. The XLA branch runs
+        anywhere, so the test only steers what `note_xla_on_tpu` sees."""
+        import importlib
+
+        import jax.numpy as jnp
+
+        from deeperspeed_tpu.models.gpt_neox import causal_attention
+        from deeperspeed_tpu.ops import dispatch_report
+        fa = importlib.import_module(
+            "deeperspeed_tpu.ops.pallas.flash_attention")
+        monkeypatch.setattr(fa, "_XLA_NOTED", set())
+        if want == "xla":
+            monkeypatch.setattr(fa, "_interpret", lambda: False)
+        q = jnp.ones((1, 128, 2, head_dim), jnp.float32)
+        for _ in range(2):
+            out = causal_attention(q, q, q)
+        assert out.shape == q.shape
+        report = dispatch_report()
+        assert report["attention"]["attention"] == want
+        named = [r for r in ds_logs.records
+                 if "causal_attention: running on XLA" in r.getMessage()]
+        if want == "xla":
+            assert report["xla_on_tpu"] == ["causal_attention"]
+            assert len(named) == 1 and "96" in named[0].getMessage()
+        else:
+            assert report["xla_on_tpu"] == [] and not named
 
     def test_decode_records_backend_and_logs_once(self, ds_logs):
         caplog = ds_logs

@@ -193,7 +193,7 @@ def test_build_plan_warm_cache_skips_probes(tmp_path):
         calls.append(cand)
         return jnp.zeros(())
 
-    kwargs = dict(device_kind="cpu", world=1, top_k=3,
+    kwargs = dict(device_kind="TPU v5 lite", world=1, top_k=3,
                   probe=probe, measurable=True,
                   cache_dir=str(tmp_path))
     plan = build_plan(shape, tuner=Autotuner(warmup=0, iters=1),
@@ -214,7 +214,7 @@ def test_build_plan_warm_cache_skips_probes(tmp_path):
 
 
 def test_build_plan_analytic_only_without_probe(tmp_path):
-    plan = build_plan(_mini_shape(), device_kind="cpu", world=1,
+    plan = build_plan(_mini_shape(), device_kind="TPU v5 lite", world=1,
                       cache_dir=str(tmp_path),
                       tuner=Autotuner(warmup=0, iters=1))
     assert not plan.probed
@@ -261,7 +261,7 @@ def test_missing_plan_file_raises():
 
 
 def test_config_consumes_plan_user_keys_win(tmp_path):
-    plan = build_plan(_mini_shape(), device_kind="cpu", world=1,
+    plan = build_plan(_mini_shape(), device_kind="TPU v5 lite", world=1,
                       cache_dir=str(tmp_path), save=False,
                       tuner=Autotuner(warmup=0, iters=1))
     path = plan.save(path=str(tmp_path / "plan.json"))
@@ -294,7 +294,7 @@ def test_plan_explicit_mode_degrades_for_hookless_model(tmp_path):
     warning at engine init; a USER-set "explicit" stays a hard error."""
     import deeperspeed_tpu
     from simple_model import SimpleModel
-    plan = build_plan(_mini_shape(), device_kind="cpu", world=1,
+    plan = build_plan(_mini_shape(), device_kind="TPU v5 lite", world=1,
                       cache_dir=str(tmp_path), save=False,
                       tuner=Autotuner(warmup=0, iters=1))
     assert plan.config["zero_optimization"]["schedule"]["mode"] == \
@@ -342,8 +342,14 @@ def test_device_kind_mismatch_warns_or_raises(tmp_path):
 # ds_plan CLI
 # ---------------------------------------------------------------------------
 
-def test_ds_plan_cli_json_and_show(tmp_path, capsys):
+def test_ds_plan_cli_json_and_show(tmp_path, capsys, monkeypatch):
+    from deeperspeed_tpu.ops import autotune
     from deeperspeed_tpu.planner.cli import main
+    # the CLI plans for the chip it runs on, and the CPU has no row in
+    # the hardware table (asserted below): the test names the chip
+    with pytest.raises(ValueError, match="never a default"):
+        main(["--preset", "125m", "--cache-dir", str(tmp_path), "--json"])
+    monkeypatch.setattr(autotune, "_device_kind", lambda: "TPU v5 lite")
     rc = main(["--preset", "125m", "--cache-dir", str(tmp_path),
                "--json"])
     assert rc == 0
@@ -375,7 +381,7 @@ def test_env_report_surfaces_plan_fingerprint(tmp_path, monkeypatch):
     from deeperspeed_tpu.env_report import env_fingerprint
     monkeypatch.setenv("DS_PLAN_CACHE", str(tmp_path))
     assert env_fingerprint()["plan_fingerprint"] is None
-    plan = build_plan(_mini_shape(), device_kind="cpu", world=1,
+    plan = build_plan(_mini_shape(), device_kind="TPU v5 lite", world=1,
                       cache_dir=str(tmp_path),
                       tuner=Autotuner(warmup=0, iters=1))
     assert env_fingerprint()["plan_fingerprint"] == plan.fingerprint

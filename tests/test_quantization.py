@@ -422,7 +422,7 @@ class TestCompressedComm:
                                                    WORLD)
             return out[None], new_e[None]
 
-        f = shard_map(body, mesh,
+        f = shard_map(body, mesh=mesh,
                       in_specs=(P("data"), P("data")),
                       out_specs=(P("data"), P("data")),
                       check_vma=False)
@@ -490,7 +490,7 @@ class TestCompressedComm:
                 x[0], we[0], se[0], "data", WORLD, n_valid=n_valid)
             return out[None], nwe[None], nse[None]
 
-        f = shard_map(body, mesh,
+        f = shard_map(body, mesh=mesh,
                       in_specs=(P("data"), P("data"), P("data")),
                       out_specs=(P("data"), P("data"), P("data")),
                       check_vma=False)
@@ -560,7 +560,7 @@ class TestEfGather:
             row_bar, new_err = jax.grad(f, argnums=(0, 1))(row[0], werr)
             return row_bar[None], new_err
 
-        f = shard_map(body, mesh,
+        f = shard_map(body, mesh=mesh,
                       in_specs=(P("data"), P("data"), P("data")),
                       out_specs=(P("data"), P("data")),
                       check_vma=False)
@@ -603,7 +603,7 @@ class TestEfGather:
             row_bar, new_err = jax.grad(f, argnums=(0, 1))(row[0], werr)
             return row_bar[None], new_err
 
-        f = shard_map(body, mesh,
+        f = shard_map(body, mesh=mesh,
                       in_specs=(P("data"), P("data"), P("data")),
                       out_specs=(P("data"), P("data")),
                       check_vma=False)

@@ -21,7 +21,8 @@ from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
 from deeperspeed_tpu.runtime.monitor import TensorBoardMonitor
 from deeperspeed_tpu.utils.timer import (SynchronizedWallClockTimer,
                                          ThroughputTimer)
-from tests.simple_model import SimpleModel, random_batches, random_dataset
+from tests.simple_model import (SimpleModel, price_mfu_against_v5e,
+                                random_batches, random_dataset)
 
 HIDDEN = 16
 BATCH = 8
@@ -316,6 +317,7 @@ def test_mfu_flops_match_profile_fn(tmp_path, devices):
                      "job_name": "unit"},
         telemetry=tel(),
     ))
+    price_mfu_against_v5e(engine)
     batches = list(random_batches(3, BATCH, HIDDEN, seed=3))
     for b in batches:
         engine.train_batch(batch=stack1(b))
@@ -336,10 +338,33 @@ def test_mfu_flops_match_profile_fn(tmp_path, devices):
     assert len(mfu) == 3
     assert all(v > 0 for _, v in mfu)
     # scalar consistency: mfu * peak * step_time == flops (same series)
-    peak = peak_flops_per_chip(jax.devices()[0])
+    peak = peak_flops_per_chip("TPU v5 lite")
     tflops = scalars["Train/Samples/achieved_tflops"]
     for (_, m), (_, t) in zip(mfu, tflops):
         assert m == pytest.approx(t * 1e12 / peak, rel=1e-4)
+
+
+def test_mfu_left_out_on_a_device_outside_the_table(tmp_path, devices):
+    """The CPU is not in `profiling.hardware`: the lookup raises, and
+    the engine emits achieved TFLOP/s without an MFU priced against
+    some other chip's peak."""
+    from deeperspeed_tpu.profiling.hardware import (
+        ici_bandwidth_per_chip, peak_flops_per_chip)
+    for lookup in (peak_flops_per_chip, ici_bandwidth_per_chip):
+        with pytest.raises(ValueError, match="never a default"):
+            lookup(jax.devices()[0])
+        assert lookup("TPU v5 lite") > 0
+    engine = make_engine(cfg(
+        tensorboard={"enabled": True, "output_path": str(tmp_path),
+                     "job_name": "unit"},
+        telemetry=tel(),
+    ))
+    for b in random_batches(2, BATCH, HIDDEN, seed=3):
+        engine.train_batch(batch=stack1(b))
+    engine.monitor.flush()
+    scalars = _read_scalars(os.path.join(str(tmp_path), "unit"))
+    assert len(scalars["Train/Samples/achieved_tflops"]) == 2
+    assert "Train/Samples/mfu" not in scalars
 
 
 def test_mfu_aot_survives_sharding_settle(tmp_path, devices):
@@ -474,6 +499,7 @@ def test_mfu_covers_train_steps_window(tmp_path, devices):
                      "job_name": "unit"},
         telemetry=tel(),
     ))
+    price_mfu_against_v5e(engine)
     rng = np.random.default_rng(0)
     x = rng.normal(size=(3, 1, BATCH, HIDDEN)).astype(np.float32)
     y = rng.normal(size=(3, 1, BATCH, 1)).astype(np.float32)

@@ -28,6 +28,7 @@ from deeperspeed_tpu.parallel.schedule import (offload_layer_plan,
                                                pack_plan_rows,
                                                unpack_plan_row)
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.simple_model import price_mfu_against_v5e
 
 pytestmark = pytest.mark.offload
 
@@ -295,6 +296,7 @@ TEL = {"telemetry": {"enabled": True, "goodput": True, "mfu": True}}
 class TestTieredTelemetry:
     def test_offload_scalars_and_mfu(self, devices):
         e = _engine({**tiered(), **TEL})
+        price_mfu_against_v5e(e)
         rec = Recorder()
         e.telemetry.monitor = rec
         _train(e, steps=2)
@@ -334,6 +336,7 @@ class TestTieredTelemetry:
         """PR 6 left host-offload tiers at MFU `none`; the grads-step
         AOT harvest fixes the bench comparability gap."""
         e = _engine({**OFFLOAD_BASE, **TEL})
+        price_mfu_against_v5e(e)
         rec = Recorder()
         e.telemetry.monitor = rec
         _train(e, steps=2)
@@ -344,6 +347,7 @@ class TestTieredTelemetry:
         e = _engine({"zero_optimization": {
             "stage": 3, "offload_optimizer": {"device": "cpu"},
             "offload_param": {"device": "cpu"}}, **TEL})
+        price_mfu_against_v5e(e)
         rec = Recorder()
         e.telemetry.monitor = rec
         _train(e, steps=2)

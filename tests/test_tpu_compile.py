@@ -1,0 +1,277 @@
+"""Every Pallas kernel, compiled for a TPU v5e from this CPU sandbox.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (`jax.experimental.topologies`). Interpret mode
+has no tiling rules, so a kernel can pass every CPU test and still be
+refused by Mosaic — `paged_decode_attention_pallas` was, from PR 8 to
+PR 22. Each case lowers one kernel at a shape the main path really runs,
+with `_interpret` forced off, compiles it for `v5e:2x2` and asserts that
+the compiled text holds a `tpu_custom_call`. Nothing runs: a compile
+that passes says nothing about results or times.
+
+The persistent compile cache is turned off around the compiles — an
+executable compiled for a described chip is written to the cache but
+cannot be read back without one.
+"""
+
+import functools
+import importlib
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+# `ops.pallas` re-exports several functions under their module's own
+# name (`flash_attention`, `grouped_matmul`, ...): fetch modules by path
+(fa, decode_attention, block_sparse_attention, grouped_matmul, quant_matmul,
+ optimizer) = KERNEL_MODULES = tuple(
+    importlib.import_module(f"deeperspeed_tpu.ops.pallas.{name}")
+    for name in ("flash_attention", "decode_attention",
+                 "block_sparse_attention", "grouped_matmul", "quant_matmul",
+                 "optimizer"))
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    """The four described chips of a v5e 2x2 host; skipped where the
+    topology cannot be described (no libtpu)."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 - any failure means no compiler
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture
+def on_chip(monkeypatch, v5e_2x2):
+    """`compile_for_chip(fn, *shape_dtypes, sharding=one chip)` →
+    compiled text, with every kernel module's `_interpret` forced off
+    and the compile cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    for mod in KERNEL_MODULES:
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def compile_for_chip(fn, *args, sharding=one_chip):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+                for shape, dtype in args]
+        # a fresh lambda: never a trace cached in interpret mode
+        return jax.jit(lambda *a: fn(*a)).lower(*args).compile().as_text()
+
+    yield compile_for_chip
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+def assert_kernel(text, at_least=1):
+    assert text.count("tpu_custom_call") >= at_least, \
+        "the compiled program holds no Mosaic kernel"
+
+
+def qkv(b, s, h, d):
+    return [((b, s, h, d), BF16)] * 3
+
+
+def loss_of(fn):
+    """Scalar fp32 loss of an attention callable, for the backward."""
+    return lambda *a: fn(*a).astype(jnp.float32).sum()
+
+
+# ---------------------------------------------------------------------------
+# flash attention
+# ---------------------------------------------------------------------------
+
+# (name, [B, S, H, D], causal): the trainer's shape, the long-context
+# shape, and the single-block / dense-grid variants of the same kernels
+FLASH_SHAPES = [
+    ("train_2x2048", (2, 2048, 12, 64), True),
+    ("smoke_8x2048", (8, 2048, 12, 64), True),
+    ("long_1x16384", (1, 16384, 12, 64), True),
+    ("single_block_4x1024", (4, 1024, 12, 64), True),
+    ("dense_grid_2x2048", (2, 2048, 16, 64), False),
+]
+
+
+@pytest.mark.parametrize("name,shape,causal", FLASH_SHAPES,
+                         ids=[s[0] for s in FLASH_SHAPES])
+def test_flash_forward_compiles(on_chip, name, shape, causal):
+    assert_kernel(on_chip(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal), *qkv(*shape)))
+
+
+@pytest.mark.parametrize("name,shape,causal", FLASH_SHAPES,
+                         ids=[s[0] for s in FLASH_SHAPES])
+def test_flash_backward_compiles(on_chip, name, shape, causal):
+    grad = jax.grad(loss_of(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal)),
+        argnums=(0, 1, 2))
+    # forward + the dkv/dq kernels (fused into one on a single block)
+    assert_kernel(on_chip(grad, *qkv(*shape)), at_least=2)
+
+
+# the serving prefill buckets (`InferenceEngine._prefill_fn` masks pad
+# rows through segment ids) and the packed-training shape
+@pytest.mark.parametrize("shape", [(4, 128, 12, 64), (4, 1024, 12, 64),
+                                   (2, 2048, 12, 64)],
+                         ids=["prefill_128", "prefill_1024", "packed_2048"])
+def test_segmented_flash_compiles(on_chip, shape):
+    seg = ((shape[0], shape[1]), jnp.int32)
+    assert_kernel(on_chip(
+        lambda q, k, v, s: fa.flash_attention_segmented(q, k, v, s, True),
+        *qkv(*shape), seg))
+    grad = jax.grad(loss_of(
+        lambda q, k, v, s: fa.flash_attention_segmented(q, k, v, s, True)),
+        argnums=(0, 1, 2))
+    assert_kernel(on_chip(grad, *qkv(*shape), seg), at_least=2)
+
+
+def test_flash_key_bias_and_dropout_compile(on_chip):
+    """The BERT-side variants: per-key additive bias, and in-kernel
+    attention dropout from a seed."""
+    shape = (4, 512, 16, 64)
+    kbias = ((4, 512), jnp.float32)
+    grad = jax.grad(loss_of(
+        lambda q, k, v, b: fa.flash_attention_kbias(q, k, v, b)),
+        argnums=(0, 1, 2))
+    assert_kernel(on_chip(grad, *qkv(*shape), kbias), at_least=2)
+    grad = jax.grad(loss_of(
+        lambda q, k, v, b, s: fa.flash_attention_train(
+            q, k, v, b, s, dropout_rate=0.1)), argnums=(0, 1, 2))
+    assert_kernel(on_chip(grad, *qkv(*shape), kbias, ((1,), jnp.int32)),
+                  at_least=2)
+
+
+def test_masked_flash_compiles(on_chip):
+    """Dense-flash iteration under a block-activity map (the sparse
+    engine's arm above the density crossover)."""
+    n = 2048 // 128
+    layout = np.tril(np.ones((n, n), np.int32))[None].repeat(12, axis=0)
+    kernel = fa.make_masked_flash_attention(layout, causal=True)
+    grad = jax.grad(loss_of(kernel), argnums=(0, 1, 2))
+    assert_kernel(on_chip(grad, *qkv(2, 2048, 12, 64)), at_least=2)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention (the serving engine's every decode step)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("page_size,quant", [(16, False), (64, False),
+                                             (32, True), (64, True)],
+                         ids=["bf16_page16", "bf16_page64", "int8_page32",
+                              "int8_page64"])
+def test_paged_decode_compiles(on_chip, page_size, quant):
+    B, H, D, P = 8, 12, 64, 513
+    n_pages = 2048 // page_size
+    pool = ((P, H, page_size, D), jnp.int8 if quant else BF16)
+    args = [((B, H, D), BF16), pool, pool, ((B, n_pages), jnp.int32),
+            ((B,), jnp.int32)]
+    if quant:
+        args += [((P, H, page_size), BF16)] * 2
+    assert decode_attention.paged_decode_supported(D, page_size, quant)
+
+    def decode(q, k, v, table, lengths, *scales):
+        return decode_attention.paged_decode_attention_pallas(
+            q, k, v, table, lengths, 0.125, *scales)
+
+    assert_kernel(on_chip(decode, *args))
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul, int8 weight matmul, fused Adam
+# ---------------------------------------------------------------------------
+
+def test_grouped_matmul_compiles(on_chip):
+    """8 experts, 768 → 3072, span 512: forward, and the backward's dx
+    (the same kernel against wᵀ) and dw kernels."""
+    E, K, N, span = 8, 768, 3072, 512
+    args = [((E * span, K), BF16), ((E, K, N), BF16), ((E,), jnp.int32)]
+    assert grouped_matmul.grouped_matmul_supported(K, N, span)
+
+    def gmm(x, w, sizes):
+        return grouped_matmul.grouped_matmul(x, w, sizes, span,
+                                             backend="pallas")
+
+    assert_kernel(on_chip(gmm, *args))
+    grad = jax.grad(lambda x, w, s: gmm(x, w, s).astype(jnp.float32).sum(),
+                    argnums=(0, 1))
+    assert_kernel(on_chip(grad, *args), at_least=2)
+
+
+@pytest.mark.parametrize("m,k,n", [(8, 768, 3072), (256, 768, 3072),
+                                   (8, 6144, 24576)],
+                         ids=["decode_768x3072", "prefill_768x3072",
+                              "decode_6144x24576"])
+def test_quant_matmul_compiles(on_chip, m, k, n):
+    def qmm(x, qval, scale):
+        return quant_matmul.quant_matmul_pallas(
+            x, quant_matmul.QuantizedWeight(qval, scale))
+
+    assert_kernel(on_chip(qmm, ((m, k), BF16), ((k, n), jnp.int8),
+                          ((n,), jnp.float32)))
+
+
+def test_fused_adam_compiles(on_chip):
+    n = 4 * 1024 * 1024
+    flat = ((n,), jnp.float32)
+    adam = functools.partial(optimizer.fused_adam_flat.__wrapped__,
+                             adam_w=True, bias_correction=True)
+    assert_kernel(on_chip(adam, ((n,), BF16), flat, flat, flat,
+                          ((), jnp.float32), ((), jnp.int32)))
+
+
+# ---------------------------------------------------------------------------
+# block-sparse attention
+# ---------------------------------------------------------------------------
+
+def test_block_sparse_compiles(on_chip):
+    """The LUT block-skipping kernels (forward, dkv, dq) under a causal
+    local + global layout at seq 2048."""
+    n = 2048 // 128
+    rows = np.arange(n)
+    layout = (np.abs(rows[:, None] - rows[None, :]) <= 2) | \
+        (rows[None, :] == 0)
+    layout = np.tril(layout).astype(np.int32)[None].repeat(12, axis=0)
+    kernel = block_sparse_attention.BlockSparseAttention(
+        layout, block=128, causal=True)
+    assert_kernel(on_chip(kernel, *qkv(2, 2048, 12, 64)))
+    grad = jax.grad(loss_of(kernel), argnums=(0, 1, 2))
+    assert_kernel(on_chip(grad, *qkv(2, 2048, 12, 64)), at_least=3)
+
+
+# ---------------------------------------------------------------------------
+# four chips: GSPMD cannot partition a Mosaic kernel
+# ---------------------------------------------------------------------------
+
+def test_attention_runs_per_shard_under_a_mesh(on_chip, v5e_2x2):
+    """Traced under a multi-device mesh, a Pallas call is refused by the
+    TPU compiler unless it sits in a `shard_map` — which the attention
+    dispatcher does once the tracing engine has declared its mesh
+    (`DeepSpeedEngine._jit`); the four-chip smoke run stands on this."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deeperspeed_tpu.models.gpt_neox import causal_attention
+    mesh = Mesh(np.asarray(v5e_2x2), ("data",))
+    batch_sharded = NamedSharding(mesh, P("data"))
+    shape = qkv(8, 2048, 12, 64)
+    grad = jax.grad(loss_of(causal_attention), argnums=(0, 1, 2))
+
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        on_chip(grad, *shape, sharding=batch_sharded)
+
+    def declared(*a):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return grad(*a)
+
+    text = on_chip(declared, *shape, sharding=batch_sharded)
+    assert_kernel(text, at_least=2)
