@@ -1,0 +1,5 @@
+from benchmarks.metrics._shared import stat_share_of_window
+
+
+def read(rec):
+    return stat_share_of_window(rec, "readback_s")

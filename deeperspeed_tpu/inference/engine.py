@@ -34,6 +34,7 @@ config seed folded with the step counter — the same request stream
 always produces the same tokens.
 """
 
+import contextlib
 import math
 import random
 import time
@@ -46,6 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import scopes
 from ..compat import shard_map
 from ..models import gpt2 as gpt2_mod
 from ..models import gpt_neox as neox
@@ -105,6 +107,7 @@ class _Family:
                 f"InferenceEngine serves the GPT-NeoX / GPT-2 families; "
                 f"got {type(model).__name__}")
 
+    @scopes.scoped("ds.embed")
     def embed_prefill(self, params, tokens):
         """tokens [B, S] → [B, S, H] at absolute positions 0..S-1."""
         x = params["embed"]["wte"][tokens]
@@ -112,6 +115,7 @@ class _Family:
             x = x + params["embed"]["wpe"][:tokens.shape[1]][None]
         return x
 
+    @scopes.scoped("ds.embed")
     def embed_decode(self, params, tokens, positions):
         """tokens [B] at absolute `positions` [B] → [B, 1, H]."""
         x = params["embed"]["wte"][tokens][:, None, :]
@@ -119,6 +123,7 @@ class _Family:
             x = x + params["embed"]["wpe"][positions][:, None, :]
         return x
 
+    @scopes.scoped("ds.embed")
     def embed_at(self, params, tokens, positions):
         """tokens [B, S] at per-token absolute `positions` [B, S] →
         [B, S, H] (the chunk programs: a window starting mid-sequence)."""
@@ -140,6 +145,7 @@ class _Family:
         ([B, S, rot], ...) — `apply_rotary` takes the 3-D form."""
         return (self._cos[positions], self._sin[positions], self.rot_dim)
 
+    @scopes.scoped("ds.lm_head")
     def head(self, params, h):
         """Final-norm hidden [B, H] → logits [B, V] (fp32)."""
         if self.kind == "gpt2":
@@ -149,6 +155,7 @@ class _Family:
         return jnp.einsum("bh,vh->bv", h, wte.astype(h.dtype),
                           preferred_element_type=jnp.float32)
 
+    @scopes.scoped("ds.lm_head")
     def head_all(self, params, h):
         """Final-norm hidden [B, S, H] → logits [B, S, V] (fp32) —
         the speculative verify needs every window position's logits."""
@@ -410,6 +417,12 @@ class InferenceEngine:
                       "evictions": 0, "finished": 0,
                       "schedule_s": 0.0, "prefill_s": 0.0,
                       "decode_s": 0.0, "admission_wait_s": 0.0,
+                      # the host phases inside prefill_s + decode_s
+                      # (`_phase`), and the context tokens the decode
+                      # steps attended over: what the paged kernel reads
+                      "build_inputs_s": 0.0, "dispatch_s": 0.0,
+                      "readback_s": 0.0, "complete_s": 0.0,
+                      "decode_kv_tokens": 0,
                       "queue_depth": 0.0, "page_pool_util": 0.0,
                       # terminal-status set: every request reaches
                       # exactly one (docs/inference.md)
@@ -612,6 +625,7 @@ class InferenceEngine:
                       else 1)
         return total
 
+    @scopes.scoped("ds.sample")
     def _sample(self, logits, rng):
         if self.temperature <= 0.0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -683,7 +697,8 @@ class InferenceEngine:
                     segment_ids=seg)
                 return y, kv
 
-            x, (ks, vs) = jax.lax.scan(body, x, stacked)
+            with scopes.scope("ds.layers"):
+                x, (ks, vs) = jax.lax.scan(body, x, stacked)
 
             # whole-page scatter: [B, S, H, D] → B·S/ps page tiles at
             # the page-table ids (pad rows hold table id 0 — the trash
@@ -705,8 +720,9 @@ class InferenceEngine:
                             sc.astype(pool.scale.dtype)))
                 return pool.at[flat_pt].set(tiles.astype(pool.dtype))
 
-            k_pool = jax.vmap(write)(k_pool, ks)
-            v_pool = jax.vmap(write)(v_pool, vs)
+            with scopes.scope("ds.kv_write"):
+                k_pool = jax.vmap(write)(k_pool, ks)
+                v_pool = jax.vmap(write)(v_pool, vs)
 
             idx = jnp.clip(lengths - 1, 0, S - 1)
             h_last = x[jnp.arange(B), idx][:, None, :]
@@ -741,6 +757,7 @@ class InferenceEngine:
                 page_table, (pos // ps)[:, None], axis=1)[:, 0]
             slot = pos % ps
 
+            @scopes.scoped("ds.kv_write")
             def store(pool, vec):
                 """One decoded token's K or V row into its page slot;
                 int8 pools quantize per (head) vector and land the
@@ -754,24 +771,27 @@ class InferenceEngine:
                 return pool.at[page_idx, :, slot].set(
                     vec.astype(pool.dtype))
 
+            @scopes.scoped("ds.block")
             def body(carry, xs):
                 bp, kp, vp = xs
                 q, k, v = neox._block_qkv(cfg, bp, carry, cos, sin,
                                           rot_dim, H)
                 kp = store(kp, k[:, 0])
                 vp = store(vp, v[:, 0])
-                qrow = q[:, 0] if isinstance(kp, QuantizedPages) \
-                    else q[:, 0].astype(kp.dtype)
-                attn = self._attention(qrow, kp, vp,
-                                       page_table, lengths)
-                attn = attn.astype(carry.dtype)
+                with scopes.scope("ds.attn"):
+                    qrow = q[:, 0] if isinstance(kp, QuantizedPages) \
+                        else q[:, 0].astype(kp.dtype)
+                    attn = self._attention(qrow, kp, vp,
+                                           page_table, lengths)
+                    attn = attn.astype(carry.dtype)
                 out = neox._block_post_attn(
                     cfg, bp, carry, attn.reshape(B, 1, H * D),
                     reduce_fn=lambda t: t)
                 return out, (kp, vp)
 
-            x, (k_pool, v_pool) = jax.lax.scan(
-                body, x, (stacked, k_pool, v_pool))
+            with scopes.scope("ds.layers"):
+                x, (k_pool, v_pool) = jax.lax.scan(
+                    body, x, (stacked, k_pool, v_pool))
             h = neox.layer_norm(x, params["final_ln"]["scale"],
                                 params["final_ln"]["bias"],
                                 cfg.layernorm_eps)
@@ -830,6 +850,7 @@ class InferenceEngine:
             # 0..p; invalid rows see nothing (safe-softmax zeros them)
             qpos = jnp.where(valid, pos_c, -1)
 
+            @scopes.scoped("ds.kv_write")
             def store(pool, new):
                 """Window K/V rows [B, S, H, D] into their (page, slot)
                 cells; int8 pools quantize per (head, token) vector —
@@ -854,6 +875,7 @@ class InferenceEngine:
                     d = pool[page_table]
                 return jnp.moveaxis(d, 2, 1).reshape(B, H, NP * ps, D)
 
+            @scopes.scoped("ds.attn_xla")
             def attend(q, kp, vp):
                 k = gather(kp)
                 v = gather(vp)
@@ -877,6 +899,7 @@ class InferenceEngine:
                                  preferred_element_type=jnp.float32)
                 return jnp.moveaxis(out, 1, 2).reshape(B, S, H * D)
 
+            @scopes.scoped("ds.block")
             def body(carry, xs):
                 bp, kp, vp = xs
                 q, k, v = neox._block_qkv(cfg, bp, carry, cos, sin,
@@ -885,13 +908,15 @@ class InferenceEngine:
                 # causal masking (qpos) keeps attention autoregressive
                 kp = store(kp, k)
                 vp = store(vp, v)
-                attn = attend(q, kp, vp).astype(carry.dtype)
+                with scopes.scope("ds.attn"):
+                    attn = attend(q, kp, vp).astype(carry.dtype)
                 out = neox._block_post_attn(cfg, bp, carry, attn,
                                             reduce_fn=lambda t: t)
                 return out, (kp, vp)
 
-            x, (k_pool, v_pool) = jax.lax.scan(
-                body, x, (stacked, k_pool, v_pool))
+            with scopes.scope("ds.layers"):
+                x, (k_pool, v_pool) = jax.lax.scan(
+                    body, x, (stacked, k_pool, v_pool))
             if mode == "write":
                 return k_pool, v_pool
             if mode == "sample":
@@ -907,10 +932,12 @@ class InferenceEngine:
                                 params["final_ln"]["bias"],
                                 cfg.layernorm_eps)
             logits = fam.head_all(params, h)
-            if self.temperature <= 0.0:
-                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                out = jax.nn.softmax(logits / self.temperature, axis=-1)
+            with scopes.scope("ds.sample"):
+                if self.temperature <= 0.0:
+                    out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                else:
+                    out = jax.nn.softmax(logits / self.temperature,
+                                         axis=-1)
             return out, k_pool, v_pool
 
         fn = jax.jit(chunk, donate_argnums=(6, 7))
@@ -954,6 +981,7 @@ class InferenceEngine:
                 slot = pos % ps
                 att_len = jnp.where(active, pos + 1, 0)
 
+                @scopes.scoped("ds.kv_write")
                 def store(pool, vec, page_idx=page_idx, slot=slot):
                     if isinstance(pool, QuantizedPages):
                         q8, sc = quantize_kv(vec)
@@ -964,6 +992,7 @@ class InferenceEngine:
                     return pool.at[page_idx, :, slot].set(
                         vec.astype(pool.dtype))
 
+                @scopes.scoped("ds.block")
                 def body(carry, xs, cos=cos, sin=sin, rot_dim=rot_dim,
                          store=store, att_len=att_len):
                     bp, kp, vp = xs
@@ -971,24 +1000,27 @@ class InferenceEngine:
                                               rot_dim, H)
                     kp = store(kp, k[:, 0])
                     vp = store(vp, v[:, 0])
-                    qrow = q[:, 0] if isinstance(kp, QuantizedPages) \
-                        else q[:, 0].astype(kp.dtype)
-                    attn = self._attention(qrow, kp, vp, page_table,
-                                           att_len)
-                    attn = attn.astype(carry.dtype)
+                    with scopes.scope("ds.attn"):
+                        qrow = q[:, 0] if isinstance(kp, QuantizedPages) \
+                            else q[:, 0].astype(kp.dtype)
+                        attn = self._attention(qrow, kp, vp, page_table,
+                                               att_len)
+                        attn = attn.astype(carry.dtype)
                     out = neox._block_post_attn(
                         cfg, bp, carry, attn.reshape(B, 1, H * D),
                         reduce_fn=lambda t: t)
                     return out, (kp, vp)
 
-                x, (k_pool, v_pool) = jax.lax.scan(
-                    body, x, (stacked, k_pool, v_pool))
+                with scopes.scope("ds.layers"):
+                    x, (k_pool, v_pool) = jax.lax.scan(
+                        body, x, (stacked, k_pool, v_pool))
                 if j < k_steps:
                     h = neox.layer_norm(x, params["final_ln"]["scale"],
                                         params["final_ln"]["bias"],
                                         cfg.layernorm_eps)
                     logits = fam.head(params, h[:, 0])
-                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    with scopes.scope("ds.sample"):
+                        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     proposed.append(tok)
             return jnp.stack(proposed, axis=1), k_pool, v_pool
 
@@ -1626,15 +1658,41 @@ class InferenceEngine:
         the registrant's draft K/V and are not rewritten. The rng slot
         is dead in write mode — a constant key keeps the target
         sampling stream identical to a non-speculative run."""
-        tokens, start, n_new, pt = self._chunk_arrays(reqs, B, S)
+        with self._phase("build_inputs"):
+            args = [jnp.asarray(a)
+                    for a in self._chunk_arrays(reqs, B, S)]
         fn = self._chunk_fn(B, S, "draft", "write")
-        self.draft_cache.k, self.draft_cache.v = fn(
-            self.draft_params, self.draft_stacked, jnp.asarray(tokens),
-            jnp.asarray(start), jnp.asarray(n_new), jnp.asarray(pt),
-            self.draft_cache.k, self.draft_cache.v, jax.random.PRNGKey(0))
+        with self._phase("dispatch"):
+            self.draft_cache.k, self.draft_cache.v = fn(
+                self.draft_params, self.draft_stacked, *args,
+                self.draft_cache.k, self.draft_cache.v,
+                jax.random.PRNGKey(0))
 
-    def _complete_prefills(self, reqs, nxt):
-        now = time.perf_counter()
+    @contextlib.contextmanager
+    def _phase(self, name):
+        """One host phase of a serve step, inside the step's `prefill` or
+        `decode` span: `build_inputs` (numpy arrays and their puts),
+        `dispatch` (the call of the compiled program, which returns when
+        it is enqueued), `readback` (the wait for the device and the
+        transfer of the sampled tokens), `complete` (scheduler
+        bookkeeping and latency observations). A telemetry span of that
+        name, and its seconds in `stats[name + "_s"]`."""
+        t0 = time.perf_counter()
+        try:
+            with self.telemetry.span(name):
+                yield
+        finally:
+            self.stats[name + "_s"] += time.perf_counter() - t0
+
+    def _readback(self, arr):
+        """The step's small result on the host, and the time it got
+        there: the one stamp this step's tokens carry (TTFT, inter-token
+        gaps, `last_token_at`)."""
+        with self._phase("readback"):
+            out = np.asarray(arr)
+        return out, time.perf_counter()
+
+    def _complete_prefills(self, reqs, nxt, now):
         for i, req in enumerate(reqs):
             self.scheduler.complete_prefill(req, int(nxt[i]))
             # TTFT: once per request, from the ORIGINAL submit — an
@@ -1655,54 +1713,59 @@ class InferenceEngine:
             # prefix-cache hit batch: suffix-only window through the
             # chunk program (the full-prefill scatter would overwrite
             # the shared pages other requests are reading)
-            tokens, start, n_new, pt = self._chunk_arrays(
-                plan.prefills, B, S)
+            with self._phase("build_inputs"):
+                args = [jnp.asarray(a) for a in self._chunk_arrays(
+                    plan.prefills, B, S)]
             fn = self._chunk_fn(B, S, "target", "sample")
-            nxt, self.cache.k, self.cache.v = fn(
-                self.params, self.params_stacked, jnp.asarray(tokens),
-                jnp.asarray(start), jnp.asarray(n_new), jnp.asarray(pt),
-                self.cache.k, self.cache.v, self._next_rng())
         else:
-            n_pages_row = S // self.page_size
-            tokens = np.zeros((B, S), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            page_table = np.zeros((B, n_pages_row), np.int32)
-            for i, req in enumerate(plan.prefills):
-                ctx = req.context
-                tokens[i, :len(ctx)] = ctx
-                lengths[i] = len(ctx)
-                page_table[i, :len(req.pages)] = req.pages
+            with self._phase("build_inputs"):
+                n_pages_row = S // self.page_size
+                tokens = np.zeros((B, S), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                page_table = np.zeros((B, n_pages_row), np.int32)
+                for i, req in enumerate(plan.prefills):
+                    ctx = req.context
+                    tokens[i, :len(ctx)] = ctx
+                    lengths[i] = len(ctx)
+                    page_table[i, :len(req.pages)] = req.pages
+                args = [jnp.asarray(a)
+                        for a in (tokens, lengths, page_table)]
             fn = self._prefill_fn(B, S)
+        with self._phase("dispatch"):
             nxt, self.cache.k, self.cache.v = fn(
-                self.params, self.params_stacked, jnp.asarray(tokens),
-                jnp.asarray(lengths), jnp.asarray(page_table), self.cache.k,
+                self.params, self.params_stacked, *args, self.cache.k,
                 self.cache.v, self._next_rng())
         if self.spec_k:
             self._draft_prefill_twin(plan.prefills, B, S)
-        self._complete_prefills(plan.prefills, np.asarray(nxt))
+        nxt, now = self._readback(nxt)
+        with self._phase("complete"):
+            self._complete_prefills(plan.prefills, nxt, now)
 
     def _run_decode(self, plan):
         B = plan.decode_batch
-        tokens = np.zeros((B,), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        page_table = np.zeros((B, self.n_pages_max), np.int32)
-        for i, req in enumerate(plan.decodes):
-            tokens[i] = req.generated[-1]
-            lengths[i] = req.cached + 1
-            page_table[i, :len(req.pages)] = req.pages
+        with self._phase("build_inputs"):
+            tokens = np.zeros((B,), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            page_table = np.zeros((B, self.n_pages_max), np.int32)
+            for i, req in enumerate(plan.decodes):
+                tokens[i] = req.generated[-1]
+                lengths[i] = req.cached + 1
+                page_table[i, :len(req.pages)] = req.pages
+            self.stats["decode_kv_tokens"] += int(lengths.sum())
+            args = [jnp.asarray(a) for a in (tokens, lengths, page_table)]
         fn = self._decode_fn(B)
-        nxt, self.cache.k, self.cache.v = fn(
-            self.params, self.params_stacked, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(page_table), self.cache.k,
-            self.cache.v, self._next_rng())
-        nxt = np.asarray(nxt)
-        now = time.perf_counter()
-        for i, req in enumerate(plan.decodes):
-            self.scheduler.complete_decode(req, int(nxt[i]))
-            if req.last_token_at is not None:
-                self.request_metrics.observe_inter_token(
-                    now - req.last_token_at)
-            req.last_token_at = now
+        with self._phase("dispatch"):
+            nxt, self.cache.k, self.cache.v = fn(
+                self.params, self.params_stacked, *args, self.cache.k,
+                self.cache.v, self._next_rng())
+        nxt, now = self._readback(nxt)
+        with self._phase("complete"):
+            for i, req in enumerate(plan.decodes):
+                self.scheduler.complete_decode(req, int(nxt[i]))
+                if req.last_token_at is not None:
+                    self.request_metrics.observe_inter_token(
+                        now - req.last_token_at)
+                req.last_token_at = now
         return len(plan.decodes)
 
     # ------------------------------------------------------------------
@@ -1762,61 +1825,68 @@ class InferenceEngine:
         Returns the number of tokens appended across the batch."""
         B = plan.decode_batch
         reqs = plan.decodes
-        tokens = np.zeros((B,), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        windows = np.full((B,), -1, np.int32)
-        page_table = np.zeros((B, self.n_pages_max), np.int32)
-        for i, req in enumerate(reqs):
-            tokens[i] = req.generated[-1]
-            lengths[i] = req.cached + 1
-            windows[i] = self.scheduler._spec_window(req)
-            page_table[i, :len(req.pages)] = req.pages
-        pt = jnp.asarray(page_table)
+        with self._phase("build_inputs"):
+            tokens = np.zeros((B,), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            windows = np.full((B,), -1, np.int32)
+            page_table = np.zeros((B, self.n_pages_max), np.int32)
+            for i, req in enumerate(reqs):
+                tokens[i] = req.generated[-1]
+                lengths[i] = req.cached + 1
+                windows[i] = self.scheduler._spec_window(req)
+                page_table[i, :len(req.pages)] = req.pages
+            self.stats["decode_kv_tokens"] += int(lengths.sum())
+            pt = jnp.asarray(page_table)
+            args = [jnp.asarray(a) for a in (tokens, lengths, windows)]
         fn = self._propose_fn(B)
-        proposed, self.draft_cache.k, self.draft_cache.v = fn(
-            self.draft_params, self.draft_stacked, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(windows), pt,
-            self.draft_cache.k, self.draft_cache.v)
-        proposed = np.asarray(proposed)
+        with self._phase("dispatch"):
+            proposed, self.draft_cache.k, self.draft_cache.v = fn(
+                self.draft_params, self.draft_stacked, *args, pt,
+                self.draft_cache.k, self.draft_cache.v)
+        proposed, _ = self._readback(proposed)
 
         # verify window per row: [pending token, proposals[:w]] at
         # positions cached..cached+w — the pending token's K/V enters
         # the target cache here, exactly like a plain decode step
         S = self.spec_k + 1
-        wtokens = np.zeros((B, S), np.int32)
-        n_new = np.zeros((B,), np.int32)
-        for i in range(len(reqs)):
-            w = int(windows[i])
-            wtokens[i, 0] = tokens[i]
-            wtokens[i, 1:1 + w] = proposed[i, :w]
-            n_new[i] = w + 1
-        start = np.maximum(lengths - 1, 0).astype(np.int32)
+        with self._phase("build_inputs"):
+            wtokens = np.zeros((B, S), np.int32)
+            n_new = np.zeros((B,), np.int32)
+            for i in range(len(reqs)):
+                w = int(windows[i])
+                wtokens[i, 0] = tokens[i]
+                wtokens[i, 1:1 + w] = proposed[i, :w]
+                n_new[i] = w + 1
+            start = np.maximum(lengths - 1, 0).astype(np.int32)
+            args = [jnp.asarray(a) for a in (wtokens, start, n_new)]
         vfn = self._chunk_fn(B, S, "target", "verify")
-        out, self.cache.k, self.cache.v = vfn(
-            self.params, self.params_stacked, jnp.asarray(wtokens),
-            jnp.asarray(start), jnp.asarray(n_new), pt, self.cache.k,
-            self.cache.v, self._next_rng())
-        out = np.asarray(out)
+        with self._phase("dispatch"):
+            out, self.cache.k, self.cache.v = vfn(
+                self.params, self.params_stacked, *args, pt, self.cache.k,
+                self.cache.v, self._next_rng())
+        out, now = self._readback(out)
 
-        now = time.perf_counter()
         produced = 0
-        for i, req in enumerate(reqs):
-            w = int(windows[i])
-            if self.temperature <= 0.0:
-                accepted = self._accept_greedy(out[i], proposed[i], w)
-            else:
-                accepted = self._accept_sampled(out[i], proposed[i], w)
-            self.stats["spec_proposed"] += w
-            self.stats["spec_accepted"] += len(accepted) - 1
-            appended = self.scheduler.complete_speculative(req, accepted)
-            produced += appended
-            if req.last_token_at is not None and appended:
-                # the user-visible cadence: one step emitted `appended`
-                # tokens, so each token's inter-token gap is dt/appended
-                per_token = (now - req.last_token_at) / appended
-                for _ in range(appended):
-                    self.request_metrics.observe_inter_token(per_token)
-            req.last_token_at = now
+        with self._phase("complete"):
+            for i, req in enumerate(reqs):
+                w = int(windows[i])
+                if self.temperature <= 0.0:
+                    accepted = self._accept_greedy(out[i], proposed[i], w)
+                else:
+                    accepted = self._accept_sampled(out[i], proposed[i], w)
+                self.stats["spec_proposed"] += w
+                self.stats["spec_accepted"] += len(accepted) - 1
+                appended = self.scheduler.complete_speculative(req,
+                                                               accepted)
+                produced += appended
+                if req.last_token_at is not None and appended:
+                    # the user-visible cadence: one step emitted
+                    # `appended` tokens, so each token's inter-token gap
+                    # is dt/appended
+                    per_token = (now - req.last_token_at) / appended
+                    for _ in range(appended):
+                        self.request_metrics.observe_inter_token(per_token)
+                req.last_token_at = now
         self.stats["spec_steps"] += 1
         return produced
 

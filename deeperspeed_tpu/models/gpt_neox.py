@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import scopes
 from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, per_shard
 
 
@@ -367,14 +368,16 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None):
             f"block divides")
     B, S, H, D = q.shape
     scale = 1.0 / math.sqrt(D)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, :, :]
-    if segment_ids is not None:
-        mask = mask & (segment_ids[:, :, None] == segment_ids[:, None, :])
-    logits = jnp.where(mask[:, None, :, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    return tag_attn_residual(jnp.einsum("bhqk,bkhd->bqhd", probs, v))
+    with scopes.scope("ds.attn_xla"):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, :, :]
+        if segment_ids is not None:
+            mask = mask & (segment_ids[:, :, None] ==
+                           segment_ids[:, None, :])
+        logits = jnp.where(mask[:, None, :, :], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+        return tag_attn_residual(jnp.einsum("bhqk,bkhd->bqhd", probs, v))
 
 
 def _wmat(x, w):
@@ -394,6 +397,7 @@ def _wmat(x, w):
     return x @ w.astype(x.dtype)
 
 
+@scopes.scoped("ds.attn")
 def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
     """ln1 + QKV projection + rotary; shared by training and decode."""
     B, S, _ = x.shape
@@ -417,30 +421,33 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
     under delayed-scaling quantization and makes the return
     (out, new_amax_row) — see `ops/pallas/quant_matmul`."""
     out_b = params["attn"]["out_b"].astype(x.dtype)
-    attn_partial = _wmat(attn_flat, params["attn"]["out_w"])
+    with scopes.scope("ds.attn"):
+        attn_partial = _wmat(attn_flat, params["attn"]["out_w"])
 
     if cfg.use_parallel_residual:
         ln2_in = x
     else:
         attn_out = reduce_fn(attn_partial) + out_b
         ln2_in = x + attn_out
-    ln2 = layer_norm(ln2_in, params["ln_mlp"]["scale"],
-                     params["ln_mlp"]["bias"], cfg.layernorm_eps)
+    with scopes.scope("ds.mlp"):
+        ln2 = layer_norm(ln2_in, params["ln_mlp"]["scale"],
+                         params["ln_mlp"]["bias"], cfg.layernorm_eps)
 
     if getattr(cfg, "moe_num_experts", 0):
         from ..moe.layer import moe_ffn_dense
         B, S, h = ln2.shape
-        y = moe_ffn_dense(
-            params["mlp"], ln2.reshape(B * S, h),
-            capacity_factor=cfg.moe_capacity_factor,
-            top_k=cfg.moe_top_k, rng=rng,
-            jitter_eps=cfg.moe_jitter_eps,
-            groups=getattr(cfg, "moe_num_groups", 1),
-            dispatch=getattr(cfg, "moe_dispatch", "einsum"),
-            renorm_kept_choices=getattr(cfg, "moe_renorm_kept_choices",
-                                        False),
-            observe=getattr(cfg, "moe_observability", False),
-            ffn_quant=ffn_quant)
+        with scopes.scope("ds.mlp"):
+            y = moe_ffn_dense(
+                params["mlp"], ln2.reshape(B * S, h),
+                capacity_factor=cfg.moe_capacity_factor,
+                top_k=cfg.moe_top_k, rng=rng,
+                jitter_eps=cfg.moe_jitter_eps,
+                groups=getattr(cfg, "moe_num_groups", 1),
+                dispatch=getattr(cfg, "moe_dispatch", "einsum"),
+                renorm_kept_choices=getattr(
+                    cfg, "moe_renorm_kept_choices", False),
+                observe=getattr(cfg, "moe_observability", False),
+                ffn_quant=ffn_quant)
         new_amax_row = None
         if ffn_quant is not None:
             y, aux, new_amax_row = y
@@ -462,20 +469,22 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
         from ..ops.pallas.quant_matmul import ffn_scaled_matmuls
         recipe, margin, amax_row = ffn_quant
         B, S, h = ln2.shape
-        y2d, new_amax_row = ffn_scaled_matmuls(
-            ln2.reshape(B * S, h), params["mlp"]["in_w"],
-            params["mlp"]["in_b"], params["mlp"]["out_w"],
-            amax_row, recipe, margin)
+        with scopes.scope("ds.mlp"):
+            y2d, new_amax_row = ffn_scaled_matmuls(
+                ln2.reshape(B * S, h), params["mlp"]["in_w"],
+                params["mlp"]["in_b"], params["mlp"]["out_w"],
+                amax_row, recipe, margin)
         mlp_partial = y2d.reshape(B, S, -1)
         if cfg.use_parallel_residual:
             out = x + reduce_fn(attn_partial + mlp_partial) + out_b + mlp_b
         else:
             out = ln2_in + reduce_fn(mlp_partial) + mlp_b
         return out, new_amax_row
-    hmid = _wmat(ln2, params["mlp"]["in_w"]) + \
-        params["mlp"]["in_b"].astype(x.dtype)
-    hmid = jax.nn.gelu(hmid)
-    mlp_partial = _wmat(hmid, params["mlp"]["out_w"])
+    with scopes.scope("ds.mlp"):
+        hmid = _wmat(ln2, params["mlp"]["in_w"]) + \
+            params["mlp"]["in_b"].astype(x.dtype)
+        hmid = jax.nn.gelu(hmid)
+        mlp_partial = _wmat(hmid, params["mlp"]["out_w"])
 
     if cfg.use_parallel_residual:
         # one reduce for both partials (the Megatron fusion win)
@@ -483,6 +492,7 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
     return ln2_in + reduce_fn(mlp_partial) + mlp_b
 
 
+@scopes.scoped("ds.block")
 def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
                 return_kv=False, rng=None, attn_fn=None,
                 segment_ids=None, ffn_quant=None):
@@ -500,12 +510,13 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     cos, sin, rot_dim = cos_sin
     q, k, v = _block_qkv(cfg, params, x, cos, sin, rot_dim,
                          cfg.num_heads // mp)
-    if attn_fn is not None:
-        attn = attn_fn(q, k, v) if segment_ids is None else \
-            attn_fn(q, k, v, segment_ids=segment_ids)
-    else:
-        attn = causal_attention(q, k, v, use_pallas=use_pallas,
-                                segment_ids=segment_ids)
+    with scopes.scope("ds.attn"):
+        if attn_fn is not None:
+            attn = attn_fn(q, k, v) if segment_ids is None else \
+                attn_fn(q, k, v, segment_ids=segment_ids)
+        else:
+            attn = causal_attention(q, k, v, use_pallas=use_pallas,
+                                    segment_ids=segment_ids)
     if return_kv and ffn_quant is not None:
         raise ValueError("return_kv and ffn_quant cannot combine (the "
                          "KV-returning decode path serves quantized "
@@ -698,7 +709,8 @@ def forward_hidden(cfg, params, tokens, use_pallas=True, remat_blocks=False,
             raise ValueError(
                 "quantization.ffn does not thread amax through the "
                 "hidden-state capture path (collect_hidden)")
-    x = params["embed"]["wte"][tokens]
+    with scopes.scope("ds.embed"):
+        x = params["embed"]["wte"][tokens]
     cos, sin, rot_dim = _rotary_cache(cfg, tokens.shape[1])
     if segment_ids is not None and rot_dim:
         # gather the rotary cache at intra-document positions: [B, S, rot]
@@ -743,47 +755,50 @@ def forward_hidden(cfg, params, tokens, use_pallas=True, remat_blocks=False,
             "uniform block stack: incompatible with MoE aux-loss "
             "threading and collect_hidden — drop number_checkpoints or "
             "use per-block remat (a policy alone)")
-    if n_ckpt is not None:
-        # segment spans own the remat; blocks inside run bare
-        x = segmented_scan_blocks(
-            lambda bp, x: plain_block(bp, x, None), x, params["blocks"],
-            n_ckpt, policy=policy, boundary_fn=boundary_fn)
-    elif scan_blocks and uniform and len(params["blocks"]) > 1:
-        if quant is not None:
-            stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                             *params["blocks"])
+    # the loop or scan over the blocks: a container scope, so that the
+    # scan's own slicing and stacking shows apart from the blocks' work
+    with scopes.scope("ds.layers"):
+        if n_ckpt is not None:
+            # segment spans own the remat; blocks inside run bare
+            x = segmented_scan_blocks(
+                lambda bp, x: plain_block(bp, x, None), x, params["blocks"],
+                n_ckpt, policy=policy, boundary_fn=boundary_fn)
+        elif scan_blocks and uniform and len(params["blocks"]) > 1:
+            if quant is not None:
+                stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                                 *params["blocks"])
 
-            def sbody(carry, xs):
-                bp, arow = xs
-                return block_fn(bp, carry, None, arow)
+                def sbody(carry, xs):
+                    bp, arow = xs
+                    return block_fn(bp, carry, None, arow)
 
-            x, new_amax = jax.lax.scan(sbody, x, (stacked, ffn_amax))
-        else:
-            x = scan_stacked_blocks(lambda bp, x: block_fn(bp, x, None),
-                                    x, params["blocks"])
-    else:
-        new_rows = []
-        for i, bp in enumerate(params["blocks"]):
-            brng = jax.random.fold_in(rng, i) if (moe and rng is not None) \
-                else None
-            y = block_fn(bp, x, brng,
-                         ffn_amax[i] if quant is not None else None)
-            if moe and quant is not None:
-                x, aux, row = y
-                aux_total = aux_total + aux
-                new_rows.append(row)
-            elif moe:
-                x, aux = y
-                aux_total = aux_total + aux
-            elif quant is not None:
-                x, row = y
-                new_rows.append(row)
+                x, new_amax = jax.lax.scan(sbody, x, (stacked, ffn_amax))
             else:
-                x = y
-            if collect_hidden:
-                hidden.append(x)
-        if quant is not None:
-            new_amax = jnp.stack(new_rows)
+                x = scan_stacked_blocks(lambda bp, x: block_fn(bp, x, None),
+                                        x, params["blocks"])
+        else:
+            new_rows = []
+            for i, bp in enumerate(params["blocks"]):
+                brng = jax.random.fold_in(rng, i) \
+                    if (moe and rng is not None) else None
+                y = block_fn(bp, x, brng,
+                             ffn_amax[i] if quant is not None else None)
+                if moe and quant is not None:
+                    x, aux, row = y
+                    aux_total = aux_total + aux
+                    new_rows.append(row)
+                elif moe:
+                    x, aux = y
+                    aux_total = aux_total + aux
+                elif quant is not None:
+                    x, row = y
+                    new_rows.append(row)
+                else:
+                    x = y
+                if collect_hidden:
+                    hidden.append(x)
+            if quant is not None:
+                new_amax = jnp.stack(new_rows)
 
     out = layer_norm(x, params["final_ln"]["scale"],
                      params["final_ln"]["bias"], cfg.layernorm_eps)
@@ -815,6 +830,7 @@ def forward(cfg, params, tokens, use_pallas=True, remat_blocks=False,
     return logits
 
 
+@scopes.scoped("ds.ce_head")
 def fused_lm_head_loss(x, wte, labels, ignore_index=-100, chunk_rows=None):
     """Next-token cross entropy fused with the LM head, chunked over rows.
 
@@ -869,6 +885,7 @@ def fused_lm_head_loss(x, wte, labels, ignore_index=-100, chunk_rows=None):
     return loss_sum / jnp.maximum(count, 1)
 
 
+@scopes.scoped("ds.ce_head")
 def lm_loss(logits, labels, ignore_index=-100):
     """Next-token cross entropy; labels already shifted or == tokens (we
     shift internally when labels is tokens)."""
@@ -1715,6 +1732,7 @@ class GPTNeoX:
 # autoregressive generation (KV cache; single jitted prefill + scan decode)
 # ---------------------------------------------------------------------------
 
+@scopes.scoped("ds.block")
 def _block_decode(cfg, bp, x, kv, pos, cos_sin):
     """One block for one new position: `_block_qkv` with the rotary
     slice at `pos`, cached attention over [0, pos], then the shared
@@ -1728,19 +1746,21 @@ def _block_decode(cfg, bp, x, kv, pos, cos_sin):
     sin = jax.lax.dynamic_slice_in_dim(sin_full, pos, 1, 0)
     q, k, v = _block_qkv(cfg, bp, x, cos, sin, rot_dim, cfg.num_heads)
 
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k.astype(k_cache.dtype), pos, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v.astype(v_cache.dtype), pos, axis=1)
+    with scopes.scope("ds.kv_write"):
+        k_cache = jax.lax.dynamic_update_slice_in_dim(
+            k_cache, k.astype(k_cache.dtype), pos, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(
+            v_cache, v.astype(v_cache.dtype), pos, axis=1)
 
     S_max = k_cache.shape[1]
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    mask = jnp.arange(S_max)[None, None, None, :] <= pos
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache)
+    with scopes.scope("ds.attn"), scopes.scope("ds.attn_xla"):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache,
+                            preferred_element_type=jnp.float32) * scale
+        mask = jnp.arange(S_max)[None, None, None, :] <= pos
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache)
 
     out = _block_post_attn(cfg, bp, x, attn.reshape(B, 1, cfg.hidden_size),
                            reduce_fn=lambda t: t)
@@ -1753,14 +1773,17 @@ def _prefill(cfg, params, tokens, s_max, use_pallas=True):
     """Run the prompt through the model, filling KV caches sized s_max.
     Returns (last-position hidden [B, 1, H], caches per layer)."""
     B, S_p = tokens.shape
-    x = params["embed"]["wte"][tokens]
+    with scopes.scope("ds.embed"):
+        x = params["embed"]["wte"][tokens]
     cos_sin = _rotary_cache(cfg, S_p)
     caches = []
-    for bp in params["blocks"]:
-        x, (k, v) = _block_core(cfg, bp, x, cos_sin, use_pallas, mp=1,
-                                reduce_fn=lambda t: t, return_kv=True)
-        pad = [(0, 0), (0, s_max - S_p), (0, 0), (0, 0)]
-        caches.append((jnp.pad(k, pad), jnp.pad(v, pad)))
+    pad = [(0, 0), (0, s_max - S_p), (0, 0), (0, 0)]
+    with scopes.scope("ds.layers"):
+        for bp in params["blocks"]:
+            x, (k, v) = _block_core(cfg, bp, x, cos_sin, use_pallas, mp=1,
+                                    reduce_fn=lambda t: t, return_kv=True)
+            with scopes.scope("ds.kv_write"):
+                caches.append((jnp.pad(k, pad), jnp.pad(v, pad)))
     return x[:, -1:, :], caches
 
 
@@ -1787,12 +1810,14 @@ def generate(cfg, params, prompt, max_new_tokens, temperature=0.0,
     cos_sin = _rotary_cache(cfg, s_max)
     out_embed = params.get("embed_out", params["embed"])["wte"]
 
+    @scopes.scoped("ds.lm_head")
     def logits_of(x):
         h = layer_norm(x, params["final_ln"]["scale"],
                        params["final_ln"]["bias"], cfg.layernorm_eps)
         return jnp.einsum("bsh,vh->bsv", h, out_embed.astype(h.dtype),
                           preferred_element_type=jnp.float32)[:, 0, :]
 
+    @scopes.scoped("ds.sample")
     def sample(logits, key):
         if temperature <= 0.0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1803,11 +1828,13 @@ def generate(cfg, params, prompt, max_new_tokens, temperature=0.0,
 
     def step(carry, key):
         tok, caches, pos = carry
-        x = params["embed"]["wte"][tok[:, None]]
+        with scopes.scope("ds.embed"):
+            x = params["embed"]["wte"][tok[:, None]]
         new_caches = []
-        for bp, kv in zip(params["blocks"], caches):
-            x, kv = _block_decode(cfg, bp, x, kv, pos, cos_sin)
-            new_caches.append(kv)
+        with scopes.scope("ds.layers"):
+            for bp, kv in zip(params["blocks"], caches):
+                x, kv = _block_decode(cfg, bp, x, kv, pos, cos_sin)
+                new_caches.append(kv)
         nxt = sample(logits_of(x), key)
         return (nxt, new_caches, pos + 1), nxt
 

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import scopes
 from ...compat import CompilerParams
 
 from .flash_attention import LANES, NEG_INF, _interpret
@@ -262,7 +263,7 @@ def sparse_attention_fwd(q, k, v, lut, bits, sentinel, causal, sm_scale,
             pltpu.VMEM((block * group_q, d), jnp.float32),
         ],
     )
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -271,8 +272,10 @@ def sparse_attention_fwd(q, k, v, lut, bits, sentinel, causal, sm_scale,
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(lut_flat, bits_flat, *inputs)
+        interpret=_interpret(), name="ds.sparse_attn_fwd",
+    )
+    with scopes.scope("ds.sparse_attn_fwd"):
+        out, lse = call(lut_flat, bits_flat, *inputs)
 
     out4 = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
     return out4, (qb, kb, vb, out, lse.reshape(b * h, s))
@@ -451,7 +454,7 @@ def sparse_attention_bwd(res, g, lut, bits, lut_t, bits_t, sentinel,
             pltpu.VMEM((block * group_q, d), jnp.float32),
         ],
     )
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         dkv_kernel, grid_spec=dkv_grid,
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), kb.dtype),
@@ -459,8 +462,10 @@ def sparse_attention_bwd(res, g, lut, bits, lut_t, bits_t, sentinel,
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(lut_t_flat, bits_t_flat, *dkv_inputs)
+        interpret=_interpret(), name="ds.sparse_attn_bwd_dkv",
+    )
+    with scopes.scope("ds.sparse_attn_bwd_dkv"):
+        dk, dv = dkv_call(lut_t_flat, bits_t_flat, *dkv_inputs)
 
     # dq: row-grouped; k/v per entry.
     emap = functools.partial(_entry_map, num_heads=h, max_u=max_u,
@@ -496,13 +501,15 @@ def sparse_attention_bwd(res, g, lut, bits, lut_t, bits_t, sentinel,
             lambda b_, gi, ai, lref, bref: (b_, gi, 0)),
         scratch_shapes=[pltpu.VMEM((block * group_q, d), jnp.float32)],
     )
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         dq_kernel, grid_spec=dq_grid,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), qb.dtype),
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(lut_flat, bits_flat, *dq_inputs)
+        interpret=_interpret(), name="ds.sparse_attn_bwd_dq",
+    )
+    with scopes.scope("ds.sparse_attn_bwd_dq"):
+        dq = dq_call(lut_flat, bits_flat, *dq_inputs)
 
     def from_bh(x):
         return x.reshape(bdim, h, s, d).transpose(0, 2, 1, 3)

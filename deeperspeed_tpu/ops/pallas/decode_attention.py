@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import scopes
 from ...compat import CompilerParams
 from .flash_attention import LANES, NEG_INF, _interpret, note_xla_on_tpu
 
@@ -219,12 +220,16 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
             ],
         ),
         compiler_params=_DIMSEM,
-        interpret=_interpret(),
+        interpret=_interpret(), name="ds.paged_decode",
     )
-    return call(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-                *args)[:, :, 0, :]
+    operands = (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+                *args)
+    with scopes.scope("ds.paged_decode"):
+        out = call(*operands)
+    return out[:, :, 0, :]
 
 
+@scopes.scoped("ds.paged_decode_xla")
 def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
                                sm_scale, k_scales=None, v_scales=None):
     """Pure-XLA reference/fallback: gather the sequence's pages back

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import scopes
 from ...compat import CompilerParams
 
 BLOCK_Q = 1024
@@ -186,11 +187,12 @@ def _index_adapter(compact, kv_major=False):
     return lambda f: lambda bh, t, qm, km: f(bh, qm[t], km[t])
 
 
-def _tiled_call(kernel, compact, grid, in_specs, out_specs, scratch,
+def _tiled_call(name, kernel, compact, grid, in_specs, out_specs, scratch,
                 out_shape, maps):
     """One pallas_call for both grid flavors: compacted trapezoid
-    (scalar-prefetch LUT grid spec) or dense. Returns (call, prefetch
-    operands) — invoke as ``call(*prefetch, *inputs)``."""
+    (scalar-prefetch LUT grid spec) or dense, named `name` (a kernel
+    scope of `scopes.SCOPES`) and run under that scope. Returns the
+    function of the kernel's inputs."""
     if compact:
         call_kw = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
@@ -201,10 +203,14 @@ def _tiled_call(kernel, compact, grid, in_specs, out_specs, scratch,
                        scratch_shapes=scratch)
         prefetch = ()
     call = pl.pallas_call(
-        kernel, out_shape=out_shape,
+        kernel, out_shape=out_shape, name=name,
         compiler_params=_DIMSEM_FLAT if compact else _DIMSEM,
         interpret=_interpret(), **call_kw)
-    return call, prefetch
+
+    def run(*inputs):
+        with scopes.scope(name):
+            return call(*prefetch, *inputs)
+    return run
 
 
 def flash_attention_supported(shape, block_q=BLOCK_Q, block_k=BLOCK_K):
@@ -477,7 +483,7 @@ def _fwd_single(qb, kb, vb, causal, sm_scale, s, d, interpret, kbias=None,
             ],
             compiler_params=CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
-            interpret=interpret,
+            interpret=interpret, name="ds.flash_fwd",
         )(*inputs)
         return out.reshape(bh, s, d), lse.reshape(bh, 1, s)
     kernel = functools.partial(_fwd_single_kernel, sm_scale=sm_scale,
@@ -507,7 +513,7 @@ def _fwd_single(qb, kb, vb, causal, sm_scale, s, d, interpret, kbias=None,
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=interpret, name="ds.flash_fwd",
     )(*inputs)
 
 
@@ -660,9 +666,10 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
         _LAST_BLOCKS["fwd"] = (s, s)
         _LAST_BLOCKS["fwd_variant"] = "single"
         _log_first_dispatch()
-        out, lse = _fwd_single(qb, kb, vb, causal, sm_scale, s, d,
-                               _interpret(), kbias=kbias, h=h,
-                               dropout_rate=dropout_rate, seed=seed)
+        with scopes.scope("ds.flash_fwd"):
+            out, lse = _fwd_single(qb, kb, vb, causal, sm_scale, s, d,
+                                   _interpret(), kbias=kbias, h=h,
+                                   dropout_rate=dropout_rate, seed=seed)
         out, lse = _tag_residuals(out, lse)
         out4 = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
         return out4, (qb, kb, vb, out, lse.reshape(b * h, s))
@@ -732,10 +739,10 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
         pltpu.VMEM((block_q, d), jnp.float32),       # out accumulator
     ]
     _LAST_GRIDS["fwd"] = grid
-    call, prefetch = _tiled_call(
-        kernel, compact, grid, in_specs, out_specs, scratch_shapes,
-        out_shape, (qmap, kmap) if compact else ())
-    out, lse = call(*prefetch, *inputs)
+    out, lse = _tiled_call(
+        "ds.flash_fwd", kernel, compact, grid, in_specs, out_specs,
+        scratch_shapes, out_shape,
+        (qmap, kmap) if compact else ())(*inputs)
     out, lse = _tag_residuals(out, lse)
 
     out4 = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
@@ -915,7 +922,7 @@ def _bwd_single(qb, kb, vb, do, lse, delta, causal, sm_scale, s, d,
             ],
             compiler_params=CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
-            interpret=interpret,
+            interpret=interpret, name="ds.flash_bwd",
         )(*inputs)
         return (dq.reshape(bh, s, d), dk.reshape(bh, s, d),
                 dv.reshape(bh, s, d))
@@ -950,7 +957,7 @@ def _bwd_single(qb, kb, vb, do, lse, delta, causal, sm_scale, s, d,
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=interpret, name="ds.flash_bwd",
     )(*inputs)
 
 
@@ -1144,10 +1151,11 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     if n_q == 1 and n_k == 1 and not use_mask and not use_seg:
         _LAST_BLOCKS["dkv"] = _LAST_BLOCKS["dq"] = (s, s)
         _LAST_BLOCKS["bwd_variant"] = "single"
-        dq, dk, dv = _bwd_single(qb, kb, vb, do, lse, delta, causal,
-                                 sm_scale, s, d, _interpret(),
-                                 kbias=kbias, h=h,
-                                 dropout_rate=dropout_rate, seed=seed)
+        with scopes.scope("ds.flash_bwd"):
+            dq, dk, dv = _bwd_single(qb, kb, vb, do, lse, delta, causal,
+                                     sm_scale, s, d, _interpret(),
+                                     kbias=kbias, h=h,
+                                     dropout_rate=dropout_rate, seed=seed)
 
         def from_bh1(x):
             return x.reshape(bdim, h, s, d).transpose(0, 2, 1, 3)
@@ -1226,11 +1234,10 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
         pltpu.VMEM((block_k, d), jnp.float32),
     ]
     _LAST_GRIDS["dkv"] = dkv_grid
-    call, prefetch = _tiled_call(
-        dkv_kernel, compact, dkv_grid, dkv_specs, dkv_out_specs,
-        dkv_scratch, dkv_out_shape,
-        (dkv_qmap, dkv_kmap) if compact else ())
-    dk, dv = call(*prefetch, *dkv_inputs)
+    dk, dv = _tiled_call(
+        "ds.flash_bwd_dkv", dkv_kernel, compact, dkv_grid, dkv_specs,
+        dkv_out_specs, dkv_scratch, dkv_out_shape,
+        (dkv_qmap, dkv_kmap) if compact else ())(*dkv_inputs)
 
     dq_kernel = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
                                   causal=causal, block_q=block_q,
@@ -1290,10 +1297,10 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     dq_out_shape = jax.ShapeDtypeStruct((bh, s, d), qb.dtype)
     dq_scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
     _LAST_GRIDS["dq"] = dq_grid
-    call, prefetch = _tiled_call(
-        dq_kernel, compact, dq_grid, dq_specs, dq_out_spec, dq_scratch,
-        dq_out_shape, (dq_qmap, dq_kmap) if compact else ())
-    dq = call(*prefetch, *dq_inputs)
+    dq = _tiled_call(
+        "ds.flash_bwd_dq", dq_kernel, compact, dq_grid, dq_specs,
+        dq_out_spec, dq_scratch, dq_out_shape,
+        (dq_qmap, dq_kmap) if compact else ())(*dq_inputs)
 
     def from_bh(x):
         return x.reshape(bdim, h, s, d).transpose(0, 2, 1, 3)

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import scopes
 from ...compat import CompilerParams
 from .flash_attention import _interpret, note_xla_on_tpu
 
@@ -167,9 +168,10 @@ def _gmm_pallas(x, w, sizes, spec):
                                    lambda j, i, lut, sz: (i, j)),
         ),
         compiler_params=_DIMSEM,
-        interpret=spec.interpret,
+        interpret=spec.interpret, name="ds.grouped_matmul",
     )
-    return call(jnp.asarray(spec.lut, jnp.int32), sizes, x, w)
+    with scopes.scope("ds.grouped_matmul"):
+        return call(jnp.asarray(spec.lut, jnp.int32), sizes, x, w)
 
 
 # ---------------------------------------------------------------------------
@@ -230,9 +232,10 @@ def _dw_pallas(x, dy, sizes, spec, n_weights):
                                    (lut[i // tpg], 0, j)),
         ),
         compiler_params=_DIMSEM,
-        interpret=spec.interpret,
+        interpret=spec.interpret, name="ds.grouped_matmul_dw",
     )
-    return call(jnp.asarray(spec.lut, jnp.int32), sizes, x, dy)
+    with scopes.scope("ds.grouped_matmul_dw"):
+        return call(jnp.asarray(spec.lut, jnp.int32), sizes, x, dy)
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import scopes
 from .flash_attention import _interpret
 
 LANES = 128
@@ -103,14 +104,17 @@ def fused_adam_flat(p, g, m, v, lr, step, *, beta1=0.9, beta2=0.999,
         jax.ShapeDtypeStruct((padded // LANES, LANES), jnp.float32),
     ]
     kernel = functools.partial(_adam_kernel, adam_w=adam_w)
-    new_p, new_m, new_v = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid,
             in_specs=[spec] * 4, out_specs=[spec] * 3),
         out_shape=out_shapes,
-        interpret=_interpret(),
-    )(scalars, flat2d(p), flat2d(g), flat2d(m), flat2d(v))
+        interpret=_interpret(), name="ds.adam",
+    )
+    operands = (scalars, flat2d(p), flat2d(g), flat2d(m), flat2d(v))
+    with scopes.scope("ds.adam"):
+        new_p, new_m, new_v = call(*operands)
     return (new_p.reshape(-1)[:n], new_m.reshape(-1)[:n],
             new_v.reshape(-1)[:n])
 

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import scopes
 from ...compat import CompilerParams
 from .flash_attention import _interpret, note_xla_on_tpu
 
@@ -193,9 +194,11 @@ def quant_matmul_pallas(x, qw, block_m=256, block_k=512, block_n=256):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=_DIMSEM,
-        interpret=_interpret(),
+        interpret=_interpret(), name="ds.quant_matmul",
     )
-    return call(x, qw.qval, qw.scale.reshape(1, N).astype(jnp.float32))
+    scale = qw.scale.reshape(1, N).astype(jnp.float32)
+    with scopes.scope("ds.quant_matmul"):
+        return call(x, qw.qval, scale)
 
 
 def quant_matmul_xla(x, qw):

@@ -34,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ops.adam.fused_adam import DeepSpeedCPUAdam, FusedAdam
 from ..ops.lamb.fused_lamb import FusedLamb
+from .. import scopes
 from ..parallel.mesh import DATA_AXIS, build_mesh
 from ..parallel.topology import ProcessTopology
 from ..utils.logging import log_dist, logger
@@ -1853,6 +1854,7 @@ class DeepSpeedEngine:
                 jax.lax.with_sharding_constraint, grads, self._grad_sh)
         return loss, grads
 
+    @scopes.scoped("ds.optimizer")
     def _apply_update(self, state, grads, lr, axis_name=None, loss=None,
                       quant=None):
         """Unscale, clip, update masters, recast; skip cleanly on overflow.

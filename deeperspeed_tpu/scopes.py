@@ -1,0 +1,81 @@
+"""The names the program gives its own work on the device.
+
+`jax.named_scope` puts a name on the jax name stack; every operation
+traced under it carries the name in its HLO `op_name`, and the profiler
+copies that into the device trace (XProf's `tf_op`). The name survives
+`grad`, `custom_vjp`, `jax.checkpoint`, `lax.scan` and `shard_map` as a
+plain path component, so a kernel keeps its name whatever transformation
+wraps it:
+
+    jit(step)/transpose(jvp())/checkpoint/ds.block/ds.attn/ds.flash_bwd_dq/...
+    jit(step)/.../checkpoint/rematted_computation/ds.block/ds.mlp/dot_general
+
+Scopes change metadata only: the compiled program is the same with and
+without them (`tests/test_scopes.py`). One table, so that the trace
+readers (`benchmarks/scope_reduce.py`), the tests and the documents agree
+on the names: `scope` refuses a name that is not here.
+
+Kinds: a `kernel` scope holds one `pallas_call`, which is given the same
+name as its `name=`, and nothing else; a `region` is a stretch of model
+or engine code; a `container` holds other scopes, and what lies in it but
+in no inner scope is its own overhead (a scan's slicing, stacking and
+carried-state copies). A reader gives each operation to the innermost
+scope of its `op_name`. docs/observability.md, "Device scopes".
+"""
+
+import functools
+
+import jax
+
+# name -> (kind, what it covers)
+SCOPES = {
+    "ds.flash_fwd": ("kernel", "flash attention forward, tiled or "
+                               "single-block, segmented or not"),
+    "ds.flash_bwd_dq": ("kernel", "flash attention backward, dq pass"),
+    "ds.flash_bwd_dkv": ("kernel", "flash attention backward, dk/dv pass"),
+    "ds.flash_bwd": ("kernel", "fused single-block flash backward"),
+    "ds.paged_decode": ("kernel", "paged decode attention"),
+    "ds.adam": ("kernel", "fused Adam over a flat shard"),
+    "ds.sparse_attn_fwd": ("kernel", "block-sparse attention forward"),
+    "ds.sparse_attn_bwd_dkv": ("kernel", "block-sparse backward, dk/dv"),
+    "ds.sparse_attn_bwd_dq": ("kernel", "block-sparse backward, dq"),
+    "ds.grouped_matmul": ("kernel", "grouped (per-expert) matmul"),
+    "ds.grouped_matmul_dw": ("kernel", "grouped matmul, weight gradient"),
+    "ds.quant_matmul": ("kernel", "int8-weight matmul"),
+    "ds.attn_xla": ("region", "the XLA fallback of attention"),
+    "ds.paged_decode_xla": ("region", "the XLA fallback of paged decode"),
+    "ds.embed": ("region", "token (and position) embedding gather"),
+    "ds.block": ("region", "one transformer block: what is in no inner "
+                           "scope is the residual adds"),
+    "ds.attn": ("region", "ln1, QKV projection, rotary, the attention "
+                          "core, the output projection"),
+    "ds.mlp": ("region", "ln2 and the MLP (dense or MoE)"),
+    "ds.ce_head": ("region", "output head fused with cross entropy"),
+    "ds.lm_head": ("region", "output head of a serving program"),
+    "ds.optimizer": ("region", "the engine's update: unscale, norm and "
+                               "clip, Adam, weight cast, loss scale"),
+    "ds.kv_write": ("region", "scatter of new K/V into the paged pools"),
+    "ds.sample": ("region", "sampling the next token from the logits"),
+    "ds.layers": ("container", "the loop or scan over the blocks"),
+}
+
+
+def scope(name):
+    """`jax.named_scope(name)` for a name of the table."""
+    if name not in SCOPES:
+        raise KeyError(f"{name!r} is not a device scope; "
+                       f"deeperspeed_tpu/scopes.py has {sorted(SCOPES)}")
+    return jax.named_scope(name)
+
+
+def scoped(name):
+    """Decorator: the whole function runs under `scope(name)`."""
+    scope(name)                 # refuse an unknown name where it is used
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
