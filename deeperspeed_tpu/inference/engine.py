@@ -25,8 +25,14 @@ Execution model (docs/inference.md):
   ladder warms up XLA never recompiles (`compile_count()` pins this in
   tests and the `DS_BENCH_SERVE` row).
 - **State.** The page pools are donated through every compiled call and
-  rebound, so XLA updates them in place; everything else (params,
-  rotary cache) is read-only.
+  rebound; everything else (params, rotary cache) is read-only. The
+  decode program leaves the pools where they are (`_token_layers`): they
+  are the layer loop's carried state, the new row is written by a kernel
+  that aliases the stacked pool (`paged_kv_write`), and the attention
+  kernel indexes the layer itself, so a step moves the pages it touches
+  and never a pool. Prefill's whole-page scatter updates in place too;
+  the chunk programs (`_chunk_fn`) still scan the pools as inputs and
+  outputs, which copies them.
 
 Sampling is deterministic: temperature 0 (default) is argmax;
 temperature > 0 draws from `jax.random.categorical` under a fixed
@@ -52,7 +58,8 @@ from ..compat import shard_map
 from ..models import gpt2 as gpt2_mod
 from ..models import gpt_neox as neox
 from ..module_inject.replace_module import prepare_inference_params
-from ..ops.pallas.decode_attention import paged_decode_attention
+from ..ops.pallas.decode_attention import (paged_decode_attention,
+                                           paged_kv_write)
 from ..ops.pallas.flash_attention import NEG_INF
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.config import (DeepSpeedConfig, parse_inference_block,
@@ -632,37 +639,105 @@ class InferenceEngine:
         return jax.random.categorical(
             rng, logits / self.temperature, axis=-1).astype(jnp.int32)
 
-    def _attention(self, q, k_pages, v_pages, page_table, lengths):
-        """Paged decode attention, shard_mapped over the model axis when
-        the mesh shards heads (attention is head-independent, so each
-        shard runs the kernel on its local heads — no collective).
-        Int8 pools arrive as `QuantizedPages`; the per-page scale pools
-        ride the same head-sharded placement as the data pools."""
-        scales = {}
-        if isinstance(k_pages, QuantizedPages):
-            scales = {"k_scales": k_pages.scale, "v_scales": v_pages.scale}
-            k_pages, v_pages = k_pages.data, v_pages.data
-        if self.mp > 1:
-            def mapped(q, k, v, pt, ln, *sc):
-                kw = ({"k_scales": sc[0], "v_scales": sc[1]} if sc
-                      else {})
-                return paged_decode_attention(
-                    q, k, v, pt, ln, backend=self._attn_backend, **kw)
+    def _attention(self, q, pools, layer, page_table, lengths):
+        """Paged decode attention over layer `layer` of the stacked
+        (K, V) `pools`, shard_mapped over the model axis when the mesh
+        shards heads (attention is head-independent, so each shard runs
+        the kernel on its local heads — no collective). Int8 pools
+        arrive as `QuantizedPages`; the per-page scale pools ride the
+        same head-sharded placement as the data pools."""
+        leaves = jax.tree_util.tree_leaves(pools)
+        quant = isinstance(pools[0], QuantizedPages)
 
-            pool_spec = P(None, MODEL_AXIS, None, None)
-            scale_specs = ((P(None, MODEL_AXIS, None),) * 2 if scales
-                           else ())
-            f = shard_map(
-                mapped, mesh=self.mesh,
-                in_specs=(P(None, MODEL_AXIS, None), pool_spec,
-                          pool_spec, P(None, None), P(None)) + scale_specs,
-                out_specs=P(None, MODEL_AXIS, None),
-                check_vma=False)
-            return f(q, k_pages, v_pages, page_table, lengths,
-                     *scales.values())
-        return paged_decode_attention(q, k_pages, v_pages, page_table,
-                                      lengths, backend=self._attn_backend,
-                                      **scales)
+        def attend(q, pt, ln, layer, *leaves):
+            if quant:                           # (data, scale) of K, of V
+                k, k_scale, v, v_scale = leaves
+                scales = {"k_scales": k_scale, "v_scales": v_scale}
+            else:
+                (k, v), scales = leaves, {}
+            return paged_decode_attention(
+                q, k, v, pt, ln, backend=self._attn_backend, layer=layer,
+                **scales)
+
+        if self.mp > 1:
+            attend = shard_map(
+                attend, mesh=self.mesh,
+                in_specs=(P(None, MODEL_AXIS, None), P(None, None), P(None),
+                          P()) + self._pool_specs(leaves),
+                out_specs=P(None, MODEL_AXIS, None), check_vma=False)
+        return attend(q, page_table, lengths, layer, *leaves)
+
+    @staticmethod
+    def _pool_specs(leaves):
+        """Head-sharded specs of stacked [L, P, H, ps(, D)] pools."""
+        return tuple(P(None, None, MODEL_AXIS, *(None,) * (x.ndim - 3))
+                     for x in leaves)
+
+    def _write_rows(self, pools, k, v, layer, page_idx, slot):
+        """One token's K and V rows [B, H, D] into their page slots of
+        layer `layer` of the stacked (K, V) `pools`, in place
+        (`paged_kv_write`; under a model-parallel mesh each shard writes
+        its own heads). Int8 pools quantize per (head) vector and land
+        the scale in the page-aligned scale pool, through the same
+        write. Returns the pools."""
+        leaves, treedef = jax.tree_util.tree_flatten(pools)
+        rows = (k, v)
+        if isinstance(pools[0], QuantizedPages):
+            # (data, scale) of K then of V: the leaves' own order
+            rows = quantize_kv(k) + quantize_kv(v)
+        n = len(leaves)
+
+        def write(layer, page_idx, slot, *args):
+            return paged_kv_write(args[:n], args[n:], layer, page_idx,
+                                  slot, backend=self._attn_backend)
+
+        if self.mp > 1:
+            specs = self._pool_specs(leaves)
+            write = shard_map(
+                write, mesh=self.mesh,
+                in_specs=(P(), P(None), P(None)) + specs + tuple(
+                    P(None, MODEL_AXIS, *(None,) * (r.ndim - 2))
+                    for r in rows),
+                out_specs=specs, check_vma=False)
+        return treedef.unflatten(write(layer, page_idx, slot, *leaves,
+                                       *rows))
+
+    def _token_layers(self, cfg, stacked, x, cos_sin, pools, page_table,
+                      page_idx, slot, lengths):
+        """The layer loop of a one-token step (decode; each of the draft's
+        proposal steps): every row's token `x` [B, 1, hidden] through
+        the blocks, its K/V written at (`page_idx`, `slot`) and attended
+        over `lengths` cached tokens. The (K, V) `pools` are the loop's
+        CARRY, not scanned inputs: a scan slices each `xs` element out
+        of its stack and stacks each `ys` element into a new one, which
+        for a pool is a copy of the pool every step. As carried state,
+        written by an aliased kernel and read by a kernel that takes the
+        layer index, they stay where they are. Returns (x, pools)."""
+        cos, sin, rot_dim = cos_sin
+        B = x.shape[0]
+        H, D = cfg.num_heads, cfg.head_dim
+
+        @scopes.scoped("ds.block")
+        def body(carry, xs):
+            x, pools = carry
+            bp, layer = xs
+            q, k, v = neox._block_qkv(cfg, bp, x, cos, sin, rot_dim, H)
+            pools = self._write_rows(pools, k[:, 0], v[:, 0], layer,
+                                     page_idx, slot)
+            with scopes.scope("ds.attn"):
+                qrow = q[:, 0] if isinstance(pools[0], QuantizedPages) \
+                    else q[:, 0].astype(pools[0].dtype)
+                attn = self._attention(qrow, pools, layer, page_table,
+                                       lengths).astype(x.dtype)
+            out = neox._block_post_attn(
+                cfg, bp, x, attn.reshape(B, 1, H * D),
+                reduce_fn=lambda t: t)
+            return (out, pools), None
+
+        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        with scopes.scope("ds.layers"):
+            carry, _ = jax.lax.scan(body, (x, pools), (stacked, layers))
+        return carry
 
     @staticmethod
     def _stacked_blocks(params):
@@ -743,55 +818,18 @@ class InferenceEngine:
         cfg = self.model.config
         fam = self.family
         ps = self.page_size
-        H, D = cfg.num_heads, cfg.head_dim
 
         def decode(params, stacked, tokens, lengths, page_table, k_pool,
                    v_pool, rng):
-            B = tokens.shape[0]
             # lengths INCLUDE the token decoded this step; 0 marks an
             # inactive (padding) row whose page table is all trash
             pos = jnp.maximum(lengths - 1, 0)
             x = fam.embed_decode(params, tokens, pos)
-            cos, sin, rot_dim = fam.cos_sin_decode(pos)
             page_idx = jnp.take_along_axis(
                 page_table, (pos // ps)[:, None], axis=1)[:, 0]
-            slot = pos % ps
-
-            @scopes.scoped("ds.kv_write")
-            def store(pool, vec):
-                """One decoded token's K or V row into its page slot;
-                int8 pools quantize per (head) vector and land the
-                scale in the page-aligned scale pool."""
-                if isinstance(pool, QuantizedPages):
-                    q8, sc = quantize_kv(vec)
-                    return QuantizedPages(
-                        pool.data.at[page_idx, :, slot].set(q8),
-                        pool.scale.at[page_idx, :, slot].set(
-                            sc.astype(pool.scale.dtype)))
-                return pool.at[page_idx, :, slot].set(
-                    vec.astype(pool.dtype))
-
-            @scopes.scoped("ds.block")
-            def body(carry, xs):
-                bp, kp, vp = xs
-                q, k, v = neox._block_qkv(cfg, bp, carry, cos, sin,
-                                          rot_dim, H)
-                kp = store(kp, k[:, 0])
-                vp = store(vp, v[:, 0])
-                with scopes.scope("ds.attn"):
-                    qrow = q[:, 0] if isinstance(kp, QuantizedPages) \
-                        else q[:, 0].astype(kp.dtype)
-                    attn = self._attention(qrow, kp, vp,
-                                           page_table, lengths)
-                    attn = attn.astype(carry.dtype)
-                out = neox._block_post_attn(
-                    cfg, bp, carry, attn.reshape(B, 1, H * D),
-                    reduce_fn=lambda t: t)
-                return out, (kp, vp)
-
-            with scopes.scope("ds.layers"):
-                x, (k_pool, v_pool) = jax.lax.scan(
-                    body, x, (stacked, k_pool, v_pool))
+            x, (k_pool, v_pool) = self._token_layers(
+                cfg, stacked, x, fam.cos_sin_decode(pos), (k_pool, v_pool),
+                page_table, page_idx, pos % ps, lengths)
             h = neox.layer_norm(x, params["final_ln"]["scale"],
                                 params["final_ln"]["bias"],
                                 cfg.layernorm_eps)
@@ -960,13 +998,11 @@ class InferenceEngine:
         cfg = self.draft_model.config
         fam = self.draft_family
         ps = self.page_size
-        H, D = cfg.num_heads, cfg.head_dim
         k_steps = self.spec_k
         window = self.max_seq_len
 
         def propose(params, stacked, tokens, lengths, windows, page_table,
                     k_pool, v_pool):
-            B = tokens.shape[0]
             base = jnp.maximum(lengths - 1, 0)
             proposed = []
             tok = tokens
@@ -974,46 +1010,14 @@ class InferenceEngine:
                 pos = jnp.clip(base + j, 0, window - 1)
                 active = (j <= windows) & (lengths > 0)
                 x = fam.embed_decode(params, tok, pos)
-                cos, sin, rot_dim = fam.cos_sin_decode(pos)
                 page_idx = jnp.take_along_axis(
                     page_table, (pos // ps)[:, None], axis=1)[:, 0]
                 page_idx = jnp.where(active, page_idx, 0)
-                slot = pos % ps
                 att_len = jnp.where(active, pos + 1, 0)
-
-                @scopes.scoped("ds.kv_write")
-                def store(pool, vec, page_idx=page_idx, slot=slot):
-                    if isinstance(pool, QuantizedPages):
-                        q8, sc = quantize_kv(vec)
-                        return QuantizedPages(
-                            pool.data.at[page_idx, :, slot].set(q8),
-                            pool.scale.at[page_idx, :, slot].set(
-                                sc.astype(pool.scale.dtype)))
-                    return pool.at[page_idx, :, slot].set(
-                        vec.astype(pool.dtype))
-
-                @scopes.scoped("ds.block")
-                def body(carry, xs, cos=cos, sin=sin, rot_dim=rot_dim,
-                         store=store, att_len=att_len):
-                    bp, kp, vp = xs
-                    q, k, v = neox._block_qkv(cfg, bp, carry, cos, sin,
-                                              rot_dim, H)
-                    kp = store(kp, k[:, 0])
-                    vp = store(vp, v[:, 0])
-                    with scopes.scope("ds.attn"):
-                        qrow = q[:, 0] if isinstance(kp, QuantizedPages) \
-                            else q[:, 0].astype(kp.dtype)
-                        attn = self._attention(qrow, kp, vp, page_table,
-                                               att_len)
-                        attn = attn.astype(carry.dtype)
-                    out = neox._block_post_attn(
-                        cfg, bp, carry, attn.reshape(B, 1, H * D),
-                        reduce_fn=lambda t: t)
-                    return out, (kp, vp)
-
-                with scopes.scope("ds.layers"):
-                    x, (k_pool, v_pool) = jax.lax.scan(
-                        body, x, (stacked, k_pool, v_pool))
+                x, (k_pool, v_pool) = self._token_layers(
+                    cfg, stacked, x, fam.cos_sin_decode(pos),
+                    (k_pool, v_pool), page_table, page_idx, pos % ps,
+                    att_len)
                 if j < k_steps:
                     h = neox.layer_norm(x, params["final_ln"]["scale"],
                                         params["final_ln"]["bias"],

@@ -18,7 +18,8 @@ def dispatch_report():
     "bwd_variant"}; ``attention``: {"attention" / "sparse_attention":
     backend} of the model-side dispatchers; ``decode_attention``:
     {"decode": backend, "decode_kv": pool dtype — "int8" when the paged
-    pools are quantized}; ``quant_matmul`` / ``grouped_matmul``:
+    pools are quantized, "kv_write": backend of the one-token row
+    write}; ``quant_matmul`` / ``grouped_matmul``:
     {name: backend}. A backend is "pallas" (the kernel, interpreted off
     a TPU) or "xla". ``xla_on_tpu`` names every dispatcher that, on a
     TPU, took XLA where it has a kernel (`note_xla_on_tpu`).

@@ -13,9 +13,13 @@ Contract (shared by kernel and XLA fallback):
 
 - ``q`` [B, H, D]: one query row per sequence (the token being decoded).
 - ``k_pages``/``v_pages`` [P, H, page_size, D]: the pooled cache for ONE
-  layer, head-major so a model-parallel mesh shards dim 1 (heads) and
-  each shard runs this kernel on its local heads unchanged (attention is
-  head-independent).
+  layer, head-major so a model-parallel mesh shards the heads and each
+  shard runs this kernel on its local heads unchanged (attention is
+  head-independent). With ``layer`` (a traced int32 scalar) they are the
+  engine's STACKED pools [L, P, H, page_size, D] and the kernel reads
+  layer ``layer`` of them where they lie: the layer index rides as a
+  third scalar-prefetch operand and is the first coordinate of the pool
+  blocks, so no layer's pool is ever sliced out of the stack.
 - ``page_table`` [B, NP] int32: page ids of sequence b's pages in
   position order. Entries past the sequence's live pages are don't-care
   (the scheduler pads with page 0 — the pool's reserved trash page);
@@ -33,10 +37,16 @@ mechanism as the compacted causal grids in `flash_attention.py`. Pages
 at or past a sequence's length skip all compute (`pl.when`); the last
 grid step writes ``acc / l``. No backward exists: decode is inference.
 
+The decoded token's own K/V row gets into its page through
+`paged_kv_write`: on a TPU a second small kernel that ALIASES the stacked
+pool (`input_output_aliases`) and rewrites one page tile per batch row
+in place, so a decode step moves the pages it touches and never a pool.
+
 Off a TPU the kernel runs in interpreter mode (slow, test-only) and
 `paged_decode_attention` defaults to the XLA form there, a gather +
 masked softmax with identical semantics. Which one ran is recorded in
-`_LAST_BACKEND`; XLA chosen on a TPU is logged by name.
+`_LAST_BACKEND`; XLA chosen on a TPU is logged by name. `paged_kv_write`
+dispatches the same way (off a TPU: XLA's ``.at[].set``).
 """
 
 import functools
@@ -83,10 +93,26 @@ def paged_decode_supported(head_dim, page_size, quantized=False):
     return head_dim in (64, 128, 256) and page_size % align == 0
 
 
-def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _auto_backend(op, head_dim, page_size, quant):
+    """The dispatchers' default: the kernel on a TPU where
+    `paged_decode_supported`, XLA otherwise — off a TPU, where it is the
+    expected stand-in, or noted by name where a TPU runs it."""
+    if not _interpret() and paged_decode_supported(head_dim, page_size,
+                                                   quantized=quant):
+        return "pallas"
+    note_xla_on_tpu(
+        op, f"head dim {head_dim}, page size {page_size}, int8 pools "
+            f"{quant}: the kernel needs a head dim of 64/128/256 and a "
+            f"page size that is a multiple of {32 if quant else 8}")
+    return "xla"
+
+
+def _decode_kernel(pt_ref, len_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, sm_scale, page_size,
                    ks_ref=None, vs_ref=None):
-    """One (batch row, head, page) step of paged flash decode. With
+    """One (batch row, head, page) step of paged flash decode
+    (`lyr_ref`, the layer of the stacked pools, is read by the index
+    maps alone). With
     int8 pools (`ks_ref`/`vs_ref` scale blocks, resolved through the
     SAME page-table LUT as the data blocks), the per-slot scales fold
     into the [1, ps] score / probability rows — ``q·(k·s) == (q·k)·s``
@@ -164,35 +190,50 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[...] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-def _decode_kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                         vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _decode_kernel_quant(pt_ref, len_ref, lyr_ref, q_ref, k_ref, v_ref,
+                         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                          sm_scale, page_size):
     """Positional-arg adapter for the int8 variant (pallas passes refs
     in in_specs order: data pools then scale pools)."""
-    _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
-                   l_scr, acc_scr, sm_scale=sm_scale,
+    _decode_kernel(pt_ref, len_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
+                   m_scr, l_scr, acc_scr, sm_scale=sm_scale,
                    page_size=page_size, ks_ref=ks_ref, vs_ref=vs_ref)
 
 
+def _layer_operand(layer):
+    """The layer index as the [1] int32 array a scalar-prefetch operand
+    has to be."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
-                                  sm_scale, k_scales=None, v_scales=None):
+                                  sm_scale, k_scales=None, v_scales=None,
+                                  layer=None):
     B, H, D = q.shape
-    Hk, page_size = k_pages.shape[1:3]
-    NP = page_table.shape[1]
     quant = k_scales is not None
+    if layer is None:
+        # one layer's pools: a stack of one (a bitcast), read at layer 0
+        layer = 0
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if quant:
+            k_scales, v_scales = k_scales[None], v_scales[None]
+    Hk, page_size = k_pages.shape[2:4]
+    NP = page_table.shape[1]
     # Mosaic takes a block only if its last two dims are (8, 128)-
     # multiples or the array's own. The query/out rows therefore ride as
     # [B, H, 1, D] (a [1, D] block over a [1, D] minor plane) and the
     # scale block is the page's whole [Hk, ps] plane; `None` dims are
     # squeezed out of the kernel's refs.
     row_spec = pl.BlockSpec((None, None, 1, D),
-                            lambda b, h, p, pt, ln: (b, h, 0, 0))
-    pool_spec = pl.BlockSpec((None, None, page_size, D),
-                             lambda b, h, p, pt, ln: (pt[b, p], h, 0, 0))
+                            lambda b, h, p, pt, ln, lyr: (b, h, 0, 0))
+    pool_spec = pl.BlockSpec(
+        (None, None, None, page_size, D),
+        lambda b, h, p, pt, ln, lyr: (lyr[0], pt[b, p], h, 0, 0))
     # the scale pool rides the SAME scalar-prefetch LUT that resolves
     # the data pool's page indirection — one page id, two DMAs
-    scale_spec = pl.BlockSpec((None, Hk, page_size),
-                              lambda b, h, p, pt, ln: (pt[b, p], 0, 0))
+    scale_spec = pl.BlockSpec(
+        (None, None, Hk, page_size),
+        lambda b, h, p, pt, ln, lyr: (lyr[0], pt[b, p], 0, 0))
     in_specs = [row_spec, pool_spec, pool_spec]
     args = [q[:, :, None, :], k_pages, v_pages]
     kernel_fn = _decode_kernel
@@ -209,7 +250,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, H, NP),
             in_specs=in_specs,
             out_specs=row_spec,
@@ -223,7 +264,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         interpret=_interpret(), name="ds.paged_decode",
     )
     operands = (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-                *args)
+                _layer_operand(layer), *args)
     with scopes.scope("ds.paged_decode"):
         out = call(*operands)
     return out[:, :, 0, :]
@@ -231,24 +272,29 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
 
 @scopes.scoped("ds.paged_decode_xla")
 def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
-                               sm_scale, k_scales=None, v_scales=None):
+                               sm_scale, k_scales=None, v_scales=None,
+                               layer=None):
     """Pure-XLA reference/fallback: gather the sequence's pages back
     into a contiguous [B, H, S_max, D] view and run a masked softmax.
     Identical semantics to the kernel, including exact-zero outputs for
-    inactive (length 0) rows and the int8 dequant at the gather."""
+    inactive (length 0) rows and the int8 dequant at the gather. With
+    ``layer`` the gather indexes that layer of the stacked pools."""
     B, H, D = q.shape
     out_dtype = q.dtype
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[-2]
     NP = page_table.shape[1]
-    k = jnp.moveaxis(k_pages[page_table], 2, 1).reshape(B, H, NP * page_size,
-                                                        D)
-    v = jnp.moveaxis(v_pages[page_table], 2, 1).reshape(B, H, NP * page_size,
-                                                        D)
+
+    def rows(pool, *tail):
+        pages = (pool[page_table] if layer is None
+                 else pool[layer, page_table])      # [B, NP, H, ps, ...]
+        return jnp.moveaxis(pages, 2, 1).reshape(B, H, NP * page_size,
+                                                 *tail)
+
+    k = rows(k_pages, D)
+    v = rows(v_pages, D)
     if k_scales is not None:
-        ks = jnp.moveaxis(k_scales[page_table], 2, 1).reshape(
-            B, H, NP * page_size)
-        vs = jnp.moveaxis(v_scales[page_table], 2, 1).reshape(
-            B, H, NP * page_size)
+        ks = rows(k_scales)
+        vs = rows(v_scales)
         k = k.astype(jnp.float32) * ks[..., None]
         v = v.astype(jnp.float32) * vs[..., None]
         q = q.astype(jnp.float32)
@@ -268,7 +314,7 @@ def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            sm_scale=None, backend=None, k_scales=None,
-                           v_scales=None):
+                           v_scales=None, layer=None):
     """One decode step of paged attention: ``out[b, h] = softmax(q[b, h]
     · K[b]) · V[b]`` with K/V read through ``page_table[b]`` and masked
     at ``lengths[b]``.
@@ -278,6 +324,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     dequantizes each page tile at the DMA boundary through the same
     page-table LUT; the fallback dequantizes at the gather. Kernel and
     fallback agree to float tolerance either way.
+
+    ``layer`` (a traced int32 scalar, or None): with it the pools (and
+    scale pools) are the engine's stacked ``[L, P, H, page_size, ...]``
+    arrays and the call attends over layer ``layer`` of them in place;
+    None keeps the one-layer contract above.
 
     backend: None = auto (Pallas kernel on TPU when
     `paged_decode_supported`, XLA fallback otherwise — CPU test runs
@@ -289,7 +340,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     if k_pages.shape != v_pages.shape:
         raise ValueError(f"k_pages {k_pages.shape} != v_pages "
                          f"{v_pages.shape}")
-    P, Hk, page_size, Dk = k_pages.shape
+    want = ("[P, H, page_size, D] without" if layer is None
+            else "[L, P, H, page_size, D] with")
+    if k_pages.ndim != want.count(",") + 1:
+        raise ValueError(f"k_pages {k_pages.shape} must be {want} a "
+                         f"layer index")
+    P, Hk, page_size, Dk = k_pages.shape[-4:]
     if (Hk, Dk) != (H, D):
         raise ValueError(f"cache heads/dim {(Hk, Dk)} != query {(H, D)}")
     if page_table.ndim != 2 or page_table.shape[0] != B:
@@ -298,28 +354,19 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     if lengths.shape != (B,):
         raise ValueError(f"lengths shape {lengths.shape} != ({B},)")
     quant = k_scales is not None
-    if quant and (k_scales.shape != (P, Hk, page_size) or
-                  v_scales is None or
-                  v_scales.shape != (P, Hk, page_size)):
+    scale_shape = k_pages.shape[:-1]
+    if quant and (k_scales.shape != scale_shape or v_scales is None or
+                  v_scales.shape != scale_shape):
         raise ValueError(
-            f"int8 pool scales must both be [{P}, {Hk}, {page_size}]; "
+            f"int8 pool scales must both be {list(scale_shape)}; "
             f"got {getattr(k_scales, 'shape', None)} / "
             f"{getattr(v_scales, 'shape', None)}")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
 
     if backend is None:
-        on_tpu = not _interpret()
-        backend = ("pallas" if on_tpu and
-                   paged_decode_supported(D, page_size, quantized=quant)
-                   else "xla")
-        if backend == "xla":
-            note_xla_on_tpu(
-                "paged_decode_attention",
-                f"head dim {D}, page size {page_size}, int8 pools "
-                f"{quant}: the kernel needs a head dim of 64/128/256 and "
-                f"a page size that is a multiple of "
-                f"{32 if quant else 8}")
+        backend = _auto_backend("paged_decode_attention", D, page_size,
+                                quant)
     _LAST_BACKEND["decode"] = backend
     _LAST_BACKEND["decode_kv"] = "int8" if quant else str(k_pages.dtype)
     _log_first_dispatch()
@@ -327,10 +374,116 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         return paged_decode_attention_xla(q, k_pages, v_pages, page_table,
                                           lengths, sm_scale,
                                           k_scales=k_scales,
-                                          v_scales=v_scales)
+                                          v_scales=v_scales, layer=layer)
     if backend != "pallas":
         raise ValueError(f"unknown paged decode backend {backend!r}")
     return paged_decode_attention_pallas(q, k_pages, v_pages, page_table,
                                          lengths, sm_scale,
                                          k_scales=k_scales,
-                                         v_scales=v_scales)
+                                         v_scales=v_scales, layer=layer)
+
+
+# ---------------------------------------------------------------------------
+# the decoded token's K/V row, written in place
+# ---------------------------------------------------------------------------
+
+def _kv_write_kernel(lyr_ref, page_ref, slot_ref, *refs):
+    """One batch row: each pool's page tile ([H, ps, D], or the scale
+    pool's [H, ps] plane) comes in, gets the row at its slot, and goes
+    back out to the page it came from (the output aliases the pool)."""
+    n = len(refs) // 3
+    slot = slot_ref[pl.program_id(0)]
+    for row, pool, out in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        tile = pool[...]
+        slots = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+        # a select, not a dynamic one-row store: a row of a packed
+        # (bf16 / int8) tile shares its sublane with its neighbours
+        out[...] = jnp.where(slots == slot, row[...], tile)
+
+
+def paged_kv_write_pallas(pools, rows, layer, page_idx, slot):
+    """`paged_kv_write` as a kernel over grid (B,). The tile is blocked
+    over heads (one contiguous [H, ps, D] page, 256 KB at 16 x 64 x 128
+    bf16) and read-modify-written, which is safe because no two live
+    rows write into one page in one step: a sequence's last page is its
+    own (shared prefix pages are full pages and never written). Only
+    the trash page 0 sees colliding writes (inactive rows), and nothing
+    reads its content."""
+    B = page_idx.shape[0]
+    n = len(pools)
+
+    def pool_spec(pool):
+        tile = pool.shape[2:]                       # [H, ps(, D)]
+        return pl.BlockSpec(
+            (None, None, *tile),
+            lambda b, lyr, pg, sl: (lyr[0], pg[b], *(0,) * len(tile)))
+
+    def row_spec(pool):
+        # rows ride as [B, H, 1(, D)]: the block's last two dims are the
+        # array's own, and the row broadcasts over the tile's slots
+        tile = (pool.shape[2], 1, *pool.shape[4:])
+        return pl.BlockSpec(
+            (None, *tile), lambda b, lyr, pg, sl: (b, *(0,) * len(tile)))
+
+    call = pl.pallas_call(
+        _kv_write_kernel,
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[row_spec(p) for p in pools] +
+                     [pool_spec(p) for p in pools],
+            out_specs=[pool_spec(p) for p in pools],
+        ),
+        # the alias index counts the three scalar-prefetch operands
+        input_output_aliases={3 + n + i: i for i in range(n)},
+        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=_interpret(), name="ds.kv_write",
+    )
+    rows = [jnp.expand_dims(r.astype(p.dtype), 2)
+            for r, p in zip(rows, pools)]
+    return tuple(call(_layer_operand(layer), page_idx.astype(jnp.int32),
+                      slot.astype(jnp.int32), *rows, *pools))
+
+
+def paged_kv_write_xla(pools, rows, layer, page_idx, slot):
+    """`paged_kv_write` as XLA scatters (on a TPU each would re-lay-out
+    the whole stacked pool around itself: the kernel exists for that)."""
+    return tuple(p.at[layer, page_idx, :, slot].set(r.astype(p.dtype))
+                 for p, r in zip(pools, rows))
+
+
+@scopes.scoped("ds.kv_write")
+def paged_kv_write(pools, rows, layer, page_idx, slot, backend=None):
+    """One decoded token's rows into layer ``layer`` of stacked pools:
+    ``pool[layer, page_idx[b], :, slot[b]] = row[b]`` for every pool.
+
+    ``pools`` are ``[L, P, H, page_size, ...]`` arrays (K and V data
+    pools ``[..., D]``, a data pool first, and for int8 pages their
+    ``[L, P, H, page_size]`` scale pools) and ``rows`` the matching ``[B, H, ...]`` rows, cast to
+    the pool's dtype here. Inactive batch rows name the trash page 0.
+    Returns the pools in order. Under jit with the pools donated the
+    kernel rewrites B page tiles a pool and nothing else moves.
+
+    backend: as `paged_decode_attention` (None = the kernel on a TPU
+    when `paged_decode_supported`, XLA otherwise).
+    """
+    pools, rows = tuple(pools), tuple(rows)
+    if len(pools) != len(rows):
+        raise ValueError(f"{len(pools)} pools for {len(rows)} rows")
+    B = page_idx.shape[0]
+    for p, r in zip(pools, rows):
+        if p.ndim < 4 or r.shape != (B, p.shape[2], *p.shape[4:]):
+            raise ValueError(
+                f"rows {r.shape} do not match pool {p.shape}: expected "
+                f"[{B}, H, ...] for a [L, P, H, page_size, ...] pool")
+    if backend is None:
+        data = pools[0]
+        backend = _auto_backend("paged_kv_write", data.shape[-1],
+                                data.shape[3], data.dtype == jnp.int8)
+    _LAST_BACKEND["kv_write"] = backend
+    if backend == "xla":
+        return paged_kv_write_xla(pools, rows, layer, page_idx, slot)
+    if backend != "pallas":
+        raise ValueError(f"unknown paged kv write backend {backend!r}")
+    return paged_kv_write_pallas(pools, rows, layer, page_idx, slot)

@@ -17,7 +17,9 @@ on the names: `scope` refuses a name that is not here.
 
 Kinds: a `kernel` scope holds one `pallas_call`, which is given the same
 name as its `name=`, and nothing else; a `region` is a stretch of model
-or engine code; a `container` holds other scopes, and what lies in it but
+or engine code (`ds.kv_write` alone also names a kernel: the one-token
+row write is a `pallas_call` on a TPU and a scatter off it, and both are
+the same work under the same name); a `container` holds other scopes, and what lies in it but
 in no inner scope is its own overhead (a scan's slicing, stacking and
 carried-state copies). A reader gives each operation to the innermost
 scope of its `op_name`. docs/observability.md, "Device scopes".
@@ -54,7 +56,10 @@ SCOPES = {
     "ds.lm_head": ("region", "output head of a serving program"),
     "ds.optimizer": ("region", "the engine's update: unscale, norm and "
                                "clip, Adam, weight cast, loss scale"),
-    "ds.kv_write": ("region", "scatter of new K/V into the paged pools"),
+    "ds.kv_write": ("region", "new K/V into the paged pools: prefill's "
+                              "whole-page scatter, and the one-token "
+                              "step's row, written in place by the "
+                              "kernel of this name"),
     "ds.sample": ("region", "sampling the next token from the logits"),
     "ds.layers": ("container", "the loop or scan over the blocks"),
 }

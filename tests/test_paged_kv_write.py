@@ -1,0 +1,203 @@
+"""The one-token step leaves the KV pools where they are (PR 25).
+
+- `paged_kv_write`: the aliased row-write kernel (interpret mode here)
+  against XLA's `pool.at[layer, page_idx, :, slot].set(row)`, for bf16
+  pools and for int8 data + scale pools, with inactive rows colliding on
+  the trash page 0: every page but page 0 is equal bit for bit, and no
+  other layer is touched.
+- `paged_decode_attention(..., layer=l)` on stacked `[L, P, H, ps, D]`
+  pools against the per-layer call on `pool[l]`, both back ends.
+- The engine under a model-parallel mesh with the kernels forced: the
+  write and the attention run per head shard, tokens unchanged.
+
+CPU, tiny sizes: results, never a time. `tests/test_tpu_compile.py`
+compiles the same kernels, and the engine's decode program, for a v5e.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deeperspeed_tpu.inference import InferenceEngine
+from deeperspeed_tpu.inference.kv_cache import quantize_kv
+from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+from deeperspeed_tpu.ops import dispatch_report
+from deeperspeed_tpu.ops.pallas.decode_attention import (
+    paged_decode_attention, paged_kv_write)
+
+L, P, H, PS = 3, 7, 4, 16
+
+
+def stacked_pools(rng, d, dtype):
+    """K and V data pools [L, P, H, PS, d] of `dtype`, with their
+    [L, P, H, PS] bf16 scale pools when int8."""
+    def data():
+        x = rng.normal(size=(L, P, H, PS, d))
+        if dtype == jnp.int8:
+            return jnp.asarray(np.round(x * 40).clip(-127, 127), jnp.int8)
+        return jnp.asarray(x, dtype)
+    pools = [data(), data()]
+    if dtype == jnp.int8:
+        pools += [jnp.asarray(rng.uniform(0.01, 0.1, size=(L, P, H, PS)),
+                              jnp.bfloat16) for _ in range(2)]
+    return pools
+
+
+def new_rows(rng, pools, b):
+    rows = [jnp.asarray(rng.normal(size=(b, H, pools[0].shape[-1])),
+                        jnp.float32) for _ in range(2)]
+    if pools[0].dtype == jnp.int8:
+        (k, ks), (v, vs) = (quantize_kv(r) for r in rows)
+        return [k, v, ks, vs]
+    return rows
+
+
+# ---------------------------------------------------------------------------
+# the write kernel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layer", [0, L - 1])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.int8],
+                         ids=["bf16", "fp32", "int8"])
+def test_write_kernel_matches_the_scatter(dtype, d, layer):
+    rng = np.random.default_rng(d + layer)
+    pools = stacked_pools(rng, d, dtype)
+    # five rows: three live ones on pages of their own (one on the last
+    # slot of its page), two inactive ones that collide on trash page 0
+    page_idx = jnp.asarray([3, 0, 6, 0, 1], jnp.int32)
+    slot = jnp.asarray([5, 0, PS - 1, 0, 0], jnp.int32)
+    rows = new_rows(rng, pools, 5)
+    want = paged_kv_write(pools, rows, jnp.int32(layer), page_idx, slot,
+                          backend="xla")
+    got = jax.jit(lambda *a: paged_kv_write(a[:len(pools)], a[len(pools):],
+                                            jnp.int32(layer), page_idx,
+                                            slot, backend="pallas"))(
+        *pools, *rows)
+    assert dispatch_report()["decode_attention"]["kv_write"] == "pallas"
+    assert len(got) == len(pools)
+    for before, w, g in zip(pools, want, got):
+        assert g.dtype == before.dtype and g.shape == before.shape
+        w, g, before = (np.asarray(x.astype(jnp.float32))
+                        for x in (w, g, before))
+        # every page but the trash page, bit for bit
+        np.testing.assert_array_equal(g[:, 1:], w[:, 1:])
+        # the live rows did land, and only in this layer
+        assert not np.array_equal(g[layer, 1:], before[layer, 1:])
+        others = [l for l in range(L) if l != layer]
+        np.testing.assert_array_equal(g[others], before[others])
+        # on the trash page every slot but the colliding one is kept
+        np.testing.assert_array_equal(g[:, 0, :, 1:], before[:, 0, :, 1:])
+
+
+def test_written_rows_are_the_rows():
+    rng = np.random.default_rng(7)
+    pools = stacked_pools(rng, 64, jnp.bfloat16)
+    page_idx = jnp.asarray([2, 4], jnp.int32)
+    slot = jnp.asarray([9, 0], jnp.int32)
+    rows = new_rows(rng, pools, 2)
+    k, v = paged_kv_write(pools, rows, 1, page_idx, slot, backend="pallas")
+    for pool, row in ((k, rows[0]), (v, rows[1])):
+        for b in range(2):
+            np.testing.assert_array_equal(
+                np.asarray(pool[1, page_idx[b], :, slot[b]], np.float32),
+                np.asarray(row[b].astype(jnp.bfloat16), np.float32))
+
+
+def test_write_refuses_what_does_not_match():
+    rng = np.random.default_rng(8)
+    pools = stacked_pools(rng, 64, jnp.bfloat16)
+    idx = jnp.zeros((2,), jnp.int32)
+    rows = new_rows(rng, pools, 2)
+    with pytest.raises(ValueError, match="2 pools for 1 rows"):
+        paged_kv_write(pools, rows[:1], 0, idx, idx)
+    with pytest.raises(ValueError, match="do not match pool"):
+        paged_kv_write(pools, [r[:, :2] for r in rows], 0, idx, idx)
+    with pytest.raises(ValueError, match="backend"):
+        paged_kv_write(pools, rows, 0, idx, idx, backend="cuda")
+
+
+# ---------------------------------------------------------------------------
+# the paged kernel on stacked pools
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8],
+                         ids=["fp32", "int8"])
+def test_layer_indexed_attention_matches_the_per_layer_call(dtype, backend):
+    rng = np.random.default_rng(11)
+    d, b, n_pages = 64, 3, 3
+    pools = stacked_pools(rng, d, dtype)
+    scales = ({"k_scales": pools[2], "v_scales": pools[3]}
+              if dtype == jnp.int8 else {})
+    q = jnp.asarray(rng.normal(size=(b, H, d)), jnp.float32)
+    table = jnp.asarray(rng.integers(1, P, size=(b, n_pages)), jnp.int32)
+    lengths = jnp.asarray([PS * 2 + 5, 0, PS], jnp.int32)
+    for layer in range(L):
+        one = {k: v[layer] for k, v in scales.items()}
+        want = paged_decode_attention(q, pools[0][layer], pools[1][layer],
+                                      table, lengths, backend=backend,
+                                      **one)
+        got = jax.jit(lambda lyr: paged_decode_attention(
+            q, pools[0], pools[1], table, lengths, backend=backend,
+            layer=lyr, **scales))(jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert (np.asarray(got)[1] == 0.0).all()    # the inactive row
+
+
+def test_layer_and_pool_rank_must_agree():
+    rng = np.random.default_rng(12)
+    pools = stacked_pools(rng, 64, jnp.float32)
+    q = jnp.zeros((1, H, 64), jnp.float32)
+    table = jnp.ones((1, 2), jnp.int32)
+    lengths = jnp.ones((1,), jnp.int32)
+    with pytest.raises(ValueError, match="with a layer index"):
+        paged_decode_attention(q, pools[0][0], pools[1][0], table, lengths,
+                               layer=0)
+    with pytest.raises(ValueError, match="without a layer index"):
+        paged_decode_attention(q, pools[0], pools[1], table, lengths)
+
+
+# ---------------------------------------------------------------------------
+# the engine: kernels per head shard under a model-parallel mesh
+# ---------------------------------------------------------------------------
+
+def engine_config(**kw):
+    # page 32: the int8 sublane tile, which a forced int8 kernel needs
+    block = {"enabled": True, "page_size": 32, "num_pages": 12,
+             "max_batch_size": 2, "token_budget": 128,
+             "prefill_lengths": [32], "prefill_batch_sizes": [1, 2],
+             "decode_batch_sizes": [2]}
+    block.update(kw)
+    return {"inference": block}
+
+
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["native", "int8"])
+def test_forced_kernels_under_a_model_parallel_mesh(devices, kv):
+    """mp = 2: the write kernel and the paged kernel run inside a
+    `shard_map` over heads (GSPMD cannot partition a Mosaic kernel) and
+    give the tokens of the XLA forms on one device."""
+    from deeperspeed_tpu.parallel.mesh import build_mesh
+    from deeperspeed_tpu.parallel.topology import ProcessTopology
+    cfg = GPTNeoXConfig.tiny()                   # 4 heads
+    model = GPTNeoX(config=cfg, use_pallas=False)
+    params = model.init_params(jax.random.PRNGKey(9))
+    mesh = build_mesh(ProcessTopology(axes=["data", "model"], dims=[4, 2]),
+                      devices)
+    extra = {"kv_cache_dtype": kv} if kv else {}
+    rng = np.random.default_rng(5)
+    prompts = [list(rng.integers(1, cfg.vocab_size, size=n))
+               for n in (6, 14)]
+    ref = InferenceEngine(model, config=engine_config(kernel="xla", **extra),
+                          params=params).generate(prompts, max_new_tokens=4)
+    tp = InferenceEngine(model, config=engine_config(kernel="pallas",
+                                                     **extra),
+                         params=params, mesh=mesh)
+    assert tp.mp == 2
+    assert tp.generate(prompts, max_new_tokens=4) == ref
+    report = dispatch_report()["decode_attention"]
+    assert report["kv_write"] == report["decode"] == "pallas"
+    assert tp.cache.k.sharding.spec[2] == "model" if kv is None else \
+        tp.cache.k.data.sharding.spec[2] == "model"

@@ -17,6 +17,7 @@ cannot be read back without one.
 import functools
 import importlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -185,6 +186,131 @@ def test_paged_decode_compiles(on_chip, page_size, quant):
             q, k, v, table, lengths, 0.125, *scales)
 
     assert_kernel(on_chip(decode, *args))
+
+
+def stacked(layers, pages, heads, page_size, head_dim, quant):
+    """(shape, dtype) of the engine's stacked K and V pools, then of the
+    int8 pages' scale pools."""
+    pool = ((layers, pages, heads, page_size, head_dim),
+            jnp.int8 if quant else BF16)
+    scale = ((layers, pages, heads, page_size), BF16)
+    return [pool, pool] + [scale, scale] * quant
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_layer_indexed_paged_decode_compiles(on_chip, head_dim, quant):
+    """The paged kernel on the stacked pools, the layer a traced scalar
+    (Pythia-410m's and Pythia-1.4b's head dims, the serve cell's pages)."""
+    B, H, page_size = 32, 16, 64
+    args = [((B, H, head_dim), BF16), ((B, 32), jnp.int32),
+            ((B,), jnp.int32), ((), jnp.int32)]
+
+    def decode(q, table, lengths, layer, k, v, *scales):
+        return decode_attention.paged_decode_attention_pallas(
+            q, k, v, table, lengths, 0.125, *scales, layer=layer)
+
+    assert_kernel(on_chip(decode, *args, *stacked(
+        24, 401, H, page_size, head_dim, quant)))
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_kv_write_compiles(on_chip, head_dim, quant):
+    """The aliased row write: K and V (and for int8 pages their scale
+    pools) in one call, a [H, page, D] tile a batch row."""
+    B, H = 32, 16
+    pools = stacked(24, 401, H, 64, head_dim, quant)
+    rows = [((B, H) + shape[4:], dtype) for shape, dtype in pools]
+    index = [((), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32)]
+
+    def write(layer, page_idx, slot, *leaves):
+        return decode_attention.paged_kv_write_pallas(
+            leaves[:len(pools)], leaves[len(pools):], layer, page_idx, slot)
+
+    text = on_chip(write, *index, *pools, *rows)
+    assert_kernel(text)
+    assert "ds.kv_write" in text
+
+
+INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (?P<type>.*?) (?P<op>[a-z][a-z\-]*)\(")
+# what may carry a pool without moving it
+CARRIES = ("parameter", "tuple", "get-tuple-element", "bitcast", "while")
+
+
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["bf16", "int8"])
+def test_decode_program_leaves_the_pools_in_place(on_chip, v5e_2x2, kv):
+    """The engine's real decode program at Pythia-1.4b's widths (hidden
+    2048, 16 heads of 128; two layers, a small vocabulary), 401 pages of
+    64, batch 32, compiled for the described v5e from shapes alone.
+
+    Apart from what only carries a pool (parameters, tuples, bitcasts,
+    the loop) and the two kernels' own custom calls, no instruction's
+    result has the shape of a pool or of one layer's pool: no copy of a
+    donated pool, no slicing a layer out of the stack or stacking it
+    back, no layout change around a scatter. The program's temporaries
+    stay under one layer's pool. Int8 pages: the data pools are held to
+    the same; their scale pools (1/64 of the bytes) get one layout
+    change a program from the compiler, because the chip's own layout
+    of a `[.., 16, 64]` bf16 array is not row-major (PERF.md, section 7).
+    """
+    from jax.sharding import SingleDeviceSharding
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+    layers, pages, page_size, batch = 2, 401, 64, 32
+    cfg = GPTNeoXConfig(vocab_size=1024, hidden_size=2048,
+                        num_layers=layers, num_heads=16, max_seq_len=2048,
+                        rotary_pct=0.25)
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, BF16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    block = {"enabled": True, "page_size": page_size,
+             # the engine's own pools stay small: the program takes the
+             # pools as arguments, and those are shapes of 401 pages
+             "num_pages": 2048 // page_size + 1, "max_batch_size": batch,
+             "token_budget": 2048, "prefill_lengths": [128],
+             "prefill_batch_sizes": [1], "decode_batch_sizes": [batch]}
+    if kv:
+        block["kv_cache_dtype"] = kv
+    engine = InferenceEngine(model, params=params,
+                             config={"inference": block})
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf, shape=None):
+        return jax.ShapeDtypeStruct(shape or leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def pool_of(pool):
+        return jax.tree_util.tree_map(
+            lambda leaf: shape_of(leaf, (layers, pages) + leaf.shape[2:]),
+            pool)
+
+    compiled = engine._decode_fn(batch).lower(
+        jax.tree_util.tree_map(shape_of, engine.params),
+        jax.tree_util.tree_map(shape_of, engine.params_stacked),
+        shape_of(np.zeros((batch,), np.int32)),
+        shape_of(np.zeros((batch,), np.int32)),
+        shape_of(np.zeros((batch, engine.n_pages_max), np.int32)),
+        pool_of(engine.cache.k), pool_of(engine.cache.v),
+        shape_of(jax.random.PRNGKey(0))).compile()
+    text = compiled.as_text()
+    for name in ("ds.kv_write", "ds.paged_decode"):
+        assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
+
+    tile = f"{pages},16,{page_size},128]"
+    pool_shaped = re.compile(
+        rf"{'s8' if kv else 'bf16'}\[(?:{layers},|1,)?{re.escape(tile)}")
+    moved = []
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        if m and pool_shaped.search(m["type"]) and m["op"] not in CARRIES \
+                and "tpu_custom_call" not in line:
+            moved.append((m["op"], m["type"][:60]))
+    assert not moved, moved
+    layer_pool = pages * 16 * page_size * 128 * (1 if kv else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
 
 
 # ---------------------------------------------------------------------------
