@@ -101,6 +101,8 @@ class _Family:
     def __init__(self, model, max_seq_len):
         self.cfg = model.config
         if isinstance(model, neox.GPTNeoX):
+            # every architecture `GPTNeoXConfig` describes (its norm,
+            # biases, QK norm, FFN kind): the seams below are the same
             self.kind = "gpt_neox"
             self._cos, self._sin, self.rot_dim = neox._rotary_cache(
                 self.cfg, max_seq_len)
@@ -113,6 +115,20 @@ class _Family:
             raise DeepSpeedConfigError(
                 f"InferenceEngine serves the GPT-NeoX / GPT-2 families; "
                 f"got {type(model).__name__}")
+        # experts a token of a served (dropless) MoE; 0: a dense model
+        self.moe_top_k = self.cfg.moe_top_k \
+            if getattr(self.cfg, "moe_dropless", False) else 0
+
+    def moe_buffer_rows(self, tokens):
+        """Rows an MoE layer's sorted buffer holds in a program compiled
+        for `tokens` token rows (host arithmetic, for `engine.stats`)."""
+        from ..moe.layer import dropless_geometry
+        return dropless_geometry(tokens, self.moe_top_k,
+                                 self.cfg.moe_num_experts)[0]
+
+    def final_norm(self, params, x):
+        """The model's own final norm (its kind rides the config)."""
+        return neox.norm(self.cfg, params["final_ln"], x)
 
     @scopes.scoped("ds.embed")
     def embed_prefill(self, params, tokens):
@@ -189,10 +205,7 @@ class InferenceEngine:
                  handoff_transport=None):
         self.model = model
         cfg = model.config
-        if getattr(cfg, "moe_num_experts", 0):
-            raise DeepSpeedConfigError(
-                "serving MoE models is not supported yet: the decode "
-                "block would silently drop the expert routing")
+        self._refuse_capacity_routing(cfg, "model")
         if getattr(cfg, "attention_engine", "dense") != "dense":
             raise DeepSpeedConfigError(
                 "serving needs attention_engine='dense' (the block-"
@@ -299,6 +312,11 @@ class InferenceEngine:
                 "unsupported: the per-channel scale leaves have no "
                 "tensor-parallel placement yet — serve quantized "
                 "weights on a replicated (mp=1) mesh")
+        if self.weight_quant and getattr(cfg, "moe_num_experts", 0):
+            raise DeepSpeedConfigError(
+                "quantization.weights with an MoE model is unsupported: "
+                "the grouped expert matmul takes the experts' stacked "
+                "weights in the compute dtype (no int8 variant)")
         # structure template for params-only checkpoint loads: the
         # QUANTIZED tree splits each weight into (qval, scale) leaves,
         # but checkpoints store the natural layout — keep an abstract
@@ -346,10 +364,7 @@ class InferenceEngine:
                     "have no tensor-parallel placement yet — serve "
                     "speculation on a replicated (mp=1) mesh")
             dcfg = draft_model.config
-            if getattr(dcfg, "moe_num_experts", 0):
-                raise DeepSpeedConfigError(
-                    "an MoE draft model is not supported (the decode "
-                    "block would silently drop the expert routing)")
+            self._refuse_capacity_routing(dcfg, "draft model")
             if dcfg.vocab_size != cfg.vocab_size:
                 raise DeepSpeedConfigError(
                     f"draft vocab_size {dcfg.vocab_size} != target "
@@ -430,6 +445,11 @@ class InferenceEngine:
                       "build_inputs_s": 0.0, "dispatch_s": 0.0,
                       "readback_s": 0.0, "complete_s": 0.0,
                       "decode_kv_tokens": 0,
+                      # (token, expert) rows the MoE layers routed, all
+                      # layers together, and the rows their buffers held
+                      # with padding (0 for a dense model)
+                      "moe_rows_prefill": 0, "moe_rows_decode": 0,
+                      "moe_buffer_rows": 0,
                       "queue_depth": 0.0, "page_pool_util": 0.0,
                       # terminal-status set: every request reaches
                       # exactly one (docs/inference.md)
@@ -521,6 +541,18 @@ class InferenceEngine:
                 hook = getattr(monitor, "set_export_labels", None)
                 if hook is not None:
                     hook({"role": self.role, "host": self.pool_id})
+
+    @staticmethod
+    def _refuse_capacity_routing(cfg, what):
+        if getattr(cfg, "moe_num_experts", 0) and \
+                not getattr(cfg, "moe_dropless", False):
+            raise DeepSpeedConfigError(
+                f"serving a capacity-routed MoE {what} is not supported: "
+                f"which token an expert drops depends on the token's "
+                f"batch neighbours and on the pad rows of a prefill "
+                f"bucket, so a request's output would depend on what it "
+                f"was batched with. An MoE whose routing drops nothing "
+                f"(moe_dropless) is served")
 
     # ------------------------------------------------------------------
     # weights
@@ -716,11 +748,16 @@ class InferenceEngine:
         cos, sin, rot_dim = cos_sin
         B = x.shape[0]
         H, D = cfg.num_heads, cfg.head_dim
+        # an inactive row attends over nothing; an MoE routes it nowhere
+        active = (lengths > 0)[:, None] \
+            if getattr(cfg, "moe_num_experts", 0) else None
+
+        block_xs, block_of = self._layer_xs(cfg, stacked)
 
         @scopes.scoped("ds.block")
         def body(carry, xs):
             x, pools = carry
-            bp, layer = xs
+            bp, layer = block_of(xs[0]), xs[1]
             q, k, v = neox._block_qkv(cfg, bp, x, cos, sin, rot_dim, H)
             pools = self._write_rows(pools, k[:, 0], v[:, 0], layer,
                                      page_idx, slot)
@@ -729,20 +766,42 @@ class InferenceEngine:
                     else q[:, 0].astype(pools[0].dtype)
                 attn = self._attention(qrow, pools, layer, page_table,
                                        lengths).astype(x.dtype)
-            out = neox._block_post_attn(
+            out = neox.block_hidden(neox._block_post_attn(
                 cfg, bp, x, attn.reshape(B, 1, H * D),
-                reduce_fn=lambda t: t)
+                reduce_fn=lambda t: t, token_mask=active))
             return (out, pools), None
 
         layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         with scopes.scope("ds.layers"):
-            carry, _ = jax.lax.scan(body, (x, pools), (stacked, layers))
+            carry, _ = jax.lax.scan(body, (x, pools), (block_xs, layers))
         return carry
 
     @staticmethod
     def _stacked_blocks(params):
         return jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *params["blocks"])
+
+    @staticmethod
+    def _layer_xs(cfg, stacked):
+        """What a layer loop scans of the stacked block weights, and the
+        function that makes a layer's block params of one slice:
+        (xs, unpack). A scan slices its `xs` a layer at a time, which
+        for an MoE's experts is a copy of 0.8 GB a layer; they stay
+        whole and the grouped-matmul kernel indexes the layer
+        (`LayerOf`). A dense model's loop scans the stack as it is."""
+        if not getattr(cfg, "moe_dropless", False):
+            return stacked, lambda bp: bp
+        from ..ops.pallas.grouped_matmul import LayerOf
+        whole = {k: stacked["mlp"][k] for k in ("w_in", "w_out")}
+        sliced = dict(stacked, mlp={k: v for k, v in stacked["mlp"].items()
+                                    if k not in whole})
+
+        def unpack(xs):
+            bp, layer = xs
+            experts = {k: LayerOf(v, layer) for k, v in whole.items()}
+            return dict(bp, mlp=dict(bp["mlp"], **experts))
+
+        return (sliced, jnp.arange(cfg.num_layers, dtype=jnp.int32)), unpack
 
     def _prefill_fn(self, batch, seqlen):
         key = ("prefill", batch, seqlen)
@@ -765,15 +824,17 @@ class InferenceEngine:
             seg = (pos < lengths[:, None]).astype(jnp.int32)
             x = fam.embed_prefill(params, tokens)
 
-            def body(carry, bp):
+            block_xs, block_of = self._layer_xs(cfg, stacked)
+
+            def body(carry, xs):
                 y, kv = neox._block_core(
-                    cfg, bp, carry, cos_sin, use_pallas, mp=1,
+                    cfg, block_of(xs), carry, cos_sin, use_pallas, mp=1,
                     reduce_fn=lambda t: t, return_kv=True,
                     segment_ids=seg)
-                return y, kv
+                return neox.block_hidden(y), kv
 
             with scopes.scope("ds.layers"):
-                x, (ks, vs) = jax.lax.scan(body, x, stacked)
+                x, (ks, vs) = jax.lax.scan(body, x, block_xs)
 
             # whole-page scatter: [B, S, H, D] → B·S/ps page tiles at
             # the page-table ids (pad rows hold table id 0 — the trash
@@ -801,9 +862,7 @@ class InferenceEngine:
 
             idx = jnp.clip(lengths - 1, 0, S - 1)
             h_last = x[jnp.arange(B), idx][:, None, :]
-            h_last = neox.layer_norm(h_last, params["final_ln"]["scale"],
-                                     params["final_ln"]["bias"],
-                                     cfg.layernorm_eps)
+            h_last = fam.final_norm(params, h_last)
             logits = fam.head(params, h_last[:, 0])
             return self._sample(logits, rng), k_pool, v_pool
 
@@ -830,9 +889,7 @@ class InferenceEngine:
             x, (k_pool, v_pool) = self._token_layers(
                 cfg, stacked, x, fam.cos_sin_decode(pos), (k_pool, v_pool),
                 page_table, page_idx, pos % ps, lengths)
-            h = neox.layer_norm(x, params["final_ln"]["scale"],
-                                params["final_ln"]["bias"],
-                                cfg.layernorm_eps)
+            h = fam.final_norm(params, x)
             logits = fam.head(params, h[:, 0])
             return self._sample(logits, rng), k_pool, v_pool
 
@@ -937,9 +994,11 @@ class InferenceEngine:
                                  preferred_element_type=jnp.float32)
                 return jnp.moveaxis(out, 1, 2).reshape(B, S, H * D)
 
+            block_xs, block_of = self._layer_xs(cfg, stacked)
+
             @scopes.scoped("ds.block")
             def body(carry, xs):
-                bp, kp, vp = xs
+                bp, kp, vp = block_of(xs[0]), xs[1], xs[2]
                 q, k, v = neox._block_qkv(cfg, bp, carry, cos, sin,
                                           rot_dim, H)
                 # write BEFORE attending: every window key is visible,
@@ -948,27 +1007,24 @@ class InferenceEngine:
                 vp = store(vp, v)
                 with scopes.scope("ds.attn"):
                     attn = attend(q, kp, vp).astype(carry.dtype)
-                out = neox._block_post_attn(cfg, bp, carry, attn,
-                                            reduce_fn=lambda t: t)
+                out = neox.block_hidden(neox._block_post_attn(
+                    cfg, bp, carry, attn, reduce_fn=lambda t: t,
+                    token_mask=valid))
                 return out, (kp, vp)
 
             with scopes.scope("ds.layers"):
                 x, (k_pool, v_pool) = jax.lax.scan(
-                    body, x, (stacked, k_pool, v_pool))
+                    body, x, (block_xs, k_pool, v_pool))
             if mode == "write":
                 return k_pool, v_pool
             if mode == "sample":
                 idx = jnp.clip(n_new - 1, 0, S - 1)
                 h_last = x[jnp.arange(B), idx][:, None, :]
-                h_last = neox.layer_norm(
-                    h_last, params["final_ln"]["scale"],
-                    params["final_ln"]["bias"], cfg.layernorm_eps)
+                h_last = fam.final_norm(params, h_last)
                 logits = fam.head(params, h_last[:, 0])
                 return self._sample(logits, rng), k_pool, v_pool
             # mode == "verify": every position's next-token view
-            h = neox.layer_norm(x, params["final_ln"]["scale"],
-                                params["final_ln"]["bias"],
-                                cfg.layernorm_eps)
+            h = fam.final_norm(params, x)
             logits = fam.head_all(params, h)
             with scopes.scope("ds.sample"):
                 if self.temperature <= 0.0:
@@ -1019,9 +1075,7 @@ class InferenceEngine:
                     (k_pool, v_pool), page_table, page_idx, pos % ps,
                     att_len)
                 if j < k_steps:
-                    h = neox.layer_norm(x, params["final_ln"]["scale"],
-                                        params["final_ln"]["bias"],
-                                        cfg.layernorm_eps)
+                    h = fam.final_norm(params, x)
                     logits = fam.head(params, h[:, 0])
                     with scopes.scope("ds.sample"):
                         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1711,6 +1765,19 @@ class InferenceEngine:
                     self.admission.observe_ttft(ttft_s * 1e3)
             req.last_token_at = now
 
+    def _count_moe_rows(self, phase, tokens, program_tokens):
+        """What a step's MoE layers route, from shapes the host already
+        has: `tokens` real tokens, top_k experts each, in every layer,
+        and the rows their buffers hold (padding included) in a program
+        compiled for `program_tokens` token rows."""
+        fam = self.family
+        if fam.moe_top_k:
+            layers = fam.cfg.num_layers
+            self.stats[f"moe_rows_{phase}"] += \
+                tokens * fam.moe_top_k * layers
+            self.stats["moe_buffer_rows"] += \
+                fam.moe_buffer_rows(program_tokens) * layers
+
     def _run_prefill(self, plan):
         B, S = plan.prefill_batch, plan.prefill_len
         if plan.prefill_kind == "chunk":
@@ -1718,8 +1785,9 @@ class InferenceEngine:
             # chunk program (the full-prefill scatter would overwrite
             # the shared pages other requests are reading)
             with self._phase("build_inputs"):
-                args = [jnp.asarray(a) for a in self._chunk_arrays(
-                    plan.prefills, B, S)]
+                arrays = self._chunk_arrays(plan.prefills, B, S)
+                self._count_moe_rows("prefill", int(arrays[2].sum()), B * S)
+                args = [jnp.asarray(a) for a in arrays]
             fn = self._chunk_fn(B, S, "target", "sample")
         else:
             with self._phase("build_inputs"):
@@ -1732,6 +1800,7 @@ class InferenceEngine:
                     tokens[i, :len(ctx)] = ctx
                     lengths[i] = len(ctx)
                     page_table[i, :len(req.pages)] = req.pages
+                self._count_moe_rows("prefill", int(lengths.sum()), B * S)
                 args = [jnp.asarray(a)
                         for a in (tokens, lengths, page_table)]
             fn = self._prefill_fn(B, S)
@@ -1756,6 +1825,7 @@ class InferenceEngine:
                 lengths[i] = req.cached + 1
                 page_table[i, :len(req.pages)] = req.pages
             self.stats["decode_kv_tokens"] += int(lengths.sum())
+            self._count_moe_rows("decode", len(plan.decodes), B)
             args = [jnp.asarray(a) for a in (tokens, lengths, page_table)]
         fn = self._decode_fn(B)
         with self._phase("dispatch"):

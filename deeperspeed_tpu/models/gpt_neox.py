@@ -51,6 +51,23 @@ class GPTNeoXConfig:
     use_parallel_residual: bool = True
     tie_word_embeddings: bool = False
     param_dtype: object = jnp.float32
+    # What a published architecture fixes about the block (a family file
+    # sets these from its `config.json`; none is a tuning switch):
+    # the norm ("layernorm": scale and bias; "rmsnorm": scale alone),
+    norm: str = "layernorm"
+    # whether the projections carry biases,
+    use_bias: bool = True
+    # an RMS norm on q and on k over ALL their features, before the
+    # split into heads (OLMoE),
+    qk_norm: bool = False
+    # the FFN's activation ("gelu": the tanh form; "silu") and whether
+    # it is gated, act(x Wgate) * (x Wup),
+    hidden_act: str = "gelu"
+    ffn_gated: bool = False
+    # and the FFN's inner width (of the dense MLP, or of ONE expert)
+    # where it is not a whole multiple of the hidden size (0: it is
+    # `intermediate_mult * hidden_size`).
+    ffn_width: int = 0
     # MoE FFN (GShard/Switch; 0 experts = dense MLP). Config-drivable
     # via the JSON `moe` block (engine `apply_ds_config`).
     moe_num_experts: int = 0
@@ -70,6 +87,13 @@ class GPTNeoXConfig:
     moe_a2a_overlap_chunks: int = 1
     # renormalize top-2 combine weights over capacity-surviving choices
     moe_renorm_kept_choices: bool = False
+    # routing that drops nothing (`moe.layer.moe_ffn_dropless`): every
+    # token keeps all `moe_top_k` experts (any k), there is no capacity,
+    # and the kept weights are renormalised only if `moe_norm_topk_prob`
+    # (the published `norm_topk_prob`). The GShard capacity router above
+    # is top-1 / top-2 and always normalises its pair.
+    moe_dropless: bool = False
+    moe_norm_topk_prob: bool = False
     # Train/MoE routing observability (sort dispatch only): per-expert
     # load + capacity-drop stats emitted host-side via async callback
     moe_observability: bool = False
@@ -98,19 +122,64 @@ class GPTNeoXConfig:
 
     @property
     def intermediate_size(self):
-        return self.intermediate_mult * self.hidden_size
+        return self.ffn_width or self.intermediate_mult * self.hidden_size
 
     def num_params(self):
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         i = self.intermediate_size
-        mlp = 2 * h * i + i + h
+        bias = 1 if self.use_bias else 0
+        norm = h * (1 if self.norm == "rmsnorm" else 2)
+        ffn = (3 if self.ffn_gated else 2) * h * i + bias * (i + h)
         if self.moe_num_experts:
-            E = self.moe_num_experts
-            mlp = h * E + E * (2 * h * i + i + h)  # gate + experts
-        per_layer = 4 * h * h + 3 * h + h + mlp + \
-            4 * h  # qkv+out + biases + ln scales/biases + ffn
+            ffn = h * self.moe_num_experts + self.moe_num_experts * ffn
+        attn = 4 * h * h + bias * 4 * h + (2 * h if self.qk_norm else 0)
         embed = v * h * (1 if self.tie_word_embeddings else 2)
-        return embed + L * per_layer + 2 * h
+        return embed + L * (attn + ffn + 2 * norm) + norm
+
+    def check_block(self):
+        """Refuse, by name, a block the code does not compute (rather
+        than compute something else)."""
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', "
+                             f"got {self.norm!r}")
+        if self.hidden_act not in FFN_ACTIVATIONS:
+            raise ValueError(f"hidden_act must be one of "
+                             f"{sorted(FFN_ACTIVATIONS)}, got "
+                             f"{self.hidden_act!r}")
+        moe, dropless = bool(self.moe_num_experts), self.moe_dropless
+        if dropless and not moe:
+            raise ValueError("moe_dropless needs moe_num_experts > 0")
+        if self.ffn_gated and not dropless:
+            raise NotImplementedError(
+                "ffn_gated is computed by the dropless MoE experts only "
+                "(moe_dropless): the dense MLP and the GShard capacity "
+                "experts are not gated")
+        if dropless and not self.ffn_gated:
+            raise NotImplementedError(
+                "dropless MoE experts are gated (ffn_gated): w_in holds "
+                "[gate | up]; ungated dropless experts are not computed")
+        if dropless and self.use_bias:
+            raise NotImplementedError(
+                "dropless MoE experts carry no biases (use_bias=False)")
+        if moe and not dropless and (self.moe_top_k not in (1, 2)
+                                     or self.hidden_act != "gelu"
+                                     or not self.use_bias):
+            raise NotImplementedError(
+                "the GShard capacity router computes top-1 / top-2 biased "
+                "GELU experts; any other top_k, activation or bias "
+                "setting needs moe_dropless")
+        if dropless and self.ffn_quant_recipe is not None:
+            raise NotImplementedError(
+                "quantization.ffn with dropless MoE experts: the delayed-"
+                "scaling path quantizes the fixed-span expert matmuls only")
+        if self.ffn_quant_recipe is not None and not self.use_bias:
+            raise NotImplementedError(
+                "quantization.ffn without biases (use_bias=False): the "
+                "delayed-scaling FFN is the biased GPT-NeoX MLP")
+        if dropless and self.moe_jitter_eps:
+            raise NotImplementedError(
+                "moe_jitter_eps with dropless routing: the published "
+                "router has no jitter")
 
     # ---- presets mirroring the config ladder (BASELINE.md) -------------
 
@@ -141,20 +210,40 @@ def _dense_init(key, shape, dtype, scale=0.02):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
+def init_norm_params(cfg):
+    """A norm's leaves: scale, and LayerNorm's bias."""
+    h, dt = cfg.hidden_size, cfg.param_dtype
+    p = {"scale": jnp.ones((h,), dt)}
+    if getattr(cfg, "norm", "layernorm") == "layernorm":
+        p["bias"] = jnp.zeros((h,), dt)
+    return p
+
+
+def _without_biases(cfg, params):
+    """`params` without its `*_b` leaves where the block has none."""
+    if getattr(cfg, "use_bias", True):
+        return params
+    return {k: v for k, v in params.items() if not k.endswith("_b")}
+
+
 def init_block_params(cfg, key):
-    h, i = cfg.hidden_size, cfg.intermediate_size
+    h = cfg.hidden_size
     keys = jax.random.split(key, 4)
     out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
     dt = cfg.param_dtype
+    attn = {
+        "qkv_w": _dense_init(keys[0], (h, 3 * h), dt),
+        "qkv_b": jnp.zeros((3 * h,), dt),
+        "out_w": _dense_init(keys[1], (h, h), dt, scale=out_scale),
+        "out_b": jnp.zeros((h,), dt),
+    }
+    if getattr(cfg, "qk_norm", False):
+        attn["q_norm"] = {"scale": jnp.ones((h,), dt)}
+        attn["k_norm"] = {"scale": jnp.ones((h,), dt)}
     return {
-        "ln_attn": {"scale": jnp.ones((h,), dt), "bias": jnp.zeros((h,), dt)},
-        "ln_mlp": {"scale": jnp.ones((h,), dt), "bias": jnp.zeros((h,), dt)},
-        "attn": {
-            "qkv_w": _dense_init(keys[0], (h, 3 * h), dt),
-            "qkv_b": jnp.zeros((3 * h,), dt),
-            "out_w": _dense_init(keys[1], (h, h), dt, scale=out_scale),
-            "out_b": jnp.zeros((h,), dt),
-        },
+        "ln_attn": init_norm_params(cfg),
+        "ln_mlp": init_norm_params(cfg),
+        "attn": _without_biases(cfg, attn),
         "mlp": _init_ffn_params(cfg, keys[2], keys[3], out_scale),
     }
 
@@ -163,13 +252,20 @@ def _init_ffn_params(cfg, k_in, k_out, out_scale):
     h, i, dt = cfg.hidden_size, cfg.intermediate_size, cfg.param_dtype
     E = getattr(cfg, "moe_num_experts", 0)
     if not E:
-        return {
+        return _without_biases(cfg, {
             "in_w": _dense_init(k_in, (h, i), dt),
             "in_b": jnp.zeros((i,), dt),
             "out_w": _dense_init(k_out, (i, h), dt, scale=out_scale),
             "out_b": jnp.zeros((h,), dt),
-        }
+        })
     kg, ki = jax.random.split(k_in)
+    if getattr(cfg, "moe_dropless", False):
+        # gated experts, no biases: w_in = [gate | up] along its last dim
+        return {
+            "gate": _dense_init(kg, (h, E), dt),
+            "w_in": _dense_init(ki, (E, h, 2 * i), dt),
+            "w_out": _dense_init(k_out, (E, i, h), dt, scale=out_scale),
+        }
     return {
         "gate": _dense_init(kg, (h, E), dt),
         "w_in": _dense_init(ki, (E, h, i), dt),
@@ -187,8 +283,7 @@ def init_params(cfg, rng):
                                                cfg.hidden_size), dt)},
         "blocks": [init_block_params(cfg, keys[i + 1])
                    for i in range(cfg.num_layers)],
-        "final_ln": {"scale": jnp.ones((cfg.hidden_size,), dt),
-                     "bias": jnp.zeros((cfg.hidden_size,), dt)},
+        "final_ln": init_norm_params(cfg),
     }
     if not cfg.tie_word_embeddings:
         params["embed_out"] = {
@@ -242,6 +337,36 @@ def layer_norm(x, scale, bias, eps):
     out = (x32 - mean) * jax.lax.rsqrt(var + eps)
     return (out * scale.astype(jnp.float32) +
             bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def rms_norm(x, scale, eps):
+    """x / sqrt(mean(x^2, -1) + eps) * scale, computed in float32."""
+    x32 = x.astype(jnp.float32)
+    out = x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (out * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def norm(cfg, p, x):
+    """The model's own norm (`cfg.norm`) with the leaves `p`."""
+    if getattr(cfg, "norm", "layernorm") == "rmsnorm":
+        return rms_norm(x, p["scale"], cfg.layernorm_eps)
+    return layer_norm(x, p["scale"], p["bias"], cfg.layernorm_eps)
+
+
+# the FFN's activation by its published name ("gelu": the tanh form)
+FFN_ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
+
+
+def _plus_bias(y, p, name):
+    """y + p[name] where the block has that bias."""
+    return y + p[name].astype(y.dtype) if name in p else y
+
+
+def block_hidden(out):
+    """The hidden states of a block's return, whatever rides beside them
+    (an MoE block's aux statistics, a quantized FFN's amax row)."""
+    return out[0] if isinstance(out, tuple) else out
 
 
 def _rotary_cache(cfg, seq_len, dtype=jnp.float32):
@@ -401,26 +526,37 @@ def _wmat(x, w):
 def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
     """ln1 + QKV projection + rotary; shared by training and decode."""
     B, S, _ = x.shape
-    ln1 = layer_norm(x, params["ln_attn"]["scale"], params["ln_attn"]["bias"],
-                     cfg.layernorm_eps)
-    qkv = _wmat(ln1, params["attn"]["qkv_w"]) + \
-        params["attn"]["qkv_b"].astype(x.dtype)
+    ln1 = norm(cfg, params["ln_attn"], x)
+    qkv = _plus_bias(_wmat(ln1, params["attn"]["qkv_w"]), params["attn"],
+                     "qkv_b")
     qkv = qkv.reshape(B, S, nh_local, 3 * cfg.head_dim)
     q, k, v = jnp.split(qkv, 3, axis=-1)
+    if getattr(cfg, "qk_norm", False):
+        # over all of q's (k's) features at once, before the heads part
+        def all_features(t, p):
+            return rms_norm(t.reshape(B, S, -1), p["scale"],
+                            cfg.layernorm_eps).reshape(t.shape)
+        q = all_features(q, params["attn"]["q_norm"])
+        k = all_features(k, params["attn"]["k_norm"])
     q, k = apply_rotary(q, k, cos, sin, rot_dim)
     return q, k, v
 
 
 def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
-                     ffn_quant=None):
+                     ffn_quant=None, token_mask=None):
     """Everything after the attention core: out projection, residuals,
     ln2, MLP (dense or MoE) — shared by training and decode.
     `attn_flat` is the flattened [B, S, h/mp] attention output. With
-    MoE enabled the return is (out, aux_load_balance_loss).
+    MoE enabled the return is (out, aux): the GShard router's
+    load-balance loss, or the dropless router's [2, E] statistics
+    (`block_hidden` takes the hidden states of either).
     `ffn_quant` = (recipe, margin, amax_row [4, H]) runs the dense FFN
     under delayed-scaling quantization and makes the return
-    (out, new_amax_row) — see `ops/pallas/quant_matmul`."""
-    out_b = params["attn"]["out_b"].astype(x.dtype)
+    (out, new_amax_row) — see `ops/pallas/quant_matmul`.
+    `token_mask` [B, S] marks the real tokens for a router that drops
+    nothing: a padded row is routed to no expert."""
+    out_b = params["attn"]["out_b"].astype(x.dtype) \
+        if "out_b" in params["attn"] else 0
     with scopes.scope("ds.attn"):
         attn_partial = _wmat(attn_flat, params["attn"]["out_w"])
 
@@ -430,8 +566,22 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
         attn_out = reduce_fn(attn_partial) + out_b
         ln2_in = x + attn_out
     with scopes.scope("ds.mlp"):
-        ln2 = layer_norm(ln2_in, params["ln_mlp"]["scale"],
-                         params["ln_mlp"]["bias"], cfg.layernorm_eps)
+        ln2 = norm(cfg, params["ln_mlp"], ln2_in)
+
+    if getattr(cfg, "moe_dropless", False):
+        from ..moe.layer import moe_ffn_dropless
+        B, S, h = ln2.shape
+        with scopes.scope("ds.mlp"):
+            y, stats = moe_ffn_dropless(
+                params["mlp"], ln2.reshape(B * S, h), cfg.moe_top_k,
+                norm_topk_prob=cfg.moe_norm_topk_prob,
+                activation=FFN_ACTIVATIONS[cfg.hidden_act],
+                token_mask=None if token_mask is None
+                else token_mask.reshape(B * S))
+        y = y.reshape(ln2.shape)
+        if cfg.use_parallel_residual:
+            return x + reduce_fn(attn_partial) + out_b + y, stats
+        return ln2_in + y, stats
 
     if getattr(cfg, "moe_num_experts", 0):
         from ..moe.layer import moe_ffn_dense
@@ -462,7 +612,8 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
             return out, aux, new_amax_row
         return out, aux
 
-    mlp_b = params["mlp"]["out_b"].astype(x.dtype)
+    mlp_b = params["mlp"]["out_b"].astype(x.dtype) \
+        if "out_b" in params["mlp"] else 0
     if ffn_quant is not None:
         # delayed-scaling quantized FFN (ops/pallas/quant_matmul):
         # amax_row [4, H] carries the histories for in-x/in-w/out-x/out-w
@@ -481,9 +632,9 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
             out = ln2_in + reduce_fn(mlp_partial) + mlp_b
         return out, new_amax_row
     with scopes.scope("ds.mlp"):
-        hmid = _wmat(ln2, params["mlp"]["in_w"]) + \
-            params["mlp"]["in_b"].astype(x.dtype)
-        hmid = jax.nn.gelu(hmid)
+        hmid = _plus_bias(_wmat(ln2, params["mlp"]["in_w"]), params["mlp"],
+                          "in_b")
+        hmid = FFN_ACTIVATIONS[getattr(cfg, "hidden_act", "gelu")](hmid)
         mlp_partial = _wmat(hmid, params["mlp"]["out_w"])
 
     if cfg.use_parallel_residual:
@@ -521,8 +672,12 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
         raise ValueError("return_kv and ffn_quant cannot combine (the "
                          "KV-returning decode path serves quantized "
                          "WEIGHTS, not the delayed-scaling FFN)")
-    out = _block_post_attn(cfg, params, x, attn.reshape(B, S, h // mp),
-                           reduce_fn, rng=rng, ffn_quant=ffn_quant)
+    # segment 0 is padding (a packed batch's tail, a prefill bucket's):
+    # a router that drops nothing routes it nowhere
+    out = _block_post_attn(
+        cfg, params, x, attn.reshape(B, S, h // mp), reduce_fn, rng=rng,
+        ffn_quant=ffn_quant,
+        token_mask=None if segment_ids is None else segment_ids > 0)
     if return_kv:
         return out, (k, v)
     return out
@@ -555,8 +710,24 @@ def block_forward_tp(cfg, params, x, cos_sin, model_axis, mp,
         raise NotImplementedError(
             "tensor-parallel blocks with an MoE FFN are not supported "
             "yet; use expert parallelism (mesh axis 'expert') instead")
+    _require_neox_block(cfg, "tensor parallelism")
     return _block_core(cfg, params, x, cos_sin, use_pallas, mp=mp,
                        reduce_fn=lambda t: jax.lax.psum(t, model_axis))
+
+
+def _require_neox_block(cfg, what):
+    """`what` (a parallel layout, a quantized path) is written for the
+    block GPT-NeoX published: LayerNorm with bias, biased projections,
+    no norm on q and k, an ungated FFN. Refuse any other by name."""
+    other = [f"{k}={getattr(cfg, k)!r}" for k, plain in
+             (("norm", "layernorm"), ("use_bias", True), ("qk_norm", False),
+              ("ffn_gated", False), ("moe_dropless", False))
+             if getattr(cfg, k, plain) != plain]
+    if other:
+        raise NotImplementedError(
+            f"{what} is not computed for a block with "
+            f"{', '.join(other)}: its parameter layout and its sharding "
+            f"rules are the GPT-NeoX block's")
 
 
 def block_param_specs_tp(pipe_axis=None):
@@ -800,8 +971,7 @@ def forward_hidden(cfg, params, tokens, use_pallas=True, remat_blocks=False,
             if quant is not None:
                 new_amax = jnp.stack(new_rows)
 
-    out = layer_norm(x, params["final_ln"]["scale"],
-                     params["final_ln"]["bias"], cfg.layernorm_eps)
+    out = norm(cfg, params["final_ln"], x)
     if moe:
         if collect_hidden:
             return out, aux_total, hidden + [out]
@@ -1070,6 +1240,7 @@ class GPTNeoX:
             raise ValueError(
                 f"attention_engine must be 'dense' or 'sparse', got "
                 f"{self.config.attention_engine!r}")
+        self.config.check_block()
 
     def _attention_fn(self):
         """The attention core `forward_hidden` should use: the SP/sparse
@@ -1089,6 +1260,12 @@ class GPTNeoX:
         library imports) drives all three axes."""
         import dataclasses
         moe = getattr(ds_config, "moe_params", None)
+        if moe and self.config.moe_dropless:
+            raise NotImplementedError(
+                "the JSON `moe` block configures the GShard capacity "
+                "router (capacity_factor, groups, dispatch engine); a "
+                "model whose routing drops nothing (moe_dropless) takes "
+                "its experts from the published architecture")
         if moe:
             self.config = dataclasses.replace(
                 self.config,
@@ -1159,6 +1336,7 @@ class GPTNeoX:
             self._sparse_params = dict(sparse)
             self._attn_fn = make_sparse_attention(self.config,
                                                   self._sparse_params)
+        self.config.check_block()
         apply_activation_checkpointing_config(self, ds_config, mesh)
 
     def init_params(self, rng):
@@ -1175,7 +1353,10 @@ class GPTNeoX:
                 "tensor parallel + MoE FFN is unsupported; shard experts "
                 "over an 'expert' mesh axis")
         if has_mp:
+            _require_neox_block(self.config, "tensor parallelism")
             return param_specs(self.config, params)
+        if has_ep:
+            _require_neox_block(self.config, "expert parallelism")
         specs = jax.tree_util.tree_map(lambda p: P(), params)
         if has_ep:
             # expert dim sharded over the expert axis; XLA inserts the
@@ -1234,9 +1415,16 @@ class GPTNeoX:
     def _head_loss(self, params, hidden, labels, aux):
         out_embed = params.get("embed_out", params["embed"])["wte"]
         loss = fused_lm_head_loss(hidden, out_embed, labels)
-        if aux is not None:
-            loss = loss + self.config.moe_aux_loss_coef * \
-                aux / max(self.config.num_layers, 1)
+        cfg = self.config
+        if aux is not None and cfg.moe_dropless:
+            # `aux` is the sum over the layers of [f, P] (each [E]):
+            # E * sum_e f_e P_e over ALL layers' routed tokens
+            f, prob = aux / max(cfg.num_layers, 1)
+            loss = loss + cfg.moe_aux_loss_coef * cfg.moe_num_experts * \
+                jnp.sum(f * prob)
+        elif aux is not None:
+            loss = loss + cfg.moe_aux_loss_coef * \
+                aux / max(cfg.num_layers, 1)
         return loss
 
     def loss_fn(self, params, batch, rng=None, ffn_amax=None):
@@ -1315,8 +1503,7 @@ class GPTNeoX:
 
         def head_fwd(sp, carry, batch, rng):
             _, labels = tok_lab(batch)
-            x = layer_norm(carry, sp["final_ln"]["scale"],
-                           sp["final_ln"]["bias"], cfg.layernorm_eps)
+            x = norm(cfg, sp["final_ln"], carry)
             return fused_lm_head_loss(x, sp["wte"], labels)
 
         segments = [("embed", lambda p: {"wte": p["embed"]["wte"]})]
@@ -1360,6 +1547,7 @@ class GPTNeoX:
         stack [L, ...] sharded over the ``pipe`` mesh axis, the loss
         runs the microbatched 1F1B tick loop inside shard_map."""
         from ..parallel.pipeline_spmd import GPTNeoXPipeSPMD
+        _require_neox_block(self.config, "the compiled pipeline")
         return GPTNeoXPipeSPMD(self.config, mesh, n_micro,
                                fp32_comm=fp32_comm,
                                use_pallas=self.use_pallas,
@@ -1510,8 +1698,7 @@ class GPTNeoX:
                         policy=policy, remat=remat, ef=ef_l)
 
                     fl = gathered(lp["final_ln"], outer["final_ln"])
-                    x = layer_norm(x, fl["scale"], fl["bias"],
-                                   cfg.layernorm_eps)
+                    x = norm(cfg, fl, x)
                     if "embed_out" in lp:
                         head_wte = gathered(lp["embed_out"],
                                             outer["embed_out"])["wte"]
@@ -1694,7 +1881,7 @@ class GPTNeoX:
         def head_core(row_ln, row_we, x, labels):
             ln = rebuild1(plans["final_ln"], row_ln)
             wte = rebuild1(we_plan, row_we)["wte"]
-            h = layer_norm(x, ln["scale"], ln["bias"], cfg.layernorm_eps)
+            h = norm(cfg, ln, x)
             return fused_lm_head_loss(h, wte, labels)
 
         def _head_loss(row_ln, row_we, x, labels):
@@ -1764,9 +1951,7 @@ def _block_decode(cfg, bp, x, kv, pos, cos_sin):
 
     out = _block_post_attn(cfg, bp, x, attn.reshape(B, 1, cfg.hidden_size),
                            reduce_fn=lambda t: t)
-    if getattr(cfg, "moe_num_experts", 0):
-        out, _ = out  # greedy decode ignores the aux loss
-    return out, (k_cache, v_cache)
+    return block_hidden(out), (k_cache, v_cache)   # decode takes no aux
 
 
 def _prefill(cfg, params, tokens, s_max, use_pallas=True):
@@ -1782,6 +1967,7 @@ def _prefill(cfg, params, tokens, s_max, use_pallas=True):
         for bp in params["blocks"]:
             x, (k, v) = _block_core(cfg, bp, x, cos_sin, use_pallas, mp=1,
                                     reduce_fn=lambda t: t, return_kv=True)
+            x = block_hidden(x)
             with scopes.scope("ds.kv_write"):
                 caches.append((jnp.pad(k, pad), jnp.pad(v, pad)))
     return x[:, -1:, :], caches
@@ -1812,8 +1998,7 @@ def generate(cfg, params, prompt, max_new_tokens, temperature=0.0,
 
     @scopes.scoped("ds.lm_head")
     def logits_of(x):
-        h = layer_norm(x, params["final_ln"]["scale"],
-                       params["final_ln"]["bias"], cfg.layernorm_eps)
+        h = norm(cfg, params["final_ln"], x)
         return jnp.einsum("bsh,vh->bsv", h, out_embed.astype(h.dtype),
                           preferred_element_type=jnp.float32)[:, 0, :]
 
@@ -1888,13 +2073,10 @@ class FinalNormPipe:
         self.cfg = cfg
 
     def init(self, rng, x):
-        h = self.cfg.hidden_size
-        return {"scale": jnp.ones((h,), self.cfg.param_dtype),
-                "bias": jnp.zeros((h,), self.cfg.param_dtype)}
+        return init_norm_params(self.cfg)
 
     def apply(self, params, x, rng=None):
-        return layer_norm(x, params["scale"], params["bias"],
-                          self.cfg.layernorm_eps)
+        return norm(self.cfg, params, x)
 
 
 class OutputHeadPipe:
@@ -1934,6 +2116,7 @@ def to_layer_specs(cfg, use_pallas=True):
             "MoE layers cannot be pipelined yet: the expert aux loss is "
             "not threaded through the inter-stage buffers. Use MoE with "
             "data/tensor/expert parallelism, or pipeline a dense model")
+    _require_neox_block(cfg, "the pipeline's layer specs")
     specs = []
     if cfg.tie_word_embeddings:
         specs.append(TiedLayerSpec("embed", EmbeddingPipe, cfg,

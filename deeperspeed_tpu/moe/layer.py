@@ -531,6 +531,116 @@ def moe_ffn_dense(params, x, capacity_factor=1.25, top_k=1, rng=None,
     return _sort_combine(out_buf, route, span, T, x.dtype), route.aux
 
 
+# ---------------------------------------------------------------------------
+# dropless top-k routing (no capacity: every token keeps all its experts)
+# ---------------------------------------------------------------------------
+
+def dropless_geometry(tokens, top_k, n_experts):
+    """(buffer rows, row tile) of the dropless dispatch of `tokens`
+    tokens: static, from shapes alone, so the serving engine can count
+    them on the host (`stats["moe_buffer_rows"]`)."""
+    from ..ops.pallas.grouped_matmul import (ragged_block_m,
+                                             ragged_buffer_rows)
+    bm = ragged_block_m(tokens * top_k, n_experts)
+    return ragged_buffer_rows(tokens * top_k, n_experts, bm), bm
+
+
+def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
+                     activation=jax.nn.silu, token_mask=None,
+                     gmm_backend=None):
+    """Top-k routing that drops nothing, through the sort engine.
+
+    params: {"gate" [H, E] (the router), "w_in" [E, H, 2I] (each
+    expert's gate and up projections, [gate | up] along the last dim),
+    "w_out" [E, I, H]}; no biases. x [T, H] -> (y [T, H], stats [2, E]).
+    A serving layer loop hands `w_in` / `w_out` as `LayerOf` the stacked
+    weights (`ops.pallas.grouped_matmul`).
+
+        p = softmax(x @ gate) in float32 over all E experts
+        (p_j, e_j) = the top_k largest, renormalised over the kept ones
+                     only if `norm_topk_prob`
+        y = sum_j p_j * (act(x Wgate[e_j]) * (x Wup[e_j])) Wdown[e_j]
+
+    The T*top_k (token, expert) rows are sorted by expert into ONE
+    buffer whose groups have their real lengths, each padded to a whole
+    row tile (`ops.pallas.grouped_matmul`, ragged layout): no capacity,
+    no `capacity_factor`. `token_mask` [T] marks real tokens: a padded
+    row (a prefill bucket's tail, an inactive decode row) is routed to no
+    expert, takes no buffer row and no part in the statistics, and comes
+    out zero. So a token's result does not depend on its batch
+    neighbours.
+
+    stats = [f, P]: f_e the share of the routed (token, choice) pairs
+    that chose e, P_e the mean router probability of e over the routed
+    tokens. The load-balancing loss E * sum_e f_e P_e is taken by the
+    model over ALL layers' routed tokens (`GPTNeoX._head_loss`), so the
+    layer returns its means, not a scalar.
+    """
+    from .. import scopes
+    from ..ops.pallas.grouped_matmul import ragged_matmul, ragged_tile_maps
+    T, H = x.shape
+    E = params["gate"].shape[1]
+    k = int(top_k)
+    if not 1 <= k <= E:
+        raise ValueError(f"dropless routing needs 1 <= top_k <= {E} "
+                         f"experts, got top_k={top_k}")
+    R, bm = dropless_geometry(T, k, E)
+    live = jnp.ones((T,), jnp.bool_) if token_mask is None \
+        else token_mask.reshape(T).astype(jnp.bool_)
+
+    with scopes.scope("ds.moe_route"):
+        # float32 at full precision: a near-tie at the k-th probability
+        # is decided by the accumulated sum, not by a bf16 pass
+        logits = jnp.dot(x.astype(jnp.float32),
+                         params["gate"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)               # [T, E]
+        weights, experts = jax.lax.top_k(probs, k)            # [T, k]
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        n_live = jnp.maximum(jnp.sum(live), 1).astype(jnp.float32)
+        mean_prob = jnp.sum(jnp.where(live[:, None], probs, 0.0),
+                            axis=0) / n_live                  # P [E]
+
+    with scopes.scope("ds.moe_dispatch"):
+        # pair p = t*k + j; a padded token's pairs go to the sentinel E,
+        # which sorts last and owns no buffer row
+        pair_expert = jnp.where(live[:, None], experts.astype(jnp.int32),
+                                E).reshape(T * k)
+        order = jnp.argsort(pair_expert)                      # stable
+        counts = jnp.zeros((E + 1,), jnp.int32).at[pair_expert].add(1)[:E]
+        tile_expert, tile_rows, starts = ragged_tile_maps(
+            counts, bm, R // bm)
+        sorted_expert = pair_expert[order]
+        begin = jnp.cumsum(counts) - counts       # first sorted pair of e
+        e_safe = jnp.minimum(sorted_expert, E - 1)
+        dest = starts[e_safe] + jnp.arange(T * k, dtype=jnp.int32) - \
+            begin[e_safe]
+        dest = jnp.where(sorted_expert < E, dest, R)          # R: nowhere
+        # buffer row -> source pair (T*k: a padding row), pair -> row
+        src = jnp.full((R,), T * k, jnp.int32).at[dest].set(
+            order.astype(jnp.int32), mode="drop")
+        pair_row = jnp.zeros((T * k,), jnp.int32).at[order].set(dest)
+        buf = jnp.where((src < T * k)[:, None],
+                        x[jnp.minimum(src, T * k - 1) // k], 0)
+        stats = jnp.stack([counts.astype(jnp.float32) /
+                           jnp.maximum(jnp.sum(counts), 1), mean_prob])
+
+    dt = x.dtype
+    inter = params["w_out"].shape[1]
+    h = ragged_matmul(buf, params["w_in"].astype(dt), tile_expert,
+                      tile_rows, bm, backend=gmm_backend)     # [R, 2I]
+    h = activation(h[:, :inter]) * h[:, inter:]
+    out = ragged_matmul(h, params["w_out"].astype(dt), tile_expert,
+                        tile_rows, bm, backend=gmm_backend)   # [R, H]
+
+    with scopes.scope("ds.moe_combine"):
+        rows = out[jnp.minimum(pair_row, R - 1)].reshape(T, k, H)
+        w = jnp.where(live[:, None], weights, 0.0).astype(dt)
+        y = jnp.sum(w[:, :, None] * rows, axis=1)
+    return y, stats
+
+
 def _a2a(t, axis_name):
     return jax.lax.all_to_all(t, axis_name, 0, 0, tiled=False)
 

@@ -44,6 +44,14 @@ SCOPES = {
     "ds.grouped_matmul": ("kernel", "grouped (per-expert) matmul"),
     "ds.grouped_matmul_dw": ("kernel", "grouped matmul, weight gradient"),
     "ds.quant_matmul": ("kernel", "int8-weight matmul"),
+    "ds.moe_route": ("region", "a dropless MoE's router: the float32 "
+                               "router matmul, softmax, top-k"),
+    "ds.moe_dispatch": ("region", "sort by expert, the groups' offsets "
+                                  "and tile maps, the gather of token "
+                                  "rows into the experts' buffer"),
+    "ds.moe_combine": ("region", "the experts' rows gathered back, "
+                                 "weighted and summed over a token's "
+                                 "experts"),
     "ds.attn_xla": ("region", "the XLA fallback of attention"),
     "ds.paged_decode_xla": ("region", "the XLA fallback of paged decode"),
     "ds.embed": ("region", "token (and position) embedding gather"),

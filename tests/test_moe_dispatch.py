@@ -95,6 +95,27 @@ def test_sort_interpret_kernel_path():
                                rtol=2e-6, atol=2e-6)
 
 
+@pytest.mark.parametrize("dispatch", DISPATCH_MODES)
+def test_capacity_router_is_top_1_or_2_and_dropless_takes_any_k(dispatch):
+    """The rule "top_k must be 1 or 2" is the GShard capacity router's,
+    in both its engines; routing that drops nothing
+    (`moe_ffn_dropless`) takes any 1 <= k <= E and names what it
+    refuses."""
+    from deeperspeed_tpu.moe.layer import moe_ffn_dropless
+    params = _params(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, H), jnp.float32)
+    with pytest.raises(ValueError, match="top_k must be 1 or 2"):
+        moe_ffn_dense(params, x, top_k=3, dispatch=dispatch)
+    gated = {"gate": params["gate"],
+             "w_in": jnp.concatenate([params["w_in"]] * 2, axis=-1),
+             "w_out": params["w_out"]}
+    y, stats = moe_ffn_dropless(gated, x, 3)
+    assert y.shape == x.shape and stats.shape == (2, E)
+    np.testing.assert_allclose(float(stats[0].sum()), 1.0, atol=1e-6)
+    with pytest.raises(ValueError, match="1 <= top_k <="):
+        moe_ffn_dropless(gated, x, E + 1)
+
+
 def test_unknown_dispatch_raises():
     params = _params(jax.random.PRNGKey(0))
     x = jnp.ones((8, H), jnp.float32)

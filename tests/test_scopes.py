@@ -39,6 +39,13 @@ CFG = GPTNeoXConfig(vocab_size=512, hidden_size=128, num_layers=2,
                     num_heads=2, max_seq_len=256)
 
 MODEL_SCOPES = ["ds.embed", "ds.layers", "ds.block", "ds.attn", "ds.mlp"]
+MOE_SCOPES = ["ds.moe_route", "ds.moe_dispatch", "ds.moe_combine"]
+MOE_CFG = GPTNeoXConfig(
+    vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
+    max_seq_len=256, rotary_pct=1.0, use_parallel_residual=False,
+    norm="rmsnorm", use_bias=False, qk_norm=True, hidden_act="silu",
+    ffn_gated=True, ffn_width=64, moe_num_experts=8, moe_top_k=2,
+    moe_dropless=True)
 PROGRAMS = {
     # the tiled kernels: 256 tokens in blocks of 128
     "train": MODEL_SCOPES + ["ds.flash_fwd", "ds.flash_bwd_dq",
@@ -53,6 +60,12 @@ PROGRAMS = {
     "decode": MODEL_SCOPES + ["ds.paged_decode", "ds.kv_write",
                               "ds.lm_head", "ds.sample"],
     "decode_xla": ["ds.paged_decode_xla"],
+    # a dropless MoE block (OLMoE's) in the serving programs: the router,
+    # the sort dispatch and the combine inside `ds.mlp`
+    "moe_prefill": MODEL_SCOPES + MOE_SCOPES + ["ds.flash_fwd",
+                                                "ds.kv_write"],
+    "moe_decode": MODEL_SCOPES + MOE_SCOPES + ["ds.paged_decode",
+                                               "ds.kv_write"],
 }
 
 
@@ -80,8 +93,8 @@ def train_text(seq, use_pallas):
                       engine._current_lr()).compile().as_text()
 
 
-def serve_texts(kernel):
-    model = GPTNeoX(CFG, use_pallas=True)
+def serve_texts(kernel, cfg=None):
+    model = GPTNeoX(cfg or CFG, use_pallas=True)
     engine = InferenceEngine(
         model, params=model.init_params(jax.random.PRNGKey(0)),
         config={"inference": {
@@ -113,6 +126,7 @@ def lower_all():
         texts["train"] = train_text(256, use_pallas=True)
     texts["prefill"], texts["decode"] = serve_texts("pallas")
     texts["decode_xla"] = serve_texts("xla")[1]
+    texts["moe_prefill"], texts["moe_decode"] = serve_texts("pallas", MOE_CFG)
     return texts
 
 
@@ -250,6 +264,9 @@ def test_stats_hold_the_phases_from_construction():
     for phase in PHASES:
         assert engine.stats[phase + "_s"] == 0.0
     assert engine.stats["decode_kv_tokens"] == 0
+    for counter in ("moe_rows_prefill", "moe_rows_decode",
+                    "moe_buffer_rows"):
+        assert engine.stats[counter] == 0
 
 
 def test_phases_and_decode_kv_tokens_of_a_hand_run_schedule():
@@ -272,6 +289,9 @@ def test_phases_and_decode_kv_tokens_of_a_hand_run_schedule():
     by_hand = sum(n + j for n in lengths for j in range(1, new))
     assert stats["decode_kv_tokens"] == by_hand
     assert stats["decode_tokens"] == len(lengths) * (new - 1)
+    # a dense model routes nothing (an MoE's counts by hand:
+    # tests/test_olmoe.py)
+    assert stats["moe_rows_prefill"] == stats["moe_buffer_rows"] == 0
 
 
 def test_phases_are_spans_of_the_telemetry_block():

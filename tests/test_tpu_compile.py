@@ -313,6 +313,69 @@ def test_decode_program_leaves_the_pools_in_place(on_chip, v5e_2x2, kv):
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_moe_serving_programs_leave_the_experts_in_place(on_chip, v5e_2x2,
+                                                         program):
+    """The engine's decode and prefill programs for an OLMoE block at the
+    published widths (hidden 2048, 16 heads of 128, experts of width
+    1024, 8 a token; two layers, 16 experts, a small vocabulary),
+    compiled for the described v5e from shapes alone. The layer loop
+    does not slice a layer's experts out of the stacked weights (0.8 GB
+    a layer at 64 experts: a third of the device's time when a scan did
+    it): but for what only carries them, and the grouped matmul's own
+    calls, no instruction's result has the experts' shape."""
+    from jax.sharding import SingleDeviceSharding
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+    layers, experts, batch, seqlen, page_size = 2, 16, 32, 256, 64
+    cfg = GPTNeoXConfig(
+        vocab_size=1024, hidden_size=2048, num_layers=layers, num_heads=16,
+        max_seq_len=2048, rotary_pct=1.0, use_parallel_residual=False,
+        norm="rmsnorm", use_bias=False, qk_norm=True, hidden_act="silu",
+        ffn_gated=True, ffn_width=1024, moe_num_experts=experts,
+        moe_top_k=8, moe_dropless=True)
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, BF16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page_size,
+        "num_pages": 2048 // page_size + 1, "max_batch_size": batch,
+        "token_budget": 2048, "prefill_lengths": [seqlen],
+        "prefill_batch_sizes": [1], "decode_batch_sizes": [batch]}})
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    if program == "decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch), ints(batch), ints(batch, engine.n_pages_max))
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+    text = fn.lower(
+        jax.tree_util.tree_map(shape_of, engine.params),
+        jax.tree_util.tree_map(shape_of, engine.params_stacked), *inputs,
+        shape_of(engine.cache.k), shape_of(engine.cache.v),
+        shape_of(jax.random.PRNGKey(0))).compile().as_text()
+    calls = re.findall(r"%ds\.grouped_matmul[.\d]* = .*tpu_custom_call", text)
+    assert len(calls) >= 2, "gate-and-up and down: two kernel calls a layer"
+    expert_shaped = re.compile(
+        rf"bf16\[(?:{layers},|1,)?{experts},(?:2048,2048|1024,2048)\]")
+    moved = []
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        if m and expert_shaped.search(m["type"]) and \
+                m["op"] not in CARRIES and "tpu_custom_call" not in line:
+            moved.append((m["op"], m["type"][:60]))
+    assert not moved, moved
+
+
 # ---------------------------------------------------------------------------
 # grouped matmul, int8 weight matmul, fused Adam
 # ---------------------------------------------------------------------------
@@ -332,6 +395,39 @@ def test_grouped_matmul_compiles(on_chip):
     grad = jax.grad(lambda x, w, s: gmm(x, w, s).astype(jnp.float32).sum(),
                     argnums=(0, 1))
     assert_kernel(on_chip(grad, *args), at_least=2)
+
+
+@pytest.mark.parametrize("tokens", [32, 256, 1024, 1536],
+                         ids=["decode_32", "prefill_256", "prefill_1024",
+                              "prefill_1536"])
+def test_ragged_grouped_matmul_compiles_at_olmoe_shapes(on_chip, tokens):
+    """The dropless layout at OLMoE-1B-7B's widths: 64 experts, 8 a
+    token, the fused gate-and-up projection 2048 -> 2048 and the down
+    projection 1024 -> 2048, at a decode step's 256 rows (4 a group on
+    average, a 16-row tile) and at the prefill buckets' rows."""
+    from deeperspeed_tpu.moe.layer import dropless_geometry
+    E, k, h, inter = 64, 8, 2048, 1024
+    rows, bm = dropless_geometry(tokens, k, E)
+    assert rows % bm == 0 and rows >= tokens * k + E
+    assert grouped_matmul.grouped_matmul_supported(h, 2 * inter, bm)
+    maps = [((rows // bm,), jnp.int32)] * 2
+
+    def ffn(x, w_in, w_out, tile_expert, tile_rows):
+        hmid = grouped_matmul.ragged_matmul(x, w_in, tile_expert,
+                                            tile_rows, bm, backend="pallas")
+        hmid = jax.nn.silu(hmid[:, :inter]) * hmid[:, inter:]
+        return grouped_matmul.ragged_matmul(hmid, w_out, tile_expert,
+                                            tile_rows, bm, backend="pallas")
+
+    args = [((rows, h), BF16), ((E, h, 2 * inter), BF16),
+            ((E, inter, h), BF16), *maps]
+    assert_kernel(on_chip(ffn, *args), at_least=2)
+    if tokens == 256:
+        # backward: dx over w's [N, K] slabs (no transposed copy) and dw
+        grad = jax.grad(lambda *a: ffn(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))
+        # (the last forward call is dead code under a sum)
+        assert_kernel(on_chip(grad, *args), at_least=5)
 
 
 @pytest.mark.parametrize("m,k,n", [(8, 768, 3072), (256, 768, 3072),
