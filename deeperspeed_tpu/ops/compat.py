@@ -52,8 +52,7 @@ def int8_kv_decode_available():
     # KERNEL module only — importing inference.kv_cache would execute
     # the whole serving package __init__, and an unrelated serving-stack
     # import failure would misreport THIS op as unavailable
-    from .pallas.decode_attention import (  # noqa: F401
-        _decode_kernel_quant, paged_decode_attention)
+    from .pallas.decode_attention import paged_decode_attention  # noqa: F401
     return True
 
 

@@ -28,14 +28,23 @@ Contract (shared by kernel and XLA fallback):
   being decoded (its K/V must already be written to its page). A length
   of 0 marks an inactive (padding) batch row; its output is exact zero.
 
-Mechanics: grid (B, H, NP) with the page dimension innermost and
-``arbitrary`` (it carries the online-softmax accumulation); the page
-table and lengths ride as scalar prefetch
-(`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index maps
-resolve page-table indirection at DMA-issue time — the same LUT
-mechanism as the compacted causal grids in `flash_attention.py`. Pages
-at or past a sequence's length skip all compute (`pl.when`); the last
-grid step writes ``acc / l``. No backward exists: decode is inference.
+Mechanics: a grid step moves one LIVE page of all the call's heads. A
+page of one layer is a contiguous [H, page_size, D] tile of the pool, so
+the K and V blocks are ``(None, None, Hb, page_size, D)`` with ``Hb`` =
+`heads_per_step`: the largest divisor of the call's head count whose
+step fits a fixed share of VMEM (H itself at every shape served so far).
+The grid is ``(H / Hb, steps)`` where ``steps`` is traced:
+`decode_steps` lists one step a live page, rows in order, and rides as
+scalar prefetch (`pltpu.PrefetchScalarGridSpec`) beside the page table,
+the lengths and the layer, so the index maps resolve step → (row, page)
+and the page-table indirection at DMA-issue time. Pages at or past a
+row's length, and whatever of the table's width no row uses, get no
+grid step and no DMA; an inactive row keeps one step, which names the
+trash page and writes its zeros. The step dimension is ``arbitrary``
+(it carries the online softmax: scratch m, l, acc for Hb heads, reset
+at a row's first page, written out as ``acc / l`` at its last). Inside
+a step the Hb queries meet the page as one [Hb * page_size, D] operand
+(`_page_update`). No backward exists: decode is inference.
 
 The decoded token's own K/V row gets into its page through
 `paged_kv_write`: on a TPU a second small kernel that ALIASES the stacked
@@ -61,8 +70,7 @@ from ... import scopes
 from ...compat import CompilerParams
 from .flash_attention import LANES, NEG_INF, _interpret, note_xla_on_tpu
 
-_DIMSEM = CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+_DIMSEM = CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 # Test/bench observability: backend ("pallas"/"xla") of the most recent
 # paged_decode_attention call — the serving tests pin which path ran.
@@ -107,82 +115,128 @@ def _auto_backend(op, head_dim, page_size, quant):
     return "xla"
 
 
-def _decode_kernel(pt_ref, len_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, sm_scale, page_size,
-                   ks_ref=None, vs_ref=None):
-    """One (batch row, head, page) step of paged flash decode
-    (`lyr_ref`, the layer of the stacked pools, is read by the index
-    maps alone). With
-    int8 pools (`ks_ref`/`vs_ref` scale blocks, resolved through the
-    SAME page-table LUT as the data blocks), the per-slot scales fold
-    into the [1, ps] score / probability rows — ``q·(k·s) == (q·k)·s``
-    — so the wire moved 1 byte/element and no [ps, 1] scale column (a
-    lane→sublane relayout) is ever built; the math runs fp32."""
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    p = pl.program_id(2)
-    n_pages = pl.num_programs(2)
+# VMEM one grid step may fill with its page tiles (K and V, and int8
+# pages' scales, each double-buffered) and the float32 tiles it computes
+# with: a quarter of the 16 MiB a Mosaic kernel gets by default, so the
+# compiler's own temporaries always have room
+_STEP_VMEM_BYTES = 4 * 2 ** 20
+
+
+def heads_per_step(heads, page_size, head_dim, pool_dtype):
+    """How many heads' K and V one grid step moves: the largest divisor
+    of `heads` (the call's own, so a model-parallel shard's H / mp) whose
+    step fits `_STEP_VMEM_BYTES`. One page of all heads is contiguous in
+    the pool ([H, page_size, D]), so the answer is H wherever it fits
+    (16 heads of 128 at page 64: 1.1 MiB) and the grid has no head
+    dimension to speak of; wider shapes split the heads and no other
+    path exists."""
+    pool_dtype = jnp.dtype(pool_dtype)
+    quant = pool_dtype == jnp.int8
+
+    def step_bytes(hb):
+        slots = hb * page_size
+        tiles = 2 * slots * head_dim * pool_dtype.itemsize      # K and V
+        work = 2 * hb * slots * 4                  # scores, probabilities
+        if quant:
+            tiles += 2 * slots * 2                 # their bf16 scale tiles
+            work += 2 * slots * head_dim * 4       # K and V widened
+        return 2 * tiles + work
+
+    # an int8 pool's [Hb, page_size] scale block has the heads on its
+    # sublanes: Mosaic takes it whole or in bf16 sublane tiles of 16
+    tiles = [hb for hb in range(1, heads + 1)
+             if heads % hb == 0 and (not quant or hb % 16 == 0 or hb == heads)]
+    return max([hb for hb in tiles
+                if step_bytes(hb) <= _STEP_VMEM_BYTES] or tiles[:1])
+
+
+def _page_update(q, k, v, k_scale, v_scale, m, l, acc, first_pos, length,
+                 sm_scale):
+    """One page of `Hb` heads folded into the running softmax: returns
+    the new (m, l, acc).
+
+    ``q`` [Hb, D]; ``k``/``v`` [Hb, ps, D] (the page as it lies in the
+    pool); ``k_scale``/``v_scale`` [Hb, ps] for int8 pages, else None;
+    ``m``/``l`` [Hb, 1] and ``acc`` [Hb, D] float32.
+
+    The page is read as ONE [Hb * ps, D] operand (a free collapse of its
+    leading dims): every head's query meets every head's keys in a
+    single [Hb, Hb * ps] matmul and each row keeps its own head's ps
+    columns (the others are masked to -inf, their probabilities exact
+    zeros, so the second matmul adds nothing of another head's V). The
+    MXU does Hb times the needed work, which at one query row a head is
+    still nothing beside the page's bytes, and in exchange a step is two
+    matmuls and one softmax over full vector registers instead of 2 * Hb
+    one-row matmuls and Hb quarter-filled softmaxes. Int8 pages: the
+    per-slot scales fold into the score / probability columns —
+    ``q·(k·s) == (q·k)·s`` — and the math runs float32."""
+    hb, ps, d = k.shape
+    if k_scale is not None:
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jax.lax.dot_general(
+        q, k.reshape(hb * ps, d), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                 # [Hb, Hb * ps]
+    # column c of row h is slot c - h * ps of head h's page, if in [0, ps)
+    slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) - \
+        jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) * ps
+    live = (slot >= 0) & (slot < ps) & (first_pos + slot < length)
+
+    def own_columns(scale):
+        # [Hb, ps] -> [Hb, Hb * ps]: row h's scales under each head's
+        # columns; only its own (the live ones) are ever used
+        return jnp.tile(scale.astype(jnp.float32), (1, hb))
+
+    if k_scale is not None:
+        s = s * own_columns(k_scale)
+    s = jnp.where(live, s * sm_scale, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    # a live page has a live slot in every row, so m_new is finite and
+    # l stays an exact count of live probability mass
+    prob = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    l_new = alpha * l + jnp.sum(prob, axis=1, keepdims=True)
+    if v_scale is not None:
+        prob = prob * own_columns(v_scale)
+    else:
+        prob = prob.astype(v.dtype)
+    pv = jax.lax.dot_general(
+        prob, v.reshape(hb * ps, d), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                 # [Hb, D]
+    return m_new, l_new, acc * alpha + pv
+
+
+def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
+                   k_ref, v_ref, *refs, sm_scale, page_size):
+    """Grid step `t` of a head group: one live page of one batch row —
+    the page's K and V tiles of `Hb` heads ([Hb, ps, D] each) meet the
+    row's `Hb` queries. `pt_ref` and `lyr_ref` (the page table and the
+    layer of the stacked pools) are read by the index maps alone. Int8
+    pools bring two more refs, the page's [Hb, ps] scale tiles, resolved
+    through the same maps as the data."""
+    *scale_refs, o_ref, m_scr, l_scr, acc_scr = refs
+    t = pl.program_id(1)
+    b = row_ref[t]
+    first_pos = (t - start_ref[b]) * page_size
     length = len_ref[b]
 
-    def scale_row(ref):
-        # the block is the page's whole [Hk, ps] scale tile (Mosaic only
-        # takes a block whose last two dims are the array's own or
-        # (8, 128)-multiples); pick this head's row by mask-and-reduce,
-        # which needs no dynamic sublane slice of a packed bf16 tile
-        tile = ref[...].astype(jnp.float32)                    # [Hk, ps]
-        rows = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
-        return jnp.sum(jnp.where(rows == h, tile, 0.0), axis=0,
-                       keepdims=True)                          # [1, ps]
-
-    @pl.when(p == 0)
+    @pl.when(first_pos == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(p * page_size < length)
+    @pl.when(first_pos < length)         # not an inactive row's one step
     def _compute():
-        q = q_ref[...]                                         # [1, D]
-        k = k_ref[...]                                         # [ps, D]
-        if ks_ref is not None:
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                # [1, ps]
-        if ks_ref is not None:
-            s = s * scale_row(ks_ref)
-        s = s * sm_scale
-        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + \
-            p * page_size
-        s = jnp.where(pos < length, s, NEG_INF)
+        k_scale, v_scale = [r[...] for r in scale_refs] or (None, None)
+        m, l, acc = _page_update(
+            q_ref[...], k_ref[...], v_ref[...], k_scale, v_scale,
+            m_scr[:, :1], l_scr[:, :1], acc_scr[:], first_pos, length,
+            sm_scale)
+        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
+        acc_scr[:] = acc
 
-        m_prev = m_scr[:, :1]                                  # [1, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        prob = jnp.exp(s - m_new)
-        # masked slots would see exp(NEG_INF - m) == 0 already, except
-        # when the whole page is masked and m_new == NEG_INF; zero them
-        # so l stays an exact count of live probability mass
-        prob = jnp.where(s <= NEG_INF * 0.5, 0.0, prob)
-        l_new = alpha * l_prev + jnp.sum(prob, axis=1, keepdims=True)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        if vs_ref is not None:
-            pv = jax.lax.dot_general(
-                prob * scale_row(vs_ref), v_ref[...].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)            # [1, D]
-        else:
-            pv = jax.lax.dot_general(
-                prob.astype(v_ref.dtype), v_ref[...],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)            # [1, D]
-        acc_scr[:] = acc_scr[:] * alpha + pv
-
-    @pl.when(p == n_pages - 1)
+    @pl.when(first_pos + page_size >= length)        # the row's last step
     def _finalize():
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -190,14 +244,20 @@ def _decode_kernel(pt_ref, len_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[...] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-def _decode_kernel_quant(pt_ref, len_ref, lyr_ref, q_ref, k_ref, v_ref,
-                         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                         sm_scale, page_size):
-    """Positional-arg adapter for the int8 variant (pallas passes refs
-    in in_specs order: data pools then scale pools)."""
-    _decode_kernel(pt_ref, len_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, sm_scale=sm_scale,
-                   page_size=page_size, ks_ref=ks_ref, vs_ref=vs_ref)
+def decode_steps(lengths, page_size, table_width):
+    """The kernel's work list: one grid step a LIVE page, rows in order
+    (a row of length 0 keeps one step, which writes its zeros). Returns
+    ``(n_steps, row, start)``: the traced step count (at most
+    ``B * table_width``), ``row`` [B * table_width] — the batch row of
+    step `t`; entries at and past `n_steps` are never read — and
+    ``start`` [B], each row's first step, so step `t` is page
+    ``t - start[row[t]]`` of its row."""
+    B = lengths.shape[0]
+    steps = jnp.maximum(-(-lengths // page_size), 1)
+    ends = jnp.cumsum(steps)
+    row = jnp.searchsorted(ends, jnp.arange(B * table_width, dtype=jnp.int32),
+                           side="right", method="compare_all")
+    return ends[-1], jnp.minimum(row, B - 1).astype(jnp.int32), ends - steps
 
 
 def _layer_operand(layer):
@@ -217,57 +277,62 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         k_pages, v_pages = k_pages[None], v_pages[None]
         if quant:
             k_scales, v_scales = k_scales[None], v_scales[None]
-    Hk, page_size = k_pages.shape[2:4]
-    NP = page_table.shape[1]
-    # Mosaic takes a block only if its last two dims are (8, 128)-
-    # multiples or the array's own. The query/out rows therefore ride as
-    # [B, H, 1, D] (a [1, D] block over a [1, D] minor plane) and the
-    # scale block is the page's whole [Hk, ps] plane; `None` dims are
-    # squeezed out of the kernel's refs.
-    row_spec = pl.BlockSpec((None, None, 1, D),
-                            lambda b, h, p, pt, ln, lyr: (b, h, 0, 0))
-    pool_spec = pl.BlockSpec(
-        (None, None, None, page_size, D),
-        lambda b, h, p, pt, ln, lyr: (lyr[0], pt[b, p], h, 0, 0))
-    # the scale pool rides the SAME scalar-prefetch LUT that resolves
-    # the data pool's page indirection — one page id, two DMAs
-    scale_spec = pl.BlockSpec(
-        (None, None, Hk, page_size),
-        lambda b, h, p, pt, ln, lyr: (lyr[0], pt[b, p], 0, 0))
+    page_size = k_pages.shape[3]
+    hb = heads_per_step(H, page_size, D, k_pages.dtype)
+
+    def row_block(g, t, row, start, pt, ln, lyr):
+        return row[t], g, 0, 0
+
+    def page_block(g, t, row, start, pt, ln, lyr):
+        b = row[t]
+        # an inactive row's step names the trash page, whatever its table
+        # row holds: a run of them fetches it once
+        page = jnp.where(ln[b] > 0, pt[b, t - start[b]], 0)
+        return lyr[0], page, g, 0, 0
+
+    # the rows ride as [B, H / Hb, Hb, D] so that a block's last two dims
+    # are the array's own whatever Hb is; `None` dims are squeezed out of
+    # the kernel's refs
+    row_spec = pl.BlockSpec((None, None, hb, D), row_block)
+    pool_spec = pl.BlockSpec((None, None, hb, page_size, D), page_block)
     in_specs = [row_spec, pool_spec, pool_spec]
-    args = [q[:, :, None, :], k_pages, v_pages]
-    kernel_fn = _decode_kernel
+    args = [q.reshape(B, H // hb, hb, D), k_pages, v_pages]
     if quant:
+        # the scale pool rides the SAME scalar-prefetch maps that resolve
+        # the data pool's page indirection — one page id, two DMAs
+        scale_spec = pl.BlockSpec((None, None, hb, page_size),
+                                  lambda *a: page_block(*a)[:-1])
         in_specs += [scale_spec, scale_spec]
         # scale pools stay at their storage dtype (bf16) on the wire;
         # the kernel widens each tile in VMEM — a whole-pool fp32
         # cast here would materialize a pool-sized copy every step
         args += [k_scales, v_scales]
-        kernel_fn = _decode_kernel_quant
-    kernel = functools.partial(kernel_fn, sm_scale=sm_scale,
-                               page_size=page_size)
-    call = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, H, NP),
-            in_specs=in_specs,
-            out_specs=row_spec,
-            scratch_shapes=[
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, D), jnp.float32),
-            ],
-        ),
-        compiler_params=_DIMSEM,
-        interpret=_interpret(), name="ds.paged_decode",
-    )
-    operands = (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-                _layer_operand(layer), *args)
     with scopes.scope("ds.paged_decode"):
-        out = call(*operands)
-    return out[:, :, 0, :]
+        lengths = lengths.astype(jnp.int32)
+        n_steps, row, start = decode_steps(lengths, page_size,
+                                           page_table.shape[1])
+        out = pl.pallas_call(
+            functools.partial(_decode_kernel, sm_scale=sm_scale,
+                              page_size=page_size),
+            out_shape=jax.ShapeDtypeStruct((B, H // hb, hb, D), q.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                # the page dimension carries the online softmax; its
+                # extent is this call's own count of live pages
+                grid=(H // hb, n_steps),
+                in_specs=in_specs,
+                out_specs=row_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((hb, LANES), jnp.float32),
+                    pltpu.VMEM((hb, LANES), jnp.float32),
+                    pltpu.VMEM((hb, D), jnp.float32),
+                ],
+            ),
+            compiler_params=_DIMSEM,
+            interpret=_interpret(), name="ds.paged_decode",
+        )(row, start, page_table.astype(jnp.int32), lengths,
+          _layer_operand(layer), *args)
+    return out.reshape(B, H, D)
 
 
 @scopes.scoped("ds.paged_decode_xla")

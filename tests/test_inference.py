@@ -27,7 +27,8 @@ from deeperspeed_tpu.models.gpt2 import forward as gpt2_forward
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.models.gpt_neox import forward as neox_forward
 from deeperspeed_tpu.ops.pallas.decode_attention import (
-    paged_decode_attention, paged_decode_attention_xla)
+    decode_steps, heads_per_step, paged_decode_attention,
+    paged_decode_attention_xla)
 from deeperspeed_tpu.runtime.config import parse_inference_block
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
 
@@ -112,6 +113,98 @@ class TestDecodeAttentionKernel:
         ref = _dense_oracle(q, kp, vp, pages, lens, B, H, D, NP)
         np.testing.assert_allclose(ref, np.asarray(o_pl, np.float32),
                                    atol=3e-2)
+
+    # page 16, table 4 wide: an inactive row, one token, an exact page
+    # edge, a row that ends mid-table beside two that fill the table
+    RAGGED = [0, 1, 32, 37, 64, 64]
+
+    @pytest.mark.parametrize("stacked", [False, True],
+                             ids=["one_layer", "layer_of_stack"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["fp32", "bf16"])
+    @pytest.mark.parametrize("D", [64, 128])
+    @pytest.mark.parametrize("H", [4, 12, 16])
+    def test_kernel_over_heads_dims_and_ragged_rows(self, H, D, dtype,
+                                                    stacked):
+        rng = np.random.default_rng(H * D)
+        B, ps, NP, P = len(self.RAGGED), 16, 4, 32
+        q, kp, vp, pt, pages = _rand_paged(rng, B, H, D, ps, NP, P)
+        lens = jnp.asarray(self.RAGGED, jnp.int32)
+        # dead entries hold the trash page, as the scheduler pads them
+        live = np.arange(NP)[None, :] * ps < np.asarray(lens)[:, None]
+        pt = jnp.where(live, pt, 0)
+        ref = _dense_oracle(q, kp, vp, pages, lens, B, H, D, NP)
+        qc, kc, vc = (t.astype(dtype) for t in (q, kp, vp))
+        if stacked:
+            # the layer under test between two layers of other content
+            kc, vc = (jnp.stack([t[::-1], t, t * 0]) for t in (kc, vc))
+
+        def call(backend):
+            if not stacked:
+                return paged_decode_attention(qc, kc, vc, pt, lens,
+                                              backend=backend)
+            return jax.jit(lambda layer: paged_decode_attention(
+                qc, kc, vc, pt, lens, backend=backend, layer=layer))(
+                    jnp.int32(1))
+
+        o_pl, o_xla = call("pallas"), call("xla")
+        assert o_pl.dtype == dtype and o_pl.shape == (B, H, D)
+        exact = dtype == jnp.float32
+        np.testing.assert_allclose(
+            np.asarray(o_pl, np.float32), np.asarray(o_xla, np.float32),
+            atol=5e-6 if exact else 2e-2)
+        np.testing.assert_allclose(ref, np.asarray(o_pl, np.float32),
+                                   atol=5e-6 if exact else 3e-2)
+        assert (np.asarray(o_pl, np.float32)[0] == 0.0).all()
+
+    @pytest.mark.parametrize("H,ps,D,dtype,want", [
+        (16, 64, 128, jnp.bfloat16, 16),    # both serve cells: all heads
+        (4, 64, 128, jnp.bfloat16, 4),      # a model-parallel shard of 4
+        (12, 16, 64, jnp.bfloat16, 12),
+        (16, 64, 256, jnp.bfloat16, 16),
+        (16, 64, 128, jnp.int8, 16),
+        (32, 64, 256, jnp.float32, 8),      # too wide: the heads split
+        (64, 64, 128, jnp.int8, 32),        # int8: whole or 16s
+        (24, 64, 128, jnp.int8, 24),        # no multiple of 16 divides it
+    ])
+    def test_heads_per_step(self, H, ps, D, dtype, want):
+        hb = heads_per_step(H, ps, D, dtype)
+        assert hb == want and H % hb == 0
+
+    @pytest.mark.parametrize("stacked", [False, True],
+                             ids=["one_layer", "layer_of_stack"])
+    def test_split_heads_match_xla(self, stacked):
+        """A shape whose page of all heads does not fit a step: the grid
+        gets a head-group dimension, through `heads_per_step` alone."""
+        rng = np.random.default_rng(9)
+        B, H, D, ps, NP, P = 3, 32, 256, 64, 3, 10
+        assert heads_per_step(H, ps, D, jnp.float32) < H
+        q, kp, vp, pt, _ = _rand_paged(rng, B, H, D, ps, NP, P)
+        lens = jnp.asarray([64 * 3, 0, 70], jnp.int32)
+        kw = {}
+        if stacked:
+            kp, vp, kw = kp[None], vp[None], {"layer": 0}
+        o_pl = paged_decode_attention(q, kp, vp, pt, lens,
+                                      backend="pallas", **kw)
+        o_xla = paged_decode_attention(q, kp, vp, pt, lens, backend="xla",
+                                       **kw)
+        np.testing.assert_allclose(np.asarray(o_xla), np.asarray(o_pl),
+                                   atol=1e-5)
+        assert (np.asarray(o_pl)[1] == 0.0).all()
+
+    def test_decode_steps_lists_live_pages_only(self):
+        lens = jnp.asarray([0, 1, 64, 65, 130, 256], jnp.int32)
+        n, row, start = decode_steps(lens, 64, 4)
+        # an inactive row keeps one step; nobody pays for the table's
+        # width: 12 steps where the (B, pages) grid had 24
+        assert int(n) == 1 + 1 + 1 + 2 + 3 + 4
+        assert row.shape == (6 * 4,)
+        assert np.asarray(row)[:12].tolist() == \
+            [0, 1, 2, 3, 3, 4, 4, 4, 5, 5, 5, 5]
+        assert np.asarray(row).max() == 5          # the unread tail too
+        assert np.asarray(start).tolist() == [0, 1, 2, 3, 5, 8]
+        full, _, _ = decode_steps(jnp.full((6,), 256, jnp.int32), 64, 4)
+        assert int(full) == 6 * 4
 
     def test_shape_validation(self):
         rng = np.random.default_rng(4)

@@ -30,7 +30,7 @@ from deeperspeed_tpu.compat import shard_map
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.ops.pallas import quant_matmul as qm
 from deeperspeed_tpu.ops.pallas.decode_attention import (
-    paged_decode_attention, paged_decode_attention_xla)
+    heads_per_step, paged_decode_attention, paged_decode_attention_xla)
 from deeperspeed_tpu.inference.kv_cache import (PagedKVCache,
                                                 QuantizedPages,
                                                 quantize_kv)
@@ -274,6 +274,46 @@ class TestInt8KV:
                                    v_scales=vs)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-5)
+
+    @pytest.mark.parametrize("stacked", [False, True],
+                             ids=["one_layer", "layer_of_stack"])
+    @pytest.mark.parametrize("H,D", [(4, 128), (12, 64), (16, 128),
+                                     (64, 128)],
+                             ids=["h4", "h12_d64", "h16", "h64_split"])
+    def test_int8_kernel_over_heads_and_ragged_rows(self, H, D, stacked):
+        """The int8 kernel against its fallback and the unquantized
+        attention, a page of all heads a step (64 heads: of 32, through
+        `heads_per_step`), over an inactive row, one token, an exact page
+        edge, a row ending mid-table beside two that fill it."""
+        rng = np.random.default_rng(H)
+        ps, NP, Pn = 32, 4, 32
+        lengths = jnp.asarray([0, 1, 64, 70, 128, 128], np.int32)
+        B = lengths.shape[0]
+        assert heads_per_step(H, ps, D, jnp.int8) == min(H, 32)
+        q = jnp.asarray(rng.normal(size=(B, H, D)).astype(np.float32))
+        k = jnp.asarray(rng.normal(size=(Pn, H, ps, D)).astype(np.float32))
+        v = jnp.asarray(rng.normal(size=(Pn, H, ps, D)).astype(np.float32))
+        pt = jnp.asarray(rng.permutation(np.arange(1, Pn))[:B * NP]
+                         .reshape(B, NP).astype(np.int32))
+        ref = paged_decode_attention_xla(q, k, v, pt, lengths,
+                                         1 / np.sqrt(D))
+        kq, ks = quantize_kv(k)
+        vq, vs = quantize_kv(v)
+        pools = [kq, vq, ks.astype(jnp.bfloat16), vs.astype(jnp.bfloat16)]
+        kw = {}
+        if stacked:
+            # the layer under test last, behind a layer of other content
+            pools = [jnp.stack([t[::-1], t]) for t in pools]
+            kw = {"layer": 1}
+        kq, vq, ks, vs = pools
+        a, b = (paged_decode_attention(q, kq, vq, pt, lengths,
+                                       backend=backend, k_scales=ks,
+                                       v_scales=vs, **kw)
+                for backend in ("pallas", "xla"))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+        rel = float(jnp.max(jnp.abs(a - ref)) / jnp.max(jnp.abs(ref)))
+        assert rel < 0.05          # documented dequant tolerance
+        assert bool(jnp.all(a[0] == 0))     # inactive row exact zero
 
     def test_scale_shape_validated(self):
         q, k, v, pt, lengths = self._decode_setup()

@@ -197,21 +197,73 @@ def stacked(layers, pages, heads, page_size, head_dim, quant):
     return [pool, pool] + [scale, scale] * quant
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_layer_indexed_paged_decode_compiles(on_chip, head_dim, quant):
-    """The paged kernel on the stacked pools, the layer a traced scalar
-    (Pythia-410m's and Pythia-1.4b's head dims, the serve cell's pages)."""
+# (page table's width, layers, pages) of the two serve cells' pools: batch
+# 32, 16 heads, pages of 64
+SERVE_CELLS = {"pythia-1.4b.serve_closed32": (32, 24, 401),
+               "olmoe-1b-7b.serve_fewshot32": (64, 6, 801)}
+# the cells' own contexts: a mean of 440 tokens over 32 rows; of 1,060
+# over 19 live rows beside 13 inactive ones
+CELL_CONTEXTS = {"pythia-1.4b.serve_closed32": [440] * 32,
+                 "olmoe-1b-7b.serve_fewshot32": [1060] * 19 + [0] * 13}
+
+
+def layer_indexed_decode(table_width, layers, pages, head_dim, quant):
+    """(callable, argument shapes) of one paged decode call on stacked
+    pools at a serve cell's shapes, the layer a traced scalar."""
     B, H, page_size = 32, 16, 64
-    args = [((B, H, head_dim), BF16), ((B, 32), jnp.int32),
+    args = [((B, H, head_dim), BF16), ((B, table_width), jnp.int32),
             ((B,), jnp.int32), ((), jnp.int32)]
 
     def decode(q, table, lengths, layer, k, v, *scales):
         return decode_attention.paged_decode_attention_pallas(
             q, k, v, table, lengths, 0.125, *scales, layer=layer)
 
-    assert_kernel(on_chip(decode, *args, *stacked(
-        24, 401, H, page_size, head_dim, quant)))
+    return decode, args + stacked(layers, pages, H, page_size, head_dim,
+                                  quant)
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_layer_indexed_paged_decode_compiles(on_chip, head_dim, quant, cell):
+    """The paged kernel on the stacked pools, the layer a traced scalar,
+    at both serve cells' shapes (head dim 128 as they run it; 64 is
+    Pythia-410m served)."""
+    decode, args = layer_indexed_decode(*SERVE_CELLS[cell], head_dim, quant)
+    assert_kernel(on_chip(decode, *args))
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+def test_paged_decode_is_one_call_over_live_pages(on_chip, cell):
+    """A decode call at a serve cell's shapes is ONE Mosaic custom call
+    named `ds.paged_decode` (the roofline metric divides by the mean time
+    of one), whose grid has no (batch, head, page) product: a page of all
+    16 heads a step, and as many steps as the rows have live pages — at
+    most batch x table width, which only a batch of full tables reaches."""
+    table_width, layers, pages = SERVE_CELLS[cell]
+    decode, args = layer_indexed_decode(table_width, layers, pages, 128,
+                                        False)
+    text = on_chip(decode, *args)
+    calls = re.findall(r"^\s*%?([\w.\-]+) = .*tpu_custom_call", text, re.M)
+    assert len(calls) == 1 and calls[0].startswith("ds.paged_decode"), calls
+
+    jaxpr = jax.make_jaxpr(decode)(
+        *[jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args])
+    (grid,) = [eqn.params["grid_mapping"].grid for eqn in jaxpr.eqns
+               if eqn.primitive.name == "pallas_call"]
+    assert decode_attention.heads_per_step(16, 64, 128, BF16) == 16
+    # (head groups, steps): one group, and a step count read at run time
+    assert len(grid) == 2 and grid[0] == 1 and not isinstance(grid[1], int)
+    B = 32
+    worst, _, _ = decode_attention.decode_steps(
+        jnp.full((B,), table_width * 64, jnp.int32), 64, table_width)
+    assert int(worst) == B * table_width
+    lengths = CELL_CONTEXTS[cell]
+    steps, _, _ = decode_attention.decode_steps(
+        jnp.asarray(lengths, jnp.int32), 64, table_width)
+    # a step a live page, one for an inactive row: 224 of 1,024; 336 of 2,048
+    assert int(steps) == sum(max(1, -(-n // 64)) for n in lengths)
+    assert int(steps) * 4 < B * table_width
 
 
 @pytest.mark.parametrize("head_dim", [64, 128])
