@@ -23,7 +23,7 @@ Execution model (docs/inference.md):
   bucket), decode per batch bucket — the scheduler
   (`inference.scheduler`) only ever emits those shapes, so after the
   ladder warms up XLA never recompiles (`compile_count()` pins this in
-  tests and the `DS_BENCH_SERVE` row).
+  tests; both serve cells report `serve_compiles_in_window`).
 - **State.** The page pools are donated through every compiled call and
   rebound; everything else (params, rotary cache) is read-only. The
   decode program leaves the pools where they are (`_token_layers`): they
@@ -408,9 +408,8 @@ class InferenceEngine:
             prefix_cache=self.prefix_cache, spec_tokens=self.spec_k)
         self.n_pages_max = pages_for_tokens(self.max_seq_len,
                                             self.page_size)
-        # precision identity of this serving engine: the bench serve row
-        # records it in `extra` so BENCH history can attribute serving
-        # deltas to precision changes (docs/quantization.md)
+        # precision identity of this serving engine
+        # (docs/quantization.md)
         self.dtypes = {
             "weight": self.weight_quant or
             str(jnp.dtype(self.compute_dtype)),
@@ -2130,7 +2129,7 @@ class InferenceEngine:
     def generate_rollouts(self, prompts, max_new_tokens, eos_token_id=None):
         """RL rollout batch API (docs/rl.md): `generate` plus the
         throughput/speculation accounting the driver's `Train/RL/*`
-        scalars and the bench row need. Returns ``(outputs, stats)``
+        scalars need. Returns ``(outputs, stats)``
         where ``outputs[i]`` is prompt ``i``'s generated token list and
         ``stats`` carries rollout wall time, generated-token counts and
         the serve-side deltas (compile count, spec acceptance) for THIS

@@ -169,8 +169,8 @@ def _bucket(value, buckets):
 class ContinuousBatchingScheduler:
     """Admission/eviction over a `PagedKVCache` pool under a per-step
     token budget. Host-side and deterministic: the same request arrival
-    order always produces the same step plans (the serving bench's
-    fixed-seed open-loop stream relies on this)."""
+    order always produces the same step plans (the benchmark's serve
+    cells rely on this)."""
 
     def __init__(self, cache, max_seq_len, token_budget, max_batch_size,
                  prefill_lengths, prefill_batch_sizes, decode_batch_sizes,

@@ -22,7 +22,6 @@ norm → (tied or untied) output head.
 """
 
 import math
-import os
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Optional
@@ -409,49 +408,13 @@ def apply_rotary(q, k, cos, sin, rot_dim):
             jnp.concatenate([k_rot, k_pass], axis=-1))
 
 
-def _parse_env_blocks(env_name, shape):
-    """'bq,bk' env override → (bq, bk) validated against `shape`, or
-    None when unset (shared by DS_FLASH_BLOCKS / DS_FLASH_BWD_BLOCKS)."""
-    from ..ops.pallas.flash_attention import flash_attention_supported
-    env_blocks = os.environ.get(env_name)
-    if not env_blocks:
-        return None
-    try:
-        bq, bk = (int(x) for x in env_blocks.split(","))
-    except ValueError as e:
-        raise ValueError(
-            f"{env_name} must be 'bq,bk' ints, got {env_blocks!r}") from e
-    if not flash_attention_supported(shape, bq, bk):
-        raise ValueError(
-            f"{env_name}={env_blocks} does not fit seq {shape[1]} "
-            f"(needs a 128-multiple block dividing the sequence)")
-    return bq, bk
-
-
-def _flash_dispatch(shape, dtype):
-    """Resolve (fwd_blocks, bwd_blocks) for a causal flash call:
-    env overrides first (perf A/B), then the measured autotune picks —
-    always at long sequences, opt-in (DS_TPU_AUTOTUNE=1) below. Either
-    may be None (= static default fwd / reuse-fwd bwd)."""
-    from ..ops.autotune import flash_blocks_for, flash_bwd_blocks_for
-    fwd = _parse_env_blocks("DS_FLASH_BLOCKS", shape)
-    if fwd is None:
-        fwd = flash_blocks_for(shape, dtype, True)
-    bwd = _parse_env_blocks("DS_FLASH_BWD_BLOCKS", shape)
-    if bwd is None:
-        bwd = flash_bwd_blocks_for(shape, dtype, True, fwd_blocks=fwd)
-    return fwd, bwd
-
-
 def causal_attention(q, k, v, use_pallas=True, segment_ids=None):
     """Causal MHA core on [B, S, H, D]; fp32 softmax accumulation.
 
     Uses the Pallas flash-attention kernel on TPU when shapes allow;
     XLA-fused fallback otherwise (the fallback still fuses well — softmax
-    and the PV matmul land on the MXU). Block geometry: DS_FLASH_BLOCKS /
-    DS_FLASH_BWD_BLOCKS env overrides, else the autotuner's measured
-    picks (forward and backward dispatched INDEPENDENTLY — the bwd
-    dkv/dq working set is larger, so its winner is usually narrower).
+    and the PV matmul land on the MXU). The kernel takes its block
+    geometry from the shape it is called at (`ops.autotune.flash_blocks`).
 
     `segment_ids` [B, S] int32 (packed ragged batches, 0 = pad) makes
     attention intra-document: the segmented kernels skip fully-cross-
@@ -465,21 +428,15 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None):
     from ..runtime.activation_checkpointing.checkpointing import \
         tag_attn_residual
     from ..ops.pallas.flash_attention import (
-        _LAST_BACKEND, BLOCK_K, BLOCK_Q, flash_attention,
-        flash_attention_segmented, flash_attention_supported,
-        note_xla_on_tpu)
+        _LAST_BACKEND, flash_attention, flash_attention_segmented,
+        flash_attention_supported, note_xla_on_tpu)
     if use_pallas and flash_attention_supported(q.shape):
         _LAST_BACKEND["attention"] = "pallas"
 
         def kernel(q, k, v, *seg):
-            # block geometry from the shape the kernel really sees: the
-            # per-device shard when `per_shard` splits batch and heads
-            fwd, bwd = _flash_dispatch(q.shape, q.dtype)
-            bq, bk = fwd if fwd is not None else (BLOCK_Q, BLOCK_K)
             if seg:
-                return flash_attention_segmented(
-                    q, k, v, seg[0], True, None, bq, bk, bwd)
-            return flash_attention(q, k, v, True, None, bq, bk, bwd)
+                return flash_attention_segmented(q, k, v, seg[0], True)
+            return flash_attention(q, k, v, True)
 
         seg = () if segment_ids is None else (segment_ids,)
         return per_shard(kernel, (q, k, v) + seg,
@@ -515,10 +472,7 @@ def _wmat(x, w):
     on every family that shares the block body."""
     from ..ops.pallas.quant_matmul import QuantizedWeight, quant_matmul
     if isinstance(w, QuantizedWeight):
-        from ..ops.autotune import quant_matmul_blocks
-        m = int(np.prod(x.shape[:-1]))
-        blocks = quant_matmul_blocks(m, w.shape[0], w.shape[1], x.dtype)
-        return quant_matmul(x, w, blocks=blocks)
+        return quant_matmul(x, w)
     return x @ w.astype(x.dtype)
 
 
@@ -1001,7 +955,7 @@ def forward(cfg, params, tokens, use_pallas=True, remat_blocks=False,
 
 
 @scopes.scoped("ds.ce_head")
-def fused_lm_head_loss(x, wte, labels, ignore_index=-100, chunk_rows=None):
+def fused_lm_head_loss(x, wte, labels, ignore_index=-100, chunk_rows=4096):
     """Next-token cross entropy fused with the LM head, chunked over rows.
 
     Never materializes the full [B, S, V] fp32 logits (6 GB at
@@ -1012,13 +966,9 @@ def fused_lm_head_loss(x, wte, labels, ignore_index=-100, chunk_rows=None):
     (`csrc/transformer/softmax_kernels.cu`), achieved as an XLA scan.
 
     x: [B, S, H] final-norm hidden states; wte: [V, H]; labels: [B, S].
-    chunk_rows tunes the scan tile (default 4096; DS_CE_CHUNK_ROWS env
-    overrides — a perf knob like the reference's gemm algo selection,
-    `csrc/includes/gemm_test.h`): bigger tiles amortize scan overhead,
+    chunk_rows is the scan tile: bigger tiles amortize scan overhead,
     smaller ones cap the [chunk, V] fp32 logits tile's HBM.
     """
-    if chunk_rows is None:
-        chunk_rows = int(os.environ.get("DS_CE_CHUNK_ROWS", "4096"))
     B, S, H = x.shape
     xs = x[:, :-1, :].reshape(-1, H)
     ts = labels[:, 1:].reshape(-1)
@@ -1165,10 +1115,8 @@ def make_sparse_attention(cfg, sparse_params=None):
     an explicitly bidirectional pattern (incl. the structurally
     bidirectional bigbird/bslongformer modes) is rejected loudly.
 
-    The kernels under it autotune: `SparseSelfAttention` consults
-    `ops.autotune.sparse_block_params` for the (group_q, fanout) grid
-    geometry at the live call shape under DS_TPU_AUTOTUNE, and its auto
-    dispatch hands dense-ish layouts to the masked dense-flash kernel.
+    `SparseSelfAttention`'s auto dispatch hands dense-ish layouts to the
+    masked dense-flash kernel.
 
     Returns `attn_fn(q, k, v)` for `forward_hidden(attn_fn=...)`."""
     d = dict(sparse_params or {})

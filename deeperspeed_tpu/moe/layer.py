@@ -455,13 +455,14 @@ def _sort_combine(out_buf, route, span, T, dtype):
 
 def _gmm_geometry(capacity, k_dim, n_dim, dtype, block_m, block_n,
                   backend):
-    """Resolve (span, block_m, block_n) — autotuned on TPU when the
-    Pallas backend is in play, static defaults otherwise."""
+    """Resolve (span, block_m, block_n): `ops.autotune`'s VMEM-screened
+    pick on a TPU when the Pallas backend is in play, the kernel's
+    defaults otherwise."""
     if (block_m is None or block_n is None) and backend != "xla":
         from ..ops.autotune import grouped_matmul_blocks
         from ..ops.pallas.grouped_matmul import _interpret
         if not _interpret():
-            bm, bn = grouped_matmul_blocks(capacity, k_dim, n_dim, dtype)
+            bm, bn = grouped_matmul_blocks(k_dim, n_dim, dtype)
             block_m = block_m or bm
             block_n = block_n or bn
     span, bm = _pick_span(capacity, block_m)

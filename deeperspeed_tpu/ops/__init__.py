@@ -8,8 +8,8 @@ def dispatch_report():
     """Last-dispatched kernel configuration, as one dict — the PUBLIC
     accessor over the kernels' internal dispatch records
     (`flash_attention._LAST_BLOCKS`, `decode_attention._LAST_BACKEND`).
-    The bench `extra` columns, the telemetry capture exports, and the
-    fleet trace metadata all consume this; WHICH block geometry / grid
+    The benchmark's fallback counters, the telemetry capture exports and
+    the fleet trace metadata all consume this; WHICH block geometry / grid
     variant / decode backend produced a number is as load-bearing as
     the number itself.
 

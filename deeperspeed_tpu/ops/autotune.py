@@ -1,36 +1,24 @@
-"""Kernel-variant autotuner (reference: `csrc/includes/gemm_test.h` — the
-transformer layer benchmarks cuBLAS algorithm ids for its GEMMs once at
-layer creation and reuses the winner).
+"""Kernel launch geometry, decided in one place: for each Pallas kernel a
+pure function from the call's shape (and, for flash attention, the
+device kind) to concrete blocks. Nothing here runs a kernel or reads a
+clock: a candidate ladder, a VMEM screen that drops what Mosaic would
+refuse, `fit_block` fitting to the call's dims, and for long flash
+sequences a small checked-in table of blocks measured through the
+benchmark. Two processes, or two hosts of one job, always get the same
+program. (XLA tiles its own GEMMs; launch geometry is the knob that
+remains ours.)
 
-XLA already autotunes its own GEMM tilings; the knob that remains OURS is
-Pallas kernel launch geometry — e.g. flash-attention block sizes, where
-the best choice flips between TPU generations (fat 1024-blocks win on v5e
-where per-instance fixed cost dominates; narrower blocks can win where
-VMEM is tighter). `Autotuner.pick` times each candidate on the live
-device once per (key, device-kind) and caches the winner for the process
-lifetime, exactly the reference's measure-once-use-forever contract.
+A kernel's public wrapper calls its function here when the caller passes
+no blocks; a caller passes blocks only to pin them (a test, a plan).
 
-Activation: autotuning runs real device work (a few warm-up fwd+bwd
-launches per candidate), so it is opt-in (`DS_TPU_AUTOTUNE=1`) for
-ordinary shapes — EXCEPT long sequences: at or beyond
-`flash_tune_min_seq()` (default 8192, `DS_FLASH_TUNE_MIN_SEQ`) the
-`flash_blocks_for` dispatch always measures, because the one-time probe
-is noise next to a single long-context step and the static default
-geometry was an MFU cliff there (claimed before PR 1 from a record
-deleted at PR 22; not measured in this round).
+The module also keeps the compile-time memory screen
+(`compiled_memory_stats`, `memory_feasible`, `hbm_bytes_limit`).
 """
 
-import functools
 import os
-import time
 
 import jax
-
-_TUNE_ENV = "DS_TPU_AUTOTUNE"
-
-
-def autotune_enabled():
-    return os.environ.get(_TUNE_ENV, "0") not in ("0", "", "false", "False")
+import jax.numpy as jnp
 
 
 def _device_kind():
@@ -40,139 +28,129 @@ def _device_kind():
         return "unknown"
 
 
-class Autotuner:
-    """Times callables on the live device, remembers the fastest.
-
-    `pick(key, candidates, run)` → winning candidate. `run(candidate)`
-    must execute the kernel variant end-to-end and return something
-    blockable (`jax.block_until_ready` is applied). Failures (e.g. a
-    block shape Mosaic rejects or VMEM OOM) disqualify the candidate
-    rather than raising — mirrors the reference skipping invalid cublas
-    algo ids."""
-
-    def __init__(self, warmup=1, iters=3, timer=time.perf_counter):
-        self.warmup = warmup
-        self.iters = iters
-        self.timer = timer
-        self._cache = {}
-
-    def cached(self, key):
-        return self._cache.get((key, _device_kind()))
-
-    def store(self, key, value):
-        """Record a decision without measuring (fallback paths cache
-        their default so repeat calls skip the candidate-fitting work)."""
-        self._cache[(key, _device_kind())] = value
-        return value
-
-    def pick(self, key, candidates, run):
-        full_key = (key, _device_kind())
-        if full_key in self._cache:
-            return self._cache[full_key]
-        best, best_t = None, float("inf")
-        for cand in candidates:
-            try:
-                for _ in range(self.warmup):
-                    jax.block_until_ready(run(cand))
-                t0 = self.timer()
-                for _ in range(self.iters):
-                    out = run(cand)
-                jax.block_until_ready(out)
-                dt = self.timer() - t0
-            except Exception:
-                continue
-            if dt < best_t:
-                best, best_t = cand, dt
-        if best is None:
-            raise RuntimeError(
-                f"autotune: every candidate failed for key {key!r}")
-        self._cache[full_key] = best
-        return best
+def fit_block(block, s):
+    """Largest 128-multiple ≤ `block` that divides s (0 if none)."""
+    for cand in range(min(block, s), 127, -128):
+        if cand % 128 == 0 and s % cand == 0:
+            return cand
+    return 0
 
 
-_global_tuner = Autotuner()
+# ---------------------------------------------------------------------------
+# flash attention (ops/pallas/flash_attention.py)
+# ---------------------------------------------------------------------------
 
-
-def ladder_pick(key, candidates, measure, tuner=None, *,
-                measurable=True, default=None):
-    """The screen→measure→cache spine shared by every kernel picker in
-    this module and by the planner's probe phase
-    (`deeperspeed_tpu.planner`). Before this helper the five pickers
-    each hand-rolled the same five steps; now they only supply their
-    candidate ladder, their probe, and their degrade verdict.
-
-    1. cache hit for (key, device kind) → returned unmeasured
-       (measure-once-use-forever);
-    2. `measurable` false (caller's verdict: interpret-mode Pallas,
-       probe-byte cap, analytic-only planning) or a multi-host run
-       (per-host wall-clock picks can disagree → different programs per
-       host → deadlock at the first collective) → the deterministic
-       `default` is stored without touching the device. When `default`
-       is None the candidate ladder's first entry is stored instead;
-    3. a ladder that collapses to one survivor → stored unmeasured;
-    4. otherwise each candidate is timed via `measure(candidate)` with
-       `perf_counter` OUTSIDE traced code and the winner is cached.
-
-    `candidates`, `measurable` and `default` may be zero-arg callables:
-    they are resolved only on a cache miss (and `default` only when
-    degrading), so expensive screens — the grouped-matmul AOT memory
-    screen lowers a composite fwd+bwd program per candidate — and
-    cap-exceeded log lines are paid once per (key, device kind), not
-    per call."""
-    tuner = tuner or _global_tuner
-    hit = tuner.cached(key)
-    if hit is not None:
-        return hit
-    if callable(measurable):
-        measurable = measurable()
-    degraded = not measurable or jax.process_count() > 1
-    if degraded:
-        if callable(default):
-            default = default()
-        if default is not None:
-            return tuner.store(key, default)
-    cands = list(candidates() if callable(candidates) else candidates)
-    if not cands:
-        raise ValueError(
-            f"autotune: no viable candidates for key {key!r}")
-    if len(cands) == 1 or degraded:
-        return tuner.store(key, cands[0])
-    return tuner.pick(key, cands, measure)
-
-# Candidate (block_q, block_k) geometries for the flash kernels, fattest
-# first (the v5e-measured winner ordering). Non-square entries exist for
-# the compacted causal grid: its trapezoid rows grow with qi, so a fat
-# block_q with a narrower block_k keeps per-instance VMEM bounded while
-# the schedule (not an in-kernel gate) already skips the dead tiles —
-# at 16k/32k the fp32 [BQ, BK] score tile is the VMEM limiter, which
-# square 1024² geometry hard-codes at 4 MB.
+# Candidate (block_q, block_k) geometries, the default first. Non-square
+# entries exist for the compacted causal grid: its trapezoid rows grow
+# with qi, so a fat block_q with a narrower block_k keeps per-instance
+# VMEM bounded while the schedule already skips the dead tiles.
 FLASH_BLOCK_CANDIDATES = ((1024, 1024), (2048, 1024), (1024, 512),
                           (2048, 512), (512, 512), (512, 1024),
                           (1024, 256), (512, 256), (256, 512),
                           (256, 256), (256, 128), (128, 128))
 
+# The geometry of every call under FLASH_LONG_SEQ tokens, forward and
+# backward alike.
+FLASH_BLOCK_Q, FLASH_BLOCK_K = FLASH_BLOCK_CANDIDATES[0]
 
-# Above this, standalone benchmark launches aren't representative (and the
-# probe arrays would strain device memory) — fall back to the default.
-_MAX_TUNE_BYTES = 1 << 30
+# The fp32 [block_q, block_k] score tile is the VMEM limiter of the
+# forward and both backward kernels: Mosaic holds a few copies of it (the
+# scores, their exponentials, the cast operand of the second matmul).
+# 4 MiB admits 1024 x 1024 and 2048 x 512 and drops 2048 x 1024, which a
+# described v5e refuses at head dim 64, 128 and 256, forward and backward
+# (tests/test_tpu_compile.py compiles what this admits).
+_FLASH_SCORE_TILE_BUDGET = 4 << 20
 
-# Sequences at or above this always take the measured block pick, even
-# without DS_TPU_AUTOTUNE=1: at 16k-32k the default square geometry was
-# a long-context MFU cliff (an older claim; not measured in this round)
-# and a one-time per-process probe is noise next to a single long-seq
-# step.
-_TUNE_MIN_SEQ_ENV = "DS_FLASH_TUNE_MIN_SEQ"
+# Sequences at or over this read FLASH_LONG_SEQ_BLOCKS. Under it every
+# call runs the default: what the two 2k train cells and the serve cells'
+# prefill trace.
+FLASH_LONG_SEQ = 8192
+
+# (device_kind, head_dim, causal) -> ((fwd block_q, block_k),
+#                                     (bwd block_q, block_k)),
+# each row a pair of blocks a benchmark cell measured on that chip. A
+# shape class without a row takes `_flash_fallback`; rows are not
+# invented for shapes no cell runs (head dim 128 at 8k and over,
+# non-causal long sequences: ROADMAP.md, D10).
+FLASH_LONG_SEQ_BLOCKS = {
+    # pythia-410m.train_16k: of the geometries the old in-trace timer
+    # picked in three runs (PR 23), the faster of the two that compiled
+    ("TPU v5 lite", 64, True): ((1024, 512), (1024, 1024)),
+}
+
+def flash_blocks_admitted(block_q, block_k):
+    """The VMEM screen: does the fp32 score tile fit its budget?"""
+    return block_q * block_k * 4 <= _FLASH_SCORE_TILE_BUDGET
 
 
-def flash_tune_min_seq():
-    return int(os.environ.get(_TUNE_MIN_SEQ_ENV, "8192"))
+def _flash_fit(blocks, s):
+    """`blocks` fitted to sequence `s`, or None where no 128-multiple
+    under a requested size divides it."""
+    fit = (fit_block(blocks[0], s), fit_block(blocks[1], s))
+    return None if 0 in fit else fit
+
+
+def _flash_fallback(s):
+    """First candidate the screen admits and `s` fits, fitted: the
+    default wherever some 128-multiple divides `s`."""
+    for cand in FLASH_BLOCK_CANDIDATES:
+        fit = _flash_fit(cand, s)
+        if fit is not None and flash_blocks_admitted(*fit):
+            return fit
+    raise ValueError(f"no flash block candidate fits sequence {s}")
+
+
+def _flash_env_blocks(env_name, s):
+    """'bq,bk' from the environment, validated against the sequence and
+    fitted, or None when unset."""
+    raw = os.environ.get(env_name)
+    if not raw:
+        return None
+    try:
+        bq, bk = (int(x) for x in raw.split(","))
+    except ValueError as e:
+        raise ValueError(
+            f"{env_name} must be 'bq,bk' ints, got {raw!r}") from e
+    fit = _flash_fit((bq, bk), s)
+    if fit is None:
+        raise ValueError(
+            f"{env_name}={raw} does not fit seq {s} "
+            f"(needs a 128-multiple block dividing the sequence)")
+    return fit
+
+
+def flash_blocks(shape, causal, device_kind=None):
+    """((fwd block_q, block_k), (bwd block_q, block_k)) for a flash call
+    on [B, S, H, D] — under a mesh, the shard the kernel really sees.
+    Always concrete, fitted pairs.
+
+    At FLASH_LONG_SEQ and over: the row of FLASH_LONG_SEQ_BLOCKS for
+    (device kind, head dim, causal). Under it, and without a row: the
+    first candidate the VMEM screen admits (the default), for both
+    passes.
+
+    `DS_FLASH_BLOCKS` / `DS_FLASH_BWD_BLOCKS` ('bq,bk') replace the
+    forward / backward pair. This is the only place that reads them, and
+    they exist for one reader: `pythia-410m.train_16k` sets them to what
+    the table's first row now holds (ROADMAP.md, D10)."""
+    _, s, _, d = shape
+    row = ()
+    if s >= FLASH_LONG_SEQ:
+        row = FLASH_LONG_SEQ_BLOCKS.get(
+            (device_kind or _device_kind(), d, bool(causal)), ())
+    fitted = [_flash_fit(blocks, s) for blocks in row]
+    if fitted and None not in fitted:
+        fwd, bwd = fitted
+    else:
+        fwd = bwd = _flash_fallback(s)
+    return (_flash_env_blocks("DS_FLASH_BLOCKS", s) or fwd,
+            _flash_env_blocks("DS_FLASH_BWD_BLOCKS", s) or bwd)
 
 
 # ---------------------------------------------------------------------------
-# Compile-time memory screening (tentpole: the (remat policy × batch)
-# bench ladder pre-screens rungs with `compiled.memory_analysis()` before
-# spending a timed run — an AOT lower+compile over abstract shapes costs
-# seconds and zero HBM, an OOM'd rung costs a whole row subprocess).
+# Compile-time memory screening: an AOT lower+compile over abstract shapes
+# costs seconds and zero HBM, where a program that does not fit costs a
+# whole run (the planner's `aot_screen`).
 # ---------------------------------------------------------------------------
 
 # Per-generation HBM capacities (spec sheet), used when the runtime does
@@ -246,9 +224,8 @@ def memory_feasible(fn, abstract_args, budget_bytes=None, safety=0.92,
 
     Returns (fits, stats). Unknown budgets or backends without
     `memory_analysis` return (True, stats_or_None) — screening never
-    blocks a rung it cannot reason about; the ladder's subprocess
-    isolation still catches real OOMs. `safety` holds back headroom for
-    fragmentation and the runtime's own buffers."""
+    blocks a rung it cannot reason about. `safety` holds back headroom
+    for fragmentation and the runtime's own buffers."""
     if budget_bytes is None:
         budget_bytes = hbm_bytes_limit()
     try:
@@ -270,8 +247,7 @@ def memory_feasible(fn, abstract_args, budget_bytes=None, safety=0.92,
 # ---------------------------------------------------------------------------
 
 # (block_m, block_n) targets, fattest first. The kernel fits each to the
-# actual span/output dims; candidates differing only after fitting are
-# deduped before measurement.
+# actual span/output dims.
 GMM_BLOCK_CANDIDATES = ((512, 512), (512, 256), (256, 512), (256, 256),
                         (128, 256), (256, 128), (128, 128))
 
@@ -290,87 +266,20 @@ def gmm_vmem_bytes(block_m, block_n, k_dim, itemsize):
             + max(block_m * block_n, k_dim * block_n) * 4)
 
 
-def _gmm_itemsize(dtype):
-    import jax.numpy as jnp
-    import numpy as np
-    return 2 if dtype == jnp.bfloat16 else np.dtype(dtype).itemsize
-
-
-def grouped_matmul_blocks(capacity, k_dim, n_dim, dtype, tuner=None):
+def grouped_matmul_blocks(k_dim, n_dim, dtype):
     """(block_m, block_n) for `grouped_matmul` at the given expert-FFN
-    geometry. The SAME block pair serves both FFN matmuls — (k_dim →
-    n_dim) and back (n_dim → k_dim) — so candidates are screened
-    against the VMEM model at BOTH contraction dims (an over-budget
-    geometry is a Mosaic allocation failure, not a slow rung); with
-    `DS_TPU_AUTOTUNE=1` the survivors are additionally memory-screened
-    via AOT `memory_analysis` and then measured fwd+bwd over the
-    composite two-matmul FFN on the live device
-    (measure-once-use-forever, like the flash blocks). Without opt-in
-    the first screened candidate wins — a deterministic static pick, no
-    probe launches at trace time."""
-    itemsize = _gmm_itemsize(dtype)
-    screened = [c for c in GMM_BLOCK_CANDIDATES
-                if max(gmm_vmem_bytes(c[0], c[1], k_dim, itemsize),
-                       gmm_vmem_bytes(c[0], c[1], n_dim, itemsize))
-                <= _GMM_VMEM_BUDGET]
-    if not screened:
-        screened = [GMM_BLOCK_CANDIDATES[-1]]
-    if not autotune_enabled():
-        return screened[0]
-
-    key = ("gmm", int(capacity), int(k_dim), int(n_dim), str(dtype))
-
-    import jax.numpy as jnp
-    from .pallas.grouped_matmul import _interpret, grouped_matmul, \
-        pick_span
-
-    n_groups = 8
-
-    def build(cand):
-        # probe the geometry EXACTLY as the MoE layer deploys it: the
-        # composite in->out FFN pair (the second matmul's contraction
-        # dim is n_dim — usually the 4x larger one), with pick_span's
-        # fitted row block (two candidates can collapse to one pair)
-        span, bm = pick_span(capacity, cand[0])
-        x = jnp.zeros((n_groups * span, k_dim), dtype)
-        w1 = jnp.zeros((n_groups, k_dim, n_dim), dtype)
-        w2 = jnp.zeros((n_groups, n_dim, k_dim), dtype)
-        sizes = jnp.full((n_groups,), min(int(capacity), span), jnp.int32)
-
-        def run(xv):
-            h = grouped_matmul(xv, w1, sizes, span, None, bm, cand[1],
-                               backend="pallas")
-            out = grouped_matmul(h, w2, sizes, span, None, bm, cand[1],
-                                 backend="pallas")
-            return jnp.sum(out.astype(jnp.float32))
-        return run, x, (bm, cand[1])
-
-    def survivors():
-        # AOT memory screen before spending a timed run on a candidate;
-        # dedupe candidates that fit to the same deployed geometry.
-        # Resolved lazily by ladder_pick: in interpret mode or
-        # multi-host this (expensive — one AOT fwd+bwd lowering per
-        # candidate) never runs
-        out, seen = [], set()
-        for cand in screened:
-            run, x, fitted = build(cand)
-            if fitted in seen:
-                continue
-            fits, _ = memory_feasible(
-                jax.grad(run), (jax.ShapeDtypeStruct(x.shape, x.dtype),))
-            if fits:
-                seen.add(fitted)
-                out.append(cand)
-        return out or [screened[0]]
-
-    def measure(cand):
-        run, x, _ = build(cand)
-        return jax.grad(run)(x)
-
-    return ladder_pick(
-        key, screened if len(screened) == 1 else survivors, measure,
-        tuner,
-        measurable=lambda: not _interpret(), default=screened[0])
+    geometry: the fattest candidate inside the VMEM model. The SAME block
+    pair serves both FFN matmuls — (k_dim → n_dim) and back (n_dim →
+    k_dim) — so candidates are screened at BOTH contraction dims (an
+    over-budget geometry is a Mosaic allocation failure, not a slow
+    rung). Where nothing fits the model, the narrowest candidate."""
+    itemsize = jnp.dtype(dtype).itemsize
+    for c in GMM_BLOCK_CANDIDATES:
+        if max(gmm_vmem_bytes(c[0], c[1], k_dim, itemsize),
+               gmm_vmem_bytes(c[0], c[1], n_dim, itemsize)) \
+                <= _GMM_VMEM_BUDGET:
+            return c
+    return GMM_BLOCK_CANDIDATES[-1]
 
 
 # ---------------------------------------------------------------------------
@@ -378,9 +287,10 @@ def grouped_matmul_blocks(capacity, k_dim, n_dim, dtype, tuner=None):
 # int8 decode/prefill weight path)
 # ---------------------------------------------------------------------------
 
-# (block_m, block_k, block_n) targets, fattest first. The weight tile is
-# int8 (1 byte/element), so fat k-blocks are cheap on the wire; the fp32
-# accumulator block is the VMEM limiter.
+# (block_m, block_k, block_n) targets, the default first. The kernel fits
+# each to the operands. The weight tile is int8 (1 byte/element), so fat
+# k-blocks are cheap on the wire; the fp32 accumulator block is the VMEM
+# limiter.
 QMM_BLOCK_CANDIDATES = ((256, 512, 256), (512, 512, 256), (256, 512, 512),
                         (256, 256, 256), (128, 512, 256), (128, 256, 256),
                         (128, 256, 128))
@@ -399,273 +309,12 @@ def qmm_vmem_bytes(block_m, block_k, block_n, itemsize):
             + block_m * block_n * itemsize)         # output tile
 
 
-def quant_matmul_blocks(m, k, n, dtype, tuner=None):
-    """(block_m, block_k, block_n) for `quant_matmul` at the given call
-    geometry: VMEM-model screen always, measured pick on the live device
-    under DS_TPU_AUTOTUNE=1 (measure-once-use-forever, like the flash and
-    grouped-matmul blocks). Without opt-in the first screened candidate
-    wins — a deterministic static pick, no probe launches at trace
-    time."""
-    itemsize = _gmm_itemsize(dtype)
-    screened = [c for c in QMM_BLOCK_CANDIDATES
-                if qmm_vmem_bytes(*c, itemsize=itemsize)
-                <= _QMM_VMEM_BUDGET]
-    if not screened:
-        screened = [QMM_BLOCK_CANDIDATES[-1]]
-    if not autotune_enabled():
-        return screened[0]
-
-    key = ("qmm", int(m), int(k), int(n), str(dtype))
-
-    import jax.numpy as jnp
-    from .pallas.quant_matmul import (_fit, _interpret, quant_matmul,
-                                      quantize_weight)
-
-    def fitted():
-        # dedupe candidates on their FITTED geometry
-        out, seen = [], set()
-        for c in screened:
-            fit = (_fit(c[0], m, 8), _fit(c[1], k, 32),
-                   _fit(c[2], n, 128))
-            if fit in seen:
-                continue
-            seen.add(fit)
-            out.append(c)
-        return out
-
-    probe = {}
-
-    def measure(cand):
-        if not probe:  # built once, on the first warmup call only
-            probe["x"] = jnp.zeros((m, k), dtype)
-            probe["qw"] = quantize_weight(jnp.zeros((k, n), jnp.float32))
-        return quant_matmul(probe["x"], probe["qw"], backend="pallas",
-                            blocks=cand)
-
-    return ladder_pick(key, fitted, measure, tuner,
-                       measurable=lambda: not _interpret(),
-                       default=screened[0])
-
-
-def _fitted_flash_candidates(shape, fit_block, supported):
-    """FLASH_BLOCK_CANDIDATES fitted to the call shape and deduped on
-    the fitted geometry — several requests can collapse to the same
-    block pair and must be measured once. Shared by the fwd and bwd
-    pickers (their fit loops were copy-identical)."""
-    _, s, _, _ = shape
-    out = []
-    for c in FLASH_BLOCK_CANDIDATES:
-        fit = (fit_block(c[0], s), fit_block(c[1], s))
-        if 0 in fit or not supported(shape, *c):
-            continue
-        if fit not in out:
-            out.append(fit)
-    if not out:
-        raise ValueError(f"no flash block candidates fit shape {shape}")
-    return out
-
-
-def flash_bwd_blocks_for(shape, dtype, causal, fwd_blocks=None,
-                         tuner=None):
-    """Dispatch-time block geometry for the flash BACKWARD (dkv/dq)
-    kernels, or None for "reuse the forward geometry".
-
-    The backward working set per instance is ~2.5× the forward's (q/k/v
-    PLUS do tiles, lse/delta rows, fp32 dk/dv/dq accumulators), so the
-    measured-best backward blocks at ≥8k sequences are usually narrower
-    than the forward winner — PR 1 tuned only the shared geometry, which
-    pinned backward to whatever forward preferred. Gating matches
-    `flash_blocks_for`: long sequences always measure, DS_TPU_AUTOTUNE=1
-    measures everywhere, an explicit DS_TPU_AUTOTUNE=0 is the kill
-    switch. The probe times ONLY the vjp application (residuals are
-    computed once per candidate outside the timed region via jax.vjp),
-    so the pick ranks pure backward cost."""
-    env = os.environ.get(_TUNE_ENV)
-    if env is not None and env in ("0", "", "false", "False"):
-        return None
-    b, s, h, d = shape
-    if not (autotune_enabled() or s >= flash_tune_min_seq()):
-        return None
-
-    from .pallas.flash_attention import (_fit_block, _interpret,
-                                         flash_attention,
-                                         flash_attention_supported)
-    import numpy as np
-    import jax.numpy as jnp
-
-    key = ("flash_bwd", tuple(shape), str(dtype), bool(causal))
-    candidates = _fitted_flash_candidates(shape, _fit_block,
-                                          flash_attention_supported)
-
-    capped = []
-
-    def measurable():
-        if _interpret():
-            # timing the interpreter ranks emulation cost
-            return False
-        itemsize = np.dtype(dtype).itemsize if dtype != jnp.bfloat16 \
-            else 2
-        if b * s * h * d * itemsize * 8 > _MAX_TUNE_BYTES:
-            from ..utils.logging import logger
-            logger.info(
-                f"flash bwd autotune: shape {tuple(shape)} exceeds the "
-                f"probe memory cap; reusing forward blocks")
-            capped.append(True)
-            return False
-        return True
-
-    def default():
-        # probe-cap degrade inherits the forward geometry; every other
-        # degrade (interpret, multi-host) takes the fattest fit
-        if capped and fwd_blocks is not None:
-            return tuple(fwd_blocks)
-        return candidates[0]
-
-    fbq, fbk = fwd_blocks if fwd_blocks is not None else candidates[0]
-    bwd_cache = {}
-
-    def measure(cand):
-        # vjp ONCE per candidate (fwd geometry held FIXED at fbq/fbk so
-        # only the backward differs), memoized so the fwd execution +
-        # trace land in the tuner's first warmup call and the timed
-        # iterations apply only the bwd closure
-        f_bwd = bwd_cache.get(cand)
-        if f_bwd is None:
-            zeros = bwd_cache.setdefault("zeros",
-                                         jnp.zeros(shape, dtype))
-            _, f_bwd = jax.vjp(
-                lambda q, k, v: flash_attention(q, k, v, causal, None,
-                                                fbq, fbk, tuple(cand)),
-                zeros, zeros, zeros)
-            bwd_cache[cand] = f_bwd
-        return f_bwd(bwd_cache["zeros"])
-
-    return ladder_pick(key, candidates, measure, tuner,
-                       measurable=measurable, default=default)
-
-
-# block-sparse attention (group_q, fanout) candidates, fattest first:
-# bigger groups amortize per-instance fixed cost when adjacent layout
-# rows share columns (windowed/global patterns); bigger fanout fetches
-# more scattered K blocks per grid step. Random-ish patterns (BigBird)
-# prefer smaller groups — the row union drags dead rows otherwise.
-SPARSE_GF_CANDIDATES = ((4, 4), (8, 4), (4, 8), (2, 8), (8, 8), (2, 4),
-                        (2, 2), (1, 4))
-
-
-def sparse_block_params(layout, shape, dtype, causal, sm_scale=None,
-                        tuner=None):
-    """(group_q, fanout) for `BlockSparseAttention` at a given layout and
-    call shape. Static default (4, 4) unless DS_TPU_AUTOTUNE=1, in which
-    case the candidates are measured fwd+bwd on the live device with the
-    ACTUAL layout (pattern structure decides the winner: the row-union
-    LUT tightness differs wildly between windowed and random patterns).
-    Cached per (layout geometry, density, shape, device kind)."""
-    default = SPARSE_GF_CANDIDATES[0]
-    if not autotune_enabled():
-        return default
-    from .pallas.block_sparse_attention import BlockSparseAttention
-    from .pallas.flash_attention import _interpret
-    import numpy as np
-    import jax.numpy as jnp
-
-    lay = np.asarray(layout)
-    key = ("sparse_gf", lay.shape, round(float((lay != 0).mean()), 3),
-           tuple(shape), str(dtype), bool(causal))
-
-    probe = {}
-
-    def measure(cand):
-        zeros = probe.setdefault("z", jnp.zeros(shape, dtype))
-        attn = BlockSparseAttention(lay, block=128, causal=causal,
-                                    sm_scale=sm_scale, group=cand[0],
-                                    fanout=cand[1])
-        return jax.grad(lambda q: jnp.sum(
-            attn(q, zeros, zeros).astype(jnp.float32)))(zeros)
-
-    return ladder_pick(key, SPARSE_GF_CANDIDATES, measure, tuner,
-                       measurable=lambda: not _interpret(),
-                       default=default)
-
-
-def flash_blocks_for(shape, dtype, causal, tuner=None):
-    """Dispatch-time flash block geometry, or None for the built-in
-    default. Long sequences (≥ `flash_tune_min_seq()`, env-tunable) and
-    explicit `DS_TPU_AUTOTUNE=1` runs get `tuned_flash_blocks`'s
-    measured pick; everything else keeps the static default so short-seq
-    call sites pay zero probe launches. Multi-host and oversized shapes
-    degrade to the deterministic fattest candidate inside the tuner.
-
-    `DS_TPU_AUTOTUNE=0` set EXPLICITLY is a kill switch: no measurement
-    anywhere, long sequences included (determinism / trace-latency /
-    probe-crash escape hatch). Unset means auto (long-seq only)."""
-    env = os.environ.get(_TUNE_ENV)
-    if env is not None and env in ("0", "", "false", "False"):
-        return None
-    b, s, h, d = shape
-    if autotune_enabled() or s >= flash_tune_min_seq():
-        return tuned_flash_blocks(shape, dtype, causal, tuner=tuner)
-    return None
-
-
-def tuned_flash_blocks(shape, dtype, causal, tuner=None):
-    """Pick (block_q, block_k) for `flash_attention` by measurement.
-
-    shape: the [B, S, H, D] call shape as seen at the call site — under
-    GSPMD tracing that is the GLOBAL shape, so results are a geometry
-    heuristic, not a per-shard measurement. Cached per (shape, dtype,
-    causal, device kind); the first miss pays a few kernel launches.
-    NOTE: that measurement runs EAGERLY during the first jit trace of any
-    step calling this — budget the one-time latency accordingly.
-    Oversized shapes and multi-host runs skip measurement and cache the
-    fattest default.
-
-    The probe runs forward AND backward: the picked geometry feeds the
-    bwd dkv/dq kernels too, whose VMEM working set is larger — a
-    candidate that only fails (or only crawls) in backward must lose
-    here, not at the first jax.grad step of training."""
-    from .pallas.flash_attention import (_fit_block, flash_attention,
-                                         flash_attention_supported)
-    import numpy as np
-    import jax.numpy as jnp
-
-    from .pallas.flash_attention import _interpret
-    b, s, h, d = shape
-    key = ("flash", tuple(shape), str(dtype), bool(causal))
-
-    def candidates():
-        return _fitted_flash_candidates(shape, _fit_block,
-                                        flash_attention_supported)
-
-    def measurable():
-        # Interpret mode (CPU): measuring would rank Pallas-interpreter
-        # emulation cost — and a 16k probe takes MINUTES per candidate
-        # there. (Multi-host degrade lives in ladder_pick.)
-        if _interpret():
-            return False
-        # x8: the fwd+bwd probe's live set is q/k/v/out + saved
-        # residuals + the cotangent and dq/dk/dv inside _bwd — about
-        # twice the old forward-only probe's four arrays
-        itemsize = np.dtype(dtype).itemsize if dtype != jnp.bfloat16 \
-            else 2
-        if b * s * h * d * itemsize * 8 > _MAX_TUNE_BYTES:
-            # not silent: the shapes most likely to hit this cap (big
-            # GSPMD global batches at 16k+) are exactly what tuning
-            # targets
-            from ..utils.logging import logger
-            logger.info(
-                f"flash autotune: shape {tuple(shape)} exceeds the "
-                f"probe memory cap; using the fattest fitted blocks")
-            return False
-        return True
-
-    probe = {}
-
-    def run(cand):
-        zeros = probe.setdefault("z", jnp.zeros(shape, dtype))
-        return jax.grad(lambda q: jnp.sum(
-            flash_attention(q, zeros, zeros, causal, None, *cand)
-            .astype(jnp.float32)))(zeros)
-
-    return ladder_pick(key, candidates, run, tuner,
-                       measurable=measurable)
+def quant_matmul_blocks(dtype):
+    """(block_m, block_k, block_n) for `quant_matmul` with activations
+    of `dtype`: the first candidate inside the VMEM model, else the
+    narrowest."""
+    itemsize = jnp.dtype(dtype).itemsize
+    for c in QMM_BLOCK_CANDIDATES:
+        if qmm_vmem_bytes(*c, itemsize=itemsize) <= _QMM_VMEM_BUDGET:
+            return c
+    return QMM_BLOCK_CANDIDATES[-1]

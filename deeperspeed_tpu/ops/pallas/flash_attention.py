@@ -47,9 +47,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ... import scopes
 from ...compat import CompilerParams
+from ..autotune import FLASH_BLOCK_K as BLOCK_K, FLASH_BLOCK_Q as BLOCK_Q, \
+    fit_block as _fit_block, flash_blocks
 
-BLOCK_Q = 1024
-BLOCK_K = 1024
 LANES = 128  # TPU minor-dim tile; in-kernel row stats are lane-broadcast
 NEG_INF = -1e30
 
@@ -85,14 +85,6 @@ def note_xla_on_tpu(op, why):
     from ...utils.logging import logger
     logger.warning(f"ops.dispatch {op}: running on XLA, not the Pallas "
                    f"kernel, on a TPU ({why})")
-
-
-def _fit_block(block, s):
-    """Largest 128-multiple ≤ `block` that divides s (0 if none)."""
-    for cand in range(min(block, s), 127, -128):
-        if cand % 128 == 0 and s % cand == 0:
-            return cand
-    return 0
 
 
 # ---------------------------------------------------------------------------
@@ -150,8 +142,7 @@ _LAST_GRIDS = {}
 
 # Ditto for dispatched block geometry: {"fwd"/"dkv"/"dq": (bq, bk)} plus
 # {"fwd_variant"/"bwd_variant": "single"/"trapezoid"/"dense"} of the most
-# recent call — the bench longseq rows record these in `extra` so a round
-# documents WHICH geometry produced its numbers.
+# recent call (`ops.dispatch_report()`).
 _LAST_BLOCKS = {}
 _DISPATCH_LOGGED = False
 
@@ -1308,18 +1299,35 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     return from_bh(dq), from_bh(dk), from_bh(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=BLOCK_Q,
-                    block_k=BLOCK_K, bwd_blocks=None):
+def _resolve_blocks(shape, causal, block_q, block_k, bwd_blocks):
+    """((fwd block_q, block_k), (bwd block_q, block_k)) of a public
+    call: `ops.autotune.flash_blocks` decides unless the caller pins. A
+    caller that pins the forward pair alone pins the backward to it."""
+    if block_q is None and block_k is None:
+        fwd, bwd = flash_blocks(shape, causal)
+    else:
+        fwd = bwd = (block_q or BLOCK_Q, block_k or BLOCK_K)
+    return fwd, tuple(bwd_blocks) if bwd_blocks is not None else bwd
+
+
+def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
+                    block_k=None, bwd_blocks=None):
     """Tiled online-softmax attention on [B, S, H, D].
 
-    `bwd_blocks` (optional `(bwd_block_q, bwd_block_k)` tuple) gives the
-    dkv/dq backward kernels their OWN block geometry: the backward
-    working set is larger (q/k/v/do tiles plus lse/delta rows and fp32
-    accumulators), so at ≥8k sequences the measured-best backward blocks
-    are usually narrower than the forward's. None = reuse the forward
-    geometry (the pre-tuning behaviour). The saved residuals (out, lse)
-    are block-independent, so fwd/bwd geometry can differ freely."""
+    Block geometry comes from `ops.autotune.flash_blocks` at the call's
+    own shape; pass `block_q` / `block_k` and / or `bwd_blocks` (a
+    `(bwd_block_q, bwd_block_k)` tuple for the dkv/dq kernels, whose
+    working set is larger than the forward's) only to pin it. The saved
+    residuals (out, lse) are block-independent, so forward and backward
+    geometry can differ freely."""
+    (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
+                                    bwd_blocks)
+    return _flash_attention(q, k, v, causal, sm_scale, bq, bk, bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
+                     bwd_blocks):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, _ = _fwd(q, k, v, causal, scale, block_q, block_k)
     return out
@@ -1332,17 +1340,15 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, bwd_blocks):
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, bwd_blocks, res, g):
-    bbq, bbk = bwd_blocks if bwd_blocks is not None else (block_q, block_k)
-    return _bwd(causal, sm_scale, bbq, bbk, res, g)
+    return _bwd(causal, sm_scale, *bwd_blocks, res, g)
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+_flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention_segmented(q, k, v, segment_ids, causal=True,
-                              sm_scale=None, block_q=BLOCK_Q,
-                              block_k=BLOCK_K, bwd_blocks=None):
+                              sm_scale=None, block_q=None, block_k=None,
+                              bwd_blocks=None):
     """Flash attention over PACKED ragged batches: tokens attend only
     within their own document (`segment_ids` [B, S] int32, 0 = pad —
     see `runtime.packing`), composed with the causal mask.
@@ -1357,9 +1363,18 @@ def flash_attention_segmented(q, k, v, segment_ids, causal=True,
     kernels' poisoned-lse convention (zero output, zero grads).
 
     segment_ids is data, not a parameter: its cotangent is float0
-    (int inputs cannot carry gradients). `bwd_blocks` as in
+    (int inputs cannot carry gradients). Block geometry as in
     `flash_attention`.
     """
+    (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
+                                    bwd_blocks)
+    return _flash_segmented(q, k, v, segment_ids, causal, sm_scale, bq,
+                            bk, bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_segmented(q, k, v, segment_ids, causal, sm_scale, block_q,
+                     block_k, bwd_blocks):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     seg3 = segment_ids.astype(jnp.int32).reshape(
         segment_ids.shape[0], 1, -1)
@@ -1382,17 +1397,15 @@ def _flash_seg_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
     res, segment_ids = res_seg
     seg3 = segment_ids.astype(jnp.int32).reshape(
         segment_ids.shape[0], 1, -1)
-    bbq, bbk = bwd_blocks if bwd_blocks is not None else (block_q, block_k)
-    dq, dk, dv = _bwd(causal, sm_scale, bbq, bbk, res, g, seg=seg3)
+    dq, dk, dv = _bwd(causal, sm_scale, *bwd_blocks, res, g, seg=seg3)
     return dq, dk, dv, np.zeros(segment_ids.shape, jax.dtypes.float0)
 
 
-flash_attention_segmented.defvjp(_flash_seg_fwd, _flash_seg_bwd)
+_flash_segmented.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention_kbias(q, k, v, kbias, causal=False, sm_scale=None,
-                          block_q=BLOCK_Q, block_k=BLOCK_K):
+                          block_q=None, block_k=None):
     """Flash attention with an additive PER-KEY bias fused into the
     softmax — the TPU-native form of the reference's mask-taking fused
     softmax kernel (`csrc/transformer/softmax_kernels.cu:18-140`,
@@ -1413,33 +1426,41 @@ def flash_attention_kbias(q, k, v, kbias, causal=False, sm_scale=None,
     outside the kernel, or extend the bwd kernels with the
     d(kbias) = Σ_h,q p·(dp − δ) reduction first.
     """
+    (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
+                                    None)
+    return _flash_kbias(q, k, v, kbias, causal, sm_scale, bq, bk, bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_kbias(q, k, v, kbias, causal, sm_scale, block_q, block_k,
+                 bwd_blocks):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     kb3 = kbias.astype(jnp.float32).reshape(kbias.shape[0], 1, -1)
     out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, kbias=kb3)
     return out
 
 
-def _flash_kbias_fwd(q, k, v, kbias, causal, sm_scale, block_q, block_k):
+def _flash_kbias_fwd(q, k, v, kbias, causal, sm_scale, block_q, block_k,
+                     bwd_blocks):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     kb3 = kbias.astype(jnp.float32).reshape(kbias.shape[0], 1, -1)
     out, res = _fwd(q, k, v, causal, scale, block_q, block_k, kbias=kb3)
     return out, (res, kbias)
 
 
-def _flash_kbias_bwd(causal, sm_scale, block_q, block_k, res_kb, g):
+def _flash_kbias_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
+                     res_kb, g):
     res, kbias = res_kb
     kb3 = kbias.astype(jnp.float32).reshape(kbias.shape[0], 1, -1)
-    dq, dk, dv = _bwd(causal, sm_scale, block_q, block_k, res, g,
-                      kbias=kb3)
+    dq, dk, dv = _bwd(causal, sm_scale, *bwd_blocks, res, g, kbias=kb3)
     return dq, dk, dv, jnp.zeros_like(kbias)
 
 
-flash_attention_kbias.defvjp(_flash_kbias_fwd, _flash_kbias_bwd)
+_flash_kbias.defvjp(_flash_kbias_fwd, _flash_kbias_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def flash_attention_train(q, k, v, kbias, seed, causal=False,
-                          sm_scale=None, block_q=BLOCK_Q, block_k=BLOCK_K,
+                          sm_scale=None, block_q=None, block_k=None,
                           dropout_rate=0.0):
     """Training-mode flash attention: fused additive per-key mask AND
     in-kernel attention-probability dropout — the full fused stack of
@@ -1458,12 +1479,10 @@ def flash_attention_train(q, k, v, kbias, seed, causal=False,
     1/keep. kbias and seed receive zero cotangents.
     """
     _check_dropout_rate(dropout_rate)
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    kb3 = None if kbias is None else \
-        kbias.astype(jnp.float32).reshape(kbias.shape[0], 1, -1)
-    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, kbias=kb3,
-                  dropout_rate=dropout_rate, seed=seed)
-    return out
+    (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
+                                    None)
+    return _flash_train(q, k, v, kbias, seed, causal, sm_scale, bq, bk,
+                        bwd, dropout_rate)
 
 
 def _check_dropout_rate(rate):
@@ -1474,9 +1493,19 @@ def _check_dropout_rate(rate):
         raise ValueError(f"dropout_rate must be in [0, 1), got {rate}")
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_train(q, k, v, kbias, seed, causal, sm_scale, block_q, block_k,
+                 bwd_blocks, dropout_rate):
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    kb3 = None if kbias is None else \
+        kbias.astype(jnp.float32).reshape(kbias.shape[0], 1, -1)
+    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, kbias=kb3,
+                  dropout_rate=dropout_rate, seed=seed)
+    return out
+
+
 def _flash_train_fwd(q, k, v, kbias, seed, causal, sm_scale, block_q,
-                     block_k, dropout_rate):
-    _check_dropout_rate(dropout_rate)
+                     block_k, bwd_blocks, dropout_rate):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     kb3 = None if kbias is None else \
         kbias.astype(jnp.float32).reshape(kbias.shape[0], 1, -1)
@@ -1485,22 +1514,22 @@ def _flash_train_fwd(q, k, v, kbias, seed, causal, sm_scale, block_q,
     return out, (res, kbias, seed)
 
 
-def _flash_train_bwd(causal, sm_scale, block_q, block_k, dropout_rate,
-                     res_kb, g):
+def _flash_train_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
+                     dropout_rate, res_kb, g):
     res, kbias, seed = res_kb
     kb3 = None if kbias is None else \
         kbias.astype(jnp.float32).reshape(kbias.shape[0], 1, -1)
-    dq, dk, dv = _bwd(causal, sm_scale, block_q, block_k, res, g,
+    dq, dk, dv = _bwd(causal, sm_scale, *bwd_blocks, res, g,
                       kbias=kb3, dropout_rate=dropout_rate, seed=seed)
     dkb = None if kbias is None else jnp.zeros_like(kbias)
     return dq, dk, dv, dkb, jnp.zeros_like(seed)
 
 
-flash_attention_train.defvjp(_flash_train_fwd, _flash_train_bwd)
+_flash_train.defvjp(_flash_train_fwd, _flash_train_bwd)
 
 
 def make_masked_flash_attention(layout128, causal=False, sm_scale=None,
-                                block_q=BLOCK_Q, block_k=BLOCK_K):
+                                block_q=None, block_k=None):
     """Dense-iteration flash attention honoring a STATIC 128-granular
     block layout: every tile is computed (dense-flash cost, independent
     of density) and inactive 128x128 blocks are masked to -inf — the
@@ -1531,25 +1560,23 @@ def make_masked_flash_attention(layout128, causal=False, sm_scale=None,
                 f"got seq {s}, layout covers "
                 f"{layout.shape[1] * MASK_GRAIN}")
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=())
-    def fn(q, k, v):
-        check(q)
-        scale = sm_scale if sm_scale is not None else \
-            1.0 / math.sqrt(q.shape[-1])
-        out, _ = _fwd(q, k, v, causal, scale, block_q, block_k,
-                      layout=layout)
-        return out
-
     def fwd(q, k, v):
         check(q)
         scale = sm_scale if sm_scale is not None else \
             1.0 / math.sqrt(q.shape[-1])
-        return _fwd(q, k, v, causal, scale, block_q, block_k,
-                    layout=layout)
+        blocks, _ = _resolve_blocks(q.shape, causal, block_q, block_k,
+                                    None)
+        return _fwd(q, k, v, causal, scale, *blocks, layout=layout)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=())
+    def fn(q, k, v):
+        return fwd(q, k, v)[0]
 
     def bwd(res, g):
-        return _bwd(causal, sm_scale, block_q, block_k, res, g,
-                    layout=layout)
+        # the cotangent has q's [B, S, H, D] shape
+        _, blocks = _resolve_blocks(g.shape, causal, block_q, block_k,
+                                    None)
+        return _bwd(causal, sm_scale, *blocks, res, g, layout=layout)
 
     fn.defvjp(fwd, bwd)
     return fn

@@ -121,9 +121,7 @@ def pick_span(capacity, block_m=None):
     rounded up to the row-block, preferring fat blocks but never padding
     a span by more than ~12.5% (padding is wasted HBM in the dense MoE
     path and wasted ICI in the expert-parallel exchange). Small
-    capacities degrade to a single 8-aligned tile per span. Shared by
-    the MoE layer and the autotuner so the measured geometry is exactly
-    the deployed one."""
+    capacities degrade to a single 8-aligned tile per span."""
     cap = max(1, int(capacity))
     target = int(block_m) if block_m else DEFAULT_BLOCK_M
     for cand in (target, target // 2, target // 4):

@@ -43,6 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ... import scopes
 from ...compat import CompilerParams
+from ..autotune import quant_matmul_blocks
 from .flash_attention import _interpret, note_xla_on_tpu
 
 _DIMSEM = CompilerParams(
@@ -218,8 +219,8 @@ def quant_matmul(x, qw, backend=None, blocks=None):
     backend: None = auto (Pallas kernel on TPU when the fitted blocks
     tile the shape, XLA fallback otherwise — CPU tests keep XLA speed
     unless a test opts into the interpreter); "pallas"/"xla" force.
-    blocks: optional (bm, bk, bn) override (`ops.autotune`
-    `quant_matmul_blocks` feeds the measured pick).
+    blocks: (bm, bk, bn) to pin; None takes
+    `ops.autotune.quant_matmul_blocks`.
     """
     if x.ndim != 2:
         lead = x.shape[:-1]
@@ -232,7 +233,8 @@ def quant_matmul(x, qw, backend=None, blocks=None):
         raise ValueError(f"x contraction dim {K} != weight rows {Kw}")
     if qw.scale.shape != (N,):
         raise ValueError(f"scale shape {qw.scale.shape} != ({N},)")
-    bm, bk, bn = blocks if blocks is not None else (256, 512, 256)
+    bm, bk, bn = blocks if blocks is not None else \
+        quant_matmul_blocks(x.dtype)
     if backend is None:
         on_tpu = not _interpret()
         fits = quant_matmul_supported(M, K, N, _fit(bm, M, 8),

@@ -78,7 +78,6 @@ class SparseSelfAttention:
             self.DENSE_DISPATCH_DENSITY if dense_dispatch_density is None
             else dense_dispatch_density)
         self._cache = {}
-        self._tuned = {}    # (seq, shape, dtype) -> retuned kernel
 
     @property
     def block(self):
@@ -126,32 +125,6 @@ class SparseSelfAttention:
             self._cache[seq_len] = (layout, kernel, causal, ops)
         return self._cache[seq_len]
 
-    def _autotuned_kernel(self, s, kernel, q):
-        """Swap the default-geometry sparse kernel for one built at the
-        autotuner's measured (group_q, fanout) — consulted lazily at the
-        first forward per (seq, call shape), because the measured pick
-        needs the LIVE q/k/v shape and dtype the layer actually runs
-        (`ops.autotune.sparse_block_params`; static default when
-        DS_TPU_AUTOTUNE is off, so the non-tuned path pays one isinstance
-        check and one dict probe)."""
-        if not isinstance(kernel, BlockSparseAttention):
-            return kernel     # masked dense-flash arm: nothing to tune
-        from ...ops.autotune import autotune_enabled, sparse_block_params
-        if not autotune_enabled():
-            return kernel
-        key = (s, tuple(q.shape), str(q.dtype))
-        if key not in self._tuned:
-            group, fanout = sparse_block_params(
-                kernel.layout, tuple(q.shape), q.dtype, kernel.causal)
-            if (group, fanout) == (kernel.group, kernel.fanout):
-                self._tuned[key] = kernel
-            else:
-                self._tuned[key] = BlockSparseAttention(
-                    kernel.layout, block=kernel.block,
-                    causal=kernel.causal, sm_scale=kernel.sm_scale,
-                    group=group, fanout=fanout)
-        return self._tuned[key]
-
     def forward(self, query, key, value, rpe=None, key_padding_mask=None,
                 attn_mask=None):
         if self.transpose_inputs:
@@ -171,7 +144,6 @@ class SparseSelfAttention:
         from ..pallas.flash_attention import _LAST_BACKEND, note_xla_on_tpu
         _LAST_BACKEND["sparse_attention"] = "pallas" if use_kernel else "xla"
         if use_kernel:
-            kernel = self._autotuned_kernel(s, kernel, query)
             # the layout is per head, so only the batch splits per shard
             out = per_shard(kernel, (query, key, value), {0: DATA_AXIS})
         else:

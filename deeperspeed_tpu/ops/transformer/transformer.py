@@ -264,25 +264,12 @@ class DeepSpeedTransformerLayer:
             if (additive_mask is None and not attn_drop_active and
                     s >= _flash_min_seq() and
                     flash_attention_supported((b, s, heads, hd))):
-                from ..autotune import (flash_blocks_for,
-                                        flash_bwd_blocks_for)
-                from ..pallas.flash_attention import (
-                    BLOCK_K, BLOCK_Q, flash_attention_segmented)
+                from ..pallas.flash_attention import \
+                    flash_attention_segmented
                 _LAST_BACKEND["attention"] = "pallas"
 
                 def seg_kernel(q, k, v, seg):
-                    # same tuned geometry + min-seq gating as the dense
-                    # branch below: the static square default was the
-                    # measured long-context MFU cliff, and packed
-                    # encoder batches hit the identical kernels. Shapes
-                    # are the shard's when `per_shard` splits the call.
-                    blocks = flash_blocks_for(q.shape, q.dtype, False)
-                    bq, bk = blocks if blocks is not None \
-                        else (BLOCK_Q, BLOCK_K)
-                    bwd = flash_bwd_blocks_for(q.shape, q.dtype, False,
-                                               fwd_blocks=blocks)
-                    return flash_attention_segmented(
-                        q, k, v, seg, False, None, bq, bk, bwd)
+                    return flash_attention_segmented(q, k, v, seg, False)
 
                 ctx = per_shard(seg_kernel, (q, k, v, segment_ids),
                                 _ATTN_SHARD_DIMS)
@@ -303,30 +290,18 @@ class DeepSpeedTransformerLayer:
                 (additive_mask is None or kbias is not None) and \
                 s >= _flash_min_seq() and not drop_unsharded and \
                 flash_attention_supported((b, s, heads, hd)):
-            from ..autotune import flash_blocks_for
-            from ..pallas.flash_attention import BLOCK_K, BLOCK_Q
             _LAST_BACKEND["attention"] = "pallas"
-
-            def blocks_for(q):
-                # measured block geometry for long sequences (and opt-in
-                # autotune runs); None keeps the static default. The
-                # shape is the shard's when `per_shard` splits the call.
-                blocks = flash_blocks_for(q.shape, q.dtype, False)
-                return blocks if blocks is not None else (BLOCK_Q, BLOCK_K)
 
             def kernel(q, k, v, *kb):
                 if not kb:
-                    return flash_attention(q, k, v, False, None,
-                                           *blocks_for(q))
-                return flash_attention_kbias(q, k, v, kb[0], False, None,
-                                             *blocks_for(q))
+                    return flash_attention(q, k, v, False)
+                return flash_attention_kbias(q, k, v, kb[0], False)
 
             if attn_drop_active:    # one device: see drop_unsharded
-                bq, bk = blocks_for(q)
                 seed = jax.random.randint(rng, (1,), 0, 2**31 - 1,
                                           dtype=jnp.int32)
                 ctx = flash_attention_train(
-                    q, k, v, kbias, seed, block_q=bq, block_k=bk,
+                    q, k, v, kbias, seed,
                     dropout_rate=float(cfg.attn_dropout_ratio))
             else:
                 kb = () if kbias is None else (kbias,)

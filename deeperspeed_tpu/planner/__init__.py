@@ -6,8 +6,8 @@ checkpointing, offload tier, quantization recipe, per-kernel block
 geometries — replacing per-knob hand-tuning.
 
 Pipeline: analytic cost model (`cost_model`) prunes the grid →
-measured probe ladder (`search`, riding `ops.autotune.ladder_pick`'s
-measure-once discipline) ranks the survivors → the winning plan is
+measured probe ladder (`search.ladder_pick`, offline, `ds_plan
+--probe`) ranks the survivors → the winning plan is
 emitted and persisted (`plan`) per (device kind, model shape) → the
 engine consumes it through the `"planner"` config block (`apply`) and
 `ds_plan` / `ds_report --json` surface it. See docs/planner.md.

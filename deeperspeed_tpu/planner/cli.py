@@ -3,9 +3,8 @@
 Mirrors `ds_lint`/`ds_report`: zero-argument friendly, `--json` for
 machine consumers. Default run is ANALYTIC-ONLY (no device work, safe
 on a backendless host); `--probe` opts into the measured ladder, which
-builds a real engine per surviving rung and times actual train steps —
-subject to the same degrades as the kernel autotuners (multi-host
-deterministic, interpret-mode and `DS_TPU_AUTOTUNE=0` analytic-only).
+builds a real engine per surviving rung and times actual train steps
+(multi-host and off a TPU it degrades to the analytic winner).
 """
 
 import argparse
@@ -16,8 +15,7 @@ from .cost_model import ModelShape
 from .plan import latest_plan, plan_cache_dir
 from .search import build_plan, candidate_config
 
-# Named model geometries (the bench ladder's shapes); batch_per_chip
-# matches the headline rows' defaults.
+# Named model geometries.
 PRESETS = {
     "125m": dict(num_layers=12, hidden_size=768, num_heads=12,
                  seq_len=1024, vocab_size=50304, batch_per_chip=48),
@@ -143,8 +141,8 @@ def main(argv=None):
                     help="analytic survivors to probe (default 4)")
     ap.add_argument("--probe", action="store_true",
                     help="measure the surviving rungs on real steps "
-                         "(requires DS_TPU_AUTOTUNE=1 and a real "
-                         "accelerator; degrades to analytic-only)")
+                         "(needs a real accelerator; degrades to "
+                         "analytic-only without one)")
     ap.add_argument("--quant", action="store_true",
                     help="let the plan consider quantized-FFN recipes "
                          "(changes training numerics; default off)")

@@ -3,8 +3,8 @@
 A plan is the FULL resolved config the search settled on — the
 `zero_optimization.schedule` knobs, activation-checkpointing policy,
 offload tier + buffer counts, quantization recipe, and the per-kernel
-block geometries — persisted per (device kind, model shape) the way the
-autotune cache is keyed per (key, device kind). `ds_plan` writes these,
+block geometries — persisted per (device kind, model shape). `ds_plan`
+writes these,
 `ds_report --json` surfaces the newest fingerprint, and the engine
 consumes one through the validated ``"planner"`` config block
 (`runtime/config.py:parse_planner_block`).

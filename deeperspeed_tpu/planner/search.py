@@ -1,20 +1,21 @@
 """The planner's search driver: enumerate → analytic prune → probe.
 
-Subsumes the per-kernel pickers' search discipline behind one driver:
-the combinatorial schedule space (mode × prefetch_depth × bucket_mb ×
+The combinatorial schedule space (mode × prefetch_depth × bucket_mb ×
 group_layers × remat × offload tier × quant recipe) is scored by the
-analytic cost model and memory-screened down to a small ladder, then
-the surviving rungs are ranked on real measured steps through the SAME
-`ladder_pick` spine the kernel autotuners run on — so the planner
-inherits the Autotuner's measure-once cache, the multi-host
-deterministic degrade, and the interpret-mode / `DS_TPU_AUTOTUNE=0`
-analytic-only fallbacks for free.
+analytic cost model and memory-screened down to a small ladder; with
+`ds_plan --probe` the surviving rungs are then ranked on real measured
+steps, offline and outside any trace (`Autotuner`, `ladder_pick`). This
+is the only place in the package that times work to choose between
+candidates; kernel block geometry never is (`ops/autotune.py`).
 """
 
 import itertools
+import time
 
-from ..ops.autotune import (Autotuner, autotune_enabled, hbm_bytes_limit,
-                            ladder_pick)
+import jax
+import jax.numpy as jnp
+
+from ..ops import autotune
 from . import cost_model as cm
 from .plan import PLAN_VERSION, Plan, cached_plan
 
@@ -41,10 +42,74 @@ QUANT_FFNS = (None, "int8")
 # How many analytic survivors graduate to the measured probe ladder.
 DEFAULT_TOP_K = 4
 
-# A dedicated tuner instance: plan probes are whole train steps, one
-# timed iteration is plenty (the kernel tuners' 3 would triple an
-# already-expensive probe phase).
+
+
+class Autotuner:
+    """Times callables on the live device, remembers the fastest.
+
+    `pick(key, candidates, run)` → winning candidate. `run(candidate)`
+    must execute the candidate end-to-end and return something blockable
+    (`jax.block_until_ready` is applied). A candidate that raises (a
+    config the engine refuses, an OOM) is disqualified rather than
+    fatal."""
+
+    def __init__(self, warmup=1, iters=3, timer=time.perf_counter):
+        self.warmup = warmup
+        self.iters = iters
+        self.timer = timer
+        self._cache = {}
+
+    def cached(self, key):
+        return self._cache.get((key, autotune._device_kind()))
+
+    def store(self, key, value):
+        """Record a decision without measuring."""
+        self._cache[(key, autotune._device_kind())] = value
+        return value
+
+    def pick(self, key, candidates, run):
+        hit = self.cached(key)
+        if hit is not None:
+            return hit
+        best, best_t = None, float("inf")
+        for cand in candidates:
+            try:
+                for _ in range(self.warmup):
+                    jax.block_until_ready(run(cand))
+                t0 = self.timer()
+                for _ in range(self.iters):
+                    out = run(cand)
+                jax.block_until_ready(out)
+                dt = self.timer() - t0
+            except Exception:
+                continue
+            if dt < best_t:
+                best, best_t = cand, dt
+        if best is None:
+            raise RuntimeError(
+                f"autotune: every candidate failed for key {key!r}")
+        return self.store(key, best)
+
+
+# Plan probes are whole train steps: one timed iteration is plenty.
 _plan_tuner = Autotuner(warmup=1, iters=1)
+
+
+def ladder_pick(key, candidates, measure, tuner, measurable):
+    """Cache hit for (key, device kind) → returned unmeasured. Not
+    `measurable`, a multi-host run (per-host wall-clock picks can
+    disagree → different programs per host → deadlock at the first
+    collective) or a single rung → the first candidate, stored without
+    touching the device. Otherwise each candidate is timed through
+    `measure(candidate)` and the winner cached."""
+    hit = tuner.cached(key)
+    if hit is not None:
+        return hit
+    if not candidates:
+        raise ValueError(f"planner: no candidates for key {key!r}")
+    if len(candidates) == 1 or not measurable or jax.process_count() > 1:
+        return tuner.store(key, candidates[0])
+    return tuner.pick(key, candidates, measure)
 
 
 def enumerate_candidates(allow_offload=True, allow_quant=True):
@@ -101,51 +166,24 @@ def analytic_ladder(shape, hw, world, stage=3, top_k=DEFAULT_TOP_K,
     return rungs
 
 
-def kernel_geometries(shape):
-    """The per-kernel block geometries the plan pins, resolved through
-    the kernel pickers' own screening tables (their deterministic
-    static picks — never a probe: the plan must be emittable on a
-    host with no accelerator). Unavailable kernels record None."""
-    import jax.numpy as jnp
-    out = {}
+def kernel_geometries(shape, device_kind=None):
+    """The per-kernel block geometries the plan pins: what
+    `ops.autotune`'s rules give for this shape on `device_kind` (pure
+    functions: the plan is emittable on a host with no accelerator)."""
     head_dim = max(1, shape.hidden_size // max(1, shape.num_heads))
     attn_shape = (shape.batch_per_chip, shape.seq_len, shape.num_heads,
                   head_dim)
     try:
-        from ..ops.autotune import _fitted_flash_candidates
-        from ..ops.pallas.flash_attention import (
-            _fit_block, flash_attention_supported)
-        out["flash_blocks"] = list(_fitted_flash_candidates(
-            attn_shape, _fit_block, flash_attention_supported)[0])
-    except Exception:  # noqa: BLE001 - kernel unavailable on this host
-        out["flash_blocks"] = None
-    try:
-        from ..ops.autotune import (GMM_BLOCK_CANDIDATES,
-                                    _GMM_VMEM_BUDGET, _gmm_itemsize,
-                                    gmm_vmem_bytes)
-        itemsize = _gmm_itemsize(jnp.bfloat16)
-        k_dim, n_dim = shape.hidden_size, 4 * shape.hidden_size
-        fits = [c for c in GMM_BLOCK_CANDIDATES
-                if max(gmm_vmem_bytes(c[0], c[1], k_dim, itemsize),
-                       gmm_vmem_bytes(c[0], c[1], n_dim, itemsize))
-                <= _GMM_VMEM_BUDGET]
-        out["gmm_blocks"] = list(fits[0] if fits
-                                 else GMM_BLOCK_CANDIDATES[-1])
-    except Exception:  # noqa: BLE001
-        out["gmm_blocks"] = None
-    try:
-        from ..ops.autotune import (_QMM_VMEM_BUDGET,
-                                    QMM_BLOCK_CANDIDATES, _gmm_itemsize,
-                                    qmm_vmem_bytes)
-        itemsize = _gmm_itemsize(jnp.bfloat16)
-        fits = [c for c in QMM_BLOCK_CANDIDATES
-                if qmm_vmem_bytes(*c, itemsize=itemsize)
-                <= _QMM_VMEM_BUDGET]
-        out["qmm_blocks"] = list(fits[0] if fits
-                                 else QMM_BLOCK_CANDIDATES[-1])
-    except Exception:  # noqa: BLE001
-        out["qmm_blocks"] = None
-    return out
+        fwd, bwd = autotune.flash_blocks(attn_shape, True, device_kind)
+    except ValueError:          # no 128-multiple divides the sequence
+        fwd = bwd = None
+    return {
+        "flash_blocks": fwd and list(fwd),
+        "flash_bwd_blocks": bwd and list(bwd),
+        "gmm_blocks": list(autotune.grouped_matmul_blocks(
+            shape.hidden_size, 4 * shape.hidden_size, jnp.bfloat16)),
+        "qmm_blocks": list(autotune.quant_matmul_blocks(jnp.bfloat16)),
+    }
 
 
 def candidate_config(cand, stage=3):
@@ -177,21 +215,16 @@ def candidate_config(cand, stage=3):
 
 
 def probes_measurable(probe, measurable):
-    """The planner's degrade verdict, mirroring the kernel pickers:
-    no probe callable, `DS_TPU_AUTOTUNE=0`/unset, or interpret-mode
-    Pallas (no real accelerator) → analytic-only. Multi-host degrade
-    lives in `ladder_pick` itself."""
+    """The planner's degrade verdict: measure when the caller handed a
+    `probe` and an accelerator is here (off a TPU a timed step ranks the
+    Pallas interpreter) → else analytic-only. An explicit `measurable`
+    overrides. Multi-host degrade lives in `ladder_pick` itself."""
     if measurable is not None:
         return bool(measurable)
-    if probe is None or not autotune_enabled():
+    if probe is None:
         return False
-    try:
-        from ..ops.pallas.flash_attention import _interpret
-        if _interpret():
-            return False
-    except Exception:  # noqa: BLE001 - kernel module unavailable
-        pass
-    return True
+    from ..ops.pallas.flash_attention import _interpret
+    return not _interpret()
 
 
 def build_plan(shape, device_kind=None, world=None, stage=3,
@@ -207,18 +240,16 @@ def build_plan(shape, device_kind=None, world=None, stage=3,
     2. analytic ladder: enumerate → cost-model score → memory screen →
        `top_k` rungs;
     3. probe phase: `ladder_pick` over the rungs with
-       `probe(candidate)` as the measure (timed by the Autotuner with
+       `probe(candidate)` as the measure (timed by the `Autotuner` with
        `perf_counter` outside traced code); degrades to the analytic
        winner per `probes_measurable`;
     4. emit: resolved config + kernel geometries + analytic scores,
        persisted to the plan cache.
     """
     if device_kind is None:
-        from ..ops.autotune import _device_kind
-        device_kind = _device_kind()
+        device_kind = autotune._device_kind()
     if world is None:
         try:
-            import jax
             world = len(jax.devices())
         except Exception:  # noqa: BLE001 - backendless planning host
             world = 1
@@ -229,7 +260,7 @@ def build_plan(shape, device_kind=None, world=None, stage=3,
 
     if hbm_limit is None:
         try:
-            hbm_limit = hbm_bytes_limit()
+            hbm_limit = autotune.hbm_bytes_limit()
         except Exception:  # noqa: BLE001
             hbm_limit = None
     hw = cm.hardware_profile(device_kind, hbm_limit)
@@ -245,8 +276,7 @@ def build_plan(shape, device_kind=None, world=None, stage=3,
         ("plan", device_kind, shape.key(), stage),
         [c for c, _ in rungs],
         probe if probe is not None else (lambda cand: None),
-        tuner or _plan_tuner,
-        measurable=can_probe)
+        tuner or _plan_tuner, can_probe)
 
     payload = {
         "version": PLAN_VERSION,
@@ -265,7 +295,7 @@ def build_plan(shape, device_kind=None, world=None, stage=3,
         },
         "chosen": chosen.label(),
         "config": candidate_config(chosen, stage),
-        "kernels": kernel_geometries(shape),
+        "kernels": kernel_geometries(shape, device_kind),
         "analytic": {
             "ladder": scores,
             "hardware": {k: hw[k] for k in ("peak_flops",

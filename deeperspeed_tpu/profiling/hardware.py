@@ -1,13 +1,13 @@
-"""Per-device-kind hardware peaks (shared by bench.py, the in-engine
-telemetry layer `runtime/telemetry.py`, and the schedule planner's
-analytic cost model, `deeperspeed_tpu/planner`).
+"""Per-device-kind hardware peaks (shared by the in-engine telemetry
+layer `runtime/telemetry.py` and the schedule planner's analytic cost
+model, `deeperspeed_tpu/planner`).
 
-One table per quantity, several consumers: `bench.py` computes offline
-MFU from measured tokens/s, the telemetry layer turns
+One table per quantity, several consumers: the telemetry layer turns
 `compiled.cost_analysis()` flops into a live `Train/Samples/mfu`
 scalar, and the planner prices candidate schedules (compute from peak
 flops, collectives from ICI bandwidth). Keeping the tables here means
 the consumers can never disagree about what "peak" means for a chip.
+(The benchmark keeps its own, `benchmarks/peaks.json`.)
 
 Import-light on purpose: no jax at module scope — callers hand in device
 objects (or kind strings), so config parsing never pays a backend init.

@@ -339,10 +339,6 @@ class DeepSpeedEngine:
             self._config.telemetry_config, monitor=self.monitor,
             devices=local or jax.local_devices())
         self._step_flops = {}   # compiled-variant key -> per-device flops
-        # cumulative offload-tier counters (stall/bytes/flops) across the
-        # run — per-step values are drained into telemetry; bench rows
-        # read these totals
-        self._offload_totals = {}
 
         # MoE routing observability (moe.observability): the sort
         # engine's in-jit stats land host-side via an async callback and
@@ -3114,8 +3110,7 @@ class DeepSpeedEngine:
                 ps["stages"], ps["n_micro"], ps["wire_latency"])
             if self._multislice is not None:
                 # exposed DCN crossings of the running schedule — the
-                # unit dcn_delay faults charge and the denominator of
-                # the two-slice throughput-ratio bench row
+                # unit dcn_delay faults charge
                 scalars["Train/Multislice/dcn_exposed_crossings"] = \
                     float(self._multislice.exposed_crossings(
                         ps["n_micro"], ps["wire_latency"]))
@@ -3402,9 +3397,6 @@ class DeepSpeedEngine:
             if self._tiered is not None:
                 metrics = self._tiered_train_batch(batch)
                 offload = self._tiered.stats.drain()
-                for k, v in offload.items():
-                    self._offload_totals[k] = \
-                        self._offload_totals.get(k, 0) + v
                 flops = offload["flops"] or None
             else:
                 metrics = self._streamed_train_batch(batch)
@@ -3655,12 +3647,10 @@ class DeepSpeedEngine:
                     "full logits)")
             if self._tiered is not None:
                 loss = self._tiered_eval(batch)
-                # fold the eval's counters into the run totals NOW —
-                # left in the runner they would inflate the NEXT train
-                # step's MFU / Train/Offload/* scalars
-                for k, v in self._tiered.stats.drain().items():
-                    self._offload_totals[k] = \
-                        self._offload_totals.get(k, 0) + v
+                # drop the eval's counters NOW — left in the runner they
+                # would inflate the NEXT train step's MFU /
+                # Train/Offload/* scalars
+                self._tiered.stats.drain()
                 return loss
             loss = self._streamed_eval(batch, rng)
             self._stream_flops.drain()   # ditto: not the next step's flops

@@ -2,9 +2,9 @@
 
 The recovery machinery in `runtime/sentinel.py` is only trustworthy if
 every path can be driven on demand: this harness injects NaN gradients,
-loss spikes, and stalled steps at chosen steps so tests and the
-`DS_BENCH_SENTINEL=1` bench row exercise detect -> quarantine ->
-rollback -> abort and the hang watchdog end to end.
+loss spikes, and stalled steps at chosen steps so tests exercise
+detect -> quarantine -> rollback -> abort and the hang watchdog end to
+end.
 
 Gating (zero overhead when off):
 
@@ -73,8 +73,7 @@ serving hang watchdog); ``page_pool_pressure`` seizes a fraction of
 the free page pool for the step (drives eviction under memory
 pressure and the admission controller's shedding signal). Together
 they make every shed/quarantine/retry/watchdog path single-host
-testable (`docs/inference.md`, the ``chaos`` test marker, and the
-``DS_BENCH_SERVE_CHAOS=1`` bench row).
+testable (`docs/inference.md`, the ``chaos`` test marker).
 """
 
 import json

@@ -118,8 +118,8 @@ class PackedDataset:
         return (self.tokens[i], self.tokens[i], self.segment_ids[i])
 
     def occupancy(self):
-        """Fraction of non-pad positions — the packing-efficiency scalar
-        the bench row records."""
+        """Fraction of non-pad positions: the packing-efficiency
+        scalar."""
         if self.segment_ids.size == 0:
             return 0.0
         return float((self.segment_ids != PAD_SEGMENT_ID).mean())
@@ -199,9 +199,8 @@ def segment_relative_positions(segment_ids):
 def synthetic_doc_mixture(seed, n_docs, vocab_size, mean_len=600.0,
                           sigma=1.0, max_len=None):
     """Deterministic lognormal document-length mixture (the shape of web
-    corpora: many short documents, a heavy long tail). Shared by the
-    packed bench row and the tests so rounds are comparable — same seed,
-    same mixture. Returns a list of int32 token arrays."""
+    corpora: many short documents, a heavy long tail): same seed, same
+    mixture. Returns a list of int32 token arrays."""
     rng = np.random.default_rng(seed)
     # lognormal with the requested mean: mean = exp(mu + sigma^2/2)
     mu = np.log(mean_len) - 0.5 * sigma * sigma

@@ -27,7 +27,7 @@ module closes the loop:
 Everything is driven by the validated ``"training_health"`` JSON block
 (`runtime/config.py`); the subsystem is entirely absent from the compiled
 program when disabled. `runtime/fault_injection.py` drives every path
-deterministically for tests and the `DS_BENCH_SENTINEL=1` bench row.
+deterministically for tests (no benchmark cell runs the sentinel).
 """
 
 import threading

@@ -5,8 +5,8 @@ moves never hits. Two rules follow: whoever runs the program may place
 the cache from outside with ``JAX_COMPILATION_CACHE_DIR`` (jax reads the
 variable itself, and then no code sets another), and without it the
 cache sits at one fixed path inside the checkout — never a temporary,
-pid- or time-derived one. `chip_smoke.py` and `bench.py` call this; the
-tests keep the cache off.
+pid- or time-derived one. `chip_smoke.py` and `benchmarks/run.py` call
+this; the tests keep the cache off.
 """
 
 import os

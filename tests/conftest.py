@@ -24,7 +24,7 @@ jax.config.update("jax_platforms", "cpu")
 
 # The tests keep the persistent compile cache off and compile fresh every
 # run: `deeperspeed_tpu.utils.compile_cache` is for `chip_smoke.py` and
-# `bench.py` only. (An earlier builder saw executables read back from the
+# `benchmarks/run.py` only. (An earlier builder saw executables read back from the
 # cache mis-execute on the CPU backend — diverging trajectories, glibc
 # aborts; that was not re-tested at PR 22, so the rule stays.)
 

@@ -2,12 +2,14 @@
 perf claims need enforced floors, reference sweep harnesses
 `csrc/aio/py_test/run_read_sweep.sh` + `tests/perf/adam_test.py`).
 
-Thresholds are deliberately ~3-7× below the values measured on the
-1-vCPU CI box (cpu-Adam 0.12 Gparams/s @16M, aio ~2.2 GB/s @1MB/qd16):
-they trip on order-of-magnitude regressions — a silent fallback to a
-pure-Python optimizer step, or the aio engine losing its thread pool /
-going synchronous — not on machine-load noise. Collected by the normal
-pytest run (fast: one size, few iters); the full sweeps stay in
+The cpu-Adam threshold is deliberately ~3× below the value measured on
+the 1-vCPU CI box (0.12 Gparams/s @16M): it trips on an
+order-of-magnitude regression — a silent fallback to a pure-Python
+optimizer step — not on machine-load noise. The aio engine's gate holds
+what a fallback would break (native library, asynchronous submit, its
+own threads, every byte back) and no GB/s: a temp disk shared with five
+other test workers read under any floor worth setting. Collected by the
+normal pytest run; the sweeps with the numbers stay in
 `cpu_adam_bench.py` / `aio_sweep.py`.
 """
 
@@ -17,9 +19,8 @@ import time
 import numpy as np
 import pytest
 
-# gate floors (see module docstring for the measured headroom)
+# gate floor (see module docstring for the measured headroom)
 CPU_ADAM_MIN_GPARAMS_PER_SEC = 0.04
-AIO_MIN_GB_PER_SEC = 0.3
 
 
 def test_cpu_adam_throughput_floor():
@@ -46,25 +47,34 @@ def test_cpu_adam_throughput_floor():
         f"{CPU_ADAM_MIN_GPARAMS_PER_SEC} floor (offload tier rotted?)")
 
 
-def test_aio_throughput_floor(tmp_path):
+def test_aio_engine_is_native_and_asynchronous(tmp_path):
+    """What the old GB/s floor stood for, without the disk's speed under
+    six test workers in it: the engine is the native library, a submit
+    returns while its chunks are still queued, the work runs on the
+    engine's own threads, and every byte comes back. (`aio_sweep.py`
+    keeps the number.)"""
+    import ctypes
+
     from deeperspeed_tpu.runtime.swap_tensor.aio_engine import AsyncIOEngine
     if not AsyncIOEngine.available():
         pytest.skip("native aio engine unavailable (no C++ toolchain)")
     mb = 128
     buf = np.random.default_rng(0).standard_normal(
         mb * 1024 * 1024 // 4).astype(np.float32)
-    out = np.empty_like(buf)
+    out = np.zeros_like(buf)
     path = os.path.join(str(tmp_path), "gate.bin")
+    tasks = "/proc/self/task"
+    threads_before = len(os.listdir(tasks)) if os.path.isdir(tasks) else None
     eng = AsyncIOEngine(block_size=1024 * 1024, queue_depth=16,
                         thread_count=2)
-    t0 = time.perf_counter()
+    assert isinstance(eng._lib, ctypes.CDLL)
+    if threads_before is not None:
+        assert len(os.listdir(tasks)) > threads_before
     eng.aio_write(buf, path)
-    eng.wait()
-    w = mb / 1024 / (time.perf_counter() - t0)
-    t0 = time.perf_counter()
+    assert eng.pending() > 0       # 128 one-MB chunks cannot be done yet
+    assert eng.wait() == 0 and eng.pending() == 0
+    assert os.path.getsize(path) == buf.nbytes
     eng.aio_read(out, path)
-    eng.wait()
-    r = mb / 1024 / (time.perf_counter() - t0)
-    assert (out[:1024] == buf[:1024]).all()
-    assert w >= AIO_MIN_GB_PER_SEC, f"aio write {w:.2f} GB/s below floor"
-    assert r >= AIO_MIN_GB_PER_SEC, f"aio read {r:.2f} GB/s below floor"
+    assert eng.pending() > 0
+    assert eng.wait() == 0 and eng.pending() == 0
+    np.testing.assert_array_equal(out, buf)

@@ -171,40 +171,6 @@ def test_imports_initialise_no_backend():
     assert proc.stdout.strip().endswith("clean")
 
 
-FAKE_ROWS = """
-import json, subprocess, sys
-import bench
-
-def fake_run(cmd, **kwargs):
-    row = cmd[cmd.index("--row") + 1]
-    if row == "zero3":
-        return subprocess.CompletedProcess(cmd, 1, "", "boom: row died")
-    out = {"tokens_per_sec_chip": 1.0, "mfu": 0.5} \\
-        if row == bench.HEADLINE else {row + "_ran": True}
-    return subprocess.CompletedProcess(cmd, 0, json.dumps(out), "")
-
-bench.subprocess.run = fake_run
-sys.argv = ["bench.py"]
-rc = bench.main()
-assert "jax" not in sys.modules, "the bench parent imported jax"
-sys.exit(rc)
-"""
-
-
-@pytest.mark.parametrize("rows,want_rc", [("bert128", 0),
-                                          ("zero3,bert128", 1)],
-                         ids=["rows_pass", "a_row_fails"])
-def test_bench_parent_stays_off_jax_and_reports_failed_rows(rows, want_rc):
-    """bench.py's parent runs every row — the headline too — in a child
-    and never imports jax; a row that dies makes the exit code non-zero
-    while the JSON line still carries what the other rows measured."""
-    proc = run_python("-c", FAKE_ROWS, env={"DS_BENCH_ROWS": rows})
-    assert proc.returncode == want_rc, proc.stderr
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["value"] == 1.0 and line["extra"]["bert128_ran"]
-    assert ("zero3_row_error" in line["extra"]) == bool(want_rc)
-
-
 @pytest.mark.parametrize("placed", [None, "/some/dir"],
                          ids=["unset", "placed_from_outside"])
 def test_compile_cache_helper(placed):

@@ -1,8 +1,8 @@
 """dslint: fixture-driven rule tests + the tier-1 zero-findings gate.
 
 The package gate (`test_package_gate_zero_findings`) IS the enforcement
-point: it runs the full rule set over `deeperspeed_tpu/`, `bench.py`
-and `tests/perf/` and fails on any non-baselined finding. It runs in
+point: it runs the full rule set over `deeperspeed_tpu/` and
+`tests/perf/` and fails on any non-baselined finding. It runs in
 tier-1 by default (no marker) — a parse of ~150 files, well under a
 second. The `dslint`-marked variants (paired with `slow`) are the
 whole-repo self-scans.
@@ -496,7 +496,7 @@ def test_gate_runs_all_rules():
     assert set(result.rules_run) == set(REGISTRY)
     assert set(RULE_FIXTURES) | {"parse-only-key"} == set(REGISTRY)
     assert len(REGISTRY) == 9
-    assert DEFAULT_PATHS == ("deeperspeed_tpu", "bench.py", "tests/perf")
+    assert DEFAULT_PATHS == ("deeperspeed_tpu", "tests/perf")
 
 
 # ---------------------------------------------------------------------------

@@ -331,13 +331,12 @@ def test_supported_gate():
 
 
 def test_autotune_static_screen():
-    """Without DS_TPU_AUTOTUNE the pick is deterministic, VMEM-screened,
-    and fattest-first."""
-    bm, bn = grouped_matmul_blocks(2560, 768, 3072, jnp.bfloat16)
+    """The pick is deterministic, VMEM-screened, and fattest-first."""
+    bm, bn = grouped_matmul_blocks(768, 3072, jnp.bfloat16)
     assert (bm, bn) in GMM_BLOCK_CANDIDATES
     assert gmm_vmem_bytes(bm, bn, 768, 2) <= (10 << 20)
     # a huge contraction dim must push the pick off the fattest blocks;
     # when NOTHING fits the model, the helper degrades to the narrowest
     # candidate rather than refusing
-    bm2, bn2 = grouped_matmul_blocks(2560, 16384, 3072, jnp.float32)
+    bm2, bn2 = grouped_matmul_blocks(16384, 3072, jnp.float32)
     assert (bm2, bn2) == GMM_BLOCK_CANDIDATES[-1]

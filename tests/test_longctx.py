@@ -76,16 +76,14 @@ def test_segmented_deep_grid_fwd_bwd():
 
 @pytest.mark.parametrize("seq", [16384, 32768])
 def test_bwd_dispatch_shapes_divide(seq, monkeypatch):
-    """The 16k/32k backward dispatch must always hand the kernels a
-    dividing geometry (whatever the tuner picked)."""
-    from deeperspeed_tpu.models.gpt_neox import _flash_dispatch
+    """The 16k/32k dispatch must always hand the kernels a dividing
+    geometry."""
+    from deeperspeed_tpu.ops.autotune import flash_blocks
     monkeypatch.delenv("DS_FLASH_BLOCKS", raising=False)
     monkeypatch.delenv("DS_FLASH_BWD_BLOCKS", raising=False)
-    fwd, bwd = _flash_dispatch((1, seq, 12, 64), jnp.bfloat16)
-    for blocks in (fwd, bwd):
-        if blocks is not None:
-            assert seq % blocks[0] == 0 and seq % blocks[1] == 0
-            assert blocks[0] % 128 == 0 and blocks[1] % 128 == 0
+    for blocks in flash_blocks((1, seq, 12, 64), True):
+        assert seq % blocks[0] == 0 and seq % blocks[1] == 0
+        assert blocks[0] % 128 == 0 and blocks[1] % 128 == 0
 
 
 def test_packed_model_1k_trains():

@@ -590,25 +590,6 @@ def test_gpt2_rejects_sparse_attention_block():
         model.apply_ds_config(ds)
 
 
-def test_flash_bwd_blocks_memory_cap_reuses_fwd(monkeypatch):
-    """Above the probe-memory cap the fallback must store the caller's
-    FORWARD geometry (what the log claims), not the fattest candidate —
-    the cap fires exactly on memory-constrained shapes."""
-    import importlib
-    import deeperspeed_tpu.ops.autotune as at
-    # the pallas package re-exports the flash_attention FUNCTION under
-    # the submodule's name; reach the module itself for patching
-    fa = importlib.import_module(
-        "deeperspeed_tpu.ops.pallas.flash_attention")
-    monkeypatch.setenv("DS_TPU_AUTOTUNE", "1")
-    monkeypatch.setattr(at, "_MAX_TUNE_BYTES", 1)
-    monkeypatch.setattr(fa, "_interpret", lambda: False)
-    got = at.flash_bwd_blocks_for((1, 16384, 2, 64), jnp.float32, True,
-                                  fwd_blocks=(512, 1024),
-                                  tuner=at.Autotuner(warmup=0, iters=1))
-    assert got == (512, 1024)
-
-
 def test_make_sparse_attention_rejects_bidirectional():
     from deeperspeed_tpu.models.gpt_neox import (GPTNeoXConfig,
                                                  make_sparse_attention)
@@ -661,66 +642,6 @@ def test_sparse_engine_config_plumb():
     model.apply_ds_config(ds)
     assert model.config.attention_engine == "sparse"
     assert model._attn_fn is not None
-
-
-def test_sparse_autotune_kernel_default_when_disabled(monkeypatch):
-    """With DS_TPU_AUTOTUNE off, the sparse layer keeps its statically
-    built kernel (no measurement on the hot path)."""
-    monkeypatch.delenv("DS_TPU_AUTOTUNE", raising=False)
-    from deeperspeed_tpu.ops.pallas.block_sparse_attention import \
-        BlockSparseAttention
-    from deeperspeed_tpu.ops.sparse_attention import (FixedSparsityConfig,
-                                                      SparseSelfAttention)
-    sp = SparseSelfAttention(
-        FixedSparsityConfig(num_heads=2, block=128, num_local_blocks=1),
-        dense_dispatch_density=1.1)   # force the sparse-kernel arm
-    _, kernel, _, _ = sp.get_layout(256)
-    assert isinstance(kernel, BlockSparseAttention)
-    same = sp._autotuned_kernel(256, kernel, jnp.zeros((1, 256, 2, 64)))
-    assert same is kernel
-
-
-# ---------------------------------------------------------------------------
-# autotune dispatch gating
-# ---------------------------------------------------------------------------
-
-def test_flash_bwd_blocks_env_off(monkeypatch):
-    from deeperspeed_tpu.ops.autotune import flash_bwd_blocks_for
-    monkeypatch.setenv("DS_TPU_AUTOTUNE", "0")
-    assert flash_bwd_blocks_for((1, 16384, 2, 64), jnp.float32,
-                                True) is None
-
-
-def test_flash_bwd_blocks_interpret_first_candidate(monkeypatch):
-    """On CPU (interpret mode) long sequences pick WITHOUT measuring —
-    timing the Pallas interpreter would rank emulation cost."""
-    from deeperspeed_tpu.ops.autotune import flash_bwd_blocks_for
-    monkeypatch.delenv("DS_TPU_AUTOTUNE", raising=False)
-    blocks = flash_bwd_blocks_for((1, 16384, 2, 64), jnp.float32,
-                                  True, fwd_blocks=(512, 1024))
-    assert blocks is not None
-    bq, bk = blocks
-    assert 16384 % bq == 0 and 16384 % bk == 0
-
-
-def test_sparse_block_params_default_when_disabled(monkeypatch):
-    from deeperspeed_tpu.ops.autotune import (SPARSE_GF_CANDIDATES,
-                                              sparse_block_params)
-    monkeypatch.delenv("DS_TPU_AUTOTUNE", raising=False)
-    layout = np.ones((2, 2, 2), np.int64)
-    assert sparse_block_params(layout, (1, 256, 2, 64), jnp.float32,
-                               True) == SPARSE_GF_CANDIDATES[0]
-
-
-def test_env_bwd_blocks_override(monkeypatch):
-    from deeperspeed_tpu.models.gpt_neox import _parse_env_blocks
-    monkeypatch.setenv("DS_FLASH_BWD_BLOCKS", "128,128")
-    assert _parse_env_blocks("DS_FLASH_BWD_BLOCKS",
-                             (1, 256, 2, 64)) == (128, 128)
-    # 100 is below the 128 grain — no dividing block fits
-    monkeypatch.setenv("DS_FLASH_BWD_BLOCKS", "100,128")
-    with pytest.raises(ValueError, match="DS_FLASH_BWD_BLOCKS"):
-        _parse_env_blocks("DS_FLASH_BWD_BLOCKS", (1, 256, 2, 64))
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Profile-guided schedule planner (tentpole: unify the per-kernel
-autotuners' search discipline behind one cost-model-driven planner).
+"""Profile-guided schedule planner: a cost-model-driven search, with an
+offline measured probe of whole steps (`Autotuner`, `ladder_pick`).
 
 Fast-lane file (NO `slow` marker): the cost model is pure arithmetic,
 plans are JSON files, and the probe phase is exercised with injected
@@ -16,13 +16,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deeperspeed_tpu.ops.autotune import Autotuner
 from deeperspeed_tpu.planner import cost_model as cm
 from deeperspeed_tpu.planner.plan import (Plan, cached_plan,
                                           latest_plan_fingerprint,
                                           load_plan, plan_fingerprint)
-from deeperspeed_tpu.planner.search import (analytic_ladder, build_plan,
-                                            candidate_config,
+from deeperspeed_tpu.planner.search import (Autotuner, analytic_ladder,
+                                            build_plan, candidate_config,
                                             enumerate_candidates,
                                             probes_measurable)
 from deeperspeed_tpu.runtime.config import (DeepSpeedConfig,
@@ -143,11 +142,71 @@ def test_candidate_config_overlay_shape():
 
 
 def test_probes_measurable_degrades(monkeypatch):
+    import importlib
+    fa = importlib.import_module(
+        "deeperspeed_tpu.ops.pallas.flash_attention")
     assert not probes_measurable(None, None)           # no probe at all
     assert probes_measurable(lambda c: None, True)     # explicit override
     assert not probes_measurable(lambda c: None, False)
-    monkeypatch.delenv("DS_TPU_AUTOTUNE", raising=False)
-    assert not probes_measurable(lambda c: None, None)  # autotune off
+    # a probe alone decides, and only where there is an accelerator
+    assert not probes_measurable(lambda c: None, None)  # this CPU
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    assert probes_measurable(lambda c: None, None)
+    assert not probes_measurable(None, None)
+
+
+def test_autotuner_picks_fastest_and_caches():
+    clock = {"t": 0.0}
+    tuner = Autotuner(warmup=0, iters=1, timer=lambda: clock["t"])
+    runs = []
+    cost = {"a": 5.0, "b": 1.0, "c": 3.0}
+
+    def run(c):
+        runs.append(c)
+        clock["t"] += cost[c]
+        return jnp.zeros(())
+
+    assert tuner.pick("k", ["a", "b", "c"], run) == "b"
+    n_runs = len(runs)
+    # second call: cached, no new runs
+    assert tuner.pick("k", ["a", "b", "c"], run) == "b"
+    assert len(runs) == n_runs
+
+
+def test_autotuner_skips_failing_candidates():
+    tuner = Autotuner(warmup=0, iters=1)
+
+    def run(c):
+        if c != "ok":
+            raise RuntimeError("the engine refused this config")
+        return jnp.zeros(())
+
+    assert tuner.pick("k2", ["bad1", "ok", "bad2"], run) == "ok"
+    with pytest.raises(RuntimeError):
+        tuner.pick("k3", ["bad1", "bad2"], run)
+
+
+@pytest.mark.parametrize("measurable,hosts,ladder,timed", [
+    (True, 1, ["a", "b"], True),
+    (False, 1, ["a", "b"], False),     # analytic only
+    (True, 2, ["a", "b"], False),      # hosts could disagree
+    (True, 1, ["a"], False)])          # nothing to rank
+def test_ladder_pick_measures_only_what_it_may(monkeypatch, measurable,
+                                               hosts, ladder, timed):
+    from deeperspeed_tpu.planner.search import ladder_pick
+    monkeypatch.setattr(jax, "process_count", lambda: hosts)
+    tuner = Autotuner(warmup=0, iters=1)
+    ran = []
+
+    def measure(c):
+        ran.append(c)
+        return jnp.zeros(())
+
+    pick = ladder_pick("k", ladder, measure, tuner, measurable)
+    assert bool(ran) == timed
+    if not timed:
+        assert pick == ladder[0]
+    assert ladder_pick("k", ladder, measure, tuner, measurable) == pick
 
 
 # ---------------------------------------------------------------------------

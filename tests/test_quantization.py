@@ -122,7 +122,7 @@ class TestQuantMatmul:
         from deeperspeed_tpu.ops.autotune import (QMM_BLOCK_CANDIDATES,
                                                   qmm_vmem_bytes,
                                                   quant_matmul_blocks)
-        pick = quant_matmul_blocks(256, 1024, 4096, jnp.bfloat16)
+        pick = quant_matmul_blocks(jnp.bfloat16)
         assert pick in QMM_BLOCK_CANDIDATES
         assert qmm_vmem_bytes(*pick, itemsize=2) <= 10 << 20
 

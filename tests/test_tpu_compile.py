@@ -121,6 +121,44 @@ def test_flash_backward_compiles(on_chip, name, shape, causal):
     assert_kernel(on_chip(grad, *qkv(*shape)), at_least=2)
 
 
+# Everything `ops.autotune.flash_blocks` can return for a v5e at 8k tokens
+# and over: each row of its table, and the fallback (asked for under a
+# device kind that has no row) at head dim 64 and 128, causal and not.
+# PR 23's cell met a candidate on the chip that did not compile; this is
+# the check that would have met it here.
+def _long_flash_cases():
+    from deeperspeed_tpu.ops.autotune import (FLASH_LONG_SEQ_BLOCKS,
+                                              flash_blocks)
+    cases = [(f"row_d{d}_{'causal' if causal else 'full'}",
+              (1, 16384, 16, d), causal, kind)
+             for kind, d, causal in FLASH_LONG_SEQ_BLOCKS
+             if kind == "TPU v5 lite"]
+    cases += [(f"fallback_d{d}_{'causal' if causal else 'full'}",
+               (1, 16384, 16, d), causal, "no row")
+              for d in (64, 128) for causal in (True, False)]
+    return [pytest.param(shape, causal, flash_blocks(shape, causal, kind),
+                         id=name) for name, shape, causal, kind in cases]
+
+
+@pytest.mark.parametrize("shape,causal,blocks", _long_flash_cases())
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_long_sequence_flash_geometry_compiles(on_chip, shape, causal,
+                                               blocks, grad):
+    (bq, bk), bwd = blocks
+
+    def attn(q, k, v):
+        return fa.flash_attention(q, k, v, causal, None, bq, bk, bwd)
+
+    if grad:
+        text = on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)),
+                       *qkv(*shape))
+        assert fa._LAST_BLOCKS["dkv"] == fa._LAST_BLOCKS["dq"] == bwd
+    else:
+        text = on_chip(attn, *qkv(*shape))
+    assert fa._LAST_BLOCKS["fwd"] == (bq, bk)
+    assert_kernel(text, at_least=3 if grad else 1)
+
+
 # the serving prefill buckets (`InferenceEngine._prefill_fn` masks pad
 # rows through segment ids) and the packed-training shape
 @pytest.mark.parametrize("shape", [(4, 128, 12, 64), (4, 1024, 12, 64),

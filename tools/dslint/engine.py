@@ -10,11 +10,11 @@ from .baseline import (DEFAULT_BASELINE_PATH, load_baseline,
 from .core import LintContext, iter_source_files
 from .rules import REGISTRY
 
-# What the tier-1 gate lints. `bench.py` and `tests/perf/` ride along
-# for the wall-clock audit (bench step timing on a wall clock is the
-# same NTP-jump hazard PR 6 fixed in utils/timer.py) — and get the full
-# rule set since they exercise the same engine surfaces.
-DEFAULT_PATHS = ("deeperspeed_tpu", "bench.py", "tests/perf")
+# What the tier-1 gate lints. `tests/perf/` rides along for the
+# wall-clock audit (step timing on a wall clock is the same NTP-jump
+# hazard PR 6 fixed in utils/timer.py) — and gets the full rule set
+# since it exercises the same engine surfaces.
+DEFAULT_PATHS = ("deeperspeed_tpu", "tests/perf")
 
 
 @dataclasses.dataclass
@@ -65,8 +65,8 @@ def run_lint(paths=None, root=None, select=None, baseline_path=None,
             if not os.path.exists(ap):
                 ctx.errors.append((p, "path does not exist"))
     else:
-        # default set: absent members are tolerated (a checkout without
-        # bench.py still lints the package)
+        # default set: absent members are tolerated (an installed
+        # package without tests/ still lints)
         paths = [p for p in DEFAULT_PATHS
                  if os.path.exists(os.path.join(root, p))]
     ctx.sources = list(iter_source_files(paths, root, errors=ctx.errors))
