@@ -34,6 +34,13 @@ Execution model (docs/inference.md):
   the chunk programs (`_chunk_fn`) still scan the pools as inputs and
   outputs, which copies them.
 
+- **One decode in flight.** `step()` enqueues its prefill and its
+  decode before it reads the previous call's decode back; a continuing
+  row's input token is gathered from that program's output on the
+  device, so the chip does not wait for the host between steps
+  (`_dispatch_decode`, `_settle`; docs/inference.md "The step's order").
+  `Request.generated` only ever holds tokens that were read back.
+
 Sampling is deterministic: temperature 0 (default) is argmax;
 temperature > 0 draws from `jax.random.categorical` under a fixed
 config seed folded with the step counter — the same request stream
@@ -41,10 +48,13 @@ always produces the same tokens.
 """
 
 import contextlib
+import itertools
 import math
 import random
 import time
 import types
+from collections import deque
+from dataclasses import dataclass
 from functools import partial
 
 import numpy as np
@@ -81,6 +91,29 @@ from .metrics import (PREFIX_HIT_RATE, PREFIX_PAGES_SHARED,
                       SPEC_ACCEPTANCE_RATE, ServeRequestMetrics)
 from .scheduler import (FINISHED, RUNNING, ContinuousBatchingScheduler,
                         Request)
+
+
+@dataclass
+class _InFlight:
+    """A dispatched program whose sampled tokens the host has not read:
+    its dispatch serial, its phase (`prefill` | `decode`), the requests
+    of its rows in row order, and the tokens on the device. A row is
+    live while its request still lists the serial in `Request.owed`
+    (every road out of `running` but a completed step clears that list):
+    only a live row's token is recorded."""
+    serial: int
+    phase: str
+    reqs: list
+    tokens: object
+
+    def rows(self):
+        """(row, request, live) of every row."""
+        return [(i, r, self.serial in r.owed)
+                for i, r in enumerate(self.reqs)]
+
+    @property
+    def live(self):
+        return [r for _, r, live in self.rows() if live]
 
 
 def _pow2_ladder(lo, hi):
@@ -433,8 +466,22 @@ class InferenceEngine:
 
         self._compiled = {}
         self._steps = 0
+        # programs dispatched and not read back, in device order; when
+        # `step()` returns it holds at most the newest decode. `_carry`
+        # is the last decode's token array: the next decode takes the
+        # tokens of its continuing rows from it, on the device
+        self._inflight = deque()
+        self._dispatched = itertools.count()
+        self._carry_width = max(self.decode_batch_sizes)
+        self._carry = self._zero_carry()
         self.stats = {"steps": 0, "prefill_requests": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
+                      # one-step lookahead (docs/inference.md): decode
+                      # programs enqueued while the previous one was
+                      # unread, and rows of a read-back decode whose
+                      # token was dropped (the step past an EOS, a row
+                      # evicted or expired since its dispatch)
+                      "lookahead_steps": 0, "lookahead_discarded": 0,
                       "evictions": 0, "finished": 0,
                       "schedule_s": 0.0, "prefill_s": 0.0,
                       "decode_s": 0.0, "admission_wait_s": 0.0,
@@ -597,6 +644,7 @@ class InferenceEngine:
         in training resume, but only the module tree is deserialized —
         a serving restart never touches Adam moments."""
         from ..checkpoint.checkpointing import load_module_checkpoint
+        self._settle()
         path, natural, client_state = load_module_checkpoint(
             load_dir, tag=tag, like=self._natural_like)
         if path is None:
@@ -620,6 +668,7 @@ class InferenceEngine:
         Returns ``{"swap_ms", "compile_delta"}``; a non-zero
         compile_delta after warmup is the regression the satellite test
         pins to 0."""
+        self._settle()      # the decode in flight ran on the old weights
         before = self.compile_count()
         t0 = time.perf_counter()
         params = prepare_inference_params(natural_params,
@@ -877,8 +926,17 @@ class InferenceEngine:
         fam = self.family
         ps = self.page_size
 
+        width = self._carry_width
+
         def decode(params, stacked, tokens, lengths, page_table, k_pool,
-                   v_pool, rng):
+                   v_pool, rng, carried, src):
+            # a row that continues from the decode still in flight takes
+            # its token from that program's output `carried`, at row
+            # `src` (-1: the host's `tokens` entry stands), so nothing of
+            # the previous step has to reach the host before this one is
+            # enqueued
+            tokens = jnp.where(src >= 0, carried[jnp.maximum(src, 0)],
+                               tokens)
             # lengths INCLUDE the token decoded this step; 0 marks an
             # inactive (padding) row whose page table is all trash
             pos = jnp.maximum(lengths - 1, 0)
@@ -890,7 +948,10 @@ class InferenceEngine:
                 page_table, page_idx, pos % ps, lengths)
             h = fam.final_norm(params, x)
             logits = fam.head(params, h[:, 0])
-            return self._sample(logits, rng), k_pool, v_pool
+            # one shape for every bucket's tokens: what the next decode
+            # (of any bucket) gathers from
+            nxt = jnp.pad(self._sample(logits, rng), (0, width - batch))
+            return nxt, k_pool, v_pool
 
         fn = jax.jit(decode, donate_argnums=(5, 6))
         self._compiled[key] = fn
@@ -1151,14 +1212,28 @@ class InferenceEngine:
         """One scheduler step: admit + prefill new requests, decode one
         token for every in-flight sequence. Returns a summary dict.
 
+        One-step lookahead (docs/inference.md): the call enqueues its
+        prefill and its decode first, the decode taking the tokens of
+        its continuing rows from the previous decode's output on the
+        device, and only then reads back the PREVIOUS call's decode and
+        this call's prefill. When it returns, its own decode is still in
+        flight; `generated` holds exactly the tokens read back, and
+        ``summary["decoded"]`` / ``stats["decode_tokens"]`` count those.
+        The speculative path (the host accepts drafts between its two
+        programs) and a `prefill`-role engine read every program back
+        before they return.
+
         A prefill/decode exception QUARANTINES the implicated batch
         (evict, free pages, capped-jittered retry; poisoned after
         ``retry.max_attempts`` consecutive failures) instead of killing
         the server — `step()` only raises on scheduler-invariant
-        violations. The hang watchdog (``inference.hang_timeout_s``) is
-        armed around the dispatch once the step's programs are warm
-        (an XLA compile is not a hang) and fed on exit — including when
-        the step DIES rather than hangs."""
+        violations. A device error surfaces at the read-back of the
+        program that raised it, and that program's batch is the one
+        quarantined. The hang watchdog (``inference.hang_timeout_s``)
+        is armed for the call once its programs are warm (an XLA compile
+        is not a hang) — a program hung on the device stops the next
+        call's read-back — and fed on exit, including when the step DIES
+        rather than hangs."""
         self._plan_step_faults()
         self._apply_page_pressure()
         try:
@@ -1208,31 +1283,35 @@ class InferenceEngine:
         self.stats["queue_depth"] = float(len(self.scheduler.waiting))
         self.stats["page_pool_util"] = 1.0 - self.cache.num_free / usable
 
-        if self.watchdog is not None and self._programs_warm(plan):
+        if self.watchdog is not None and (
+                self._programs_warm(plan) or
+                (plan.empty and self._inflight)):
             self.watchdog.arm()
 
+        # lookahead is what the step does whenever its decode is the
+        # plain program: nothing the host must see lies between this
+        # call's programs. Speculation accepts drafts on the host
+        # between its two, and a prefill pool hands its first tokens off
+        lookahead = not self.spec_k and self.role != "prefill"
+        decoded_before = self.stats["decode_tokens"]
+        newest = None       # the decode this call leaves in flight
+        touched = [r for rec in self._inflight for r in rec.reqs] + \
+            plan.prefills
+
         if plan.prefills:
-            t0 = time.perf_counter()
-            ok = True
-            with self.telemetry.span("prefill"):
-                try:
+            # the phase closes before a failure settles the engine: the
+            # reads of what is in flight open phases of their own
+            try:
+                with self._phase("prefill"):
                     fault = self._fault_fired("prefill_error")
                     if fault is not None:
                         raise InjectedServingFault(
                             "injected prefill_error fault")
-                    self._run_prefill(plan)
-                except Exception as e:  # noqa: BLE001 - quarantine, don't die
-                    ok = False
-                    self._quarantine_batch(plan.prefills, e, "prefill")
-            self.stats["prefill_s"] += time.perf_counter() - t0
-            if ok:
-                self.stats["prefill_requests"] += len(plan.prefills)
-                # r.cached is the pre-sampling context length (complete_
-                # prefill pins it before appending the first token) —
-                # len(r.context) here would double-count that token once
-                # decode accounting starts
-                self.stats["prefill_tokens"] += \
-                    sum(r.cached for r in plan.prefills)
+                    self._dispatch_prefill(plan)
+            except Exception as e:  # noqa: BLE001 - quarantine, don't die
+                self._quarantine_batch(plan.prefills, e, "prefill")
+            if not lookahead:
+                self._settle()
 
         if self.role == "prefill":
             # a prefill pool never decodes: freshly prefilled sequences
@@ -1242,36 +1321,40 @@ class InferenceEngine:
             self._collect_handoffs()
             self._dispatch_handoffs(now)
 
-        # a mid-execution prefill failure may have run cache-loss
+        # a failed prefill settled what was in flight (a row may have
+        # finished on its EOS there) and may have run cache-loss
         # recovery, evicting EVERY running sequence (their K/V is
         # gone): the planned decode batch would read trash pages and
         # append garbage tokens — skip it; the evicted requests
         # re-prefill on later steps
         decodes_intact = all(r.state == RUNNING for r in plan.decodes)
-        produced = 0
         if plan.decodes and decodes_intact:
             stall = self._fault_fired("decode_stall")
             if stall is not None:
                 time.sleep(stall["seconds"])   # drives the watchdog
-            t0 = time.perf_counter()
-            ok = True
-            with self.telemetry.span("decode"):
-                try:
+            try:
+                with self._phase("decode"):
                     fault = self._fault_fired("decode_error")
                     if fault is not None:
                         raise InjectedServingFault(
                             "injected decode_error fault")
                     if self.spec_k:
-                        produced = self._run_speculative(plan)
+                        self.stats["decode_tokens"] += \
+                            self._run_speculative(plan)
                     else:
-                        produced = self._run_decode(plan)
-                except Exception as e:  # noqa: BLE001
-                    ok = False
-                    produced = 0
-                    self._quarantine_batch(plan.decodes, e, "decode")
-            self.stats["decode_s"] += time.perf_counter() - t0
-            if ok:
-                self.stats["decode_tokens"] += produced
+                        newest = self._dispatch_decode(plan)
+            except Exception as e:  # noqa: BLE001
+                self._quarantine_batch(plan.decodes, e, "decode")
+
+        # read back in device order: the previous call's decode, then
+        # this call's prefill (TTFT is stamped in the call that ran the
+        # prefill, with this call's decode already queued behind it).
+        # The newest decode stays in flight, unless none of its rows is
+        # live any more: then no later call is owed
+        self._settle(keep=newest)
+        if self._inflight and not newest.live:
+            self._settle()
+        produced = self.stats["decode_tokens"] - decoded_before
 
         finished = len(self.scheduler.finished) - finished_before
         self.stats["finished"] += finished
@@ -1279,7 +1362,7 @@ class InferenceEngine:
         self._sync_status_counts()
         if self.admission is not None and finished:
             self.admission.note_finished(finished)
-        self._record_request_spans(plan)
+        self._record_request_spans(touched)
         if self.monitor is not None:
             # per-step saturation series keyed by total generated tokens
             # (the Serve/* convention); buffered — no per-step flush
@@ -1371,6 +1454,11 @@ class InferenceEngine:
         retries; a request failing ``retry.max_attempts`` consecutive
         steps is poisoned permanently with a typed `RequestFailed`
         (the serving mirror of PR 9's poison-step detector)."""
+        # what is still in flight lands first: its tokens are the
+        # requests' own (a program that fails at ITS read-back
+        # quarantines its own batch there, `_read_failed`), and recovery
+        # below needs a settled engine
+        self._settle()
         now = time.perf_counter()
         self._recover_cache_if_lost(now)
         # the exception rides on poisoned requests (RequestFailed.
@@ -1378,11 +1466,21 @@ class InferenceEngine:
         # traceback NOW, or the stored frame graph pins this step's
         # plan/batch arrays (and the engine) for that whole lifetime
         exc.__traceback__ = None
+        # one fault, one quarantine: the settle above may have failed at
+        # a read-back that held some of these requests (`_read_failed`
+        # quarantined them there), and cache-loss recovery may have
+        # failed some
+        parked = {id(r) for r in self.scheduler.quarantined}
+        requests = [r for r in requests
+                    if r.state != FINISHED and id(r) not in parked]
+        if not requests:
+            logger.warning(
+                f"serving {phase} step failed ({type(exc).__name__}: "
+                f"{exc}) — its requests are quarantined already")
+            return
         rp = self.retry_params
         poisoned = 0
         for req in requests:
-            if req.state == FINISHED:
-                continue       # cache-loss recovery may have failed it
             req.failures += 1
             if req.failures >= rp["max_attempts"]:
                 poisoned += 1
@@ -1671,10 +1769,11 @@ class InferenceEngine:
         except Exception:  # noqa: BLE001 - best-effort from the thread
             pass
 
-    def _record_request_spans(self, plan):
+    def _record_request_spans(self, requests):
         """Per-request lifecycle records behind the telemetry capture
-        machinery: while a capture window is open, every request that
-        FINISHED this step lands in the span buffer as one event
+        machinery: while a capture window is open, every request (of
+        `requests`: the rows read back this step) that FINISHED this
+        step lands in the span buffer as one event
         covering submit → last token (exported in the Chrome trace next
         to the schedule/prefill/decode spans). Zero cost outside a
         window."""
@@ -1682,7 +1781,7 @@ class InferenceEngine:
         if tracer is None or not tracer.capturing:
             return
         now = time.perf_counter()
-        for req in plan.prefills + plan.decodes:
+        for req in requests:
             if req.state == FINISHED and req.submitted_at is not None:
                 tracer.record_event(
                     f"request/{req.request_id}", req.submitted_at,
@@ -1749,9 +1848,18 @@ class InferenceEngine:
             out = np.asarray(arr)
         return out, time.perf_counter()
 
-    def _complete_prefills(self, reqs, nxt, now):
-        for i, req in enumerate(reqs):
+    def _complete_prefills(self, rec, nxt, now):
+        for i, req, live in rec.rows():
+            if not live:
+                continue    # evicted by cache-loss recovery meanwhile
+            req.owed.remove(rec.serial)
             self.scheduler.complete_prefill(req, int(nxt[i]))
+            self.stats["prefill_requests"] += 1
+            # req.cached is the pre-sampling context length (complete_
+            # prefill pins it before appending the first token) —
+            # len(req.context) here would double-count that token once
+            # decode accounting starts
+            self.stats["prefill_tokens"] += req.cached
             # TTFT: once per request, from the ORIGINAL submit — an
             # evicted request's re-prefill resamples a token it already
             # delivered and must not re-count
@@ -1777,7 +1885,9 @@ class InferenceEngine:
             self.stats["moe_buffer_rows"] += \
                 fam.moe_buffer_rows(program_tokens) * layers
 
-    def _run_prefill(self, plan):
+    def _dispatch_prefill(self, plan):
+        """Build and enqueue the plan's prefill (and its draft twin);
+        its first tokens are read back by `_settle`."""
         B, S = plan.prefill_batch, plan.prefill_len
         if plan.prefill_kind == "chunk":
             # prefix-cache hit batch: suffix-only window through the
@@ -1807,39 +1917,118 @@ class InferenceEngine:
             nxt, self.cache.k, self.cache.v = fn(
                 self.params, self.params_stacked, *args, self.cache.k,
                 self.cache.v, self._next_rng())
+        self._enqueued("prefill", plan.prefills, nxt)
         if self.spec_k:
             self._draft_prefill_twin(plan.prefills, B, S)
-        nxt, now = self._readback(nxt)
-        with self._phase("complete"):
-            self._complete_prefills(plan.prefills, nxt, now)
 
-    def _run_decode(self, plan):
+    def _dispatch_decode(self, plan):
+        """Build and enqueue the plan's decode step. A row whose last
+        token is itself unread (`pending`) names its row of the decode in
+        flight, and the program takes the token from there; `lengths`,
+        the page table and the page growth behind it are counts the host
+        already has."""
         B = plan.decode_batch
+        prev = next((rec for rec in reversed(self._inflight)
+                     if rec.phase == "decode"), None)
         with self._phase("build_inputs"):
             tokens = np.zeros((B,), np.int32)
+            src = np.full((B,), -1, np.int32)
             lengths = np.zeros((B,), np.int32)
             page_table = np.zeros((B, self.n_pages_max), np.int32)
+            prev_row = {id(r): i for i, r in enumerate(prev.reqs)} \
+                if prev else {}
             for i, req in enumerate(plan.decodes):
-                tokens[i] = req.generated[-1]
-                lengths[i] = req.cached + 1
+                if req.pending:
+                    src[i] = prev_row[id(req)]
+                else:
+                    tokens[i] = req.generated[-1]
+                lengths[i] = req.cached + req.pending + 1
                 page_table[i, :len(req.pages)] = req.pages
             self.stats["decode_kv_tokens"] += int(lengths.sum())
             self._count_moe_rows("decode", len(plan.decodes), B)
             args = [jnp.asarray(a) for a in (tokens, lengths, page_table)]
+            src = jnp.asarray(src)
         fn = self._decode_fn(B)
         with self._phase("dispatch"):
             nxt, self.cache.k, self.cache.v = fn(
                 self.params, self.params_stacked, *args, self.cache.k,
-                self.cache.v, self._next_rng())
-        nxt, now = self._readback(nxt)
-        with self._phase("complete"):
-            for i, req in enumerate(plan.decodes):
-                self.scheduler.complete_decode(req, int(nxt[i]))
-                if req.last_token_at is not None:
-                    self.request_metrics.observe_inter_token(
-                        now - req.last_token_at)
-                req.last_token_at = now
-        return len(plan.decodes)
+                self.cache.v, self._next_rng(), self._carry, src)
+        if prev is not None:
+            self.stats["lookahead_steps"] += 1
+        self._carry = nxt
+        return self._enqueued("decode", plan.decodes, nxt)
+
+    def _zero_carry(self):
+        return jnp.asarray(np.zeros((self._carry_width,), np.int32))
+
+    def _enqueued(self, phase, reqs, tokens):
+        rec = _InFlight(next(self._dispatched), phase, list(reqs), tokens)
+        for req in reqs:
+            req.owed.append(rec.serial)
+        self._inflight.append(rec)
+        return rec
+
+    def _settle(self, keep=None):
+        """Read back what is in flight, oldest first, and record it: all
+        of it, or all that was enqueued before the program `keep` (the
+        decode a step leaves in flight). Whatever needs the engine
+        between steps as the synchronous loop left it (drain, a weight
+        swap, quarantine, the end of `run`) calls this first. The tokens
+        land in the requests, the counts in `stats`."""
+        while self._inflight and self._inflight[0] is not keep:
+            self._read(self._inflight.popleft())
+
+    def _read(self, rec):
+        """One program's tokens to the host and into its requests. A row
+        whose request left `running` since the dispatch (it finished on
+        an EOS the step before, was evicted, quarantined or expired) is
+        dropped: never appended, never counted in `decode_tokens`, its
+        pages already released."""
+        failure = None
+        with self._phase(rec.phase):
+            try:
+                nxt, now = self._readback(rec.tokens)
+            except Exception as e:  # noqa: BLE001 - the device error is here
+                failure = e
+            else:
+                with self._phase("complete"):
+                    if rec.phase == "prefill":
+                        self._complete_prefills(rec, nxt, now)
+                    else:
+                        self._complete_decodes(rec, nxt, now)
+        if failure is not None:
+            self._read_failed(rec, failure)
+
+    def _complete_decodes(self, rec, nxt, now):
+        for i, req, live in rec.rows():
+            if not live:
+                self.stats["lookahead_discarded"] += 1
+                continue
+            req.owed.remove(rec.serial)
+            self.scheduler.complete_decode(req, int(nxt[i]))
+            self.stats["decode_tokens"] += 1
+            if req.last_token_at is not None:
+                self.request_metrics.observe_inter_token(
+                    now - req.last_token_at)
+            req.last_token_at = now
+
+    def _read_failed(self, rec, exc):
+        """A program's device error surfaces at its read-back, when the
+        next decode is already enqueued behind it. That one consumed this
+        one's tokens and pools, so whatever it holds for the same
+        requests is dropped with them (it may fail in its turn, or
+        succeed on a token that was never delivered): their `owed` is
+        cleared BEFORE the quarantine settles the rest. The batch is
+        quarantined once, as the synchronous loop did; rows the successor
+        holds for other requests are read and judged on their own."""
+        batch = rec.live
+        for req in batch:
+            req.owed.clear()
+        # the failed program's output may be what `_carry` holds; no row
+        # reads it once everything is settled, but the next decode takes
+        # the array as an argument
+        self._carry = self._zero_carry()
+        self._quarantine_batch(batch, exc, rec.phase)
 
     # ------------------------------------------------------------------
     # speculative decoding (docs/inference.md "Speculative decoding")
@@ -2041,6 +2230,7 @@ class InferenceEngine:
                 deadline_hit = True
                 break
             self.step()
+        self._settle()      # the deadline cut the loop mid-flight
         abandoned = 0
         for key, (req, _) in list(self._pending_handoff.items()):
             self.handoff.withdraw(key)
@@ -2108,6 +2298,7 @@ class InferenceEngine:
             self.step()
             steps += 1
             if max_steps is not None and steps >= max_steps:
+                self._settle()
                 break
         return steps
 

@@ -28,6 +28,13 @@ Admission policy (in order, per step):
    bucket: shorter queued prompts pad up into the batch's bucket, a
    longer one closes the batch and leads the next step's.
 
+The engine keeps one decode step in flight (docs/inference.md "The
+step's order"), so the scheduler plans with tokens that are dispatched
+but not yet read: `Request.owed`, counted by `Request.pending`. Page
+growth counts `cached + pending`, and a request whose last token by
+`max_new_tokens` or by the window's edge is pending stays in `running`
+(its pages are in use) but out of the decode batch.
+
 Token accounting uses PADDED bucket sizes, not raw prompt lengths: the
 budget is a compute bound, and compute is spent at compiled shapes.
 The budget must cover the largest user prefill bucket (validated at
@@ -98,6 +105,17 @@ class Request:
     generated: list = field(default_factory=list)
     pages: list = field(default_factory=list)
     cached: int = 0          # tokens whose K/V sit in `pages`
+    # the dispatched programs that owe this request a token the host has
+    # not read yet, by the engine's dispatch serial (the serve loop's
+    # one-step lookahead, docs/inference.md): none or one whenever the
+    # scheduler plans. It is the one record of what is in flight for the
+    # request: the engine appends a serial at dispatch and records a
+    # program's token at its read-back only if its serial is still here.
+    # `generated` only ever holds tokens that were read back; planning
+    # counts `cached + pending` and `len(generated) + pending`. Leaving
+    # `running` by any road but a completed step (eviction, quarantine,
+    # a terminal status) clears it, so the unread token is dropped.
+    owed: list = field(default_factory=list)
     # prefix-cache attachment: the first `n_shared` entries of `pages`
     # are registry pages this request only READS (retained, never
     # written); `prefix_node` is the deepest matched/registered chain
@@ -136,6 +154,21 @@ class Request:
             return True
         return (self.eos_token_id is not None and self.generated and
                 self.generated[-1] == self.eos_token_id)
+
+    @property
+    def pending(self):
+        """Tokens dispatched to the device and not read back yet."""
+        return len(self.owed)
+
+    def last_token_pending(self, max_seq_len):
+        """The token that ends this request by count (`max_new_tokens`,
+        or the serving window's edge) is dispatched and unread: it takes
+        no further decode step. (An end by EOS is the one the host learns
+        only at the read-back.)"""
+        n = len(self.generated) + self.pending
+        return bool(self.pending) and (
+            n >= self.max_new_tokens or
+            len(self.prompt) + n >= max_seq_len)
 
 
 @dataclass
@@ -387,6 +420,7 @@ class ContinuousBatchingScheduler:
         except ValueError:
             pass
         self._release_pages(request)
+        request.owed.clear()
         request.status = status
         if error is not None:
             request.error = error
@@ -437,6 +471,7 @@ class ContinuousBatchingScheduler:
             pass
         self._release_pages(request)
         request.cached = 0
+        request.owed.clear()
         request.evictions += 1
         request.state = WAITING
         request.enqueued_at = now
@@ -467,6 +502,7 @@ class ContinuousBatchingScheduler:
             self.running.remove(request)
         self._release_pages(request)
         request.cached = 0
+        request.owed.clear()
         request.evictions += 1
         request.state = WAITING
         request.enqueued_at = now
@@ -506,7 +542,10 @@ class ContinuousBatchingScheduler:
                             kv[0]))[1]
         self.running.remove(req)
         self._release_pages(req)
+        # a token still in flight for it is dropped at its read-back: the
+        # re-prefill samples that position again
         req.cached = 0
+        req.owed.clear()
         req.evictions += 1
         req.state = WAITING
         # admission wait restarts from the requeue, else readmission
@@ -542,9 +581,12 @@ class ContinuousBatchingScheduler:
         for req in list(self.running):
             if req not in self.running:           # evicted by an earlier turn
                 continue
+            if req.last_token_pending(self.max_seq_len):
+                continue                          # takes no further step
             # last slot this step's writes reach (the speculative
-            # verify writes the full window before acceptance)
-            pos = req.cached + self._spec_window(req)
+            # verify writes the full window before acceptance); a token
+            # in flight has its slot already
+            pos = req.cached + req.pending + self._spec_window(req)
             page_idx = pos // self.page_size
             while page_idx >= len(req.pages):
                 got = self.cache.allocate(1)
@@ -571,7 +613,8 @@ class ContinuousBatchingScheduler:
         self._release_quarantined(now)
         evicted = []
         self._grow_running(evicted, now)
-        decodes = list(self.running)
+        decodes = [r for r in self.running
+                   if not r.last_token_pending(self.max_seq_len)]
         # a decode step costs 1 token per row — plus its speculative
         # window: the verify forward computes window+1 positions
         budget = self.token_budget - sum(1 + self._spec_window(r)

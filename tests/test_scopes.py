@@ -105,15 +105,18 @@ def serve_texts(kernel, cfg=None):
     rng = jax.random.PRNGKey(0)
     pools = (engine.cache.k, engine.cache.v)
 
-    def text(fn, tokens, lengths, page_table):
+    def text(fn, tokens, lengths, page_table, *carry):
         return fn.lower(engine.params, engine.params_stacked, tokens,
                         lengths, page_table, *pools,
-                        rng).compile().as_text()
+                        rng, *carry).compile().as_text()
     prefill = text(engine._prefill_fn(1, 128), np.zeros((1, 128), np.int32),
                    np.ones((1,), np.int32), np.zeros((1, 8), np.int32))
     decode = text(engine._decode_fn(2), np.zeros((2,), np.int32),
                   np.ones((2,), np.int32),
-                  np.zeros((2, engine.n_pages_max), np.int32))
+                  np.zeros((2, engine.n_pages_max), np.int32),
+                  # the tokens of the decode in flight, and each row's
+                  # place in them (-1: the host's token stands)
+                  np.zeros((2,), np.int32), np.full((2,), -1, np.int32))
     return prefill, decode
 
 

@@ -9,6 +9,7 @@ on small streams so the whole file stays well under the tier-1 budget.
 Run the robustness subset alone with ``-m chaos``.
 """
 
+import contextlib
 import json
 import time
 
@@ -413,32 +414,69 @@ class TestDeadlineScheduling:
 # ---------------------------------------------------------------------------
 
 class TestQuarantineRetry:
-    def test_transient_decode_error_retries_to_exact_tokens(self):
+    @pytest.mark.parametrize("step,unread", [(1, 0), (3, 1)], ids=[
+        "first_decode", "behind_a_decode_in_flight"])
+    def test_transient_decode_error_retries_to_exact_tokens(self, step,
+                                                            unread):
+        """At step 1 the failing decode is the stream's first; at step 3
+        the previous decode is still unread when the fault fires: its
+        token is the request's own and lands before the quarantine."""
         eng, cfg, params = _tiny_engine(
             fault_injection={"faults": [
-                {"kind": "decode_error", "step": 3, "times": 1}]},
+                {"kind": "decode_error", "step": step, "times": 1}]},
             retry={"max_attempts": 3, "backoff_base_ms": 1,
                    "backoff_cap_ms": 2, "jitter": 0.0})
         rng = np.random.default_rng(2)
         p = list(rng.integers(1, cfg.vocab_size, size=9))
-        (out,) = eng.generate([p], max_new_tokens=6)
-        assert out == _teacher_forced(cfg, params, p, 6)
-        assert eng.stats["quarantines"] == 1
+        rid = eng.submit(p, max_new_tokens=6)
+        for _ in range(step):
+            eng.step()
+        (req,) = eng.scheduler.running
+        assert req.pending == unread and len(eng._inflight) == unread
+        seen = len(req.generated)
+        eng.step()                               # the fault fires here
+        assert eng.stats["quarantines"] == 1 and not eng._inflight
+        assert len(req.generated) == seen + unread and req.pending == 0
+        eng.run()
+        (done,) = eng.scheduler.pop_finished()
+        assert done.request_id == rid
+        assert done.generated == _teacher_forced(cfg, params, p, 6)
         assert eng.stats["retries"] == 1
         assert eng.stats["requests_failed"] == 0
+        assert eng.stats["lookahead_discarded"] == 0
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
-    def test_transient_prefill_error_retries(self):
+    @pytest.mark.parametrize("step", [0, 3], ids=[
+        "idle_engine", "behind_a_decode_in_flight"])
+    def test_transient_prefill_error_retries(self, step):
+        """At step 3 the failing prefill is the second request's, while
+        the first one's decode is in flight: that one is settled, not
+        lost, and both streams stay exact."""
         eng, cfg, params = _tiny_engine(
             fault_injection={"faults": [
-                {"kind": "prefill_error", "step": 0, "times": 1}]},
+                {"kind": "prefill_error", "step": step, "times": 1}]},
             retry={"max_attempts": 3, "backoff_base_ms": 1,
                    "backoff_cap_ms": 2, "jitter": 0.0})
         rng = np.random.default_rng(3)
-        p = list(rng.integers(1, cfg.vocab_size, size=5))
-        (out,) = eng.generate([p], max_new_tokens=4)
-        assert out == _teacher_forced(cfg, params, p, 4)
+        prompts = [list(rng.integers(1, cfg.vocab_size, size=n))
+                   for n in (5, 11)]
+        ids = [eng.submit(prompts[0], max_new_tokens=8)]
+        for _ in range(step):
+            eng.step()
+        assert len(eng._inflight) == (1 if step else 0)
+        ids.append(eng.submit(prompts[1], max_new_tokens=4))
+        eng.step()                               # the fault fires here
         assert eng.stats["quarantines"] == 1
+        # the first request's decode went on in the same step
+        assert [r.request_id for r in eng.scheduler.running] == \
+            ([0] if step else [])
+        assert len(eng._inflight) == (1 if step else 0)
+        eng.run()
+        done = {r.request_id: r for r in eng.scheduler.pop_finished()}
+        for rid, p, n in zip(ids, prompts, (8, 4)):
+            assert done[rid].generated == _teacher_forced(cfg, params, p, n)
+        assert eng.stats["requests_failed"] == 0
+        assert eng.cache.num_free == eng.cache.num_pages - 1
 
     def test_persistent_failure_poisons_typed(self):
         # `times` counts engine-step serials, not prefill attempts —
@@ -508,12 +546,15 @@ class TestQuarantineRetry:
             assert o == _teacher_forced(cfg, params, p, 8)
         assert eng.stats["requests_failed"] == 0
 
-    def test_mid_execution_cache_loss_recovers(self):
+    @pytest.mark.parametrize("in_flight", [0, 1], ids=[
+        "settled", "a_decode_in_flight"])
+    def test_mid_execution_cache_loss_recovers(self, in_flight):
         """A compiled call that dies MID-EXECUTION consumes the donated
         KV pools: the quarantine path must rebuild them zeroed, evict
         every running sequence, and leave each request in exactly one
         scheduler collection — then everything still completes with the
-        exact greedy continuation (re-prefill from full context)."""
+        exact greedy continuation (re-prefill from full context). With a
+        decode in flight, its tokens are read before the pools go."""
         eng, cfg, params = _tiny_engine(
             retry={"max_attempts": 3, "backoff_base_ms": 1,
                    "backoff_cap_ms": 2, "jitter": 0.0})
@@ -522,14 +563,17 @@ class TestQuarantineRetry:
                    for n in (5, 12)]
         for p in prompts:
             eng.submit(p, max_new_tokens=6)
-        while not eng.scheduler.running:
+        while len(eng.scheduler.running) < 2 or \
+                len(eng._inflight) < in_flight:
             eng.step()
         running = list(eng.scheduler.running)
+        assert len(eng._inflight) == in_flight
         eng.cache.k.delete()                     # simulate the death
         eng.cache.v.delete()
         eng._quarantine_batch([running[0]], RuntimeError("device OOM"),
                               "decode")
         assert not eng.cache.k.is_deleted()      # pools rebuilt
+        assert not eng._inflight and not any(r.pending for r in running)
         for r in running:
             places = sum([r in eng.scheduler.running,
                           r in eng.scheduler.quarantined,
@@ -562,16 +606,16 @@ class TestQuarantineRetry:
         tokens_before = list(r1.generated)
         eng.submit(p2, max_new_tokens=6)
 
-        real = eng._run_prefill
+        real = eng._dispatch_prefill
 
         def dying_prefill(plan):
             eng.cache.k.delete()                 # donated pools consumed
             eng.cache.v.delete()
             raise RuntimeError("mid-execution death")
 
-        eng._run_prefill = dying_prefill
+        eng._dispatch_prefill = dying_prefill
         summary = eng.step()     # prefill dies -> recovery evicts r1
-        eng._run_prefill = real
+        eng._dispatch_prefill = real
         assert summary["decoded"] == 0           # stale decode skipped
         assert list(r1.generated) == tokens_before   # no garbage token
         assert not eng.cache.k.is_deleted()
@@ -665,18 +709,25 @@ class _RecMonitor:
 
 @pytest.mark.elastic
 class TestDrainDeadlineTyped:
-    def test_inflight_failed_typed_and_flushed(self):
+    @pytest.mark.parametrize("steps", [1, 3], ids=[
+        "after_the_prefill", "a_decode_in_flight"])
+    def test_inflight_failed_typed_and_flushed(self, steps):
         mon = _RecMonitor()
         eng, cfg, _ = _tiny_engine(monitor=mon)
         rng = np.random.default_rng(10)
         rid = eng.submit(list(rng.integers(1, cfg.vocab_size, size=6)),
                          max_new_tokens=64)
-        eng.step()
+        for _ in range(steps):
+            eng.step()
+        assert len(eng._inflight) == (steps > 1)
         summary = eng.drain(deadline_s=0.0)
         assert summary["deadline_hit"] is True
         assert summary["inflight_abandoned"] == 1
         (req,) = eng.scheduler.pop_finished()
         assert req.request_id == rid
+        # what was in flight was read before the request was failed
+        assert not eng._inflight and req.pending == 0
+        assert len(req.generated) == steps
         assert req.status == "failed"
         assert isinstance(req.error, DrainAborted)
         assert "drain" in str(req.error)
@@ -700,6 +751,190 @@ class TestDrainDeadlineTyped:
         assert summary["inflight_abandoned"] == 1
         (req,) = eng.scheduler.pop_finished()
         assert isinstance(req.error, DrainAborted)
+
+
+# ---------------------------------------------------------------------------
+# a decode program in flight (the serve loop's one-step lookahead)
+# ---------------------------------------------------------------------------
+
+class TestProgramInFlight:
+    """What happens to a request between the dispatch of its token and
+    the read-back, in the cases the tests above do not already cover
+    with a program in flight."""
+
+    def _midstream(self, **kw):
+        eng, cfg, params = _tiny_engine(**kw)
+        rng = np.random.default_rng(21)
+        prompts = [list(rng.integers(1, cfg.vocab_size, size=n))
+                   for n in (5, 12)]
+        for p in prompts:
+            eng.submit(p, max_new_tokens=8)
+        for _ in range(4):
+            eng.step()
+        reqs = list(eng.scheduler.running)
+        assert len(reqs) == 2 and len(eng._inflight) == 1
+        assert all(r.pending == 1 for r in reqs)
+        return eng, cfg, params, prompts, reqs
+
+    def _finish(self, eng):
+        t0 = time.time()
+        while eng.scheduler.has_work and time.time() - t0 < 30:
+            eng.step()
+        assert not eng._inflight
+        return {r.request_id: r for r in eng.scheduler.pop_finished()}
+
+    def test_evicting_a_row_drops_its_pending_token(self):
+        eng, cfg, params, prompts, reqs = self._midstream()
+        seen = [list(r.generated) for r in reqs]
+        victim = eng.scheduler._evict_victim(time.perf_counter())
+        assert victim is reqs[1] and victim.pending == 0
+        eng.step()       # reads the decode back: the victim's row is dropped
+        assert eng.stats["lookahead_discarded"] == 1
+        # the victim re-prefilled in that step: one token, sampled anew
+        # at the dropped one's position
+        assert len(victim.generated) == len(seen[1]) + 1
+        assert victim.generated[:-1] == seen[1]
+        done = self._finish(eng)
+        for rid, p in enumerate(prompts):
+            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+        assert eng.stats["decode_tokens"] + eng.stats["prefill_requests"] \
+            == 16
+        assert eng.cache.num_free == eng.cache.num_pages - 1
+
+    def test_deadline_expiry_drops_the_pending_token(self):
+        eng, cfg, params, prompts, reqs = self._midstream()
+        seen = list(reqs[0].generated)
+        reqs[0].deadline_ms, reqs[0].deadline_at = 1.0, 0.0   # long past
+        eng.step()
+        assert reqs[0].status == "deadline_exceeded"
+        assert reqs[0].generated == seen and reqs[0].pending == 0
+        assert eng.stats["lookahead_discarded"] == 1
+        done = self._finish(eng)
+        assert done[1].generated == _teacher_forced(cfg, params,
+                                                    prompts[1], 8)
+        assert eng.cache.num_free == eng.cache.num_pages - 1
+
+    def test_drain_finishes_what_is_in_flight(self):
+        eng, cfg, params, prompts, reqs = self._midstream()
+        summary = eng.drain(deadline_s=30.0)
+        assert summary["deadline_hit"] is False
+        assert summary["inflight_abandoned"] == 0
+        assert not eng._inflight
+        done = {r.request_id: r for r in eng.scheduler.pop_finished()}
+        for rid, p in enumerate(prompts):
+            assert done[rid].status == "ok"
+            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+
+    def test_hot_swap_between_steps_settles_first(self):
+        eng, cfg, params, prompts, reqs = self._midstream()
+        seen = [len(r.generated) for r in reqs]
+        out = eng.hot_swap_weights(params)
+        assert out["compile_delta"] == 0
+        assert not eng._inflight
+        assert [len(r.generated) for r in reqs] == [n + 1 for n in seen]
+        assert all(r.pending == 0 for r in reqs)
+        done = self._finish(eng)
+        for rid, p in enumerate(prompts):
+            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+        assert eng.compile_count() == len(eng._compiled)
+
+
+    _RETRY = {"max_attempts": 3, "backoff_base_ms": 1,
+              "backoff_cap_ms": 2, "jitter": 0.0}
+
+    @staticmethod
+    def _fail_readbacks(eng, n):
+        """The next `n` read-backs raise, as a device error does: at the
+        host's read of the program's tokens, not at its dispatch."""
+        real, left = eng._readback, [n]
+
+        def flaky(arr):
+            if left[0]:
+                left[0] -= 1
+                raise RuntimeError("device error at the read-back")
+            return real(arr)
+        eng._readback = flaky
+
+    @pytest.mark.parametrize("fails", [1, 2], ids=[
+        "its_own_read_only", "the_successor_fails_too"])
+    def test_readback_error_quarantines_its_batch_once(self, fails):
+        """Decode n's error surfaces when n+1 is already enqueued behind
+        it. n+1 consumed n's tokens: whether its own read fails too (a
+        real device error) or succeeds, nothing of it is recorded for
+        n's requests, and they are quarantined once."""
+        eng, cfg, params, prompts, reqs = self._midstream(retry=self._RETRY)
+        seen = [list(r.generated) for r in reqs]
+        self._fail_readbacks(eng, fails)
+        eng.step()          # enqueues n+1, then reads n: the error is here
+        assert not eng._inflight
+        assert eng.stats["quarantines"] == 1 and eng.stats["retries"] == 2
+        assert sorted(map(id, eng.scheduler.quarantined)) == \
+            sorted(map(id, reqs))
+        for r, before in zip(reqs, seen):
+            assert (r.failures, r.evictions, r.pending) == (1, 1, 0)
+            assert r.generated == before
+        # n+1's rows: read and dropped, or lost with their program
+        assert eng.stats["lookahead_discarded"] == (2 if fails == 1 else 0)
+        done = self._finish(eng)
+        for rid, p in enumerate(prompts):
+            assert done[rid].status == "ok"
+            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+        assert eng.stats["quarantines"] == 1
+        assert eng.stats["requests_failed"] == 0
+        assert eng.cache.num_free == eng.cache.num_pages - 1
+
+    def test_prefill_readback_error_retries(self):
+        eng, cfg, params = _tiny_engine(retry=self._RETRY)
+        p = list(np.random.default_rng(22).integers(1, cfg.vocab_size, 9))
+        eng.submit(p, max_new_tokens=4)
+        self._fail_readbacks(eng, 1)
+        eng.step()
+        (req,) = eng.scheduler.quarantined
+        assert (req.failures, req.pending, req.generated) == (1, 0, [])
+        assert eng.stats["quarantines"] == 1 and not eng._inflight
+        done = self._finish(eng)
+        assert done[0].generated == _teacher_forced(cfg, params, p, 4)
+        assert eng.cache.num_free == eng.cache.num_pages - 1
+
+    def test_dispatch_error_over_a_failing_readback_is_one_fault(self):
+        """A decode that fails at dispatch settles what is in flight
+        first; when that read fails too, its requests are quarantined
+        there and not a second time for the dispatch."""
+        eng, cfg, params, prompts, reqs = self._midstream(
+            retry=self._RETRY, fault_injection={"faults": [
+                {"kind": "decode_error", "step": 4, "times": 1}]})
+        self._fail_readbacks(eng, 1)
+        eng.step()
+        assert eng.stats["quarantines"] == 1 and eng.stats["retries"] == 2
+        assert len(eng.scheduler.quarantined) == 2
+        assert all((r.failures, r.evictions) == (1, 1) for r in reqs)
+        done = self._finish(eng)
+        for rid, p in enumerate(prompts):
+            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+        assert eng.cache.num_free == eng.cache.num_pages - 1
+
+    def test_failure_path_counts_each_phase_once(self):
+        """The read-backs a failure settles are timed under phases of
+        their own, not inside the dispatch phase that failed."""
+        eng, cfg, params, prompts, reqs = self._midstream(
+            retry=self._RETRY, fault_injection={"faults": [
+                {"kind": "decode_error", "step": 4, "times": 1}]})
+        opened = []
+        real = eng._phase
+
+        @contextlib.contextmanager
+        def spy(name):
+            opened.append(name)
+            try:
+                with real(name):
+                    yield
+            finally:
+                opened.append("/" + name)
+        eng._phase = spy
+        eng.step()
+        assert opened == ["decode", "/decode",           # the dispatch
+                          "decode", "readback", "/readback",
+                          "complete", "/complete", "/decode"]
 
 
 # ---------------------------------------------------------------------------

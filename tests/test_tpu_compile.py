@@ -384,7 +384,10 @@ def test_decode_program_leaves_the_pools_in_place(on_chip, v5e_2x2, kv):
         shape_of(np.zeros((batch,), np.int32)),
         shape_of(np.zeros((batch, engine.n_pages_max), np.int32)),
         pool_of(engine.cache.k), pool_of(engine.cache.v),
-        shape_of(jax.random.PRNGKey(0))).compile()
+        shape_of(jax.random.PRNGKey(0)),
+        # the in-flight decode's tokens and each row's place in them
+        shape_of(np.zeros((batch,), np.int32)),
+        shape_of(np.zeros((batch,), np.int32))).compile()
     text = compiled.as_text()
     for name in ("ds.kv_write", "ds.paged_decode"):
         assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
@@ -442,9 +445,11 @@ def test_moe_serving_programs_leave_the_experts_in_place(on_chip, v5e_2x2,
     def ints(*shape):
         return shape_of(np.zeros(shape, np.int32))
 
+    carry = ()
     if program == "decode":
         fn = engine._decode_fn(batch)
         inputs = (ints(batch), ints(batch), ints(batch, engine.n_pages_max))
+        carry = (ints(batch), ints(batch))   # in-flight tokens, row of each
     else:
         fn = engine._prefill_fn(1, seqlen)
         inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
@@ -452,7 +457,7 @@ def test_moe_serving_programs_leave_the_experts_in_place(on_chip, v5e_2x2,
         jax.tree_util.tree_map(shape_of, engine.params),
         jax.tree_util.tree_map(shape_of, engine.params_stacked), *inputs,
         shape_of(engine.cache.k), shape_of(engine.cache.v),
-        shape_of(jax.random.PRNGKey(0))).compile().as_text()
+        shape_of(jax.random.PRNGKey(0)), *carry).compile().as_text()
     calls = re.findall(r"%ds\.grouped_matmul[.\d]* = .*tpu_custom_call", text)
     assert len(calls) >= 2, "gate-and-up and down: two kernel calls a layer"
     expert_shaped = re.compile(
