@@ -139,6 +139,8 @@ class _Family:
             self.kind = "gpt_neox"
             self._cos, self._sin, self.rot_dim = neox._rotary_cache(
                 self.cfg, max_seq_len)
+            # a planned model's layers rotate by attention kind
+            self._rotary = neox.plan_rotary(self.cfg, max_seq_len)
         elif isinstance(model, gpt2_mod.GPT2):
             self.kind = "gpt2"
             self._cos = jnp.zeros((max_seq_len, 0), jnp.float32)
@@ -151,13 +153,19 @@ class _Family:
         # experts a token of a served (dropless) MoE; 0: a dense model
         self.moe_top_k = self.cfg.moe_top_k \
             if getattr(self.cfg, "moe_dropless", False) else 0
+        # the layers that route (a planned model's `experts` layers), and
+        # whether only a share of the router's experts is held here
+        plan = getattr(self.cfg, "layer_plan", ())
+        self.moe_layers = (sum(1 for s in plan if s.ffn == "experts")
+                           if plan else self.cfg.num_layers)
+        self.moe_held = tuple(getattr(self.cfg, "moe_held", ()))
 
     def moe_buffer_rows(self, tokens):
         """Rows an MoE layer's sorted buffer holds in a program compiled
         for `tokens` token rows (host arithmetic, for `engine.stats`)."""
         from ..moe.layer import dropless_geometry
         return dropless_geometry(tokens, self.moe_top_k,
-                                 self.cfg.moe_num_experts)[0]
+                                 self.cfg.experts_held)[0]
 
     def final_norm(self, params, x):
         """The model's own final norm (its kind rides the config)."""
@@ -188,13 +196,22 @@ class _Family:
             x = x + params["embed"]["wpe"][positions]
         return x
 
-    def cos_sin_prefill(self, seqlen):
-        return (self._cos[:seqlen], self._sin[:seqlen], self.rot_dim)
+    def _table(self, attn):
+        """(cos, sin, rot_dim): the model's, or a planned model's for
+        the layers of attention kind `attn`."""
+        if attn is None:
+            return self._cos, self._sin, self.rot_dim
+        return self._rotary[attn]
 
-    def cos_sin_decode(self, positions):
+    def cos_sin_prefill(self, seqlen, attn=None):
+        cos, sin, rot_dim = self._table(attn)
+        return (cos[:seqlen], sin[:seqlen], rot_dim)
+
+    def cos_sin_decode(self, positions, attn=None):
         """Per-batch rotary rows at `positions` [B] → ([B, 1, rot], ...)."""
-        return (self._cos[positions][:, None, :],
-                self._sin[positions][:, None, :], self.rot_dim)
+        cos, sin, rot_dim = self._table(attn)
+        return (cos[positions][:, None, :], sin[positions][:, None, :],
+                rot_dim)
 
     def cos_sin_at(self, positions):
         """Per-token rotary rows at `positions` [B, S] →
@@ -239,6 +256,12 @@ class InferenceEngine:
         self.model = model
         cfg = model.config
         self._refuse_capacity_routing(cfg, "model")
+        # a planned model (`GPTNeoXConfig.layer_plan`): layers of unequal
+        # shape, run from one parameter stack a layer kind, with a page
+        # pool a cache kind (docs/inference.md "Planned models")
+        self.planned = bool(getattr(cfg, "layer_plan", ()))
+        self.window = cfg.attn_window if self.planned and \
+            cfg.cache_layers("window") else 0
         if getattr(cfg, "attention_engine", "dense") != "dense":
             raise DeepSpeedConfigError(
                 "serving needs attention_engine='dense' (the block-"
@@ -339,6 +362,7 @@ class InferenceEngine:
         # the validated "quantization" block (weights choice): int8
         # block matmul weights at rest (docs/quantization.md)
         self.weight_quant = (quantization or {}).get("weights")
+        self._refuse_unplanned(ip, draft_model)
         if self.weight_quant and self.mp > 1:
             raise DeepSpeedConfigError(
                 "quantization.weights with a model-parallel mesh is "
@@ -363,10 +387,26 @@ class InferenceEngine:
 
         # -- cache / scheduler ---------------------------------------------
         self.family = _Family(model, self.max_seq_len)
+        # page pools by layer kind. `cache`: what a full-attention layer
+        # keeps, a sequence's whole context (`num_pages`; every layer of
+        # a homogeneous model). `window_cache`: what a window layer
+        # keeps, at most window / page + 1 pages a sequence, so the pool
+        # is sized for `max_batch_size` of those and the scheduler gives
+        # the rest back as a sequence grows
+        kv_heads = getattr(cfg, "kv_heads", cfg.num_heads)
+        n_window = cfg.cache_layers("window") if self.planned else 0
         self.cache = PagedKVCache(
-            num_layers=cfg.num_layers, num_pages=ip["num_pages"],
-            num_heads=cfg.num_heads, page_size=self.page_size,
+            num_layers=cfg.num_layers - n_window, num_pages=ip["num_pages"],
+            num_heads=kv_heads, page_size=self.page_size,
             head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh)
+        self.window_cache = None
+        if n_window:
+            self.window_cache = PagedKVCache(
+                num_layers=n_window,
+                num_pages=self.max_batch_size *
+                (self.window // self.page_size + 1) + 1,
+                num_heads=kv_heads, page_size=self.page_size,
+                head_dim=cfg.head_dim, dtype=self.kv_cache_dtype)
         # -- prefix/radix cache + speculative decoding (both default-off:
         #    without their config sub-blocks the engine is bit-identical
         #    to the plain PR 8 serving loop) --------------------------------
@@ -438,7 +478,8 @@ class InferenceEngine:
             prefill_lengths=self.prefill_lengths,
             prefill_batch_sizes=self.prefill_batch_sizes,
             decode_batch_sizes=self.decode_batch_sizes,
-            prefix_cache=self.prefix_cache, spec_tokens=self.spec_k)
+            prefix_cache=self.prefix_cache, spec_tokens=self.spec_k,
+            window_cache=self.window_cache, window=self.window)
         self.n_pages_max = pages_for_tokens(self.max_seq_len,
                                             self.page_size)
         # precision identity of this serving engine
@@ -473,6 +514,11 @@ class InferenceEngine:
         self._inflight = deque()
         self._dispatched = itertools.count()
         self._carry_width = max(self.decode_batch_sizes)
+        # a model that holds a share of its experts counts, on the
+        # device, the (token, choice) pairs that fell on a held expert:
+        # one more entry behind every program's tokens, read back with
+        # them
+        self._counts_held = bool(self.family.moe_held)
         self._carry = self._zero_carry()
         self.stats = {"steps": 0, "prefill_requests": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
@@ -491,6 +537,18 @@ class InferenceEngine:
                       "build_inputs_s": 0.0, "dispatch_s": 0.0,
                       "readback_s": 0.0, "complete_s": 0.0,
                       "decode_kv_tokens": 0,
+                      # the same for the window layers alone (a row
+                      # attends over at most the window there), and the
+                      # pages that held a decode step's context, by cache
+                      # kind, summed over steps; pages the window kind
+                      # gave back while its sequence ran
+                      "decode_kv_tokens_window": 0,
+                      "kv_page_steps_full": 0, "kv_page_steps_window": 0,
+                      "window_pages_released": 0,
+                      # (token, choice) pairs the routers kept, all
+                      # layers, and those that fell on an expert held
+                      # here (all of them unless the model holds a share)
+                      "moe_rows_routed": 0, "moe_rows_held": 0,
                       # (token, expert) rows the MoE layers routed, all
                       # layers together, and the rows their buffers held
                       # with padding (0 for a dense model)
@@ -588,6 +646,40 @@ class InferenceEngine:
                 if hook is not None:
                     hook({"role": self.role, "host": self.pool_id})
 
+    def _refuse_unplanned(self, ip, draft_model):
+        """What a planned model (or its window cache kind) does not do
+        yet, refused by name."""
+        if not self.planned:
+            return
+        what = None
+        if self.mp > 1:
+            what = ("a model-parallel mesh (mp > 1): the kind stacks and "
+                    "the grouped KV heads have no tensor-parallel "
+                    "placement")
+        elif self.weight_quant:
+            what = ("quantization.weights: the int8 surgery knows the "
+                    "homogeneous `blocks` layout")
+        elif ip["prefix_cache"] is not None:
+            what = ("inference.prefix_cache: a shared prefix page has no "
+                    "counterpart in a window pool, whose pages behind the "
+                    "window are gone, and the chunk program scans one "
+                    "homogeneous stack")
+        elif ip["speculative"] is not None or draft_model is not None:
+            what = ("inference.speculative: the verify chunk and the "
+                    "rollback of rejected pages know one pool and one "
+                    "homogeneous stack")
+        elif ip["disaggregation"]["role"] != "unified":
+            what = ("handoff between pools (disaggregation.role != "
+                    "'unified'): the page payload carries one pool's "
+                    "pages")
+        elif self.window and self.kv_quant:
+            what = ("kv_cache_dtype int8 with a window cache kind: the "
+                    "paged kernel's window has no int8 variant")
+        if what:
+            raise DeepSpeedConfigError(
+                f"serving a planned model (layer_plan) with {what} is "
+                f"not built")
+
     @staticmethod
     def _refuse_capacity_routing(cfg, what):
         if getattr(cfg, "moe_num_experts", 0) and \
@@ -619,6 +711,13 @@ class InferenceEngine:
         compiled step would materialize a full copy of the block
         params every call (params are runtime jit inputs — XLA cannot
         hoist the stack out)."""
+        if self.planned:
+            # the model's own tree IS the layout the programs run from
+            # (one stack a layer kind): taken by reference, no copy, so
+            # the weights live on the device once
+            self.params = params
+            self.params_stacked = params["stacks"]
+            return
         self.params = self._place_params(params)
         stacked = self._stacked_blocks(self.params)
         if self.mp > 1:
@@ -719,7 +818,7 @@ class InferenceEngine:
         return jax.random.categorical(
             rng, logits / self.temperature, axis=-1).astype(jnp.int32)
 
-    def _attention(self, q, pools, layer, page_table, lengths):
+    def _attention(self, q, pools, layer, page_table, lengths, window=None):
         """Paged decode attention over layer `layer` of the stacked
         (K, V) `pools`, shard_mapped over the model axis when the mesh
         shards heads (attention is head-independent, so each shard runs
@@ -737,7 +836,7 @@ class InferenceEngine:
                 (k, v), scales = leaves, {}
             return paged_decode_attention(
                 q, k, v, pt, ln, backend=self._attn_backend, layer=layer,
-                **scales)
+                window=window, **scales)
 
         if self.mp > 1:
             attend = shard_map(
@@ -825,6 +924,127 @@ class InferenceEngine:
         return carry
 
     @staticmethod
+    def _run_xs(stack, at, n):
+        """What a layer loop over layers [at, at + n) of one kind's
+        `stack` takes: (the leaves a layer is sliced out of, the function
+        that makes layer i's block params). The experts stay whole: the
+        grouped-matmul kernel indexes the layer (`LayerOf`)."""
+        from ..ops.pallas.grouped_matmul import LayerOf
+        whole = {k: v for k, v in stack["mlp"].items()
+                 if k in ("w_in", "w_out")}
+        sliced = dict(stack, mlp={k: v for k, v in stack["mlp"].items()
+                                  if k not in whole})
+        if (at, n) != (0, jax.tree_util.tree_leaves(sliced)[0].shape[0]):
+            sliced = jax.tree_util.tree_map(lambda a: a[at:at + n], sliced)
+
+        def layer_of(bp, i):
+            experts = {k: LayerOf(v, jnp.asarray(at + i, jnp.int32))
+                       for k, v in whole.items()}
+            return dict(bp, mlp=dict(bp["mlp"], **experts))
+
+        return sliced, layer_of
+
+    def _plan_layers(self, cfg, stacks, carry, layer_fn):
+        """A planned model's layer loop: `layer_fn(carry, bp, spec,
+        cache_layer) -> (carry, ys)` over the plan, a run of consecutive
+        layers of one kind at a time (a scan where the run is longer than
+        one layer). `cache_layer` is the layer's index in its cache
+        kind's pools. Returns (carry, [(spec, ys stacked over the run)])."""
+        cache_at, out = {"full": 0, "window": 0}, []
+        for spec, _, at, n in cfg.plan_runs():
+            base = cache_at[spec.attn]
+            cache_at[spec.attn] += n
+            xs, layer_of = self._run_xs(stacks[spec.kind], at, n)
+
+            def body(carry, x, spec=spec, base=base, layer_of=layer_of):
+                bp, i = x
+                return layer_fn(carry, layer_of(bp, i), spec, base + i)
+
+            if n == 1:
+                carry, ys = body(carry, (jax.tree_util.tree_map(
+                    lambda a: a[0], xs), 0))
+                ys = jax.tree_util.tree_map(lambda y: y[None], ys)
+            else:
+                carry, ys = jax.lax.scan(
+                    body, carry, (xs, jnp.arange(n, dtype=jnp.int32)))
+            out.append((spec, ys))
+        return carry, out
+
+    @staticmethod
+    def _held_rows(cfg, block_out):
+        """(hidden states, the (token, choice) pairs of this layer that
+        fell on a held expert) of a block's return."""
+        if isinstance(block_out, tuple) and cfg.moe_held:
+            lo, hi = cfg.moe_held
+            return block_out[0], jnp.sum(block_out[1][2, lo:hi])
+        return neox.block_hidden(block_out), jnp.zeros((), jnp.float32)
+
+    def _plan_token_layers(self, cfg, stacks, x, pos, pools, tables,
+                           lengths):
+        """`_token_layers` of a planned model: `pools` and `tables` are
+        {cache kind: (K, V) pools} and {cache kind: page table}; a window
+        layer writes and attends in the window kind's. Returns (x, pools,
+        held pairs)."""
+        fam, ps = self.family, self.page_size
+        B = x.shape[0]
+        active = (lengths > 0)[:, None]
+        kinds = list(pools)
+        rot = {k: fam.cos_sin_decode(pos, k) for k in kinds}
+        page_idx = {k: jnp.take_along_axis(
+            tables[k], (pos // ps)[:, None], axis=1)[:, 0] for k in kinds}
+        slot = pos % ps
+
+        @scopes.scoped("ds.block")
+        def layer(carry, bp, spec, cache_layer):
+            x, pools, held = carry
+            kind = spec.attn
+            q, k, v = neox._block_qkv(cfg, bp, x, *rot[kind], spec.heads)
+            kv = self._write_rows(pools[kind], k[:, 0], v[:, 0],
+                                  cache_layer, page_idx[kind], slot)
+            with scopes.scope("ds.attn"):
+                attn = self._attention(
+                    q[:, 0].astype(kv[0].dtype), kv, cache_layer,
+                    tables[kind], lengths,
+                    window=self.window if kind == "window" else None
+                ).astype(x.dtype)
+            out, rows = self._held_rows(cfg, neox._block_post_attn(
+                cfg, bp, x, attn.reshape(B, 1, -1),
+                reduce_fn=lambda t: t, token_mask=active))
+            return (out, dict(pools, **{kind: kv}), held + rows), None
+
+        with scopes.scope("ds.layers"):
+            carry, _ = self._plan_layers(
+                cfg, stacks, (x, pools, jnp.zeros((), jnp.float32)), layer)
+        return carry
+
+    def _with_held(self, tokens, held):
+        """A program's tokens, and behind them the count of held pairs
+        where the model holds a share of its experts."""
+        if not self._counts_held:
+            return tokens
+        return jnp.concatenate([tokens, held.astype(jnp.int32)[None]])
+
+    def _kind_pools(self, k_pool, v_pool):
+        """{cache kind: (K, V)} of the programs' pool arguments (a pair of
+        pools, or with a window kind a pair of (full, window) pairs)."""
+        if self.window_cache is None:
+            return {"full": (k_pool, v_pool)}
+        return {"full": (k_pool[0], v_pool[0]),
+                "window": (k_pool[1], v_pool[1])}
+
+    def _pool_args(self, pools):
+        """The inverse of `_kind_pools`: (k_pool, v_pool)."""
+        if self.window_cache is None:
+            return pools["full"]
+        return ((pools["full"][0], pools["window"][0]),
+                (pools["full"][1], pools["window"][1]))
+
+    def _kind_tables(self, page_table):
+        if self.window_cache is None:
+            return {"full": page_table}
+        return {"full": page_table[0], "window": page_table[1]}
+
+    @staticmethod
     def _stacked_blocks(params):
         return jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *params["blocks"])
@@ -862,6 +1082,22 @@ class InferenceEngine:
         n_pages_row = seqlen // ps
         cos_sin = fam.cos_sin_prefill(seqlen)
 
+        def first_token(params, x, lengths, rng):
+            """The token sampled at each row's last real position."""
+            B, S = x.shape[:2]
+            idx = jnp.clip(lengths - 1, 0, S - 1)
+            h_last = x[jnp.arange(B), idx][:, None, :]
+            h_last = fam.final_norm(params, h_last)
+            return self._sample(fam.head(params, h_last[:, 0]), rng)
+
+        def page_tiles(new, heads, head_dim):
+            """[B, S, H, D] -> the B * S / ps page tiles [H, ps, D] of a
+            whole-page scatter, rows in page-table order."""
+            B = new.shape[0]
+            tiles = new.reshape(B, n_pages_row, ps, heads, head_dim)
+            tiles = jnp.moveaxis(tiles, 3, 2)
+            return tiles.reshape(B * n_pages_row, heads, ps, head_dim)
+
         def prefill(params, stacked, tokens, lengths, page_table, k_pool,
                     v_pool, rng):
             B, S = tokens.shape
@@ -891,9 +1127,7 @@ class InferenceEngine:
             H, D = cfg.num_heads, cfg.head_dim
 
             def write(pool, new):
-                tiles = new.reshape(B, n_pages_row, ps, H, D)
-                tiles = jnp.moveaxis(tiles, 3, 2)
-                tiles = tiles.reshape(B * n_pages_row, H, ps, D)
+                tiles = page_tiles(new, H, D)
                 if isinstance(pool, QuantizedPages):
                     # int8 pages: quantize each (head, slot) vector and
                     # scatter data + scale through the same page ids
@@ -908,13 +1142,58 @@ class InferenceEngine:
                 k_pool = jax.vmap(write)(k_pool, ks)
                 v_pool = jax.vmap(write)(v_pool, vs)
 
-            idx = jnp.clip(lengths - 1, 0, S - 1)
-            h_last = x[jnp.arange(B), idx][:, None, :]
-            h_last = fam.final_norm(params, h_last)
-            logits = fam.head(params, h_last[:, 0])
-            return self._sample(logits, rng), k_pool, v_pool
+            return first_token(params, x, lengths, rng), k_pool, v_pool
 
-        fn = jax.jit(prefill, donate_argnums=(5, 6))
+        def planned_prefill(params, stacks, tokens, lengths, page_table,
+                            k_pool, v_pool, rng):
+            """The same program for a planned model: the layers a run of
+            one kind at a time, each cache kind's K/V scattered into its
+            own pools through its own page table (a window layer's pages
+            behind the window are table entry 0, the trash page)."""
+            B, S = tokens.shape
+            pos = jnp.arange(S, dtype=jnp.int32)[None, :]
+            seg = (pos < lengths[:, None]).astype(jnp.int32)
+            x = fam.embed_prefill(params, tokens)
+            pools = self._kind_pools(k_pool, v_pool)
+            tables = self._kind_tables(page_table)
+            rot = {k: fam.cos_sin_prefill(S, k) for k in pools}
+
+            def layer(carry, bp, spec, cache_layer):
+                x, held = carry
+                y, kv = neox._block_core(
+                    cfg, bp, x, rot[spec.attn], use_pallas, mp=1,
+                    reduce_fn=lambda t: t, return_kv=True,
+                    segment_ids=seg, spec=spec)
+                out, rows = self._held_rows(cfg, y)
+                return (out, held + rows), kv
+
+            with scopes.scope("ds.layers"):
+                (x, held), runs = self._plan_layers(
+                    cfg, stacks, (x, jnp.zeros((), jnp.float32)), layer)
+
+            G, D = cfg.kv_heads, cfg.head_dim
+
+            def write(pool, new, flat_pt):
+                return pool.at[flat_pt].set(
+                    page_tiles(new, G, D).astype(pool.dtype))
+
+            with scopes.scope("ds.kv_write"):
+                for kind, (kp, vp) in list(pools.items()):
+                    # the kind's layers in order: [L_kind, B, S, G, D]
+                    ks = jnp.concatenate([kv[0] for spec, kv in runs
+                                          if spec.attn == kind])
+                    vs = jnp.concatenate([kv[1] for spec, kv in runs
+                                          if spec.attn == kind])
+                    flat_pt = tables[kind].reshape(-1)
+                    scatter = jax.vmap(write, in_axes=(0, 0, None))
+                    pools[kind] = (scatter(kp, ks, flat_pt),
+                                   scatter(vp, vs, flat_pt))
+
+            return (self._with_held(first_token(params, x, lengths, rng),
+                                    held), *self._pool_args(pools))
+
+        fn = jax.jit(planned_prefill if self.planned else prefill,
+                     donate_argnums=(5, 6))
         self._compiled[key] = fn
         return fn
 
@@ -953,7 +1232,23 @@ class InferenceEngine:
             nxt = jnp.pad(self._sample(logits, rng), (0, width - batch))
             return nxt, k_pool, v_pool
 
-        fn = jax.jit(decode, donate_argnums=(5, 6))
+        def planned_decode(params, stacks, tokens, lengths, page_table,
+                           k_pool, v_pool, rng, carried, src):
+            """The same step for a planned model (`_plan_token_layers`)."""
+            tokens = jnp.where(src >= 0, carried[jnp.maximum(src, 0)],
+                               tokens)
+            pos = jnp.maximum(lengths - 1, 0)
+            x = fam.embed_decode(params, tokens, pos)
+            x, pools, held = self._plan_token_layers(
+                cfg, stacks, x, pos, self._kind_pools(k_pool, v_pool),
+                self._kind_tables(page_table), lengths)
+            h = fam.final_norm(params, x)
+            logits = fam.head(params, h[:, 0])
+            nxt = jnp.pad(self._sample(logits, rng), (0, width - batch))
+            return (self._with_held(nxt, held), *self._pool_args(pools))
+
+        fn = jax.jit(planned_decode if self.planned else decode,
+                     donate_argnums=(5, 6))
         self._compiled[key] = fn
         return fn
 
@@ -1401,6 +1696,8 @@ class InferenceEngine:
         engine stats (``shed`` is engine-owned: shed requests never
         enter the scheduler)."""
         sc = self.scheduler.status_counts
+        self.stats["window_pages_released"] = \
+            self.scheduler.window_pages_released
         self.stats["requests_ok"] = sc["ok"]
         self.stats["requests_deadline_exceeded"] = sc["deadline_exceeded"]
         self.stats["requests_failed"] = sc["failed"]
@@ -1518,6 +1815,8 @@ class InferenceEngine:
             "— rebuilding zeroed pools and re-prefilling every running "
             "sequence")
         self.cache.reset_pools()
+        if self.window_cache is not None:
+            self.window_cache.reset_pools()
         if self.draft_cache is not None:
             # the draft pools ride the same compiled calls (donated):
             # assume them consumed too and rebuild — the re-prefills
@@ -1879,9 +2178,12 @@ class InferenceEngine:
         compiled for `program_tokens` token rows."""
         fam = self.family
         if fam.moe_top_k:
-            layers = fam.cfg.num_layers
-            self.stats[f"moe_rows_{phase}"] += \
-                tokens * fam.moe_top_k * layers
+            layers = fam.moe_layers
+            routed = tokens * fam.moe_top_k * layers
+            self.stats[f"moe_rows_{phase}"] += routed
+            self.stats["moe_rows_routed"] += routed
+            if not self._counts_held:
+                self.stats["moe_rows_held"] += routed   # every expert here
             self.stats["moe_buffer_rows"] += \
                 fam.moe_buffer_rows(program_tokens) * layers
 
@@ -1904,19 +2206,23 @@ class InferenceEngine:
                 tokens = np.zeros((B, S), np.int32)
                 lengths = np.zeros((B,), np.int32)
                 page_table = np.zeros((B, n_pages_row), np.int32)
+                window_table = np.zeros((B, n_pages_row), np.int32)
                 for i, req in enumerate(plan.prefills):
                     ctx = req.context
                     tokens[i, :len(ctx)] = ctx
                     lengths[i] = len(ctx)
                     page_table[i, :len(req.pages)] = req.pages
+                    in_bucket = req.window_pages[:n_pages_row]
+                    window_table[i, :len(in_bucket)] = in_bucket
                 self._count_moe_rows("prefill", int(lengths.sum()), B * S)
-                args = [jnp.asarray(a)
-                        for a in (tokens, lengths, page_table)]
+                args = [jnp.asarray(tokens), jnp.asarray(lengths),
+                        self._table_args(page_table, window_table)]
             fn = self._prefill_fn(B, S)
         with self._phase("dispatch"):
-            nxt, self.cache.k, self.cache.v = fn(
-                self.params, self.params_stacked, *args, self.cache.k,
-                self.cache.v, self._next_rng())
+            nxt, *pools = fn(
+                self.params, self.params_stacked, *args, *self._pools(),
+                self._next_rng())
+            self._rebind_pools(*pools)
         self._enqueued("prefill", plan.prefills, nxt)
         if self.spec_k:
             self._draft_prefill_twin(plan.prefills, B, S)
@@ -1935,6 +2241,7 @@ class InferenceEngine:
             src = np.full((B,), -1, np.int32)
             lengths = np.zeros((B,), np.int32)
             page_table = np.zeros((B, self.n_pages_max), np.int32)
+            window_table = np.zeros((B, self.n_pages_max), np.int32)
             prev_row = {id(r): i for i, r in enumerate(prev.reqs)} \
                 if prev else {}
             for i, req in enumerate(plan.decodes):
@@ -1944,22 +2251,53 @@ class InferenceEngine:
                     tokens[i] = req.generated[-1]
                 lengths[i] = req.cached + req.pending + 1
                 page_table[i, :len(req.pages)] = req.pages
+                window_table[i, :len(req.window_pages)] = req.window_pages
             self.stats["decode_kv_tokens"] += int(lengths.sum())
+            self.stats["kv_page_steps_full"] += int(
+                (-(-lengths // self.page_size)).sum())
+            if self.window:
+                self.stats["decode_kv_tokens_window"] += int(
+                    np.minimum(lengths, self.window).sum())
+                self.stats["kv_page_steps_window"] += int(
+                    np.count_nonzero(window_table))
             self._count_moe_rows("decode", len(plan.decodes), B)
-            args = [jnp.asarray(a) for a in (tokens, lengths, page_table)]
+            args = [jnp.asarray(tokens), jnp.asarray(lengths),
+                    self._table_args(page_table, window_table)]
             src = jnp.asarray(src)
         fn = self._decode_fn(B)
         with self._phase("dispatch"):
-            nxt, self.cache.k, self.cache.v = fn(
-                self.params, self.params_stacked, *args, self.cache.k,
-                self.cache.v, self._next_rng(), self._carry, src)
+            nxt, *pools = fn(
+                self.params, self.params_stacked, *args, *self._pools(),
+                self._next_rng(), self._carry, src)
+            self._rebind_pools(*pools)
         if prev is not None:
             self.stats["lookahead_steps"] += 1
         self._carry = nxt
         return self._enqueued("decode", plan.decodes, nxt)
 
     def _zero_carry(self):
-        return jnp.asarray(np.zeros((self._carry_width,), np.int32))
+        return jnp.asarray(np.zeros(
+            (self._carry_width + int(self._counts_held),), np.int32))
+
+    def _pools(self):
+        """(k_pool, v_pool) as the programs take them: the full kind's
+        pools, or with a window kind (full, window) pairs."""
+        if self.window_cache is None:
+            return self.cache.k, self.cache.v
+        return ((self.cache.k, self.window_cache.k),
+                (self.cache.v, self.window_cache.v))
+
+    def _rebind_pools(self, k_pool, v_pool):
+        if self.window_cache is None:
+            self.cache.k, self.cache.v = k_pool, v_pool
+        else:
+            (self.cache.k, self.window_cache.k) = k_pool
+            (self.cache.v, self.window_cache.v) = v_pool
+
+    def _table_args(self, page_table, window_table):
+        if self.window_cache is None:
+            return jnp.asarray(page_table)
+        return (jnp.asarray(page_table), jnp.asarray(window_table))
 
     def _enqueued(self, phase, reqs, tokens):
         rec = _InFlight(next(self._dispatched), phase, list(reqs), tokens)
@@ -1991,6 +2329,10 @@ class InferenceEngine:
             except Exception as e:  # noqa: BLE001 - the device error is here
                 failure = e
             else:
+                if self._counts_held:
+                    # the program's count of pairs on a held expert rides
+                    # behind its tokens: the same read-back
+                    self.stats["moe_rows_held"] += int(nxt[-1])
                 with self._phase("complete"):
                     if rec.phase == "prefill":
                         self._complete_prefills(rec, nxt, now)

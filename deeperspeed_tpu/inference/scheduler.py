@@ -105,6 +105,12 @@ class Request:
     generated: list = field(default_factory=list)
     pages: list = field(default_factory=list)
     cached: int = 0          # tokens whose K/V sit in `pages`
+    # the WINDOW cache kind's pages (a model with window layers; else
+    # empty): entry i is the page of that kind's pool that holds context
+    # tokens [i*ps, (i+1)*ps), or 0 where the sequence holds none there:
+    # behind the window (never taken at a prefill, given back as the
+    # sequence grows)
+    window_pages: list = field(default_factory=list)
     # the dispatched programs that owe this request a token the host has
     # not read yet, by the engine's dispatch serial (the serve loop's
     # one-step lookahead, docs/inference.md): none or one whenever the
@@ -207,8 +213,24 @@ class ContinuousBatchingScheduler:
 
     def __init__(self, cache, max_seq_len, token_budget, max_batch_size,
                  prefill_lengths, prefill_batch_sizes, decode_batch_sizes,
-                 prefix_cache=None, spec_tokens=0):
+                 prefix_cache=None, spec_tokens=0, window_cache=None,
+                 window=0):
         self.cache = cache
+        # page pools by layer kind: `cache` holds what a FULL layer
+        # keeps, a sequence's whole context; `window_cache` (a model with
+        # window layers) what a WINDOW layer keeps, the pages that hold
+        # its last `window` positions: at most window / page + 1 a
+        # sequence, the rest returned as it grows
+        self.window_cache = window_cache
+        self.window = int(window)
+        self.window_pages_released = 0
+        if window_cache is not None and (
+                self.window < 1 or prefix_cache is not None or spec_tokens
+                or window_cache.page_size != cache.page_size):
+            raise ValueError(
+                "a window cache kind needs a window, the full kind's page "
+                "size, and neither a prefix cache nor speculation (a "
+                "shared or rolled-back page has no window counterpart)")
         self.page_size = cache.page_size
         self.max_seq_len = int(max_seq_len)
         self.token_budget = int(token_budget)
@@ -400,6 +422,40 @@ class ContinuousBatchingScheduler:
         request.pages = []
         request.n_shared = 0
         request.prefix_node = None
+        if request.window_pages:
+            self.window_cache.free([p for p in request.window_pages if p])
+            request.window_pages = []
+
+    # -- the window cache kind ---------------------------------------------
+
+    def _window_first(self, pos):
+        """The first page a window layer's decode at position `pos`
+        reads: the one that holds position pos - window + 1."""
+        return max(0, pos - self.window + 1) // self.page_size
+
+    def _allocate_window(self, n_context):
+        """The window kind's pages for a prefill of `n_context` tokens:
+        those the NEXT decode (position `n_context`) reads or writes,
+        from the window's first page to the one of that position. What
+        lies behind the window is never taken: the prefill's scatter
+        sends it to the trash page. None if the pool cannot."""
+        last = min(n_context // self.page_size,
+                   self.max_seq_len // self.page_size - 1)
+        first = self._window_first(n_context)
+        got = self.window_cache.allocate(last + 1 - first)
+        return None if got is None else [0] * first + got
+
+    def _release_behind_window(self, req, pos):
+        """Give back the window kind's pages no decode at or after
+        position `pos` reads. The decode still in flight read them
+        through the table it was dispatched with, and whatever takes the
+        page next is enqueued behind it."""
+        first = min(self._window_first(pos), len(req.window_pages))
+        behind = [p for p in req.window_pages[:first] if p]
+        if behind:
+            self.window_cache.free(behind)
+            req.window_pages[:first] = [0] * first
+            self.window_pages_released += len(behind)
 
     def _finish(self, request, status, error=None):
         """The ONLY exit gate: pull the request out of whatever
@@ -579,7 +635,7 @@ class ContinuousBatchingScheduler:
         the pool math guarantees its page fits or the config was
         rejected at engine init."""
         for req in list(self.running):
-            if req not in self.running:           # evicted by an earlier turn
+            if any(v is req for v in evicted):    # evicted by an earlier turn
                 continue
             if req.last_token_pending(self.max_seq_len):
                 continue                          # takes no further step
@@ -587,20 +643,33 @@ class ContinuousBatchingScheduler:
             # verify writes the full window before acceptance); a token
             # in flight has its slot already
             pos = req.cached + req.pending + self._spec_window(req)
-            page_idx = pos // self.page_size
-            while page_idx >= len(req.pages):
-                got = self.cache.allocate(1)
-                if got is not None:
-                    req.pages.extend(got)
-                    continue
-                victim = self._evict_youngest(now)
-                if victim is None:
-                    raise RuntimeError(
-                        "page pool exhausted with nothing left to evict "
-                        "— num_pages is too small for max_seq_len")
-                evicted.append(victim)
-                if victim is req:                 # req evicted itself
-                    break
+            if not self._grow_pages(req, self.cache, req.pages, pos,
+                                    evicted, now):
+                continue
+            if self.window_cache is not None:
+                self._release_behind_window(req, pos)
+                self._grow_pages(req, self.window_cache, req.window_pages,
+                                 pos, evicted, now)
+
+    def _grow_pages(self, req, cache, pages, pos, evicted, now):
+        """Extend `pages` (one cache kind's list of `req`) to hold
+        position `pos`, evicting youngest-first into `evicted` while the
+        kind's pool is dry. False: `req` evicted itself (its page lists
+        were replaced and it left `running`)."""
+        while pos // self.page_size >= len(pages):
+            got = cache.allocate(1)
+            if got is not None:
+                pages.extend(got)
+                continue
+            victim = self._evict_youngest(now)
+            if victim is None:
+                raise RuntimeError(
+                    "page pool exhausted with nothing left to evict "
+                    "— num_pages is too small for max_seq_len")
+            evicted.append(victim)
+            if victim is req:
+                return False
+        return True
 
     def schedule(self, now=None):
         """Build this step's `StepPlan` (see the module docstring for
@@ -672,6 +741,12 @@ class ContinuousBatchingScheduler:
                                                          self.page_size))
             if pages is None:
                 break                      # pool full: wait for completions
+            if self.window_cache is not None:
+                req.window_pages = self._allocate_window(len(req.context))
+                if req.window_pages is None:
+                    req.window_pages = []  # both kinds or neither
+                    self.cache.free(pages)
+                    break
             budget -= row_len
             step_len = row_len
             step_kind = req_kind

@@ -37,6 +37,30 @@ from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, per_shard
 
 
 @dataclass(frozen=True)
+class LayerSpec:
+    """One layer of a `GPTNeoXConfig.layer_plan`: what its attention sees
+    (`full`: every earlier position; `window`: the last
+    `GPTNeoXConfig.attn_window`, which is also its KV cache kind), its
+    query heads (the KV heads and the head dim are the model's), its
+    rotary facts, and its FFN (`dense`: one gated MLP of width
+    `ffn_width`; `experts`: the routed experts of width
+    `moe_expert_width`, with the shared expert where the model has one).
+    `rope` is () for plain rotary, or ("yarn", factor, original_max,
+    beta_fast, beta_slow, attention_factor)."""
+    attn: str = "full"
+    heads: int = 0
+    rotary_pct: float = 1.0
+    rotary_base: float = 10000.0
+    rope: tuple = ()
+    ffn: str = "dense"
+
+    @property
+    def kind(self):
+        """The name of the parameter stack that holds such layers."""
+        return f"{self.attn}{self.heads}.{self.ffn}"
+
+
+@dataclass(frozen=True)
 class GPTNeoXConfig:
     vocab_size: int = 50304
     hidden_size: int = 768
@@ -114,16 +138,115 @@ class GPTNeoXConfig:
     ffn_quant_recipe: object = None
     ffn_quant_margin: float = 1.0
     ffn_quant_history: int = 16
+    # A PLANNED model: its layers are not one kind repeated. `layer_plan`
+    # names each layer's kind (a `LayerSpec` a layer; empty: the
+    # homogeneous block above, `num_layers` times), and the facts below
+    # are what such architectures fix beside it (again a family file's,
+    # from the published `config.json`; a value the code does not compute
+    # raises by name in `check_block`):
+    layer_plan: tuple = ()
+    # the head dim where it is not `hidden_size / num_heads`,
+    attn_head_dim: int = 0
+    # KV heads under the query heads (0: one a query head),
+    num_kv_heads: int = 0
+    # the window of a `window` layer,
+    attn_window: int = 0
+    # a gate on the attention output before the output projection
+    # ("none" | "per-head": sigmoid(a Wg), one scalar a head and token,
+    # from the normed input `a`),
+    attn_gate: str = "none"
+    # how the router scores ("softmax": over all experts, float32),
+    moe_router_score: str = "softmax"
+    # the width of ONE expert where `ffn_width` is a dense layer's (0:
+    # `intermediate_size`), a shared expert's width (0: none; it is not
+    # gated by the router), the factor on the routed experts' sum,
+    moe_expert_width: int = 0
+    moe_shared_width: int = 0
+    moe_routing_scale: float = 1.0
+    # and WHICH experts are held here: (first, past-the-last) of the
+    # `moe_num_experts` the router scores; () holds them all. The layer
+    # computes the held experts' part of the result; what the others
+    # would have added is left out (one chip's share of an
+    # expert-parallel deployment, without its exchange).
+    moe_held: tuple = ()
 
     @property
     def head_dim(self):
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def kv_heads(self):
+        return self.num_kv_heads or self.num_heads
 
     @property
     def intermediate_size(self):
         return self.ffn_width or self.intermediate_mult * self.hidden_size
 
-    def num_params(self):
+    @property
+    def expert_width(self):
+        return self.moe_expert_width or self.intermediate_size
+
+    @property
+    def experts_held(self):
+        """How many of the router's experts this model holds."""
+        lo, hi = self.moe_held or (0, self.moe_num_experts)
+        return hi - lo
+
+    def plan_kinds(self):
+        """The plan's parameter stacks in order of first appearance:
+        {kind name: (its LayerSpec, the layers of that kind)}."""
+        kinds = {}
+        for i, spec in enumerate(self.layer_plan):
+            kinds.setdefault(spec.kind, (spec, []))[1].append(i)
+        return kinds
+
+    def plan_runs(self):
+        """The plan as runs of consecutive layers of one kind:
+        [(spec, first layer, its index within the kind's stack, length)]:
+        what a layer loop scans at a time."""
+        seen, runs = {}, []
+        for i, spec in enumerate(self.layer_plan):
+            at = seen.get(spec.kind, 0)
+            seen[spec.kind] = at + 1
+            if runs and runs[-1][0] == spec:
+                runs[-1][3] += 1
+            else:
+                runs.append([spec, i, at, 1])
+        return [tuple(r) for r in runs]
+
+    def cache_layers(self, attn):
+        """How many layers keep a KV cache of kind `attn`
+        ("full" | "window"); a homogeneous model's are all "full"."""
+        if not self.layer_plan:
+            return self.num_layers if attn == "full" else 0
+        return sum(1 for s in self.layer_plan if s.attn == attn)
+
+    def _planned_params(self, held):
+        """Parameters of a planned model by layer kind; `held` counts the
+        experts held here (else all the router scores)."""
+        h, d, G = self.hidden_size, self.head_dim, self.kv_heads
+        E = self.experts_held if held else self.moe_num_experts
+        total = self.vocab_size * h * \
+            (1 if self.tie_word_embeddings else 2) + h
+        for spec in self.layer_plan:
+            attn = 2 * h * spec.heads * d + 2 * h * G * d
+            if self.attn_gate == "per-head":
+                attn += h * spec.heads
+            if spec.ffn == "dense":
+                ffn = 3 * h * self.intermediate_size
+            else:
+                ffn = h * self.moe_num_experts + \
+                    3 * h * (E * self.expert_width + self.moe_shared_width)
+            total += attn + ffn + 2 * h
+        return total
+
+    def num_params(self, held=True):
+        """Parameters of the model. A planned model's are counted by
+        layer kind; with a held share of the experts (`moe_held`) the
+        count is of what is held here, or with `held=False` of the
+        published model."""
+        if self.layer_plan:
+            return self._planned_params(held)
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         i = self.intermediate_size
         bias = 1 if self.use_bias else 0
@@ -148,7 +271,8 @@ class GPTNeoXConfig:
         moe, dropless = bool(self.moe_num_experts), self.moe_dropless
         if dropless and not moe:
             raise ValueError("moe_dropless needs moe_num_experts > 0")
-        if self.ffn_gated and not dropless:
+        self._check_plan()
+        if self.ffn_gated and not dropless and not self.layer_plan:
             raise NotImplementedError(
                 "ffn_gated is computed by the dropless MoE experts only "
                 "(moe_dropless): the dense MLP and the GShard capacity "
@@ -179,6 +303,81 @@ class GPTNeoXConfig:
             raise NotImplementedError(
                 "moe_jitter_eps with dropless routing: the published "
                 "router has no jitter")
+
+    def _check_plan(self):
+        """The planned model's facts, and the facts only a planned model
+        computes, each refused by name where the code has no path."""
+        plan = self.layer_plan
+        planned_only = [
+            f"{k}={getattr(self, k)!r}" for k, plain in
+            (("attn_head_dim", 0), ("num_kv_heads", 0), ("attn_window", 0),
+             ("attn_gate", "none"), ("moe_expert_width", 0),
+             ("moe_shared_width", 0), ("moe_routing_scale", 1.0),
+             ("moe_held", ())) if getattr(self, k) != plain]
+        if self.moe_router_score != "softmax":
+            raise NotImplementedError(
+                f"moe_router_score {self.moe_router_score!r}: the router "
+                f"scores by a float32 softmax over all experts; sigmoid "
+                f"(or bias-corrected) scoring is not computed")
+        if self.attn_gate not in ("none", "per-head"):
+            raise NotImplementedError(
+                f"attn_gate {self.attn_gate!r}: 'none', or 'per-head' "
+                f"(sigmoid(a Wg) a head and token on the attention output "
+                f"before the output projection); an elementwise gate is "
+                f"not computed")
+        if not plan:
+            if planned_only:
+                raise NotImplementedError(
+                    f"{', '.join(planned_only)} without a layer_plan: the "
+                    f"homogeneous block has one KV head a query head of "
+                    f"hidden_size / num_heads features, full attention, "
+                    f"no gate, no shared expert and every expert held")
+            return
+        if len(plan) != self.num_layers:
+            raise ValueError(f"layer_plan names {len(plan)} layers, "
+                             f"num_layers is {self.num_layers}")
+        other = [f"{k}={getattr(self, k)!r}" for k, want in
+                 (("norm", "rmsnorm"), ("use_bias", False),
+                  ("qk_norm", False), ("use_parallel_residual", False),
+                  ("ffn_gated", True), ("tie_word_embeddings", False),
+                  ("attention_engine", "dense"), ("ffn_quant_recipe", None))
+                 if getattr(self, k) != want]
+        if other:
+            raise NotImplementedError(
+                f"a planned block with {', '.join(other)} is not computed: "
+                f"it is pre-norm RMSNorm, two norms a layer, a sequential "
+                f"residual, no bias, no norm on q or k, gated FFNs, an "
+                f"untied head")
+        for i, spec in enumerate(plan):
+            if spec.attn not in ("full", "window") or \
+                    spec.ffn not in ("dense", "experts"):
+                raise NotImplementedError(
+                    f"layer {i}: attention {spec.attn!r} / FFN "
+                    f"{spec.ffn!r}; the kinds are full | window and "
+                    f"dense | experts")
+            if spec.heads < 1 or spec.heads % self.kv_heads:
+                raise ValueError(
+                    f"layer {i}: {spec.heads} query heads over "
+                    f"{self.kv_heads} KV heads")
+            if spec.attn == "window" and self.attn_window < 1:
+                raise ValueError(f"layer {i} is a window layer and "
+                                 f"attn_window is {self.attn_window}")
+            if spec.rope and (spec.rope[0] != "yarn" or len(spec.rope) != 6):
+                raise NotImplementedError(
+                    f"layer {i}: rope {spec.rope!r}; plain rotary (()) or "
+                    f"('yarn', factor, original_max, beta_fast, "
+                    f"beta_slow, attention_factor) are computed")
+            if spec.ffn == "experts" and not self.moe_dropless:
+                raise NotImplementedError(
+                    f"layer {i}: a planned model's experts are routed "
+                    f"without capacity (moe_dropless); the GShard capacity "
+                    f"router is not told which experts are held")
+        if self.moe_held:
+            lo, hi = self.moe_held
+            if not 0 <= lo < hi <= self.moe_num_experts:
+                raise ValueError(
+                    f"moe_held {self.moe_held} is not a range of the "
+                    f"router's {self.moe_num_experts} experts")
 
     # ---- presets mirroring the config ladder (BASELINE.md) -------------
 
@@ -274,9 +473,73 @@ def _init_ffn_params(cfg, k_in, k_out, out_scale):
     }
 
 
+def _stack_init(key, lead, shape, dtype, scale=0.02):
+    """`_dense_init` of `lead` + `shape`, one `shape` matrix at a time
+    (a map over their keys): a kind's stack of experts is made without
+    its float32 original ever being whole on the device."""
+    n = int(np.prod(lead))
+    out = jax.lax.map(lambda k: _dense_init(k, shape, dtype, scale),
+                      jax.random.split(key, n))
+    return out.reshape(*lead, *shape)
+
+
+def init_stack_params(cfg, spec, n, key):
+    """The parameter stack of `n` layers of kind `spec`, every leaf with
+    the leading dim `n`. No biases. Attention: `q_w` [h, H*d], `kv_w`
+    [h, 2*G*d] ([K | V], each G heads of d), `out_w` [H*d, h], and with
+    a per-head gate `gate_w` [h, H]. FFN, dense: `in_w` [h, 2i]
+    ([gate | up]), `out_w` [i, h]. Experts: the router `gate`
+    [h, E scored], `w_in` [E held, h, 2w], `w_out` [E held, w, h], and a
+    shared expert's `shared_in` [h, 2s], `shared_out` [s, h]."""
+    h, d, dt = cfg.hidden_size, cfg.head_dim, cfg.param_dtype
+    H, G = spec.heads, cfg.kv_heads
+    out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
+    ks = jax.random.split(key, 10)
+    attn = {"q_w": _stack_init(ks[0], (n,), (h, H * d), dt),
+            "kv_w": _stack_init(ks[1], (n,), (h, 2 * G * d), dt),
+            "out_w": _stack_init(ks[2], (n,), (H * d, h), dt, out_scale)}
+    if cfg.attn_gate == "per-head":
+        attn["gate_w"] = _stack_init(ks[3], (n,), (h, H), dt)
+    if spec.ffn == "dense":
+        i = cfg.intermediate_size
+        mlp = {"in_w": _stack_init(ks[4], (n,), (h, 2 * i), dt),
+               "out_w": _stack_init(ks[5], (n,), (i, h), dt, out_scale)}
+    else:
+        E, w = cfg.experts_held, cfg.expert_width
+        mlp = {"gate": _stack_init(ks[4], (n,), (h, cfg.moe_num_experts),
+                                   dt),
+               "w_in": _stack_init(ks[5], (n, E), (h, 2 * w), dt),
+               "w_out": _stack_init(ks[6], (n, E), (w, h), dt, out_scale)}
+        if cfg.moe_shared_width:
+            sw = cfg.moe_shared_width
+            mlp["shared_in"] = _stack_init(ks[7], (n,), (h, 2 * sw), dt)
+            mlp["shared_out"] = _stack_init(ks[8], (n,), (sw, h), dt,
+                                            out_scale)
+    ones = jnp.ones((n, h), dt)
+    return {"ln_attn": {"scale": ones}, "ln_mlp": {"scale": ones},
+            "attn": attn, "mlp": mlp}
+
+
 def init_params(cfg, rng):
+    """The parameter tree. A homogeneous model's blocks are a list, one
+    entry a layer (`blocks`); a planned model's are one stack a layer
+    kind (`stacks`: {`LayerSpec.kind`: leaves [layers of the kind, ...]}),
+    the layout the serving engine runs from as it is, so that it holds
+    the weights once."""
     keys = jax.random.split(rng, cfg.num_layers + 2)
     dt = cfg.param_dtype
+    if cfg.layer_plan:
+        kinds = cfg.plan_kinds()
+        return {
+            "embed": {"wte": _dense_init(keys[0], (cfg.vocab_size,
+                                                   cfg.hidden_size), dt)},
+            "stacks": {name: init_stack_params(cfg, spec, len(layers),
+                                               keys[1 + layers[0]])
+                       for name, (spec, layers) in kinds.items()},
+            "final_ln": init_norm_params(cfg),
+            "embed_out": {"wte": _dense_init(
+                keys[-1], (cfg.vocab_size, cfg.hidden_size), dt)},
+        }
     params = {
         "embed": {"wte": _dense_init(keys[0], (cfg.vocab_size,
                                                cfg.hidden_size), dt)},
@@ -368,16 +631,65 @@ def block_hidden(out):
     return out[0] if isinstance(out, tuple) else out
 
 
-def _rotary_cache(cfg, seq_len, dtype=jnp.float32):
-    rot_dim = int(cfg.head_dim * cfg.rotary_pct)
+def rope_inv_freq(head_dim, rotary_pct, base, rope=()):
+    """(inv_freq [rot/2], the factor on cos and sin, rot_dim) of a
+    layer's rotary. `rope` = ("yarn", factor, original_max, beta_fast,
+    beta_slow, attention_factor) blends, dimension by dimension, the
+    plain frequencies with the same divided by `factor`, as Hugging
+    Face's `rope_type: yarn` does: the correction range is
+    [floor(c(beta_fast)), ceil(c(beta_slow))] clipped to the dims, with
+    c(b) = rot * ln(original_max / (2 pi b)) / (2 ln base), the blend a
+    linear ramp over it, and cos and sin are scaled by
+    `attention_factor`."""
+    rot_dim = int(head_dim * rotary_pct)
     rot_dim -= rot_dim % 2
-    inv_freq = 1.0 / (cfg.rotary_emb_base **
+    inv_freq = 1.0 / (base **
                       (np.arange(0, rot_dim, 2, dtype=np.float32) / rot_dim))
+    if not rope:
+        return inv_freq, 1.0, rot_dim
+    _, factor, original, beta_fast, beta_slow, attention_factor = rope
+
+    def correction_dim(rotations):
+        return rot_dim * math.log(original / (rotations * 2 * math.pi)) / \
+            (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rot_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot_dim // 2, dtype=np.float32) - low) /
+                   (high - low), 0.0, 1.0).astype(np.float32)
+    blended = inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+    return blended.astype(np.float32), float(attention_factor), rot_dim
+
+
+def _rotary_table(inv_freq, factor, rot_dim, seq_len, dtype):
     t = np.arange(seq_len, dtype=np.float32)
     freqs = np.outer(t, inv_freq)                      # [S, rot/2]
     emb = np.concatenate([freqs, freqs], axis=-1)      # [S, rot]
-    return (jnp.asarray(np.cos(emb), dtype),
-            jnp.asarray(np.sin(emb), dtype), rot_dim)
+    return (jnp.asarray(np.cos(emb) * factor, dtype),
+            jnp.asarray(np.sin(emb) * factor, dtype), rot_dim)
+
+
+def _rotary_cache(cfg, seq_len, dtype=jnp.float32, spec=None):
+    """(cos, sin, rot_dim) over `seq_len` positions: the model's own
+    rotary, or with `spec` (a planned model's `LayerSpec`) that layer
+    kind's."""
+    if spec is not None:
+        return _rotary_table(*rope_inv_freq(
+            cfg.head_dim, spec.rotary_pct, spec.rotary_base, spec.rope),
+            seq_len, dtype)
+    return _rotary_table(*rope_inv_freq(
+        cfg.head_dim, cfg.rotary_pct, cfg.rotary_emb_base), seq_len, dtype)
+
+
+def plan_rotary(cfg, seq_len):
+    """A planned model's rotary tables, one an attention kind:
+    {"full" | "window": (cos, sin, rot_dim)} (a kind's layers share their
+    rotary facts; `check_block` does not hold that, the family file
+    does)."""
+    return {spec.attn: _rotary_cache(cfg, seq_len, spec=spec)
+            for spec in reversed(cfg.layer_plan)}
 
 
 def _rotate_half(x):
@@ -408,8 +720,13 @@ def apply_rotary(q, k, cos, sin, rot_dim):
             jnp.concatenate([k_rot, k_pass], axis=-1))
 
 
-def causal_attention(q, k, v, use_pallas=True, segment_ids=None):
+def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
+                     window=None):
     """Causal MHA core on [B, S, H, D]; fp32 softmax accumulation.
+    `k` / `v` may hold fewer (KV) heads than `q`: query head h reads KV
+    head h // (H / G); `window` keeps the keys less than `window`
+    positions behind their query (both a planned model's, on the
+    segmented forward kernel and the XLA fallback alike).
 
     Uses the Pallas flash-attention kernel on TPU when shapes allow;
     XLA-fused fallback otherwise (the fallback still fuses well — softmax
@@ -435,9 +752,15 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None):
 
         def kernel(q, k, v, *seg):
             if seg:
-                return flash_attention_segmented(q, k, v, seg[0], True)
+                return flash_attention_segmented(q, k, v, seg[0], True,
+                                                 window=window)
             return flash_attention(q, k, v, True)
 
+        grouped = window is not None or k.shape[2] != q.shape[2]
+        if grouped and segment_ids is None:
+            # one kernel path for a window or grouped KV heads: the
+            # segmented forward, every token of one document
+            segment_ids = jnp.ones(q.shape[:2], jnp.int32)
         seg = () if segment_ids is None else (segment_ids,)
         return per_shard(kernel, (q, k, v) + seg,
                          {0: DATA_AXIS, 2: MODEL_AXIS})
@@ -451,9 +774,15 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None):
     B, S, H, D = q.shape
     scale = 1.0 / math.sqrt(D)
     with scopes.scope("ds.attn_xla"):
+        if k.shape[2] != H:
+            k = jnp.repeat(k, H // k.shape[2], axis=2)
+            v = jnp.repeat(v, H // v.shape[2], axis=2)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32) * scale
         mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, :, :]
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((S, S), jnp.bool_),
+                                    -int(window))[None, :, :]
         if segment_ids is not None:
             mask = mask & (segment_ids[:, :, None] ==
                            segment_ids[:, None, :])
@@ -476,11 +805,27 @@ def _wmat(x, w):
     return x @ w.astype(x.dtype)
 
 
+def _gated_mlp(x, w_in, w_out, act):
+    """(act(x Wgate) * (x Wup)) Wdown with `w_in` = [Wgate | Wup]."""
+    hmid = _wmat(x, w_in)
+    inter = hmid.shape[-1] // 2
+    return _wmat(act(hmid[..., :inter]) * hmid[..., inter:], w_out)
+
+
 @scopes.scoped("ds.attn")
 def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
     """ln1 + QKV projection + rotary; shared by training and decode."""
     B, S, _ = x.shape
     ln1 = norm(cfg, params["ln_attn"], x)
+    if "q_w" in params["attn"]:
+        # a planned block: `nh_local` query heads over the model's KV
+        # heads, [K | V] fused, head dim a fact of the model
+        d, G = cfg.head_dim, cfg.kv_heads
+        q = _wmat(ln1, params["attn"]["q_w"]).reshape(B, S, nh_local, d)
+        kv = _wmat(ln1, params["attn"]["kv_w"]).reshape(B, S, 2, G, d)
+        k, v = kv[:, :, 0], kv[:, :, 1]
+        q, k = apply_rotary(q, k, cos, sin, rot_dim)
+        return q, k, v
     qkv = _plus_bias(_wmat(ln1, params["attn"]["qkv_w"]), params["attn"],
                      "qkv_b")
     qkv = qkv.reshape(B, S, nh_local, 3 * cfg.head_dim)
@@ -511,6 +856,17 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
     nothing: a padded row is routed to no expert."""
     out_b = params["attn"]["out_b"].astype(x.dtype) \
         if "out_b" in params["attn"] else 0
+    if "gate_w" in params["attn"]:
+        # per-head gate: sigmoid(a Wg), a scalar a head and token, from
+        # the normed input `a` (the norm `_block_qkv` took: one value)
+        with scopes.scope("ds.attn_gate"):
+            B, S, _ = attn_flat.shape
+            a = norm(cfg, params["ln_attn"], x)
+            gate = jax.nn.sigmoid(
+                _wmat(a, params["attn"]["gate_w"]).astype(jnp.float32))
+            attn_flat = (attn_flat.reshape(B, S, gate.shape[-1], -1) *
+                         gate[..., None].astype(attn_flat.dtype)
+                         ).reshape(B, S, -1)
     with scopes.scope("ds.attn"):
         attn_partial = _wmat(attn_flat, params["attn"]["out_w"])
 
@@ -522,22 +878,29 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
     with scopes.scope("ds.mlp"):
         ln2 = norm(cfg, params["ln_mlp"], ln2_in)
 
-    if getattr(cfg, "moe_dropless", False):
+    if getattr(cfg, "moe_dropless", False) and "w_in" in params["mlp"]:
         from ..moe.layer import moe_ffn_dropless
         B, S, h = ln2.shape
+        act = FFN_ACTIVATIONS[cfg.hidden_act]
         with scopes.scope("ds.mlp"):
             y, stats = moe_ffn_dropless(
                 params["mlp"], ln2.reshape(B * S, h), cfg.moe_top_k,
                 norm_topk_prob=cfg.moe_norm_topk_prob,
-                activation=FFN_ACTIVATIONS[cfg.hidden_act],
+                activation=act,
                 token_mask=None if token_mask is None
-                else token_mask.reshape(B * S))
-        y = y.reshape(ln2.shape)
+                else token_mask.reshape(B * S),
+                held=cfg.moe_held or None, scale=cfg.moe_routing_scale)
+            y = y.reshape(ln2.shape)
+            if "shared_in" in params["mlp"]:
+                # the shared expert: every token, no router weight
+                with scopes.scope("ds.moe_shared"):
+                    y = y + _gated_mlp(ln2, params["mlp"]["shared_in"],
+                                       params["mlp"]["shared_out"], act)
         if cfg.use_parallel_residual:
             return x + reduce_fn(attn_partial) + out_b + y, stats
         return ln2_in + y, stats
 
-    if getattr(cfg, "moe_num_experts", 0):
+    if getattr(cfg, "moe_num_experts", 0) and "w_in" in params["mlp"]:
         from ..moe.layer import moe_ffn_dense
         B, S, h = ln2.shape
         with scopes.scope("ds.mlp"):
@@ -585,11 +948,15 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
         else:
             out = ln2_in + reduce_fn(mlp_partial) + mlp_b
         return out, new_amax_row
+    act = FFN_ACTIVATIONS[getattr(cfg, "hidden_act", "gelu")]
     with scopes.scope("ds.mlp"):
-        hmid = _plus_bias(_wmat(ln2, params["mlp"]["in_w"]), params["mlp"],
-                          "in_b")
-        hmid = FFN_ACTIVATIONS[getattr(cfg, "hidden_act", "gelu")](hmid)
-        mlp_partial = _wmat(hmid, params["mlp"]["out_w"])
+        if getattr(cfg, "layer_plan", ()):       # a planned dense FFN is gated
+            mlp_partial = _gated_mlp(ln2, params["mlp"]["in_w"],
+                                     params["mlp"]["out_w"], act)
+        else:
+            hmid = act(_plus_bias(_wmat(ln2, params["mlp"]["in_w"]),
+                                  params["mlp"], "in_b"))
+            mlp_partial = _wmat(hmid, params["mlp"]["out_w"])
 
     if cfg.use_parallel_residual:
         # one reduce for both partials (the Megatron fusion win)
@@ -600,7 +967,7 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
 @scopes.scoped("ds.block")
 def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
                 return_kv=False, rng=None, attn_fn=None,
-                segment_ids=None, ffn_quant=None):
+                segment_ids=None, ffn_quant=None, spec=None):
     """Shared block body: `mp == 1` with identity `reduce_fn` is the
     dense block; TP callers pass pre-sliced params (column/row parallel)
     and a psum reduce; the KV-cached decode step reuses the same
@@ -610,18 +977,24 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
 
     `segment_ids` [B, S] (packed ragged batches) makes attention
     intra-document on every path: the default flash/XLA core and any
-    segment-capable `attn_fn` (the SP ring accepts the kwarg)."""
+    segment-capable `attn_fn` (the SP ring accepts the kwarg).
+
+    `spec` (a planned model's `LayerSpec`, `params` a layer of that
+    kind's stack): the layer's own query heads, and its window if it is
+    a window layer."""
     B, S, h = x.shape
     cos, sin, rot_dim = cos_sin
-    q, k, v = _block_qkv(cfg, params, x, cos, sin, rot_dim,
-                         cfg.num_heads // mp)
+    heads = spec.heads if spec is not None else cfg.num_heads
+    window = cfg.attn_window \
+        if spec is not None and spec.attn == "window" else None
+    q, k, v = _block_qkv(cfg, params, x, cos, sin, rot_dim, heads // mp)
     with scopes.scope("ds.attn"):
         if attn_fn is not None:
             attn = attn_fn(q, k, v) if segment_ids is None else \
                 attn_fn(q, k, v, segment_ids=segment_ids)
         else:
             attn = causal_attention(q, k, v, use_pallas=use_pallas,
-                                    segment_ids=segment_ids)
+                                    segment_ids=segment_ids, window=window)
     if return_kv and ffn_quant is not None:
         raise ValueError("return_kv and ffn_quant cannot combine (the "
                          "KV-returning decode path serves quantized "
@@ -629,7 +1002,7 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     # segment 0 is padding (a packed batch's tail, a prefill bucket's):
     # a router that drops nothing routes it nowhere
     out = _block_post_attn(
-        cfg, params, x, attn.reshape(B, S, h // mp), reduce_fn, rng=rng,
+        cfg, params, x, attn.reshape(B, S, -1), reduce_fn, rng=rng,
         ffn_quant=ffn_quant,
         token_mask=None if segment_ids is None else segment_ids > 0)
     if return_kv:
@@ -815,6 +1188,16 @@ def forward_hidden(cfg, params, tokens, use_pallas=True, remat_blocks=False,
     moe = bool(getattr(cfg, "moe_num_experts", 0))
     do_remat, policy, n_ckpt = resolve_remat(remat_blocks, remat_policy,
                                              number_checkpoints)
+    if cfg.layer_plan:
+        if do_remat or collect_hidden or attn_fn is not None or \
+                ffn_amax is not None:
+            raise NotImplementedError(
+                "a planned model's forward is the plain one: no remat, "
+                "hidden-state capture, custom attention or quantized FFN "
+                "(training of a planned block is not built)")
+        out = _forward_hidden_planned(cfg, params, tokens, use_pallas,
+                                      segment_ids)
+        return (out, jnp.asarray(0.0, jnp.float32)) if moe else out
     quant = None
     if ffn_amax is not None:
         # delayed-scaling quantized FFN: `ffn_amax` [L, 4, H] carries
@@ -937,6 +1320,37 @@ def forward_hidden(cfg, params, tokens, use_pallas=True, remat_blocks=False,
     if quant is not None:
         return out, new_amax
     return out
+
+
+def plan_layer_params(cfg, stacks):
+    """A planned model's layers in order: [(spec, that layer's slice of
+    its kind's stack)]."""
+    at, out = {}, []
+    for spec in cfg.layer_plan:
+        i = at.get(spec.kind, 0)
+        at[spec.kind] = i + 1
+        out.append((spec, jax.tree_util.tree_map(lambda a, i=i: a[i],
+                                                 stacks[spec.kind])))
+    return out
+
+
+def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids):
+    """`forward_hidden` of a planned model: the same block code, a layer
+    at a time with its own `LayerSpec`."""
+    S = tokens.shape[1]
+    with scopes.scope("ds.embed"):
+        x = params["embed"]["wte"][tokens]
+    rotary = plan_rotary(cfg, S)
+    if segment_ids is not None:
+        from ..runtime.packing import segment_relative_positions
+        pos = segment_relative_positions(segment_ids)
+        rotary = {k: (c[pos], s_[pos], r) for k, (c, s_, r) in rotary.items()}
+    with scopes.scope("ds.layers"):
+        for spec, bp in plan_layer_params(cfg, params["stacks"]):
+            x = block_hidden(_block_core(
+                cfg, bp, x, rotary[spec.attn], use_pallas, mp=1,
+                reduce_fn=lambda t: t, segment_ids=segment_ids, spec=spec))
+    return norm(cfg, params["final_ln"], x)
 
 
 def forward(cfg, params, tokens, use_pallas=True, remat_blocks=False,
@@ -1190,6 +1604,17 @@ class GPTNeoX:
                 f"{self.config.attention_engine!r}")
         self.config.check_block()
 
+    def _refuse_planned_training(self, what):
+        if self.config.layer_plan:
+            from ..runtime.config_utils import DeepSpeedConfigError
+            raise DeepSpeedConfigError(
+                f"{what}: training of a planned model (layer_plan: window "
+                f"layers, grouped KV heads, an attention gate, a shared "
+                f"expert, a held share of the experts) is not built; the "
+                f"flash backward, the parameter specs and the pipeline "
+                f"layers are the homogeneous block's. InferenceEngine "
+                f"serves it")
+
     def _attention_fn(self):
         """The attention core `forward_hidden` should use: the SP/sparse
         attn_fn when configured, with a lazily-built sparse engine for
@@ -1207,6 +1632,7 @@ class GPTNeoX:
         calls this before parameter init, so a user config alone (no
         library imports) drives all three axes."""
         import dataclasses
+        self._refuse_planned_training("deeperspeed_tpu.initialize")
         moe = getattr(ds_config, "moe_params", None)
         if moe and self.config.moe_dropless:
             raise NotImplementedError(
@@ -1291,6 +1717,7 @@ class GPTNeoX:
         return init_params(self.config, rng)
 
     def param_specs(self, params, mesh):
+        self._refuse_planned_training("param_specs")
         has_mp = MODEL_AXIS in mesh.axis_names and \
             mesh.shape[MODEL_AXIS] > 1
         has_ep = ("expert" in mesh.axis_names
@@ -1379,6 +1806,7 @@ class GPTNeoX:
         """Scalar LM loss; with `ffn_amax` (delayed-scaling quantized
         FFN state, [L, 4, H]) the return is (loss, new_ffn_amax) — the
         engine threads the state through `EngineState.quant`."""
+        self._refuse_planned_training("loss_fn")
         hidden, labels, aux, new_amax = self._lm_forward(
             params, batch, rng, ffn_amax=ffn_amax)
         loss = self._head_loss(params, hidden, labels, aux)

@@ -548,7 +548,7 @@ def dropless_geometry(tokens, top_k, n_experts):
 
 def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                      activation=jax.nn.silu, token_mask=None,
-                     gmm_backend=None):
+                     gmm_backend=None, held=None, scale=1.0):
     """Top-k routing that drops nothing, through the sort engine.
 
     params: {"gate" [H, E] (the router), "w_in" [E, H, 2I] (each
@@ -576,15 +576,33 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
     tokens. The load-balancing loss E * sum_e f_e P_e is taken by the
     model over ALL layers' routed tokens (`GPTNeoX._head_loss`), so the
     layer returns its means, not a scalar.
+
+    `held` = (first, past-the-last): WHICH of the router's experts this
+    layer holds (`w_in` / `w_out` then hold ``past - first`` experts, in
+    order; one chip's share of an expert-parallel layer). The router
+    still scores all E and keeps `top_k`; a (token, choice) pair that
+    fell on an absent expert takes no buffer row, and the result is the
+    held experts' part of the sum: what the absent ones would have added
+    is left out, and the shares of all the holders add up to the whole
+    layer. stats then has a third row, c: the raw count of routed pairs
+    on each of the E experts (exact in float32), from which a caller
+    counts the pairs that were held. `scale` multiplies the kept
+    weights (after the renormalisation).
     """
     from .. import scopes
     from ..ops.pallas.grouped_matmul import ragged_matmul, ragged_tile_maps
     T, H = x.shape
-    E = params["gate"].shape[1]
+    E_all = params["gate"].shape[1]          # the experts the router scores
+    lo, hi = held if held is not None else (0, E_all)
+    E = hi - lo                              # the experts held here
     k = int(top_k)
-    if not 1 <= k <= E:
-        raise ValueError(f"dropless routing needs 1 <= top_k <= {E} "
+    if not 1 <= k <= E_all:
+        raise ValueError(f"dropless routing needs 1 <= top_k <= {E_all} "
                          f"experts, got top_k={top_k}")
+    if params["w_in"].shape[0] != E:
+        raise ValueError(
+            f"w_in holds {params['w_in'].shape[0]} experts; the held range "
+            f"{(lo, hi)} of the router's {E_all} names {E}")
     R, bm = dropless_geometry(T, k, E)
     live = jnp.ones((T,), jnp.bool_) if token_mask is None \
         else token_mask.reshape(T).astype(jnp.bool_)
@@ -599,6 +617,8 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
         weights, experts = jax.lax.top_k(probs, k)            # [T, k]
         if norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * scale
         n_live = jnp.maximum(jnp.sum(live), 1).astype(jnp.float32)
         mean_prob = jnp.sum(jnp.where(live[:, None], probs, 0.0),
                             axis=0) / n_live                  # P [E]
@@ -606,8 +626,9 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
     with scopes.scope("ds.moe_dispatch"):
         # pair p = t*k + j; a padded token's pairs go to the sentinel E,
         # which sorts last and owns no buffer row
-        pair_expert = jnp.where(live[:, None], experts.astype(jnp.int32),
-                                E).reshape(T * k)
+        experts = experts.astype(jnp.int32)
+        here = live[:, None] & (experts >= lo) & (experts < hi)
+        pair_expert = jnp.where(here, experts - lo, E).reshape(T * k)
         order = jnp.argsort(pair_expert)                      # stable
         counts = jnp.zeros((E + 1,), jnp.int32).at[pair_expert].add(1)[:E]
         tile_expert, tile_rows, starts = ragged_tile_maps(
@@ -624,8 +645,15 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
         pair_row = jnp.zeros((T * k,), jnp.int32).at[order].set(dest)
         buf = jnp.where((src < T * k)[:, None],
                         x[jnp.minimum(src, T * k - 1) // k], 0)
-        stats = jnp.stack([counts.astype(jnp.float32) /
-                           jnp.maximum(jnp.sum(counts), 1), mean_prob])
+        if held is None:
+            stats = jnp.stack([counts.astype(jnp.float32) /
+                               jnp.maximum(jnp.sum(counts), 1), mean_prob])
+        else:
+            routed = jnp.zeros((E_all + 1,), jnp.float32).at[
+                jnp.where(live[:, None], experts, E_all).reshape(T * k)
+            ].add(1.0)[:E_all]
+            stats = jnp.stack([routed / jnp.maximum(jnp.sum(routed), 1.0),
+                               mean_prob, routed])
 
     dt = x.dtype
     inter = params["w_out"].shape[1]
@@ -637,7 +665,7 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
 
     with scopes.scope("ds.moe_combine"):
         rows = out[jnp.minimum(pair_row, R - 1)].reshape(T, k, H)
-        w = jnp.where(live[:, None], weights, 0.0).astype(dt)
+        w = jnp.where(here, weights, 0.0).astype(dt)
         y = jnp.sum(w[:, :, None] * rows, axis=1)
     return y, stats
 

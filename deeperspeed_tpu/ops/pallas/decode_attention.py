@@ -122,10 +122,11 @@ def _auto_backend(op, head_dim, page_size, quant):
 _STEP_VMEM_BYTES = 4 * 2 ** 20
 
 
-def heads_per_step(heads, page_size, head_dim, pool_dtype):
-    """How many heads' K and V one grid step moves: the largest divisor
-    of `heads` (the call's own, so a model-parallel shard's H / mp) whose
-    step fits `_STEP_VMEM_BYTES`. One page of all heads is contiguous in
+def heads_per_step(heads, page_size, head_dim, pool_dtype, group=1):
+    """How many KV heads' K and V one grid step moves: the largest divisor
+    of `heads` (the call's own KV heads, so a model-parallel shard's
+    H / mp; `group` query heads read each) whose step fits
+    `_STEP_VMEM_BYTES`. One page of all heads is contiguous in
     the pool ([H, page_size, D]), so the answer is H wherever it fits
     (16 heads of 128 at page 64: 1.1 MiB) and the grid has no head
     dimension to speak of; wider shapes split the heads and no other
@@ -136,7 +137,7 @@ def heads_per_step(heads, page_size, head_dim, pool_dtype):
     def step_bytes(hb):
         slots = hb * page_size
         tiles = 2 * slots * head_dim * pool_dtype.itemsize      # K and V
-        work = 2 * hb * slots * 4                  # scores, probabilities
+        work = 2 * hb * group * slots * 4          # scores, probabilities
         if quant:
             tiles += 2 * slots * 2                 # their bf16 scale tiles
             work += 2 * slots * head_dim * 4       # K and V widened
@@ -151,13 +152,17 @@ def heads_per_step(heads, page_size, head_dim, pool_dtype):
 
 
 def _page_update(q, k, v, k_scale, v_scale, m, l, acc, first_pos, length,
-                 sm_scale):
+                 sm_scale, window=None):
     """One page of `Hb` heads folded into the running softmax: returns
     the new (m, l, acc).
 
-    ``q`` [Hb, D]; ``k``/``v`` [Hb, ps, D] (the page as it lies in the
-    pool); ``k_scale``/``v_scale`` [Hb, ps] for int8 pages, else None;
-    ``m``/``l`` [Hb, 1] and ``acc`` [Hb, D] float32.
+    ``q`` [Hb * r, D]: the `r` query heads of each of the page's `Hb` KV
+    heads, a KV head's queries together (r = 1 where every query head has
+    its own); ``k``/``v`` [Hb, ps, D] (the page as it lies in the pool);
+    ``k_scale``/``v_scale`` [Hb, ps] for int8 pages, else None;
+    ``m``/``l`` [Hb * r, 1] and ``acc`` [Hb * r, D] float32. With
+    ``window`` a slot is live only if it lies among the last `window`
+    positions before `length`.
 
     The page is read as ONE [Hb * ps, D] operand (a free collapse of its
     leading dims): every head's query meets every head's keys in a
@@ -171,20 +176,25 @@ def _page_update(q, k, v, k_scale, v_scale, m, l, acc, first_pos, length,
     per-slot scales fold into the score / probability columns —
     ``q·(k·s) == (q·k)·s`` — and the math runs float32."""
     hb, ps, d = k.shape
+    r = q.shape[0] // hb
     if k_scale is not None:
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     s = jax.lax.dot_general(
         q, k.reshape(hb * ps, d), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # [Hb, Hb * ps]
-    # column c of row h is slot c - h * ps of head h's page, if in [0, ps)
+        preferred_element_type=jnp.float32)             # [Hb * r, Hb * ps]
+    # column c of query row h is slot c - (h // r) * ps of its KV head's
+    # page, if in [0, ps)
     slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) - \
-        jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) * ps
+        (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // r) * ps
     live = (slot >= 0) & (slot < ps) & (first_pos + slot < length)
+    if window is not None:
+        live = live & (first_pos + slot >= length - window)
 
     def own_columns(scale):
-        # [Hb, ps] -> [Hb, Hb * ps]: row h's scales under each head's
-        # columns; only its own (the live ones) are ever used
-        return jnp.tile(scale.astype(jnp.float32), (1, hb))
+        # [Hb, ps] -> [Hb * r, Hb * ps]: a query row's KV head's scales
+        # under each head's columns; only its own (the live ones) are used
+        return jnp.tile(jnp.repeat(scale.astype(jnp.float32), r, axis=0),
+                        (1, hb))
 
     if k_scale is not None:
         s = s * own_columns(k_scale)
@@ -205,8 +215,16 @@ def _page_update(q, k, v, k_scale, v_scale, m, l, acc, first_pos, length,
     return m_new, l_new, acc * alpha + pv
 
 
+def _first_page(length, page_size, window):
+    """The first page a row of `length` tokens attends over: page 0, or
+    with a `window` the page that holds position `length - window`."""
+    if window is None:
+        return 0
+    return jnp.maximum(length - window, 0) // page_size
+
+
 def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
-                   k_ref, v_ref, *refs, sm_scale, page_size):
+                   k_ref, v_ref, *refs, sm_scale, page_size, window):
     """Grid step `t` of a head group: one live page of one batch row —
     the page's K and V tiles of `Hb` heads ([Hb, ps, D] each) meet the
     row's `Hb` queries. `pt_ref` and `lyr_ref` (the page table and the
@@ -216,10 +234,11 @@ def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
     *scale_refs, o_ref, m_scr, l_scr, acc_scr = refs
     t = pl.program_id(1)
     b = row_ref[t]
-    first_pos = (t - start_ref[b]) * page_size
     length = len_ref[b]
+    first_pos = (t - start_ref[b] +
+                 _first_page(length, page_size, window)) * page_size
 
-    @pl.when(first_pos == 0)
+    @pl.when(t == start_ref[b])
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -231,7 +250,7 @@ def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
         m, l, acc = _page_update(
             q_ref[...], k_ref[...], v_ref[...], k_scale, v_scale,
             m_scr[:, :1], l_scr[:, :1], acc_scr[:], first_pos, length,
-            sm_scale)
+            sm_scale, window)
         m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
         acc_scr[:] = acc
@@ -244,16 +263,20 @@ def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
         o_ref[...] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-def decode_steps(lengths, page_size, table_width):
+def decode_steps(lengths, page_size, table_width, window=None):
     """The kernel's work list: one grid step a LIVE page, rows in order
-    (a row of length 0 keeps one step, which writes its zeros). Returns
+    (a row of length 0 keeps one step, which writes its zeros); with a
+    `window`, a page is live only from the one that holds position
+    ``length - window`` on, so a row has at most
+    ``window / page_size + 1`` steps. Returns
     ``(n_steps, row, start)``: the traced step count (at most
     ``B * table_width``), ``row`` [B * table_width] — the batch row of
     step `t`; entries at and past `n_steps` are never read — and
     ``start`` [B], each row's first step, so step `t` is page
-    ``t - start[row[t]]`` of its row."""
+    ``t - start[row[t]]`` of its row's live pages."""
     B = lengths.shape[0]
-    steps = jnp.maximum(-(-lengths // page_size), 1)
+    steps = jnp.maximum(-(-lengths // page_size) -
+                        _first_page(lengths, page_size, window), 1)
     ends = jnp.cumsum(steps)
     row = jnp.searchsorted(ends, jnp.arange(B * table_width, dtype=jnp.int32),
                            side="right", method="compare_all")
@@ -268,7 +291,8 @@ def _layer_operand(layer):
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
                                   sm_scale, k_scales=None, v_scales=None,
-                                  layer=None):
+                                  layer=None, window=None,
+                                  name="ds.paged_decode"):
     B, H, D = q.shape
     quant = k_scales is not None
     if layer is None:
@@ -277,8 +301,9 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         k_pages, v_pages = k_pages[None], v_pages[None]
         if quant:
             k_scales, v_scales = k_scales[None], v_scales[None]
-    page_size = k_pages.shape[3]
-    hb = heads_per_step(H, page_size, D, k_pages.dtype)
+    G, page_size = k_pages.shape[2], k_pages.shape[3]
+    r = H // G              # query heads a KV head (1: each its own)
+    hb = heads_per_step(G, page_size, D, k_pages.dtype, group=r)
 
     def row_block(g, t, row, start, pt, ln, lyr):
         return row[t], g, 0, 0
@@ -287,16 +312,18 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         b = row[t]
         # an inactive row's step names the trash page, whatever its table
         # row holds: a run of them fetches it once
-        page = jnp.where(ln[b] > 0, pt[b, t - start[b]], 0)
+        page = jnp.where(
+            ln[b] > 0,
+            pt[b, t - start[b] + _first_page(ln[b], page_size, window)], 0)
         return lyr[0], page, g, 0, 0
 
-    # the rows ride as [B, H / Hb, Hb, D] so that a block's last two dims
-    # are the array's own whatever Hb is; `None` dims are squeezed out of
-    # the kernel's refs
-    row_spec = pl.BlockSpec((None, None, hb, D), row_block)
+    # the rows ride as [B, G / Hb, Hb * r, D] (a KV head's r queries lie
+    # together) so that a block's last two dims are the array's own
+    # whatever Hb is; `None` dims are squeezed out of the kernel's refs
+    row_spec = pl.BlockSpec((None, None, hb * r, D), row_block)
     pool_spec = pl.BlockSpec((None, None, hb, page_size, D), page_block)
     in_specs = [row_spec, pool_spec, pool_spec]
-    args = [q.reshape(B, H // hb, hb, D), k_pages, v_pages]
+    args = [q.reshape(B, G // hb, hb * r, D), k_pages, v_pages]
     if quant:
         # the scale pool rides the SAME scalar-prefetch maps that resolve
         # the data pool's page indirection — one page id, two DMAs
@@ -307,29 +334,30 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         # the kernel widens each tile in VMEM — a whole-pool fp32
         # cast here would materialize a pool-sized copy every step
         args += [k_scales, v_scales]
-    with scopes.scope("ds.paged_decode"):
+    with scopes.scope(name):
         lengths = lengths.astype(jnp.int32)
         n_steps, row, start = decode_steps(lengths, page_size,
-                                           page_table.shape[1])
+                                           page_table.shape[1], window)
         out = pl.pallas_call(
             functools.partial(_decode_kernel, sm_scale=sm_scale,
-                              page_size=page_size),
-            out_shape=jax.ShapeDtypeStruct((B, H // hb, hb, D), q.dtype),
+                              page_size=page_size, window=window),
+            out_shape=jax.ShapeDtypeStruct((B, G // hb, hb * r, D),
+                                           q.dtype),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 # the page dimension carries the online softmax; its
                 # extent is this call's own count of live pages
-                grid=(H // hb, n_steps),
+                grid=(G // hb, n_steps),
                 in_specs=in_specs,
                 out_specs=row_spec,
                 scratch_shapes=[
-                    pltpu.VMEM((hb, LANES), jnp.float32),
-                    pltpu.VMEM((hb, LANES), jnp.float32),
-                    pltpu.VMEM((hb, D), jnp.float32),
+                    pltpu.VMEM((hb * r, LANES), jnp.float32),
+                    pltpu.VMEM((hb * r, LANES), jnp.float32),
+                    pltpu.VMEM((hb * r, D), jnp.float32),
                 ],
             ),
             compiler_params=_DIMSEM,
-            interpret=_interpret(), name="ds.paged_decode",
+            interpret=_interpret(), name=name,
         )(row, start, page_table.astype(jnp.int32), lengths,
           _layer_operand(layer), *args)
     return out.reshape(B, H, D)
@@ -338,21 +366,22 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
 @scopes.scoped("ds.paged_decode_xla")
 def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
                                sm_scale, k_scales=None, v_scales=None,
-                               layer=None):
+                               layer=None, window=None):
     """Pure-XLA reference/fallback: gather the sequence's pages back
-    into a contiguous [B, H, S_max, D] view and run a masked softmax.
+    into a contiguous [B, G, S_max, D] view and run a masked softmax.
     Identical semantics to the kernel, including exact-zero outputs for
-    inactive (length 0) rows and the int8 dequant at the gather. With
-    ``layer`` the gather indexes that layer of the stacked pools."""
+    inactive (length 0) rows, the int8 dequant at the gather, grouped KV
+    heads and the `window`. With ``layer`` the gather indexes that layer
+    of the stacked pools."""
     B, H, D = q.shape
     out_dtype = q.dtype
-    page_size = k_pages.shape[-2]
+    G, page_size = k_pages.shape[-3], k_pages.shape[-2]
     NP = page_table.shape[1]
 
     def rows(pool, *tail):
         pages = (pool[page_table] if layer is None
-                 else pool[layer, page_table])      # [B, NP, H, ps, ...]
-        return jnp.moveaxis(pages, 2, 1).reshape(B, H, NP * page_size,
+                 else pool[layer, page_table])      # [B, NP, G, ps, ...]
+        return jnp.moveaxis(pages, 2, 1).reshape(B, G, NP * page_size,
                                                  *tail)
 
     k = rows(k_pages, D)
@@ -363,23 +392,27 @@ def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
         k = k.astype(jnp.float32) * ks[..., None]
         v = v.astype(jnp.float32) * vs[..., None]
         q = q.astype(jnp.float32)
-    s = jnp.einsum("bhd,bhsd->bhs", q, k,
+    # query head h reads KV head h // (H / G)
+    s = jnp.einsum("bgrd,bgsd->bgrs", q.reshape(B, G, H // G, D), k,
                    preferred_element_type=jnp.float32) * sm_scale
-    pos = jnp.arange(NP * page_size, dtype=jnp.int32)
-    s = jnp.where(pos[None, None, :] < lengths[:, None, None], s, NEG_INF)
+    pos = jnp.arange(NP * page_size, dtype=jnp.int32)[None, :]
+    live = pos < lengths[:, None]
+    if window is not None:
+        live = live & (pos >= lengths[:, None] - window)
+    s = jnp.where(live[:, None, None, :], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     prob = jnp.exp(s - m)
     prob = jnp.where(s <= NEG_INF * 0.5, 0.0, prob)
     l = jnp.sum(prob, axis=-1, keepdims=True)
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = jnp.einsum("bhs,bhsd->bhd", (prob / l_safe).astype(v.dtype), v,
+    out = jnp.einsum("bgrs,bgsd->bgrd", (prob / l_safe).astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.astype(out_dtype)
+    return out.reshape(B, H, D).astype(out_dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            sm_scale=None, backend=None, k_scales=None,
-                           v_scales=None, layer=None):
+                           v_scales=None, layer=None, window=None):
     """One decode step of paged attention: ``out[b, h] = softmax(q[b, h]
     · K[b]) · V[b]`` with K/V read through ``page_table[b]`` and masked
     at ``lengths[b]``.
@@ -394,6 +427,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     scale pools) are the engine's stacked ``[L, P, H, page_size, ...]``
     arrays and the call attends over layer ``layer`` of them in place;
     None keeps the one-layer contract above.
+
+    The pools may hold FEWER heads than ``q``: with G KV heads under H
+    query heads, query head h reads KV head ``h // (H / G)``. ``window``
+    (a static int, or None) keeps a row's last `window` positions only:
+    the kernel's work list then starts at the page that holds position
+    ``length - window``, so earlier pages are neither read nor need a
+    live entry in the page table (the serving engine has given them
+    back), and the call runs under the scope `ds.paged_decode_window`.
 
     backend: None = auto (Pallas kernel on TPU when
     `paged_decode_supported`, XLA fallback otherwise — CPU test runs
@@ -411,8 +452,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         raise ValueError(f"k_pages {k_pages.shape} must be {want} a "
                          f"layer index")
     P, Hk, page_size, Dk = k_pages.shape[-4:]
-    if (Hk, Dk) != (H, D):
-        raise ValueError(f"cache heads/dim {(Hk, Dk)} != query {(H, D)}")
+    if Dk != D or H % Hk:
+        raise ValueError(f"cache heads/dim {(Hk, Dk)} do not serve query "
+                         f"{(H, D)}: the head dim must match and the KV "
+                         f"heads divide the query heads")
+    if window is not None and (k_scales is not None or int(window) < 1):
+        raise ValueError(f"window {window!r}: a positive int, over pools "
+                         f"that are not int8")
     if page_table.ndim != 2 or page_table.shape[0] != B:
         raise ValueError(f"page_table shape {page_table.shape} must be "
                          f"[{B}, n_pages]")
@@ -439,13 +485,15 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         return paged_decode_attention_xla(q, k_pages, v_pages, page_table,
                                           lengths, sm_scale,
                                           k_scales=k_scales,
-                                          v_scales=v_scales, layer=layer)
+                                          v_scales=v_scales, layer=layer,
+                                          window=window)
     if backend != "pallas":
         raise ValueError(f"unknown paged decode backend {backend!r}")
-    return paged_decode_attention_pallas(q, k_pages, v_pages, page_table,
-                                         lengths, sm_scale,
-                                         k_scales=k_scales,
-                                         v_scales=v_scales, layer=layer)
+    return paged_decode_attention_pallas(
+        q, k_pages, v_pages, page_table, lengths, sm_scale,
+        k_scales=k_scales, v_scales=v_scales, layer=layer, window=window,
+        name="ds.paged_decode" if window is None
+        else "ds.paged_decode_window")
 
 
 # ---------------------------------------------------------------------------

@@ -91,7 +91,7 @@ def note_xla_on_tpu(op, why):
 # compacted causal grid (trapezoidal schedule)
 # ---------------------------------------------------------------------------
 
-def causal_grid_maps(n_q, n_k, block_q, block_k, order="row"):
+def causal_grid_maps(n_q, n_k, block_q, block_k, order="row", window=None):
     """The compacted causal (qi, ki) schedule: every tile with
     ki*block_k <= qi*block_q + block_q - 1, i.e. exactly the causally
     alive blocks. Returns (qmap, kmap) int32 numpy arrays consumed as
@@ -105,13 +105,16 @@ def causal_grid_maps(n_q, n_k, block_q, block_k, order="row"):
 
     For n = n_q = n_k (equal blocks) the schedule has n(n+1)/2 entries
     instead of the dense grid's n² — the compile-time-verifiable
-    invariant (`_LAST_GRIDS` records what each call launched)."""
+    invariant (`_LAST_GRIDS` records what each call launched). With a
+    `window` (row order only) a row starts at the first tile that holds a
+    key within `window` positions of the row's first query: the band."""
     import numpy as np
     qs, ks = [], []
     if order == "row":
         for qi in range(n_q):
             kmax = min(n_k - 1, (qi * block_q + block_q - 1) // block_k)
-            for ki in range(kmax + 1):
+            for ki in range(_first_k(qi, block_q, block_k, window),
+                            kmax + 1):
                 qs.append(qi)
                 ks.append(ki)
     elif order == "col":
@@ -122,6 +125,18 @@ def causal_grid_maps(n_q, n_k, block_q, block_k, order="row"):
     else:
         raise ValueError(f"unknown order {order!r}")
     return np.asarray(qs, np.int32), np.asarray(ks, np.int32)
+
+
+def _first_k(qi, block_q, block_k, window):
+    """The first key tile a query tile `qi` sees: tile 0, or under a
+    `window` the tile of the key `window - 1` positions before the
+    tile's first query (`qi` a python int or a traced scalar)."""
+    if window is None:
+        return 0
+    first = qi * block_q - (window - 1)
+    if isinstance(first, int):
+        return max(first, 0) // block_k
+    return jnp.maximum(first, 0) // block_k
 
 
 def causal_grid_size(s, block_q=BLOCK_Q, block_k=BLOCK_K):
@@ -213,12 +228,17 @@ def flash_attention_supported(shape, block_q=BLOCK_Q, block_k=BLOCK_K):
         d in (64, 128, 256)
 
 
-def _causal_mask(s, qi, ki, block_q, block_k):
+def _causal_mask(s, qi, ki, block_q, block_k, window=None):
+    """Key j is visible to query i iff j <= i, and under a `window` also
+    i - j < window."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + \
         qi * block_q
     cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + \
         ki * block_k
-    return jnp.where(rows >= cols, s, NEG_INF)
+    seen = rows >= cols
+    if window is not None:
+        seen = seen & (rows - cols < window)
+    return jnp.where(seen, s, NEG_INF)
 
 
 MASK_GRAIN = 128  # layout-mask granularity (one sparsity block)
@@ -514,7 +534,7 @@ def _fwd_single(qb, kb, vb, causal, sm_scale, s, d, interpret, kbias=None,
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
                 use_seg=False, use_mask=False, use_bias=False,
-                dropout_rate=0.0, compact=False):
+                dropout_rate=0.0, compact=False, window=None):
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -538,7 +558,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         ki = pl.program_id(2)
         last_k = pl.num_programs(2) - 1
 
-    @pl.when(ki == 0)
+    # a window's rows start at their band's first tile (compact only)
+    @pl.when(ki == _first_k(qi, block_q, block_k, window))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -569,7 +590,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale    # [BQ, BK]
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+            s = _causal_mask(s, qi, ki, block_q, block_k, window)
         if seg_eq is not None:
             s = jnp.where(seg_eq, s, NEG_INF)
         if m_ref is not None:
@@ -583,7 +604,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)                       # [BQ, 1]
         p = jnp.exp(s - m_new)                                # [BQ, BK]
-        if seg_eq is not None or m_ref is not None or b_ref is not None:
+        if seg_eq is not None or m_ref is not None or \
+                b_ref is not None or window is not None:
             # rows with EVERY entry masked would otherwise see
             # exp(s - max) == 1 uniformly; zero masked entries so l==0
             # flags the dead row (poisoned-lse convention)
@@ -640,18 +662,32 @@ def _tag_residuals(out, lse):
 
 
 def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
-         layout=None, kbias=None, dropout_rate=0.0, seed=None, seg=None):
+         layout=None, kbias=None, dropout_rate=0.0, seed=None, seg=None,
+         window=None):
+    """`k` / `v` may hold fewer heads than `q` (G under H: query head i
+    reads KV head ``i // (H / G)``, through the K and V index maps), and
+    a `window` (causal only) keeps keys less than `window` positions
+    behind their query: the compacted grid launches the band's tiles
+    alone, under the scope `ds.flash_fwd_window`. Both are the forward's
+    (a serving prefill's); the backward kernels take neither."""
     b, s, h, d = q.shape
+    g = k.shape[2]
     block_q, block_k = _fit_block(block_q, s), _fit_block(block_k, s)
 
     # [B, S, H, D] → [B*H, S, D] for contiguous per-head tiles.
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s,
+                                               x.shape[-1])
+
+    def kv_of(bh):
+        """The [B*G, S, D] row that holds query row `bh`'s KV head."""
+        return bh if g == h else (bh // h) * g + (bh % h) // (h // g)
 
     qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
     n_q, n_k = s // block_q, s // block_k
 
-    if n_q == 1 and n_k == 1 and layout is None and seg is None:
+    if n_q == 1 and n_k == 1 and layout is None and seg is None and \
+            window is None and g == h:
         # whole sequence in one block: the online-softmax machinery is
         # pure overhead — run the specialized straight-softmax kernel
         _LAST_BLOCKS["fwd"] = (s, s)
@@ -676,9 +712,10 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
                                use_mask=layout is not None,
                                use_bias=kbias is not None,
                                dropout_rate=dropout_rate,
-                               compact=compact)
+                               compact=compact, window=window)
     if compact:
-        qmap, kmap = causal_grid_maps(n_q, n_k, block_q, block_k, "row")
+        qmap, kmap = causal_grid_maps(n_q, n_k, block_q, block_k, "row",
+                                      window)
         grid = (b * h, len(qmap))
     else:
         qmap = kmap = None
@@ -688,9 +725,9 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
         pl.BlockSpec((1, block_q, d),
                      ix(lambda bh, qi, ki: (bh, qi, 0))),
         pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (bh, ki, 0))),
+                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
         pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (bh, ki, 0))),
+                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
     ]
     bias_spec = pl.BlockSpec(
         (1, 1, block_k), ix(lambda bh, qi, ki, h=h: (bh // h, 0, ki)))
@@ -731,7 +768,8 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
     ]
     _LAST_GRIDS["fwd"] = grid
     out, lse = _tiled_call(
-        "ds.flash_fwd", kernel, compact, grid, in_specs, out_specs,
+        "ds.flash_fwd" if window is None else "ds.flash_fwd_window",
+        kernel, compact, grid, in_specs, out_specs,
         scratch_shapes, out_shape,
         (qmap, kmap) if compact else ())(*inputs)
     out, lse = _tag_residuals(out, lse)
@@ -1348,7 +1386,7 @@ _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention_segmented(q, k, v, segment_ids, causal=True,
                               sm_scale=None, block_q=None, block_k=None,
-                              bwd_blocks=None):
+                              bwd_blocks=None, window=None):
     """Flash attention over PACKED ragged batches: tokens attend only
     within their own document (`segment_ids` [B, S] int32, 0 = pad —
     see `runtime.packing`), composed with the causal mask.
@@ -1365,11 +1403,55 @@ def flash_attention_segmented(q, k, v, segment_ids, causal=True,
     segment_ids is data, not a parameter: its cotangent is float0
     (int inputs cannot carry gradients). Block geometry as in
     `flash_attention`.
+
+    Forward only (a serving prefill's): `k` / `v` [B, S, G, D] with G
+    KV heads under q's H (query head h reads KV head ``h // (H / G)``),
+    and / or a `window` (a static int; causal): key j is visible to
+    query i iff ``j <= i and i - j < window``, the tiles wholly behind
+    the window are never launched, and the call runs under the scope
+    `ds.flash_fwd_window`. The backward kernels compute neither, so a
+    gradient through such a call raises.
     """
     (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
                                     bwd_blocks)
+    if window is not None or k.shape[2] != q.shape[2]:
+        if window is not None and (not causal or int(window) < 1):
+            raise ValueError(f"window {window!r} needs causal attention "
+                             f"and a positive int")
+        if q.shape[2] % k.shape[2] or k.shape != v.shape:
+            raise ValueError(f"KV heads {k.shape} / {v.shape} do not "
+                             f"divide the query heads {q.shape}")
+        if window is not None and block_q is None and block_k is None:
+            # a tile wider than the window computes mostly masked scores
+            cap = max(int(window), 128)
+            bq = _fit_block(min(bq, cap), q.shape[1]) or bq
+            bk = _fit_block(min(bk, cap), q.shape[1]) or bk
+        return _flash_serving(q, k, v, segment_ids, causal, sm_scale, bq,
+                              bk, window)
     return _flash_segmented(q, k, v, segment_ids, causal, sm_scale, bq,
                             bk, bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_serving(q, k, v, segment_ids, causal, sm_scale, block_q,
+                   block_k, window):
+    """The segmented forward with grouped KV heads and / or a window."""
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    seg3 = segment_ids.astype(jnp.int32).reshape(
+        segment_ids.shape[0], 1, -1)
+    return _fwd(q, k, v, causal, scale, block_q, block_k, seg=seg3,
+                window=window)[0]
+
+
+def _flash_serving_fwd(*args):
+    raise NotImplementedError(
+        "flash attention with grouped KV heads or a window has no "
+        "backward: the dq / dkv kernels compute full causal attention "
+        "with one KV head a query head (training of a planned block is "
+        "not built)")
+
+
+_flash_serving.defvjp(_flash_serving_fwd, lambda *a: None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))

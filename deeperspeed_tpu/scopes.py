@@ -37,6 +37,12 @@ SCOPES = {
     "ds.flash_bwd_dkv": ("kernel", "flash attention backward, dk/dv pass"),
     "ds.flash_bwd": ("kernel", "fused single-block flash backward"),
     "ds.paged_decode": ("kernel", "paged decode attention"),
+    "ds.flash_fwd_window": ("kernel", "the flash forward of a window "
+                                      "layer: the same kernel, tiles "
+                                      "wholly behind the window skipped"),
+    "ds.paged_decode_window": ("kernel", "the paged decode of a window "
+                                         "layer: the same kernel over the "
+                                         "pages inside the window"),
     "ds.adam": ("kernel", "fused Adam over a flat shard"),
     "ds.sparse_attn_fwd": ("kernel", "block-sparse attention forward"),
     "ds.sparse_attn_bwd_dkv": ("kernel", "block-sparse backward, dk/dv"),
@@ -52,6 +58,11 @@ SCOPES = {
     "ds.moe_combine": ("region", "the experts' rows gathered back, "
                                  "weighted and summed over a token's "
                                  "experts"),
+    "ds.attn_gate": ("region", "the per-head sigmoid gate on the "
+                               "attention output: its projection from "
+                               "the normed input, and the product"),
+    "ds.moe_shared": ("region", "the shared expert every token passes "
+                                "through, beside the routed ones"),
     "ds.attn_xla": ("region", "the XLA fallback of attention"),
     "ds.paged_decode_xla": ("region", "the XLA fallback of paged decode"),
     "ds.embed": ("region", "token (and position) embedding gather"),

@@ -231,3 +231,66 @@ def test_mh_single_block_dropout_matches_hb1(monkeypatch):
 
     np.testing.assert_array_equal(np.asarray(out_mh), np.asarray(out_1))
     np.testing.assert_array_equal(np.asarray(g_mh), np.asarray(g_1))
+
+
+# ---------------------------------------------------------------------------
+# grouped KV heads and a window (a planned model's prefill)
+# ---------------------------------------------------------------------------
+
+def grouped_window_reference(q, k, v, seg, window):
+    B, S, H, D = q.shape
+    r = H // k.shape[2]
+    k, v = jnp.repeat(k, r, axis=2), jnp.repeat(v, r, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(D)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    seen = j <= i
+    if window is not None:
+        seen = seen & (i - j < window)
+    seen = seen[None] & (seg[:, :, None] == seg[:, None, :])
+    p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("window", [None, 200, 128, 130],
+                         ids=["full", "w200", "w128", "w130"])
+@pytest.mark.parametrize("heads,kv_heads", [(12, 2), (18, 2), (4, 4)],
+                         ids=["6_a_group", "9_a_group", "1_a_group"])
+def test_grouped_heads_and_window_forward_matches_xla(heads, kv_heads,
+                                                      window):
+    """The segmented forward with G < H (6 and 9 query heads a KV head are
+    Laguna's) and a window on and off, against plain XLA attention, on a
+    padded batch (one row full, one of 300 real tokens)."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    B, S, D = 2, 512, 64
+    q = jax.random.normal(ks[0], (B, S, heads, D))
+    k = jax.random.normal(ks[1], (B, S, kv_heads, D))
+    v = jax.random.normal(ks[2], (B, S, kv_heads, D))
+    seg = (jnp.arange(S)[None, :] < jnp.array([512, 300])[:, None]) \
+        .astype(jnp.int32)
+    got = fa.flash_attention_segmented(q, k, v, seg, True, block_q=128,
+                                       block_k=128, window=window)
+    want = grouped_window_reference(q, k, v, seg, window)
+    real = seg[:, :, None, None]
+    np.testing.assert_allclose(got * real, want * real, atol=2e-6, rtol=0)
+
+
+def test_window_launches_the_band_alone():
+    """Tiles wholly behind the window are never launched: at 1024 tokens
+    in blocks of 128 the causal trapezoid has 36 tiles, a window of 128
+    keeps the diagonal and the one before it (15), as does 129 (the key
+    128 back is that tile's first); a window of 130 takes one more a row."""
+    assert len(fa.causal_grid_maps(8, 8, 128, 128)[0]) == 36
+    qm, km = fa.causal_grid_maps(8, 8, 128, 128, window=128)
+    assert len(qm) == 8 + 7
+    assert all(q - k in (0, 1) for q, k in zip(qm, km))
+    assert len(fa.causal_grid_maps(8, 8, 128, 128, window=129)[0]) == 8 + 7
+    assert len(fa.causal_grid_maps(8, 8, 128, 128, window=130)[0]) \
+        == 8 + 7 + 6
+    q, k, v = make_qkv(s=1024)
+    seg = jnp.ones((1, 1024), jnp.int32)
+    fa.flash_attention_segmented(q, k, v, seg, True, block_q=128,
+                                 block_k=128, window=128)
+    assert fa._LAST_GRIDS["fwd"] == (2, 15)
+    # the blocks a window layer is given are no wider than its window
+    fa.flash_attention_segmented(q, k, v, seg, True, window=256)
+    assert fa._LAST_BLOCKS["fwd"] == (256, 256)

@@ -201,3 +201,83 @@ def test_forced_kernels_under_a_model_parallel_mesh(devices, kv):
     assert report["kv_write"] == report["decode"] == "pallas"
     assert tp.cache.k.sharding.spec[2] == "model" if kv is None else \
         tp.cache.k.data.sharding.spec[2] == "model"
+
+
+# ---------------------------------------------------------------------------
+# grouped KV heads and a window (a planned model's layers)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("window", [None, 20, 16], ids=["full", "w20", "w16"])
+@pytest.mark.parametrize("group", [1, 6, 9])
+def test_grouped_heads_and_window_kernel_matches_xla(group, window):
+    """G = 2 KV heads under 2 * group query heads (6 and 9 a group are
+    Laguna's), window off, not page-aligned (20) and page-aligned (16):
+    the kernel in interpret mode against the XLA gather, on layer 1 of
+    stacked pools, with an inactive row and rows shorter and longer than
+    the window."""
+    rng = np.random.default_rng(0)
+    G, d, ps, n_pages = 2, 16, 8, 6
+    q = jnp.asarray(rng.normal(size=(4, G * group, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 30, G, ps, d)), jnp.float32)
+            for _ in range(2))
+    table = jnp.asarray(rng.permutation(np.arange(1, 30))[:4 * n_pages]
+                        .reshape(4, n_pages), jnp.int32)
+    lengths = jnp.asarray([45, 0, 7, 24], jnp.int32)
+    got, want = (paged_decode_attention(q, k, v, table, lengths, layer=1,
+                                        window=window, backend=b)
+                 for b in ("pallas", "xla"))
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+    assert not np.asarray(got[1]).any()              # the inactive row
+    # brute force for row 0: head h reads KV head h // group, the last
+    # `window` positions only
+    n = int(lengths[0])
+    keys = np.moveaxis(np.asarray(k[1, table[0]]), 1, 0).reshape(G, -1, d)
+    vals = np.moveaxis(np.asarray(v[1, table[0]]), 1, 0).reshape(G, -1, d)
+    lo = 0 if window is None else max(0, n - window)
+    for h in (0, G * group - 1):
+        s = keys[h // group, lo:n] @ np.asarray(q[0, h]) / np.sqrt(d)
+        p = np.exp(s - s.max())
+        np.testing.assert_allclose(got[0, h],
+                                   (p / p.sum()) @ vals[h // group, lo:n],
+                                   atol=2e-6, rtol=0)
+
+
+def test_pages_behind_the_window_are_never_read():
+    """With a window the work list starts at the page of position
+    `length - window`: table entries before it may be anything (the
+    engine has given those pages back) and the step count is the live
+    pages alone."""
+    from deeperspeed_tpu.ops.pallas.decode_attention import decode_steps
+    rng = np.random.default_rng(1)
+    G, d, ps, window = 2, 16, 8, 16
+    q = jnp.asarray(rng.normal(size=(3, 12, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(20, G, ps, d)), jnp.float32)
+            for _ in range(2))
+    table = np.arange(1, 19, dtype=np.int32).reshape(3, 6)
+    lengths = jnp.asarray([45, 17, 3], jnp.int32)
+    want = paged_decode_attention(q, k, v, jnp.asarray(table), lengths,
+                                  window=window, backend="pallas")
+    first = np.maximum(np.asarray(lengths) - window, 0) // ps
+    released = table.copy()
+    for b in range(3):
+        released[b, :first[b]] = 0
+    assert (released == 0).sum() == 3
+    got = paged_decode_attention(q, k, v, jnp.asarray(released), lengths,
+                                 window=window, backend="pallas")
+    np.testing.assert_array_equal(got, want)
+    n_steps, row, start = decode_steps(lengths, ps, 6, window)
+    # rows of 45, 17, 3 tokens: pages 3..5, 0..2, 0 -> 3 + 3 + 1 steps
+    assert int(n_steps) == 7 and list(np.asarray(start)) == [0, 3, 6]
+    assert int(decode_steps(lengths, ps, 6)[0]) == 6 + 3 + 1
+
+
+def test_attention_refuses_heads_that_do_not_group():
+    q = jnp.zeros((1, 5, 16))
+    pool = jnp.zeros((4, 2, 8, 16))
+    with pytest.raises(ValueError, match="KV\\s+heads divide"):
+        paged_decode_attention(q, pool, pool, jnp.zeros((1, 2), jnp.int32),
+                               jnp.ones((1,), jnp.int32))
+    with pytest.raises(ValueError, match="window"):
+        paged_decode_attention(jnp.zeros((1, 4, 16)), pool, pool,
+                               jnp.zeros((1, 2), jnp.int32),
+                               jnp.ones((1,), jnp.int32), window=0)

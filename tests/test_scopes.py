@@ -30,7 +30,8 @@ import jax
 import deeperspeed_tpu
 from deeperspeed_tpu import scopes
 from deeperspeed_tpu.inference import InferenceEngine
-from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
+                                             LayerSpec)
 
 PACKAGE = os.path.dirname(os.path.abspath(deeperspeed_tpu.__file__))
 
@@ -46,6 +47,21 @@ MOE_CFG = GPTNeoXConfig(
     norm="rmsnorm", use_bias=False, qk_norm=True, hidden_act="silu",
     ffn_gated=True, ffn_width=64, moe_num_experts=8, moe_top_k=2,
     moe_dropless=True)
+# a planned block (Laguna's): window and full layers over grouped KV
+# heads, the per-head gate, a held share of the experts and a shared one
+PLAN_CFG = GPTNeoXConfig(
+    vocab_size=512, hidden_size=128, num_layers=3, num_heads=2,
+    max_seq_len=256, use_parallel_residual=False, norm="rmsnorm",
+    use_bias=False, hidden_act="silu", ffn_gated=True, ffn_width=64,
+    layer_plan=(LayerSpec(attn="full", heads=2, ffn="dense"),
+                LayerSpec(attn="window", heads=4, ffn="experts"),
+                LayerSpec(attn="window", heads=4, ffn="experts")),
+    attn_head_dim=64, num_kv_heads=2, attn_window=32, attn_gate="per-head",
+    moe_num_experts=8, moe_top_k=2, moe_dropless=True,
+    moe_norm_topk_prob=True, moe_expert_width=64, moe_shared_width=64,
+    moe_routing_scale=2.5, moe_held=(0, 4))
+PLAN_SCOPES = MODEL_SCOPES + MOE_SCOPES + ["ds.attn_gate", "ds.moe_shared",
+                                           "ds.kv_write"]
 PROGRAMS = {
     # the tiled kernels: 256 tokens in blocks of 128
     "train": MODEL_SCOPES + ["ds.flash_fwd", "ds.flash_bwd_dq",
@@ -66,6 +82,11 @@ PROGRAMS = {
                                                 "ds.kv_write"],
     "moe_decode": MODEL_SCOPES + MOE_SCOPES + ["ds.paged_decode",
                                                "ds.kv_write"],
+    # a window layer's kernels run under their own names, beside the
+    # full layer's
+    "plan_prefill": PLAN_SCOPES + ["ds.flash_fwd", "ds.flash_fwd_window"],
+    "plan_decode": PLAN_SCOPES + ["ds.paged_decode",
+                                  "ds.paged_decode_window"],
 }
 
 
@@ -103,12 +124,12 @@ def serve_texts(kernel, cfg=None):
             "prefill_lengths": [128], "prefill_batch_sizes": [1],
             "decode_batch_sizes": [2], "kernel": kernel}})
     rng = jax.random.PRNGKey(0)
-    pools = (engine.cache.k, engine.cache.v)
+    pools = engine._pools()
 
     def text(fn, tokens, lengths, page_table, *carry):
         return fn.lower(engine.params, engine.params_stacked, tokens,
-                        lengths, page_table, *pools,
-                        rng, *carry).compile().as_text()
+                        lengths, engine._table_args(page_table, page_table),
+                        *pools, rng, *carry).compile().as_text()
     prefill = text(engine._prefill_fn(1, 128), np.zeros((1, 128), np.int32),
                    np.ones((1,), np.int32), np.zeros((1, 8), np.int32))
     decode = text(engine._decode_fn(2), np.zeros((2,), np.int32),
@@ -116,7 +137,7 @@ def serve_texts(kernel, cfg=None):
                   np.zeros((2, engine.n_pages_max), np.int32),
                   # the tokens of the decode in flight, and each row's
                   # place in them (-1: the host's token stands)
-                  np.zeros((2,), np.int32), np.full((2,), -1, np.int32))
+                  engine._zero_carry(), np.full((2,), -1, np.int32))
     return prefill, decode
 
 
@@ -130,6 +151,8 @@ def lower_all():
     texts["prefill"], texts["decode"] = serve_texts("pallas")
     texts["decode_xla"] = serve_texts("xla")[1]
     texts["moe_prefill"], texts["moe_decode"] = serve_texts("pallas", MOE_CFG)
+    texts["plan_prefill"], texts["plan_decode"] = serve_texts("pallas",
+                                                              PLAN_CFG)
     return texts
 
 
@@ -238,8 +261,14 @@ def test_pallas_call_is_named_from_the_table(where, name, fn, tree):
                and isinstance(c.func, ast.Name) and c.func.id == fn.name]
     assert callers, f"{where}: {fn.name} is never called"
     for call in callers:
-        given = call.args[position]
-        assert isinstance(given, ast.Constant) and given.value in kernels, \
+        given = next((k.value for k in call.keywords if k.arg == name.id),
+                     None) or call.args[position]
+        # a literal, or a choice between two (a window layer's kernel is
+        # the full layer's under its own name)
+        choices = [given.body, given.orelse] \
+            if isinstance(given, ast.IfExp) else [given]
+        assert all(isinstance(c, ast.Constant) and c.value in kernels
+                   for c in choices), \
             f"{where}: {fn.name} called at line {call.lineno} without " \
             f"a kernel scope"
 

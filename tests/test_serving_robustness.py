@@ -670,7 +670,11 @@ class TestServingWatchdog:
         assert eng._drain_requested              # emergency flush armed
 
     def test_compile_is_not_a_hang(self):
-        eng, cfg, _ = _tiny_engine(hang_timeout_s=0.001)
+        # 0.2 s: under a cold program's compile (a second or so), and far
+        # over the read-back of the warm decode left in flight, for which
+        # the watchdog IS armed (at 1 ms that read-back lost the race on a
+        # loaded machine, which is not what this test is about)
+        eng, cfg, _ = _tiny_engine(hang_timeout_s=0.2)
         rng = np.random.default_rng(9)
         # every program cold: the watchdog must never arm on the
         # first (compiling) call of a bucket

@@ -472,6 +472,130 @@ def test_moe_serving_programs_leave_the_experts_in_place(on_chip, v5e_2x2,
 
 
 # ---------------------------------------------------------------------------
+# a planned model (Laguna-S-2.1) at its published widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)],
+                         ids=["full_48", "window_72"])
+def test_grouped_window_paged_decode_compiles(on_chip, heads, window):
+    """The paged kernel at Laguna's decode shapes: 48 (full) or 72
+    (window 512) query heads over 8 KV heads of 128, batch 32, a table of
+    136 pages of 64, the layer a traced scalar."""
+    B, G, D, ps = 32, 8, 128, 64
+    pool = ((2, 289, G, ps, D), BF16)
+
+    def decode(q, table, lengths, layer, k, v):
+        return decode_attention.paged_decode_attention_pallas(
+            q, k, v, table, lengths, D ** -0.5, layer=layer, window=window)
+
+    assert_kernel(on_chip(decode, ((B, heads, D), BF16),
+                          ((B, 136), jnp.int32), ((B,), jnp.int32),
+                          ((), jnp.int32), pool, pool))
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)],
+                         ids=["full_48", "window_72"])
+def test_grouped_window_flash_forward_compiles(on_chip, heads, window):
+    """The segmented forward at Laguna's prefill shapes: one row of 8,192
+    tokens, 48 or 72 query heads over 8 KV heads of 128."""
+    S, G, D = 8192, 8, 128
+
+    def prefill(q, k, v, seg):
+        return fa.flash_attention_segmented(q, k, v, seg, True,
+                                            window=window)
+
+    assert_kernel(on_chip(prefill, ((1, S, heads, D), BF16),
+                          ((1, S, G, D), BF16), ((1, S, G, D), BF16),
+                          ((1, S), jnp.int32)))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_planned_serving_programs_compile_and_hold_the_weights_once(
+        on_chip, v5e_2x2, program):
+    """The engine's decode and prefill programs for Laguna's block at the
+    published widths (hidden 3072, head dim 128, 8 KV heads under 48 / 72
+    query heads, window 512, dense width 12288, experts of width 1024, 10
+    a token of 256 scored, a shared expert; 16 experts held and a small
+    vocabulary), five layers in the published order, compiled for the
+    described v5e from shapes alone. Both attention kernels run under
+    both names, no instruction's result has the shape of a kind's
+    experts, and the engine's stacks are the caller's arrays."""
+    from jax.sharding import SingleDeviceSharding
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
+                                                 LayerSpec)
+    yarn = ("yarn", 128, 8192, 32, 1, 1.4852030263919618)
+    full = dict(attn="full", heads=48, rotary_pct=0.5, rotary_base=5e5,
+                rope=yarn)
+    window = dict(attn="window", heads=72, rotary_pct=1.0, rotary_base=1e4)
+    held, batch, seqlen, page_size = 16, 32, 1024, 64
+    cfg = GPTNeoXConfig(
+        vocab_size=1024, hidden_size=3072, num_layers=5, num_heads=48,
+        max_seq_len=2048, use_parallel_residual=False, norm="rmsnorm",
+        use_bias=False, hidden_act="silu", ffn_gated=True, ffn_width=12288,
+        layernorm_eps=1e-6,
+        layer_plan=(LayerSpec(ffn="dense", **full),
+                    *(LayerSpec(ffn="experts", **window),) * 3,
+                    LayerSpec(ffn="experts", **full)),
+        attn_head_dim=128, num_kv_heads=8, attn_window=512,
+        attn_gate="per-head", moe_num_experts=256, moe_top_k=10,
+        moe_dropless=True, moe_norm_topk_prob=True, moe_expert_width=1024,
+        moe_shared_width=1024, moe_routing_scale=2.5, moe_held=(0, held))
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, BF16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page_size,
+        "num_pages": 2048 // page_size + 1, "max_batch_size": batch,
+        "token_budget": 2048, "prefill_lengths": [seqlen],
+        "prefill_batch_sizes": [1], "decode_batch_sizes": [batch]}})
+    assert all(a is b for a, b in zip(
+        jax.tree_util.tree_leaves(params["stacks"]),
+        jax.tree_util.tree_leaves(engine.params_stacked)))
+    assert engine.window_cache.num_pages == batch * 9 + 1
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    shapes = functools.partial(jax.tree_util.tree_map, shape_of)
+    carry = ()
+    if program == "decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch), ints(batch),
+                  (ints(batch, engine.n_pages_max),) * 2)
+        carry = (ints(batch + 1), ints(batch))
+        kernels = ("ds.paged_decode", "ds.paged_decode_window",
+                   "ds.kv_write", "ds.grouped_matmul")
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1),
+                  (ints(1, seqlen // page_size),) * 2)
+        kernels = ("ds.flash_fwd", "ds.flash_fwd_window",
+                   "ds.grouped_matmul")
+    text = fn.lower(
+        shapes(engine.params), shapes(engine.params_stacked), *inputs,
+        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        *carry).compile().as_text()
+    for name in kernels:
+        assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
+    expert_shaped = re.compile(
+        rf"bf16\[(?:\d,)?{held},(?:3072,2048|1024,3072)\]")
+    moved = []
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        if m and expert_shaped.search(m["type"]) and \
+                m["op"] not in CARRIES and "tpu_custom_call" not in line:
+            moved.append((m["op"], m["type"][:60]))
+    assert not moved, moved
+
+
+# ---------------------------------------------------------------------------
 # grouped matmul, int8 weight matmul, fused Adam
 # ---------------------------------------------------------------------------
 
