@@ -15,7 +15,11 @@ def dispatch_report():
 
     Keys (present once the corresponding kernel has dispatched):
     ``flash``: {"fwd": (bq, bk), "fwd_variant", "dkv", "dq",
-    "bwd_variant"}; ``attention``: {"attention" / "sparse_attention":
+    "bwd_variant", "masked_tiles": {"fwd" / "dkv" / "dq": (masked,
+    launched) tiles a head of the last tiled call}, "bodies_built":
+    {"fwd" / "dkv" / "dq": (times the kernel's body was built in this
+    process, host seconds that took): set-up every run pays, compile
+    cache or not}}; ``attention``: {"attention" / "sparse_attention":
     backend} of the model-side dispatchers; ``decode_attention``:
     {"decode": backend, "decode_kv": pool dtype — "int8" when the paged
     pools are quantized, "kv_write": backend of the one-token row
@@ -26,10 +30,13 @@ def dispatch_report():
     """
     from .pallas.decode_attention import _LAST_BACKEND
     from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
-    from .pallas.flash_attention import _LAST_BLOCKS, _XLA_NOTED
+    from .pallas.flash_attention import (_BODY_BUILDS, _LAST_BLOCKS,
+                                         _LAST_MASKED, _XLA_NOTED)
     from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
     from .pallas.quant_matmul import _LAST_BACKEND as _QMM_BACKEND
-    return {"flash": dict(_LAST_BLOCKS),
+    return {"flash": dict(_LAST_BLOCKS, masked_tiles=dict(_LAST_MASKED),
+                          bodies_built={k: (n, round(t, 3)) for k, (n, t)
+                                        in _BODY_BUILDS.items()}),
             "attention": dict(_ATTN_BACKEND),
             "decode_attention": dict(_LAST_BACKEND),
             "quant_matmul": dict(_QMM_BACKEND),

@@ -15,12 +15,11 @@ less residual HBM traffic); backward recomputes probabilities blockwise
 (no SxS residual).
 
 Block sizes default to 1024x1024, auto-fitted down to the largest
-128-multiple dividing the sequence length. Bigger blocks mean fewer grid
-instances; per-instance fixed cost (DMA setup + kernel entry, measured
-~6us/instance on v5e) dominates d=64-per-head shapes, so the fewest,
-fattest instances win — 1024-blocks measured ~20% faster than 512 at
-GPT-small shapes. Matmuls run at the input dtype (bf16 → full MXU rate)
-with fp32 accumulation; softmax math is fp32.
+128-multiple dividing the sequence length (`ops/autotune.py`): a fat
+block pushes each weight tile through the MXU for more rows, and the
+tile body walks it in strips (below), so a grid step's cost is its
+matmuls' and not its size's. Matmuls run at the input dtype (bf16 → full
+MXU rate) with fp32 accumulation; softmax math is fp32.
 
 Causal grids are COMPACTED (splash-attention style): instead of an
 n_q x n_k grid whose upper-triangle instances are gated off in-kernel
@@ -39,9 +38,11 @@ Off a TPU the kernels run in interpreter mode (slow, test-only).
 
 import functools
 import math
+import time
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -50,7 +51,7 @@ from ...compat import CompilerParams
 from ..autotune import FLASH_BLOCK_K as BLOCK_K, FLASH_BLOCK_Q as BLOCK_Q, \
     fit_block as _fit_block, flash_blocks
 
-LANES = 128  # TPU minor-dim tile; in-kernel row stats are lane-broadcast
+LANES = 128  # TPU minor-dim tile
 NEG_INF = -1e30
 
 _DIMSEM = CompilerParams(
@@ -155,6 +156,34 @@ def causal_grid_size(s, block_q=BLOCK_Q, block_k=BLOCK_K):
 # assert on this instead of re-deriving lowering internals.
 _LAST_GRIDS = {}
 
+# Ditto, (masked, launched) tiles per (batch x head) of the most recent
+# tiled call per kernel family: how many take the masked body
+# (`masked_tile_count`).
+_LAST_MASKED = {}
+
+# The set-up account: [times built, host seconds] of each tiled kernel's
+# BODY in this process. A body is python that unrolls pairs x strips x
+# diagonal bodies into one jaxpr, which Pallas then lowers to Mosaic MLIR:
+# host time on every run, compile cache hit or not (the cache's key is
+# computed from the lowered module). `_fwd_call` / `_bwd_calls` keep one
+# `pallas_call` a call signature for the life of the process, so a model
+# of 24 unrolled layers builds each body once and not 24 times (PERF.md,
+# PR 34). `ops.dispatch_report()["flash"]["bodies_built"]` reads it.
+_BODY_BUILDS = {"fwd": [0, 0.0], "dkv": [0, 0.0], "dq": [0, 0.0]}
+
+
+def _accounted(kind, kernel):
+    """`kernel`, every trace of it entered in `_BODY_BUILDS[kind]`."""
+    def body(*refs):
+        t0 = time.perf_counter()
+        try:
+            kernel(*refs)
+        finally:
+            built = _BODY_BUILDS[kind]
+            built[0] += 1
+            built[1] += time.perf_counter() - t0
+    return body
+
 # Ditto for dispatched block geometry: {"fwd"/"dkv"/"dq": (bq, bk)} plus
 # {"fwd_variant"/"bwd_variant": "single"/"trapezoid"/"dense"} of the most
 # recent call (`ops.dispatch_report()`).
@@ -180,42 +209,45 @@ def _log_first_dispatch():
 
 
 def _index_adapter(compact, kv_major=False):
-    """BlockSpec index maps are written once, in dense (bh, i, j) form;
-    this returns the wrapper that adapts them to the grid in use.
-    Identity for dense grids. For compacted grids the flat index t
-    resolves through the prefetched LUTs — (i, j) = (qi, ki) for the
-    row-major fwd/dq schedules, (ki, qi) for the column-major dkv
-    schedule (``kv_major``)."""
-    if not compact:
-        return lambda f: f
+    """BlockSpec index maps are written once, as functions of
+    (bh, qi, ki); this returns the wrapper that adapts them to the grid
+    in use. The flat index t of a compacted grid resolves through the
+    prefetched LUTs, whichever order the schedule has; a dense grid
+    hands (bh, qi, ki) itself, or (bh, ki, qi) where it is ``kv_major``
+    (the dkv kernel's)."""
+    if compact:
+        return lambda f: lambda bh, t, qm, km: f(bh, qm[t], km[t])
     if kv_major:
-        return lambda f: lambda bh, t, qm, km: f(bh, km[t], qm[t])
-    return lambda f: lambda bh, t, qm, km: f(bh, qm[t], km[t])
+        return lambda f: lambda bh, ki, qi: f(bh, qi, ki)
+    return lambda f: f
 
 
-def _tiled_call(name, kernel, compact, grid, in_specs, out_specs, scratch,
-                out_shape, maps):
+def _tiled_call(kind, name, kernel, compact, grid, in_specs, out_specs,
+                scratch, out_shape, maps, interpret):
     """One pallas_call for both grid flavors: compacted trapezoid
     (scalar-prefetch LUT grid spec) or dense, named `name` (a kernel
-    scope of `scopes.SCOPES`) and run under that scope. Returns the
-    function of the kernel's inputs."""
+    scope of `scopes.SCOPES`) and run under that scope; its body's traces
+    are entered under `kind` in the set-up account. Returns the function
+    of the kernel's inputs: kept by the caller's cache, every call of it
+    at one signature and in one trace context binds the one traced body
+    (`pallas_call` is a `jit(inline=True)` of its own)."""
     if compact:
         call_kw = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch))
-        prefetch = tuple(jnp.asarray(m) for m in maps)
     else:
         call_kw = dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
                        scratch_shapes=scratch)
-        prefetch = ()
     call = pl.pallas_call(
-        kernel, out_shape=out_shape, name=name,
+        _accounted(kind, kernel), out_shape=out_shape, name=name,
         compiler_params=_DIMSEM_FLAT if compact else _DIMSEM,
-        interpret=_interpret(), **call_kw)
+        interpret=interpret, **call_kw)
 
     def run(*inputs):
         with scopes.scope(name):
-            return call(*prefetch, *inputs)
+            # the LUTs as the numpy arrays they are: constants of
+            # whichever trace calls, never an array kept from another
+            return call(*maps, *inputs)
     return run
 
 
@@ -228,45 +260,17 @@ def flash_attention_supported(shape, block_q=BLOCK_Q, block_k=BLOCK_K):
         d in (64, 128, 256)
 
 
-def _causal_mask(s, qi, ki, block_q, block_k, window=None):
-    """Key j is visible to query i iff j <= i, and under a `window` also
-    i - j < window."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + \
-        qi * block_q
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + \
-        ki * block_k
-    seen = rows >= cols
-    if window is not None:
-        seen = seen & (rows - cols < window)
-    return jnp.where(seen, s, NEG_INF)
+def _causal_mask(s):
+    """Key j of a whole-sequence tile is visible to query i iff j <= i."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(rows >= cols, s, NEG_INF)
 
 
 MASK_GRAIN = 128  # layout-mask granularity (one sparsity block)
 
 
-def _apply_layout_mask(s, m_ref, qi, ki, block_q, block_k):
-    """Mask scores with the head's [S/128, S/128] block-activity map
-    (whole map in SMEM; scalar reads take dynamic indices — the same
-    mechanism as the block-sparse kernels' LUTs). Inactive 128x128
-    sub-blocks of the [BQ, BK] tile get NEG_INF; the expansion uses
-    static sub-block slices (no in-kernel gather/reshape needed)."""
-    mq, mk = block_q // MASK_GRAIN, block_k // MASK_GRAIN
-    rows = []
-    for a in range(mq):
-        tiles = []
-        for c in range(mk):
-            penalty = jnp.where(m_ref[0, qi * mq + a, ki * mk + c] > 0,
-                                0.0, NEG_INF)
-            tiles.append(jnp.full((MASK_GRAIN, MASK_GRAIN), penalty,
-                                  jnp.float32))
-        rows.append(tiles[0] if mk == 1 else
-                    jnp.concatenate(tiles, axis=1))
-    penalty = rows[0] if mq == 1 else jnp.concatenate(rows, axis=0)
-    # additive, not select: NEG_INF + finite score stays ~NEG_INF
-    return s + penalty
-
-
-def _dropout_keep(seed, pid, row0, col0, shape, rate):
+def _dropout_keep(seed, pid, row0, col0, shape, rate, q_axis=0):
     """Deterministic keep-mask for in-kernel attention-probability
     dropout: a 2-round avalanche hash of (seed, batch*head, absolute
     row, absolute col). The same call sites in the backward kernels
@@ -276,8 +280,8 @@ def _dropout_keep(seed, pid, row0, col0, shape, rate):
     (wrapping mul/xor/shift): lowers on Mosaic AND in interpret mode
     (pltpu.prng_* has no CPU lowering). Comparison uses the low 31 bits
     so int32 arithmetic stays sign-safe."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + row0
-    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + col0
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) + row0
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) + col0
     x = rows * (-1640531527) ^ cols * (-2048144789)   # 0x9E3779B9/0x85EBCA6B
     x = x ^ (seed + pid * (-1028477387))              # 0xC2B2AE35
     x = (x ^ ((x >> 16) & 0xFFFF)) * 0x7FEB352D
@@ -374,7 +378,7 @@ def _head_fwd(q, k, v, bias_row, seed, pid, *, sm_scale, causal,
         p = jnp.concatenate(p_strips, axis=1)                 # [Sq, Sk]
     else:
         if causal:
-            s = _causal_mask(s, 0, 0, s_q, s_k)
+            s = _causal_mask(s)
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.exp(s - m)
         if use_bias:
@@ -529,21 +533,445 @@ def _fwd_single(qb, kb, vb, causal, sm_scale, s, d, interpret, kbias=None,
 
 
 # ---------------------------------------------------------------------------
+# the tile body of the tiled kernels
+# ---------------------------------------------------------------------------
+#
+# The forward and the dkv kernel hold a grid step's [block_k, block_q]
+# tile of scores TRANSPOSED: keys on sublanes, queries on lanes (s^T =
+# k q^T). A query's running maximum, denominator, lse and delta are then
+# lane-dense rows ([1, block_q]: the layout lse and delta already have in
+# HBM), a reduction over keys is elementwise across vregs with one 8-to-1
+# sublane step at its end, and the forward's output accumulates as
+# [D, block_q]. The dq kernel keeps queries on sublanes: its dS is the
+# left operand of dS K.
+#
+# The forward never holds its tile whole. It walks it in PAIRS of CHUNK
+# key rows x GROUP query columns, whose scores are one matmul (every
+# MXU gets a 128-column weight tile of q and streams the chunk's keys),
+# and each pair in STRIPS of 128 query columns: a strip's chain (mask,
+# max, exp2, sum, cast, second matmul) works on a [CHUNK, 128] fp32
+# block and its [1, 128] / [D, 128] state, in registers, instead of
+# writing 2-4 MB of fp32 scores and probabilities out to VMEM between
+# whole-tile passes. The next pair's scores are issued before this
+# pair's strips, so the MXU and the VPU overlap; the walk is unrolled at
+# trace time (a tile is 1-4 pairs), one basic block the scheduler may
+# reorder as it likes.
+#
+# A tile an edge crosses (the causal diagonal, a window's far edge, a
+# document boundary; a layout mask, a key bias or dropout always) takes
+# the MASKED body; every other tile takes the same body with no iota,
+# compare or select in it. A tile on the causal diagonal takes a masked
+# body of its own, compiled for the one offset its first query has from
+# its first key (square blocks have one such offset, 2:1 blocks two):
+# which of its blocks lie wholly beyond the diagonal is then a trace-time
+# fact, and they are not computed; nor is the causal compare made in the
+# blocks wholly before it.
+#
+# `sm_scale` never touches a tile: scores stay raw, the exponent is
+# exp2((s - m) * sm_scale * log2(e)) (the multiply `exp` would spend on
+# log2(e) anyway), the bias row is divided by the scale once a tile, and
+# dS's scale waits for the accumulators' last step.
+
+STRIP = 128   # query columns a strip: one lane tile
+CHUNK = 512   # key rows a pair: a strip's block is [CHUNK, STRIP] fp32
+GROUP = 512   # query columns a pair: one weight tile of q an MXU
+# Columns a step of the backward kernels' diagonal bodies takes: narrower
+# skips more of the dead triangle, wider pushes each weight tile for more
+# rows. dkv + dq at [16, 2048, 16, 64] on a v5e: 8.00 ms at 512, 7.35 at
+# 256, 8.44 at 128 (PERF.md, PR 33).
+DIAGONAL_GROUP = 256
+LOG2E = 1.4426950408889634
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+
+def _part(block, most):
+    """The largest 128-multiple under `most` that divides `block`."""
+    return next(n for n in range(most, 0, -128) if block % n == 0)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _when(cond, fn):
+    """`pl.when` that also takes a python bool (a trace-time fact)."""
+    if cond is True:
+        fn()
+    elif cond is not False:
+        pl.when(cond)(fn)
+
+
+# and / or / not of facts that are python bools (known at trace time) or
+# traced scalars (known a grid step)
+
+def _and(a, b):
+    if isinstance(a, bool):
+        return b if a else False
+    if isinstance(b, bool):
+        return a if b else False
+    return jnp.logical_and(a, b)
+
+
+def _not(a):
+    return (not a) if isinstance(a, bool) else jnp.logical_not(a)
+
+
+def _or(a, b):
+    return _not(_and(_not(a), _not(b)))
+
+
+def _segment_facts(sq_ref, sk_ref):
+    """(run, uniform) of a tile's query and key id slices: can the two
+    share an id (their ranges overlap; exact wherever a document's ids
+    are contiguous, as `runtime.packing` and a prefill's pad rows make
+    them, and a superset otherwise: a tile that shares none adds zeros),
+    and are all of them one id (no boundary inside the tile)."""
+    sq, sk = sq_ref[0], sk_ref[0]
+    q_lo, q_hi, k_lo, k_hi = jnp.min(sq), jnp.max(sq), jnp.min(sk), \
+        jnp.max(sk)
+    run = jnp.logical_and(q_lo <= k_hi, k_lo <= q_hi)
+    uniform = jnp.logical_and(jnp.logical_and(q_lo == q_hi, k_lo == k_hi),
+                              q_lo == k_lo)
+    return run, uniform
+
+
+def _edge_crosses(qi, ki, block_q, block_k, causal, window):
+    """Does the causal diagonal, or a window's far edge, pass through
+    tile (qi, ki)? True where some key of the tile lies after some query
+    of it, or `window` or more positions before one. `qi` / `ki` python
+    ints (the trace-time count) or traced scalars (the kernel)."""
+    if not causal:
+        return False
+    crossed = ki * block_k + block_k - 1 > qi * block_q
+    if window is not None:
+        crossed = _or(crossed,
+                      qi * block_q + block_q - 1 - ki * block_k >= window)
+    return crossed
+
+
+class _Tile:
+    """What the three kernels share of one grid step: which body the
+    tile takes, and a block's masks in the transposed orientation."""
+
+    def __init__(self, qi, ki, block_q, block_k, *, sm_scale, causal,
+                 window=None, sq_ref=None, sk_ref=None, m_ref=None,
+                 b_ref=None, seed_ref=None, dropout_rate=0.0,
+                 kseg_scr=None, kbias_scr=None):
+        self.qi, self.ki = qi, ki
+        self.block_q, self.block_k = block_q, block_k
+        self.sm_scale, self.causal, self.window = sm_scale, causal, window
+        self.sq_ref, self.sk_ref, self.m_ref, self.b_ref = \
+            sq_ref, sk_ref, m_ref, b_ref
+        self.seed_ref, self.dropout_rate = seed_ref, dropout_rate
+        # read here, at the kernel's top level: the interpreter has no
+        # program_id inside a loop body
+        self.pid = pl.program_id(0) if dropout_rate > 0.0 else None
+        self.kseg_scr, self.kbias_scr = kseg_scr, kbias_scr
+        # the tile's first query and key, and how far the key lies past
+        # the query: once a tile, not once a block
+        self.q_base, self.k_base = qi * block_q, ki * block_k
+        self.lead = self.k_base - self.q_base
+        # rows whose every key is masked must end with l == 0 (the
+        # poisoned lse): where that can happen, masked entries' exp is
+        # forced to 0 (exp2((NEG_INF - NEG_INF) * c) is 1, not 0)
+        self.zero_masked = sq_ref is not None or m_ref is not None or \
+            b_ref is not None or window is not None
+        self.run = True
+        # an edge of the geometry crosses the tile / a mask the data or
+        # the call brings applies to it
+        self.crossed = _edge_crosses(qi, ki, block_q, block_k, causal,
+                                     window)
+        self.other = False
+        if sq_ref is not None:
+            self.run, uniform = _segment_facts(sq_ref, sk_ref)
+            self.other = _not(uniform)
+        if m_ref is not None or b_ref is not None or dropout_rate > 0.0:
+            self.other = True
+        # a block of a diagonal tile that the diagonal misses is masked
+        # only where the call brings such a mask at all
+        self.any_other = self.other is not False
+
+    def bodies(self, body):
+        """Run `body(masked, offset, crossed)` as the tile takes it (not
+        at all where no id is shared). A tile the causal diagonal crosses
+        runs `body(True, offset, True)` under the offset of its first
+        query from its first key: a trace-time int, one body for each
+        offset the geometry has (`diagonal_offsets`), from which the body
+        knows which of its blocks lie beyond the diagonal. Every other
+        tile, and every tile of a windowed call or of a geometry with too
+        many offsets, runs `body(masked, None, crossed)`: `crossed` False
+        where the diagonal is known to miss it."""
+        def masked(offset, crossed):
+            def masked_body():
+                self.stage_key_columns()
+                body(True, offset, crossed)
+            return masked_body
+
+        offsets = diagonal_offsets(self.block_q, self.block_k) \
+            if self.causal and self.window is None else ()
+        for d in offsets:
+            _when(_and(self.run, self.lead == -d), masked(d, True))
+        # what is left: the tiles the diagonal misses, or every tile
+        rest = _and(self.run, _not(self.crossed)) if offsets else self.run
+        any_mask = self.other if offsets else _or(self.crossed, self.other)
+        _when(_and(rest, any_mask), masked(None, not offsets))
+        _when(_and(rest, _not(any_mask)),
+              lambda: body(False, None, not offsets))
+
+    def stage_key_columns(self):
+        """The key side's per-key rows ([1, block_k] segment ids, bias)
+        as the [block_k, 1] columns a transposed block compares and adds:
+        one relayout a masked tile, not one a block."""
+        if self.kseg_scr is not None:
+            self.kseg_scr[...] = self.sk_ref[0].reshape(self.block_k, 1)
+        if self.kbias_scr is not None:
+            self.kbias_scr[...] = (self.b_ref[0] * (1.0 / self.sm_scale)
+                                   ).reshape(self.block_k, 1)
+
+    def mask(self, s, c0, r0, causal=True, q_axis=1):
+        """Raw scores of the tile's keys r0.. x queries c0.. (python
+        ints; transposed, or with `q_axis=0` queries on sublanes), masked
+        entries at NEG_INF and the bias (over the scale) added.
+        `causal=False`: the caller knows every key of the block is at or
+        before every query of it."""
+        nq, nk = s.shape[q_axis], s.shape[1 - q_axis]
+        qs, ks = pl.ds(c0, nq), pl.ds(r0, nk)
+        masked_out = lax.full_like(s, NEG_INF)
+        if self.causal and causal:
+            rel = lax.sub(lax.broadcasted_iota(jnp.int32, s.shape, q_axis),
+                          lax.broadcasted_iota(jnp.int32, s.shape,
+                                               1 - q_axis))
+            lead = self.lead + (r0 - c0)             # key - query, block's
+            seen = lax.ge(rel, lax.broadcast(lead, s.shape))  # key <= query
+            if self.window is not None:
+                seen = seen & (rel < self.window + lead)
+            s = lax.select(seen, s, masked_out)
+        if self.sq_ref is not None:
+            if q_axis:
+                kseg, qseg = self.kseg_scr[ks, :], self.sq_ref[0, :, qs]
+                same = lax.eq(lax.broadcast_in_dim(kseg, s.shape, (0, 1)),
+                              lax.broadcast_in_dim(qseg, s.shape, (0, 1)))
+            else:
+                same = self.sq_ref[0, :, qs].reshape(-1, 1) == \
+                    self.sk_ref[0, :, ks]
+            s = lax.select(same, s, masked_out)
+        if self.m_ref is not None:
+            q0, k0 = self.q_base + c0, self.k_base + r0
+            # the head's [S/128, S/128] block-activity map in SMEM: one
+            # scalar a 128 x 128 sub-block, added (NEG_INF + a finite
+            # score stays ~NEG_INF)
+            g = MASK_GRAIN
+
+            def sub(a, b):       # sub-block a of keys, b of queries
+                part = s[a * g:(a + 1) * g, b * g:(b + 1) * g] if q_axis \
+                    else s[b * g:(b + 1) * g, a * g:(a + 1) * g]
+                return part + jnp.where(
+                    self.m_ref[0, q0 // g + b, k0 // g + a] > 0, 0.0,
+                    NEG_INF)
+
+            major, minor = (nk, nq) if q_axis else (nq, nk)
+            s = jnp.concatenate([jnp.concatenate([
+                sub(i, j) if q_axis else sub(j, i)
+                for j in range(minor // g)], axis=1)
+                for i in range(major // g)], axis=0)
+        if self.b_ref is not None:
+            s = s + (self.kbias_scr[ks, :] if q_axis else
+                     self.b_ref[0, :, ks] * (1.0 / self.sm_scale))
+        return s
+
+    def keep(self, c0, r0, shape, q_axis=1):
+        """The dropout keep-mask of a block, from the same absolute
+        (query, key) coordinates in every kernel."""
+        return _dropout_keep(
+            self.seed_ref[0], self.pid, self.q_base + c0, self.k_base + r0,
+            shape, self.dropout_rate, q_axis=q_axis)
+
+
+MAX_DIAGONAL_BODIES = 4
+
+
+def diagonal_offsets(block_q, block_k):
+    """The offsets (first query - first key) a tile the causal diagonal
+    crosses can have: the multiples of gcd(block_q, block_k) in
+    (-block_q, block_k). One for square blocks (0), two at 2:1; empty
+    where a geometry would need more than MAX_DIAGONAL_BODIES bodies
+    (its crossed tiles then take the one masked body, every block)."""
+    g = math.gcd(block_q, block_k)
+    offsets = tuple(range(g - block_q, block_k, g))
+    return offsets if len(offsets) <= MAX_DIAGONAL_BODIES else ()
+
+
+def masked_tile_count(n_q, n_k, block_q, block_k, causal, window=None,
+                      always=False):
+    """(masked, launched): of the tiles a call launches per (batch x
+    head), how many an edge of the GEOMETRY crosses and so take the
+    masked body (`always`: a layout mask, a key bias or dropout sends
+    every tile there). A segmented call adds the tiles a document
+    boundary crosses, which only the data knows."""
+    if causal:
+        qmap, kmap = causal_grid_maps(n_q, n_k, block_q, block_k, "row",
+                                      window)
+        tiles = list(zip(qmap.tolist(), kmap.tolist()))
+    else:
+        tiles = [(qi, ki) for qi in range(n_q) for ki in range(n_k)]
+    masked = sum(1 for qi, ki in tiles if always or _edge_crosses(
+        qi, ki, block_q, block_k, causal, window))
+    return masked, len(tiles)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
-                use_seg=False, use_mask=False, use_bias=False,
-                dropout_rate=0.0, compact=False, window=None):
+                use_mask=False, use_bias=False, dropout_rate=0.0,
+                compact=False, window=None):
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
-    sq_ref = next(it) if use_seg else None
-    sk_ref = next(it) if use_seg else None
     m_ref = next(it) if use_mask else None
     b_ref = next(it) if use_bias else None
     seed_ref = next(it) if dropout_rate > 0.0 else None
+    o_ref, lse_ref = next(it), next(it)
+    m_scr, l_scr, acc_scr = next(it), next(it), next(it)
+    kbias_scr = next(it) if use_bias else None
+    if compact:
+        # flat trapezoidal schedule: (qi, ki) from the prefetched LUTs;
+        # the row ends at its causal k-extent, not at n_k - 1
+        t = pl.program_id(1)
+        qi, ki = qmap_ref[t], kmap_ref[t]
+        last_k = jnp.minimum(n_k - 1,
+                             (qi * block_q + block_q - 1) // block_k)
+    else:
+        qi = pl.program_id(1)
+        ki = pl.program_id(2)
+        last_k = pl.num_programs(2) - 1
+
+    # a window's rows start at their band's first tile (compact only)
+    @pl.when(ki == _first_k(qi, block_q, block_k, window))
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    tile = _Tile(qi, ki, block_q, block_k, sm_scale=sm_scale,
+                 causal=causal, window=window, m_ref=m_ref, b_ref=b_ref,
+                 seed_ref=seed_ref, dropout_rate=dropout_rate,
+                 kbias_scr=kbias_scr)
+    c2 = jnp.float32(sm_scale * LOG2E)
+    ck, gw = _part(block_k, CHUNK), _part(block_q, GROUP)
+    pairs = [(r0, g0) for g0 in range(0, block_q, gw)
+             for r0 in range(0, block_k, ck)]
+
+    def scores(r0, g0):
+        # raw, transposed: keys r0.. x queries g0..  [ck, gw]
+        return _dot(k_ref[0, r0:r0 + ck, :], q_ref[0, g0:g0 + gw, :], _NT)
+
+    def strip(sT, r0, c0, masked, diagonal):
+        """One block of the online softmax: sT the raw scores of the
+        keys r0.. (as many as sT has rows) x the queries c0.. + STRIP; m /
+        l [1, w], acc [D, w]. `diagonal`: the causal compare is needed.
+        Written in `lax` primitives with explicit broadcasts: a kernel
+        unrolls dozens of strips and a `jnp` operator costs ten times a
+        primitive's bind to trace (PERF.md, PR 33: set-up)."""
+        cols = pl.ds(c0, STRIP)
+        rows = sT.shape[0]
+        m, l, acc = m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]
+        if masked:
+            sT = tile.mask(sT, c0, r0, diagonal)
+        m_new = lax.max(m, lax.reduce_max(sT, (0,)).reshape(1, STRIP))
+        alpha = lax.exp2(lax.mul(lax.sub(m, m_new), c2))
+        pT = lax.exp2(lax.mul(
+            lax.sub(sT, lax.broadcast_in_dim(m_new, sT.shape, (0, 1))), c2))
+        if masked and tile.zero_masked:
+            pT = lax.select(lax.le(sT, jnp.float32(NEG_INF * 0.5)),
+                            lax.full_like(pT, 0.0), pT)
+        l_scr[:, cols] = lax.add(
+            lax.mul(alpha, l), lax.reduce_sum(pT, (0,)).reshape(1, STRIP))
+        m_scr[:, cols] = m_new
+        if dropout_rate > 0.0:
+            # post-l: the denominator sums the undropped probabilities
+            pT = jnp.where(tile.keep(c0, r0, pT.shape),
+                           pT * (1.0 / (1.0 - dropout_rate)), 0.0)
+        v = v_ref[0, pl.ds(r0, rows), :]
+        acc_scr[:, cols] = lax.add(
+            lax.mul(acc, lax.broadcast_in_dim(alpha, acc.shape, (0, 1))),
+            _dot(v, pT.astype(v.dtype), _TN))
+
+    def live_rows(r0, c0, offset, crossed):
+        """Of the chunk at key r0, the rows a strip at query c0 sees any
+        of (a multiple of 128), and whether the diagonal may pass through
+        them. `offset` None: all of them, and it may if it may cross the
+        tile at all."""
+        if offset is None:
+            return ck, crossed
+        last = offset + c0 + STRIP - 1 - r0        # the last key it sees
+        return max(0, min(ck, last + 1)), last < ck + STRIP - 1
+
+    def body(masked, offset, crossed):
+        live = [(r0, g0) for r0, g0 in pairs
+                if live_rows(r0, g0 + gw - STRIP, offset, crossed)[0]]
+        ahead = scores(*live[0])
+        for i, (r0, g0) in enumerate(live):
+            sT = ahead
+            if i + 1 < len(live):
+                # the next pair's scores go to the MXU before this
+                # pair's softmax starts on the VPU
+                ahead = scores(*live[i + 1])
+            for c in range(0, gw, STRIP):
+                rows, diagonal = live_rows(r0, g0 + c, offset, crossed)
+                if rows:
+                    strip(lax.slice(sT, (0, c), (rows, c + STRIP)), r0, g0 + c,
+                          masked and (diagonal or tile.any_other),
+                          diagonal)
+
+    tile.bodies(body)
+
+    @pl.when(ki == last_k)
+    def _finalize():
+        l = l_scr[...]                                         # [1, BQ]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[...] / l_safe).T.astype(o_ref.dtype)
+        # Dead rows (no visible key: a layout mask, a pad row's window)
+        # get POISONED lse (+1e30) so backward's exp(s - lse) is exactly
+        # 0, the block-sparse kernels' invariant.
+        lse_ref[0] = jnp.where(l == 0.0, -NEG_INF,
+                               m_scr[...] * sm_scale + jnp.log(l_safe))
+
+
+# A SEGMENTED forward (a serving prefill, a packed batch) keeps the
+# whole-tile body: one max, exp and sum over the [block_q, block_k] tile,
+# lane-broadcast running stats. A serving engine builds a prefill program
+# a bucket and a kernel a layer kind in each (24 kernels in Laguna's
+# cell), and the strip walk's unrolled bodies doubled that cell's warm
+# set-up (47.8 -> 100.8 s; a loop over pairs with four strips unrolled
+# still read 55 s and ran no faster than this body: PERF.md, PR 33). The
+# backward of a segmented call takes the new tile bodies.
+
+def _window_mask(s, qi, ki, block_q, block_k, window=None):
+    """Key j is visible to query i iff j <= i, and under a `window` also
+    i - j < window."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + \
+        qi * block_q
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + \
+        ki * block_k
+    seen = rows >= cols
+    if window is not None:
+        seen = seen & (rows - cols < window)
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _fwd_segmented_kernel(*refs, sm_scale, causal, block_q, block_k,
+                          n_k=None, compact=False, window=None):
+    it = iter(refs)
+    if compact:
+        qmap_ref, kmap_ref = next(it), next(it)
+    q_ref, k_ref, v_ref, sq_ref, sk_ref = (next(it) for _ in range(5))
     o_ref, lse_ref = next(it), next(it)
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
     if compact:
@@ -565,20 +993,11 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal: block row qi attends to block cols ki with
-    # ki*block_k <= qi*block_q + block_q - 1. Compacted schedules only
-    # ever launch such tiles, so no gate is needed there.
-    run = True
-    if causal and not compact:
-        run = ki * block_k <= qi * block_q + (block_q - 1)
-    seg_eq = None
-    if use_seg:
-        # [BQ, 1] vs [1, BK] segment-id equality: the elementwise mask
-        # AND the block-level skip — a tile whose q and k blocks share
-        # no document runs NO matmul/softmax work (the compare itself is
-        # O(BQ·BK) VPU next to the O(BQ·BK·D) MXU work it gates)
-        seg_eq = sq_ref[0].reshape(-1, 1) == sk_ref[0]
-        run = jnp.logical_and(run, jnp.any(seg_eq))
+    # [BQ, 1] vs [1, BK] segment-id equality: the elementwise mask AND the
+    # block-level skip — a tile whose q and k blocks share no document
+    # runs NO matmul/softmax work
+    seg_eq = sq_ref[0].reshape(-1, 1) == sk_ref[0]
+    run = jnp.any(seg_eq)
 
     @pl.when(run)
     def _compute():
@@ -590,13 +1009,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale    # [BQ, BK]
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, window)
-        if seg_eq is not None:
-            s = jnp.where(seg_eq, s, NEG_INF)
-        if m_ref is not None:
-            s = _apply_layout_mask(s, m_ref, qi, ki, block_q, block_k)
-        if b_ref is not None:
-            s = s + b_ref[0]                                  # [1, BK] bcast
+            s = _window_mask(s, qi, ki, block_q, block_k, window)
+        s = jnp.where(seg_eq, s, NEG_INF)
 
         m_prev = m_scr[:, :1]                                 # [BQ, 1]
         l_prev = l_scr[:, :1]
@@ -604,21 +1018,14 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)                       # [BQ, 1]
         p = jnp.exp(s - m_new)                                # [BQ, BK]
-        if seg_eq is not None or m_ref is not None or \
-                b_ref is not None or window is not None:
-            # rows with EVERY entry masked would otherwise see
-            # exp(s - max) == 1 uniformly; zero masked entries so l==0
-            # flags the dead row (poisoned-lse convention)
-            p = jnp.where(s <= NEG_INF * 0.5, 0.0, p)
+        # rows with EVERY entry masked would otherwise see exp(s - max)
+        # == 1 uniformly; zero masked entries so l == 0 flags the dead
+        # row (poisoned-lse convention)
+        p = jnp.where(s <= NEG_INF * 0.5, 0.0, p)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
 
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        if dropout_rate > 0.0:
-            # post-l (denominator sums undropped p); absolute tile
-            # coordinates so the backward kernels regenerate this mask
-            p = _apply_dropout(p, seed_ref[0], pl.program_id(0),
-                               qi * block_q, ki * block_k, dropout_rate)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [BQ, D]
@@ -636,6 +1043,42 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         # the block-sparse kernels' invariant.
         lse = jnp.where(l == 0.0, -NEG_INF, m_scr[:, :1] + jnp.log(l_safe))
         lse_ref[0] = lse.reshape(1, -1)
+
+
+def _key_column_scratch(block_k, use_seg, use_bias):
+    """The [block_k, 1] columns `_Tile.stage_key_columns` fills."""
+    return [pltpu.VMEM((block_k, 1), jnp.int32)] * use_seg + \
+        [pltpu.VMEM((block_k, 1), jnp.float32)] * use_bias
+
+
+def _optional_specs(ix, h, s, block_q, block_k, use_seg, use_mask, use_bias,
+                    dropout_rate):
+    """BlockSpecs of the inputs a call may bring after its tensors, in
+    the order `_optional_inputs` hands them; `ix` adapts the index maps,
+    written as (bh, qi, ki), to the grid (`_index_adapter`)."""
+    specs = []
+    if use_seg:
+        # per-token segment ids [B, 1, S]: one q-row slice and one k-row
+        # slice per tile (same batch-indexed layout as the kbias row)
+        specs.append(pl.BlockSpec(
+            (1, 1, block_q), ix(lambda bh, qi, ki: (bh // h, 0, qi))))
+        specs.append(pl.BlockSpec(
+            (1, 1, block_k), ix(lambda bh, qi, ki: (bh // h, 0, ki))))
+    if use_mask:
+        specs.append(_mask_spec(h, s // MASK_GRAIN, s // MASK_GRAIN, ix))
+    if use_bias:
+        specs.append(pl.BlockSpec(
+            (1, 1, block_k), ix(lambda bh, qi, ki: (bh // h, 0, ki))))
+    if dropout_rate > 0.0:
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    return specs
+
+
+def _optional_inputs(seg, layout, kbias, seed, dropout_rate):
+    """The inputs `_optional_specs` describes: the segment ids twice (the
+    q side's and the k side's), the layout, the key bias, the seed."""
+    return [x for x in (seg, seg, layout, kbias) if x is not None] + \
+        ([seed] if dropout_rate > 0.0 else [])
 
 
 def _mask_spec(h, n_fine_q, n_fine_k, ix=lambda f: f):
@@ -661,6 +1104,80 @@ def _tag_residuals(out, lse):
             checkpoint_name(lse, "ds_attn_lse"))
 
 
+@functools.cache
+def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
+              use_mask, use_bias, dropout_rate, segmented, window,
+              interpret):
+    """The tiled forward at one call signature: (the function of its
+    inputs, its grid, its (masked, launched) tiles), built once a process
+    (`_BODY_BUILDS`). Inputs in order: q, k, v as [B*H | B*G, S, D], then
+    `_optional_inputs`."""
+    n_q, n_k = s // block_q, s // block_k
+
+    def kv_of(bh):
+        """The [B*G, S, D] row that holds query row `bh`'s KV head."""
+        return bh if g == h else (bh // h) * g + (bh % h) // (h // g)
+
+    compact = causal   # causal ⇒ trapezoidal schedule (no dead launches)
+    if segmented:
+        assert not use_mask and not use_bias and dropout_rate == 0.0
+        kernel = functools.partial(_fwd_segmented_kernel, sm_scale=sm_scale,
+                                   causal=causal, block_q=block_q,
+                                   block_k=block_k, n_k=n_k,
+                                   compact=compact, window=window)
+    else:
+        kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
+                                   causal=causal, block_q=block_q,
+                                   block_k=block_k, n_k=n_k,
+                                   use_mask=use_mask, use_bias=use_bias,
+                                   dropout_rate=dropout_rate,
+                                   compact=compact, window=window)
+    if compact:
+        maps = causal_grid_maps(n_q, n_k, block_q, block_k, "row", window)
+        grid = (b * h, len(maps[0]))
+    else:
+        maps = ()
+        grid = (b * h, n_q, n_k)
+    ix = _index_adapter(compact)
+    in_specs = [
+        pl.BlockSpec((1, block_q, d),
+                     ix(lambda bh, qi, ki: (bh, qi, 0))),
+        pl.BlockSpec((1, block_k, d),
+                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
+        pl.BlockSpec((1, block_k, d),
+                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
+    ]
+    out_specs = [
+        pl.BlockSpec((1, block_q, d),
+                     ix(lambda bh, qi, ki: (bh, qi, 0))),
+        pl.BlockSpec((1, 1, block_q),
+                     ix(lambda bh, qi, ki: (bh, 0, qi))),
+    ]
+    in_specs += _optional_specs(ix, h, s, block_q, block_k, segmented,
+                                use_mask, use_bias, dropout_rate)
+    out_shape = [
+        jax.ShapeDtypeStruct((b * h, s, d), dtype),
+        jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
+        pltpu.VMEM((block_q, LANES), jnp.float32),   # running denom
+        pltpu.VMEM((block_q, d), jnp.float32),       # out accumulator
+    ] if segmented else [
+        pltpu.VMEM((1, block_q), jnp.float32),       # running max (raw)
+        pltpu.VMEM((1, block_q), jnp.float32),       # running denom
+        pltpu.VMEM((d, block_q), jnp.float32),       # out accumulator^T
+    ] + _key_column_scratch(block_k, False, use_bias)
+    masked = masked_tile_count(
+        n_q, n_k, block_q, block_k, causal, window,
+        always=segmented or use_mask or use_bias or dropout_rate > 0.0)
+    run = _tiled_call(
+        "fwd", "ds.flash_fwd" if window is None else "ds.flash_fwd_window",
+        kernel, compact, grid, in_specs, out_specs, scratch_shapes,
+        out_shape, maps, interpret)
+    return run, grid, masked
+
+
 def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
          layout=None, kbias=None, dropout_rate=0.0, seed=None, seg=None,
          window=None):
@@ -679,15 +1196,10 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s,
                                                x.shape[-1])
 
-    def kv_of(bh):
-        """The [B*G, S, D] row that holds query row `bh`'s KV head."""
-        return bh if g == h else (bh // h) * g + (bh % h) // (h // g)
-
     qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
-    n_q, n_k = s // block_q, s // block_k
 
-    if n_q == 1 and n_k == 1 and layout is None and seg is None and \
-            window is None and g == h:
+    if s // block_q == 1 and s // block_k == 1 and layout is None and \
+            seg is None and window is None and g == h:
         # whole sequence in one block: the online-softmax machinery is
         # pure overhead — run the specialized straight-softmax kernel
         _LAST_BLOCKS["fwd"] = (s, s)
@@ -701,77 +1213,15 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
         out4 = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
         return out4, (qb, kb, vb, out, lse.reshape(b * h, s))
 
-    compact = causal   # causal ⇒ trapezoidal schedule (no dead launches)
     _LAST_BLOCKS["fwd"] = (block_q, block_k)
-    _LAST_BLOCKS["fwd_variant"] = "trapezoid" if compact else "dense"
+    _LAST_BLOCKS["fwd_variant"] = "trapezoid" if causal else "dense"
     _log_first_dispatch()
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
-                               causal=causal, block_q=block_q,
-                               block_k=block_k, n_k=n_k,
-                               use_seg=seg is not None,
-                               use_mask=layout is not None,
-                               use_bias=kbias is not None,
-                               dropout_rate=dropout_rate,
-                               compact=compact, window=window)
-    if compact:
-        qmap, kmap = causal_grid_maps(n_q, n_k, block_q, block_k, "row",
-                                      window)
-        grid = (b * h, len(qmap))
-    else:
-        qmap = kmap = None
-        grid = (b * h, n_q, n_k)
-    ix = _index_adapter(compact)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     ix(lambda bh, qi, ki: (bh, qi, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
-    ]
-    bias_spec = pl.BlockSpec(
-        (1, 1, block_k), ix(lambda bh, qi, ki, h=h: (bh // h, 0, ki)))
-    out_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     ix(lambda bh, qi, ki: (bh, qi, 0))),
-        pl.BlockSpec((1, 1, block_q),
-                     ix(lambda bh, qi, ki: (bh, 0, qi))),
-    ]
-    inputs = [qb, kb, vb]
-    if seg is not None:
-        # per-token segment ids [B, 1, S]: one q-row slice and one k-row
-        # slice per tile (same batch-indexed layout as the kbias row)
-        in_specs.append(pl.BlockSpec(
-            (1, 1, block_q), ix(lambda bh, qi, ki, h=h: (bh // h, 0, qi))))
-        inputs.append(seg)
-        in_specs.append(pl.BlockSpec(
-            (1, 1, block_k), ix(lambda bh, qi, ki, h=h: (bh // h, 0, ki))))
-        inputs.append(seg)
-    if layout is not None:
-        in_specs.append(_mask_spec(h, s // MASK_GRAIN, s // MASK_GRAIN,
-                                   ix))
-        inputs.append(layout)
-    if kbias is not None:
-        in_specs.append(bias_spec)
-        inputs.append(kbias)
-    if dropout_rate > 0.0:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        inputs.append(seed)
-    out_shape = [
-        jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-        jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
-    ]
-    scratch_shapes = [
-        pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
-        pltpu.VMEM((block_q, LANES), jnp.float32),   # running denom
-        pltpu.VMEM((block_q, d), jnp.float32),       # out accumulator
-    ]
-    _LAST_GRIDS["fwd"] = grid
-    out, lse = _tiled_call(
-        "ds.flash_fwd" if window is None else "ds.flash_fwd_window",
-        kernel, compact, grid, in_specs, out_specs,
-        scratch_shapes, out_shape,
-        (qmap, kmap) if compact else ())(*inputs)
+    run, _LAST_GRIDS["fwd"], _LAST_MASKED["fwd"] = _fwd_call(
+        b, s, h, g, d, q.dtype, block_q, block_k, causal, sm_scale,
+        layout is not None, kbias is not None, dropout_rate,
+        seg is not None, window, _interpret())
+    out, lse = run(qb, kb, vb, *_optional_inputs(seg, layout, kbias, seed,
+                                                 dropout_rate))
     out, lse = _tag_residuals(out, lse)
 
     out4 = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
@@ -892,7 +1342,7 @@ def _head_bwd(q, k, v, do, lse, delta, bias_row, seed, pid, *, sm_scale,
         dv = jnp.concatenate(dv_parts, axis=0)
     else:
         if causal:
-            s = _causal_mask(s, 0, 0, s_q, s_k)
+            s = _causal_mask(s)
         p = jnp.exp(s - lse)
         p_v = p
         if dropout_rate > 0.0:
@@ -994,6 +1444,13 @@ def _bwd_single(qb, kb, vb, do, lse, delta, causal, sm_scale, s, d,
 # backward
 # ---------------------------------------------------------------------------
 
+# The two backward kernels are MXU-bound as they stand (PERF.md, PR 33:
+# seven matmuls of a [1024, 1024] tile are 2,144 pushes of 16 cycles over
+# four MXUs, 8.6k cycles of the 8.9k a dkv step takes), so they keep
+# their whole-tile matmuls, whose weights are pushed once a tile; what
+# the tile body drops is the work around them, and on the diagonal the
+# matmuls of the triangle no query sees.
+
 def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
                     use_seg=False, use_mask=False, use_bias=False,
                     dropout_rate=0.0, compact=False):
@@ -1008,6 +1465,8 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
     b_ref = next(it) if use_bias else None
     seed_ref = next(it) if dropout_rate > 0.0 else None
     dk_ref, dv_ref, dk_scr, dv_scr = next(it), next(it), next(it), next(it)
+    kseg_scr = next(it) if use_seg else None
+    kbias_scr = next(it) if use_bias else None
     if compact:
         # column-major trapezoid: column ki starts at its first alive
         # row (the diagonal) and always ends at the bottom row
@@ -1023,65 +1482,59 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
 
     @pl.when(qi == first_q)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    run = True
-    if causal and not compact:
-        run = ki * block_k <= qi * block_q + (block_q - 1)
-    seg_eq = None
-    if use_seg:
-        # block-level document skip, mirroring the forward kernel: a
-        # fully-cross-segment tile contributes zero to dk/dv
-        seg_eq = sq_ref[0].reshape(-1, 1) == sk_ref[0]
-        run = jnp.logical_and(run, jnp.any(seg_eq))
+    tile = _Tile(qi, ki, block_q, block_k, sm_scale=sm_scale,
+                 causal=causal, sq_ref=sq_ref, sk_ref=sk_ref, m_ref=m_ref,
+                 b_ref=b_ref, seed_ref=seed_ref, dropout_rate=dropout_rate,
+                 kseg_scr=kseg_scr, kbias_scr=kbias_scr)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]                                         # [BQ, D] bf16
-        k = k_ref[0]                                         # [BK, D] bf16
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [BQ, BK]
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        if seg_eq is not None:
-            s = jnp.where(seg_eq, s, NEG_INF)
-        if m_ref is not None:
-            s = _apply_layout_mask(s, m_ref, qi, ki, block_q, block_k)
-        if b_ref is not None:
-            s = s + b_ref[0]                                 # [1, BK] bcast
-        p = jnp.exp(s - lse_ref[0].reshape(-1, 1))           # [BQ, BK] f32
-        do = do_ref[0]                                       # [BQ, D]
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [BQ, BK]
-        p_v = p
+    def group(g0, gw, rows, masked, diagonal):
+        # the tile transposed (k q^T, v dO^T): lse and delta broadcast
+        # along lanes as the rows they are, and dV += P^T dO, dK += dS^T Q
+        # are plain matmuls with no transposed left operand. Queries
+        # g0.. + gw against the keys before `rows`
+        cols = slice(g0, g0 + gw)
+        q, do = q_ref[0, cols, :], do_ref[0, cols, :]          # [gw, D]
+        sT = _dot(k_ref[0, :rows, :], q, _NT)                  # [rows, gw]
+        if masked:
+            sT = tile.mask(sT, g0, 0, diagonal)
+        pT = jnp.exp2(sT * (sm_scale * LOG2E)
+                      - lse_ref[0, :, cols] * LOG2E)
+        dpT = _dot(v_ref[0, :rows, :], do, _NT)
+        pT_v = pT
         if dropout_rate > 0.0:
-            # note grid order (bh, ki, qi): program_id(0) is still bh
-            # and the absolute (row, col) coords match the fwd tiles
-            keep = _dropout_keep(seed_ref[0], pl.program_id(0),
-                                 qi * block_q, ki * block_k, p.shape,
-                                 dropout_rate)
+            keep = tile.keep(g0, 0, pT.shape)
             inv = 1.0 / (1.0 - dropout_rate)
-            p_v = jnp.where(keep, p * inv, 0.0)
-            dp = jnp.where(keep, dp * inv, 0.0)
-        # dV += P_dropᵀ dO  (P quantized to the wire dtype for MXU rate,
-        # matching the reference's fp16 kernel precision)
-        dv_scr[:] += jax.lax.dot_general(
-            p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dS = P ∘ (M ∘ dO Vᵀ / keep − delta)
-        ds = p * (dp - delta_ref[0].reshape(-1, 1)) * sm_scale
-        # dK += dSᵀ Q
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            pT_v = jnp.where(keep, pT * inv, 0.0)
+            dpT = jnp.where(keep, dpT * inv, 0.0)
+        # dS = P o (M o dO V^T / keep - delta), unscaled. P and dS are
+        # quantized to the wire dtype for MXU rate, matching the
+        # reference's fp16 kernel precision
+        dsT = pT * (dpT - delta_ref[0, :, cols])
+        dv_scr[:rows, :] += _dot(pT_v.astype(do.dtype), do, _NN)
+        dk_scr[:rows, :] += _dot(dsT.astype(q.dtype), q, _NN)
+
+    def body(masked, offset, crossed):
+        if offset is None:
+            return group(0, block_q, block_k, masked, crossed)
+        # a tile on the diagonal, a group of query columns at a time: each
+        # against the keys up to its last query alone
+        gw = _part(block_q, DIAGONAL_GROUP)
+        for g0 in range(0, block_q, gw):
+            rows = max(0, min(block_k, offset + g0 + gw))
+            if rows:
+                diagonal = rows - 1 > offset + g0
+                group(g0, gw, rows,
+                      diagonal or tile.any_other, diagonal)
+
+    tile.bodies(body)
 
     @pl.when(qi == last_q)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
@@ -1110,49 +1563,123 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 
     @pl.when(ki == 0)
     def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = True
-    if causal and not compact:
-        run = ki * block_k <= qi * block_q + (block_q - 1)
-    seg_eq = None
-    if use_seg:
-        seg_eq = sq_ref[0].reshape(-1, 1) == sk_ref[0]
-        run = jnp.logical_and(run, jnp.any(seg_eq))
+    tile = _Tile(qi, ki, block_q, block_k, sm_scale=sm_scale,
+                 causal=causal, sq_ref=sq_ref, sk_ref=sk_ref, m_ref=m_ref,
+                 b_ref=b_ref, seed_ref=seed_ref, dropout_rate=dropout_rate)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        if seg_eq is not None:
-            s = jnp.where(seg_eq, s, NEG_INF)
-        if m_ref is not None:
-            s = _apply_layout_mask(s, m_ref, qi, ki, block_q, block_k)
-        if b_ref is not None:
-            s = s + b_ref[0]
-        p = jnp.exp(s - lse_ref[0].reshape(-1, 1))
-        do = do_ref[0]
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def group(r0, kw, q0, masked, diagonal):
+        # queries on sublanes here: dS is the left operand of dS K. Keys
+        # r0.. + kw against the queries from q0 on
+        rows, keys = slice(q0, block_q), slice(r0, r0 + kw)
+        k = k_ref[0, keys, :]
+        s = _dot(q_ref[0, rows, :], k, _NT)                    # [nq, kw]
+        if masked:
+            s = tile.mask(s, q0, r0, diagonal, q_axis=0)
+        p = jnp.exp2(s * (sm_scale * LOG2E)
+                     - (lse_ref[0, :, rows] * LOG2E).reshape(-1, 1))
+        dp = _dot(do_ref[0, rows, :], v_ref[0, keys, :], _NT)
         if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0], pl.program_id(0),
-                                 qi * block_q, ki * block_k, p.shape,
-                                 dropout_rate)
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        ds = p * (dp - delta_ref[0].reshape(-1, 1)) * sm_scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            dp = jnp.where(tile.keep(q0, r0, p.shape, q_axis=0),
+                           dp * (1.0 / (1.0 - dropout_rate)), 0.0)
+        ds = p * (dp - delta_ref[0, :, rows].reshape(-1, 1))
+        dq_scr[rows, :] += _dot(ds.astype(k.dtype), k, _NN)
+
+    def body(masked, offset, crossed):
+        if offset is None:
+            return group(0, block_k, 0, masked, crossed)
+        # a tile on the diagonal, a group of keys at a time: each against
+        # the queries from its first key on alone
+        kw = _part(block_k, DIAGONAL_GROUP)
+        for r0 in range(0, block_k, kw):
+            q0 = max(0, min(block_q, r0 - offset))
+            if q0 < block_q:
+                diagonal = r0 + kw - 1 > offset + q0
+                group(r0, kw, q0, diagonal or tile.any_other, diagonal)
+
+    tile.bodies(body)
 
     @pl.when(ki == last_k)
     def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
+
+
+@functools.cache
+def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
+               use_seg, use_mask, use_bias, dropout_rate, interpret):
+    """The tiled backward's two kernels at one call signature: (dkv's
+    function of the inputs, dq's, their grids, their (masked, launched)
+    tiles), built once a process as `_fwd_call` is. `dtypes` are q's,
+    k's and v's; both take q, k, v, dO as [B*H, S, D], lse and delta as
+    [B*H, 1, S], then `_optional_inputs`."""
+    n_q, n_k = s // block_q, s // block_k
+    compact = causal   # mirror the forward's trapezoidal schedule
+    flags = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                 block_k=block_k, use_seg=use_seg, use_mask=use_mask,
+                 use_bias=use_bias, dropout_rate=dropout_rate,
+                 compact=compact)
+
+    def specs(ix):
+        """The inputs' BlockSpecs, index maps written as (bh, qi, ki)."""
+        row = pl.BlockSpec((1, 1, block_q),
+                           ix(lambda bh, qi, ki: (bh, 0, qi)))
+        in_specs = [
+            pl.BlockSpec((1, block_q, d),
+                         ix(lambda bh, qi, ki: (bh, qi, 0))),
+            pl.BlockSpec((1, block_k, d),
+                         ix(lambda bh, qi, ki: (bh, ki, 0))),
+            pl.BlockSpec((1, block_k, d),
+                         ix(lambda bh, qi, ki: (bh, ki, 0))),
+            pl.BlockSpec((1, block_q, d),
+                         ix(lambda bh, qi, ki: (bh, qi, 0))),
+            row, row]
+        return in_specs + _optional_specs(
+            ix, h, s, block_q, block_k, use_seg, use_mask, use_bias,
+            dropout_rate)
+
+    # dkv accumulates per k column → column-major trapezoid; its dense
+    # grid order is (bh, ki, qi)
+    if compact:
+        dkv_maps = causal_grid_maps(n_q, n_k, block_q, block_k, "col")
+        dkv_grid = (bh, len(dkv_maps[0]))
+    else:
+        dkv_maps = ()
+        dkv_grid = (bh, n_k, n_q)
+    ixc = _index_adapter(compact, kv_major=True)
+    kv_spec = pl.BlockSpec((1, block_k, d),
+                           ixc(lambda bh, qi, ki: (bh, ki, 0)))
+    dkv_run = _tiled_call(
+        "dkv", "ds.flash_bwd_dkv",
+        functools.partial(_bwd_dkv_kernel, n_q=n_q, **flags), compact,
+        dkv_grid, specs(ixc), [kv_spec, kv_spec],
+        [pltpu.VMEM((block_k, d), jnp.float32),
+         pltpu.VMEM((block_k, d), jnp.float32)]
+        + _key_column_scratch(block_k, use_seg, use_bias),
+        [jax.ShapeDtypeStruct((bh, s, d), dtypes[1]),
+         jax.ShapeDtypeStruct((bh, s, d), dtypes[2])],
+        dkv_maps, interpret)
+
+    # dq accumulates per q row → row-major trapezoid (same as fwd)
+    if compact:
+        dq_maps = causal_grid_maps(n_q, n_k, block_q, block_k, "row")
+        dq_grid = (bh, len(dq_maps[0]))
+    else:
+        dq_maps = ()
+        dq_grid = (bh, n_q, n_k)
+    ix = _index_adapter(compact)
+    dq_run = _tiled_call(
+        "dq", "ds.flash_bwd_dq",
+        functools.partial(_bwd_dq_kernel, n_k=n_k, **flags), compact,
+        dq_grid, specs(ix),
+        pl.BlockSpec((1, block_q, d), ix(lambda bh, qi, ki: (bh, qi, 0))),
+        [pltpu.VMEM((block_q, d), jnp.float32)],
+        jax.ShapeDtypeStruct((bh, s, d), dtypes[0]), dq_maps, interpret)
+    # the two schedules launch the same tiles in another order
+    masked = masked_tile_count(
+        n_q, n_k, block_q, block_k, causal,
+        always=use_mask or use_bias or dropout_rate > 0.0)
+    return dkv_run, dq_run, dkv_grid, dq_grid, masked
 
 
 def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
@@ -1172,12 +1699,11 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, s)                # [BH, 1, S]
 
-    n_q, n_k = s // block_q, s // block_k
-    use_seg = seg is not None
-    use_mask = layout is not None
-    use_bias = kbias is not None
+    def from_bh(x):
+        return x.reshape(bdim, h, s, d).transpose(0, 2, 1, 3)
 
-    if n_q == 1 and n_k == 1 and not use_mask and not use_seg:
+    if s // block_q == 1 and s // block_k == 1 and layout is None and \
+            seg is None:
         _LAST_BLOCKS["dkv"] = _LAST_BLOCKS["dq"] = (s, s)
         _LAST_BLOCKS["bwd_variant"] = "single"
         with scopes.scope("ds.flash_bwd"):
@@ -1185,155 +1711,20 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
                                      sm_scale, s, d, _interpret(),
                                      kbias=kbias, h=h,
                                      dropout_rate=dropout_rate, seed=seed)
+        return from_bh(dq), from_bh(dk), from_bh(dv)
 
-        def from_bh1(x):
-            return x.reshape(bdim, h, s, d).transpose(0, 2, 1, 3)
-
-        return from_bh1(dq), from_bh1(dk), from_bh1(dv)
-
-    compact = causal   # mirror the forward's trapezoidal schedule
     _LAST_BLOCKS["dkv"] = _LAST_BLOCKS["dq"] = (block_q, block_k)
-    _LAST_BLOCKS["bwd_variant"] = "trapezoid" if compact else "dense"
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
-                                   causal=causal, block_q=block_q,
-                                   block_k=block_k, n_q=n_q,
-                                   use_seg=use_seg,
-                                   use_mask=use_mask,
-                                   use_bias=use_bias,
-                                   dropout_rate=dropout_rate,
-                                   compact=compact)
-    if compact:
-        # dkv accumulates per k column → column-major trapezoid
-        dkv_qmap, dkv_kmap = causal_grid_maps(n_q, n_k, block_q, block_k,
-                                              "col")
-        dkv_grid = (bh, len(dkv_qmap))
-    else:
-        dkv_qmap = dkv_kmap = None
-        dkv_grid = (bh, n_k, n_q)
-    # dense dkv grid order is (bh, ki, qi) — kv_major adapter
-    ixc = _index_adapter(compact, kv_major=True)
-    dkv_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     ixc(lambda bh, ki, qi: (bh, qi, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ixc(lambda bh, ki, qi: (bh, ki, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ixc(lambda bh, ki, qi: (bh, ki, 0))),
-        pl.BlockSpec((1, block_q, d),
-                     ixc(lambda bh, ki, qi: (bh, qi, 0))),
-        pl.BlockSpec((1, 1, block_q),
-                     ixc(lambda bh, ki, qi: (bh, 0, qi))),
-        pl.BlockSpec((1, 1, block_q),
-                     ixc(lambda bh, ki, qi: (bh, 0, qi))),
-    ]
-    dkv_bias_spec = pl.BlockSpec(
-        (1, 1, block_k), ixc(lambda bh, ki, qi, h=h: (bh // h, 0, ki)))
-    dkv_out_specs = [
-        pl.BlockSpec((1, block_k, d),
-                     ixc(lambda bh, ki, qi: (bh, ki, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ixc(lambda bh, ki, qi: (bh, ki, 0))),
-    ]
-    dkv_inputs = [qb, kb, vb, do, lse, delta]
-    if use_seg:
-        dkv_specs.append(pl.BlockSpec(
-            (1, 1, block_q),
-            ixc(lambda bh, ki, qi, h=h: (bh // h, 0, qi))))
-        dkv_inputs.append(seg)
-        dkv_specs.append(pl.BlockSpec(
-            (1, 1, block_k),
-            ixc(lambda bh, ki, qi, h=h: (bh // h, 0, ki))))
-        dkv_inputs.append(seg)
-    if use_mask:
-        dkv_specs.append(_mask_spec(h, s // MASK_GRAIN, s // MASK_GRAIN,
-                                    ixc))
-        dkv_inputs.append(layout)
-    if use_bias:
-        dkv_specs.append(dkv_bias_spec)
-        dkv_inputs.append(kbias)
-    if dropout_rate > 0.0:
-        dkv_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dkv_inputs.append(seed)
-    dkv_out_shape = [
-        jax.ShapeDtypeStruct((bh, s, d), kb.dtype),
-        jax.ShapeDtypeStruct((bh, s, d), vb.dtype),
-    ]
-    dkv_scratch = [
-        pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d), jnp.float32),
-    ]
-    _LAST_GRIDS["dkv"] = dkv_grid
-    dk, dv = _tiled_call(
-        "ds.flash_bwd_dkv", dkv_kernel, compact, dkv_grid, dkv_specs,
-        dkv_out_specs, dkv_scratch, dkv_out_shape,
-        (dkv_qmap, dkv_kmap) if compact else ())(*dkv_inputs)
-
-    dq_kernel = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
-                                  causal=causal, block_q=block_q,
-                                  block_k=block_k, n_k=n_k,
-                                  use_seg=use_seg,
-                                  use_mask=use_mask,
-                                  use_bias=use_bias,
-                                  dropout_rate=dropout_rate,
-                                  compact=compact)
-    if compact:
-        # dq accumulates per q row → row-major trapezoid (same as fwd)
-        dq_qmap, dq_kmap = causal_grid_maps(n_q, n_k, block_q, block_k,
-                                            "row")
-        dq_grid = (bh, len(dq_qmap))
-    else:
-        dq_qmap = dq_kmap = None
-        dq_grid = (bh, n_q, n_k)
-    ix = _index_adapter(compact)
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     ix(lambda bh, qi, ki: (bh, qi, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (bh, ki, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (bh, ki, 0))),
-        pl.BlockSpec((1, block_q, d),
-                     ix(lambda bh, qi, ki: (bh, qi, 0))),
-        pl.BlockSpec((1, 1, block_q),
-                     ix(lambda bh, qi, ki: (bh, 0, qi))),
-        pl.BlockSpec((1, 1, block_q),
-                     ix(lambda bh, qi, ki: (bh, 0, qi))),
-    ]
-    dq_bias_spec = pl.BlockSpec(
-        (1, 1, block_k), ix(lambda bh, qi, ki, h=h: (bh // h, 0, ki)))
-    dq_out_spec = pl.BlockSpec(
-        (1, block_q, d), ix(lambda bh, qi, ki: (bh, qi, 0)))
-    dq_inputs = [qb, kb, vb, do, lse, delta]
-    if use_seg:
-        dq_specs.append(pl.BlockSpec(
-            (1, 1, block_q),
-            ix(lambda bh, qi, ki, h=h: (bh // h, 0, qi))))
-        dq_inputs.append(seg)
-        dq_specs.append(pl.BlockSpec(
-            (1, 1, block_k),
-            ix(lambda bh, qi, ki, h=h: (bh // h, 0, ki))))
-        dq_inputs.append(seg)
-    if use_mask:
-        dq_specs.append(_mask_spec(h, s // MASK_GRAIN, s // MASK_GRAIN,
-                                   ix))
-        dq_inputs.append(layout)
-    if use_bias:
-        dq_specs.append(dq_bias_spec)
-        dq_inputs.append(kbias)
-    if dropout_rate > 0.0:
-        dq_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dq_inputs.append(seed)
-    dq_out_shape = jax.ShapeDtypeStruct((bh, s, d), qb.dtype)
-    dq_scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
-    _LAST_GRIDS["dq"] = dq_grid
-    dq = _tiled_call(
-        "ds.flash_bwd_dq", dq_kernel, compact, dq_grid, dq_specs,
-        dq_out_spec, dq_scratch, dq_out_shape,
-        (dq_qmap, dq_kmap) if compact else ())(*dq_inputs)
-
-    def from_bh(x):
-        return x.reshape(bdim, h, s, d).transpose(0, 2, 1, 3)
-
+    _LAST_BLOCKS["bwd_variant"] = "trapezoid" if causal else "dense"
+    dkv_run, dq_run, _LAST_GRIDS["dkv"], _LAST_GRIDS["dq"], masked = \
+        _bwd_calls(bh, s, h, d, (qb.dtype, kb.dtype, vb.dtype), block_q,
+                   block_k, causal, sm_scale, seg is not None,
+                   layout is not None, kbias is not None, dropout_rate,
+                   _interpret())
+    _LAST_MASKED["dkv"] = _LAST_MASKED["dq"] = masked
+    inputs = [qb, kb, vb, do, lse, delta] + \
+        _optional_inputs(seg, layout, kbias, seed, dropout_rate)
+    dk, dv = dkv_run(*inputs)
+    dq = dq_run(*inputs)
     return from_bh(dq), from_bh(dk), from_bh(dv)
 
 

@@ -294,3 +294,388 @@ def test_window_launches_the_band_alone():
     # the blocks a window layer is given are no wider than its window
     fa.flash_attention_segmented(q, k, v, seg, True, window=256)
     assert fa._LAST_BLOCKS["fwd"] == (256, 256)
+
+
+# ---------------------------------------------------------------------------
+# the tile body (PR 33): strips, the masked and the unmasked body
+# ---------------------------------------------------------------------------
+
+def masked_reference(q, k, v, seen=None, kbias=None, keep=None, rate=0.0):
+    """Plain fp32 attention under an explicit [B, H, S, S] visibility
+    mask, a per-key bias and a dropout keep-mask; rows that see no key
+    give zeros (the kernels' poisoned-lse convention)."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    r = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, r, axis=2), jnp.repeat(v, r, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    if kbias is not None:
+        s = s + kbias[:, None, None, :]
+    if seen is not None:
+        s = jnp.where(seen, s, -1e30)
+    alive = jnp.max(s, axis=-1, keepdims=True) > -1e29
+    p = jnp.where(alive, jax.nn.softmax(s, axis=-1), 0.0)
+    if keep is not None:
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def causal_seen(S, window=None):
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    seen = j <= i
+    if window is not None:
+        seen = seen & (i - j < window)
+    return seen[None, None]
+
+
+def _documents(S):
+    """[2, S] segment ids: row 0 has a boundary inside a strip (200), on
+    a strip's edge (384) and on a tile's edge (512 at every block here);
+    row 1 is one document and a run of pad rows."""
+    pos = jnp.arange(S)
+    row0 = 1 + (pos >= 200) + (pos >= 384) + (pos >= 512)
+    row1 = (pos < S - 150).astype(jnp.int32)
+    return jnp.stack([row0, row1]).astype(jnp.int32)
+
+
+def _layout(S, heads):
+    """A block layout with its diagonal, a few further blocks, and one
+    query block row with NO active block (rows whose every key is
+    masked: the poisoned lse)."""
+    n = S // fa.MASK_GRAIN
+    rng = np.random.RandomState(0)
+    lay = (rng.rand(heads, n, n) < 0.4) | np.eye(n, dtype=bool)[None]
+    lay[:, 1, :] = False
+    return lay
+
+
+def _variant(name, q, k, v, blocks, bwd_blocks):
+    """(kernel fn of (q, k, v), reference fn of (q, k, v))."""
+    B, S, H, _ = q.shape
+    bq, bk = blocks
+    if name in ("causal", "full"):
+        causal = name == "causal"
+        return (lambda q, k, v: fa.flash_attention(
+                    q, k, v, causal, None, bq, bk, bwd_blocks),
+                lambda q, k, v: masked_reference(
+                    q, k, v, causal_seen(S) if causal else None))
+    if name == "segmented":
+        seg = _documents(S)[:B]
+        seen = causal_seen(S) & \
+            (seg[:, :, None] == seg[:, None, :])[:, None]
+        return (lambda q, k, v: fa.flash_attention_segmented(
+                    q, k, v, seg, True, None, bq, bk, bwd_blocks),
+                lambda q, k, v: masked_reference(q, k, v, seen))
+    if name == "kbias":
+        bias = jnp.where(jax.random.uniform(jax.random.PRNGKey(7), (B, S))
+                         < 0.2, -1e30, 0.0)
+        bias = bias + 0.3 * jax.random.normal(jax.random.PRNGKey(8), (B, S))
+        return (lambda q, k, v: fa.flash_attention_kbias(
+                    q, k, v, bias, True, None, bq, bk),
+                lambda q, k, v: masked_reference(
+                    q, k, v, causal_seen(S), kbias=bias))
+    if name == "dropout":
+        rate, seed = 0.25, jnp.array([1234], jnp.int32)
+        keep = jnp.stack([jnp.stack([
+            fa._dropout_keep(seed[0], jnp.int32(b * H + h), 0, 0, (S, S), rate)
+            for h in range(H)]) for b in range(B)])
+        return (lambda q, k, v: fa.flash_attention_train(
+                    q, k, v, None, seed, True, None, bq, bk,
+                    dropout_rate=rate),
+                lambda q, k, v: masked_reference(
+                    q, k, v, causal_seen(S), keep=keep, rate=rate))
+    if name == "layout":
+        lay = _layout(S, H)
+        fine = jnp.asarray(np.kron(lay, np.ones((fa.MASK_GRAIN,) * 2)) > 0)
+        return (fa.make_masked_flash_attention(lay, True, None, bq, bk),
+                lambda q, k, v: masked_reference(
+                    q, k, v, causal_seen(S) & fine[None]))
+    raise ValueError(name)
+
+
+# blocks with block_q > block_k (the 16k cell's 2:1, scaled down),
+# block_q < block_k and equal; (512, 512) walks four strips of one pair,
+# (1024, 1024) at 2,048 tokens two pairs a side as the 2k cells do. A
+# segmented call's forward walks its pairs and strips in loops.
+TILE_BODY_CASES = [
+    # variant, S, (block_q, block_k), head dim, dtype
+    ("causal", 512, (256, 128), 64, jnp.float32),
+    ("causal", 512, (128, 256), 128, jnp.float32),
+    ("causal", 512, (256, 256), 64, jnp.bfloat16),
+    ("causal", 1024, (512, 512), 128, jnp.bfloat16),
+    ("causal", 2048, (1024, 1024), 64, jnp.float32),
+    ("full", 512, (256, 128), 64, jnp.float32),
+    ("full", 512, (128, 256), 128, jnp.bfloat16),
+    ("full", 1024, (512, 512), 64, jnp.float32),
+    ("segmented", 1024, (256, 128), 64, jnp.float32),
+    ("segmented", 1024, (128, 256), 128, jnp.float32),
+    ("segmented", 1024, (512, 512), 64, jnp.bfloat16),
+    ("segmented", 1024, (256, 256), 128, jnp.float32),
+    ("segmented", 2048, (1024, 1024), 64, jnp.float32),
+    ("kbias", 512, (256, 128), 64, jnp.float32),
+    ("kbias", 512, (128, 256), 128, jnp.bfloat16),
+    ("kbias", 1024, (512, 512), 64, jnp.float32),
+    ("dropout", 512, (256, 128), 64, jnp.float32),
+    ("dropout", 512, (128, 256), 128, jnp.float32),
+    ("dropout", 512, (256, 256), 64, jnp.float32),
+    ("layout", 512, (256, 128), 64, jnp.float32),
+    ("layout", 512, (128, 256), 128, jnp.float32),
+    ("layout", 512, (256, 256), 64, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize(
+    "variant,S,blocks,d,dtype", TILE_BODY_CASES,
+    ids=[f"{c[0]}-{c[1]}-{c[2][0]}x{c[2][1]}-d{c[3]}-{c[4].__name__}"
+         for c in TILE_BODY_CASES])
+def test_tile_body_matches_fp32_reference(variant, S, blocks, d, dtype):
+    """Forward, dq, dk and dv of the tiled kernels against plain fp32
+    attention, for every mask a tile body knows."""
+    B, H = (1, 1) if S == 2048 else (2, 2)   # `_documents` row 0 alone
+    q, k, v = make_qkv(b=B, s=S, h=H, d=d, dtype=dtype, seed=3)
+    w = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
+    kernel, reference = _variant(variant, q, k, v, blocks, blocks)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+    out = kernel(q, k, v)
+    want = reference(q, k, v)
+    grads = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(loss(reference), argnums=(0, 1, 2))(q, k, v)
+    tol = dict(atol=3e-5, rtol=3e-5) if dtype == jnp.float32 else \
+        dict(atol=4e-2, rtol=4e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), **tol)
+    gtol = dict(atol=2e-4, rtol=2e-3) if dtype == jnp.float32 else \
+        dict(atol=8e-2, rtol=8e-2)
+    for got, ref, name in zip(grads, wants, "qkv"):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref, np.float32), **gtol,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("window,blocks,heads,kv_heads,d", [
+    (200, (256, 128), 4, 4, 64),     # an edge inside a strip
+    (100, (256, 256), 6, 2, 128),    # window < block, G < H
+    (384, (128, 256), 4, 1, 64),     # an edge on a strip's edge
+    (600, (512, 512), 2, 2, 64),     # the band's first tile is crossed
+], ids=["w200", "w100_grouped", "w384_grouped", "w600"])
+def test_windowed_tile_body_matches_fp32_reference(window, blocks, heads,
+                                                   kv_heads, d):
+    """The windowed / grouped forward (no backward exists) over
+    documents and pad rows, in blocks that put a window's edge inside a
+    strip, on one, and a whole block behind it."""
+    S = 1024
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (2, S, heads, d)) * 0.5
+    k = jax.random.normal(ks[1], (2, S, kv_heads, d)) * 0.5
+    v = jax.random.normal(ks[2], (2, S, kv_heads, d)) * 0.5
+    seg = _documents(S)
+    got = fa.flash_attention_segmented(q, k, v, seg, True,
+                                       block_q=blocks[0],
+                                       block_k=blocks[1], window=window)
+    seen = causal_seen(S, window) & \
+        (seg[:, :, None] == seg[:, None, :])[:, None]
+    want = masked_reference(q, k, v, seen)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-5, rtol=3e-5)
+
+
+def _closed_form_masked_tiles(n_q, n_k, bq, bk, causal, window):
+    """Tiles of the launched grid that an edge crosses, from the
+    inequalities alone (no schedule)."""
+    if not causal:
+        return 0, n_q * n_k
+    masked = launched = 0
+    for qi in range(n_q):
+        q_lo, q_hi = qi * bq, qi * bq + bq - 1
+        for ki in range(n_k):
+            k_lo, k_hi = ki * bk, ki * bk + bk - 1
+            if k_lo > q_hi:
+                continue                  # above the diagonal
+            if window is not None and ki < max(q_lo - window + 1, 0) // bk:
+                continue                  # behind the band's first tile
+            launched += 1
+            masked += k_hi > q_lo or (
+                window is not None and q_hi - k_lo >= window)
+    return masked, launched
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)])
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 200),
+                                           (False, None)],
+                         ids=["causal", "causal_window", "dense"])
+def test_masked_tile_count_is_its_closed_form(blocks, causal, window):
+    """`_LAST_MASKED` records, at trace time, how many of a call's
+    launched tiles take the masked body: the diagonal's (and a window's
+    far edge's) tiles, none of a dense grid."""
+    S = 1024
+    bq, bk = blocks
+    q, k, v = make_qkv(s=S, h=1)
+    if window is None:
+        jax.grad(lambda q: fa.flash_attention(
+            q, k, v, causal, None, bq, bk, blocks).sum())(q)
+        kinds = ("fwd", "dkv", "dq")
+    else:
+        fa.flash_attention_segmented(q, k, v, jnp.ones((1, S), jnp.int32),
+                                     True, block_q=bq, block_k=bk,
+                                     window=window)
+        kinds = ("fwd",)
+    want = _closed_form_masked_tiles(S // bq, S // bk, bq, bk, causal,
+                                     window)
+    for kind in kinds:
+        assert fa._LAST_MASKED[kind] == want, kind
+        assert fa._LAST_MASKED[kind][1] == fa._LAST_GRIDS[kind][1] * (
+            1 if causal else fa._LAST_GRIDS[kind][2])
+    # the 16k cell's forward: 240 of a head's 272 tiles are unmasked
+    assert fa.masked_tile_count(16, 32, 1024, 512, True) == (32, 272)
+    assert fa.masked_tile_count(2, 2, 1024, 1024, True) == (2, 3)
+    # a bias, a layout mask or dropout sends every tile to the masked body
+    assert fa.masked_tile_count(2, 2, 1024, 1024, True, always=True) \
+        == (3, 3)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["dense", "causal"])
+def test_masked_and_unmasked_body_agree_bit_for_bit(causal):
+    """A zero key bias sends every tile through the masked body; without
+    it the tiles no edge crosses take the unmasked one. Same blocks, same
+    order of operations: the outputs are identical to the bit. (The
+    gradients agree to a rounding: the CPU compiler behind interpret
+    mode contracts `s * c - lse` to one fused multiply-add in one of the
+    two programs and not in the other.)"""
+    q, k, v = make_qkv(s=512, h=2)
+    zero = jnp.zeros((1, 512), jnp.float32)
+
+    def masked(q, k, v):
+        return fa.flash_attention_kbias(q, k, v, zero, causal, None, 128,
+                                        128)
+
+    def unmasked(q, k, v):
+        return fa.flash_attention(q, k, v, causal, None, 128, 128,
+                                  (128, 128))
+
+    np.testing.assert_array_equal(np.asarray(masked(q, k, v)),
+                                  np.asarray(unmasked(q, k, v)))
+    assert fa._LAST_MASKED["fwd"] == ((4, 10) if causal else (0, 16))
+    for fn in (masked, unmasked):
+        fn.grads = jax.grad(lambda *a: jnp.sum(fn(*a) ** 2),
+                            argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(masked.grads, unmasked.grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-7, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the set-up account (PR 34): a body is built once a process, and is small
+# ---------------------------------------------------------------------------
+#
+# A kernel body is python that unrolls pairs x strips x diagonal bodies;
+# tracing it and lowering it to Mosaic are host time on EVERY run, compile
+# cache hit or not. PR 33 was refused for that alone: a model of 24
+# unrolled layers without remat built each body 24 times, +39 s of set-up
+# in the four-chip cell. No clock here: builds and equations are counted.
+
+def _kernel_jaxprs(jaxpr):
+    """(name, kernel jaxpr) of every `pallas_call` anywhere under it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], eqn.params["jaxpr"]
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_jaxprs(sub)
+
+
+def _equations(jaxpr):
+    return sum(1 + sum(_equations(sub)
+                       for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+# The three train cells' per-shard attention, and the most equations each
+# kernel's body may unroll to there: about 1.3 times what this tree counts
+# (fwd 688 / 907 / 907, dkv 199, dq 182). A body that grows past it is
+# set-up every run pays: shrink it, or let its unrolling adapt to the
+# shape (docs/long-context.md, "What a body costs the host").
+SETUP_CASES = [
+    # shape [B, S, H, D], budget of equations a kernel
+    ((1, 16384, 16, 64), {"ds.flash_fwd": 900, "ds.flash_bwd_dkv": 260,
+                          "ds.flash_bwd_dq": 240}),
+    ((16, 2048, 16, 64), {"ds.flash_fwd": 1200, "ds.flash_bwd_dkv": 260,
+                          "ds.flash_bwd_dq": 240}),
+    ((4, 2048, 16, 128), {"ds.flash_fwd": 1200, "ds.flash_bwd_dkv": 260,
+                          "ds.flash_bwd_dq": 240}),
+]
+LAYERS = 3      # unrolled, as a model without remat calls the attention
+
+
+def _layers(q, k, v):
+    """At the blocks the rule gives the chip the cells run on (the CPU
+    has no row of its own for 16k)."""
+    from deeperspeed_tpu.ops.autotune import flash_blocks
+    (bq, bk), bwd = flash_blocks(q.shape, True, device_kind="TPU v5 lite")
+    x = q
+    for _ in range(LAYERS):
+        x = fa.flash_attention(x, k, v, True, None, bq, bk, bwd)
+    return x.astype(jnp.float32)
+
+
+def _layers_loss(q, k, v):
+    return _layers(q, k, v).sum()
+
+
+def _fresh_account():
+    fa._fwd_call.cache_clear()
+    fa._bwd_calls.cache_clear()
+    return {kind: n for kind, (n, _) in fa._BODY_BUILDS.items()}
+
+
+def _built_since(before):
+    return {kind: n - before[kind]
+            for kind, (n, _) in fa._BODY_BUILDS.items()}
+
+
+@pytest.mark.parametrize("shape,budget", SETUP_CASES,
+                         ids=["train_16k", "train_2k", "zero3_shard"])
+def test_bodies_are_built_once_and_stay_small(shape, budget):
+    """Three unrolled layers' forward and backward, traced twice in one
+    process, build each of the three kernel bodies ONCE; and each body
+    stays under its written budget of equations."""
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    before = _fresh_account()
+    grad = jax.grad(_layers_loss, argnums=(0, 1, 2))
+    first = jax.make_jaxpr(grad)(spec, spec, spec)
+    jax.make_jaxpr(grad)(spec, spec, spec)
+    assert _built_since(before) == {"fwd": 1, "dkv": 1, "dq": 1}
+    kernels = list(_kernel_jaxprs(first.jaxpr))
+    assert sorted(name for name, _ in kernels) == sorted(
+        ["ds.flash_fwd", "ds.flash_bwd_dkv", "ds.flash_bwd_dq"] * LAYERS)
+    for name, body in kernels:
+        assert _equations(body) <= budget[name], (name, _equations(body))
+    # one traced body, bound by every layer: what lets jax lower it once
+    # a module, too (its lowering cache is keyed on the equation's params)
+    assert len({id(body) for _, body in kernels}) == 3
+    report = importlib.import_module(
+        "deeperspeed_tpu.ops").dispatch_report()["flash"]
+    assert set(report["bodies_built"]) == {"fwd", "dkv", "dq"}
+    assert report["masked_tiles"]["fwd"] == fa._LAST_MASKED["fwd"]
+
+
+def test_bodies_are_built_once_under_shard_map():
+    """The four-chip cell's path: 16 sequences over a 4-device
+    `shard_map` (`parallel.mesh.per_shard`), 4 a shard at head dim 128."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+    spec = jax.ShapeDtypeStruct((16, 2048, 16, 128), jnp.bfloat16)
+    sharded = jax.shard_map(_layers, mesh=mesh,
+                            in_specs=(P("data"),) * 3, out_specs=P("data"),
+                            check_vma=False)
+    before = _fresh_account()
+    grad = jax.grad(lambda *a: sharded(*a).sum(), argnums=(0, 1, 2))
+    first = jax.make_jaxpr(grad)(spec, spec, spec)
+    jax.make_jaxpr(grad)(spec, spec, spec)
+    assert _built_since(before) == {"fwd": 1, "dkv": 1, "dq": 1}
+    kernels = list(_kernel_jaxprs(first.jaxpr))
+    assert len(kernels) == 3 * LAYERS
+    assert len({id(body) for _, body in kernels}) == 3
+    for name, body in kernels:
+        assert _equations(body) <= SETUP_CASES[2][1][name], name

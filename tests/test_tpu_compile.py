@@ -159,6 +159,44 @@ def test_long_sequence_flash_geometry_compiles(on_chip, shape, causal,
     assert_kernel(text, at_least=3 if grad else 1)
 
 
+# The tiled kernels at the shapes the benchmark's cells run them (per
+# chip): a tile body Mosaic refuses (VMEM, a relayout it cannot do in a
+# strip) fails here and not in the cell. (name, [B, S, H, D], fwd blocks,
+# bwd blocks; None = `flash_blocks`' own.)
+CELL_FLASH_SHAPES = [
+    ("train_16k", (1, 16384, 16, 64), (1024, 512), (1024, 1024)),
+    ("train_2k", (16, 2048, 16, 64), None, None),
+    ("train_zero3_4c", (4, 2048, 16, 128), None, None),
+]
+
+
+@pytest.mark.parametrize("name,shape,fwd,bwd", CELL_FLASH_SHAPES,
+                         ids=[c[0] for c in CELL_FLASH_SHAPES])
+def test_flash_compiles_at_the_train_cells_shapes(on_chip, name, shape, fwd,
+                                                  bwd):
+    """Forward, dkv and dq, each with its masked and its unmasked body."""
+    bq, bk = fwd or (None, None)
+
+    def attn(q, k, v):
+        return fa.flash_attention(q, k, v, True, None, bq, bk, bwd)
+
+    assert_kernel(on_chip(attn, *qkv(*shape)))
+    assert_kernel(on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)),
+                          *qkv(*shape)), at_least=3)
+    masked, launched = fa._LAST_MASKED["fwd"]
+    assert 0 < masked < launched          # both bodies are in the kernel
+    assert fa._LAST_MASKED["dkv"] == fa._LAST_MASKED["dq"]
+
+
+def test_segmented_prefill_compiles_at_the_serve_cells_bucket(on_chip):
+    """The Pythia and OLMoE cells' largest prefill bucket: one row of
+    1,536 tokens, 16 heads of 128, pad rows masked through segment ids."""
+    shape = (1, 1536, 16, 128)
+    assert_kernel(on_chip(
+        lambda q, k, v, s: fa.flash_attention_segmented(q, k, v, s, True),
+        *qkv(*shape), ((1, 1536), jnp.int32)))
+
+
 # the serving prefill buckets (`InferenceEngine._prefill_fn` masks pad
 # rows through segment ids) and the packed-training shape
 @pytest.mark.parametrize("shape", [(4, 128, 12, 64), (4, 1024, 12, 64),
