@@ -69,7 +69,9 @@ from ..models import gpt2 as gpt2_mod
 from ..models import gpt_neox as neox
 from ..module_inject.replace_module import prepare_inference_params
 from ..ops.pallas.decode_attention import (paged_decode_attention,
-                                           paged_kv_write)
+                                           paged_kv_write,
+                                           paged_latent_decode,
+                                           paged_latent_write)
 from ..ops.pallas.flash_attention import NEG_INF
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.config import (DeepSpeedConfig, parse_inference_block,
@@ -262,6 +264,10 @@ class InferenceEngine:
         self.planned = bool(getattr(cfg, "layer_plan", ()))
         self.window = cfg.attn_window if self.planned and \
             cfg.cache_layers("window") else 0
+        # the width of a latent layer's cache row (MLA), where the model's
+        # attention is latent: one pool with no head axis
+        self.latent = cfg.latent_width if self.planned and \
+            cfg.cache_layers("latent") else 0
         if getattr(cfg, "attention_engine", "dense") != "dense":
             raise DeepSpeedConfigError(
                 "serving needs attention_engine='dense' (the block-"
@@ -389,7 +395,9 @@ class InferenceEngine:
         self.family = _Family(model, self.max_seq_len)
         # page pools by layer kind. `cache`: what a full-attention layer
         # keeps, a sequence's whole context (`num_pages`; every layer of
-        # a homogeneous model). `window_cache`: what a window layer
+        # a homogeneous model; where the model's attention is latent, its
+        # latent rows in ONE pool, not a K and a V). `window_cache`: what
+        # a window layer
         # keeps, at most window / page + 1 pages a sequence, so the pool
         # is sized for `max_batch_size` of those and the scheduler gives
         # the rest back as a sequence grows
@@ -398,7 +406,8 @@ class InferenceEngine:
         self.cache = PagedKVCache(
             num_layers=cfg.num_layers - n_window, num_pages=ip["num_pages"],
             num_heads=kv_heads, page_size=self.page_size,
-            head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh)
+            head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh,
+            latent_width=self.latent)
         self.window_cache = None
         if n_window:
             self.window_cache = PagedKVCache(
@@ -544,6 +553,11 @@ class InferenceEngine:
                       # gave back while its sequence ran
                       "decode_kv_tokens_window": 0,
                       "kv_page_steps_full": 0, "kv_page_steps_window": 0,
+                      # the same of a latent model's one cache kind: the
+                      # rows its absorbed decode kernel attended, and the
+                      # pages that held them
+                      "decode_kv_tokens_latent": 0,
+                      "kv_page_steps_latent": 0,
                       "window_pages_released": 0,
                       # (token, choice) pairs the routers kept, all
                       # layers, and those that fell on an expert held
@@ -647,8 +661,8 @@ class InferenceEngine:
                     hook({"role": self.role, "host": self.pool_id})
 
     def _refuse_unplanned(self, ip, draft_model):
-        """What a planned model (or its window cache kind) does not do
-        yet, refused by name."""
+        """What a planned model (or its window or latent cache kind) does
+        not do yet, refused by name."""
         if not self.planned:
             return
         what = None
@@ -675,6 +689,15 @@ class InferenceEngine:
         elif self.window and self.kv_quant:
             what = ("kv_cache_dtype int8 with a window cache kind: the "
                     "paged kernel's window has no int8 variant")
+        elif self.latent and any(s.attn != "latent"
+                                 for s in self.model.config.layer_plan):
+            what = ("latent layers beside full or window layers in one "
+                    "plan: the programs carry one latent pool, or K and V "
+                    "pools")
+        elif self.latent and jnp.dtype(self.kv_cache_dtype).itemsize < 2:
+            what = ("kv_cache_dtype int8 or fp8 with latent pages: the "
+                    "absorbed decode kernel reads bf16 / float32 rows, "
+                    "and a latent row has no per-head scale")
         if what:
             raise DeepSpeedConfigError(
                 f"serving a planned model (layer_plan) with {what} is "
@@ -950,7 +973,7 @@ class InferenceEngine:
         layers of one kind at a time (a scan where the run is longer than
         one layer). `cache_layer` is the layer's index in its cache
         kind's pools. Returns (carry, [(spec, ys stacked over the run)])."""
-        cache_at, out = {"full": 0, "window": 0}, []
+        cache_at, out = {"full": 0, "window": 0, "latent": 0}, []
         for spec, _, at, n in cfg.plan_runs():
             base = cache_at[spec.attn]
             cache_at[spec.attn] += n
@@ -994,8 +1017,32 @@ class InferenceEngine:
             tables[k], (pos // ps)[:, None], axis=1)[:, 0] for k in kinds}
         slot = pos % ps
 
+        def latent_layer(carry, bp, spec, cache_layer):
+            """The absorbed form: the token's latent row into its page,
+            q' = q_nope W_uk^T against the latent pages, o = u W_uv."""
+            x, pools, held = carry
+            q_nope, q_rope, row = neox._latent_rows(
+                cfg, bp, x, *rot["latent"][:2], spec.heads)
+            pool = paged_latent_write(
+                pools["latent"][0], row[:, 0], cache_layer,
+                page_idx["latent"], slot, backend=self._attn_backend)
+            with scopes.scope("ds.attn"):
+                q = neox.latent_absorb_q(cfg, bp, q_nope[:, 0], q_rope[:, 0])
+                u = paged_latent_decode(
+                    q.astype(pool.dtype), pool, tables["latent"], lengths,
+                    1.0 / math.sqrt(cfg.mla_nope_dim + cfg.mla_rope_dim),
+                    cfg.mla_kv_rank, cache_layer,
+                    backend=self._attn_backend).astype(x.dtype)
+                attn = neox.latent_absorb_out(cfg, bp, u)
+            out, rows = self._held_rows(cfg, neox._block_post_attn(
+                cfg, bp, x, attn.reshape(B, 1, -1),
+                reduce_fn=lambda t: t, token_mask=active))
+            return (out, {"latent": (pool,)}, held + rows), None
+
         @scopes.scoped("ds.block")
         def layer(carry, bp, spec, cache_layer):
+            if spec.attn == "latent":
+                return latent_layer(carry, bp, spec, cache_layer)
             x, pools, held = carry
             kind = spec.attn
             q, k, v = neox._block_qkv(cfg, bp, x, *rot[kind], spec.heads)
@@ -1026,7 +1073,10 @@ class InferenceEngine:
 
     def _kind_pools(self, k_pool, v_pool):
         """{cache kind: (K, V)} of the programs' pool arguments (a pair of
-        pools, or with a window kind a pair of (full, window) pairs)."""
+        pools, or with a window kind a pair of (full, window) pairs; a
+        latent model's one pool rides as `k_pool` beside a None)."""
+        if self.latent:
+            return {"latent": (k_pool,)}
         if self.window_cache is None:
             return {"full": (k_pool, v_pool)}
         return {"full": (k_pool[0], v_pool[0]),
@@ -1034,12 +1084,16 @@ class InferenceEngine:
 
     def _pool_args(self, pools):
         """The inverse of `_kind_pools`: (k_pool, v_pool)."""
+        if self.latent:
+            return pools["latent"][0], None
         if self.window_cache is None:
             return pools["full"]
         return ((pools["full"][0], pools["window"][0]),
                 (pools["full"][1], pools["window"][1]))
 
     def _kind_tables(self, page_table):
+        if self.latent:
+            return {"latent": page_table}
         if self.window_cache is None:
             return {"full": page_table}
         return {"full": page_table[0], "window": page_table[1]}
@@ -1149,7 +1203,8 @@ class InferenceEngine:
             """The same program for a planned model: the layers a run of
             one kind at a time, each cache kind's K/V scattered into its
             own pools through its own page table (a window layer's pages
-            behind the window are table entry 0, the trash page)."""
+            behind the window are table entry 0, the trash page; a latent
+            kind's one pool takes its layers' latent rows)."""
             B, S = tokens.shape
             pos = jnp.arange(S, dtype=jnp.int32)[None, :]
             seg = (pos < lengths[:, None]).astype(jnp.int32)
@@ -1173,21 +1228,31 @@ class InferenceEngine:
 
             G, D = cfg.kv_heads, cfg.head_dim
 
-            def write(pool, new, flat_pt):
-                return pool.at[flat_pt].set(
-                    page_tiles(new, G, D).astype(pool.dtype))
+            def scatter(kind, pool, new):
+                """One pool of cache kind `kind` with its layers' new rows
+                [L_kind, B, S, ...] written as whole pages: K or V rows
+                [G, D] a token as [G, ps, D] tiles, a latent layer's
+                rows [width] as [ps, row], padded to the pool's row."""
+                flat_pt = tables[kind].reshape(-1)
+
+                def tiles(rows):
+                    if kind != "latent":
+                        return page_tiles(rows, G, D)
+                    rows = jnp.pad(rows, ((0, 0), (0, 0), (
+                        0, pool.shape[-1] - rows.shape[-1])))
+                    return rows.reshape(B * n_pages_row, ps, -1)
+
+                return jax.vmap(lambda p, rows: p.at[flat_pt].set(
+                    tiles(rows).astype(p.dtype)))(pool, new)
 
             with scopes.scope("ds.kv_write"):
-                for kind, (kp, vp) in list(pools.items()):
-                    # the kind's layers in order: [L_kind, B, S, G, D]
-                    ks = jnp.concatenate([kv[0] for spec, kv in runs
-                                          if spec.attn == kind])
-                    vs = jnp.concatenate([kv[1] for spec, kv in runs
-                                          if spec.attn == kind])
-                    flat_pt = tables[kind].reshape(-1)
-                    scatter = jax.vmap(write, in_axes=(0, 0, None))
-                    pools[kind] = (scatter(kp, ks, flat_pt),
-                                   scatter(vp, vs, flat_pt))
+                for kind, kind_pools in list(pools.items()):
+                    # the kind's layers in order: [L_kind, B, S, ...]
+                    of_kind = [kv for spec, kv in runs if spec.attn == kind]
+                    pools[kind] = tuple(
+                        scatter(kind, pool,
+                                jnp.concatenate([kv[i] for kv in of_kind]))
+                        for i, pool in enumerate(kind_pools))
 
             return (self._with_held(first_token(params, x, lengths, rng),
                                     held), *self._pool_args(pools))
@@ -2253,8 +2318,11 @@ class InferenceEngine:
                 page_table[i, :len(req.pages)] = req.pages
                 window_table[i, :len(req.window_pages)] = req.window_pages
             self.stats["decode_kv_tokens"] += int(lengths.sum())
-            self.stats["kv_page_steps_full"] += int(
+            self.stats["kv_page_steps_latent" if self.latent
+                       else "kv_page_steps_full"] += int(
                 (-(-lengths // self.page_size)).sum())
+            if self.latent:
+                self.stats["decode_kv_tokens_latent"] += int(lengths.sum())
             if self.window:
                 self.stats["decode_kv_tokens_window"] += int(
                     np.minimum(lengths, self.window).sum())

@@ -42,7 +42,17 @@ the decode-attention kernel dequantizes at the DMA boundary
 (`ops/pallas/decode_attention.py`). A resident token costs
 ``2·L·H·(D + 2)`` bytes instead of ``2·L·H·D·2`` at bf16 — ~1.94× more
 sessions at a fixed pool budget for D = 64 (bf16 scales deliberately:
-fp32 would cost D + 4 and cap the ratio at 1.88×)."""
+fp32 would cost D + 4 and cap the ratio at 1.88×).
+
+**Latent pages** (``latent_width``; a model whose attention is latent,
+MLA): ONE pool ``[L, P, page_size, row]`` with no head axis, held as
+``k`` (``v`` is None): a token's row is its compressed K/V
+``[c_kv | rot(k_r)]``, which every head reads, in front of the zeros that
+fill the row's last lane tile (``row`` = `latent_row_width`: 640 for 576;
+a TPU lays an array with a ragged last dim out with another dim
+innermost, and every kernel call would copy the pool). The allocator,
+the reference counts and the page tables are the ones every kind
+shares."""
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -110,7 +120,7 @@ class PagedKVCache:
     """
 
     def __init__(self, num_layers, num_pages, num_heads, page_size,
-                 head_dim, dtype=jnp.bfloat16, mesh=None):
+                 head_dim, dtype=jnp.bfloat16, mesh=None, latent_width=0):
         if num_pages < 2:
             raise ValueError(
                 f"num_pages must be >= 2 (page 0 is the reserved trash "
@@ -122,6 +132,9 @@ class PagedKVCache:
         self.head_dim = int(head_dim)
         self.dtype = dtype
         self.quantized = jnp.dtype(dtype) == jnp.int8
+        self.latent_width = int(latent_width)
+        if self.latent_width and self.quantized:
+            raise ValueError("latent pages have no int8 variant")
         self.mesh = mesh
         self.sharding = None
         self.scale_sharding = None
@@ -136,8 +149,7 @@ class PagedKVCache:
                 mesh, P(None, None, MODEL_AXIS, None, None))
             self.scale_sharding = NamedSharding(
                 mesh, P(None, None, MODEL_AXIS, None))
-        self.k = self._make_pool()
-        self.v = self._make_pool()
+        self.reset_pools()
         # free list: every page except the trash page, low ids first so
         # tests are deterministic
         self._free = list(range(self.num_pages - 1, 0, -1))
@@ -149,6 +161,12 @@ class PagedKVCache:
         self.prefix_cache = None
 
     def _make_pool(self):
+        if self.latent_width:
+            from ..ops.pallas.decode_attention import latent_row_width
+            return jnp.zeros((self.num_layers, self.num_pages,
+                              self.page_size,
+                              latent_row_width(self.latent_width)),
+                             self.dtype)
         shape = (self.num_layers, self.num_pages, self.num_heads,
                  self.page_size, self.head_dim)
         data = jnp.zeros(shape, self.dtype)
@@ -179,7 +197,7 @@ class PagedKVCache:
         every running sequence, so the zeroed contents are never
         read."""
         self.k = self._make_pool()
-        self.v = self._make_pool()
+        self.v = None if self.latent_width else self._make_pool()
 
     # -- allocator (host-side) --------------------------------------------
 
@@ -256,6 +274,9 @@ class PagedKVCache:
         """K + V bytes of cache one token occupies across all layers
         (int8 pools count the per-slot bf16 scale)."""
         itemsize = jnp.dtype(self.dtype).itemsize
+        if self.latent_width:
+            # what the pool holds: the row with its padding
+            return self.num_layers * self.k.shape[-1] * itemsize
         per_head = self.head_dim * itemsize + (2 if self.quantized else 0)
         return 2 * self.num_layers * self.num_heads * per_head
 

@@ -40,9 +40,10 @@ from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, per_shard
 class LayerSpec:
     """One layer of a `GPTNeoXConfig.layer_plan`: what its attention sees
     (`full`: every earlier position; `window`: the last
-    `GPTNeoXConfig.attn_window`, which is also its KV cache kind), its
-    query heads (the KV heads and the head dim are the model's), its
-    rotary facts, and its FFN (`dense`: one gated MLP of width
+    `GPTNeoXConfig.attn_window`; `latent`: every earlier position through
+    one low-rank row a token, `GPTNeoXConfig.mla_*`; each is also the
+    layer's KV cache kind), its query heads (the KV heads and the head dim
+    are the model's), its rotary facts, and its FFN (`dense`: one gated MLP of width
     `ffn_width`; `experts`: the routed experts of width
     `moe_expert_width`, with the shared expert where the model has one).
     `rope` is () for plain rotary, or ("yarn", factor, original_max,
@@ -155,8 +156,14 @@ class GPTNeoXConfig:
     # ("none" | "per-head": sigmoid(a Wg), one scalar a head and token,
     # from the normed input `a`),
     attn_gate: str = "none"
-    # how the router scores ("softmax": over all experts, float32),
+    # how the router scores, in float32 ("softmax": over all experts;
+    # "sigmoid": each expert alone, with a learned correction bias that
+    # is added for the CHOICE of the top k and is no part of the kept
+    # weights, the published `noaux_tc`), and over how many groups of
+    # experts the choice is limited (1: no groups; more is not computed),
     moe_router_score: str = "softmax"
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # the width of ONE expert where `ffn_width` is a dense layer's (0:
     # `intermediate_size`), a shared expert's width (0: none; it is not
     # gated by the router), the factor on the routed experts' sum,
@@ -169,10 +176,32 @@ class GPTNeoXConfig:
     # would have added is left out (one chip's share of an
     # expert-parallel deployment, without its exchange).
     moe_held: tuple = ()
+    # a `latent` layer's attention (MLA): the ranks of the query's and of
+    # the keys' and values' low-rank rows, and a head's dims: `nope` (q.k
+    # without position), `rope` (q.k with rotary; ONE key row for all
+    # heads), `v`. A token's cache row is `mla_kv_rank + mla_rope_dim`
+    # wide and has no head axis,
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
+    # and the multi-token-prediction (`nextn`) block behind the last
+    # layer (0 or 1): from the last layer's hidden state at i and the
+    # embedding of token i+1, one more layer and the shared head predict
+    # token i+2. Training's (an extra loss term of this weight) and a
+    # drafter's; serving does not load it.
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.0
 
     @property
     def head_dim(self):
         return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def latent_width(self):
+        """Features of a latent layer's cache row: [c_kv | rot(k_r)]."""
+        return self.mla_kv_rank + self.mla_rope_dim
 
     @property
     def kv_heads(self):
@@ -216,7 +245,8 @@ class GPTNeoXConfig:
 
     def cache_layers(self, attn):
         """How many layers keep a KV cache of kind `attn`
-        ("full" | "window"); a homogeneous model's are all "full"."""
+        ("full" | "window" | "latent"); a homogeneous model's are all
+        "full"."""
         if not self.layer_plan:
             return self.num_layers if attn == "full" else 0
         return sum(1 for s in self.layer_plan if s.attn == attn)
@@ -228,8 +258,11 @@ class GPTNeoXConfig:
         E = self.experts_held if held else self.moe_num_experts
         total = self.vocab_size * h * \
             (1 if self.tie_word_embeddings else 2) + h
-        for spec in self.layer_plan:
-            attn = 2 * h * spec.heads * d + 2 * h * G * d
+        def layer(spec):
+            if spec.attn == "latent":
+                attn = self._latent_params(spec.heads)
+            else:
+                attn = 2 * h * spec.heads * d + 2 * h * G * d
             if self.attn_gate == "per-head":
                 attn += h * spec.heads
             if spec.ffn == "dense":
@@ -237,8 +270,24 @@ class GPTNeoXConfig:
             else:
                 ffn = h * self.moe_num_experts + \
                     3 * h * (E * self.expert_width + self.moe_shared_width)
-            total += attn + ffn + 2 * h
+                if self.moe_router_score == "sigmoid":
+                    ffn += self.moe_num_experts     # the correction bias
+            return attn + ffn + 2 * h
+
+        total += sum(layer(spec) for spec in self.layer_plan)
+        if self.mtp_layers:
+            # the nextn block: two norms, the [2h, h] projection, one
+            # layer of the last layer's kind, its own final norm
+            total += 2 * h + 2 * h * h + layer(self.layer_plan[-1]) + h
         return total
+
+    def _latent_params(self, heads):
+        """A latent layer's seven attention leaves."""
+        h, qr, kr = self.hidden_size, self.mla_q_rank, self.mla_kv_rank
+        nope, rope, v = self.mla_nope_dim, self.mla_rope_dim, self.mla_v_dim
+        return (h * qr + qr + qr * heads * (nope + rope) +
+                h * (kr + rope) + kr + kr * heads * (nope + v) +
+                heads * v * h)
 
     def num_params(self, held=True):
         """Parameters of the model. A planned model's are counted by
@@ -313,12 +362,26 @@ class GPTNeoXConfig:
             (("attn_head_dim", 0), ("num_kv_heads", 0), ("attn_window", 0),
              ("attn_gate", "none"), ("moe_expert_width", 0),
              ("moe_shared_width", 0), ("moe_routing_scale", 1.0),
-             ("moe_held", ())) if getattr(self, k) != plain]
-        if self.moe_router_score != "softmax":
+             ("moe_held", ()), ("moe_router_score", "softmax"),
+             ("mla_q_rank", 0), ("mla_kv_rank", 0), ("mla_nope_dim", 0),
+             ("mla_rope_dim", 0), ("mla_v_dim", 0), ("mtp_layers", 0))
+            if getattr(self, k) != plain]
+        if self.moe_router_score not in ("softmax", "sigmoid"):
             raise NotImplementedError(
                 f"moe_router_score {self.moe_router_score!r}: the router "
-                f"scores by a float32 softmax over all experts; sigmoid "
-                f"(or bias-corrected) scoring is not computed")
+                f"scores in float32 by a 'softmax' over all experts, or "
+                f"by a 'sigmoid' of each with a correction bias that "
+                f"chooses and does not weigh; no other scoring is computed")
+        if (self.moe_n_group, self.moe_topk_group) != (1, 1):
+            raise NotImplementedError(
+                f"moe_n_group={self.moe_n_group}, moe_topk_group="
+                f"{self.moe_topk_group}: the router chooses among all "
+                f"experts at once (one group); group-limited routing is "
+                f"not computed")
+        if self.mtp_layers not in (0, 1):
+            raise NotImplementedError(
+                f"mtp_layers={self.mtp_layers}: one next-token-prediction "
+                f"block (or none) is computed")
         if self.attn_gate not in ("none", "per-head"):
             raise NotImplementedError(
                 f"attn_gate {self.attn_gate!r}: 'none', or 'per-head' "
@@ -331,7 +394,9 @@ class GPTNeoXConfig:
                     f"{', '.join(planned_only)} without a layer_plan: the "
                     f"homogeneous block has one KV head a query head of "
                     f"hidden_size / num_heads features, full attention, "
-                    f"no gate, no shared expert and every expert held")
+                    f"no gate, a softmax router, no shared expert, every "
+                    f"expert held, no latent attention and no "
+                    f"next-token-prediction block")
             return
         if len(plan) != self.num_layers:
             raise ValueError(f"layer_plan names {len(plan)} layers, "
@@ -349,13 +414,15 @@ class GPTNeoXConfig:
                 f"residual, no bias, no norm on q or k, gated FFNs, an "
                 f"untied head")
         for i, spec in enumerate(plan):
-            if spec.attn not in ("full", "window") or \
+            if spec.attn not in ("full", "window", "latent") or \
                     spec.ffn not in ("dense", "experts"):
                 raise NotImplementedError(
                     f"layer {i}: attention {spec.attn!r} / FFN "
-                    f"{spec.ffn!r}; the kinds are full | window and "
-                    f"dense | experts")
-            if spec.heads < 1 or spec.heads % self.kv_heads:
+                    f"{spec.ffn!r}; the kinds are full | window | latent "
+                    f"and dense | experts")
+            if spec.attn == "latent":
+                self._check_latent(i, spec)
+            elif spec.heads < 1 or spec.heads % self.kv_heads:
                 raise ValueError(
                     f"layer {i}: {spec.heads} query heads over "
                     f"{self.kv_heads} KV heads")
@@ -378,6 +445,34 @@ class GPTNeoXConfig:
                 raise ValueError(
                     f"moe_held {self.moe_held} is not a range of the "
                     f"router's {self.moe_num_experts} experts")
+
+    def _check_latent(self, i, spec):
+        """A latent layer's facts: all five dims, an even rotary part, a
+        query.key width the prefill's flash kernel is given as ONE head
+        dim beside the value's, plain rotary over the whole rotary part,
+        no gate."""
+        dims = {k: getattr(self, k) for k in
+                ("mla_q_rank", "mla_kv_rank", "mla_nope_dim",
+                 "mla_rope_dim", "mla_v_dim")}
+        if spec.heads < 1 or min(dims.values()) < 1 or \
+                self.mla_rope_dim % 2:
+            raise ValueError(
+                f"layer {i} is a latent layer of {spec.heads} heads and "
+                f"{dims}: every dim is positive and mla_rope_dim even")
+        if self.mla_nope_dim + self.mla_rope_dim != self.mla_v_dim:
+            raise NotImplementedError(
+                f"layer {i}: mla_nope_dim + mla_rope_dim = "
+                f"{self.mla_nope_dim + self.mla_rope_dim} and mla_v_dim = "
+                f"{self.mla_v_dim}: the prefill's attention kernel takes "
+                f"one head dim for q.k and for v; unequal ones are not "
+                f"computed")
+        if spec.rope or spec.rotary_pct != 1.0 or self.attn_gate != "none":
+            raise NotImplementedError(
+                f"layer {i}: a latent layer with rope={spec.rope!r}, "
+                f"rotary_pct={spec.rotary_pct}, attn_gate="
+                f"{self.attn_gate!r}: plain rotary over all mla_rope_dim "
+                f"features and no gate are computed (a scaled rotary "
+                f"would also scale the softmax)")
 
     # ---- presets mirroring the config ladder (BASELINE.md) -------------
 
@@ -487,17 +582,36 @@ def init_stack_params(cfg, spec, n, key):
     """The parameter stack of `n` layers of kind `spec`, every leaf with
     the leading dim `n`. No biases. Attention: `q_w` [h, H*d], `kv_w`
     [h, 2*G*d] ([K | V], each G heads of d), `out_w` [H*d, h], and with
-    a per-head gate `gate_w` [h, H]. FFN, dense: `in_w` [h, 2i]
+    a per-head gate `gate_w` [h, H]; a latent layer's seven leaves:
+    `q_a` [h, q_rank], `q_a_norm` [q_rank], `q_b` [q_rank, H*(nope+rope)]
+    (a head's [nope | rope]), `kv_a` [h, kv_rank+rope] ([c_kv | k_r]),
+    `kv_a_norm` [kv_rank], `kv_b` [kv_rank, H*(nope+v)] (a head's
+    [W_uk | W_uv]), `out_w` [H*v, h]. FFN, dense: `in_w` [h, 2i]
     ([gate | up]), `out_w` [i, h]. Experts: the router `gate`
-    [h, E scored], `w_in` [E held, h, 2w], `w_out` [E held, w, h], and a
-    shared expert's `shared_in` [h, 2s], `shared_out` [s, h]."""
+    [h, E scored] (and a sigmoid router's `gate_bias` [E scored], seeded
+    non-zero at a quarter of the spread of the scores, so that a bias
+    that is dropped, or leaks into the weights, shows), `w_in` [E held, h, 2w],
+    `w_out` [E held, w, h], and a shared expert's `shared_in` [h, 2s],
+    `shared_out` [s, h]."""
     h, d, dt = cfg.hidden_size, cfg.head_dim, cfg.param_dtype
     H, G = spec.heads, cfg.kv_heads
     out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
-    ks = jax.random.split(key, 10)
-    attn = {"q_w": _stack_init(ks[0], (n,), (h, H * d), dt),
-            "kv_w": _stack_init(ks[1], (n,), (h, 2 * G * d), dt),
-            "out_w": _stack_init(ks[2], (n,), (H * d, h), dt, out_scale)}
+    ks = jax.random.split(key, 12)
+    if spec.attn == "latent":
+        qr, kr = cfg.mla_q_rank, cfg.mla_kv_rank
+        nope, rope, vd = cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim
+        attn = {"q_a": _stack_init(ks[0], (n,), (h, qr), dt),
+                "q_a_norm": jnp.ones((n, qr), dt),
+                "q_b": _stack_init(ks[1], (n,), (qr, H * (nope + rope)), dt),
+                "kv_a": _stack_init(ks[9], (n,), (h, kr + rope), dt),
+                "kv_a_norm": jnp.ones((n, kr), dt),
+                "kv_b": _stack_init(ks[10], (n,), (kr, H * (nope + vd)), dt),
+                "out_w": _stack_init(ks[2], (n,), (H * vd, h), dt,
+                                     out_scale)}
+    else:
+        attn = {"q_w": _stack_init(ks[0], (n,), (h, H * d), dt),
+                "kv_w": _stack_init(ks[1], (n,), (h, 2 * G * d), dt),
+                "out_w": _stack_init(ks[2], (n,), (H * d, h), dt, out_scale)}
     if cfg.attn_gate == "per-head":
         attn["gate_w"] = _stack_init(ks[3], (n,), (h, H), dt)
     if spec.ffn == "dense":
@@ -510,6 +624,19 @@ def init_stack_params(cfg, spec, n, key):
                                    dt),
                "w_in": _stack_init(ks[5], (n, E), (h, 2 * w), dt),
                "w_out": _stack_init(ks[6], (n, E), (w, h), dt, out_scale)}
+        if cfg.moe_router_score == "sigmoid":
+            # sigmoid(m Wr) spreads by about a quarter of the logits'
+            # 0.02 * sqrt(h); the bias by a quarter of THAT: non-zero, so
+            # that a bias that is dropped, or one that leaks into the
+            # weights, moves a fifth of the choices, and small, because a
+            # trained bias balances the load: here a decode step of 32
+            # rows touches 50 of 64 experts (uniform routing: 55). A bias
+            # at the scores' own spread decides most choices and leaves 23
+            # touched: half the expert bytes of a deployment's step
+            # (PERF.md section 6, PR 35).
+            mlp["gate_bias"] = _dense_init(
+                ks[11], (n, cfg.moe_num_experts), dt,
+                min(0.0625, 0.00125 * math.sqrt(h)))
         if cfg.moe_shared_width:
             sw = cfg.moe_shared_width
             mlp["shared_in"] = _stack_init(ks[7], (n,), (h, 2 * sw), dt)
@@ -518,6 +645,20 @@ def init_stack_params(cfg, spec, n, key):
     ones = jnp.ones((n, h), dt)
     return {"ln_attn": {"scale": ones}, "ln_mlp": {"scale": ones},
             "attn": attn, "mlp": mlp}
+
+
+def init_mtp_params(cfg, key):
+    """The next-token-prediction block: a norm on the last layer's hidden
+    state (`hnorm`) and on the next token's embedding (`enorm`), the
+    projection `proj` [2h, h] of [hidden | embedding], one layer of the
+    last layer's kind (`block`: a stack of one) and the block's own final
+    norm. The embedding and the head are the model's."""
+    h, dt = cfg.hidden_size, cfg.param_dtype
+    k_proj, k_block = jax.random.split(key)
+    return {"hnorm": init_norm_params(cfg), "enorm": init_norm_params(cfg),
+            "proj": _dense_init(k_proj, (2 * h, h), dt),
+            "block": init_stack_params(cfg, cfg.layer_plan[-1], 1, k_block),
+            "final_ln": init_norm_params(cfg)}
 
 
 def init_params(cfg, rng):
@@ -530,7 +671,7 @@ def init_params(cfg, rng):
     dt = cfg.param_dtype
     if cfg.layer_plan:
         kinds = cfg.plan_kinds()
-        return {
+        params = {
             "embed": {"wte": _dense_init(keys[0], (cfg.vocab_size,
                                                    cfg.hidden_size), dt)},
             "stacks": {name: init_stack_params(cfg, spec, len(layers),
@@ -540,6 +681,9 @@ def init_params(cfg, rng):
             "embed_out": {"wte": _dense_init(
                 keys[-1], (cfg.vocab_size, cfg.hidden_size), dt)},
         }
+        if cfg.mtp_layers:
+            params["mtp"] = init_mtp_params(cfg, jax.random.fold_in(rng, 1))
+        return params
     params = {
         "embed": {"wte": _dense_init(keys[0], (cfg.vocab_size,
                                                cfg.hidden_size), dt)},
@@ -676,8 +820,10 @@ def _rotary_cache(cfg, seq_len, dtype=jnp.float32, spec=None):
     rotary, or with `spec` (a planned model's `LayerSpec`) that layer
     kind's."""
     if spec is not None:
+        # a latent layer rotates its mla_rope_dim features, all of them
+        dim = cfg.mla_rope_dim if spec.attn == "latent" else cfg.head_dim
         return _rotary_table(*rope_inv_freq(
-            cfg.head_dim, spec.rotary_pct, spec.rotary_base, spec.rope),
+            dim, spec.rotary_pct, spec.rotary_base, spec.rope),
             seq_len, dtype)
     return _rotary_table(*rope_inv_freq(
         cfg.head_dim, cfg.rotary_pct, cfg.rotary_emb_base), seq_len, dtype)
@@ -685,7 +831,7 @@ def _rotary_cache(cfg, seq_len, dtype=jnp.float32, spec=None):
 
 def plan_rotary(cfg, seq_len):
     """A planned model's rotary tables, one an attention kind:
-    {"full" | "window": (cos, sin, rot_dim)} (a kind's layers share their
+    {"full" | "window" | "latent": (cos, sin, rot_dim)} (a kind's layers share their
     rotary facts; `check_block` does not hold that, the family file
     does)."""
     return {spec.attn: _rotary_cache(cfg, seq_len, spec=spec)
@@ -697,6 +843,17 @@ def _rotate_half(x):
     return jnp.concatenate([-x2, x1], axis=-1)
 
 
+def _rotary_rows(x, cos, sin):
+    """Rotate-half rotary over ALL features of x [B, S, H, rot]; cos/sin
+    are [S, rot] (a shared position stream) or [B, S, rot] (per-batch
+    positions)."""
+    if cos.ndim == 2:
+        cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    else:
+        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    return x * cos.astype(x.dtype) + _rotate_half(x) * sin.astype(x.dtype)
+
+
 def apply_rotary(q, k, cos, sin, rot_dim):
     """Rotary embedding on the first rot_dim dims of q/k [B, S, H, D].
 
@@ -704,20 +861,9 @@ def apply_rotary(q, k, cos, sin, rot_dim):
     (per-batch positions — packed batches gather the cache at each
     token's INTRA-document position, so a packed document sees the same
     rotary stream as the same document padded alone)."""
-    q_rot, q_pass = q[..., :rot_dim], q[..., rot_dim:]
-    k_rot, k_pass = k[..., :rot_dim], k[..., rot_dim:]
-    if cos.ndim == 2:
-        cos = cos[None, :, None, :]
-        sin = sin[None, :, None, :]
-    else:
-        cos = cos[:, :, None, :]
-        sin = sin[:, :, None, :]
-    cos = cos.astype(q.dtype)
-    sin = sin.astype(q.dtype)
-    q_rot = q_rot * cos + _rotate_half(q_rot) * sin
-    k_rot = k_rot * cos + _rotate_half(k_rot) * sin
-    return (jnp.concatenate([q_rot, q_pass], axis=-1),
-            jnp.concatenate([k_rot, k_pass], axis=-1))
+    return tuple(
+        jnp.concatenate([_rotary_rows(x[..., :rot_dim], cos, sin),
+                         x[..., rot_dim:]], axis=-1) for x in (q, k))
 
 
 def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
@@ -841,6 +987,69 @@ def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
     return q, k, v
 
 
+@scopes.scoped("ds.attn")
+def _latent_rows(cfg, params, x, cos, sin, heads):
+    """A latent (MLA) layer's ln1 and its two low-rank projections:
+    (q_nope [B, S, H, nope], rot(q_rope) [B, S, H, rope], latent
+    [B, S, kv_rank + rope]). `latent` = [rms(c_kv) | rot(k_r)] is the
+    token's cache row: everything a later query needs of this token, with
+    ONE rotary key row for all heads. Shared by the expanded form
+    (training, prefill) and the absorbed one (decode)."""
+    a = params["attn"]
+    B, S, _ = x.shape
+    nope, kr, eps = cfg.mla_nope_dim, cfg.mla_kv_rank, cfg.layernorm_eps
+    ln1 = norm(cfg, params["ln_attn"], x)
+    with scopes.scope("ds.mla_q"):
+        c_q = rms_norm(_wmat(ln1, a["q_a"]), a["q_a_norm"], eps)
+        q = _wmat(c_q, a["q_b"]).reshape(B, S, heads, -1)
+        q_nope = q[..., :nope]
+        q_rope = _rotary_rows(q[..., nope:], cos, sin)
+    with scopes.scope("ds.mla_kv"):
+        ckv = _wmat(ln1, a["kv_a"])
+        c_kv = rms_norm(ckv[..., :kr], a["kv_a_norm"], eps)
+        k_r = _rotary_rows(ckv[..., None, kr:], cos, sin)[:, :, 0]
+        latent = jnp.concatenate([c_kv, k_r], axis=-1)
+    return q_nope, q_rope, latent
+
+
+def _latent_up(cfg, params, heads):
+    """`kv_b` as (W_uk [kv_rank, H, nope], W_uv [kv_rank, H, v])."""
+    w = params["attn"]["kv_b"].reshape(cfg.mla_kv_rank, heads, -1)
+    return w[..., :cfg.mla_nope_dim], w[..., cfg.mla_nope_dim:]
+
+
+@scopes.scoped("ds.mla_expand")
+def _latent_expand(cfg, params, latent, heads):
+    """The expanded form: every head's keys and values from the latent
+    rows, (k [B, S, H, nope + rope], v [B, S, H, v]); a head's key is
+    [k_nope_h | rot(k_r)], the rotary part the same for all heads."""
+    B, S, _ = latent.shape
+    kr, nope = cfg.mla_kv_rank, cfg.mla_nope_dim
+    kv = _wmat(latent[..., :kr], params["attn"]["kv_b"]).reshape(
+        B, S, heads, -1)
+    k_r = jnp.broadcast_to(latent[:, :, None, kr:],
+                           (B, S, heads, cfg.mla_rope_dim))
+    return (jnp.concatenate([kv[..., :nope], k_r], axis=-1), kv[..., nope:])
+
+
+@scopes.scoped("ds.mla_absorb")
+def latent_absorb_q(cfg, params, q_nope, q_rope):
+    """The absorbed form's query [B, H, kv_rank + rope] of one token a
+    row: q'_h = q_nope_h W_uk_h^T meets c_kv where q_nope_h met
+    k_nope_h = c_kv W_uk_h, the same sum in another order."""
+    w_uk, _ = _latent_up(cfg, params, q_nope.shape[-2])
+    q_lat = jnp.einsum("bhn,khn->bhk", q_nope, w_uk.astype(q_nope.dtype))
+    return jnp.concatenate([q_lat, q_rope], axis=-1)
+
+
+@scopes.scoped("ds.mla_absorb")
+def latent_absorb_out(cfg, params, u):
+    """o_h = u_h W_uv_h of the absorbed form: `u` [B, H, kv_rank] is the
+    attention's weighted sum of c_kv rows; returns [B, H, v]."""
+    _, w_uv = _latent_up(cfg, params, u.shape[-2])
+    return jnp.einsum("bhk,khv->bhv", u, w_uv.astype(u.dtype))
+
+
 def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
                      ffn_quant=None, token_mask=None):
     """Everything after the attention core: out projection, residuals,
@@ -889,7 +1098,8 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
                 activation=act,
                 token_mask=None if token_mask is None
                 else token_mask.reshape(B * S),
-                held=cfg.moe_held or None, scale=cfg.moe_routing_scale)
+                held=cfg.moe_held or None, scale=cfg.moe_routing_scale,
+                score=cfg.moe_router_score)
             y = y.reshape(ln2.shape)
             if "shared_in" in params["mlp"]:
                 # the shared expert: every token, no router weight
@@ -981,13 +1191,24 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
 
     `spec` (a planned model's `LayerSpec`, `params` a layer of that
     kind's stack): the layer's own query heads, and its window if it is
-    a window layer."""
+    a window layer. A latent layer runs expanded here (every head's keys
+    and values from the latent rows, then the same attention core), and
+    what `return_kv` hands back is (its latent rows [B, S, kv_rank +
+    rope],): the cache's one pool's."""
     B, S, h = x.shape
     cos, sin, rot_dim = cos_sin
     heads = spec.heads if spec is not None else cfg.num_heads
     window = cfg.attn_window \
         if spec is not None and spec.attn == "window" else None
-    q, k, v = _block_qkv(cfg, params, x, cos, sin, rot_dim, heads // mp)
+    if spec is not None and spec.attn == "latent":
+        q_nope, q_rope, latent = _latent_rows(cfg, params, x, cos, sin,
+                                              heads)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k, v = _latent_expand(cfg, params, latent, heads)
+        kv = (latent,)
+    else:
+        q, k, v = _block_qkv(cfg, params, x, cos, sin, rot_dim, heads // mp)
+        kv = (k, v)
     with scopes.scope("ds.attn"):
         if attn_fn is not None:
             attn = attn_fn(q, k, v) if segment_ids is None else \
@@ -1006,7 +1227,7 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
         ffn_quant=ffn_quant,
         token_mask=None if segment_ids is None else segment_ids > 0)
     if return_kv:
-        return out, (k, v)
+        return out, kv
     return out
 
 
@@ -1334,9 +1555,12 @@ def plan_layer_params(cfg, stacks):
     return out
 
 
-def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids):
+def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids,
+                            with_last=False):
     """`forward_hidden` of a planned model: the same block code, a layer
-    at a time with its own `LayerSpec`."""
+    at a time with its own `LayerSpec`. `with_last` also returns the last
+    layer's hidden states, before the final norm (what the
+    next-token-prediction block reads)."""
     S = tokens.shape[1]
     with scopes.scope("ds.embed"):
         x = params["embed"]["wte"][tokens]
@@ -1350,7 +1574,31 @@ def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids):
             x = block_hidden(_block_core(
                 cfg, bp, x, rotary[spec.attn], use_pallas, mp=1,
                 reduce_fn=lambda t: t, segment_ids=segment_ids, spec=spec))
-    return norm(cfg, params["final_ln"], x)
+    out = norm(cfg, params["final_ln"], x)
+    return (out, x) if with_last else out
+
+
+def mtp_hidden(cfg, params, tokens, last, use_pallas=True):
+    """The next-token-prediction block's final-norm hidden states
+    [B, S, h]: position i from the last layer's hidden state at i (`last`
+    [B, S, h], before the final norm) and the embedding of token i + 1,
+    through the projection of [rms(hidden) | rms(embedding)], one layer
+    of the last layer's kind and the block's own final norm. Under the
+    model's head, position i predicts token i + 2. Position S - 1 has no
+    next token (it is given token 0's, and by causality moves no earlier
+    position): a caller reads [:, :S - 1]."""
+    mtp = params["mtp"]
+    spec = cfg.layer_plan[-1]
+    with scopes.scope("ds.embed"):
+        emb = params["embed"]["wte"][jnp.roll(tokens, -1, axis=1)]
+    x = _wmat(jnp.concatenate([norm(cfg, mtp["hnorm"], last),
+                               norm(cfg, mtp["enorm"], emb)], axis=-1),
+              mtp["proj"])
+    bp = jax.tree_util.tree_map(lambda a: a[0], mtp["block"])
+    x = block_hidden(_block_core(
+        cfg, bp, x, _rotary_cache(cfg, tokens.shape[1], spec=spec),
+        use_pallas, mp=1, reduce_fn=lambda t: t, spec=spec))
+    return norm(cfg, mtp["final_ln"], x)
 
 
 def forward(cfg, params, tokens, use_pallas=True, remat_blocks=False,
@@ -1610,10 +1858,11 @@ class GPTNeoX:
             raise DeepSpeedConfigError(
                 f"{what}: training of a planned model (layer_plan: window "
                 f"layers, grouped KV heads, an attention gate, a shared "
-                f"expert, a held share of the experts) is not built; the "
-                f"flash backward, the parameter specs and the pipeline "
-                f"layers are the homogeneous block's. InferenceEngine "
-                f"serves it")
+                f"expert, a held share of the experts, latent attention) "
+                f"is not built; the flash backward, the parameter specs "
+                f"and the pipeline layers are the homogeneous block's. "
+                f"InferenceEngine serves it (`loss_fn` alone computes a "
+                f"plan of latent layers, packing apart)")
 
     def _attention_fn(self):
         """The attention core `forward_hidden` should use: the SP/sparse
@@ -1806,13 +2055,59 @@ class GPTNeoX:
         """Scalar LM loss; with `ffn_amax` (delayed-scaling quantized
         FFN state, [L, 4, H]) the return is (loss, new_ffn_amax) — the
         engine threads the state through `EngineState.quant`."""
-        self._refuse_planned_training("loss_fn")
+        if self.config.layer_plan:
+            return self._planned_loss(params, batch)
         hidden, labels, aux, new_amax = self._lm_forward(
             params, batch, rng, ffn_amax=ffn_amax)
         loss = self._head_loss(params, hidden, labels, aux)
         if ffn_amax is not None:
             return loss, new_amax
         return loss
+
+    def _planned_hidden(self, params, tokens):
+        """(final-norm hidden states, the nextn block's or None) of a
+        planned model."""
+        cfg = self.config
+        hidden, last = _forward_hidden_planned(
+            cfg, params, tokens, self.use_pallas, None, with_last=True)
+        if not cfg.mtp_layers:
+            return hidden, None
+        return hidden, mtp_hidden(cfg, params, tokens, last,
+                                  self.use_pallas)
+
+    def _planned_loss(self, params, batch):
+        """A planned model's loss, as the model's description and not a
+        training path (`initialize` refuses a planned model): next-token
+        cross entropy, plus `mtp_loss_weight` times the nextn block's
+        (position i against token i + 2). No load-balancing term: the
+        sigmoid router's bias is corrected outside the gradient. Only a
+        plan of latent layers has every backward it needs (the window and
+        grouped-head flash forward has none)."""
+        cfg = self.config
+        tokens, labels, seg = split_lm_batch(batch)
+        if seg is not None or any(s.attn != "latent"
+                                  for s in cfg.layer_plan):
+            self._refuse_planned_training("loss_fn")
+        hidden, mtp = self._planned_hidden(params, tokens)
+        head = params["embed_out"]["wte"]
+        loss = fused_lm_head_loss(hidden, head, labels)
+        if mtp is not None:
+            # position i of `mtp` against labels[i + 2]
+            loss = loss + cfg.mtp_loss_weight * fused_lm_head_loss(
+                mtp[:, :-1], head, labels[:, 1:])
+        return loss
+
+    def mtp_logits(self, params, tokens):
+        """The next-token-prediction block's logits [B, S - 1, V]:
+        position i, from the last layer's hidden state at i and token
+        i + 1, predicts token i + 2."""
+        if not self.config.mtp_layers:
+            raise ValueError("the model has no next-token-prediction "
+                             "block (mtp_layers=0)")
+        _, mtp = self._planned_hidden(params, tokens)
+        return jnp.einsum("bsh,vh->bsv", mtp[:, :-1],
+                          params["embed_out"]["wte"].astype(mtp.dtype),
+                          preferred_element_type=jnp.float32)
 
     def init_ffn_amax(self):
         """Zero amax-history state for `loss_fn(..., ffn_amax=)` —

@@ -548,7 +548,8 @@ def dropless_geometry(tokens, top_k, n_experts):
 
 def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                      activation=jax.nn.silu, token_mask=None,
-                     gmm_backend=None, held=None, scale=1.0):
+                     gmm_backend=None, held=None, scale=1.0,
+                     score="softmax"):
     """Top-k routing that drops nothing, through the sort engine.
 
     params: {"gate" [H, E] (the router), "w_in" [E, H, 2I] (each
@@ -588,6 +589,13 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
     on each of the E experts (exact in float32), from which a caller
     counts the pairs that were held. `scale` multiplies the kept
     weights (after the renormalisation).
+
+    `score="sigmoid"` (the published `noaux_tc` router): s = sigmoid(x @
+    gate) in float32, each expert alone; the top_k are the largest of
+    s + params["gate_bias"] ([E], a learned correction that balances the
+    load), and the kept weights are those experts' s, WITHOUT the bias,
+    renormalised and scaled as above. P of the statistics is then the
+    mean of s / sum_e s.
     """
     from .. import scopes
     from ..ops.pallas.grouped_matmul import ragged_matmul, ragged_tile_maps
@@ -613,8 +621,19 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
         logits = jnp.dot(x.astype(jnp.float32),
                          params["gate"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)               # [T, E]
-        weights, experts = jax.lax.top_k(probs, k)            # [T, k]
+        if score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)                   # [T, E]
+            # the bias picks; what it picked is weighed by its own score
+            _, experts = jax.lax.top_k(
+                scores + params["gate_bias"].astype(jnp.float32), k)
+            weights = jnp.take_along_axis(scores, experts, axis=-1)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        elif score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)           # [T, E]
+            weights, experts = jax.lax.top_k(probs, k)        # [T, k]
+        else:
+            raise ValueError(f"router score {score!r}: 'softmax' or "
+                             f"'sigmoid'")
         if norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         if scale != 1.0:

@@ -71,7 +71,11 @@ FLASH_LONG_SEQ = 8192
 # each row a pair of blocks a benchmark cell measured on that chip. A
 # shape class without a row takes `_flash_fallback`; rows are not
 # invented for shapes no cell runs (head dim 128 at 8k and over,
-# non-causal long sequences: ROADMAP.md, D10).
+# non-causal long sequences: ROADMAP.md, D10). Head dim 256, causal,
+# forward (the latent cell's expanded prefill, 8k-16k) was measured and
+# has no row because the fallback won: (1024, 1024) 6.98 / 25.3 ms at
+# 8k / 16k against (1024, 512) 7.59 / 27.7, (512, 1024) 7.43 / 27.0,
+# (512, 512) 8.44 / 31.6; (2048, 512) ran out of VMEM (PERF.md, PR 35).
 FLASH_LONG_SEQ_BLOCKS = {
     # pythia-410m.train_16k: of the geometries the old in-trace timer
     # picked in three runs (PR 23), the faster of the two that compiled

@@ -600,3 +600,272 @@ def paged_kv_write(pools, rows, layer, page_idx, slot, backend=None):
     if backend != "pallas":
         raise ValueError(f"unknown paged kv write backend {backend!r}")
     return paged_kv_write_pallas(pools, rows, layer, page_idx, slot)
+
+
+# ---------------------------------------------------------------------------
+# latent pages (MLA): the absorbed decode, and the token's latent row
+# ---------------------------------------------------------------------------
+
+# Pages of one row a grid step of the latent kernel moves. A latent page
+# has no head axis ([page_size, row]: 80 KB at 64 x 640 bf16, a sixth of a
+# 16-head K and V page), so one page a step would leave the step's fixed
+# cost, not its bytes, to set the time. Measured on a v5e at 32 rows over
+# 315k attended rows of 20 heads (PERF.md section 6, PR 35): 4 pages a
+# step 1.07 ms a call, 8 0.80, 16 0.70 (63% of the roofline's 0.44).
+LATENT_PAGES_PER_STEP = 16
+
+
+def latent_row_width(width):
+    """The pool's row for a latent row of `width` features: whole lane
+    tiles (576 -> 640)."""
+    return -(-width // LANES) * LANES
+
+
+def _latent_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
+                   *refs, sm_scale, page_size, v_width):
+    """Grid step `t`: `LATENT_PAGES_PER_STEP` consecutive pages of one
+    batch row, each a [ps, width] tile every head reads, meet the row's
+    [H, width] queries as ONE [pages * ps, width] operand. The value is
+    the tile's first `v_width` columns (c_kv). A page past the row's
+    length is the trash page, and masked."""
+    *page_refs, o_ref, m_scr, l_scr, acc_scr = refs
+    t = pl.program_id(0)
+    b = row_ref[t]
+    length = len_ref[b]
+    span = len(page_refs) * page_size
+    first_pos = (t - start_ref[b]) * span
+
+    @pl.when(t == start_ref[b])
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(first_pos < length)         # not an inactive row's one step
+    def _compute():
+        rows = jnp.concatenate([r[...] for r in page_refs], axis=0)
+        s = jax.lax.dot_general(
+            q_ref[...], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale      # [H, span]
+        live = first_pos + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) < length
+        s = jnp.where(live, s, NEG_INF)
+        m = m_scr[:, :1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        prob = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_scr[:, :1] + jnp.sum(prob, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            prob.astype(rows.dtype), rows[:, :v_width],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[:] = acc_scr[:] * alpha + pv
+
+    @pl.when(first_pos + span >= length)             # the row's last step
+    def _finalize():
+        l = l_scr[:, :1]
+        o_ref[...] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
+                      ).astype(o_ref.dtype)
+
+
+def paged_latent_decode_pallas(q, pool, page_table, lengths, sm_scale,
+                               v_width, layer):
+    """The latent kernel over grid (steps,), `decode_steps` at a span of
+    `LATENT_PAGES_PER_STEP` pages: the pool rides once a page of the
+    step, each operand's index map resolving its own page through the
+    prefetched page table. (The grid's extent is traced, so the
+    `pallas_call` is built where it is called; its body is a dozen
+    lines, not flash attention's unrolled strips.)"""
+    B, H, W = q.shape
+    page_size, table_width = pool.shape[2], page_table.shape[1]
+    pages = LATENT_PAGES_PER_STEP
+    # the heads are the matmuls' M: padded to whole sublane tiles of the
+    # operand's dtype (20 heads of bf16 ride as 32)
+    tile = 32 // jnp.dtype(q.dtype).itemsize
+    Hp = -(-H // tile) * tile
+    if Hp != H:
+        q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
+
+    def row_block(t, row, start, pt, ln, lyr):
+        return row[t], 0, 0
+
+    def page_block(j):
+        def block(t, row, start, pt, ln, lyr):
+            b = row[t]
+            page = (t - start[b]) * pages + j
+            # past the row's length (an inactive row's every page): the
+            # trash page, which a run of them fetches once
+            return (lyr[0], jnp.where(
+                page * page_size < ln[b],
+                pt[b, jnp.minimum(page, table_width - 1)], 0), 0, 0)
+        return block
+
+    with scopes.scope("ds.paged_decode_latent"):
+        lengths = lengths.astype(jnp.int32)
+        n_steps, row, start = decode_steps(lengths, pages * page_size,
+                                           -(-table_width // pages))
+        out = pl.pallas_call(
+            functools.partial(_latent_kernel, sm_scale=sm_scale,
+                              page_size=page_size, v_width=v_width),
+            out_shape=jax.ShapeDtypeStruct((B, Hp, v_width), q.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(n_steps,),
+                in_specs=[pl.BlockSpec((None, Hp, W), row_block)] +
+                [pl.BlockSpec((None, None, page_size, W), page_block(j))
+                 for j in range(pages)],
+                out_specs=pl.BlockSpec((None, Hp, v_width), row_block),
+                scratch_shapes=[
+                    pltpu.VMEM((Hp, LANES), jnp.float32),
+                    pltpu.VMEM((Hp, LANES), jnp.float32),
+                    pltpu.VMEM((Hp, v_width), jnp.float32),
+                ],
+            ),
+            compiler_params=CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=_interpret(), name="ds.paged_decode_latent",
+        )(row, start, page_table.astype(jnp.int32), lengths,
+          _layer_operand(layer), q, *(pool,) * pages)
+    return out[:, :H]
+
+
+@scopes.scoped("ds.paged_decode_xla")
+def paged_latent_decode_xla(q, pool, page_table, lengths, sm_scale, v_width,
+                            layer):
+    """The kernel's XLA twin: gather the row's pages into [B, S_max,
+    width] and run a masked softmax; exact zeros for a length of 0."""
+    B = q.shape[0]
+    rows = pool[layer, page_table].reshape(B, -1, pool.shape[-1])
+    s = jnp.einsum("bhw,bsw->bhs", q, rows,
+                   preferred_element_type=jnp.float32) * sm_scale
+    live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] < \
+        lengths[:, None]
+    s = jnp.where(live[:, None, :], s, NEG_INF)
+    prob = jnp.where(live[:, None, :],
+                     jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    l = jnp.sum(prob, axis=-1, keepdims=True)
+    prob = prob / jnp.where(l == 0.0, 1.0, l)
+    return jnp.einsum("bhs,bsv->bhv", prob.astype(rows.dtype),
+                      rows[..., :v_width],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def paged_latent_supported(width, v_width, page_size):
+    """Mosaic constraints of the latent kernel: a page's rows on whole
+    sublane tiles, and the value a lane-aligned prefix of the row."""
+    if _interpret():
+        return True
+    return page_size % 16 == 0 and v_width % LANES == 0 and width > v_width
+
+
+def paged_latent_decode(q, pool, page_table, lengths, sm_scale, v_width,
+                        layer, backend=None):
+    """One decode step of ABSORBED latent attention (MLA): ``u[b, h] =
+    softmax(q[b, h] . rows[b]) . rows[b][:, :v_width]`` over the token
+    rows of sequence b, read through ``page_table[b]`` and masked at
+    ``lengths[b]`` (which includes the token being decoded; 0 marks an
+    inactive row, whose output is exact zero).
+
+    ``q`` [B, H, width]: a head's absorbed query [q_nope W_uk^T |
+    rot(q_rope)]; ``pool`` [L, P, page_size, row]: the engine's stacked
+    latent pages, read at layer ``layer`` where they lie, a row being
+    [c_kv | rot(k_r) | zeros up to a whole lane tile] with NO head axis:
+    all H heads read the one tile. (``row`` is `latent_row_width(width)`:
+    a TPU lays an array whose rows are not whole lane tiles out with
+    another dim innermost, and the kernel would be handed a copy of the
+    pool, not the pool.)
+    ``sm_scale`` is the model's 1 / sqrt(nope + rope), which the row's
+    width does not tell. Returns u [B, H, v_width]: the caller's W_uv
+    makes each head's output of it.
+
+    backend: as `paged_decode_attention` (None = the kernel on a TPU
+    where `paged_latent_supported`, its XLA twin otherwise)."""
+    B, H, W = q.shape
+    if pool.ndim != 4 or pool.shape[-1] < W or not 0 < v_width <= W:
+        raise ValueError(f"latent pool {pool.shape} must be [L, P, "
+                         f"page_size, >= {W}] for queries {q.shape}, with "
+                         f"a value width in (0, {W}], got {v_width}")
+    # a pool row is the token's row and the padding that fills its last
+    # lane tile: the query's zeros meet it
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - W)))
+    W = pool.shape[-1]
+    if page_table.ndim != 2 or page_table.shape[0] != B or \
+            lengths.shape != (B,):
+        raise ValueError(f"page_table {page_table.shape} / lengths "
+                         f"{lengths.shape} do not serve {B} rows")
+    if backend is None:
+        if not _interpret() and paged_latent_supported(W, v_width,
+                                                       pool.shape[2]):
+            backend = "pallas"
+        else:
+            note_xla_on_tpu(
+                "paged_latent_decode",
+                f"row width {W}, value width {v_width}, page size "
+                f"{pool.shape[2]}: the kernel needs a page size that is a "
+                f"multiple of 16 and a value width that is a multiple of "
+                f"{LANES}")
+            backend = "xla"
+    _LAST_BACKEND["decode_latent"] = backend
+    if backend == "xla":
+        return paged_latent_decode_xla(q, pool, page_table, lengths,
+                                       sm_scale, v_width, layer)
+    if backend != "pallas":
+        raise ValueError(f"unknown paged decode backend {backend!r}")
+    return paged_latent_decode_pallas(q, pool, page_table, lengths,
+                                      sm_scale, v_width, layer)
+
+
+def _latent_write_kernel(lyr_ref, page_ref, slot_ref, row_ref, pool_ref,
+                         out_ref):
+    """One batch row: its page tile [ps, width] comes in, gets the row at
+    its slot, and goes back to where it came from (as `_kv_write_kernel`)."""
+    tile = pool_ref[...]
+    slots = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    out_ref[...] = jnp.where(slots == slot_ref[pl.program_id(0)],
+                             row_ref[...], tile)
+
+
+@scopes.scoped("ds.kv_write")
+def paged_latent_write(pool, rows, layer, page_idx, slot, backend=None):
+    """One decoded token's latent row into layer ``layer`` of the stacked
+    latent pool: ``pool[layer, page_idx[b], slot[b]] = rows[b]``
+    (``pool`` [L, P, page_size, row], ``rows`` [B, width <= row], padded
+    with zeros to the pool's row). On a TPU a kernel that aliases the
+    pool and rewrites one page tile a row, as `paged_kv_write`; XLA's
+    scatter off it. Returns the pool."""
+    B = rows.shape[0]
+    if pool.ndim != 4 or rows.ndim != 2 or pool.shape[-1] < rows.shape[1]:
+        raise ValueError(f"rows {rows.shape} do not match the latent pool "
+                         f"{pool.shape}: expected [{B}, width] for "
+                         f"[L, P, page_size, >= width]")
+    if backend is None:
+        backend = "xla" if _interpret() else "pallas"
+    _LAST_BACKEND["kv_write_latent"] = backend
+    W = pool.shape[-1]
+    rows = jnp.pad(rows.astype(pool.dtype),
+                   ((0, 0), (0, W - rows.shape[1])))
+    if backend == "xla":
+        return pool.at[layer, page_idx, slot].set(rows)
+    if backend != "pallas":
+        raise ValueError(f"unknown paged kv write backend {backend!r}")
+    tile = pool.shape[2:]
+    pool_spec = pl.BlockSpec((None, None, *tile),
+                             lambda b, lyr, pg, sl: (lyr[0], pg[b], 0, 0))
+    return pl.pallas_call(
+        _latent_write_kernel,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            # the row rides as [B, 1, width] and broadcasts over the slots
+            in_specs=[pl.BlockSpec((None, 1, W),
+                                   lambda b, lyr, pg, sl: (b, 0, 0)),
+                      pool_spec],
+            out_specs=pool_spec,
+        ),
+        input_output_aliases={4: 0},
+        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=_interpret(), name="ds.kv_write",
+    )(_layer_operand(layer), page_idx.astype(jnp.int32),
+      slot.astype(jnp.int32), rows[:, None, :], pool)

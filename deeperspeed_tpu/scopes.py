@@ -43,6 +43,9 @@ SCOPES = {
     "ds.paged_decode_window": ("kernel", "the paged decode of a window "
                                          "layer: the same kernel over the "
                                          "pages inside the window"),
+    "ds.paged_decode_latent": ("kernel", "the absorbed paged decode of a "
+                                         "latent layer: every head over "
+                                         "ONE [page, row] tile a page"),
     "ds.adam": ("kernel", "fused Adam over a flat shard"),
     "ds.sparse_attn_fwd": ("kernel", "block-sparse attention forward"),
     "ds.sparse_attn_bwd_dkv": ("kernel", "block-sparse backward, dk/dv"),
@@ -61,6 +64,18 @@ SCOPES = {
     "ds.attn_gate": ("region", "the per-head sigmoid gate on the "
                                "attention output: its projection from "
                                "the normed input, and the product"),
+    "ds.mla_q": ("region", "a latent layer's query: the low-rank "
+                           "projection, its norm, the projection to the "
+                           "heads, the rotary of their rope parts"),
+    "ds.mla_kv": ("region", "a latent layer's cache row: the low-rank "
+                            "projection, the norm of c_kv, the rotary of "
+                            "the one key row k_r"),
+    "ds.mla_expand": ("region", "the expanded form (training, prefill): "
+                                "every head's keys and values from the "
+                                "latent rows"),
+    "ds.mla_absorb": ("region", "the absorbed form (decode): q' = q_nope "
+                                "W_uk^T before the kernel, o = u W_uv "
+                                "after it"),
     "ds.moe_shared": ("region", "the shared expert every token passes "
                                 "through, beside the routed ones"),
     "ds.attn_xla": ("region", "the XLA fallback of attention"),

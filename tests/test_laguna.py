@@ -405,7 +405,7 @@ def _plan_config(**over):
 
 
 REFUSED_BLOCK = {
-    "sigmoid router": (dict(moe_router_score="sigmoid"), "moe_router_score"),
+    "tanh router": (dict(moe_router_score="tanh"), "moe_router_score"),
     "elementwise gate": (dict(attn_gate="elementwise"), "attn_gate"),
     "qk norm": (dict(qk_norm=True), "qk_norm"),
     "parallel residual": (dict(use_parallel_residual=True),

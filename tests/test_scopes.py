@@ -62,6 +62,22 @@ PLAN_CFG = GPTNeoXConfig(
     moe_routing_scale=2.5, moe_held=(0, 4))
 PLAN_SCOPES = MODEL_SCOPES + MOE_SCOPES + ["ds.attn_gate", "ds.moe_shared",
                                            "ds.kv_write"]
+# a latent plan (GLM-MoE-lite's): low-rank q and kv rows, expanded for
+# prefill and absorbed for decode over head-less latent pages, a sigmoid
+# router with its bias, a shared expert
+LATENT_CFG = GPTNeoXConfig(
+    vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
+    max_seq_len=256, use_parallel_residual=False, norm="rmsnorm",
+    use_bias=False, hidden_act="silu", ffn_gated=True, ffn_width=64,
+    layer_plan=(LayerSpec(attn="latent", heads=2, ffn="dense"),
+                LayerSpec(attn="latent", heads=2, ffn="experts")),
+    attn_head_dim=64, num_kv_heads=2, mla_q_rank=48, mla_kv_rank=128,
+    mla_nope_dim=48, mla_rope_dim=16, mla_v_dim=64,
+    moe_num_experts=8, moe_top_k=2, moe_dropless=True,
+    moe_norm_topk_prob=True, moe_router_score="sigmoid",
+    moe_expert_width=64, moe_shared_width=64, moe_routing_scale=1.8)
+LATENT_SCOPES = MODEL_SCOPES + MOE_SCOPES + [
+    "ds.mla_q", "ds.mla_kv", "ds.moe_shared", "ds.kv_write"]
 PROGRAMS = {
     # the tiled kernels: 256 tokens in blocks of 128
     "train": MODEL_SCOPES + ["ds.flash_fwd", "ds.flash_bwd_dq",
@@ -87,6 +103,11 @@ PROGRAMS = {
     "plan_prefill": PLAN_SCOPES + ["ds.flash_fwd", "ds.flash_fwd_window"],
     "plan_decode": PLAN_SCOPES + ["ds.paged_decode",
                                   "ds.paged_decode_window"],
+    # a latent layer expands for prefill (the flash kernel the others
+    # run) and absorbs for decode (its own kernel)
+    "latent_prefill": LATENT_SCOPES + ["ds.mla_expand", "ds.flash_fwd"],
+    "latent_decode": LATENT_SCOPES + ["ds.mla_absorb",
+                                      "ds.paged_decode_latent"],
 }
 
 
@@ -153,6 +174,8 @@ def lower_all():
     texts["moe_prefill"], texts["moe_decode"] = serve_texts("pallas", MOE_CFG)
     texts["plan_prefill"], texts["plan_decode"] = serve_texts("pallas",
                                                               PLAN_CFG)
+    texts["latent_prefill"], texts["latent_decode"] = serve_texts(
+        "pallas", LATENT_CFG)
     return texts
 
 
@@ -236,8 +259,8 @@ def pallas_calls():
 CALLS = pallas_calls()
 
 
-def test_all_fourteen_sites_are_found():
-    assert len(CALLS) == 14
+def test_all_sixteen_sites_are_found():
+    assert len(CALLS) == 16
 
 
 @pytest.mark.parametrize("where,name,fn,tree", CALLS,

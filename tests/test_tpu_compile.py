@@ -634,6 +634,140 @@ def test_planned_serving_programs_compile_and_hold_the_weights_once(
 
 
 # ---------------------------------------------------------------------------
+# latent attention (GLM-4.7-Flash) at its published widths
+# ---------------------------------------------------------------------------
+
+LATENT_POOL = ((6, 289, 64, 640), BF16)     # a 576-wide row in whole lanes
+
+
+def pool_shaped_moves(text, shape):
+    """Instructions that produce an array of the pool's shape and are
+    neither a kernel nor a way of carrying it."""
+    shaped = re.compile(r"bf16\[" + ",".join(map(str, shape)) + r"\]")
+    moved = []
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        if m and shaped.search(m["type"]) and m["op"] not in CARRIES and \
+                "tpu_custom_call" not in line:
+            moved.append((m["op"], m["type"][:60]))
+    return moved
+
+
+def test_latent_paged_decode_compiles_and_reads_the_pool_where_it_lies(
+        on_chip):
+    """The absorbed kernel at the cell's decode shapes: 20 query heads of
+    512 + 64 over ONE [64, 640] tile a page, batch 32, a table of 264
+    pages, the layer a traced scalar. The pool's row is whole lane tiles:
+    with a 576-wide row the chip's own layout puts another dim innermost
+    and the call is handed a COPY of the pool (seen here before the first
+    chip run, PR 35)."""
+    def decode(q, table, lengths, layer, pool):
+        return decode_attention.paged_latent_decode(
+            q, pool, table, lengths, 1 / 16, 512, layer, backend="pallas")
+
+    text = on_chip(decode, ((32, 20, 576), BF16), ((32, 264), jnp.int32),
+                   ((32,), jnp.int32), ((), jnp.int32), LATENT_POOL)
+    assert re.search(r"%ds\.paged_decode_latent[.\d]* = .*tpu_custom_call",
+                     text)
+    assert not pool_shaped_moves(text, LATENT_POOL[0])
+    assert decode_attention.latent_row_width(576) == 640
+
+
+def test_latent_row_write_compiles(on_chip):
+    def write(pool, rows, layer, page_idx, slot):
+        return decode_attention.paged_latent_write(
+            pool, rows, layer, page_idx, slot, backend="pallas")
+
+    text = on_chip(write, LATENT_POOL, ((32, 576), BF16), ((), jnp.int32),
+                   ((32,), jnp.int32), ((32,), jnp.int32))
+    assert re.search(r"%ds\.kv_write[.\d]* = .*tpu_custom_call", text)
+
+
+def test_flash_forward_compiles_at_head_dim_256(on_chip):
+    """The expanded prefill's attention: one row of 16,384 tokens, 20
+    heads of 192 + 64 for q.k and 256 for v, segmented."""
+    def prefill(q, k, v, seg):
+        return fa.flash_attention_segmented(q, k, v, seg, True)
+
+    qkv = ((1, 16384, 20, 256), BF16)
+    assert_kernel(on_chip(prefill, qkv, qkv, qkv, ((1, 16384), jnp.int32)))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_serving_programs_compile_and_leave_the_pool_in_place(
+        on_chip, v5e_2x2, program):
+    """The engine's decode and prefill programs for GLM-4.7-Flash's block
+    at the published widths (hidden 2048, 20 heads of 192 + 64 / 256,
+    ranks 768 and 512, dense width 10,240, experts of width 1,536, 4 a
+    token, a sigmoid router with its bias, a shared expert; 8 experts
+    and a small vocabulary), layer 0 dense and two expert layers,
+    compiled for the described v5e from shapes alone: the latent kernel,
+    the row write and the grouped matmul are there, and no instruction
+    of the decode step but them produces an array of the pool's shape."""
+    from jax.sharding import SingleDeviceSharding
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
+                                                 LayerSpec)
+    latent = dict(attn="latent", heads=20, rotary_pct=1.0, rotary_base=1e6)
+    batch, seqlen, page_size = 32, 2048, 64
+    cfg = GPTNeoXConfig(
+        vocab_size=1024, hidden_size=2048, num_layers=3, num_heads=20,
+        num_kv_heads=20, max_seq_len=4096, use_parallel_residual=False,
+        norm="rmsnorm", use_bias=False, hidden_act="silu", ffn_gated=True,
+        ffn_width=10240,
+        layer_plan=(LayerSpec(ffn="dense", **latent),
+                    *(LayerSpec(ffn="experts", **latent),) * 2),
+        attn_head_dim=256, mla_q_rank=768, mla_kv_rank=512,
+        mla_nope_dim=192, mla_rope_dim=64, mla_v_dim=256,
+        moe_num_experts=8, moe_top_k=4, moe_dropless=True,
+        moe_norm_topk_prob=True, moe_router_score="sigmoid",
+        moe_expert_width=1536, moe_shared_width=1536,
+        moe_routing_scale=1.8)
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, BF16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page_size,
+        "num_pages": 4096 // page_size + 1, "max_batch_size": batch,
+        "token_budget": 4096, "prefill_lengths": [seqlen],
+        "prefill_batch_sizes": [1], "decode_batch_sizes": [batch]}})
+    pool = engine.cache.k
+    assert pool.shape == (3, 65, page_size, 640) and engine.cache.v is None
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    shapes = functools.partial(jax.tree_util.tree_map, shape_of)
+    carry = ()
+    if program == "decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch), ints(batch), ints(batch, engine.n_pages_max))
+        carry = (ints(batch), ints(batch))
+        kernels = ("ds.paged_decode_latent", "ds.kv_write",
+                   "ds.grouped_matmul")
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+        kernels = ("ds.flash_fwd", "ds.grouped_matmul")
+    text = fn.lower(
+        shapes(engine.params), shapes(engine.params_stacked), *inputs,
+        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        *carry).compile().as_text()
+    for name in kernels:
+        assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
+    if program == "decode":
+        # the step's only writer of the pool is the row-write kernel
+        # (prefill's whole-page scatter is XLA's, and writes it)
+        assert not pool_shaped_moves(text, pool.shape)
+
+
+# ---------------------------------------------------------------------------
 # grouped matmul, int8 weight matmul, fused Adam
 # ---------------------------------------------------------------------------
 
