@@ -14,11 +14,15 @@ def dispatch_report():
     the number itself.
 
     Keys (present once the corresponding kernel has dispatched):
-    ``flash``: {"fwd": (bq, bk), "fwd_variant", "dkv", "dq",
-    "bwd_variant", "masked_tiles": {"fwd" / "dkv" / "dq": (masked,
-    launched) tiles a head of the last tiled call}, "bodies_built":
-    {"fwd" / "dkv" / "dq": (times the kernel's body was built in this
-    process, host seconds that took): set-up every run pays, compile
+    ``flash``: {"fwd": (bq, bk), "fwd_variant", "dkv", "dq" (the
+    backward's blocks), "bwd_variant" ("fused-trapezoid" / "fused-dense":
+    the tiled backward as one kernel; "trapezoid" / "dense": the two
+    kernels of a sequence whose dq slab is over the budget; "single"),
+    "masked_tiles": {"fwd" / "bwd" / "dkv" / "dq": (masked, launched)
+    tiles a head of the last tiled call of that kind of kernel; of the
+    backward's kinds, those the last backward ran}, "bodies_built":
+    {"fwd" / "bwd" / "dkv" / "dq": (times the kernel's body was built in
+    this process, host seconds that took): set-up every run pays, compile
     cache or not}}; ``attention``: {"attention" / "sparse_attention":
     backend} of the model-side dispatchers; ``decode_attention``:
     {"decode": backend, "decode_kv": pool dtype — "int8" when the paged

@@ -54,7 +54,7 @@ FLASH_BLOCK_CANDIDATES = ((1024, 1024), (2048, 1024), (1024, 512),
 FLASH_BLOCK_Q, FLASH_BLOCK_K = FLASH_BLOCK_CANDIDATES[0]
 
 # The fp32 [block_q, block_k] score tile is the VMEM limiter of the
-# forward and both backward kernels: Mosaic holds a few copies of it (the
+# forward and of every backward kernel: Mosaic holds a few copies of it (the
 # scores, their exponentials, the cast operand of the second matmul).
 # 4 MiB admits 1024 x 1024 and 2048 x 512 and drops 2048 x 1024, which a
 # described v5e refuses at head dim 64, 128 and 256, forward and backward
@@ -85,6 +85,30 @@ FLASH_LONG_SEQ_BLOCKS = {
 def flash_blocks_admitted(block_q, block_k):
     """The VMEM screen: does the fp32 score tile fit its budget?"""
     return block_q * block_k * 4 <= _FLASH_SCORE_TILE_BUDGET
+
+
+# The tiled backward is ONE kernel where dq of a (batch x head) can stay
+# in VMEM while the dk/dv column walk crosses it: a float32 [D, S] slab,
+# `S * D * 4` bytes, beside the score tile's copies. 8 MiB admits 16k
+# tokens at head dim 128 and 32k at 64, which a described v5e compiles at
+# (1024, 1024) blocks with the limit `flash_bwd_vmem_limit` asks for, and
+# leaves 32k at 128 / 64k at 64 to the two kernels
+# (tests/test_tpu_compile.py compiles both sides of the line).
+_FLASH_DQ_SLAB_BUDGET = 8 << 20
+
+
+def flash_dq_slab_admitted(s, d):
+    """Does a sequence's dq slab fit VMEM: does the tiled backward run
+    as one kernel? A fact of the input's shape and of nothing else."""
+    return s * d * 4 <= _FLASH_DQ_SLAB_BUDGET
+
+
+def flash_bwd_vmem_limit(s, d):
+    """`vmem_limit_bytes` of the fused backward: the 16 MiB the dk/dv
+    walk has by the compiler's default (the blocks' buffers, the score
+    tile's copies) and the slab. The default alone holds the kernel up
+    to 32k tokens at head dim 64 and not 16k at 128."""
+    return (16 << 20) + s * d * 4
 
 
 def _flash_fit(blocks, s):

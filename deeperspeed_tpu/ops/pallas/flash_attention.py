@@ -49,17 +49,16 @@ from jax.experimental.pallas import tpu as pltpu
 from ... import scopes
 from ...compat import CompilerParams
 from ..autotune import FLASH_BLOCK_K as BLOCK_K, FLASH_BLOCK_Q as BLOCK_Q, \
-    fit_block as _fit_block, flash_blocks
+    fit_block as _fit_block, flash_blocks, flash_bwd_vmem_limit, \
+    flash_dq_slab_admitted
 
 LANES = 128  # TPU minor-dim tile
 NEG_INF = -1e30
 
-_DIMSEM = CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+_DIMSEM = ("parallel", "parallel", "arbitrary")
 # compacted causal grids: (batch·head, flat trapezoid) — the flat dim
 # carries the per-row/-column sequential accumulation, so `arbitrary`
-_DIMSEM_FLAT = CompilerParams(
-    dimension_semantics=("parallel", "arbitrary"))
+_DIMSEM_FLAT = ("parallel", "arbitrary")
 
 
 def _interpret():
@@ -152,8 +151,10 @@ def causal_grid_size(s, block_q=BLOCK_Q, block_k=BLOCK_K):
 
 
 # Test/debug observability: grid of the most recent tiled pallas_call per
-# kernel family ("fwd" / "dkv" / "dq"). The compaction invariant tests
-# assert on this instead of re-deriving lowering internals.
+# kernel family ("fwd"; of the backward "bwd", the one fused kernel, or
+# "dkv" and "dq", the two a sequence over the slab's budget takes). The
+# compaction invariant tests assert on this instead of re-deriving
+# lowering internals.
 _LAST_GRIDS = {}
 
 # Ditto, (masked, launched) tiles per (batch x head) of the most recent
@@ -169,7 +170,8 @@ _LAST_MASKED = {}
 # `pallas_call` a call signature for the life of the process, so a model
 # of 24 unrolled layers builds each body once and not 24 times (PERF.md,
 # PR 34). `ops.dispatch_report()["flash"]["bodies_built"]` reads it.
-_BODY_BUILDS = {"fwd": [0, 0.0], "dkv": [0, 0.0], "dq": [0, 0.0]}
+_BODY_BUILDS = {"fwd": [0, 0.0], "bwd": [0, 0.0], "dkv": [0, 0.0],
+                "dq": [0, 0.0]}
 
 
 def _accounted(kind, kernel):
@@ -186,7 +188,8 @@ def _accounted(kind, kernel):
 
 # Ditto for dispatched block geometry: {"fwd"/"dkv"/"dq": (bq, bk)} plus
 # {"fwd_variant"/"bwd_variant": "single"/"trapezoid"/"dense"} of the most
-# recent call (`ops.dispatch_report()`).
+# recent call, the backward's "fused-trapezoid"/"fused-dense" where it ran
+# as one tiled kernel (`ops.dispatch_report()`).
 _LAST_BLOCKS = {}
 _DISPATCH_LOGGED = False
 
@@ -223,14 +226,15 @@ def _index_adapter(compact, kv_major=False):
 
 
 def _tiled_call(kind, name, kernel, compact, grid, in_specs, out_specs,
-                scratch, out_shape, maps, interpret):
+                scratch, out_shape, maps, interpret, vmem_limit=None):
     """One pallas_call for both grid flavors: compacted trapezoid
     (scalar-prefetch LUT grid spec) or dense, named `name` (a kernel
     scope of `scopes.SCOPES`) and run under that scope; its body's traces
-    are entered under `kind` in the set-up account. Returns the function
-    of the kernel's inputs: kept by the caller's cache, every call of it
-    at one signature and in one trace context binds the one traced body
-    (`pallas_call` is a `jit(inline=True)` of its own)."""
+    are entered under `kind` in the set-up account. `vmem_limit`: bytes,
+    where the compiler's default does not hold the kernel. Returns the
+    function of the kernel's inputs: kept by the caller's cache, every
+    call of it at one signature and in one trace context binds the one
+    traced body (`pallas_call` is a `jit(inline=True)` of its own)."""
     if compact:
         call_kw = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
@@ -240,7 +244,9 @@ def _tiled_call(kind, name, kernel, compact, grid, in_specs, out_specs,
                        scratch_shapes=scratch)
     call = pl.pallas_call(
         _accounted(kind, kernel), out_shape=out_shape, name=name,
-        compiler_params=_DIMSEM_FLAT if compact else _DIMSEM,
+        compiler_params=CompilerParams(
+            dimension_semantics=_DIMSEM_FLAT if compact else _DIMSEM,
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret, **call_kw)
 
     def run(*inputs):
@@ -1444,16 +1450,62 @@ def _bwd_single(qb, kb, vb, do, lse, delta, causal, sm_scale, s, d,
 # backward
 # ---------------------------------------------------------------------------
 
-# The two backward kernels are MXU-bound as they stand (PERF.md, PR 33:
-# seven matmuls of a [1024, 1024] tile are 2,144 pushes of 16 cycles over
-# four MXUs, 8.6k cycles of the 8.9k a dkv step takes), so they keep
-# their whole-tile matmuls, whose weights are pushed once a tile; what
-# the tile body drops is the work around them, and on the diagonal the
-# matmuls of the triangle no query sees.
+# The tiled backward is ONE kernel (PR 36): the dk/dv walk, column by
+# column over the tiles of a (batch x head), which also adds each tile's
+# dS into dq. A tile's scores, dO V^T and exponentials are computed once
+# and five matmuls run where two kernels ran seven.
+#
+# dq's sum crosses the columns, so it lives in a float32 SLAB over the
+# whole sequence, [S / block_q, D, block_q], that stays in VMEM for all
+# the steps of a (batch x head). A query row's slice is zeroed at its
+# first column (column 0) and, at its LAST (the tile on its diagonal; in
+# a dense grid the last column), scaled, transposed, rounded once and
+# stored to the dq block, which the pipeline writes back while the walk
+# goes on (`_dq_block`).
+#
+# The slab is TRANSPOSED, and so are the fused kernel's dk and dv
+# accumulators ([D, block_k]): the tile is held transposed, and
+# dq^T += k^T dS^T, dk^T += q^T dS, dv^T += dO^T P take it as the
+# matmul's WEIGHTS, as it lies, with the D rows of k^T, q^T or dO^T
+# streamed past each 128 x 128 unit of it. At head dim 64 that is 4
+# pushes a unit and a dense [64, 128] result, where the tile as the left
+# operand is 8 pushes and a result half of whose lanes are padding; the
+# weights' loads hide behind the pushes (PERF.md, PR 36: 11.3 ms a layer
+# at 16k tokens against 13.9 with dk and dv the other way round, 21.5 as
+# two kernels; at head dim 128 the two forms are within 5%). And
+# [D, S] float32 has no lane padding where [S, 64] would double.
+#
+# Which sequences: those whose slab `ops.autotune.flash_dq_slab_admitted`
+# admits. A longer one takes this kernel without dq, its dk and dv as
+# [block_k, D] with the tile as the left operand, and `_bwd_dq_kernel`
+# beside it: the two passes every tiled backward was before, as they
+# were (MXU-bound: PERF.md, PR 33).
+
+def _last_k(qi, n_k, block_q, block_k, causal):
+    """The last key column query row `qi` meets."""
+    if not causal:
+        return n_k - 1
+    return jnp.minimum(n_k - 1, (qi * block_q + block_q - 1) // block_k)
+
+
+def _dq_block(qi, ki, n_k, block_q, block_k, causal):
+    """The dq block the fused kernel holds at tile (qi, ki) of its column
+    walk: the query row that was completed last (row 0 before any is).
+    Rows complete in ascending order, each at one tile, so a block is
+    held for one run of steps, stored at the first of them, and written
+    back when the run ends."""
+    if not causal:
+        return jnp.where(ki == n_k - 1, qi, 0)
+    # rows up to this one whose last column is `ki` or an earlier one
+    done = jnp.maximum((ki + 1) * block_k - block_q, 0) // block_q
+    return jnp.minimum(qi, done)
+
 
 def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
-                    use_seg=False, use_mask=False, use_bias=False,
-                    dropout_rate=0.0, compact=False):
+                    n_k=None, use_seg=False, use_mask=False,
+                    use_bias=False, dropout_rate=0.0, compact=False,
+                    fused=False):
+    """dk and dv of a key column, and with `fused` dq as well."""
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -1464,7 +1516,10 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
     m_ref = next(it) if use_mask else None
     b_ref = next(it) if use_bias else None
     seed_ref = next(it) if dropout_rate > 0.0 else None
-    dk_ref, dv_ref, dk_scr, dv_scr = next(it), next(it), next(it), next(it)
+    dk_ref, dv_ref = next(it), next(it)
+    dq_ref = next(it) if fused else None
+    dk_scr, dv_scr = next(it), next(it)
+    dq_scr = next(it) if fused else None
     kseg_scr = next(it) if use_seg else None
     kbias_scr = next(it) if use_bias else None
     if compact:
@@ -1485,6 +1540,11 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    if fused:
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_scr[qi] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
+
     tile = _Tile(qi, ki, block_q, block_k, sm_scale=sm_scale,
                  causal=causal, sq_ref=sq_ref, sk_ref=sk_ref, m_ref=m_ref,
                  b_ref=b_ref, seed_ref=seed_ref, dropout_rate=dropout_rate,
@@ -1492,9 +1552,9 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
 
     def group(g0, gw, rows, masked, diagonal):
         # the tile transposed (k q^T, v dO^T): lse and delta broadcast
-        # along lanes as the rows they are, and dV += P^T dO, dK += dS^T Q
-        # are plain matmuls with no transposed left operand. Queries
-        # g0.. + gw against the keys before `rows`
+        # along lanes as the rows they are, and no matmul transposes a
+        # tile-sized operand. Queries g0.. + gw against the keys before
+        # `rows`
         cols = slice(g0, g0 + gw)
         q, do = q_ref[0, cols, :], do_ref[0, cols, :]          # [gw, D]
         sT = _dot(k_ref[0, :rows, :], q, _NT)                  # [rows, gw]
@@ -1512,9 +1572,18 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         # dS = P o (M o dO V^T / keep - delta), unscaled. P and dS are
         # quantized to the wire dtype for MXU rate, matching the
         # reference's fp16 kernel precision
-        dsT = pT * (dpT - delta_ref[0, :, cols])
-        dv_scr[:rows, :] += _dot(pT_v.astype(do.dtype), do, _NN)
-        dk_scr[:rows, :] += _dot(dsT.astype(q.dtype), q, _NN)
+        pT_v = pT_v.astype(do.dtype)
+        dsT = (pT * (dpT - delta_ref[0, :, cols])).astype(q.dtype)
+        if fused:
+            # the tile as the weights of all three: [D, rows] += dO^T P,
+            # [D, rows] += q^T dS, [D, gw] += k^T dS^T
+            dv_scr[:, :rows] += _dot(do.T, pT_v, _NT)
+            dk_scr[:, :rows] += _dot(q.T, dsT, _NT)
+            dq_scr[qi, :, cols] += _dot(
+                k_ref[0, :rows, :], dsT.astype(k_ref.dtype), _TN)
+        else:
+            dv_scr[:rows, :] += _dot(pT_v, do, _NN)
+            dk_scr[:rows, :] += _dot(dsT, q, _NN)
 
     def body(masked, offset, crossed):
         if offset is None:
@@ -1533,9 +1602,18 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
 
     @pl.when(qi == last_q)
     def _finalize():
-        dk_ref[0] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        dk, dv = dk_scr[...] * sm_scale, dv_scr[...]
+        dk_ref[0] = (dk.T if fused else dk).astype(dk_ref.dtype)
+        dv_ref[0] = (dv.T if fused else dv).astype(dv_ref.dtype)
 
+    if fused:
+        @pl.when(ki == _last_k(qi, n_k, block_q, block_k, causal))
+        def _finalize_dq():
+            dq_ref[0] = (dq_scr[qi] * sm_scale).T.astype(dq_ref.dtype)
+
+
+# The dq pass of a sequence too long for the fused kernel's slab: the
+# same tiles in row order, their scores and dO V^T computed a second time.
 
 def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
                    use_seg=False, use_mask=False, use_bias=False,
@@ -1554,12 +1632,10 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     if compact:
         t = pl.program_id(1)
         qi, ki = qmap_ref[t], kmap_ref[t]
-        last_k = jnp.minimum(n_k - 1,
-                             (qi * block_q + block_q - 1) // block_k)
     else:
         qi = pl.program_id(1)
         ki = pl.program_id(2)
-        last_k = pl.num_programs(2) - 1
+    last_k = _last_k(qi, n_k, block_q, block_k, causal)
 
     @pl.when(ki == 0)
     def _init():
@@ -1607,18 +1683,20 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 
 @functools.cache
 def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
-               use_seg, use_mask, use_bias, dropout_rate, interpret):
-    """The tiled backward's two kernels at one call signature: (dkv's
-    function of the inputs, dq's, their grids, their (masked, launched)
-    tiles), built once a process as `_fwd_call` is. `dtypes` are q's,
-    k's and v's; both take q, k, v, dO as [B*H, S, D], lse and delta as
-    [B*H, 1, S], then `_optional_inputs`."""
+               use_seg, use_mask, use_bias, dropout_rate, fused, interpret):
+    """The tiled backward at one call signature: (the function of its
+    inputs that returns (dq, dk, dv), the grid of each kernel it runs by
+    kind, their (masked, launched) tiles), built once a process as
+    `_fwd_call` is. `fused`: one kernel ("bwd", under the scope
+    `ds.flash_bwd`); else the dk/dv walk and the dq pass ("dkv", "dq").
+    `dtypes` are q's, k's and v's; every kernel takes q, k, v, dO as
+    [B*H, S, D], lse and delta as [B*H, 1, S], then `_optional_inputs`."""
     n_q, n_k = s // block_q, s // block_k
     compact = causal   # mirror the forward's trapezoidal schedule
     flags = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                 block_k=block_k, use_seg=use_seg, use_mask=use_mask,
-                 use_bias=use_bias, dropout_rate=dropout_rate,
-                 compact=compact)
+                 block_k=block_k, n_k=n_k, use_seg=use_seg,
+                 use_mask=use_mask, use_bias=use_bias,
+                 dropout_rate=dropout_rate, compact=compact)
 
     def specs(ix):
         """The inputs' BlockSpecs, index maps written as (bh, qi, ki)."""
@@ -1638,8 +1716,13 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
             ix, h, s, block_q, block_k, use_seg, use_mask, use_bias,
             dropout_rate)
 
-    # dkv accumulates per k column → column-major trapezoid; its dense
-    # grid order is (bh, ki, qi)
+    # the two schedules launch the same tiles in another order
+    masked = masked_tile_count(
+        n_q, n_k, block_q, block_k, causal,
+        always=use_mask or use_bias or dropout_rate > 0.0)
+
+    # dk and dv accumulate per k column → column-major trapezoid; the
+    # dense grid's order is (bh, ki, qi)
     if compact:
         dkv_maps = causal_grid_maps(n_q, n_k, block_q, block_k, "col")
         dkv_grid = (bh, len(dkv_maps[0]))
@@ -1649,16 +1732,35 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
     ixc = _index_adapter(compact, kv_major=True)
     kv_spec = pl.BlockSpec((1, block_k, d),
                            ixc(lambda bh, qi, ki: (bh, ki, 0)))
+    dq_shape = jax.ShapeDtypeStruct((bh, s, d), dtypes[0])
+    dkv_shapes = [jax.ShapeDtypeStruct((bh, s, d), dtypes[1]),
+                  jax.ShapeDtypeStruct((bh, s, d), dtypes[2])]
+    if fused:
+        acc = pltpu.VMEM((d, block_k), jnp.float32)   # dk^T, dv^T
+        run = _tiled_call(
+            "bwd", "ds.flash_bwd",
+            functools.partial(_bwd_dkv_kernel, n_q=n_q, fused=True, **flags),
+            compact, dkv_grid, specs(ixc),
+            [kv_spec, kv_spec, pl.BlockSpec(
+                (1, block_q, d), ixc(lambda bh, qi, ki: (bh, _dq_block(
+                    qi, ki, n_k, block_q, block_k, causal), 0)))],
+            [acc, acc, pltpu.VMEM((n_q, d, block_q), jnp.float32)]
+            + _key_column_scratch(block_k, use_seg, use_bias),
+            dkv_shapes + [dq_shape], dkv_maps, interpret,
+            vmem_limit=flash_bwd_vmem_limit(s, d))
+
+        def fused_run(*inputs):
+            dk, dv, dq = run(*inputs)
+            return dq, dk, dv
+        return fused_run, {"bwd": dkv_grid}, masked
+
     dkv_run = _tiled_call(
         "dkv", "ds.flash_bwd_dkv",
         functools.partial(_bwd_dkv_kernel, n_q=n_q, **flags), compact,
         dkv_grid, specs(ixc), [kv_spec, kv_spec],
-        [pltpu.VMEM((block_k, d), jnp.float32),
-         pltpu.VMEM((block_k, d), jnp.float32)]
+        [pltpu.VMEM((block_k, d), jnp.float32)] * 2
         + _key_column_scratch(block_k, use_seg, use_bias),
-        [jax.ShapeDtypeStruct((bh, s, d), dtypes[1]),
-         jax.ShapeDtypeStruct((bh, s, d), dtypes[2])],
-        dkv_maps, interpret)
+        dkv_shapes, dkv_maps, interpret)
 
     # dq accumulates per q row → row-major trapezoid (same as fwd)
     if compact:
@@ -1670,16 +1772,16 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
     ix = _index_adapter(compact)
     dq_run = _tiled_call(
         "dq", "ds.flash_bwd_dq",
-        functools.partial(_bwd_dq_kernel, n_k=n_k, **flags), compact,
+        functools.partial(_bwd_dq_kernel, **flags), compact,
         dq_grid, specs(ix),
         pl.BlockSpec((1, block_q, d), ix(lambda bh, qi, ki: (bh, qi, 0))),
-        [pltpu.VMEM((block_q, d), jnp.float32)],
-        jax.ShapeDtypeStruct((bh, s, d), dtypes[0]), dq_maps, interpret)
-    # the two schedules launch the same tiles in another order
-    masked = masked_tile_count(
-        n_q, n_k, block_q, block_k, causal,
-        always=use_mask or use_bias or dropout_rate > 0.0)
-    return dkv_run, dq_run, dkv_grid, dq_grid, masked
+        [pltpu.VMEM((block_q, d), jnp.float32)], dq_shape, dq_maps,
+        interpret)
+
+    def two_runs(*inputs):
+        dk, dv = dkv_run(*inputs)
+        return dq_run(*inputs), dk, dv
+    return two_runs, {"dkv": dkv_grid, "dq": dq_grid}, masked
 
 
 def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
@@ -1713,18 +1815,22 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
                                      dropout_rate=dropout_rate, seed=seed)
         return from_bh(dq), from_bh(dk), from_bh(dv)
 
+    fused = flash_dq_slab_admitted(s, d)
     _LAST_BLOCKS["dkv"] = _LAST_BLOCKS["dq"] = (block_q, block_k)
-    _LAST_BLOCKS["bwd_variant"] = "trapezoid" if causal else "dense"
-    dkv_run, dq_run, _LAST_GRIDS["dkv"], _LAST_GRIDS["dq"], masked = \
-        _bwd_calls(bh, s, h, d, (qb.dtype, kb.dtype, vb.dtype), block_q,
-                   block_k, causal, sm_scale, seg is not None,
-                   layout is not None, kbias is not None, dropout_rate,
-                   _interpret())
-    _LAST_MASKED["dkv"] = _LAST_MASKED["dq"] = masked
-    inputs = [qb, kb, vb, do, lse, delta] + \
-        _optional_inputs(seg, layout, kbias, seed, dropout_rate)
-    dk, dv = dkv_run(*inputs)
-    dq = dq_run(*inputs)
+    _LAST_BLOCKS["bwd_variant"] = "fused-" * fused + \
+        ("trapezoid" if causal else "dense")
+    run, grids, masked = _bwd_calls(
+        bh, s, h, d, (qb.dtype, kb.dtype, vb.dtype), block_q, block_k,
+        causal, sm_scale, seg is not None, layout is not None,
+        kbias is not None, dropout_rate, fused, _interpret())
+    # what the most recent backward launched, and nothing an earlier one did
+    for kind in ("bwd", "dkv", "dq"):
+        _LAST_GRIDS.pop(kind, None)
+        _LAST_MASKED.pop(kind, None)
+    _LAST_GRIDS.update(grids)
+    _LAST_MASKED.update(dict.fromkeys(grids, masked))
+    dq, dk, dv = run(qb, kb, vb, do, lse, delta, *_optional_inputs(
+        seg, layout, kbias, seed, dropout_rate))
     return from_bh(dq), from_bh(dk), from_bh(dv)
 
 

@@ -7,7 +7,7 @@ copies that into the device trace (XProf's `tf_op`). The name survives
 plain path component, so a kernel keeps its name whatever transformation
 wraps it:
 
-    jit(step)/transpose(jvp())/checkpoint/ds.block/ds.attn/ds.flash_bwd_dq/...
+    jit(step)/transpose(jvp())/checkpoint/ds.block/ds.attn/ds.flash_bwd/...
     jit(step)/.../checkpoint/rematted_computation/ds.block/ds.mlp/dot_general
 
 Scopes change metadata only: the compiled program is the same with and
@@ -33,9 +33,12 @@ import jax
 SCOPES = {
     "ds.flash_fwd": ("kernel", "flash attention forward, tiled or "
                                "single-block, segmented or not"),
-    "ds.flash_bwd_dq": ("kernel", "flash attention backward, dq pass"),
-    "ds.flash_bwd_dkv": ("kernel", "flash attention backward, dk/dv pass"),
-    "ds.flash_bwd": ("kernel", "fused single-block flash backward"),
+    "ds.flash_bwd_dq": ("kernel", "flash attention backward, dq pass of a "
+                                  "sequence over the fused kernel's budget"),
+    "ds.flash_bwd_dkv": ("kernel", "flash attention backward, dk/dv pass of "
+                                   "such a sequence"),
+    "ds.flash_bwd": ("kernel", "flash attention backward as one kernel: the "
+                               "tiled dq/dk/dv walk, or a single block"),
     "ds.paged_decode": ("kernel", "paged decode attention"),
     "ds.flash_fwd_window": ("kernel", "the flash forward of a window "
                                       "layer: the same kernel, tiles "

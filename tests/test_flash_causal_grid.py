@@ -3,6 +3,10 @@
 match the XLA reference numerically on every path, and the heads-batched
 (hb > 1) single-block kernels must agree with hb = 1 exactly.
 
+The tiled backward is one fused kernel where a sequence's dq slab fits
+VMEM (`ops.autotune.flash_dq_slab_admitted`) and two kernels where it
+does not: the `backward` fixture runs a test on both sides of that line.
+
 Runs on CPU in interpret mode — fast lane (no slow marker)."""
 
 import importlib
@@ -14,8 +18,23 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from deeperspeed_tpu.ops import autotune
+
 fa = importlib.import_module(
     "deeperspeed_tpu.ops.pallas.flash_attention")
+
+
+@pytest.fixture(params=["fused", "two_kernels"])
+def backward(request, monkeypatch):
+    """The kinds of kernel the tiled backward runs as, on each side of
+    `flash_dq_slab_admitted`: ("bwd",), the fused kernel, at every shape
+    of this file; and ("dkv", "dq"), what a sequence over the slab's
+    budget takes, with the budget taken to nothing (the program has no
+    switch: the predicate is a function of the shape)."""
+    if request.param == "two_kernels":
+        monkeypatch.setattr(autotune, "_FLASH_DQ_SLAB_BUDGET", 0)
+        return ("dkv", "dq")
+    return ("bwd",)
 
 
 def reference_attention(q, k, v, causal=True, kbias=None):
@@ -63,9 +82,9 @@ def test_causal_grid_size_matches_maps():
     assert fa.causal_grid_size(256, 1024, 1024) == 1      # single block
 
 
-def test_causal_launch_is_compacted():
+def test_causal_launch_is_compacted(backward):
     """A causal call with n = S/block ≥ 4 launches the trapezoid (10
-    instances at n=4) on fwd AND both backward kernels — not n² = 16."""
+    instances at n=4) on fwd AND every backward kernel — not n² = 16."""
     b, s, h, d = 1, 512, 2, 64
     q, k, v = make_qkv(b=b, s=s, h=h, d=d)
     n = s // 128
@@ -78,8 +97,10 @@ def test_causal_launch_is_compacted():
     jax.grad(lambda q, k, v: jnp.sum(
         fa.flash_attention(q, k, v, True, None, 128, 128) ** 2),
         argnums=(0, 1, 2))(q, k, v)
-    assert fa._LAST_GRIDS["dkv"] == (b * h, tri)
-    assert fa._LAST_GRIDS["dq"] == (b * h, tri)
+    assert {kind: grid for kind, grid in fa._LAST_GRIDS.items()
+            if kind != "fwd"} == dict.fromkeys(backward, (b * h, tri))
+    assert fa._LAST_BLOCKS["bwd_variant"] == \
+        ("fused-trapezoid" if backward == ("bwd",) else "trapezoid")
 
     # the non-causal grid stays dense (nothing to compact)
     fa.flash_attention(q, k, v, False, None, 128, 128)
@@ -102,7 +123,7 @@ def test_compacted_forward_parity(blocks):
 
 
 @pytest.mark.parametrize("blocks", [(128, 128), (256, 128)])
-def test_compacted_backward_parity(blocks):
+def test_compacted_backward_parity(blocks, backward):
     q, k, v = make_qkv(s=512)
     bq, bk = blocks
 
@@ -427,9 +448,11 @@ TILE_BODY_CASES = [
     "variant,S,blocks,d,dtype", TILE_BODY_CASES,
     ids=[f"{c[0]}-{c[1]}-{c[2][0]}x{c[2][1]}-d{c[3]}-{c[4].__name__}"
          for c in TILE_BODY_CASES])
-def test_tile_body_matches_fp32_reference(variant, S, blocks, d, dtype):
+def test_tile_body_matches_fp32_reference(variant, S, blocks, d, dtype,
+                                          backward):
     """Forward, dq, dk and dv of the tiled kernels against plain fp32
-    attention, for every mask a tile body knows."""
+    attention, for every mask a tile body knows; the backward as the
+    fused kernel and as the two."""
     B, H = (1, 1) if S == 2048 else (2, 2)   # `_documents` row 0 alone
     q, k, v = make_qkv(b=B, s=S, h=H, d=d, dtype=dtype, seed=3)
     w = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
@@ -452,6 +475,115 @@ def test_tile_body_matches_fp32_reference(variant, S, blocks, d, dtype):
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(ref, np.float32), **gtol,
                                    err_msg=f"d{name}")
+    assert set(fa._LAST_GRIDS) == {"fwd", *backward}
+
+
+# Where dq leaves the fused kernel: a query row is stored at the last
+# column it meets. Blocks 2:1 (a row ends one column after it began),
+# 1:2 (two rows end in one column, at consecutive steps), equal, one
+# column wide and many; the dense grid (every row ends in the last
+# column); documents, whose tiles may be skipped whole.
+BOUNDARY_CASES = [
+    # variant, S, (block_q, block_k)
+    ("causal", 1024, (256, 128)),
+    ("causal", 1024, (128, 256)),
+    ("causal", 1024, (128, 128)),
+    ("causal", 1024, (512, 256)),
+    ("causal", 1024, (128, 512)),
+    ("causal", 1024, (1024, 128)),
+    ("causal", 1024, (128, 1024)),
+    ("full", 768, (256, 128)),
+    ("full", 768, (128, 256)),
+    ("full", 768, (384, 128)),
+    ("segmented", 1024, (256, 128)),
+    ("segmented", 1024, (128, 256)),
+    ("kbias", 512, (128, 256)),
+    ("dropout", 512, (256, 128)),
+    ("layout", 512, (128, 128)),
+]
+
+
+@pytest.mark.parametrize(
+    "variant,S,blocks", BOUNDARY_CASES,
+    ids=[f"{c[0]}-{c[1]}-{c[2][0]}x{c[2][1]}" for c in BOUNDARY_CASES])
+def test_fused_backward_agrees_with_the_two_kernels(variant, S, blocks,
+                                                    monkeypatch):
+    """The same gradients from the one kernel and from the two: dk and dv
+    to a rounding (the same sums, the matmuls' operands the other way
+    round), dq to the order of its float32 sum."""
+    q, k, v = make_qkv(b=2, s=S, h=2, d=64, seed=11)
+    w = jax.random.normal(jax.random.PRNGKey(4), q.shape, jnp.float32)
+    kernel, _ = _variant(variant, q, k, v, blocks, blocks)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(kernel(*a) * w),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    fused = grads()
+    assert fa._LAST_BLOCKS["bwd_variant"].startswith("fused-")
+    assert set(fa._LAST_GRIDS) == {"fwd", "bwd"}
+    monkeypatch.setattr(autotune, "_FLASH_DQ_SLAB_BUDGET", 0)
+    two = grads()
+    assert not fa._LAST_BLOCKS["bwd_variant"].startswith("fused-")
+    assert set(fa._LAST_GRIDS) == {"fwd", "dkv", "dq"}
+    for got, want, name in zip(fused, two, "qkv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=2e-5,
+                                   err_msg=f"d{name}")
+
+
+def test_dq_block_follows_the_rows_as_they_complete():
+    """`_dq_block`, the fused kernel's dq index map, over the column
+    walk's schedule: the block held is the row completed last; rows
+    complete in ascending order, each at the one tile that is the last of
+    its row (`_last_k`); so every block is held for one run of steps that
+    starts where the kernel stores it."""
+    for n_q, n_k, bq, bk in [(4, 4, 128, 128), (2, 4, 256, 128),
+                             (4, 2, 128, 256), (8, 2, 128, 512),
+                             (1, 8, 1024, 128), (16, 16, 1024, 1024)]:
+        qm, km = fa.causal_grid_maps(n_q, n_k, bq, bk, "col")
+        held = [int(fa._dq_block(jnp.int32(qi), jnp.int32(ki), n_k, bq, bk,
+                                 True)) for qi, ki in zip(qm, km)]
+        stored = [int(qi) for qi, ki in zip(qm, km)
+                  if ki == int(fa._last_k(jnp.int32(qi), n_k, bq, bk, True))]
+        assert stored == list(range(n_q))
+        assert held == sorted(held) and set(held) == set(range(n_q))
+        for t, (qi, ki) in enumerate(zip(qm, km)):
+            if ki == int(fa._last_k(jnp.int32(qi), n_k, bq, bk, True)):
+                assert held[t] == qi
+                assert t == 0 or held[t - 1] == max(qi - 1, 0)
+    # a dense grid: nothing completes before the last column
+    assert [int(fa._dq_block(jnp.int32(qi), jnp.int32(ki), 3, 128, 128,
+                             False)) for ki in range(3) for qi in range(2)] \
+        == [0, 0, 0, 0, 0, 1]
+
+
+@pytest.mark.parametrize("s,d,admitted", [
+    (2048, 64, True), (2048, 128, True), (16384, 64, True),
+    (16384, 128, True), (32768, 64, True), (32768, 128, False),
+    (65536, 64, False)])
+def test_slab_predicate_is_a_function_of_the_shape(s, d, admitted):
+    """S * D * 4 bytes of float32 slab against 8 MiB, and what the
+    backward of such a sequence is traced as (nothing runs)."""
+    assert autotune.flash_dq_slab_admitted(s, d) is admitted
+    if s < 16384:
+        return
+    spec = jax.ShapeDtypeStruct((1, s, 1, d), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)))(spec, spec, spec)
+    names = sorted(name for name, _ in _kernel_jaxprs(jaxpr.jaxpr))
+    report = importlib.import_module(
+        "deeperspeed_tpu.ops").dispatch_report()["flash"]
+    if admitted:
+        assert names == ["ds.flash_bwd", "ds.flash_fwd"]
+        assert report["bwd_variant"] == "fused-trapezoid"
+        assert set(report["masked_tiles"]) == {"fwd", "bwd"}
+    else:
+        assert names == ["ds.flash_bwd_dkv", "ds.flash_bwd_dq",
+                         "ds.flash_fwd"]
+        assert report["bwd_variant"] == "trapezoid"
+        assert set(report["masked_tiles"]) == {"fwd", "dkv", "dq"}
 
 
 @pytest.mark.parametrize("window,blocks,heads,kv_heads,d", [
@@ -505,7 +637,8 @@ def _closed_form_masked_tiles(n_q, n_k, bq, bk, causal, window):
 @pytest.mark.parametrize("causal,window", [(True, None), (True, 200),
                                            (False, None)],
                          ids=["causal", "causal_window", "dense"])
-def test_masked_tile_count_is_its_closed_form(blocks, causal, window):
+def test_masked_tile_count_is_its_closed_form(blocks, causal, window,
+                                              backward):
     """`_LAST_MASKED` records, at trace time, how many of a call's
     launched tiles take the masked body: the diagonal's (and a window's
     far edge's) tiles, none of a dense grid."""
@@ -515,7 +648,7 @@ def test_masked_tile_count_is_its_closed_form(blocks, causal, window):
     if window is None:
         jax.grad(lambda q: fa.flash_attention(
             q, k, v, causal, None, bq, bk, blocks).sum())(q)
-        kinds = ("fwd", "dkv", "dq")
+        kinds = ("fwd", *backward)
     else:
         fa.flash_attention_segmented(q, k, v, jnp.ones((1, S), jnp.int32),
                                      True, block_q=bq, block_k=bk,
@@ -593,18 +726,22 @@ def _equations(jaxpr):
 
 # The three train cells' per-shard attention, and the most equations each
 # kernel's body may unroll to there: about 1.3 times what this tree counts
-# (fwd 688 / 907 / 907, dkv 199, dq 182). A body that grows past it is
-# set-up every run pays: shrink it, or let its unrolling adapt to the
-# shape (docs/long-context.md, "What a body costs the host").
+# (fwd 688 / 907 / 907; the fused backward 265 = dkv's 199 + the fifth
+# matmul, two small transposes and the slab's read and write in each of
+# its five groups, and dq's init and store; as two kernels dkv 199, dq
+# 182). A body that grows past it is set-up every run pays: shrink it, or
+# let its unrolling adapt to the shape (docs/long-context.md, "What a
+# body costs the host").
+BACKWARD_BUDGET = {"ds.flash_bwd": 340, "ds.flash_bwd_dkv": 260,
+                   "ds.flash_bwd_dq": 240}
 SETUP_CASES = [
     # shape [B, S, H, D], budget of equations a kernel
-    ((1, 16384, 16, 64), {"ds.flash_fwd": 900, "ds.flash_bwd_dkv": 260,
-                          "ds.flash_bwd_dq": 240}),
-    ((16, 2048, 16, 64), {"ds.flash_fwd": 1200, "ds.flash_bwd_dkv": 260,
-                          "ds.flash_bwd_dq": 240}),
-    ((4, 2048, 16, 128), {"ds.flash_fwd": 1200, "ds.flash_bwd_dkv": 260,
-                          "ds.flash_bwd_dq": 240}),
+    ((1, 16384, 16, 64), {"ds.flash_fwd": 900, **BACKWARD_BUDGET}),
+    ((16, 2048, 16, 64), {"ds.flash_fwd": 1200, **BACKWARD_BUDGET}),
+    ((4, 2048, 16, 128), {"ds.flash_fwd": 1200, **BACKWARD_BUDGET}),
 ]
+KERNEL_OF = {"fwd": "ds.flash_fwd", "bwd": "ds.flash_bwd",
+             "dkv": "ds.flash_bwd_dkv", "dq": "ds.flash_bwd_dq"}
 LAYERS = 3      # unrolled, as a model without remat calls the attention
 
 
@@ -636,33 +773,40 @@ def _built_since(before):
 
 @pytest.mark.parametrize("shape,budget", SETUP_CASES,
                          ids=["train_16k", "train_2k", "zero3_shard"])
-def test_bodies_are_built_once_and_stay_small(shape, budget):
+def test_bodies_are_built_once_and_stay_small(shape, budget, backward):
     """Three unrolled layers' forward and backward, traced twice in one
-    process, build each of the three kernel bodies ONCE; and each body
-    stays under its written budget of equations."""
+    process, build each kernel body ONCE: two bodies, the forward's and
+    the fused backward's (three where the backward is two kernels); and
+    each body stays under its written budget of equations."""
     spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kinds = ("fwd", *backward)
     before = _fresh_account()
     grad = jax.grad(_layers_loss, argnums=(0, 1, 2))
     first = jax.make_jaxpr(grad)(spec, spec, spec)
     jax.make_jaxpr(grad)(spec, spec, spec)
-    assert _built_since(before) == {"fwd": 1, "dkv": 1, "dq": 1}
+    assert _built_since(before) == {
+        kind: int(kind in kinds) for kind in KERNEL_OF}
     kernels = list(_kernel_jaxprs(first.jaxpr))
     assert sorted(name for name, _ in kernels) == sorted(
-        ["ds.flash_fwd", "ds.flash_bwd_dkv", "ds.flash_bwd_dq"] * LAYERS)
+        [KERNEL_OF[kind] for kind in kinds] * LAYERS)
     for name, body in kernels:
         assert _equations(body) <= budget[name], (name, _equations(body))
     # one traced body, bound by every layer: what lets jax lower it once
     # a module, too (its lowering cache is keyed on the equation's params)
-    assert len({id(body) for _, body in kernels}) == 3
+    assert len({id(body) for _, body in kernels}) == len(kinds)
     report = importlib.import_module(
         "deeperspeed_tpu.ops").dispatch_report()["flash"]
-    assert set(report["bodies_built"]) == {"fwd", "dkv", "dq"}
+    assert set(report["bodies_built"]) == set(KERNEL_OF)
+    assert set(report["masked_tiles"]) == set(kinds)
     assert report["masked_tiles"]["fwd"] == fa._LAST_MASKED["fwd"]
+    assert report["bwd_variant"] == \
+        ("fused-trapezoid" if backward == ("bwd",) else "trapezoid")
 
 
-def test_bodies_are_built_once_under_shard_map():
+def test_bodies_are_built_once_under_shard_map(backward):
     """The four-chip cell's path: 16 sequences over a 4-device
-    `shard_map` (`parallel.mesh.per_shard`), 4 a shard at head dim 128."""
+    `shard_map` (`parallel.mesh.per_shard`), 4 a shard at head dim 128:
+    TWO bodies, the forward's and the fused backward's."""
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
     spec = jax.ShapeDtypeStruct((16, 2048, 16, 128), jnp.bfloat16)
@@ -673,9 +817,11 @@ def test_bodies_are_built_once_under_shard_map():
     grad = jax.grad(lambda *a: sharded(*a).sum(), argnums=(0, 1, 2))
     first = jax.make_jaxpr(grad)(spec, spec, spec)
     jax.make_jaxpr(grad)(spec, spec, spec)
-    assert _built_since(before) == {"fwd": 1, "dkv": 1, "dq": 1}
+    kinds = ("fwd", *backward)
+    assert _built_since(before) == {
+        kind: int(kind in kinds) for kind in KERNEL_OF}
     kernels = list(_kernel_jaxprs(first.jaxpr))
-    assert len(kernels) == 3 * LAYERS
-    assert len({id(body) for _, body in kernels}) == 3
+    assert len(kernels) == len(kinds) * LAYERS
+    assert len({id(body) for _, body in kernels}) == len(kinds)
     for name, body in kernels:
         assert _equations(body) <= SETUP_CASES[2][1][name], name

@@ -30,6 +30,7 @@ import jax
 import deeperspeed_tpu
 from deeperspeed_tpu import scopes
 from deeperspeed_tpu.inference import InferenceEngine
+from deeperspeed_tpu.ops import autotune
 from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
                                              LayerSpec)
 
@@ -79,11 +80,15 @@ LATENT_CFG = GPTNeoXConfig(
 LATENT_SCOPES = MODEL_SCOPES + MOE_SCOPES + [
     "ds.mla_q", "ds.mla_kv", "ds.moe_shared", "ds.kv_write"]
 PROGRAMS = {
-    # the tiled kernels: 256 tokens in blocks of 128
-    "train": MODEL_SCOPES + ["ds.flash_fwd", "ds.flash_bwd_dq",
-                             "ds.flash_bwd_dkv", "ds.ce_head",
+    # the tiled kernels: 256 tokens in blocks of 128; the backward is the
+    # one fused kernel
+    "train": MODEL_SCOPES + ["ds.flash_fwd", "ds.flash_bwd", "ds.ce_head",
                              "ds.optimizer"],
-    # one block of 128: the fused single-block backward
+    # the same with no room for the backward's dq slab: the two kernels a
+    # sequence over its budget takes
+    "train_two_kernels": ["ds.flash_fwd", "ds.flash_bwd_dq",
+                          "ds.flash_bwd_dkv"],
+    # one block of 128: the single-block backward
     "train_single_block": ["ds.flash_fwd", "ds.flash_bwd"],
     "train_xla": MODEL_SCOPES + ["ds.attn_xla", "ds.ce_head",
                                  "ds.optimizer"],
@@ -169,6 +174,8 @@ def lower_all():
         mp.setenv("DS_FLASH_BLOCKS", "128,128")
         mp.setenv("DS_FLASH_BWD_BLOCKS", "128,128")
         texts["train"] = train_text(256, use_pallas=True)
+        mp.setattr(autotune, "_FLASH_DQ_SLAB_BUDGET", 0)
+        texts["train_two_kernels"] = train_text(256, use_pallas=True)
     texts["prefill"], texts["decode"] = serve_texts("pallas")
     texts["decode_xla"] = serve_texts("xla")[1]
     texts["moe_prefill"], texts["moe_decode"] = serve_texts("pallas", MOE_CFG)
@@ -208,8 +215,10 @@ def test_recomputed_work_is_marked(texts):
     assert any("ds.block" in n and "rematted_computation" not in n
                for n in names)
     # the kernels keep their names under the transformations around them
-    assert any("transpose(jvp" in n and "ds.flash_bwd_dq" in n
+    assert any("transpose(jvp" in n and "ds.flash_bwd" in n
                for n in names)
+    assert any("transpose(jvp" in n and "ds.flash_bwd_dq" in n
+               for n in op_names(texts["train_two_kernels"]))
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAMS))

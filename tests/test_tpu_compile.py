@@ -80,6 +80,12 @@ def assert_kernel(text, at_least=1):
         "the compiled program holds no Mosaic kernel"
 
 
+def kernel_names(text):
+    """The `ds.*` kernel scope each Mosaic call of the program lies in."""
+    return {m for line in text.splitlines() if "tpu_custom_call" in line
+            for m in re.findall(r"/(ds\.[a-z0-9_]+)/pallas_call", line)}
+
+
 def qkv(b, s, h, d):
     return [((b, s, h, d), BF16)] * 3
 
@@ -117,7 +123,7 @@ def test_flash_backward_compiles(on_chip, name, shape, causal):
     grad = jax.grad(loss_of(
         lambda q, k, v: fa.flash_attention(q, k, v, causal)),
         argnums=(0, 1, 2))
-    # forward + the dkv/dq kernels (fused into one on a single block)
+    # forward + the backward, one kernel tiled or on a single block
     assert_kernel(on_chip(grad, *qkv(*shape)), at_least=2)
 
 
@@ -153,10 +159,12 @@ def test_long_sequence_flash_geometry_compiles(on_chip, shape, causal,
         text = on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)),
                        *qkv(*shape))
         assert fa._LAST_BLOCKS["dkv"] == fa._LAST_BLOCKS["dq"] == bwd
+        # 16k tokens at head dim 64 and 128: the slab is admitted
+        assert fa._LAST_BLOCKS["bwd_variant"].startswith("fused-")
     else:
         text = on_chip(attn, *qkv(*shape))
     assert fa._LAST_BLOCKS["fwd"] == (bq, bk)
-    assert_kernel(text, at_least=3 if grad else 1)
+    assert_kernel(text, at_least=2 if grad else 1)
 
 
 # The tiled kernels at the shapes the benchmark's cells run them (per
@@ -174,18 +182,55 @@ CELL_FLASH_SHAPES = [
                          ids=[c[0] for c in CELL_FLASH_SHAPES])
 def test_flash_compiles_at_the_train_cells_shapes(on_chip, name, shape, fwd,
                                                   bwd):
-    """Forward, dkv and dq, each with its masked and its unmasked body."""
+    """The forward and the fused backward, each with its masked and its
+    unmasked body; the backward's dq slab ([16, 64, 1024] float32 at 16k)
+    under the VMEM limit the call asks for."""
     bq, bk = fwd or (None, None)
 
     def attn(q, k, v):
         return fa.flash_attention(q, k, v, True, None, bq, bk, bwd)
 
     assert_kernel(on_chip(attn, *qkv(*shape)))
-    assert_kernel(on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)),
-                          *qkv(*shape)), at_least=3)
-    masked, launched = fa._LAST_MASKED["fwd"]
-    assert 0 < masked < launched          # both bodies are in the kernel
-    assert fa._LAST_MASKED["dkv"] == fa._LAST_MASKED["dq"]
+    text = on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)), *qkv(*shape))
+    assert_kernel(text, at_least=2)
+    assert fa._LAST_BLOCKS["bwd_variant"] == "fused-trapezoid"
+    assert {"ds.flash_fwd", "ds.flash_bwd"} <= kernel_names(text)
+    assert not {"ds.flash_bwd_dkv", "ds.flash_bwd_dq"} & kernel_names(text)
+    for kind in ("fwd", "bwd"):
+        masked, launched = fa._LAST_MASKED[kind]
+        assert 0 < masked < launched      # both bodies are in the kernel
+
+
+# Both sides of `ops.autotune.flash_dq_slab_admitted`, at the blocks the
+# rule gives a v5e: the largest slabs it admits (8 MiB: 32k tokens at head
+# dim 64, 16k at 128) run the fused backward, and the next sequence up
+# takes the two kernels. (name, [B, S, H, D], fused)
+SLAB_SHAPES = [
+    ("largest_slab_d64", (1, 32768, 16, 64), True),
+    ("largest_slab_d128", (1, 16384, 16, 128), True),
+    ("over_budget_d128", (1, 32768, 16, 128), False),
+    ("over_budget_d64", (1, 65536, 16, 64), False),
+]
+
+
+@pytest.mark.parametrize("name,shape,fused", SLAB_SHAPES,
+                         ids=[c[0] for c in SLAB_SHAPES])
+def test_flash_backward_compiles_on_both_sides_of_the_slab_budget(
+        on_chip, name, shape, fused):
+    from deeperspeed_tpu.ops.autotune import (flash_blocks,
+                                              flash_dq_slab_admitted)
+    assert flash_dq_slab_admitted(shape[1], shape[3]) is fused
+    (bq, bk), bwd = flash_blocks(shape, True, "TPU v5 lite")
+
+    def attn(q, k, v):
+        return fa.flash_attention(q, k, v, True, None, bq, bk, bwd)
+
+    text = on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)), *qkv(*shape))
+    backward = {"ds.flash_bwd"} if fused else \
+        {"ds.flash_bwd_dkv", "ds.flash_bwd_dq"}
+    assert kernel_names(text) == {"ds.flash_fwd"} | backward
+    assert fa._LAST_BLOCKS["bwd_variant"] == \
+        ("fused-trapezoid" if fused else "trapezoid")
 
 
 def test_segmented_prefill_compiles_at_the_serve_cells_bucket(on_chip):
