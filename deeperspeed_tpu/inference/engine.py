@@ -47,7 +47,6 @@ config seed folded with the step counter — the same request stream
 always produces the same tokens.
 """
 
-import contextlib
 import itertools
 import math
 import random
@@ -503,7 +502,7 @@ class InferenceEngine:
 
         # -- telemetry (spans: schedule / prefill / decode; admission
         #    wait is a per-request scalar — docs/inference.md) ------------
-        from ..runtime.telemetry import build_telemetry
+        from ..runtime.telemetry import StepTimeline, build_telemetry
         self.monitor = monitor
         # co-residency contract (docs/rl.md): when the monitor is BORROWED
         # from a co-located training engine (owns_monitor=False), drain()
@@ -545,6 +544,22 @@ class InferenceEngine:
                       # steps attended over: what the paged kernel reads
                       "build_inputs_s": 0.0, "dispatch_s": 0.0,
                       "readback_s": 0.0, "complete_s": 0.0,
+                      # inside readback_s: the wait for the device,
+                      # before the transfer
+                      "device_wait_s": 0.0,
+                      # the step timeline's sums (runtime/telemetry.py,
+                      # docs/observability.md "Slow steps"): steps that
+                      # ran over their key's typical step, the seconds
+                      # they ran over, and what held those seconds: the
+                      # wait on the device, the collector, the thread's
+                      # own CPU time, the caller between two steps; all
+                      # collector seconds; steps that lowered a program,
+                      # or saw a profiler trace start or stop
+                      "slow_steps": 0, "slow_step_excess_s": 0.0,
+                      "slow_excess_device_wait_s": 0.0,
+                      "slow_excess_gc_s": 0.0, "slow_excess_host_s": 0.0,
+                      "slow_excess_outside_s": 0.0, "gc_s": 0.0,
+                      "compile_steps": 0, "profiler_steps": 0,
                       "decode_kv_tokens": 0,
                       # the same for the window layers alone (a row
                       # attends over at most the window there), and the
@@ -586,6 +601,13 @@ class InferenceEngine:
                       "handoff_sent": 0, "handoff_acked": 0,
                       "handoff_rejected": 0, "handoff_expired": 0,
                       "handoff_installed": 0, "handoff_refused": 0}
+        # one record a step, the spans' seconds into `stats`; whether the
+        # scheduler had work when the last step returned (the caller's
+        # time before a step counts against it only then)
+        self.timeline = StepTimeline("serve", counters=self.stats)
+        if self.telemetry.enabled:
+            self.telemetry.attach(self.timeline)
+        self._busy = False
         # request-level latency histograms (inference/metrics.py):
         # admission-wait / TTFT / inter-token distributions, fanned out
         # to the monitor's export backends (Prometheus histogram
@@ -1594,32 +1616,40 @@ class InferenceEngine:
         is not a hang) — a program hung on the device stops the next
         call's read-back — and fed on exit, including when the step DIES
         rather than hangs."""
+        self.timeline.begin(busy=self._busy)
         self._plan_step_faults()
         self._apply_page_pressure()
+        summary = None
         try:
-            return self._step_inner()
+            summary = self._step_inner()
+            return summary
         finally:
             if self.watchdog is not None:
                 self.watchdog.feed()
             self._release_page_pressure()
+            self._busy = self.scheduler.has_work
+            slow = self.timeline.end(
+                rows=summary["decoded"] if summary else 0)
+            if slow is not None:
+                self.telemetry.on_anomaly(self, "slow_step",
+                                          step=slow["serial"])
 
     def _step_inner(self):
         now = time.perf_counter()
-        if self.handoff is not None:
-            # pool discovery rides every step: the prefill side's dst
-            # pick and the router's gauges read the freshest announce
-            self.handoff.announce(self.role, self._pool_load())
-            if self.role == "decode":
-                # install BEFORE schedule(): a page set acked this step
-                # joins this step's decode batch
-                self._install_handoffs(now)
-            else:
-                self._poll_handoff_acks(now)
-        t0 = now
         finished_before = len(self.scheduler.finished)
-        with self.telemetry.span("schedule"):
+        with self._phase("schedule"):
+            if self.handoff is not None:
+                # pool discovery rides every step: the prefill side's
+                # dst pick and the router's gauges read the freshest
+                # announce
+                self.handoff.announce(self.role, self._pool_load())
+                if self.role == "decode":
+                    # install BEFORE schedule(): a page set acked this
+                    # step joins this step's decode batch
+                    self._install_handoffs(now)
+                else:
+                    self._poll_handoff_acks(now)
             plan = self.scheduler.schedule(now=now)
-        self.stats["schedule_s"] += time.perf_counter() - t0
         self.stats["evictions"] += len(plan.evicted)
         if plan.empty and self.scheduler.quarantined:
             # nothing dispatchable until a quarantine backoff window
@@ -1731,7 +1761,8 @@ class InferenceEngine:
             scalars = {
                 "Serve/queue_depth": self.stats["queue_depth"],
                 "Serve/page_pool_util": self.stats["page_pool_util"],
-                "Serve/running": float(len(self.scheduler.running))}
+                "Serve/running": float(len(self.scheduler.running)),
+                "Serve/slow_steps": float(self.stats["slow_steps"])}
             # per-status terminal counters: exported through every
             # monitor backend (Prometheus gauges + JSONL events)
             for status, tag in REQUEST_STATUS_FAMILIES.items():
@@ -2139,8 +2170,9 @@ class InferenceEngine:
         `requests`: the rows read back this step) that FINISHED this
         step lands in the span buffer as one event
         covering submit → last token (exported in the Chrome trace next
-        to the schedule/prefill/decode spans). Zero cost outside a
-        window."""
+        to the schedule/prefill/decode spans), with the serials of the
+        step records that read back its prefill and its last token.
+        Zero cost outside a window."""
         tracer = getattr(self.telemetry, "tracer", None)
         if tracer is None or not tracer.capturing:
             return
@@ -2149,7 +2181,9 @@ class InferenceEngine:
             if req.state == FINISHED and req.submitted_at is not None:
                 tracer.record_event(
                     f"request/{req.request_id}", req.submitted_at,
-                    (req.last_token_at or now) - req.submitted_at)
+                    (req.last_token_at or now) - req.submitted_at,
+                    step=self.timeline.serial,
+                    prefill_step=req.prefill_step)
 
     def _chunk_arrays(self, reqs, B, S):
         """Window inputs for the chunk programs: each request's suffix
@@ -2188,27 +2222,26 @@ class InferenceEngine:
                 self.draft_cache.k, self.draft_cache.v,
                 jax.random.PRNGKey(0))
 
-    @contextlib.contextmanager
     def _phase(self, name):
-        """One host phase of a serve step, inside the step's `prefill` or
-        `decode` span: `build_inputs` (numpy arrays and their puts),
-        `dispatch` (the call of the compiled program, which returns when
-        it is enqueued), `readback` (the wait for the device and the
-        transfer of the sampled tokens), `complete` (scheduler
-        bookkeeping and latency observations). A telemetry span of that
-        name, and its seconds in `stats[name + "_s"]`."""
-        t0 = time.perf_counter()
-        try:
-            with self.telemetry.span(name):
-                yield
-        finally:
-            self.stats[name + "_s"] += time.perf_counter() - t0
+        """One host phase of a serve step, a span of the step timeline:
+        `schedule`; the step's `prefill` and `decode`, and inside them
+        `build_inputs` (numpy arrays and their puts), `dispatch` (the
+        call of the compiled program, which returns when it is
+        enqueued), `readback` (the wait for the device, `device_wait`
+        inside it, then the transfer of the sampled tokens), `complete`
+        (scheduler bookkeeping and latency observations). Its seconds
+        land in `stats[name + "_s"]` and, less the phases inside it, in
+        the open step record; with a `telemetry` block it is a span of
+        that name there too."""
+        return self.timeline.span(name)
 
     def _readback(self, arr):
         """The step's small result on the host, and the time it got
         there: the one stamp this step's tokens carry (TTFT, inter-token
         gaps, `last_token_at`)."""
         with self._phase("readback"):
+            with self._phase("device_wait"):
+                arr.block_until_ready()
             out = np.asarray(arr)
         return out, time.perf_counter()
 
@@ -2218,6 +2251,7 @@ class InferenceEngine:
                 continue    # evicted by cache-loss recovery meanwhile
             req.owed.remove(rec.serial)
             self.scheduler.complete_prefill(req, int(nxt[i]))
+            req.prefill_step = self.timeline.serial
             self.stats["prefill_requests"] += 1
             # req.cached is the pre-sampling context length (complete_
             # prefill pins it before appending the first token) —
@@ -2265,7 +2299,9 @@ class InferenceEngine:
                 self._count_moe_rows("prefill", int(arrays[2].sum()), B * S)
                 args = [jnp.asarray(a) for a in arrays]
             fn = self._chunk_fn(B, S, "target", "sample")
+            self.timeline.enqueued(f"chunk {B}x{S}")
         else:
+            self.timeline.enqueued(f"prefill {B}x{S}")
             with self._phase("build_inputs"):
                 n_pages_row = S // self.page_size
                 tokens = np.zeros((B, S), np.int32)
@@ -2333,6 +2369,7 @@ class InferenceEngine:
                     self._table_args(page_table, window_table)]
             src = jnp.asarray(src)
         fn = self._decode_fn(B)
+        self.timeline.enqueued(f"decode x{B}")
         with self._phase("dispatch"):
             nxt, *pools = fn(
                 self.params, self.params_stacked, *args, *self._pools(),
@@ -2511,6 +2548,7 @@ class InferenceEngine:
             pt = jnp.asarray(page_table)
             args = [jnp.asarray(a) for a in (tokens, lengths, windows)]
         fn = self._propose_fn(B)
+        self.timeline.enqueued(f"speculate x{B}")
         with self._phase("dispatch"):
             proposed, self.draft_cache.k, self.draft_cache.v = fn(
                 self.draft_params, self.draft_stacked, *args, pt,

@@ -148,6 +148,9 @@ class Request:
     submitted_at: float = None
     first_token_at: float = None
     last_token_at: float = None
+    # serial of the engine's step record that read back its (latest)
+    # prefill: joins the request to the step timeline
+    prefill_step: int = None
 
     @property
     def context(self):

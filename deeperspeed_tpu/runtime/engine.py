@@ -332,13 +332,22 @@ class DeepSpeedEngine:
         # goodput buckets, in-engine MFU from compiled cost analysis, and
         # trigger-driven trace/memory capture. NULL_TELEMETRY (every hook
         # a no-op) when the block is absent — the hot path is unchanged.
-        from .telemetry import build_telemetry
+        from .telemetry import StepTimeline, build_telemetry
         local = [d for d in self.mesh.devices.flat
                  if getattr(d, "process_index", 0) == jax.process_index()]
         self.telemetry = build_telemetry(
             self._config.telemetry_config, monitor=self.monitor,
             devices=local or jax.local_devices())
         self._step_flops = {}   # compiled-variant key -> per-device flops
+        # one record a train_batch / train_steps call, kept with or
+        # without the block: dispatch to dispatch, so in steady state the
+        # step (docs/observability.md, "Slow steps"). `_newest_loss` is
+        # the last call's loss, asked `is_ready()` at the next entry
+        self.timeline = StepTimeline("train")
+        if self.telemetry.enabled:
+            self.telemetry.attach(self.timeline)
+        self._newest_loss = None
+        self._open_rows = 0
 
         # MoE routing observability (moe.observability): the sort
         # engine's in-jit stats land host-side via an async callback and
@@ -3320,6 +3329,7 @@ class DeepSpeedEngine:
         captures those layers' activations for this batch (fork:
         `pipe/engine.py:264`'s kwarg, here on the base engine too).
         """
+        self._step_entered(self.train_batch_size())
         if layers_to_hook is not None:
             self.set_layers_to_hook(layers_to_hook)
         tel = self.telemetry
@@ -3369,7 +3379,8 @@ class DeepSpeedEngine:
             _time.sleep(stall_s)   # deterministic hung-step fault
 
         try:
-            return self._train_batch_execute(batch, gas, fault)
+            return self._step_returns(
+                self._train_batch_execute(batch, gas, fault))
         except BaseException:
             # the step DIED rather than hung: disarm, or the deadline
             # would later fire a spurious stack dump + emergency-save
@@ -3377,6 +3388,41 @@ class DeepSpeedEngine:
             if self.sentinel is not None:
                 self.sentinel.watchdog_feed()
             raise
+
+    def _step_entered(self, rows):
+        """A `train_batch` / `train_steps` call enters: the record of the
+        call before closes here, so it runs dispatch to dispatch, and
+        that interval is what the throughput timer counts (a clock
+        around one asynchronous call times the enqueue). The newest loss
+        already computed means the device finished everything it was
+        given while the host was away: the step was `starved`."""
+        timeline = self.timeline
+        if timeline.open:
+            ready = getattr(self._newest_loss, "is_ready", None)
+            slow = timeline.end(rows=self._open_rows,
+                                starved=bool(ready and ready()))
+            self.tput_timer.stop(duration=timeline.ring[-1].wall)
+            if slow is not None:
+                self.telemetry.on_anomaly(self, "slow_step",
+                                          step=slow["serial"])
+        timeline.begin()
+        self._open_rows = rows
+        self.tput_timer.start()
+
+    def _step_returns(self, loss):
+        """The call returns with its step enqueued: the caller's time
+        starts, and the record stays open until the next entry."""
+        self._newest_loss = loss
+        self.timeline.leave()
+        return loss
+
+    @staticmethod
+    def _program_name(key):
+        """A step program's key in `_compiled_train`, as the timeline
+        spells it: `gas 1`, `gas 1 fault`, `grads 1`, `window 1 8`."""
+        parts = key if isinstance(key, tuple) else (key,)
+        head = "gas " if isinstance(parts[0], int) else ""
+        return head + " ".join(str(p) for p in parts)
 
     def _train_batch_execute(self, batch, gas, fault):
         tel = self.telemetry
@@ -3393,7 +3439,7 @@ class DeepSpeedEngine:
             # whole-batch device upload and the full-params profiler
             # below (both would materialize state this mode exists to
             # keep out of HBM).
-            self.tput_timer.start()
+            self.timeline.enqueued("streamed")
             if self._tiered is not None:
                 metrics = self._tiered_train_batch(batch)
                 offload = self._tiered.stats.drain()
@@ -3403,14 +3449,11 @@ class DeepSpeedEngine:
                 offload = None   # stall rides the param_gather span
                 flops = self._stream_flops.drain()["flops"] or None
             verdict = self._after_step(metrics)
-            self.tput_timer.stop()
             tel.on_step_end(self, verdict=verdict, tokens=tokens,
                             flops=flops, offload=offload)
             return metrics.loss
 
         self._maybe_profile_flops(batch)
-
-        self.tput_timer.start()
 
         # comms_timer (fork: engine.py:1164, zero/stage1.py:688): in-jit
         # collectives are profiled via jax.profiler; the host-visible comm
@@ -3448,6 +3491,7 @@ class DeepSpeedEngine:
                     self._step_flops[key] = flops
                     tel.register_compiled(key, flops)
                 self._compiled_train[key] = step_fn
+            self.timeline.enqueued(self._program_name(key))
             with tel.span("train_dispatch"):
                 loss, grads = self._compiled_train[key](*call_args)
             with tel.span("host_optimizer"):
@@ -3489,12 +3533,12 @@ class DeepSpeedEngine:
                     self._step_flops[key] = flops
                     tel.register_compiled(key, flops)
                 self._compiled_train[key] = step_fn
+            self.timeline.enqueued(self._program_name(key))
             with tel.span("train_dispatch"), \
                     tel.step_annotation(self.global_steps):
                 self.state, metrics = self._compiled_train[key](*call_args)
         self.micro_steps += gas
         verdict = self._after_step(metrics)
-        self.tput_timer.stop()
         tel.on_step_end(self, verdict=verdict,
                         flops=self._step_flops.get(key), tokens=tokens)
         return metrics.loss
@@ -3535,8 +3579,8 @@ class DeepSpeedEngine:
                 f"batches must be [n_steps, accum={gas}, micro, ...], "
                 f"got leading {lead[:2]}")
         self._assert_comm_precision()
+        self._step_entered(self.train_batch_size() * n_steps)
         self.telemetry.on_step_start(self.global_steps)
-        self.tput_timer.start()
         if self.sentinel is not None and \
                 ("window", gas, n_steps) in self._compiled_train:
             # one deadline for the whole fused window (n_steps device
@@ -3544,7 +3588,8 @@ class DeepSpeedEngine:
             # first call compiles and is exempt, as in train_batch
             self.sentinel.watchdog_arm()
         try:
-            return self._train_steps_execute(batches, gas, n_steps)
+            losses = self._train_steps_execute(batches, gas, n_steps)
+            return self._step_returns(losses)
         except BaseException:
             # died, not hung: disarm (see train_batch)
             if self.sentinel is not None:
@@ -3582,6 +3627,7 @@ class DeepSpeedEngine:
                 self._step_flops[key] = flops
                 tel.register_compiled(key, flops)
             self._compiled_train[key] = window_fn
+        self.timeline.enqueued(self._program_name(key))
         with tel.span("train_dispatch"), \
                 tel.step_annotation(self.global_steps):
             self.state, losses = self._compiled_train[key](*call_args)
@@ -3614,7 +3660,6 @@ class DeepSpeedEngine:
                 self.monitor.record(base + bs * (i + 1),
                                     {"Train/Samples/train_loss": losses[i],
                                      "Train/Samples/lr": lr})
-        self.tput_timer.stop()
         # windows classify as one block: wholly productive unless every
         # step was skipped (goodput cannot see intra-window skips — the
         # per-step loop can)

@@ -29,17 +29,31 @@ pieces fuse into one config-driven layer the engine consults every step:
   memory snapshot immediately and a trace of the following step(s)
   automatically, at most once per anomaly episode.
 
+- **Step timeline** (`StepTimeline`, one per engine, kept with or
+  without the block): every step one record (its key, its phases' self
+  seconds, the caller's time, thread CPU time, collector time, whether
+  something was lowered), a slow step judged by one rule against its
+  key's own typical step and its excess split over what held it.
+  `step_report()` is the process-wide accessor; the spans above write
+  into the open record and carry its serial (docs/observability.md,
+  "Slow steps").
+
 Zero-overhead path: when the block is absent the engine holds
 `NULL_TELEMETRY`, whose hooks are empty methods and whose `span()`
-returns a shared no-op context manager — the compiled programs and the
-host loop are unchanged.
+returns a shared no-op context manager — the compiled programs are
+unchanged, and the host loop keeps only its step timeline.
 """
 
+import bisect
+import gc
 import json
 import os
+import statistics
 import threading
 import time
+import typing
 import weakref
+from collections import deque
 
 from ..utils.logging import log_dist, logger
 
@@ -148,10 +162,12 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: phase accumulation + optional capture-buffer entry
-    + a mirrored `jax.profiler.TraceAnnotation` so device timelines show
-    the same names."""
-    __slots__ = ("tel", "name", "t0", "ann")
+    """One live span of a `SpanTracer` or a `StepTimeline`: its seconds
+    go to the owner's `_on_span` with the serial of the step record that
+    was open when it began (None outside an engine), and it mirrors a
+    `jax.profiler.TraceAnnotation` (the serial as its `step` argument)
+    so device timelines show the same names."""
+    __slots__ = ("tel", "name", "t0", "ann", "step")
 
     def __init__(self, tel, name):
         self.tel = tel
@@ -161,21 +177,37 @@ class _Span:
     def __enter__(self):
         tel = self.tel
         self.t0 = time.perf_counter()
-        tel._depth += 1
+        self.step = tel.serial
+        tel._stack.append(0.0)      # seconds of the spans opened inside
         if tel.mirror_annotations:
             import jax
-            self.ann = jax.profiler.TraceAnnotation(self.name)
+            self.ann = jax.profiler.TraceAnnotation(self.name) \
+                if self.step is None else \
+                jax.profiler.TraceAnnotation(self.name, step=self.step)
             self.ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        tel = self.tel
         t1 = time.perf_counter()
-        tel._depth -= 1
         if self.ann is not None:
             self.ann.__exit__(*exc)
-        tel._on_span(self.name, self.t0, t1 - self.t0, tel._depth)
+        self.tel._on_span(self.name, self.t0, t1 - self.t0, self.step)
         return False
+
+
+class SpanEvent(tuple):
+    """A buffered span, `(name, t0, dur, depth)` as every consumer
+    unpacks it, with the serial of the step record it belongs to beside
+    it (`step`; None outside an engine) and further Chrome `args`."""
+    step = None
+    args = None
+
+
+def clock_pair():
+    """One reading of both clocks, `(perf_counter, time_ns)`: spans and
+    step records are stamped on the first, a profiler's xplane file on
+    the second; the pair lines them up offline."""
+    return time.perf_counter(), time.time_ns()
 
 
 class SpanTracer:
@@ -185,9 +217,11 @@ class SpanTracer:
     Chrome-trace JSON (`{"traceEvents": [...]}`, "X" complete events,
     microsecond timestamps) loadable in Perfetto/chrome://tracing."""
 
+    serial = None       # a bare tracer's spans belong to no step record
+
     def __init__(self, mirror_annotations=True):
         self.mirror_annotations = mirror_annotations
-        self._depth = 0
+        self._stack = []            # open spans (`_Span` keeps it)
         self._phase_acc = {}        # name -> seconds, this step window
         self._buffer = []           # capture-window events
         self.capturing = False
@@ -195,19 +229,30 @@ class SpanTracer:
     def span(self, name):
         return _Span(self, name)
 
-    def _on_span(self, name, t0, dur, depth):
+    def _on_span(self, name, t0, dur, step):
+        self._stack.pop()
+        self.record(name, t0, dur, len(self._stack), step)
+
+    def record(self, name, t0, dur, depth, step=None):
+        """One closed span: its seconds to the phase account and, inside
+        a capture window, to the buffer. An engine's `StepTimeline`
+        hands its spans on through here."""
         self._phase_acc[name] = self._phase_acc.get(name, 0.0) + dur
         if self.capturing:
-            self._buffer.append((name, t0, dur, depth))
+            self.record_event(name, t0, dur, depth, step=step)
 
-    def record_event(self, name, t0, dur, depth=0):
+    def record_event(self, name, t0, dur, depth=0, step=None, **args):
         """Append one pre-timed event to an open capture window (the
         serving engine's per-request lifecycle records ride this — they
         are not live spans, the request's wall time was measured by the
-        scheduler). No-op outside a window."""
+        scheduler). `step` is the serial of the step record the event
+        belongs to; further keywords land in the Chrome event's `args`.
+        No-op outside a window."""
         if self.capturing:
-            self._buffer.append((str(name), float(t0), float(dur),
-                                 int(depth)))
+            event = SpanEvent((str(name), float(t0), float(dur),
+                               int(depth)))
+            event.step, event.args = step, args or None
+            self._buffer.append(event)
 
     def drain_phases(self):
         phases, self._phase_acc = self._phase_acc, {}
@@ -224,19 +269,29 @@ class SpanTracer:
 
     @staticmethod
     def chrome_trace(events, pid=0, metadata=None):
-        """Chrome-trace dict for a list of (name, t0, dur, depth);
-        `metadata` (kernel dispatch report, env fingerprint) lands in
-        the trace's ``otherData``."""
-        trace_events = [
-            {"name": name, "ph": "X", "pid": pid, "tid": depth,
-             "ts": t0 * 1e6, "dur": dur * 1e6,
-             "cat": "deeperspeed_tpu"}
-            for name, t0, dur, depth in events]
-        trace = {"traceEvents": trace_events,
-                 "displayTimeUnit": "ms"}
-        if metadata:
-            trace["otherData"] = metadata
-        return trace
+        """Chrome-trace dict for a list of (name, t0, dur, depth), each
+        with its step serial and further arguments as the event's
+        ``args`` where it carries any (`SpanEvent`). `metadata` (kernel
+        dispatch report, env fingerprint) lands in the trace's
+        ``otherData``, beside one `clock_pair()`: ``ts`` is
+        `perf_counter` microseconds."""
+        trace_events = []
+        for event in events:
+            name, t0, dur, depth = event
+            out = {"name": name, "ph": "X", "pid": pid, "tid": depth,
+                   "ts": t0 * 1e6, "dur": dur * 1e6,
+                   "cat": "deeperspeed_tpu"}
+            step = getattr(event, "step", None)
+            args = dict(getattr(event, "args", None) or {})
+            if step is not None:
+                args["step"] = step
+            if args:
+                out["args"] = args
+            trace_events.append(out)
+        perf, epoch_ns = clock_pair()
+        return {"traceEvents": trace_events, "displayTimeUnit": "ms",
+                "otherData": dict(metadata or {}, clock={
+                    "perf_counter": perf, "time_ns": epoch_ns})}
 
     @classmethod
     def export_chrome_trace(cls, events, path, pid=0, metadata=None):
@@ -304,6 +359,361 @@ class GoodputMeter:
         return out
 
 
+# ---------------------------------------------------------------------------
+# step timeline: one record a step, a slow step named by what held it
+# ---------------------------------------------------------------------------
+
+STEP_RING = 4096            # records a timeline keeps
+SLOW_KEPT = 256             # slow records it keeps whole
+TYPICAL_STEPS = 64          # a key's last steps not judged slow
+MIN_STEPS = 8               # no verdict before this many steps of a key
+# a step is slow where wall - typical > max(SLOW_FLOOR_S, SLOW_DEVIATIONS
+# x the key's median absolute deviation). False positives (a slow verdict
+# in a run whose end-to-end metric is within its spread of the median) in
+# the seven cells at these values: PERF.md section 6, PR 37
+SLOW_FLOOR_S = 5e-3
+SLOW_DEVIATIONS = 8
+# this many slow verdicts of a key in a row are its new level, not a
+# stall: the key learns its typical step anew
+RELEVEL_AFTER = 8
+SLOW_LOG_INTERVAL_S = 10.0
+
+LOWERED_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# process-wide: [seconds inside collections, start of the one running],
+# [programs lowered]; one gc.callbacks hook and one jax.monitoring
+# listener, installed with the first timeline
+_GC = [0.0, 0.0]
+_LOWERED = [0]
+# the newest timelines, held past their engines' lives: `step_report()`
+# is read after a run, when the engine that made the records may be gone
+_TIMELINES = deque(maxlen=16)
+
+
+def _on_gc(phase, info):  # noqa: ARG001
+    if phase == "start":
+        _GC[1] = time.perf_counter()
+    else:
+        _GC[0] += time.perf_counter() - _GC[1]
+
+
+def _on_lowered(event, duration, **_):  # noqa: ARG001
+    if event == LOWERED_EVENT:
+        _LOWERED[0] += 1
+
+
+_PROFILE_STATE = []         # jax's profiler state, looked up once
+
+
+def _profiler_on():
+    """Is a `jax.profiler` trace being recorded? Starting one and, far
+    more, stopping one (the trace is written out) holds the loop for
+    seconds, and that is the profiler's doing, not a stall. jax keeps the
+    session in a private place; where it moves, nothing is profiled as far
+    as the timeline knows."""
+    if not _PROFILE_STATE:
+        try:
+            from jax._src.profiler import _profile_state
+        except Exception:  # noqa: BLE001
+            _profile_state = None
+        _PROFILE_STATE.append(_profile_state)
+    return getattr(_PROFILE_STATE[0], "profile_session", None) is not None
+
+
+class StepRecord(typing.NamedTuple):
+    """One step of an engine, as its `StepTimeline` keeps it."""
+    serial: int         # the timeline's count of steps, from 1
+    key: str            # the programs the step enqueued
+    t_start: float      # perf_counter: the engine was entered
+    t_end: float        # ... and the record closed
+    wall: float         # what the rule judges: `outside` + the step
+    outside: float      # the caller's seconds charged to the step
+    phases: dict        # self seconds by span name, and "other"
+    cpu_s: float        # thread CPU seconds over `wall`
+    gc_s: float         # seconds inside garbage collections over `wall`
+    compiled: bool      # a program was lowered during it
+    profiler: bool      # a profiler trace started or stopped during it
+    rows: int           # rows or tokens the step accounted for
+    starved: bool       # train: the device's queue was empty at its end
+    verdict: str        # None, "slow", "compile" or "profiler"
+    excess: float       # a slow step's seconds over its key's typical
+
+
+class _KeySteps:
+    """What the rule knows of one key: its last steps not judged slow,
+    and their lengths sorted."""
+    __slots__ = ("records", "walls", "slow_run")
+
+    def __init__(self):
+        self.records = deque()
+        self.walls = []
+        self.slow_run = 0
+
+
+class StepTimeline:
+    """The always-kept record of an engine's host loop (module docstring;
+    docs/observability.md, "Slow steps").
+
+    The engine calls `begin()` when a step enters it, names the programs
+    it enqueues (`enqueued`), opens its phases as `span(name)`, and
+    closes the record with `end()`. A serve step's record closes when
+    `step()` returns and is charged the caller's time BEFORE it
+    (`outside`: the previous record's end to this one's start). A train
+    step's record stays open past the call's return (`leave()`) until
+    the next entry, so it runs dispatch to dispatch and is charged the
+    caller's time AFTER the call. Either way the records of a busy
+    engine tile the timeline with no hole.
+
+    `counters` takes every span's seconds (`<name>_s`) and the slow-step
+    sums; the serving engine hands in its `stats` dict. `tracer` (a
+    `SpanTracer`, attached by `Telemetry.attach`) receives every span
+    for the goodput account and the capture buffer."""
+
+    def __init__(self, engine, counters=None):
+        self.engine = engine        # "train" or "serve"
+        self.counters = {} if counters is None else counters
+        for name in ("slow_steps", "compile_steps", "profiler_steps",
+                     "starved_steps"):     # the last: train records only
+            self.counters.setdefault(name, 0)
+        for name in ("slow_step_excess_s", "slow_excess_device_wait_s",
+                     "slow_excess_gc_s", "slow_excess_host_s",
+                     "slow_excess_outside_s", "gc_s"):
+            self.counters.setdefault(name, 0.0)
+        self.tracer = None
+        self.mirror_annotations = False
+        self.serial = 0
+        self.open = False
+        self.ring = deque(maxlen=STEP_RING)
+        self.slow = deque(maxlen=SLOW_KEPT)
+        self._keys = {}
+        self._stack = []
+        self._phases = {}
+        self._key = ""
+        self._t_start = self._t_leave = self._t_end = None
+        self._cover = self._cpu0 = self._gc0 = self._lowered0 = None
+        self._profiler0 = False
+        self._log_at = None
+        self._log_held = 0
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_lowered)
+        _TIMELINES.append(self)
+
+    # -- the engine's calls -------------------------------------------------
+
+    def begin(self, busy=True):
+        """A step enters the engine. `busy` False: the engine had nothing
+        to do since the last record closed (an idle server), so the time
+        since is nobody's stall and the record covers the step alone."""
+        now = time.perf_counter()
+        if not busy or self._t_end is None:
+            self._cover, self._cpu0 = now, time.thread_time()
+            self._gc0, self._lowered0 = _GC[0], _LOWERED[0]
+            self._profiler0 = _profiler_on()
+        self._t_start, self._t_leave = now, None
+        self._phases, self._key = {}, ""
+        self.serial += 1
+        self.open = True
+
+    def enqueued(self, program):
+        """Name a program the open step enqueued, by the key the engine's
+        own program cache uses."""
+        self._key = f"{self._key} + {program}" if self._key else program
+
+    def span(self, name):
+        return _Span(self, name)
+
+    def leave(self):
+        """The train engine's call returns; the record stays open."""
+        self._t_leave = time.perf_counter()
+
+    def end(self, rows=0, starved=False):
+        """Close the open record and judge it. Returns the slow step's
+        whole record (a dict, also kept in `slow`) or None."""
+        now, cpu = time.perf_counter(), time.thread_time()
+        gc_s, lowered, profiler = _GC[0], _LOWERED[0], _profiler_on()
+        wall = now - self._cover
+        inside = (self._t_leave or now) - self._t_start
+        phases = self._phases
+        phases["other"] = inside - sum(phases.values())
+        key = self._key or "none"
+        record = StepRecord(
+            self.serial, key, self._t_start, now, wall, wall - inside,
+            phases, cpu - self._cpu0, gc_s - self._gc0,
+            lowered != self._lowered0, profiler != self._profiler0, rows,
+            starved, None, 0.0)
+        self._t_end = self._cover = now
+        self._cpu0, self._gc0, self._lowered0 = cpu, gc_s, lowered
+        self._profiler0 = profiler
+        self.open = False
+        if record.gc_s:
+            self.counters["gc_s"] += record.gc_s
+        record, slow = self._judge(record)
+        if record.starved:
+            self.counters["starved_steps"] += 1
+        self.ring.append(record)
+        return slow
+
+    # -- spans (`_Span` calls these) -----------------------------------------
+
+    def _on_span(self, name, t0, dur, step):
+        stack = self._stack
+        inner = stack.pop()
+        if stack:
+            stack[-1] += dur
+        phases = self._phases
+        phases[name] = phases.get(name, 0.0) + dur - inner
+        counters = self.counters
+        name_s = name + "_s"
+        counters[name_s] = counters.get(name_s, 0.0) + dur
+        if self.tracer is not None:
+            self.tracer.record(name, t0, dur, len(stack), step)
+
+    # -- the rule -------------------------------------------------------------
+
+    def _judge(self, record):
+        steps = self._keys.get(record.key)
+        if steps is None:
+            steps = self._keys[record.key] = _KeySteps()
+        if record.compiled or record.profiler:
+            # the runtime being set up or observed, not a steady step:
+            # never slow, never part of the key's typical step, and the
+            # device's queue running dry meanwhile is no one's starving
+            verdict = "compile" if record.compiled else "profiler"
+            self.counters[verdict + "_steps"] += 1
+            return record._replace(verdict=verdict, starved=False), None
+        walls = steps.walls
+        n = len(walls)
+        if n >= MIN_STEPS:
+            typical = (walls[(n - 1) >> 1] + walls[n >> 1]) / 2
+            excess = record.wall - typical
+            if excess > SLOW_FLOOR_S:
+                deviation = statistics.median(
+                    abs(w - typical) for w in walls)
+                if excess > SLOW_DEVIATIONS * deviation:
+                    record = record._replace(verdict="slow", excess=excess)
+                    slow = self._slow(record, steps, typical, deviation)
+                    steps.slow_run += 1
+                    if steps.slow_run >= RELEVEL_AFTER:
+                        self._keys[record.key] = _KeySteps()
+                    return record, slow
+        steps.slow_run = 0
+        steps.records.append(record)
+        bisect.insort(walls, record.wall)
+        if n >= TYPICAL_STEPS:
+            del walls[bisect.bisect_left(walls,
+                                         steps.records.popleft().wall)]
+        return record, None
+
+    def _slow(self, record, steps, typical, deviation):
+        """Split a slow step's excess over what held it. Each part is
+        the excess of one quantity over its own typical for the key, and
+        the parts are paid out of the step's excess in this order:
+        `device_wait` (the thread slept in the runtime: no Python ran),
+        `gc` (collections), `host` (thread CPU time, less `gc`: the
+        program's own Python), then the other phases, `other` (the
+        step's time under no span) and `outside` (the caller), largest
+        first: what is left of a phase's excess there is time its thread
+        spent off the CPU. `unattributed` is the rest."""
+        usual = steps.records
+
+        def over(value, get):
+            return max(value - statistics.median(map(get, usual)), 0.0)
+
+        raw = {name: over(seconds, lambda r, n=name: r.phases.get(n, 0.0))
+               for name, seconds in record.phases.items()}
+        raw["outside"] = over(record.outside, lambda r: r.outside)
+        gc_s = over(record.gc_s, lambda r: r.gc_s)
+        first = {"device_wait": raw.pop("device_wait", 0.0), "gc": gc_s,
+                 "host": max(over(record.cpu_s, lambda r: r.cpu_s) - gc_s,
+                             0.0)}
+        held, left = {}, record.excess
+        for name, seconds in list(first.items()) + sorted(
+                raw.items(), key=lambda kv: -kv[1]):
+            seconds = min(seconds, left)
+            if seconds > 0.0:
+                held[name] = seconds
+                left -= seconds
+        if left > 0.0:
+            held["unattributed"] = left
+
+        counters = self.counters
+        counters["slow_steps"] += 1
+        counters["slow_step_excess_s"] += record.excess
+        for name in ("device_wait", "gc", "host", "outside"):
+            counters[f"slow_excess_{name}_s"] += held.get(name, 0.0)
+        slow = {"engine": self.engine, **record._asdict(),
+                "typical_s": typical, "deviation_s": deviation,
+                "held_by": held, "clock": clock_pair()}
+        self.slow.append(slow)
+        self._log(slow)
+        return slow
+
+    def _log(self, slow):
+        """One line a slow step, at most one every SLOW_LOG_INTERVAL_S;
+        the next line says how many were held back."""
+        now = slow["t_end"]
+        if self._log_at is not None and \
+                now - self._log_at < SLOW_LOG_INTERVAL_S:
+            self._log_held += 1
+            return
+        held = ", ".join(f"{s * 1e3:,.1f} in {name}"
+                         for name, s in slow["held_by"].items()
+                         if s >= 5e-5)
+        gc_ms = slow["gc_s"] * 1e3
+        line = (f"{self.engine} step {slow['serial']} ({slow['key']}) "
+                f"{slow['wall'] * 1e3:,.1f} ms, typical "
+                f"{slow['typical_s'] * 1e3:,.1f}: {held}, CPU "
+                f"{slow['cpu_s'] * 1e3:,.1f} ms, "
+                + (f"collections {gc_ms:,.1f} ms" if gc_ms
+                   else "no collection") + ", no compile"
+                + (", the device's queue ran dry" if slow["starved"]
+                   else ""))
+        if self._log_held:
+            line += f" ({self._log_held} more slow steps since the last line)"
+        self._log_at, self._log_held = now, 0
+        logger.warning(line)
+
+    # -- the report -----------------------------------------------------------
+
+    def report(self, last=None):
+        """Counters over the newest `last` closed records (all the ring
+        holds by default), with the slow ones whole."""
+        records = list(self.ring)
+        if last is not None:
+            records = records[-last:] if last > 0 else []
+        first = records[0].serial if records else self.serial + 1
+        slow = [s for s in self.slow if s["serial"] >= first]
+        held = {}
+        for s in slow:
+            for name, seconds in s["held_by"].items():
+                held[name] = held.get(name, 0.0) + seconds
+        return {"engine": self.engine, "serial": self.serial,
+                "steps": len(records),
+                "wall_s": sum(r.wall for r in records),
+                "slow_steps": sum(r.verdict == "slow" for r in records),
+                "slow_step_excess_s": sum(r.excess for r in records),
+                "slow_excess_s": held,
+                "compile_steps": sum(r.verdict == "compile"
+                                     for r in records),
+                "profiler_steps": sum(r.verdict == "profiler"
+                                      for r in records),
+                "starved_steps": sum(r.starved for r in records),
+                "gc_s": sum(r.gc_s for r in records),
+                "slow": slow}
+
+
+def step_report(last=None):
+    """The step timelines of this process's engines (the newest 16,
+    whether or not the engine still lives), process-wide as
+    `ops.dispatch_report()` is: `{"clock": clock_pair(), "timelines":
+    [StepTimeline.report(last), ...]}`, train engines first."""
+    return {"clock": clock_pair(),
+            "timelines": sorted((t.report(last) for t in list(_TIMELINES)),
+                                key=lambda r: r["engine"] != "train")}
+
+
 class _NullTelemetry:
     """The absent-config telemetry object: every hook is a no-op and
     `span()` hands back one shared do-nothing context manager."""
@@ -369,6 +779,7 @@ class Telemetry:
         self.anomaly_capture_steps = int(anomaly_capture_steps)
 
         self.tracer = SpanTracer(mirror_annotations=self.spans_enabled)
+        self.timeline = None        # the engine's, once it `attach`es
         self.goodput = GoodputMeter()
         self.compiled_flops = {}    # step-variant key -> per-device flops
 
@@ -419,6 +830,14 @@ class Telemetry:
     # spans
     # ------------------------------------------------------------------
 
+    def attach(self, timeline):
+        """Join the engine's `StepTimeline`: from here on a span writes
+        into the open step record first, carries its serial, and reaches
+        the tracer (goodput account, capture buffer) through it."""
+        self.timeline = timeline
+        timeline.tracer = self.tracer
+        timeline.mirror_annotations = self.tracer.mirror_annotations
+
     def span(self, name):
         # goodput keeps phase timing alive even with spans off: the
         # data_wait / ckpt-stall buckets are fed by these spans, and
@@ -426,6 +845,8 @@ class Telemetry:
         # spans: false DOES turn off: the jax.profiler annotation
         # mirroring (tracer.mirror_annotations) and span capture/export
         # (_open_window skips start_capture).
+        if self.timeline is not None:
+            return self.timeline.span(name)
         if not (self.spans_enabled or self.goodput_enabled
                 or self.fleet is not None):
             return _NULL_SPAN
@@ -532,6 +953,11 @@ class Telemetry:
                                  param_wait=param_wait,
                                  ckpt_stall=ckpt_delta)
             scalars.update(self.goodput.scalars())
+            if self.timeline is not None:
+                # seconds slow steps ran over their typical (one step
+                # late: a train record closes at the next entry)
+                scalars["Train/Goodput/slow_step_s"] = \
+                    self.timeline.counters["slow_step_excess_s"]
         if self.fleet is not None:
             scalars.update(self.fleet.on_step_end(
                 dt, data_wait_s=data_wait, ckpt_stall_s=ckpt_delta,

@@ -3,7 +3,9 @@
 `SynchronizedWallClockTimer` fences XLA's async dispatch with
 `jax.block_until_ready`/`jax.effects_barrier` where the reference used
 `cuda.synchronize()`. `ThroughputTimer` reports samples/sec with warmup
-skip.
+skip; the training engine feeds it each step's dispatch to dispatch
+interval (`stop(duration=...)`), so the rate is one of training, not of
+enqueueing.
 """
 
 import contextlib
@@ -134,16 +136,22 @@ class ThroughputTimer:
             _device_barrier()
             self.start_time = time.monotonic()
 
-    def stop(self, report_speed=True):
+    def stop(self, report_speed=True, duration=None):
+        """Count one step. `duration`: its seconds where the caller has
+        measured them. The engine hands in its step timeline's dispatch
+        to dispatch interval: a clock around one `train_batch` call
+        times the enqueue of an asynchronous step, not the step
+        (`effects_barrier` waits for no computation)."""
         if not self.started:
             return
         self.started = False
         self.micro_step_count += 1
         self.global_step_count += 1
         if self.start_time > 0:
-            _device_barrier()
-            self.end_time = time.monotonic()
-            duration = self.end_time - self.start_time
+            if duration is None:
+                _device_barrier()
+                self.end_time = time.monotonic()
+                duration = self.end_time - self.start_time
             self.total_elapsed_time += duration
             if report_speed and \
                     self.global_step_count % self.steps_per_output == 0:

@@ -936,8 +936,10 @@ class TestProgramInFlight:
                 opened.append("/" + name)
         eng._phase = spy
         eng.step()
-        assert opened == ["decode", "/decode",           # the dispatch
-                          "decode", "readback", "/readback",
+        assert opened == ["schedule", "/schedule",
+                          "decode", "/decode",           # the dispatch
+                          "decode", "readback",
+                          "device_wait", "/device_wait", "/readback",
                           "complete", "/complete", "/decode"]
 
 
