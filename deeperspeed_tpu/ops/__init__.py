@@ -27,7 +27,11 @@ def dispatch_report():
     backend} of the model-side dispatchers; ``decode_attention``:
     {"decode": backend, "decode_kv": pool dtype — "int8" when the paged
     pools are quantized, "kv_write": backend of the one-token row
-    write}; ``quant_matmul`` / ``grouped_matmul``:
+    write, "kv_write_slots": the slots of a page that kernel read and
+    wrote back for one row (the row's packed sublane group: 16 for bf16,
+    32 for int8, 8 for float32, or the page; absent under XLA),
+    "kv_write_latent" / "kv_write_latent_slots": the same of a latent
+    layer's row write}; ``quant_matmul`` / ``grouped_matmul``:
     {name: backend}. A backend is "pallas" (the kernel, interpreted off
     a TPU) or "xla". ``xla_on_tpu`` names every dispatcher that, on a
     TPU, took XLA where it has a kernel (`note_xla_on_tpu`).

@@ -48,8 +48,11 @@ a step the Hb queries meet the page as one [Hb * page_size, D] operand
 
 The decoded token's own K/V row gets into its page through
 `paged_kv_write`: on a TPU a second small kernel that ALIASES the stacked
-pool (`input_output_aliases`) and rewrites one page tile per batch row
-in place, so a decode step moves the pages it touches and never a pool.
+pool (`input_output_aliases`) and rewrites, per batch row, the row's
+packed sublane GROUP of its page in place (`_write_group`: the 16 slots
+of a bf16 page that share the row's sublanes, 32 of an int8 page, 8 of a
+float32 one; never the page's 64), so a decode step moves the pages it
+attends over and never a pool.
 
 Off a TPU the kernel runs in interpreter mode (slow, test-only) and
 `paged_decode_attention` defaults to the XLA form there, a gather +
@@ -500,10 +503,23 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
 # the decoded token's K/V row, written in place
 # ---------------------------------------------------------------------------
 
+def _write_group(page_size, dtype):
+    """Slots of a page one row's write reads, selects into and writes
+    back: the row's packed sublane group. A pool's HBM tile holds 8
+    sublanes of 32 bits, so a row of `dtype` shares its sublanes with
+    the 8 * (4 / itemsize) slots of its group (float32 8, bf16 16,
+    int8 32) and with no other; that is the least a read-modify-write
+    can move. A page the group does not divide (8 or 24 slots of bf16)
+    is moved whole."""
+    group = 32 // jnp.dtype(dtype).itemsize
+    return group if page_size % group == 0 else page_size
+
+
 def _kv_write_kernel(lyr_ref, page_ref, slot_ref, *refs):
-    """One batch row: each pool's page tile ([H, ps, D], or the scale
-    pool's [H, ps] plane) comes in, gets the row at its slot, and goes
-    back out to the page it came from (the output aliases the pool)."""
+    """One batch row: each pool's tile (the [H, g, D] group of the row's
+    page, or the scale pool's whole [H, ps] plane) comes in, gets the row
+    at its slot, and goes back out to where it came from (the output
+    aliases the pool)."""
     n = len(refs) // 3
     slot = slot_ref[pl.program_id(0)]
     for row, pool, out in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
@@ -511,25 +527,33 @@ def _kv_write_kernel(lyr_ref, page_ref, slot_ref, *refs):
         slots = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
         # a select, not a dynamic one-row store: a row of a packed
         # (bf16 / int8) tile shares its sublane with its neighbours
-        out[...] = jnp.where(slots == slot, row[...], tile)
+        out[...] = jnp.where(slots == slot % tile.shape[1], row[...], tile)
 
 
 def paged_kv_write_pallas(pools, rows, layer, page_idx, slot):
-    """`paged_kv_write` as a kernel over grid (B,). The tile is blocked
-    over heads (one contiguous [H, ps, D] page, 256 KB at 16 x 64 x 128
-    bf16) and read-modify-written, which is safe because no two live
-    rows write into one page in one step: a sequence's last page is its
-    own (shared prefix pages are full pages and never written). Only
-    the trash page 0 sees colliding writes (inactive rows), and nothing
-    reads its content."""
+    """`paged_kv_write` as a kernel over grid (B,). A data pool is
+    blocked by the row's sublane group: the [H, g, D] slots
+    ``slot // g * g ...`` of one page (`_write_group`; 64 KB at
+    16 x 16 x 128 bf16, a quarter of the page), read, selected into at
+    ``slot % g`` and written back. An int8 page's scale pool has the
+    slots on its LANES: its [H, ps] plane (2 KB) rides whole. The
+    read-modify-write is safe because no two live rows write into one
+    page in one step, so none write into one group: a sequence's last
+    page is its own (shared prefix pages are full pages and never
+    written). Only the trash page 0 sees colliding writes (inactive
+    rows), and nothing reads its content."""
     B = page_idx.shape[0]
     n = len(pools)
+    _LAST_BACKEND["kv_write_slots"] = _write_group(pools[0].shape[3],
+                                                   pools[0].dtype)
 
     def pool_spec(pool):
-        tile = pool.shape[2:]                       # [H, ps(, D)]
+        H, page_size, *D = pool.shape[2:]           # no D: a scale pool
+        g = _write_group(page_size, pool.dtype) if D else page_size
         return pl.BlockSpec(
-            (None, None, *tile),
-            lambda b, lyr, pg, sl: (lyr[0], pg[b], *(0,) * len(tile)))
+            (None, None, H, g, *D),
+            lambda b, lyr, pg, sl: (lyr[0], pg[b], 0, sl[b] // g,
+                                    *(0,) * len(D)))
 
     def row_spec(pool):
         # rows ride as [B, H, 1(, D)]: the block's last two dims are the
@@ -573,10 +597,13 @@ def paged_kv_write(pools, rows, layer, page_idx, slot, backend=None):
 
     ``pools`` are ``[L, P, H, page_size, ...]`` arrays (K and V data
     pools ``[..., D]``, a data pool first, and for int8 pages their
-    ``[L, P, H, page_size]`` scale pools) and ``rows`` the matching ``[B, H, ...]`` rows, cast to
-    the pool's dtype here. Inactive batch rows name the trash page 0.
-    Returns the pools in order. Under jit with the pools donated the
-    kernel rewrites B page tiles a pool and nothing else moves.
+    ``[L, P, H, page_size]`` scale pools) and ``rows`` the matching
+    ``[B, H, ...]`` rows, cast to the pool's dtype here. Inactive batch
+    rows name the trash page 0. Returns the pools in order. Under jit
+    with the pools donated the kernel rewrites B sublane groups a data
+    pool (`_write_group` slots of a page each: `dispatch_report()`'s
+    ``kv_write_slots``, the useful share of the write's traffic being one
+    over it) and nothing else moves.
 
     backend: as `paged_decode_attention` (None = the kernel on a TPU
     when `paged_decode_supported`, XLA otherwise).
@@ -596,6 +623,7 @@ def paged_kv_write(pools, rows, layer, page_idx, slot, backend=None):
                                 data.shape[3], data.dtype == jnp.int8)
     _LAST_BACKEND["kv_write"] = backend
     if backend == "xla":
+        _LAST_BACKEND.pop("kv_write_slots", None)
         return paged_kv_write_xla(pools, rows, layer, page_idx, slot)
     if backend != "pallas":
         raise ValueError(f"unknown paged kv write backend {backend!r}")
@@ -818,12 +846,14 @@ def paged_latent_decode(q, pool, page_table, lengths, sm_scale, v_width,
 
 def _latent_write_kernel(lyr_ref, page_ref, slot_ref, row_ref, pool_ref,
                          out_ref):
-    """One batch row: its page tile [ps, width] comes in, gets the row at
-    its slot, and goes back to where it came from (as `_kv_write_kernel`)."""
+    """One batch row: the [g, width] sublane group of its page comes in,
+    gets the row at its slot, and goes back to where it came from (as
+    `_kv_write_kernel`)."""
     tile = pool_ref[...]
     slots = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
-    out_ref[...] = jnp.where(slots == slot_ref[pl.program_id(0)],
-                             row_ref[...], tile)
+    out_ref[...] = jnp.where(
+        slots == slot_ref[pl.program_id(0)] % tile.shape[0], row_ref[...],
+        tile)
 
 
 @scopes.scoped("ds.kv_write")
@@ -832,8 +862,9 @@ def paged_latent_write(pool, rows, layer, page_idx, slot, backend=None):
     latent pool: ``pool[layer, page_idx[b], slot[b]] = rows[b]``
     (``pool`` [L, P, page_size, row], ``rows`` [B, width <= row], padded
     with zeros to the pool's row). On a TPU a kernel that aliases the
-    pool and rewrites one page tile a row, as `paged_kv_write`; XLA's
-    scatter off it. Returns the pool."""
+    pool and rewrites the row's sublane group of its page ([g, row],
+    `_write_group`; ``kv_write_latent_slots`` in `dispatch_report()`), as
+    `paged_kv_write`; XLA's scatter off it. Returns the pool."""
     B = rows.shape[0]
     if pool.ndim != 4 or rows.ndim != 2 or pool.shape[-1] < rows.shape[1]:
         raise ValueError(f"rows {rows.shape} do not match the latent pool "
@@ -846,12 +877,15 @@ def paged_latent_write(pool, rows, layer, page_idx, slot, backend=None):
     rows = jnp.pad(rows.astype(pool.dtype),
                    ((0, 0), (0, W - rows.shape[1])))
     if backend == "xla":
+        _LAST_BACKEND.pop("kv_write_latent_slots", None)
         return pool.at[layer, page_idx, slot].set(rows)
     if backend != "pallas":
         raise ValueError(f"unknown paged kv write backend {backend!r}")
-    tile = pool.shape[2:]
-    pool_spec = pl.BlockSpec((None, None, *tile),
-                             lambda b, lyr, pg, sl: (lyr[0], pg[b], 0, 0))
+    g = _write_group(pool.shape[2], pool.dtype)
+    _LAST_BACKEND["kv_write_latent_slots"] = g
+    pool_spec = pl.BlockSpec(
+        (None, None, g, W),
+        lambda b, lyr, pg, sl: (lyr[0], pg[b], sl[b] // g, 0))
     return pl.pallas_call(
         _latent_write_kernel,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),

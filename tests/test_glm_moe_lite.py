@@ -373,6 +373,44 @@ def test_the_latent_kernel_body_against_its_xla_twin():
     assert not np.asarray(a[1, 31, 0, W:]).any()
 
 
+@pytest.mark.parametrize("ps", [8, 16, 24, 64])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.int8],
+                         ids=["bf16", "fp32", "int8"])
+def test_latent_write_kernel_matches_the_scatter(dtype, ps):
+    """`paged_latent_write` moves the row's packed sublane group of its
+    page ([g, row]; the page where the group does not divide it), as
+    `paged_kv_write` does: the kernel in interpret mode against XLA's
+    scatter at the edges of a group, with two inactive rows on the trash
+    page; every slot no row names is the input's, bit for bit."""
+    L, P, W = 2, 7, 128
+    g = decode_attention._write_group(ps, dtype)
+    keys = jax.random.split(jax.random.PRNGKey(ps), 2)
+    pool = (jax.random.normal(keys[0], (L, P, ps, W)) * 40).astype(dtype)
+    rows = (jax.random.normal(keys[1], (7, 40)) * 40).astype(dtype)
+    page = jnp.asarray([3, 5, 6, 2, 1, 0, 0], jnp.int32)
+    trash = [g - 1, g % ps]
+    slot = jnp.asarray([0, g - 1, g % ps, ps - 1, (g + g // 2) % ps] + trash,
+                       jnp.int32)
+    want = decode_attention.paged_latent_write(pool, rows, jnp.int32(1),
+                                               page, slot, backend="xla")
+    assert "kv_write_latent_slots" not in decode_attention._LAST_BACKEND
+    got = jax.jit(lambda pool, rows: decode_attention.paged_latent_write(
+        pool, rows, jnp.int32(1), page, slot, backend="pallas"))(pool, rows)
+    assert decode_attention._LAST_BACKEND["kv_write_latent_slots"] == g
+    assert got.dtype == pool.dtype
+    got, want, before = (np.asarray(x.astype(jnp.float32))
+                         for x in (got, want, pool))
+    np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+    np.testing.assert_array_equal(got[1, 1, (g + g // 2) % ps, :40],
+                                  np.asarray(rows[4].astype(jnp.float32)))
+    named = np.zeros((L, P, ps), bool)
+    named[1, np.asarray(page), np.asarray(slot)] = True
+    np.testing.assert_array_equal(got[~named], before[~named])
+    if trash[0] // g != trash[1] // g:
+        # neighbouring groups of the trash page are two blocks: both land
+        np.testing.assert_array_equal(got[:, 0], want[:, 0])
+
+
 def test_the_latent_page_kind_shares_the_allocator():
     cache = PagedKVCache(6, 10, 1, 64, 256, latent_width=576)
     assert cache.k.shape == (6, 10, 64, 640) and cache.v is None

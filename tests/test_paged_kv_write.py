@@ -1,10 +1,12 @@
 """The one-token step leaves the KV pools where they are (PR 25).
 
 - `paged_kv_write`: the aliased row-write kernel (interpret mode here)
-  against XLA's `pool.at[layer, page_idx, :, slot].set(row)`, for bf16
-  pools and for int8 data + scale pools, with inactive rows colliding on
-  the trash page 0: every page but page 0 is equal bit for bit, and no
-  other layer is touched.
+  against XLA's `pool.at[layer, page_idx, :, slot].set(row)`, for
+  float32, bf16 and int8 data + scale pools, at the edges of the row's
+  packed sublane group (`_write_group`: what a row's write moves since
+  PR 38) and on pages the group does not divide, with inactive rows on
+  the trash page 0: every slot no row names is the input's, bit for bit,
+  and no other layer is touched.
 - `paged_decode_attention(..., layer=l)` on stacked `[L, P, H, ps, D]`
   pools against the per-layer call on `pool[l]`, both back ends.
 - The engine under a model-parallel mesh with the kernels forced: the
@@ -25,22 +27,22 @@ from deeperspeed_tpu.inference.kv_cache import quantize_kv
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.ops import dispatch_report
 from deeperspeed_tpu.ops.pallas.decode_attention import (
-    paged_decode_attention, paged_kv_write)
+    _write_group, paged_decode_attention, paged_kv_write)
 
 L, P, H, PS = 3, 7, 4, 16
 
 
-def stacked_pools(rng, d, dtype):
-    """K and V data pools [L, P, H, PS, d] of `dtype`, with their
-    [L, P, H, PS] bf16 scale pools when int8."""
+def stacked_pools(rng, d, dtype, ps=PS):
+    """K and V data pools [L, P, H, ps, d] of `dtype`, with their
+    [L, P, H, ps] bf16 scale pools when int8."""
     def data():
-        x = rng.normal(size=(L, P, H, PS, d))
+        x = rng.normal(size=(L, P, H, ps, d))
         if dtype == jnp.int8:
             return jnp.asarray(np.round(x * 40).clip(-127, 127), jnp.int8)
         return jnp.asarray(x, dtype)
     pools = [data(), data()]
     if dtype == jnp.int8:
-        pools += [jnp.asarray(rng.uniform(0.01, 0.1, size=(L, P, H, PS)),
+        pools += [jnp.asarray(rng.uniform(0.01, 0.1, size=(L, P, H, ps)),
                               jnp.bfloat16) for _ in range(2)]
     return pools
 
@@ -58,38 +60,68 @@ def new_rows(rng, pools, b):
 # the write kernel
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("dtype,page_size,group", [
+    (jnp.float32, 8, 8), (jnp.float32, 24, 8), (jnp.float32, 64, 8),
+    (jnp.bfloat16, 8, 8), (jnp.bfloat16, 16, 16), (jnp.bfloat16, 24, 24),
+    (jnp.bfloat16, 64, 16), (jnp.int8, 32, 32), (jnp.int8, 24, 24),
+    (jnp.int8, 64, 32), (jnp.int8, 96, 32)])
+def test_write_group_is_the_rows_packed_sublanes(dtype, page_size, group):
+    """8 sublanes of 32 bits: 8 float32, 16 bf16, 32 int8 slots, where
+    that divides the page; else the page."""
+    assert _write_group(page_size, dtype) == group
+
+
+def group_edge_slots(ps, g):
+    """Five live rows' slots (each on a page of its own): the page's
+    first slot, a group's last, the next group's first, the page's last,
+    and one in the middle of a group; then two inactive rows' slots on
+    the trash page, in neighbouring groups where the page has two."""
+    return [0, g - 1, g % ps, ps - 1, (g + g // 2) % ps], [g - 1, g % ps]
+
+
 @pytest.mark.parametrize("layer", [0, L - 1])
 @pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("ps", [8, 16, 24, 64])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.int8],
                          ids=["bf16", "fp32", "int8"])
-def test_write_kernel_matches_the_scatter(dtype, d, layer):
-    rng = np.random.default_rng(d + layer)
-    pools = stacked_pools(rng, d, dtype)
-    # five rows: three live ones on pages of their own (one on the last
-    # slot of its page), two inactive ones that collide on trash page 0
-    page_idx = jnp.asarray([3, 0, 6, 0, 1], jnp.int32)
-    slot = jnp.asarray([5, 0, PS - 1, 0, 0], jnp.int32)
-    rows = new_rows(rng, pools, 5)
+def test_write_kernel_matches_the_scatter(dtype, ps, d, layer):
+    rng = np.random.default_rng(d + layer + ps)
+    pools = stacked_pools(rng, d, dtype, ps)
+    g = _write_group(ps, dtype)
+    live, trash = group_edge_slots(ps, g)
+    page_idx = jnp.asarray([3, 5, 6, 2, 1, 0, 0], jnp.int32)
+    slot = jnp.asarray(live + trash, jnp.int32)
+    rows = new_rows(rng, pools, 7)
     want = paged_kv_write(pools, rows, jnp.int32(layer), page_idx, slot,
                           backend="xla")
+    assert "kv_write_slots" not in dispatch_report()["decode_attention"]
     got = jax.jit(lambda *a: paged_kv_write(a[:len(pools)], a[len(pools):],
                                             jnp.int32(layer), page_idx,
                                             slot, backend="pallas"))(
         *pools, *rows)
-    assert dispatch_report()["decode_attention"]["kv_write"] == "pallas"
-    assert len(got) == len(pools)
-    for before, w, g in zip(pools, want, got):
-        assert g.dtype == before.dtype and g.shape == before.shape
-        w, g, before = (np.asarray(x.astype(jnp.float32))
-                        for x in (w, g, before))
+    report = dispatch_report()["decode_attention"]
+    assert report["kv_write"] == "pallas" and report["kv_write_slots"] == g
+    assert len(got) == len(pools)              # K, V (and their scales)
+    named = np.zeros((L, P, ps), bool)
+    named[layer, np.asarray(page_idx), np.asarray(slot)] = True
+    for before, w, after in zip(pools, want, got):
+        assert after.dtype == before.dtype and after.shape == before.shape
+        w, after, before = (np.asarray(x.astype(jnp.float32))
+                            for x in (w, after, before))
         # every page but the trash page, bit for bit
-        np.testing.assert_array_equal(g[:, 1:], w[:, 1:])
-        # the live rows did land, and only in this layer
-        assert not np.array_equal(g[layer, 1:], before[layer, 1:])
-        others = [l for l in range(L) if l != layer]
-        np.testing.assert_array_equal(g[others], before[others])
-        # on the trash page every slot but the colliding one is kept
-        np.testing.assert_array_equal(g[:, 0, :, 1:], before[:, 0, :, 1:])
+        np.testing.assert_array_equal(after[:, 1:], w[:, 1:])
+        # the live rows did land
+        assert not np.array_equal(after[layer, 1:], before[layer, 1:])
+        # every slot no row names is the input's: the rest of the row's
+        # own group, its page's other groups, every other page and layer
+        kept = np.moveaxis(~named, 2, 0)       # [ps, L, P]
+        np.testing.assert_array_equal(np.moveaxis(after, 3, 0)[kept],
+                                      np.moveaxis(before, 3, 0)[kept])
+        if before.ndim == 5 and trash[0] // g != trash[1] // g:
+            # two rows in neighbouring groups of one page: two blocks of
+            # a data pool, so both land even there (a scale pool's plane
+            # is one block: the second row's write may drop the first's)
+            np.testing.assert_array_equal(after[:, 0], w[:, 0])
 
 
 def test_written_rows_are_the_rows():

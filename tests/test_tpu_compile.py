@@ -35,6 +35,8 @@ import jax.numpy as jnp  # noqa: E402
     for name in ("flash_attention", "decode_attention",
                  "block_sparse_attention", "grouped_matmul", "quant_matmul",
                  "optimizer"))
+from deeperspeed_tpu.ops import dispatch_report  # noqa: E402
+
 BF16 = jnp.bfloat16
 
 
@@ -391,7 +393,9 @@ def test_paged_decode_is_one_call_over_live_pages(on_chip, cell):
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_kv_write_compiles(on_chip, head_dim, quant):
     """The aliased row write: K and V (and for int8 pages their scale
-    pools) in one call, a [H, page, D] tile a batch row."""
+    pools) in one call, the row's packed sublane group of its page a
+    batch row: a [H, 16, D] block of a bf16 pool, [H, 32, D] of an int8
+    one, and the scale pool's whole [H, page] plane."""
     B, H = 32, 16
     pools = stacked(24, 401, H, 64, head_dim, quant)
     rows = [((B, H) + shape[4:], dtype) for shape, dtype in pools]
@@ -404,6 +408,18 @@ def test_kv_write_compiles(on_chip, head_dim, quant):
     text = on_chip(write, *index, *pools, *rows)
     assert_kernel(text)
     assert "ds.kv_write" in text
+    g = 32 if quant else 16
+    assert dispatch_report()["decode_attention"]["kv_write_slots"] == g
+    jaxpr = jax.make_jaxpr(write)(
+        *[jax.ShapeDtypeStruct(shape, dtype)
+          for shape, dtype in (*index, *pools, *rows)])
+    (maps,) = [eqn.params["grid_mapping"].block_mappings
+               for eqn in jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    blocks = [m.block_aval.shape for m in maps]
+    # rows, pools in, pools out: K, V (and their scales) each
+    n = len(pools)
+    assert blocks[n:2 * n] == blocks[2 * n:] == \
+        [(H, g, head_dim)] * 2 + [(H, 64)] * (n - 2)
 
 
 INSTRUCTION = re.compile(
@@ -726,6 +742,9 @@ def test_latent_row_write_compiles(on_chip):
     text = on_chip(write, LATENT_POOL, ((32, 576), BF16), ((), jnp.int32),
                    ((32,), jnp.int32), ((32,), jnp.int32))
     assert re.search(r"%ds\.kv_write[.\d]* = .*tpu_custom_call", text)
+    # the row's sublane group of its [64, 640] page: a [16, 640] block
+    assert dispatch_report()["decode_attention"]["kv_write_latent_slots"] \
+        == 16
 
 
 def test_flash_forward_compiles_at_head_dim_256(on_chip):
