@@ -1,0 +1,5 @@
+from benchmarks import ouro_costs
+
+
+def read(rec):
+    return ouro_costs.loop_step_roofline(rec)

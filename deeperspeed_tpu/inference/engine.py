@@ -71,7 +71,8 @@ from ..ops.pallas.decode_attention import (paged_decode_attention,
                                            paged_kv_write,
                                            paged_latent_decode,
                                            paged_latent_write)
-from ..ops.pallas.flash_attention import NEG_INF
+from ..ops.autotune import whole_blocks
+from ..ops.pallas.flash_attention import NEG_INF, flash_attention_supported
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.config import (DeepSpeedConfig, parse_inference_block,
                               parse_quantization_block)
@@ -157,7 +158,8 @@ class _Family:
         # the layers that route (a planned model's `experts` layers), and
         # whether only a share of the router's experts is held here
         plan = getattr(self.cfg, "layer_plan", ())
-        self.moe_layers = (sum(1 for s in plan if s.ffn == "experts")
+        self.moe_layers = (getattr(self.cfg, "loop_steps", 1) *
+                           sum(1 for s in plan if s.ffn == "experts")
                            if plan else self.cfg.num_layers)
         self.moe_held = tuple(getattr(self.cfg, "moe_held", ()))
 
@@ -267,6 +269,10 @@ class InferenceEngine:
         # attention is latent: one pool with no head axis
         self.latent = cfg.latent_width if self.planned and \
             cfg.cache_layers("latent") else 0
+        # a looped model (`GPTNeoXConfig.loop_steps`): the plan's stack
+        # run that many times over the same weights, a pass's K/V in
+        # cache layers of its own (docs/inference.md "Looped models")
+        self.loop_steps = cfg.loop_steps if self.planned else 1
         if getattr(cfg, "attention_engine", "dense") != "dense":
             raise DeepSpeedConfigError(
                 "serving needs attention_engine='dense' (the block-"
@@ -399,11 +405,13 @@ class InferenceEngine:
         # a window layer
         # keeps, at most window / page + 1 pages a sequence, so the pool
         # is sized for `max_batch_size` of those and the scheduler gives
-        # the rest back as a sequence grows
+        # the rest back as a sequence grows. A looped model's pools hold
+        # `loop_steps` cache layers a layer (`cfg.cache_layers`)
         kv_heads = getattr(cfg, "kv_heads", cfg.num_heads)
         n_window = cfg.cache_layers("window") if self.planned else 0
         self.cache = PagedKVCache(
-            num_layers=cfg.num_layers - n_window, num_pages=ip["num_pages"],
+            num_layers=self.loop_steps * cfg.num_layers - n_window,
+            num_pages=ip["num_pages"],
             num_heads=kv_heads, page_size=self.page_size,
             head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh,
             latent_width=self.latent)
@@ -561,6 +569,10 @@ class InferenceEngine:
                       "slow_excess_outside_s": 0.0, "gc_s": 0.0,
                       "compile_steps": 0, "profiler_steps": 0,
                       "decode_kv_tokens": 0,
+                      # decode programs dispatched, and the passes of
+                      # the layer stack all dispatched programs ran
+                      # (`loop_steps` a program; 1 unless the model loops)
+                      "decode_steps": 0, "loop_passes": 0,
                       # the same for the window layers alone (a row
                       # attends over at most the window there), and the
                       # pages that held a decode step's context, by cache
@@ -601,6 +613,9 @@ class InferenceEngine:
                       "handoff_sent": 0, "handoff_acked": 0,
                       "handoff_rejected": 0, "handoff_expired": 0,
                       "handoff_installed": 0, "handoff_refused": 0}
+        # tokens by the pass their exit gate chose (a looped model's
+        # `t*`, from 1), read back with the tokens
+        self.loop_exit_hist = [0] * self.loop_steps
         # one record a step, the spans' seconds into `stats`; whether the
         # scheduler had work when the last step returned (the caller's
         # time before a step counts against it only then)
@@ -720,10 +735,15 @@ class InferenceEngine:
             what = ("kv_cache_dtype int8 or fp8 with latent pages: the "
                     "absorbed decode kernel reads bf16 / float32 rows, "
                     "and a latent row has no per-head scale")
+        elif self.loop_steps > 1 and self.kv_quant:
+            what = ("kv_cache_dtype int8 with a looped model: prefill's "
+                    "scatter of a pass's pages writes plain pools")
         if what:
+            looped = f", loop_steps={self.loop_steps}" \
+                if self.loop_steps > 1 else ""
             raise DeepSpeedConfigError(
-                f"serving a planned model (layer_plan) with {what} is "
-                f"not built")
+                f"serving a planned model (layer_plan{looped}) with {what} "
+                f"is not built")
 
     @staticmethod
     def _refuse_capacity_routing(cfg, what):
@@ -989,13 +1009,18 @@ class InferenceEngine:
 
         return sliced, layer_of
 
-    def _plan_layers(self, cfg, stacks, carry, layer_fn):
+    def _plan_layers(self, cfg, stacks, carry, layer_fn, loop_pass=0):
         """A planned model's layer loop: `layer_fn(carry, bp, spec,
         cache_layer) -> (carry, ys)` over the plan, a run of consecutive
         layers of one kind at a time (a scan where the run is longer than
         one layer). `cache_layer` is the layer's index in its cache
-        kind's pools. Returns (carry, [(spec, ys stacked over the run)])."""
-        cache_at, out = {"full": 0, "window": 0, "latent": 0}, []
+        kind's pools: pass `loop_pass` of a looped model keeps its K/V
+        behind those of the passes before it. Returns (carry, [(spec, ys
+        stacked over the run)])."""
+        cache_at = {kind: loop_pass * (cfg.cache_layers(kind) //
+                                       cfg.loop_steps)
+                    for kind in ("full", "window", "latent")}
+        out = []
         for spec, _, at, n in cfg.plan_runs():
             base = cache_at[spec.attn]
             cache_at[spec.attn] += n
@@ -1025,11 +1050,11 @@ class InferenceEngine:
         return neox.block_hidden(block_out), jnp.zeros((), jnp.float32)
 
     def _plan_token_layers(self, cfg, stacks, x, pos, pools, tables,
-                           lengths):
+                           lengths, loop_pass=0):
         """`_token_layers` of a planned model: `pools` and `tables` are
         {cache kind: (K, V) pools} and {cache kind: page table}; a window
-        layer writes and attends in the window kind's. Returns (x, pools,
-        held pairs)."""
+        layer writes and attends in the window kind's; `loop_pass` as
+        `_plan_layers` takes it. Returns (x, pools, held pairs)."""
         fam, ps = self.family, self.page_size
         B = x.shape[0]
         active = (lengths > 0)[:, None]
@@ -1083,15 +1108,51 @@ class InferenceEngine:
 
         with scopes.scope("ds.layers"):
             carry, _ = self._plan_layers(
-                cfg, stacks, (x, pools, jnp.zeros((), jnp.float32)), layer)
+                cfg, stacks, (x, pools, jnp.zeros((), jnp.float32)), layer,
+                loop_pass)
         return carry
 
-    def _with_held(self, tokens, held):
-        """A program's tokens, and behind them the count of held pairs
-        where the model holds a share of its experts."""
-        if not self._counts_held:
-            return tokens
-        return jnp.concatenate([tokens, held.astype(jnp.int32)[None]])
+    def _loop(self, cfg, params, x, state, one_pass, rows):
+        """The plan's stack `cfg.loop_steps` times over the SAME weights:
+        `one_pass(x, state, loop_pass) -> (x, state)` walks the plan once
+        (it closes over the weights: loop-invariant operands, held once
+        whatever the passes, never stacked a pass), `state` what it
+        carries beside the hidden states (the page pools: carried,
+        aliased state, as `_token_layers` says why), `rows(x) -> [B, h]`
+        the rows the head reads. The final norm follows EVERY pass of a
+        looped model and its output is the next pass's input; the head
+        reads the pass the exit gate names (`neox.loop_exit`). Returns
+        (the head's input [B, h], state, each row's exit pass [B] or
+        None where the model does not loop)."""
+        fam = self.family
+        if cfg.loop_steps == 1:
+            x, state = one_pass(x, state, 0)
+            return fam.final_norm(params, rows(x)), state, None
+
+        def body(carry, loop_pass):
+            with scopes.scope("ds.loop"):
+                x, state = one_pass(*carry, loop_pass)
+                with scopes.scope("ds.loop_exit"):
+                    x = fam.final_norm(params, x)
+                return (x, state), rows(x)
+
+        (_, state), passes = jax.lax.scan(
+            body, (x, state), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
+        h, exit_pass = neox.loop_exit(cfg, params, passes)
+        return h, state, exit_pass
+
+    def _with_held(self, tokens, held, exit_pass=None):
+        """A program's tokens, and behind them each row's exit pass where
+        the model loops (padded to the tokens' length), then the count of
+        held pairs where the model holds a share of its experts: one
+        array, one read-back."""
+        parts = [tokens]
+        if exit_pass is not None:
+            parts.append(jnp.pad(
+                exit_pass, (0, tokens.shape[0] - exit_pass.shape[0])))
+        if self._counts_held:
+            parts.append(held.astype(jnp.int32)[None])
+        return jnp.concatenate(parts) if len(parts) > 1 else tokens
 
     def _kind_pools(self, k_pool, v_pool):
         """{cache kind: (K, V)} of the programs' pool arguments (a pair of
@@ -1158,12 +1219,34 @@ class InferenceEngine:
         n_pages_row = seqlen // ps
         cos_sin = fam.cos_sin_prefill(seqlen)
 
+        def last_rows(x, lengths):
+            """[B, h]: each row's last real position of x [B, S, h]."""
+            B, S = x.shape[:2]
+            return x[jnp.arange(B), jnp.clip(lengths - 1, 0, S - 1)]
+
+        def attention(q, k, v, segment_ids, window=None):
+            """`_block_core`'s attention of a prefill bucket. A bucket the
+            flash forward does not take as it is (fewer rows than its
+            128-row block: a 64-token bucket) is padded up to whole blocks
+            for the attention alone and its real rows given back: the pad
+            keys lie behind every real query and are segment 0, so a real
+            row sums over its own keys; the projections and the MLP run
+            the bucket's rows."""
+            B, S, H, D = q.shape
+            pad = whole_blocks(S) - S
+            if use_pallas and pad and \
+                    not flash_attention_supported(q.shape) and \
+                    flash_attention_supported((B, S + pad, H, D)):
+                q, k, v, segment_ids = (
+                    jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                    for t in (q, k, v, segment_ids))
+            return neox.causal_attention(
+                q, k, v, use_pallas=use_pallas, segment_ids=segment_ids,
+                window=window)[:, :S]
+
         def first_token(params, x, lengths, rng):
             """The token sampled at each row's last real position."""
-            B, S = x.shape[:2]
-            idx = jnp.clip(lengths - 1, 0, S - 1)
-            h_last = x[jnp.arange(B), idx][:, None, :]
-            h_last = fam.final_norm(params, h_last)
+            h_last = fam.final_norm(params, last_rows(x, lengths)[:, None, :])
             return self._sample(fam.head(params, h_last[:, 0]), rng)
 
         def page_tiles(new, heads, head_dim):
@@ -1190,7 +1273,7 @@ class InferenceEngine:
                 y, kv = neox._block_core(
                     cfg, block_of(xs), carry, cos_sin, use_pallas, mp=1,
                     reduce_fn=lambda t: t, return_kv=True,
-                    segment_ids=seg)
+                    attn_fn=attention, segment_ids=seg)
                 return neox.block_hidden(y), kv
 
             with scopes.scope("ds.layers"):
@@ -1240,21 +1323,21 @@ class InferenceEngine:
                 y, kv = neox._block_core(
                     cfg, bp, x, rot[spec.attn], use_pallas, mp=1,
                     reduce_fn=lambda t: t, return_kv=True,
+                    attn_fn=partial(
+                        attention, window=cfg.attn_window
+                        if spec.attn == "window" else None),
                     segment_ids=seg, spec=spec)
                 out, rows = self._held_rows(cfg, y)
                 return (out, held + rows), kv
 
-            with scopes.scope("ds.layers"):
-                (x, held), runs = self._plan_layers(
-                    cfg, stacks, (x, jnp.zeros((), jnp.float32)), layer)
-
             G, D = cfg.kv_heads, cfg.head_dim
 
-            def scatter(kind, pool, new):
-                """One pool of cache kind `kind` with its layers' new rows
-                [L_kind, B, S, ...] written as whole pages: K or V rows
-                [G, D] a token as [G, ps, D] tiles, a latent layer's
-                rows [width] as [ps, row], padded to the pool's row."""
+            def scatter(kind, pool, new, loop_pass):
+                """One pool of cache kind `kind` with a pass's new rows
+                [L_kind, B, S, ...] written as whole pages into that
+                pass's cache layers: K or V rows [G, D] a token as
+                [G, ps, D] tiles, a latent layer's rows [width] as
+                [ps, row], padded to the pool's row."""
                 flat_pt = tables[kind].reshape(-1)
 
                 def tiles(rows):
@@ -1264,20 +1347,36 @@ class InferenceEngine:
                         0, pool.shape[-1] - rows.shape[-1])))
                     return rows.reshape(B * n_pages_row, ps, -1)
 
-                return jax.vmap(lambda p, rows: p.at[flat_pt].set(
-                    tiles(rows).astype(p.dtype)))(pool, new)
+                new = jax.vmap(tiles)(new).astype(pool.dtype)
+                n = new.shape[0]
+                layers = loop_pass * n + jnp.arange(n, dtype=jnp.int32)
+                return pool.at[layers[:, None], flat_pt[None, :]].set(new)
 
-            with scopes.scope("ds.kv_write"):
-                for kind, kind_pools in list(pools.items()):
-                    # the kind's layers in order: [L_kind, B, S, ...]
-                    of_kind = [kv for spec, kv in runs if spec.attn == kind]
-                    pools[kind] = tuple(
-                        scatter(kind, pool,
-                                jnp.concatenate([kv[i] for kv in of_kind]))
-                        for i, pool in enumerate(kind_pools))
+            def one_pass(x, state, loop_pass):
+                """The plan once over x [B, S, h], the pass's K/V into
+                its own cache layers of the pools."""
+                pools, held = state
+                with scopes.scope("ds.layers"):
+                    (x, held), runs = self._plan_layers(
+                        cfg, stacks, (x, held), layer, loop_pass)
+                with scopes.scope("ds.kv_write"):
+                    pools = dict(pools)
+                    for kind, kind_pools in list(pools.items()):
+                        # the kind's layers in order: [L_kind, B, S, ...]
+                        of_kind = [kv for spec, kv in runs
+                                   if spec.attn == kind]
+                        pools[kind] = tuple(
+                            scatter(kind, pool, jnp.concatenate(
+                                [kv[i] for kv in of_kind]), loop_pass)
+                            for i, pool in enumerate(kind_pools))
+                return x, (pools, held)
 
-            return (self._with_held(first_token(params, x, lengths, rng),
-                                    held), *self._pool_args(pools))
+            h, (pools, held), exit_pass = self._loop(
+                cfg, params, x, (pools, jnp.zeros((), jnp.float32)),
+                one_pass, lambda x: last_rows(x, lengths))
+            nxt = self._sample(fam.head(params, h), rng)
+            return (self._with_held(nxt, held, exit_pass),
+                    *self._pool_args(pools))
 
         fn = jax.jit(planned_prefill if self.planned else prefill,
                      donate_argnums=(5, 6))
@@ -1326,13 +1425,22 @@ class InferenceEngine:
                                tokens)
             pos = jnp.maximum(lengths - 1, 0)
             x = fam.embed_decode(params, tokens, pos)
-            x, pools, held = self._plan_token_layers(
-                cfg, stacks, x, pos, self._kind_pools(k_pool, v_pool),
-                self._kind_tables(page_table), lengths)
-            h = fam.final_norm(params, x)
-            logits = fam.head(params, h[:, 0])
-            nxt = jnp.pad(self._sample(logits, rng), (0, width - batch))
-            return (self._with_held(nxt, held), *self._pool_args(pools))
+            tables = self._kind_tables(page_table)
+
+            def one_pass(x, state, loop_pass):
+                pools, held = state
+                x, pools, rows = self._plan_token_layers(
+                    cfg, stacks, x, pos, pools, tables, lengths, loop_pass)
+                return x, (pools, held + rows)
+
+            h, (pools, held), exit_pass = self._loop(
+                cfg, params, x, (self._kind_pools(k_pool, v_pool),
+                                 jnp.zeros((), jnp.float32)),
+                one_pass, lambda x: x[:, 0])
+            nxt = jnp.pad(self._sample(fam.head(params, h), rng),
+                          (0, width - batch))
+            return (self._with_held(nxt, held, exit_pass),
+                    *self._pool_args(pools))
 
         fn = jax.jit(planned_decode if self.planned else decode,
                      donate_argnums=(5, 6))
@@ -2353,6 +2461,7 @@ class InferenceEngine:
                 lengths[i] = req.cached + req.pending + 1
                 page_table[i, :len(req.pages)] = req.pages
                 window_table[i, :len(req.window_pages)] = req.window_pages
+            self.stats["decode_steps"] += 1
             self.stats["decode_kv_tokens"] += int(lengths.sum())
             self.stats["kv_page_steps_latent" if self.latent
                        else "kv_page_steps_full"] += int(
@@ -2381,8 +2490,11 @@ class InferenceEngine:
         return self._enqueued("decode", plan.decodes, nxt)
 
     def _zero_carry(self):
+        # a decode program's output (`_with_held`): the tokens, a looped
+        # model's exit passes behind them, the held count
         return jnp.asarray(np.zeros(
-            (self._carry_width + int(self._counts_held),), np.int32))
+            (self._carry_width * (2 if self.loop_steps > 1 else 1) +
+             int(self._counts_held),), np.int32))
 
     def _pools(self):
         """(k_pool, v_pool) as the programs take them: the full kind's
@@ -2406,6 +2518,7 @@ class InferenceEngine:
 
     def _enqueued(self, phase, reqs, tokens):
         rec = _InFlight(next(self._dispatched), phase, list(reqs), tokens)
+        self.stats["loop_passes"] += self.loop_steps
         for req in reqs:
             req.owed.append(rec.serial)
         self._inflight.append(rec)
@@ -2438,6 +2551,13 @@ class InferenceEngine:
                     # the program's count of pairs on a held expert rides
                     # behind its tokens: the same read-back
                     self.stats["moe_rows_held"] += int(nxt[-1])
+                    nxt = nxt[:-1]
+                if self.loop_steps > 1:
+                    # and so does each row's exit pass, behind the tokens
+                    exits = nxt[len(nxt) // 2:]
+                    for i, _, live in rec.rows():
+                        if live:
+                            self.loop_exit_hist[int(exits[i]) - 1] += 1
                 with self._phase("complete"):
                     if rec.phase == "prefill":
                         self._complete_prefills(rec, nxt, now)
@@ -2812,8 +2932,15 @@ class InferenceEngine:
         if self.spec_k:
             out["spec_acceptance_rate"] = self.stats["spec_accepted"] / \
                 max(self.stats["spec_proposed"], 1)
+        # the page pools' bytes a cached token occupies, every cache
+        # layer (a looped model's: `loop_steps` a layer)
+        out["kv_bytes_per_token"] = self.cache.bytes_per_token() + (
+            self.window_cache.bytes_per_token() if self.window_cache else 0)
         total = out["prefill_tokens"] + out["decode_tokens"]
         if self.monitor is not None:
             self.monitor.record(
                 total, {f"Serve/{k}": float(v) for k, v in out.items()})
+        if self.loop_steps > 1:
+            # tokens by the pass their exit gate chose (entry 0: pass 1)
+            out["loop_exit_hist"] = list(self.loop_exit_hist)
         return out

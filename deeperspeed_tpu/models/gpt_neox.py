@@ -193,6 +193,22 @@ class GPTNeoXConfig:
     # drafter's; serving does not load it.
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.0
+    # an RMS norm on each sublayer's OUTPUT as well as its input, before
+    # the residual add ("sandwich" norms: two more scale leaves a layer,
+    # `ln_attn_out` and `ln_mlp_out`),
+    sublayer_out_norm: bool = False
+    # a LOOPED model: the whole stack of layers applied `loop_steps`
+    # times over the SAME weights, the final norm after every pass (its
+    # output is the next pass's input), every pass with a KV cache of its
+    # own: pass t of layer l keeps cache layer t * num_layers + l. An
+    # exit gate (`loop_exit`: one output a token, sigmoid) gives each
+    # pass the probability p_t = g_t prod_{j<t}(1 - g_j) of being the
+    # last; the head reads the first pass whose cumulative probability
+    # reaches `loop_exit_threshold`, else the last (`loop_exit`). Every
+    # pass is computed whatever the gate says: pass t of a later token
+    # attends to pass t of this one.
+    loop_steps: int = 1
+    loop_exit_threshold: float = 1.0
 
     @property
     def head_dim(self):
@@ -244,12 +260,13 @@ class GPTNeoXConfig:
         return [tuple(r) for r in runs]
 
     def cache_layers(self, attn):
-        """How many layers keep a KV cache of kind `attn`
-        ("full" | "window" | "latent"); a homogeneous model's are all
-        "full"."""
+        """How many cache layers of kind `attn` ("full" | "window" |
+        "latent") the model keeps: one a layer of that kind and pass of
+        the loop (`loop_steps`); a homogeneous model's are all "full"."""
         if not self.layer_plan:
             return self.num_layers if attn == "full" else 0
-        return sum(1 for s in self.layer_plan if s.attn == attn)
+        return self.loop_steps * sum(1 for s in self.layer_plan
+                                     if s.attn == attn)
 
     def _planned_params(self, held):
         """Parameters of a planned model by layer kind; `held` counts the
@@ -258,6 +275,9 @@ class GPTNeoXConfig:
         E = self.experts_held if held else self.moe_num_experts
         total = self.vocab_size * h * \
             (1 if self.tie_word_embeddings else 2) + h
+        if self.loop_steps > 1:
+            total += h + 1              # the exit gate; the loop's weights once
+
         def layer(spec):
             if spec.attn == "latent":
                 attn = self._latent_params(spec.heads)
@@ -272,7 +292,7 @@ class GPTNeoXConfig:
                     3 * h * (E * self.expert_width + self.moe_shared_width)
                 if self.moe_router_score == "sigmoid":
                     ffn += self.moe_num_experts     # the correction bias
-            return attn + ffn + 2 * h
+            return attn + ffn + (4 if self.sublayer_out_norm else 2) * h
 
         total += sum(layer(spec) for spec in self.layer_plan)
         if self.mtp_layers:
@@ -364,7 +384,9 @@ class GPTNeoXConfig:
              ("moe_shared_width", 0), ("moe_routing_scale", 1.0),
              ("moe_held", ()), ("moe_router_score", "softmax"),
              ("mla_q_rank", 0), ("mla_kv_rank", 0), ("mla_nope_dim", 0),
-             ("mla_rope_dim", 0), ("mla_v_dim", 0), ("mtp_layers", 0))
+             ("mla_rope_dim", 0), ("mla_v_dim", 0), ("mtp_layers", 0),
+             ("sublayer_out_norm", False), ("loop_steps", 1),
+             ("loop_exit_threshold", 1.0))
             if getattr(self, k) != plain]
         if self.moe_router_score not in ("softmax", "sigmoid"):
             raise NotImplementedError(
@@ -395,8 +417,9 @@ class GPTNeoXConfig:
                     f"homogeneous block has one KV head a query head of "
                     f"hidden_size / num_heads features, full attention, "
                     f"no gate, a softmax router, no shared expert, every "
-                    f"expert held, no latent attention and no "
-                    f"next-token-prediction block")
+                    f"expert held, no latent attention, no "
+                    f"next-token-prediction block, no norm on a "
+                    f"sublayer's output and no loop")
             return
         if len(plan) != self.num_layers:
             raise ValueError(f"layer_plan names {len(plan)} layers, "
@@ -439,12 +462,35 @@ class GPTNeoXConfig:
                     f"layer {i}: a planned model's experts are routed "
                     f"without capacity (moe_dropless); the GShard capacity "
                     f"router is not told which experts are held")
+        self._check_loop()
         if self.moe_held:
             lo, hi = self.moe_held
             if not 0 <= lo < hi <= self.moe_num_experts:
                 raise ValueError(
                     f"moe_held {self.moe_held} is not a range of the "
                     f"router's {self.moe_num_experts} experts")
+
+    def _check_loop(self):
+        """A looped model's facts, and the norm on a sublayer's output."""
+        if self.loop_steps < 1 or not 0.0 < self.loop_exit_threshold <= 1.0:
+            raise ValueError(
+                f"loop_steps={self.loop_steps}, loop_exit_threshold="
+                f"{self.loop_exit_threshold}: at least one pass, and a "
+                f"threshold on a cumulative probability in (0, 1]")
+        if self.loop_steps == 1 and self.loop_exit_threshold != 1.0:
+            raise ValueError(
+                f"loop_exit_threshold={self.loop_exit_threshold} with "
+                f"loop_steps=1: one pass has no exit gate")
+        if self.loop_steps > 1 and self.mtp_layers:
+            raise NotImplementedError(
+                f"loop_steps={self.loop_steps} with mtp_layers="
+                f"{self.mtp_layers}: which pass's hidden state a "
+                f"next-token-prediction block reads is not computed")
+        if self.sublayer_out_norm and any(s.ffn != "dense"
+                                          for s in self.layer_plan):
+            raise NotImplementedError(
+                "sublayer_out_norm with an experts layer: the norm on a "
+                "sublayer's output is computed for the dense gated MLP")
 
     def _check_latent(self, i, spec):
         """A latent layer's facts: all five dims, an even rotary part, a
@@ -592,7 +638,9 @@ def init_stack_params(cfg, spec, n, key):
     non-zero at a quarter of the spread of the scores, so that a bias
     that is dropped, or leaks into the weights, shows), `w_in` [E held, h, 2w],
     `w_out` [E held, w, h], and a shared expert's `shared_in` [h, 2s],
-    `shared_out` [s, h]."""
+    `shared_out` [s, h]. Norms: `ln_attn`, `ln_mlp` on the sublayers'
+    inputs, and with `sublayer_out_norm` `ln_attn_out`, `ln_mlp_out` on
+    their outputs."""
     h, d, dt = cfg.hidden_size, cfg.head_dim, cfg.param_dtype
     H, G = spec.heads, cfg.kv_heads
     out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
@@ -643,8 +691,10 @@ def init_stack_params(cfg, spec, n, key):
             mlp["shared_out"] = _stack_init(ks[8], (n,), (sw, h), dt,
                                             out_scale)
     ones = jnp.ones((n, h), dt)
-    return {"ln_attn": {"scale": ones}, "ln_mlp": {"scale": ones},
-            "attn": attn, "mlp": mlp}
+    norms = {"ln_attn": {"scale": ones}, "ln_mlp": {"scale": ones}}
+    if cfg.sublayer_out_norm:
+        norms.update(ln_attn_out={"scale": ones}, ln_mlp_out={"scale": ones})
+    return dict(norms, attn=attn, mlp=mlp)
 
 
 def init_mtp_params(cfg, key):
@@ -683,6 +733,12 @@ def init_params(cfg, rng):
         }
         if cfg.mtp_layers:
             params["mtp"] = init_mtp_params(cfg, jax.random.fold_in(rng, 1))
+        if cfg.loop_steps > 1:
+            # the exit gate, one output a token: a weight row and a bias
+            params["loop_exit"] = {
+                "w": _dense_init(jax.random.fold_in(rng, 2),
+                                 (cfg.hidden_size,), dt),
+                "b": jnp.zeros((1,), dt)}
         return params
     params = {
         "embed": {"wte": _dense_init(keys[0], (cfg.vocab_size,
@@ -1083,6 +1139,9 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
         ln2_in = x
     else:
         attn_out = reduce_fn(attn_partial) + out_b
+        if "ln_attn_out" in params:      # a norm on the sublayer's output
+            with scopes.scope("ds.attn"):
+                attn_out = norm(cfg, params["ln_attn_out"], attn_out)
         ln2_in = x + attn_out
     with scopes.scope("ds.mlp"):
         ln2 = norm(cfg, params["ln_mlp"], ln2_in)
@@ -1163,6 +1222,8 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
         if getattr(cfg, "layer_plan", ()):       # a planned dense FFN is gated
             mlp_partial = _gated_mlp(ln2, params["mlp"]["in_w"],
                                      params["mlp"]["out_w"], act)
+            if "ln_mlp_out" in params:
+                mlp_partial = norm(cfg, params["ln_mlp_out"], mlp_partial)
         else:
             hmid = act(_plus_bias(_wmat(ln2, params["mlp"]["in_w"]),
                                   params["mlp"], "in_b"))
@@ -1569,13 +1630,57 @@ def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids,
         from ..runtime.packing import segment_relative_positions
         pos = segment_relative_positions(segment_ids)
         rotary = {k: (c[pos], s_[pos], r) for k, (c, s_, r) in rotary.items()}
-    with scopes.scope("ds.layers"):
-        for spec, bp in plan_layer_params(cfg, params["stacks"]):
-            x = block_hidden(_block_core(
-                cfg, bp, x, rotary[spec.attn], use_pallas, mp=1,
-                reduce_fn=lambda t: t, segment_ids=segment_ids, spec=spec))
+    layers = plan_layer_params(cfg, params["stacks"])
+
+    def stack(x):
+        with scopes.scope("ds.layers"):
+            for spec, bp in layers:
+                x = block_hidden(_block_core(
+                    cfg, bp, x, rotary[spec.attn], use_pallas, mp=1,
+                    reduce_fn=lambda t: t, segment_ids=segment_ids,
+                    spec=spec))
+        return x
+
+    if cfg.loop_steps > 1:
+        # the same layers `loop_steps` times, the final norm after every
+        # pass; the head reads the pass the exit gate names
+        passes = []
+        for _ in range(cfg.loop_steps):
+            with scopes.scope("ds.loop"):
+                x = stack(x)
+                with scopes.scope("ds.loop_exit"):
+                    x = norm(cfg, params["final_ln"], x)
+            passes.append(x)
+        out = loop_exit(cfg, params, jnp.stack(passes))[0]
+        # no next-token-prediction block reads a looped stack's hidden
+        # states (`_check_loop`)
+        return (out, None) if with_last else out
+    x = stack(x)
     out = norm(cfg, params["final_ln"], x)
     return (out, x) if with_last else out
+
+
+@scopes.scoped("ds.loop_exit")
+def loop_exit(cfg, params, passes):
+    """The exit gate of a looped model on `passes` [T, ..., h], every
+    pass's final-norm hidden state: g_t = sigmoid(z_t . w + b) in
+    float32; pass t < T is the last with probability p_t = g_t prod_{j<t}
+    (1 - g_j), and T with what is left; the head reads t* = the first
+    pass whose cumulative probability c_t = p_1 + ... + p_t reaches
+    `cfg.loop_exit_threshold`, else T. Returns (z_{t*} [..., h], t*
+    [...], from 1). At threshold 1 t* is T unless a gate saturates."""
+    gate = params["loop_exit"]
+    g = jax.nn.sigmoid(
+        jnp.einsum("t...h,h->t...", passes[:-1].astype(jnp.float32),
+                   gate["w"].astype(jnp.float32)) +
+        gate["b"].astype(jnp.float32)[0])
+    stay = jnp.cumprod(1.0 - g, axis=0)
+    p = g * jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    short = jnp.cumsum(p, axis=0) < cfg.loop_exit_threshold
+    t_star = 1 + jnp.sum(short, axis=0, dtype=jnp.int32)
+    chosen = jnp.take_along_axis(passes, (t_star - 1)[None, ..., None],
+                                 axis=0)[0]
+    return chosen, t_star
 
 
 def mtp_hidden(cfg, params, tokens, last, use_pallas=True):
@@ -1858,7 +1963,8 @@ class GPTNeoX:
             raise DeepSpeedConfigError(
                 f"{what}: training of a planned model (layer_plan: window "
                 f"layers, grouped KV heads, an attention gate, a shared "
-                f"expert, a held share of the experts, latent attention) "
+                f"expert, a held share of the experts, latent attention, "
+                f"a stack looped over its weights) "
                 f"is not built; the flash backward, the parameter specs "
                 f"and the pipeline layers are the homogeneous block's. "
                 f"InferenceEngine serves it (`loss_fn` alone computes a "
@@ -2653,6 +2759,12 @@ def generate(cfg, params, prompt, max_new_tokens, temperature=0.0,
     prompt [B, S_p] int32 → generated tokens [B, max_new_tokens].
     """
     B, S_p = prompt.shape
+    if cfg.layer_plan:
+        raise NotImplementedError(
+            f"generate: this cache is the homogeneous block's, one K and "
+            f"V a layer; a planned model (layer_plan; loop_steps="
+            f"{cfg.loop_steps}: a cache a pass) is served by "
+            f"InferenceEngine")
     if max_new_tokens <= 0:
         return jnp.zeros((B, 0), jnp.int32)
     s_max = S_p + max_new_tokens

@@ -36,6 +36,12 @@ def fit_block(block, s):
     return 0
 
 
+def whole_blocks(s):
+    """`s` rounded up to the least length `fit_block` fits: whole 128-row
+    blocks."""
+    return -(-s // 128) * 128
+
+
 # ---------------------------------------------------------------------------
 # flash attention (ops/pallas/flash_attention.py)
 # ---------------------------------------------------------------------------

@@ -99,6 +99,13 @@ SCOPES = {
                               "kernel of this name"),
     "ds.sample": ("region", "sampling the next token from the logits"),
     "ds.layers": ("container", "the loop or scan over the blocks"),
+    "ds.loop": ("container", "one pass of a looped model's stack: what is "
+                             "in no inner scope is the loop's own "
+                             "overhead (slicing, carried-state copies)"),
+    "ds.loop_exit": ("region", "a looped model between passes and after "
+                               "them: the final norm of a pass, the exit "
+                               "gate, the choice of the pass the head "
+                               "reads"),
 }
 
 

@@ -832,6 +832,84 @@ def test_latent_serving_programs_compile_and_leave_the_pool_in_place(
 
 
 # ---------------------------------------------------------------------------
+# a looped model (Ouro-2.6B) at its published widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("program,seqlen", [
+    ("decode", 256), ("prefill", 256), ("prefill", 64)])
+def test_looped_serving_programs_compile_and_carry_the_pools(
+        on_chip, v5e_2x2, program, seqlen):
+    """The engine's decode and prefill programs for Ouro's block at the
+    published widths (hidden 2048, 16 heads of 128, a gated MLP of width
+    5,632, a norm on each sublayer's output; three layers and a small
+    vocabulary) run 4 times over the same weights, compiled for the
+    described v5e from shapes alone: the paged kernel and the row write
+    (prefill: the flash forward) are there ONCE, in the body of the pass
+    loop, the pool has 12 cache layers, and no instruction of the decode
+    step but the row write produces an array of the pool's shape: the
+    pools ride the pass loop and the layer scan as carried state. A
+    64-token prefill bucket, half the flash forward's least block, still
+    runs the kernel (the engine pads its attention up to one block)."""
+    from jax.sharding import SingleDeviceSharding
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
+                                                 LayerSpec)
+    batch, page_size, layers, passes = 16, 64, 3, 4
+    cfg = GPTNeoXConfig(
+        vocab_size=1024, hidden_size=2048, num_layers=layers, num_heads=16,
+        num_kv_heads=16, max_seq_len=640, use_parallel_residual=False,
+        norm="rmsnorm", use_bias=False, hidden_act="silu", ffn_gated=True,
+        ffn_width=5632, layernorm_eps=1e-6, attn_head_dim=128,
+        layer_plan=(LayerSpec(attn="full", heads=16, rotary_pct=1.0,
+                              rotary_base=1e6, ffn="dense"),) * layers,
+        sublayer_out_norm=True, loop_steps=passes)
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, BF16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page_size, "num_pages": 81,
+        "max_seq_len": 640, "max_batch_size": batch, "token_budget": 272,
+        "prefill_lengths": [seqlen], "prefill_batch_sizes": [1],
+        "decode_batch_sizes": [batch]}})
+    pool = engine.cache.k
+    assert pool.shape == (passes * layers, 81, 16, page_size, 128)
+    assert engine.params_stacked is engine.params["stacks"]
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    shapes = functools.partial(jax.tree_util.tree_map, shape_of)
+    carry = ()
+    if program == "decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch), ints(batch), ints(batch, engine.n_pages_max))
+        # the tokens and, behind them, each row's exit pass
+        carry = (ints(2 * batch), ints(batch))
+        kernels = ("ds.paged_decode", "ds.kv_write")
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+        kernels = ("ds.flash_fwd",)
+    text = fn.lower(
+        shapes(engine.params), shapes(engine.params_stacked), *inputs,
+        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        *carry).compile().as_text()
+    for name in kernels:
+        calls = re.findall(rf"%{name}[.\d]* = .*tpu_custom_call", text)
+        assert len(calls) == 1, (name, len(calls))
+    assert "ds.loop/ds.layers" in text and "ds.loop_exit" in text
+    assert "ds.attn_xla" not in text
+    if program == "decode":
+        assert not pool_shaped_moves(text, pool.shape)
+
+
+# ---------------------------------------------------------------------------
 # grouped matmul, int8 weight matmul, fused Adam
 # ---------------------------------------------------------------------------
 
