@@ -1007,6 +1007,27 @@ def _wmat(x, w):
     return x @ w.astype(x.dtype)
 
 
+def _heads_dot(x, w):
+    """`_wmat(x, w)` for a result `[B, S, out]` that its caller reshapes
+    to heads. XLA folds that reshape into the dot and re-lays out the
+    weight `[k, out]` for it: right under many rows, wrong for a decode
+    step's few (the layer's weight copied on chip every layer; a
+    loop-invariant stack copied whole, every step). Where the shapes say
+    the result is the small operand (`autotune.head_projection_plain`)
+    a barrier keeps the reshape off the dot, which then reads the weight
+    where it lies. Same operands, same arithmetic, in both forms; a
+    `QuantizedWeight`'s matmul is a kernel no reshape enters."""
+    from ..ops.autotune import head_projection_plain
+    from ..ops.pallas.flash_attention import _HEAD_PROJECTIONS
+    from ..ops.pallas.quant_matmul import QuantizedWeight
+    y = _wmat(x, w)
+    if isinstance(w, QuantizedWeight):
+        return y
+    plain = head_projection_plain(math.prod(x.shape[:-1]), x.shape[-1])
+    _HEAD_PROJECTIONS["plain" if plain else "folded"] += 1
+    return jax.lax.optimization_barrier(y) if plain else y
+
+
 def _gated_mlp(x, w_in, w_out, act):
     """(act(x Wgate) * (x Wup)) Wdown with `w_in` = [Wgate | Wup]."""
     hmid = _wmat(x, w_in)
@@ -1023,13 +1044,13 @@ def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
         # a planned block: `nh_local` query heads over the model's KV
         # heads, [K | V] fused, head dim a fact of the model
         d, G = cfg.head_dim, cfg.kv_heads
-        q = _wmat(ln1, params["attn"]["q_w"]).reshape(B, S, nh_local, d)
-        kv = _wmat(ln1, params["attn"]["kv_w"]).reshape(B, S, 2, G, d)
+        q = _heads_dot(ln1, params["attn"]["q_w"]).reshape(B, S, nh_local, d)
+        kv = _heads_dot(ln1, params["attn"]["kv_w"]).reshape(B, S, 2, G, d)
         k, v = kv[:, :, 0], kv[:, :, 1]
         q, k = apply_rotary(q, k, cos, sin, rot_dim)
         return q, k, v
-    qkv = _plus_bias(_wmat(ln1, params["attn"]["qkv_w"]), params["attn"],
-                     "qkv_b")
+    qkv = _plus_bias(_heads_dot(ln1, params["attn"]["qkv_w"]),
+                     params["attn"], "qkv_b")
     qkv = qkv.reshape(B, S, nh_local, 3 * cfg.head_dim)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     if getattr(cfg, "qk_norm", False):

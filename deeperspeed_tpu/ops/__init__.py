@@ -24,7 +24,12 @@ def dispatch_report():
     {"fwd" / "bwd" / "dkv" / "dq": (times the kernel's body was built in
     this process, host seconds that took): set-up every run pays, compile
     cache or not}}; ``attention``: {"attention" / "sparse_attention":
-    backend} of the model-side dispatchers; ``decode_attention``:
+    backend} of the model-side dispatchers, and "head_projection":
+    {"plain": n, "folded": n}, the attention projections traced in this
+    process by the form their reshape to heads took (plain: kept out of
+    the dot, the weight read where it lies, every decode step's;
+    folded: XLA's to place, a train step's; `gpt_neox._heads_dot`);
+    ``decode_attention``:
     {"decode": backend, "decode_kv": pool dtype — "int8" when the paged
     pools are quantized, "kv_write": backend of the one-token row
     write, "kv_write_slots": the slots of a page that kernel read and
@@ -38,14 +43,16 @@ def dispatch_report():
     """
     from .pallas.decode_attention import _LAST_BACKEND
     from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
-    from .pallas.flash_attention import (_BODY_BUILDS, _LAST_BLOCKS,
-                                         _LAST_MASKED, _XLA_NOTED)
+    from .pallas.flash_attention import (_BODY_BUILDS, _HEAD_PROJECTIONS,
+                                         _LAST_BLOCKS, _LAST_MASKED,
+                                         _XLA_NOTED)
     from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
     from .pallas.quant_matmul import _LAST_BACKEND as _QMM_BACKEND
     return {"flash": dict(_LAST_BLOCKS, masked_tiles=dict(_LAST_MASKED),
                           bodies_built={k: (n, round(t, 3)) for k, (n, t)
                                         in _BODY_BUILDS.items()}),
-            "attention": dict(_ATTN_BACKEND),
+            "attention": dict(_ATTN_BACKEND,
+                              head_projection=dict(_HEAD_PROJECTIONS)),
             "decode_attention": dict(_LAST_BACKEND),
             "quant_matmul": dict(_QMM_BACKEND),
             "grouped_matmul": dict(_GMM_BACKEND),

@@ -12,7 +12,8 @@ A kernel's public wrapper calls its function here when the caller passes
 no blocks; a caller passes blocks only to pin them (a test, a plan).
 
 The module also keeps the compile-time memory screen
-(`compiled_memory_stats`, `memory_feasible`, `hbm_bytes_limit`).
+(`compiled_memory_stats`, `memory_feasible`, `hbm_bytes_limit`) and the
+one shape rule of a dot that is XLA's (`head_projection_plain`).
 """
 
 import os
@@ -40,6 +41,25 @@ def whole_blocks(s):
     """`s` rounded up to the least length `fit_block` fits: whole 128-row
     blocks."""
     return -(-s // 128) * 128
+
+
+# ---------------------------------------------------------------------------
+# attention's projection to heads (models/gpt_neox.py::_heads_dot)
+# ---------------------------------------------------------------------------
+
+def head_projection_plain(rows, k):
+    """Does `x[rows, k] @ w[k, out]`, whose result is reshaped to heads,
+    keep that reshape out of the dot? XLA folds it in (a convolution
+    over the heads) and pays with a re-layout of the WEIGHT, `k x out`;
+    kept out, it is a re-layout of the RESULT, `rows x out`: the plain
+    form wins where the result is the smaller of the two. A fact of the
+    two shapes: every decode step is plain (16-256 rows under a hidden
+    size of 1,024 and up) and so is a prefill bucket under the hidden
+    size; a train step's 16 x 2048 rows are XLA's to fold. Measured on a
+    v5e at k = 2048, out = 6144, 24 layers (PERF.md section 6, PR 40):
+    a prefill program is 0.80 ms shorter plain at 128 rows, 0.14 ms at
+    1,536, 0.16 ms LONGER at 2,048."""
+    return rows < k
 
 
 # ---------------------------------------------------------------------------

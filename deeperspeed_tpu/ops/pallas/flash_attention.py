@@ -73,6 +73,9 @@ def _interpret():
 # `ops.dispatch_report()` reads them all.
 _LAST_BACKEND = {}
 _XLA_NOTED = set()
+# Attention projections traced in this process by the form their reshape
+# to heads took (`models/gpt_neox.py::_heads_dot`).
+_HEAD_PROJECTIONS = {"plain": 0, "folded": 0}
 
 
 def note_xla_on_tpu(op, why):

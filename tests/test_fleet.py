@@ -754,6 +754,30 @@ class TestDispatchReport:
                                "quant_matmul", "grouped_matmul",
                                "xla_on_tpu"}
         assert isinstance(report["flash"], dict)
+        # the attention projections traced so far, by their form
+        assert set(report["attention"]["head_projection"]) == \
+            {"plain", "folded"}
+
+    @pytest.mark.parametrize("rows,form", [(4, "plain"), (64, "folded")])
+    def test_head_projection_counts_the_form_of_each_trace(self, rows,
+                                                           form):
+        """A projection to heads is counted when it is traced, under
+        the form its shapes gave it (rows of the activation against the
+        weight's contracting dim, `autotune.head_projection_plain`); a
+        copy of the report does not move with the record."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeperspeed_tpu.models.gpt_neox import _heads_dot
+        from deeperspeed_tpu.ops import dispatch_report
+        before = dispatch_report()["attention"]["head_projection"]
+        x = jax.ShapeDtypeStruct((rows, 1, 16), jnp.float32)
+        w = jax.ShapeDtypeStruct((16, 48), jnp.float32)
+        assert jax.eval_shape(_heads_dot, x, w).shape == (rows, 1, 48)
+        after = dispatch_report()["attention"]["head_projection"]
+        other = {"plain": "folded", "folded": "plain"}[form]
+        assert after[form] == before[form] + 1
+        assert after[other] == before[other]
 
     @pytest.mark.parametrize("head_dim,want", [(64, "pallas"), (96, "xla")])
     def test_attention_records_backend_and_names_xla_on_a_tpu(

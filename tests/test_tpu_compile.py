@@ -428,6 +428,29 @@ INSTRUCTION = re.compile(
 CARRIES = ("parameter", "tuple", "get-tuple-element", "bitcast", "while")
 
 
+ATTN_WEIGHT = re.compile(r"bf16\[(?:\d+,)?2048,(?:6144|4096|2048)\]")
+
+
+def attention_weight_relayouts(text):
+    """What the reshape to heads costs a program when XLA folds it into
+    the projection's dot (`gpt_neox._heads_dot`): every `copy` whose
+    result has the shape of a layer's attention weight or of a stack of
+    them (hidden 2048: `qkv_w` [.., 2048, 6144], `kv_w` [.., 2048, 4096],
+    `q_w` / `out_w` [.., 2048, 2048]), and every convolution over a
+    window of heads under `ds.attn`."""
+    found = []
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        if not m:
+            continue
+        if m["op"] == "copy" and ATTN_WEIGHT.search(m["type"]):
+            found.append(("copy", m["type"][:60]))
+        if m["op"] == "convolution" and "window={size=" in line \
+                and "ds.attn" in line:
+            found.append(("convolution", m["type"][:60]))
+    return found
+
+
 @pytest.mark.parametrize("kv", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_program_leaves_the_pools_in_place(on_chip, v5e_2x2, kv):
     """The engine's real decode program at Pythia-1.4b's widths (hidden
@@ -443,6 +466,11 @@ def test_decode_program_leaves_the_pools_in_place(on_chip, v5e_2x2, kv):
     the same; their scale pools (1/64 of the bytes) get one layout
     change a program from the compiler, because the chip's own layout
     of a `[.., 16, 64]` bf16 array is not row-major (PERF.md, section 7).
+
+    The QKV weight is read where it lies in the stack: 32 rows under a
+    hidden size of 2048 keep the projection a plain dot, so no copy has
+    the shape of a layer's attention weight and no convolution under
+    `ds.attn` runs over a window of heads (PERF.md, section 6, PR 40).
     """
     from jax.sharding import SingleDeviceSharding
     from deeperspeed_tpu.inference import InferenceEngine
@@ -501,6 +529,7 @@ def test_decode_program_leaves_the_pools_in_place(on_chip, v5e_2x2, kv):
                 and "tpu_custom_call" not in line:
             moved.append((m["op"], m["type"][:60]))
     assert not moved, moved
+    assert not attention_weight_relayouts(text)
     layer_pool = pages * 16 * page_size * 128 * (1 if kv else 2)
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
 
@@ -836,7 +865,7 @@ def test_latent_serving_programs_compile_and_leave_the_pool_in_place(
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("program,seqlen", [
-    ("decode", 256), ("prefill", 256), ("prefill", 64)])
+    ("decode", 256), ("prefill", 256), ("prefill", 128), ("prefill", 64)])
 def test_looped_serving_programs_compile_and_carry_the_pools(
         on_chip, v5e_2x2, program, seqlen):
     """The engine's decode and prefill programs for Ouro's block at the
@@ -849,7 +878,11 @@ def test_looped_serving_programs_compile_and_carry_the_pools(
     step but the row write produces an array of the pool's shape: the
     pools ride the pass loop and the layer scan as carried state. A
     64-token prefill bucket, half the flash forward's least block, still
-    runs the kernel (the engine pads its attention up to one block)."""
+    runs the kernel (the engine pads its attention up to one block).
+    Neither program copies the q or the k/v weight stack into another
+    layout (folded into the dot, the reshape to heads costs a copy of
+    the WHOLE loop-invariant stack a step: 1.21 GB at 48 layers): 16 to
+    256 rows under a hidden size of 2048 keep the projections plain."""
     from jax.sharding import SingleDeviceSharding
     from deeperspeed_tpu.inference import InferenceEngine
     from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
@@ -905,6 +938,7 @@ def test_looped_serving_programs_compile_and_carry_the_pools(
         assert len(calls) == 1, (name, len(calls))
     assert "ds.loop/ds.layers" in text and "ds.loop_exit" in text
     assert "ds.attn_xla" not in text
+    assert not attention_weight_relayouts(text)
     if program == "decode":
         assert not pool_shaped_moves(text, pool.shape)
 
