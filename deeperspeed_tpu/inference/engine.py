@@ -67,7 +67,8 @@ from ..compat import shard_map
 from ..models import gpt2 as gpt2_mod
 from ..models import gpt_neox as neox
 from ..module_inject.replace_module import prepare_inference_params
-from ..ops.pallas.decode_attention import (paged_decode_attention,
+from ..ops.pallas.decode_attention import (_write_group,
+                                           paged_decode_attention,
                                            paged_kv_write,
                                            paged_latent_decode,
                                            paged_latent_write)
@@ -216,10 +217,11 @@ class _Family:
         return (cos[positions][:, None, :], sin[positions][:, None, :],
                 rot_dim)
 
-    def cos_sin_at(self, positions):
+    def cos_sin_at(self, positions, attn=None):
         """Per-token rotary rows at `positions` [B, S] →
         ([B, S, rot], ...) — `apply_rotary` takes the 3-D form."""
-        return (self._cos[positions], self._sin[positions], self.rot_dim)
+        cos, sin, rot_dim = self._table(attn)
+        return (cos[positions], sin[positions], rot_dim)
 
     @scopes.scoped("ds.lm_head")
     def head(self, params, h):
@@ -273,6 +275,16 @@ class InferenceEngine:
         # run that many times over the same weights, a pass's K/V in
         # cache layers of its own (docs/inference.md "Looped models")
         self.loop_steps = cfg.loop_steps if self.planned else 1
+        # a model that generates a BLOCK of tokens at a time
+        # (`GPTNeoXConfig.generation_block`; docs/inference.md "Block
+        # generation"): a decode pass carries the block's rows of every
+        # sequence under the block-causal mask and unmasks by confidence
+        self.block = cfg.generation_block if self.planned else 0
+        if self.block:
+            # rows a pass unmasks at least: the floor under the threshold
+            self.block_floor = max(1, self.block // (
+                cfg.generation_steps or self.block))
+            self.block_threshold = cfg.generation_threshold
         if getattr(cfg, "attention_engine", "dense") != "dense":
             raise DeepSpeedConfigError(
                 "serving needs attention_engine='dense' (the block-"
@@ -495,7 +507,9 @@ class InferenceEngine:
             prefill_batch_sizes=self.prefill_batch_sizes,
             decode_batch_sizes=self.decode_batch_sizes,
             prefix_cache=self.prefix_cache, spec_tokens=self.spec_k,
-            window_cache=self.window_cache, window=self.window)
+            window_cache=self.window_cache, window=self.window,
+            block=self.block,
+            mask_token_id=cfg.mask_token_id if self.block else 0)
         self.n_pages_max = pages_for_tokens(self.max_seq_len,
                                             self.page_size)
         # precision identity of this serving engine
@@ -536,6 +550,11 @@ class InferenceEngine:
         # them
         self._counts_held = bool(self.family.moe_held)
         self._carry = self._zero_carry()
+        # a block model's passes as they are read back, where a list is
+        # put here (None: not kept): one dict a live row-pass, the block
+        # going in and coming out (`_complete_blocks`). What a test or a
+        # benchmark's check replays against the reference
+        self.block_trace = None
         self.stats = {"steps": 0, "prefill_requests": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
                       # one-step lookahead (docs/inference.md): decode
@@ -573,6 +592,22 @@ class InferenceEngine:
                       # the layer stack all dispatched programs ran
                       # (`loop_steps` a program; 1 unless the model loops)
                       "decode_steps": 0, "loop_passes": 0,
+                      # a block-generating model's passes, in ROW-passes
+                      # (one sequence's block through one program):
+                      # dispatched; read back as commits; blocks a live
+                      # request committed; rows its passes unmasked; the
+                      # positions the dispatched rows attended (n + block
+                      # a row-pass); the host seconds of the passes
+                      # inside decode_s. `decode_tokens` stays the tokens
+                      # DELIVERED (a block's contiguous unmasked prefix);
+                      # requests whose FIRST row was unmasked (wherever
+                      # it lies in the block: the answer begins to exist,
+                      # the delivered prefix may wait for a row to its
+                      # left) and their seconds since the submit
+                      "block_passes": 0, "block_commit_passes": 0,
+                      "blocks_committed": 0, "block_tokens_final": 0,
+                      "decode_kv_tokens_block": 0, "block_pass_s": 0.0,
+                      "block_first_unmasks": 0, "block_first_unmask_s": 0.0,
                       # the same for the window layers alone (a row
                       # attends over at most the window there), and the
                       # pages that held a decode step's context, by cache
@@ -738,9 +773,24 @@ class InferenceEngine:
         elif self.loop_steps > 1 and self.kv_quant:
             what = ("kv_cache_dtype int8 with a looped model: prefill's "
                     "scatter of a pass's pages writes plain pools")
+        elif self.block and self.kv_quant:
+            what = ("kv_cache_dtype int8 with block generation: a block's "
+                    "rows are written as one run into plain pools")
+        elif self.block and self.temperature > 0.0:
+            what = ("a sampled request (inference.temperature > 0) with "
+                    "block generation: a pass unmasks the argmax of its "
+                    "most confident rows; temperature / top-k / top-p "
+                    "sampling inside a block is not computed")
+        elif self.block and (ip["page_size"] % self.block or _write_group(
+                ip["page_size"], self.kv_cache_dtype) % self.block):
+            what = (f"page_size {ip['page_size']} and "
+                    f"{jnp.dtype(self.kv_cache_dtype).name} pages: a block's rows lie in one page and one packed "
+                    f"sublane group of it (`paged_kv_write`)")
         if what:
             looped = f", loop_steps={self.loop_steps}" \
                 if self.loop_steps > 1 else ""
+            looped += f", generation_block={self.block}" if self.block \
+                else ""
             raise DeepSpeedConfigError(
                 f"serving a planned model (layer_plan{looped}) with {what} "
                 f"is not built")
@@ -883,7 +933,8 @@ class InferenceEngine:
         return jax.random.categorical(
             rng, logits / self.temperature, axis=-1).astype(jnp.int32)
 
-    def _attention(self, q, pools, layer, page_table, lengths, window=None):
+    def _attention(self, q, pools, layer, page_table, lengths, window=None,
+                   block_pass=False):
         """Paged decode attention over layer `layer` of the stacked
         (K, V) `pools`, shard_mapped over the model axis when the mesh
         shards heads (attention is head-independent, so each shard runs
@@ -901,7 +952,7 @@ class InferenceEngine:
                 (k, v), scales = leaves, {}
             return paged_decode_attention(
                 q, k, v, pt, ln, backend=self._attn_backend, layer=layer,
-                window=window, **scales)
+                window=window, block_pass=block_pass, **scales)
 
         if self.mp > 1:
             attend = shard_map(
@@ -918,7 +969,8 @@ class InferenceEngine:
                      for x in leaves)
 
     def _write_rows(self, pools, k, v, layer, page_idx, slot):
-        """One token's K and V rows [B, H, D] into their page slots of
+        """One token's K and V rows [B, H, D] (or a block's run of rows
+        [B, H, block, D], from `slot` on) into their page slots of
         layer `layer` of the stacked (K, V) `pools`, in place
         (`paged_kv_write`; under a model-parallel mesh each shard writes
         its own heads). Int8 pools quantize per (head) vector and land
@@ -1054,15 +1106,50 @@ class InferenceEngine:
         """`_token_layers` of a planned model: `pools` and `tables` are
         {cache kind: (K, V) pools} and {cache kind: page table}; a window
         layer writes and attends in the window kind's; `loop_pass` as
-        `_plan_layers` takes it. Returns (x, pools, held pairs)."""
+        `_plan_layers` takes it. Returns (x, pools, held pairs).
+
+        `x` [B, R, hidden]: R = 1, a token a sequence at position `pos`
+        [B]; or a block model's block, R rows a sequence at positions
+        `pos` .. `pos + R - 1`. A block's K and V rows go into their page
+        as one run (they lie in one packed group of it) and its rows
+        attend over `lengths` = `pos + R` positions with NO mask among
+        themselves: the R rows x the query heads of a KV head ride as
+        that KV head's one group of the grouped paged kernel, under the
+        name `ds.paged_decode_block`."""
         fam, ps = self.family, self.page_size
-        B = x.shape[0]
-        active = (lengths > 0)[:, None]
+        B, R = x.shape[:2]
+        active = jnp.broadcast_to((lengths > 0)[:, None], (B, R))
         kinds = list(pools)
-        rot = {k: fam.cos_sin_decode(pos, k) for k in kinds}
+        if R == 1:
+            rot = {k: fam.cos_sin_decode(pos, k) for k in kinds}
+        else:
+            at = pos[:, None] + jnp.arange(R, dtype=pos.dtype)
+            rot = {k: fam.cos_sin_at(at, k) for k in kinds}
         page_idx = {k: jnp.take_along_axis(
             tables[k], (pos // ps)[:, None], axis=1)[:, 0] for k in kinds}
         slot = pos % ps
+        G = cfg.kv_heads
+
+        def kv_rows(t):
+            """K or V [B, R, G, D] as `_write_rows` takes it."""
+            return t[:, 0] if R == 1 else jnp.swapaxes(t, 1, 2)
+
+        def q_rows(q):
+            """q [B, R, H, D] as the paged kernel's [B, heads, D]: a KV
+            head's R x H / G query rows together."""
+            if R == 1:
+                return q[:, 0]
+            D = q.shape[-1]
+            return jnp.swapaxes(q.reshape(B, R, G, -1, D), 1, 2).reshape(
+                B, -1, D)
+
+        def attn_rows(attn):
+            """The inverse, flattened to [B, R, H * D]."""
+            if R == 1:
+                return attn.reshape(B, 1, -1)
+            D = attn.shape[-1]
+            return jnp.swapaxes(attn.reshape(B, G, R, -1, D), 1, 2).reshape(
+                B, R, -1)
 
         def latent_layer(carry, bp, spec, cache_layer):
             """The absorbed form: the token's latent row into its page,
@@ -1093,16 +1180,16 @@ class InferenceEngine:
             x, pools, held = carry
             kind = spec.attn
             q, k, v = neox._block_qkv(cfg, bp, x, *rot[kind], spec.heads)
-            kv = self._write_rows(pools[kind], k[:, 0], v[:, 0],
+            kv = self._write_rows(pools[kind], kv_rows(k), kv_rows(v),
                                   cache_layer, page_idx[kind], slot)
             with scopes.scope("ds.attn"):
                 attn = self._attention(
-                    q[:, 0].astype(kv[0].dtype), kv, cache_layer,
+                    q_rows(q).astype(kv[0].dtype), kv, cache_layer,
                     tables[kind], lengths,
-                    window=self.window if kind == "window" else None
-                ).astype(x.dtype)
+                    window=self.window if kind == "window" else None,
+                    block_pass=R > 1).astype(x.dtype)
             out, rows = self._held_rows(cfg, neox._block_post_attn(
-                cfg, bp, x, attn.reshape(B, 1, -1),
+                cfg, bp, x, attn_rows(attn),
                 reduce_fn=lambda t: t, token_mask=active))
             return (out, dict(pools, **{kind: kv}), held + rows), None
 
@@ -1240,9 +1327,10 @@ class InferenceEngine:
                 q, k, v, segment_ids = (
                     jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                     for t in (q, k, v, segment_ids))
+            # a block model reads its prompt too under the block-causal mask
             return neox.causal_attention(
                 q, k, v, use_pallas=use_pallas, segment_ids=segment_ids,
-                window=window)[:, :S]
+                window=window, block=self.block)[:, :S]
 
         def first_token(params, x, lengths, rng):
             """The token sampled at each row's last real position."""
@@ -1371,6 +1459,15 @@ class InferenceEngine:
                             for i, pool in enumerate(kind_pools))
                 return x, (pools, held)
 
+            if self.block:
+                # a block model's prefill caches the context's whole
+                # blocks and samples nothing: no final norm, no head. What
+                # the host reads back is a [B] of zeros that waits on the
+                # last layer, so a device error still surfaces there
+                x, (pools, _) = one_pass(
+                    x, (pools, jnp.zeros((), jnp.float32)), 0)
+                done = (last_rows(x, lengths)[:, 0] * 0).astype(jnp.int32)
+                return (done, *self._pool_args(pools))
             h, (pools, held), exit_pass = self._loop(
                 cfg, params, x, (pools, jnp.zeros((), jnp.float32)),
                 one_pass, lambda x: last_rows(x, lengths))
@@ -1442,7 +1539,73 @@ class InferenceEngine:
             return (self._with_held(nxt, held, exit_pass),
                     *self._pool_args(pools))
 
-        fn = jax.jit(planned_decode if self.planned else decode,
+        B_ = self.block
+        mask_id = cfg.mask_token_id
+
+        def planned_block_decode(params, stacks, state, lengths, page_table,
+                                 k_pool, v_pool, rng, carried, src):
+            """One PASS of a block-generating model over the block of
+            every row. `state` [batch, 2 block + 1] int32 is a row's block
+            as the host knows it: its tokens, which rows are masked, and
+            whether the pass before committed it; a row that continues
+            from the pass in flight takes the state from that program's
+            output `carried` at row `src` instead, so rows of one batch
+            are at different passes of different blocks and nothing
+            reaches the host between passes. A committed block's
+            successor is all masks. `lengths` = the block's first
+            position + block (0: an inactive row), which the host knows
+            (`scheduler.block_start`).
+
+            The pass decides its own duty from the state: a block with no
+            mask left is COMMITTED (its rows, written like any pass's,
+            are now the final ones; nothing is unmasked), any other block
+            is denoised: every masked row whose confidence, the softmax
+            probability of its argmax in float32, is over the threshold
+            is unmasked, and never fewer than `block_floor` of the most
+            confident masked rows (ties: the lower position). A denoising
+            pass's K/V rows are provisional: only this pass's own rows
+            read them, and the block's next pass overwrites them. Returns
+            the state after the pass in `state`'s layout, padded to the
+            widest batch, and the pools."""
+            st = jnp.where((src >= 0)[:, None], carried[jnp.maximum(src, 0)],
+                           state)
+            committed = st[:, 2 * B_] > 0
+            tok = jnp.where(committed[:, None], mask_id, st[:, :B_])
+            masked = (st[:, B_:2 * B_] > 0) | committed[:, None]
+            active = lengths > 0
+            pos = jnp.maximum(lengths - B_, 0)
+            commit = active & ~jnp.any(masked, axis=1)
+            at = pos[:, None] + jnp.arange(B_, dtype=pos.dtype)
+            x = fam.embed_at(params, tok, at)
+            x, pools, _ = self._plan_token_layers(
+                cfg, stacks, x, pos, self._kind_pools(k_pool, v_pool),
+                self._kind_tables(page_table), lengths)
+            logits = fam.head_all(params, fam.final_norm(params, x))
+            with scopes.scope("ds.unmask"):
+                top = jnp.max(logits, axis=-1)
+                best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]),
+                                     axis=-1)
+                conf = jnp.where(masked, conf, -jnp.inf)
+                # a row's rank among its block's masked rows by confidence
+                row = jnp.arange(B_)
+                ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                    (conf[:, None, :] == conf[:, :, None]) &
+                    (row[None, None, :] < row[None, :, None]))
+                rank = jnp.sum(ahead, axis=-1)
+                unmask = masked & active[:, None] & (
+                    (conf > self.block_threshold) |
+                    (rank < self.block_floor))
+                tok = jnp.where(unmask, best, tok)
+                masked = masked & ~unmask
+                out = jnp.concatenate(
+                    [tok, masked.astype(jnp.int32),
+                     commit.astype(jnp.int32)[:, None]], axis=1)
+            return (jnp.pad(out, ((0, width - batch), (0, 0))),
+                    *self._pool_args(pools))
+
+        fn = jax.jit(planned_block_decode if self.block else
+                     planned_decode if self.planned else decode,
                      donate_argnums=(5, 6))
         self._compiled[key] = fn
         return fn
@@ -1839,6 +2002,9 @@ class InferenceEngine:
                     if self.spec_k:
                         self.stats["decode_tokens"] += \
                             self._run_speculative(plan)
+                    elif self.block:
+                        with self._phase("block_pass"):
+                            newest = self._dispatch_block_decode(plan)
                     else:
                         newest = self._dispatch_decode(plan)
             except Exception as e:  # noqa: BLE001
@@ -2358,25 +2524,36 @@ class InferenceEngine:
             if not live:
                 continue    # evicted by cache-loss recovery meanwhile
             req.owed.remove(rec.serial)
-            self.scheduler.complete_prefill(req, int(nxt[i]))
             req.prefill_step = self.timeline.serial
             self.stats["prefill_requests"] += 1
+            if self.block:
+                # no token comes of a block model's prefill: TTFT is
+                # stamped by the pass that delivers the first one
+                self.scheduler.complete_prefill(req)
+                self.stats["prefill_tokens"] += req.cached
+                continue
+            self.scheduler.complete_prefill(req, int(nxt[i]))
             # req.cached is the pre-sampling context length (complete_
             # prefill pins it before appending the first token) —
             # len(req.context) here would double-count that token once
             # decode accounting starts
             self.stats["prefill_tokens"] += req.cached
-            # TTFT: once per request, from the ORIGINAL submit — an
-            # evicted request's re-prefill resamples a token it already
-            # delivered and must not re-count
-            if req.first_token_at is None and req.submitted_at is not None:
-                req.first_token_at = now
-                ttft_s = now - req.submitted_at
-                self.request_metrics.observe_ttft(ttft_s)
-                if self.admission is not None:
-                    # the shedding signal: measured TTFT EMA vs SLOs
-                    self.admission.observe_ttft(ttft_s * 1e3)
+            self._observe_first_token(req, now)
             req.last_token_at = now
+
+    def _observe_first_token(self, req, now):
+        """TTFT: once per request, from the ORIGINAL submit — an evicted
+        request's re-prefill resamples a token it already delivered and
+        must not re-count. True where `now` is the request's first."""
+        if req.first_token_at is not None or req.submitted_at is None:
+            return False
+        req.first_token_at = now
+        ttft_s = now - req.submitted_at
+        self.request_metrics.observe_ttft(ttft_s)
+        if self.admission is not None:
+            # the shedding signal: measured TTFT EMA vs SLOs
+            self.admission.observe_ttft(ttft_s * 1e3)
+        return True
 
     def _count_moe_rows(self, phase, tokens, program_tokens):
         """What a step's MoE layers route, from shapes the host already
@@ -2417,7 +2594,10 @@ class InferenceEngine:
                 page_table = np.zeros((B, n_pages_row), np.int32)
                 window_table = np.zeros((B, n_pages_row), np.int32)
                 for i, req in enumerate(plan.prefills):
+                    # a block model's whole blocks; the rest opens the
+                    # first generated block
                     ctx = req.context
+                    ctx = ctx[:self.scheduler.prefill_tokens(len(ctx))]
                     tokens[i, :len(ctx)] = ctx
                     lengths[i] = len(ctx)
                     page_table[i, :len(req.pages)] = req.pages
@@ -2443,16 +2623,13 @@ class InferenceEngine:
         the page table and the page growth behind it are counts the host
         already has."""
         B = plan.decode_batch
-        prev = next((rec for rec in reversed(self._inflight)
-                     if rec.phase == "decode"), None)
+        prev, prev_row = self._decode_in_flight()
         with self._phase("build_inputs"):
             tokens = np.zeros((B,), np.int32)
             src = np.full((B,), -1, np.int32)
             lengths = np.zeros((B,), np.int32)
             page_table = np.zeros((B, self.n_pages_max), np.int32)
             window_table = np.zeros((B, self.n_pages_max), np.int32)
-            prev_row = {id(r): i for i, r in enumerate(prev.reqs)} \
-                if prev else {}
             for i, req in enumerate(plan.decodes):
                 if req.pending:
                     src[i] = prev_row[id(req)]
@@ -2477,8 +2654,22 @@ class InferenceEngine:
             args = [jnp.asarray(tokens), jnp.asarray(lengths),
                     self._table_args(page_table, window_table)]
             src = jnp.asarray(src)
-        fn = self._decode_fn(B)
-        self.timeline.enqueued(f"decode x{B}")
+        return self._launch_decode(plan, f"decode x{B}", args, src, prev)
+
+    def _decode_in_flight(self):
+        """(the decode program in flight or None, {id(request): its row
+        there}): where a continuing row's input lies on the device."""
+        prev = next((rec for rec in reversed(self._inflight)
+                     if rec.phase == "decode"), None)
+        return prev, {id(r): i for i, r in enumerate(prev.reqs)} \
+            if prev else {}
+
+    def _launch_decode(self, plan, key, args, src, prev):
+        """Enqueue the plan's decode program on `args`, its continuing
+        rows taking their input from `_carry` at `src`; its output is the
+        next program's `_carry`."""
+        fn = self._decode_fn(plan.decode_batch)
+        self.timeline.enqueued(key)
         with self._phase("dispatch"):
             nxt, *pools = fn(
                 self.params, self.params_stacked, *args, *self._pools(),
@@ -2489,9 +2680,95 @@ class InferenceEngine:
         self._carry = nxt
         return self._enqueued("decode", plan.decodes, nxt)
 
+    def _dispatch_block_decode(self, plan):
+        """`_dispatch_decode` of a block-generating model: one pass over
+        the block of every decoding row. A row whose last pass is unread
+        names its row of the pass in flight and the program takes the
+        block's state from there; any other row brings the state the
+        host last read (`Request.block_tokens` / `block_masked`). Where
+        the block lies (`scheduler.block_start`), the page table and the
+        page growth behind it the host knows without the read-back."""
+        B, blk = plan.decode_batch, self.block
+        prev, prev_row = self._decode_in_flight()
+        with self._phase("build_inputs"):
+            state = np.zeros((B, 2 * blk + 1), np.int32)
+            src = np.full((B,), -1, np.int32)
+            lengths = np.zeros((B,), np.int32)
+            page_table = np.zeros((B, self.n_pages_max), np.int32)
+            for i, req in enumerate(plan.decodes):
+                if req.pending:
+                    src[i] = prev_row[id(req)]
+                else:
+                    state[i, :blk] = req.block_tokens
+                    state[i, blk:2 * blk] = req.block_masked
+                lengths[i] = self.scheduler.block_start(req) + blk
+                page_table[i, :len(req.pages)] = req.pages
+            kv_tokens = int(lengths.sum())
+            self.stats["decode_steps"] += 1
+            self.stats["block_passes"] += len(plan.decodes)
+            self.stats["decode_kv_tokens"] += kv_tokens
+            self.stats["decode_kv_tokens_block"] += kv_tokens
+            self.stats["kv_page_steps_full"] += int(
+                (-(-lengths // self.page_size)).sum())
+            self._count_moe_rows("decode", len(plan.decodes) * blk, B * blk)
+            args = [jnp.asarray(state), jnp.asarray(lengths),
+                    jnp.asarray(page_table)]
+            src = jnp.asarray(src)
+        return self._launch_decode(plan, f"block x{B}", args, src, prev)
+
+    def _complete_blocks(self, rec, nxt, now):
+        """A read-back pass of a block model into its requests: each live
+        row's block after the pass (`scheduler.complete_block`), the
+        tokens it made final counted where they were unmasked and
+        `decode_tokens` where they were delivered."""
+        blk = self.block
+        for i, req, live in rec.rows():
+            tokens, masked = nxt[i, :blk], nxt[i, blk:2 * blk]
+            committed = bool(nxt[i, 2 * blk])
+            self.stats["block_commit_passes"] += committed
+            if not live:
+                self.stats["lookahead_discarded"] += 1
+                continue
+            req.owed.remove(rec.serial)
+            if self.block_trace is not None:
+                # tuples of numbers: a record kept through a whole run
+                # is then nothing the collector has to walk
+                self.block_trace.append({
+                    "request": req.request_id, "program": rec.serial,
+                    "start": req.cached, "committed": committed,
+                    "tokens_in": tuple(req.block_tokens),
+                    "masked_in": tuple(req.block_masked),
+                    "tokens": tuple(tokens.tolist()),
+                    "masked": tuple(bool(m) for m in masked)})
+            if committed:
+                self.stats["blocks_committed"] += 1
+            else:
+                final = sum(req.block_masked) - int(masked.sum())
+                self.stats["block_tokens_final"] += final
+                if final and req.first_unmask_at is None and \
+                        req.submitted_at is not None:
+                    req.first_unmask_at = now
+                    self.stats["block_first_unmasks"] += 1
+                    self.stats["block_first_unmask_s"] += \
+                        now - req.submitted_at
+            delivered = self.scheduler.complete_block(req, tokens, masked,
+                                                      committed)
+            if not delivered:
+                continue
+            self.stats["decode_tokens"] += delivered
+            if not self._observe_first_token(req, now) and \
+                    req.last_token_at is not None:
+                self.request_metrics.observe_inter_token(
+                    (now - req.last_token_at) / delivered)
+            req.last_token_at = now
+
     def _zero_carry(self):
         # a decode program's output (`_with_held`): the tokens, a looped
-        # model's exit passes behind them, the held count
+        # model's exit passes behind them, the held count; a block model's
+        # is its rows' block state (`planned_block_decode`)
+        if self.block:
+            return jnp.asarray(np.zeros(
+                (self._carry_width, 2 * self.block + 1), np.int32))
         return jnp.asarray(np.zeros(
             (self._carry_width * (2 if self.loop_steps > 1 else 1) +
              int(self._counts_held),), np.int32))
@@ -2561,6 +2838,8 @@ class InferenceEngine:
                 with self._phase("complete"):
                     if rec.phase == "prefill":
                         self._complete_prefills(rec, nxt, now)
+                    elif self.block:
+                        self._complete_blocks(rec, nxt, now)
                     else:
                         self._complete_decodes(rec, nxt, now)
         if failure is not None:

@@ -35,6 +35,16 @@ growth counts `cached + pending`, and a request whose last token by
 `max_new_tokens` or by the window's edge is pending stays in `running`
 (its pages are in use) but out of the decode batch.
 
+A model that generates a BLOCK of tokens at a time (`block` > 0;
+docs/inference.md "Block generation") is counted in blocks: a prefill
+caches the context's whole blocks and yields no token (a context under
+one block takes none), `cached` advances by a block at each commit pass,
+a decode row costs a block of the budget, pages grow to the block the
+next pass writes (which may lie one past the block the host last read),
+and a pass's result lands through `complete_block`: the newly final
+tokens are the contiguous unmasked prefix of the block, so `generated`
+grows left to right whatever order the rows were unmasked in.
+
 Token accounting uses PADDED bucket sizes, not raw prompt lengths: the
 budget is a compute bound, and compute is spent at compiled shapes.
 The budget must cover the largest user prefill bucket (validated at
@@ -148,9 +158,19 @@ class Request:
     submitted_at: float = None
     first_token_at: float = None
     last_token_at: float = None
+    # a block-generating model's request: when a pass first unmasked one
+    # of its rows (the first token is the LEFTMOST row's, 1 to `block`
+    # passes later as the confidences fall)
+    first_unmask_at: float = None
     # serial of the engine's step record that read back its (latest)
     # prefill: joins the request to the step timeline
     prefill_step: int = None
+    # a block-generating model's request: the block at positions `cached`
+    # .. as the host last READ it (its tokens, the mask token where a row
+    # is masked, and which rows are masked); the device carries the newer
+    # state from pass to pass
+    block_tokens: list = field(default_factory=list)
+    block_masked: list = field(default_factory=list)
 
     @property
     def context(self):
@@ -173,7 +193,14 @@ class Request:
         """The token that ends this request by count (`max_new_tokens`,
         or the serving window's edge) is dispatched and unread: it takes
         no further decode step. (An end by EOS is the one the host learns
-        only at the read-back.)"""
+        only at the read-back.) A block request: the pass in flight is
+        sure to unmask the last row of the block that holds its last
+        token (a pass unmasks at least one row)."""
+        if self.block_masked:
+            block = len(self.block_masked)
+            end = min(len(self.prompt) + self.max_new_tokens, max_seq_len)
+            return bool(self.pending) and self.cached + block >= end and \
+                sum(self.block_masked) == 1
         n = len(self.generated) + self.pending
         return bool(self.pending) and (
             n >= self.max_new_tokens or
@@ -217,8 +244,19 @@ class ContinuousBatchingScheduler:
     def __init__(self, cache, max_seq_len, token_budget, max_batch_size,
                  prefill_lengths, prefill_batch_sizes, decode_batch_sizes,
                  prefix_cache=None, spec_tokens=0, window_cache=None,
-                 window=0):
+                 window=0, block=0, mask_token_id=0):
         self.cache = cache
+        # a model that generates `block` tokens at a time (0: one), and
+        # the token a row of a block holds until it is unmasked
+        self.block = int(block)
+        self.mask_token_id = int(mask_token_id)
+        if self.block and (prefix_cache is not None or spec_tokens or
+                           window_cache is not None or
+                           cache.page_size % self.block):
+            raise ValueError(
+                f"a block-generating model (block {self.block}) needs a "
+                f"page size its block divides, and neither a prefix cache, "
+                f"speculation nor a window cache kind")
         # page pools by layer kind: `cache` holds what a FULL layer
         # keeps, a sequence's whole context; `window_cache` (a model with
         # window layers) what a WINDOW layer keeps, the pages that hold
@@ -300,7 +338,8 @@ class ContinuousBatchingScheduler:
                 f"max_new_tokens must be >= 1, got "
                 f"{request.max_new_tokens} (prefill always samples the "
                 f"first token)")
-        if _bucket(prompt_len, self.prefill_lengths) is None:
+        if _bucket(self.prefill_tokens(prompt_len),
+                   self.prefill_lengths) is None:
             raise ValueError(
                 f"prompt length {prompt_len} exceeds the largest prefill "
                 f"bucket {self.prefill_lengths[-1]}")
@@ -380,6 +419,32 @@ class ContinuousBatchingScheduler:
     @property
     def has_work(self):
         return bool(self.waiting or self.running or self.quarantined)
+
+    # -- block generation ---------------------------------------------------
+
+    def prefill_tokens(self, n_context):
+        """Tokens of a context of `n_context` a prefill caches: all of
+        them, or a block model's whole blocks (the rest open the first
+        generated block)."""
+        return n_context - n_context % self.block if self.block \
+            else n_context
+
+    def _open_block(self, request):
+        """The request's block at `cached`, as its context leaves it: the
+        context's tail past the cached blocks, then mask tokens."""
+        tail = request.context[request.cached:]
+        n_mask = self.block - len(tail)
+        request.block_tokens = tail + [self.mask_token_id] * n_mask
+        request.block_masked = [False] * len(tail) + [True] * n_mask
+
+    def block_start(self, request):
+        """The first position of the block the NEXT pass of `request`
+        works on. The pass in flight (if any) is a commit exactly when
+        the block the host last read has no mask left, and then the next
+        pass opens the block behind it."""
+        if request.pending and not any(request.block_masked):
+            return request.cached + self.block
+        return request.cached
 
     # -- graceful drain ----------------------------------------------------
 
@@ -646,6 +711,9 @@ class ContinuousBatchingScheduler:
             # verify writes the full window before acceptance); a token
             # in flight has its slot already
             pos = req.cached + req.pending + self._spec_window(req)
+            if self.block:
+                # the last row the next pass writes
+                pos = self.block_start(req) + self.block - 1
             if not self._grow_pages(req, self.cache, req.pages, pos,
                                     evicted, now):
                 continue
@@ -689,8 +757,8 @@ class ContinuousBatchingScheduler:
                    if not r.last_token_pending(self.max_seq_len)]
         # a decode step costs 1 token per row — plus its speculative
         # window: the verify forward computes window+1 positions
-        budget = self.token_budget - sum(1 + self._spec_window(r)
-                                         for r in decodes)
+        budget = self.token_budget - sum(
+            (self.block or 1) + self._spec_window(r) for r in decodes)
 
         prefills = []
         step_len = 0
@@ -713,7 +781,20 @@ class ContinuousBatchingScheduler:
             # a prefix-attached request prefills only its SUFFIX (the
             # shared pages already hold the prefix K/V): bucket that
             req_kind = "chunk" if req.n_shared else "full"
-            suffix_len = len(req.context) - req.n_shared * self.page_size
+            suffix_len = self.prefill_tokens(len(req.context)) - \
+                req.n_shared * self.page_size
+            if self.block and not suffix_len:
+                # a context under one block: nothing to cache, its tokens
+                # open the first generated block. One page, no prefill
+                pages = self.cache.allocate(1)
+                if pages is None:
+                    break
+                self.waiting.popleft()
+                req.pages, req.cached = pages, 0
+                req.state, req.admitted_at = RUNNING, now
+                self._open_block(req)
+                self.running.append(req)
+                continue
             length = _bucket(suffix_len, self._prefill_ladder)
             if length is None:
                 # unreachable: the ladder tops at the aligned window and
@@ -780,14 +861,20 @@ class ContinuousBatchingScheduler:
 
     # -- results -----------------------------------------------------------
 
-    def complete_prefill(self, request, first_token):
+    def complete_prefill(self, request, first_token=None):
         """Record a prefill's result: the prompt's K/V is cached and the
-        first generated token sampled."""
+        first generated token sampled. A block model's prefill cached the
+        context's whole blocks and sampled nothing (no `first_token`):
+        the rest of the context opens the first generated block."""
+        request.failures = 0     # a completed step ends the failure run
+        if self.block:
+            request.cached = self.prefill_tokens(len(request.context))
+            self._open_block(request)
+            return
         request.cached = len(request.context)
         if self.prefix_cache is not None:
             self._register_prefix(request)
         request.generated.append(int(first_token))
-        request.failures = 0     # a completed step ends the failure run
         self._maybe_finish(request)
 
     def complete_decode(self, request, token):
@@ -821,6 +908,36 @@ class ContinuousBatchingScheduler:
         self._maybe_finish(request)
         if request.status is None:
             self._rollback_spec_pages(request)
+        return appended
+
+    def complete_block(self, request, tokens, masked, committed):
+        """Record one pass of a block model over the request's block:
+        `tokens` and `masked` are the block's rows after the pass,
+        `committed` whether it was the commit pass (the block had no mask
+        left going in, and its K/V now stays in the cache). A commit
+        advances `cached` by the block and opens the next, all masks. A
+        denoising pass appends the newly final tokens: the block's
+        contiguous unmasked prefix past what `generated` already holds
+        (a row unmasked behind a masked one waits for it), up to the
+        request's natural end, as `complete_speculative` appends an
+        accepted window (no page is rolled back: a block's rows lie in
+        pages grown for it). Returns the number of tokens appended."""
+        appended = 0
+        if committed:
+            request.cached += self.block
+            self._open_block(request)
+        else:
+            request.block_tokens = [int(t) for t in tokens]
+            request.block_masked = [bool(m) for m in masked]
+            at = len(request.prompt) + len(request.generated) - \
+                request.cached
+            while at < self.block and not request.block_masked[at] and \
+                    not request.done:
+                request.generated.append(request.block_tokens[at])
+                at += 1
+                appended += 1
+        request.failures = 0
+        self._maybe_finish(request)
         return appended
 
     def _rollback_spec_pages(self, request):

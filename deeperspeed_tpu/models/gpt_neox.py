@@ -81,9 +81,11 @@ class GPTNeoXConfig:
     norm: str = "layernorm"
     # whether the projections carry biases,
     use_bias: bool = True
-    # an RMS norm on q and on k over ALL their features, before the
-    # split into heads (OLMoE),
-    qk_norm: bool = False
+    # an RMS norm on q and on k: True, over ALL their features, before
+    # the split into heads (OLMoE; the homogeneous block's); "head", over
+    # the features of EACH head, one scale vector [head_dim] shared by the
+    # heads, before the rotary (a planned block's),
+    qk_norm: object = False
     # the FFN's activation ("gelu": the tanh form; "silu") and whether
     # it is gated, act(x Wgate) * (x Wup),
     hidden_act: str = "gelu"
@@ -209,6 +211,21 @@ class GPTNeoXConfig:
     # attends to pass t of this one.
     loop_steps: int = 1
     loop_exit_threshold: float = 1.0
+    # a model that GENERATES A BLOCK of tokens at a time (diffusion over
+    # blocks): positions are cut into blocks of `generation_block`, aligned
+    # to absolute multiples of it, and position i sees j wherever
+    # j // block <= i // block (BLOCK-causal: everything before its block
+    # and all of its block). A block to be generated starts as
+    # `mask_token_id` and is unmasked pass by pass (InferenceEngine,
+    # docs/inference.md "Block generation"): a pass unmasks every masked
+    # row whose confidence is over `generation_threshold` and never fewer
+    # than block / `generation_steps` rows (0: the block length, one row
+    # a pass). 0: a token at a time under the causal mask, as every other
+    # model.
+    generation_block: int = 0
+    mask_token_id: int = 0
+    generation_steps: int = 0
+    generation_threshold: float = 0.9
 
     @property
     def head_dim(self):
@@ -283,6 +300,8 @@ class GPTNeoXConfig:
                 attn = self._latent_params(spec.heads)
             else:
                 attn = 2 * h * spec.heads * d + 2 * h * G * d
+                if self.qk_norm == "head":
+                    attn += 2 * d
             if self.attn_gate == "per-head":
                 attn += h * spec.heads
             if spec.ffn == "dense":
@@ -386,8 +405,15 @@ class GPTNeoXConfig:
              ("mla_q_rank", 0), ("mla_kv_rank", 0), ("mla_nope_dim", 0),
              ("mla_rope_dim", 0), ("mla_v_dim", 0), ("mtp_layers", 0),
              ("sublayer_out_norm", False), ("loop_steps", 1),
-             ("loop_exit_threshold", 1.0))
+             ("loop_exit_threshold", 1.0), ("generation_block", 0))
             if getattr(self, k) != plain]
+        if self.qk_norm not in (False, True, "head"):
+            raise NotImplementedError(
+                f"qk_norm {self.qk_norm!r}: False, True (over all of q's "
+                f"and k's features, the homogeneous block's) or 'head' "
+                f"(over each head's features, a planned block's)")
+        if not plan and self.qk_norm == "head":
+            planned_only.append("qk_norm='head'")
         if self.moe_router_score not in ("softmax", "sigmoid"):
             raise NotImplementedError(
                 f"moe_router_score {self.moe_router_score!r}: the router "
@@ -419,23 +445,27 @@ class GPTNeoXConfig:
                     f"no gate, a softmax router, no shared expert, every "
                     f"expert held, no latent attention, no "
                     f"next-token-prediction block, no norm on a "
-                    f"sublayer's output and no loop")
+                    f"sublayer's output, no loop and one token a step "
+                    f"under the causal mask")
             return
         if len(plan) != self.num_layers:
             raise ValueError(f"layer_plan names {len(plan)} layers, "
                              f"num_layers is {self.num_layers}")
         other = [f"{k}={getattr(self, k)!r}" for k, want in
                  (("norm", "rmsnorm"), ("use_bias", False),
-                  ("qk_norm", False), ("use_parallel_residual", False),
+                  ("use_parallel_residual", False),
                   ("ffn_gated", True), ("tie_word_embeddings", False),
                   ("attention_engine", "dense"), ("ffn_quant_recipe", None))
                  if getattr(self, k) != want]
+        if self.qk_norm is True:
+            other.append("qk_norm=True")
         if other:
             raise NotImplementedError(
                 f"a planned block with {', '.join(other)} is not computed: "
                 f"it is pre-norm RMSNorm, two norms a layer, a sequential "
-                f"residual, no bias, no norm on q or k, gated FFNs, an "
-                f"untied head")
+                f"residual, no bias, no norm on q or k but the one over each "
+                f"head's features (qk_norm='head'), gated FFNs, an untied "
+                f"head")
         for i, spec in enumerate(plan):
             if spec.attn not in ("full", "window", "latent") or \
                     spec.ffn not in ("dense", "experts"):
@@ -463,6 +493,7 @@ class GPTNeoXConfig:
                     f"without capacity (moe_dropless); the GShard capacity "
                     f"router is not told which experts are held")
         self._check_loop()
+        self._check_generation_block()
         if self.moe_held:
             lo, hi = self.moe_held
             if not 0 <= lo < hi <= self.moe_num_experts:
@@ -491,6 +522,41 @@ class GPTNeoXConfig:
             raise NotImplementedError(
                 "sublayer_out_norm with an experts layer: the norm on a "
                 "sublayer's output is computed for the dense gated MLP")
+
+    def _check_generation_block(self):
+        """A block-generating model's facts."""
+        B = self.generation_block
+        if not B:
+            return
+        if B < 2 or B & (B - 1) or B > 128:
+            raise ValueError(
+                f"generation_block={B}: a power of two from 2 to 128 (the "
+                f"block-causal mask is `col <= row | (block - 1)`, and a "
+                f"block lies inside one attention tile and one page)")
+        if not 0 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(
+                f"mask_token_id={self.mask_token_id} is no token of a "
+                f"vocabulary of {self.vocab_size}")
+        if not 0 <= self.generation_steps <= B or \
+                not 0.0 <= self.generation_threshold <= 1.0:
+            raise ValueError(
+                f"generation_steps={self.generation_steps}, "
+                f"generation_threshold={self.generation_threshold}: at "
+                f"most a step a row of the block of {B}, and a probability "
+                f"(1: never over it, so block / steps rows a pass)")
+        held = [f"{k}={getattr(self, k)!r}" for k, plain in
+                (("loop_steps", 1), ("mtp_layers", 0), ("attn_window", 0),
+                 ("moe_held", ()))
+                if getattr(self, k) != plain]
+        if held or any(s.attn != "full" for s in self.layer_plan):
+            raise NotImplementedError(
+                f"generation_block={B} with "
+                f"{', '.join(held) or 'a window or latent layer'}: a block "
+                f"pass is computed for a plan of full-attention layers run "
+                f"once with every expert held (a window or a latent row "
+                f"under the block-causal mask, a loop's pass a block, a "
+                f"next-token-prediction block and a held share's count "
+                f"are not)")
 
     def _check_latent(self, i, spec):
         """A latent layer's facts: all five dims, an even rotary part, a
@@ -627,7 +693,9 @@ def _stack_init(key, lead, shape, dtype, scale=0.02):
 def init_stack_params(cfg, spec, n, key):
     """The parameter stack of `n` layers of kind `spec`, every leaf with
     the leading dim `n`. No biases. Attention: `q_w` [h, H*d], `kv_w`
-    [h, 2*G*d] ([K | V], each G heads of d), `out_w` [H*d, h], and with
+    [h, 2*G*d] ([K | V], each G heads of d), `out_w` [H*d, h], with
+    `qk_norm='head'` the scales `q_norm`, `k_norm` [d] of the norm on each
+    head of q and k, and with
     a per-head gate `gate_w` [h, H]; a latent layer's seven leaves:
     `q_a` [h, q_rank], `q_a_norm` [q_rank], `q_b` [q_rank, H*(nope+rope)]
     (a head's [nope | rope]), `kv_a` [h, kv_rank+rope] ([c_kv | k_r]),
@@ -660,6 +728,8 @@ def init_stack_params(cfg, spec, n, key):
         attn = {"q_w": _stack_init(ks[0], (n,), (h, H * d), dt),
                 "kv_w": _stack_init(ks[1], (n,), (h, 2 * G * d), dt),
                 "out_w": _stack_init(ks[2], (n,), (H * d, h), dt, out_scale)}
+        if cfg.qk_norm == "head":
+            attn.update(q_norm=jnp.ones((n, d), dt), k_norm=jnp.ones((n, d), dt))
     if cfg.attn_gate == "per-head":
         attn["gate_w"] = _stack_init(ks[3], (n,), (h, H), dt)
     if spec.ffn == "dense":
@@ -923,12 +993,15 @@ def apply_rotary(q, k, cos, sin, rot_dim):
 
 
 def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
-                     window=None):
+                     window=None, block=0):
     """Causal MHA core on [B, S, H, D]; fp32 softmax accumulation.
     `k` / `v` may hold fewer (KV) heads than `q`: query head h reads KV
     head h // (H / G); `window` keeps the keys less than `window`
     positions behind their query (both a planned model's, on the
-    segmented forward kernel and the XLA fallback alike).
+    segmented forward kernel and the XLA fallback alike). `block` (a
+    power of two, `GPTNeoXConfig.generation_block`; 0: causal) makes the
+    mask BLOCK-causal: query i sees key j wherever j // block <= i //
+    block, all of its own block included.
 
     Uses the Pallas flash-attention kernel on TPU when shapes allow;
     XLA-fused fallback otherwise (the fallback still fuses well — softmax
@@ -955,10 +1028,12 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
         def kernel(q, k, v, *seg):
             if seg:
                 return flash_attention_segmented(q, k, v, seg[0], True,
-                                                 window=window)
+                                                 window=window,
+                                                 mask_block=block)
             return flash_attention(q, k, v, True)
 
-        grouped = window is not None or k.shape[2] != q.shape[2]
+        grouped = window is not None or k.shape[2] != q.shape[2] or \
+            bool(block)
         if grouped and segment_ids is None:
             # one kernel path for a window or grouped KV heads: the
             # segmented forward, every token of one document
@@ -982,6 +1057,9 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32) * scale
         mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, :, :]
+        if block:
+            pos = jnp.arange(S)
+            mask = (pos[None, :] <= (pos[:, None] | (block - 1)))[None]
         if window is not None:
             mask = mask & ~jnp.tril(jnp.ones((S, S), jnp.bool_),
                                     -int(window))[None, :, :]
@@ -1047,6 +1125,10 @@ def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
         q = _heads_dot(ln1, params["attn"]["q_w"]).reshape(B, S, nh_local, d)
         kv = _heads_dot(ln1, params["attn"]["kv_w"]).reshape(B, S, 2, G, d)
         k, v = kv[:, :, 0], kv[:, :, 1]
+        if cfg.qk_norm == "head":
+            # over the features of each head, one scale for all heads
+            q = rms_norm(q, params["attn"]["q_norm"], cfg.layernorm_eps)
+            k = rms_norm(k, params["attn"]["k_norm"], cfg.layernorm_eps)
         q, k = apply_rotary(q, k, cos, sin, rot_dim)
         return q, k, v
     qkv = _plus_bias(_heads_dot(ln1, params["attn"]["qkv_w"]),
@@ -1296,8 +1378,9 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
             attn = attn_fn(q, k, v) if segment_ids is None else \
                 attn_fn(q, k, v, segment_ids=segment_ids)
         else:
-            attn = causal_attention(q, k, v, use_pallas=use_pallas,
-                                    segment_ids=segment_ids, window=window)
+            attn = causal_attention(
+                q, k, v, use_pallas=use_pallas, segment_ids=segment_ids,
+                window=window, block=getattr(cfg, "generation_block", 0))
     if return_kv and ffn_quant is not None:
         raise ValueError("return_kv and ffn_quant cannot combine (the "
                          "KV-returning decode path serves quantized "
@@ -1985,7 +2068,8 @@ class GPTNeoX:
                 f"{what}: training of a planned model (layer_plan: window "
                 f"layers, grouped KV heads, an attention gate, a shared "
                 f"expert, a held share of the experts, latent attention, "
-                f"a stack looped over its weights) "
+                f"a stack looped over its weights, generation by blocks, "
+                f"whose masking schedule no configuration key gives) "
                 f"is not built; the flash backward, the parameter specs "
                 f"and the pipeline layers are the homogeneous block's. "
                 f"InferenceEngine serves it (`loss_fn` alone computes a "
@@ -2783,9 +2867,11 @@ def generate(cfg, params, prompt, max_new_tokens, temperature=0.0,
     if cfg.layer_plan:
         raise NotImplementedError(
             f"generate: this cache is the homogeneous block's, one K and "
-            f"V a layer; a planned model (layer_plan; loop_steps="
-            f"{cfg.loop_steps}: a cache a pass) is served by "
-            f"InferenceEngine")
+            f"V a layer, and its step one token under the causal mask; a "
+            f"planned model (layer_plan; loop_steps={cfg.loop_steps}: a "
+            f"cache a pass; generation_block={cfg.generation_block}: a "
+            f"block of tokens a step under the block-causal mask) is "
+            f"served by InferenceEngine")
     if max_new_tokens <= 0:
         return jnp.zeros((B, 0), jnp.int32)
     s_max = S_p + max_new_tokens

@@ -415,7 +415,8 @@ def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            sm_scale=None, backend=None, k_scales=None,
-                           v_scales=None, layer=None, window=None):
+                           v_scales=None, layer=None, window=None,
+                           block_pass=False):
     """One decode step of paged attention: ``out[b, h] = softmax(q[b, h]
     · K[b]) · V[b]`` with K/V read through ``page_table[b]`` and masked
     at ``lengths[b]``.
@@ -438,6 +439,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     ``length - window``, so earlier pages are neither read nor need a
     live entry in the page table (the serving engine has given them
     back), and the call runs under the scope `ds.paged_decode_window`.
+    ``block_pass``: the call is a block pass's
+    (`InferenceEngine._plan_token_layers`), which brings a block's rows x
+    the query heads of a KV head as that KV head's group of "query heads":
+    the same kernel under the scope `ds.paged_decode_block`.
 
     backend: None = auto (Pallas kernel on TPU when
     `paged_decode_supported`, XLA fallback otherwise — CPU test runs
@@ -492,6 +497,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                                           window=window)
     if backend != "pallas":
         raise ValueError(f"unknown paged decode backend {backend!r}")
+    if block_pass:
+        return paged_decode_attention_pallas(
+            q, k_pages, v_pages, page_table, lengths, sm_scale,
+            k_scales=k_scales, v_scales=v_scales, layer=layer,
+            window=window, name="ds.paged_decode_block")
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, page_table, lengths, sm_scale,
         k_scales=k_scales, v_scales=v_scales, layer=layer, window=window,
@@ -515,11 +525,19 @@ def _write_group(page_size, dtype):
     return group if page_size % group == 0 else page_size
 
 
-def _kv_write_kernel(lyr_ref, page_ref, slot_ref, *refs):
+def _run_of(pools, rows):
+    """Rows a sequence `rows` bring: 1 ([B, H, ...]), or the length of a
+    run ([B, H, run, D], one dim more than a pool's row)."""
+    return rows[0].shape[2] if rows[0].ndim == pools[0].ndim - 1 else 1
+
+
+def _kv_write_kernel(lyr_ref, page_ref, slot_ref, *refs, run=1):
     """One batch row: each pool's tile (the [H, g, D] group of the row's
     page, or the scale pool's whole [H, ps] plane) comes in, gets the row
     at its slot, and goes back out to where it came from (the output
-    aliases the pool)."""
+    aliases the pool). `run` > 1: a run of that many rows a batch row, at
+    the slots from `slot` on (a block pass's; they lie in one group and
+    ride tiled over the group's slots, so slot s holds row s % run)."""
     n = len(refs) // 3
     slot = slot_ref[pl.program_id(0)]
     for row, pool, out in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
@@ -527,7 +545,13 @@ def _kv_write_kernel(lyr_ref, page_ref, slot_ref, *refs):
         slots = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
         # a select, not a dynamic one-row store: a row of a packed
         # (bf16 / int8) tile shares its sublane with its neighbours
-        out[...] = jnp.where(slots == slot % tile.shape[1], row[...], tile)
+        if run == 1:
+            out[...] = jnp.where(slots == slot % tile.shape[1], row[...],
+                                 tile)
+        else:
+            first = slot % tile.shape[1]
+            out[...] = jnp.where((slots >= first) & (slots < first + run),
+                                 row[...], tile)
 
 
 def paged_kv_write_pallas(pools, rows, layer, page_idx, slot):
@@ -546,6 +570,7 @@ def paged_kv_write_pallas(pools, rows, layer, page_idx, slot):
     n = len(pools)
     _LAST_BACKEND["kv_write_slots"] = _write_group(pools[0].shape[3],
                                                    pools[0].dtype)
+    run = _run_of(pools, rows)
 
     def pool_spec(pool):
         H, page_size, *D = pool.shape[2:]           # no D: a scale pool
@@ -557,13 +582,16 @@ def paged_kv_write_pallas(pools, rows, layer, page_idx, slot):
 
     def row_spec(pool):
         # rows ride as [B, H, 1(, D)]: the block's last two dims are the
-        # array's own, and the row broadcasts over the tile's slots
-        tile = (pool.shape[2], 1, *pool.shape[4:])
+        # array's own, and the row broadcasts over the tile's slots (a
+        # run of rows rides as [B, H, g, D], tiled over the group)
+        g = _write_group(pool.shape[3], pool.dtype) if run > 1 else 1
+        tile = (pool.shape[2], g, *pool.shape[4:])
         return pl.BlockSpec(
             (None, *tile), lambda b, lyr, pg, sl: (b, *(0,) * len(tile)))
 
     call = pl.pallas_call(
-        _kv_write_kernel,
+        _kv_write_kernel if run == 1 else functools.partial(
+            _kv_write_kernel, run=run),
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -577,8 +605,12 @@ def paged_kv_write_pallas(pools, rows, layer, page_idx, slot):
         compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=_interpret(), name="ds.kv_write",
     )
-    rows = [jnp.expand_dims(r.astype(p.dtype), 2)
-            for r, p in zip(rows, pools)]
+    if run == 1:
+        rows = [jnp.expand_dims(r.astype(p.dtype), 2)
+                for r, p in zip(rows, pools)]
+    else:
+        rows = [jnp.tile(r.astype(p.dtype), (1, 1, _write_group(
+            p.shape[3], p.dtype) // run, 1)) for r, p in zip(rows, pools)]
     return tuple(call(_layer_operand(layer), page_idx.astype(jnp.int32),
                       slot.astype(jnp.int32), *rows, *pools))
 
@@ -586,6 +618,13 @@ def paged_kv_write_pallas(pools, rows, layer, page_idx, slot):
 def paged_kv_write_xla(pools, rows, layer, page_idx, slot):
     """`paged_kv_write` as XLA scatters (on a TPU each would re-lay-out
     the whole stacked pool around itself: the kernel exists for that)."""
+    run = _run_of(pools, rows)
+    if run > 1:
+        slots = slot[:, None] + jnp.arange(run)
+        # advanced indices apart: their dims lead, [B, run, H, ...]
+        return tuple(p.at[layer, page_idx[:, None], :, slots].set(
+            jnp.moveaxis(r, 2, 1).astype(p.dtype))
+            for p, r in zip(pools, rows))
     return tuple(p.at[layer, page_idx, :, slot].set(r.astype(p.dtype))
                  for p, r in zip(pools, rows))
 
@@ -598,8 +637,12 @@ def paged_kv_write(pools, rows, layer, page_idx, slot, backend=None):
     ``pools`` are ``[L, P, H, page_size, ...]`` arrays (K and V data
     pools ``[..., D]``, a data pool first, and for int8 pages their
     ``[L, P, H, page_size]`` scale pools) and ``rows`` the matching
-    ``[B, H, ...]`` rows, cast to the pool's dtype here. Inactive batch
-    rows name the trash page 0. Returns the pools in order. Under jit
+    ``[B, H, ...]`` rows, cast to the pool's dtype here; or a RUN of rows
+    a sequence, ``[B, H, run, D]`` into the slots ``slot[b] ..
+    slot[b] + run - 1`` (a block pass's: `run` a power of two that
+    divides the pool's `_write_group`, `slot` a multiple of it, so a run
+    lies in one group and is one read-modify-write; plain pools only).
+    Inactive batch rows name the trash page 0. Returns the pools in order. Under jit
     with the pools donated the kernel rewrites B sublane groups a data
     pool (`_write_group` slots of a page each: `dispatch_report()`'s
     ``kv_write_slots``, the useful share of the write's traffic being one
@@ -612,8 +655,18 @@ def paged_kv_write(pools, rows, layer, page_idx, slot, backend=None):
     if len(pools) != len(rows):
         raise ValueError(f"{len(pools)} pools for {len(rows)} rows")
     B = page_idx.shape[0]
+    a_run = rows[0].ndim == pools[0].ndim - 1
+    run = _run_of(pools, rows)
     for p, r in zip(pools, rows):
-        if p.ndim < 4 or r.shape != (B, p.shape[2], *p.shape[4:]):
+        if a_run:
+            group = _write_group(p.shape[3], p.dtype)
+            if p.ndim != 5 or r.shape != (B, p.shape[2], run, p.shape[4]) \
+                    or run < 2 or group % run:
+                raise ValueError(
+                    f"rows {r.shape} are no run of rows for pool {p.shape}:"
+                    f" expected [{B}, H, run, D] for a [L, P, H, page_size,"
+                    f" D] pool, run >= 2 dividing its write group {group}")
+        elif p.ndim < 4 or r.shape != (B, p.shape[2], *p.shape[4:]):
             raise ValueError(
                 f"rows {r.shape} do not match pool {p.shape}: expected "
                 f"[{B}, H, ...] for a [L, P, H, page_size, ...] pool")

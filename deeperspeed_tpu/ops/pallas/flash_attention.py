@@ -962,13 +962,18 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 # still read 55 s and ran no faster than this body: PERF.md, PR 33). The
 # backward of a segmented call takes the new tile bodies.
 
-def _window_mask(s, qi, ki, block_q, block_k, window=None):
+def _window_mask(s, qi, ki, block_q, block_k, window=None, mask_block=0):
     """Key j is visible to query i iff j <= i, and under a `window` also
-    i - j < window."""
+    i - j < window. With `mask_block` (a power of two) the first rule is
+    BLOCK-causal: j <= i | (mask_block - 1), every key of the query's own
+    block of positions; a block divides every tile edge, so the tiles that
+    are launched are the causal rule's."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + \
         qi * block_q
     cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + \
         ki * block_k
+    if mask_block:
+        rows = rows | (mask_block - 1)
     seen = rows >= cols
     if window is not None:
         seen = seen & (rows - cols < window)
@@ -976,7 +981,8 @@ def _window_mask(s, qi, ki, block_q, block_k, window=None):
 
 
 def _fwd_segmented_kernel(*refs, sm_scale, causal, block_q, block_k,
-                          n_k=None, compact=False, window=None):
+                          n_k=None, compact=False, window=None,
+                          mask_block=0):
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -1018,7 +1024,7 @@ def _fwd_segmented_kernel(*refs, sm_scale, causal, block_q, block_k,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale    # [BQ, BK]
         if causal:
-            s = _window_mask(s, qi, ki, block_q, block_k, window)
+            s = _window_mask(s, qi, ki, block_q, block_k, window, mask_block)
         s = jnp.where(seg_eq, s, NEG_INF)
 
         m_prev = m_scr[:, :1]                                 # [BQ, 1]
@@ -1116,7 +1122,7 @@ def _tag_residuals(out, lse):
 @functools.cache
 def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
               use_mask, use_bias, dropout_rate, segmented, window,
-              interpret):
+              interpret, mask_block=0):
     """The tiled forward at one call signature: (the function of its
     inputs, its grid, its (masked, launched) tiles), built once a process
     (`_BODY_BUILDS`). Inputs in order: q, k, v as [B*H | B*G, S, D], then
@@ -1133,7 +1139,8 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
         kernel = functools.partial(_fwd_segmented_kernel, sm_scale=sm_scale,
                                    causal=causal, block_q=block_q,
                                    block_k=block_k, n_k=n_k,
-                                   compact=compact, window=window)
+                                   compact=compact, window=window,
+                                   mask_block=mask_block)
     else:
         kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                                    causal=causal, block_q=block_q,
@@ -1189,13 +1196,15 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
 
 def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
          layout=None, kbias=None, dropout_rate=0.0, seed=None, seg=None,
-         window=None):
+         window=None, mask_block=0):
     """`k` / `v` may hold fewer heads than `q` (G under H: query head i
     reads KV head ``i // (H / G)``, through the K and V index maps), and
     a `window` (causal only) keeps keys less than `window` positions
     behind their query: the compacted grid launches the band's tiles
-    alone, under the scope `ds.flash_fwd_window`. Both are the forward's
-    (a serving prefill's); the backward kernels take neither."""
+    alone, under the scope `ds.flash_fwd_window`. `mask_block` (segmented
+    and causal only) makes the diagonal BLOCK-causal (`_window_mask`).
+    All three are the forward's (a serving prefill's); the backward
+    kernels take none."""
     b, s, h, d = q.shape
     g = k.shape[2]
     block_q, block_k = _fit_block(block_q, s), _fit_block(block_k, s)
@@ -1228,7 +1237,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
     run, _LAST_GRIDS["fwd"], _LAST_MASKED["fwd"] = _fwd_call(
         b, s, h, g, d, q.dtype, block_q, block_k, causal, sm_scale,
         layout is not None, kbias is not None, dropout_rate,
-        seg is not None, window, _interpret())
+        seg is not None, window, _interpret(), mask_block)
     out, lse = run(qb, kb, vb, *_optional_inputs(seg, layout, kbias, seed,
                                                  dropout_rate))
     out, lse = _tag_residuals(out, lse)
@@ -1886,7 +1895,7 @@ _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention_segmented(q, k, v, segment_ids, causal=True,
                               sm_scale=None, block_q=None, block_k=None,
-                              bwd_blocks=None, window=None):
+                              bwd_blocks=None, window=None, mask_block=0):
     """Flash attention over PACKED ragged batches: tokens attend only
     within their own document (`segment_ids` [B, S] int32, 0 = pad —
     see `runtime.packing`), composed with the causal mask.
@@ -1909,12 +1918,20 @@ def flash_attention_segmented(q, k, v, segment_ids, causal=True,
     and / or a `window` (a static int; causal): key j is visible to
     query i iff ``j <= i and i - j < window``, the tiles wholly behind
     the window are never launched, and the call runs under the scope
-    `ds.flash_fwd_window`. The backward kernels compute neither, so a
+    `ds.flash_fwd_window`; and / or `mask_block` (a static power of two
+    up to 128; causal): the BLOCK-causal mask of a model that generates a
+    block of positions at a time, key j visible to query i iff
+    ``j // mask_block <= i // mask_block``, on the tiles the causal rule
+    launches. The backward kernels compute none of the three, so a
     gradient through such a call raises.
     """
     (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
                                     bwd_blocks)
-    if window is not None or k.shape[2] != q.shape[2]:
+    if mask_block and (not causal or mask_block & (mask_block - 1) or
+                       not 2 <= mask_block <= 128):
+        raise ValueError(f"mask_block {mask_block!r} needs causal "
+                         f"attention and a power of two from 2 to 128")
+    if window is not None or k.shape[2] != q.shape[2] or mask_block:
         if window is not None and (not causal or int(window) < 1):
             raise ValueError(f"window {window!r} needs causal attention "
                              f"and a positive int")
@@ -1927,28 +1944,29 @@ def flash_attention_segmented(q, k, v, segment_ids, causal=True,
             bq = _fit_block(min(bq, cap), q.shape[1]) or bq
             bk = _fit_block(min(bk, cap), q.shape[1]) or bk
         return _flash_serving(q, k, v, segment_ids, causal, sm_scale, bq,
-                              bk, window)
+                              bk, window, mask_block)
     return _flash_segmented(q, k, v, segment_ids, causal, sm_scale, bq,
                             bk, bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_serving(q, k, v, segment_ids, causal, sm_scale, block_q,
-                   block_k, window):
-    """The segmented forward with grouped KV heads and / or a window."""
+                   block_k, window, mask_block=0):
+    """The segmented forward with grouped KV heads, a window and / or a
+    block-causal mask."""
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     seg3 = segment_ids.astype(jnp.int32).reshape(
         segment_ids.shape[0], 1, -1)
     return _fwd(q, k, v, causal, scale, block_q, block_k, seg=seg3,
-                window=window)[0]
+                window=window, mask_block=mask_block)[0]
 
 
 def _flash_serving_fwd(*args):
     raise NotImplementedError(
-        "flash attention with grouped KV heads or a window has no "
-        "backward: the dq / dkv kernels compute full causal attention "
-        "with one KV head a query head (training of a planned block is "
-        "not built)")
+        "flash attention with grouped KV heads, a window or a block-causal "
+        "mask has no backward: the dq / dkv kernels compute full causal "
+        "attention with one KV head a query head (training of a planned "
+        "block is not built)")
 
 
 _flash_serving.defvjp(_flash_serving_fwd, lambda *a: None)

@@ -46,6 +46,10 @@ SCOPES = {
     "ds.paged_decode_window": ("kernel", "the paged decode of a window "
                                          "layer: the same kernel over the "
                                          "pages inside the window"),
+    "ds.paged_decode_block": ("kernel", "the paged decode of a block "
+                                        "pass: the same kernel, a block's "
+                                        "rows x a KV head's query heads "
+                                        "as that head's one group"),
     "ds.paged_decode_latent": ("kernel", "the absorbed paged decode of a "
                                          "latent layer: every head over "
                                          "ONE [page, row] tile a page"),
@@ -98,6 +102,10 @@ SCOPES = {
                               "step's row, written in place by the "
                               "kernel of this name"),
     "ds.sample": ("region", "sampling the next token from the logits"),
+    "ds.unmask": ("region", "a block pass after the head: each row's "
+                            "confidence (the softmax probability of its "
+                            "argmax), the choice among the masked rows, "
+                            "the block state's update, the commit flag"),
     "ds.layers": ("container", "the loop or scan over the blocks"),
     "ds.loop": ("container", "one pass of a looped model's stack: what is "
                              "in no inner scope is the loop's own "

@@ -433,8 +433,13 @@ def test_a_first_seen_prefill_bucket_is_compile_not_slow():
 
 
 def test_decode_stall_is_one_slow_step_not_in_device_wait():
+    """The injected stall is 0.2 s and the limits are one-sided or wide:
+    on a loaded machine a sleep overruns and a neighbouring step runs
+    tens of milliseconds late, which a stall of 0.05 s held to 10% took
+    for a second stall or a wrong length (the driver's run of PR 40)."""
+    stall = 0.2
     engine, cfg = tiny_server(inference={"fault_injection": {"faults": [
-        {"kind": "decode_stall", "step": 30, "seconds": 0.05}]}})
+        {"kind": "decode_stall", "step": 30, "seconds": stall}]}})
     engine.generate(prompts(cfg, 4), max_new_tokens=3)      # warm
     anomalies = []
     engine.telemetry = types.SimpleNamespace(
@@ -444,14 +449,14 @@ def test_decode_stall_is_one_slow_step_not_in_device_wait():
     for p in prompts(cfg, 4):
         engine.submit(p, max_new_tokens=48)
     serve_until_done(engine)
-    stalled = [s for s in engine.timeline.slow if s["excess"] > 0.04]
+    stalled = [s for s in engine.timeline.slow if s["excess"] > 0.75 * stall]
     assert len(stalled) == 1
     slow = stalled[0]
     assert slow["key"] == "decode x4" and not slow["compiled"]
-    assert slow["excess"] == pytest.approx(0.05, rel=0.1)
-    assert slow["held_by"].get("device_wait", 0.0) < 0.005
+    assert 0.95 * stall <= slow["excess"] < 1.5 * stall
+    assert slow["held_by"].get("device_wait", 0.0) < 0.1 * stall
     # the injector sleeps inside the step and under no span
-    assert slow["held_by"]["other"] == pytest.approx(0.05, rel=0.1)
+    assert 0.9 * stall <= slow["held_by"]["other"] <= slow["excess"]
     assert engine.stats["slow_step_excess_s"] >= slow["excess"]
     assert engine.stats["slow_steps"] == len(engine.timeline.slow)
     assert ("slow_step", slow["serial"]) in anomalies

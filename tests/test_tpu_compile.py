@@ -389,16 +389,22 @@ def test_paged_decode_is_one_call_over_live_pages(on_chip, cell):
     assert int(steps) * 4 < B * table_width
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_kv_write_compiles(on_chip, head_dim, quant):
+@pytest.mark.parametrize("quant,head_dim,run", [
+    (False, 64, 1), (False, 128, 1), (True, 64, 1), (True, 128, 1),
+    (False, 128, 4)],
+    ids=["bf16-64", "bf16-128", "int8-64", "int8-128", "bf16-128-run4"])
+def test_kv_write_compiles(on_chip, head_dim, quant, run):
     """The aliased row write: K and V (and for int8 pages their scale
     pools) in one call, the row's packed sublane group of its page a
     batch row: a [H, 16, D] block of a bf16 pool, [H, 32, D] of an int8
-    one, and the scale pool's whole [H, page] plane."""
-    B, H = 32, 16
-    pools = stacked(24, 401, H, 64, head_dim, quant)
-    rows = [((B, H) + shape[4:], dtype) for shape, dtype in pools]
+    one, and the scale pool's whole [H, page] plane. `run` 4: a block
+    pass's 4 rows a sequence (SDAR's shapes: 4 KV heads, 1,601 pages),
+    still one group a batch row."""
+    B, H = 32, 16 if run == 1 else 4
+    pools = stacked(24 if run == 1 else 6, 401 if run == 1 else 1601, H, 64,
+                    head_dim, quant)
+    rows = [((B, H) + ((run,) if run > 1 else ()) + shape[4:], dtype)
+            for shape, dtype in pools]
     index = [((), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32)]
 
     def write(layer, page_idx, slot, *leaves):
@@ -603,41 +609,152 @@ def test_moe_serving_programs_leave_the_experts_in_place(on_chip, v5e_2x2,
 # a planned model (Laguna-S-2.1) at its published widths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)],
-                         ids=["full_48", "window_72"])
-def test_grouped_window_paged_decode_compiles(on_chip, heads, window):
+@pytest.mark.parametrize("heads,window,G", [(48, None, 8), (72, 512, 8),
+                                            (128, None, 4)],
+                         ids=["full_48", "window_72", "block_4x32"])
+def test_grouped_window_paged_decode_compiles(on_chip, heads, window, G):
     """The paged kernel at Laguna's decode shapes: 48 (full) or 72
     (window 512) query heads over 8 KV heads of 128, batch 32, a table of
-    136 pages of 64, the layer a traced scalar."""
-    B, G, D, ps = 32, 8, 128, 64
-    pool = ((2, 289, G, ps, D), BF16)
+    136 pages of 64, the layer a traced scalar. And at a block pass's
+    (SDAR): a block's 4 rows x 8 query heads as a group of 32 rows under
+    each of 4 KV heads, a table of 48 pages, a pool of 1,601."""
+    B, D, ps = 32, 128, 64
+    pool = ((2, 289, G, ps, D) if G == 8 else (6, 1601, G, ps, D), BF16)
+    name = "ds.paged_decode_block" if G == 4 else \
+        "ds.paged_decode" if window is None else "ds.paged_decode_window"
 
     def decode(q, table, lengths, layer, k, v):
-        return decode_attention.paged_decode_attention_pallas(
-            q, k, v, table, lengths, D ** -0.5, layer=layer, window=window)
+        return decode_attention.paged_decode_attention(
+            q, k, v, table, lengths, D ** -0.5, backend="pallas",
+            layer=layer, window=window, block_pass=G == 4)
 
-    assert_kernel(on_chip(decode, ((B, heads, D), BF16),
-                          ((B, 136), jnp.int32), ((B,), jnp.int32),
-                          ((), jnp.int32), pool, pool))
+    text = on_chip(decode, ((B, heads, D), BF16),
+                   ((B, 136 if G == 8 else 48), jnp.int32),
+                   ((B,), jnp.int32), ((), jnp.int32), pool, pool)
+    assert_kernel(text)
+    assert kernel_names(text) == {name}
+    assert not pool_shaped_moves(text, pool[0])
 
 
-@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)],
-                         ids=["full_48", "window_72"])
-def test_grouped_window_flash_forward_compiles(on_chip, heads, window):
+@pytest.mark.parametrize("heads,window,block", [
+    (48, None, 0), (72, 512, 0), (32, None, 4)],
+    ids=["full_48", "window_72", "block_causal_32"])
+def test_grouped_window_flash_forward_compiles(on_chip, heads, window,
+                                               block):
     """The segmented forward at Laguna's prefill shapes: one row of 8,192
-    tokens, 48 or 72 query heads over 8 KV heads of 128."""
-    S, G, D = 8192, 8, 128
+    tokens, 48 or 72 query heads over 8 KV heads of 128. And under the
+    block-causal mask at SDAR's: a bucket of 2,048 tokens, 32 query heads
+    over 4 KV heads of 128, blocks of 4."""
+    S, G, D = (2048, 4, 128) if block else (8192, 8, 128)
 
     def prefill(q, k, v, seg):
         return fa.flash_attention_segmented(q, k, v, seg, True,
-                                            window=window)
+                                            window=window, mask_block=block)
 
     assert_kernel(on_chip(prefill, ((1, S, heads, D), BF16),
                           ((1, S, G, D), BF16), ((1, S, G, D), BF16),
                           ((1, S), jnp.int32)))
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+def _block_programs_hold_the_weights_once(v5e_2x2, program):
+    """The engine's block-pass and prefill programs for SDAR's block at
+    the published widths (hidden 2048, 32 query heads over 4 KV heads of
+    128 with a norm a head, 128 experts of width 768, 8 a token, the whole
+    vocabulary of 151,936; two layers) at the cell's shapes (32 sequences
+    x 4 rows, page 64, 1,601 pages, a window of 3,072, a bucket of
+    2,048), compiled for the described v5e from shapes alone. The paged
+    kernel runs under the block pass's name and the row write is there;
+    no instruction produces an array of the pool's or of the experts'
+    shape, none re-lays out an attention weight, and the engine's stack
+    is the caller's array: the weights are held once."""
+    from jax.sharding import SingleDeviceSharding
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
+                                                 LayerSpec)
+    batch, seqlen, page_size, layers, block = 32, 2048, 64, 2, 4
+    cfg = GPTNeoXConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=layers,
+        num_heads=32, num_kv_heads=4, max_seq_len=3072,
+        use_parallel_residual=False, norm="rmsnorm", use_bias=False,
+        qk_norm="head", hidden_act="silu", ffn_gated=True, ffn_width=768,
+        layernorm_eps=1e-6, attn_head_dim=128,
+        layer_plan=(LayerSpec(attn="full", heads=32, rotary_pct=1.0,
+                              rotary_base=1e6, ffn="experts"),) * layers,
+        moe_num_experts=128, moe_top_k=8, moe_dropless=True,
+        moe_norm_topk_prob=True, moe_expert_width=768,
+        generation_block=block, mask_token_id=151669)
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, BF16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page_size, "num_pages": 1601,
+        "max_seq_len": 3072, "max_batch_size": batch,
+        "token_budget": 2048 + batch * block, "prefill_lengths": [seqlen],
+        "prefill_batch_sizes": [1], "decode_batch_sizes": [batch]}})
+    pool = engine.cache.k
+    assert pool.shape == (layers, 1601, 4, page_size, 128)
+    assert engine.params_stacked is engine.params["stacks"]
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    shapes = functools.partial(jax.tree_util.tree_map, shape_of)
+    carry = ()
+    if program == "block_decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch, 2 * block + 1), ints(batch),
+                  ints(batch, engine.n_pages_max))
+        carry = (ints(batch, 2 * block + 1), ints(batch))
+        kernels = ("ds.paged_decode_block", "ds.kv_write",
+                   "ds.grouped_matmul")
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+        kernels = ("ds.flash_fwd", "ds.grouped_matmul")
+    text = fn.lower(
+        shapes(engine.params), shapes(engine.params_stacked), *inputs,
+        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        *carry).compile().as_text()
+    for name in kernels:
+        assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
+    assert "ds.attn_xla" not in text and "ds.paged_decode_xla" not in text
+    assert not re.search(r"%ds\.paged_decode[.\d]* = ", text)
+    expert_shaped = re.compile(
+        rf"bf16\[(?:\d,)?128,(?:2048,1536|768,2048)\]")
+    moved = [line[:120] for line in text.splitlines()
+             if (m := INSTRUCTION.match(line)) and
+             expert_shaped.search(m["type"]) and m["op"] not in CARRIES
+             and "tpu_custom_call" not in line]
+    assert not moved, moved
+    if program == "block_decode":
+        assert "ds.unmask" in text
+        assert not pool_shaped_moves(text, pool.shape)
+        # 128 rows under a hidden size of 2048 keep the projections to
+        # heads plain: no copy of a layer's q, k/v or output weight (the
+        # dots over [32, 4, 2048] rows are convolutions of window 1, which
+        # `attention_weight_relayouts` would take for the folded form)
+        weight = re.compile(
+            r"bf16\[(?:\d,)?(?:2048,4096|2048,1024|4096,2048)\]")
+        assert not [line[:120] for line in text.splitlines()
+                    if (m := INSTRUCTION.match(line)) and m["op"] == "copy"
+                    and weight.search(m["type"])]
+        assert "window={size=1}" in text and not re.search(
+            r"window=\{size=(?!1\})\d+\}.*ds\.attn", text)
+    else:
+        # no head in a block model's prefill: nothing of the vocabulary's
+        # width is computed
+        assert "ds.lm_head" not in text and \
+            not re.search(r"f32\[[\d,]*151936\]", text)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "block_decode",
+                                     "block_prefill"])
 def test_planned_serving_programs_compile_and_hold_the_weights_once(
         on_chip, v5e_2x2, program):
     """The engine's decode and prefill programs for Laguna's block at the
@@ -647,7 +764,11 @@ def test_planned_serving_programs_compile_and_hold_the_weights_once(
     vocabulary), five layers in the published order, compiled for the
     described v5e from shapes alone. Both attention kernels run under
     both names, no instruction's result has the shape of a kind's
-    experts, and the engine's stacks are the caller's arrays."""
+    experts, and the engine's stacks are the caller's arrays. `block_*`:
+    the same for a block-generating model
+    (`_block_programs_hold_the_weights_once`)."""
+    if program.startswith("block_"):
+        return _block_programs_hold_the_weights_once(v5e_2x2, program)
     from jax.sharding import SingleDeviceSharding
     from deeperspeed_tpu.inference import InferenceEngine
     from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
