@@ -31,7 +31,14 @@ def dispatch_report():
     folded: XLA's to place, a train step's; `gpt_neox._heads_dot`);
     ``decode_attention``:
     {"decode": backend, "decode_kv": pool dtype — "int8" when the paged
-    pools are quantized, "kv_write": backend of the one-token row
+    pools are quantized, "decode_heads_per_step" / "decode_pages_per_step":
+    the KV heads and the pages of a row one grid step of the paged kernel
+    moved at the last call traced (`decode_attention.step_geometry`: 16 and
+    2 at 16 heads of 128, 4 and 8 at SDAR's 4), "decode_scores": how a
+    step scored them, "per_head" (a KV head's group of query rows against
+    its own slots) or "collapsed" (all rows against all slots: one row a
+    head, int8 pages); the three are absent under XLA; "kv_write":
+    backend of the one-token row
     write, "kv_write_slots": the slots of a page that kernel read and
     wrote back for one row (the row's packed sublane group: 16 for bf16,
     32 for int8, 8 for float32, or the page; absent under XLA),

@@ -28,23 +28,35 @@ Contract (shared by kernel and XLA fallback):
   being decoded (its K/V must already be written to its page). A length
   of 0 marks an inactive (padding) batch row; its output is exact zero.
 
-Mechanics: a grid step moves one LIVE page of all the call's heads. A
-page of one layer is a contiguous [H, page_size, D] tile of the pool, so
-the K and V blocks are ``(None, None, Hb, page_size, D)`` with ``Hb`` =
-`heads_per_step`: the largest divisor of the call's head count whose
-step fits a fixed share of VMEM (H itself at every shape served so far).
-The grid is ``(H / Hb, steps)`` where ``steps`` is traced:
-`decode_steps` lists one step a live page, rows in order, and rides as
-scalar prefetch (`pltpu.PrefetchScalarGridSpec`) beside the page table,
-the lengths and the layer, so the index maps resolve step → (row, page)
-and the page-table indirection at DMA-issue time. Pages at or past a
-row's length, and whatever of the table's width no row uses, get no
-grid step and no DMA; an inactive row keeps one step, which names the
-trash page and writes its zeros. The step dimension is ``arbitrary``
-(it carries the online softmax: scratch m, l, acc for Hb heads, reset
-at a row's first page, written out as ``acc / l`` at its last). Inside
-a step the Hb queries meet the page as one [Hb * page_size, D] operand
-(`_page_update`). No backward exists: decode is inference.
+Mechanics: a grid step moves a SPAN of one row's live pages, of as many
+of the call's KV heads as fit, and its geometry follows the call's own
+shape (`step_geometry`: KV heads, query rows a KV head, a page's bytes,
+the pool's dtype, the table's width; no option). A page of one layer is
+a contiguous [H, page_size, D] tile of the pool, so a K or V block is
+``(None, None, Hb, page_size, D)`` with ``Hb`` the largest divisor of the
+head count whose step fits a fixed share of VMEM (H itself at every
+shape served so far), and a step takes the fewest pages whose K and V
+reach the bytes at which its fixed cost is amortised (`_STEP_MIN_BYTES`:
+two pages of 16 heads of 128, four of 8, eight of 4). The pools ride once a
+page of the step, each operand's index map resolving its own page. The
+grid is ``(H / Hb, steps)`` where ``steps`` is traced: `decode_steps`
+lists one step a span of live pages, rows in order, and rides as scalar
+prefetch (`pltpu.PrefetchScalarGridSpec`) beside the page table, the
+lengths and the layer, so the index maps resolve step → (row, page) and
+the page-table indirection at DMA-issue time. Pages past a row's last
+span, and whatever of the table's width no row uses, get no grid step
+and no DMA; a slot of a row's last span past its length names the page
+its operand already holds (no second fetch: the table a span reads has
+such slots resolved before the call, `span_table`) and is masked; an
+inactive row keeps one step, which names the trash page and writes its
+zeros. The step dimension is ``arbitrary`` (it carries the online
+softmax: scratch m, l, acc, reset at a row's first step, written out as
+``acc / l`` at its last). Inside a step the span's pages are joined
+along their slots and the scores take one of two forms, by the query rows
+a KV head: one row a head (and int8 pages) meets ALL the step's slots as
+one [Hb * S, D] operand (`_page_update`); a GROUP of rows a head (grouped
+KV heads, a block pass) meets its own head's slots in a head-batched
+matmul (`_group_update`). No backward exists: decode is inference.
 
 The decoded token's own K/V row gets into its page through
 `paged_kv_write`: on a TPU a second small kernel that ALIASES the stacked
@@ -79,6 +91,10 @@ _DIMSEM = CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 # paged_decode_attention call — the serving tests pin which path ran.
 _LAST_BACKEND = {}
 _DISPATCH_LOGGED = False
+# how the kernel's grid step engaged at the last call traced: KV heads and
+# pages of a row a step, and the score form (absent under XLA)
+_STEP_KEYS = ("decode_heads_per_step", "decode_pages_per_step",
+              "decode_scores")
 
 
 def _log_first_dispatch():
@@ -124,69 +140,129 @@ def _auto_backend(op, head_dim, page_size, quant):
 # compiler's own temporaries always have room
 _STEP_VMEM_BYTES = 4 * 2 ** 20
 
+# K and V bytes a grid step should move. A step's DMAs are one step ahead
+# and no more (a block has one or two buffers), so each step pays their
+# latency over its bytes' time, about 0.3 us on a v5e whatever the tile,
+# beside the scalar core's walk through every operand's index map. Timed
+# alone on a v5e (PERF.md section 6, PR 44): 16 heads of 128 (a page of K
+# and V 512 KiB) 182 us a call at one page a step, 154 at two, 160 at
+# four; 4 KV heads (128 KiB) 246 / 152 / 114 / 102 at 1 / 2 / 4 / 8;
+# 8 KV heads (256 KiB) 818 / 569 / 469 at 1 / 2 / 4.
+_STEP_MIN_BYTES = 2 ** 20
 
-def heads_per_step(heads, page_size, head_dim, pool_dtype, group=1):
-    """How many KV heads' K and V one grid step moves: the largest divisor
-    of `heads` (the call's own KV heads, so a model-parallel shard's
-    H / mp; `group` query heads read each) whose step fits
-    `_STEP_VMEM_BYTES`. One page of all heads is contiguous in
-    the pool ([H, page_size, D]), so the answer is H wherever it fits
-    (16 heads of 128 at page 64: 1.1 MiB) and the grid has no head
-    dimension to speak of; wider shapes split the heads and no other
-    path exists."""
+# the most pages of a row one step takes (operands a pool: the latent
+# kernel's 16 is the most this file has timed)
+_STEP_MAX_PAGES = 16
+
+
+def scores_per_head(group, pool_dtype):
+    """Whether a step scores each KV head's `group` query rows against
+    that head's own slots alone (`_group_update`) or all the step's rows
+    against all its slots at once (`_page_update`). A group makes full
+    vector registers of its own; one row a head does not, and int8 pages'
+    [Hb, slots] scale tiles lie as the collapsed form's columns do."""
+    return group > 1 and jnp.dtype(pool_dtype) != jnp.int8
+
+
+def step_geometry(heads, page_size, head_dim, pool_dtype, group=1,
+                  table_width=None):
+    """A grid step's geometry from the call's own shape: ``(heads,
+    pages)``, the KV heads whose K and V it moves and the pages of a row
+    it moves of them.
+
+    Heads: the largest divisor of `heads` (the call's own KV heads, so a
+    model-parallel shard's H / mp; `group` query rows read each) whose
+    one-page step fits `_STEP_VMEM_BYTES`. One page of all heads is
+    contiguous in the pool ([H, page_size, D]), so the answer is H
+    wherever it fits (16 heads of 128 at page 64: 1.1 MiB) and the grid
+    has no head dimension to speak of; wider shapes split the heads and
+    no other path exists.
+
+    Pages: the fewest whose K and V tiles reach `_STEP_MIN_BYTES` (8 at
+    4 KV heads of 128, 4 at 8, 2 at 16: bf16 pages of 64), no more than
+    fit `_STEP_VMEM_BYTES`, than `table_width` (the page table's) or
+    than `_STEP_MAX_PAGES`."""
     pool_dtype = jnp.dtype(pool_dtype)
     quant = pool_dtype == jnp.int8
+    per_head = scores_per_head(group, pool_dtype)
 
-    def step_bytes(hb):
-        slots = hb * page_size
+    def step_bytes(hb, pages=1):
+        slots = hb * pages * page_size
         tiles = 2 * slots * head_dim * pool_dtype.itemsize      # K and V
-        work = 2 * hb * group * slots * 4          # scores, probabilities
+        # scores, probabilities: a row meets its own head's slots, or all
+        work = 2 * group * slots * 4 * (1 if per_head else hb)
         if quant:
             tiles += 2 * slots * 2                 # their bf16 scale tiles
             work += 2 * slots * head_dim * 4       # K and V widened
+        if pages > 1:
+            work += tiles                          # the pages joined
         return 2 * tiles + work
 
     # an int8 pool's [Hb, page_size] scale block has the heads on its
     # sublanes: Mosaic takes it whole or in bf16 sublane tiles of 16
     tiles = [hb for hb in range(1, heads + 1)
              if heads % hb == 0 and (not quant or hb % 16 == 0 or hb == heads)]
-    return max([hb for hb in tiles
-                if step_bytes(hb) <= _STEP_VMEM_BYTES] or tiles[:1])
+    hb = max([hb for hb in tiles
+              if step_bytes(hb) <= _STEP_VMEM_BYTES] or tiles[:1])
+    most = min(-(-_STEP_MIN_BYTES // (2 * hb * page_size * head_dim *
+                                      pool_dtype.itemsize)),
+               table_width or _STEP_MAX_PAGES, _STEP_MAX_PAGES)
+    return hb, max([n for n in range(1, most + 1)
+                    if step_bytes(hb, n) <= _STEP_VMEM_BYTES] or [1])
+
+
+def _fold(s, live, m, l, acc, sm_scale, weigh):
+    """A step's score tile `s` (its LAST axis the step's slots, `live`
+    those that count) folded into the running softmax: the new
+    (m, l, acc). ``weigh(prob)`` is the step's probabilities times V."""
+    s = jnp.where(live, s * sm_scale, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    # a live step has a live slot in every row, so m_new is finite and
+    # l stays an exact count of live probability mass
+    prob = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    l_new = alpha * l + jnp.sum(prob, axis=-1, keepdims=True)
+    return m_new, l_new, acc * alpha + weigh(prob)
 
 
 def _page_update(q, k, v, k_scale, v_scale, m, l, acc, first_pos, length,
                  sm_scale, window=None):
-    """One page of `Hb` heads folded into the running softmax: returns
-    the new (m, l, acc).
+    """A step's slots of `Hb` heads folded into the running softmax, ALL
+    ROWS AGAINST ALL SLOTS (the form of one query row a head, and of int8
+    pages): returns the new (m, l, acc).
 
-    ``q`` [Hb * r, D]: the `r` query heads of each of the page's `Hb` KV
-    heads, a KV head's queries together (r = 1 where every query head has
-    its own); ``k``/``v`` [Hb, ps, D] (the page as it lies in the pool);
-    ``k_scale``/``v_scale`` [Hb, ps] for int8 pages, else None;
-    ``m``/``l`` [Hb * r, 1] and ``acc`` [Hb * r, D] float32. With
-    ``window`` a slot is live only if it lies among the last `window`
-    positions before `length`.
+    ``q`` [Hb * r, D]: the `r` query rows of each of the step's `Hb` KV
+    heads, a KV head's rows together (r = 1 where every query head has
+    its own); ``k``/``v`` [Hb, S, D]: the step's S slots a head (one page
+    as it lies in the pool, or a span of pages joined); ``k_scale``/
+    ``v_scale`` [Hb, S] for int8 pages, else None; ``m``/``l``
+    [Hb * r, 1] and ``acc`` [Hb * r, D] float32. With ``window`` a slot
+    is live only if it lies among the last `window` positions before
+    `length`.
 
-    The page is read as ONE [Hb * ps, D] operand (a free collapse of its
+    The tile is read as ONE [Hb * S, D] operand (a free collapse of its
     leading dims): every head's query meets every head's keys in a
-    single [Hb, Hb * ps] matmul and each row keeps its own head's ps
+    single [Hb * r, Hb * S] matmul and each row keeps its own head's S
     columns (the others are masked to -inf, their probabilities exact
     zeros, so the second matmul adds nothing of another head's V). The
     MXU does Hb times the needed work, which at one query row a head is
-    still nothing beside the page's bytes, and in exchange a step is two
+    still nothing beside the tile's bytes, and in exchange a step is two
     matmuls and one softmax over full vector registers instead of 2 * Hb
-    one-row matmuls and Hb quarter-filled softmaxes. Int8 pages: the
-    per-slot scales fold into the score / probability columns —
-    ``q·(k·s) == (q·k)·s`` — and the math runs float32."""
+    one-row matmuls and Hb eighth-filled softmaxes (timed: 0.357 against
+    0.454 ms, PR 27). At a GROUP of rows a head the exchange turns: Hb
+    times the softmax's registers are other heads' columns, and
+    `_group_update` is the form. Int8 pages: the per-slot scales fold
+    into the score / probability columns — ``q·(k·s) == (q·k)·s`` — and
+    the math runs float32."""
     hb, ps, d = k.shape
     r = q.shape[0] // hb
     if k_scale is not None:
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     s = jax.lax.dot_general(
         q, k.reshape(hb * ps, d), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)             # [Hb * r, Hb * ps]
-    # column c of query row h is slot c - (h // r) * ps of its KV head's
-    # page, if in [0, ps)
+        preferred_element_type=jnp.float32)             # [Hb * r, Hb * S]
+    # column c of query row h is slot c - (h // r) * S of its KV head's
+    # tile, if in [0, S)
     slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) - \
         (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // r) * ps
     live = (slot >= 0) & (slot < ps) & (first_pos + slot < length)
@@ -194,28 +270,48 @@ def _page_update(q, k, v, k_scale, v_scale, m, l, acc, first_pos, length,
         live = live & (first_pos + slot >= length - window)
 
     def own_columns(scale):
-        # [Hb, ps] -> [Hb * r, Hb * ps]: a query row's KV head's scales
+        # [Hb, S] -> [Hb * r, Hb * S]: a query row's KV head's scales
         # under each head's columns; only its own (the live ones) are used
         return jnp.tile(jnp.repeat(scale.astype(jnp.float32), r, axis=0),
                         (1, hb))
 
     if k_scale is not None:
         s = s * own_columns(k_scale)
-    s = jnp.where(live, s * sm_scale, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    # a live page has a live slot in every row, so m_new is finite and
-    # l stays an exact count of live probability mass
-    prob = jnp.where(live, jnp.exp(s - m_new), 0.0)
-    l_new = alpha * l + jnp.sum(prob, axis=1, keepdims=True)
-    if v_scale is not None:
-        prob = prob * own_columns(v_scale)
-    else:
-        prob = prob.astype(v.dtype)
-    pv = jax.lax.dot_general(
-        prob, v.reshape(hb * ps, d), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # [Hb, D]
-    return m_new, l_new, acc * alpha + pv
+
+    def weigh(prob):
+        if v_scale is not None:
+            prob = prob * own_columns(v_scale)
+        else:
+            prob = prob.astype(v.dtype)
+        return jax.lax.dot_general(
+            prob, v.reshape(hb * ps, d), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [Hb * r, D]
+
+    return _fold(s, live, m, l, acc, sm_scale, weigh)
+
+
+def _group_update(q, k, v, m, l, acc, first_pos, length, sm_scale,
+                  window=None):
+    """`_page_update` for a GROUP of query rows a KV head, EACH HEAD'S
+    ROWS AGAINST ITS OWN SLOTS: ``q`` [Hb, r, D] meets ``k``/``v``
+    [Hb, S, D] in a head-batched matmul, so the float32 score tile is
+    [Hb, r, S] with every column live but the tail past `length` (and
+    what lies before a `window`), and no head mask exists. ``m``/``l``
+    [Hb, r, 1], ``acc`` [Hb, r, D]."""
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)                 # [Hb, r, S]
+    pos = first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    live = pos < length
+    if window is not None:
+        live = live & (pos >= length - window)
+
+    def weigh(prob):
+        return jax.lax.dot_general(
+            prob.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)             # [Hb, r, D]
+
+    return _fold(s, live, m, l, acc, sm_scale, weigh)
 
 
 def _first_page(length, page_size, window):
@@ -227,19 +323,27 @@ def _first_page(length, page_size, window):
 
 
 def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
-                   k_ref, v_ref, *refs, sm_scale, page_size, window):
-    """Grid step `t` of a head group: one live page of one batch row —
-    the page's K and V tiles of `Hb` heads ([Hb, ps, D] each) meet the
-    row's `Hb` queries. `pt_ref` and `lyr_ref` (the page table and the
-    layer of the stacked pools) are read by the index maps alone. Int8
-    pools bring two more refs, the page's [Hb, ps] scale tiles, resolved
-    through the same maps as the data."""
-    *scale_refs, o_ref, m_scr, l_scr, acc_scr = refs
+                   *refs, sm_scale, page_size, pages, window, per_head):
+    """Grid step `t` of a head group: `pages` consecutive live pages of
+    one batch row — each page's K and V tiles of `Hb` heads ([Hb, ps, D],
+    an operand a page) joined into the step's [Hb, pages * ps, D] — meet
+    the row's queries, [Hb * r, D] (`_page_update`) or, `per_head`,
+    [Hb, r, D] (`_group_update`). `pt_ref` and `lyr_ref` (the page table
+    and the layer of the stacked pools) are read by the index maps alone.
+    Int8 pools bring their pages' [Hb, ps] scale tiles behind the data's,
+    resolved through the same maps. A slot past the row's length holds a
+    page the maps chose for costing nothing, and is masked."""
+    *tile_refs, o_ref, m_scr, l_scr, acc_scr = refs
     t = pl.program_id(1)
     b = row_ref[t]
     length = len_ref[b]
-    first_pos = (t - start_ref[b] +
+    first_pos = ((t - start_ref[b]) * pages +
                  _first_page(length, page_size, window)) * page_size
+
+    def span(refs):
+        # one pool's pages of the step, joined along their slots
+        tiles = [r[...] for r in refs]
+        return jnp.concatenate(tiles, axis=1) if pages > 1 else tiles[0]
 
     @pl.when(t == start_ref[b])
     def _init():
@@ -249,41 +353,66 @@ def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
 
     @pl.when(first_pos < length)         # not an inactive row's one step
     def _compute():
-        k_scale, v_scale = [r[...] for r in scale_refs] or (None, None)
-        m, l, acc = _page_update(
-            q_ref[...], k_ref[...], v_ref[...], k_scale, v_scale,
-            m_scr[:, :1], l_scr[:, :1], acc_scr[:], first_pos, length,
-            sm_scale, window)
+        k, v, *scales = [span(tile_refs[i:i + pages])
+                         for i in range(0, len(tile_refs), pages)]
+        carry = m_scr[..., :1], l_scr[..., :1], acc_scr[:]
+        if per_head:
+            m, l, acc = _group_update(q_ref[...], k, v, *carry, first_pos,
+                                      length, sm_scale, window)
+        else:
+            m, l, acc = _page_update(q_ref[...], k, v,
+                                     *scales or (None, None), *carry,
+                                     first_pos, length, sm_scale, window)
         m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
         acc_scr[:] = acc
 
-    @pl.when(first_pos + page_size >= length)        # the row's last step
+    @pl.when(first_pos + pages * page_size >= length)  # the row's last step
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[..., :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         # inactive rows (length 0) never accumulated: acc == 0 → out 0
         o_ref[...] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-def decode_steps(lengths, page_size, table_width, window=None):
-    """The kernel's work list: one grid step a LIVE page, rows in order
-    (a row of length 0 keeps one step, which writes its zeros); with a
-    `window`, a page is live only from the one that holds position
-    ``length - window`` on, so a row has at most
-    ``window / page_size + 1`` steps. Returns
-    ``(n_steps, row, start)``: the traced step count (at most
-    ``B * table_width``), ``row`` [B * table_width] — the batch row of
-    step `t`; entries at and past `n_steps` are never read — and
-    ``start`` [B], each row's first step, so step `t` is page
-    ``t - start[row[t]]`` of its row's live pages."""
+def decode_steps(lengths, page_size, table_width, window=None, pages=1):
+    """The kernel's work list: one grid step a span of `pages` LIVE pages,
+    rows in order (a row of length 0 keeps one step, which writes its
+    zeros; a row's last step may hold fewer live pages than the span);
+    with a `window`, a page is live only from the one that holds position
+    ``length - window`` on, so a row has at most ``window / page_size +
+    1`` of them. Returns ``(n_steps, row, start)``: the traced step count
+    (at most ``B * ceil(table_width / pages)``), ``row`` of that many
+    entries — the batch row of step `t`; entries at and past `n_steps`
+    are never read — and ``start`` [B], each row's first step, so step
+    `t` starts at page ``(t - start[row[t]]) * pages`` of its row's live
+    pages."""
     B = lengths.shape[0]
-    steps = jnp.maximum(-(-lengths // page_size) -
-                        _first_page(lengths, page_size, window), 1)
+    live = -(-lengths // page_size) - _first_page(lengths, page_size, window)
+    steps = jnp.maximum(-(-live // pages), 1)
     ends = jnp.cumsum(steps)
-    row = jnp.searchsorted(ends, jnp.arange(B * table_width, dtype=jnp.int32),
-                           side="right", method="compare_all")
+    row = jnp.searchsorted(
+        ends, jnp.arange(B * -(-table_width // pages), dtype=jnp.int32),
+        side="right", method="compare_all")
     return ends[-1], jnp.minimum(row, B - 1).astype(jnp.int32), ends - steps
+
+
+def span_table(page_table, lengths, page_size, pages, window=None):
+    """The page table as the kernel's steps read it, [B, table width +
+    pages - 1]: a slot past its row's length names the page its operand
+    held a step ago if that was the row's own (a block index that repeats
+    is not fetched again), else the trash page (an inactive row's one
+    step: a run of them fetches it once). Resolved here, once a call and
+    elementwise, so that an operand's index map is one lookup (a map that
+    also walked length -> live -> held was 90 bundles of scalar code an
+    operand and step in the compiler's listing, a lookup 40)."""
+    table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, pages - 1)))
+    page = jnp.arange(table.shape[1], dtype=jnp.int32)
+    held = jnp.pad(table, ((0, 0), (pages, 0)))[:, :table.shape[1]]
+    first = jnp.broadcast_to(_first_page(lengths, page_size, window),
+                             lengths.shape)
+    return jnp.where(page * page_size < lengths[:, None], table, jnp.where(
+        page - pages >= first[:, None], held, 0))
 
 
 def _layer_operand(layer):
@@ -305,64 +434,74 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         if quant:
             k_scales, v_scales = k_scales[None], v_scales[None]
     G, page_size = k_pages.shape[2], k_pages.shape[3]
-    r = H // G              # query heads a KV head (1: each its own)
-    hb = heads_per_step(G, page_size, D, k_pages.dtype, group=r)
+    table_width = page_table.shape[1]
+    r = H // G              # query rows a KV head (1: each its own)
+    hb, pages = step_geometry(G, page_size, D, k_pages.dtype, group=r,
+                              table_width=table_width)
+    per_head = scores_per_head(r, k_pages.dtype)
+    _LAST_BACKEND.update(zip(_STEP_KEYS, (
+        hb, pages, "per_head" if per_head else "collapsed")))
+    # the rows ride as [B, G / Hb, Hb * r, D] (a KV head's r queries lie
+    # together), or a group a head as [B, G / Hb, Hb, r, D], so that a
+    # block's last two dims are the array's own whatever Hb is; `None`
+    # dims are squeezed out of the kernel's refs
+    rows = (hb, r, D) if per_head else (hb * r, D)
 
     def row_block(g, t, row, start, pt, ln, lyr):
-        return row[t], g, 0, 0
+        return (row[t], g) + (0,) * len(rows)
 
-    def page_block(g, t, row, start, pt, ln, lyr):
-        b = row[t]
-        # an inactive row's step names the trash page, whatever its table
-        # row holds: a run of them fetches it once
-        page = jnp.where(
-            ln[b] > 0,
-            pt[b, t - start[b] + _first_page(ln[b], page_size, window)], 0)
-        return lyr[0], page, g, 0, 0
+    def page_block(j):
+        def block(g, t, row, start, pt, ln, lyr):
+            b = row[t]
+            page = (t - start[b]) * pages + j + \
+                _first_page(ln[b], page_size, window)
+            return lyr[0], pt[b, page], g, 0, 0     # `pt` is `span_table`
+        return block
 
-    # the rows ride as [B, G / Hb, Hb * r, D] (a KV head's r queries lie
-    # together) so that a block's last two dims are the array's own
-    # whatever Hb is; `None` dims are squeezed out of the kernel's refs
-    row_spec = pl.BlockSpec((None, None, hb * r, D), row_block)
-    pool_spec = pl.BlockSpec((None, None, hb, page_size, D), page_block)
-    in_specs = [row_spec, pool_spec, pool_spec]
-    args = [q.reshape(B, G // hb, hb * r, D), k_pages, v_pages]
+    row_spec = pl.BlockSpec((None, None, *rows), row_block)
+    in_specs = [row_spec] + [
+        pl.BlockSpec((None, None, hb, page_size, D), page_block(j))
+        for _ in range(2) for j in range(pages)]
+    args = [q.reshape(B, G // hb, *rows)] + [k_pages] * pages + \
+        [v_pages] * pages
     if quant:
         # the scale pool rides the SAME scalar-prefetch maps that resolve
         # the data pool's page indirection — one page id, two DMAs
-        scale_spec = pl.BlockSpec((None, None, hb, page_size),
-                                  lambda *a: page_block(*a)[:-1])
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [
+            pl.BlockSpec((None, None, hb, page_size),
+                         lambda *a, block=page_block(j): block(*a)[:-1])
+            for _ in range(2) for j in range(pages)]
         # scale pools stay at their storage dtype (bf16) on the wire;
         # the kernel widens each tile in VMEM — a whole-pool fp32
         # cast here would materialize a pool-sized copy every step
-        args += [k_scales, v_scales]
+        args += [k_scales] * pages + [v_scales] * pages
     with scopes.scope(name):
         lengths = lengths.astype(jnp.int32)
-        n_steps, row, start = decode_steps(lengths, page_size,
-                                           page_table.shape[1], window)
+        n_steps, row, start = decode_steps(lengths, page_size, table_width,
+                                           window, pages)
         out = pl.pallas_call(
             functools.partial(_decode_kernel, sm_scale=sm_scale,
-                              page_size=page_size, window=window),
-            out_shape=jax.ShapeDtypeStruct((B, G // hb, hb * r, D),
-                                           q.dtype),
+                              page_size=page_size, pages=pages,
+                              window=window, per_head=per_head),
+            out_shape=jax.ShapeDtypeStruct((B, G // hb, *rows), q.dtype),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
-                # the page dimension carries the online softmax; its
-                # extent is this call's own count of live pages
+                # the step dimension carries the online softmax; its
+                # extent is this call's own count of live spans
                 grid=(G // hb, n_steps),
                 in_specs=in_specs,
                 out_specs=row_spec,
                 scratch_shapes=[
-                    pltpu.VMEM((hb * r, LANES), jnp.float32),
-                    pltpu.VMEM((hb * r, LANES), jnp.float32),
-                    pltpu.VMEM((hb * r, D), jnp.float32),
+                    pltpu.VMEM((*rows[:-1], LANES), jnp.float32),
+                    pltpu.VMEM((*rows[:-1], LANES), jnp.float32),
+                    pltpu.VMEM(rows, jnp.float32),
                 ],
             ),
             compiler_params=_DIMSEM,
             interpret=_interpret(), name=name,
-        )(row, start, page_table.astype(jnp.int32), lengths,
-          _layer_operand(layer), *args)
+        )(row, start,
+          span_table(page_table, lengths, page_size, pages, window),
+          lengths, _layer_operand(layer), *args)
     return out.reshape(B, H, D)
 
 
@@ -490,6 +629,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     _LAST_BACKEND["decode_kv"] = "int8" if quant else str(k_pages.dtype)
     _log_first_dispatch()
     if backend == "xla":
+        for key in _STEP_KEYS:
+            _LAST_BACKEND.pop(key, None)
         return paged_decode_attention_xla(q, k_pages, v_pages, page_table,
                                           lengths, sm_scale,
                                           k_scales=k_scales,

@@ -447,22 +447,32 @@ def test_a_run_of_rows_is_written_in_place_as_one_group(dtype, run):
             2, page_idx, slot, backend="xla")
 
 
-def test_a_blocks_rows_ride_as_one_group_of_the_grouped_kernel():
+@pytest.mark.parametrize("G,rep,ps,lengths", [
+    (2, 2, 8, [20, 12, 4]),
+    # SDAR's own group: 4 KV heads, 4 rows x 8 query heads a KV head, pages
+    # of 64, a grid step of several pages: rows that end mid-span
+    (4, 8, 64, [170, 70, 4]),
+], ids=["2x8_page8", "sdar_4x32_page64"])
+def test_a_blocks_rows_ride_as_one_group_of_the_grouped_kernel(G, rep, ps,
+                                                               lengths):
     """The paged kernel (interpret mode) at a group of block x (query
     heads a KV head) rows a KV head, no mask inside the block, against a
     dense softmax over each sequence's first `length` positions."""
-    P, G, ps, D, B, rep = 9, 2, 8, 32, 3, 2
+    P, D, B = 9, 32, 3
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     k_pool = jax.random.normal(ks[0], (1, P, G, ps, D), jnp.float32)
     v_pool = jax.random.normal(ks[1], (1, P, G, ps, D), jnp.float32)
     q = jax.random.normal(ks[2], (B, BLOCK, G * rep, D), jnp.float32)
     table = jnp.asarray([[1, 2, 3], [4, 5, 0], [6, 0, 0]], jnp.int32)
-    lengths = jnp.asarray([20, 12, 4], jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     rows = jnp.swapaxes(q.reshape(B, BLOCK, G, rep, D), 1, 2).reshape(
         B, -1, D)
     got = da.paged_decode_attention(
         rows, k_pool, v_pool, table, lengths, backend="pallas",
         layer=jnp.asarray(0, jnp.int32), block_pass=True)
+    # a KV head's group met its own slots, a span of the table's 3 pages
+    assert da._LAST_BACKEND["decode_scores"] == "per_head"
+    assert da._LAST_BACKEND["decode_pages_per_step"] == 3
     got = jnp.swapaxes(got.reshape(B, G, BLOCK, rep, D), 1, 2).reshape(
         B, BLOCK, G * rep, D)
     for b in range(B):

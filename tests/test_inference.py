@@ -26,9 +26,11 @@ from deeperspeed_tpu.models.gpt2 import GPT2, GPT2Config
 from deeperspeed_tpu.models.gpt2 import forward as gpt2_forward
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.models.gpt_neox import forward as neox_forward
+from deeperspeed_tpu.ops import dispatch_report
+from deeperspeed_tpu.ops.pallas import decode_attention
 from deeperspeed_tpu.ops.pallas.decode_attention import (
-    decode_steps, heads_per_step, paged_decode_attention,
-    paged_decode_attention_xla)
+    decode_steps, paged_decode_attention, paged_decode_attention_xla,
+    span_table, step_geometry)
 from deeperspeed_tpu.runtime.config import parse_inference_block
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
 
@@ -47,17 +49,20 @@ def _rand_paged(rng, B, H, D, ps, NP, P):
     return q, kp, vp, jnp.asarray(pages, jnp.int32), pages
 
 
-def _dense_oracle(q, kp, vp, pages, lens, B, H, D, NP):
+def _dense_oracle(q, kp, vp, pages, lens, B, H, D, NP, window=None):
+    """Softmax attention over each row's first `lens[b]` cached tokens
+    (its last `window` of them), query head h over KV head h // (H / G)."""
+    r = H // np.asarray(kp).shape[1]
     out = []
     for b in range(B):
         L = int(lens[b])
         if L == 0:
             out.append(np.zeros((H, D), np.float32))
             continue
-        ks = np.concatenate([np.asarray(kp)[pages[b, i]]
-                             for i in range(NP)], axis=1)[:, :L]
-        vs = np.concatenate([np.asarray(vp)[pages[b, i]]
-                             for i in range(NP)], axis=1)[:, :L]
+        lo = max(L - window, 0) if window else 0
+        ks, vs = (np.repeat(np.concatenate(
+            [np.asarray(pool)[pages[b, i]] for i in range(NP)],
+            axis=1)[:, lo:L], r, axis=0) for pool in (kp, vp))
         s = np.einsum("hd,hsd->hs", np.asarray(q)[b],
                       ks) / np.sqrt(D)
         p = np.exp(s - s.max(-1, keepdims=True))
@@ -166,19 +171,43 @@ class TestDecodeAttentionKernel:
         (32, 64, 256, jnp.float32, 8),      # too wide: the heads split
         (64, 64, 128, jnp.int8, 32),        # int8: whole or 16s
         (24, 64, 128, jnp.int8, 24),        # no multiple of 16 divides it
+        # (heads, pages) a step, at (query rows a KV head, table width):
+        # SDAR's block pass, Laguna's two layer kinds, Pythia, Ouro: a
+        # step of 1 MiB of K and V
+        (4, 64, 128, jnp.bfloat16, (4, 8, 32, 48)),
+        (8, 64, 128, jnp.bfloat16, (8, 4, 6, 136)),
+        (8, 64, 128, jnp.bfloat16, (8, 4, 9, 136)),
+        (16, 64, 128, jnp.bfloat16, (16, 2, 1, 32)),
+        (16, 64, 128, jnp.bfloat16, (16, 2, 1, 5)),
+        # a page that is a step's bytes already: one
+        (16, 64, 256, jnp.bfloat16, (16, 1, 1, 32)),
+        # a table narrower than the span the bytes ask for
+        (4, 64, 128, jnp.bfloat16, (4, 3, 32, 3)),
+        # pages of 16 slots: no more than the most a step takes
+        (12, 16, 64, jnp.bfloat16, (12, 16, 1, 128)),
+        # int8 pages: half the bytes a slot, as many pages as fit the
+        # step's share of VMEM beside their widened copies
+        (16, 64, 128, jnp.int8, (16, 2, 1, 32)),
+        (24, 64, 128, jnp.int8, (24, 1, 1, 32)),
     ])
     def test_heads_per_step(self, H, ps, D, dtype, want):
-        hb = heads_per_step(H, ps, D, dtype)
-        assert hb == want and H % hb == 0
+        if isinstance(want, tuple):
+            *want, group, table = want
+            got = step_geometry(H, ps, D, dtype, group=group,
+                                table_width=table)
+            assert got == tuple(want)
+            return
+        hb, pages = step_geometry(H, ps, D, dtype)
+        assert hb == want and H % hb == 0 and pages >= 1
 
     @pytest.mark.parametrize("stacked", [False, True],
                              ids=["one_layer", "layer_of_stack"])
     def test_split_heads_match_xla(self, stacked):
         """A shape whose page of all heads does not fit a step: the grid
-        gets a head-group dimension, through `heads_per_step` alone."""
+        gets a head-group dimension, through `step_geometry` alone."""
         rng = np.random.default_rng(9)
         B, H, D, ps, NP, P = 3, 32, 256, 64, 3, 10
-        assert heads_per_step(H, ps, D, jnp.float32) < H
+        assert step_geometry(H, ps, D, jnp.float32)[0] < H
         q, kp, vp, pt, _ = _rand_paged(rng, B, H, D, ps, NP, P)
         lens = jnp.asarray([64 * 3, 0, 70], jnp.int32)
         kw = {}
@@ -191,6 +220,104 @@ class TestDecodeAttentionKernel:
         np.testing.assert_allclose(np.asarray(o_xla), np.asarray(o_pl),
                                    atol=1e-5)
         assert (np.asarray(o_pl)[1] == 0.0).all()
+
+    # page 16, table 8 wide: an inactive row, one token, a page edge,
+    # rows that end in every page of a span of 2 or 4 (mid-span, at a
+    # span's edge: 32, 64, 96), and a full table
+    SPANS = [0, 1, 16, 17, 33, 49, 64, 65, 81, 97, 113, 128]
+
+    @pytest.mark.parametrize("stacked", [False, True],
+                             ids=["one_layer", "layer_of_stack"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["fp32", "bf16"])
+    @pytest.mark.parametrize("G,r,pages,window", [
+        (4, 32, 4, None),       # SDAR's block pass: 4 rows x 8 query heads
+        (8, 6, 2, None),        # Laguna's full layers
+        (8, 9, 2, 40),          # Laguna's window layers
+        (8, 9, 4, 24),          # a window inside one span
+        (4, 1, 1, None),        # one row a head: a page a step
+        (4, 1, 2, None),        # one row a head: a span of two
+        (4, 1, 2, 40),
+    ], ids=["sdar_4x32_p4", "laguna_8x6_p2", "laguna_8x9_p2_window",
+            "group_8x9_p4_window", "mha_p1", "mha_p2", "mha_p2_window"])
+    def test_kernel_over_groups_spans_and_ragged_rows(
+            self, monkeypatch, G, r, pages, window, dtype, stacked):
+        """Each form of the grid step (a KV head's query group against its
+        own slots / all rows against all slots; one page / a span of
+        pages a step, chosen by bytes: the threshold is set so that the
+        shape asks for `pages`) against the XLA form and the dense
+        oracle, over rows that end in every slot of a span."""
+        rng = np.random.default_rng(G * r + pages)
+        B, ps, NP, D = len(self.SPANS), 16, 8, 64
+        H, P = G * r, len(self.SPANS) * 8 + 1
+        q, _, _, pt, pages_np = _rand_paged(rng, B, H, D, ps, NP, P)
+        kp, vp = (jnp.asarray(rng.normal(size=(P, G, ps, D)), jnp.float32)
+                  for _ in range(2))
+        lens = jnp.asarray(self.SPANS, jnp.int32)
+        # dead entries hold the trash page, as the scheduler pads them,
+        # and so do the pages a window has left behind
+        at = np.arange(NP)[None, :] * ps
+        live = at < np.asarray(lens)[:, None]
+        if window:
+            live &= at + ps > np.asarray(lens)[:, None] - window
+        pt = jnp.where(live, pt, 0)
+        ref = _dense_oracle(q, kp, vp, pages_np, lens, B, H, D, NP, window)
+        qc, kc, vc = (t.astype(dtype) for t in (q, kp, vp))
+        if stacked:
+            kc, vc = (jnp.stack([t[::-1], t, t * 0]) for t in (kc, vc))
+        monkeypatch.setattr(
+            decode_attention, "_STEP_MIN_BYTES",
+            pages * 2 * G * ps * D * jnp.dtype(dtype).itemsize)
+
+        def call(backend):
+            layer = {"layer": jnp.int32(1)} if stacked else {}
+            return paged_decode_attention(qc, kc, vc, pt, lens,
+                                          backend=backend, window=window,
+                                          **layer)
+
+        o_pl = call("pallas")
+        report = dispatch_report()["decode_attention"]
+        assert report["decode_pages_per_step"] == pages
+        assert report["decode_heads_per_step"] == G
+        assert report["decode_scores"] == \
+            ("per_head" if r > 1 else "collapsed")
+        o_xla = call("xla")
+        assert "decode_pages_per_step" not in \
+            dispatch_report()["decode_attention"]
+        assert o_pl.dtype == dtype and o_pl.shape == (B, H, D)
+        exact = dtype == jnp.float32
+        np.testing.assert_allclose(
+            np.asarray(o_pl, np.float32), np.asarray(o_xla, np.float32),
+            atol=5e-6 if exact else 2e-2)
+        np.testing.assert_allclose(ref, np.asarray(o_pl, np.float32),
+                                   atol=5e-6 if exact else 3e-2)
+        assert (np.asarray(o_pl, np.float32)[0] == 0.0).all()
+
+    @pytest.mark.parametrize("shape,want", [
+        # (B, H, KV heads, table width, block pass, window): SDAR's block
+        # pass, Laguna's full and window layers, Pythia's decode step
+        ((32, 128, 4, 48, True, None), (4, 8, "per_head")),
+        ((32, 48, 8, 136, False, None), (8, 4, "per_head")),
+        ((32, 72, 8, 136, False, 512), (8, 4, "per_head")),
+        ((32, 16, 16, 32, False, None), (16, 2, "collapsed")),
+    ], ids=["sdar_block", "laguna_full", "laguna_window", "pythia"])
+    def test_dispatch_report_names_the_step(self, shape, want):
+        """`dispatch_report()["decode_attention"]` says how the grid step
+        engaged at the last call traced, at the serve cells' own shapes
+        (bf16 pages of 64 x 128; traced, not run)."""
+        B, H, G, NP, block, window = shape
+        S = jax.ShapeDtypeStruct
+        pool = S((2, 9, G, 64, 128), jnp.bfloat16)
+        jax.eval_shape(
+            lambda q, k, v, pt, ln, layer: paged_decode_attention(
+                q, k, v, pt, ln, backend="pallas", layer=layer,
+                window=window, block_pass=block),
+            S((B, H, 128), jnp.bfloat16), pool, pool, S((B, NP), jnp.int32),
+            S((B,), jnp.int32), S((), jnp.int32))
+        report = dispatch_report()["decode_attention"]
+        assert (report["decode_heads_per_step"],
+                report["decode_pages_per_step"],
+                report["decode_scores"]) == want
 
     def test_decode_steps_lists_live_pages_only(self):
         lens = jnp.asarray([0, 1, 64, 65, 130, 256], jnp.int32)
@@ -205,6 +332,52 @@ class TestDecodeAttentionKernel:
         assert np.asarray(start).tolist() == [0, 1, 2, 3, 5, 8]
         full, _, _ = decode_steps(jnp.full((6,), 256, jnp.int32), 64, 4)
         assert int(full) == 6 * 4
+        # a step a SPAN of 2 pages: a row's last step may be half live
+        n, row, start = decode_steps(lens, 64, 4, pages=2)
+        assert int(n) == 1 + 1 + 1 + 1 + 2 + 2
+        assert row.shape == (6 * 2,)
+        assert np.asarray(row)[:8].tolist() == [0, 1, 2, 3, 4, 4, 5, 5]
+        assert np.asarray(start).tolist() == [0, 1, 2, 3, 4, 6]
+        # a span wider than the table: a step a row, one entry a row
+        n, row, _ = decode_steps(lens, 64, 4, pages=8)
+        assert int(n) == 6 and np.asarray(row).tolist() == list(range(6))
+        # under a window the spans count from the row's first live page:
+        # 130 tokens, window 64: pages 1 and 2 (one step of 2, two of 1);
+        # 256 tokens: page 3 alone
+        for pages, want in ((1, [1, 1, 1, 2, 2, 1]), (2, [1] * 6)):
+            n, _, start = decode_steps(lens, 64, 4, 64, pages)
+            assert int(n) == sum(want)
+            assert np.asarray(start).tolist() == \
+                np.cumsum([0] + want[:-1]).tolist()
+
+    def test_span_table_resolves_the_slots_past_a_row(self):
+        """What a step's operands fetch: a live slot its page; a slot past
+        the row's length the page its operand held a step ago (`pages`
+        entries back: no second fetch) where the row has such a step,
+        else the trash page; an inactive row the trash page alone."""
+        table = jnp.asarray([[11, 12, 13, 14, 15, 16],
+                             [21, 22, 0, 0, 0, 0],
+                             [31, 32, 33, 34, 35, 36],
+                             [7, 7, 7, 7, 7, 7]], jnp.int32)
+        lens = jnp.asarray([5 * 8, 10, 6 * 8, 0], jnp.int32)
+        got = np.asarray(span_table(table, lens, 8, 4))
+        assert got.shape == (4, 6 + 3)
+        # 5 live pages: the second step's slots 1-3 repeat pages 2-4
+        assert got[0].tolist() == [11, 12, 13, 14, 15, 12, 13, 14, 15]
+        # one step, two live: the rest of it is the trash page (entries
+        # no step reads are don't-care)
+        assert got[1, :4].tolist() == [21, 22, 0, 0]
+        assert got[2, :8].tolist() == [31, 32, 33, 34, 35, 36, 33, 34]
+        assert not got[3, :4].any()
+        # a window of 16: 40 tokens start at page 3 (pages 3 and 4, one
+        # step of 2), 41 tokens at page 3 too (pages 3, 4 and 5: the
+        # second step's dead slot repeats page 4); a held page is never
+        # one the window has left behind
+        got = np.asarray(span_table(table, jnp.asarray([40, 10, 41, 0]), 8,
+                                    2, window=16))
+        assert got[0, 3:5].tolist() == [14, 15]
+        assert got[2, 3:7].tolist() == [34, 35, 36, 35]
+        assert got[1, :2].tolist() == [21, 22]
 
     def test_shape_validation(self):
         rng = np.random.default_rng(4)

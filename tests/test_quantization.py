@@ -29,8 +29,10 @@ import deeperspeed_tpu
 from deeperspeed_tpu.compat import shard_map
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.ops.pallas import quant_matmul as qm
+from deeperspeed_tpu.ops import dispatch_report
+from deeperspeed_tpu.ops.pallas import decode_attention
 from deeperspeed_tpu.ops.pallas.decode_attention import (
-    heads_per_step, paged_decode_attention, paged_decode_attention_xla)
+    paged_decode_attention, paged_decode_attention_xla, step_geometry)
 from deeperspeed_tpu.inference.kv_cache import (PagedKVCache,
                                                 QuantizedPages,
                                                 quantize_kv)
@@ -283,13 +285,13 @@ class TestInt8KV:
     def test_int8_kernel_over_heads_and_ragged_rows(self, H, D, stacked):
         """The int8 kernel against its fallback and the unquantized
         attention, a page of all heads a step (64 heads: of 32, through
-        `heads_per_step`), over an inactive row, one token, an exact page
+        `step_geometry`), over an inactive row, one token, an exact page
         edge, a row ending mid-table beside two that fill it."""
         rng = np.random.default_rng(H)
         ps, NP, Pn = 32, 4, 32
         lengths = jnp.asarray([0, 1, 64, 70, 128, 128], np.int32)
         B = lengths.shape[0]
-        assert heads_per_step(H, ps, D, jnp.int8) == min(H, 32)
+        assert step_geometry(H, ps, D, jnp.int8)[0] == min(H, 32)
         q = jnp.asarray(rng.normal(size=(B, H, D)).astype(np.float32))
         k = jnp.asarray(rng.normal(size=(Pn, H, ps, D)).astype(np.float32))
         v = jnp.asarray(rng.normal(size=(Pn, H, ps, D)).astype(np.float32))
@@ -310,6 +312,53 @@ class TestInt8KV:
                                        backend=backend, k_scales=ks,
                                        v_scales=vs, **kw)
                 for backend in ("pallas", "xla"))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+        rel = float(jnp.max(jnp.abs(a - ref)) / jnp.max(jnp.abs(ref)))
+        assert rel < 0.05          # documented dequant tolerance
+        assert bool(jnp.all(a[0] == 0))     # inactive row exact zero
+
+    @pytest.mark.parametrize("stacked", [False, True],
+                             ids=["one_layer", "layer_of_stack"])
+    @pytest.mark.parametrize("G,r,pages", [(4, 1, 1), (4, 1, 2), (4, 1, 4),
+                                           (4, 6, 2), (2, 32, 4)],
+                             ids=["mha_p1", "mha_p2", "mha_p4", "group6_p2",
+                                  "group32_p4"])
+    def test_int8_kernel_over_spans_and_groups(self, monkeypatch, G, r,
+                                               pages, stacked):
+        """Int8 pages through a grid step of several pages (the scale
+        tiles ride the same maps, a page an operand, and join along their
+        slots) and under grouped KV heads, where they keep the collapsed
+        score form: rows that end in every page of a span, an inactive
+        row, one token, a full table."""
+        rng = np.random.default_rng(G * r + pages)
+        ps, NP, D = 32, 8, 64
+        lengths = jnp.asarray([0, 1, 32, 33, 70, 100, 128, 129, 200, 256],
+                              np.int32)
+        B, H, Pn = lengths.shape[0], G * r, 81
+        q = jnp.asarray(rng.normal(size=(B, H, D)).astype(np.float32))
+        k = jnp.asarray(rng.normal(size=(Pn, G, ps, D)).astype(np.float32))
+        v = jnp.asarray(rng.normal(size=(Pn, G, ps, D)).astype(np.float32))
+        pt = jnp.asarray(rng.permutation(np.arange(1, Pn))[:B * NP]
+                         .reshape(B, NP).astype(np.int32))
+        ref = paged_decode_attention_xla(q, k, v, pt, lengths,
+                                         1 / np.sqrt(D))
+        kq, ks = quantize_kv(k)
+        vq, vs = quantize_kv(v)
+        pools = [kq, vq, ks.astype(jnp.bfloat16), vs.astype(jnp.bfloat16)]
+        kw = {}
+        if stacked:
+            pools = [jnp.stack([t[::-1], t]) for t in pools]
+            kw = {"layer": 1}
+        kq, vq, ks, vs = pools
+        monkeypatch.setattr(decode_attention, "_STEP_MIN_BYTES",
+                            pages * 2 * G * ps * D)
+        a = paged_decode_attention(q, kq, vq, pt, lengths, backend="pallas",
+                                   k_scales=ks, v_scales=vs, **kw)
+        report = dispatch_report()["decode_attention"]
+        assert (report["decode_pages_per_step"],
+                report["decode_scores"]) == (pages, "collapsed")
+        b = paged_decode_attention(q, kq, vq, pt, lengths, backend="xla",
+                                   k_scales=ks, v_scales=vs, **kw)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
         rel = float(jnp.max(jnp.abs(a - ref)) / jnp.max(jnp.abs(ref)))
         assert rel < 0.05          # documented dequant tolerance

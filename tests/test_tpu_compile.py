@@ -360,9 +360,13 @@ def test_layer_indexed_paged_decode_compiles(on_chip, head_dim, quant, cell):
 def test_paged_decode_is_one_call_over_live_pages(on_chip, cell):
     """A decode call at a serve cell's shapes is ONE Mosaic custom call
     named `ds.paged_decode` (the roofline metric divides by the mean time
-    of one), whose grid has no (batch, head, page) product: a page of all
-    16 heads a step, and as many steps as the rows have live pages — at
-    most batch x table width, which only a batch of full tables reaches."""
+    of one), whose grid has no (batch, head, page) product. A step is
+    what `step_geometry` says of the call's own shape: here a SPAN OF TWO
+    pages of all 16 heads (1 MiB of K and V, the bytes at which a step's
+    fixed cost is amortised; 8 KV heads take 4 pages a step, 4 take 8),
+    all rows against all slots, and as many steps as the rows have live
+    spans — at most batch x half the table's width, which only a batch of
+    full tables reaches."""
     table_width, layers, pages = SERVE_CELLS[cell]
     decode, args = layer_indexed_decode(table_width, layers, pages, 128,
                                         False)
@@ -374,19 +378,24 @@ def test_paged_decode_is_one_call_over_live_pages(on_chip, cell):
         *[jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args])
     (grid,) = [eqn.params["grid_mapping"].grid for eqn in jaxpr.eqns
                if eqn.primitive.name == "pallas_call"]
-    assert decode_attention.heads_per_step(16, 64, 128, BF16) == 16
+    assert decode_attention.step_geometry(
+        16, 64, 128, BF16, table_width=table_width) == (16, 2)
+    assert dispatch_report()["decode_attention"]["decode_scores"] == \
+        "collapsed"
     # (head groups, steps): one group, and a step count read at run time
     assert len(grid) == 2 and grid[0] == 1 and not isinstance(grid[1], int)
     B = 32
     worst, _, _ = decode_attention.decode_steps(
-        jnp.full((B,), table_width * 64, jnp.int32), 64, table_width)
-    assert int(worst) == B * table_width
+        jnp.full((B,), table_width * 64, jnp.int32), 64, table_width,
+        pages=2)
+    assert int(worst) == B * table_width // 2
     lengths = CELL_CONTEXTS[cell]
     steps, _, _ = decode_attention.decode_steps(
-        jnp.asarray(lengths, jnp.int32), 64, table_width)
-    # a step a live page, one for an inactive row: 224 of 1,024; 336 of 2,048
-    assert int(steps) == sum(max(1, -(-n // 64)) for n in lengths)
-    assert int(steps) * 4 < B * table_width
+        jnp.asarray(lengths, jnp.int32), 64, table_width, pages=2)
+    # a step a live span, one for an inactive row: 128 of 1,024 pages'
+    # worth; 184 of 2,048
+    assert int(steps) == sum(max(1, -(-n // 128)) for n in lengths)
+    assert int(steps) * 8 <= B * table_width
 
 
 @pytest.mark.parametrize("quant,head_dim,run", [
@@ -617,7 +626,8 @@ def test_grouped_window_paged_decode_compiles(on_chip, heads, window, G):
     (window 512) query heads over 8 KV heads of 128, batch 32, a table of
     136 pages of 64, the layer a traced scalar. And at a block pass's
     (SDAR): a block's 4 rows x 8 query heads as a group of 32 rows under
-    each of 4 KV heads, a table of 48 pages, a pool of 1,601."""
+    each of 4 KV heads, a table of 48 pages, a pool of 1,601. Each is ONE
+    Mosaic call (a pool rides once a page of the step: still one call)."""
     B, D, ps = 32, 128, 64
     pool = ((2, 289, G, ps, D) if G == 8 else (6, 1601, G, ps, D), BF16)
     name = "ds.paged_decode_block" if G == 4 else \
@@ -631,9 +641,15 @@ def test_grouped_window_paged_decode_compiles(on_chip, heads, window, G):
     text = on_chip(decode, ((B, heads, D), BF16),
                    ((B, 136 if G == 8 else 48), jnp.int32),
                    ((B,), jnp.int32), ((), jnp.int32), pool, pool)
-    assert_kernel(text)
+    # ONE custom call under the kind's name, the pools read where they lie
+    assert text.count("tpu_custom_call") == 1
     assert kernel_names(text) == {name}
     assert not pool_shaped_moves(text, pool[0])
+    # a step is 1 MiB of K and V (8 pages of 4 KV heads, 4 of 8), and a
+    # KV head's query group meets its own slots alone
+    step = dispatch_report()["decode_attention"]
+    assert (step["decode_heads_per_step"], step["decode_pages_per_step"],
+            step["decode_scores"]) == (G, 32 // G, "per_head")
 
 
 @pytest.mark.parametrize("heads,window,block", [
