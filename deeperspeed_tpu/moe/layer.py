@@ -546,11 +546,75 @@ def dropless_geometry(tokens, top_k, n_experts):
     return ragged_buffer_rows(tokens * top_k, n_experts, bm), bm
 
 
+# pairs a block of the running count: a pair meets the 127 before it in
+# one compare, the blocks' totals run down a short cumsum
+_RANK_BLOCK = 128
+
+
+def dropless_plan(pair_expert, n_experts, block_m, rows):
+    """The ragged layout's plan, COUNTED: the pairs are never sorted by
+    expert.
+
+    pair_expert [P] int32: the held expert of pair p = t * k + j, or the
+    sentinel `n_experts` for a pair that owns no row (a padded token's,
+    an absent expert's). Returns (counts [E], tile_expert, tile_rows,
+    starts [E] as `ragged_tile_maps` gives them, pair_row [P]: the
+    buffer row of each pair (`rows` for a sentinel pair), src [rows]:
+    the pair of each buffer row (P for a padding row)).
+
+    Inside a group the rows keep pair order, which is the order a stable
+    sort by expert gives: a pair's row is its group's start plus its
+    RANK, the number of earlier pairs of the same expert. The rank is
+    counted in two levels: inside a block of `_RANK_BLOCK` pairs by
+    comparing the block with itself, and across blocks by the running
+    sum of the blocks' per-expert totals, [P / 128, E].
+
+    `src` inverts `pair_row`, and is the one operation left that moves
+    integers by index: ONE sort of the pairs' rows together with the
+    padding rows' own numbers, after which every buffer row is named
+    once, in order. (A scatter of the pairs' numbers at their rows takes
+    one dynamic index a pair, which a TPU runs one after the other: it
+    is 0-6 us of 55 ahead of the sort at a decode step's pairs and costs
+    1.4-1.7x the sort at a prefill's, PERF.md section 6, PR 45.)"""
+    from ..ops.pallas.grouped_matmul import _PLANS_TRACED, ragged_tile_maps
+    P, E, B = pair_expert.shape[0], int(n_experts), _RANK_BLOCK
+    nb = -(-P // B)
+    pe = pair_expert
+    if nb * B != P:
+        pe = jnp.concatenate([pe, jnp.full((nb * B - P,), E, jnp.int32)])
+    pe = pe.reshape(nb, B)
+    i = jnp.arange(B, dtype=jnp.int32)
+    earlier = (i[None, :] < i[:, None])[None]                 # [1, i, j<i]
+    rank = jnp.sum((pe[:, :, None] == pe[:, None, :]) & earlier, axis=2,
+                   dtype=jnp.int32)                           # [nb, B]
+    # compared where it is used, twice: a [P, E] one-hot is never stored
+    onehot = pe[:, :, None] == jnp.arange(E, dtype=jnp.int32)
+    totals = jnp.sum(onehot, axis=1, dtype=jnp.int32)         # [nb, E]
+    before = jnp.cumsum(totals, axis=0) - totals
+    counts = before[-1] + totals[-1]
+    tile_expert, tile_rows, starts = ragged_tile_maps(counts, block_m,
+                                                      rows // block_m)
+    first = (starts[None, :] + before)[:, None, :]            # [nb, 1, E]
+    row = jnp.sum(jnp.where(onehot, first, 0), axis=2) + rank
+    pair_row = jnp.where(pe < E, row, rows).reshape(nb * B)[:P]
+    # a row past its tile's live rows is padding and names itself; the
+    # keys under `rows` are then 0 .. rows - 1, each once
+    lane = jnp.arange(block_m, dtype=jnp.int32)
+    padding = (lane[None, :] >= tile_rows[:, None]).reshape(rows)
+    keys = jnp.concatenate([pair_row, jnp.where(
+        padding, jnp.arange(rows, dtype=jnp.int32), rows)])
+    values = jnp.concatenate([jnp.arange(P, dtype=jnp.int32),
+                              jnp.full((rows,), P, jnp.int32)])
+    src = jax.lax.sort((keys, values), num_keys=1, is_stable=False)[1][:rows]
+    _PLANS_TRACED["counted"] = _PLANS_TRACED.get("counted", 0) + 1
+    return counts, tile_expert, tile_rows, starts, pair_row, src
+
+
 def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                      activation=jax.nn.silu, token_mask=None,
                      gmm_backend=None, held=None, scale=1.0,
                      score="softmax"):
-    """Top-k routing that drops nothing, through the sort engine.
+    """Top-k routing that drops nothing, through the grouped matmul.
 
     params: {"gate" [H, E] (the router), "w_in" [E, H, 2I] (each
     expert's gate and up projections, [gate | up] along the last dim),
@@ -563,8 +627,9 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                      only if `norm_topk_prob`
         y = sum_j p_j * (act(x Wgate[e_j]) * (x Wup[e_j])) Wdown[e_j]
 
-    The T*top_k (token, expert) rows are sorted by expert into ONE
-    buffer whose groups have their real lengths, each padded to a whole
+    The T*top_k (token, expert) rows are laid out by expert in ONE
+    buffer, a group's rows in pair order (`dropless_plan`: counted, not
+    sorted), whose groups have their real lengths, each padded to a whole
     row tile (`ops.pallas.grouped_matmul`, ragged layout): no capacity,
     no `capacity_factor`. `token_mask` [T] marks real tokens: a padded
     row (a prefill bucket's tail, an inactive decode row) is routed to no
@@ -598,7 +663,7 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
     mean of s / sum_e s.
     """
     from .. import scopes
-    from ..ops.pallas.grouped_matmul import ragged_matmul, ragged_tile_maps
+    from ..ops.pallas.grouped_matmul import ragged_matmul
     T, H = x.shape
     E_all = params["gate"].shape[1]          # the experts the router scores
     lo, hi = held if held is not None else (0, E_all)
@@ -644,33 +709,22 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
 
     with scopes.scope("ds.moe_dispatch"):
         # pair p = t*k + j; a padded token's pairs go to the sentinel E,
-        # which sorts last and owns no buffer row
+        # which owns no buffer row
         experts = experts.astype(jnp.int32)
         here = live[:, None] & (experts >= lo) & (experts < hi)
         pair_expert = jnp.where(here, experts - lo, E).reshape(T * k)
-        order = jnp.argsort(pair_expert)                      # stable
-        counts = jnp.zeros((E + 1,), jnp.int32).at[pair_expert].add(1)[:E]
-        tile_expert, tile_rows, starts = ragged_tile_maps(
-            counts, bm, R // bm)
-        sorted_expert = pair_expert[order]
-        begin = jnp.cumsum(counts) - counts       # first sorted pair of e
-        e_safe = jnp.minimum(sorted_expert, E - 1)
-        dest = starts[e_safe] + jnp.arange(T * k, dtype=jnp.int32) - \
-            begin[e_safe]
-        dest = jnp.where(sorted_expert < E, dest, R)          # R: nowhere
-        # buffer row -> source pair (T*k: a padding row), pair -> row
-        src = jnp.full((R,), T * k, jnp.int32).at[dest].set(
-            order.astype(jnp.int32), mode="drop")
-        pair_row = jnp.zeros((T * k,), jnp.int32).at[order].set(dest)
+        counts, tile_expert, tile_rows, _, pair_row, src = dropless_plan(
+            pair_expert, E, bm, R)
         buf = jnp.where((src < T * k)[:, None],
                         x[jnp.minimum(src, T * k - 1) // k], 0)
         if held is None:
             stats = jnp.stack([counts.astype(jnp.float32) /
                                jnp.maximum(jnp.sum(counts), 1), mean_prob])
         else:
-            routed = jnp.zeros((E_all + 1,), jnp.float32).at[
-                jnp.where(live[:, None], experts, E_all).reshape(T * k)
-            ].add(1.0)[:E_all]
+            routed = jnp.sum(
+                (jnp.where(live[:, None], experts, E_all).reshape(T * k, 1)
+                 == jnp.arange(E_all, dtype=jnp.int32)),
+                axis=0, dtype=jnp.int32).astype(jnp.float32)
             stats = jnp.stack([routed / jnp.maximum(jnp.sum(routed), 1.0),
                                mean_prob, routed])
 

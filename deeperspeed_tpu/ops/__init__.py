@@ -45,8 +45,13 @@ def dispatch_report():
     "kv_write_latent" / "kv_write_latent_slots": the same of a latent
     layer's row write}; ``quant_matmul`` / ``grouped_matmul``:
     {name: backend}. A backend is "pallas" (the kernel, interpreted off
-    a TPU) or "xla". ``xla_on_tpu`` names every dispatcher that, on a
-    TPU, took XLA where it has a kernel (`note_xla_on_tpu`).
+    a TPU) or "xla". ``moe``: {"plan": {"counted": n}}, the traces of
+    the dropless MoE layer in this process by the form the ragged
+    layout's plan took (counted: each pair's row from its rank among its
+    expert's pairs, never a sort by expert; the buffer row -> pair map
+    by one sort of the rows: `moe.layer.dropless_plan`); empty until
+    that layer is traced. ``xla_on_tpu`` names every dispatcher
+    that, on a TPU, took XLA where it has a kernel (`note_xla_on_tpu`).
     """
     from .pallas.decode_attention import _LAST_BACKEND
     from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
@@ -54,6 +59,7 @@ def dispatch_report():
                                          _LAST_BLOCKS, _LAST_MASKED,
                                          _XLA_NOTED)
     from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
+    from .pallas.grouped_matmul import _PLANS_TRACED
     from .pallas.quant_matmul import _LAST_BACKEND as _QMM_BACKEND
     return {"flash": dict(_LAST_BLOCKS, masked_tiles=dict(_LAST_MASKED),
                           bodies_built={k: (n, round(t, 3)) for k, (n, t)
@@ -63,6 +69,7 @@ def dispatch_report():
             "decode_attention": dict(_LAST_BACKEND),
             "quant_matmul": dict(_QMM_BACKEND),
             "grouped_matmul": dict(_GMM_BACKEND),
+            "moe": {"plan": dict(_PLANS_TRACED)},
             "xla_on_tpu": sorted(_XLA_NOTED)}
 
 

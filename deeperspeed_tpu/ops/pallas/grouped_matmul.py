@@ -70,6 +70,11 @@ _DIMSEM = CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 # the same record `decode_attention._LAST_BACKEND` keeps.
 _LAST_BACKEND = {}
 
+# `ops.dispatch_report()["moe"]["plan"]`: the traces of the dropless MoE
+# layer in this process by the form of the ragged layout's plan
+# (`moe.layer.dropless_plan` bumps it)
+_PLANS_TRACED = {}
+
 
 class LayerOf(NamedTuple):
     """Layer `layer` (a traced int32 scalar) of expert weights stacked
@@ -183,19 +188,31 @@ def ragged_tile_maps(counts, block_m, n_tiles):
     starts at row `starts[e]` (a tile boundary) and owns
     ceil(counts[e] / block_m) tiles. Returns (tile_expert, tile_rows,
     starts). Tiles past the last group have no rows and point at the
-    last live tile's expert, so they move no weight."""
+    last live tile's expert, so they move no weight.
+
+    Every table lookup here is a compare-and-sum over [n_tiles, E] or
+    [E, E]: on a TPU an operation that takes a dynamic index an element
+    (a search's steps, a gather of `n_tiles` ids) costs more than the
+    whole table compared at once (PERF.md section 6, PR 45)."""
     counts = counts.astype(jnp.int32)
+    e = jnp.arange(counts.shape[0], dtype=jnp.int32)
     tiles = (counts + block_m - 1) // block_m             # [E]
-    ends = jnp.cumsum(tiles)
+    ends = jnp.sum(jnp.where(e[None, :] <= e[:, None], tiles[None, :], 0),
+                   axis=1)                                # running sum
     first = ends - tiles                                  # first tile of e
     m = jnp.arange(n_tiles, dtype=jnp.int32)
     live = m < ends[-1]
     last_live = jnp.maximum(ends[-1] - 1, 0)
-    owner = jnp.searchsorted(ends, jnp.where(live, m, last_live),
-                             side="right").astype(jnp.int32)
+    # the group whose tiles hold m: the groups that end at or before it
+    owner = jnp.sum(jnp.where(live, m, last_live)[:, None] >= ends[None, :],
+                    axis=1, dtype=jnp.int32)
     owner = jnp.minimum(owner, counts.shape[0] - 1)
-    rows = jnp.clip(counts[owner] - (m - first[owner]) * block_m, 0, block_m)
-    return owner, jnp.where(live, rows, 0), first * block_m
+    rows = jnp.sum(jnp.where(owner[:, None] == e[None, :],
+                             counts[None, :] -
+                             (m[:, None] - first[None, :]) * block_m, 0),
+                   axis=1)
+    return owner, jnp.where(live, jnp.clip(rows, 0, block_m), 0), \
+        first * block_m
 
 
 # ---------------------------------------------------------------------------

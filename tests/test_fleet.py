@@ -751,8 +751,11 @@ class TestDispatchReport:
         from deeperspeed_tpu.ops import dispatch_report
         report = dispatch_report()
         assert set(report) == {"flash", "attention", "decode_attention",
-                               "quant_matmul", "grouped_matmul",
+                               "quant_matmul", "grouped_matmul", "moe",
                                "xla_on_tpu"}
+        # the dropless MoE layers traced so far, by the form of their plan
+        assert set(report["moe"]) == {"plan"}
+        assert set(report["moe"]["plan"]) <= {"counted"}
         assert isinstance(report["flash"], dict)
         # the attention projections traced so far, by their form
         assert set(report["attention"]["head_projection"]) == \

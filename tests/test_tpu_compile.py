@@ -1134,6 +1134,42 @@ def test_ragged_grouped_matmul_compiles_at_olmoe_shapes(on_chip, tokens):
         assert_kernel(on_chip(grad, *args), at_least=5)
 
 
+@pytest.mark.parametrize(
+    "tokens,k,e_all,held,h,inter",
+    [(32, 8, 64, None, 2048, 1024), (8192, 10, 256, (0, 128), 3072, 1024),
+     (16384, 4, 64, None, 2048, 1536)],
+    ids=["olmoe_decode_32", "laguna_prefill_8192", "glm_prefill_16384"])
+def test_dropless_layer_moves_integers_by_index_once(on_chip, tokens, k,
+                                                     e_all, held, h, inter):
+    """The whole dropless layer at a decode step's rows and at the two
+    largest prefills of the serving cells (81,920 and 65,536 pairs): the
+    ragged layout's plan is counted (`moe.layer.dropless_plan`), so
+    beyond the router's `top_k` the program the chip's compiler emits
+    holds ONE sort (the buffer's rows, for `src`), no scatter, and the
+    two grouped matmuls."""
+    from deeperspeed_tpu.moe.layer import moe_ffn_dropless
+    E = held[1] - held[0] if held else e_all
+
+    def count(op, text):
+        return len(re.findall(rf" {op}\(", text))
+
+    def layer(x, gate, w_in, w_out, mask):
+        return moe_ffn_dropless({"gate": gate, "w_in": w_in, "w_out": w_out},
+                                x, k, norm_topk_prob=True, token_mask=mask,
+                                gmm_backend="pallas", held=held)
+
+    def routed(x, gate):                 # what `top_k` alone compiles to
+        return jax.lax.top_k(jax.nn.softmax(
+            x.astype(jnp.float32) @ gate, axis=-1), k)
+
+    args = [((tokens, h), BF16), ((h, e_all), jnp.float32)]
+    text = on_chip(layer, *args, ((E, h, 2 * inter), BF16),
+                   ((E, inter, h), BF16), ((tokens,), jnp.bool_))
+    assert_kernel(text, at_least=2)
+    sorts = count("sort", text) - count("sort", on_chip(routed, *args))
+    assert (sorts, count("scatter", text)) == (1, 0)
+
+
 @pytest.mark.parametrize("m,k,n", [(8, 768, 3072), (256, 768, 3072),
                                    (8, 6144, 24576)],
                          ids=["decode_768x3072", "prefill_768x3072",
