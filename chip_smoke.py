@@ -307,8 +307,14 @@ def phase_serve(cfg, seed, ckpt_dir, prompt_lens, max_new, inference, log):
         rows[b, :len(p) + len(g)] = p + g
     positions = np.asarray([[n - 1 + t for t in range(max_new)]
                             for n in prompt_lens], np.int32)
+    # the weights the engine serves, in the natural layout the plain
+    # forward pass reads: the layers sliced back out of their stack
+    (stack,) = engine.params_stacked.values()
+    served_params = dict(engine.params, blocks=[
+        jax.tree_util.tree_map(lambda a, i=i: a[i], stack)
+        for i in range(cfg.num_layers)])
     with log.span() as ref:
-        logits = reference_logits(serve_cfg, engine.params, rows, positions)
+        logits = reference_logits(serve_cfg, served_params, rows, positions)
     served = np.asarray(served)
     best = logits.max(axis=-1)
     got = np.take_along_axis(logits, served[:, :, None], axis=-1)[..., 0]

@@ -23,17 +23,21 @@ Execution model (docs/inference.md):
   bucket), decode per batch bucket — the scheduler
   (`inference.scheduler`) only ever emits those shapes, so after the
   ladder warms up XLA never recompiles (`compile_count()` pins this in
-  tests; both serve cells report `serve_compiles_in_window`).
+  tests; every serve cell reports `serve_compiles_in_window`).
+- **One walk.** Every model's layers are walked one way: the family
+  says what to walk (`_Family.runs`: a planned model's plan, a
+  homogeneous model as a plan of ONE run of all its layers), the weights
+  are one stack a layer kind (`_stacked`), and the pools and page tables
+  travel as `{cache kind: ...}` (`_pools`, `_tables`).
 - **State.** The page pools are donated through every compiled call and
   rebound; everything else (params, rotary cache) is read-only. The
-  decode program leaves the pools where they are (`_token_layers`): they
-  are the layer loop's carried state, the new row is written by a kernel
+  decode program leaves the pools where they are (`_plan_token_layers`):
+  they are the layer loop's carried state, the new row is written by a kernel
   that aliases the stacked pool (`paged_kv_write`), and the attention
   kernel indexes the layer itself, so a step moves the pages it touches
   and never a pool. Prefill's whole-page scatter updates in place too;
   the chunk programs (`_chunk_fn`) still scan the pools as inputs and
   outputs, which copies them.
-
 - **One decode in flight.** `step()` enqueues its prefill and its
   decode before it reads the previous call's decode back; a continuing
   row's input token is gathered from that program's output on the
@@ -135,34 +139,63 @@ class _Family:
     the shared `gpt_neox._block_qkv`/`_block_post_attn`."""
 
     def __init__(self, model, max_seq_len):
-        self.cfg = model.config
+        self.cfg = cfg = model.config
+        plan = getattr(cfg, "layer_plan", ())
+        # (cos, sin, rot_dim) by attention kind: a planned model's layers
+        # rotate by their kind; a homogeneous model's one kind, `full`, by
+        # the model's own facts
         if isinstance(model, neox.GPTNeoX):
             # every architecture `GPTNeoXConfig` describes (its norm,
             # biases, QK norm, FFN kind): the seams below are the same
             self.kind = "gpt_neox"
-            self._cos, self._sin, self.rot_dim = neox._rotary_cache(
-                self.cfg, max_seq_len)
-            # a planned model's layers rotate by attention kind
-            self._rotary = neox.plan_rotary(self.cfg, max_seq_len)
+            self._rotary = neox.plan_rotary(cfg, max_seq_len) if plan else \
+                {"full": neox._rotary_cache(cfg, max_seq_len)}
         elif isinstance(model, gpt2_mod.GPT2):
-            self.kind = "gpt2"
-            self._cos = jnp.zeros((max_seq_len, 0), jnp.float32)
-            self._sin = jnp.zeros((max_seq_len, 0), jnp.float32)
-            self.rot_dim = 0
+            self.kind = "gpt2"      # order comes from wpe: nothing rotates
+            none = jnp.zeros((max_seq_len, 0), jnp.float32)
+            self._rotary = {"full": (none, none, 0)}
         else:
             raise DeepSpeedConfigError(
                 f"InferenceEngine serves the GPT-NeoX / GPT-2 families; "
                 f"got {type(model).__name__}")
+        self.kv_heads = getattr(cfg, "kv_heads", cfg.num_heads)
+        # a looped model (`GPTNeoXConfig.loop_steps`): the plan's stack
+        # run that many times over the same weights, a pass's K/V in
+        # cache layers of its own (docs/inference.md "Looped models")
+        self.loop_steps = getattr(cfg, "loop_steps", 1)
+        # the window of the model's window layers, and the width of a
+        # latent layer's cache row (MLA: one pool with no head axis),
+        # where its plan has such layers
+        self.window = cfg.attn_window if self.cache_layers("window") else 0
+        self.latent = cfg.latent_width if self.cache_layers("latent") else 0
         # experts a token of a served (dropless) MoE; 0: a dense model
-        self.moe_top_k = self.cfg.moe_top_k \
-            if getattr(self.cfg, "moe_dropless", False) else 0
-        # the layers that route (a planned model's `experts` layers), and
+        self.moe_top_k = cfg.moe_top_k \
+            if getattr(cfg, "moe_dropless", False) else 0
+        # the layers that route, every pass of the loop counted, and
         # whether only a share of the router's experts is held here
-        plan = getattr(self.cfg, "layer_plan", ())
-        self.moe_layers = (getattr(self.cfg, "loop_steps", 1) *
-                           sum(1 for s in plan if s.ffn == "experts")
-                           if plan else self.cfg.num_layers)
-        self.moe_held = tuple(getattr(self.cfg, "moe_held", ()))
+        self.moe_layers = self.loop_steps * sum(
+            n for spec, _, _, n in self.runs() if spec.ffn == "experts")
+        self.moe_held = tuple(getattr(cfg, "moe_held", ()))
+
+    def runs(self):
+        """What the engine walks: the model's layers as runs of
+        consecutive layers of one kind, [(spec, first layer, its index
+        within the kind's stack, length)]. A planned model's plan
+        (`GPTNeoXConfig.plan_runs`); a homogeneous model is a plan of ONE
+        run of all its layers, of cache kind `full`, whose spec says what
+        `spec=None` says to the block (the model's own heads, no window,
+        not latent; its rotary facts are the model's, `_rotary`)."""
+        if getattr(self.cfg, "layer_plan", ()):
+            return self.cfg.plan_runs()
+        spec = neox.LayerSpec(
+            "full", self.cfg.num_heads,
+            ffn="experts" if self.moe_top_k else "dense")
+        return [(spec, 0, 0, self.cfg.num_layers)]
+
+    def cache_layers(self, attn):
+        """`GPTNeoXConfig.cache_layers`; a GPT-2's layers are all `full`."""
+        return getattr(self.cfg, "cache_layers", lambda kind: (
+            self.cfg.num_layers if kind == "full" else 0))(attn)
 
     def moe_buffer_rows(self, tokens):
         """Rows an MoE layer's sorted buffer holds in a program compiled
@@ -200,48 +233,32 @@ class _Family:
             x = x + params["embed"]["wpe"][positions]
         return x
 
-    def _table(self, attn):
-        """(cos, sin, rot_dim): the model's, or a planned model's for
-        the layers of attention kind `attn`."""
-        if attn is None:
-            return self._cos, self._sin, self.rot_dim
-        return self._rotary[attn]
-
-    def cos_sin_prefill(self, seqlen, attn=None):
-        cos, sin, rot_dim = self._table(attn)
+    def cos_sin_prefill(self, seqlen, attn="full"):
+        cos, sin, rot_dim = self._rotary[attn]
         return (cos[:seqlen], sin[:seqlen], rot_dim)
 
-    def cos_sin_decode(self, positions, attn=None):
+    def cos_sin_decode(self, positions, attn="full"):
         """Per-batch rotary rows at `positions` [B] → ([B, 1, rot], ...)."""
-        cos, sin, rot_dim = self._table(attn)
+        cos, sin, rot_dim = self._rotary[attn]
         return (cos[positions][:, None, :], sin[positions][:, None, :],
                 rot_dim)
 
-    def cos_sin_at(self, positions, attn=None):
+    def cos_sin_at(self, positions, attn="full"):
         """Per-token rotary rows at `positions` [B, S] →
         ([B, S, rot], ...) — `apply_rotary` takes the 3-D form."""
-        cos, sin, rot_dim = self._table(attn)
+        cos, sin, rot_dim = self._rotary[attn]
         return (cos[positions], sin[positions], rot_dim)
 
     @scopes.scoped("ds.lm_head")
     def head(self, params, h):
-        """Final-norm hidden [B, H] → logits [B, V] (fp32)."""
+        """Final-norm hidden [..., H] → logits [..., V] (fp32): a row a
+        sequence, or every window position's (the speculative verify,
+        a block's pass)."""
         if self.kind == "gpt2":
             wte = params["embed"]["wte"]
         else:
             wte = params.get("embed_out", params["embed"])["wte"]
-        return jnp.einsum("bh,vh->bv", h, wte.astype(h.dtype),
-                          preferred_element_type=jnp.float32)
-
-    @scopes.scoped("ds.lm_head")
-    def head_all(self, params, h):
-        """Final-norm hidden [B, S, H] → logits [B, S, V] (fp32) —
-        the speculative verify needs every window position's logits."""
-        if self.kind == "gpt2":
-            wte = params["embed"]["wte"]
-        else:
-            wte = params.get("embed_out", params["embed"])["wte"]
-        return jnp.einsum("bsh,vh->bsv", h, wte.astype(h.dtype),
+        return jnp.einsum("...h,vh->...v", h, wte.astype(h.dtype),
                           preferred_element_type=jnp.float32)
 
 
@@ -265,16 +282,6 @@ class InferenceEngine:
         # shape, run from one parameter stack a layer kind, with a page
         # pool a cache kind (docs/inference.md "Planned models")
         self.planned = bool(getattr(cfg, "layer_plan", ()))
-        self.window = cfg.attn_window if self.planned and \
-            cfg.cache_layers("window") else 0
-        # the width of a latent layer's cache row (MLA), where the model's
-        # attention is latent: one pool with no head axis
-        self.latent = cfg.latent_width if self.planned and \
-            cfg.cache_layers("latent") else 0
-        # a looped model (`GPTNeoXConfig.loop_steps`): the plan's stack
-        # run that many times over the same weights, a pass's K/V in
-        # cache layers of its own (docs/inference.md "Looped models")
-        self.loop_steps = cfg.loop_steps if self.planned else 1
         # a model that generates a BLOCK of tokens at a time
         # (`GPTNeoXConfig.generation_block`; docs/inference.md "Block
         # generation"): a decode pass carries the block's rows of every
@@ -385,6 +392,9 @@ class InferenceEngine:
         # the validated "quantization" block (weights choice): int8
         # block matmul weights at rest (docs/quantization.md)
         self.weight_quant = (quantization or {}).get("weights")
+        self.family = fam = _Family(model, self.max_seq_len)
+        self.loop_steps, self.window, self.latent = \
+            fam.loop_steps, fam.window, fam.latent
         self._refuse_unplanned(ip, draft_model)
         if self.weight_quant and self.mp > 1:
             raise DeepSpeedConfigError(
@@ -404,37 +414,28 @@ class InferenceEngine:
         self._natural_like = jax.tree_util.tree_map(
             lambda l: jax.ShapeDtypeStruct(jnp.shape(l),
                                            jnp.result_type(l)), params)
-        params = prepare_inference_params(params, self.compute_dtype,
-                                          weight_quant=self.weight_quant)
-        self._set_params(params)
+        self._set_params(prepare_inference_params(
+            params, self.compute_dtype, weight_quant=self.weight_quant))
 
         # -- cache / scheduler ---------------------------------------------
-        self.family = _Family(model, self.max_seq_len)
-        # page pools by layer kind. `cache`: what a full-attention layer
-        # keeps, a sequence's whole context (`num_pages`; every layer of
-        # a homogeneous model; where the model's attention is latent, its
-        # latent rows in ONE pool, not a K and a V). `window_cache`: what
-        # a window layer
-        # keeps, at most window / page + 1 pages a sequence, so the pool
-        # is sized for `max_batch_size` of those and the scheduler gives
-        # the rest back as a sequence grows. A looped model's pools hold
-        # `loop_steps` cache layers a layer (`cfg.cache_layers`)
-        kv_heads = getattr(cfg, "kv_heads", cfg.num_heads)
-        n_window = cfg.cache_layers("window") if self.planned else 0
-        self.cache = PagedKVCache(
-            num_layers=self.loop_steps * cfg.num_layers - n_window,
-            num_pages=ip["num_pages"],
-            num_heads=kv_heads, page_size=self.page_size,
+        # page pools by cache kind, as the programs take and return them
+        # (`_pools`). `full` (or `latent`: a latent model's rows in ONE
+        # pool, not a K and a V): a sequence's whole context, `num_pages`.
+        # `window`: what a window layer keeps, at most window / page + 1
+        # pages a sequence, so the pool is sized for `max_batch_size` of
+        # those and the scheduler gives the rest back as a sequence grows
+        pages = {"latent" if self.latent else "full": ip["num_pages"]}
+        if self.window:
+            pages["window"] = self.max_batch_size * (
+                self.window // self.page_size + 1) + 1
+        self.caches = {kind: PagedKVCache(
+            num_layers=self.family.cache_layers(kind), num_pages=n,
+            num_heads=self.family.kv_heads, page_size=self.page_size,
             head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh,
-            latent_width=self.latent)
-        self.window_cache = None
-        if n_window:
-            self.window_cache = PagedKVCache(
-                num_layers=n_window,
-                num_pages=self.max_batch_size *
-                (self.window // self.page_size + 1) + 1,
-                num_heads=kv_heads, page_size=self.page_size,
-                head_dim=cfg.head_dim, dtype=self.kv_cache_dtype)
+            latent_width=self.latent) for kind, n in pages.items()}
+        # the names the scheduler and a benchmark's probes read
+        self.cache = self.caches["latent" if self.latent else "full"]
+        self.window_cache = self.caches.get("window")
         # -- prefix/radix cache + speculative decoding (both default-off:
         #    without their config sub-blocks the engine is bit-identical
         #    to the plain PR 8 serving loop) --------------------------------
@@ -481,11 +482,11 @@ class InferenceEngine:
             if draft_params is None:
                 draft_params = draft_model.init_params(
                     jax.random.PRNGKey(self.seed))
-            self.draft_params = prepare_inference_params(
-                draft_params, self.compute_dtype,
-                weight_quant=sp["draft_weight_quant"])
-            self.draft_stacked = self._stacked_blocks(self.draft_params)
             self.draft_family = _Family(draft_model, self.max_seq_len)
+            self.draft_params, self.draft_stacked = self._stacked(
+                self.draft_family, prepare_inference_params(
+                    draft_params, self.compute_dtype,
+                    weight_quant=sp["draft_weight_quant"]))
             # the draft's shadow pools MIRROR the target allocator: same
             # num_pages/page_size, so one page id addresses a sequence's
             # K/V in both models and no second allocator exists — every
@@ -743,17 +744,19 @@ class InferenceEngine:
                     "the grouped KV heads have no tensor-parallel "
                     "placement")
         elif self.weight_quant:
-            what = ("quantization.weights: the int8 surgery knows the "
-                    "homogeneous `blocks` layout")
+            what = ("quantization.weights: the int8 surgery reads the "
+                    "`blocks` list of a natural tree, not a stack a layer "
+                    "kind")
         elif ip["prefix_cache"] is not None:
             what = ("inference.prefix_cache: a shared prefix page has no "
                     "counterpart in a window pool, whose pages behind the "
-                    "window are gone, and the chunk program scans one "
-                    "homogeneous stack")
+                    "window are gone, and the chunk program walks one run "
+                    "of layers and attends one K/V pool pair through an "
+                    "XLA gather")
         elif ip["speculative"] is not None or draft_model is not None:
-            what = ("inference.speculative: the verify chunk and the "
-                    "rollback of rejected pages know one pool and one "
-                    "homogeneous stack")
+            what = ("inference.speculative: the verify chunk (one run of "
+                    "layers, one K/V pool pair through an XLA gather) and "
+                    "the rollback of rejected pages know one pool")
         elif ip["disaggregation"]["role"] != "unified":
             what = ("handoff between pools (disaggregation.role != "
                     "'unified'): the page payload carries one pool's "
@@ -811,37 +814,44 @@ class InferenceEngine:
     # weights
     # ------------------------------------------------------------------
 
-    def _place_params(self, params):
-        if self.mp > 1:
-            specs = self.model.param_specs(params, self.mesh)
-            return jax.tree_util.tree_map(
-                lambda p, s: jax.device_put(
-                    p, NamedSharding(self.mesh, s)), params, specs,
-                is_leaf=lambda x: isinstance(x, P))
-        return params
+    def _stacked(self, fam, params, mesh_specs=None):
+        """A model's prepared tree as the programs read it: (the leaves
+        beside the layers, {stack kind: the layers of that kind, every
+        leaf with a leading layer axis}). The programs scan the stacks:
+        stacking inside a compiled step would make a full copy of the
+        block weights every call (params are runtime jit inputs, XLA
+        cannot hoist the stack out).
 
-    def _set_params(self, params):
-        """Place the params and pre-stack the block weights ONCE:
-        decode is weight-bandwidth bound, and stacking inside the
-        compiled step would materialize a full copy of the block
-        params every call (params are runtime jit inputs — XLA cannot
-        hoist the stack out)."""
-        if self.planned:
-            # the model's own tree IS the layout the programs run from
-            # (one stack a layer kind): taken by reference, no copy, so
-            # the weights live on the device once
-            self.params = params
-            self.params_stacked = params["stacks"]
-            return
-        self.params = self._place_params(params)
-        stacked = self._stacked_blocks(self.params)
-        if self.mp > 1:
-            specs = self.model.param_specs(self.params, self.mesh)
-            stacked = jax.tree_util.tree_map(
+        A planned model's own tree IS that layout (`params["stacks"]`):
+        taken by reference, no copy. A homogeneous model's `blocks` are
+        stacked here ONCE under its run's kind name and left out of what
+        is returned, so the engine holds the layers' weights once.
+        `mesh_specs`: the tree's tensor-parallel specs, where the mesh
+        shards the model."""
+        if "stacks" in params:
+            return params, params["stacks"]
+        (spec, _, _, _), = fam.runs()
+        rest = {k: v for k, v in params.items() if k != "blocks"}
+        stack = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                       *params["blocks"])
+        if mesh_specs is not None:
+            rest = jax.tree_util.tree_map(
+                lambda p, s: jax.device_put(p, NamedSharding(self.mesh, s)),
+                rest, {k: mesh_specs[k] for k in rest},
+                is_leaf=lambda x: isinstance(x, P))
+            stack = jax.tree_util.tree_map(
                 lambda x, s: jax.device_put(
                     x, NamedSharding(self.mesh, P(None, *s))),
-                stacked, specs["blocks"][0])
-        self.params_stacked = stacked
+                stack, mesh_specs["blocks"][0])
+        return rest, {spec.kind: stack}
+
+    def _set_params(self, params):
+        """Take a prepared tree as the weights the programs run from
+        (`_stacked`)."""
+        self.params, self.params_stacked = self._stacked(
+            self.family, params,
+            self.model.param_specs(params, self.mesh) if self.mp > 1
+            else None)
         # a weight hot-swap invalidates every registered prefix page:
         # the cached K/V is a function of the OLD weights, so new
         # requests must not share it — drop the registry and detach
@@ -863,12 +873,11 @@ class InferenceEngine:
             load_dir, tag=tag, like=self._natural_like)
         if path is None:
             return None, {}
-        params = prepare_inference_params(natural, self.compute_dtype,
-                                          weight_quant=self.weight_quant)
         # the compiled programs take params as runtime arguments, so the
         # warmed bucket executables stay valid across a weight hot-swap
         # (same avals = jit cache hit) — no recompile ladder to repay
-        self._set_params(params)
+        self._set_params(prepare_inference_params(
+            natural, self.compute_dtype, weight_quant=self.weight_quant))
         return path, client_state
 
     def hot_swap_weights(self, natural_params):
@@ -885,11 +894,10 @@ class InferenceEngine:
         self._settle()      # the decode in flight ran on the old weights
         before = self.compile_count()
         t0 = time.perf_counter()
-        params = prepare_inference_params(natural_params,
-                                          self.compute_dtype,
-                                          weight_quant=self.weight_quant)
-        self._set_params(params)
-        jax.block_until_ready(self.params)
+        self._set_params(prepare_inference_params(
+            natural_params, self.compute_dtype,
+            weight_quant=self.weight_quant))
+        jax.block_until_ready((self.params, self.params_stacked))
         swap_ms = (time.perf_counter() - t0) * 1e3
         return {"swap_ms": swap_ms,
                 "compile_delta": self.compile_count() - before}
@@ -920,11 +928,8 @@ class InferenceEngine:
         """Total compiled executables across all bucketed programs; the
         zero-recompile tests/bench pin that this stops growing once the
         bucket ladder has warmed up."""
-        total = 0
-        for fn in self._compiled.values():
-            total += (fn._cache_size() if hasattr(fn, "_cache_size")
-                      else 1)
-        return total
+        return sum(fn._cache_size() if hasattr(fn, "_cache_size") else 1
+                   for fn in self._compiled.values())
 
     @scopes.scoped("ds.sample")
     def _sample(self, logits, rng):
@@ -998,48 +1003,6 @@ class InferenceEngine:
         return treedef.unflatten(write(layer, page_idx, slot, *leaves,
                                        *rows))
 
-    def _token_layers(self, cfg, stacked, x, cos_sin, pools, page_table,
-                      page_idx, slot, lengths):
-        """The layer loop of a one-token step (decode; each of the draft's
-        proposal steps): every row's token `x` [B, 1, hidden] through
-        the blocks, its K/V written at (`page_idx`, `slot`) and attended
-        over `lengths` cached tokens. The (K, V) `pools` are the loop's
-        CARRY, not scanned inputs: a scan slices each `xs` element out
-        of its stack and stacks each `ys` element into a new one, which
-        for a pool is a copy of the pool every step. As carried state,
-        written by an aliased kernel and read by a kernel that takes the
-        layer index, they stay where they are. Returns (x, pools)."""
-        cos, sin, rot_dim = cos_sin
-        B = x.shape[0]
-        H, D = cfg.num_heads, cfg.head_dim
-        # an inactive row attends over nothing; an MoE routes it nowhere
-        active = (lengths > 0)[:, None] \
-            if getattr(cfg, "moe_num_experts", 0) else None
-
-        block_xs, block_of = self._layer_xs(cfg, stacked)
-
-        @scopes.scoped("ds.block")
-        def body(carry, xs):
-            x, pools = carry
-            bp, layer = block_of(xs[0]), xs[1]
-            q, k, v = neox._block_qkv(cfg, bp, x, cos, sin, rot_dim, H)
-            pools = self._write_rows(pools, k[:, 0], v[:, 0], layer,
-                                     page_idx, slot)
-            with scopes.scope("ds.attn"):
-                qrow = q[:, 0] if isinstance(pools[0], QuantizedPages) \
-                    else q[:, 0].astype(pools[0].dtype)
-                attn = self._attention(qrow, pools, layer, page_table,
-                                       lengths).astype(x.dtype)
-            out = neox.block_hidden(neox._block_post_attn(
-                cfg, bp, x, attn.reshape(B, 1, H * D),
-                reduce_fn=lambda t: t, token_mask=active))
-            return (out, pools), None
-
-        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-        with scopes.scope("ds.layers"):
-            carry, _ = jax.lax.scan(body, (x, pools), (block_xs, layers))
-        return carry
-
     @staticmethod
     def _run_xs(stack, at, n):
         """What a layer loop over layers [at, at + n) of one kind's
@@ -1061,19 +1024,20 @@ class InferenceEngine:
 
         return sliced, layer_of
 
-    def _plan_layers(self, cfg, stacks, carry, layer_fn, loop_pass=0):
-        """A planned model's layer loop: `layer_fn(carry, bp, spec,
-        cache_layer) -> (carry, ys)` over the plan, a run of consecutive
-        layers of one kind at a time (a scan where the run is longer than
-        one layer). `cache_layer` is the layer's index in its cache
-        kind's pools: pass `loop_pass` of a looped model keeps its K/V
-        behind those of the passes before it. Returns (carry, [(spec, ys
-        stacked over the run)])."""
-        cache_at = {kind: loop_pass * (cfg.cache_layers(kind) //
-                                       cfg.loop_steps)
+    def _plan_layers(self, fam, stacks, carry, layer_fn, loop_pass=0):
+        """The layer loop of every model: `layer_fn(carry, bp, spec,
+        cache_layer) -> (carry, ys)` over the family's runs
+        (`_Family.runs`), a run of consecutive layers of one kind at a
+        time (a scan where the run is longer than one layer; a
+        homogeneous model is one run, one scan). `cache_layer` is the
+        layer's index in its cache kind's pools: pass `loop_pass` of a
+        looped model keeps its K/V behind those of the passes before it.
+        Returns (carry, [(spec, ys stacked over the run)])."""
+        cache_at = {kind: loop_pass * (fam.cache_layers(kind) //
+                                       fam.loop_steps)
                     for kind in ("full", "window", "latent")}
         out = []
-        for spec, _, at, n in cfg.plan_runs():
+        for spec, _, at, n in fam.runs():
             base = cache_at[spec.attn]
             cache_at[spec.attn] += n
             xs, layer_of = self._run_xs(stacks[spec.kind], at, n)
@@ -1093,20 +1057,28 @@ class InferenceEngine:
         return carry, out
 
     @staticmethod
-    def _held_rows(cfg, block_out):
+    def _held_rows(fam, block_out):
         """(hidden states, the (token, choice) pairs of this layer that
         fell on a held expert) of a block's return."""
-        if isinstance(block_out, tuple) and cfg.moe_held:
-            lo, hi = cfg.moe_held
+        if isinstance(block_out, tuple) and fam.moe_held:
+            lo, hi = fam.moe_held
             return block_out[0], jnp.sum(block_out[1][2, lo:hi])
         return neox.block_hidden(block_out), jnp.zeros((), jnp.float32)
 
-    def _plan_token_layers(self, cfg, stacks, x, pos, pools, tables,
+    def _plan_token_layers(self, fam, stacks, x, pos, pools, tables,
                            lengths, loop_pass=0):
-        """`_token_layers` of a planned model: `pools` and `tables` are
-        {cache kind: (K, V) pools} and {cache kind: page table}; a window
-        layer writes and attends in the window kind's; `loop_pass` as
-        `_plan_layers` takes it. Returns (x, pools, held pairs).
+        """The layer loop of a one-token step (decode; each of the
+        draft's proposal steps) of the model `fam` describes: `pools` and
+        `tables` are {cache kind: (K, V) pools} and {cache kind: page
+        table}; a window layer writes and attends in the window kind's;
+        `loop_pass` as `_plan_layers` takes it. Returns (x, pools, held
+        pairs).
+
+        The pools are the loop's CARRY, not scanned inputs: a scan slices
+        each `xs` element out of its stack and stacks each `ys` element
+        into a new one, which for a pool is a copy of the pool every
+        step. As carried state, written by an aliased kernel and read by
+        a kernel that takes the layer index, they stay where they are.
 
         `x` [B, R, hidden]: R = 1, a token a sequence at position `pos`
         [B]; or a block model's block, R rows a sequence at positions
@@ -1116,8 +1088,9 @@ class InferenceEngine:
         themselves: the R rows x the query heads of a KV head ride as
         that KV head's one group of the grouped paged kernel, under the
         name `ds.paged_decode_block`."""
-        fam, ps = self.family, self.page_size
+        cfg, ps = fam.cfg, self.page_size
         B, R = x.shape[:2]
+        # an inactive row attends over nothing; an MoE routes it nowhere
         active = jnp.broadcast_to((lengths > 0)[:, None], (B, R))
         kinds = list(pools)
         if R == 1:
@@ -1128,7 +1101,7 @@ class InferenceEngine:
         page_idx = {k: jnp.take_along_axis(
             tables[k], (pos // ps)[:, None], axis=1)[:, 0] for k in kinds}
         slot = pos % ps
-        G = cfg.kv_heads
+        G = fam.kv_heads
 
         def kv_rows(t):
             """K or V [B, R, G, D] as `_write_rows` takes it."""
@@ -1151,15 +1124,14 @@ class InferenceEngine:
             return jnp.swapaxes(attn.reshape(B, G, R, -1, D), 1, 2).reshape(
                 B, R, -1)
 
-        def latent_layer(carry, bp, spec, cache_layer):
+        def latent_attn(x, kv, bp, spec, cache_layer):
             """The absorbed form: the token's latent row into its page,
             q' = q_nope W_uk^T against the latent pages, o = u W_uv."""
-            x, pools, held = carry
             q_nope, q_rope, row = neox._latent_rows(
                 cfg, bp, x, *rot["latent"][:2], spec.heads)
             pool = paged_latent_write(
-                pools["latent"][0], row[:, 0], cache_layer,
-                page_idx["latent"], slot, backend=self._attn_backend)
+                kv[0], row[:, 0], cache_layer, page_idx["latent"], slot,
+                backend=self._attn_backend)
             with scopes.scope("ds.attn"):
                 q = neox.latent_absorb_q(cfg, bp, q_nope[:, 0], q_rope[:, 0])
                 u = paged_latent_decode(
@@ -1168,51 +1140,53 @@ class InferenceEngine:
                     cfg.mla_kv_rank, cache_layer,
                     backend=self._attn_backend).astype(x.dtype)
                 attn = neox.latent_absorb_out(cfg, bp, u)
-            out, rows = self._held_rows(cfg, neox._block_post_attn(
-                cfg, bp, x, attn.reshape(B, 1, -1),
-                reduce_fn=lambda t: t, token_mask=active))
-            return (out, {"latent": (pool,)}, held + rows), None
+            return attn.reshape(B, 1, -1), (pool,)
+
+        def paged_attn(x, kv, bp, spec, cache_layer):
+            kind = spec.attn
+            q, k, v = neox._block_qkv(cfg, bp, x, *rot[kind], spec.heads)
+            kv = self._write_rows(kv, kv_rows(k), kv_rows(v), cache_layer,
+                                  page_idx[kind], slot)
+            with scopes.scope("ds.attn"):
+                # int8 pages dequantize inside the kernel: q stays as it is
+                q = q_rows(q)
+                if not isinstance(kv[0], QuantizedPages):
+                    q = q.astype(kv[0].dtype)
+                attn = self._attention(
+                    q, kv, cache_layer, tables[kind], lengths,
+                    window=self.window if kind == "window" else None,
+                    block_pass=R > 1).astype(x.dtype)
+            return attn_rows(attn), kv
 
         @scopes.scoped("ds.block")
         def layer(carry, bp, spec, cache_layer):
-            if spec.attn == "latent":
-                return latent_layer(carry, bp, spec, cache_layer)
             x, pools, held = carry
-            kind = spec.attn
-            q, k, v = neox._block_qkv(cfg, bp, x, *rot[kind], spec.heads)
-            kv = self._write_rows(pools[kind], kv_rows(k), kv_rows(v),
-                                  cache_layer, page_idx[kind], slot)
-            with scopes.scope("ds.attn"):
-                attn = self._attention(
-                    q_rows(q).astype(kv[0].dtype), kv, cache_layer,
-                    tables[kind], lengths,
-                    window=self.window if kind == "window" else None,
-                    block_pass=R > 1).astype(x.dtype)
-            out, rows = self._held_rows(cfg, neox._block_post_attn(
-                cfg, bp, x, attn_rows(attn),
-                reduce_fn=lambda t: t, token_mask=active))
-            return (out, dict(pools, **{kind: kv}), held + rows), None
+            attend = latent_attn if spec.attn == "latent" else paged_attn
+            attn, kv = attend(x, pools[spec.attn], bp, spec, cache_layer)
+            out, rows = self._held_rows(fam, neox._block_post_attn(
+                cfg, bp, x, attn, reduce_fn=lambda t: t, token_mask=active))
+            return (out, dict(pools, **{spec.attn: kv}), held + rows), None
 
         with scopes.scope("ds.layers"):
             carry, _ = self._plan_layers(
-                cfg, stacks, (x, pools, jnp.zeros((), jnp.float32)), layer,
+                fam, stacks, (x, pools, jnp.zeros((), jnp.float32)), layer,
                 loop_pass)
         return carry
 
-    def _loop(self, cfg, params, x, state, one_pass, rows):
-        """The plan's stack `cfg.loop_steps` times over the SAME weights:
-        `one_pass(x, state, loop_pass) -> (x, state)` walks the plan once
+    def _loop(self, params, x, state, one_pass, rows):
+        """The model's layers `loop_steps` times over the SAME weights:
+        `one_pass(x, state, loop_pass) -> (x, state)` walks them once
         (it closes over the weights: loop-invariant operands, held once
         whatever the passes, never stacked a pass), `state` what it
         carries beside the hidden states (the page pools: carried,
-        aliased state, as `_token_layers` says why), `rows(x) -> [B, h]`
-        the rows the head reads. The final norm follows EVERY pass of a
-        looped model and its output is the next pass's input; the head
-        reads the pass the exit gate names (`neox.loop_exit`). Returns
-        (the head's input [B, h], state, each row's exit pass [B] or
-        None where the model does not loop)."""
+        aliased state, as `_plan_token_layers` says why), `rows(x) ->
+        [B, h]` the rows the head reads. The final norm follows EVERY
+        pass of a looped model and its output is the next pass's input;
+        the head reads the pass the exit gate names (`neox.loop_exit`).
+        Returns (the head's input [B, h], state, each row's exit pass
+        [B] or None where the model does not loop)."""
         fam = self.family
-        if cfg.loop_steps == 1:
+        if fam.loop_steps == 1:
             x, state = one_pass(x, state, 0)
             return fam.final_norm(params, rows(x)), state, None
 
@@ -1224,8 +1198,8 @@ class InferenceEngine:
                 return (x, state), rows(x)
 
         (_, state), passes = jax.lax.scan(
-            body, (x, state), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
-        h, exit_pass = neox.loop_exit(cfg, params, passes)
+            body, (x, state), jnp.arange(fam.loop_steps, dtype=jnp.int32))
+        h, exit_pass = neox.loop_exit(fam.cfg, params, passes)
         return h, state, exit_pass
 
     def _with_held(self, tokens, held, exit_pass=None):
@@ -1241,60 +1215,6 @@ class InferenceEngine:
             parts.append(held.astype(jnp.int32)[None])
         return jnp.concatenate(parts) if len(parts) > 1 else tokens
 
-    def _kind_pools(self, k_pool, v_pool):
-        """{cache kind: (K, V)} of the programs' pool arguments (a pair of
-        pools, or with a window kind a pair of (full, window) pairs; a
-        latent model's one pool rides as `k_pool` beside a None)."""
-        if self.latent:
-            return {"latent": (k_pool,)}
-        if self.window_cache is None:
-            return {"full": (k_pool, v_pool)}
-        return {"full": (k_pool[0], v_pool[0]),
-                "window": (k_pool[1], v_pool[1])}
-
-    def _pool_args(self, pools):
-        """The inverse of `_kind_pools`: (k_pool, v_pool)."""
-        if self.latent:
-            return pools["latent"][0], None
-        if self.window_cache is None:
-            return pools["full"]
-        return ((pools["full"][0], pools["window"][0]),
-                (pools["full"][1], pools["window"][1]))
-
-    def _kind_tables(self, page_table):
-        if self.latent:
-            return {"latent": page_table}
-        if self.window_cache is None:
-            return {"full": page_table}
-        return {"full": page_table[0], "window": page_table[1]}
-
-    @staticmethod
-    def _stacked_blocks(params):
-        return jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *params["blocks"])
-
-    @staticmethod
-    def _layer_xs(cfg, stacked):
-        """What a layer loop scans of the stacked block weights, and the
-        function that makes a layer's block params of one slice:
-        (xs, unpack). A scan slices its `xs` a layer at a time, which
-        for an MoE's experts is a copy of 0.8 GB a layer; they stay
-        whole and the grouped-matmul kernel indexes the layer
-        (`LayerOf`). A dense model's loop scans the stack as it is."""
-        if not getattr(cfg, "moe_dropless", False):
-            return stacked, lambda bp: bp
-        from ..ops.pallas.grouped_matmul import LayerOf
-        whole = {k: stacked["mlp"][k] for k in ("w_in", "w_out")}
-        sliced = dict(stacked, mlp={k: v for k, v in stacked["mlp"].items()
-                                    if k not in whole})
-
-        def unpack(xs):
-            bp, layer = xs
-            experts = {k: LayerOf(v, layer) for k, v in whole.items()}
-            return dict(bp, mlp=dict(bp["mlp"], **experts))
-
-        return (sliced, jnp.arange(cfg.num_layers, dtype=jnp.int32)), unpack
-
     def _prefill_fn(self, batch, seqlen):
         key = ("prefill", batch, seqlen)
         if key in self._compiled:
@@ -1304,7 +1224,6 @@ class InferenceEngine:
         use_pallas = getattr(self.model, "use_pallas", True)
         ps = self.page_size
         n_pages_row = seqlen // ps
-        cos_sin = fam.cos_sin_prefill(seqlen)
 
         def last_rows(x, lengths):
             """[B, h]: each row's last real position of x [B, S, h]."""
@@ -1332,21 +1251,14 @@ class InferenceEngine:
                 q, k, v, use_pallas=use_pallas, segment_ids=segment_ids,
                 window=window, block=self.block)[:, :S]
 
-        def first_token(params, x, lengths, rng):
-            """The token sampled at each row's last real position."""
-            h_last = fam.final_norm(params, last_rows(x, lengths)[:, None, :])
-            return self._sample(fam.head(params, h_last[:, 0]), rng)
-
-        def page_tiles(new, heads, head_dim):
-            """[B, S, H, D] -> the B * S / ps page tiles [H, ps, D] of a
-            whole-page scatter, rows in page-table order."""
-            B = new.shape[0]
-            tiles = new.reshape(B, n_pages_row, ps, heads, head_dim)
-            tiles = jnp.moveaxis(tiles, 3, 2)
-            return tiles.reshape(B * n_pages_row, heads, ps, head_dim)
-
-        def prefill(params, stacked, tokens, lengths, page_table, k_pool,
-                    v_pool, rng):
+        def planned_prefill(params, stacks, tokens, lengths, tables, pools,
+                            rng):
+            """Every model's prefill: the layers a run of one kind at a
+            time, each cache kind's K/V scattered as whole pages into
+            its own `pools` through its own page table of `tables` (a
+            window layer's pages behind the window are table entry 0,
+            the trash page; a latent kind's one pool takes its layers'
+            latent rows). Returns (the first tokens, the pools)."""
             B, S = tokens.shape
             pos = jnp.arange(S, dtype=jnp.int32)[None, :]
             # 1 = real token, 0 = pad: the segmented attention kernels
@@ -1354,56 +1266,6 @@ class InferenceEngine:
             # causal attention over its own tokens only
             seg = (pos < lengths[:, None]).astype(jnp.int32)
             x = fam.embed_prefill(params, tokens)
-
-            block_xs, block_of = self._layer_xs(cfg, stacked)
-
-            def body(carry, xs):
-                y, kv = neox._block_core(
-                    cfg, block_of(xs), carry, cos_sin, use_pallas, mp=1,
-                    reduce_fn=lambda t: t, return_kv=True,
-                    attn_fn=attention, segment_ids=seg)
-                return neox.block_hidden(y), kv
-
-            with scopes.scope("ds.layers"):
-                x, (ks, vs) = jax.lax.scan(body, x, block_xs)
-
-            # whole-page scatter: [B, S, H, D] → B·S/ps page tiles at
-            # the page-table ids (pad rows hold table id 0 — the trash
-            # page — so duplicates only ever collide there)
-            flat_pt = page_table.reshape(-1)
-            H, D = cfg.num_heads, cfg.head_dim
-
-            def write(pool, new):
-                tiles = page_tiles(new, H, D)
-                if isinstance(pool, QuantizedPages):
-                    # int8 pages: quantize each (head, slot) vector and
-                    # scatter data + scale through the same page ids
-                    q8, sc = quantize_kv(tiles)
-                    return QuantizedPages(
-                        pool.data.at[flat_pt].set(q8),
-                        pool.scale.at[flat_pt].set(
-                            sc.astype(pool.scale.dtype)))
-                return pool.at[flat_pt].set(tiles.astype(pool.dtype))
-
-            with scopes.scope("ds.kv_write"):
-                k_pool = jax.vmap(write)(k_pool, ks)
-                v_pool = jax.vmap(write)(v_pool, vs)
-
-            return first_token(params, x, lengths, rng), k_pool, v_pool
-
-        def planned_prefill(params, stacks, tokens, lengths, page_table,
-                            k_pool, v_pool, rng):
-            """The same program for a planned model: the layers a run of
-            one kind at a time, each cache kind's K/V scattered into its
-            own pools through its own page table (a window layer's pages
-            behind the window are table entry 0, the trash page; a latent
-            kind's one pool takes its layers' latent rows)."""
-            B, S = tokens.shape
-            pos = jnp.arange(S, dtype=jnp.int32)[None, :]
-            seg = (pos < lengths[:, None]).astype(jnp.int32)
-            x = fam.embed_prefill(params, tokens)
-            pools = self._kind_pools(k_pool, v_pool)
-            tables = self._kind_tables(page_table)
             rot = {k: fam.cos_sin_prefill(S, k) for k in pools}
 
             def layer(carry, bp, spec, cache_layer):
@@ -1415,48 +1277,67 @@ class InferenceEngine:
                         attention, window=cfg.attn_window
                         if spec.attn == "window" else None),
                     segment_ids=seg, spec=spec)
-                out, rows = self._held_rows(cfg, y)
+                out, rows = self._held_rows(fam, y)
                 return (out, held + rows), kv
 
-            G, D = cfg.kv_heads, cfg.head_dim
+            G, D = fam.kv_heads, cfg.head_dim
 
             def scatter(kind, pool, new, loop_pass):
                 """One pool of cache kind `kind` with a pass's new rows
                 [L_kind, B, S, ...] written as whole pages into that
-                pass's cache layers: K or V rows [G, D] a token as
-                [G, ps, D] tiles, a latent layer's rows [width] as
-                [ps, row], padded to the pool's row."""
+                pass's cache layers at the page-table ids (pad rows hold
+                table id 0, the trash page, so duplicates only ever
+                collide there): K or V rows [G, D] a token as [G, ps, D]
+                tiles, a latent layer's rows [width] as [ps, row], padded
+                to the pool's row. Int8 pages: each (head, slot) vector
+                quantized, data and scale through the same page ids.
+
+                ONE form for every model, the general one (a pass's layers
+                of a looped pool, several runs of a kind), and not the
+                fastest at every shape: alone on the chip, us a pool,
+                Pythia's 24 layers x 1,024 tokens 914.5 against 679.9 for
+                a `vmap` over the layers and 542.1 for a loop over pages,
+                Laguna's 2 x 8,192 239.4 / 236.5 / 374.2 (chip runs, PR
+                46: PERF.md section 6; ROADMAP S5 has the rule)."""
                 flat_pt = tables[kind].reshape(-1)
 
                 def tiles(rows):
-                    if kind != "latent":
-                        return page_tiles(rows, G, D)
-                    rows = jnp.pad(rows, ((0, 0), (0, 0), (
-                        0, pool.shape[-1] - rows.shape[-1])))
-                    return rows.reshape(B * n_pages_row, ps, -1)
+                    """A layer's rows [B, S, ...] as its B * S / ps page
+                    tiles, in page-table order."""
+                    if kind == "latent":
+                        rows = jnp.pad(rows, ((0, 0), (0, 0), (
+                            0, pool.shape[-1] - rows.shape[-1])))
+                        return rows.reshape(B * n_pages_row, ps, -1)
+                    rows = rows.reshape(B, n_pages_row, ps, G, D)
+                    return jnp.moveaxis(rows, 3, 2).reshape(
+                        B * n_pages_row, G, ps, D)
 
-                new = jax.vmap(tiles)(new).astype(pool.dtype)
+                new = jax.vmap(tiles)(new)
                 n = new.shape[0]
                 layers = loop_pass * n + jnp.arange(n, dtype=jnp.int32)
-                return pool.at[layers[:, None], flat_pt[None, :]].set(new)
+                at = (layers[:, None], flat_pt[None, :])
+                if isinstance(pool, QuantizedPages):
+                    q8, sc = quantize_kv(new)
+                    return QuantizedPages(
+                        pool.data.at[at].set(q8),
+                        pool.scale.at[at].set(sc.astype(pool.scale.dtype)))
+                return pool.at[at].set(new.astype(pool.dtype))
 
             def one_pass(x, state, loop_pass):
-                """The plan once over x [B, S, h], the pass's K/V into
+                """The layers once over x [B, S, h], the pass's K/V into
                 its own cache layers of the pools."""
                 pools, held = state
                 with scopes.scope("ds.layers"):
                     (x, held), runs = self._plan_layers(
-                        cfg, stacks, (x, held), layer, loop_pass)
+                        fam, stacks, (x, held), layer, loop_pass)
                 with scopes.scope("ds.kv_write"):
-                    pools = dict(pools)
-                    for kind, kind_pools in list(pools.items()):
-                        # the kind's layers in order: [L_kind, B, S, ...]
-                        of_kind = [kv for spec, kv in runs
-                                   if spec.attn == kind]
-                        pools[kind] = tuple(
-                            scatter(kind, pool, jnp.concatenate(
-                                [kv[i] for kv in of_kind]), loop_pass)
-                            for i, pool in enumerate(kind_pools))
+                    # a kind's layers in order: [L_kind, B, S, ...]
+                    pools = {kind: tuple(
+                        scatter(kind, pool, jnp.concatenate(
+                            [kv[i] for spec, kv in runs
+                             if spec.attn == kind]), loop_pass)
+                        for i, pool in enumerate(kind_pools))
+                        for kind, kind_pools in pools.items()}
                 return x, (pools, held)
 
             if self.block:
@@ -1467,16 +1348,14 @@ class InferenceEngine:
                 x, (pools, _) = one_pass(
                     x, (pools, jnp.zeros((), jnp.float32)), 0)
                 done = (last_rows(x, lengths)[:, 0] * 0).astype(jnp.int32)
-                return (done, *self._pool_args(pools))
+                return done, pools
             h, (pools, held), exit_pass = self._loop(
-                cfg, params, x, (pools, jnp.zeros((), jnp.float32)),
+                params, x, (pools, jnp.zeros((), jnp.float32)),
                 one_pass, lambda x: last_rows(x, lengths))
             nxt = self._sample(fam.head(params, h), rng)
-            return (self._with_held(nxt, held, exit_pass),
-                    *self._pool_args(pools))
+            return self._with_held(nxt, held, exit_pass), pools
 
-        fn = jax.jit(planned_prefill if self.planned else prefill,
-                     donate_argnums=(5, 6))
+        fn = jax.jit(planned_prefill, donate_argnums=(5,))
         self._compiled[key] = fn
         return fn
 
@@ -1486,12 +1365,11 @@ class InferenceEngine:
             return self._compiled[key]
         cfg = self.model.config
         fam = self.family
-        ps = self.page_size
-
         width = self._carry_width
 
-        def decode(params, stacked, tokens, lengths, page_table, k_pool,
-                   v_pool, rng, carried, src):
+        def planned_decode(params, stacks, tokens, lengths, tables, pools,
+                           rng, carried, src):
+            """Every model's one-token step (`_plan_token_layers`)."""
             # a row that continues from the decode still in flight takes
             # its token from that program's output `carried`, at row
             # `src` (-1: the host's `tokens` entry stands), so nothing of
@@ -1503,47 +1381,26 @@ class InferenceEngine:
             # inactive (padding) row whose page table is all trash
             pos = jnp.maximum(lengths - 1, 0)
             x = fam.embed_decode(params, tokens, pos)
-            page_idx = jnp.take_along_axis(
-                page_table, (pos // ps)[:, None], axis=1)[:, 0]
-            x, (k_pool, v_pool) = self._token_layers(
-                cfg, stacked, x, fam.cos_sin_decode(pos), (k_pool, v_pool),
-                page_table, page_idx, pos % ps, lengths)
-            h = fam.final_norm(params, x)
-            logits = fam.head(params, h[:, 0])
-            # one shape for every bucket's tokens: what the next decode
-            # (of any bucket) gathers from
-            nxt = jnp.pad(self._sample(logits, rng), (0, width - batch))
-            return nxt, k_pool, v_pool
-
-        def planned_decode(params, stacks, tokens, lengths, page_table,
-                           k_pool, v_pool, rng, carried, src):
-            """The same step for a planned model (`_plan_token_layers`)."""
-            tokens = jnp.where(src >= 0, carried[jnp.maximum(src, 0)],
-                               tokens)
-            pos = jnp.maximum(lengths - 1, 0)
-            x = fam.embed_decode(params, tokens, pos)
-            tables = self._kind_tables(page_table)
 
             def one_pass(x, state, loop_pass):
                 pools, held = state
                 x, pools, rows = self._plan_token_layers(
-                    cfg, stacks, x, pos, pools, tables, lengths, loop_pass)
+                    fam, stacks, x, pos, pools, tables, lengths, loop_pass)
                 return x, (pools, held + rows)
 
             h, (pools, held), exit_pass = self._loop(
-                cfg, params, x, (self._kind_pools(k_pool, v_pool),
-                                 jnp.zeros((), jnp.float32)),
+                params, x, (pools, jnp.zeros((), jnp.float32)),
                 one_pass, lambda x: x[:, 0])
+            # one shape for every bucket's tokens: what the next decode
+            # (of any bucket) gathers from
             nxt = jnp.pad(self._sample(fam.head(params, h), rng),
                           (0, width - batch))
-            return (self._with_held(nxt, held, exit_pass),
-                    *self._pool_args(pools))
+            return self._with_held(nxt, held, exit_pass), pools
 
         B_ = self.block
-        mask_id = cfg.mask_token_id
 
-        def planned_block_decode(params, stacks, state, lengths, page_table,
-                                 k_pool, v_pool, rng, carried, src):
+        def planned_block_decode(params, stacks, state, lengths, tables,
+                                 pools, rng, carried, src):
             """One PASS of a block-generating model over the block of
             every row. `state` [batch, 2 block + 1] int32 is a row's block
             as the host knows it: its tokens, which rows are masked, and
@@ -1570,7 +1427,8 @@ class InferenceEngine:
             st = jnp.where((src >= 0)[:, None], carried[jnp.maximum(src, 0)],
                            state)
             committed = st[:, 2 * B_] > 0
-            tok = jnp.where(committed[:, None], mask_id, st[:, :B_])
+            tok = jnp.where(committed[:, None], cfg.mask_token_id,
+                            st[:, :B_])
             masked = (st[:, B_:2 * B_] > 0) | committed[:, None]
             active = lengths > 0
             pos = jnp.maximum(lengths - B_, 0)
@@ -1578,9 +1436,8 @@ class InferenceEngine:
             at = pos[:, None] + jnp.arange(B_, dtype=pos.dtype)
             x = fam.embed_at(params, tok, at)
             x, pools, _ = self._plan_token_layers(
-                cfg, stacks, x, pos, self._kind_pools(k_pool, v_pool),
-                self._kind_tables(page_table), lengths)
-            logits = fam.head_all(params, fam.final_norm(params, x))
+                fam, stacks, x, pos, pools, tables, lengths)
+            logits = fam.head(params, fam.final_norm(params, x))
             with scopes.scope("ds.unmask"):
                 top = jnp.max(logits, axis=-1)
                 best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1601,12 +1458,10 @@ class InferenceEngine:
                 out = jnp.concatenate(
                     [tok, masked.astype(jnp.int32),
                      commit.astype(jnp.int32)[:, None]], axis=1)
-            return (jnp.pad(out, ((0, width - batch), (0, 0))),
-                    *self._pool_args(pools))
+            return jnp.pad(out, ((0, width - batch), (0, 0))), pools
 
-        fn = jax.jit(planned_block_decode if self.block else
-                     planned_decode if self.planned else decode,
-                     donate_argnums=(5, 6))
+        fn = jax.jit(planned_block_decode if self.block else planned_decode,
+                     donate_argnums=(5,))
         self._compiled[key] = fn
         return fn
 
@@ -1707,11 +1562,13 @@ class InferenceEngine:
                                  preferred_element_type=jnp.float32)
                 return jnp.moveaxis(out, 1, 2).reshape(B, S, H * D)
 
-            block_xs, block_of = self._layer_xs(cfg, stacked)
+            # the one run of a homogeneous model (`_refuse_unplanned`)
+            (spec, _, at, n), = fam.runs()
+            block_xs, layer_of = self._run_xs(stacked[spec.kind], at, n)
 
             @scopes.scoped("ds.block")
             def body(carry, xs):
-                bp, kp, vp = block_of(xs[0]), xs[1], xs[2]
+                bp, kp, vp = layer_of(*xs[0]), xs[1], xs[2]
                 q, k, v = neox._block_qkv(cfg, bp, carry, cos, sin,
                                           rot_dim, H)
                 # write BEFORE attending: every window key is visible,
@@ -1727,7 +1584,8 @@ class InferenceEngine:
 
             with scopes.scope("ds.layers"):
                 x, (k_pool, v_pool) = jax.lax.scan(
-                    body, x, (block_xs, k_pool, v_pool))
+                    body, x, ((block_xs, jnp.arange(n, dtype=jnp.int32)),
+                              k_pool, v_pool))
             if mode == "write":
                 return k_pool, v_pool
             if mode == "sample":
@@ -1738,7 +1596,7 @@ class InferenceEngine:
                 return self._sample(logits, rng), k_pool, v_pool
             # mode == "verify": every position's next-token view
             h = fam.final_norm(params, x)
-            logits = fam.head_all(params, h)
+            logits = fam.head(params, h)
             with scopes.scope("ds.sample"):
                 if self.temperature <= 0.0:
                     out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1764,9 +1622,7 @@ class InferenceEngine:
         key = ("spec_propose", batch)
         if key in self._compiled:
             return self._compiled[key]
-        cfg = self.draft_model.config
         fam = self.draft_family
-        ps = self.page_size
         k_steps = self.spec_k
         window = self.max_seq_len
 
@@ -1775,25 +1631,24 @@ class InferenceEngine:
             base = jnp.maximum(lengths - 1, 0)
             proposed = []
             tok = tokens
+            pools = {"full": (k_pool, v_pool)}
             for j in range(k_steps + 1):
                 pos = jnp.clip(base + j, 0, window - 1)
                 active = (j <= windows) & (lengths > 0)
                 x = fam.embed_decode(params, tok, pos)
-                page_idx = jnp.take_along_axis(
-                    page_table, (pos // ps)[:, None], axis=1)[:, 0]
-                page_idx = jnp.where(active, page_idx, 0)
-                att_len = jnp.where(active, pos + 1, 0)
-                x, (k_pool, v_pool) = self._token_layers(
-                    cfg, stacked, x, fam.cos_sin_decode(pos),
-                    (k_pool, v_pool), page_table, page_idx, pos % ps,
-                    att_len)
+                # an inactive row's table is all trash and it attends
+                # over nothing
+                x, pools, _ = self._plan_token_layers(
+                    fam, stacked, x, pos, pools,
+                    {"full": jnp.where(active[:, None], page_table, 0)},
+                    jnp.where(active, pos + 1, 0))
                 if j < k_steps:
                     h = fam.final_norm(params, x)
                     logits = fam.head(params, h[:, 0])
                     with scopes.scope("ds.sample"):
                         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     proposed.append(tok)
-            return jnp.stack(proposed, axis=1), k_pool, v_pool
+            return (jnp.stack(proposed, axis=1), *pools["full"])
 
         fn = jax.jit(propose, donate_argnums=(6, 7))
         self._compiled[key] = fn
@@ -2184,9 +2039,8 @@ class InferenceEngine:
             "serving step died mid-execution with the KV pools donated "
             "— rebuilding zeroed pools and re-prefilling every running "
             "sequence")
-        self.cache.reset_pools()
-        if self.window_cache is not None:
-            self.window_cache.reset_pools()
+        for cache in self.caches.values():
+            cache.reset_pools()
         if self.draft_cache is not None:
             # the draft pools ride the same compiled calls (donated):
             # assume them consumed too and rebuild — the re-prefills
@@ -2468,15 +2322,14 @@ class InferenceEngine:
         tokens = np.zeros((B, S), np.int32)
         start = np.zeros((B,), np.int32)
         n_new = np.zeros((B,), np.int32)
-        page_table = np.zeros((B, self.n_pages_max), np.int32)
         for i, req in enumerate(reqs):
             shared = req.n_shared * self.page_size
             suffix = req.context[shared:]
             tokens[i, :len(suffix)] = suffix
             start[i] = shared
             n_new[i] = len(suffix)
-            page_table[i, :len(req.pages)] = req.pages
-        return tokens, start, n_new, page_table
+        return (tokens, start, n_new,
+                self._tables(reqs, B, self.n_pages_max)["full"])
 
     def _draft_prefill_twin(self, reqs, B, S):
         """Mirror a prefill into the draft pools (speculation on): the
@@ -2588,11 +2441,8 @@ class InferenceEngine:
         else:
             self.timeline.enqueued(f"prefill {B}x{S}")
             with self._phase("build_inputs"):
-                n_pages_row = S // self.page_size
                 tokens = np.zeros((B, S), np.int32)
                 lengths = np.zeros((B,), np.int32)
-                page_table = np.zeros((B, n_pages_row), np.int32)
-                window_table = np.zeros((B, n_pages_row), np.int32)
                 for i, req in enumerate(plan.prefills):
                     # a block model's whole blocks; the rest opens the
                     # first generated block
@@ -2600,18 +2450,19 @@ class InferenceEngine:
                     ctx = ctx[:self.scheduler.prefill_tokens(len(ctx))]
                     tokens[i, :len(ctx)] = ctx
                     lengths[i] = len(ctx)
-                    page_table[i, :len(req.pages)] = req.pages
-                    in_bucket = req.window_pages[:n_pages_row]
-                    window_table[i, :len(in_bucket)] = in_bucket
                 self._count_moe_rows("prefill", int(lengths.sum()), B * S)
                 args = [jnp.asarray(tokens), jnp.asarray(lengths),
-                        self._table_args(page_table, window_table)]
+                        jax.device_put(self._tables(
+                            plan.prefills, B, S // self.page_size))]
             fn = self._prefill_fn(B, S)
+        # the chunk program takes and returns the full kind's K and V apart
+        chunk = plan.prefill_kind == "chunk"
+        pools = self._pools()
         with self._phase("dispatch"):
-            nxt, *pools = fn(
-                self.params, self.params_stacked, *args, *self._pools(),
-                self._next_rng())
-            self._rebind_pools(*pools)
+            nxt, *out = fn(self.params, self.params_stacked, *args,
+                           *(pools["full"] if chunk else (pools,)),
+                           self._next_rng())
+            self._rebind_pools({"full": tuple(out)} if chunk else out[0])
         self._enqueued("prefill", plan.prefills, nxt)
         if self.spec_k:
             self._draft_prefill_twin(plan.prefills, B, S)
@@ -2628,16 +2479,13 @@ class InferenceEngine:
             tokens = np.zeros((B,), np.int32)
             src = np.full((B,), -1, np.int32)
             lengths = np.zeros((B,), np.int32)
-            page_table = np.zeros((B, self.n_pages_max), np.int32)
-            window_table = np.zeros((B, self.n_pages_max), np.int32)
             for i, req in enumerate(plan.decodes):
                 if req.pending:
                     src[i] = prev_row[id(req)]
                 else:
                     tokens[i] = req.generated[-1]
                 lengths[i] = req.cached + req.pending + 1
-                page_table[i, :len(req.pages)] = req.pages
-                window_table[i, :len(req.window_pages)] = req.window_pages
+            tables = self._tables(plan.decodes, B, self.n_pages_max)
             self.stats["decode_steps"] += 1
             self.stats["decode_kv_tokens"] += int(lengths.sum())
             self.stats["kv_page_steps_latent" if self.latent
@@ -2649,10 +2497,10 @@ class InferenceEngine:
                 self.stats["decode_kv_tokens_window"] += int(
                     np.minimum(lengths, self.window).sum())
                 self.stats["kv_page_steps_window"] += int(
-                    np.count_nonzero(window_table))
+                    np.count_nonzero(tables["window"]))
             self._count_moe_rows("decode", len(plan.decodes), B)
             args = [jnp.asarray(tokens), jnp.asarray(lengths),
-                    self._table_args(page_table, window_table)]
+                    jax.device_put(tables)]
             src = jnp.asarray(src)
         return self._launch_decode(plan, f"decode x{B}", args, src, prev)
 
@@ -2671,10 +2519,10 @@ class InferenceEngine:
         fn = self._decode_fn(plan.decode_batch)
         self.timeline.enqueued(key)
         with self._phase("dispatch"):
-            nxt, *pools = fn(
-                self.params, self.params_stacked, *args, *self._pools(),
+            nxt, pools = fn(
+                self.params, self.params_stacked, *args, self._pools(),
                 self._next_rng(), self._carry, src)
-            self._rebind_pools(*pools)
+            self._rebind_pools(pools)
         if prev is not None:
             self.stats["lookahead_steps"] += 1
         self._carry = nxt
@@ -2694,7 +2542,6 @@ class InferenceEngine:
             state = np.zeros((B, 2 * blk + 1), np.int32)
             src = np.full((B,), -1, np.int32)
             lengths = np.zeros((B,), np.int32)
-            page_table = np.zeros((B, self.n_pages_max), np.int32)
             for i, req in enumerate(plan.decodes):
                 if req.pending:
                     src[i] = prev_row[id(req)]
@@ -2702,7 +2549,6 @@ class InferenceEngine:
                     state[i, :blk] = req.block_tokens
                     state[i, blk:2 * blk] = req.block_masked
                 lengths[i] = self.scheduler.block_start(req) + blk
-                page_table[i, :len(req.pages)] = req.pages
             kv_tokens = int(lengths.sum())
             self.stats["decode_steps"] += 1
             self.stats["block_passes"] += len(plan.decodes)
@@ -2712,7 +2558,8 @@ class InferenceEngine:
                 (-(-lengths // self.page_size)).sum())
             self._count_moe_rows("decode", len(plan.decodes) * blk, B * blk)
             args = [jnp.asarray(state), jnp.asarray(lengths),
-                    jnp.asarray(page_table)]
+                    jax.device_put(self._tables(
+                        plan.decodes, B, self.n_pages_max))]
             src = jnp.asarray(src)
         return self._launch_decode(plan, f"block x{B}", args, src, prev)
 
@@ -2774,24 +2621,28 @@ class InferenceEngine:
              int(self._counts_held),), np.int32))
 
     def _pools(self):
-        """(k_pool, v_pool) as the programs take them: the full kind's
-        pools, or with a window kind (full, window) pairs."""
-        if self.window_cache is None:
-            return self.cache.k, self.cache.v
-        return ((self.cache.k, self.window_cache.k),
-                (self.cache.v, self.window_cache.v))
+        """{cache kind: (K, V) pools | (latent pool,)} as the programs
+        take them, donated, and give them back (`_rebind_pools`)."""
+        return {kind: (c.k,) if c.v is None else (c.k, c.v)
+                for kind, c in self.caches.items()}
 
-    def _rebind_pools(self, k_pool, v_pool):
-        if self.window_cache is None:
-            self.cache.k, self.cache.v = k_pool, v_pool
-        else:
-            (self.cache.k, self.window_cache.k) = k_pool
-            (self.cache.v, self.window_cache.v) = v_pool
+    def _rebind_pools(self, pools):
+        for kind, c in self.caches.items():
+            c.k, c.v = (*pools[kind], None)[:2]     # a latent kind: no V
 
-    def _table_args(self, page_table, window_table):
-        if self.window_cache is None:
-            return jnp.asarray(page_table)
-        return (jnp.asarray(page_table), jnp.asarray(window_table))
+    def _tables(self, reqs, batch, width):
+        """{cache kind: page table [batch, width]} of the rows `reqs`, on
+        the host: a request's pages of that kind (the window kind's are
+        `Request.window_pages`, those inside `width`), the rest the trash
+        page 0."""
+        tables = {kind: np.zeros((batch, width), np.int32)
+                  for kind in self.caches}
+        for kind, table in tables.items():
+            for i, req in enumerate(reqs):
+                pages = req.window_pages[:width] if kind == "window" \
+                    else req.pages
+                table[i, :len(pages)] = pages
+        return tables
 
     def _enqueued(self, phase, reqs, tokens):
         rec = _InFlight(next(self._dispatched), phase, list(reqs), tokens)
@@ -2937,14 +2788,12 @@ class InferenceEngine:
             tokens = np.zeros((B,), np.int32)
             lengths = np.zeros((B,), np.int32)
             windows = np.full((B,), -1, np.int32)
-            page_table = np.zeros((B, self.n_pages_max), np.int32)
             for i, req in enumerate(reqs):
                 tokens[i] = req.generated[-1]
                 lengths[i] = req.cached + 1
                 windows[i] = self.scheduler._spec_window(req)
-                page_table[i, :len(req.pages)] = req.pages
             self.stats["decode_kv_tokens"] += int(lengths.sum())
-            pt = jnp.asarray(page_table)
+            pt = jnp.asarray(self._tables(reqs, B, self.n_pages_max)["full"])
             args = [jnp.asarray(a) for a in (tokens, lengths, windows)]
         fn = self._propose_fn(B)
         self.timeline.enqueued(f"speculate x{B}")
@@ -3213,8 +3062,8 @@ class InferenceEngine:
                 max(self.stats["spec_proposed"], 1)
         # the page pools' bytes a cached token occupies, every cache
         # layer (a looped model's: `loop_steps` a layer)
-        out["kv_bytes_per_token"] = self.cache.bytes_per_token() + (
-            self.window_cache.bytes_per_token() if self.window_cache else 0)
+        out["kv_bytes_per_token"] = sum(
+            cache.bytes_per_token() for cache in self.caches.values())
         total = out["prefill_tokens"] + out["decode_tokens"]
         if self.monitor is not None:
             self.monitor.record(

@@ -355,8 +355,8 @@ def test_the_weights_are_held_once_and_the_pools_carried(setup):
     def decode_text(engine):
         args = (engine.params, engine.params_stacked,
                 jnp.zeros((4,), jnp.int32), jnp.zeros((4,), jnp.int32),
-                jnp.zeros((4, engine.n_pages_max), jnp.int32),
-                *engine._pools(), engine._next_rng(), engine._carry,
+                {"full": jnp.zeros((4, engine.n_pages_max), jnp.int32)},
+                engine._pools(), engine._next_rng(), engine._carry,
                 jnp.full((4,), -1, jnp.int32))
         return engine._decode_fn(4).lower(*args).as_text()
 

@@ -410,9 +410,10 @@ class TestServingQuant:
         eng = InferenceEngine(model, config=conf, params=params)
         assert eng.dtypes["weight"] == "int8"
         # the block stack rests int8; embed/head stay compute dtype
-        b0 = eng.params["blocks"][0]
-        assert isinstance(b0["attn"]["qkv_w"], qm.QuantizedWeight)
-        assert isinstance(b0["mlp"]["in_w"], qm.QuantizedWeight)
+        (stack,) = eng.params_stacked.values()
+        assert isinstance(stack["attn"]["qkv_w"], qm.QuantizedWeight)
+        assert isinstance(stack["mlp"]["in_w"], qm.QuantizedWeight)
+        assert "blocks" not in eng.params
         assert eng.params["embed"]["wte"].dtype != jnp.int8
         rid = eng.submit([3, 5, 7, 9], max_new_tokens=6)
         (toks,) = _drain(eng, [rid])

@@ -154,8 +154,8 @@ def serve_texts(kernel, cfg=None):
 
     def text(fn, tokens, lengths, page_table, *carry):
         return fn.lower(engine.params, engine.params_stacked, tokens,
-                        lengths, engine._table_args(page_table, page_table),
-                        *pools, rng, *carry).compile().as_text()
+                        lengths, dict.fromkeys(engine.caches, page_table),
+                        pools, rng, *carry).compile().as_text()
     prefill = text(engine._prefill_fn(1, 128), np.zeros((1, 128), np.int32),
                    np.ones((1,), np.int32), np.zeros((1, 8), np.int32))
     decode = text(engine._decode_fn(2), np.zeros((2,), np.int32),

@@ -524,8 +524,8 @@ def test_decode_program_leaves_the_pools_in_place(on_chip, v5e_2x2, kv):
         jax.tree_util.tree_map(shape_of, engine.params_stacked),
         shape_of(np.zeros((batch,), np.int32)),
         shape_of(np.zeros((batch,), np.int32)),
-        shape_of(np.zeros((batch, engine.n_pages_max), np.int32)),
-        pool_of(engine.cache.k), pool_of(engine.cache.v),
+        {"full": shape_of(np.zeros((batch, engine.n_pages_max), np.int32))},
+        pool_of(engine._pools()),
         shape_of(jax.random.PRNGKey(0)),
         # the in-flight decode's tokens and each row's place in them
         shape_of(np.zeros((batch,), np.int32)),
@@ -591,15 +591,17 @@ def test_moe_serving_programs_leave_the_experts_in_place(on_chip, v5e_2x2,
     carry = ()
     if program == "decode":
         fn = engine._decode_fn(batch)
-        inputs = (ints(batch), ints(batch), ints(batch, engine.n_pages_max))
+        inputs = (ints(batch), ints(batch),
+                  {"full": ints(batch, engine.n_pages_max)})
         carry = (ints(batch), ints(batch))   # in-flight tokens, row of each
     else:
         fn = engine._prefill_fn(1, seqlen)
-        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+        inputs = (ints(1, seqlen), ints(1),
+                  {"full": ints(1, seqlen // page_size)})
     text = fn.lower(
         jax.tree_util.tree_map(shape_of, engine.params),
         jax.tree_util.tree_map(shape_of, engine.params_stacked), *inputs,
-        shape_of(engine.cache.k), shape_of(engine.cache.v),
+        jax.tree_util.tree_map(shape_of, engine._pools()),
         shape_of(jax.random.PRNGKey(0)), *carry).compile().as_text()
     calls = re.findall(r"%ds\.grouped_matmul[.\d]* = .*tpu_custom_call", text)
     assert len(calls) >= 2, "gate-and-up and down: two kernel calls a layer"
@@ -672,6 +674,38 @@ def test_grouped_window_flash_forward_compiles(on_chip, heads, window,
                           ((1, S), jnp.int32)))
 
 
+def _homogeneous_engine_holds_the_weights_once():
+    """A homogeneous model (`blocks`: a list of layers) behind the same
+    walk: the engine stacks its layers once and keeps no `blocks`, so
+    what the construction leaves live is the weights ONCE beside the
+    pools (the caller here keeps no tree of its own; with the placed list
+    kept beside the stack it was the block weights twice)."""
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+    layers = 4
+    cfg = GPTNeoXConfig(vocab_size=256, hidden_size=256, num_layers=layers,
+                        num_heads=4, max_seq_len=256, param_dtype=BF16)
+    model = GPTNeoX(cfg, use_pallas=False)
+    before = {id(a): a for a in jax.live_arrays()}
+    engine = InferenceEngine(
+        model, params=model.init_params(jax.random.PRNGKey(0)),
+        config={"inference": {
+            "enabled": True, "page_size": 16, "num_pages": 17,
+            "max_batch_size": 2, "token_budget": 256}})
+
+    def nbytes(tree):
+        return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+
+    assert "blocks" not in engine.params
+    (stack,) = engine.params_stacked.values()
+    assert all(leaf.shape[0] == layers
+               for leaf in jax.tree_util.tree_leaves(stack))
+    held = nbytes(engine.params) + nbytes(stack) + nbytes(engine._pools())
+    live = sum(a.nbytes for a in jax.live_arrays() if id(a) not in before)
+    # the rotary tables, the carried tokens: small beside a layer
+    assert held <= live < held + nbytes(stack) // layers, (live, held)
+
+
 def _block_programs_hold_the_weights_once(v5e_2x2, program):
     """The engine's block-pass and prefill programs for SDAR's block at
     the published widths (hidden 2048, 32 query heads over 4 KV heads of
@@ -725,17 +759,20 @@ def _block_programs_hold_the_weights_once(v5e_2x2, program):
     if program == "block_decode":
         fn = engine._decode_fn(batch)
         inputs = (ints(batch, 2 * block + 1), ints(batch),
-                  ints(batch, engine.n_pages_max))
+                  {kind: ints(batch, engine.n_pages_max)
+                   for kind in engine.caches})
         carry = (ints(batch, 2 * block + 1), ints(batch))
         kernels = ("ds.paged_decode_block", "ds.kv_write",
                    "ds.grouped_matmul")
     else:
         fn = engine._prefill_fn(1, seqlen)
-        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+        inputs = (ints(1, seqlen), ints(1),
+                  {kind: ints(1, seqlen // page_size)
+                   for kind in engine.caches})
         kernels = ("ds.flash_fwd", "ds.grouped_matmul")
     text = fn.lower(
         shapes(engine.params), shapes(engine.params_stacked), *inputs,
-        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
         *carry).compile().as_text()
     for name in kernels:
         assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
@@ -770,7 +807,7 @@ def _block_programs_hold_the_weights_once(v5e_2x2, program):
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "block_decode",
-                                     "block_prefill"])
+                                     "block_prefill", "homogeneous"])
 def test_planned_serving_programs_compile_and_hold_the_weights_once(
         on_chip, v5e_2x2, program):
     """The engine's decode and prefill programs for Laguna's block at the
@@ -782,7 +819,11 @@ def test_planned_serving_programs_compile_and_hold_the_weights_once(
     both names, no instruction's result has the shape of a kind's
     experts, and the engine's stacks are the caller's arrays. `block_*`:
     the same for a block-generating model
-    (`_block_programs_hold_the_weights_once`)."""
+    (`_block_programs_hold_the_weights_once`); `homogeneous`: a model of
+    one layer kind walks the same way and its weights too are held once
+    (`_homogeneous_engine_holds_the_weights_once`)."""
+    if program == "homogeneous":
+        return _homogeneous_engine_holds_the_weights_once()
     if program.startswith("block_"):
         return _block_programs_hold_the_weights_once(v5e_2x2, program)
     from jax.sharding import SingleDeviceSharding
@@ -833,19 +874,21 @@ def test_planned_serving_programs_compile_and_hold_the_weights_once(
     if program == "decode":
         fn = engine._decode_fn(batch)
         inputs = (ints(batch), ints(batch),
-                  (ints(batch, engine.n_pages_max),) * 2)
+                  {kind: ints(batch, engine.n_pages_max)
+                   for kind in engine.caches})
         carry = (ints(batch + 1), ints(batch))
         kernels = ("ds.paged_decode", "ds.paged_decode_window",
                    "ds.kv_write", "ds.grouped_matmul")
     else:
         fn = engine._prefill_fn(1, seqlen)
         inputs = (ints(1, seqlen), ints(1),
-                  (ints(1, seqlen // page_size),) * 2)
+                  {kind: ints(1, seqlen // page_size)
+                   for kind in engine.caches})
         kernels = ("ds.flash_fwd", "ds.flash_fwd_window",
                    "ds.grouped_matmul")
     text = fn.lower(
         shapes(engine.params), shapes(engine.params_stacked), *inputs,
-        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
         *carry).compile().as_text()
     for name in kernels:
         assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
@@ -977,17 +1020,21 @@ def test_latent_serving_programs_compile_and_leave_the_pool_in_place(
     carry = ()
     if program == "decode":
         fn = engine._decode_fn(batch)
-        inputs = (ints(batch), ints(batch), ints(batch, engine.n_pages_max))
+        inputs = (ints(batch), ints(batch),
+                  {kind: ints(batch, engine.n_pages_max)
+                   for kind in engine.caches})
         carry = (ints(batch), ints(batch))
         kernels = ("ds.paged_decode_latent", "ds.kv_write",
                    "ds.grouped_matmul")
     else:
         fn = engine._prefill_fn(1, seqlen)
-        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+        inputs = (ints(1, seqlen), ints(1),
+                  {kind: ints(1, seqlen // page_size)
+                   for kind in engine.caches})
         kernels = ("ds.flash_fwd", "ds.grouped_matmul")
     text = fn.lower(
         shapes(engine.params), shapes(engine.params_stacked), *inputs,
-        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
         *carry).compile().as_text()
     for name in kernels:
         assert re.search(rf"%{name}[.\d]* = .*tpu_custom_call", text), name
@@ -1058,17 +1105,21 @@ def test_looped_serving_programs_compile_and_carry_the_pools(
     carry = ()
     if program == "decode":
         fn = engine._decode_fn(batch)
-        inputs = (ints(batch), ints(batch), ints(batch, engine.n_pages_max))
+        inputs = (ints(batch), ints(batch),
+                  {kind: ints(batch, engine.n_pages_max)
+                   for kind in engine.caches})
         # the tokens and, behind them, each row's exit pass
         carry = (ints(2 * batch), ints(batch))
         kernels = ("ds.paged_decode", "ds.kv_write")
     else:
         fn = engine._prefill_fn(1, seqlen)
-        inputs = (ints(1, seqlen), ints(1), ints(1, seqlen // page_size))
+        inputs = (ints(1, seqlen), ints(1),
+                  {kind: ints(1, seqlen // page_size)
+                   for kind in engine.caches})
         kernels = ("ds.flash_fwd",)
     text = fn.lower(
         shapes(engine.params), shapes(engine.params_stacked), *inputs,
-        *shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
         *carry).compile().as_text()
     for name in kernels:
         calls = re.findall(rf"%{name}[.\d]* = .*tpu_custom_call", text)
