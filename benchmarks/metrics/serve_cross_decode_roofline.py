@@ -1,0 +1,5 @@
+from benchmarks import phi4flash_costs
+
+
+def read(rec):
+    return phi4flash_costs.cross_decode_roofline(rec)

@@ -91,7 +91,7 @@ from .admission import (AdmissionController, DrainAborted, RequestFailed,
                         validate_priority)
 from .handoff import (ACCEPTED, HandoffChannel, HandoffRejected,
                       check_geometry, encode_pages, write_pages)
-from .kv_cache import (PagedKVCache, PrefixCache, QuantizedPages,
+from .kv_cache import (PagedKVCache, PrefixCache, QuantizedPages, StateCache,
                        pages_for_tokens, quantize_kv)
 from .metrics import (PREFIX_HIT_RATE, PREFIX_PAGES_SHARED,
                       PREFIX_SAVED_PREFILL_TOKENS, REQUEST_STATUS_FAMILIES,
@@ -176,6 +176,12 @@ class _Family:
         self.moe_layers = self.loop_steps * sum(
             n for spec, _, _, n in self.runs() if spec.ffn == "experts")
         self.moe_held = tuple(getattr(cfg, "moe_held", ()))
+        # layers that read what another layer made (a memory, another
+        # layer's K and V): the walks then carry it; and the first layer
+        # from which a prefill computes the last row alone (the layer
+        # count: none)
+        self.shares = getattr(cfg, "plan_shares", False)
+        self.last_row_from = getattr(cfg, "last_row_from", cfg.num_layers)
 
     def runs(self):
         """What the engine walks: the model's layers as runs of
@@ -436,6 +442,15 @@ class InferenceEngine:
         # the names the scheduler and a benchmark's probes read
         self.cache = self.caches["latent" if self.latent else "full"]
         self.window_cache = self.caches.get("window")
+        # the cache kind WITHOUT pages (a model with state-space layers):
+        # one slot of recurrent state a running sequence, + the trash slot
+        self.state_cache = None
+        if fam.cache_layers("state"):
+            self.state_cache = StateCache(
+                num_layers=fam.cache_layers("state"),
+                num_slots=self.max_batch_size + 1, inner=cfg.ssm_inner,
+                state=cfg.ssm_state, conv=cfg.ssm_conv,
+                dtype=self.compute_dtype)
         # -- prefix/radix cache + speculative decoding (both default-off:
         #    without their config sub-blocks the engine is bit-identical
         #    to the plain PR 8 serving loop) --------------------------------
@@ -510,7 +525,8 @@ class InferenceEngine:
             prefix_cache=self.prefix_cache, spec_tokens=self.spec_k,
             window_cache=self.window_cache, window=self.window,
             block=self.block,
-            mask_token_id=cfg.mask_token_id if self.block else 0)
+            mask_token_id=cfg.mask_token_id if self.block else 0,
+            state_cache=self.state_cache)
         self.n_pages_max = pages_for_tokens(self.max_seq_len,
                                             self.page_size)
         # precision identity of this serving engine
@@ -622,6 +638,19 @@ class InferenceEngine:
                       "decode_kv_tokens_latent": 0,
                       "kv_page_steps_latent": 0,
                       "window_pages_released": 0,
+                      # the recurrent-state cache kind (0 without one):
+                      # slots held now, the bytes one sequence's state
+                      # takes, and both summed over the decode steps
+                      # (slots live in a step; their bytes); the K/V bytes
+                      # a live token holds in each page kind
+                      "state_slots_in_use": 0, "state_bytes": 0,
+                      "state_slot_steps": 0, "state_byte_steps": 0,
+                      "kv_bytes_per_token_full": 0,
+                      "kv_bytes_per_token_window": 0,
+                      # rows a prefill's first and last layers computed
+                      # (equal unless the model's later layers need the
+                      # last row alone: `GPTNeoXConfig.last_row_from`)
+                      "prefill_rows": 0, "prefill_rows_cross": 0,
                       # (token, choice) pairs the routers kept, all
                       # layers, and those that fell on an expert held
                       # here (all of them unless the model holds a share)
@@ -652,6 +681,12 @@ class InferenceEngine:
         # tokens by the pass their exit gate chose (a looped model's
         # `t*`, from 1), read back with the tokens
         self.loop_exit_hist = [0] * self.loop_steps
+        if self.state_cache is not None:
+            self.stats["state_bytes"] = self.state_cache.bytes_per_sequence()
+        for kind in ("full", "window"):
+            if kind in self.caches:
+                self.stats[f"kv_bytes_per_token_{kind}"] = \
+                    self.caches[kind].bytes_per_token()
         # one record a step, the spans' seconds into `stats`; whether the
         # scheduler had work when the last step returned (the caller's
         # time before a step counts against it only then)
@@ -764,6 +799,16 @@ class InferenceEngine:
         elif self.window and self.kv_quant:
             what = ("kv_cache_dtype int8 with a window cache kind: the "
                     "paged kernel's window has no int8 variant")
+        elif self.family.cache_layers("state") and (
+                self.kv_quant or ip["num_pages"] <= self.max_batch_size *
+                self.max_seq_len // self.page_size):
+            whole = self.max_batch_size * self.max_seq_len // self.page_size
+            what = (f"a recurrent-state cache kind and int8 pages, or "
+                    f"num_pages {ip['num_pages']} under max_batch_size x "
+                    f"the pages of max_seq_len + 1 = {whole + 1}: a dry "
+                    f"pool would preempt a request whose state no snapshot "
+                    f"could resume, so the pools hold every running "
+                    f"request whole")
         elif self.latent and any(s.attn != "latent"
                                  for s in self.model.config.layer_plan):
             what = ("latent layers beside full or window layers in one "
@@ -939,7 +984,7 @@ class InferenceEngine:
             rng, logits / self.temperature, axis=-1).astype(jnp.int32)
 
     def _attention(self, q, pools, layer, page_table, lengths, window=None,
-                   block_pass=False):
+                   block_pass=False, sm_scale=None, cross=False):
         """Paged decode attention over layer `layer` of the stacked
         (K, V) `pools`, shard_mapped over the model axis when the mesh
         shards heads (attention is head-independent, so each shard runs
@@ -956,8 +1001,9 @@ class InferenceEngine:
             else:
                 (k, v), scales = leaves, {}
             return paged_decode_attention(
-                q, k, v, pt, ln, backend=self._attn_backend, layer=layer,
-                window=window, block_pass=block_pass, **scales)
+                q, k, v, pt, ln, sm_scale=sm_scale,
+                backend=self._attn_backend, layer=layer, window=window,
+                block_pass=block_pass, cross=cross, **scales)
 
         if self.mp > 1:
             attend = shard_map(
@@ -1004,6 +1050,13 @@ class InferenceEngine:
                                        *rows))
 
     @staticmethod
+    def _write_state(pool, new, slots):
+        """A prefill's recurrent state [layers, B, ...] into its rows'
+        slots of `pool`, whole: the scan started from zero, so nothing of
+        what a slot held before is left."""
+        return pool.at[:, slots].set(new.astype(pool.dtype))
+
+    @staticmethod
     def _run_xs(stack, at, n):
         """What a layer loop over layers [at, at + n) of one kind's
         `stack` takes: (the leaves a layer is sliced out of, the function
@@ -1024,27 +1077,40 @@ class InferenceEngine:
 
         return sliced, layer_of
 
-    def _plan_layers(self, fam, stacks, carry, layer_fn, loop_pass=0):
+    def _plan_layers(self, fam, stacks, carry, layer_fn, loop_pass=0,
+                     layers=None):
         """The layer loop of every model: `layer_fn(carry, bp, spec,
         cache_layer) -> (carry, ys)` over the family's runs
         (`_Family.runs`), a run of consecutive layers of one kind at a
         time (a scan where the run is longer than one layer; a
         homogeneous model is one run, one scan). `cache_layer` is the
         layer's index in its cache kind's pools: pass `loop_pass` of a
-        looped model keeps its K/V behind those of the passes before it.
-        Returns (carry, [(spec, ys stacked over the run)])."""
+        looped model keeps its K/V behind those of the passes before it; a
+        cross layer's is the full layer's it reads, a gmu layer has none.
+        `layers` = (first, past-the-last): the runs that lie in that range
+        of layers alone (a range ends where a run ends). Returns (carry,
+        [(spec, ys stacked over the run)])."""
         cache_at = {kind: loop_pass * (fam.cache_layers(kind) //
                                        fam.loop_steps)
                     for kind in ("full", "window", "latent")}
+        cache_at["ssm"] = 0
         out = []
-        for spec, _, at, n in fam.runs():
-            base = cache_at[spec.attn]
-            cache_at[spec.attn] += n
+        for spec, first, at, n in fam.runs():
+            shared = spec.attn in ("cross", "gmu")
+            if shared:
+                base = cache_at["full"] - 1      # the full layer before it
+            else:
+                base = cache_at[spec.attn]
+                cache_at[spec.attn] += n
+            if layers is not None and not layers[0] <= first < layers[1]:
+                continue
             xs, layer_of = self._run_xs(stacks[spec.kind], at, n)
 
-            def body(carry, x, spec=spec, base=base, layer_of=layer_of):
+            def body(carry, x, spec=spec, base=base, layer_of=layer_of,
+                     shared=shared):
                 bp, i = x
-                return layer_fn(carry, layer_of(bp, i), spec, base + i)
+                return layer_fn(carry, layer_of(bp, i), spec,
+                                base if shared else base + i)
 
             if n == 1:
                 carry, ys = body(carry, (jax.tree_util.tree_map(
@@ -1066,7 +1132,7 @@ class InferenceEngine:
         return neox.block_hidden(block_out), jnp.zeros((), jnp.float32)
 
     def _plan_token_layers(self, fam, stacks, x, pos, pools, tables,
-                           lengths, loop_pass=0):
+                           lengths, loop_pass=0, layers=None, mem=None):
         """The layer loop of a one-token step (decode; each of the
         draft's proposal steps) of the model `fam` describes: `pools` and
         `tables` are {cache kind: (K, V) pools} and {cache kind: page
@@ -1087,17 +1153,31 @@ class InferenceEngine:
         attend over `lengths` = `pos + R` positions with NO mask among
         themselves: the R rows x the query heads of a KV head ride as
         that KV head's one group of the grouped paged kernel, under the
-        name `ds.paged_decode_block`."""
+        name `ds.paged_decode_block`.
+
+        A model whose layers share (`_Family.shares`): the `state` kind's
+        pools are (convolution rows, scan states) and its "table" each
+        row's slot; an ssm layer updates its row's state in place and
+        leaves its scan output as the walk's memory, a gmu layer gates
+        that, a cross layer attends over the full layer's pages and
+        writes nothing. `layers` = (first, past-the-last) walks those
+        layers alone, on pools that hold this token's rows of the full
+        kind already, from the memory `mem` [B, 1, inner] (the second
+        half of a prefill, on each prompt's last row)."""
         cfg, ps = fam.cfg, self.page_size
         B, R = x.shape[:2]
         # an inactive row attends over nothing; an MoE routes it nowhere
         active = jnp.broadcast_to((lengths > 0)[:, None], (B, R))
-        kinds = list(pools)
+        kinds = [k for k in pools if k != "state"]
+        diff = getattr(cfg, "attn_diff", False)
+        scale = getattr(cfg, "attn_scale", None)
+        # a cross layer brings no cache kind of its own
+        rotary = kinds + [k for k in fam._rotary if k not in kinds]
         if R == 1:
-            rot = {k: fam.cos_sin_decode(pos, k) for k in kinds}
+            rot = {k: fam.cos_sin_decode(pos, k) for k in rotary}
         else:
             at = pos[:, None] + jnp.arange(R, dtype=pos.dtype)
-            rot = {k: fam.cos_sin_at(at, k) for k in kinds}
+            rot = {k: fam.cos_sin_at(at, k) for k in rotary}
         page_idx = {k: jnp.take_along_axis(
             tables[k], (pos // ps)[:, None], axis=1)[:, 0] for k in kinds}
         slot = pos % ps
@@ -1142,12 +1222,20 @@ class InferenceEngine:
                 attn = neox.latent_absorb_out(cfg, bp, u)
             return attn.reshape(B, 1, -1), (pool,)
 
+        def cache_kind(spec):
+            # a cross layer reads the full kind's pages and writes none
+            return "full" if spec.attn == "cross" else spec.attn
+
         def paged_attn(x, kv, bp, spec, cache_layer):
-            kind = spec.attn
-            q, k, v = neox._block_qkv(cfg, bp, x, *rot[kind], spec.heads)
-            kv = self._write_rows(kv, kv_rows(k), kv_rows(v), cache_layer,
-                                  page_idx[kind], slot)
+            kind = cache_kind(spec)
+            q, k, v = neox._block_qkv(cfg, bp, x, *rot[spec.attn],
+                                      spec.heads)
+            if k is not None and layers is None:
+                kv = self._write_rows(kv, kv_rows(k), kv_rows(v),
+                                      cache_layer, page_idx[kind], slot)
             with scopes.scope("ds.attn"):
+                if diff:
+                    q = neox.diff_queries(q)
                 # int8 pages dequantize inside the kernel: q stays as it is
                 q = q_rows(q)
                 if not isinstance(kv[0], QuantizedPages):
@@ -1155,23 +1243,50 @@ class InferenceEngine:
                 attn = self._attention(
                     q, kv, cache_layer, tables[kind], lengths,
                     window=self.window if kind == "window" else None,
-                    block_pass=R > 1).astype(x.dtype)
+                    block_pass=R > 1, sm_scale=scale,
+                    cross=spec.attn == "cross").astype(x.dtype)
+                if diff:
+                    attn = neox.diff_combine(cfg, bp["attn"], attn)
             return attn_rows(attn), kv
+
+        def state_mixer(x, pools, mem, bp, spec, cache_layer):
+            """An ssm layer's step on its rows' states, or a gmu layer's
+            gate on the memory: (the mixer's output, pools, memory)."""
+            with scopes.scope("ds.attn"):
+                a = neox.norm(cfg, bp["ln_attn"], x)
+            if spec.attn == "gmu":
+                return neox.gmu_mixer(bp["attn"], a, mem), pools, mem
+            mixed, state, mem = neox.ssm_token(
+                cfg, bp["attn"], a, pools["state"], tables["state"],
+                cache_layer, lengths > 0, backend=self._attn_backend)
+            return mixed, dict(pools, state=state), mem
 
         @scopes.scoped("ds.block")
         def layer(carry, bp, spec, cache_layer):
-            x, pools, held = carry
+            x, pools, held, aux = carry
+            if spec.attn in ("ssm", "gmu"):
+                mixed, pools, mem = state_mixer(
+                    x, pools, aux["mem"], bp, spec, cache_layer)
+                out = neox._block_post_attn(
+                    cfg, bp, x, mixed, reduce_fn=lambda t: t,
+                    token_mask=active, projected=True)
+                return (out, pools, held, {"mem": mem}), None
+            kind = cache_kind(spec)
             attend = latent_attn if spec.attn == "latent" else paged_attn
-            attn, kv = attend(x, pools[spec.attn], bp, spec, cache_layer)
+            attn, kv = attend(x, pools[kind], bp, spec, cache_layer)
             out, rows = self._held_rows(fam, neox._block_post_attn(
                 cfg, bp, x, attn, reduce_fn=lambda t: t, token_mask=active))
-            return (out, dict(pools, **{spec.attn: kv}), held + rows), None
+            return (out, dict(pools, **{kind: kv}), held + rows, aux), None
 
+        aux = {}
+        if fam.shares:
+            aux["mem"] = mem if mem is not None else jnp.zeros(
+                (B, R, cfg.ssm_inner), jnp.float32)
         with scopes.scope("ds.layers"):
             carry, _ = self._plan_layers(
-                fam, stacks, (x, pools, jnp.zeros((), jnp.float32)), layer,
-                loop_pass)
-        return carry
+                fam, stacks, (x, pools, jnp.zeros((), jnp.float32), aux),
+                layer, loop_pass, layers)
+        return carry[:3]
 
     def _loop(self, params, x, state, one_pass, rows):
         """The model's layers `loop_steps` times over the SAME weights:
@@ -1249,7 +1364,8 @@ class InferenceEngine:
             # a block model reads its prompt too under the block-causal mask
             return neox.causal_attention(
                 q, k, v, use_pallas=use_pallas, segment_ids=segment_ids,
-                window=window, block=self.block)[:, :S]
+                window=window, block=self.block,
+                sm_scale=getattr(cfg, "attn_scale", None))[:, :S]
 
         def planned_prefill(params, stacks, tokens, lengths, tables, pools,
                             rng):
@@ -1266,19 +1382,36 @@ class InferenceEngine:
             # causal attention over its own tokens only
             seg = (pos < lengths[:, None]).astype(jnp.int32)
             x = fam.embed_prefill(params, tokens)
-            rot = {k: fam.cos_sin_prefill(S, k) for k in pools}
+            rot = {k: fam.cos_sin_prefill(S, k) for k in pools
+                   if k != "state"}
+            # a model whose later layers need a prompt's LAST row alone:
+            # every row through the layers before `split`, the rest as a
+            # one-token walk over the pages this program has written
+            split = fam.last_row_from
+            L = cfg.num_layers
 
             def layer(carry, bp, spec, cache_layer):
-                x, held = carry
+                x, held, aux = carry
                 y, kv = neox._block_core(
-                    cfg, bp, x, rot[spec.attn], use_pallas, mp=1,
+                    cfg, bp, x, rot.get(spec.attn), use_pallas, mp=1,
                     reduce_fn=lambda t: t, return_kv=True,
                     attn_fn=partial(
                         attention, window=cfg.attn_window
                         if spec.attn == "window" else None),
-                    segment_ids=seg, spec=spec)
+                    segment_ids=seg, spec=spec, shared=aux)
+                if spec.attn == "ssm":
+                    # its state into the slot; its scan output onwards
+                    kv, aux = kv[:2], {"mem": kv[2]}
                 out, rows = self._held_rows(fam, y)
-                return (out, held + rows), kv
+                return (out, held + rows, aux), kv
+
+            def keys_values(x, bp, spec, cache_layer):
+                """The layer `split`'s K and V of every row, and nothing
+                else of it."""
+                with scopes.scope("ds.block"):
+                    _, k, v = neox._block_qkv(cfg, bp, x, *rot[spec.attn],
+                                              spec.heads)
+                return x, (k, v)
 
             G, D = fam.kv_heads, cfg.head_dim
 
@@ -1299,6 +1432,8 @@ class InferenceEngine:
                 a `vmap` over the layers and 542.1 for a loop over pages,
                 Laguna's 2 x 8,192 239.4 / 236.5 / 374.2 (chip runs, PR
                 46: PERF.md section 6; ROADMAP S5 has the rule)."""
+                if kind == "state":
+                    return self._write_state(pool, new, tables[kind])
                 flat_pt = tables[kind].reshape(-1)
 
                 def tiles(rows):
@@ -1327,17 +1462,34 @@ class InferenceEngine:
                 """The layers once over x [B, S, h], the pass's K/V into
                 its own cache layers of the pools."""
                 pools, held = state
+                aux = {"mem": jnp.zeros((B, S, cfg.ssm_inner), jnp.float32)
+                       } if fam.shares else {}
                 with scopes.scope("ds.layers"):
-                    (x, held), runs = self._plan_layers(
-                        fam, stacks, (x, held), layer, loop_pass)
+                    (x, held, aux), runs = self._plan_layers(
+                        fam, stacks, (x, held, aux), layer, loop_pass,
+                        (0, split) if split < L else None)
+                    if split < L:
+                        _, full = self._plan_layers(
+                            fam, stacks, x, keys_values, loop_pass,
+                            (split, split + 1))
+                        runs += full
+                of_kind = {"state": "ssm"}
                 with scopes.scope("ds.kv_write"):
                     # a kind's layers in order: [L_kind, B, S, ...]
                     pools = {kind: tuple(
                         scatter(kind, pool, jnp.concatenate(
                             [kv[i] for spec, kv in runs
-                             if spec.attn == kind]), loop_pass)
+                             if spec.attn == of_kind.get(kind, kind)]),
+                            loop_pass)
                         for i, pool in enumerate(kind_pools))
                         for kind, kind_pools in pools.items()}
+                if split < L:
+                    x, pools, rows = self._plan_token_layers(
+                        fam, stacks, last_rows(x, lengths)[:, None],
+                        jnp.maximum(lengths - 1, 0), pools, tables, lengths,
+                        loop_pass, layers=(split, L),
+                        mem=last_rows(aux["mem"], lengths)[:, None])
+                    held = held + rows
                 return x, (pools, held)
 
             if self.block:
@@ -1351,7 +1503,8 @@ class InferenceEngine:
                 return done, pools
             h, (pools, held), exit_pass = self._loop(
                 params, x, (pools, jnp.zeros((), jnp.float32)),
-                one_pass, lambda x: last_rows(x, lengths))
+                one_pass, (lambda x: last_rows(x, lengths)) if split == L
+                else (lambda x: x[:, 0]))
             nxt = self._sample(fam.head(params, h), rng)
             return self._with_held(nxt, held, exit_pass), pools
 
@@ -2041,6 +2194,8 @@ class InferenceEngine:
             "sequence")
         for cache in self.caches.values():
             cache.reset_pools()
+        if self.state_cache is not None:
+            self.state_cache.reset_pools()
         if self.draft_cache is not None:
             # the draft pools ride the same compiled calls (donated):
             # assume them consumed too and rebuild — the re-prefills
@@ -2451,6 +2606,10 @@ class InferenceEngine:
                     tokens[i, :len(ctx)] = ctx
                     lengths[i] = len(ctx)
                 self._count_moe_rows("prefill", int(lengths.sum()), B * S)
+                self.stats["prefill_rows"] += B * S
+                self.stats["prefill_rows_cross"] += B * (
+                    S if self.family.last_row_from ==
+                    self.model.config.num_layers else 1)
                 args = [jnp.asarray(tokens), jnp.asarray(lengths),
                         jax.device_put(self._tables(
                             plan.prefills, B, S // self.page_size))]
@@ -2498,6 +2657,11 @@ class InferenceEngine:
                     np.minimum(lengths, self.window).sum())
                 self.stats["kv_page_steps_window"] += int(
                     np.count_nonzero(tables["window"]))
+            if self.state_cache is not None:
+                self.stats["state_slots_in_use"] = self.state_cache.in_use
+                self.stats["state_slot_steps"] += len(plan.decodes)
+                self.stats["state_byte_steps"] += len(plan.decodes) * \
+                    self.stats["state_bytes"]
             self._count_moe_rows("decode", len(plan.decodes), B)
             args = [jnp.asarray(tokens), jnp.asarray(lengths),
                     jax.device_put(tables)]
@@ -2623,12 +2787,17 @@ class InferenceEngine:
     def _pools(self):
         """{cache kind: (K, V) pools | (latent pool,)} as the programs
         take them, donated, and give them back (`_rebind_pools`)."""
-        return {kind: (c.k,) if c.v is None else (c.k, c.v)
-                for kind, c in self.caches.items()}
+        pools = {kind: (c.k,) if c.v is None else (c.k, c.v)
+                 for kind, c in self.caches.items()}
+        if self.state_cache is not None:
+            pools["state"] = (self.state_cache.conv, self.state_cache.ssm)
+        return pools
 
     def _rebind_pools(self, pools):
         for kind, c in self.caches.items():
             c.k, c.v = (*pools[kind], None)[:2]     # a latent kind: no V
+        if self.state_cache is not None:
+            self.state_cache.conv, self.state_cache.ssm = pools["state"]
 
     def _tables(self, reqs, batch, width):
         """{cache kind: page table [batch, width]} of the rows `reqs`, on
@@ -2642,6 +2811,10 @@ class InferenceEngine:
                 pages = req.window_pages[:width] if kind == "window" \
                     else req.pages
                 table[i, :len(pages)] = pages
+        if self.state_cache is not None:
+            # the state kind's "table": each row's slot (padding: trash)
+            tables["state"] = np.zeros((batch,), np.int32)
+            tables["state"][:len(reqs)] = [r.state_slot for r in reqs]
         return tables
 
     def _enqueued(self, phase, reqs, tokens):

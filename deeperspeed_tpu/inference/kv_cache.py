@@ -52,7 +52,20 @@ fill the row's last lane tile (``row`` = `latent_row_width`: 640 for 576;
 a TPU lays an array with a ragged last dim out with another dim
 innermost, and every kernel call would copy the pool). The allocator,
 the reference counts and the page tables are the ones every kind
-shares."""
+shares.
+
+**Recurrent state** (`StateCache`; a model with state-space layers): a
+cache kind WITHOUT pages. A sequence owns one fixed SLOT for its life,
+which holds, for every state-space layer, the scan's state (float32,
+`[N, sub, lanes]` as `ops.pallas.ssm.state_tile` lays the channels out)
+and the last K - 1 rows of the convolution's input. A prefill writes the
+slot with the state after the prompt's last real token, computed from a
+zero state (a slot's old content is never read: a slot handed on starts
+from zero), every decode step updates it in place, the scheduler gives
+it back at the request's end. Slot 0 is the trash slot, as page 0 is the
+trash page: padding rows of a batch name it."""
+
+import math
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -279,6 +292,61 @@ class PagedKVCache:
             return self.num_layers * self.k.shape[-1] * itemsize
         per_head = self.head_dim * itemsize + (2 if self.quantized else 0)
         return 2 * self.num_layers * self.num_heads * per_head
+
+
+class StateCache:
+    """The recurrent-state pools and their slot allocator: `conv`
+    [layers, slots, K - 1, sub, lanes] in `dtype` and `ssm` [layers,
+    slots, N, sub, lanes] float32, `num_slots` including the reserved
+    trash slot 0."""
+
+    def __init__(self, num_layers, num_slots, inner, state, conv, dtype):
+        from ..ops.pallas.ssm import state_tile
+        if num_slots < 2:
+            raise ValueError(f"num_slots must be >= 2 (slot 0 is the "
+                             f"reserved trash slot), got {num_slots}")
+        self.num_layers, self.num_slots = int(num_layers), int(num_slots)
+        self.dtype = dtype
+        self._conv_shape = (self.num_layers, self.num_slots, int(conv) - 1,
+                            *state_tile(int(inner)))
+        self._ssm_shape = (self.num_layers, self.num_slots, int(state),
+                           *state_tile(int(inner)))
+        self.reset_pools()
+        self._free = list(range(self.num_slots - 1, 0, -1))
+        self._held = set()
+
+    def reset_pools(self):
+        self.conv = jnp.zeros(self._conv_shape, self.dtype)
+        self.ssm = jnp.zeros(self._ssm_shape, jnp.float32)
+
+    @property
+    def num_free(self):
+        return len(self._free)
+
+    @property
+    def in_use(self):
+        return len(self._held)
+
+    def allocate(self):
+        """A free slot, or None when every one is held."""
+        if not self._free:
+            return None
+        slot = self._free.pop()
+        self._held.add(slot)
+        return slot
+
+    def free(self, slot):
+        if slot not in self._held:
+            raise ValueError(f"double free of state slot {slot}")
+        self._held.remove(slot)
+        self._free.append(slot)
+
+    def bytes_per_sequence(self):
+        """Bytes of recurrent state one sequence holds, all layers."""
+        return self.num_layers * (
+            math.prod(self._conv_shape[2:]) *
+            jnp.dtype(self.dtype).itemsize +
+            math.prod(self._ssm_shape[2:]) * 4)
 
 
 class _PrefixNode:

@@ -121,6 +121,9 @@ class Request:
     # behind the window (never taken at a prefill, given back as the
     # sequence grows)
     window_pages: list = field(default_factory=list)
+    # the request's slot of the recurrent-state cache kind (a model with
+    # state-space layers; 0: none held, the trash slot)
+    state_slot: int = 0
     # the dispatched programs that owe this request a token the host has
     # not read yet, by the engine's dispatch serial (the serve loop's
     # one-step lookahead, docs/inference.md): none or one whenever the
@@ -244,8 +247,20 @@ class ContinuousBatchingScheduler:
     def __init__(self, cache, max_seq_len, token_budget, max_batch_size,
                  prefill_lengths, prefill_batch_sizes, decode_batch_sizes,
                  prefix_cache=None, spec_tokens=0, window_cache=None,
-                 window=0, block=0, mask_token_id=0):
+                 window=0, block=0, mask_token_id=0, state_cache=None):
         self.cache = cache
+        # the recurrent-state cache kind (`kv_cache.StateCache`; a model
+        # with state-space layers): a request holds one slot from its
+        # admission to its end. A state has no pages to share, roll back
+        # or rebuild a part of, and no snapshot is kept
+        self.state_cache = state_cache
+        if state_cache is not None and (prefix_cache is not None or
+                                        spec_tokens or block):
+            raise ValueError(
+                "a recurrent-state cache kind takes neither a prefix cache "
+                "(a shared page has no state that goes with it), "
+                "speculation (a rejected token's step cannot be rolled "
+                "back) nor block generation")
         # a model that generates `block` tokens at a time (0: one), and
         # the token a row of a block holds until it is unmasked
         self.block = int(block)
@@ -493,6 +508,9 @@ class ContinuousBatchingScheduler:
         if request.window_pages:
             self.window_cache.free([p for p in request.window_pages if p])
             request.window_pages = []
+        if request.state_slot:
+            self.state_cache.free(request.state_slot)
+            request.state_slot = 0
 
     # -- the window cache kind ---------------------------------------------
 
@@ -732,6 +750,13 @@ class ContinuousBatchingScheduler:
             if got is not None:
                 pages.extend(got)
                 continue
+            if self.state_cache is not None:
+                raise RuntimeError(
+                    "a page pool ran dry under a model with a "
+                    "recurrent-state cache kind: preemption would drop a "
+                    "state no snapshot could resume; size the pools so "
+                    "that every running request fits (InferenceEngine "
+                    "refuses a smaller num_pages)")
             victim = self._evict_youngest(now)
             if victim is None:
                 raise RuntimeError(
@@ -821,6 +846,9 @@ class ContinuousBatchingScheduler:
                 # requeue at the queue front — holding them to the
                 # budget would wedge the queue behind them forever
                 break
+            if self.state_cache is not None and \
+                    not self.state_cache.num_free:
+                break                      # every kind or none: no slot
             pages = self.cache.allocate(pages_for_tokens(row_len,
                                                          self.page_size))
             if pages is None:
@@ -831,6 +859,9 @@ class ContinuousBatchingScheduler:
                     req.window_pages = []  # both kinds or neither
                     self.cache.free(pages)
                     break
+            if self.state_cache is not None:
+                # one slot for the request's life (seen free above)
+                req.state_slot = self.state_cache.allocate()
             budget -= row_len
             step_len = row_len
             step_kind = req_kind

@@ -47,7 +47,15 @@ class LayerSpec:
     `ffn_width`; `experts`: the routed experts of width
     `moe_expert_width`, with the shared expert where the model has one).
     `rope` is () for plain rotary, or ("yarn", factor, original_max,
-    beta_fast, beta_slow, attention_factor)."""
+    beta_fast, beta_slow, attention_factor).
+
+    Three more mixers take the attention's place and keep no pages of
+    their own: `ssm`, a state-space layer (Mamba-1; `GPTNeoXConfig.ssm_*`)
+    whose cache is a fixed recurrent state a sequence (the `state` cache
+    kind); `gmu`, a gated memory unit on the scan output of the nearest
+    `ssm` layer before it, of the same token; `cross`, attention with the
+    layer's own queries over the K and V of the model's ONE `full` layer,
+    which lies before it. `heads` is 0 for `ssm` and `gmu`."""
     attn: str = "full"
     heads: int = 0
     rotary_pct: float = 1.0
@@ -226,10 +234,55 @@ class GPTNeoXConfig:
     mask_token_id: int = 0
     generation_steps: int = 0
     generation_threshold: float = 0.9
+    # DIFFERENTIAL attention (arXiv:2410.05258) in every attention layer
+    # of the plan: a head here is a PAIR of published heads, `head_dim` the
+    # pair's width: q_p = [q_p1 | q_p2], a KV head's key [k_g1 | k_g2],
+    # its value the whole width; o_p = softmax(q_p1 k_g1 / s) v_g -
+    # lam softmax(q_p2 k_g2 / s) v_g with s = sqrt(head_dim / 2), then an
+    # RMS norm over o_p's features (one scale `subln` a layer) times
+    # (1 - lam0); lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0 from four
+    # vectors a layer, lam0 = `diff_lambda_init(layer)`. Computed on the
+    # kernels every attention uses (`diff_queries`).
+    attn_diff: bool = False
+    # a state-space (`ssm`) layer's facts (Mamba-1): inner channels, state
+    # size, the causal depthwise convolution's taps, the rank of the
+    # step's low-rank projection (0: the plan has no such layer)
+    ssm_inner: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_dt_rank: int = 0
 
     @property
     def head_dim(self):
         return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def attn_scale(self):
+        """The softmax scale where it is not 1 / sqrt(head_dim) (None):
+        differential attention scores half a pair's width."""
+        return 1.0 / math.sqrt(self.head_dim // 2) if self.attn_diff \
+            else None
+
+    @property
+    def plan_shares(self):
+        """Whether layers of the plan read what another layer made (a
+        memory, another layer's K and V): the layer walks then carry it."""
+        return any(s.attn in ("ssm", "gmu", "cross")
+                   for s in self.layer_plan)
+
+    @property
+    def last_row_from(self):
+        """The first layer from which a prefill needs the LAST row alone
+        (`num_layers`: every row everywhere): the one `full` layer of a
+        plan whose later layers are all `gmu` or `cross`, which read the
+        same token's memory and that layer's K and V, never another
+        row's output."""
+        full = [i for i, s in enumerate(self.layer_plan) if s.attn == "full"]
+        if len(full) == 1 and full[0] + 1 < len(self.layer_plan) and all(
+                s.attn in ("gmu", "cross")
+                for s in self.layer_plan[full[0] + 1:]):
+            return full[0]
+        return self.num_layers
 
     @property
     def latent_width(self):
@@ -278,28 +331,45 @@ class GPTNeoXConfig:
 
     def cache_layers(self, attn):
         """How many cache layers of kind `attn` ("full" | "window" |
-        "latent") the model keeps: one a layer of that kind and pass of
-        the loop (`loop_steps`); a homogeneous model's are all "full"."""
+        "latent": pages; "state": a recurrent state a sequence) the model
+        keeps: one a layer of that kind (a `state` layer is an `ssm`
+        layer; a `cross` or `gmu` layer keeps none) and pass of the loop
+        (`loop_steps`); a homogeneous model's are all "full"."""
         if not self.layer_plan:
             return self.num_layers if attn == "full" else 0
+        kind = "ssm" if attn == "state" else attn
         return self.loop_steps * sum(1 for s in self.layer_plan
-                                     if s.attn == attn)
+                                     if s.attn == kind)
 
     def _planned_params(self, held):
         """Parameters of a planned model by layer kind; `held` counts the
         experts held here (else all the router scores)."""
         h, d, G = self.hidden_size, self.head_dim, self.kv_heads
         E = self.experts_held if held else self.moe_num_experts
+        ln = h * (2 if self.norm == "layernorm" else 1)
         total = self.vocab_size * h * \
-            (1 if self.tie_word_embeddings else 2) + h
+            (1 if self.tie_word_embeddings else 2) + ln
         if self.loop_steps > 1:
             total += h + 1              # the exit gate; the loop's weights once
+        bias = 1 if self.use_bias else 0
+        # differential attention: four lambda vectors and the norm's scale
+        diff = 2 * d + d if self.attn_diff else 0
 
         def layer(spec):
             if spec.attn == "latent":
                 attn = self._latent_params(spec.heads)
+            elif spec.attn == "ssm":
+                di, N, R = self.ssm_inner, self.ssm_state, self.ssm_dt_rank
+                attn = h * 2 * di + (self.ssm_conv + 1) * di + \
+                    di * (R + 2 * N) + (R + 1) * di + di * N + di + di * h
+            elif spec.attn == "gmu":
+                attn = 2 * h * self.ssm_inner
+            elif spec.attn == "cross":
+                attn = 2 * h * spec.heads * d + \
+                    bias * (spec.heads * d + h) + diff
             else:
-                attn = 2 * h * spec.heads * d + 2 * h * G * d
+                attn = 2 * h * spec.heads * d + 2 * h * G * d + \
+                    bias * (spec.heads * d + 2 * G * d + h) + diff
                 if self.qk_norm == "head":
                     attn += 2 * d
             if self.attn_gate == "per-head":
@@ -311,13 +381,13 @@ class GPTNeoXConfig:
                     3 * h * (E * self.expert_width + self.moe_shared_width)
                 if self.moe_router_score == "sigmoid":
                     ffn += self.moe_num_experts     # the correction bias
-            return attn + ffn + (4 if self.sublayer_out_norm else 2) * h
+            return attn + ffn + (4 if self.sublayer_out_norm else 2) * ln
 
         total += sum(layer(spec) for spec in self.layer_plan)
         if self.mtp_layers:
             # the nextn block: two norms, the [2h, h] projection, one
             # layer of the last layer's kind, its own final norm
-            total += 2 * h + 2 * h * h + layer(self.layer_plan[-1]) + h
+            total += 2 * ln + 2 * h * h + layer(self.layer_plan[-1]) + ln
         return total
 
     def _latent_params(self, heads):
@@ -405,7 +475,9 @@ class GPTNeoXConfig:
              ("mla_q_rank", 0), ("mla_kv_rank", 0), ("mla_nope_dim", 0),
              ("mla_rope_dim", 0), ("mla_v_dim", 0), ("mtp_layers", 0),
              ("sublayer_out_norm", False), ("loop_steps", 1),
-             ("loop_exit_threshold", 1.0), ("generation_block", 0))
+             ("loop_exit_threshold", 1.0), ("generation_block", 0),
+             ("attn_diff", False), ("ssm_inner", 0), ("ssm_state", 0),
+             ("ssm_conv", 0), ("ssm_dt_rank", 0))
             if getattr(self, k) != plain]
         if self.qk_norm not in (False, True, "head"):
             raise NotImplementedError(
@@ -445,37 +517,44 @@ class GPTNeoXConfig:
                     f"no gate, a softmax router, no shared expert, every "
                     f"expert held, no latent attention, no "
                     f"next-token-prediction block, no norm on a "
-                    f"sublayer's output, no loop and one token a step "
-                    f"under the causal mask")
+                    f"sublayer's output, no loop, one token a step "
+                    f"under the causal mask, no differential attention "
+                    f"and no state-space layer")
             return
         if len(plan) != self.num_layers:
             raise ValueError(f"layer_plan names {len(plan)} layers, "
                              f"num_layers is {self.num_layers}")
         other = [f"{k}={getattr(self, k)!r}" for k, want in
-                 (("norm", "rmsnorm"), ("use_bias", False),
-                  ("use_parallel_residual", False),
-                  ("ffn_gated", True), ("tie_word_embeddings", False),
+                 (("use_parallel_residual", False), ("ffn_gated", True),
                   ("attention_engine", "dense"), ("ffn_quant_recipe", None))
                  if getattr(self, k) != want]
+        if (self.norm == "layernorm") != bool(self.use_bias):
+            other.append(f"norm={self.norm!r} and use_bias={self.use_bias!r}")
         if self.qk_norm is True:
             other.append("qk_norm=True")
         if other:
             raise NotImplementedError(
                 f"a planned block with {', '.join(other)} is not computed: "
-                f"it is pre-norm RMSNorm, two norms a layer, a sequential "
-                f"residual, no bias, no norm on q or k but the one over each "
-                f"head's features (qk_norm='head'), gated FFNs, an untied "
-                f"head")
+                f"it is pre-norm, two norms a layer, a sequential residual, "
+                f"either RMSNorm without biases or LayerNorm (scale and "
+                f"bias) with biases on the attention projections and none "
+                f"on the FFN, no norm on q or k but the one over each "
+                f"head's features (qk_norm='head'), gated FFNs")
         for i, spec in enumerate(plan):
-            if spec.attn not in ("full", "window", "latent") or \
+            if spec.attn not in MIXERS or \
                     spec.ffn not in ("dense", "experts"):
                 raise NotImplementedError(
                     f"layer {i}: attention {spec.attn!r} / FFN "
-                    f"{spec.ffn!r}; the kinds are full | window | latent "
-                    f"and dense | experts")
+                    f"{spec.ffn!r}; the kinds are full | window | latent | "
+                    f"ssm | gmu | cross and dense | experts")
             if spec.attn == "latent":
                 self._check_latent(i, spec)
-            elif spec.heads < 1 or spec.heads % self.kv_heads:
+            elif spec.attn in ("ssm", "gmu"):
+                if spec.heads:
+                    raise ValueError(f"layer {i}: an {spec.attn} layer has "
+                                     f"no heads, got {spec.heads}")
+            elif spec.heads < 1 or (spec.attn != "cross" and
+                                    spec.heads % self.kv_heads):
                 raise ValueError(
                     f"layer {i}: {spec.heads} query heads over "
                     f"{self.kv_heads} KV heads")
@@ -494,12 +573,66 @@ class GPTNeoXConfig:
                     f"router is not told which experts are held")
         self._check_loop()
         self._check_generation_block()
+        self._check_shared()
         if self.moe_held:
             lo, hi = self.moe_held
             if not 0 <= lo < hi <= self.moe_num_experts:
                 raise ValueError(
                     f"moe_held {self.moe_held} is not a range of the "
                     f"router's {self.moe_num_experts} experts")
+
+    def _check_shared(self):
+        """The facts of a plan whose layers read what another layer made
+        (`ssm` / `gmu` / `cross`) and of differential attention, each
+        refused by name where the code has no path."""
+        plan, kinds = self.layer_plan, [s.attn for s in self.layer_plan]
+        dims = {k: getattr(self, k) for k in
+                ("ssm_inner", "ssm_state", "ssm_conv", "ssm_dt_rank")}
+        if "ssm" in kinds and (min(dims.values()) < 1 or
+                               self.ssm_conv < 2):
+            raise ValueError(
+                f"an ssm layer needs every one of {dims} positive and a "
+                f"convolution of at least 2 taps")
+        if "ssm" not in kinds and any(dims.values()):
+            raise ValueError(f"{dims} without an ssm layer in the plan")
+        for i, kind in enumerate(kinds):
+            if kind == "gmu" and "ssm" not in kinds[:i]:
+                raise ValueError(
+                    f"layer {i} is a gmu layer with no ssm layer before it "
+                    f"whose scan output it could gate")
+            if kind == "cross" and kinds[:i].count("full") != 1:
+                raise NotImplementedError(
+                    f"layer {i} is a cross layer behind "
+                    f"{kinds[:i].count('full')} full layers: it reads the "
+                    f"K and V of the plan's ONE full layer, which lies "
+                    f"before it")
+            if kind == "cross" and plan[i].heads % self.kv_heads:
+                raise ValueError(
+                    f"layer {i}: {plan[i].heads} query heads over "
+                    f"{self.kv_heads} KV heads")
+        if self.attn_diff and (
+                self.head_dim % 2 or self.qk_norm or
+                self.attn_gate != "none" or "latent" in kinds or
+                any(s.rotary_pct for s in plan
+                    if s.attn in ("full", "window", "cross"))):
+            raise NotImplementedError(
+                "attn_diff with an odd head_dim, a norm on q or k, an "
+                "attention gate, a latent layer or a rotary: differential "
+                "attention is computed over pairs of half-width heads "
+                "without positions (a rotary would have to turn each half "
+                "of a pair apart)")
+        if not self.plan_shares and not self.attn_diff:
+            return
+        held = [f"{k}={getattr(self, k)!r}" for k, plain in
+                (("loop_steps", 1), ("mtp_layers", 0),
+                 ("generation_block", 0), ("sublayer_out_norm", False))
+                if getattr(self, k) != plain]
+        if held or any(s.ffn != "dense" for s in plan):
+            raise NotImplementedError(
+                f"a plan with an ssm, gmu or cross layer or differential "
+                f"attention, with {', '.join(held) or 'an experts layer'}: "
+                f"such a plan is computed run once, a token a step, with "
+                f"dense FFNs")
 
     def _check_loop(self):
         """A looped model's facts, and the norm on a sublayer's output."""
@@ -607,6 +740,15 @@ class GPTNeoXConfig:
                    num_heads=64, rotary_pct=0.25, **kw)
 
 
+# what takes the attention's place in a planned layer (`LayerSpec.attn`)
+MIXERS = ("full", "window", "latent", "ssm", "gmu", "cross")
+
+
+def diff_lambda_init(layer):
+    """Differential attention's lam0 of 0-based layer `layer`."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -690,9 +832,44 @@ def _stack_init(key, lead, shape, dtype, scale=0.02):
     return out.reshape(*lead, *shape)
 
 
-def init_stack_params(cfg, spec, n, key):
+def _init_ssm_params(cfg, n, ks, out_scale):
+    """An `ssm` layer's leaves (Mamba-1), in the layout the scan runs
+    from (channels last): `in_w` [h, 2 d_i] ([u | z]), `conv_w` [K, d_i]
+    and `conv_b` [d_i] (tap k meets u_{t-(K-1)+k}), `x_w` [d_i, R + 2N]
+    ([step | B | C]), `dt_w` [R, d_i] and `dt_b` [d_i], `A_log` [N, d_i],
+    `D` [d_i], `out_w` [d_i, h]. The published initialisers where they
+    keep the scan alive over thousands of steps: A = -(1 .. N) a channel,
+    `dt_b` the inverse softplus of a step drawn log-uniformly from
+    [0.001, 0.1], `dt_w` at R ** -0.5, D = 1."""
+    h, dt = cfg.hidden_size, cfg.param_dtype
+    di, N, K, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank
+    step = jnp.exp(jax.random.uniform(ks[3], (n, di)) *
+                   (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return {"in_w": _stack_init(ks[0], (n,), (h, 2 * di), dt),
+            "conv_w": _stack_init(ks[1], (n,), (K, di), dt, K ** -0.5),
+            "conv_b": jnp.zeros((n, di), dt),
+            "x_w": _stack_init(ks[9], (n,), (di, R + 2 * N), dt),
+            "dt_w": _stack_init(ks[10], (n,), (R, di), dt, R ** -0.5),
+            "dt_b": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+            "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1, N + 1, dtype=jnp.float32))[None, :, None],
+                (n, N, di)).astype(dt),
+            "D": jnp.ones((n, di), dt),
+            "out_w": _stack_init(ks[2], (n,), (di, h), dt, out_scale)}
+
+
+def init_stack_params(cfg, spec, n, key, layers=None):
     """The parameter stack of `n` layers of kind `spec`, every leaf with
-    the leading dim `n`. No biases. Attention: `q_w` [h, H*d], `kv_w`
+    the leading dim `n` (`layers`: their indices in the model, which
+    differential attention's lam0 follows). Biases only with
+    `cfg.use_bias`, on the attention projections (`q_b`, `kv_b`, `out_b`);
+    with `cfg.attn_diff` four lambda vectors `lam_q1`, `lam_k1`, `lam_q2`,
+    `lam_k2` [d / 2] (normal 0.1), the scale `subln` [d] of the norm over
+    a pair's features, and `lam0` [n] float32, the layer's constant
+    (`diff_lambda_init`; no parameter, stored so that a layer's slice of
+    the stack carries it). A `cross` layer has `q_w`, `out_w` and those
+    alone; an `ssm` layer `_init_ssm_params`' leaves; a `gmu` layer
+    `in_w` [h, d_i] and `out_w` [d_i, h]. Attention: `q_w` [h, H*d], `kv_w`
     [h, 2*G*d] ([K | V], each G heads of d), `out_w` [H*d, h], with
     `qk_norm='head'` the scales `q_norm`, `k_norm` [d] of the norm on each
     head of q and k, and with
@@ -724,12 +901,32 @@ def init_stack_params(cfg, spec, n, key):
                 "kv_b": _stack_init(ks[10], (n,), (kr, H * (nope + vd)), dt),
                 "out_w": _stack_init(ks[2], (n,), (H * vd, h), dt,
                                      out_scale)}
+    elif spec.attn == "ssm":
+        attn = _init_ssm_params(cfg, n, ks, out_scale)
+    elif spec.attn == "gmu":
+        attn = {"in_w": _stack_init(ks[0], (n,), (h, cfg.ssm_inner), dt),
+                "out_w": _stack_init(ks[2], (n,), (cfg.ssm_inner, h), dt,
+                                     out_scale)}
     else:
         attn = {"q_w": _stack_init(ks[0], (n,), (h, H * d), dt),
                 "kv_w": _stack_init(ks[1], (n,), (h, 2 * G * d), dt),
                 "out_w": _stack_init(ks[2], (n,), (H * d, h), dt, out_scale)}
+        if cfg.use_bias:
+            attn.update(q_b=jnp.zeros((n, H * d), dt),
+                        kv_b=jnp.zeros((n, 2 * G * d), dt),
+                        out_b=jnp.zeros((n, h), dt))
+        if spec.attn == "cross":        # another layer's K and V
+            attn = {k: v for k, v in attn.items() if not k.startswith("kv")}
         if cfg.qk_norm == "head":
             attn.update(q_norm=jnp.ones((n, d), dt), k_norm=jnp.ones((n, d), dt))
+        if cfg.attn_diff:
+            for i, name in enumerate(("lam_q1", "lam_k1", "lam_q2",
+                                      "lam_k2")):
+                attn[name] = _stack_init(jax.random.fold_in(ks[3], i), (n,),
+                                         (d // 2,), dt, 0.1)
+            attn["subln"] = jnp.ones((n, d), dt)
+            attn["lam0"] = jnp.asarray(
+                [diff_lambda_init(i) for i in layers], jnp.float32)
     if cfg.attn_gate == "per-head":
         attn["gate_w"] = _stack_init(ks[3], (n,), (h, H), dt)
     if spec.ffn == "dense":
@@ -760,10 +957,12 @@ def init_stack_params(cfg, spec, n, key):
             mlp["shared_in"] = _stack_init(ks[7], (n,), (h, 2 * sw), dt)
             mlp["shared_out"] = _stack_init(ks[8], (n,), (sw, h), dt,
                                             out_scale)
-    ones = jnp.ones((n, h), dt)
-    norms = {"ln_attn": {"scale": ones}, "ln_mlp": {"scale": ones}}
+    ln = {"scale": jnp.ones((n, h), dt)}
+    if cfg.norm == "layernorm":
+        ln["bias"] = jnp.zeros((n, h), dt)
+    norms = {"ln_attn": ln, "ln_mlp": dict(ln)}
     if cfg.sublayer_out_norm:
-        norms.update(ln_attn_out={"scale": ones}, ln_mlp_out={"scale": ones})
+        norms.update(ln_attn_out=dict(ln), ln_mlp_out=dict(ln))
     return dict(norms, attn=attn, mlp=mlp)
 
 
@@ -795,12 +994,13 @@ def init_params(cfg, rng):
             "embed": {"wte": _dense_init(keys[0], (cfg.vocab_size,
                                                    cfg.hidden_size), dt)},
             "stacks": {name: init_stack_params(cfg, spec, len(layers),
-                                               keys[1 + layers[0]])
+                                               keys[1 + layers[0]], layers)
                        for name, (spec, layers) in kinds.items()},
             "final_ln": init_norm_params(cfg),
-            "embed_out": {"wte": _dense_init(
-                keys[-1], (cfg.vocab_size, cfg.hidden_size), dt)},
         }
+        if not cfg.tie_word_embeddings:
+            params["embed_out"] = {"wte": _dense_init(
+                keys[-1], (cfg.vocab_size, cfg.hidden_size), dt)}
         if cfg.mtp_layers:
             params["mtp"] = init_mtp_params(cfg, jax.random.fold_in(rng, 1))
         if cfg.loop_steps > 1:
@@ -961,7 +1161,8 @@ def plan_rotary(cfg, seq_len):
     rotary facts; `check_block` does not hold that, the family file
     does)."""
     return {spec.attn: _rotary_cache(cfg, seq_len, spec=spec)
-            for spec in reversed(cfg.layer_plan)}
+            for spec in reversed(cfg.layer_plan)
+            if spec.attn not in ("ssm", "gmu")}
 
 
 def _rotate_half(x):
@@ -993,7 +1194,7 @@ def apply_rotary(q, k, cos, sin, rot_dim):
 
 
 def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
-                     window=None, block=0):
+                     window=None, block=0, sm_scale=None):
     """Causal MHA core on [B, S, H, D]; fp32 softmax accumulation.
     `k` / `v` may hold fewer (KV) heads than `q`: query head h reads KV
     head h // (H / G); `window` keeps the keys less than `window`
@@ -1001,7 +1202,9 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
     segmented forward kernel and the XLA fallback alike). `block` (a
     power of two, `GPTNeoXConfig.generation_block`; 0: causal) makes the
     mask BLOCK-causal: query i sees key j wherever j // block <= i //
-    block, all of its own block included.
+    block, all of its own block included. `sm_scale`: the softmax scale
+    where it is not 1 / sqrt(D) (differential attention's, on the grouped
+    forward and the fallback).
 
     Uses the Pallas flash-attention kernel on TPU when shapes allow;
     XLA-fused fallback otherwise (the fallback still fuses well — softmax
@@ -1028,12 +1231,13 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
         def kernel(q, k, v, *seg):
             if seg:
                 return flash_attention_segmented(q, k, v, seg[0], True,
+                                                 sm_scale=sm_scale,
                                                  window=window,
                                                  mask_block=block)
             return flash_attention(q, k, v, True)
 
         grouped = window is not None or k.shape[2] != q.shape[2] or \
-            bool(block)
+            bool(block) or sm_scale is not None
         if grouped and segment_ids is None:
             # one kernel path for a window or grouped KV heads: the
             # segmented forward, every token of one document
@@ -1049,7 +1253,7 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
             f"head dim of 64/128/256 and a sequence some 128-multiple "
             f"block divides")
     B, S, H, D = q.shape
-    scale = 1.0 / math.sqrt(D)
+    scale = sm_scale or 1.0 / math.sqrt(D)
     with scopes.scope("ds.attn_xla"):
         if k.shape[2] != H:
             k = jnp.repeat(k, H // k.shape[2], axis=2)
@@ -1122,8 +1326,13 @@ def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
         # a planned block: `nh_local` query heads over the model's KV
         # heads, [K | V] fused, head dim a fact of the model
         d, G = cfg.head_dim, cfg.kv_heads
-        q = _heads_dot(ln1, params["attn"]["q_w"]).reshape(B, S, nh_local, d)
-        kv = _heads_dot(ln1, params["attn"]["kv_w"]).reshape(B, S, 2, G, d)
+        a = params["attn"]
+        q = _plus_bias(_heads_dot(ln1, a["q_w"]), a, "q_b").reshape(
+            B, S, nh_local, d)
+        if "kv_w" not in a:         # a cross layer: another layer's K and V
+            return q, None, None
+        kv = _plus_bias(_heads_dot(ln1, a["kv_w"]), a, "kv_b").reshape(
+            B, S, 2, G, d)
         k, v = kv[:, :, 0], kv[:, :, 1]
         if cfg.qk_norm == "head":
             # over the features of each head, one scale for all heads
@@ -1209,8 +1418,139 @@ def latent_absorb_out(cfg, params, u):
     return jnp.einsum("bhk,khv->bhv", u, w_uv.astype(u.dtype))
 
 
+@scopes.scoped("ds.attn_diff")
+def diff_queries(q):
+    """Differential attention on the kernels every attention uses: pair
+    p's query [q_p1 | q_p2] becomes two query heads, [q_p1 | 0] and
+    [0 | q_p2], which against the KV head's key [k_g1 | k_g2] score
+    q_p1 . k_g1 and q_p2 . k_g2 to the bit (a product with 0 adds 0), so
+    the two softmaxes of a pair are two heads of ordinary grouped
+    attention over the value's whole width. q [..., P, d] -> [..., 2P, d]."""
+    half = q.shape[-1] // 2
+    zeros = jnp.zeros_like(q[..., :half])
+    heads = jnp.stack([jnp.concatenate([q[..., :half], zeros], axis=-1),
+                       jnp.concatenate([zeros, q[..., half:]], axis=-1)],
+                      axis=-2)
+    return heads.reshape(*q.shape[:-2], 2 * q.shape[-2], q.shape[-1])
+
+
+@scopes.scoped("ds.attn_diff")
+def diff_combine(cfg, attn_p, out):
+    """o_p = out_{p,1} - lam out_{p,2}, an RMS norm over o_p's features
+    with the layer's scale `subln`, times (1 - lam0); lam = exp(lq1 . lk1)
+    - exp(lq2 . lk2) + lam0, in float32. out [..., 2P, d] -> [..., P, d]."""
+    f32 = jnp.float32
+    lam0 = attn_p["lam0"].astype(f32)
+    lam = jnp.exp(jnp.sum(attn_p["lam_q1"].astype(f32) *
+                          attn_p["lam_k1"].astype(f32))) - \
+        jnp.exp(jnp.sum(attn_p["lam_q2"].astype(f32) *
+                        attn_p["lam_k2"].astype(f32))) + lam0
+    pairs = out.reshape(*out.shape[:-2], out.shape[-2] // 2, 2,
+                        out.shape[-1]).astype(f32)
+    o = pairs[..., 0, :] - lam * pairs[..., 1, :]
+    o = rms_norm(o, attn_p["subln"], cfg.layernorm_eps) * (1.0 - lam0)
+    return o.astype(out.dtype)
+
+
+def _ssm_conv(p, taps):
+    """c = silu(conv_b + sum_k conv_w[k] * taps[k]) in float32: `taps`
+    are u_{t-K+1} .. u_t, each [..., d_i]."""
+    w = p["conv_w"].astype(jnp.float32)
+    c = p["conv_b"].astype(jnp.float32)
+    for k, tap in enumerate(taps):
+        c = c + w[k] * tap.astype(jnp.float32)
+    return jax.nn.silu(c)
+
+
+def _ssm_coefficients(cfg, p, c, real):
+    """The scan's operands from the convolution's output `c` [..., d_i]
+    (float32): (the step dt [..., d_i], zero where `real` is False, B and
+    C [..., N], A [N, d_i]), all float32."""
+    N, R = cfg.ssm_state, cfg.ssm_dt_rank
+    proj = _wmat(c.astype(p["x_w"].dtype), p["x_w"])
+    dt = jax.nn.softplus(
+        _wmat(proj[..., :R], p["dt_w"]).astype(jnp.float32) +
+        p["dt_b"].astype(jnp.float32))
+    if real is not None:
+        dt = jnp.where(real[..., None], dt, 0.0)
+    return (dt, proj[..., R:R + N].astype(jnp.float32),
+            proj[..., R + N:].astype(jnp.float32),
+            -jnp.exp(p["A_log"].astype(jnp.float32)))
+
+
+@scopes.scoped("ds.ssm_out")
+def _ssm_out(p, s, z):
+    """(s * silu(z)) W_out, the gate in float32."""
+    gated = s * jax.nn.silu(z.astype(jnp.float32))
+    return _wmat(gated.astype(z.dtype), p["out_w"])
+
+
+def ssm_mixer(cfg, p, a, real=None, use_pallas=True):
+    """A state-space layer (Mamba-1) over whole sequences from a zero
+    state: `a` [B, S, h] the normed input, `real` [B, S] marks the real
+    rows (a prefill bucket's padding moves no state: its step is 0 and
+    its input to the convolution is 0). Returns (the mixer's output
+    [B, S, h], (the convolution's state, the last K - 1 real rows of u
+    [B, K - 1, sub, lanes]; the scan's state after the last real row
+    [B, N, sub, lanes]; the scan's output s [B, S, d_i], float32: the
+    MEMORY a later gmu layer gates, after the skip and before the
+    gate))."""
+    from ..ops.pallas.ssm import ssm_scan
+    B, S, _ = a.shape
+    di, K = cfg.ssm_inner, cfg.ssm_conv
+    with scopes.scope("ds.ssm_in"):
+        uz = _wmat(a, p["in_w"])
+        u, z = uz[..., :di], uz[..., di:]
+        if real is not None:
+            u = jnp.where(real[..., None], u, 0)
+        padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
+        c = _ssm_conv(p, [padded[:, k:k + S] for k in range(K)])
+        dt, Bm, Cm, A = _ssm_coefficients(cfg, p, c, real)
+        # u_{n-K+1} .. u_{n-1} of a row of n real tokens: padded[n + j]
+        n = jnp.sum(real, axis=1) if real is not None else \
+            jnp.full((B,), S, jnp.int32)
+        tail = jnp.take_along_axis(
+            padded, (n[:, None] + jnp.arange(K - 1))[..., None], axis=1)
+    s, h = ssm_scan(dt, c, Bm, Cm, A, p["D"],
+                    backend=None if use_pallas else "xla")
+    return _ssm_out(p, s, z), (tail.reshape(B, K - 1, *h.shape[2:]), h, s)
+
+
+def ssm_token(cfg, p, a, state, slots, layer, active, backend=None):
+    """The same layer for ONE token a row: `a` [B, 1, h]; `state` the
+    stacked (convolution rows [L, slots, K - 1, sub, lanes], scan state
+    [L, slots, N, sub, lanes]) pools, row b's at `slots[b]` of layer
+    `layer`, updated in place; an inactive row (`active` False) moves
+    nothing. Returns (output [B, 1, h], the pools, the memory
+    [B, 1, d_i])."""
+    from ..ops.pallas.ssm import ssm_step
+    conv = state[0]
+    B = a.shape[0]
+    di, K = cfg.ssm_inner, cfg.ssm_conv
+    with scopes.scope("ds.ssm_in"):
+        uz = _wmat(a[:, 0], p["in_w"])
+        u, z = uz[..., :di], uz[..., di:]
+        rows = jnp.concatenate(
+            [conv[layer, slots].reshape(B, K - 1, di),
+             u[:, None].astype(conv.dtype)], axis=1)
+        c = _ssm_conv(p, [rows[:, k] for k in range(K)])
+        tail = jnp.where(active[:, None, None], rows[:, 1:], rows[:, :-1])
+        dt, Bm, Cm, A = _ssm_coefficients(cfg, p, c, active)
+    s, state = ssm_step(state, tail, slots, layer, dt, c, Bm, Cm, A, p["D"],
+                        backend=backend)
+    return _ssm_out(p, s, z)[:, None], state, s[:, None]
+
+
+@scopes.scoped("ds.gmu")
+def gmu_mixer(p, a, mem):
+    """A gated memory unit: (mem * silu(a W_1)) W_2 with `mem` the memory
+    state-space layer's scan output of the same tokens (float32)."""
+    gate = jax.nn.silu(_wmat(a, p["in_w"]).astype(jnp.float32))
+    return _wmat((mem * gate).astype(a.dtype), p["out_w"])
+
+
 def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
-                     ffn_quant=None, token_mask=None):
+                     ffn_quant=None, token_mask=None, projected=False):
     """Everything after the attention core: out projection, residuals,
     ln2, MLP (dense or MoE) — shared by training and decode.
     `attn_flat` is the flattened [B, S, h/mp] attention output. With
@@ -1221,7 +1561,9 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
     under delayed-scaling quantization and makes the return
     (out, new_amax_row) — see `ops/pallas/quant_matmul`.
     `token_mask` [B, S] marks the real tokens for a router that drops
-    nothing: a padded row is routed to no expert."""
+    nothing: a padded row is routed to no expert. `projected`: `attn_flat`
+    is the mixer's output [B, S, h] already (an ssm or gmu layer's, whose
+    out-projection is its own)."""
     out_b = params["attn"]["out_b"].astype(x.dtype) \
         if "out_b" in params["attn"] else 0
     if "gate_w" in params["attn"]:
@@ -1235,8 +1577,11 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
             attn_flat = (attn_flat.reshape(B, S, gate.shape[-1], -1) *
                          gate[..., None].astype(attn_flat.dtype)
                          ).reshape(B, S, -1)
-    with scopes.scope("ds.attn"):
-        attn_partial = _wmat(attn_flat, params["attn"]["out_w"])
+    if projected:
+        attn_partial = attn_flat
+    else:
+        with scopes.scope("ds.attn"):
+            attn_partial = _wmat(attn_flat, params["attn"]["out_w"])
 
     if cfg.use_parallel_residual:
         ln2_in = x
@@ -1341,7 +1686,7 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
 @scopes.scoped("ds.block")
 def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
                 return_kv=False, rng=None, attn_fn=None,
-                segment_ids=None, ffn_quant=None, spec=None):
+                segment_ids=None, ffn_quant=None, spec=None, shared=None):
     """Shared block body: `mp == 1` with identity `reduce_fn` is the
     dense block; TP callers pass pre-sliced params (column/row parallel)
     and a psum reduce; the KV-cached decode step reuses the same
@@ -1358,8 +1703,27 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     a window layer. A latent layer runs expanded here (every head's keys
     and values from the latent rows, then the same attention core), and
     what `return_kv` hands back is (its latent rows [B, S, kv_rank +
-    rope],): the cache's one pool's."""
+    rope],): the cache's one pool's.
+
+    `shared` (a plan whose layers read what another made,
+    `GPTNeoXConfig.plan_shares`): {"mem": the memory of the nearest ssm
+    layer before this one, "kv": the K and V of the plan's full layer}.
+    An ssm layer's `return_kv` is `ssm_mixer`'s (convolution state, scan
+    state, memory); a gmu's or a cross layer's is ()."""
     B, S, h = x.shape
+    kind = spec.attn if spec is not None else "full"
+    if kind in ("ssm", "gmu"):
+        token_mask = None if segment_ids is None else segment_ids > 0
+        with scopes.scope("ds.attn"):
+            a = norm(cfg, params["ln_attn"], x)
+        if kind == "ssm":
+            mixed, kv = ssm_mixer(cfg, params["attn"], a, token_mask,
+                                  use_pallas)
+        else:
+            mixed, kv = gmu_mixer(params["attn"], a, shared["mem"]), ()
+        out = _block_post_attn(cfg, params, x, mixed, reduce_fn,
+                               token_mask=token_mask, projected=True)
+        return (out, kv) if return_kv else out
     cos, sin, rot_dim = cos_sin
     heads = spec.heads if spec is not None else cfg.num_heads
     window = cfg.attn_window \
@@ -1373,14 +1737,22 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     else:
         q, k, v = _block_qkv(cfg, params, x, cos, sin, rot_dim, heads // mp)
         kv = (k, v)
+        if kind == "cross":
+            (k, v), kv = shared["kv"], ()
+    diff = getattr(cfg, "attn_diff", False)
     with scopes.scope("ds.attn"):
+        if diff:
+            q = diff_queries(q)
         if attn_fn is not None:
             attn = attn_fn(q, k, v) if segment_ids is None else \
                 attn_fn(q, k, v, segment_ids=segment_ids)
         else:
             attn = causal_attention(
                 q, k, v, use_pallas=use_pallas, segment_ids=segment_ids,
-                window=window, block=getattr(cfg, "generation_block", 0))
+                window=window, block=getattr(cfg, "generation_block", 0),
+                sm_scale=getattr(cfg, "attn_scale", None))
+        if diff:
+            attn = diff_combine(cfg, params["attn"], attn)
     if return_kv and ffn_quant is not None:
         raise ValueError("return_kv and ffn_quant cannot combine (the "
                          "KV-returning decode path serves quantized "
@@ -1727,6 +2099,11 @@ def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids,
     layer's hidden states, before the final norm (what the
     next-token-prediction block reads)."""
     S = tokens.shape[1]
+    if segment_ids is not None and cfg.plan_shares:
+        raise NotImplementedError(
+            "packed rows (segment_ids) through a plan with an ssm, gmu or "
+            "cross layer: the scan and the convolution do not start anew "
+            "at a document's edge; one prompt a row is computed")
     with scopes.scope("ds.embed"):
         x = params["embed"]["wte"][tokens]
     rotary = plan_rotary(cfg, S)
@@ -1735,14 +2112,24 @@ def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids,
         pos = segment_relative_positions(segment_ids)
         rotary = {k: (c[pos], s_[pos], r) for k, (c, s_, r) in rotary.items()}
     layers = plan_layer_params(cfg, params["stacks"])
+    shares = cfg.plan_shares
 
     def stack(x):
+        shared = {}
         with scopes.scope("ds.layers"):
             for spec, bp in layers:
-                x = block_hidden(_block_core(
-                    cfg, bp, x, rotary[spec.attn], use_pallas, mp=1,
+                out = _block_core(
+                    cfg, bp, x, rotary.get(spec.attn), use_pallas, mp=1,
                     reduce_fn=lambda t: t, segment_ids=segment_ids,
-                    spec=spec))
+                    spec=spec, return_kv=shares, shared=shared)
+                if shares:
+                    # what a later gmu or cross layer reads
+                    out, kv = out
+                    if spec.attn == "ssm":
+                        shared["mem"] = kv[2]
+                    elif spec.attn == "full":
+                        shared["kv"] = kv
+                x = block_hidden(out)
         return x
 
     if cfg.loop_steps > 1:
@@ -2069,7 +2456,9 @@ class GPTNeoX:
                 f"layers, grouped KV heads, an attention gate, a shared "
                 f"expert, a held share of the experts, latent attention, "
                 f"a stack looped over its weights, generation by blocks, "
-                f"whose masking schedule no configuration key gives) "
+                f"whose masking schedule no configuration key gives, a "
+                f"state-space layer, whose scan has no backward, "
+                f"differential attention) "
                 f"is not built; the flash backward, the parameter specs "
                 f"and the pipeline layers are the homogeneous block's. "
                 f"InferenceEngine serves it (`loss_fn` alone computes a "

@@ -50,7 +50,9 @@ def dispatch_report():
     layout's plan took (counted: each pair's row from its rank among its
     expert's pairs, never a sort by expert; the buffer row -> pair map
     by one sort of the rows: `moe.layer.dropless_plan`); empty until
-    that layer is traced. ``xla_on_tpu`` names every dispatcher
+    that layer is traced. ``ssm``: {"scan" / "step": backend} of a
+    state-space layer's selective scan and its one-token step
+    (`ops.pallas.ssm`). ``xla_on_tpu`` names every dispatcher
     that, on a TPU, took XLA where it has a kernel (`note_xla_on_tpu`).
     """
     from .pallas.decode_attention import _LAST_BACKEND
@@ -61,6 +63,7 @@ def dispatch_report():
     from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
     from .pallas.grouped_matmul import _PLANS_TRACED
     from .pallas.quant_matmul import _LAST_BACKEND as _QMM_BACKEND
+    from .pallas.ssm import _LAST_BACKEND as _SSM_BACKEND
     return {"flash": dict(_LAST_BLOCKS, masked_tiles=dict(_LAST_MASKED),
                           bodies_built={k: (n, round(t, 3)) for k, (n, t)
                                         in _BODY_BUILDS.items()}),
@@ -70,6 +73,7 @@ def dispatch_report():
             "quant_matmul": dict(_QMM_BACKEND),
             "grouped_matmul": dict(_GMM_BACKEND),
             "moe": {"plan": dict(_PLANS_TRACED)},
+            "ssm": dict(_SSM_BACKEND),
             "xla_on_tpu": sorted(_XLA_NOTED)}
 
 

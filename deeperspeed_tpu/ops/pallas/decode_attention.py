@@ -555,7 +555,7 @@ def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            sm_scale=None, backend=None, k_scales=None,
                            v_scales=None, layer=None, window=None,
-                           block_pass=False):
+                           block_pass=False, cross=False):
     """One decode step of paged attention: ``out[b, h] = softmax(q[b, h]
     · K[b]) · V[b]`` with K/V read through ``page_table[b]`` and masked
     at ``lengths[b]``.
@@ -581,7 +581,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     ``block_pass``: the call is a block pass's
     (`InferenceEngine._plan_token_layers`), which brings a block's rows x
     the query heads of a KV head as that KV head's group of "query heads":
-    the same kernel under the scope `ds.paged_decode_block`.
+    the same kernel under the scope `ds.paged_decode_block`. ``cross``:
+    the call is a cross layer's, over pages another layer wrote: the same
+    kernel under the scope `ds.paged_decode_cross`.
 
     backend: None = auto (Pallas kernel on TPU when
     `paged_decode_supported`, XLA fallback otherwise — CPU test runs
@@ -646,8 +648,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, page_table, lengths, sm_scale,
         k_scales=k_scales, v_scales=v_scales, layer=layer, window=window,
-        name="ds.paged_decode" if window is None
-        else "ds.paged_decode_window")
+        name="ds.paged_decode_cross" if cross else
+        "ds.paged_decode" if window is None else "ds.paged_decode_window")
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,18 @@ SCOPES = {
     "ds.paged_decode_latent": ("kernel", "the absorbed paged decode of a "
                                          "latent layer: every head over "
                                          "ONE [page, row] tile a page"),
+    "ds.paged_decode_cross": ("kernel", "the paged decode of a cross "
+                                        "layer: the same kernel over "
+                                        "ANOTHER layer's pages (the one "
+                                        "full layer's, which alone "
+                                        "writes them)"),
+    "ds.ssm_scan": ("kernel", "a state-space layer's selective scan over "
+                              "a prompt: a lane tile's state resident "
+                              "over the time walk"),
+    "ds.ssm_step": ("kernel", "the scan's one-token step of a decode "
+                              "batch: each row's recurrent state read "
+                              "from its slot, updated and written back "
+                              "in place"),
     "ds.adam": ("kernel", "fused Adam over a flat shard"),
     "ds.sparse_attn_fwd": ("kernel", "block-sparse attention forward"),
     "ds.sparse_attn_bwd_dkv": ("kernel", "block-sparse backward, dk/dv"),
@@ -83,6 +95,22 @@ SCOPES = {
     "ds.mla_absorb": ("region", "the absorbed form (decode): q' = q_nope "
                                 "W_uk^T before the kernel, o = u W_uv "
                                 "after it"),
+    "ds.ssm_in": ("region", "a state-space layer before its scan: the "
+                            "in-projection to [u | z], the causal "
+                            "depthwise convolution of u with its "
+                            "state's rows, the projections to the step, "
+                            "B and C, the step's softplus"),
+    "ds.ssm_out": ("region", "a state-space layer after its scan: the "
+                             "gate s * silu(z) and the out-projection"),
+    "ds.gmu": ("region", "a gated memory unit: the memory state-space "
+                         "layer's scan output of the same token, gated "
+                         "by silu of a projection of the normed input, "
+                         "and the out-projection"),
+    "ds.attn_diff": ("region", "differential attention around its two "
+                               "softmaxes: the pairs' queries laid out "
+                               "for the kernel, lambda, the subtraction, "
+                               "the norm over a pair's features and its "
+                               "scale"),
     "ds.moe_shared": ("region", "the shared expert every token passes "
                                 "through, beside the routed ones"),
     "ds.attn_xla": ("region", "the XLA fallback of attention"),

@@ -752,7 +752,9 @@ class TestDispatchReport:
         report = dispatch_report()
         assert set(report) == {"flash", "attention", "decode_attention",
                                "quant_matmul", "grouped_matmul", "moe",
-                               "xla_on_tpu"}
+                               "ssm", "xla_on_tpu"}
+        # a state-space layer's scan and step, by the backend that ran
+        assert set(report["ssm"]) <= {"scan", "step"}
         # the dropless MoE layers traced so far, by the form of their plan
         assert set(report["moe"]) == {"plan"}
         assert set(report["moe"]["plan"]) <= {"counted"}

@@ -268,8 +268,8 @@ def pallas_calls():
 CALLS = pallas_calls()
 
 
-def test_all_sixteen_sites_are_found():
-    assert len(CALLS) == 16
+def test_all_eighteen_sites_are_found():
+    assert len(CALLS) == 18
 
 
 @pytest.mark.parametrize("where,name,fn,tree", CALLS,
@@ -295,10 +295,12 @@ def test_pallas_call_is_named_from_the_table(where, name, fn, tree):
     for call in callers:
         given = next((k.value for k in call.keywords if k.arg == name.id),
                      None) or call.args[position]
-        # a literal, or a choice between two (a window layer's kernel is
-        # the full layer's under its own name)
-        choices = [given.body, given.orelse] \
-            if isinstance(given, ast.IfExp) else [given]
+        # a literal, or a choice among literals (a window or a cross
+        # layer's kernel is the full layer's under its own name)
+        choices = [given]
+        while any(isinstance(c, ast.IfExp) for c in choices):
+            choices = [part for c in choices for part in (
+                (c.body, c.orelse) if isinstance(c, ast.IfExp) else (c,))]
         assert all(isinstance(c, ast.Constant) and c.value in kernels
                    for c in choices), \
             f"{where}: {fn.name} called at line {call.lineno} without " \

@@ -30,11 +30,11 @@ import jax.numpy as jnp  # noqa: E402
 # `ops.pallas` re-exports several functions under their module's own
 # name (`flash_attention`, `grouped_matmul`, ...): fetch modules by path
 (fa, decode_attention, block_sparse_attention, grouped_matmul, quant_matmul,
- optimizer) = KERNEL_MODULES = tuple(
+ optimizer, ssm) = KERNEL_MODULES = tuple(
     importlib.import_module(f"deeperspeed_tpu.ops.pallas.{name}")
     for name in ("flash_attention", "decode_attention",
                  "block_sparse_attention", "grouped_matmul", "quant_matmul",
-                 "optimizer"))
+                 "optimizer", "ssm"))
 from deeperspeed_tpu.ops import dispatch_report  # noqa: E402
 
 BF16 = jnp.bfloat16
@@ -904,16 +904,122 @@ def test_planned_serving_programs_compile_and_hold_the_weights_once(
 
 
 # ---------------------------------------------------------------------------
+# a recurrent-state cache kind (phi4flash) at its published widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows", [1024, 128])
+def test_ssm_scan_compiles(on_chip, rows):
+    f32 = jnp.float32
+    text = on_chip(
+        lambda *a: ssm.ssm_scan(*a, backend="pallas"),
+        ((1, rows, 5120), f32), ((1, rows, 5120), f32), ((1, rows, 16), f32),
+        ((1, rows, 16), f32), ((16, 5120), f32), ((5120,), f32))
+    assert kernel_names(text) == {"ds.ssm_scan"}
+
+
+def test_ssm_step_compiles(on_chip):
+    f32 = jnp.float32
+    text = on_chip(
+        lambda conv, pool, *a: ssm.ssm_step((conv, pool), *a,
+                                            backend="pallas"),
+        ((9, 97, 3, 8, 640), BF16), ((9, 97, 16, 8, 640), f32),
+        ((96, 3, 5120), BF16), ((96,), jnp.int32), ((), jnp.int32),
+        ((96, 5120), f32), ((96, 5120), f32), ((96, 16), f32),
+        ((96, 16), f32), ((16, 5120), f32), ((5120,), f32))
+    assert kernel_names(text) == {"ds.ssm_step"}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_state_kind_serving_programs_compile_and_carry_every_pool(
+        on_chip, v5e_2x2, program):
+    """The engine's decode and prefill programs for phi4flash's block at
+    the published widths (hidden 2560, 20 pairs of 128 over 10 KV heads,
+    window 512, inner 5120, state 16, MLP 10240; a small vocabulary),
+    eight layers in the published order (ssm, window, ssm, window, ssm,
+    full, gmu, cross), compiled for the described v5e from shapes alone:
+    every kernel runs under its own name, nothing falls to XLA, and no
+    instruction's result has the shape of a page pool or of a state
+    pool (a decode step moves rows and states, never a pool)."""
+    from jax.sharding import SingleDeviceSharding
+    from benchmarks.families import phi4flash as family
+    from deeperspeed_tpu.inference import InferenceEngine
+    conf = {"hidden_act": "silu", "hidden_size": 2560,
+            "intermediate_size": 10240, "layer_norm_eps": 1e-5,
+            "max_position_embeddings": 262144, "mb_per_layer": 2,
+            "num_attention_heads": 40, "num_hidden_layers": 8,
+            "num_key_value_heads": 20, "sliding_window": 512,
+            "tie_word_embeddings": True, "mlp_bias": False,
+            "lm_head_bias": False, "vocab_size": 1024, "embd_pdrop": 0,
+            "resid_pdrop": 0}
+    model = family.build_model(conf, "bfloat16",
+                               {"use_pallas": True, "max_seq_len": 3072})
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    batch, page, seqlen = 96, 64, 1024
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page, "num_pages": batch * 48 + 17,
+        "max_seq_len": 3072, "max_batch_size": batch,
+        "token_budget": seqlen + batch, "prefill_lengths": [seqlen],
+        "prefill_batch_sizes": [1], "decode_batch_sizes": [batch]}})
+    assert engine.cache.k.shape == (1, batch * 48 + 17, 10, page, 128)
+    assert engine.window_cache.k.shape == (2, batch * 9 + 1, 10, page, 128)
+    assert engine.state_cache.ssm.shape == (3, batch + 1, 16, 8, 640)
+    assert engine.state_cache.conv.shape == (3, batch + 1, 3, 8, 640)
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    def tables(rows, width):
+        return dict({kind: ints(rows, width) for kind in engine.caches},
+                    state=ints(rows))
+
+    shapes = functools.partial(jax.tree_util.tree_map, shape_of)
+    carry = ()
+    if program == "decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch), ints(batch),
+                  tables(batch, engine.n_pages_max))
+        carry = (ints(batch), ints(batch))
+        kernels = {"ds.paged_decode", "ds.paged_decode_window",
+                   "ds.paged_decode_cross", "ds.kv_write", "ds.ssm_step"}
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1), tables(1, seqlen // page))
+        kernels = {"ds.flash_fwd_window", "ds.ssm_scan", "ds.paged_decode",
+                   "ds.paged_decode_cross"}
+    text = fn.lower(
+        shapes(engine.params), shapes(engine.params_stacked), *inputs,
+        shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        *carry).compile().as_text()
+    assert kernel_names(text) == kernels
+    assert "ds.attn_xla" not in text and "ds.paged_decode_xla" not in text
+    for name in ("ds.ssm_in", "ds.ssm_out", "ds.gmu", "ds.attn_diff"):
+        assert name in text, name
+    if program == "decode":
+        for pool in (engine.cache.k, engine.window_cache.k):
+            assert not pool_shaped_moves(text, pool.shape)
+        assert not pool_shaped_moves(text, engine.state_cache.ssm.shape,
+                                     "f32")
+        assert not pool_shaped_moves(text, engine.state_cache.conv.shape)
+
+
+# ---------------------------------------------------------------------------
 # latent attention (GLM-4.7-Flash) at its published widths
 # ---------------------------------------------------------------------------
 
 LATENT_POOL = ((6, 289, 64, 640), BF16)     # a 576-wide row in whole lanes
 
 
-def pool_shaped_moves(text, shape):
+def pool_shaped_moves(text, shape, dtype="bf16"):
     """Instructions that produce an array of the pool's shape and are
     neither a kernel nor a way of carrying it."""
-    shaped = re.compile(r"bf16\[" + ",".join(map(str, shape)) + r"\]")
+    shaped = re.compile(dtype + r"\[" + ",".join(map(str, shape)) + r"\]")
     moved = []
     for line in text.splitlines():
         m = INSTRUCTION.match(line)
