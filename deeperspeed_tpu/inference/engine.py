@@ -610,11 +610,14 @@ class InferenceEngine:
                       # (`loop_steps` a program; 1 unless the model loops)
                       "decode_steps": 0, "loop_passes": 0,
                       # a block-generating model's passes, in ROW-passes
-                      # (one sequence's block through one program):
-                      # dispatched; read back as commits; blocks a live
-                      # request committed; rows its passes unmasked; the
-                      # positions the dispatched rows attended (n + block
-                      # a row-pass); the host seconds of the passes
+                      # (one sequence's two slots through one program):
+                      # dispatched; read back as having committed a block
+                      # (and of those: the commits that rode the pass
+                      # that opened the successor; the passes that did
+                      # nothing but commit); blocks a live request
+                      # committed; rows its passes unmasked; the
+                      # positions the dispatched rows attended (n + 2
+                      # blocks a row-pass); the host seconds of the passes
                       # inside decode_s. `decode_tokens` stays the tokens
                       # DELIVERED (a block's contiguous unmasked prefix);
                       # requests whose FIRST row was unmasked (wherever
@@ -622,6 +625,8 @@ class InferenceEngine:
                       # the delivered prefix may wait for a row to its
                       # left) and their seconds since the submit
                       "block_passes": 0, "block_commit_passes": 0,
+                      "block_fused_commits": 0,
+                      "block_commit_only_passes": 0,
                       "blocks_committed": 0, "block_tokens_final": 0,
                       "decode_kv_tokens_block": 0, "block_pass_s": 0.0,
                       "block_first_unmasks": 0, "block_first_unmask_s": 0.0,
@@ -984,31 +989,36 @@ class InferenceEngine:
             rng, logits / self.temperature, axis=-1).astype(jnp.int32)
 
     def _attention(self, q, pools, layer, page_table, lengths, window=None,
-                   block_pass=False, sm_scale=None, cross=False):
+                   sm_scale=None, cross=False):
         """Paged decode attention over layer `layer` of the stacked
         (K, V) `pools`, shard_mapped over the model axis when the mesh
         shards heads (attention is head-independent, so each shard runs
         the kernel on its local heads — no collective). Int8 pools
         arrive as `QuantizedPages`; the per-page scale pools ride the
-        same head-sharded placement as the data pools."""
+        same head-sharded placement as the data pools. `lengths` [B, 2]:
+        a block pass's, the ends of its two slots."""
         leaves = jax.tree_util.tree_leaves(pools)
         quant = isinstance(pools[0], QuantizedPages)
+        block_pass = lengths.ndim == 2
 
         def attend(q, pt, ln, layer, *leaves):
             if quant:                           # (data, scale) of K, of V
                 k, k_scale, v, v_scale = leaves
-                scales = {"k_scales": k_scale, "v_scales": v_scale}
+                extra = {"k_scales": k_scale, "v_scales": v_scale}
             else:
-                (k, v), scales = leaves, {}
+                (k, v), extra = leaves, {}
+            if block_pass:
+                extra["first_lengths"], ln = ln[:, 0], ln[:, 1]
             return paged_decode_attention(
                 q, k, v, pt, ln, sm_scale=sm_scale,
                 backend=self._attn_backend, layer=layer, window=window,
-                block_pass=block_pass, cross=cross, **scales)
+                block_pass=block_pass, cross=cross, **extra)
 
         if self.mp > 1:
             attend = shard_map(
                 attend, mesh=self.mesh,
-                in_specs=(P(None, MODEL_AXIS, None), P(None, None), P(None),
+                in_specs=(P(None, MODEL_AXIS, None), P(None, None),
+                          P(*(None,) * lengths.ndim),
                           P()) + self._pool_specs(leaves),
                 out_specs=P(None, MODEL_AXIS, None), check_vma=False)
         return attend(q, page_table, lengths, layer, *leaves)
@@ -1132,7 +1142,8 @@ class InferenceEngine:
         return neox.block_hidden(block_out), jnp.zeros((), jnp.float32)
 
     def _plan_token_layers(self, fam, stacks, x, pos, pools, tables,
-                           lengths, loop_pass=0, layers=None, mem=None):
+                           lengths, loop_pass=0, layers=None, mem=None,
+                           active=None):
         """The layer loop of a one-token step (decode; each of the
         draft's proposal steps) of the model `fam` describes: `pools` and
         `tables` are {cache kind: (K, V) pools} and {cache kind: page
@@ -1147,13 +1158,17 @@ class InferenceEngine:
         a kernel that takes the layer index, they stay where they are.
 
         `x` [B, R, hidden]: R = 1, a token a sequence at position `pos`
-        [B]; or a block model's block, R rows a sequence at positions
-        `pos` .. `pos + R - 1`. A block's K and V rows go into their page
-        as one run (they lie in one packed group of it) and its rows
-        attend over `lengths` = `pos + R` positions with NO mask among
-        themselves: the R rows x the query heads of a KV head ride as
-        that KV head's one group of the grouped paged kernel, under the
-        name `ds.paged_decode_block`.
+        [B]; or a block model's pass, R = 2 blocks of rows a sequence at
+        positions `pos` .. `pos + R - 1`: two SLOTS, the block at `pos`
+        and its successor, with `lengths` [B, 2] their ends and `active`
+        [B, R] the rows that count (a dead slot's are routed to no expert
+        and written to the trash page). Each slot's K and V rows go into
+        their page as one run (a block lies in one packed group of one
+        page; the two together need not) and a slot's rows attend the
+        positions under the slot's end, their own among them and NO mask
+        inside the slot: the R rows x the query heads of a KV head ride
+        as that KV head's one group of the grouped paged kernel, K and V
+        read once for both slots, under the name `ds.paged_decode_block`.
 
         A model whose layers share (`_Family.shares`): the `state` kind's
         pools are (convolution rows, scan states) and its "table" each
@@ -1166,26 +1181,36 @@ class InferenceEngine:
         half of a prefill, on each prompt's last row)."""
         cfg, ps = fam.cfg, self.page_size
         B, R = x.shape[:2]
-        # an inactive row attends over nothing; an MoE routes it nowhere
-        active = jnp.broadcast_to((lengths > 0)[:, None], (B, R))
         kinds = [k for k in pools if k != "state"]
         diff = getattr(cfg, "attn_diff", False)
         scale = getattr(cfg, "attn_scale", None)
         # a cross layer brings no cache kind of its own
         rotary = kinds + [k for k in fam._rotary if k not in kinds]
         if R == 1:
+            # an inactive row attends over nothing; an MoE routes it nowhere
+            active = jnp.broadcast_to((lengths > 0)[:, None], (B, R))
             rot = {k: fam.cos_sin_decode(pos, k) for k in rotary}
+            # the runs of rows written: (first position, rows, live or None)
+            runs = [(pos, 0, None)]
         else:
             at = pos[:, None] + jnp.arange(R, dtype=pos.dtype)
             rot = {k: fam.cos_sin_at(at, k) for k in rotary}
-        page_idx = {k: jnp.take_along_axis(
-            tables[k], (pos // ps)[:, None], axis=1)[:, 0] for k in kinds}
-        slot = pos % ps
+            runs = [(pos + lo, slice(lo, lo + R // 2), active[:, lo])
+                    for lo in (0, R // 2)]
+
+        def page_of(table, start, live):
+            page = jnp.take_along_axis(
+                table, (start // ps)[:, None], axis=1)[:, 0]
+            return page if live is None else jnp.where(live, page, 0)
+
+        page_idx = {k: [page_of(tables[k], start, live)
+                        for start, _, live in runs] for k in kinds}
+        slot = [start % ps for start, _, _ in runs]
         G = fam.kv_heads
 
-        def kv_rows(t):
-            """K or V [B, R, G, D] as `_write_rows` takes it."""
-            return t[:, 0] if R == 1 else jnp.swapaxes(t, 1, 2)
+        def kv_rows(t, rows):
+            """A run's K or V of [B, R, G, D] as `_write_rows` takes it."""
+            return t[:, rows] if R == 1 else jnp.swapaxes(t[:, rows], 1, 2)
 
         def q_rows(q):
             """q [B, R, H, D] as the paged kernel's [B, heads, D]: a KV
@@ -1210,8 +1235,8 @@ class InferenceEngine:
             q_nope, q_rope, row = neox._latent_rows(
                 cfg, bp, x, *rot["latent"][:2], spec.heads)
             pool = paged_latent_write(
-                kv[0], row[:, 0], cache_layer, page_idx["latent"], slot,
-                backend=self._attn_backend)
+                kv[0], row[:, 0], cache_layer, page_idx["latent"][0],
+                slot[0], backend=self._attn_backend)
             with scopes.scope("ds.attn"):
                 q = neox.latent_absorb_q(cfg, bp, q_nope[:, 0], q_rope[:, 0])
                 u = paged_latent_decode(
@@ -1231,8 +1256,10 @@ class InferenceEngine:
             q, k, v = neox._block_qkv(cfg, bp, x, *rot[spec.attn],
                                       spec.heads)
             if k is not None and layers is None:
-                kv = self._write_rows(kv, kv_rows(k), kv_rows(v),
-                                      cache_layer, page_idx[kind], slot)
+                for i, (_, rows, _) in enumerate(runs):
+                    kv = self._write_rows(
+                        kv, kv_rows(k, rows), kv_rows(v, rows), cache_layer,
+                        page_idx[kind][i], slot[i])
             with scopes.scope("ds.attn"):
                 if diff:
                     q = neox.diff_queries(q)
@@ -1243,7 +1270,7 @@ class InferenceEngine:
                 attn = self._attention(
                     q, kv, cache_layer, tables[kind], lengths,
                     window=self.window if kind == "window" else None,
-                    block_pass=R > 1, sm_scale=scale,
+                    sm_scale=scale,
                     cross=spec.attn == "cross").astype(x.dtype)
                 if diff:
                     attn = neox.diff_combine(cfg, bp["attn"], attn)
@@ -1554,63 +1581,84 @@ class InferenceEngine:
 
         def planned_block_decode(params, stacks, state, lengths, tables,
                                  pools, rng, carried, src):
-            """One PASS of a block-generating model over the block of
-            every row. `state` [batch, 2 block + 1] int32 is a row's block
-            as the host knows it: its tokens, which rows are masked, and
-            whether the pass before committed it; a row that continues
-            from the pass in flight takes the state from that program's
-            output `carried` at row `src` instead, so rows of one batch
-            are at different passes of different blocks and nothing
-            reaches the host between passes. A committed block's
-            successor is all masks. `lengths` = the block's first
-            position + block (0: an inactive row), which the host knows
-            (`scheduler.block_start`).
+            """One PASS of a block-generating model over every row's TWO
+            SLOTS: slot A, the row's block, and slot B, the block behind
+            it. `state` [batch, 4 block + 1] int32 is a row as the host
+            knows it: slot A's tokens and which of its rows are masked
+            (slot B's columns and the last, the duty of the pass before,
+            0); a row that continues from the pass in flight takes the
+            state from that program's output `carried` at row `src`
+            instead, and where that pass committed its slot A the row's
+            block is ITS slot B. So rows of one batch are at different
+            passes of different blocks and nothing reaches the host
+            between passes. `lengths` [batch, 2] = the ends of the two
+            slots, the block's first position + block (0: an inactive row)
+            and + 2 block (or the first again: the request's last block,
+            which has no successor), which the host knows
+            (`scheduler.block_ends`).
 
-            The pass decides its own duty from the state: a block with no
-            mask left is COMMITTED (its rows, written like any pass's,
-            are now the final ones; nothing is unmasked), any other block
-            is denoised: every masked row whose confidence, the softmax
-            probability of its argmax in float32, is over the threshold
-            is unmasked, and never fewer than `block_floor` of the most
-            confident masked rows (ties: the lower position). A denoising
+            The pass decides its own duty from the state. A block with a
+            mask left is DENOISED in slot A: every masked row whose
+            confidence, the softmax probability of its argmax in float32,
+            is over the threshold is unmasked, and never fewer than
+            `block_floor` of the most confident masked rows (ties: the
+            lower position); slot B is then dead (`_plan_token_layers`).
+            A block with no mask left is COMMITTED in slot A (its rows,
+            written like any pass's, are now the final ones) and slot B
+            is the first denoising pass of its successor, all masks, whose
+            rows see the committed block's K and V of the same layer, as a
+            block's rows see their own: rows `p .. p + 2 block - 1` of ONE
+            forward under the block-causal mask. The head and the
+            unmasking run on the denoising slot's rows alone. A denoising
             pass's K/V rows are provisional: only this pass's own rows
             read them, and the block's next pass overwrites them. Returns
-            the state after the pass in `state`'s layout, padded to the
-            widest batch, and the pools."""
+            [slot A's tokens, masked | slot B's | duty: 0 denoised, 1
+            committed alone, 2 committed and opened slot B] in `state`'s
+            layout, padded to the widest batch, and the pools."""
             st = jnp.where((src >= 0)[:, None], carried[jnp.maximum(src, 0)],
                            state)
-            committed = st[:, 2 * B_] > 0
-            tok = jnp.where(committed[:, None], cfg.mask_token_id,
-                            st[:, :B_])
-            masked = (st[:, B_:2 * B_] > 0) | committed[:, None]
-            active = lengths > 0
-            pos = jnp.maximum(lengths - B_, 0)
+            moved = (st[:, 4 * B_] > 0)[:, None]
+            tok = jnp.where(moved, st[:, 2 * B_:3 * B_], st[:, :B_])
+            masked = jnp.where(moved, st[:, 3 * B_:4 * B_],
+                               st[:, B_:2 * B_]) > 0
+            active = lengths[:, 0] > 0
+            pos = jnp.maximum(lengths[:, 0] - B_, 0)
             commit = active & ~jnp.any(masked, axis=1)
-            at = pos[:, None] + jnp.arange(B_, dtype=pos.dtype)
-            x = fam.embed_at(params, tok, at)
+            opens = (commit & (lengths[:, 1] > lengths[:, 0]))[:, None]
+            fresh = jnp.full_like(tok, cfg.mask_token_id)
+            at = pos[:, None] + jnp.arange(2 * B_, dtype=pos.dtype)
+            x = fam.embed_at(params, jnp.concatenate([tok, fresh], 1), at)
             x, pools, _ = self._plan_token_layers(
-                fam, stacks, x, pos, pools, tables, lengths)
+                fam, stacks, x, pos, pools, tables, lengths,
+                active=jnp.repeat(jnp.concatenate(
+                    [active[:, None], opens], 1), B_, axis=1))
+            # the denoising slot: B where the pass opened it, else A (whose
+            # block has no mask left where the pass committed alone)
+            x = jnp.where(opens[..., None], x[:, B_:], x[:, :B_])
+            new, masked_new = jnp.where(opens, fresh, tok), masked | opens
             logits = fam.head(params, fam.final_norm(params, x))
             with scopes.scope("ds.unmask"):
                 top = jnp.max(logits, axis=-1)
                 best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]),
                                      axis=-1)
-                conf = jnp.where(masked, conf, -jnp.inf)
+                conf = jnp.where(masked_new, conf, -jnp.inf)
                 # a row's rank among its block's masked rows by confidence
                 row = jnp.arange(B_)
                 ahead = (conf[:, None, :] > conf[:, :, None]) | (
                     (conf[:, None, :] == conf[:, :, None]) &
                     (row[None, None, :] < row[None, :, None]))
                 rank = jnp.sum(ahead, axis=-1)
-                unmask = masked & active[:, None] & (
+                unmask = masked_new & active[:, None] & (
                     (conf > self.block_threshold) |
                     (rank < self.block_floor))
-                tok = jnp.where(unmask, best, tok)
-                masked = masked & ~unmask
+                new = jnp.where(unmask, best, new)
+                masked_new = masked_new & ~unmask
+                duty = commit[:, None].astype(jnp.int32) + opens
                 out = jnp.concatenate(
-                    [tok, masked.astype(jnp.int32),
-                     commit.astype(jnp.int32)[:, None]], axis=1)
+                    [jnp.where(opens, tok, new), masked_new & ~opens,
+                     jnp.where(opens, new, fresh), masked_new | ~opens,
+                     duty], axis=1).astype(jnp.int32)
             return jnp.pad(out, ((0, width - batch), (0, 0))), pools
 
         fn = jax.jit(planned_block_decode if self.block else planned_decode,
@@ -2563,11 +2611,12 @@ class InferenceEngine:
             self.admission.observe_ttft(ttft_s * 1e3)
         return True
 
-    def _count_moe_rows(self, phase, tokens, program_tokens):
+    def _count_moe_rows(self, phase, tokens, program_tokens=None):
         """What a step's MoE layers route, from shapes the host already
         has: `tokens` real tokens, top_k experts each, in every layer,
         and the rows their buffers hold (padding included) in a program
-        compiled for `program_tokens` token rows."""
+        compiled for `program_tokens` token rows (None: tokens more of a
+        program already counted)."""
         fam = self.family
         if fam.moe_top_k:
             layers = fam.moe_layers
@@ -2576,8 +2625,9 @@ class InferenceEngine:
             self.stats["moe_rows_routed"] += routed
             if not self._counts_held:
                 self.stats["moe_rows_held"] += routed   # every expert here
-            self.stats["moe_buffer_rows"] += \
-                fam.moe_buffer_rows(program_tokens) * layers
+            if program_tokens is not None:
+                self.stats["moe_buffer_rows"] += \
+                    fam.moe_buffer_rows(program_tokens) * layers
 
     def _dispatch_prefill(self, plan):
         """Build and enqueue the plan's prefill (and its draft twin);
@@ -2694,33 +2744,36 @@ class InferenceEngine:
 
     def _dispatch_block_decode(self, plan):
         """`_dispatch_decode` of a block-generating model: one pass over
-        the block of every decoding row. A row whose last pass is unread
-        names its row of the pass in flight and the program takes the
-        block's state from there; any other row brings the state the
+        the two slots of every decoding row. A row whose last pass is
+        unread names its row of the pass in flight and the program takes
+        the block's state from there; any other row brings the state the
         host last read (`Request.block_tokens` / `block_masked`). Where
-        the block lies (`scheduler.block_start`), the page table and the
+        the slots lie (`scheduler.block_ends`), the page table and the
         page growth behind it the host knows without the read-back."""
         B, blk = plan.decode_batch, self.block
         prev, prev_row = self._decode_in_flight()
         with self._phase("build_inputs"):
-            state = np.zeros((B, 2 * blk + 1), np.int32)
+            state = np.zeros((B, 4 * blk + 1), np.int32)
             src = np.full((B,), -1, np.int32)
-            lengths = np.zeros((B,), np.int32)
+            lengths = np.zeros((B, 2), np.int32)
             for i, req in enumerate(plan.decodes):
                 if req.pending:
                     src[i] = prev_row[id(req)]
                 else:
                     state[i, :blk] = req.block_tokens
                     state[i, blk:2 * blk] = req.block_masked
-                lengths[i] = self.scheduler.block_start(req) + blk
-            kv_tokens = int(lengths.sum())
+                lengths[i] = self.scheduler.block_ends(req)
+            # what the kernel reads: up to the later slot's end
+            kv_tokens = int(lengths[:, 1].sum())
             self.stats["decode_steps"] += 1
             self.stats["block_passes"] += len(plan.decodes)
             self.stats["decode_kv_tokens"] += kv_tokens
             self.stats["decode_kv_tokens_block"] += kv_tokens
             self.stats["kv_page_steps_full"] += int(
-                (-(-lengths // self.page_size)).sum())
-            self._count_moe_rows("decode", len(plan.decodes) * blk, B * blk)
+                (-(-lengths[:, 1] // self.page_size)).sum())
+            # slot A's rows; an opened slot B's are counted at the read-back
+            self._count_moe_rows("decode", len(plan.decodes) * blk,
+                                 B * 2 * blk)
             args = [jnp.asarray(state), jnp.asarray(lengths),
                     jax.device_put(self._tables(
                         plan.decodes, B, self.n_pages_max))]
@@ -2728,33 +2781,53 @@ class InferenceEngine:
         return self._launch_decode(plan, f"block x{B}", args, src, prev)
 
     def _complete_blocks(self, rec, nxt, now):
-        """A read-back pass of a block model into its requests: each live
-        row's block after the pass (`scheduler.complete_block`), the
-        tokens it made final counted where they were unmasked and
-        `decode_tokens` where they were delivered."""
+        """A read-back pass of a block model into its requests: what each
+        live row's pass did (its duty, `planned_block_decode`): a commit
+        of the block the host knew, a denoising of that block or of its
+        successor (`scheduler.complete_block`), the tokens it made final
+        counted where they were unmasked and `decode_tokens` where they
+        were delivered."""
         blk = self.block
+        opened = 0
+
+        def note(req, start, committed, tokens_in, masked_in, tokens, masked):
+            # tuples of numbers: a record kept through a whole run is then
+            # nothing the collector has to walk
+            if self.block_trace is not None:
+                self.block_trace.append({
+                    "request": req.request_id, "program": rec.serial,
+                    "start": start, "committed": committed,
+                    "tokens_in": tuple(tokens_in),
+                    "masked_in": tuple(masked_in),
+                    "tokens": tuple(tokens.tolist()),
+                    "masked": tuple(bool(m) for m in masked)})
+
         for i, req, live in rec.rows():
-            tokens, masked = nxt[i, :blk], nxt[i, blk:2 * blk]
-            committed = bool(nxt[i, 2 * blk])
+            duty = int(nxt[i, 4 * blk])
+            committed = duty > 0
+            at = 2 * blk if committed else 0      # the denoised slot
+            tokens, masked = nxt[i, at:at + blk], nxt[i, at + blk:at + 2 * blk]
             self.stats["block_commit_passes"] += committed
+            self.stats["block_fused_commits"] += duty == 2
+            self.stats["block_commit_only_passes"] += duty == 1
+            opened += duty == 2
             if not live:
                 self.stats["lookahead_discarded"] += 1
                 continue
             req.owed.remove(rec.serial)
-            if self.block_trace is not None:
-                # tuples of numbers: a record kept through a whole run
-                # is then nothing the collector has to walk
-                self.block_trace.append({
-                    "request": req.request_id, "program": rec.serial,
-                    "start": req.cached, "committed": committed,
-                    "tokens_in": tuple(req.block_tokens),
-                    "masked_in": tuple(req.block_masked),
-                    "tokens": tuple(tokens.tolist()),
-                    "masked": tuple(bool(m) for m in masked)})
+            start = req.cached
+            tokens_in, masked_in = req.block_tokens, req.block_masked
             if committed:
                 self.stats["blocks_committed"] += 1
-            else:
-                final = sum(req.block_masked) - int(masked.sum())
+                note(req, start, True, tokens_in, masked_in, nxt[i, :blk],
+                     nxt[i, blk:2 * blk])
+                # its successor came into the pass all masks
+                start += blk
+                tokens_in = [self.model.config.mask_token_id] * blk
+                masked_in = [True] * blk
+            if duty != 1:
+                note(req, start, False, tokens_in, masked_in, tokens, masked)
+                final = sum(masked_in) - int(masked.sum())
                 self.stats["block_tokens_final"] += final
                 if final and req.first_unmask_at is None and \
                         req.submitted_at is not None:
@@ -2772,6 +2845,7 @@ class InferenceEngine:
                 self.request_metrics.observe_inter_token(
                     (now - req.last_token_at) / delivered)
             req.last_token_at = now
+        self._count_moe_rows("decode", opened * blk)
 
     def _zero_carry(self):
         # a decode program's output (`_with_held`): the tokens, a looped
@@ -2779,7 +2853,7 @@ class InferenceEngine:
         # is its rows' block state (`planned_block_decode`)
         if self.block:
             return jnp.asarray(np.zeros(
-                (self._carry_width, 2 * self.block + 1), np.int32))
+                (self._carry_width, 4 * self.block + 1), np.int32))
         return jnp.asarray(np.zeros(
             (self._carry_width * (2 if self.loop_steps > 1 else 1) +
              int(self._counts_held),), np.int32))

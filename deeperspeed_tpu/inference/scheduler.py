@@ -38,12 +38,14 @@ growth counts `cached + pending`, and a request whose last token by
 A model that generates a BLOCK of tokens at a time (`block` > 0;
 docs/inference.md "Block generation") is counted in blocks: a prefill
 caches the context's whole blocks and yields no token (a context under
-one block takes none), `cached` advances by a block at each commit pass,
-a decode row costs a block of the budget, pages grow to the block the
-next pass writes (which may lie one past the block the host last read),
-and a pass's result lands through `complete_block`: the newly final
-tokens are the contiguous unmasked prefix of the block, so `generated`
-grows left to right whatever order the rows were unmasked in.
+one block takes none), `cached` advances by a block at each commit, a
+decode row costs a block of the budget, pages grow to the TWO blocks the
+next pass may write (`block_ends`: the pass that commits a block opens
+its successor, and the threshold can complete a block the host has not
+read yet), never past the request's natural end, and a pass's result
+lands through `complete_block`: the newly final tokens are the contiguous
+unmasked prefix of the block, so `generated` grows left to right whatever
+order the rows were unmasked in.
 
 Token accounting uses PADDED bucket sizes, not raw prompt lengths: the
 budget is a compute bound, and compute is spent at compiled shapes.
@@ -454,12 +456,23 @@ class ContinuousBatchingScheduler:
 
     def block_start(self, request):
         """The first position of the block the NEXT pass of `request`
-        works on. The pass in flight (if any) is a commit exactly when
-        the block the host last read has no mask left, and then the next
-        pass opens the block behind it."""
+        works on. The pass in flight (if any) commits exactly when the
+        block the host last read has no mask left, and then it has opened
+        the block behind it, which the next pass goes on with."""
         if request.pending and not any(request.block_masked):
             return request.cached + self.block
         return request.cached
+
+    def block_ends(self, request):
+        """The ends of the two slots of `request`'s next pass: that of
+        its block (`block_start` + block), and that of the block behind
+        it, which the pass opens where it commits the first; the first
+        again where the request ends inside its block (no successor: its
+        last block is never committed)."""
+        end = self.block_start(request) + self.block
+        last = min(len(request.prompt) + request.max_new_tokens,
+                   self.max_seq_len)
+        return end, end + self.block if end < last else end
 
     # -- graceful drain ----------------------------------------------------
 
@@ -730,8 +743,8 @@ class ContinuousBatchingScheduler:
             # in flight has its slot already
             pos = req.cached + req.pending + self._spec_window(req)
             if self.block:
-                # the last row the next pass writes
-                pos = self.block_start(req) + self.block - 1
+                # the last row the next pass may write
+                pos = self.block_ends(req)[1] - 1
             if not self._grow_pages(req, self.cache, req.pages, pos,
                                     evicted, now):
                 continue
@@ -942,31 +955,29 @@ class ContinuousBatchingScheduler:
         return appended
 
     def complete_block(self, request, tokens, masked, committed):
-        """Record one pass of a block model over the request's block:
-        `tokens` and `masked` are the block's rows after the pass,
-        `committed` whether it was the commit pass (the block had no mask
-        left going in, and its K/V now stays in the cache). A commit
-        advances `cached` by the block and opens the next, all masks. A
-        denoising pass appends the newly final tokens: the block's
-        contiguous unmasked prefix past what `generated` already holds
-        (a row unmasked behind a masked one waits for it), up to the
-        request's natural end, as `complete_speculative` appends an
-        accepted window (no page is rolled back: a block's rows lie in
-        pages grown for it). Returns the number of tokens appended."""
+        """Record one pass of a block model over the request's block.
+        `committed`: the block had no mask left going in, the pass wrote
+        its final K/V and `cached` advances by the block; `tokens` and
+        `masked` are then the rows of its SUCCESSOR after the same pass's
+        first denoising of it (all masks where the pass did nothing but
+        commit), else the block's own rows after the pass. The newly
+        final tokens are appended: the block's contiguous unmasked prefix
+        past what `generated` already holds (a row unmasked behind a
+        masked one waits for it), up to the request's natural end, as
+        `complete_speculative` appends an accepted window (no page is
+        rolled back: a block's rows lie in pages grown for it). Returns
+        the number of tokens appended."""
         appended = 0
         if committed:
             request.cached += self.block
-            self._open_block(request)
-        else:
-            request.block_tokens = [int(t) for t in tokens]
-            request.block_masked = [bool(m) for m in masked]
-            at = len(request.prompt) + len(request.generated) - \
-                request.cached
-            while at < self.block and not request.block_masked[at] and \
-                    not request.done:
-                request.generated.append(request.block_tokens[at])
-                at += 1
-                appended += 1
+        request.block_tokens = [int(t) for t in tokens]
+        request.block_masked = [bool(m) for m in masked]
+        at = len(request.prompt) + len(request.generated) - request.cached
+        while at < self.block and not request.block_masked[at] and \
+                not request.done:
+            request.generated.append(request.block_tokens[at])
+            at += 1
+            appended += 1
         request.failures = 0
         self._maybe_finish(request)
         return appended

@@ -56,7 +56,10 @@ along their slots and the scores take one of two forms, by the query rows
 a KV head: one row a head (and int8 pages) meets ALL the step's slots as
 one [Hb * S, D] operand (`_page_update`); a GROUP of rows a head (grouped
 KV heads, a block pass) meets its own head's slots in a head-batched
-matmul (`_group_update`). No backward exists: decode is inference.
+matmul (`_group_update`). A block pass of two slots (`first_lengths`)
+prefetches a second length a row: the first half of a group's rows end
+there, the second half at the row's own, K and V read once for both. No
+backward exists: decode is inference.
 
 The decoded token's own K/V row gets into its page through
 `paged_kv_write`: on a TPU a second small kernel that ALIASES the stacked
@@ -291,17 +294,23 @@ def _page_update(q, k, v, k_scale, v_scale, m, l, acc, first_pos, length,
 
 
 def _group_update(q, k, v, m, l, acc, first_pos, length, sm_scale,
-                  window=None):
+                  window=None, first_length=None):
     """`_page_update` for a GROUP of query rows a KV head, EACH HEAD'S
     ROWS AGAINST ITS OWN SLOTS: ``q`` [Hb, r, D] meets ``k``/``v``
     [Hb, S, D] in a head-batched matmul, so the float32 score tile is
     [Hb, r, S] with every column live but the tail past `length` (and
     what lies before a `window`), and no head mask exists. ``m``/``l``
-    [Hb, r, 1], ``acc`` [Hb, r, D]."""
+    [Hb, r, 1], ``acc`` [Hb, r, D]. With ``first_length`` the group is a
+    block pass's TWO SLOTS: the first half of its rows (the earlier
+    block's) end at `first_length`, the second half at `length`."""
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)                 # [Hb, r, S]
     pos = first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    if first_length is not None:
+        length = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) <
+            s.shape[1] // 2, first_length, length)
     live = pos < length
     if window is not None:
         live = live & (pos >= length - window)
@@ -322,8 +331,8 @@ def _first_page(length, page_size, window):
     return jnp.maximum(length - window, 0) // page_size
 
 
-def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
-                   *refs, sm_scale, page_size, pages, window, per_head):
+def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, *refs,
+                   sm_scale, page_size, pages, window, per_head, two_slots):
     """Grid step `t` of a head group: `pages` consecutive live pages of
     one batch row — each page's K and V tiles of `Hb` heads ([Hb, ps, D],
     an operand a page) joined into the step's [Hb, pages * ps, D] — meet
@@ -332,8 +341,13 @@ def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
     and the layer of the stacked pools) are read by the index maps alone.
     Int8 pools bring their pages' [Hb, ps] scale tiles behind the data's,
     resolved through the same maps. A slot past the row's length holds a
-    page the maps chose for costing nothing, and is masked."""
-    *tile_refs, o_ref, m_scr, l_scr, acc_scr = refs
+    page the maps chose for costing nothing, and is masked. `two_slots`:
+    one more prefetched operand leads `refs`, each row's `first_length`
+    (`_group_update`)."""
+    first_ref = None
+    if two_slots:
+        first_ref, *refs = refs
+    q_ref, *tile_refs, o_ref, m_scr, l_scr, acc_scr = refs
     t = pl.program_id(1)
     b = row_ref[t]
     length = len_ref[b]
@@ -357,8 +371,9 @@ def _decode_kernel(row_ref, start_ref, pt_ref, len_ref, lyr_ref, q_ref,
                          for i in range(0, len(tile_refs), pages)]
         carry = m_scr[..., :1], l_scr[..., :1], acc_scr[:]
         if per_head:
-            m, l, acc = _group_update(q_ref[...], k, v, *carry, first_pos,
-                                      length, sm_scale, window)
+            m, l, acc = _group_update(
+                q_ref[...], k, v, *carry, first_pos, length, sm_scale,
+                window, first_ref[b] if two_slots else None)
         else:
             m, l, acc = _page_update(q_ref[...], k, v,
                                      *scales or (None, None), *carry,
@@ -424,7 +439,8 @@ def _layer_operand(layer):
 def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
                                   sm_scale, k_scales=None, v_scales=None,
                                   layer=None, window=None,
-                                  name="ds.paged_decode"):
+                                  name="ds.paged_decode",
+                                  first_lengths=None):
     B, H, D = q.shape
     quant = k_scales is not None
     if layer is None:
@@ -447,11 +463,12 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
     # dims are squeezed out of the kernel's refs
     rows = (hb, r, D) if per_head else (hb * r, D)
 
-    def row_block(g, t, row, start, pt, ln, lyr):
+    # (a block pass of two slots prefetches `first_lengths` behind these)
+    def row_block(g, t, row, start, pt, ln, lyr, *_):
         return (row[t], g) + (0,) * len(rows)
 
     def page_block(j):
-        def block(g, t, row, start, pt, ln, lyr):
+        def block(g, t, row, start, pt, ln, lyr, *_):
             b = row[t]
             page = (t - start[b]) * pages + j + \
                 _first_page(ln[b], page_size, window)
@@ -475,6 +492,8 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         # the kernel widens each tile in VMEM — a whole-pool fp32
         # cast here would materialize a pool-sized copy every step
         args += [k_scales] * pages + [v_scales] * pages
+    slots = () if first_lengths is None else (
+        first_lengths.astype(jnp.int32),)
     with scopes.scope(name):
         lengths = lengths.astype(jnp.int32)
         n_steps, row, start = decode_steps(lengths, page_size, table_width,
@@ -482,10 +501,11 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         out = pl.pallas_call(
             functools.partial(_decode_kernel, sm_scale=sm_scale,
                               page_size=page_size, pages=pages,
-                              window=window, per_head=per_head),
+                              window=window, per_head=per_head,
+                              two_slots=bool(slots)),
             out_shape=jax.ShapeDtypeStruct((B, G // hb, *rows), q.dtype),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5,
+                num_scalar_prefetch=5 + len(slots),
                 # the step dimension carries the online softmax; its
                 # extent is this call's own count of live spans
                 grid=(G // hb, n_steps),
@@ -501,20 +521,20 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
             interpret=_interpret(), name=name,
         )(row, start,
           span_table(page_table, lengths, page_size, pages, window),
-          lengths, _layer_operand(layer), *args)
+          lengths, _layer_operand(layer), *slots, *args)
     return out.reshape(B, H, D)
 
 
 @scopes.scoped("ds.paged_decode_xla")
 def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
                                sm_scale, k_scales=None, v_scales=None,
-                               layer=None, window=None):
+                               layer=None, window=None, first_lengths=None):
     """Pure-XLA reference/fallback: gather the sequence's pages back
     into a contiguous [B, G, S_max, D] view and run a masked softmax.
     Identical semantics to the kernel, including exact-zero outputs for
     inactive (length 0) rows, the int8 dequant at the gather, grouped KV
-    heads and the `window`. With ``layer`` the gather indexes that layer
-    of the stacked pools."""
+    heads, the `window` and a block pass's `first_lengths`. With
+    ``layer`` the gather indexes that layer of the stacked pools."""
     B, H, D = q.shape
     out_dtype = q.dtype
     G, page_size = k_pages.shape[-3], k_pages.shape[-2]
@@ -541,7 +561,13 @@ def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
     live = pos < lengths[:, None]
     if window is not None:
         live = live & (pos >= lengths[:, None] - window)
-    s = jnp.where(live[:, None, None, :], s, NEG_INF)
+    live = live[:, None, None, :]
+    if first_lengths is not None:
+        # the first half of a KV head's rows: the earlier slot's
+        first = jnp.arange(H // G)[:, None] < H // G // 2
+        live = jnp.where(first, pos < first_lengths[:, None, None, None],
+                         live)
+    s = jnp.where(live, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     prob = jnp.exp(s - m)
     prob = jnp.where(s <= NEG_INF * 0.5, 0.0, prob)
@@ -555,7 +581,8 @@ def paged_decode_attention_xla(q, k_pages, v_pages, page_table, lengths,
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            sm_scale=None, backend=None, k_scales=None,
                            v_scales=None, layer=None, window=None,
-                           block_pass=False, cross=False):
+                           block_pass=False, cross=False,
+                           first_lengths=None):
     """One decode step of paged attention: ``out[b, h] = softmax(q[b, h]
     · K[b]) · V[b]`` with K/V read through ``page_table[b]`` and masked
     at ``lengths[b]``.
@@ -581,9 +608,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     ``block_pass``: the call is a block pass's
     (`InferenceEngine._plan_token_layers`), which brings a block's rows x
     the query heads of a KV head as that KV head's group of "query heads":
-    the same kernel under the scope `ds.paged_decode_block`. ``cross``:
-    the call is a cross layer's, over pages another layer wrote: the same
-    kernel under the scope `ds.paged_decode_cross`.
+    the same kernel under the scope `ds.paged_decode_block`. With
+    ``first_lengths`` [B] int32 the block pass carries TWO SLOTS, two
+    consecutive blocks of a sequence: the first half of each KV head's
+    rows (the earlier block's) attends the positions below
+    ``first_lengths[b]`` and the second half those below ``lengths[b]``,
+    K and V read once for both; a call without it traces what it always
+    traced. ``cross``: the call is a cross layer's, over pages another
+    layer wrote: the same kernel under the scope `ds.paged_decode_cross`.
 
     backend: None = auto (Pallas kernel on TPU when
     `paged_decode_supported`, XLA fallback otherwise — CPU test runs
@@ -614,6 +646,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     if lengths.shape != (B,):
         raise ValueError(f"lengths shape {lengths.shape} != ({B},)")
     quant = k_scales is not None
+    if first_lengths is not None and (
+            not block_pass or quant or window is not None or
+            H // Hk % 2 or first_lengths.shape != (B,)):
+        raise ValueError(
+            f"first_lengths {getattr(first_lengths, 'shape', None)}: a "
+            f"block pass's [{B}] over plain pools and no window, its two "
+            f"slots the halves of an even group of rows a KV head "
+            f"(got {H // Hk})")
     scale_shape = k_pages.shape[:-1]
     if quant and (k_scales.shape != scale_shape or v_scales is None or
                   v_scales.shape != scale_shape):
@@ -637,14 +677,16 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                                           lengths, sm_scale,
                                           k_scales=k_scales,
                                           v_scales=v_scales, layer=layer,
-                                          window=window)
+                                          window=window,
+                                          first_lengths=first_lengths)
     if backend != "pallas":
         raise ValueError(f"unknown paged decode backend {backend!r}")
     if block_pass:
         return paged_decode_attention_pallas(
             q, k_pages, v_pages, page_table, lengths, sm_scale,
             k_scales=k_scales, v_scales=v_scales, layer=layer,
-            window=window, name="ds.paged_decode_block")
+            window=window, name="ds.paged_decode_block",
+            first_lengths=first_lengths)
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, page_table, lengths, sm_scale,
         k_scales=k_scales, v_scales=v_scales, layer=layer, window=window,

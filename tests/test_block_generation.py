@@ -1,6 +1,7 @@
 """Generation by diffusion over blocks (SDAR-MoE: a block of 4 positions
-a step under the block-causal mask, unmasking by confidence, a commit pass
-a block) on the normal path against the plain reference
+a step under the block-causal mask, unmasking by confidence, a block's
+commit riding the first pass of its successor) on the normal path against
+the plain reference
 (`benchmarks/reference/sdar_moe.py`), at a small size on the CPU: hidden
 64, 4 query heads over 2 KV heads of 16, 8 experts of width 32 (2 a
 token), 2 layers, block 4, page 8.
@@ -216,8 +217,11 @@ def test_unmasking_order_and_delivered_prefix_equal_generate(
     `generate` (no cache, the whole prefix a pass): the same rows unmasked
     in the same passes with the same tokens, and the same delivered
     tokens. At 1.0 no confidence fires and every block takes the floor,
-    one row a pass, 4 denoising passes and a commit; at 0.5 passes unmask
-    several rows and blocks take fewer than 5 passes."""
+    one row a row-pass: a block of 4 masks takes 4 row-passes, and its
+    commit rides the first of its successor's (no row-pass does nothing
+    but commit). At 0.5 passes unmask several rows, the threshold
+    completes blocks the host had not foreseen, and the served tokens and
+    the delivered prefixes are still `generate`'s."""
     c, model, params = setup
     rng = np.random.default_rng(1)
     prompts = [rng.integers(1, MASK, size=n).tolist()
@@ -252,35 +256,76 @@ def test_unmasking_order_and_delivered_prefix_equal_generate(
                for r in done)
     assert stats["block_first_unmask_s"] == pytest.approx(
         sum(r.first_unmask_at - r.submitted_at for r in done))
+    commits = [p for p in engine.block_trace if p["committed"]]
+    assert stats["blocks_committed"] == len(commits) > len(done)
+    assert stats["block_commit_passes"] == \
+        stats["block_fused_commits"] + stats["block_commit_only_passes"]
     if threshold == 1.0:
         assert several == 0
+        # every row-pass read unmasked one row: 4 row-passes a block of 4
+        # masks (unread: dispatched before the read-back that ended a
+        # request inside its last block)
         assert stats["block_tokens_final"] == \
-            stats["block_passes"] - stats["block_commit_passes"] - \
-            stats["lookahead_discarded"]
+            stats["block_passes"] - stats["lookahead_discarded"]
+        assert stats["block_commit_only_passes"] == 0
+        assert stats["block_fused_commits"] == stats["blocks_committed"]
+        for r in done:
+            mine = [p for p in engine.block_trace
+                    if p["request"] == r.request_id]
+            for i, p in enumerate(mine):
+                if p["committed"]:
+                    # the commit and its successor's first pass: one program
+                    nxt = mine[i + 1]
+                    assert nxt["program"] == p["program"]
+                    assert nxt["start"] == p["start"] + BLOCK
+                    assert all(nxt["masked_in"]) and not nxt["committed"]
+                    assert [q["program"] for q in mine].count(
+                        p["program"]) == 2
     else:
         assert several > 0
         assert stats["block_tokens_final"] > 1.3 * (
-            stats["block_passes"] - stats["block_commit_passes"])
+            stats["block_passes"] - stats["lookahead_discarded"])
 
 
-def test_rows_of_one_batch_are_at_different_passes(setup):
-    """One program serves rows at different passes of different blocks:
-    some commit while others denoise, and a row's block may lie one past
-    the block the host last read."""
+def test_rows_of_one_batch_at_different_duties_equal_each_served_alone(setup):
+    """One program serves rows at different duties: in a batch of 4 some
+    row-passes denoise their block, some commit it and open its successor,
+    and the fourth row is inactive (3 requests), a row's block lying one
+    past the block the host last read. Every request's record of passes
+    is the one it leaves when served alone."""
     c, model, params = setup
     rng = np.random.default_rng(2)
-    prompts = [rng.integers(1, MASK, size=n).tolist() for n in (8, 9, 11, 3)]
+    prompts = [rng.integers(1, MASK, size=n).tolist() for n in (8, 9, 3)]
     engine = engine_for(model, params)
-    serve(engine, prompts, [12] * 4)
+    together, _ = serve(engine, prompts, [12] * 3)
     by_program = {}
     for p in engine.block_trace:
         by_program.setdefault(p["program"], []).append(p)
-    mixed = [ps for ps in by_program.values()
-             if len({p["committed"] for p in ps}) == 2]
-    assert mixed
+    # a program in which one request committed (two records) and another
+    # went on denoising (one)
+    assert any(
+        {sum(1 for p in ps if p["request"] == r) for r in
+         {p["request"] for p in ps}} == {1, 2} for ps in by_program.values())
     assert any(len({sum(p["masked_in"]) for p in ps
                     if not p["committed"]}) > 1
                for ps in by_program.values())
+    # a pass that did nothing but commit: a request's last block, which
+    # the threshold completed before the read-back that ended the request
+    stats = engine.serve_stats()
+    assert stats["block_commit_only_passes"] <= stats["lookahead_discarded"]
+    assert stats["block_fused_commits"] >= stats["blocks_committed"] > 3
+
+    def record(engine, request):
+        return [{k: v for k, v in p.items() if k not in ("request",
+                                                         "program")}
+                for p in engine.block_trace
+                if p["request"] == request.request_id]
+
+    for prompt, request in zip(prompts, together):
+        other = engine_for(model, params)
+        (alone,), _ = serve(other, [prompt], [12])
+        assert alone.generated == request.generated
+        assert record(other, alone) == record(engine, request)
 
 
 def held_rows(engine, pages, n):
@@ -295,27 +340,38 @@ def held_rows(engine, pages, n):
     return jnp.concatenate([held(engine.cache.k), held(engine.cache.v)], -1)
 
 
-def test_the_pool_after_a_commit_is_a_teacher_forced_prefills(setup):
+@pytest.mark.parametrize("kernel,page", [("xla", 8), ("pallas", 8),
+                                         ("pallas", 16)])
+def test_the_pool_after_a_commit_is_a_teacher_forced_prefills(setup, kernel,
+                                                              page):
     """After a request's commits its pool rows are the finished
     sequence's K (after the rotary) and V of every layer: the reference's
     `cache_rows`, and what a block-causal prefill of the same tokens
-    writes. A provisional row left in the pool (a denoising pass's, made
-    from a block still holding mask tokens) differs by far more than the
-    tolerance (asserted)."""
+    writes. The two slots of a pass are two runs of rows: from the block
+    at 12 they straddle a page (12..15 | 16..19, at both page sizes) and
+    a packed group (a float32 page of 16 has two of 8: 20..23 | 24..27
+    lie in one page and two groups). A provisional row left in the pool
+    (a denoising pass's, made from a block still holding mask tokens)
+    differs by far more than the tolerance (asserted)."""
     c, model, params = setup
     prompt = np.random.default_rng(3).integers(1, MASK, size=11).tolist()
-    engine = engine_for(model, params)
+    over = {"kernel": kernel, "page_size": page, "prefill_lengths": [16, 32]}
+    engine = engine_for(model, params, **over)
     (request,), pages = serve(engine, [prompt], [22])
     n = request.cached
-    # every block but the last was committed
+    # every block but the last was committed, each in the pass that opened
+    # the next
     assert n == (len(prompt) + 22 - 1) // BLOCK * BLOCK and n > 3 * PAGE
+    stats = engine.serve_stats()
+    assert stats["block_fused_commits"] == stats["blocks_committed"] == \
+        (n - 8) // BLOCK
     tokens = (list(prompt) + list(request.generated))[:n]
     got = held_rows(engine, pages[request.request_id], n)
     with jax.default_matmul_precision("highest"):
         want = reference.cache_rows(c, params, jnp.asarray(tokens), BLOCK)
     np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
 
-    other = engine_for(model, params)
+    other = engine_for(model, params, **over)
     with jax.default_matmul_precision("highest"):
         rid = other.submit(tokens, max_new_tokens=8)
         other.step()
@@ -356,6 +412,71 @@ def test_a_causal_prefill_mask_fails_the_cache_rows(setup, monkeypatch):
         want = reference.cache_rows(c, params, jnp.asarray(prompt), BLOCK)
     assert float(jnp.abs(got[0] - want[0]).max()) <= ATOL     # layer 1 sees no mask
     assert float(jnp.abs(got[1] - want[1]).max()) > 100 * ATOL
+
+
+def test_slot_a_seeing_slot_b_fails_the_cache_rows(setup, monkeypatch):
+    """The deliberate fault: the committed block's rows (slot A) attend
+    the positions of the block the same pass opens (slot B's mask tokens).
+    From the second layer on the committed rows then miss the reference's
+    by far more than the tolerance."""
+    from deeperspeed_tpu.inference import engine as engine_module
+    c, model, params = setup
+    real = engine_module.paged_decode_attention
+
+    def one_limit(q, k, v, table, lengths, first_lengths=None, **kw):
+        return real(q, k, v, table, lengths, first_lengths=lengths, **kw)
+
+    monkeypatch.setattr(engine_module, "paged_decode_attention", one_limit)
+    prompt = np.random.default_rng(3).integers(1, MASK, size=11).tolist()
+    engine = engine_for(model, params)
+    (request,), pages = serve(engine, [prompt], [14])
+    n = request.cached
+    assert n == 24
+    tokens = (list(prompt) + list(request.generated))[:n]
+    got = held_rows(engine, pages[request.request_id], n)
+    with jax.default_matmul_precision("highest"):
+        want = reference.cache_rows(c, params, jnp.asarray(tokens), BLOCK)
+    assert float(jnp.abs(got[0] - want[0]).max()) <= ATOL     # sees no mask
+    assert float(jnp.abs(got[1, 8:] - want[1, 8:]).max()) > 100 * ATOL
+
+
+def test_a_requests_first_and_last_block_are_slot_a_alone(setup):
+    """The first generated block is the context's tail and masks, denoised
+    where it lies; the last block is never committed and has no successor:
+    no page is grown for one (the natural end 16 is a page's edge), a
+    dead slot B writes to the trash page even where the request holds
+    the page its rows would lie in, and no other page is touched."""
+    c, model, params = setup
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, MASK, size=n).tolist() for n in (10, 8)]
+    engine = engine_for(model, params)
+    (short, edge), pages = serve(engine, prompts, [2, 8])
+    first = [next(p for p in engine.block_trace if p["request"] == r.request_id)
+             for r in (short, edge)]
+    assert first[0]["start"] == 8 and not first[0]["committed"]
+    assert first[0]["tokens_in"] == (*prompts[0][8:], MASK, MASK)
+    assert first[0]["masked_in"] == (False, False, True, True)
+    assert first[1]["start"] == 8 and all(first[1]["masked_in"])
+    # 8 + 8 tokens: the block at 8 was committed in the pass that opened
+    # the block at 12, which ends the request where the second page does
+    assert edge.cached == 12 and len(pages[edge.request_id]) == 2
+    assert [p["start"] for p in engine.block_trace
+            if p["request"] == edge.request_id and p["committed"]] == [8]
+    # 10 + 2 tokens end inside the block at 8: never committed, and the
+    # rows 12..15 of its page, where a slot B would lie, were never written
+    assert short.cached == 8 and len(pages[short.request_id]) == 2
+    assert not any(p["committed"] for p in engine.block_trace
+                   if p["request"] == short.request_id)
+    held = held_rows(engine, pages[short.request_id], 16)
+    assert float(jnp.abs(held[:, 8:12]).min()) > 0
+    assert float(jnp.abs(held[:, 12:]).max()) == 0
+    used = set(pages[short.request_id]) | set(pages[edge.request_id]) | {0}
+    free = [p for p in range(engine.cache.num_pages) if p not in used]
+    assert float(jnp.abs(engine.cache.k[:, free]).max()) == 0
+    assert float(jnp.abs(engine.cache.k[:, 0]).max()) > 0     # the trash
+    stats = engine.serve_stats()
+    assert stats["block_commit_only_passes"] <= stats["lookahead_discarded"]
+    assert stats["block_fused_commits"] == stats["blocks_committed"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -447,44 +568,90 @@ def test_a_run_of_rows_is_written_in_place_as_one_group(dtype, run):
             2, page_idx, slot, backend="xla")
 
 
-@pytest.mark.parametrize("G,rep,ps,lengths", [
-    (2, 2, 8, [20, 12, 4]),
-    # SDAR's own group: 4 KV heads, 4 rows x 8 query heads a KV head, pages
-    # of 64, a grid step of several pages: rows that end mid-span
-    (4, 8, 64, [170, 70, 4]),
-], ids=["2x8_page8", "sdar_4x32_page64"])
-def test_a_blocks_rows_ride_as_one_group_of_the_grouped_kernel(G, rep, ps,
-                                                               lengths):
-    """The paged kernel (interpret mode) at a group of block x (query
-    heads a KV head) rows a KV head, no mask inside the block, against a
-    dense softmax over each sequence's first `length` positions."""
-    P, D, B = 9, 32, 3
+@pytest.mark.parametrize("slots", [1, 2])
+@pytest.mark.parametrize("G,rep,ps,width,ends", [
+    # a span of 16 pages: a first slot that ends where a grid step does
+    # (128), rows without a successor (12, 12) and inactive (0, 0)
+    (2, 2, 8, 17, [(20, 24), (128, 132), (4, 8), (12, 12), (0, 0)]),
+    # SDAR's own group: 4 KV heads, 2 x 4 rows x 8 query heads a KV head,
+    # pages of 64, a grid step of several pages: rows that end mid-span,
+    # and a second slot that opens a page (64 | 68)
+    (4, 8, 64, 3, [(170, 174), (64, 68), (4, 8)]),
+], ids=["2x2_page8", "sdar_4x8_page64"])
+def test_a_blocks_rows_ride_as_one_group_of_the_grouped_kernel(
+        G, rep, ps, width, ends, slots):
+    """The paged kernel (interpret mode) and its XLA twin at a group of
+    block x (query heads a KV head) rows a KV head, no mask inside the
+    block, against a dense softmax over each sequence's first `length`
+    positions; and at TWO SLOTS a group (`first_lengths`), the first
+    half of the rows under their own, earlier end."""
+    D, B, R = 32, len(ends), slots * BLOCK
+    P = B * width + 1
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     k_pool = jax.random.normal(ks[0], (1, P, G, ps, D), jnp.float32)
     v_pool = jax.random.normal(ks[1], (1, P, G, ps, D), jnp.float32)
-    q = jax.random.normal(ks[2], (B, BLOCK, G * rep, D), jnp.float32)
-    table = jnp.asarray([[1, 2, 3], [4, 5, 0], [6, 0, 0]], jnp.int32)
-    lengths = jnp.asarray(lengths, jnp.int32)
-    rows = jnp.swapaxes(q.reshape(B, BLOCK, G, rep, D), 1, 2).reshape(
-        B, -1, D)
-    got = da.paged_decode_attention(
-        rows, k_pool, v_pool, table, lengths, backend="pallas",
-        layer=jnp.asarray(0, jnp.int32), block_pass=True)
-    # a KV head's group met its own slots, a span of the table's 3 pages
-    assert da._LAST_BACKEND["decode_scores"] == "per_head"
-    assert da._LAST_BACKEND["decode_pages_per_step"] == 3
-    got = jnp.swapaxes(got.reshape(B, G, BLOCK, rep, D), 1, 2).reshape(
-        B, BLOCK, G * rep, D)
-    for b in range(B):
-        n = int(lengths[b])
-        pages = table[b, :-(-n // ps)]
-        k = jnp.moveaxis(k_pool[0, pages], 1, 0).reshape(G, -1, D)[:, :n]
-        v = jnp.moveaxis(v_pool[0, pages], 1, 0).reshape(G, -1, D)[:, :n]
-        for h in range(G * rep):
-            s = q[b, :, h] @ k[h // rep].T / np.sqrt(D)
-            want = jax.nn.softmax(s, axis=-1) @ v[h // rep]
-            np.testing.assert_allclose(got[b, :, h], want, atol=2e-5,
-                                       rtol=0)
+    q = jax.random.normal(ks[2], (B, R, G * rep, D), jnp.float32)
+    table = 1 + jnp.arange(B * width, dtype=jnp.int32).reshape(B, width)
+    ends = np.asarray(ends, np.int32)
+    if slots == 1:
+        ends = ends[:, 1:]
+    table = jnp.where(jnp.arange(width) * ps < ends[:, -1:], table, 0)
+    rows = jnp.swapaxes(q.reshape(B, R, G, rep, D), 1, 2).reshape(B, -1, D)
+    kw = {"first_lengths": jnp.asarray(ends[:, 0])} if slots == 2 else {}
+    for backend in ("pallas", "xla"):
+        got = da.paged_decode_attention(
+            rows, k_pool, v_pool, table, jnp.asarray(ends[:, -1]),
+            backend=backend, layer=jnp.asarray(0, jnp.int32),
+            block_pass=True, **kw)
+        if backend == "pallas":
+            # a KV head's group met its own slots, a span of pages
+            assert da._LAST_BACKEND["decode_scores"] == "per_head"
+            assert da._LAST_BACKEND["decode_pages_per_step"] == \
+                min(width, 16)
+        got = jnp.swapaxes(got.reshape(B, G, R, rep, D), 1, 2).reshape(
+            B, R, G * rep, D)
+        for b in range(B):
+            for slot, n in enumerate(ends[b]):
+                mine = got[b, slot * BLOCK:(slot + 1) * BLOCK]
+                if n == 0:
+                    assert float(jnp.abs(mine).max()) == 0
+                    continue
+                pages = table[b, :-(-n // ps)]
+                k = jnp.moveaxis(k_pool[0, pages], 1, 0).reshape(
+                    G, -1, D)[:, :n]
+                v = jnp.moveaxis(v_pool[0, pages], 1, 0).reshape(
+                    G, -1, D)[:, :n]
+                for h in range(G * rep):
+                    s = q[b, slot * BLOCK:(slot + 1) * BLOCK, h] @ \
+                        k[h // rep].T / np.sqrt(D)
+                    want = jax.nn.softmax(s, axis=-1) @ v[h // rep]
+                    np.testing.assert_allclose(mine[:, h], want, atol=2e-5,
+                                               rtol=0)
+
+
+def test_the_grouped_kernels_traced_program_without_slots_is_unchanged():
+    """`first_lengths` left out adds nothing to the paged kernel's call:
+    the same jaxpr as with it None, and the scope's name apart the jaxpr
+    of a token step's call (every other model's program); with it the
+    body selects a row's end once more."""
+    q = jnp.zeros((2, 16, 32), jnp.float32)
+    pool = jnp.zeros((1, 5, 2, 8, 32), jnp.float32)
+    table = jnp.zeros((2, 3), jnp.int32)
+    lengths = jnp.full((2,), 12, jnp.int32)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda q: da.paged_decode_attention(
+            q, pool, pool, table, lengths, backend="pallas",
+            layer=jnp.asarray(0, jnp.int32), **kw))(q))
+
+    plain = text(block_pass=True)
+    assert plain == text(block_pass=True, first_lengths=None)
+    slots = text(block_pass=True, first_lengths=lengths - BLOCK)
+    assert slots.count("select_n") == plain.count("select_n") + 1
+    assert text().replace("ds.paged_decode", "") == \
+        plain.replace("ds.paged_decode_block", "")
+    with pytest.raises(ValueError, match="first_lengths"):
+        text(first_lengths=lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -531,18 +698,29 @@ def test_a_pass_appends_the_contiguous_unmasked_prefix_and_commits_by_block():
     assert r.generated == []
     assert s.complete_block(r, [9, 10, 33, 44], [0, 0, 0, 0], False) == 2
     assert r.generated == [33, 44] and r.cached == 8
-    # the block the next pass works on: the commit is in flight
+    # the slots of the next pass: the block at 8 and, committed in it, its
+    # successor; with that pass in flight the next goes on at 12, where
+    # the request ends: its last block has no successor
+    assert s.block_ends(r) == (12, 16)
     r.owed.append(7)
-    assert s.block_start(r) == 12
+    assert s.block_start(r) == 12 and s.block_ends(r) == (16, 16)
     r.owed.clear()
-    assert s.complete_block(r, [9, 10, 33, 44], [0, 0, 0, 0], True) == 0
-    assert r.cached == 12 and r.block_tokens == [MASK] * 4
+    # ONE read-back: the commit, and the successor's first denoising
+    assert s.complete_block(r, [MASK, 2, MASK, MASK], [1, 0, 1, 1],
+                            True) == 0
+    assert r.cached == 12 and r.block_masked == [True, False, True, True]
     assert s.complete_block(r, [1, 2, 3, 4], [0, 0, 0, 0], False) == 4
     # tokens past max_new_tokens are dropped, and the request ends
     assert r.generated == [33, 44, 1, 2, 3, 4] and r.status == "ok"
+    # a pass that did nothing but commit leaves the successor all masks
+    r = Request(list(range(1, 9)), 8)
+    r.cached, r.block_tokens, r.block_masked = 8, [5, 6, 7, 8], [False] * 4
+    r.generated = [5, 6, 7, 8]
+    assert s.complete_block(r, [MASK] * 4, [1, 1, 1, 1], True) == 0
+    assert r.cached == 12 and r.block_masked == [True] * 4
 
 
-def test_a_running_row_costs_a_block_and_pages_grow_to_the_next_block():
+def test_a_running_row_costs_a_block_and_pages_grow_to_the_second_slot():
     s = block_scheduler(token_budget=16)
     a, b = Request(list(range(1, 9)), 20), Request(list(range(1, 9)), 20)
     s.add_request(a)
@@ -561,8 +739,23 @@ def test_a_running_row_costs_a_block_and_pages_grow_to_the_next_block():
     s.add_request(d)
     assert s.schedule().prefills == []          # 16 - 3 * 4 < 8
     # the prefill bucket's page holds positions 0..7; the block at 8 needs
-    # the second page before its pass is dispatched
+    # the second page before its pass is dispatched, and slot B (12..15)
+    # lies in it too
     assert len(a.pages) == 2
+    # the block at 12: slot B (16..19) needs the third page, whatever the
+    # host has read of the block (the threshold may complete it unseen)
+    a.cached, a.block_masked = 12, [True] * 4
+    s.schedule()
+    assert len(a.pages) == 3 and s.block_ends(a) == (16, 20)
+    # never past the request's natural end (8 + 20 = 28): the block at 24
+    # is its last, and the fourth page (24..31) its last page
+    a.cached = 24
+    s.schedule()
+    assert len(a.pages) == 4 and s.block_ends(a) == (28, 28)
+    # nor past the serving window
+    wide = Request(list(range(1, 9)), 100)
+    wide.cached, wide.block_masked = 60, [True] * 4
+    assert s.block_ends(wide) == (64, 64)
     # the last row of the last block, with its pass in flight: no step
     e = Request(list(range(1, 9)), 4)
     e.cached, e.block_masked, e.owed = 8, [False, False, True, False], [3]

@@ -621,15 +621,18 @@ def test_moe_serving_programs_leave_the_experts_in_place(on_chip, v5e_2x2,
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("heads,window,G", [(48, None, 8), (72, 512, 8),
-                                            (128, None, 4)],
-                         ids=["full_48", "window_72", "block_4x32"])
+                                            (128, None, 4), (256, None, 4)],
+                         ids=["full_48", "window_72", "block_4x32",
+                              "block_slots_4x64"])
 def test_grouped_window_paged_decode_compiles(on_chip, heads, window, G):
     """The paged kernel at Laguna's decode shapes: 48 (full) or 72
     (window 512) query heads over 8 KV heads of 128, batch 32, a table of
     136 pages of 64, the layer a traced scalar. And at a block pass's
     (SDAR): a block's 4 rows x 8 query heads as a group of 32 rows under
-    each of 4 KV heads, a table of 48 pages, a pool of 1,601. Each is ONE
-    Mosaic call (a pool rides once a page of the step: still one call)."""
+    each of 4 KV heads, a table of 48 pages, a pool of 1,601; and at its
+    two slots, 64 rows a KV head, the first half's end prefetched beside
+    the row's. Each is ONE Mosaic call (a pool rides once a page of the
+    step: still one call)."""
     B, D, ps = 32, 128, 64
     pool = ((2, 289, G, ps, D) if G == 8 else (6, 1601, G, ps, D), BF16)
     name = "ds.paged_decode_block" if G == 4 else \
@@ -638,7 +641,8 @@ def test_grouped_window_paged_decode_compiles(on_chip, heads, window, G):
     def decode(q, table, lengths, layer, k, v):
         return decode_attention.paged_decode_attention(
             q, k, v, table, lengths, D ** -0.5, backend="pallas",
-            layer=layer, window=window, block_pass=G == 4)
+            layer=layer, window=window, block_pass=G == 4,
+            first_lengths=lengths - 4 if heads == 256 else None)
 
     text = on_chip(decode, ((B, heads, D), BF16),
                    ((B, 136 if G == 8 else 48), jnp.int32),
@@ -711,9 +715,9 @@ def _block_programs_hold_the_weights_once(v5e_2x2, program):
     the published widths (hidden 2048, 32 query heads over 4 KV heads of
     128 with a norm a head, 128 experts of width 768, 8 a token, the whole
     vocabulary of 151,936; two layers) at the cell's shapes (32 sequences
-    x 4 rows, page 64, 1,601 pages, a window of 3,072, a bucket of
-    2,048), compiled for the described v5e from shapes alone. The paged
-    kernel runs under the block pass's name and the row write is there;
+    x 2 slots of 4 rows, page 64, 1,601 pages, a window of 3,072, a bucket
+    of 2,048), compiled for the described v5e from shapes alone. The paged
+    kernel runs under the block pass's name and the row writes are there;
     no instruction produces an array of the pool's or of the experts'
     shape, none re-lays out an attention weight, and the engine's stack
     is the caller's array: the weights are held once."""
@@ -758,10 +762,11 @@ def _block_programs_hold_the_weights_once(v5e_2x2, program):
     carry = ()
     if program == "block_decode":
         fn = engine._decode_fn(batch)
-        inputs = (ints(batch, 2 * block + 1), ints(batch),
+        # a row's two slots: their state, their ends
+        inputs = (ints(batch, 4 * block + 1), ints(batch, 2),
                   {kind: ints(batch, engine.n_pages_max)
                    for kind in engine.caches})
-        carry = (ints(batch, 2 * block + 1), ints(batch))
+        carry = (ints(batch, 4 * block + 1), ints(batch))
         kernels = ("ds.paged_decode_block", "ds.kv_write",
                    "ds.grouped_matmul")
     else:
