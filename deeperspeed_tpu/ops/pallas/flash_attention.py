@@ -663,8 +663,9 @@ def _edge_crosses(qi, ki, block_q, block_k, causal, window):
 
 
 class _Tile:
-    """What the three kernels share of one grid step: which body the
-    tile takes, and a block's masks in the transposed orientation."""
+    """What the tiled kernels share of one grid step: which body the
+    tile takes, and a block's masks in the transposed orientation (the
+    segmented forward takes the first alone)."""
 
     def __init__(self, qi, ki, block_q, block_k, *, sm_scale, causal,
                  window=None, sq_ref=None, sk_ref=None, m_ref=None,
@@ -704,16 +705,17 @@ class _Tile:
         # only where the call brings such a mask at all
         self.any_other = self.other is not False
 
-    def bodies(self, body):
+    def bodies(self, body, diagonal=True):
         """Run `body(masked, offset, crossed)` as the tile takes it (not
         at all where no id is shared). A tile the causal diagonal crosses
         runs `body(True, offset, True)` under the offset of its first
         query from its first key: a trace-time int, one body for each
         offset the geometry has (`diagonal_offsets`), from which the body
         knows which of its blocks lie beyond the diagonal. Every other
-        tile, and every tile of a windowed call or of a geometry with too
-        many offsets, runs `body(masked, None, crossed)`: `crossed` False
-        where the diagonal is known to miss it."""
+        tile, and every tile of a windowed call, of a geometry with too
+        many offsets or of a kernel that takes no `diagonal` bodies, runs
+        `body(masked, None, crossed)`: `crossed` False where the diagonal
+        is known to miss it."""
         def masked(offset, crossed):
             def masked_body():
                 self.stage_key_columns()
@@ -721,7 +723,7 @@ class _Tile:
             return masked_body
 
         offsets = diagonal_offsets(self.block_q, self.block_k) \
-            if self.causal and self.window is None else ()
+            if diagonal and self.causal and self.window is None else ()
         for d in offsets:
             _when(_and(self.run, self.lead == -d), masked(d, True))
         # what is left: the tiles the diagonal misses, or every tile
@@ -954,13 +956,20 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 
 
 # A SEGMENTED forward (a serving prefill, a packed batch) keeps the
-# whole-tile body: one max, exp and sum over the [block_q, block_k] tile,
-# lane-broadcast running stats. A serving engine builds a prefill program
-# a bucket and a kernel a layer kind in each (24 kernels in Laguna's
-# cell), and the strip walk's unrolled bodies doubled that cell's warm
-# set-up (47.8 -> 100.8 s; a loop over pairs with four strips unrolled
-# still read 55 s and ran no faster than this body: PERF.md, PR 33). The
-# backward of a segmented call takes the new tile bodies.
+# WHOLE-TILE form: one max, exp2 and sum over the [block_q, block_k] tile,
+# lane-broadcast running stats, no strip walk and no body a diagonal
+# offset. A serving engine builds a prefill program a bucket and a kernel
+# a layer kind in each (24 kernels in Laguna's cell), and it was the strip
+# walk's unrolled bodies that doubled that cell's warm set-up (47.8 ->
+# 100.8 s; a loop over pairs with four strips unrolled still read 55 s:
+# PERF.md, PR 33), not the choice of a body by tile: the kernel has two
+# whole-tile bodies, a few dozen equations each. A tile no edge crosses
+# and whose id slices are one document (`_Tile.crossed`, `.other`) takes
+# the INTERIOR body: scores, max, exp2, sum, cast, PV, rescale. Any other
+# tile that runs takes the EDGE body: the same, with the causal / window /
+# block-causal compare, the segment compare and the zeroing of masked
+# entries around it. The backward of a segmented call takes the strip
+# walk's tile bodies.
 
 def _window_mask(s, qi, ki, block_q, block_k, window=None, mask_block=0):
     """Key j is visible to query i iff j <= i, and under a `window` also
@@ -1008,43 +1017,41 @@ def _fwd_segmented_kernel(*refs, sm_scale, causal, block_q, block_k,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # [BQ, 1] vs [1, BK] segment-id equality: the elementwise mask AND the
-    # block-level skip — a tile whose q and k blocks share no document
-    # runs NO matmul/softmax work
-    seg_eq = sq_ref[0].reshape(-1, 1) == sk_ref[0]
-    run = jnp.any(seg_eq)
+    tile = _Tile(qi, ki, block_q, block_k, sm_scale=sm_scale,
+                 causal=causal, window=window, sq_ref=sq_ref, sk_ref=sk_ref)
+    # scores stay raw and so does the running max: the scale rides the
+    # exponent's multiply, as in `_fwd_kernel`
+    c2 = jnp.float32(sm_scale * LOG2E)
 
-    @pl.when(run)
-    def _compute():
+    def body(masked, *_):
         # Matmuls take the inputs' native dtype (bf16 → MXU-rate) and
         # accumulate fp32; only the softmax math is explicitly fp32.
-        q = q_ref[0]                                          # [BQ, D]
-        k = k_ref[0]                                          # [BK, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale    # [BQ, BK]
-        if causal:
-            s = _window_mask(s, qi, ki, block_q, block_k, window, mask_block)
-        s = jnp.where(seg_eq, s, NEG_INF)
-
+        s = _dot(q_ref[0], k_ref[0], _NT)                     # [BQ, BK]
+        if masked:
+            if causal:
+                s = _window_mask(s, qi, ki, block_q, block_k, window,
+                                 mask_block)
+            # [BQ, 1] vs [1, BK] segment ids: a document boundary, pad rows
+            s = jnp.where(sq_ref[0].reshape(-1, 1) == sk_ref[0], s, NEG_INF)
         m_prev = m_scr[:, :1]                                 # [BQ, 1]
         l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)                       # [BQ, 1]
-        p = jnp.exp(s - m_new)                                # [BQ, BK]
-        # rows with EVERY entry masked would otherwise see exp(s - max)
-        # == 1 uniformly; zero masked entries so l == 0 flags the dead
-        # row (poisoned-lse convention)
-        p = jnp.where(s <= NEG_INF * 0.5, 0.0, p)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp2((m_prev - m_new) * c2)               # [BQ, 1]
+        p = jnp.exp2((s - m_new) * c2)                        # [BQ, BK]
+        if masked:
+            # rows with EVERY entry masked would otherwise see exp2(0)
+            # == 1 uniformly; zero masked entries so l == 0 flags the
+            # dead row (poisoned-lse convention)
+            p = jnp.where(s <= NEG_INF * 0.5, 0.0, p)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [BQ, D]
-        acc_scr[:] = acc_scr[:] * alpha + pv
+        acc_scr[:] = acc_scr[:] * alpha + _dot(p.astype(v_ref.dtype),
+                                               v_ref[0], _NN)  # [BQ, D]
+
+    # the edge body, the interior body, or (a tile whose q and k slices
+    # can share no document) NO body
+    tile.bodies(body, diagonal=False)
 
     @pl.when(ki == last_k)
     def _finalize():
@@ -1053,10 +1060,11 @@ def _fwd_segmented_kernel(*refs, sm_scale, causal, block_q, block_k,
         o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
         # lse row-vector [1, BQ]: the [BQ]-per-row stats transposed onto
         # the lane dim — 128x less HBM than a lane-broadcast [BQ, LANES].
-        # Dead rows (no active block — possible under a layout mask) get
-        # POISONED lse (+1e30) so backward's exp(s - lse) is exactly 0,
-        # the block-sparse kernels' invariant.
-        lse = jnp.where(l == 0.0, -NEG_INF, m_scr[:, :1] + jnp.log(l_safe))
+        # Dead rows (every key another document's, or behind a pad row's
+        # window) get POISONED lse (+1e30) so backward's exp(s - lse) is
+        # exactly 0, the block-sparse kernels' invariant.
+        lse = jnp.where(l == 0.0, -NEG_INF,
+                        m_scr[:, :1] * sm_scale + jnp.log(l_safe))
         lse_ref[0] = lse.reshape(1, -1)
 
 
@@ -1186,7 +1194,7 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
     ] + _key_column_scratch(block_k, False, use_bias)
     masked = masked_tile_count(
         n_q, n_k, block_q, block_k, causal, window,
-        always=segmented or use_mask or use_bias or dropout_rate > 0.0)
+        always=use_mask or use_bias or dropout_rate > 0.0)
     run = _tiled_call(
         "fwd", "ds.flash_fwd" if window is None else "ds.flash_fwd_window",
         kernel, compact, grid, in_specs, out_specs, scratch_shapes,

@@ -321,10 +321,12 @@ def test_window_launches_the_band_alone():
 # the tile body (PR 33): strips, the masked and the unmasked body
 # ---------------------------------------------------------------------------
 
-def masked_reference(q, k, v, seen=None, kbias=None, keep=None, rate=0.0):
+def masked_reference(q, k, v, seen=None, kbias=None, keep=None, rate=0.0,
+                     with_lse=False):
     """Plain fp32 attention under an explicit [B, H, S, S] visibility
     mask, a per-key bias and a dropout keep-mask; rows that see no key
-    give zeros (the kernels' poisoned-lse convention)."""
+    give zeros (the kernels' poisoned-lse convention: `with_lse` returns
+    the [B, H, S] lse beside the output, +1e30 on such a row)."""
     q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     r = q.shape[2] // k.shape[2]
     k, v = jnp.repeat(k, r, axis=2), jnp.repeat(v, r, axis=2)
@@ -337,12 +339,18 @@ def masked_reference(q, k, v, seen=None, kbias=None, keep=None, rate=0.0):
     p = jnp.where(alive, jax.nn.softmax(s, axis=-1), 0.0)
     if keep is not None:
         p = jnp.where(keep, p / (1.0 - rate), 0.0)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    if with_lse:
+        return out, jnp.where(alive[..., 0],
+                              jax.nn.logsumexp(s, axis=-1), 1e30)
+    return out
 
 
-def causal_seen(S, window=None):
+def causal_seen(S, window=None, mask_block=0):
+    """[1, 1, S, S]: key j at or before query i (of `mask_block`: at or
+    before the last position of i's block), within `window` of it."""
     i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
-    seen = j <= i
+    seen = j <= (i | (mask_block - 1) if mask_block else i)
     if window is not None:
         seen = seen & (i - j < window)
     return seen[None, None]
@@ -699,6 +707,240 @@ def test_masked_and_unmasked_body_agree_bit_for_bit(causal):
 
 
 # ---------------------------------------------------------------------------
+# the segmented forward's two whole-tile bodies (PR 49)
+# ---------------------------------------------------------------------------
+#
+# A serving prefill's kernel: a tile no edge crosses and whose id slices
+# are one document takes the interior body (no iota, compare or select),
+# any other tile that runs the edge body, a tile whose id ranges cannot
+# overlap none.
+
+def _ids(S, *bounds):
+    """[S] segment ids: 1 up to the first bound, 2 up to the next, ...;
+    0 (pad) from the last one on."""
+    pos = np.arange(S)
+    ids = sum((pos < b).astype(np.int32) for b in bounds)
+    return np.where(ids > 0, len(bounds) + 1 - ids, 0).astype(np.int32)
+
+
+def segmented_reference(q, k, v, seg, causal, window, mask_block):
+    """(out, lse) of plain fp32 attention within documents: a row that
+    sees no key gives zeros and the poisoned lse."""
+    seen = (seg[:, :, None] == seg[:, None, :])[:, None]
+    if causal:
+        seen = seen & causal_seen(q.shape[1], window, mask_block)
+    return masked_reference(q, k, v, seen, with_lse=True)
+
+
+def _segmented_fwd(q, k, v, seg, causal, blocks, window=None, mask_block=0):
+    """(out [B, S, H, D], lse [B, H, S]) of the segmented forward."""
+    B, S, H, D = q.shape
+    out, res = fa._fwd(q, k, v, causal, 1.0 / math.sqrt(D), *blocks,
+                       seg=jnp.asarray(seg, jnp.int32).reshape(B, 1, S),
+                       window=window, mask_block=mask_block)
+    return out, res[-1].reshape(B, H, S)
+
+
+SEGMENTED_CASES = [
+    # name, S, (block_q, block_k), heads, KV heads, head dim, dtype,
+    # rows of id bounds, causal, window, mask_block
+    # one document over several tiles, then pad rows: interior tiles, the
+    # diagonal's, the one that holds the pad boundary (700 lies inside
+    # tile 2), a last row of tiles that is all pad
+    ("one_document_pad_boundary", 1024, (256, 256), 2, 2, 64, jnp.float32,
+     [(700,)], True, None, 0),
+    ("two_documents_boundary_in_a_tile", 1024, (256, 256), 2, 2, 128,
+     jnp.float32, [(300, 1024)], True, None, 0),
+    ("boundary_on_a_tile_edge", 1024, (256, 256), 2, 2, 64, jnp.float32,
+     [(512, 1024)], True, None, 0),
+    ("pad_rows_fill_the_last_tiles", 1024, (128, 128), 2, 2, 64,
+     jnp.bfloat16, [(384,)], True, None, 0),
+    ("two_rows_of_their_own_documents", 512, (128, 128), 2, 2, 64,
+     jnp.float32, [(200, 450), (512,)], True, None, 0),
+    ("blocks_2_to_1", 1024, (256, 128), 2, 2, 64, jnp.float32,
+     [(300, 900)], True, None, 0),
+    ("blocks_1_to_2", 1024, (128, 256), 2, 2, 128, jnp.bfloat16,
+     [(300, 900)], True, None, 0),
+    ("window_under_a_block", 1024, (128, 128), 4, 2, 64, jnp.float32,
+     [(900,)], True, 100, 0),
+    # a band four tiles wide: those between the diagonal and the far edge
+    # are interior
+    ("window_over_blocks", 1024, (128, 128), 2, 2, 64, jnp.float32,
+     [(1024,)], True, 600, 0),
+    ("window_documents_grouped", 1024, (256, 256), 6, 2, 128, jnp.bfloat16,
+     [(333, 800)], True, 384, 0),
+    ("mask_block_4", 1024, (256, 256), 8, 2, 128, jnp.float32,
+     [(1022,)], True, None, 4),
+    ("mask_block_4_documents", 512, (128, 128), 2, 2, 64, jnp.bfloat16,
+     [(200, 508)], True, None, 4),
+    ("grouped_kv_heads", 1024, (256, 256), 12, 2, 64, jnp.float32,
+     [(1000,)], True, None, 0),
+    ("head_dim_256", 512, (128, 128), 2, 2, 256, jnp.float32,
+     [(450,)], True, None, 0),
+    ("head_dim_256_bf16", 1024, (256, 256), 2, 2, 256, jnp.bfloat16,
+     [(600, 1024)], True, None, 0),
+    ("head_dim_128_bf16", 1024, (512, 512), 2, 2, 128, jnp.bfloat16,
+     [(1024,)], True, None, 0),
+    ("head_dim_64_bf16_fat_blocks", 2048, (1024, 1024), 1, 1, 64,
+     jnp.bfloat16, [(1500,)], True, None, 0),
+    ("packed_not_causal", 512, (128, 128), 2, 2, 64, jnp.float32,
+     [(130, 400)], False, None, 0),
+]
+
+
+@pytest.mark.parametrize(
+    "name,S,blocks,H,G,d,dtype,bounds,causal,window,mask_block",
+    SEGMENTED_CASES, ids=[c[0] for c in SEGMENTED_CASES])
+def test_segmented_forward_matches_fp32_reference(
+        name, S, blocks, H, G, d, dtype, bounds, causal, window, mask_block):
+    """Output and lse of the segmented forward against plain fp32
+    attention, on calls whose tiles take the interior body, the edge body
+    and none; a pad row's output is its own business (it attends the pad
+    rows before it), every other row is held."""
+    B = len(bounds)
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    q = (jax.random.normal(ks[0], (B, S, H, d)) * 0.5).astype(dtype)
+    k = (jax.random.normal(ks[1], (B, S, G, d)) * 0.5).astype(dtype)
+    v = (jax.random.normal(ks[2], (B, S, G, d)) * 0.5).astype(dtype)
+    seg = jnp.asarray(np.stack([_ids(S, *b) for b in bounds]))
+    out, lse = _segmented_fwd(q, k, v, seg, causal, blocks, window,
+                              mask_block)
+    want, want_lse = segmented_reference(q, k, v, seg, causal, window,
+                                         mask_block)
+    real = np.asarray(seg > 0)
+    tol = dict(atol=3e-5, rtol=3e-5) if dtype == jnp.float32 else \
+        dict(atol=4e-2, rtol=4e-2)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32) * real[:, :, None, None],
+        np.asarray(want) * real[:, :, None, None], **tol)
+    np.testing.assert_allclose(np.asarray(lse) * real[:, None, :],
+                               np.asarray(want_lse) * real[:, None, :], **tol)
+    # the count is the geometry's: what the data adds, only the data knows
+    assert fa._LAST_MASKED["fwd"] == fa.masked_tile_count(
+        S // blocks[0], S // blocks[1], *blocks, causal, window)
+
+
+@pytest.mark.parametrize("d,dtype", [(64, jnp.float32), (256, jnp.bfloat16)],
+                         ids=["d64_float32", "d256_bfloat16"])
+def test_interior_and_edge_body_agree_bit_for_bit(d, dtype, monkeypatch):
+    """A dense call over ONE document: every tile is all-visible and takes
+    the interior body. Told that no tile holds one document, every tile
+    takes the edge body, whose compares then mask nothing: the same
+    output and lse, to the bit."""
+    q, k, v = make_qkv(s=512, h=2, d=d, dtype=dtype, seed=5)
+    seg = np.ones((1, 512), np.int32)
+    fa._fwd_call.cache_clear()
+    interior = _segmented_fwd(q, k, v, seg, False, (128, 128))
+    assert fa._LAST_MASKED["fwd"] == (0, 16)
+    facts = fa._segment_facts
+    monkeypatch.setattr(
+        fa, "_segment_facts",
+        lambda sq, sk: (facts(sq, sk)[0], jnp.bool_(False)))
+    fa._fwd_call.cache_clear()
+    try:
+        edge = _segmented_fwd(q, k, v, seg, False, (128, 128))
+    finally:
+        fa._fwd_call.cache_clear()      # the patched body dies here
+    for got, want in zip(edge, interior):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# GLM's two largest buckets at (1024, 1024), Laguna's window layer at
+# (512, 512) and a band four tiles of 1,024 wide (the two between the
+# diagonal and the far edge are interior): a segmented call reports the
+# tiles the GEOMETRY crosses
+SEGMENTED_COUNTS = [
+    # [B, S, H, D], window, (masked, launched)
+    ((1, 16384, 20, 256), None, (16, 136)),
+    ((1, 8192, 20, 256), None, (8, 36)),
+    ((1, 8192, 72, 128), 512, (31, 31)),
+    ((1, 8192, 8, 128), 4096, (12, 30)),
+]
+
+
+@pytest.mark.parametrize("shape,window,count", SEGMENTED_COUNTS,
+                         ids=["glm_16k", "glm_8k", "laguna_window_512",
+                              "window_4096"])
+def test_segmented_call_reports_the_geometrys_masked_tiles(shape, window,
+                                                           count):
+    """`dispatch_report()["flash"]["masked_tiles"]["fwd"]` of a serving
+    prefill, traced at the blocks the rule gives (nothing runs): the
+    diagonal's tiles and a window's far edge's over the tiles launched,
+    as the closed form counts them."""
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jax.make_jaxpr(lambda q, k, v, s: fa.flash_attention_segmented(
+        q, k, v, s, True, window=window))(
+            spec, spec, spec, jax.ShapeDtypeStruct(shape[:2], jnp.int32))
+    report = importlib.import_module(
+        "deeperspeed_tpu.ops").dispatch_report()["flash"]
+    bq, bk = report["fwd"]
+    S = shape[1]
+    assert report["masked_tiles"]["fwd"] == count == \
+        _closed_form_masked_tiles(S // bq, S // bk, bq, bk, True, window)
+    assert report["masked_tiles"]["fwd"][1] == fa._LAST_GRIDS["fwd"][1]
+
+
+# The most equations the segmented forward may trace to at the serve
+# cells' prefill shapes, whole and a body: about 1.3 times what this tree
+# counts (168 / 196 / 169 whole; the edge body 53-56, the interior 29). The
+# strip walk this kernel does not take is 688-907 (SETUP_CASES): a prefill
+# program is built a bucket and a layer kind, so this is the test that
+# catches a set-up regression in the serve cells.
+SEGMENTED_SETUP_CASES = [
+    # [B, S, H, D], KV heads, window, mask_block
+    ((1, 16384, 20, 256), 20, None, 0),
+    ((1, 8192, 72, 128), 8, 512, 0),
+    ((1, 2048, 32, 128), 4, None, 4),
+]
+SEGMENTED_BUDGET = {"kernel": 255, "edge": 75, "interior": 40}
+
+
+@pytest.mark.parametrize("shape,G,window,mask_block", SEGMENTED_SETUP_CASES,
+                         ids=["glm_16k", "laguna_window", "sdar_block4"])
+def test_segmented_bodies_are_built_once_and_stay_small(shape, G, window,
+                                                        mask_block):
+    """Three layers' prefill attention, traced twice, build the kernel's
+    body ONCE; it holds two whole-tile bodies (and the init and the
+    finalize) under their budgets of equations, the interior one with no
+    iota, compare or select in it."""
+    B, S, H, D = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, G, D), jnp.bfloat16)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32)
+
+    def layers(q, k, v, seg):
+        for _ in range(LAYERS):
+            q = fa.flash_attention_segmented(q, k, v, seg, True,
+                                             window=window,
+                                             mask_block=mask_block)
+        return q
+
+    before = _fresh_account()
+    first = jax.make_jaxpr(layers)(q, kv, kv, seg)
+    jax.make_jaxpr(layers)(q, kv, kv, seg)
+    assert _built_since(before) == {"fwd": 1, "bwd": 0, "dkv": 0, "dq": 0}
+    kernels = list(_kernel_jaxprs(first.jaxpr))
+    assert len(kernels) == LAYERS and len({id(b) for _, b in kernels}) == 1
+    name, kernel = kernels[0]
+    assert name == ("ds.flash_fwd" if window is None
+                    else "ds.flash_fwd_window")
+    assert _equations(kernel) <= SEGMENTED_BUDGET["kernel"], \
+        _equations(kernel)
+    # init, the edge body, the interior body, finalize: four `pl.when`s
+    conds = [eqn.params["branches"][1].jaxpr for eqn in kernel.eqns
+             if eqn.primitive.name == "cond"]
+    assert len(conds) == 4
+    _, edge, interior, _ = conds
+    assert _equations(edge) <= SEGMENTED_BUDGET["edge"], _equations(edge)
+    assert _equations(interior) <= SEGMENTED_BUDGET["interior"], \
+        _equations(interior)
+    used = _primitives(interior)
+    assert not used & {"iota", "select_n", "eq", "ge", "lt", "le", "or",
+                       "and"}, used
+    assert {"iota", "select_n", "eq", "ge"} <= _primitives(edge)
+
+
+# ---------------------------------------------------------------------------
 # the set-up account (PR 34): a body is built once a process, and is small
 # ---------------------------------------------------------------------------
 #
@@ -716,6 +958,14 @@ def _kernel_jaxprs(jaxpr):
             continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
             yield from _kernel_jaxprs(sub)
+
+
+def _primitives(jaxpr):
+    """The names of the primitives anywhere under it."""
+    return {eqn.primitive.name for eqn in jaxpr.eqns} | {
+        name for eqn in jaxpr.eqns
+        for sub in jax.core.jaxprs_in_params(eqn.params)
+        for name in _primitives(sub)}
 
 
 def _equations(jaxpr):

@@ -1067,14 +1067,23 @@ def test_latent_row_write_compiles(on_chip):
         == 16
 
 
-def test_flash_forward_compiles_at_head_dim_256(on_chip):
-    """The expanded prefill's attention: one row of 16,384 tokens, 20
-    heads of 192 + 64 for q.k and 256 for v, segmented."""
+@pytest.mark.parametrize("tokens,masked", [(16384, (16, 136)),
+                                           (8192, (8, 36))])
+def test_flash_forward_compiles_at_head_dim_256(on_chip, tokens, masked):
+    """The expanded prefill's attention at the latent cell's two largest
+    buckets: one row of 16,384 or 8,192 tokens, 20 heads of 192 + 64 for
+    q.k and 256 for v, segmented: both whole-tile bodies of the kernel at
+    (1024, 1024), the diagonal's tiles alone counted as masked."""
     def prefill(q, k, v, seg):
         return fa.flash_attention_segmented(q, k, v, seg, True)
 
-    qkv = ((1, 16384, 20, 256), BF16)
-    assert_kernel(on_chip(prefill, qkv, qkv, qkv, ((1, 16384), jnp.int32)))
+    qkv = ((1, tokens, 20, 256), BF16)
+    text = on_chip(prefill, qkv, qkv, qkv, ((1, tokens), jnp.int32))
+    assert_kernel(text)
+    assert kernel_names(text) == {"ds.flash_fwd"}
+    report = dispatch_report()["flash"]
+    assert report["fwd"] == (1024, 1024)
+    assert report["masked_tiles"]["fwd"] == masked
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
