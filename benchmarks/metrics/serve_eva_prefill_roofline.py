@@ -1,0 +1,5 @@
+from benchmarks import evabyte_costs
+
+
+def read(rec):
+    return evabyte_costs.prefill_roofline(rec)

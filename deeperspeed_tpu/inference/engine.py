@@ -77,6 +77,7 @@ from ..ops.pallas.decode_attention import (_write_group,
                                            paged_latent_decode,
                                            paged_latent_write)
 from ..ops.autotune import whole_blocks
+from ..ops.pallas.eva import eva_summarize
 from ..ops.pallas.flash_attention import NEG_INF, flash_attention_supported
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.config import (DeepSpeedConfig, parse_inference_block,
@@ -93,9 +94,11 @@ from .handoff import (ACCEPTED, HandoffChannel, HandoffRejected,
                       check_geometry, encode_pages, write_pages)
 from .kv_cache import (PagedKVCache, PrefixCache, QuantizedPages, StateCache,
                        pages_for_tokens, quantize_kv)
-from .metrics import (PREFIX_HIT_RATE, PREFIX_PAGES_SHARED,
-                      PREFIX_SAVED_PREFILL_TOKENS, REQUEST_STATUS_FAMILIES,
-                      SPEC_ACCEPTANCE_RATE, ServeRequestMetrics)
+from .metrics import (EVA_PAGES_RELEASED, EVA_PENDING_PAGES,
+                      EVA_WINDOWS_ROLLED, PREFIX_HIT_RATE,
+                      PREFIX_PAGES_SHARED, PREFIX_SAVED_PREFILL_TOKENS,
+                      REQUEST_STATUS_FAMILIES, SPEC_ACCEPTANCE_RATE,
+                      ServeRequestMetrics)
 from .scheduler import (FINISHED, RUNNING, ContinuousBatchingScheduler,
                         Request)
 
@@ -112,6 +115,9 @@ class _InFlight:
     phase: str
     reqs: list
     tokens: object
+    # every prediction head's logits of the rows, on the device (a model
+    # with several heads; else None): read only for `engine.head_trace`
+    logits: object = None
 
     def rows(self):
         """(row, request, live) of every row."""
@@ -168,6 +174,11 @@ class _Family:
         # where its plan has such layers
         self.window = cfg.attn_window if self.cache_layers("window") else 0
         self.latent = cfg.latent_width if self.cache_layers("latent") else 0
+        # a chunk-pooled (eva) model's window and chunk (0, 0: none), and
+        # the prediction heads the output head holds
+        self.eva = (cfg.eva_window, cfg.eva_chunk) \
+            if self.cache_layers("eva") else (0, 0)
+        self.pred_heads = getattr(cfg, "num_pred_heads", 1)
         # experts a token of a served (dropless) MoE; 0: a dense model
         self.moe_top_k = cfg.moe_top_k \
             if getattr(cfg, "moe_dropless", False) else 0
@@ -401,6 +412,7 @@ class InferenceEngine:
         self.family = fam = _Family(model, self.max_seq_len)
         self.loop_steps, self.window, self.latent = \
             fam.loop_steps, fam.window, fam.latent
+        self.eva_window, self.eva_chunk = fam.eva
         self._refuse_unplanned(ip, draft_model)
         if self.weight_quant and self.mp > 1:
             raise DeepSpeedConfigError(
@@ -430,7 +442,13 @@ class InferenceEngine:
         # `window`: what a window layer keeps, at most window / page + 1
         # pages a sequence, so the pool is sized for `max_batch_size` of
         # those and the scheduler gives the rest back as a sequence grows
-        pages = {"latent" if self.latent else "full": ip["num_pages"]}
+        # `eva`: a chunk-pooled model's ONE pool, of two populations of
+        # rows in the `full` kind's layout: the exact rows of a sequence's
+        # current window and one pooled row a chunk of its earlier windows
+        # (docs/inference.md "Chunk-pooled pages"), `num_pages`
+        primary = "latent" if self.latent else \
+            "eva" if self.eva_window else "full"
+        pages = {primary: ip["num_pages"]}
         if self.window:
             pages["window"] = self.max_batch_size * (
                 self.window // self.page_size + 1) + 1
@@ -440,7 +458,7 @@ class InferenceEngine:
             head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh,
             latent_width=self.latent) for kind, n in pages.items()}
         # the names the scheduler and a benchmark's probes read
-        self.cache = self.caches["latent" if self.latent else "full"]
+        self.cache = self.caches[primary]
         self.window_cache = self.caches.get("window")
         # the cache kind WITHOUT pages (a model with state-space layers):
         # one slot of recurrent state a running sequence, + the trash slot
@@ -526,9 +544,16 @@ class InferenceEngine:
             window_cache=self.window_cache, window=self.window,
             block=self.block,
             mask_token_id=cfg.mask_token_id if self.block else 0,
-            state_cache=self.state_cache)
+            state_cache=self.state_cache, eva_window=self.eva_window,
+            eva_chunk=self.eva_chunk, span=self._phase)
         self.n_pages_max = pages_for_tokens(self.max_seq_len,
                                             self.page_size)
+        if self.eva_window:
+            # the widest table: every whole window's pooled pages and one
+            # window's pages
+            sch = self.scheduler
+            self.n_pages_max = sch.eva_pages_summary * (
+                self.max_seq_len // self.eva_window) + sch.eva_pages_window
         # precision identity of this serving engine
         # (docs/quantization.md)
         self.dtypes = {
@@ -572,6 +597,11 @@ class InferenceEngine:
         # going in and coming out (`_complete_blocks`). What a test or a
         # benchmark's check replays against the reference
         self.block_trace = None
+        # a model with several prediction heads: where a list is put here
+        # (None: not kept), every read-back row's logits of ALL heads: one
+        # dict a live row {"request", "at": the index in prompt +
+        # generated of the token head 0 predicts, "logits" [heads * vocab]}
+        self.head_trace = None
         self.stats = {"steps": 0, "prefill_requests": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
                       # one-step lookahead (docs/inference.md): decode
@@ -643,6 +673,25 @@ class InferenceEngine:
                       "decode_kv_tokens_latent": 0,
                       "kv_page_steps_latent": 0,
                       "window_pages_released": 0,
+                      # a chunk-pooled (eva) model (0 without one): the
+                      # rows its decode steps read, by population (exact
+                      # rows of the current window, pooled rows of the
+                      # earlier ones) and the live contexts those stand
+                      # for; the (query, key) pairs its prefills scored a
+                      # head and layer; chunks its decode steps pooled;
+                      # tables rolled at a window's end and the pages
+                      # those gave back; pending pages held now; the bytes
+                      # a row of either population takes, all layers, and
+                      # the pooled rows' bytes a context byte behind the
+                      # window comes to
+                      "decode_kv_tokens_eva_window": 0,
+                      "decode_kv_tokens_eva_summary": 0,
+                      "decode_context_tokens_eva": 0,
+                      "eva_prefill_pairs": 0,
+                      "eva_chunks_pooled": 0, "eva_windows_rolled": 0,
+                      "eva_pages_released": 0, "eva_pending_pages": 0,
+                      "kv_bytes_per_row_eva": 0,
+                      "kv_bytes_per_token_eva_summary": 0.0,
                       # the recurrent-state cache kind (0 without one):
                       # slots held now, the bytes one sequence's state
                       # takes, and both summed over the decode steps
@@ -692,6 +741,13 @@ class InferenceEngine:
             if kind in self.caches:
                 self.stats[f"kv_bytes_per_token_{kind}"] = \
                     self.caches[kind].bytes_per_token()
+        if self.eva_window:
+            # the one pool's two populations apart: an exact row (a byte
+            # of the current window) and a pooled row (a chunk behind it)
+            row = self.cache.bytes_per_token()
+            self.stats["kv_bytes_per_row_eva"] = row
+            self.stats["kv_bytes_per_token_eva_summary"] = \
+                row / self.eva_chunk
         # one record a step, the spans' seconds into `stats`; whether the
         # scheduler had work when the last step returned (the caller's
         # time before a step counts against it only then)
@@ -804,6 +860,10 @@ class InferenceEngine:
         elif self.window and self.kv_quant:
             what = ("kv_cache_dtype int8 with a window cache kind: the "
                     "paged kernel's window has no int8 variant")
+        elif self.eva_window and self.kv_quant:
+            what = ("kv_cache_dtype int8 with a chunk-pooled (eva) cache "
+                    "kind: a pooled row is a float32 sum rounded once to "
+                    "the pages' type, and the pooling reads plain rows")
         elif self.family.cache_layers("state") and (
                 self.kv_quant or ip["num_pages"] <= self.max_batch_size *
                 self.max_seq_len // self.page_size):
@@ -983,6 +1043,9 @@ class InferenceEngine:
 
     @scopes.scoped("ds.sample")
     def _sample(self, logits, rng):
+        if self.family.pred_heads > 1:
+            # head 0 is the next token's distribution
+            logits = logits[..., :self.model.config.vocab_size]
         if self.temperature <= 0.0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return jax.random.categorical(
@@ -1102,7 +1165,7 @@ class InferenceEngine:
         [(spec, ys stacked over the run)])."""
         cache_at = {kind: loop_pass * (fam.cache_layers(kind) //
                                        fam.loop_steps)
-                    for kind in ("full", "window", "latent")}
+                    for kind in ("full", "window", "latent", "eva")}
         cache_at["ssm"] = 0
         out = []
         for spec, first, at, n in fam.runs():
@@ -1204,9 +1267,33 @@ class InferenceEngine:
             return page if live is None else jnp.where(live, page, 0)
 
         page_idx = {k: [page_of(tables[k], start, live)
-                        for start, _, live in runs] for k in kinds}
+                        for start, _, live in runs]
+                    for k in kinds if k != "eva"}
         slot = [start % ps for start, _, _ in runs]
         G = fam.kv_heads
+        # the rows a layer's decode attends, by cache kind
+        attended = {k: lengths for k in kinds}
+        W, C = fam.eva
+        if W:
+            # a chunk-pooled table: [the pooled rows' pages of the windows
+            # that have ended | the current window's pages]
+            # (`scheduler.eva_table_index` / `eva_length`, on the device)
+            per_win = W // C
+            window = pos // W
+            row = pos - window * W
+            live = lengths > 0
+            page_idx["eva"] = [jnp.where(live, jnp.take_along_axis(
+                tables["eva"], (window * (per_win // ps) + row // ps)[:, None],
+                axis=1)[:, 0], 0)]
+            attended["eva"] = jnp.where(
+                live, window * per_win + row + 1, 0).astype(lengths.dtype)
+            # the token that closes a chunk: its pooled row's place in the
+            # pending pages, which no table names yet
+            chunk = row // C
+            closing = live & (pos % C == C - 1)
+            pending = (jnp.take_along_axis(
+                tables["eva_pending"], (chunk // ps)[:, None],
+                axis=1)[:, 0], chunk % ps)
 
         def kv_rows(t, rows):
             """A run's K or V of [B, R, G, D] as `_write_rows` takes it."""
@@ -1260,6 +1347,12 @@ class InferenceEngine:
                     kv = self._write_rows(
                         kv, kv_rows(k, rows), kv_rows(v, rows), cache_layer,
                         page_idx[kind][i], slot[i])
+            if kind == "eva":
+                kv = eva_summarize(
+                    kv, bp["attn"]["eva_phi"], bp["attn"]["eva_mu"],
+                    cache_layer, page_idx[kind][0], slot[0], closing,
+                    *pending, C, scale or 1.0 / math.sqrt(cfg.head_dim),
+                    backend=self._attn_backend)
             with scopes.scope("ds.attn"):
                 if diff:
                     q = neox.diff_queries(q)
@@ -1268,7 +1361,7 @@ class InferenceEngine:
                 if not isinstance(kv[0], QuantizedPages):
                     q = q.astype(kv[0].dtype)
                 attn = self._attention(
-                    q, kv, cache_layer, tables[kind], lengths,
+                    q, kv, cache_layer, tables[kind], attended[kind],
                     window=self.window if kind == "window" else None,
                     sm_scale=scale,
                     cross=spec.attn == "cross").astype(x.dtype)
@@ -1429,6 +1522,14 @@ class InferenceEngine:
                 if spec.attn == "ssm":
                     # its state into the slot; its scan output onwards
                     kv, aux = kv[:2], {"mem": kv[2]}
+                if spec.attn == "eva" and S > fam.eva[0]:
+                    # the pool keeps exact rows of the prompt's LAST window
+                    # alone (`_eva_tables`), and pooled rows of the rest
+                    W = fam.eva[0]
+                    start = jnp.minimum(lengths // W * W, S - W)
+                    kv = tuple(jax.vmap(
+                        lambda t, at: jax.lax.dynamic_slice_in_dim(t, at, W)
+                    )(t, start) for t in kv[:2]) + kv[2:]
                 out, rows = self._held_rows(fam, y)
                 return (out, held + rows, aux), kv
 
@@ -1442,7 +1543,7 @@ class InferenceEngine:
 
             G, D = fam.kv_heads, cfg.head_dim
 
-            def scatter(kind, pool, new, loop_pass):
+            def scatter(kind, pool, new, loop_pass, table=None):
                 """One pool of cache kind `kind` with a pass's new rows
                 [L_kind, B, S, ...] written as whole pages into that
                 pass's cache layers at the page-table ids (pad rows hold
@@ -1461,7 +1562,9 @@ class InferenceEngine:
                 46: PERF.md section 6; ROADMAP S5 has the rule)."""
                 if kind == "state":
                     return self._write_state(pool, new, tables[kind])
-                flat_pt = tables[kind].reshape(-1)
+                table = tables[kind] if table is None else table
+                flat_pt = table.reshape(-1)
+                n_pages_row = table.shape[1]
 
                 def tiles(rows):
                     """A layer's rows [B, S, ...] as its B * S / ps page
@@ -1501,15 +1604,28 @@ class InferenceEngine:
                             (split, split + 1))
                         runs += full
                 of_kind = {"state": "ssm"}
+
+                def made(kind, i):
+                    """Entry i of what the layers of cache kind `kind`
+                    returned, in order: [L_kind, B, rows, ...]."""
+                    return jnp.concatenate(
+                        [kv[i] for spec, kv in runs
+                         if spec.attn == of_kind.get(kind, kind)])
+
                 with scopes.scope("ds.kv_write"):
-                    # a kind's layers in order: [L_kind, B, S, ...]
                     pools = {kind: tuple(
-                        scatter(kind, pool, jnp.concatenate(
-                            [kv[i] for spec, kv in runs
-                             if spec.attn == of_kind.get(kind, kind)]),
-                            loop_pass)
+                        scatter(kind, pool, made(kind, i), loop_pass)
                         for i, pool in enumerate(kind_pools))
                         for kind, kind_pools in pools.items()}
+                    if "eva" in pools:
+                        # the pooled rows of every chunk, a page at a
+                        # time: a whole window's into the table's prefix,
+                        # the last window's into the pending pages, the
+                        # rest (no whole chunk's) into the trash page
+                        pools["eva"] = tuple(
+                            scatter("eva", pool, made("eva", 2 + i),
+                                    loop_pass, tables["eva_pooled"])
+                            for i, pool in enumerate(pools["eva"]))
                 if split < L:
                     x, pools, rows = self._plan_token_layers(
                         fam, stacks, last_rows(x, lengths)[:, None],
@@ -1532,8 +1648,11 @@ class InferenceEngine:
                 params, x, (pools, jnp.zeros((), jnp.float32)),
                 one_pass, (lambda x: last_rows(x, lengths)) if split == L
                 else (lambda x: x[:, 0]))
-            nxt = self._sample(fam.head(params, h), rng)
-            return self._with_held(nxt, held, exit_pass), pools
+            logits = fam.head(params, h)
+            nxt = self._with_held(self._sample(logits, rng), held, exit_pass)
+            if fam.pred_heads > 1:
+                return nxt, pools, logits
+            return nxt, pools
 
         fn = jax.jit(planned_prefill, donate_argnums=(5,))
         self._compiled[key] = fn
@@ -1573,9 +1692,13 @@ class InferenceEngine:
                 one_pass, lambda x: x[:, 0])
             # one shape for every bucket's tokens: what the next decode
             # (of any bucket) gathers from
-            nxt = jnp.pad(self._sample(fam.head(params, h), rng),
-                          (0, width - batch))
-            return self._with_held(nxt, held, exit_pass), pools
+            logits = fam.head(params, h)
+            nxt = self._with_held(jnp.pad(
+                self._sample(logits, rng), (0, width - batch)), held,
+                exit_pass)
+            if fam.pred_heads > 1:
+                return nxt, pools, logits
+            return nxt, pools
 
         B_ = self.block
 
@@ -2108,6 +2231,13 @@ class InferenceEngine:
                 scalars[SPEC_ACCEPTANCE_RATE] = \
                     self.stats["spec_accepted"] / \
                     max(self.stats["spec_proposed"], 1)
+            if self.eva_window:
+                scalars[EVA_WINDOWS_ROLLED] = float(
+                    self.stats["eva_windows_rolled"])
+                scalars[EVA_PAGES_RELEASED] = float(
+                    self.stats["eva_pages_released"])
+                scalars[EVA_PENDING_PAGES] = float(
+                    self.stats["eva_pending_pages"])
             if self.role != "unified":
                 for key in ("handoff_sent", "handoff_acked",
                             "handoff_rejected", "handoff_expired",
@@ -2124,6 +2254,8 @@ class InferenceEngine:
         sc = self.scheduler.status_counts
         self.stats["window_pages_released"] = \
             self.scheduler.window_pages_released
+        self.stats["eva_windows_rolled"] = self.scheduler.eva_windows_rolled
+        self.stats["eva_pages_released"] = self.scheduler.eva_pages_released
         self.stats["requests_ok"] = sc["ok"]
         self.stats["requests_deadline_exceeded"] = sc["deadline_exceeded"]
         self.stats["requests_failed"] = sc["failed"]
@@ -2656,13 +2788,17 @@ class InferenceEngine:
                     tokens[i, :len(ctx)] = ctx
                     lengths[i] = len(ctx)
                 self._count_moe_rows("prefill", int(lengths.sum()), B * S)
+                if self.eva_window:
+                    self.stats["eva_prefill_pairs"] += sum(
+                        self._eva_pairs(int(n)) for n in lengths)
                 self.stats["prefill_rows"] += B * S
                 self.stats["prefill_rows_cross"] += B * (
                     S if self.family.last_row_from ==
                     self.model.config.num_layers else 1)
                 args = [jnp.asarray(tokens), jnp.asarray(lengths),
                         jax.device_put(self._tables(
-                            plan.prefills, B, S // self.page_size))]
+                            plan.prefills, B, S // self.page_size,
+                            prefill=lengths))]
             fn = self._prefill_fn(B, S)
         # the chunk program takes and returns the full kind's K and V apart
         chunk = plan.prefill_kind == "chunk"
@@ -2672,7 +2808,10 @@ class InferenceEngine:
                            *(pools["full"] if chunk else (pools,)),
                            self._next_rng())
             self._rebind_pools({"full": tuple(out)} if chunk else out[0])
-        self._enqueued("prefill", plan.prefills, nxt)
+        # a model with several prediction heads: their logits behind the
+        # pools
+        self._enqueued("prefill", plan.prefills, nxt,
+                       out[1] if not chunk and len(out) > 1 else None)
         if self.spec_k:
             self._draft_prefill_twin(plan.prefills, B, S)
 
@@ -2696,10 +2835,11 @@ class InferenceEngine:
                 lengths[i] = req.cached + req.pending + 1
             tables = self._tables(plan.decodes, B, self.n_pages_max)
             self.stats["decode_steps"] += 1
-            self.stats["decode_kv_tokens"] += int(lengths.sum())
-            self.stats["kv_page_steps_latent" if self.latent
-                       else "kv_page_steps_full"] += int(
-                (-(-lengths // self.page_size)).sum())
+            if not self.eva_window:
+                self.stats["decode_kv_tokens"] += int(lengths.sum())
+                self.stats["kv_page_steps_latent" if self.latent
+                           else "kv_page_steps_full"] += int(
+                    (-(-lengths // self.page_size)).sum())
             if self.latent:
                 self.stats["decode_kv_tokens_latent"] += int(lengths.sum())
             if self.window:
@@ -2707,6 +2847,23 @@ class InferenceEngine:
                     np.minimum(lengths, self.window).sum())
                 self.stats["kv_page_steps_window"] += int(
                     np.count_nonzero(tables["window"]))
+            if self.eva_window:
+                # what the paged kernel reads, by population: the window's
+                # rows up to the token, the pooled rows before the window
+                pos = lengths[:len(plan.decodes)] - 1
+                read = int(self.scheduler.eva_length(pos).sum())
+                rows = int((pos % self.eva_window + 1).sum())
+                self.stats["decode_kv_tokens_eva_window"] += rows
+                self.stats["decode_kv_tokens_eva_summary"] += read - rows
+                # `decode_kv_tokens` stays the rows the kernel READS; the
+                # contexts those stand for are counted beside it
+                self.stats["decode_kv_tokens"] += read
+                self.stats["decode_context_tokens_eva"] += int(
+                    lengths.sum())
+                self.stats["eva_chunks_pooled"] += int(np.count_nonzero(
+                    pos % self.eva_chunk == self.eva_chunk - 1))
+                self.stats["eva_pending_pages"] = sum(
+                    len(r.eva_pending) for r in self.scheduler.running)
             if self.state_cache is not None:
                 self.stats["state_slots_in_use"] = self.state_cache.in_use
                 self.stats["state_slot_steps"] += len(plan.decodes)
@@ -2733,14 +2890,15 @@ class InferenceEngine:
         fn = self._decode_fn(plan.decode_batch)
         self.timeline.enqueued(key)
         with self._phase("dispatch"):
-            nxt, pools = fn(
+            nxt, pools, *logits = fn(
                 self.params, self.params_stacked, *args, self._pools(),
                 self._next_rng(), self._carry, src)
             self._rebind_pools(pools)
         if prev is not None:
             self.stats["lookahead_steps"] += 1
         self._carry = nxt
-        return self._enqueued("decode", plan.decodes, nxt)
+        return self._enqueued("decode", plan.decodes, nxt,
+                              logits[0] if logits else None)
 
     def _dispatch_block_decode(self, plan):
         """`_dispatch_decode` of a block-generating model: one pass over
@@ -2873,11 +3031,14 @@ class InferenceEngine:
         if self.state_cache is not None:
             self.state_cache.conv, self.state_cache.ssm = pools["state"]
 
-    def _tables(self, reqs, batch, width):
+    def _tables(self, reqs, batch, width, prefill=None):
         """{cache kind: page table [batch, width]} of the rows `reqs`, on
         the host: a request's pages of that kind (the window kind's are
         `Request.window_pages`, those inside `width`), the rest the trash
-        page 0."""
+        page 0. A chunk-pooled (eva) model's: `_eva_tables` (`prefill`:
+        each row's prompt length, where the tables are a prefill's)."""
+        if self.eva_window:
+            return self._eva_tables(reqs, batch, width, prefill)
         tables = {kind: np.zeros((batch, width), np.int32)
                   for kind in self.caches}
         for kind, table in tables.items():
@@ -2891,8 +3052,52 @@ class InferenceEngine:
             tables["state"][:len(reqs)] = [r.state_slot for r in reqs]
         return tables
 
-    def _enqueued(self, phase, reqs, tokens):
-        rec = _InFlight(next(self._dispatched), phase, list(reqs), tokens)
+    def _eva_pairs(self, n):
+        """(query, key) pairs a chunk-pooled prefill of `n` tokens scores
+        a head and layer: each query's own window up to itself and one
+        pooled row a chunk of the windows before it."""
+        W, per_win = self.eva_window, self.eva_window // self.eva_chunk
+        full, rest = divmod(n, W)
+        return full * W * (W + 1) // 2 + W * per_win * full * (full - 1) // 2 \
+            + rest * (rest + 1) // 2 + rest * per_win * full
+
+    def _eva_tables(self, reqs, batch, width, prefill):
+        """A chunk-pooled model's tables. A decode step's: `eva` [batch,
+        width], each request's table as it is (`Request.pages`: the pooled
+        rows' pages of its ended windows, then its window's), and
+        `eva_pending` [batch, pages a window's pooled rows fill], the
+        pages that take the rows pooled now. A prefill's, of prompts of
+        `prefill` tokens in a bucket of `width` pages: `eva` [batch, a
+        window's pages], the pages of the prompt's last, partial window
+        (the program hands that window's rows alone to the scatter; all
+        trash where the prompt is whole windows), and `eva_pooled` [batch,
+        width / chunk] by CHUNK: the table's prefix, then the pending
+        pages for the last window's chunks, trash behind them."""
+        sch = self.scheduler
+        per_win, win = sch.eva_pages_summary, sch.eva_pages_window
+        if prefill is None:
+            tables = {"eva": np.zeros((batch, width), np.int32),
+                      "eva_pending": np.zeros((batch, per_win), np.int32)}
+            for i, req in enumerate(reqs):
+                tables["eva"][i, :len(req.pages)] = req.pages
+                tables["eva_pending"][i] = req.eva_pending
+            return tables
+        tables = {"eva": np.zeros((batch, min(win, width)), np.int32),
+                  "eva_pooled": np.zeros(
+                      (batch, width // self.eva_chunk), np.int32)}
+        for i, req in enumerate(reqs):
+            ended = int(prefill[i]) // self.eva_window
+            pooled = (req.pages[:per_win * ended] + req.eva_pending)[
+                :tables["eva_pooled"].shape[1]]
+            tables["eva_pooled"][i, :len(pooled)] = pooled
+            if int(prefill[i]) % self.eva_window:
+                rows = req.pages[per_win * ended:]
+                tables["eva"][i, :len(rows)] = rows
+        return tables
+
+    def _enqueued(self, phase, reqs, tokens, logits=None):
+        rec = _InFlight(next(self._dispatched), phase, list(reqs), tokens,
+                        logits)
         self.stats["loop_passes"] += self.loop_steps
         for req in reqs:
             req.owed.append(rec.serial)
@@ -2933,6 +3138,12 @@ class InferenceEngine:
                     for i, _, live in rec.rows():
                         if live:
                             self.loop_exit_hist[int(exits[i]) - 1] += 1
+                if self.head_trace is not None and rec.logits is not None:
+                    heads = np.asarray(rec.logits)
+                    self.head_trace += [
+                        {"request": req.request_id, "logits": heads[i],
+                         "at": len(req.prompt) + len(req.generated)}
+                        for i, req, live in rec.rows() if live]
                 with self._phase("complete"):
                     if rec.phase == "prefill":
                         self._complete_prefills(rec, nxt, now)

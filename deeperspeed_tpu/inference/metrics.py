@@ -51,6 +51,13 @@ PREFIX_PAGES_SHARED = "Serve/prefix_cache/pages_shared"
 PREFIX_SAVED_PREFILL_TOKENS = "Serve/prefix_cache/saved_prefill_tokens"
 SPEC_ACCEPTANCE_RATE = "Serve/speculative/acceptance_rate"
 
+# a chunk-pooled (eva) model's gauges (docs/inference.md "Chunk-pooled
+# pages"), recorded every step beside the saturation series: tables
+# rolled at a window's end, the pages those gave back, pending pages held
+EVA_WINDOWS_ROLLED = "Serve/eva/windows_rolled"
+EVA_PAGES_RELEASED = "Serve/eva/pages_released"
+EVA_PENDING_PAGES = "Serve/eva/pending_pages"
+
 # per-terminal-status request counters (admission.REQUEST_STATUSES):
 # the engine records these every step as monitor scalars, so they ride
 # the single buffered drain into EVERY export backend — latest-value

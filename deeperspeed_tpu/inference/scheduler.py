@@ -47,6 +47,18 @@ lands through `complete_block`: the newly final tokens are the contiguous
 unmasked prefix of the block, so `generated` grows left to right whatever
 order the rows were unmasked in.
 
+A model whose layers are chunk-pooled (`eva_window` > 0; docs/inference.md
+"Chunk-pooled pages") keeps TWO populations of rows in the one pool, and
+`Request.pages` is the table a query reads: the pooled rows of every
+window that has ENDED (`eva_window / eva_chunk` rows, a whole number of
+pages, a window), then the exact rows of the current window. The pooled
+rows of the current window's closed chunks collect in `Request.eva_pending`,
+pages outside the table. When the position a step writes is the first of
+a new window the table ROLLS (`_eva_roll`): the ended window's pages go
+back to the allocator, the pending pages join the table's prefix and fresh
+ones take their place. A dispatched step holds the table it was built
+with, and whatever takes a freed page next is enqueued behind it.
+
 Token accounting uses PADDED bucket sizes, not raw prompt lengths: the
 budget is a compute bound, and compute is spent at compiled shapes.
 The budget must cover the largest user prefill bucket (validated at
@@ -88,6 +100,7 @@ Serving-speedup layer (docs/inference.md "Prefix/radix cache" +
   rolls tail pages the next window cannot reach back to the allocator.
 """
 
+import contextlib
 import math
 from collections import deque
 from dataclasses import dataclass, field
@@ -95,6 +108,8 @@ from dataclasses import dataclass, field
 from .admission import (DeadlineExceeded, PRIORITY_RANK, STATUS_DEADLINE,
                         STATUS_FAILED, STATUS_OK)
 from .kv_cache import pages_for_tokens
+
+_NO_SPAN = contextlib.nullcontext()
 
 WAITING = "waiting"
 RUNNING = "running"
@@ -123,6 +138,11 @@ class Request:
     # behind the window (never taken at a prefill, given back as the
     # sequence grows)
     window_pages: list = field(default_factory=list)
+    # a chunk-pooled (eva) model's request: the pages, outside `pages`,
+    # that take the pooled rows of the current window as its chunks close,
+    # and the windows whose pooled rows `pages` starts with
+    eva_pending: list = field(default_factory=list)
+    eva_windows: int = 0
     # the request's slot of the recurrent-state cache kind (a model with
     # state-space layers; 0: none held, the trash slot)
     state_slot: int = 0
@@ -249,7 +269,8 @@ class ContinuousBatchingScheduler:
     def __init__(self, cache, max_seq_len, token_budget, max_batch_size,
                  prefill_lengths, prefill_batch_sizes, decode_batch_sizes,
                  prefix_cache=None, spec_tokens=0, window_cache=None,
-                 window=0, block=0, mask_token_id=0, state_cache=None):
+                 window=0, block=0, mask_token_id=0, state_cache=None,
+                 eva_window=0, eva_chunk=0, span=None):
         self.cache = cache
         # the recurrent-state cache kind (`kv_cache.StateCache`; a model
         # with state-space layers): a request holds one slot from its
@@ -283,12 +304,46 @@ class ContinuousBatchingScheduler:
         self.window = int(window)
         self.window_pages_released = 0
         if window_cache is not None and (
-                self.window < 1 or prefix_cache is not None or spec_tokens
-                or window_cache.page_size != cache.page_size):
-            raise ValueError(
-                "a window cache kind needs a window, the full kind's page "
-                "size, and neither a prefix cache nor speculation (a "
-                "shared or rolled-back page has no window counterpart)")
+                self.window < 1 or
+                window_cache.page_size != cache.page_size):
+            raise ValueError("a window cache kind needs a window and the "
+                             "full kind's page size")
+        # a chunk-pooled (eva) model: `cache` is its ONE pool, of exact
+        # rows and pooled rows (the module docstring). `span(name)`: the
+        # engine's host phase round the table's rewrite
+        self.eva_window, self.eva_chunk = int(eva_window), int(eva_chunk)
+        self.span = span or (lambda name: _NO_SPAN)
+        self.eva_windows_rolled = self.eva_pages_released = 0
+        self.eva_pages_window = self.eva_pages_summary = 0
+        if self.eva_window:
+            rows = self.eva_window // max(self.eva_chunk, 1)
+            if self.eva_chunk < 1 or self.eva_window % self.eva_chunk or \
+                    rows % cache.page_size or \
+                    cache.page_size % self.eva_chunk or \
+                    int(max_seq_len) % self.eva_window or any(
+                        int(b) % self.eva_window for b in prefill_lengths) \
+                    or block or window_cache is not None or \
+                    state_cache is not None:
+                raise ValueError(
+                    f"a chunk-pooled cache kind (window {self.eva_window}, "
+                    f"chunk {self.eva_chunk}, page {cache.page_size}) needs "
+                    f"a chunk that divides the page, a window's "
+                    f"{rows} pooled rows to fill whole pages, max_seq_len "
+                    f"{max_seq_len} and every prefill bucket "
+                    f"{list(prefill_lengths)} whole windows, and no other "
+                    f"cache kind beside it")
+            self.eva_pages_window = self.eva_window // cache.page_size
+            self.eva_pages_summary = rows // cache.page_size
+        for kind, met in (("window", window_cache is not None),
+                          ("eva", bool(self.eva_window))):
+            if met and (prefix_cache is not None or spec_tokens):
+                raise ValueError(
+                    f"a {kind} cache kind takes neither a prefix cache nor "
+                    f"speculation (a shared or rolled-back page has no "
+                    f"{kind} counterpart: the rows behind a window are "
+                    f"gone" + (", and what stands for them are pooled rows "
+                               "no other prompt's prefix shares)"
+                               if kind == "eva" else ")"))
         self.page_size = cache.page_size
         self.max_seq_len = int(max_seq_len)
         self.token_budget = int(token_budget)
@@ -521,6 +576,10 @@ class ContinuousBatchingScheduler:
         if request.window_pages:
             self.window_cache.free([p for p in request.window_pages if p])
             request.window_pages = []
+        if request.eva_pending:
+            self.cache.free(request.eva_pending)
+            request.eva_pending = []
+        request.eva_windows = 0
         if request.state_slot:
             self.state_cache.free(request.state_slot)
             request.state_slot = 0
@@ -555,6 +614,55 @@ class ContinuousBatchingScheduler:
             self.window_cache.free(behind)
             req.window_pages[:first] = [0] * first
             self.window_pages_released += len(behind)
+
+    # -- the chunk-pooled (eva) cache kind ------------------------------------
+
+    def eva_table_index(self, pos):
+        """Where, in a request's table, the page of position `pos` lies
+        once the table has rolled to `pos`'s window."""
+        window = pos // self.eva_window
+        return self.eva_pages_summary * window + \
+            (pos - window * self.eva_window) // self.page_size
+
+    def eva_length(self, pos):
+        """Rows the decode of position `pos` attends: the pooled rows of
+        the windows before its own, and its window's rows up to itself."""
+        window = pos // self.eva_window
+        return window * (self.eva_window // self.eva_chunk) + \
+            pos - window * self.eva_window + 1
+
+    def _eva_allocate(self, n_context):
+        """(table, pending pages) for a prefill of `n_context` tokens: the
+        pages of the pooled rows of its whole windows, those of its last,
+        partial window's rows up to the position the next decode writes,
+        and the pending pages. None if the pool cannot."""
+        last = min(n_context, self.max_seq_len - 1)
+        table = self.eva_table_index(last) + 1
+        got = self.cache.allocate(table + self.eva_pages_summary)
+        return None if got is None else (got[:table], got[table:])
+
+    def _eva_roll(self, req, pos):
+        """Roll `req`'s table to the window of position `pos`, the next a
+        decode writes: where that is the first of a new window, the ended
+        window's pages go back, its pooled rows (the pending pages) become
+        the table's next entries and fresh pages take their place. The
+        decode in flight read the old table, the one it was dispatched
+        with; whatever takes a freed page next is enqueued behind it. The
+        fresh pages come out of those just freed, so a roll cannot fail."""
+        while req.eva_windows < pos // self.eva_window:
+            with self.span("eva_roll"):
+                kept = self.eva_pages_summary * req.eva_windows
+                ended = req.pages[kept:]
+                if len(ended) != self.eva_pages_window:
+                    raise RuntimeError(
+                        f"request {req.request_id} rolls a window of "
+                        f"{len(ended)} pages, not {self.eva_pages_window}")
+                self.cache.free(ended)
+                req.pages = req.pages[:kept] + req.eva_pending
+                req.eva_pending = self.cache.allocate(self.eva_pages_summary)
+                req.eva_windows += 1
+                self.eva_windows_rolled += 1
+                self.eva_pages_released += len(ended)
 
     def _finish(self, request, status, error=None):
         """The ONLY exit gate: pull the request out of whatever
@@ -745,6 +853,10 @@ class ContinuousBatchingScheduler:
             if self.block:
                 # the last row the next pass may write
                 pos = self.block_ends(req)[1] - 1
+            if self.eva_window:
+                # the table's entry of `pos`, once rolled to its window
+                self._eva_roll(req, pos)
+                pos = self.eva_table_index(pos) * self.page_size
             if not self._grow_pages(req, self.cache, req.pages, pos,
                                     evicted, now):
                 continue
@@ -862,8 +974,15 @@ class ContinuousBatchingScheduler:
             if self.state_cache is not None and \
                     not self.state_cache.num_free:
                 break                      # every kind or none: no slot
-            pages = self.cache.allocate(pages_for_tokens(row_len,
-                                                         self.page_size))
+            if self.eva_window:
+                got = self._eva_allocate(len(req.context))
+                if got is None:
+                    break
+                pages, req.eva_pending = got
+                req.eva_windows = len(req.context) // self.eva_window
+            else:
+                pages = self.cache.allocate(pages_for_tokens(
+                    row_len, self.page_size))
             if pages is None:
                 break                      # pool full: wait for completions
             if self.window_cache is not None:

@@ -55,7 +55,14 @@ class LayerSpec:
     kind); `gmu`, a gated memory unit on the scan output of the nearest
     `ssm` layer before it, of the same token; `cross`, attention with the
     layer's own queries over the K and V of the model's ONE `full` layer,
-    which lies before it. `heads` is 0 for `ssm` and `gmu`."""
+    which lies before it. `heads` is 0 for `ssm` and `gmu`.
+
+    `eva` (EVA attention, arXiv:2302.04542, as a byte model uses it): exact
+    causal attention inside the query's own window of
+    `GPTNeoXConfig.eva_window` positions and, in the same softmax, ONE
+    pooled key and value for each chunk of `eva_chunk` positions of every
+    EARLIER window (`eva_attention`); its cache kind holds both populations
+    of rows in one page pool (docs/inference.md "Chunk-pooled pages")."""
     attn: str = "full"
     heads: int = 0
     rotary_pct: float = 1.0
@@ -251,6 +258,17 @@ class GPTNeoXConfig:
     ssm_state: int = 0
     ssm_conv: int = 0
     ssm_dt_rank: int = 0
+    # an `eva` layer's facts: the window inside which attention is exact
+    # and the chunk whose rows ONE pooled key and value stand for once
+    # their window has ended (0: the plan has no such layer),
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # an RMS norm whose scale is 1 + w (the leaf holds w, zero at init),
+    norm_unit_offset: bool = False
+    # and a head of `num_pred_heads` x `vocab_size` logits a position:
+    # head m is the distribution of token t + 1 + m. Greedy decoding
+    # reads head 0; every head is computed.
+    num_pred_heads: int = 1
 
     @property
     def head_dim(self):
@@ -347,8 +365,8 @@ class GPTNeoXConfig:
         h, d, G = self.hidden_size, self.head_dim, self.kv_heads
         E = self.experts_held if held else self.moe_num_experts
         ln = h * (2 if self.norm == "layernorm" else 1)
-        total = self.vocab_size * h * \
-            (1 if self.tie_word_embeddings else 2) + ln
+        total = self.vocab_size * h * (
+            1 if self.tie_word_embeddings else 1 + self.num_pred_heads) + ln
         if self.loop_steps > 1:
             total += h + 1              # the exit gate; the loop's weights once
         bias = 1 if self.use_bias else 0
@@ -372,6 +390,8 @@ class GPTNeoXConfig:
                     bias * (spec.heads * d + 2 * G * d + h) + diff
                 if self.qk_norm == "head":
                     attn += 2 * d
+                if spec.attn == "eva":
+                    attn += 2 * spec.heads * d      # phi and mu
             if self.attn_gate == "per-head":
                 attn += h * spec.heads
             if spec.ffn == "dense":
@@ -477,7 +497,9 @@ class GPTNeoXConfig:
              ("sublayer_out_norm", False), ("loop_steps", 1),
              ("loop_exit_threshold", 1.0), ("generation_block", 0),
              ("attn_diff", False), ("ssm_inner", 0), ("ssm_state", 0),
-             ("ssm_conv", 0), ("ssm_dt_rank", 0))
+             ("ssm_conv", 0), ("ssm_dt_rank", 0), ("eva_window", 0),
+             ("eva_chunk", 0), ("norm_unit_offset", False),
+             ("num_pred_heads", 1))
             if getattr(self, k) != plain]
         if self.qk_norm not in (False, True, "head"):
             raise NotImplementedError(
@@ -518,8 +540,10 @@ class GPTNeoXConfig:
                     f"expert held, no latent attention, no "
                     f"next-token-prediction block, no norm on a "
                     f"sublayer's output, no loop, one token a step "
-                    f"under the causal mask, no differential attention "
-                    f"and no state-space layer")
+                    f"under the causal mask, no differential attention, "
+                    f"no state-space layer, no chunk-pooled (eva) "
+                    f"attention, a norm scale without a unit offset and "
+                    f"one prediction head")
             return
         if len(plan) != self.num_layers:
             raise ValueError(f"layer_plan names {len(plan)} layers, "
@@ -546,7 +570,7 @@ class GPTNeoXConfig:
                 raise NotImplementedError(
                     f"layer {i}: attention {spec.attn!r} / FFN "
                     f"{spec.ffn!r}; the kinds are full | window | latent | "
-                    f"ssm | gmu | cross and dense | experts")
+                    f"ssm | gmu | cross | eva and dense | experts")
             if spec.attn == "latent":
                 self._check_latent(i, spec)
             elif spec.attn in ("ssm", "gmu"):
@@ -574,6 +598,7 @@ class GPTNeoXConfig:
         self._check_loop()
         self._check_generation_block()
         self._check_shared()
+        self._check_eva()
         if self.moe_held:
             lo, hi = self.moe_held
             if not 0 <= lo < hi <= self.moe_num_experts:
@@ -633,6 +658,54 @@ class GPTNeoXConfig:
                 f"attention, with {', '.join(held) or 'an experts layer'}: "
                 f"such a plan is computed run once, a token a step, with "
                 f"dense FFNs")
+
+    def _check_eva(self):
+        """An `eva` layer's facts, the unit-offset norm and the prediction
+        heads, each refused by name where the code has no path."""
+        plan = self.layer_plan
+        W, C = self.eva_window, self.eva_chunk
+        if self.norm_unit_offset and self.norm != "rmsnorm":
+            raise NotImplementedError(
+                "norm_unit_offset with norm='layernorm': the scale 1 + w is "
+                "computed for the RMS norm")
+        if self.num_pred_heads < 1 or (self.num_pred_heads > 1 and
+                                       self.tie_word_embeddings):
+            raise ValueError(
+                f"num_pred_heads={self.num_pred_heads} with "
+                f"tie_word_embeddings={self.tie_word_embeddings}: at least "
+                f"one head, and a head of several is no embedding's "
+                f"transpose")
+        eva = [i for i, s in enumerate(plan) if s.attn == "eva"]
+        if not eva:
+            if W or C:
+                raise ValueError(f"eva_window={W}, eva_chunk={C} without an "
+                                 f"eva layer in the plan")
+            return
+        if W < 1 or C < 1 or W % C:
+            raise ValueError(
+                f"an eva layer needs a window and a chunk that divides it, "
+                f"got eva_window={W}, eva_chunk={C}")
+        if len(eva) != len(plan):
+            raise NotImplementedError(
+                f"eva layers {eva} beside layers of another attention kind "
+                f"in one plan: the serving programs carry ONE page pool "
+                f"whose table the window's end rewrites")
+        if any(plan[i].heads != self.kv_heads for i in eva):
+            raise NotImplementedError(
+                f"an eva layer of {plan[eva[0]].heads} query heads over "
+                f"{self.kv_heads} KV heads: a chunk is pooled by its KV "
+                f"head's own phi and mu, one query head a KV head")
+        held = [f"{k}={getattr(self, k)!r}" for k, plain in
+                (("loop_steps", 1), ("mtp_layers", 0),
+                 ("generation_block", 0), ("sublayer_out_norm", False),
+                 ("attn_diff", False), ("attn_gate", "none"),
+                 ("qk_norm", False), ("use_bias", False))
+                if getattr(self, k) != plain]
+        if held:
+            raise NotImplementedError(
+                f"an eva layer with {', '.join(held)}: chunk-pooled "
+                f"attention is computed run once, a token a step, with "
+                f"plain rotary queries and keys, no bias and no gate")
 
     def _check_loop(self):
         """A looped model's facts, and the norm on a sublayer's output."""
@@ -741,7 +814,7 @@ class GPTNeoXConfig:
 
 
 # what takes the attention's place in a planned layer (`LayerSpec.attn`)
-MIXERS = ("full", "window", "latent", "ssm", "gmu", "cross")
+MIXERS = ("full", "window", "latent", "ssm", "gmu", "cross", "eva")
 
 
 def diff_lambda_init(layer):
@@ -760,7 +833,9 @@ def _dense_init(key, shape, dtype, scale=0.02):
 def init_norm_params(cfg):
     """A norm's leaves: scale, and LayerNorm's bias."""
     h, dt = cfg.hidden_size, cfg.param_dtype
-    p = {"scale": jnp.ones((h,), dt)}
+    # a unit-offset scale holds w of 1 + w
+    p = {"scale": jnp.zeros((h,), dt)
+         if getattr(cfg, "norm_unit_offset", False) else jnp.ones((h,), dt)}
     if getattr(cfg, "norm", "layernorm") == "layernorm":
         p["bias"] = jnp.zeros((h,), dt)
     return p
@@ -873,7 +948,8 @@ def init_stack_params(cfg, spec, n, key, layers=None):
     [h, 2*G*d] ([K | V], each G heads of d), `out_w` [H*d, h], with
     `qk_norm='head'` the scales `q_norm`, `k_norm` [d] of the norm on each
     head of q and k, and with
-    a per-head gate `gate_w` [h, H]; a latent layer's seven leaves:
+    a per-head gate `gate_w` [h, H]; an `eva` layer's pooling leaves
+    `eva_phi`, `eva_mu` [H, d]; a latent layer's seven leaves:
     `q_a` [h, q_rank], `q_a_norm` [q_rank], `q_b` [q_rank, H*(nope+rope)]
     (a head's [nope | rope]), `kv_a` [h, kv_rank+rope] ([c_kv | k_r]),
     `kv_a_norm` [kv_rank], `kv_b` [kv_rank, H*(nope+v)] (a head's
@@ -919,6 +995,11 @@ def init_stack_params(cfg, spec, n, key, layers=None):
             attn = {k: v for k, v in attn.items() if not k.startswith("kv")}
         if cfg.qk_norm == "head":
             attn.update(q_norm=jnp.ones((n, d), dt), k_norm=jnp.ones((n, d), dt))
+        if spec.attn == "eva":
+            # phi at the keys' own spread, so that the pooling softmax is
+            # no plain mean; mu a fifth of it, so that a mu left out shows
+            attn.update(eva_phi=_stack_init(ks[3], (n,), (H, d), dt, 1.0),
+                        eva_mu=_stack_init(ks[9], (n,), (H, d), dt, 0.25))
         if cfg.attn_diff:
             for i, name in enumerate(("lam_q1", "lam_k1", "lam_q2",
                                       "lam_k2")):
@@ -957,7 +1038,8 @@ def init_stack_params(cfg, spec, n, key, layers=None):
             mlp["shared_in"] = _stack_init(ks[7], (n,), (h, 2 * sw), dt)
             mlp["shared_out"] = _stack_init(ks[8], (n,), (sw, h), dt,
                                             out_scale)
-    ln = {"scale": jnp.ones((n, h), dt)}
+    ln = {"scale": jnp.zeros((n, h), dt) if cfg.norm_unit_offset
+          else jnp.ones((n, h), dt)}
     if cfg.norm == "layernorm":
         ln["bias"] = jnp.zeros((n, h), dt)
     norms = {"ln_attn": ln, "ln_mlp": dict(ln)}
@@ -999,8 +1081,10 @@ def init_params(cfg, rng):
             "final_ln": init_norm_params(cfg),
         }
         if not cfg.tie_word_embeddings:
+            # head m's rows are [m * vocab, (m + 1) * vocab)
             params["embed_out"] = {"wte": _dense_init(
-                keys[-1], (cfg.vocab_size, cfg.hidden_size), dt)}
+                keys[-1], (cfg.num_pred_heads * cfg.vocab_size,
+                           cfg.hidden_size), dt)}
         if cfg.mtp_layers:
             params["mtp"] = init_mtp_params(cfg, jax.random.fold_in(rng, 1))
         if cfg.loop_steps > 1:
@@ -1082,7 +1166,10 @@ def rms_norm(x, scale, eps):
 def norm(cfg, p, x):
     """The model's own norm (`cfg.norm`) with the leaves `p`."""
     if getattr(cfg, "norm", "layernorm") == "rmsnorm":
-        return rms_norm(x, p["scale"], cfg.layernorm_eps)
+        scale = p["scale"]
+        if getattr(cfg, "norm_unit_offset", False):
+            scale = 1.0 + scale.astype(jnp.float32)
+        return rms_norm(x, scale, cfg.layernorm_eps)
     return layer_norm(x, p["scale"], p["bias"], cfg.layernorm_eps)
 
 
@@ -1418,6 +1505,82 @@ def latent_absorb_out(cfg, params, u):
     return jnp.einsum("bhk,khv->bhv", u, w_uv.astype(u.dtype))
 
 
+def eva_attention(cfg, attn_p, q, k, v, real, core):
+    """An `eva` layer's attention over whole rows (training's forward, a
+    serving prefill): q, k, v [B, S, H, D] (k after the rotary), `real`
+    [B, S] the rows that are tokens (None: all), `core(q, k, v,
+    segment_ids)` the causal attention of rows of one segment.
+
+    Query t, in window j = t // W, sees the exact rows of its own window
+    up to itself and ONE pooled row (`ops.pallas.eva.eva_pool`) for each
+    chunk of C rows of every EARLIER window, all under one softmax. That
+    is plain causal attention over the rows [pooled rows of windows < j |
+    window j's rows], so each window becomes a sequence of its own:
+    P slots of pooled rows (segment 1 where the chunk lies in an earlier
+    window, 0 where it is padding), then the window's W rows, through the
+    segmented kernel every prefill runs. The tiles of a padding slot are
+    skipped there; the queries that ride in the pooled slots are zeros
+    whose output is dropped. Returns (out [B, S, H, D], (K~, V~) [B, S / C,
+    H, D] of EVERY chunk, whole or not: the caller keeps the whole ones)."""
+    from ..ops.pallas.eva import eva_pool
+    B, S, H, D = q.shape
+    W, C = cfg.eva_window, cfg.eva_chunk
+    scale = cfg.attn_scale or 1.0 / math.sqrt(D)
+    if real is None:
+        real = jnp.ones((B, S), jnp.bool_)
+    n_win = -(-S // W)
+    pad = n_win * W - S
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+        real = jnp.pad(real, ((0, 0), (0, pad)))
+    with scopes.scope("ds.eva_summarize"):
+        # the rows as the pages hold them: a decode step pools what it
+        # reads back from a page, so a prefill pools the same numbers
+        # (XLA would else fuse the projection's unrounded output in)
+        bits = jnp.finfo(k.dtype)
+        held = (jax.lax.reduce_precision(t, bits.nexp, bits.nmant).reshape(
+            B, -1, C, H, D) for t in (k, v))
+        pooled = tuple(t.astype(q.dtype) for t in eva_pool(
+            *held, attn_p["eva_phi"], attn_p["eva_mu"], scale))
+    with scopes.scope("ds.eva_prefill"):
+        per_win = W // C
+        if n_win == 1:
+            out = core(q, k, v, real.astype(jnp.int32))
+        else:
+            # slots for the pooled rows of n_win - 1 windows, in a unit
+            # the attention kernel's blocks divide
+            unit = min(512, W)
+            P = -(-(n_win - 1) * per_win // unit) * unit
+            slot = jnp.arange(P)
+            visible = slot[None, :] < per_win * jnp.arange(n_win)[:, None]
+
+            def windows(rows, prefix):
+                """rows [B, n_win * W, ...] as n_win sequences a batch row,
+                each behind the `prefix` [B, P, ...] all of them share."""
+                rows = rows.reshape(B, n_win, W, *rows.shape[2:])
+                prefix = jnp.broadcast_to(
+                    prefix[:, None], (B, n_win, *prefix.shape[1:]))
+                return jnp.concatenate([prefix, rows], axis=2).reshape(
+                    B * n_win, P + W, *rows.shape[3:])
+
+            def slots(t):
+                have = t.shape[1]
+                return jnp.pad(t, ((0, 0), (0, max(P - have, 0)), (0, 0),
+                                   (0, 0)))[:, :P]
+
+            seg = jnp.concatenate(
+                [jnp.broadcast_to(visible[None], (B, n_win, P)),
+                 real.reshape(B, n_win, W)], axis=2).astype(
+                     jnp.int32).reshape(B * n_win, P + W)
+            out = core(windows(q, jnp.zeros((B, P, H, D), q.dtype)),
+                       windows(k, slots(pooled[0])),
+                       windows(v, slots(pooled[1])), seg)
+            out = out.reshape(B, n_win, P + W, H, D)[:, :, P:].reshape(
+                B, n_win * W, H, D)
+    return out[:, :S], pooled
+
+
 @scopes.scoped("ds.attn_diff")
 def diff_queries(q):
     """Differential attention on the kernels every attention uses: pair
@@ -1705,6 +1868,10 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     what `return_kv` hands back is (its latent rows [B, S, kv_rank +
     rope],): the cache's one pool's.
 
+    An `eva` layer's attention is `eva_attention` around the same core,
+    and its `return_kv` is (k, v, K~, V~): its rows and every chunk's
+    pooled row.
+
     `shared` (a plan whose layers read what another made,
     `GPTNeoXConfig.plan_shares`): {"mem": the memory of the nearest ssm
     layer before this one, "kv": the K and V of the plan's full layer}.
@@ -1743,7 +1910,17 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     with scopes.scope("ds.attn"):
         if diff:
             q = diff_queries(q)
-        if attn_fn is not None:
+        if kind == "eva":
+            def core(q, k, v, seg):
+                if attn_fn is not None:
+                    return attn_fn(q, k, v, segment_ids=seg)
+                return causal_attention(q, k, v, use_pallas=use_pallas,
+                                        segment_ids=seg)
+            attn, pooled = eva_attention(
+                cfg, params["attn"], q, k, v,
+                None if segment_ids is None else segment_ids > 0, core)
+            kv = kv + pooled
+        elif attn_fn is not None:
             attn = attn_fn(q, k, v) if segment_ids is None else \
                 attn_fn(q, k, v, segment_ids=segment_ids)
         else:
@@ -2104,6 +2281,11 @@ def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids,
             "packed rows (segment_ids) through a plan with an ssm, gmu or "
             "cross layer: the scan and the convolution do not start anew "
             "at a document's edge; one prompt a row is computed")
+    if segment_ids is not None and cfg.eva_window:
+        raise NotImplementedError(
+            "packed rows (segment_ids) through an eva layer: its windows "
+            "and chunks are counted from the row's start; one prompt a row "
+            "is computed")
     with scopes.scope("ds.embed"):
         x = params["embed"]["wte"][tokens]
     rotary = plan_rotary(cfg, S)
@@ -2458,7 +2640,8 @@ class GPTNeoX:
                 f"a stack looped over its weights, generation by blocks, "
                 f"whose masking schedule no configuration key gives, a "
                 f"state-space layer, whose scan has no backward, "
-                f"differential attention) "
+                f"differential attention, chunk-pooled (eva) attention, "
+                f"whose pooling has no backward) "
                 f"is not built; the flash backward, the parameter specs "
                 f"and the pipeline layers are the homogeneous block's. "
                 f"InferenceEngine serves it (`loss_fn` alone computes a "

@@ -65,6 +65,16 @@ SCOPES = {
                               "batch: each row's recurrent state read "
                               "from its slot, updated and written back "
                               "in place"),
+    "ds.eva_summarize": ("kernel", "an eva layer's pooling: a chunk's rows "
+                                   "to ONE key and ONE value under a "
+                                   "softmax over the chunk, in float32. A "
+                                   "decode step's closing rows: the gather "
+                                   "of their chunks from the window's "
+                                   "pages and the kernel of this name, "
+                                   "which pools and writes the row into a "
+                                   "pending page in place; a prefill's: "
+                                   "every chunk of the prompt at once, a "
+                                   "fusion"),
     "ds.adam": ("kernel", "fused Adam over a flat shard"),
     "ds.sparse_attn_fwd": ("kernel", "block-sparse attention forward"),
     "ds.sparse_attn_bwd_dkv": ("kernel", "block-sparse backward, dk/dv"),
@@ -111,6 +121,12 @@ SCOPES = {
                                "for the kernel, lambda, the subtraction, "
                                "the norm over a pair's features and its "
                                "scale"),
+    "ds.eva_prefill": ("region", "an eva layer's attention over a prompt: "
+                                 "each window's rows laid behind the "
+                                 "pooled rows of the windows before it as "
+                                 "one sequence, the flash forward over "
+                                 "them (`ds.flash_fwd`, inside), the "
+                                 "window's rows taken back out"),
     "ds.moe_shared": ("region", "the shared expert every token passes "
                                 "through, beside the routed ones"),
     "ds.attn_xla": ("region", "the XLA fallback of attention"),

@@ -77,6 +77,16 @@ LATENT_CFG = GPTNeoXConfig(
     moe_num_experts=8, moe_top_k=2, moe_dropless=True,
     moe_norm_topk_prob=True, moe_router_score="sigmoid",
     moe_expert_width=64, moe_shared_width=64, moe_routing_scale=1.8)
+# a chunk-pooled plan (EvaByte's): every layer exact inside a window of
+# 128 and one pooled row a chunk of 4 behind it, a unit-offset norm, eight
+# prediction heads
+EVA_CFG = GPTNeoXConfig(
+    vocab_size=320, hidden_size=128, num_layers=2, num_heads=2,
+    max_seq_len=256, use_parallel_residual=False, norm="rmsnorm",
+    use_bias=False, hidden_act="silu", ffn_gated=True, ffn_width=64,
+    tie_word_embeddings=False,
+    layer_plan=(LayerSpec(attn="eva", heads=2, ffn="dense"),) * 2,
+    eva_window=128, eva_chunk=4, norm_unit_offset=True, num_pred_heads=8)
 LATENT_SCOPES = MODEL_SCOPES + MOE_SCOPES + [
     "ds.mla_q", "ds.mla_kv", "ds.moe_shared", "ds.kv_write"]
 PROGRAMS = {
@@ -110,6 +120,12 @@ PROGRAMS = {
                                   "ds.paged_decode_window"],
     # a latent layer expands for prefill (the flash kernel the others
     # run) and absorbs for decode (its own kernel)
+    # a chunk-pooled layer: the flash forward inside its own region, the
+    # pooling a fusion in a prefill and a kernel in a decode step
+    "eva_prefill": MODEL_SCOPES + ["ds.eva_prefill", "ds.eva_summarize",
+                                   "ds.flash_fwd", "ds.kv_write"],
+    "eva_decode": MODEL_SCOPES + ["ds.eva_summarize", "ds.paged_decode",
+                                  "ds.kv_write"],
     "latent_prefill": LATENT_SCOPES + ["ds.mla_expand", "ds.flash_fwd"],
     "latent_decode": LATENT_SCOPES + ["ds.mla_absorb",
                                       "ds.paged_decode_latent"],
@@ -153,9 +169,16 @@ def serve_texts(kernel, cfg=None):
     pools = engine._pools()
 
     def text(fn, tokens, lengths, page_table, *carry):
+        tables = dict.fromkeys(engine.caches, page_table)
+        if engine.eva_window:
+            # a chunk-pooled model's tables (`InferenceEngine._eva_tables`)
+            reqs, width = [], page_table.shape[1]
+            tables = engine._eva_tables(
+                reqs, len(tokens), width, lengths if tokens.ndim == 2
+                else None)
         return fn.lower(engine.params, engine.params_stacked, tokens,
-                        lengths, dict.fromkeys(engine.caches, page_table),
-                        pools, rng, *carry).compile().as_text()
+                        lengths, tables, pools, rng,
+                        *carry).compile().as_text()
     prefill = text(engine._prefill_fn(1, 128), np.zeros((1, 128), np.int32),
                    np.ones((1,), np.int32), np.zeros((1, 8), np.int32))
     decode = text(engine._decode_fn(2), np.zeros((2,), np.int32),
@@ -183,6 +206,8 @@ def lower_all():
                                                               PLAN_CFG)
     texts["latent_prefill"], texts["latent_decode"] = serve_texts(
         "pallas", LATENT_CFG)
+    texts["eva_prefill"], texts["eva_decode"] = serve_texts("pallas",
+                                                            EVA_CFG)
     return texts
 
 
@@ -268,8 +293,8 @@ def pallas_calls():
 CALLS = pallas_calls()
 
 
-def test_all_eighteen_sites_are_found():
-    assert len(CALLS) == 18
+def test_all_nineteen_sites_are_found():
+    assert len(CALLS) == 19
 
 
 @pytest.mark.parametrize("where,name,fn,tree", CALLS,

@@ -30,11 +30,11 @@ import jax.numpy as jnp  # noqa: E402
 # `ops.pallas` re-exports several functions under their module's own
 # name (`flash_attention`, `grouped_matmul`, ...): fetch modules by path
 (fa, decode_attention, block_sparse_attention, grouped_matmul, quant_matmul,
- optimizer, ssm) = KERNEL_MODULES = tuple(
+ optimizer, ssm, eva) = KERNEL_MODULES = tuple(
     importlib.import_module(f"deeperspeed_tpu.ops.pallas.{name}")
     for name in ("flash_attention", "decode_attention",
                  "block_sparse_attention", "grouped_matmul", "quant_matmul",
-                 "optimizer", "ssm"))
+                 "optimizer", "ssm", "eva"))
 from deeperspeed_tpu.ops import dispatch_report  # noqa: E402
 
 BF16 = jnp.bfloat16
@@ -1019,6 +1019,123 @@ def test_state_kind_serving_programs_compile_and_carry_every_pool(
 # ---------------------------------------------------------------------------
 
 LATENT_POOL = ((6, 289, 64, 640), BF16)     # a 576-wide row in whole lanes
+
+
+# ---------------------------------------------------------------------------
+# a chunk-pooled cache kind (EvaByte) at its published widths
+# ---------------------------------------------------------------------------
+
+def test_eva_summarize_compiles(on_chip):
+    """A decode step's pooling at EvaByte's shapes (24 rows, 32 heads of
+    128, chunk 16, page 64, 8 layers x 1,001 pages): the pools stay in
+    HBM and alias the outputs, a closing row's four [32, 16, 128] tiles
+    are moved by the kernel itself."""
+    pools = stacked(8, 1001, 32, 64, 128, False)
+    B = 24
+    ints = [((), jnp.int32)] + [((B,), jnp.int32)] * 4 + [((B,), jnp.bool_)]
+    heads = [((32, 128), BF16)] * 2
+
+    def summarize(layer, src_page, src_slot, dst_page, dst_slot, closing,
+                  phi, mu, *pools):
+        return eva.eva_summarize(pools, phi, mu, layer, src_page, src_slot,
+                                 closing, dst_page, dst_slot, 16,
+                                 128 ** -0.5, backend="pallas")
+
+    text = on_chip(summarize, *ints, *heads, *pools)
+    assert_kernel(text)
+    assert kernel_names(text) == {"ds.eva_summarize"}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_eva_serving_programs_compile_and_leave_the_pool_in_place(
+        on_chip, v5e_2x2, program):
+    """The engine's decode and prefill programs for EvaByte's block at the
+    published widths (hidden 4096, 32 heads of 128, SwiGLU 11,008,
+    vocabulary 320 x 8 heads, window 2,048, chunk 16; two layers) at the
+    cell's shapes (24 rows, page 64, 1,001 pages, a bucket of 4,096: two
+    windows), compiled for the described v5e from shapes alone. The decode
+    step is the row write, the pooling and the ORDINARY paged kernel, and
+    no instruction of it produces an array of the pool's shape: no copy
+    around the pooling's read. The prefill runs the flash forward, keeps
+    under its temporaries the rows of ONE window a layer (not the
+    bucket's), and neither program re-lays out a weight a decode step
+    would stream."""
+    from jax.sharding import SingleDeviceSharding
+    from deeperspeed_tpu.inference import InferenceEngine
+    from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
+                                                 LayerSpec)
+    layers, pages, batch, page_size, seqlen = 2, 1001, 24, 64, 4096
+    cfg = GPTNeoXConfig(
+        vocab_size=320, hidden_size=4096, num_layers=layers, num_heads=32,
+        max_seq_len=20480, layernorm_eps=1e-5, use_parallel_residual=False,
+        tie_word_embeddings=False, norm="rmsnorm", use_bias=False,
+        hidden_act="silu", ffn_gated=True, ffn_width=11008,
+        layer_plan=(LayerSpec(attn="eva", heads=32, rotary_pct=1.0,
+                              rotary_base=1e5, ffn="dense"),) * layers,
+        eva_window=2048, eva_chunk=16, norm_unit_offset=True,
+        num_pred_heads=8, param_dtype=BF16)
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, BF16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page_size,
+        # the engine's own pool stays small: the programs take the pools
+        # as arguments, and those are shapes of 1,001 pages
+        "num_pages": 20480 // page_size + 1, "max_seq_len": 20480,
+        "max_batch_size": batch, "token_budget": seqlen + batch,
+        "prefill_lengths": [2048, seqlen], "prefill_batch_sizes": [1],
+        "decode_batch_sizes": [batch]}})
+    assert engine.n_pages_max == 2 * 10 + 32
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf, shape=None):
+        return jax.ShapeDtypeStruct(shape or leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    shapes = functools.partial(jax.tree_util.tree_map, shape_of)
+    pools = jax.tree_util.tree_map(
+        lambda leaf: shape_of(leaf, (layers, pages) + leaf.shape[2:]),
+        engine._pools())
+    carry = ()
+    if program == "decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch), ints(batch),
+                  {"eva": ints(batch, engine.n_pages_max),
+                   "eva_pending": ints(batch, 2)})
+        carry = (ints(batch), ints(batch))
+        kernels = {"ds.kv_write", "ds.eva_summarize", "ds.paged_decode"}
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1),
+                  {"eva": ints(1, 2048 // page_size),
+                   "eva_pooled": ints(1, seqlen // 16 // page_size)})
+        kernels = {"ds.flash_fwd"}
+    compiled = fn.lower(
+        shapes(engine.params), shapes(engine.params_stacked), *inputs, pools,
+        shape_of(jax.random.PRNGKey(0)), *carry).compile()
+    text = compiled.as_text()
+    assert kernels == set(re.findall(
+        r"%(ds\.[a-z0-9_]+)[.\d]* = .*tpu_custom_call", text))
+    assert "ds.attn_xla" not in text and "ds.paged_decode_xla" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if program == "decode":
+        assert not pool_shaped_moves(text, (layers, pages, 32, 64, 128))
+        assert not pool_shaped_moves(text, (1, pages, 32, 64, 128))
+        weight = re.compile(
+            r"bf16\[(?:\d,)?(?:4096,(?:4096|8192|22016)|11008,4096)\]")
+        assert not [line[:120] for line in text.splitlines()
+                    if (m := INSTRUCTION.match(line)) and m["op"] == "copy"
+                    and weight.search(m["type"])]
+        assert temp < 16 * 2 ** 20
+    else:
+        assert "ds.eva_prefill" in text and "ds.eva_summarize" in text
+        # q, k, v and the MLP's halves of 4,096 rows, not a bucket's K and
+        # V of every layer
+        assert temp < 2 ** 30
 
 
 def pool_shaped_moves(text, shape, dtype="bf16"):
