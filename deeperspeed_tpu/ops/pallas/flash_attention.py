@@ -557,14 +557,30 @@ def _fwd_single(qb, kb, vb, causal, sm_scale, s, d, interpret, kbias=None,
 # The forward never holds its tile whole. It walks it in PAIRS of CHUNK
 # key rows x GROUP query columns, whose scores are one matmul (every
 # MXU gets a 128-column weight tile of q and streams the chunk's keys),
-# and each pair in STRIPS of 128 query columns: a strip's chain (mask,
-# max, exp2, sum, cast, second matmul) works on a [CHUNK, 128] fp32
-# block and its [1, 128] / [D, 128] state, in registers, instead of
-# writing 2-4 MB of fp32 scores and probabilities out to VMEM between
-# whole-tile passes. The next pair's scores are issued before this
-# pair's strips, so the MXU and the VPU overlap; the walk is unrolled at
-# trace time (a tile is 1-4 pairs), one basic block the scheduler may
-# reorder as it likes.
+# each pair in STRIPS of 128 query columns, and each strip in BLOCKS of
+# 128 keys: a block's chain (max, exp2, sum, cast, second matmul, the
+# rescale of the running state) works on [BLOCK, STRIP] fp32, 16 of the
+# 64 vector registers, and the strip's state ([1, 128] max and sum,
+# [D, 128] output) stays in registers from the strip's first block to
+# its last. A block of 512 keys was the WHOLE register file, walked
+# twice (the max, then exp2 against it), under four strips' worth of the
+# next pair's scores: every score went MXU -> register -> VMEM ->
+# register and a tile's body ran 2,384 cycles where its matmuls hold an
+# MXU 1,600 (PERF.md, PR 51). The scores of the next AHEAD pairs are
+# issued before a pair's strips, so the MXU and the VPU overlap: the way
+# from a pair's last pop to its first weight push (pop, max, exp2, cast,
+# push: some 350 cycles) is longer than one pair's matmuls. Pairs run
+# keys outermost, so the pairs in flight share no query column and
+# neither's softmax waits for the other's running max.
+#
+# The walk is one basic block the scheduler may reorder as it likes: the
+# pairs and strips no mask touches are `lax.fori_loop`s with
+# `unroll=True`, which the Mosaic lowering unrolls with the index a
+# constant, so the body is TRACED once a loop (set-up: the jaxpr holds a
+# pair's strip once, not thirty-two) and COMPILED straight. What picks a
+# value by the index (`switch`) is folded there too. In interpret mode
+# the loops stay rolled (the same operations in the same order; XLA's
+# CPU compiler would otherwise compile every block of every test).
 #
 # A tile an edge crosses (the causal diagonal, a window's far edge, a
 # document boundary; a layout mask, a key bias or dropout always) takes
@@ -582,8 +598,10 @@ def _fwd_single(qb, kb, vb, causal, sm_scale, s, d, interpret, kbias=None,
 # dS's scale waits for the accumulators' last step.
 
 STRIP = 128   # query columns a strip: one lane tile
-CHUNK = 512   # key rows a pair: a strip's block is [CHUNK, STRIP] fp32
+BLOCK = STRIP  # key rows a block: [BLOCK, STRIP] fp32 is 16 vector registers
+CHUNK = 256   # key rows a pair: what one push of q's weight tiles streams
 GROUP = 512   # query columns a pair: one weight tile of q an MXU
+AHEAD = 2     # pairs whose scores are on the MXU before a pair's softmax
 # Columns a step of the backward kernels' diagonal bodies takes: narrower
 # skips more of the dead triangle, wider pushes each weight tile for more
 # rows. dkv + dq at [16, 2048, 16, 64] on a v5e: 8.00 ms at 512, 7.35 at
@@ -599,6 +617,17 @@ _NN = (((1,), (0,)), ((), ()))   # a @ b
 def _part(block, most):
     """The largest 128-multiple under `most` that divides `block`."""
     return next(n for n in range(most, 0, -128) if block % n == 0)
+
+
+def _rectangles(pairs):
+    """`pairs` ((key row, query column), keys outermost) as lists that
+    are each a rectangle in the same order: all of them where they are
+    one, else a list a query column (the whole pairs of a tile on the
+    causal diagonal are a staircase)."""
+    rows, cols = ({p[i] for p in pairs} for i in (0, 1))
+    if len(pairs) == len(rows) * len(cols):
+        return [pairs] if pairs else []
+    return [[p for p in pairs if p[1] == g0] for g0 in sorted(cols)]
 
 
 def _dot(a, b, dims):
@@ -840,7 +869,7 @@ def masked_tile_count(n_q, n_k, block_q, block_k, causal, window=None,
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
                 use_mask=False, use_bias=False, dropout_rate=0.0,
-                compact=False, window=None):
+                compact=False, window=None, unroll=True):
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -876,23 +905,26 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
                  kbias_scr=kbias_scr)
     c2 = jnp.float32(sm_scale * LOG2E)
     ck, gw = _part(block_k, CHUNK), _part(block_q, GROUP)
-    pairs = [(r0, g0) for g0 in range(0, block_q, gw)
-             for r0 in range(0, block_k, ck)]
+    # keys outermost: two pairs in flight never share a query column, so
+    # neither's softmax waits for the other's running max
+    pairs = [(r0, g0) for r0 in range(0, block_k, ck)
+             for g0 in range(0, block_q, gw)]
+    # (first row, rows, masked, diagonal) of a strip no mask touches
+    whole_strip = [(b, BLOCK, False, False) for b in range(0, ck, BLOCK)]
 
     def scores(r0, g0):
         # raw, transposed: keys r0.. x queries g0..  [ck, gw]
-        return _dot(k_ref[0, r0:r0 + ck, :], q_ref[0, g0:g0 + gw, :], _NT)
+        return _dot(k_ref[0, pl.ds(r0, ck), :], q_ref[0, pl.ds(g0, gw), :],
+                    _NT)
 
-    def strip(sT, r0, c0, masked, diagonal):
-        """One block of the online softmax: sT the raw scores of the
-        keys r0.. (as many as sT has rows) x the queries c0.. + STRIP; m /
-        l [1, w], acc [D, w]. `diagonal`: the causal compare is needed.
-        Written in `lax` primitives with explicit broadcasts: a kernel
-        unrolls dozens of strips and a `jnp` operator costs ten times a
-        primitive's bind to trace (PERF.md, PR 33: set-up)."""
-        cols = pl.ds(c0, STRIP)
-        rows = sT.shape[0]
-        m, l, acc = m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]
+    def block(state, sT, r0, c0, masked, diagonal):
+        """One step of a strip's online softmax: `state` (m, l [1, w], acc
+        [D, w]) after sT, the raw scores of the keys r0.. (as many as sT
+        has rows) x the queries c0.. + STRIP. `diagonal`: the causal
+        compare is needed. Written in `lax` primitives with explicit
+        broadcasts: a `jnp` operator costs ten times a primitive's bind to
+        trace (PERF.md, PR 33: set-up)."""
+        m, l, acc = state
         if masked:
             sT = tile.mask(sT, c0, r0, diagonal)
         m_new = lax.max(m, lax.reduce_max(sT, (0,)).reshape(1, STRIP))
@@ -902,44 +934,126 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         if masked and tile.zero_masked:
             pT = lax.select(lax.le(sT, jnp.float32(NEG_INF * 0.5)),
                             lax.full_like(pT, 0.0), pT)
-        l_scr[:, cols] = lax.add(
-            lax.mul(alpha, l), lax.reduce_sum(pT, (0,)).reshape(1, STRIP))
-        m_scr[:, cols] = m_new
+        l = lax.add(lax.mul(alpha, l),
+                    lax.reduce_sum(pT, (0,)).reshape(1, STRIP))
         if dropout_rate > 0.0:
             # post-l: the denominator sums the undropped probabilities
             pT = jnp.where(tile.keep(c0, r0, pT.shape),
                            pT * (1.0 / (1.0 - dropout_rate)), 0.0)
-        v = v_ref[0, pl.ds(r0, rows), :]
-        acc_scr[:, cols] = lax.add(
+        v = v_ref[0, pl.ds(r0, sT.shape[0]), :]
+        acc = lax.add(
             lax.mul(acc, lax.broadcast_in_dim(alpha, acc.shape, (0, 1))),
             _dot(v, pT.astype(v.dtype), _TN))
+        return m_new, l, acc
 
-    def live_rows(r0, c0, offset, crossed):
-        """Of the chunk at key r0, the rows a strip at query c0 sees any
-        of (a multiple of 128), and whether the diagonal may pass through
-        them. `offset` None: all of them, and it may if it may cross the
-        tile at all."""
-        if offset is None:
-            return ck, crossed
-        last = offset + c0 + STRIP - 1 - r0        # the last key it sees
-        return max(0, min(ck, last + 1)), last < ck + STRIP - 1
+    def strip(sT, r0, c0, blocks):
+        """A strip of a pair: its state read once, walked through
+        `blocks` ((first row, rows, masked, diagonal) of sT), written
+        once."""
+        cols = pl.ds(c0, STRIP)
+        state = m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]
+        for b, rows, masked, diagonal in blocks:
+            state = block(state, lax.slice(sT, (b, 0), (b + rows, STRIP)),
+                          r0 + b, c0, masked, diagonal)
+        m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols] = state
+
+    def whole(sT, r0, g0, first=0):
+        """The strips of a pair from `first` on, where no mask touches
+        them: a BLOCK of keys at a time, 16 registers of scores from the
+        max to the cast, a strip's state in registers from its first block
+        to its last. The strips are a loop the lowering unrolls, as the
+        pairs are (`run_of_whole`): `switch` picks the strip's columns by
+        the loop's index, a constant there."""
+        def one(i, carry):
+            strip(lax.switch(i, [
+                functools.partial(lax.slice, sT, (0, c), (ck, c + STRIP))
+                for c in range(0, gw, STRIP)]), r0, g0 + i * STRIP,
+                whole_strip)
+            return carry
+
+        lax.fori_loop(first, gw // STRIP, one, 0, unroll=unroll)
+
+    def plan(r0, c0, masked, offset, crossed):
+        """The blocks a strip at query c0 walks of the chunk at key r0.
+        `offset` None: every row, and the diagonal may pass where it may
+        cross the tile at all. A tile on the causal diagonal that no other
+        mask touches: the diagonal crosses ONE block of the strip, the
+        blocks before it are whole and those after it are not computed.
+        Under any other mask a strip is one masked block."""
+        if offset is not None and not tile.any_other:
+            # BLOCK == STRIP: the block it crosses
+            t = (offset + c0 - r0) // BLOCK
+            return [(b, BLOCK, b == t * BLOCK, b == t * BLOCK)
+                    for b in range(0, min(ck, (t + 1) * BLOCK), BLOCK)]
+        rows, diagonal = ck, crossed
+        if offset is not None:
+            last = offset + c0 + STRIP - 1 - r0        # the last key it sees
+            rows, diagonal = max(0, min(ck, last + 1)), last < ck + STRIP - 1
+        if masked and (diagonal or tile.any_other):
+            return [(0, rows, True, diagonal)] * bool(rows)
+        return whole_strip[:rows // BLOCK]
+
+    def run_of_whole(pairs_, ahead):
+        """The pairs of a rectangle of whole pairs (keys outermost), as
+        ONE loop the lowering unrolls: its index is a constant in the
+        kernel, and its body is traced once and not once a pair. `ahead`:
+        the scores of its first pairs, already on the MXU."""
+        (r_lo, g_lo), n = pairs_[0], len(pairs_)
+        n_g = len({g0 for _, g0 in pairs_})
+
+        def where(i):
+            return (r_lo + (i // n_g) * ck, g_lo + (i % n_g) * gw)
+
+        assert [where(i) for i in range(n)] == pairs_, pairs_
+        nothing = jnp.zeros((ck, gw), jnp.float32)
+        ahead = list(ahead) + [nothing] * (AHEAD - len(ahead))
+
+        def one(j, ahead):
+            # j <= 0 while a pair AHEAD of this one exists: `switch`
+            # clamps its index to a branch, and the lowering folds a
+            # clamp of a constant, so no branch is left in the kernel
+            i = j + (n - 1 - AHEAD)
+            nxt = lax.switch(j, [lambda: scores(*where(i + AHEAD)),
+                                 lambda: nothing])
+            whole(ahead[0], *where(i))
+            return (*ahead[1:], nxt)
+
+        lax.fori_loop(AHEAD + 1 - n, AHEAD + 1, one, tuple(ahead),
+                      unroll=unroll)
 
     def body(masked, offset, crossed):
-        live = [(r0, g0) for r0, g0 in pairs
-                if live_rows(r0, g0 + gw - STRIP, offset, crossed)[0]]
-        ahead = scores(*live[0])
-        for i, (r0, g0) in enumerate(live):
-            sT = ahead
-            if i + 1 < len(live):
-                # the next pair's scores go to the MXU before this
-                # pair's softmax starts on the VPU
-                ahead = scores(*live[i + 1])
-            for c in range(0, gw, STRIP):
-                rows, diagonal = live_rows(r0, g0 + c, offset, crossed)
-                if rows:
-                    strip(lax.slice(sT, (0, c), (rows, c + STRIP)), r0, g0 + c,
-                          masked and (diagonal or tile.any_other),
-                          diagonal)
+        plans = {(r0, g0): [plan(r0, g0 + c, masked, offset, crossed)
+                            for c in range(0, gw, STRIP)]
+                 for r0, g0 in pairs}
+        live = [pair for pair in pairs if any(plans[pair])]
+        # the pairs an edge touches first, strip by strip as each needs;
+        # then the whole pairs, a loop a rectangle of them
+        edge = [pair for pair in live
+                if any(blocks != whole_strip for blocks in plans[pair])]
+        runs = _rectangles([pair for pair in live if pair not in edge])
+        order = edge + (runs[0] if runs else [])
+        # AHEAD pairs' scores go to the MXU before a pair's softmax starts
+        # on the VPU: a pair's matmuls are shorter than the way from its
+        # last pop to its first weight push
+        ahead = [scores(*pair) for pair in order[:AHEAD]]
+        for i, (r0, g0) in enumerate(edge):
+            sT = ahead.pop(0)
+            if i + AHEAD < len(order):
+                ahead.append(scores(*order[i + AHEAD]))
+            strips = plans[r0, g0]
+            # a strip sees no fewer keys than the one before it
+            first = next((j for j, blocks in enumerate(strips)
+                          if blocks == whole_strip), len(strips))
+            for j, blocks in enumerate(strips[:first]):
+                if blocks:
+                    c = j * STRIP
+                    strip(lax.slice(sT, (0, c), (ck, c + STRIP)), r0, g0 + c,
+                          blocks)
+            if first < len(strips):
+                whole(sT, r0, g0, first)
+        for i, run in enumerate(runs):
+            run_of_whole(run, ahead if i == 0 else
+                         [scores(*pair) for pair in run[:AHEAD]])
 
     tile.bodies(body)
 
@@ -1155,7 +1269,10 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
                                    block_k=block_k, n_k=n_k,
                                    use_mask=use_mask, use_bias=use_bias,
                                    dropout_rate=dropout_rate,
-                                   compact=compact, window=window)
+                                   compact=compact, window=window,
+                                   # the interpreter gains nothing from a
+                                   # body written out, and compiles it
+                                   unroll=not interpret)
     if compact:
         maps = causal_grid_maps(n_q, n_k, block_q, block_k, "row", window)
         grid = (b * h, len(maps[0]))

@@ -422,9 +422,16 @@ def _variant(name, q, k, v, blocks, bwd_blocks):
 
 
 # blocks with block_q > block_k (the 16k cell's 2:1, scaled down),
-# block_q < block_k and equal; (512, 512) walks four strips of one pair,
-# (1024, 1024) at 2,048 tokens two pairs a side as the 2k cells do. A
-# segmented call's forward walks its pairs and strips in loops.
+# block_q < block_k and equal; (512, 512) walks four strips of two pairs,
+# (1024, 1024) at 2,048 tokens eight pairs as the 2k cells do (a tile on
+# the diagonal: four pairs strip by strip, then a loop of two whole
+# ones; the tile under it: a loop of eight, whose prefetch runs).
+# (1024, 512) at 1,024 tokens is the 16k cell's own tile at each of its
+# two diagonal offsets (0: two pairs strip by strip, then a loop of two
+# whole ones; -512: two pairs strip by strip). (512, 256) is the smallest
+# 2:1 tile that walks a strip in two blocks, at its offsets 0 and -256;
+# under a key bias, dropout or a layout mask every strip is one masked
+# block of 256 keys.
 TILE_BODY_CASES = [
     # variant, S, (block_q, block_k), head dim, dtype
     ("causal", 512, (256, 128), 64, jnp.float32),
@@ -449,6 +456,11 @@ TILE_BODY_CASES = [
     ("layout", 512, (256, 128), 64, jnp.float32),
     ("layout", 512, (128, 256), 128, jnp.float32),
     ("layout", 512, (256, 256), 64, jnp.bfloat16),
+    ("causal", 1024, (1024, 512), 64, jnp.float32),
+    ("causal", 512, (512, 256), 128, jnp.bfloat16),
+    ("kbias", 512, (512, 256), 128, jnp.float32),
+    ("dropout", 512, (512, 256), 64, jnp.float32),
+    ("layout", 512, (512, 256), 128, jnp.float32),
 ]
 
 
@@ -508,6 +520,8 @@ BOUNDARY_CASES = [
     ("kbias", 512, (128, 256)),
     ("dropout", 512, (256, 128)),
     ("layout", 512, (128, 128)),
+    ("kbias", 512, (512, 256)),
+    ("layout", 512, (512, 256)),
 ]
 
 
@@ -976,8 +990,10 @@ def _equations(jaxpr):
 
 # The three train cells' per-shard attention, and the most equations each
 # kernel's body may unroll to there: about 1.3 times what this tree counts
-# (fwd 688 / 907 / 907; the fused backward 265 = dkv's 199 + the fifth
-# matmul, two small transposes and the slab's read and write in each of
+# for the backward, less for the forward (fwd 843 / 840 / 840: the strips
+# and pairs no mask touches are loops the lowering unrolls, counted once;
+# written out they were 1,751 / 2,544; the fused backward 265 = dkv's 199
+# + the fifth matmul, two small transposes and the slab's read and write in each of
 # its five groups, and dq's init and store; as two kernels dkv 199, dq
 # 182). A body that grows past it is set-up every run pays: shrink it, or
 # let its unrolling adapt to the shape (docs/long-context.md, "What a
@@ -987,8 +1003,8 @@ BACKWARD_BUDGET = {"ds.flash_bwd": 340, "ds.flash_bwd_dkv": 260,
 SETUP_CASES = [
     # shape [B, S, H, D], budget of equations a kernel
     ((1, 16384, 16, 64), {"ds.flash_fwd": 900, **BACKWARD_BUDGET}),
-    ((16, 2048, 16, 64), {"ds.flash_fwd": 1200, **BACKWARD_BUDGET}),
-    ((4, 2048, 16, 128), {"ds.flash_fwd": 1200, **BACKWARD_BUDGET}),
+    ((16, 2048, 16, 64), {"ds.flash_fwd": 1000, **BACKWARD_BUDGET}),
+    ((4, 2048, 16, 128), {"ds.flash_fwd": 1000, **BACKWARD_BUDGET}),
 ]
 KERNEL_OF = {"fwd": "ds.flash_fwd", "bwd": "ds.flash_bwd",
              "dkv": "ds.flash_bwd_dkv", "dq": "ds.flash_bwd_dq"}

@@ -92,6 +92,14 @@ def qkv(b, s, h, d):
     return [((b, s, h, d), BF16)] * 3
 
 
+def _equations_under(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations_under(sub)
+
+
 def loss_of(fn):
     """Scalar fp32 loss of an attention callable, for the backward."""
     return lambda *a: fn(*a).astype(jnp.float32).sum()
@@ -186,13 +194,22 @@ def test_flash_compiles_at_the_train_cells_shapes(on_chip, name, shape, fwd,
                                                   bwd):
     """The forward and the fused backward, each with its masked and its
     unmasked body; the backward's dq slab ([16, 64, 1024] float32 at 16k)
-    under the VMEM limit the call asks for."""
+    under the VMEM limit the call asks for. The forward's loops over
+    pairs and strips are ones the lowering unrolls: a loop left rolled
+    would compile too, and cut a tile's body into basic blocks that
+    overlap nothing."""
     bq, bk = fwd or (None, None)
 
     def attn(q, k, v):
         return fa.flash_attention(q, k, v, True, None, bq, bk, bwd)
 
     assert_kernel(on_chip(attn, *qkv(*shape)))
+    loops = [eqn for eqn in _equations_under(jax.make_jaxpr(attn)(
+        *(jax.ShapeDtypeStruct(*a) for a in qkv(*shape))).jaxpr)
+        if eqn.primitive.name in ("scan", "while")]
+    assert loops and all(eqn.primitive.name == "scan" and
+                         eqn.params["unroll"] == eqn.params["length"]
+                         for eqn in loops), loops
     text = on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)), *qkv(*shape))
     assert_kernel(text, at_least=2)
     assert fa._LAST_BLOCKS["bwd_variant"] == "fused-trapezoid"
