@@ -44,11 +44,6 @@ SERVE_LOGIT_MARGIN = 0.05
 # Four devices reduce in another order than one; bf16 keeps eight bits.
 MULTICHIP_LOSS_RTOL = 2e-2
 
-LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT = "/jax/compilation_cache/cache_hits"
-
-
 class SmokeFailure(Exception):
     """A check of the smoke run did not hold."""
 
@@ -58,39 +53,20 @@ def check(ok, what):
         raise SmokeFailure(what)
 
 
-class CompileLog:
-    """Counts the programs jax lowers (each is then compiled, or read
-    from the persistent cache: `cache_hits`) and sums the seconds the
-    backend spent on them."""
-
-    def __init__(self):
-        import jax
-        self.programs = self.cache_hits = 0
-        self.compile_s = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_time)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_time(self, event, duration, **_):
-        if event == LOWERED:
-            self.programs += 1
-        elif event == BACKEND_COMPILE:
-            self.compile_s += duration
-
-    def _on_event(self, event, **_):
-        if event == CACHE_HIT:
-            self.cache_hits += 1
-
-    @contextlib.contextmanager
-    def span(self):
-        """Yields a dict filled, on exit, with this span's counts."""
-        p0, h0, c0 = self.programs, self.cache_hits, self.compile_s
-        t0 = time.perf_counter()
-        out = {}
-        yield out
-        out["programs"] = self.programs - p0
-        out["cache_hits"] = self.cache_hits - h0
-        out["compile_s"] = round(self.compile_s - c0, 2)
-        out["wall_s"] = round(time.perf_counter() - t0, 2)
+@contextlib.contextmanager
+def compile_span():
+    """Yields a dict filled, on exit, with what the program's own compile
+    account (`telemetry.setup_report()`: programs lowered, persistent
+    cache hits, the backend's compile seconds) gained over the span."""
+    from deeperspeed_tpu.runtime.telemetry import setup_report
+    before, t0 = setup_report()["totals"], time.perf_counter()
+    out = {}
+    yield out
+    after = setup_report()["totals"]
+    out["programs"] = after["programs"] - before["programs"]
+    out["cache_hits"] = after["cache_hits"] - before["cache_hits"]
+    out["compile_s"] = round(after["compile_s"] - before["compile_s"], 2)
+    out["wall_s"] = round(time.perf_counter() - t0, 2)
 
 
 def device_line(devices):
@@ -139,14 +115,14 @@ def host_params(model, seed):
     return jax.device_get(model.init_params(jax.random.PRNGKey(seed)))
 
 
-def run_steps(engine, batch, steps, log):
+def run_steps(engine, batch, steps):
     """`steps` train steps on one repeated batch. Returns the losses and
-    the compile-log spans of the first step and of the later ones."""
+    the compile spans of the first step and of the later ones."""
     import jax
     losses = []
-    with log.span() as first:
+    with compile_span() as first:
         losses.append(float(engine.train_batch(batch=batch)))
-    with log.span() as rest:
+    with compile_span() as rest:
         for _ in range(steps - 1):
             losses.append(float(engine.train_batch(batch=batch)))
         jax.block_until_ready(engine.state.params)
@@ -157,7 +133,7 @@ def run_steps(engine, batch, steps, log):
 # phase: train
 # ---------------------------------------------------------------------------
 
-def phase_train(cfg, seed, batch, seq, steps, ckpt_dir, log):
+def phase_train(cfg, seed, batch, seq, steps, ckpt_dir):
     """Train `steps` steps, save, train one more; load the checkpoint
     into a fresh engine and take that step again. Returns the record."""
     import jax
@@ -175,9 +151,9 @@ def phase_train(cfg, seed, batch, seq, steps, ckpt_dir, log):
             config_params=train_config(batch, zero_stage=2))
         return engine
 
-    with log.span() as whole:
+    with compile_span() as whole:
         engine = fresh_engine(seed)
-        losses, first, rest = run_steps(engine, data, steps, log)
+        losses, first, rest = run_steps(engine, data, steps)
         engine.save_checkpoint(ckpt_dir, tag="smoke")
         after_save = float(engine.train_batch(batch=data))
         del engine
@@ -258,7 +234,7 @@ def reference_logits(cfg, params, rows, positions):
     return np.asarray(run(params, jnp.asarray(rows), jnp.asarray(positions)))
 
 
-def phase_serve(cfg, seed, ckpt_dir, prompt_lens, max_new, inference, log):
+def phase_serve(cfg, seed, ckpt_dir, prompt_lens, max_new, inference):
     """Serve prompts of `prompt_lens` tokens from the checkpoint the
     train phase wrote, and hold every served token against a plain greedy
     decode: full forward passes of the same weights in this process.
@@ -281,7 +257,7 @@ def phase_serve(cfg, seed, ckpt_dir, prompt_lens, max_new, inference, log):
     prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
                for n in prompt_lens]
 
-    with log.span() as whole:
+    with compile_span() as whole:
         engine = InferenceEngine(model, config={"inference": inference})
         path, _ = engine.load_checkpoint(ckpt_dir, tag="smoke")
         check(path is not None, f"no checkpoint loaded from {ckpt_dir}")
@@ -313,7 +289,7 @@ def phase_serve(cfg, seed, ckpt_dir, prompt_lens, max_new, inference, log):
     served_params = dict(engine.params, blocks=[
         jax.tree_util.tree_map(lambda a, i=i: a[i], stack)
         for i in range(cfg.num_layers)])
-    with log.span() as ref:
+    with compile_span() as ref:
         logits = reference_logits(serve_cfg, served_params, rows, positions)
     served = np.asarray(served)
     best = logits.max(axis=-1)
@@ -388,7 +364,7 @@ def sharded_state_report(state, n_devices):
     return total, sharded, bad
 
 
-def phase_multichip(cfg, seed, batch, seq, steps, devices, log):
+def phase_multichip(cfg, seed, batch, seq, steps, devices):
     """ZeRO stage 3 over every device (`data = n`) against the same steps
     on a one-device mesh, same weights, same global batch."""
     import jax
@@ -406,10 +382,10 @@ def phase_multichip(cfg, seed, batch, seq, steps, devices, log):
         engine, *_ = deeperspeed_tpu.initialize(
             model=model, model_parameters=params, mesh=mesh,
             config_params=train_config(batch, zero_stage=3))
-        losses, first, rest = run_steps(engine, data, steps, log)
+        losses, first, rest = run_steps(engine, data, steps)
         return engine, losses, first, rest
 
-    with log.span() as whole:
+    with compile_span() as whole:
         engine, losses, first, rest = run(
             build_mesh(devices=devices, axes=["data"], dims=[n]))
         total, sharded, bad = sharded_state_report(engine.state, n)
@@ -477,7 +453,6 @@ def main(argv=None):
     from deeperspeed_tpu.models.gpt_neox import GPTNeoXConfig
     from deeperspeed_tpu.utils.compile_cache import configure_compile_cache
     cache_dir = configure_compile_cache()
-    log = CompileLog()
     cfg = GPTNeoXConfig()
     emit({"phase": "start", "device": device_line(devices),
           "jax": jax.__version__, "compile_cache": cache_dir,
@@ -489,15 +464,15 @@ def main(argv=None):
                   f"{len(devices)}", file=sys.stderr)
             return 1
         phase_multichip(cfg, args.seed, batch=8, seq=cfg.max_seq_len,
-                        steps=4, devices=devices, log=log)
+                        steps=4, devices=devices)
     else:
         with tempfile.TemporaryDirectory(prefix="chip_smoke_") as ckpt_dir:
             phase_train(cfg, args.seed, batch=8, seq=cfg.max_seq_len,
-                        steps=8, ckpt_dir=ckpt_dir, log=log)
+                        steps=8, ckpt_dir=ckpt_dir)
             phase_serve(
                 cfg, args.seed, ckpt_dir,
                 prompt_lens=(64, 150, 333, 512, 640, 777, 900, 1024),
-                max_new=32, log=log,
+                max_new=32,
                 # prefill buckets from 128 up: the flash kernel takes
                 # sequences that a 128-multiple block divides
                 inference={"enabled": True, "page_size": 64,

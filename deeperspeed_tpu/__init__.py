@@ -7,6 +7,12 @@ JSON configs written for the reference parse unmodified. The machinery
 underneath is JAX/XLA/pjit/Pallas over a `jax.sharding.Mesh`.
 """
 
+import time as _time
+
+import jax  # noqa: F401  (first: its own import is not `import_s`)
+
+_IMPORT_T0 = _time.perf_counter()   # runtime/telemetry.py, `setup_report`
+
 import argparse
 
 from . import moe, ops  # noqa: F401
@@ -23,6 +29,7 @@ from .runtime.lr_schedules import add_tuning_arguments
 from .runtime.pipe import LayerSpec, PipelineModule, TiedLayerSpec
 from .runtime.pipe.engine import PipelineEngine
 from .runtime.sentinel import TrainingHealthError
+from .runtime.telemetry import note_import as _note_import
 from .utils.distributed import init_distributed
 from .utils.logging import log_dist, logger
 from .version import __version__
@@ -103,3 +110,7 @@ def _add_core_arguments(parser):
 def add_config_arguments(parser):
     """Add DeepSpeed's argparse flags (reference `__init__.py:199`)."""
     return _add_core_arguments(parser)
+
+
+# the last line: this file's imports are `setup_report()["import_s"]`
+_note_import(_IMPORT_T0)

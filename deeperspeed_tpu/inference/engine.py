@@ -292,6 +292,22 @@ class InferenceEngine:
                  mesh=None, rng=None, monitor=None, draft_model=None,
                  draft_params=None, owns_monitor=True,
                  handoff_transport=None):
+        # one record a step, the spans' seconds into `stats`; the first
+        # record is this constructor, `build` (docs/observability.md,
+        # "Set-up"): whatever it costs and compiles is on that record
+        from ..runtime.telemetry import StepTimeline
+        self.stats = {}
+        self.timeline = StepTimeline("serve", counters=self.stats)
+        with self.timeline.build():
+            self._build(model, config, config_params, params, mesh, rng,
+                        monitor, draft_model, draft_params, owns_monitor,
+                        handoff_transport)
+
+    def _build(self, model, config, config_params, params, mesh, rng,
+               monitor, draft_model, draft_params, owns_monitor,
+               handoff_transport):
+        """The constructor's body, inside the timeline's build record;
+        its phases are the `_phase` spans below and `other`."""
         self.model = model
         cfg = model.config
         self._refuse_capacity_routing(cfg, "model")
@@ -342,6 +358,21 @@ class InferenceEngine:
                 "the 'inference' config block is required (with "
                 "\"enabled\": true) to build an InferenceEngine")
         ip = self.inference_params
+        # -- telemetry (spans: schedule / prefill / decode; admission
+        #    wait is a per-request scalar — docs/inference.md). First, so
+        #    that the build's phases mirror as annotations too ------------
+        from ..runtime.telemetry import build_telemetry
+        self.monitor = monitor
+        # co-residency contract (docs/rl.md): when the monitor is BORROWED
+        # from a co-located training engine (owns_monitor=False), drain()
+        # flushes it but must not close it — the training engine still
+        # records Train/* scalars, and TensorBoardMonitor registers its
+        # own weak atexit close, so no second registration happens here
+        self._owns_monitor = bool(owns_monitor)
+        self.telemetry = build_telemetry(telemetry_config, monitor=monitor,
+                                         devices=jax.local_devices())
+        if self.telemetry.enabled:
+            self.telemetry.attach(self.timeline)
 
         self.page_size = ip["page_size"]
         self.max_seq_len = ip["max_seq_len"] or cfg.max_seq_len
@@ -432,8 +463,12 @@ class InferenceEngine:
         self._natural_like = jax.tree_util.tree_map(
             lambda l: jax.ShapeDtypeStruct(jnp.shape(l),
                                            jnp.result_type(l)), params)
-        self._set_params(prepare_inference_params(
-            params, self.compute_dtype, weight_quant=self.weight_quant))
+        with self._phase("weights"):
+            # the cast, the placement and the stack of the layers, waited
+            # for: the phase holds what they cost, not what it enqueued
+            self._set_params(prepare_inference_params(
+                params, self.compute_dtype, weight_quant=self.weight_quant))
+            jax.block_until_ready((self.params, self.params_stacked))
 
         # -- cache / scheduler ---------------------------------------------
         # page pools by cache kind, as the programs take and return them
@@ -452,23 +487,25 @@ class InferenceEngine:
         if self.window:
             pages["window"] = self.max_batch_size * (
                 self.window // self.page_size + 1) + 1
-        self.caches = {kind: PagedKVCache(
-            num_layers=self.family.cache_layers(kind), num_pages=n,
-            num_heads=self.family.kv_heads, page_size=self.page_size,
-            head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh,
-            latent_width=self.latent) for kind, n in pages.items()}
-        # the names the scheduler and a benchmark's probes read
-        self.cache = self.caches[primary]
-        self.window_cache = self.caches.get("window")
-        # the cache kind WITHOUT pages (a model with state-space layers):
-        # one slot of recurrent state a running sequence, + the trash slot
-        self.state_cache = None
-        if fam.cache_layers("state"):
-            self.state_cache = StateCache(
-                num_layers=fam.cache_layers("state"),
-                num_slots=self.max_batch_size + 1, inner=cfg.ssm_inner,
-                state=cfg.ssm_state, conv=cfg.ssm_conv,
-                dtype=self.compute_dtype)
+        with self._phase("pools"):
+            self.caches = {kind: PagedKVCache(
+                num_layers=self.family.cache_layers(kind), num_pages=n,
+                num_heads=self.family.kv_heads, page_size=self.page_size,
+                head_dim=cfg.head_dim, dtype=self.kv_cache_dtype, mesh=mesh,
+                latent_width=self.latent) for kind, n in pages.items()}
+            # the names the scheduler and a benchmark's probes read
+            self.cache = self.caches[primary]
+            self.window_cache = self.caches.get("window")
+            # the cache kind WITHOUT pages (a model with state-space
+            # layers): one slot of recurrent state a running sequence, +
+            # the trash slot
+            self.state_cache = None
+            if fam.cache_layers("state"):
+                self.state_cache = StateCache(
+                    num_layers=fam.cache_layers("state"),
+                    num_slots=self.max_batch_size + 1, inner=cfg.ssm_inner,
+                    state=cfg.ssm_state, conv=cfg.ssm_conv,
+                    dtype=self.compute_dtype)
         # -- prefix/radix cache + speculative decoding (both default-off:
         #    without their config sub-blocks the engine is bit-identical
         #    to the plain PR 8 serving loop) --------------------------------
@@ -512,23 +549,25 @@ class InferenceEngine:
                     f"draft could not reach every decode position")
             self.spec_k = sp["num_draft_tokens"]
             self.draft_model = draft_model
-            if draft_params is None:
-                draft_params = draft_model.init_params(
-                    jax.random.PRNGKey(self.seed))
-            self.draft_family = _Family(draft_model, self.max_seq_len)
-            self.draft_params, self.draft_stacked = self._stacked(
-                self.draft_family, prepare_inference_params(
-                    draft_params, self.compute_dtype,
-                    weight_quant=sp["draft_weight_quant"]))
-            # the draft's shadow pools MIRROR the target allocator: same
-            # num_pages/page_size, so one page id addresses a sequence's
-            # K/V in both models and no second allocator exists — every
-            # write path (prefill twin, chunk twin, propose) lands draft
-            # K/V at the page ids the target's scheduler handed out
-            self.draft_cache = PagedKVCache(
-                num_layers=dcfg.num_layers, num_pages=ip["num_pages"],
-                num_heads=dcfg.num_heads, page_size=self.page_size,
-                head_dim=dcfg.head_dim, dtype=self.kv_cache_dtype)
+            with self._phase("draft"):
+                if draft_params is None:
+                    draft_params = draft_model.init_params(
+                        jax.random.PRNGKey(self.seed))
+                self.draft_family = _Family(draft_model, self.max_seq_len)
+                self.draft_params, self.draft_stacked = self._stacked(
+                    self.draft_family, prepare_inference_params(
+                        draft_params, self.compute_dtype,
+                        weight_quant=sp["draft_weight_quant"]))
+                # the draft's shadow pools MIRROR the target allocator:
+                # same num_pages/page_size, so one page id addresses a
+                # sequence's K/V in both models and no second allocator
+                # exists — every write path (prefill twin, chunk twin,
+                # propose) lands draft K/V at the page ids the target's
+                # scheduler handed out
+                self.draft_cache = PagedKVCache(
+                    num_layers=dcfg.num_layers, num_pages=ip["num_pages"],
+                    num_heads=dcfg.num_heads, page_size=self.page_size,
+                    head_dim=dcfg.head_dim, dtype=self.kv_cache_dtype)
             # host-side rejection sampling (temperature > 0): its own
             # deterministic stream, separate from the jax sampling keys
             self._spec_rng = np.random.default_rng(self.seed)
@@ -564,19 +603,6 @@ class InferenceEngine:
                          else str(jnp.dtype(self.kv_cache_dtype))),
         }
 
-        # -- telemetry (spans: schedule / prefill / decode; admission
-        #    wait is a per-request scalar — docs/inference.md) ------------
-        from ..runtime.telemetry import StepTimeline, build_telemetry
-        self.monitor = monitor
-        # co-residency contract (docs/rl.md): when the monitor is BORROWED
-        # from a co-located training engine (owns_monitor=False), drain()
-        # flushes it but must not close it — the training engine still
-        # records Train/* scalars, and TensorBoardMonitor registers its
-        # own weak atexit close, so no second registration happens here
-        self._owns_monitor = bool(owns_monitor)
-        self.telemetry = build_telemetry(telemetry_config, monitor=monitor,
-                                         devices=jax.local_devices())
-
         self._compiled = {}
         self._steps = 0
         # programs dispatched and not read back, in device order; when
@@ -602,7 +628,7 @@ class InferenceEngine:
         # dict a live row {"request", "at": the index in prompt +
         # generated of the token head 0 predicts, "logits" [heads * vocab]}
         self.head_trace = None
-        self.stats = {"steps": 0, "prefill_requests": 0,
+        self.stats.update({"steps": 0, "prefill_requests": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
                       # one-step lookahead (docs/inference.md): decode
                       # programs enqueued while the previous one was
@@ -731,7 +757,7 @@ class InferenceEngine:
                       # verdicts (installed/refused)
                       "handoff_sent": 0, "handoff_acked": 0,
                       "handoff_rejected": 0, "handoff_expired": 0,
-                      "handoff_installed": 0, "handoff_refused": 0}
+                      "handoff_installed": 0, "handoff_refused": 0})
         # tokens by the pass their exit gate chose (a looped model's
         # `t*`, from 1), read back with the tokens
         self.loop_exit_hist = [0] * self.loop_steps
@@ -748,12 +774,8 @@ class InferenceEngine:
             self.stats["kv_bytes_per_row_eva"] = row
             self.stats["kv_bytes_per_token_eva_summary"] = \
                 row / self.eva_chunk
-        # one record a step, the spans' seconds into `stats`; whether the
-        # scheduler had work when the last step returned (the caller's
-        # time before a step counts against it only then)
-        self.timeline = StepTimeline("serve", counters=self.stats)
-        if self.telemetry.enabled:
-            self.telemetry.attach(self.timeline)
+        # whether the scheduler had work when the last step returned (the
+        # caller's time before a step counts against it only then)
         self._busy = False
         # request-level latency histograms (inference/metrics.py):
         # admission-wait / TTFT / inter-token distributions, fanned out
@@ -2067,10 +2089,10 @@ class InferenceEngine:
         call's read-back — and fed on exit, including when the step DIES
         rather than hangs."""
         self.timeline.begin(busy=self._busy)
-        self._plan_step_faults()
-        self._apply_page_pressure()
         summary = None
         try:
+            self._plan_step_faults()
+            self._apply_page_pressure()
             summary = self._step_inner()
             return summary
         finally:

@@ -131,6 +131,25 @@ class DeepSpeedEngine:
                  lr_scheduler=None, mpu=None, dist_init_required=None,
                  collate_fn=None, config=None, config_params=None,
                  dont_change_device=False, mesh=None, rng=None):
+        # one record a train_batch / train_steps call, kept with or
+        # without a telemetry block; the first record is this
+        # constructor, `build` (docs/observability.md, "Set-up"): what it
+        # costs and compiles is on that record, by phase
+        from .telemetry import StepTimeline
+        self.timeline = StepTimeline("train")
+        with self.timeline.build():
+            self._build(args, model, optimizer, model_parameters,
+                        training_data, lr_scheduler, mpu, dist_init_required,
+                        collate_fn, config, config_params,
+                        dont_change_device, mesh, rng)
+
+    def _build(self, args, model, optimizer, model_parameters,
+               training_data, lr_scheduler, mpu, dist_init_required,
+               collate_fn, config, config_params, dont_change_device, mesh,
+               rng):
+        """The constructor's body, inside the timeline's build record;
+        its phases are the `timeline.span`s of `_init_state` and below,
+        and `other`."""
         self.loss_fn = self._resolve_model(model)
         self.module_obj = model
         self.client_optimizer = optimizer
@@ -178,9 +197,10 @@ class DeepSpeedEngine:
             np.prod([self.mesh.shape[a] for a in self.mesh.axis_names
                      if a != self.data_axis]))
 
-        self._config = DeepSpeedConfig(config_arg, mpu=mpu,
-                                       param_dict=config_params,
-                                       world_size=self.dp_world_size)
+        with self.timeline.span("config"):
+            self._config = DeepSpeedConfig(config_arg, mpu=mpu,
+                                           param_dict=config_params,
+                                           world_size=self.dp_world_size)
         self.plan_fingerprint = getattr(
             self._config, "planner_plan_fingerprint", None)
         if self.plan_fingerprint:
@@ -323,27 +343,28 @@ class DeepSpeedEngine:
         # snapshot-then-commit saves in a background writer, auto-save
         # every N steps, retention GC, and SIGTERM/SIGINT emergency saves
         # — all driven by the "checkpoint" config block.
-        from ..checkpoint.async_manager import AsyncCheckpointManager
-        self.checkpoint_manager = AsyncCheckpointManager(
-            self, **self._config.checkpoint_config)
+        with self.timeline.span("checkpoint_manager"):
+            # (the first engine of a process pays the import of the
+            # checkpoint serializer here, torch's among it where installed)
+            from ..checkpoint.async_manager import AsyncCheckpointManager
+            self.checkpoint_manager = AsyncCheckpointManager(
+                self, **self._config.checkpoint_config)
 
         # Unified telemetry (runtime/telemetry.py; the "telemetry" config
         # block): span tracing mirrored into jax.profiler annotations,
         # goodput buckets, in-engine MFU from compiled cost analysis, and
         # trigger-driven trace/memory capture. NULL_TELEMETRY (every hook
         # a no-op) when the block is absent — the hot path is unchanged.
-        from .telemetry import StepTimeline, build_telemetry
+        from .telemetry import build_telemetry
         local = [d for d in self.mesh.devices.flat
                  if getattr(d, "process_index", 0) == jax.process_index()]
         self.telemetry = build_telemetry(
             self._config.telemetry_config, monitor=self.monitor,
             devices=local or jax.local_devices())
         self._step_flops = {}   # compiled-variant key -> per-device flops
-        # one record a train_batch / train_steps call, kept with or
-        # without the block: dispatch to dispatch, so in steady state the
-        # step (docs/observability.md, "Slow steps"). `_newest_loss` is
-        # the last call's loss, asked `is_ready()` at the next entry
-        self.timeline = StepTimeline("train")
+        # a step's record runs dispatch to dispatch, so in steady state
+        # the step (docs/observability.md, "Slow steps"). `_newest_loss`
+        # is the last call's loss, asked `is_ready()` at the next entry
         if self.telemetry.enabled:
             self.telemetry.attach(self.timeline)
         self._newest_loss = None
@@ -624,8 +645,9 @@ class DeepSpeedEngine:
 
         # --- state --------------------------------------------------------
         if model_parameters is None and hasattr(model, "init_params"):
-            model_parameters = model.init_params(
-                rng if rng is not None else jax.random.PRNGKey(0))
+            with self.timeline.span("init_params"):
+                model_parameters = model.init_params(
+                    rng if rng is not None else jax.random.PRNGKey(0))
         if model_parameters is None:
             raise DeepSpeedConfigError(
                 "model_parameters (a pytree of arrays) is required")
@@ -637,7 +659,8 @@ class DeepSpeedEngine:
         self._explicit_zero3_loss = None
         zsched = self._config.zero_config.schedule
         if zsched.mode == "explicit":
-            self._configure_explicit_zero3(zsched)
+            with self.timeline.span("schedule"):
+                self._configure_explicit_zero3(zsched)
 
         # --- quantization (docs/quantization.md): delayed-scaling FFN
         # amax history and/or compressed-gradient error feedback ride
@@ -1490,8 +1513,12 @@ class DeepSpeedEngine:
         return jax.device_put(fields, self._replicated_sharding)
 
     def _init_state(self, model_parameters):
-        """Place params/master/opt-state on the mesh with ZeRO shardings."""
-        self._compute_shardings(model_parameters)
+        """Place params/master/opt-state on the mesh with ZeRO shardings.
+        Each part is a phase of the build record (host seconds: nothing
+        here waits for the device)."""
+        phase = self.timeline.span
+        with phase("partition"):
+            self._compute_shardings(model_parameters)
         if hasattr(self.optimizer, "pad_info"):
             # 1-bit optimizers must know which masters are flat-padded so
             # compression scales exclude (and never write) the pad tails.
@@ -1510,11 +1537,13 @@ class DeepSpeedEngine:
                 raise DeepSpeedConfigError(
                     "LazyLeaf parameters require offload_param "
                     "{device: nvme} (the NVMe store of record)")
-            self._init_host_state(model_parameters, defer_masters=lazy)
+            with phase("host_state"):
+                self._init_host_state(model_parameters, defer_masters=lazy)
         if self.param_offload:
-            if self._tiered_mode:
-                return self._init_tiered_state(model_parameters)
-            return self._init_streamed_state(model_parameters)
+            with phase("offload_state"):
+                if self._tiered_mode:
+                    return self._init_tiered_state(model_parameters)
+                return self._init_streamed_state(model_parameters)
 
         if self.host_offload or (not self.keep_master
                                  and self.compute_dtype != jnp.float32):
@@ -1535,14 +1564,16 @@ class DeepSpeedEngine:
                 return jax.device_put(
                     jnp.array(p, dtype=self.compute_dtype, copy=True), sh)
 
-            params = jax.tree_util.tree_map(
-                make_param_direct, model_parameters, self._param_sh)
+            with phase("params"):
+                params = jax.tree_util.tree_map(
+                    make_param_direct, model_parameters, self._param_sh)
             if self.host_offload:
                 opt_state = ()    # moments live host-side
             else:
-                opt_state = self.optimizer.init_state(params)
-                opt_state = _place_opt_state(opt_state, params,
-                                             self._master_sh, self.mesh)
+                with phase("optimizer_state"):
+                    opt_state = self.optimizer.init_state(params)
+                    opt_state = _place_opt_state(opt_state, params,
+                                                 self._master_sh, self.mesh)
             return EngineState(params=params, master=None,
                                opt_state=opt_state,
                                **self._replicated_state_scalars())
@@ -1557,8 +1588,10 @@ class DeepSpeedEngine:
                 m = flat_pad(m, info)
             return jax.device_put(m, sh)
 
-        master = jax.tree_util.tree_map(
-            make_master, model_parameters, self._master_sh, self._padinfo)
+        with phase("master"):
+            master = jax.tree_util.tree_map(
+                make_master, model_parameters, self._master_sh,
+                self._padinfo)
 
         def make_param(m, sh, info, pinfo):
             # pinfo set (stage-3 ragged): the compute param keeps the
@@ -1569,14 +1602,17 @@ class DeepSpeedEngine:
             return jax.device_put(
                 jnp.array(m, dtype=self.compute_dtype, copy=True), sh)
 
-        params = jax.tree_util.tree_map(
-            make_param, master, self._param_sh, self._padinfo,
-            self._param_padinfo)
+        with phase("params"):
+            params = jax.tree_util.tree_map(
+                make_param, master, self._param_sh, self._padinfo,
+                self._param_padinfo)
 
-        opt_state = self.optimizer.init_state(master)
-        # Moments follow master sharding; scalar fields stay replicated.
-        opt_state = _place_opt_state(opt_state, master, self._master_sh,
-                                     self.mesh)
+        with phase("optimizer_state"):
+            opt_state = self.optimizer.init_state(master)
+            # Moments follow master sharding; scalar fields stay
+            # replicated.
+            opt_state = _place_opt_state(opt_state, master, self._master_sh,
+                                         self.mesh)
 
         if not self.keep_master:
             master = None
@@ -3387,6 +3423,7 @@ class DeepSpeedEngine:
             # request while the process handles the exception
             if self.sentinel is not None:
                 self.sentinel.watchdog_feed()
+            self.timeline.leave()   # what compiles next is the caller's
             raise
 
     def _step_entered(self, rows):
@@ -3594,6 +3631,7 @@ class DeepSpeedEngine:
             # died, not hung: disarm (see train_batch)
             if self.sentinel is not None:
                 self.sentinel.watchdog_feed()
+            self.timeline.leave()
             raise
 
     def _train_steps_execute(self, batches, gas, n_steps):

@@ -37,6 +37,13 @@ pieces fuse into one config-driven layer the engine consults every step:
   `step_report()` is the process-wide accessor; the spans above write
   into the open record and carry its serial (docs/observability.md,
   "Slow steps").
+- **Set-up account** (always kept, like the timeline): ONE
+  `jax.monitoring` listener stamps every trace, lowering, backend compile
+  and persistent-cache read or miss of the process by name, and charges
+  it to the engine call it fired in or to the caller; each engine's
+  constructor is one `build` record of named phases; a step that
+  compiled says what compiled. `setup_report(until)` is the process-wide
+  accessor (docs/observability.md, "Set-up").
 
 Zero-overhead path: when the block is absent the engine holds
 `NULL_TELEMETRY`, whose hooks are empty methods and whose `span()`
@@ -45,6 +52,8 @@ unchanged, and the host loop keeps only its step timeline.
 """
 
 import bisect
+import contextlib
+import dataclasses
 import gc
 import json
 import os
@@ -378,15 +387,14 @@ SLOW_DEVIATIONS = 8
 RELEVEL_AFTER = 8
 SLOW_LOG_INTERVAL_S = 10.0
 
-LOWERED_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-# process-wide: [seconds inside collections, start of the one running],
-# [programs lowered]; one gc.callbacks hook and one jax.monitoring
-# listener, installed with the first timeline
-_GC = [0.0, 0.0]
-_LOWERED = [0]
+COMPILES_KEPT = 256         # compile records (and the build) kept whole
+COMPILE_LOG_AFTER = 64      # steady steps before a compile is worth a line
 # the newest timelines, held past their engines' lives: `step_report()`
 # is read after a run, when the engine that made the records may be gone
 _TIMELINES = deque(maxlen=16)
+# process-wide: [seconds inside collections, start of the one running];
+# one gc.callbacks hook, installed with the listener below
+_GC = [0.0, 0.0]
 
 
 def _on_gc(phase, info):  # noqa: ARG001
@@ -396,9 +404,165 @@ def _on_gc(phase, info):  # noqa: ARG001
         _GC[0] += time.perf_counter() - _GC[1]
 
 
-def _on_lowered(event, duration, **_):  # noqa: ARG001
-    if event == LOWERED_EVENT:
-        _LOWERED[0] += 1
+# ---------------------------------------------------------------------------
+# the compile account: what jax traced, lowered, compiled or read, and whose
+# ---------------------------------------------------------------------------
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWERED_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# jax.monitoring's events (jax 0.9.0) -> the account's kinds. The first
+# three carry `fun_name`; the backend's covers `compile_or_get_cached`,
+# so at a cache hit it IS the read (key, retrieval, deserialization), and
+# jax's own `cache_retrieval_time_sec` and `compile_time_saved_sec` would
+# have no reader here
+COMPILE_EVENTS = {
+    TRACE_EVENT: "trace", LOWERED_EVENT: "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+EVENTS_KEPT = 4096          # entries kept whole, at either end
+NAMES_KEPT = 8              # program names a record's delta carries
+_SECONDS_OF = {"trace": "trace_s", "lower": "lower_s",
+               "compile": "compile_s", "cache_read": "cache_read_s"}
+
+
+@dataclasses.dataclass(slots=True)
+class CompileDelta:
+    """What jax did on a record's account (or the caller's, or the whole
+    process's): sums that `add` grows an event at a time. Seconds are
+    SELF seconds: an event's duration less the timed events that fired
+    inside it (a jitted helper traced inside an outer trace, a constant's
+    program run while tracing), so the four never count a second twice
+    and fit inside the wall they fell in."""
+    programs: int = 0           # modules lowered: each then read or compiled
+    trace_s: float = 0.0        # Python to jaxpr: the package's own code
+    lower_s: float = 0.0        # jaxpr to StableHLO
+    compile_s: float = 0.0      # the backend's compiles
+    cache_read_s: float = 0.0   # ... and its reads of the persistent cache
+    cache_hits: int = 0
+    cache_misses: int = 0       # compiled and written: the cache lacked it
+    fun_names: tuple = ()       # the lowered programs, the first few
+
+    def add(self, kind, name, seconds):
+        field = _SECONDS_OF.get(kind)
+        if field is not None:
+            setattr(self, field, getattr(self, field) + seconds)
+            if kind == "lower":
+                self.programs += 1
+                if name and len(self.fun_names) < NAMES_KEPT:
+                    self.fun_names += (name,)
+        elif kind == "cache_hit":
+            self.cache_hits += 1
+        else:
+            self.cache_misses += 1
+
+
+NO_COMPILE = CompileDelta()     # shared by every record that compiled nothing
+
+# process-wide, one of each: the entries `(perf_counter stamp, kind,
+# fun_name or None, self seconds or 1, the owner's engine or None)`, the
+# process's first EVENTS_KEPT (a set-up lives there) and, once those are
+# full, its newest EVENTS_KEPT, with the stamp of the first entry that fell
+# between the two; the caller's sums (whatever fired outside every engine
+# call) and everyone's; the timeline inside an engine call right now;
+# timed events that a later, longer one may turn out to hold `(start,
+# seconds)`; whether the cache answered the compile request in flight; the
+# package's import intervals
+_FIRST = []
+_EVENTS = deque(maxlen=EVENTS_KEPT)
+_DROPPED = [None]
+_CALLER = CompileDelta()
+_TOTALS = CompileDelta()
+_OWNER = [None]
+_NESTED = deque(maxlen=EVENTS_KEPT)
+_HIT = [False]
+_IMPORTS = []
+
+
+def _program_name(fun_name):
+    """`jit(planned_prefill)` (a module, as lowering and the backend name
+    it) -> `planned_prefill` (the function, as tracing names it)."""
+    if fun_name and fun_name[-1] == ")" and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name
+
+
+def _on_lowered(event, duration=1, fun_name=None, **_):
+    """THE `jax.monitoring` listener of this package, for plain events
+    and durations both: one entry an event, charged to the engine call it
+    fired in (`StepTimeline.begin` to `leave` / `end`, or a constructor's
+    build record) or else to the caller."""
+    kind = COMPILE_EVENTS.get(event)
+    if kind is None:
+        return
+    now = time.perf_counter()
+    name = _program_name(fun_name)
+    seconds = duration
+    if kind in _SECONDS_OF:
+        # self seconds: what fired inside this event arrived before it
+        start = now - duration
+        nested = _NESTED
+        while nested and nested[-1][0] >= start and \
+                nested[-1][0] + nested[-1][1] <= now + 1e-3:
+            seconds -= nested.pop()[1]
+        nested.append((start, duration))
+        seconds = max(seconds, 0.0)
+        if kind == "compile":
+            if _HIT[0]:
+                kind = "cache_read"
+            _HIT[0] = False
+        elif kind == "lower":
+            _HIT[0] = False     # a new program: its request is yet to come
+    elif kind == "cache_hit":
+        _HIT[0] = True
+    owner = _OWNER[0]
+    _TOTALS.add(kind, name, seconds)
+    (_CALLER if owner is None else owner._owned()).add(kind, name, seconds)
+    whose = None if owner is None else owner.engine
+    newest = _EVENTS or _FIRST
+    last = newest[-1] if newest else None
+    if kind == "trace" and last is not None and last[1] == kind \
+            and last[4] == whose:
+        # jax reports every jitted helper traced inside an outer trace
+        # (`add`, `_where`: nine events in ten) as an event of its own,
+        # ending first: a run of traces is one entry, the last's
+        newest[-1] = (now, kind, name or last[2], last[3] + seconds, whose)
+    elif len(_FIRST) < EVENTS_KEPT:
+        _FIRST.append((now, kind, name, seconds, whose))
+    else:
+        if _DROPPED[0] is None and len(_EVENTS) == EVENTS_KEPT:
+            _DROPPED[0] = _EVENTS[0][0]
+        _EVENTS.append((now, kind, name, seconds, whose))
+
+
+def _entries():
+    """The entries kept, oldest first."""
+    return _FIRST + list(_EVENTS)
+
+
+def note_import(t0):
+    """One of the package's `__init__` files ran, from `t0` to now (jax's
+    own import before `t0`). One that ran inside another counts once."""
+    t1 = time.perf_counter()
+    _IMPORTS[:] = [(a, b) for a, b in _IMPORTS if not (t0 <= a and b <= t1)]
+    if not any(a <= t0 and t1 <= b for a, b in _IMPORTS):
+        _IMPORTS.append((t0, t1))
+
+
+def _listen():
+    """Install the process's one collector hook and its one compile
+    listener: the package's only site that listens to jax's monitoring,
+    run at this module's import, which the package's own import ends
+    with: before anything a user can compile through the package."""
+    if _on_gc in gc.callbacks:
+        return
+    gc.callbacks.append(_on_gc)
+    import jax
+    # jax keeps plain events and durations on two lists: the one
+    # listener goes on both
+    jax.monitoring.register_event_listener(_on_lowered)
+    jax.monitoring.register_event_duration_secs_listener(_on_lowered)
 
 
 _PROFILE_STATE = []         # jax's profiler state, looked up once
@@ -421,7 +585,7 @@ def _profiler_on():
 
 class StepRecord(typing.NamedTuple):
     """One step of an engine, as its `StepTimeline` keeps it."""
-    serial: int         # the timeline's count of steps, from 1
+    serial: int         # the timeline's count of steps, from 1 (build: 0)
     key: str            # the programs the step enqueued
     t_start: float      # perf_counter: the engine was entered
     t_end: float        # ... and the record closed
@@ -430,12 +594,13 @@ class StepRecord(typing.NamedTuple):
     phases: dict        # self seconds by span name, and "other"
     cpu_s: float        # thread CPU seconds over `wall`
     gc_s: float         # seconds inside garbage collections over `wall`
-    compiled: bool      # a program was lowered during it
+    compiled: bool      # jax traced, lowered or compiled on its account
     profiler: bool      # a profiler trace started or stopped during it
     rows: int           # rows or tokens the step accounted for
     starved: bool       # train: the device's queue was empty at its end
-    verdict: str        # None, "slow", "compile" or "profiler"
+    verdict: str        # None, "slow", "compile", "profiler" or "build"
     excess: float       # a slow step's seconds over its key's typical
+    compile: CompileDelta = NO_COMPILE  # what `compiled` stands for
 
 
 class _KeySteps:
@@ -463,6 +628,14 @@ class StepTimeline:
     caller's time AFTER the call. Either way the records of a busy
     engine tile the timeline with no hole.
 
+    The engine's CONSTRUCTOR is one record more, `with timeline.build():`
+    around its whole body: key and verdict `build`, serial 0, its phases
+    the spans opened inside, kept as `built` and at the head of
+    `compiles` and nowhere else: never judged, no step of the ring.
+    Whatever jax traces, lowers, compiles or reads between `begin()` and
+    `leave()` / `end()`, or inside the build, is on the record's account
+    (`StepRecord.compile`); anything else is the caller's.
+
     `counters` takes every span's seconds (`<name>_s`) and the slow-step
     sums; the serving engine hands in its `stats` dict. `tracer` (a
     `SpanTracer`, attached by `Telemetry.attach`) receives every span
@@ -484,20 +657,22 @@ class StepTimeline:
         self.open = False
         self.ring = deque(maxlen=STEP_RING)
         self.slow = deque(maxlen=SLOW_KEPT)
+        # the build record and every record of verdict `compile`, whole:
+        # the ring turns over inside one serving window
+        self.compiles = deque(maxlen=COMPILES_KEPT)
+        self.built = None
+        # the open record's `CompileDelta`, made by its first event; the
+        # timeline this one's call runs inside (an engine built or stepped
+        # inside another's call), which gets the account back
+        self._own = self._outer = None
         self._keys = {}
         self._stack = []
         self._phases = {}
         self._key = ""
         self._t_start = self._t_leave = self._t_end = None
-        self._cover = self._cpu0 = self._gc0 = self._lowered0 = None
+        self._cover = self._cpu0 = self._gc0 = None
         self._profiler0 = False
-        self._log_at = None
-        self._log_held = 0
-        if _on_gc not in gc.callbacks:
-            gc.callbacks.append(_on_gc)
-            import jax
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_lowered)
+        self._logged = {}       # kind of line -> [when, lines held back]
         _TIMELINES.append(self)
 
     # -- the engine's calls -------------------------------------------------
@@ -509,12 +684,13 @@ class StepTimeline:
         now = time.perf_counter()
         if not busy or self._t_end is None:
             self._cover, self._cpu0 = now, time.thread_time()
-            self._gc0, self._lowered0 = _GC[0], _LOWERED[0]
-            self._profiler0 = _profiler_on()
+            self._gc0, self._profiler0 = _GC[0], _profiler_on()
         self._t_start, self._t_leave = now, None
         self._phases, self._key = {}, ""
         self.serial += 1
         self.open = True
+        if _OWNER[0] is not self:       # compile events are this call's
+            self._outer, _OWNER[0] = _OWNER[0], self
 
     def enqueued(self, program):
         """Name a program the open step enqueued, by the key the engine's
@@ -525,31 +701,57 @@ class StepTimeline:
         return _Span(self, name)
 
     def leave(self):
-        """The train engine's call returns; the record stays open."""
+        """The train engine's call returns (or dies); the record stays
+        open, and what compiles from here on is the caller's."""
         self._t_leave = time.perf_counter()
+        if _OWNER[0] is self:
+            _OWNER[0], self._outer = self._outer, None
 
-    def end(self, rows=0, starved=False):
-        """Close the open record and judge it. Returns the slow step's
-        whole record (a dict, also kept in `slow`) or None."""
+    @contextlib.contextmanager
+    def build(self):
+        """The engine's constructor as one record (the class docstring)."""
+        self.begin(busy=False)
+        self.serial, self._key = 0, "build"     # serial 0: no step
+        try:
+            yield self
+        finally:
+            self.built = self._close()._replace(verdict="build")
+            self.compiles.append(self.built)
+            self._t_end = None      # the first step covers itself alone
+
+    def _owned(self):
+        """The open record's compile account (the listener's call)."""
+        own = self._own
+        if own is None:
+            own = self._own = CompileDelta()
+        return own
+
+    def _close(self, rows=0, starved=False):
         now, cpu = time.perf_counter(), time.thread_time()
-        gc_s, lowered, profiler = _GC[0], _LOWERED[0], _profiler_on()
+        gc_s, profiler = _GC[0], _profiler_on()
+        if _OWNER[0] is self:
+            _OWNER[0], self._outer = self._outer, None
         wall = now - self._cover
         inside = (self._t_leave or now) - self._t_start
         phases = self._phases
         phases["other"] = inside - sum(phases.values())
-        key = self._key or "none"
+        own, self._own = self._own, None
         record = StepRecord(
-            self.serial, key, self._t_start, now, wall, wall - inside,
-            phases, cpu - self._cpu0, gc_s - self._gc0,
-            lowered != self._lowered0, profiler != self._profiler0, rows,
-            starved, None, 0.0)
+            self.serial, self._key or "none", self._t_start, now, wall,
+            wall - inside, phases, cpu - self._cpu0, gc_s - self._gc0,
+            own is not None, profiler != self._profiler0, rows, starved,
+            None, 0.0, NO_COMPILE if own is None else own)
         self._t_end = self._cover = now
-        self._cpu0, self._gc0, self._lowered0 = cpu, gc_s, lowered
-        self._profiler0 = profiler
+        self._cpu0, self._gc0, self._profiler0 = cpu, gc_s, profiler
         self.open = False
-        if record.gc_s:
+        if record.gc_s and self.serial:     # the counters: steps only
             self.counters["gc_s"] += record.gc_s
-        record, slow = self._judge(record)
+        return record
+
+    def end(self, rows=0, starved=False):
+        """Close the open record and judge it. Returns the slow step's
+        whole record (a dict, also kept in `slow`) or None."""
+        record, slow = self._judge(self._close(rows, starved))
         if record.starved:
             self.counters["starved_steps"] += 1
         self.ring.append(record)
@@ -564,9 +766,10 @@ class StepTimeline:
             stack[-1] += dur
         phases = self._phases
         phases[name] = phases.get(name, 0.0) + dur - inner
-        counters = self.counters
-        name_s = name + "_s"
-        counters[name_s] = counters.get(name_s, 0.0) + dur
+        if self.serial:             # a build's phases are no step's sums
+            counters = self.counters
+            name_s = name + "_s"
+            counters[name_s] = counters.get(name_s, 0.0) + dur
         if self.tracer is not None:
             self.tracer.record(name, t0, dur, len(stack), step)
 
@@ -582,7 +785,11 @@ class StepTimeline:
             # device's queue running dry meanwhile is no one's starving
             verdict = "compile" if record.compiled else "profiler"
             self.counters[verdict + "_steps"] += 1
-            return record._replace(verdict=verdict, starved=False), None
+            record = record._replace(verdict=verdict, starved=False)
+            if record.compiled:
+                self.compiles.append(record)
+                self._log_compile(record)
+            return record, None
         walls = steps.walls
         n = len(walls)
         if n >= MIN_STEPS:
@@ -644,36 +851,72 @@ class StepTimeline:
         for name in ("device_wait", "gc", "host", "outside"):
             counters[f"slow_excess_{name}_s"] += held.get(name, 0.0)
         slow = {"engine": self.engine, **record._asdict(),
+                "compile": dataclasses.asdict(record.compile),
                 "typical_s": typical, "deviation_s": deviation,
                 "held_by": held, "clock": clock_pair()}
         self.slow.append(slow)
         self._log(slow)
         return slow
 
+    def _may_log(self, kind, now):
+        """At most one line of a kind every SLOW_LOG_INTERVAL_S. Returns
+        None to hold this one back, else what the line ends with: how
+        many were held back since the last."""
+        at = self._logged.setdefault(kind, [None, 0])
+        if at[0] is not None and now - at[0] < SLOW_LOG_INTERVAL_S:
+            at[1] += 1
+            return None
+        more = f" ({at[1]} more {kind} steps since the last line)" \
+            if at[1] else ""
+        at[:] = now, 0
+        return more
+
     def _log(self, slow):
-        """One line a slow step, at most one every SLOW_LOG_INTERVAL_S;
-        the next line says how many were held back."""
-        now = slow["t_end"]
-        if self._log_at is not None and \
-                now - self._log_at < SLOW_LOG_INTERVAL_S:
-            self._log_held += 1
+        """One line a slow step; the next line says how many were held
+        back."""
+        more = self._may_log("slow", slow["t_end"])
+        if more is None:
             return
         held = ", ".join(f"{s * 1e3:,.1f} in {name}"
                          for name, s in slow["held_by"].items()
                          if s >= 5e-5)
         gc_ms = slow["gc_s"] * 1e3
-        line = (f"{self.engine} step {slow['serial']} ({slow['key']}) "
-                f"{slow['wall'] * 1e3:,.1f} ms, typical "
-                f"{slow['typical_s'] * 1e3:,.1f}: {held}, CPU "
-                f"{slow['cpu_s'] * 1e3:,.1f} ms, "
-                + (f"collections {gc_ms:,.1f} ms" if gc_ms
-                   else "no collection") + ", no compile"
-                + (", the device's queue ran dry" if slow["starved"]
-                   else ""))
-        if self._log_held:
-            line += f" ({self._log_held} more slow steps since the last line)"
-        self._log_at, self._log_held = now, 0
-        logger.warning(line)
+        logger.warning(
+            f"{self.engine} step {slow['serial']} ({slow['key']}) "
+            f"{slow['wall'] * 1e3:,.1f} ms, typical "
+            f"{slow['typical_s'] * 1e3:,.1f}: {held}, CPU "
+            f"{slow['cpu_s'] * 1e3:,.1f} ms, "
+            + (f"collections {gc_ms:,.1f} ms" if gc_ms
+               else "no collection") + ", no compile"
+            + (", the device's queue ran dry" if slow["starved"]
+               else "") + more)
+
+    def _log_compile(self, record):
+        """One line a step that compiled once the engine is steady (its
+        first COMPILE_LOG_AFTER steps that were neither compile, profiler
+        nor slow behind it): which step, which program, and whether the
+        persistent cache had it. A set-up's compiles are not news."""
+        counters = self.counters
+        steady = self.serial - counters["compile_steps"] - \
+            counters["profiler_steps"] - counters["slow_steps"]
+        if steady < COMPILE_LOG_AFTER:
+            return
+        more = self._may_log("compile", record.t_end)
+        if more is None:
+            return
+        c = record.compile
+        cache = "no persistent cache"
+        if c.cache_hits or c.cache_misses:
+            cache = "cache miss" if not c.cache_hits else "cache hit" \
+                if not c.cache_misses else \
+                f"{c.cache_hits} cache hits, {c.cache_misses} misses"
+        logger.warning(
+            f"{self.engine} step {record.serial:,} ({record.key}) "
+            f"compiled: {', '.join(c.fun_names) or 'no new program'} "
+            f"traced {c.trace_s:.2f} s, lowered {c.lower_s:.2f} s, "
+            f"compiled {c.compile_s:.2f} s"
+            + (f", read {c.cache_read_s:.2f} s" if c.cache_hits else "")
+            + f" ({cache})" + more)
 
     # -- the report -----------------------------------------------------------
 
@@ -703,6 +946,34 @@ class StepTimeline:
                 "gc_s": sum(r.gc_s for r in records),
                 "slow": slow}
 
+    def setup(self, until=None):
+        """This engine's part of `setup_report`: its build record and
+        every record that compiled, of those that closed (train: whose
+        call returned) at or before `until`. None for a timeline that
+        holds neither."""
+        build, programs = None, []
+        for r in self.compiles:
+            inside = r.wall - r.outside
+            # a serve record's `outside` lies before its start, a train
+            # record's after its call: either way the call ended here
+            if until is not None and r.t_start + inside > until:
+                continue
+            c = r.compile
+            entry = {"key": r.key, "serial": r.serial, "t_start": r.t_start,
+                     "wall_s": r.wall, "outside_s": r.outside,
+                     **dataclasses.asdict(c)}
+            if r.verdict == "build":
+                build = dict(entry, phases=dict(r.phases))
+                continue
+            # what the call cost the host beyond compiling: loading the
+            # executable, its transfers, the step itself
+            entry["first_call_s"] = inside - c.trace_s - c.lower_s \
+                - c.compile_s - c.cache_read_s
+            programs.append(entry)
+        if build is None and not programs:
+            return None
+        return {"engine": self.engine, "build": build, "programs": programs}
+
 
 def step_report(last=None):
     """The step timelines of this process's engines (the newest 16,
@@ -712,6 +983,64 @@ def step_report(last=None):
     return {"clock": clock_pair(),
             "timelines": sorted((t.report(last) for t in list(_TIMELINES)),
                                 key=lambda r: r["engine"] != "train")}
+
+
+def setup_report(until=None):
+    """Where a process's set-up went, process-wide beside `step_report()`:
+
+        {"clock": clock_pair(),
+         "import_s": seconds inside the package's own `__init__` files,
+         "engines": [{"engine", "build": {"wall_s", "phases", the compile
+                      delta's fields, ...}, "programs": [one entry a
+                      record that compiled: "key", "serial", "t_start",
+                      "wall_s", "outside_s", the delta, "first_call_s"]}],
+         "caller": {the delta's sums of whatever compiled outside every
+                    engine call, "programs_by_name": the ten dearest
+                    of the entries kept},
+         "totals": {the whole process's: the engines' records, the
+                    caller's and what a still open record holds},
+         "complete": bool}
+
+    `until` (a `perf_counter` reading; None: everything) leaves out every
+    entry stamped after it: a benchmark cuts the account where its window
+    opens, so that a reference compiled after the window is no set-up.
+    The caller's sums and the totals at `until` are added up from the
+    entries kept: the process's first `EVENTS_KEPT` and its newest (a run
+    of trace events is one entry). They are exact while `until` lies
+    before the first entry that fell between the two (a set-up of fewer
+    than 4,096 entries, whatever compiles after it); `complete` False
+    says it does not, and they are then too small.
+    The engines' parts come from their records and are exact either way.
+    docs/observability.md, "Set-up"."""
+    engines = [e for e in (t.setup(until) for t in sorted(
+        list(_TIMELINES), key=lambda t: t.engine != "train")) if e]
+    cut = until is not None
+    caller, totals = (CompileDelta(), CompileDelta()) if cut \
+        else (_CALLER, _TOTALS)
+    by_name = {}
+    for stamp, kind, name, seconds, owner in _entries():
+        if cut and stamp > until:
+            continue
+        if cut:
+            totals.add(kind, name, seconds)
+        if owner is None:
+            if cut:
+                caller.add(kind, name, seconds)
+            if name and kind in _SECONDS_OF:
+                by_name[name] = by_name.get(name, 0.0) + seconds
+    dearest = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
+    caller, totals = dataclasses.asdict(caller), dataclasses.asdict(totals)
+    del caller["fun_names"], totals["fun_names"]
+    caller["programs_by_name"] = [
+        {"fun_name": name, "seconds": seconds}
+        for name, seconds in dearest if seconds > 0.0]
+    return {"clock": clock_pair(),
+            "import_s": sum(b - a if until is None
+                            else max(min(b, until) - a, 0.0)
+                            for a, b in _IMPORTS),
+            "engines": engines, "caller": caller, "totals": totals,
+            "complete": until is None or _DROPPED[0] is None
+            or until < _DROPPED[0]}
 
 
 class _NullTelemetry:
@@ -1168,3 +1497,6 @@ def build_telemetry(config_dict, monitor=None, devices=None):
         return NULL_TELEMETRY
     kwargs = {k: v for k, v in config_dict.items() if k != "enabled"}
     return Telemetry(monitor=monitor, devices=devices, **kwargs)
+
+
+_listen()

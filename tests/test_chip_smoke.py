@@ -43,17 +43,12 @@ def run_python(*argv, cwd=REPO, env=None, timeout=300):
 
 
 @pytest.fixture(scope="module")
-def compile_log():
-    return chip_smoke.CompileLog()
-
-
-@pytest.fixture(scope="module")
-def trained(tmp_path_factory, compile_log, devices):
+def trained(tmp_path_factory, devices):
     """The train phase's checkpoint and record, shared with the serve
     rehearsal as on the chip."""
     ckpt_dir = str(tmp_path_factory.mktemp("smoke_ckpt"))
     record = chip_smoke.phase_train(TINY, seed=0, batch=8, seq=256, steps=4,
-                                    ckpt_dir=ckpt_dir, log=compile_log)
+                                    ckpt_dir=ckpt_dir)
     return ckpt_dir, record
 
 
@@ -66,11 +61,11 @@ def test_train_phase_and_checkpoint_round_trip(trained):
     assert record["programs_later_steps"] == 0
 
 
-def test_serve_phase_agrees_with_plain_decode(trained, compile_log):
+def test_serve_phase_agrees_with_plain_decode(trained):
     ckpt_dir, _ = trained
     record = chip_smoke.phase_serve(
         TINY, seed=0, ckpt_dir=ckpt_dir, prompt_lens=(8, 40, 100, 128),
-        max_new=6, log=compile_log,
+        max_new=6,
         inference={"enabled": True, "page_size": 16, "num_pages": 64,
                    "max_batch_size": 4, "token_budget": 256,
                    "prefill_lengths": [128, 256], "kernel": "pallas"})
@@ -80,8 +75,7 @@ def test_serve_phase_agrees_with_plain_decode(trained, compile_log):
     assert record["exact_match_share"] > 0.9
 
 
-def test_serve_phase_fails_on_a_wrong_token(trained, compile_log,
-                                            monkeypatch):
+def test_serve_phase_fails_on_a_wrong_token(trained, monkeypatch):
     """The comparison has teeth: a plain decode that disagrees with the
     served tokens fails the phase."""
     ckpt_dir, _ = trained
@@ -91,14 +85,13 @@ def test_serve_phase_fails_on_a_wrong_token(trained, compile_log,
     with pytest.raises(chip_smoke.SmokeFailure, match="below the plain"):
         chip_smoke.phase_serve(
             TINY, seed=0, ckpt_dir=ckpt_dir, prompt_lens=(8, 40),
-            max_new=4, log=compile_log,
+            max_new=4,
             inference={"enabled": True, "page_size": 16, "num_pages": 64,
                        "max_batch_size": 4, "token_budget": 256,
                        "prefill_lengths": [128, 256], "kernel": "pallas"})
 
 
-def test_multichip_phase_on_four_virtual_devices(compile_log, devices,
-                                                 monkeypatch):
+def test_multichip_phase_on_four_virtual_devices(devices, monkeypatch):
     # what earlier tests of this worker left live (device 0 holds most of
     # it) is not the phase's: kept referenced, so that no new array takes
     # an old one's id
@@ -113,8 +106,7 @@ def test_multichip_phase_on_four_virtual_devices(compile_log, devices,
 
     monkeypatch.setattr(chip_smoke, "bytes_in_use", live_bytes)
     record = chip_smoke.phase_multichip(TINY, seed=0, batch=4, seq=256,
-                                        steps=3, devices=devices[:4],
-                                        log=compile_log)
+                                        steps=3, devices=devices[:4])
     assert record["mesh"] == {"data": 4}
     assert record["max_rel_loss_diff"] <= chip_smoke.MULTICHIP_LOSS_RTOL
     assert record["sharded_state_bytes"] >= 0.9 * record["state_bytes"]

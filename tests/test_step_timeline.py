@@ -7,6 +7,7 @@ import json
 import statistics
 import time
 import types
+from collections import deque
 
 import numpy as np
 import pytest
@@ -672,3 +673,389 @@ def test_with_a_block_the_train_spans_write_into_the_record(tmp_path,
     from tests.test_telemetry import _read_scalars
     scalars = _read_scalars(str(tmp_path / "unit"))
     assert "Train/Goodput/slow_step_s" in scalars
+
+
+# ---------------------------------------------------------------------------
+# the set-up account (docs/observability.md, "Set-up"). These come last:
+# they serve and train on the module's engines, whose records the tests
+# above read as the fixtures left them
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def account(monkeypatch):
+    """The process's compile account with nobody inside an engine call
+    (a step or a constructor that died gave the account back), no timed
+    event waiting for a longer one to hold it, and no entry kept yet; the
+    running sums are the process's own."""
+    assert tm._OWNER[0] is None
+    tm._NESTED.clear()
+    monkeypatch.setattr(tm, "_FIRST", [])
+    monkeypatch.setattr(tm, "_EVENTS", deque(maxlen=tm.EVENTS_KEPT))
+    monkeypatch.setattr(tm, "_DROPPED", [None])
+    return tm
+
+
+def fire(clock, kind, seconds=None, name=None):
+    """jax reports an event that took `seconds` and ends now."""
+    event = next(e for e, k in tm.COMPILE_EVENTS.items() if k == kind)
+    if seconds is None:
+        tm._on_lowered(event)
+    else:
+        clock.now += seconds
+        tm._on_lowered(event, seconds, fun_name=name)
+
+
+def compile_step(timeline, clock, key="prefill 1x4096 + decode x32",
+                 cache="cache_miss", compile_s=38.20):
+    """A step whose program jax traces, lowers and compiles: the events
+    of a persistent-cache miss (or hit), in jax's order."""
+    timeline.begin()
+    timeline.enqueued(key)
+    fire(clock, "trace", 0.41, "planned_prefill")
+    fire(clock, "lower", 0.62, "jit(planned_prefill)")
+    if cache == "cache_hit":
+        fire(clock, "cache_hit")
+    fire(clock, "compile", compile_s, "jit(planned_prefill)")
+    if cache == "cache_miss":
+        fire(clock, "cache_miss")
+    clock.now += 0.1
+    return timeline.end()
+
+
+BUILD_PHASES = {
+    "serve": {"weights", "pools", "other"},
+    "train": {"config", "checkpoint_manager", "partition", "master",
+              "params", "optimizer_state", "other"},
+}
+
+
+@pytest.mark.parametrize("kind", ["serve", "train"])
+def test_the_constructor_is_the_first_record_and_no_step(kind, served,
+                                                         trained):
+    timeline = (served if kind == "serve" else trained[0]).timeline
+    build = timeline.built
+    assert build is timeline.compiles[0]
+    assert (build.serial, build.key, build.verdict) == (0, "build", "build")
+    assert BUILD_PHASES[kind] <= set(build.phases)
+    # the phases and `other` tile the constructor
+    assert sum(build.phases.values()) == pytest.approx(build.wall)
+    assert build.outside == 0.0 and build.excess == 0.0
+    assert not build.starved
+    # what it placed through jax is on its account (nothing, where this
+    # process compiled the same placements for an earlier engine)
+    assert build.compiled == (build.compile is not tm.NO_COMPILE)
+    assert build.compile.trace_s + build.compile.lower_s \
+        + build.compile.compile_s < build.wall
+    # no step: not in the ring, no key's typical, no counter
+    assert all(r.serial >= 1 for r in timeline.ring)
+    assert "build" not in timeline._keys
+    assert all(s["key"] != "build" for s in timeline.slow)
+    assert not any(name.endswith("_s") and name[:-2] in build.phases
+                   for name in timeline.counters)
+    entry = timeline.setup()
+    assert entry["engine"] == kind
+    assert entry["build"]["wall_s"] == build.wall
+    assert entry["build"]["phases"] == build.phases
+    assert all(p["serial"] >= 1 for p in entry["programs"])
+
+
+def test_a_steady_step_carries_an_empty_delta(served):
+    record = [r for r in served.timeline.ring if r.key == "decode x4"][-1]
+    assert not record.compiled and record.verdict is None
+    assert record.compile is tm.NO_COMPILE
+    assert record.compile.programs == 0 and record.compile.fun_names == ()
+
+
+def test_a_first_seen_bucket_says_what_compiled(served, account, tmp_path):
+    """The record of a step that compiled carries the program's name,
+    its tracing and lowering seconds and whether the persistent cache
+    had it (a fresh cache directory here: a miss, and nothing is read)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    before = tm.setup_report()
+    mine = served.timeline.setup()
+    try:
+        served.submit(prompts(served.model.config, 1, length=20)[0],
+                      max_new_tokens=2)
+        served.step()
+    finally:
+        for name, value in was.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+    record = served.timeline.ring[-1]
+    assert record.key.startswith("prefill 1x32")
+    assert record.compiled and record.verdict == "compile"
+    assert served.timeline.compiles[-1] is record
+    delta = record.compile
+    assert "planned_prefill" in delta.fun_names
+    assert delta.programs >= 1
+    assert delta.trace_s > 0.0 and delta.lower_s > 0.0
+    assert delta.cache_hits + delta.cache_misses >= 1
+    assert delta.compile_s > 0.0 and delta.cache_read_s == 0.0
+    inside = record.wall - record.outside
+    assert delta.trace_s + delta.lower_s + delta.compile_s < inside
+    serve_until_done(served)
+    # the report names the program, and its totals moved by what the
+    # caller's and this engine's records did
+    after, entry = tm.setup_report(), served.timeline.setup()
+    program = next(p for p in entry["programs"]
+                   if p["serial"] == record.serial)
+    assert program["key"] == record.key
+    assert program["first_call_s"] == pytest.approx(
+        inside - delta.trace_s - delta.lower_s - delta.compile_s)
+    new = entry["programs"][len(mine["programs"]):]
+    for field in ("programs", "trace_s", "lower_s", "compile_s",
+                  "cache_misses"):
+        parts = sum(p[field] for p in new) \
+            + after["caller"][field] - before["caller"][field]
+        assert after["totals"][field] - before["totals"][field] == \
+            pytest.approx(parts), field
+    json.dumps(after)                                       # plain data
+
+
+def test_what_the_caller_compiles_between_two_steps_is_the_callers(
+        trained, account):
+    engine, _ = trained
+    (batch,) = stacked(1)
+    float(engine.train_batch(batch=batch))
+    before = tm.setup_report()["caller"]
+    jax.jit(lambda x: x * 2.0 + 1.0)(np.ones(3, np.float32))    # the caller's
+    float(engine.train_batch(batch=batch))      # closes the record between
+    record = engine.timeline.ring[-1]
+    assert record.outside > 0.0
+    assert not record.compiled and record.compile is tm.NO_COMPILE
+    assert record.verdict != "compile"
+    after = tm.setup_report()["caller"]
+    assert after["programs"] > before["programs"]
+    assert after["compile_s"] > before["compile_s"]
+
+
+def test_an_event_outside_every_engine_call_is_the_callers(clock, account,
+                                                           monkeypatch):
+    before = tm.setup_report()
+    fire(clock, "trace", 0.125, "a_users_helper")
+    fire(clock, "trace", 0.125, "a_users_function")
+    fire(clock, "lower", 0.5, "jit(a_users_function)")
+    fire(clock, "compile", 2.0, "jit(another)")
+    after = tm.setup_report()
+    assert after["caller"]["trace_s"] - before["caller"]["trace_s"] == \
+        pytest.approx(0.25)
+    assert after["caller"]["programs"] == before["caller"]["programs"] + 1
+    assert after["totals"]["lower_s"] - before["totals"]["lower_s"] == \
+        pytest.approx(0.5)
+    # by name too, dearest first. A run of traces (jax reports a jitted
+    # helper traced inside an outer trace first) is ONE entry, the last's
+    assert after["caller"]["programs_by_name"] == [
+        {"fun_name": "another", "seconds": 2.0},
+        {"fun_name": "a_users_function", "seconds": pytest.approx(0.75)}]
+    assert [e[1:] for e in tm._entries()] == [
+        ("trace", "a_users_function", pytest.approx(0.25), None),
+        ("lower", "a_users_function", 0.5, None),
+        ("compile", "another", 2.0, None)]
+    assert tm._entries()[-1][0] == clock.now
+
+
+def test_a_step_owns_what_compiles_inside_it_until_it_leaves(clock,
+                                                             account):
+    """begin() to leave(): the record's. After leave(), while a train
+    record stays open for the caller's time: the caller's."""
+    timeline = tm.StepTimeline("train")
+    before = tm.setup_report()["caller"]["programs"]
+    timeline.begin()
+    timeline.enqueued("gas 1")
+    fire(clock, "lower", 0.3, "jit(train_step)")
+    timeline.leave()
+    fire(clock, "lower", 0.2, "jit(the_callers)")
+    timeline.end()
+    record = timeline.ring[-1]
+    assert record.compile.programs == 1
+    assert record.compile.fun_names == ("train_step",)
+    assert record.compile.lower_s == pytest.approx(0.3)
+    assert record.outside == pytest.approx(0.2)
+    assert tm.setup_report()["caller"]["programs"] == before + 1
+    assert tm._OWNER[0] is None
+
+
+def test_seconds_are_self_seconds_and_a_hit_is_a_read(clock, account):
+    """A helper traced inside an outer trace is not counted twice, and
+    the backend's call that the cache answered is the read, whole."""
+    timeline = tm.StepTimeline("serve")
+    timeline.begin()
+    timeline.enqueued("decode x4")
+    clock.now += 0.2                        # the outer trace has begun
+    fire(clock, "trace", 0.1, "_where")     # ... a helper inside it
+    clock.now += 0.2
+    tm._on_lowered(tm.TRACE_EVENT, 0.5, fun_name="planned_decode")
+    fire(clock, "lower", 0.3, "jit(planned_decode)")
+    fire(clock, "cache_hit")
+    fire(clock, "compile", 0.05, "jit(planned_decode)")
+    timeline.end()
+    delta = timeline.ring[-1].compile
+    assert delta.trace_s == pytest.approx(0.5)
+    assert delta.lower_s == pytest.approx(0.3)
+    assert (delta.cache_hits, delta.cache_misses) == (1, 0)
+    assert delta.compile_s == 0.0
+    assert delta.cache_read_s == pytest.approx(0.05)
+    assert delta.fun_names == ("planned_decode",)
+    # the two traces are one entry, the outer's
+    assert [e[1:3] for e in tm._entries()] == [
+        ("trace", "planned_decode"), ("lower", "planned_decode"),
+        ("cache_hit", None), ("cache_read", "planned_decode")]
+
+
+def test_until_cuts_the_report_at_a_stamp(clock, account):
+    timeline = tm.StepTimeline("serve")
+    compile_step(timeline, clock)                       # before the cut
+    fire(clock, "lower", 0.5, "jit(weights)")           # the caller's, too
+    cut = clock.now
+    compile_step(timeline, clock, key="prefill 1x64")   # after it
+    fire(clock, "lower", 0.7, "jit(reference)")
+    # (both cut: the stamps of this process's real events lie far past
+    # the fake clock's)
+    early = tm.setup_report(until=cut)
+    late = tm.setup_report(until=clock.now)
+    mine = timeline.setup(until=cut)
+    assert [p["key"] for p in mine["programs"]] == \
+        ["prefill 1x4096 + decode x32"]
+    assert len(timeline.setup()["programs"]) == 2
+    assert late["caller"]["lower_s"] - early["caller"]["lower_s"] == \
+        pytest.approx(0.7)
+    assert late["totals"]["programs"] - early["totals"]["programs"] == 2
+    assert late["totals"]["compile_s"] - early["totals"]["compile_s"] == \
+        pytest.approx(38.20)
+    assert "reference" not in {p["fun_name"] for p in
+                               early["caller"]["programs_by_name"]}
+
+
+def test_a_set_up_is_cut_exactly_whatever_compiles_after_it(clock, account,
+                                                            monkeypatch):
+    """The first entries of the process are kept as its newest are: a
+    reference that compiles thousands of programs after the window opened
+    takes nothing off the set-up's sums. A cut past the first entry that
+    fell between the two says so."""
+    kept = 4
+    monkeypatch.setattr(tm, "EVENTS_KEPT", kept)
+    monkeypatch.setattr(tm, "_EVENTS", deque(maxlen=kept))
+    fire(clock, "lower", 0.5, "jit(weights)")
+    fire(clock, "cache_miss")
+    fire(clock, "compile", 2.0, "jit(weights)")
+    cut = clock.now                         # the window opens
+    stamps = []
+    for n in range(2 * kept + 1):           # the reference, after it
+        fire(clock, "lower", 0.25, f"jit(reference_{n})")
+        stamps.append(clock.now)
+    # reference_0 was the last of the first four; 1 to 4 fell between
+    assert len(tm._entries()) == 2 * kept and tm._DROPPED[0] == stamps[1]
+    early = tm.setup_report(until=cut)
+    assert early["complete"]
+    assert early["totals"] == pytest.approx({
+        "programs": 1, "trace_s": 0.0, "lower_s": 0.5, "compile_s": 2.0,
+        "cache_read_s": 0.0, "cache_hits": 0, "cache_misses": 1})
+    assert early["caller"]["programs_by_name"] == [
+        {"fun_name": "weights", "seconds": pytest.approx(2.5)}]
+    late = tm.setup_report(until=clock.now)
+    assert not late["complete"]
+    assert late["totals"]["programs"] == 1 + 1 + kept    # too few, and said
+    assert tm.setup_report()["complete"]    # no cut: the running sums
+
+
+def test_compile_records_outlive_the_ring(clock, account):
+    timeline = tm.StepTimeline("serve")
+    with timeline.build():
+        with timeline.span("weights"):
+            fire(clock, "lower", 0.2, "jit(concatenate)")
+    compile_step(timeline, clock, key="decode x4")
+    flat(timeline, clock, tm.STEP_RING + 10, seconds=1 * MS)
+    assert timeline.ring[0].serial > 1
+    assert [(r.serial, r.verdict) for r in timeline.compiles] == \
+        [(0, "build"), (1, "compile")]
+    entry = timeline.setup()
+    assert entry["build"]["phases"] == pytest.approx(
+        {"weights": 0.2, "other": 0.0})
+    assert entry["build"]["lower_s"] == pytest.approx(0.2)
+    (program,) = entry["programs"]
+    assert program["fun_names"] == ("planned_prefill",)
+    assert program["first_call_s"] == pytest.approx(0.1)
+    assert program["compile_s"] == pytest.approx(38.20)
+    assert program["cache_misses"] == 1
+    # and the build fed no counter of the steps
+    assert "weights_s" not in timeline.counters
+    assert timeline.counters["compile_steps"] == 1
+
+
+@pytest.mark.parametrize("steady, logged", [
+    (tm.COMPILE_LOG_AFTER - 1, False),      # still setting up: no news
+    (tm.COMPILE_LOG_AFTER, True),
+])
+def test_the_late_compile_line_and_its_rate_limit(clock, account,
+                                                  monkeypatch, steady,
+                                                  logged):
+    lines = []
+    monkeypatch.setattr(tm.logger, "warning", lines.append)
+    timeline = tm.StepTimeline("serve")
+    flat(timeline, clock, steady, key="decode x32")
+    compile_step(timeline, clock)
+    if not logged:
+        assert lines == []
+        return
+    assert lines == [
+        f"serve step {steady + 1} (prefill 1x4096 + decode x32) compiled: "
+        "planned_prefill traced 0.41 s, lowered 0.62 s, compiled 38.20 s "
+        "(cache miss)"]
+    compile_step(timeline, clock, key="prefill 1x64",
+                 compile_s=0.5)                         # held back: too soon
+    assert len(lines) == 1
+    clock.now += tm.SLOW_LOG_INTERVAL_S
+    compile_step(timeline, clock, key="prefill 1x128", cache="cache_hit")
+    assert len(lines) == 2
+    assert "compiled 0.00 s, read 38.20 s (cache hit)" in lines[1]
+    assert lines[1].endswith("(1 more compile steps since the last line)")
+    assert timeline.counters["slow_steps"] == 0
+
+
+def test_a_nested_import_counts_once(clock, account, monkeypatch):
+    monkeypatch.setattr(tm, "_IMPORTS", [])
+    clock.now += 1.0
+    outer = clock.now                   # the package's __init__ begins
+    clock.now += 0.5
+    inner = clock.now                   # ... and imports inference inside
+    clock.now += 2.0
+    tm.note_import(inner)
+    clock.now += 0.5
+    tm.note_import(outer)
+    assert tm.setup_report()["import_s"] == pytest.approx(3.0)
+    assert tm.setup_report(until=inner)["import_s"] == pytest.approx(0.5)
+    clock.now += 4.0
+    later = clock.now                   # a subpackage imported on its own
+    clock.now += 0.25
+    tm.note_import(later)
+    assert tm.setup_report()["import_s"] == pytest.approx(3.25)
+
+
+def test_a_constructor_that_raises_gives_the_account_back(account):
+    from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+    model = GPTNeoX(config=GPTNeoXConfig.tiny(), use_pallas=False)
+    with pytest.raises(DeepSpeedConfigError):
+        InferenceEngine(model, config={"inference": {"enabled": False}})
+    assert tm._OWNER[0] is None
+
+
+def test_a_step_that_dies_before_its_programs_gives_the_account_back(
+        served, account, monkeypatch):
+    def dies():
+        raise RuntimeError("the injector's plan is broken")
+    monkeypatch.setattr(served, "_plan_step_faults", dies)
+    with pytest.raises(RuntimeError):
+        served.step()
+    assert tm._OWNER[0] is None and not served.timeline.open
+
+
+def test_the_package_import_is_on_the_account():
+    assert 0.0 < tm.setup_report()["import_s"] < 120.0
