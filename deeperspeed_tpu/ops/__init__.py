@@ -23,7 +23,13 @@ def dispatch_report():
     backward's kinds, those the last backward ran}, "bodies_built":
     {"fwd" / "bwd" / "dkv" / "dq": (times the kernel's body was built in
     this process, host seconds that took): set-up every run pays, compile
-    cache or not}}; ``attention``: {"attention" / "sparse_attention":
+    cache or not}, "heads": {"fwd" / "bwd": {"in_place": n, "moved": n}},
+    the tiled calls traced in this process by where they found the heads:
+    in the [B, S, H*D] the model holds, or through a [B, S, H, D] ->
+    [B*H, S, D] copy of every operand and result
+    (`flash_attention.heads_in_place`: a training call at a head dim of
+    whole lane tiles, or of 64 with an even number of heads, is in place;
+    a serving prefill moves them)}; ``attention``: {"attention" / "sparse_attention":
     backend} of the model-side dispatchers, and "head_projection":
     {"plain": n, "folded": n}, the attention projections traced in this
     process by the form their reshape to heads took (plain: kept out of
@@ -58,7 +64,7 @@ def dispatch_report():
     from .pallas.decode_attention import _LAST_BACKEND
     from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
     from .pallas.flash_attention import (_BODY_BUILDS, _HEAD_PROJECTIONS,
-                                         _LAST_BLOCKS, _LAST_MASKED,
+                                         _HEADS, _LAST_BLOCKS, _LAST_MASKED,
                                          _XLA_NOTED)
     from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
     from .pallas.grouped_matmul import _PLANS_TRACED
@@ -66,7 +72,8 @@ def dispatch_report():
     from .pallas.ssm import _LAST_BACKEND as _SSM_BACKEND
     return {"flash": dict(_LAST_BLOCKS, masked_tiles=dict(_LAST_MASKED),
                           bodies_built={k: (n, round(t, 3)) for k, (n, t)
-                                        in _BODY_BUILDS.items()}),
+                                        in _BODY_BUILDS.items()},
+                          heads={k: dict(v) for k, v in _HEADS.items()}),
             "attention": dict(_ATTN_BACKEND,
                               head_projection=dict(_HEAD_PROJECTIONS)),
             "decode_attention": dict(_LAST_BACKEND),

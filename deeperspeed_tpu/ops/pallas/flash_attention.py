@@ -7,12 +7,19 @@ kernels (`csrc/transformer/softmax_kernels.cu`,
 which is both the perf win (HBM bandwidth is the bottleneck) and the
 long-sequence enabler.
 
-Layout: [B, S, H, D] in, [B, S, H, D] out (kernels run on a [B*H, S, D]
-view; Mosaic's last-two-dims tiling rule rules out indexing the 4-D layout
-with per-head singleton blocks). Forward saves the per-row logsumexp as a
-compact [BH, S] row-vector (not a lane-broadcast [.., 128] tile — 128x
-less residual HBM traffic); backward recomputes probabilities blockwise
-(no SxS residual).
+Layout: [B, S, H, D] in, [B, S, H, D] out. Mosaic's last-two-dims tiling
+rule rules out indexing that 4-D layout with per-head singleton blocks,
+so a kernel sees the heads one of two ways. The training call
+(`flash_attention`, forward and fused backward, where `heads_in_place`
+admits the shape) takes each tensor TRANSPOSED, [B, H*D, S], a head its
+block of D rows: that is where XLA keeps a `[B, S, H, D]` tensor of a
+train step on a TPU (sequence minor), so the transpose in front of the
+kernel is a bitcast and nothing is copied. Every other call (segments, a
+window, grouped KV heads, a layout, a key bias, dropout; a single block)
+runs on [B*H, S, D], a COPY of each operand and result. Forward saves the
+per-row logsumexp as a compact [BH, S] row-vector (not a lane-broadcast
+[.., 128] tile — 128x less residual HBM traffic); backward recomputes
+probabilities blockwise (no SxS residual).
 
 Block sizes default to 1024x1024, auto-fitted down to the largest
 128-multiple dividing the sequence length (`ops/autotune.py`): a fat
@@ -76,6 +83,32 @@ _XLA_NOTED = set()
 # Attention projections traced in this process by the form their reshape
 # to heads took (`models/gpt_neox.py::_heads_dot`).
 _HEAD_PROJECTIONS = {"plain": 0, "folded": 0}
+# Tiled flash calls traced in this process by where they found the heads:
+# "in_place" read q, k, v (and dO) and wrote out (dq, dk, dv) where the
+# program holds them (`heads_in_place`); "moved" went through a
+# [B, S, H, D] -> [B*H, S, D] copy of each. `ops.dispatch_report()
+# ["flash"]["heads"]` reads it.
+_HEADS = {"fwd": {"in_place": 0, "moved": 0},
+          "bwd": {"in_place": 0, "moved": 0}}
+
+
+def heads_in_place(h, g, d):
+    """Can the tiled training kernels take the heads where the model's
+    program holds them, with no copy of a tensor? As many KV heads as
+    query heads, and a head dim of whole packed sublane tiles (16 rows of
+    bfloat16). A fact of the shape and of nothing else.
+
+    WHERE that is, is XLA's choice, not the model's source: a
+    `[B, S, H, D]` tensor of head dim 64 is laid out with the SEQUENCE
+    minor (`{1,3,2,0}`: physically [B, H, D, S]; D minor would leave
+    half of every lane tile empty), the rotary fusions write it so and
+    read its gradient so. The kernels therefore take q^T, k^T, v^T (and
+    dO^T) and give out^T (dq^T, dk^T, dv^T) as [B, H*D, S]: the
+    `transpose(0, 2, 3, 1)` in front of them is a bitcast of that
+    layout, a head is the block of D ROWS at row block `head`, and the
+    tile bodies, which hold their tiles transposed already, lose their
+    own transposes (`_fwd_kernel`, `_bwd_dkv_kernel`: `by_rows`)."""
+    return g == h and d % 16 == 0
 
 
 def note_xla_on_tpu(op, why):
@@ -869,7 +902,13 @@ def masked_tile_count(n_q, n_k, block_q, block_k, causal, window=None,
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
                 use_mask=False, use_bias=False, dropout_rate=0.0,
-                compact=False, window=None, unroll=True):
+                compact=False, window=None, unroll=True, by_rows=False):
+    """`by_rows`: the blocks are a head's TRANSPOSED tensors
+    (`heads_in_place`): q^T [D, block_q], k^T and v^T [D, block_k] in,
+    out^T [D, block_q] out. The tile body is the one every call runs; v^T and out^T are the
+    operands it wanted (it multiplies v^T by P^T into out^T), q^T is the
+    k q^T matmul's right operand as it lies, and k alone is transposed,
+    once a grid step."""
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -880,6 +919,9 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     o_ref, lse_ref = next(it), next(it)
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
     kbias_scr = next(it) if use_bias else None
+    if by_rows:
+        k_scr = next(it)
+        k_scr[...] = k_ref[0].T                                # [BK, D]
     if compact:
         # flat trapezoidal schedule: (qi, ki) from the prefetched LUTs;
         # the row ends at its causal k-extent, not at n_k - 1
@@ -914,8 +956,19 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 
     def scores(r0, g0):
         # raw, transposed: keys r0.. x queries g0..  [ck, gw]
+        if by_rows:
+            return _dot(k_scr[pl.ds(r0, ck), :],
+                        q_ref[0, :, pl.ds(g0, gw)], _NN)
         return _dot(k_ref[0, pl.ds(r0, ck), :], q_ref[0, pl.ds(g0, gw), :],
                     _NT)
+
+    def pv(r0, pT):
+        # [D, w]: v^T of the keys r0.. (as many as pT has rows) times pT
+        if by_rows:
+            return _dot(v_ref[0, :, pl.ds(r0, pT.shape[0])],
+                        pT.astype(v_ref.dtype), _NN)
+        v = v_ref[0, pl.ds(r0, pT.shape[0]), :]
+        return _dot(v, pT.astype(v.dtype), _TN)
 
     def block(state, sT, r0, c0, masked, diagonal):
         """One step of a strip's online softmax: `state` (m, l [1, w], acc
@@ -940,10 +993,9 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
             # post-l: the denominator sums the undropped probabilities
             pT = jnp.where(tile.keep(c0, r0, pT.shape),
                            pT * (1.0 / (1.0 - dropout_rate)), 0.0)
-        v = v_ref[0, pl.ds(r0, sT.shape[0]), :]
         acc = lax.add(
             lax.mul(acc, lax.broadcast_in_dim(alpha, acc.shape, (0, 1))),
-            _dot(v, pT.astype(v.dtype), _TN))
+            pv(r0, pT))
         return m_new, l, acc
 
     def strip(sT, r0, c0, blocks):
@@ -1061,7 +1113,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     def _finalize():
         l = l_scr[...]                                         # [1, BQ]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l_safe).T.astype(o_ref.dtype)
+        oT = acc_scr[...] / l_safe                             # [D, BQ]
+        o_ref[0] = (oT if by_rows else oT.T).astype(o_ref.dtype)
         # Dead rows (no visible key: a layout mask, a pad row's window)
         # get POISONED lse (+1e30) so backward's exp(s - lse) is exactly
         # 0, the block-sparse kernels' invariant.
@@ -1241,15 +1294,34 @@ def _tag_residuals(out, lse):
             checkpoint_name(lse, "ds_attn_lse"))
 
 
+def _head_spec(ix, by_rows, h, d, block, which, row_of=lambda bh: bh):
+    """BlockSpec of `block` positions of one head, the `which(qi, ki)`-th
+    such block: rows of [B*H, S, D] (of its row `row_of(bh)`), or with
+    `by_rows` columns of the head's D rows of [B, H*D, S]. `ix` adapts
+    the index map, written as (bh, qi, ki), to the grid."""
+    if by_rows:
+        return pl.BlockSpec((1, d, block), ix(
+            lambda bh, qi, ki: (bh // h, bh % h, which(qi, ki))))
+    return pl.BlockSpec((1, block, d), ix(
+        lambda bh, qi, ki: (row_of(bh), which(qi, ki), 0)))
+
+
 @functools.cache
 def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
               use_mask, use_bias, dropout_rate, segmented, window,
-              interpret, mask_block=0):
+              interpret, mask_block=0, by_rows=False):
     """The tiled forward at one call signature: (the function of its
     inputs, its grid, its (masked, launched) tiles), built once a process
     (`_BODY_BUILDS`). Inputs in order: q, k, v as [B*H | B*G, S, D], then
-    `_optional_inputs`."""
+    `_optional_inputs`.
+
+    `by_rows` (`heads_in_place`): q^T, k^T, v^T in and out^T out, each
+    [B, H*D, S]; a BlockSpec picks a head's (1, D, block) at row block
+    `head` of row `batch`, on the same grid."""
     n_q, n_k = s // block_q, s // block_k
+    if by_rows:
+        assert g == h and not (use_mask or use_bias or segmented) and \
+            dropout_rate == 0.0 and window is None
 
     def kv_of(bh):
         """The [B*G, S, D] row that holds query row `bh`'s KV head."""
@@ -1272,7 +1344,7 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
                                    compact=compact, window=window,
                                    # the interpreter gains nothing from a
                                    # body written out, and compiles it
-                                   unroll=not interpret)
+                                   unroll=not interpret, by_rows=by_rows)
     if compact:
         maps = causal_grid_maps(n_q, n_k, block_q, block_k, "row", window)
         grid = (b * h, len(maps[0]))
@@ -1280,24 +1352,20 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
         maps = ()
         grid = (b * h, n_q, n_k)
     ix = _index_adapter(compact)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     ix(lambda bh, qi, ki: (bh, qi, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
-        pl.BlockSpec((1, block_k, d),
-                     ix(lambda bh, qi, ki: (kv_of(bh), ki, 0))),
-    ]
+    q_spec = _head_spec(ix, by_rows, h, d, block_q, lambda qi, ki: qi)
+    kv_spec = _head_spec(ix, by_rows, h, d, block_k, lambda qi, ki: ki,
+                         kv_of)
+    in_specs = [q_spec, kv_spec, kv_spec]
     out_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     ix(lambda bh, qi, ki: (bh, qi, 0))),
+        q_spec,
         pl.BlockSpec((1, 1, block_q),
                      ix(lambda bh, qi, ki: (bh, 0, qi))),
     ]
     in_specs += _optional_specs(ix, h, s, block_q, block_k, segmented,
                                 use_mask, use_bias, dropout_rate)
     out_shape = [
-        jax.ShapeDtypeStruct((b * h, s, d), dtype),
+        jax.ShapeDtypeStruct((b, h * d, s) if by_rows else (b * h, s, d),
+                             dtype),
         jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
     ]
     scratch_shapes = [
@@ -1308,7 +1376,8 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
         pltpu.VMEM((1, block_q), jnp.float32),       # running max (raw)
         pltpu.VMEM((1, block_q), jnp.float32),       # running denom
         pltpu.VMEM((d, block_q), jnp.float32),       # out accumulator^T
-    ] + _key_column_scratch(block_k, False, use_bias)
+    ] + _key_column_scratch(block_k, False, use_bias) \
+        + [pltpu.VMEM((block_k, d), dtype)] * by_rows  # k of a k^T block
     masked = masked_tile_count(
         n_q, n_k, block_q, block_k, causal, window,
         always=use_mask or use_bias or dropout_rate > 0.0)
@@ -1319,9 +1388,36 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
     return run, grid, masked
 
 
+def _to_bh(x):
+    """[B, S, H, D] -> [B*H, S, D]: a head's rows contiguous, by a COPY of
+    the tensor (at head dim 64 a transpose of half-filled lane tiles: a
+    quarter of the memory's rate on a v5e, PERF.md PR 53)."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _from_bh(x, h):
+    """`_to_bh`'s inverse, and as much a copy."""
+    bh, s, d = x.shape
+    return x.reshape(bh // h, h, s, d).transpose(0, 2, 1, 3)
+
+
+def _to_rows(x):
+    """[B, S, H, D] -> [B, H*D, S], a head's D rows together: the tensor
+    where XLA holds it (`heads_in_place`), so no copy on the chip."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 3, 1).reshape(b, h * d, s)
+
+
+def _from_rows(x, h):
+    """`_to_rows`' inverse."""
+    b, hd, s = x.shape
+    return x.reshape(b, h, hd // h, s).transpose(0, 3, 1, 2)
+
+
 def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
          layout=None, kbias=None, dropout_rate=0.0, seed=None, seg=None,
-         window=None, mask_block=0):
+         window=None, mask_block=0, in_place=False):
     """`k` / `v` may hold fewer heads than `q` (G under H: query head i
     reads KV head ``i // (H / G)``, through the K and V index maps), and
     a `window` (causal only) keeps keys less than `window` positions
@@ -1329,46 +1425,55 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
     alone, under the scope `ds.flash_fwd_window`. `mask_block` (segmented
     and causal only) makes the diagonal BLOCK-causal (`_window_mask`).
     All three are the forward's (a serving prefill's); the backward
-    kernels take none."""
+    kernels take none.
+
+    `in_place` (`flash_attention`'s call alone): a tiled call whose shape
+    `heads_in_place` admits hands the kernel q^T, k^T, v^T and takes
+    out^T, [B, H*D, S] each (`_to_rows`: no copy where XLA holds the
+    tensors sequence-minor), and its residuals are those four as
+    [B, H, D, S]. Every other call (a layout, a key bias, dropout,
+    segments, a window, grouped KV heads; a single block) MOVES the
+    heads: a `[B, S, H, D] -> [B*H, S, D]` copy of each operand and of
+    out, residuals [B*H, S, D]."""
     b, s, h, d = q.shape
     g = k.shape[2]
     block_q, block_k = _fit_block(block_q, s), _fit_block(block_k, s)
+    single = s // block_q == 1 and s // block_k == 1 and layout is None \
+        and seg is None and window is None and g == h
+    in_place = in_place and not single and heads_in_place(h, g, d)
+    # [B, S, H, D] → [B, H*D, S] where it lies so, else → [B*H, S, D]
+    # for contiguous per-head tiles
+    heads = tuple((_to_rows if in_place else _to_bh)(x) for x in (q, k, v))
 
-    # [B, S, H, D] → [B*H, S, D] for contiguous per-head tiles.
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s,
-                                               x.shape[-1])
-
-    qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
-
-    if s // block_q == 1 and s // block_k == 1 and layout is None and \
-            seg is None and window is None and g == h:
+    if single:
         # whole sequence in one block: the online-softmax machinery is
         # pure overhead — run the specialized straight-softmax kernel
         _LAST_BLOCKS["fwd"] = (s, s)
         _LAST_BLOCKS["fwd_variant"] = "single"
         _log_first_dispatch()
         with scopes.scope("ds.flash_fwd"):
-            out, lse = _fwd_single(qb, kb, vb, causal, sm_scale, s, d,
+            out, lse = _fwd_single(*heads, causal, sm_scale, s, d,
                                    _interpret(), kbias=kbias, h=h,
                                    dropout_rate=dropout_rate, seed=seed)
         out, lse = _tag_residuals(out, lse)
-        out4 = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-        return out4, (qb, kb, vb, out, lse.reshape(b * h, s))
+        return _from_bh(out, h), (*heads, out, lse.reshape(b * h, s))
 
+    _HEADS["fwd"]["in_place" if in_place else "moved"] += 1
     _LAST_BLOCKS["fwd"] = (block_q, block_k)
     _LAST_BLOCKS["fwd_variant"] = "trapezoid" if causal else "dense"
     _log_first_dispatch()
     run, _LAST_GRIDS["fwd"], _LAST_MASKED["fwd"] = _fwd_call(
         b, s, h, g, d, q.dtype, block_q, block_k, causal, sm_scale,
         layout is not None, kbias is not None, dropout_rate,
-        seg is not None, window, _interpret(), mask_block)
-    out, lse = run(qb, kb, vb, *_optional_inputs(seg, layout, kbias, seed,
-                                                 dropout_rate))
+        seg is not None, window, _interpret(), mask_block, in_place)
+    out, lse = run(*heads, *_optional_inputs(seg, layout, kbias, seed,
+                                             dropout_rate))
     out, lse = _tag_residuals(out, lse)
-
-    out4 = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-    return out4, (qb, kb, vb, out, lse.reshape(b * h, s))
+    if in_place:
+        return _from_rows(out, h), (
+            *(x.reshape(b, h, d, s) for x in (*heads, out)),
+            lse.reshape(b * h, s))
+    return _from_bh(out, h), (*heads, out, lse.reshape(b * h, s))
 
 
 # ---------------------------------------------------------------------------
@@ -1641,8 +1746,16 @@ def _dq_block(qi, ki, n_k, block_q, block_k, causal):
 def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
                     n_k=None, use_seg=False, use_mask=False,
                     use_bias=False, dropout_rate=0.0, compact=False,
-                    fused=False):
-    """dk and dv of a key column, and with `fused` dq as well."""
+                    fused=False, by_rows=False):
+    """dk and dv of a key column, and with `fused` dq as well.
+
+    `by_rows` (fused only): the blocks are a head's TRANSPOSED tensors
+    (`heads_in_place`): q^T and dO^T [D, block_q], k^T and v^T
+    [D, block_k] in, dk^T, dv^T and dq^T out. q^T and dO^T are the
+    operands the three matmuls on the tile's weights wanted, k^T is
+    dq^T's, the accumulators are the out blocks' layout, and k and v for
+    the two score matmuls are transposed once a key COLUMN: a step
+    transposes nothing."""
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -1672,10 +1785,15 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         first_q = 0
         last_q = pl.num_programs(2) - 1
 
+    if by_rows:
+        k_scr, v_scr = next(it), next(it)
+
     @pl.when(qi == first_q)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        if by_rows:
+            k_scr[...], v_scr[...] = k_ref[0].T, v_ref[0].T    # [BK, D]
 
     if fused:
         @pl.when(ki == 0)
@@ -1693,13 +1811,18 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         # tile-sized operand. Queries g0.. + gw against the keys before
         # `rows`
         cols = slice(g0, g0 + gw)
-        q, do = q_ref[0, cols, :], do_ref[0, cols, :]          # [gw, D]
-        sT = _dot(k_ref[0, :rows, :], q, _NT)                  # [rows, gw]
+        if by_rows:
+            q, do = q_ref[0, :, cols], do_ref[0, :, cols]      # [D, gw]
+            sT = _dot(k_scr[:rows, :], q, _NN)                 # [rows, gw]
+        else:
+            q, do = q_ref[0, cols, :], do_ref[0, cols, :]      # [gw, D]
+            sT = _dot(k_ref[0, :rows, :], q, _NT)              # [rows, gw]
         if masked:
             sT = tile.mask(sT, g0, 0, diagonal)
         pT = jnp.exp2(sT * (sm_scale * LOG2E)
                       - lse_ref[0, :, cols] * LOG2E)
-        dpT = _dot(v_ref[0, :rows, :], do, _NT)
+        dpT = _dot(v_scr[:rows, :], do, _NN) if by_rows else \
+            _dot(v_ref[0, :rows, :], do, _NT)
         pT_v = pT
         if dropout_rate > 0.0:
             keep = tile.keep(g0, 0, pT.shape)
@@ -1711,7 +1834,12 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         # reference's fp16 kernel precision
         pT_v = pT_v.astype(do.dtype)
         dsT = (pT * (dpT - delta_ref[0, :, cols])).astype(q.dtype)
-        if fused:
+        if by_rows:
+            dv_scr[:, :rows] += _dot(do, pT_v, _NT)
+            dk_scr[:, :rows] += _dot(q, dsT, _NT)
+            dq_scr[qi, :, cols] += _dot(
+                k_ref[0, :, :rows], dsT.astype(k_ref.dtype), _NN)
+        elif fused:
             # the tile as the weights of all three: [D, rows] += dO^T P,
             # [D, rows] += q^T dS, [D, gw] += k^T dS^T
             dv_scr[:, :rows] += _dot(do.T, pT_v, _NT)
@@ -1740,13 +1868,16 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
     @pl.when(qi == last_q)
     def _finalize():
         dk, dv = dk_scr[...] * sm_scale, dv_scr[...]
-        dk_ref[0] = (dk.T if fused else dk).astype(dk_ref.dtype)
-        dv_ref[0] = (dv.T if fused else dv).astype(dv_ref.dtype)
+        if fused and not by_rows:
+            dk, dv = dk.T, dv.T
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
 
     if fused:
         @pl.when(ki == _last_k(qi, n_k, block_q, block_k, causal))
         def _finalize_dq():
-            dq_ref[0] = (dq_scr[qi] * sm_scale).T.astype(dq_ref.dtype)
+            dq = dq_scr[qi] * sm_scale
+            dq_ref[0] = (dq if by_rows else dq.T).astype(dq_ref.dtype)
 
 
 # The dq pass of a sequence too long for the fused kernel's slab: the
@@ -1820,14 +1951,19 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 
 @functools.cache
 def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
-               use_seg, use_mask, use_bias, dropout_rate, fused, interpret):
+               use_seg, use_mask, use_bias, dropout_rate, fused, interpret,
+               by_rows=False):
     """The tiled backward at one call signature: (the function of its
     inputs that returns (dq, dk, dv), the grid of each kernel it runs by
     kind, their (masked, launched) tiles), built once a process as
     `_fwd_call` is. `fused`: one kernel ("bwd", under the scope
     `ds.flash_bwd`); else the dk/dv walk and the dq pass ("dkv", "dq").
     `dtypes` are q's, k's and v's; every kernel takes q, k, v, dO as
-    [B*H, S, D], lse and delta as [B*H, 1, S], then `_optional_inputs`."""
+    [B*H, S, D], lse and delta as [B*H, 1, S], then `_optional_inputs`.
+
+    `by_rows` (`heads_in_place`; the fused kernel's): q^T, k^T, v^T and dO^T
+    in, dq^T, dk^T and dv^T out, each [B, H*D, S], a head's (1, D, block)
+    at row block `head` of row `batch`; lse and delta as ever."""
     n_q, n_k = s // block_q, s // block_k
     compact = causal   # mirror the forward's trapezoidal schedule
     flags = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
@@ -1835,23 +1971,20 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
                  use_mask=use_mask, use_bias=use_bias,
                  dropout_rate=dropout_rate, compact=compact)
 
+    assert fused or not by_rows
+
+    def spec(ix, block, which):
+        return _head_spec(ix, by_rows, h, d, block, which)
+
     def specs(ix):
         """The inputs' BlockSpecs, index maps written as (bh, qi, ki)."""
         row = pl.BlockSpec((1, 1, block_q),
                            ix(lambda bh, qi, ki: (bh, 0, qi)))
-        in_specs = [
-            pl.BlockSpec((1, block_q, d),
-                         ix(lambda bh, qi, ki: (bh, qi, 0))),
-            pl.BlockSpec((1, block_k, d),
-                         ix(lambda bh, qi, ki: (bh, ki, 0))),
-            pl.BlockSpec((1, block_k, d),
-                         ix(lambda bh, qi, ki: (bh, ki, 0))),
-            pl.BlockSpec((1, block_q, d),
-                         ix(lambda bh, qi, ki: (bh, qi, 0))),
-            row, row]
-        return in_specs + _optional_specs(
-            ix, h, s, block_q, block_k, use_seg, use_mask, use_bias,
-            dropout_rate)
+        q_spec = spec(ix, block_q, lambda qi, ki: qi)
+        kv_spec = spec(ix, block_k, lambda qi, ki: ki)
+        return [q_spec, kv_spec, kv_spec, q_spec, row, row] + \
+            _optional_specs(ix, h, s, block_q, block_k, use_seg, use_mask,
+                            use_bias, dropout_rate)
 
     # the two schedules launch the same tiles in another order
     masked = masked_tile_count(
@@ -1867,22 +2000,24 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
         dkv_maps = ()
         dkv_grid = (bh, n_k, n_q)
     ixc = _index_adapter(compact, kv_major=True)
-    kv_spec = pl.BlockSpec((1, block_k, d),
-                           ixc(lambda bh, qi, ki: (bh, ki, 0)))
-    dq_shape = jax.ShapeDtypeStruct((bh, s, d), dtypes[0])
-    dkv_shapes = [jax.ShapeDtypeStruct((bh, s, d), dtypes[1]),
-                  jax.ShapeDtypeStruct((bh, s, d), dtypes[2])]
+    kv_spec = spec(ixc, block_k, lambda qi, ki: ki)
+    shape = (bh // h, h * d, s) if by_rows else (bh, s, d)
+    dq_shape = jax.ShapeDtypeStruct(shape, dtypes[0])
+    dkv_shapes = [jax.ShapeDtypeStruct(shape, dtypes[1]),
+                  jax.ShapeDtypeStruct(shape, dtypes[2])]
     if fused:
         acc = pltpu.VMEM((d, block_k), jnp.float32)   # dk^T, dv^T
         run = _tiled_call(
             "bwd", "ds.flash_bwd",
-            functools.partial(_bwd_dkv_kernel, n_q=n_q, fused=True, **flags),
+            functools.partial(_bwd_dkv_kernel, n_q=n_q, fused=True,
+                              by_rows=by_rows, **flags),
             compact, dkv_grid, specs(ixc),
-            [kv_spec, kv_spec, pl.BlockSpec(
-                (1, block_q, d), ixc(lambda bh, qi, ki: (bh, _dq_block(
-                    qi, ki, n_k, block_q, block_k, causal), 0)))],
+            [kv_spec, kv_spec, spec(ixc, block_q, lambda qi, ki: _dq_block(
+                qi, ki, n_k, block_q, block_k, causal))],
             [acc, acc, pltpu.VMEM((n_q, d, block_q), jnp.float32)]
-            + _key_column_scratch(block_k, use_seg, use_bias),
+            + _key_column_scratch(block_k, use_seg, use_bias)
+            # k and v of a column's k^T and v^T blocks
+            + [pltpu.VMEM((block_k, d), dtypes[i]) for i in (1, 2)] * by_rows,
             dkv_shapes + [dq_shape], dkv_maps, interpret,
             vmem_limit=flash_bwd_vmem_limit(s, d))
 
@@ -1911,7 +2046,7 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
         "dq", "ds.flash_bwd_dq",
         functools.partial(_bwd_dq_kernel, **flags), compact,
         dq_grid, specs(ix),
-        pl.BlockSpec((1, block_q, d), ix(lambda bh, qi, ki: (bh, qi, 0))),
+        spec(ix, block_q, lambda qi, ki: qi),
         [pltpu.VMEM((block_q, d), jnp.float32)], dq_shape, dq_maps,
         interpret)
 
@@ -1923,26 +2058,45 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
 
 def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
          kbias=None, dropout_rate=0.0, seed=None, seg=None):
+    """(dq, dk, dv) [B, S, H, D] from a forward's residuals and the
+    cotangent `g` of its out. The residuals of a forward that took the
+    heads in place ([B, H, D, S]: `_fwd`) go to the fused kernel as they
+    are, with dO^T, and dq^T, dk^T and dv^T come back ([B, H*D, S]:
+    `_to_rows`). A backward of one block, or of the two kernels of a
+    sequence over the slab's budget, moves them to [B*H, S, D] first, as
+    every other forward's residuals are, with dO, and moves dq, dk and dv
+    back."""
     qb, kb, vb, out, lse = res
-    bh, s, d = qb.shape
+    bdim, s, h, d = g.shape
+    bh = bdim * h
     block_q, block_k = _fit_block(block_q, s), _fit_block(block_k, s)
     lse = lse.reshape(bh, 1, s)     # row-vector layout, lanes = seq
     sm_scale = sm_scale_arg if sm_scale_arg is not None else \
         1.0 / math.sqrt(d)
+    single = s // block_q == 1 and s // block_k == 1 and layout is None \
+        and seg is None
+    fused = flash_dq_slab_admitted(s, d)
+    in_place = qb.ndim == 4 and fused and not single
+    if in_place:
+        do = g.transpose(0, 2, 3, 1)                           # [B, H, D, S]
+        # summed where dO and out lie: over D, the rows of a head's block
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=2).reshape(bh, 1, s)
+        qb, kb, vb, do = (x.reshape(bdim, h * d, s)
+                          for x in (qb, kb, vb, do))
+        back = functools.partial(_from_rows, h=h)
+    else:
+        if qb.ndim == 4:
+            qb, kb, vb, out = (x.transpose(0, 1, 3, 2).reshape(bh, s, d)
+                               for x in (qb, kb, vb, out))
+        # g arrives as [B, S, H, D]; reshape like the saved qb.
+        do = _to_bh(g)
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1).reshape(bh, 1, s)             # [BH, 1, S]
 
-    # g arrives as [B, S, H, D]; reshape like the saved qb.
-    bdim = g.shape[0]
-    h = bh // bdim
-    do = g.transpose(0, 2, 1, 3).reshape(bh, s, d)
+        back = functools.partial(_from_bh, h=h)
 
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(bh, 1, s)                # [BH, 1, S]
-
-    def from_bh(x):
-        return x.reshape(bdim, h, s, d).transpose(0, 2, 1, 3)
-
-    if s // block_q == 1 and s // block_k == 1 and layout is None and \
-            seg is None:
+    if single:
         _LAST_BLOCKS["dkv"] = _LAST_BLOCKS["dq"] = (s, s)
         _LAST_BLOCKS["bwd_variant"] = "single"
         with scopes.scope("ds.flash_bwd"):
@@ -1950,16 +2104,16 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
                                      sm_scale, s, d, _interpret(),
                                      kbias=kbias, h=h,
                                      dropout_rate=dropout_rate, seed=seed)
-        return from_bh(dq), from_bh(dk), from_bh(dv)
+        return back(dq), back(dk), back(dv)
 
-    fused = flash_dq_slab_admitted(s, d)
+    _HEADS["bwd"]["in_place" if in_place else "moved"] += 1
     _LAST_BLOCKS["dkv"] = _LAST_BLOCKS["dq"] = (block_q, block_k)
     _LAST_BLOCKS["bwd_variant"] = "fused-" * fused + \
         ("trapezoid" if causal else "dense")
     run, grids, masked = _bwd_calls(
         bh, s, h, d, (qb.dtype, kb.dtype, vb.dtype), block_q, block_k,
         causal, sm_scale, seg is not None, layout is not None,
-        kbias is not None, dropout_rate, fused, _interpret())
+        kbias is not None, dropout_rate, fused, _interpret(), in_place)
     # what the most recent backward launched, and nothing an earlier one did
     for kind in ("bwd", "dkv", "dq"):
         _LAST_GRIDS.pop(kind, None)
@@ -1968,7 +2122,7 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     _LAST_MASKED.update(dict.fromkeys(grids, masked))
     dq, dk, dv = run(qb, kb, vb, do, lse, delta, *_optional_inputs(
         seg, layout, kbias, seed, dropout_rate))
-    return from_bh(dq), from_bh(dk), from_bh(dv)
+    return back(dq), back(dk), back(dv)
 
 
 def _resolve_blocks(shape, causal, block_q, block_k, bwd_blocks):
@@ -1991,7 +2145,20 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     `(bwd_block_q, bwd_block_k)` tuple for the dkv/dq kernels, whose
     working set is larger than the forward's) only to pin it. The saved
     residuals (out, lse) are block-independent, so forward and backward
-    geometry can differ freely."""
+    geometry can differ freely.
+
+    Where the heads are read: a tiled call with as many KV heads as query
+    heads and a head dim of 16 rows or a multiple (64, 128, 256) takes
+    them IN PLACE (`heads_in_place`): the forward and the fused backward
+    read q^T, k^T, v^T, dO^T and write out^T, dq^T, dk^T, dv^T as
+    [B, H*D, S], which on a TPU is the layout a train step's
+    `[B, S, H, D]` tensors have, so no tensor is copied on either side of
+    either kernel, and the residuals are the operands themselves. MOVED
+    to [B*H, S, D] and back, by a copy of each: a call of one block (the
+    single-block kernels), fewer KV heads than query heads, and a
+    backward whose dq slab is over the budget (the two kernels; it moves
+    the residuals it was left). No option chooses: the shape does.
+    `ops.dispatch_report()["flash"]["heads"]` counts both."""
     (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
                                     bwd_blocks)
     return _flash_attention(q, k, v, causal, sm_scale, bq, bk, bwd)
@@ -2001,13 +2168,13 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
 def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
                      bwd_blocks):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k)
+    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, in_place=True)
     return out
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, bwd_blocks):
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    out, res = _fwd(q, k, v, causal, scale, block_q, block_k)
+    out, res = _fwd(q, k, v, causal, scale, block_q, block_k, in_place=True)
     return out, res
 
 

@@ -174,6 +174,110 @@ def test_compacted_dropout_deterministic_and_grad():
 
 
 # ---------------------------------------------------------------------------
+# the heads where the program holds them (`heads_in_place`)
+# ---------------------------------------------------------------------------
+#
+# `flash_attention`'s tiled call hands the kernels q^T, k^T, v^T (and
+# dO^T) as [B, H*D, S] and takes out^T (dq^T, dk^T, dv^T) back: the same
+# tile bodies on the transposed blocks. Against the same call through the
+# [B*H, S, D] copies (`_fwd` / `_bwd` without `in_place`).
+
+def _head_counts():
+    report = importlib.import_module(
+        "deeperspeed_tpu.ops").dispatch_report()["flash"]["heads"]
+    return {kind: dict(n) for kind, n in report.items()}
+
+
+def _counted_since(before):
+    return {kind: {where: n - before[kind][where] for where, n in c.items()}
+            for kind, c in _head_counts().items()}
+
+
+IN_PLACE_CASES = [
+    # [B, S, H, G, D], forward blocks, backward blocks, dtype, in place
+    ((1, 256, 16, 16, 64), (128, 128), (128, 128), jnp.bfloat16, True),
+    ((1, 256, 16, 16, 128), (128, 128), (128, 128), jnp.bfloat16, True),
+    # the 16k cell's blocks at a short sequence: block_q != block_k in
+    # the forward, another pair in the backward
+    ((1, 2048, 2, 2, 64), (1024, 512), (1024, 1024), jnp.bfloat16, True),
+    ((2, 768, 2, 2, 64), (256, 128), (128, 384), jnp.float32, True),
+    # fewer KV heads than query heads: the heads are moved
+    ((1, 256, 4, 2, 64), (128, 128), None, jnp.bfloat16, False),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,blocks,bwd_blocks,dtype,in_place", IN_PLACE_CASES,
+    ids=["h16-d64", "h16-d128", "blocks-of-16k", "dense-uneven-blocks",
+         "grouped-kv-falls-back"])
+def test_heads_in_place_match_the_moved_heads(shape, blocks, bwd_blocks,
+                                              dtype, in_place):
+    """Forward and gradients of `flash_attention` where it reads the heads
+    in place, against the same kernels on the moved heads; a shape the
+    rule does not admit goes through the copies and says so."""
+    B, S, H, G, D = shape
+    causal = dtype != jnp.float32     # the float32 case is the dense grid
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, w = (jax.random.normal(kk, (B, S, H, D), dtype) * 0.5
+            for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (B, S, G, D), dtype) * 0.5
+            for kk in ks[2:])
+    scale = 1.0 / math.sqrt(D)
+    before = _head_counts()
+
+    def counted(kind):
+        return _counted_since(before)[kind]
+
+    out = fa.flash_attention(q, k, v, causal, None, *blocks, bwd_blocks)
+    assert counted("fwd") == {"in_place": int(in_place),
+                              "moved": int(not in_place)}
+    moved, res = fa._fwd(q, k, v, causal, scale, *blocks)
+    assert counted("fwd")["moved"] == 1 + int(not in_place)
+    # the same sums of the same products: float32 differs by the order
+    # XLA's CPU dots take them in, bfloat16 by a rounding of that
+    tol = dict(atol=2e-6, rtol=2e-6) if dtype == jnp.float32 else \
+        dict(atol=1e-2, rtol=1e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(moved, np.float32), **tol)
+    if bwd_blocks is None:
+        return      # the backward kernels take one KV head a query head
+    grads = jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+        *a, causal, None, *blocks, bwd_blocks).astype(jnp.float32)
+        * w.astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+    assert counted("bwd") == {"in_place": 1, "moved": 0}
+    wants = fa._bwd(causal, None, *bwd_blocks, res, w)
+    assert counted("bwd") == {"in_place": 1, "moved": 1}
+    gtol = dict(atol=2e-5, rtol=2e-5) if dtype == jnp.float32 else \
+        dict(atol=2e-2, rtol=2e-2)
+    for got, want, name in zip(grads, wants, "qkv"):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), **gtol,
+                                   err_msg=f"d{name}")
+
+
+def test_a_backward_over_the_slab_budget_moves_the_heads(monkeypatch):
+    """The forward's residuals lie by rows; the two kernels of a sequence
+    over the slab's budget read [B*H, S, D], so that backward moves the
+    residuals and agrees with the fused one."""
+    q, k, v = make_qkv(b=1, s=512, h=2, d=64, seed=2)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+            *a, True, None, 128, 128) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+    fused = grads()
+    before = dict(fa._HEADS["bwd"])
+    monkeypatch.setattr(autotune, "_FLASH_DQ_SLAB_BUDGET", 0)
+    two = grads()
+    assert set(fa._LAST_GRIDS) == {"fwd", "dkv", "dq"}
+    assert fa._HEADS["bwd"] == {"in_place": before["in_place"],
+                                "moved": before["moved"] + 1}
+    for got, want, name in zip(two, fused, "qkv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=2e-5, err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
 # heads-batched (hb > 1) single-block kernels vs hb = 1 and the reference
 # (ADVICE r5: the hb > 1 fwd/bwd paths had no direct equivalence tests)
 # ---------------------------------------------------------------------------
@@ -995,9 +1099,13 @@ def _equations(jaxpr):
 # written out they were 1,751 / 2,544; the fused backward 265 = dkv's 199
 # + the fifth matmul, two small transposes and the slab's read and write in each of
 # its five groups, and dq's init and store; as two kernels dkv 199, dq
-# 182). A body that grows past it is set-up every run pays: shrink it, or
-# let its unrolling adapt to the shape (docs/long-context.md, "What a
-# body costs the host").
+# 182). Those are the bodies on MOVED heads ([B*H, S, D] blocks); where
+# the cells' calls read the heads in place (`heads_in_place`: transposed
+# blocks) the forward counts 845 / 842 / 842 (k's transpose and its
+# store) and the fused backward 258 (no transpose in a group, k's and v's
+# once a column). A body that grows past it is set-up every run pays:
+# shrink it, or let its unrolling adapt to the shape
+# (docs/long-context.md, "What a body costs the host").
 BACKWARD_BUDGET = {"ds.flash_bwd": 340, "ds.flash_bwd_dkv": 260,
                    "ds.flash_bwd_dq": 240}
 SETUP_CASES = [
@@ -1037,13 +1145,75 @@ def _built_since(before):
             for kind, (n, _) in fa._BODY_BUILDS.items()}
 
 
+def test_a_train_step_reads_the_heads_in_place_and_a_prefill_moves_them():
+    """`dispatch_report()["flash"]["heads"]`: a traced train step of a tiny
+    Pythia (two layers, two heads of 64, 2,048 tokens: two blocks) counts
+    2 x layers tiled calls in place, a forward and a backward a layer, and
+    none through the copies; a serving prefill's attention (the segmented
+    forward, as `InferenceEngine._prefill_fn` calls it) counts the
+    reverse."""
+    from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
+                                                  causal_attention)
+    layers = 2
+    cfg = GPTNeoXConfig(vocab_size=256, hidden_size=128, num_layers=layers,
+                        num_heads=2, max_seq_len=2048, rotary_pct=0.25)
+    model = GPTNeoX(cfg, use_pallas=True)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32)
+    before = _head_counts()
+    jax.make_jaxpr(jax.value_and_grad(model.loss_fn))(params,
+                                                      (tokens, tokens))
+    assert _counted_since(before) == {
+        "fwd": {"in_place": layers, "moved": 0},
+        "bwd": {"in_place": layers, "moved": 0}}
+    q = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16)
+    before = _head_counts()
+    jax.make_jaxpr(lambda q, k, v, seg: causal_attention(
+        q, k, v, use_pallas=True, segment_ids=seg))(q, q, q, tokens)
+    assert _counted_since(before) == {
+        "fwd": {"in_place": 0, "moved": 1},
+        "bwd": {"in_place": 0, "moved": 0}}
+
+
+def _transposes(jaxpr):
+    """The permutation of every `transpose` of a 4-D operand under it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "transpose" and \
+                len(eqn.params["permutation"]) == 4:
+            yield tuple(eqn.params["permutation"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _transposes(sub)
+
+
+@pytest.mark.parametrize("shape", [case[0] for case in SETUP_CASES],
+                         ids=["train_16k", "train_2k", "zero3_shard"])
+def test_the_training_call_never_moves_a_head(shape):
+    """`value_and_grad` of the training call at the train cells' shapes
+    holds no `[B, S, H, D] -> [B, H, S, D]` transpose (`_to_bh`'s: a copy
+    of the tensor on the chip) nor its inverse. What it holds is
+    `_to_rows`' (0, 2, 3, 1) and back (0, 3, 1, 2): the layout XLA keeps
+    such a tensor in, so a bitcast there (tests/test_tpu_compile.py reads
+    the compiled program for that)."""
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        _layers_loss, argnums=(0, 1, 2)))(spec, spec, spec)
+    found = set(_transposes(jaxpr.jaxpr))
+    assert found == {(0, 2, 3, 1), (0, 3, 1, 2)}, found
+
+
+@pytest.mark.parametrize("heads", ["in_place", "moved"])
 @pytest.mark.parametrize("shape,budget", SETUP_CASES,
                          ids=["train_16k", "train_2k", "zero3_shard"])
-def test_bodies_are_built_once_and_stay_small(shape, budget, backward):
+def test_bodies_are_built_once_and_stay_small(shape, budget, backward,
+                                              heads, monkeypatch):
     """Three unrolled layers' forward and backward, traced twice in one
     process, build each kernel body ONCE: two bodies, the forward's and
     the fused backward's (three where the backward is two kernels); and
-    each body stays under its written budget of equations."""
+    each body stays under its written budget of equations, on the heads
+    in place (what the cells run) and on moved heads (a shape the rule
+    does not admit; every masked, biased or segmented call)."""
+    if heads == "moved":
+        monkeypatch.setattr(fa, "heads_in_place", lambda h, g, d: False)
     spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     kinds = ("fwd", *backward)
     before = _fresh_account()

@@ -220,6 +220,110 @@ def test_flash_compiles_at_the_train_cells_shapes(on_chip, name, shape, fwd,
         assert 0 < masked < launched      # both bodies are in the kernel
 
 
+# The heads where the program holds them (PR 53). XLA lays a
+# `[B, S, H, D]` tensor of a train step out with the SEQUENCE minor
+# (physically [B, H, D, S]: the rotary fusions write q and k so and read
+# their gradients so), and the tiled kernels take q^T, k^T, v^T, dO^T as
+# [B, H*D, S] (`flash_attention.heads_in_place`): between the model and a
+# kernel there is then no copy of a tensor, where [B*H, S, D] operands
+# cost eight a layer (7.4% of `train_2k`'s step, more around them).
+
+def _copies(text, elements):
+    """The `copy` instructions of a compiled program, and the `transpose`s
+    that permute anything, whose result holds `elements` elements: a
+    tensor of the attention."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = \w+\[([0-9,]*)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if not m or not m.group(1) or np.prod(
+                [int(n) for n in m.group(1).split(",")]) != elements:
+            continue
+        dims = re.search(r"dimensions=\{([0-9,]*)\}", line)
+        if m.group(2) == "copy" or dims.group(1).split(",") != sorted(
+                dims.group(1).split(",")):
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("name,shape,fwd,bwd", CELL_FLASH_SHAPES,
+                         ids=[c[0] for c in CELL_FLASH_SHAPES])
+def test_flash_call_moves_no_tensor_at_the_train_cells_shapes(on_chip, name,
+                                                              shape, fwd, bwd):
+    """Forward and gradients of the training call from tensors that lie
+    as a train step's do ([B, H, D, S]; the model's [B, S, H, D] is their
+    transpose): the compiled program is the two kernels and a reduction
+    (delta), with no copy and no transpose of a tensor. The same call on
+    the moved heads copies its operands and results."""
+    bq, bk = fwd or (None, None)
+    b, s, h, d = shape
+    held = [((b, h, d, s), BF16)] * 4
+
+    def call(attention):
+        def run(qT, kT, vT, wT):
+            def loss(*a):
+                out = attention(*(x.transpose(0, 3, 1, 2) for x in a))
+                return (out.transpose(0, 2, 3, 1).astype(jnp.float32)
+                        * wT.astype(jnp.float32)).sum()
+            return jax.grad(loss, argnums=(0, 1, 2))(qT, kT, vT)
+        return run
+
+    def in_place(q, k, v):
+        return fa.flash_attention(q, k, v, True, None, bq, bk, bwd)
+
+    text = on_chip(call(in_place), *held)
+    assert kernel_names(text) == {"ds.flash_fwd", "ds.flash_bwd"}
+    assert not _copies(text, b * s * h * d), _copies(text, b * s * h * d)
+
+    @jax.custom_vjp
+    def moved(q, k, v):
+        return moved_fwd(q, k, v)[0]
+
+    def moved_fwd(q, k, v):
+        (fq, fk), _ = fa._resolve_blocks(q.shape, True, bq, bk, bwd)
+        return fa._fwd(q, k, v, True, 1.0 / np.sqrt(d), fq, fk)
+
+    def moved_bwd(res, g):
+        _, blocks = fa._resolve_blocks(g.shape, True, bq, bk, bwd)
+        return fa._bwd(True, None, *blocks, res, g)
+
+    moved.defvjp(moved_fwd, moved_bwd)
+    # (eight a layer in the model; alone, XLA folds some into others)
+    assert len(_copies(on_chip(call(moved), *held), b * s * h * d)) >= 4
+
+
+def test_train_step_copies_no_attention_tensor(on_chip):
+    """Loss and gradients of one Pythia-410m layer at `train_2k`'s batch
+    (16 x 2,048 tokens, 16 heads of 64, the cell's remat policy),
+    compiled for the described v5e: no `copy` or `transpose` has the size
+    of q, k, v, out or a gradient of one. What XLA does copy there is the
+    QKV projection's result (three times the size, once a pass), before
+    the split: PERF.md, section 7."""
+    from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+    batch, seq, heads, hidden = 16, 2048, 16, 1024
+    cfg = GPTNeoXConfig(vocab_size=1024, hidden_size=hidden, num_layers=1,
+                        num_heads=heads, max_seq_len=seq, rotary_pct=0.25,
+                        param_dtype=BF16)
+    model = GPTNeoX(cfg, use_pallas=True)
+    model.remat_policy = "attn_residuals"
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+
+    def step(tokens, *leaves):
+        params = jax.tree_util.tree_unflatten(treedef, leaves)
+        return jax.value_and_grad(model.loss_fn)(params, (tokens, tokens))
+
+    moved_before = {kind: n["moved"] for kind, n in
+                    dispatch_report()["flash"]["heads"].items()}
+    text = on_chip(step, ((batch, seq), jnp.int32),
+                   *((leaf.shape, leaf.dtype) for leaf in leaves))
+    assert kernel_names(text) >= {"ds.flash_fwd", "ds.flash_bwd"}
+    moved = _copies(text, batch * seq * hidden)
+    assert not moved, moved
+    assert moved_before == {kind: n["moved"] for kind, n in
+                            dispatch_report()["flash"]["heads"].items()}
+
+
 # Both sides of `ops.autotune.flash_dq_slab_admitted`, at the blocks the
 # rule gives a v5e: the largest slabs it admits (8 MiB: 32k tokens at head
 # dim 64, 16k at 128) run the fused backward, and the next sequence up
