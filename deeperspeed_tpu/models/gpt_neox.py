@@ -2394,55 +2394,128 @@ def forward(cfg, params, tokens, use_pallas=True, remat_blocks=False,
     return logits
 
 
+# calls of the CE head traced in this process, by the rule that ran:
+# the custom_vjp's forward rule (loss and both gradients from one logits
+# tile a chunk) or the primal (loss only); `ops.dispatch_report()["ce_head"]`
+_CE_HEAD_TRACED = {"loss_and_grads": 0, "loss_only": 0}
+
+
+def _ce_head_chunks(x, labels, ignore_index, chunk_rows):
+    """The head's rows as the scan walks them: x[:, :-1] against
+    labels[:, 1:], padded with ignored rows to whole chunks."""
+    H = x.shape[-1]
+    xs = x[:, :-1, :].reshape(-1, H)
+    ts = labels[:, 1:].reshape(-1)
+    n_pad = (-xs.shape[0]) % chunk_rows
+    if n_pad:
+        xs = jnp.pad(xs, ((0, n_pad), (0, 0)))
+        ts = jnp.pad(ts, (0, n_pad), constant_values=ignore_index)
+    n_chunks = xs.shape[0] // chunk_rows
+    return (xs.reshape(n_chunks, chunk_rows, H),
+            ts.reshape(n_chunks, chunk_rows))
+
+
+def _ce_head_tile(xc, tc, w, ignore_index):
+    """One chunk's float32 logits tile `xc w^T` and what the loss takes
+    from it: (tile, lse, valid, safe labels, the chunk's summed -log p)."""
+    valid = tc != ignore_index
+    safe = jnp.where(valid, tc, 0)
+    logits = jnp.einsum("ch,vh->cv", xc, w,
+                        preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    # the label's logit as a row-dot against the gathered label
+    # embeddings ([chunk, H]) rather than a take_along_axis on the tile
+    # (a gather over 824 MB). The tile IS written to HBM, once a chunk:
+    # the matmul's fusion reduces the row max through its output and
+    # `exp`, the row sum and the gradients' matmuls read it back
+    picked = jnp.einsum("ch,ch->c", xc, w[safe],
+                        preferred_element_type=jnp.float32)
+    return logits, lse, valid, safe, -jnp.sum((picked - lse) * valid)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _ce_head(x, wte, labels, ignore_index, chunk_rows):
+    _CE_HEAD_TRACED["loss_only"] += 1
+    xs, ts = _ce_head_chunks(x, labels, ignore_index, chunk_rows)
+    w = wte.astype(x.dtype)
+
+    def body(carry, xt):
+        loss_sum, count = carry
+        _, _, valid, _, nll = _ce_head_tile(*xt, w, ignore_index)
+        return (loss_sum + nll, count + jnp.sum(valid)), None
+
+    (loss_sum, count), _ = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
+        (xs, ts))
+    return loss_sum / jnp.maximum(count, 1)
+
+
+def _ce_head_fwd(x, wte, labels, ignore_index, chunk_rows):
+    _CE_HEAD_TRACED["loss_and_grads"] += 1
+    B, S, H = x.shape
+    xs, ts = _ce_head_chunks(x, labels, ignore_index, chunk_rows)
+    w = wte.astype(x.dtype)
+    vocab = jax.lax.broadcasted_iota(jnp.int32, (1, w.shape[0]), 1)
+
+    def body(carry, xt):
+        loss_sum, count, dw = carry
+        xc, tc = xt
+        logits, lse, valid, safe, nll = _ce_head_tile(xc, tc, w,
+                                                      ignore_index)
+        # d(sum of -log p)/d(logits): at most 1 in magnitude, so it
+        # rounds to the operands' dtype without a scale; the one-hot is
+        # a compare inside the tile's consumers, not a scatter
+        d = ((jnp.exp(logits - lse[:, None]) - (vocab == safe[:, None]))
+             * valid[:, None]).astype(xc.dtype)
+        dx = jnp.einsum("cv,vh->ch", d, w,
+                        preferred_element_type=jnp.float32)
+        dw = dw + jnp.einsum("cv,ch->vh", d, xc,
+                             preferred_element_type=jnp.float32)
+        return (loss_sum + nll, count + jnp.sum(valid), dw), \
+            dx.astype(xc.dtype)
+
+    (loss_sum, count, dw), dxs = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
+               jnp.zeros(w.shape, jnp.float32)), (xs, ts))
+    dx = dxs.reshape(-1, H)[:B * (S - 1)].reshape(B, S - 1, H)
+    dx = jnp.pad(dx, ((0, 0), (0, 1), (0, 0)))
+    count = jnp.maximum(count, 1).astype(jnp.float32)
+    return loss_sum / count, (dx, dw, count, jnp.zeros((0,), wte.dtype))
+
+
+def _ce_head_bwd(ignore_index, chunk_rows, res, g):
+    dx, dw, count, like_wte = res
+    with scopes.scope("ds.ce_head"):
+        scale = g.astype(jnp.float32) / count
+        return ((dx.astype(jnp.float32) * scale).astype(dx.dtype),
+                (dw * scale).astype(like_wte.dtype), None)
+
+
+_ce_head.defvjp(_ce_head_fwd, _ce_head_bwd)
+
+
 @scopes.scoped("ds.ce_head")
 def fused_lm_head_loss(x, wte, labels, ignore_index=-100, chunk_rows=4096):
     """Next-token cross entropy fused with the LM head, chunked over rows.
 
-    Never materializes the full [B, S, V] fp32 logits (6 GB at
-    batch 32 × seq 1024 × vocab 50k): each scan step computes one
-    [chunk, V] logits tile, reduces it to loss contributions, and
-    `jax.checkpoint` recomputes the tile in backward. This is the memory
-    behaviour of the reference's fused softmax-xent CUDA kernels
-    (`csrc/transformer/softmax_kernels.cu`), achieved as an XLA scan.
+    Never holds the full [B, S, V] fp32 logits (6 GB at batch 32 x seq
+    1024 x vocab 50k): a scan forms one [chunk, V] float32 tile at a time
+    (824 MB at 4,096 x 50,304), which XLA DOES write to HBM, once a chunk.
+    A `jax.custom_vjp`: differentiated, its forward rule takes the loss
+    AND both gradients from that one tile (`d = softmax - onehot`, then
+    `dx = d W` and `dW += d^T x` into a float32 carry: three matmuls a
+    chunk), keeps `dx` (x's dtype, unscaled), `dW` (float32) and the
+    valid count, and its backward rule only multiplies them by the
+    upstream cotangent over the count, in float32 (a float16 loss scale
+    meets the sums after the matmuls). Not differentiated (evaluation),
+    the same scan computes the loss alone, one matmul a chunk. Nothing is
+    recomputed. No forward-mode rule (`jax.jvp` of it raises).
 
     x: [B, S, H] final-norm hidden states; wte: [V, H]; labels: [B, S].
     chunk_rows is the scan tile: bigger tiles amortize scan overhead,
     smaller ones cap the [chunk, V] fp32 logits tile's HBM.
     """
-    B, S, H = x.shape
-    xs = x[:, :-1, :].reshape(-1, H)
-    ts = labels[:, 1:].reshape(-1)
-    n = xs.shape[0]
-    n_pad = (-n) % chunk_rows
-    if n_pad:
-        xs = jnp.pad(xs, ((0, n_pad), (0, 0)))
-        ts = jnp.pad(ts, (0, n_pad), constant_values=ignore_index)
-    n_chunks = xs.shape[0] // chunk_rows
-    xs = xs.reshape(n_chunks, chunk_rows, H)
-    ts = ts.reshape(n_chunks, chunk_rows)
-
-    def body(carry, xt):
-        loss_sum, count = carry
-        xc, tc = xt
-        valid = tc != ignore_index
-        safe = jnp.where(valid, tc, 0)
-        logits = jnp.einsum("ch,vh->cv", xc, wte.astype(xc.dtype),
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        # label logit as a row-dot against the gathered label embeddings
-        # ([chunk, H] — 6 MB) instead of take_along_axis on the logits
-        # tile: logsumexp is then the tile's ONLY consumer, so XLA can
-        # reduce it through the matmul output without materializing the
-        # [chunk, V] fp32 tile in HBM
-        picked = jnp.einsum("ch,ch->c", xc, wte[safe].astype(xc.dtype),
-                            preferred_element_type=jnp.float32)
-        ll = (picked - lse) * valid
-        return (loss_sum - jnp.sum(ll), count + jnp.sum(valid)), None
-
-    (loss_sum, count), _ = jax.lax.scan(
-        jax.checkpoint(body),
-        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), (xs, ts))
-    return loss_sum / jnp.maximum(count, 1)
+    return _ce_head(x, wte, labels, ignore_index, chunk_rows)
 
 
 @scopes.scoped("ds.ce_head")

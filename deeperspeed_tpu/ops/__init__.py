@@ -58,9 +58,16 @@ def dispatch_report():
     by one sort of the rows: `moe.layer.dropless_plan`); empty until
     that layer is traced. ``ssm``: {"scan" / "step": backend} of a
     state-space layer's selective scan and its one-token step
-    (`ops.pallas.ssm`). ``xla_on_tpu`` names every dispatcher
+    (`ops.pallas.ssm`). ``ce_head``: {"loss_and_grads": n, "loss_only":
+    n}, the calls of `models.gpt_neox.fused_lm_head_loss` traced in this
+    process by the rule that ran: its `custom_vjp`'s forward rule (the
+    loss and both gradients from one logits tile a chunk: a train step's)
+    or its primal (the loss alone: evaluation; also traced, and thrown
+    away, where `jax.checkpoint`, `scan` or `shard_map` stages the call
+    before it is differentiated). ``xla_on_tpu`` names every dispatcher
     that, on a TPU, took XLA where it has a kernel (`note_xla_on_tpu`).
     """
+    from ..models.gpt_neox import _CE_HEAD_TRACED
     from .pallas.decode_attention import _LAST_BACKEND
     from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
     from .pallas.flash_attention import (_BODY_BUILDS, _HEAD_PROJECTIONS,
@@ -81,6 +88,7 @@ def dispatch_report():
             "grouped_matmul": dict(_GMM_BACKEND),
             "moe": {"plan": dict(_PLANS_TRACED)},
             "ssm": dict(_SSM_BACKEND),
+            "ce_head": dict(_CE_HEAD_TRACED),
             "xla_on_tpu": sorted(_XLA_NOTED)}
 
 

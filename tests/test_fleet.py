@@ -752,7 +752,9 @@ class TestDispatchReport:
         report = dispatch_report()
         assert set(report) == {"flash", "attention", "decode_attention",
                                "quant_matmul", "grouped_matmul", "moe",
-                               "ssm", "xla_on_tpu"}
+                               "ssm", "ce_head", "xla_on_tpu"}
+        # the CE head's calls traced so far, by the rule that ran
+        assert set(report["ce_head"]) == {"loss_and_grads", "loss_only"}
         # a state-space layer's scan and step, by the backend that ran
         assert set(report["ssm"]) <= {"scan", "step"}
         # the dropless MoE layers traced so far, by the form of their plan

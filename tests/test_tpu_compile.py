@@ -1621,6 +1621,62 @@ def test_block_sparse_compiles(on_chip):
 
 
 # ---------------------------------------------------------------------------
+# the CE head: no kernel of ours, but what XLA makes of it is the cost
+# ---------------------------------------------------------------------------
+
+def _written_to_hbm(text, shape):
+    """The instructions OUTSIDE any fused computation (a while body's or
+    the entry's own: their results lie in HBM) whose result holds an
+    array of `shape`."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    found, nested = [], False
+    for line in text.splitlines():
+        header = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if header:
+            nested = header.group(1) in fused
+        elif not nested and re.match(
+                r"\s*(?:ROOT )?%\S+ = [^=]*?" + re.escape(shape)
+                + r"[^=]*? \w[\w\-]*\(", line) and \
+                " get-tuple-element(" not in line:
+            found.append(line.strip()[:140])
+    return found
+
+
+def test_ce_head_runs_three_matmuls_and_writes_the_tile_once(on_chip):
+    """Loss and gradients of the head alone at `train_2k`'s shape
+    ([16, 2048, 1024] against 50,304 words), compiled for the described
+    v5e. A chunk (the scan's body, in the text once) runs exactly three
+    convolutions over the vocabulary under `ds.ce_head`: the logits tile,
+    `dx` and `dW`. A fourth is a forward recomputed for the backward,
+    which is what `jax.checkpoint` round the body cost until PR 56. None
+    lies under `rematted_computation`, and the float32 [4096, 50304]
+    tile is written to HBM once a chunk, by the first matmul's fusion."""
+    from deeperspeed_tpu.models.gpt_neox import fused_lm_head_loss
+    batch, seq, hidden, vocab = 16, 2048, 1024, 50304
+
+    def step(x, wte, labels):
+        return jax.value_and_grad(
+            lambda x, w: fused_lm_head_loss(x, w, labels), (0, 1))(x, wte)
+
+    before = dispatch_report()["ce_head"]
+    text = on_chip(step, ((batch, seq, hidden), BF16),
+                   ((vocab, hidden), BF16), ((batch, seq), jnp.int32))
+    after = dispatch_report()["ce_head"]
+    assert after["loss_and_grads"] == before["loss_and_grads"] + 1
+    head = [line for line in text.splitlines() if "ds.ce_head" in line]
+    # (the label's logit is a row-dot, which XLA makes a reduction: every
+    # convolution of the head has the vocabulary as one of its dims)
+    matmuls = [line.strip()[:100] for line in head
+               if " convolution(" in line]
+    assert len(matmuls) == 3, \
+        "a fourth matmul over the vocabulary is a recomputed forward:\n" \
+        + "\n".join(matmuls)
+    assert not [line for line in head if "rematted_computation" in line]
+    tiles = _written_to_hbm(text, f"f32[4096,{vocab}]")
+    assert len(tiles) == 1 and " fusion(" in tiles[0], tiles
+
+
+# ---------------------------------------------------------------------------
 # four chips: GSPMD cannot partition a Mosaic kernel
 # ---------------------------------------------------------------------------
 
