@@ -1280,6 +1280,22 @@ def apply_rotary(q, k, cos, sin, rot_dim):
                          x[..., rot_dim:]], axis=-1) for x in (q, k))
 
 
+def _flash_route(shape, kv_heads, use_pallas=True, segment_ids=None,
+                 window=None, block=0, sm_scale=None):
+    """Which flash entry `causal_attention` takes for q `shape`
+    [B, S, H, D] over `kv_heads`: "training" (`flash_attention`, forward
+    and backward; the tiled call reads the heads in place), "segmented"
+    (`flash_attention_segmented`: packed documents, and every serving
+    forward's window, grouped KV heads, block mask or scale) or None
+    (XLA)."""
+    from ..ops.pallas.flash_attention import flash_attention_supported
+    if not (use_pallas and flash_attention_supported(shape)):
+        return None
+    grouped = window is not None or kv_heads != shape[2] or \
+        bool(block) or sm_scale is not None
+    return "segmented" if grouped or segment_ids is not None else "training"
+
+
 def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
                      window=None, block=0, sm_scale=None):
     """Causal MHA core on [B, S, H, D]; fp32 softmax accumulation.
@@ -1311,8 +1327,10 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
         tag_attn_residual
     from ..ops.pallas.flash_attention import (
         _LAST_BACKEND, flash_attention, flash_attention_segmented,
-        flash_attention_supported, note_xla_on_tpu)
-    if use_pallas and flash_attention_supported(q.shape):
+        note_xla_on_tpu)
+    route = _flash_route(q.shape, k.shape[2], use_pallas, segment_ids,
+                         window, block, sm_scale)
+    if route:
         _LAST_BACKEND["attention"] = "pallas"
 
         def kernel(q, k, v, *seg):
@@ -1323,9 +1341,7 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
                                                  mask_block=block)
             return flash_attention(q, k, v, True)
 
-        grouped = window is not None or k.shape[2] != q.shape[2] or \
-            bool(block) or sm_scale is not None
-        if grouped and segment_ids is None:
+        if route == "segmented" and segment_ids is None:
             # one kernel path for a window or grouped KV heads: the
             # segmented forward, every token of one document
             segment_ids = jnp.ones(q.shape[:2], jnp.int32)
@@ -1397,6 +1413,45 @@ def _heads_dot(x, w):
     return jax.lax.optimization_barrier(y) if plain else y
 
 
+def _split_heads_dots(x, a, heads, d):
+    """The fused QKV projection of `x` [B, S, K] as THREE dots against
+    the q, k and v columns of the ONE `qkv_w` leaf ([K, heads x (q | k |
+    v) x d]; `qkv_b` sliced the same way): (q, k, v), each
+    [B, S, heads, d]. The same operands and the same K-long dot product
+    an element as the fused dot and its split; what differs is where XLA
+    writes the results (`autotune.head_projection_split`)."""
+    from ..ops.pallas.flash_attention import _HEAD_PROJECTIONS
+    _HEAD_PROJECTIONS["split"] += 1
+    B, S, K = x.shape
+    w = a["qkv_w"].reshape(K, heads, 3, d)
+    b = a["qkv_b"].reshape(heads, 3, d) if "qkv_b" in a else None
+
+    def part(i):
+        y = _wmat(x, w[:, :, i].reshape(K, heads * d))
+        if b is not None:
+            y = y + b[:, i].reshape(heads * d).astype(y.dtype)
+        return y.reshape(B, S, heads, d)
+    return part(0), part(1), part(2)
+
+
+def _qkv_split(attn, shape, attn_fn, **call):
+    """Does a block whose attention is `causal_attention(q [shape], k, v,
+    **call)` (no `attn_fn` in its place) project its fused QKV weight
+    `attn["qkv_w"]` (as many KV heads as query heads) as three dots?
+    Where that call is the training flash call on heads in place and
+    `autotune.head_projection_split` says the shape gains."""
+    from ..ops.autotune import head_projection_split
+    from ..ops.pallas.flash_attention import tiled_in_place
+    from ..ops.pallas.quant_matmul import QuantizedWeight
+    if attn_fn is not None or "qkv_w" not in attn or \
+            isinstance(attn["qkv_w"], QuantizedWeight):
+        return False
+    heads = shape[2]
+    return head_projection_split(
+        shape[3], _flash_route(shape, heads, **call) == "training"
+        and tiled_in_place(shape, heads))
+
+
 def _gated_mlp(x, w_in, w_out, act):
     """(act(x Wgate) * (x Wup)) Wdown with `w_in` = [Wgate | Wup]."""
     hmid = _wmat(x, w_in)
@@ -1405,8 +1460,10 @@ def _gated_mlp(x, w_in, w_out, act):
 
 
 @scopes.scoped("ds.attn")
-def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
-    """ln1 + QKV projection + rotary; shared by training and decode."""
+def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local, split=False):
+    """ln1 + QKV projection + rotary; shared by training and decode.
+    `split` (`_block_core`'s to say: `_qkv_split`): the fused projection
+    as three dots, for the tiled flash kernels' reading in place."""
     B, S, _ = x.shape
     ln1 = norm(cfg, params["ln_attn"], x)
     if "q_w" in params["attn"]:
@@ -1427,10 +1484,14 @@ def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local):
             k = rms_norm(k, params["attn"]["k_norm"], cfg.layernorm_eps)
         q, k = apply_rotary(q, k, cos, sin, rot_dim)
         return q, k, v
-    qkv = _plus_bias(_heads_dot(ln1, params["attn"]["qkv_w"]),
-                     params["attn"], "qkv_b")
-    qkv = qkv.reshape(B, S, nh_local, 3 * cfg.head_dim)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    if split:
+        q, k, v = _split_heads_dots(ln1, params["attn"], nh_local,
+                                    cfg.head_dim)
+    else:
+        qkv = _plus_bias(_heads_dot(ln1, params["attn"]["qkv_w"]),
+                         params["attn"], "qkv_b")
+        qkv = qkv.reshape(B, S, nh_local, 3 * cfg.head_dim)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
     if getattr(cfg, "qk_norm", False):
         # over all of q's (k's) features at once, before the heads part
         def all_features(t, p):
@@ -1895,6 +1956,10 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     heads = spec.heads if spec is not None else cfg.num_heads
     window = cfg.attn_window \
         if spec is not None and spec.attn == "window" else None
+    # the attention core's call: `_qkv_split` reads it before q, k, v are
+    call = dict(use_pallas=use_pallas, segment_ids=segment_ids,
+                window=window, block=getattr(cfg, "generation_block", 0),
+                sm_scale=getattr(cfg, "attn_scale", None))
     if spec is not None and spec.attn == "latent":
         q_nope, q_rope, latent = _latent_rows(cfg, params, x, cos, sin,
                                               heads)
@@ -1902,7 +1967,11 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
         k, v = _latent_expand(cfg, params, latent, heads)
         kv = (latent,)
     else:
-        q, k, v = _block_qkv(cfg, params, x, cos, sin, rot_dim, heads // mp)
+        q, k, v = _block_qkv(
+            cfg, params, x, cos, sin, rot_dim, heads // mp,
+            split=_qkv_split(
+                params["attn"], (B, S, heads // mp, cfg.head_dim),
+                attn_fn, **call))
         kv = (k, v)
         if kind == "cross":
             (k, v), kv = shared["kv"], ()
@@ -1924,10 +1993,7 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
             attn = attn_fn(q, k, v) if segment_ids is None else \
                 attn_fn(q, k, v, segment_ids=segment_ids)
         else:
-            attn = causal_attention(
-                q, k, v, use_pallas=use_pallas, segment_ids=segment_ids,
-                window=window, block=getattr(cfg, "generation_block", 0),
-                sm_scale=getattr(cfg, "attn_scale", None))
+            attn = causal_attention(q, k, v, **call)
         if diff:
             attn = diff_combine(cfg, params["attn"], attn)
     if return_kv and ffn_quant is not None:

@@ -31,10 +31,15 @@ def dispatch_report():
     whole lane tiles, or of 64 with an even number of heads, is in place;
     a serving prefill moves them)}; ``attention``: {"attention" / "sparse_attention":
     backend} of the model-side dispatchers, and "head_projection":
-    {"plain": n, "folded": n}, the attention projections traced in this
-    process by the form their reshape to heads took (plain: kept out of
-    the dot, the weight read where it lies, every decode step's;
-    folded: XLA's to place, a train step's; `gpt_neox._heads_dot`);
+    {"plain": n, "folded": n, "split": n}, the attention projections
+    traced in this process by the form their reshape to heads took
+    (plain: kept out of the dot, the weight read where it lies, every
+    decode step's; folded: XLA's to place, a train step's at a head dim
+    of 128, a prefill's; `gpt_neox._heads_dot`) and, "split", the fused
+    QKV projections that ran as three dots against the q, k and v
+    columns of the one weight, whose results XLA writes where the tiled
+    flash kernels read them: a train step's at a head dim under 128
+    (`autotune.head_projection_split`);
     ``decode_attention``:
     {"decode": backend, "decode_kv": pool dtype — "int8" when the paged
     pools are quantized, "decode_heads_per_step" / "decode_pages_per_step":

@@ -13,7 +13,8 @@ no blocks; a caller passes blocks only to pin them (a test, a plan).
 
 The module also keeps the compile-time memory screen
 (`compiled_memory_stats`, `memory_feasible`, `hbm_bytes_limit`) and the
-one shape rule of a dot that is XLA's (`head_projection_plain`).
+two shape rules of a dot that is XLA's (`head_projection_plain`,
+`head_projection_split`).
 """
 
 import os
@@ -60,6 +61,35 @@ def head_projection_plain(rows, k):
     a prefill program is 0.80 ms shorter plain at 128 rows, 0.14 ms at
     1,536, 0.16 ms LONGER at 2,048."""
     return rows < k
+
+
+def head_projection_split(head_dim, tiled_in_place):
+    """Does the fused QKV projection run as THREE dots against the q, k
+    and v columns of its one weight (`gpt_neox._block_qkv`)? Where the
+    tiled flash kernels are about to read the heads in place
+    (`tiled_in_place`: `flash_attention.tiled_in_place` of the call
+    `causal_attention` hands to `flash_attention`, a train step's) and
+    the head dim is under one lane tile, the layout XLA holds such heads
+    in with the SEQUENCE minor.
+
+    The evidence is compiled text (one layer's loss and gradients for a
+    described v5e, `tests/test_tpu_compile.py`; PERF.md section 6, PR 59).
+    At 16 heads of 64 the one fused dot is a convolution that writes
+    `[B, S, H, 3, D]` feature-minor, and the per-head `[H, 3, D]`
+    interleave between it and the kernels costs a
+    `copy bf16[B,S,3*hidden]` a pass (16 x 2,048 tokens; an async copy
+    of it at 1 x 16,384) and the backward a pass that assembles dq, dk,
+    dv into one `dqkv`. Three dots have nothing between them and the
+    kernels: XLA writes each result sequence-minor, `[B, H*D, S]`, the
+    kernels' operand, and the program holds no copy of `B*S*hidden` elements
+    or of three times that. At 16 heads of 128 XLA lays a head dim of a
+    whole lane tile the other way, and the split gains nothing (4 x 2,048
+    tokens: six copies of `B*S*hidden` where the fused form has one of
+    `3*B*S*hidden`; forced there, the four-chip cell read 15,802 tok/s/chip
+    against 15,812 and 15,816): the fused form stays. A serving forward, a
+    decode step, a call of one block and the XLA fallback do not read the
+    heads in place and keep it too."""
+    return bool(tiled_in_place) and head_dim < 128
 
 
 # ---------------------------------------------------------------------------

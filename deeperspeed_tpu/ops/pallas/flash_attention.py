@@ -81,8 +81,10 @@ def _interpret():
 _LAST_BACKEND = {}
 _XLA_NOTED = set()
 # Attention projections traced in this process by the form their reshape
-# to heads took (`models/gpt_neox.py::_heads_dot`).
-_HEAD_PROJECTIONS = {"plain": 0, "folded": 0}
+# to heads took (`models/gpt_neox.py::_heads_dot`: "plain" | "folded"), and
+# the fused QKV projections that ran as three dots against the weight's
+# q, k and v columns (`_block_qkv`: "split").
+_HEAD_PROJECTIONS = {"plain": 0, "folded": 0, "split": 0}
 # Tiled flash calls traced in this process by where they found the heads:
 # "in_place" read q, k, v (and dO) and wrote out (dq, dk, dv) where the
 # program holds them (`heads_in_place`); "moved" went through a
@@ -109,6 +111,24 @@ def heads_in_place(h, g, d):
     tile bodies, which hold their tiles transposed already, lose their
     own transposes (`_fwd_kernel`, `_bwd_dkv_kernel`: `by_rows`)."""
     return g == h and d % 16 == 0
+
+
+def tiled_in_place(shape, g, causal=True):
+    """Will `flash_attention` on q `shape` [B, S, H, D] and `g` KV heads,
+    at the blocks `ops.autotune.flash_blocks` gives it, run the TILED
+    kernels on the heads in place (`heads_in_place`)? Not a shape the
+    kernels do not take, not a call of one block (`_fwd`'s single-block
+    kernel moves its heads)."""
+    _, s, h, d = shape
+    if not flash_attention_supported(shape):
+        return False
+    (block_q, block_k), _ = _resolve_blocks(shape, causal, None, None, None)
+    return not _one_block(s, block_q, block_k) and heads_in_place(h, g, d)
+
+
+def _one_block(s, block_q, block_k):
+    """Is a sequence of `s` one block of the forward, at fitted blocks?"""
+    return s // block_q == 1 and s // block_k == 1
 
 
 def note_xla_on_tpu(op, why):
@@ -1438,7 +1458,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
     b, s, h, d = q.shape
     g = k.shape[2]
     block_q, block_k = _fit_block(block_q, s), _fit_block(block_k, s)
-    single = s // block_q == 1 and s // block_k == 1 and layout is None \
+    single = _one_block(s, block_q, block_k) and layout is None \
         and seg is None and window is None and g == h
     in_place = in_place and not single and heads_in_place(h, g, d)
     # [B, S, H, D] → [B, H*D, S] where it lies so, else → [B*H, S, D]

@@ -261,7 +261,8 @@ def test_models_and_transformer_ops_import_no_block_picker(subtree):
                 names = [a.name for a in node.names]
             # the one thing a model asks of that module is a shape rule
             # of its own dots, no kernel's blocks
-            if names[1:] == ["head_projection_plain"]:
+            if names[1:] in (["head_projection_plain"],
+                             ["head_projection_split"]):
                 continue
             assert not any("autotune" in n for n in names), path
         for word in ("BLOCK_Q", "BLOCK_K", "DS_FLASH_BLOCKS",

@@ -763,7 +763,7 @@ class TestDispatchReport:
         assert isinstance(report["flash"], dict)
         # the attention projections traced so far, by their form
         assert set(report["attention"]["head_projection"]) == \
-            {"plain", "folded"}
+            {"plain", "folded", "split"}
 
     @pytest.mark.parametrize("rows,form", [(4, "plain"), (64, "folded")])
     def test_head_projection_counts_the_form_of_each_trace(self, rows,

@@ -292,20 +292,16 @@ def test_flash_call_moves_no_tensor_at_the_train_cells_shapes(on_chip, name,
     assert len(_copies(on_chip(call(moved), *held), b * s * h * d)) >= 4
 
 
-def test_train_step_copies_no_attention_tensor(on_chip):
-    """Loss and gradients of one Pythia-410m layer at `train_2k`'s batch
-    (16 x 2,048 tokens, 16 heads of 64, the cell's remat policy),
-    compiled for the described v5e: no `copy` or `transpose` has the size
-    of q, k, v, out or a gradient of one. What XLA does copy there is the
-    QKV projection's result (three times the size, once a pass), before
-    the split: PERF.md, section 7."""
+def _one_layer_step(batch, seq, heads, hidden, remat):
+    """(loss and gradients of one Pythia layer, its arguments' shapes): a
+    train cell's layer at the cell's batch a chip, bfloat16 parameters."""
     from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
-    batch, seq, heads, hidden = 16, 2048, 16, 1024
     cfg = GPTNeoXConfig(vocab_size=1024, hidden_size=hidden, num_layers=1,
                         num_heads=heads, max_seq_len=seq, rotary_pct=0.25,
                         param_dtype=BF16)
     model = GPTNeoX(cfg, use_pallas=True)
-    model.remat_policy = "attn_residuals"
+    if remat:
+        model.remat_policy = remat
     params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten(params)
 
@@ -313,15 +309,54 @@ def test_train_step_copies_no_attention_tensor(on_chip):
         params = jax.tree_util.tree_unflatten(treedef, leaves)
         return jax.value_and_grad(model.loss_fn)(params, (tokens, tokens))
 
-    moved_before = {kind: n["moved"] for kind, n in
-                    dispatch_report()["flash"]["heads"].items()}
-    text = on_chip(step, ((batch, seq), jnp.int32),
-                   *((leaf.shape, leaf.dtype) for leaf in leaves))
+    return step, [((batch, seq), jnp.int32),
+                  *((leaf.shape, leaf.dtype) for leaf in leaves)]
+
+
+def _heads_moved():
+    return {kind: n["moved"] for kind, n in
+            dispatch_report()["flash"]["heads"].items()}
+
+
+def _projections():
+    return dict(dispatch_report()["attention"]["head_projection"])
+
+
+# (name, batch, seq, heads, hidden, remat policy, the QKV projection's form)
+TRAIN_LAYERS = [
+    ("train_2k", 16, 2048, 16, 1024, "attn_residuals", "split"),
+    ("train_16k", 1, 16384, 16, 1024, "attn_residuals", "split"),
+    ("train_zero3_4c_shard", 4, 2048, 16, 2048, None, "folded"),
+]
+
+
+@pytest.mark.parametrize("name,batch,seq,heads,hidden,remat,form",
+                         TRAIN_LAYERS, ids=[c[0] for c in TRAIN_LAYERS])
+def test_train_step_copies_no_attention_tensor(on_chip, name, batch, seq,
+                                               heads, hidden, remat, form):
+    """Loss and gradients of one layer at a train cell's batch a chip,
+    compiled for the described v5e: no `copy` or `transpose` has the size
+    of q, k, v, out or a gradient of one. At 16 heads of 64 (Pythia-410m,
+    16 x 2,048 and 1 x 16,384 tokens, the cells' remat policy) none has
+    the size of the three together either: the QKV projection runs as
+    three dots against the weight's slices
+    (`autotune.head_projection_split`), whose results XLA writes where
+    the flash kernels read them, where one fused dot's result was copied
+    whole, once a pass, before its split (PERF.md section 6, PR 59). At 16
+    heads of 128 (`train_zero3_4c`'s shard of Pythia-1.4b, no remat) XLA
+    lays a head dim of a whole lane tile the other way, three dots would
+    cost six copies of `B*S*hidden`, and the rule keeps the ONE fused dot
+    and its one copy."""
+    step, args = _one_layer_step(batch, seq, heads, hidden, remat)
+    moved_before, projections = _heads_moved(), _projections()
+    text = on_chip(step, *args)
     assert kernel_names(text) >= {"ds.flash_fwd", "ds.flash_bwd"}
     moved = _copies(text, batch * seq * hidden)
     assert not moved, moved
-    assert moved_before == {kind: n["moved"] for kind, n in
-                            dispatch_report()["flash"]["heads"].items()}
+    whole = _copies(text, 3 * batch * seq * hidden)
+    assert len(whole) == (0 if form == "split" else 1), whole
+    assert moved_before == _heads_moved()
+    assert _projections() == {**projections, form: projections[form] + 1}
 
 
 # Both sides of `ops.autotune.flash_dq_slab_admitted`, at the blocks the
