@@ -11,12 +11,6 @@ import jax.numpy as jnp
 
 from deeperspeed_tpu.runtime.activation_checkpointing import checkpointing
 
-import pytest
-
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def setup_function(_):
     checkpointing.reset()

@@ -9,11 +9,6 @@ import jax
 import deeperspeed_tpu
 from deeperspeed_tpu.models.vision import AlexNet, alexnet_pipe
 
-import pytest
-
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
 
 STEPS = 5
 BATCH = 16

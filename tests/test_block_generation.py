@@ -30,6 +30,7 @@ from deeperspeed_tpu.models import gpt_neox as neox
 from deeperspeed_tpu.models.gpt_neox import GPTNeoXConfig, LayerSpec
 from deeperspeed_tpu.ops.pallas import decode_attention as da
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import jitted
 
 # the package re-exports the function under the module's name
 fa = importlib.import_module("deeperspeed_tpu.ops.pallas.flash_attention")
@@ -80,6 +81,17 @@ def setup():
     return c, model, params
 
 
+@pytest.fixture
+def one_program_a_pass(monkeypatch):
+    """`reference.generate` calls the module's `forward` once a pass, over
+    the prefix up to the pass's block: the same forward under `jax.jit`,
+    one program a length, where bare every operation is its own."""
+    plain = reference.forward
+    monkeypatch.setattr(
+        reference, "forward", lambda conf, params, tokens, block:
+        jitted(plain, conf, block=block)(params, tokens))
+
+
 def gen_for(c):
     return family.generation(c)
 
@@ -120,11 +132,12 @@ def replay(c, params, request, trace):
     seq = list(request.prompt) + list(request.generated)
     tokens = np.zeros(64, np.int32)
     tokens[:len(seq)] = seq
-    stats = reference.replay_stats(
-        c, params, jnp.asarray(tokens), len(seq),
+    # under one jit, as the cell's driver calls it (`n` traced)
+    stats = jitted(reference.replay_stats, c, block=BLOCK, head_rows=8)(
+        params, jnp.asarray(tokens), len(seq),
         jnp.asarray([p["start"] for p in passes]),
         jnp.asarray([p["tokens_in"] for p in passes]),
-        jnp.asarray([p["tokens"] for p in passes]), BLOCK, head_rows=8)
+        jnp.asarray([p["tokens"] for p in passes]))
     return {k: np.asarray(v) for k, v in stats.items()}, passes
 
 
@@ -138,9 +151,10 @@ def test_forward_logits_equal_the_references(setup):
     c, model, params = setup
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 23), 0, VOCAB)
     with jax.default_matmul_precision("highest"):
-        got = model.apply(params, tokens)
+        got = jitted(model.apply)(params, tokens)
         for row in range(2):
-            want = reference.forward(c, params, tokens[row], BLOCK)
+            want = jitted(reference.forward, c, block=BLOCK)(
+                params, tokens[row])
             np.testing.assert_allclose(got[row], want, atol=ATOL, rtol=0)
 
 
@@ -152,17 +166,16 @@ def test_each_fact_of_the_block_moves_the_logits(setup, what):
     c, model, params = setup
     tokens = jax.random.randint(jax.random.PRNGKey(2), (23,), 0, VOCAB)
     with jax.default_matmul_precision("highest"):
-        want = reference.forward(c, params, tokens, BLOCK)
+        want = jitted(reference.forward, c, block=BLOCK)(params, tokens)
         if what == "causal mask":
-            got = reference.forward(c, params, tokens, 1)
+            got = jitted(reference.forward, c, block=1)(params, tokens)
         else:
             stack = reference.stack(c, params)
             scale = {k: stack["attn"][k] for k in ("q_norm", "k_norm")}
             flat = dict(stack, attn=dict(stack["attn"], **{
                 k: jnp.ones_like(v) for k, v in scale.items()}))
-            got = reference.forward(
-                c, dict(params, stacks={"full4.experts": flat}), tokens,
-                BLOCK)
+            got = jitted(reference.forward, c, block=BLOCK)(
+                dict(params, stacks={"full4.experts": flat}), tokens)
     assert float(jnp.abs(got - want).max()) > 100 * ATOL
 
 
@@ -212,7 +225,7 @@ def test_block_passes_through_the_paged_cache_equal_the_full_forward(
 
 @pytest.mark.parametrize("threshold", [1.0, 0.5])
 def test_unmasking_order_and_delivered_prefix_equal_generate(
-        setup, threshold):
+        setup, one_program_a_pass, threshold):
     """The engine's passes, request by request, against the reference's
     `generate` (no cache, the whole prefix a pass): the same rows unmasked
     in the same passes with the same tokens, and the same delivered

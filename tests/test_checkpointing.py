@@ -12,10 +12,6 @@ import jax
 import deeperspeed_tpu
 from tests.simple_model import SimpleModel, random_batches
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 HIDDEN = 16
 
 

@@ -8,10 +8,6 @@ import pytest
 from deeperspeed_tpu.runtime.comm import NcclBackend, MpiBackend
 from deeperspeed_tpu.runtime.compression import CupyBackend
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def test_cupy_backend_pack_roundtrip():
     be = CupyBackend()

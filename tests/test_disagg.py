@@ -22,7 +22,6 @@ from deeperspeed_tpu.inference.handoff import (HandoffChannel,
                                                write_pages)
 from deeperspeed_tpu.inference.kv_cache import QuantizedPages
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
-from deeperspeed_tpu.models.gpt_neox import forward as neox_forward
 from deeperspeed_tpu.runtime import constants as c
 from deeperspeed_tpu.runtime.config import parse_inference_block
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
@@ -53,16 +52,6 @@ def tiny():
     return cfg, model, params
 
 
-def _teacher_forced(cfg, params, prompt, n):
-    toks = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = neox_forward(cfg, params, jnp.asarray([toks], jnp.int32),
-                              use_pallas=False)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
 
 
 def _no_leaks(cache):

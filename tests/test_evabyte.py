@@ -27,6 +27,7 @@ from deeperspeed_tpu.inference.scheduler import (ContinuousBatchingScheduler,
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, LayerSpec
 from deeperspeed_tpu.ops.pallas import eva as eva_ops
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import jitted, reference_rows
 
 VOCAB, HEADS, WINDOW, CHUNK, PAGE = 320, 8, 32, 4, 8
 # float32 rounding through two layers on logits of size ~1; a pooled row
@@ -107,8 +108,8 @@ def test_forward_matches_reference_over_three_and_a_half_windows(setup):
     c, model, params = setup
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 112), 0, VOCAB)
     with jax.default_matmul_precision("highest"):
-        got = model.apply(params, tokens)
-    want = reference.logits(c, params, tokens)
+        got = jitted(model.apply)(params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
     assert got.shape == (2, 112, HEADS * VOCAB)
     np.testing.assert_allclose(got, want, atol=LOGITS_ATOL)
 
@@ -119,7 +120,7 @@ def test_forward_refuses_a_fault(setup, fault):
     it."""
     c, model, params = setup
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 80), 0, VOCAB)
-    want = reference.logits(c, params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
     cfg = model.config
     if fault == "no_offset":
         cfg = dataclasses.replace(cfg, norm_unit_offset=False)
@@ -234,8 +235,8 @@ def test_served_logits_of_every_head_match_the_reference(setup, case):
     assert engine.stats["eva_windows_rolled"] > 0 or case == "ends_in_prefill"
     for r, n in zip(done, new):
         assert len(r.generated) == n and r.status == "ok"
-        row = jnp.asarray(list(r.prompt) + list(r.generated))[None]
-        want = np.asarray(reference.logits(c, params, row)[0])
+        want = reference_rows(reference, c, params,
+                              list(r.prompt) + list(r.generated), 512)
         rows = [t for t in engine.head_trace if t["request"] == r.request_id]
         assert len(rows) == n
         for t in rows:
@@ -258,8 +259,8 @@ def test_an_evicted_request_re_prefills_across_its_windows(setup):
     assert engine.stats["evictions"] > 0
     for r in done:
         row = jnp.asarray(list(r.prompt) + list(r.generated))[None]
-        want = np.asarray(reference.logits_at(
-            c, params, row,
+        want = np.asarray(jitted(reference.logits_at, c)(
+            params, row,
             (len(r.prompt) - 1 + np.arange(len(r.generated)))[None])[0])
         assert (want.argmax(-1) == np.asarray(r.generated)).all()
     assert engine.cache.num_free == engine.cache.num_pages - 1
@@ -423,8 +424,8 @@ def test_the_lookahead_step_reads_the_table_it_was_built_with(setup):
     assert first[0][0] == last[1][0]            # the pending page, spliced
     assert first[1][0] != last[1][0]            # and a fresh one pending
     row = jnp.asarray(list(done.prompt) + list(done.generated))[None]
-    want = np.asarray(reference.logits_at(
-        c, params, row, (19 + np.arange(30))[None])[0])
+    want = np.asarray(jitted(reference.logits_at, c)(
+        params, row, (19 + np.arange(30))[None])[0])
     assert (want.argmax(-1) == np.asarray(done.generated)).all()
 
 

@@ -175,10 +175,6 @@ def test_kbias_bf16():
 
 from deeperspeed_tpu.ops.pallas.flash_attention import flash_attention_train
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def _zeros_bias(b, s):
     return jnp.zeros((b, s), jnp.float32)
@@ -215,10 +211,11 @@ def test_dropout_unbiased():
     ref = np.asarray(reference_attention(q, k, v, False))
     acc = np.zeros_like(ref)
     n = 64
+    # one program for the 64 seeds, not 64 calls of every operation
+    dropped = jax.jit(lambda seed: flash_attention_train(
+        q, k, v, _zeros_bias(b, s), seed, False, None, 1024, 1024, 0.3))
     for i in range(n):
-        acc += np.asarray(flash_attention_train(
-            q, k, v, _zeros_bias(b, s), jnp.asarray([i], jnp.int32),
-            False, None, 1024, 1024, 0.3))
+        acc += np.asarray(dropped(jnp.asarray([i], jnp.int32)))
     err = np.abs(acc / n - ref).mean() / (np.abs(ref).mean() + 1e-9)
     assert err < 0.15, err
 

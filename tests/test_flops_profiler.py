@@ -15,12 +15,6 @@ from deeperspeed_tpu.profiling.flops_profiler.profiler import (
     profile_fn)
 from tests.simple_model import SimpleModel
 
-import pytest
-
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def test_profile_fn_counts_matmul_flops():
     a = jnp.ones((64, 128), jnp.float32)

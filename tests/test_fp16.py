@@ -12,10 +12,6 @@ from deeperspeed_tpu.ops.adam.fused_adam import FusedAdam
 from deeperspeed_tpu.ops.lamb.fused_lamb import FusedLamb
 from deeperspeed_tpu.runtime.fp16 import FP16_Optimizer, FP16_UnfusedOptimizer
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def tiny_params(dtype=jnp.float16):
     rng = jax.random.PRNGKey(0)

@@ -29,6 +29,7 @@ from deeperspeed_tpu.models.gpt_neox import GPTNeoX, LayerSpec
 from deeperspeed_tpu.moe.layer import moe_ffn_dropless
 from deeperspeed_tpu.ops.pallas import decode_attention
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import jitted, reference_rows
 
 VOCAB, PAGE = 128, 8
 # float32 rounding through three layers (and the nextn block) on logits of
@@ -144,16 +145,17 @@ def test_logits_agree_with_the_reference(setup, use_pallas):
     c, model, params, tokens = setup
     run = GPTNeoX(model.config, use_pallas=use_pallas)
     with jax.default_matmul_precision("highest"):
-        got = run.apply(params, tokens)
-    np.testing.assert_allclose(got, reference.logits(c, params, tokens),
+        got = jitted(run.apply)(params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
+    np.testing.assert_allclose(got, want,
                                atol=ATOL, rtol=0)
 
 
 def test_mtp_logits_agree_with_the_reference(setup):
     c, model, params, tokens = setup
     with jax.default_matmul_precision("highest"):
-        got = model.mtp_logits(params, tokens)
-    want = reference.mtp_logits(c, params, tokens)
+        got = jitted(model.mtp_logits)(params, tokens)
+    want = jitted(reference.mtp_logits, c)(params, tokens)
     assert got.shape == want.shape == (2, 23, VOCAB)
     np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
 
@@ -164,11 +166,12 @@ def test_the_tolerance_refuses_what_is_dropped_or_misplaced(setup):
     comparison would see a norm that is skipped, a rotary part that is
     not rotated and a bias that is ignored."""
     c, model, params, tokens = setup
-    want = reference.logits(c, params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
 
     def worst(p):
         with jax.default_matmul_precision("highest"):
-            return float(jnp.max(jnp.abs(model.apply(p, tokens) - want)))
+            got = jitted(model.apply)(p, tokens)
+            return float(jnp.max(jnp.abs(got - want)))
 
     ones = with_leaf(params, "latent4.experts", "attn", "kv_a_norm",
                      jnp.ones_like)
@@ -226,19 +229,19 @@ def test_the_shares_add_up_to_the_uncut_layer(setup):
                                  params["stacks"]["latent4.experts"]["mlp"])
     m = jax.random.normal(jax.random.PRNGKey(4), (40, 64))
     with jax.default_matmul_precision("highest"):
-        want = reference.moe_layer(c, mlp, m)
+        want = jitted(reference.moe_layer, c)(mlp, m)
         total = neox._gated_mlp(m, mlp["shared_in"], mlp["shared_out"],
                                 jax.nn.silu)
         rows = 0
         for lo, hi in ((0, 2), (2, 4), (4, 6), (6, 8)):
             share = dict(mlp, w_in=mlp["w_in"][lo:hi],
                          w_out=mlp["w_out"][lo:hi])
-            y, stats = moe_ffn_dropless(
-                share, m, 2, norm_topk_prob=True, held=(lo, hi), scale=1.8,
-                score="sigmoid")
-            np.testing.assert_allclose(
-                y, reference.moe_layer(c, mlp, m, held=(lo, hi),
-                                       shared=False), atol=1e-5, rtol=0)
+            y, stats = jitted(moe_ffn_dropless, top_k=2, norm_topk_prob=True,
+                              held=(lo, hi), scale=1.8,
+                              score="sigmoid")(share, m)
+            ref_share = jitted(reference.moe_layer, c, held=(lo, hi),
+                               shared=False)(mlp, m)
+            np.testing.assert_allclose(y, ref_share, atol=1e-5, rtol=0)
             total = total + y
             rows += float(stats[2, lo:hi].sum())
     np.testing.assert_allclose(total, want, atol=1e-5, rtol=0)
@@ -272,16 +275,16 @@ def test_the_loss_and_four_gradients_agree_with_the_reference(setup):
         return dict(p, mtp=dict(p["mtp"], proj=x["mtp_proj"]))
 
     with jax.default_matmul_precision("highest"):
-        got, got_grad = jax.value_and_grad(
-            lambda x: model.loss_fn(put(x), (tokens, tokens)))(
+        got, got_grad = jax.jit(jax.value_and_grad(
+            lambda x: model.loss_fn(put(x), (tokens, tokens))))(
             leaves(params))
-    want, want_grad = jax.value_and_grad(
-        lambda x: reference.loss(c, put(x), tokens, weight))(leaves(params))
+    want, want_grad = jax.jit(jax.value_and_grad(
+        lambda x: reference.loss(c, put(x), tokens, weight)))(leaves(params))
     assert abs(float(got) - float(want)) < ATOL
     # the MTP term is there: without it the loss is smaller by about
     # weight * ln(vocab)
-    plain = reference.loss(dict(c, num_nextn_predict_layers=0), params,
-                           tokens, weight)
+    plain = jitted(reference.loss, dict(c, num_nextn_predict_layers=0))(
+        params, tokens, weight)
     assert float(want) - float(plain) > 0.5 * weight * np.log(VOCAB)
     for name in got_grad:
         scale = float(jnp.max(jnp.abs(want_grad[name])))
@@ -298,11 +301,11 @@ def test_the_loss_and_four_gradients_agree_with_the_reference(setup):
 def _served_logit_shortfall(c, params, requests):
     worst = 0.0
     for r in requests:
-        row = jnp.asarray(list(r.prompt) + list(r.generated))[None]
-        lg = reference.logits(c, params, row)[0]
+        lg = reference_rows(reference, c, params,
+                            list(r.prompt) + list(r.generated), 512)
         at = len(r.prompt) - 1 + np.arange(len(r.generated))
         got = lg[at, np.asarray(r.generated)]
-        worst = max(worst, float(jnp.max(lg[at].max(-1) - got)))
+        worst = max(worst, float(np.max(lg[at].max(-1) - got)))
     return worst
 
 

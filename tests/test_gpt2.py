@@ -11,12 +11,6 @@ import deeperspeed_tpu
 from deeperspeed_tpu.models.gpt2 import (GPT2, GPT2Config, forward,
                                          init_params)
 
-import pytest
-
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def test_forward_shapes_and_tied_head():
     cfg = GPT2Config.tiny()

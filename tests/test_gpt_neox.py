@@ -13,10 +13,7 @@ from deeperspeed_tpu.models import gpt_neox
 from deeperspeed_tpu.parallel.mesh import build_mesh
 from deeperspeed_tpu.parallel.topology import ProcessTopology
 from deeperspeed_tpu.runtime.pipe import PipelineModule
-
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
+from tests.model.references import jitted
 
 CFG = gpt_neox.GPTNeoXConfig.tiny()
 
@@ -158,8 +155,8 @@ def test_generate_greedy_matches_full_forward():
     seq = np.asarray(prompt)
     ref = []
     for _ in range(N):
-        logits = np.asarray(forward(cfg, params, jnp.asarray(seq),
-                                    use_pallas=False))
+        logits = np.asarray(jitted(forward, cfg, use_pallas=False)(
+            params, jnp.asarray(seq)))       # one program a length
         nxt = logits[:, -1, :].argmax(-1).astype(np.int32)
         ref.append(nxt)
         seq = np.concatenate([seq, nxt[:, None]], axis=1)
@@ -206,13 +203,13 @@ def test_scan_blocks_matches_loop():
     cfg = dataclasses.replace(gpt_neox.GPTNeoXConfig.tiny(), num_layers=3)
     params = gpt_neox.init_params(cfg, jax.random.PRNGKey(0))
     toks = np.arange(2 * 32, dtype=np.int32).reshape(2, 32) % cfg.vocab_size
-    loop = gpt_neox.forward(cfg, params, toks, use_pallas=False)
-    scan = gpt_neox.forward(cfg, params, toks, use_pallas=False,
-                            scan_blocks=True)
+    loop = jitted(gpt_neox.forward, cfg, use_pallas=False)(params, toks)
+    scan = jitted(gpt_neox.forward, cfg, use_pallas=False,
+                  scan_blocks=True)(params, toks)
     np.testing.assert_allclose(np.asarray(scan), np.asarray(loop),
                                rtol=1e-5, atol=1e-5)
-    scan_r = gpt_neox.forward(cfg, params, toks, use_pallas=False,
-                              scan_blocks=True, remat_blocks=True)
+    scan_r = jitted(gpt_neox.forward, cfg, use_pallas=False,
+                    scan_blocks=True, remat_blocks=True)(params, toks)
     np.testing.assert_allclose(np.asarray(scan_r), np.asarray(loop),
                                rtol=1e-5, atol=1e-5)
 

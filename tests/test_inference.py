@@ -33,6 +33,7 @@ from deeperspeed_tpu.ops.pallas.decode_attention import (
     span_table, step_geometry)
 from deeperspeed_tpu.runtime.config import parse_inference_block
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import teacher_forced
 
 pytestmark = pytest.mark.serving
 
@@ -653,31 +654,42 @@ def _engine_config(**kw):
     return {"inference": block}
 
 
-def _teacher_forced(cfg, params, forward_fn, prompt, n, use_pallas=False):
-    toks = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = forward_fn(cfg, params, jnp.asarray([toks], jnp.int32),
-                            use_pallas=use_pallas)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+
+
+@pytest.fixture(scope="module")
+def tiny_neox():
+    """(config, model, params) of the tiny NeoX on the XLA path."""
+    cfg = GPTNeoXConfig.tiny()
+    model = GPTNeoX(config=cfg, use_pallas=False)
+    return cfg, model, model.init_params(jax.random.PRNGKey(1))
+
+
+@pytest.fixture(scope="module")
+def _plain_engine(tiny_neox):
+    _, model, params = tiny_neox
+    return InferenceEngine(model, config=_engine_config(), params=params)
+
+
+@pytest.fixture
+def plain_engine(_plain_engine):
+    """The module's one engine over `_engine_config()`, for a test that
+    only generates and compares: its programs compile once a module. It
+    comes back with nothing queued and every page in the pool."""
+    yield _plain_engine
+    assert not _plain_engine.scheduler.has_work
+    assert _plain_engine.cache.num_free == _plain_engine.cache.num_pages - 1
 
 
 class TestGreedyDecodeParity:
-    def test_gpt_neox_token_identical(self):
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(1))
-        eng = InferenceEngine(model, config=_engine_config(),
-                              params=params)
+    def test_gpt_neox_token_identical(self, tiny_neox, plain_engine):
+        cfg, _, params = tiny_neox
+        eng = plain_engine
         rng = np.random.default_rng(0)
         prompts = [list(rng.integers(1, cfg.vocab_size, size=n))
                    for n in (5, 11, 17, 30)]
         outs = eng.generate(prompts, max_new_tokens=6)
         for p, o in zip(prompts, outs):
-            assert o == _teacher_forced(cfg, params, neox_forward, p, 6)
+            assert o == teacher_forced(cfg, params, neox_forward, p, 6)
         # every page returned to the pool
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
@@ -692,31 +704,27 @@ class TestGreedyDecodeParity:
                    for n in (4, 9, 21)]
         outs = eng.generate(prompts, max_new_tokens=5)
         for p, o in zip(prompts, outs):
-            assert o == _teacher_forced(cfg, params, gpt2_forward, p, 5)
+            assert o == teacher_forced(cfg, params, gpt2_forward, p, 5)
 
-    def test_pallas_kernel_path_token_identical(self):
+    def test_pallas_kernel_path_token_identical(self, tiny_neox):
         """Force the interpreted Pallas kernel end-to-end on CPU: the
         acceptance pin runs through the real kernel, not the fallback."""
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(3))
+        cfg, model, params = tiny_neox
         eng = InferenceEngine(model, config=_engine_config(
             kernel="pallas", prefill_lengths=[16], num_pages=16),
             params=params)
         rng = np.random.default_rng(2)
         prompt = list(rng.integers(1, cfg.vocab_size, size=9))
         (out,) = eng.generate([prompt], max_new_tokens=4)
-        assert out == _teacher_forced(cfg, params, neox_forward, prompt, 4)
+        assert out == teacher_forced(cfg, params, neox_forward, prompt, 4)
         from deeperspeed_tpu.ops.pallas.decode_attention import \
             _LAST_BACKEND
         assert _LAST_BACKEND["decode"] == "pallas"
 
-    def test_eviction_preserves_greedy_tokens(self):
+    def test_eviction_preserves_greedy_tokens(self, tiny_neox):
         """A request evicted mid-flight re-prefills its full context and
         must still emit the exact greedy continuation."""
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(4))
+        cfg, model, params = tiny_neox
         # 4 usable pages of 16 = 64 tokens; two 30-token prompts force
         # an eviction when the older request outgrows its bucket
         eng = InferenceEngine(model, config=_engine_config(
@@ -727,13 +735,11 @@ class TestGreedyDecodeParity:
         pb = list(rng.integers(1, cfg.vocab_size, size=30))
         outs = eng.generate([pa, pb], max_new_tokens=6)
         assert eng.stats["evictions"] >= 1
-        assert outs[0] == _teacher_forced(cfg, params, neox_forward, pa, 6)
-        assert outs[1] == _teacher_forced(cfg, params, neox_forward, pb, 6)
+        assert outs[0] == teacher_forced(cfg, params, neox_forward, pa, 6)
+        assert outs[1] == teacher_forced(cfg, params, neox_forward, pb, 6)
 
-    def test_temperature_sampling_deterministic(self):
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(5))
+    def test_temperature_sampling_deterministic(self, tiny_neox):
+        cfg, model, params = tiny_neox
         outs = []
         for _ in range(2):
             eng = InferenceEngine(
@@ -742,27 +748,20 @@ class TestGreedyDecodeParity:
             outs.append(eng.generate([[5, 6, 7]], max_new_tokens=6)[0])
         assert outs[0] == outs[1]
 
-    def test_generate_drains_finished(self):
+    def test_generate_drains_finished(self, plain_engine):
         """Long-lived serving must not accumulate completed requests:
         generate() consumes pop_finished(), so repeated batches leave
         the scheduler's finished list empty."""
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        eng = InferenceEngine(model, config=_engine_config(),
-                              params=model.init_params(
-                                  jax.random.PRNGKey(11)))
+        eng = plain_engine
         for _ in range(3):
             eng.generate([[1, 2, 3], [4, 5]], max_new_tokens=2)
         assert eng.scheduler.finished == []
 
-    def test_eos_stops_early_and_frees_pages(self):
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(6))
-        eng = InferenceEngine(model, config=_engine_config(),
-                              params=params)
+    def test_eos_stops_early_and_frees_pages(self, tiny_neox, plain_engine):
+        cfg, _, params = tiny_neox
+        eng = plain_engine
         prompt = [3, 4, 5]
-        ref = _teacher_forced(cfg, params, neox_forward, prompt, 8)
+        ref = teacher_forced(cfg, params, neox_forward, prompt, 8)
         eos = ref[2]
         (out,) = eng.generate([prompt], max_new_tokens=8,
                               eos_token_id=eos)
@@ -771,12 +770,10 @@ class TestGreedyDecodeParity:
 
 
 class TestNoRecompiles:
-    def test_mixed_stream_zero_recompiles_after_warmup(self):
+    def test_mixed_stream_zero_recompiles_after_warmup(self, tiny_neox):
         """The acceptance pin: a mixed prefill/decode stream holds the
         compile count constant once the bucket ladder has warmed up."""
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(7))
+        cfg, model, params = tiny_neox
         eng = InferenceEngine(model, config=_engine_config(),
                               params=params)
         rng = np.random.default_rng(4)
@@ -792,10 +789,8 @@ class TestNoRecompiles:
         eng.generate(stream(1), max_new_tokens=5)    # same bucket coverage
         assert eng.compile_count() == warm
 
-    def test_compile_count_tracks_new_buckets(self):
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(8))
+    def test_compile_count_tracks_new_buckets(self, tiny_neox):
+        cfg, model, params = tiny_neox
         eng = InferenceEngine(model, config=_engine_config(),
                               params=params)
         eng.generate([[1, 2, 3]], max_new_tokens=2)
@@ -1037,10 +1032,8 @@ class TestBaseEngineInferenceAPI:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
 
-    def test_loss_fn_only_model_raises(self):
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(0))
+    def test_loss_fn_only_model_raises(self, tiny_neox):
+        cfg, model, params = tiny_neox
         eng, *_ = deeperspeed_tpu.initialize(
             model=model.loss_fn, model_parameters=params,
             config_params={"train_batch_size": 8,
@@ -1057,14 +1050,12 @@ class TestBaseEngineInferenceAPI:
 
 @pytest.mark.slow
 class TestServingSoak:
-    def test_open_loop_stream_soak(self):
+    def test_open_loop_stream_soak(self, tiny_neox):
         """A fixed-seed open-loop arrival stream over many steps: every
         request completes with its exact greedy continuation, the page
         pool drains to empty, and the compile count freezes after the
         warmup phase."""
-        cfg = GPTNeoXConfig.tiny()
-        model = GPTNeoX(config=cfg, use_pallas=False)
-        params = model.init_params(jax.random.PRNGKey(10))
+        cfg, model, params = tiny_neox
         eng = InferenceEngine(model, config=_engine_config(num_pages=48),
                               params=params)
         rng = np.random.default_rng(6)
@@ -1097,7 +1088,7 @@ class TestServingSoak:
                  if r.request_id in pending}    # warmup also finished
         assert len(by_id) == len(pending)
         for rid, prompt in list(pending.items())[::7]:  # spot-check
-            assert list(by_id[rid].generated) == _teacher_forced(
+            assert list(by_id[rid].generated) == teacher_forced(
                 cfg, params, neox_forward, prompt, 6)
 
 
@@ -1165,7 +1156,7 @@ class TestGracefulDrain:
         assert summary["inflight_abandoned"] == 0
         assert summary["unserved"] == 1           # p2 left for successor
         done = {r.request_id: r for r in eng.scheduler.pop_finished()}
-        assert list(done[r1].generated) == _teacher_forced(
+        assert list(done[r1].generated) == teacher_forced(
             cfg, params, neox_forward, p1, 4)
         # drained engine flushed its signal handlers
         assert eng._prev_handlers == {}

@@ -30,6 +30,7 @@ from deeperspeed_tpu.models import gpt_neox as neox
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig, LayerSpec
 from deeperspeed_tpu.moe.layer import moe_ffn_dropless
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import jitted, reference_rows
 
 VOCAB, WINDOW, PAGE = 128, 16, 8
 # float32 rounding through five layers on logits of size ~1; a bf16 router
@@ -136,8 +137,8 @@ def test_logits_agree_with_the_reference(setup, use_pallas):
     c, model, params, tokens = setup
     run = GPTNeoX(model.config, use_pallas=use_pallas)
     with jax.default_matmul_precision("highest"):
-        got = run.apply(params, tokens)
-    want = reference.logits(c, params, tokens)
+        got = jitted(run.apply)(params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
     np.testing.assert_allclose(got, want, atol=LOGITS_ATOL, rtol=0)
 
 
@@ -147,19 +148,20 @@ def test_bfloat16_weights_agree_at_a_written_tolerance(setup):
     run = GPTNeoX(dataclasses.replace(model.config,
                                       param_dtype=jnp.bfloat16),
                   use_pallas=False)
-    got = run.apply(bf16, tokens)
-    want = reference.logits(c, bf16, tokens)
+    got = jitted(run.apply)(bf16, tokens)
+    want = jitted(reference.logits, c)(bf16, tokens)
     err = float(jnp.max(jnp.abs(got - want)))
     assert LOGITS_ATOL < err < BF16_ATOL, err
 
 
 def test_the_tolerance_refuses_a_bf16_router_and_a_dropped_gate(setup):
     c, model, params, tokens = setup
-    want = reference.logits(c, params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
 
     def worst(p):
         with jax.default_matmul_precision("highest"):
-            return float(jnp.max(jnp.abs(model.apply(p, tokens) - want)))
+            got = jitted(model.apply)(p, tokens)
+            return float(jnp.max(jnp.abs(got - want)))
 
     def edit(stack, group, leaf, fn):
         stacks = dict(params["stacks"])
@@ -224,19 +226,19 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                  params["stacks"]["window6.experts"]["mlp"])
     m = jax.random.normal(jax.random.PRNGKey(4), (40, 64))
     with jax.default_matmul_precision("highest"):
-        want = reference.moe_layer(whole, mlp, m)
+        want = jitted(reference.moe_layer, whole)(mlp, m)
         shared = neox._gated_mlp(m, mlp["shared_in"], mlp["shared_out"],
                                  jax.nn.silu)
         total, rows = shared, 0
         for lo, hi in ((0, 4), (4, 8)):
             share = dict(mlp, w_in=mlp["w_in"][lo:hi],
                          w_out=mlp["w_out"][lo:hi])
-            y, stats = moe_ffn_dropless(share, m, 3, norm_topk_prob=True,
-                                        held=(lo, hi), scale=2.5)
+            y, stats = jitted(moe_ffn_dropless, top_k=3, norm_topk_prob=True,
+                              held=(lo, hi), scale=2.5)(share, m)
             total = total + y
             rows += float(stats[2, lo:hi].sum())
-            ref_share = reference.moe_layer(whole, mlp, m, held=(lo, hi),
-                                            shared=False)
+            ref_share = jitted(reference.moe_layer, whole, held=(lo, hi),
+                               shared=False)(mlp, m)
             np.testing.assert_allclose(y, ref_share, atol=1e-5, rtol=0)
     np.testing.assert_allclose(total, want, atol=1e-5, rtol=0)
     assert rows == 40 * 3                # every routed pair is held once
@@ -257,11 +259,11 @@ def _served_logit_shortfall(c, params, requests):
     reference's best, teacher-forced over prompt + served tokens."""
     worst = 0.0
     for r in requests:
-        row = jnp.asarray(list(r.prompt) + list(r.generated))[None]
-        lg = reference.logits(c, params, row)[0]
+        lg = reference_rows(reference, c, params,
+                            list(r.prompt) + list(r.generated), 128)
         at = len(r.prompt) - 1 + np.arange(len(r.generated))
         got = lg[at, np.asarray(r.generated)]
-        worst = max(worst, float(jnp.max(lg[at].max(-1) - got)))
+        worst = max(worst, float(np.max(lg[at].max(-1) - got)))
     return worst
 
 

@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+# 16k/32k shapes: `slow` by tier-1's rule (`pyproject.toml`)
 pytestmark = [pytest.mark.longctx, pytest.mark.slow]
 
 

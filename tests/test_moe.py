@@ -14,10 +14,6 @@ import deeperspeed_tpu
 from deeperspeed_tpu.moe import (MoELayer, moe_ffn_dense,
                                  moe_ffn_expert_parallel)
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 H, I, E = 16, 32, 4
 
 

@@ -20,6 +20,13 @@ from deeperspeed_tpu.moe.layer import _pick_span, _resolve_groups
 H, I, E = 16, 32, 8
 
 
+def dense(params, x, rng=None, **static):
+    """`moe_ffn_dense` under one `jax.jit`: called bare, every operation
+    of the dispatch is dispatched, and compiled, on its own."""
+    return jax.jit(lambda p, x, rng: moe_ffn_dense(p, x, rng=rng, **static))(
+        params, x, rng)
+
+
 def _params(rng, E=E):
     return MoELayer(H, I, E).init(rng)
 
@@ -31,8 +38,8 @@ def _params(rng, E=E):
 def test_sort_matches_einsum_dense(top_k, groups):
     params = _params(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (32, H), jnp.float32)
-    y_e, aux_e = moe_ffn_dense(params, x, top_k=top_k, groups=groups)
-    y_s, aux_s = moe_ffn_dense(params, x, top_k=top_k, groups=groups,
+    y_e, aux_e = dense(params, x, top_k=top_k, groups=groups)
+    y_s, aux_s = dense(params, x, top_k=top_k, groups=groups,
                                dispatch="sort")
     np.testing.assert_allclose(np.asarray(y_s), np.asarray(y_e),
                                rtol=2e-6, atol=2e-6)
@@ -45,8 +52,8 @@ def test_sort_matches_einsum_capacity_overflow():
     params = _params(jax.random.PRNGKey(0))
     params["gate"] = jnp.zeros_like(params["gate"]).at[:, 0].set(1.0)
     x = jnp.ones((16, H), jnp.float32)
-    y_e, _ = moe_ffn_dense(params, x, capacity_factor=E / 16)
-    y_s, _ = moe_ffn_dense(params, x, capacity_factor=E / 16,
+    y_e, _ = dense(params, x, capacity_factor=E / 16)
+    y_s, _ = dense(params, x, capacity_factor=E / 16,
                            dispatch="sort")
     np.testing.assert_allclose(np.asarray(y_s), np.asarray(y_e),
                                rtol=2e-6, atol=2e-6)
@@ -61,8 +68,8 @@ def test_sort_matches_einsum_with_jitter():
     x = jax.random.normal(jax.random.PRNGKey(3), (32, H), jnp.float32)
     kw = dict(top_k=2, groups=2, rng=jax.random.PRNGKey(7),
               jitter_eps=0.3)
-    y_e, aux_e = moe_ffn_dense(params, x, **kw)
-    y_s, aux_s = moe_ffn_dense(params, x, dispatch="sort", **kw)
+    y_e, aux_e = dense(params, x, **kw)
+    y_s, aux_s = dense(params, x, dispatch="sort", **kw)
     np.testing.assert_allclose(np.asarray(y_s), np.asarray(y_e),
                                rtol=2e-6, atol=2e-6)
     np.testing.assert_allclose(float(aux_s), float(aux_e), rtol=1e-6)
@@ -73,7 +80,7 @@ def test_sort_grads_match_einsum():
     x = jax.random.normal(jax.random.PRNGKey(4), (24, H), jnp.float32)
 
     def loss(p, dispatch):
-        y, aux = moe_ffn_dense(p, x, top_k=2, dispatch=dispatch)
+        y, aux = dense(p, x, top_k=2, dispatch=dispatch)
         return jnp.sum(y ** 2) + 0.01 * aux
 
     g_e = jax.grad(lambda p: loss(p, "einsum"))(params)
@@ -88,8 +95,8 @@ def test_sort_interpret_kernel_path():
     layer — the exact code path a TPU run takes."""
     params = _params(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(5), (16, H), jnp.float32)
-    y_e, _ = moe_ffn_dense(params, x, top_k=2)
-    y_k, _ = moe_ffn_dense(params, x, top_k=2, dispatch="sort",
+    y_e, _ = dense(params, x, top_k=2)
+    y_k, _ = dense(params, x, top_k=2, dispatch="sort",
                            gmm_backend="pallas")
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_e),
                                rtol=2e-6, atol=2e-6)
@@ -171,15 +178,15 @@ def test_renorm_kept_choices_restores_leaked_mass(dispatch):
     x = jnp.zeros((4, H), jnp.float32)
     x = x.at[0, 0].set(1.0).at[1, 0].set(1.0)
     x = x.at[2, 1].set(1.0).at[3, 1].set(1.0)
-    y_r, _ = moe_ffn_dense(params, x, top_k=2, capacity_factor=1.0,
+    y_r, _ = dense(params, x, top_k=2, capacity_factor=1.0,
                            renorm_kept_choices=True, dispatch=dispatch)
-    y_l, _ = moe_ffn_dense(params, x, top_k=2, capacity_factor=1.0,
+    y_l, _ = dense(params, x, top_k=2, capacity_factor=1.0,
                            dispatch=dispatch)
     # tokens 2-3 (overflowed second choice) change; tokens 0-1 don't
     diff = np.abs(np.asarray(y_r) - np.asarray(y_l)).max(axis=-1)
     assert diff[2] > 1e-6 and diff[3] > 1e-6
     assert diff[0] < 1e-7 and diff[1] < 1e-7
-    y_ref, _ = moe_ffn_dense(params, x, top_k=2, capacity_factor=1.0,
+    y_ref, _ = dense(params, x, top_k=2, capacity_factor=1.0,
                              renorm_kept_choices=True, dispatch="einsum")
     np.testing.assert_allclose(np.asarray(y_r), np.asarray(y_ref),
                                rtol=2e-6, atol=2e-6)
@@ -247,7 +254,7 @@ def test_sort_matches_einsum_expert_parallel(devices):
 
     # and both match the per-shard dense reference
     ref = jnp.concatenate([
-        moe_ffn_dense(params, x[r * 8:(r + 1) * 8], top_k=2, groups=2,
+        dense(params, x[r * 8:(r + 1) * 8], top_k=2, groups=2,
                       dispatch="sort")[0] for r in range(ep)])
     np.testing.assert_allclose(np.asarray(y_s), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)

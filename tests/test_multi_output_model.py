@@ -10,12 +10,6 @@ import jax.numpy as jnp
 
 import deeperspeed_tpu
 
-import pytest
-
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 class MultiOutputModel:
     """Two heads over a shared trunk; loss = w1*mse1 + w2*mse2."""

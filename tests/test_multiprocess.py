@@ -17,7 +17,7 @@ import sys
 import numpy as np
 import pytest
 
-# real multi-process workers: ~1-5 min each (fast lane: -m "not slow")
+# real multi-process workers: `slow` by tier-1's rule (`pyproject.toml`)
 pytestmark = pytest.mark.slow
 
 

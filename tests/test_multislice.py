@@ -330,9 +330,10 @@ class TestPackedWire:
                 x[0], e[0], "data", WORLD, valid=valid, packed=packed)
             return out[None], new_e[None]
 
-        f = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=(P("data"), P("data")),
-                      check_vma=False)
+        f = jax.jit(shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data")),
+                              out_specs=(P("data"), P("data")),
+                              check_vma=False))
         out, new_e = f(jnp.asarray(xs), jnp.asarray(errs))
         return np.asarray(out), np.asarray(new_e), xs, errs
 

@@ -18,9 +18,7 @@ from deeperspeed_tpu.runtime.swap_tensor.optimizer_swappers import (
 from deeperspeed_tpu.runtime.swap_tensor.partitioned_param_swapper import \
     AsyncPartitionedParameterSwapper
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = [pytest.mark.slow, pytest.mark.offload]
+pytestmark = pytest.mark.offload
 
 needs_aio = pytest.mark.skipif(not AsyncIOEngine.available(),
                                reason="no C++ toolchain for aio engine")

@@ -26,6 +26,7 @@ from benchmarks.reference import olmoe as reference
 from deeperspeed_tpu.models import gpt_neox as neox
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.moe.layer import dropless_geometry, moe_ffn_dropless
+from tests.model.references import jitted, reference_rows
 
 # the public config.json's keys at a small size: 16 experts of which 2 a
 # token, so that with 24 tokens some experts get none
@@ -103,12 +104,14 @@ def test_some_expert_gets_no_token_and_no_router_near_tie(setup):
 def test_logits_agree_with_the_reference(setup, norm_topk_prob):
     _, params, tokens = setup
     model = GPTNeoX(config(norm_topk_prob=norm_topk_prob), use_pallas=False)
-    ours = np.asarray(model.apply(params, tokens))
-    theirs = np.asarray(reference.logits(
-        dict(CONF, norm_topk_prob=norm_topk_prob), params, tokens))
+    ours = np.asarray(jitted(model.apply)(params, tokens))
+    theirs = np.asarray(jitted(
+        reference.logits, dict(CONF, norm_topk_prob=norm_topk_prob))(
+        params, tokens))
     assert np.abs(ours - theirs).max() <= LOGITS_ATOL
-    other = np.asarray(reference.logits(
-        dict(CONF, norm_topk_prob=not norm_topk_prob), params, tokens))
+    other = np.asarray(jitted(
+        reference.logits, dict(CONF, norm_topk_prob=not norm_topk_prob))(
+        params, tokens))
     assert np.abs(ours - other).max() > 100 * LOGITS_ATOL
 
 
@@ -118,19 +121,20 @@ def test_the_tolerance_refuses_a_bfloat16_pass(setup):
     model, params, tokens = setup
     low = jax.tree_util.tree_map(
         lambda p: p.astype(jnp.bfloat16) if p.ndim >= 2 else p, params)
-    ours = np.asarray(model.apply(low, tokens), np.float32)
-    theirs = np.asarray(reference.logits(CONF, params, tokens))
+    ours = np.asarray(jitted(model.apply)(low, tokens), np.float32)
+    theirs = np.asarray(jitted(reference.logits, CONF)(params, tokens))
     assert np.abs(ours - theirs).max() > 20 * LOGITS_ATOL
 
 
 def test_loss_with_its_aux_term_agrees_and_the_aux_term_counts(setup):
     model, params, tokens = setup
-    ours = float(model.loss_fn(params, (tokens, tokens)))
-    theirs = float(reference.loss(CONF, params, tokens, tokens))
+    ours = float(jitted(model.loss_fn)(params, (tokens, tokens)))
+    theirs = float(jitted(reference.loss, CONF)(params, tokens, tokens))
     # two float32 scalars of size ~6 from the same arithmetic
     assert abs(ours - theirs) <= 1e-5 * abs(theirs)
-    no_aux = float(reference.loss(dict(CONF, router_aux_loss_coef=0.0),
-                                  params, tokens, tokens))
+    no_aux = float(jitted(reference.loss,
+                          dict(CONF, router_aux_loss_coef=0.0))(
+        params, tokens, tokens))
     assert abs(theirs - no_aux) > 1e-3      # ~0.01 * (about 1)
 
 
@@ -145,9 +149,9 @@ def test_gradient_of_every_leaf_agrees_with_the_reference(setup):
     included: its gradient is exactly zero on both sides), the four
     norms' scales of each block, the embeddings and the head."""
     model, params, tokens = setup
-    ours = jax.grad(model.loss_fn)(params, (tokens, tokens))
-    theirs = jax.grad(
-        lambda p: reference.loss(CONF, p, tokens, tokens))(params)
+    ours = jax.jit(jax.grad(model.loss_fn))(params, (tokens, tokens))
+    theirs = jax.jit(jax.grad(
+        lambda p: reference.loss(CONF, p, tokens, tokens)))(params)
     names = []
     for (name, a), (_, b) in zip(_leaf_paths(ours), _leaf_paths(theirs)):
         a, b = np.asarray(a), np.asarray(b)
@@ -235,8 +239,8 @@ def _serve(model, params, prompts, max_new=6, **over):
 def _shortfall(params, request):
     """Worst (best logit - the served token's logit) of the reference's
     one full pass over prompt + served tokens."""
-    row = jnp.asarray([list(request.prompt) + list(request.generated)])
-    lg = np.asarray(reference.logits(CONF, params, row))[0]
+    lg = reference_rows(reference, CONF, params,
+                        list(request.prompt) + list(request.generated), 512)
     n_p = len(request.prompt)
     at = lg[n_p - 1:n_p - 1 + len(request.generated)]
     return float((at.max(-1) -
@@ -309,8 +313,8 @@ def test_one_train_batch_step_through_initialize(devices):
     toks = np.random.default_rng(0).integers(
         0, CONF["vocab_size"], (1, 8, 16), np.int32)
     loss = float(engine.train_batch(batch=(toks, toks)))
-    want = float(reference.loss(CONF, start, jnp.asarray(toks[0]),
-                                jnp.asarray(toks[0])))
+    want = float(jitted(reference.loss, CONF)(
+        start, jnp.asarray(toks[0]), jnp.asarray(toks[0])))
     assert np.isfinite(loss)
     # the engine means the loss over 8 data shards of one sequence each
     # where the reference takes one mean; the aux term is computed per

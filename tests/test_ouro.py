@@ -26,6 +26,7 @@ from deeperspeed_tpu.inference.kv_cache import PagedKVCache
 from deeperspeed_tpu.models import gpt_neox as neox
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig, LayerSpec
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import jitted, reference_rows
 
 VOCAB, PAGE, LAYERS, T = 128, 8, 3, 3
 # float32 rounding through 3 x 3 layers on logits of size ~1 (measured
@@ -90,12 +91,13 @@ def engine_for(model, params, **over):
 def test_forward_logits_equal_the_references(setup):
     c, model, params, tokens = setup
     with jax.default_matmul_precision("highest"):
-        got = model.apply(params, tokens)
-    want = reference.logits(c, params, tokens)
+        got = jitted(model.apply)(params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
     assert float(jnp.abs(want).max()) > 0.3
     np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
     # at the published threshold of 1 every token reads the last pass
-    assert (np.asarray(reference.exit_passes(c, params, tokens)) == T).all()
+    exits = jitted(reference.exit_passes, c)(params, tokens)
+    assert (np.asarray(exits) == T).all()
 
 
 @pytest.mark.parametrize("what", ["attn out norm", "mlp out norm",
@@ -105,7 +107,7 @@ def test_each_fact_of_the_loop_moves_the_logits(setup, what):
     sublayer's output, the final norm's scale (applied after EVERY
     pass), and the number of passes."""
     c, model, params, tokens = setup
-    want = reference.logits(c, params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
     stack = dict(params["stacks"]["full2.dense"])
     cfg = model.config
     if what == "attn out norm":
@@ -118,7 +120,7 @@ def test_each_fact_of_the_loop_moves_the_logits(setup, what):
     if what == "final norm":
         other["final_ln"] = {"scale": jnp.ones((64,))}
     with jax.default_matmul_precision("highest"):
-        got = neox.forward(cfg, other, tokens, use_pallas=False)
+        got = jitted(neox.forward, cfg, use_pallas=False)(other, tokens)
     assert float(jnp.abs(got - want).max()) > 100 * ATOL
 
 
@@ -130,15 +132,16 @@ def test_the_exit_gate_picks_the_references_pass(setup, threshold):
     c, model, params, tokens = setup
     c = conf(early_exit_threshold=threshold)
     looped = family.build_model(c, "float32", {"use_pallas": False})
-    want = np.asarray(reference.exit_passes(c, params, tokens))
-    z = jnp.stack([reference.passes(c, params, row) for row in tokens],
+    want = np.asarray(jitted(reference.exit_passes, c)(params, tokens))
+    z = jnp.stack([jitted(reference.passes, c)(params, row) for row in tokens],
                   axis=1)                                   # [T, B, S, h]
     _, got = neox.loop_exit(looped.config, params, z)
     np.testing.assert_array_equal(got, want)
     assert len(set(want.ravel().tolist())) == T
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(looped.apply(params, tokens),
-                                   reference.logits(c, params, tokens),
+        want = jitted(reference.logits, c)(params, tokens)
+        np.testing.assert_allclose(jitted(looped.apply)(params, tokens),
+                                   want,
                                    atol=ATOL, rtol=0)
 
 
@@ -206,11 +209,11 @@ def test_parameters_are_counted_once_and_cache_layers_a_pass(setup):
 # serving: prefill then decode through a paged cache a pass
 # ---------------------------------------------------------------------------
 
-def _teacher_forced(c, params, request):
+def _served_logits(c, params, request):
     """(the reference's logits at every served position, the served
     tokens)."""
-    row = jnp.asarray(list(request.prompt) + list(request.generated))[None]
-    lg = reference.logits(c, params, row)[0]
+    lg = reference_rows(reference, c, params,
+                        list(request.prompt) + list(request.generated), 512)
     at = len(request.prompt) - 1 + np.arange(len(request.generated))
     return lg[at], np.asarray(request.generated)
 
@@ -244,11 +247,11 @@ def test_prefill_then_decode_equals_the_references_full_forward(
     assert all(done[i].status == "ok" for i in ids)
     hist = np.zeros(T, int)
     for r in done.values():
-        lg, served = _teacher_forced(c, params, r)
+        lg, served = _served_logits(c, params, r)
         short = lg.max(-1) - lg[np.arange(len(served)), served]
         assert float(short.max()) <= ATOL
         row = jnp.asarray(list(r.prompt) + list(r.generated))[None]
-        t_star = np.asarray(reference.exit_passes(c, params, row))[0]
+        t_star = np.asarray(jitted(reference.exit_passes, c)(params, row))[0]
         hist += np.bincount(t_star[len(r.prompt) - 1:-1] - 1, minlength=T)
     stats = engine.serve_stats()
     assert stats["loop_exit_hist"] == hist.tolist()
@@ -292,7 +295,7 @@ def test_each_pass_keeps_its_own_rows_in_the_cache(setup):
         return rows.reshape(pool.shape[0], -1, 64)[:, :n]
 
     got = jnp.concatenate([held(engine.cache.k), held(engine.cache.v)], -1)
-    want = reference.cache_rows(c, params, jnp.asarray(tokens))
+    want = jitted(reference.cache_rows, c)(params, jnp.asarray(tokens))
     assert want.shape == (T * LAYERS, n, 2 * 2 * 32)
     np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
     for layer in range(LAYERS):
@@ -334,9 +337,10 @@ def test_a_bucket_under_the_flash_block_is_padded_not_sent_to_xla():
         return rows.reshape(pool.shape[0], -1, 128)[:, :n]
 
     got = jnp.concatenate([held(engine.cache.k), held(engine.cache.v)], -1)
-    want = reference.cache_rows(c, params, jnp.asarray(prompt))
+    want = jitted(reference.cache_rows, c)(params, jnp.asarray(prompt))
     np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
-    lg = np.asarray(reference.logits(c, params, jnp.asarray(prompt)[None]))
+    lg = np.asarray(jitted(reference.logits, c)(
+        params, jnp.asarray(prompt)[None]))
     first = request.generated[0]
     assert float(lg[0, -1].max() - lg[0, -1, first]) <= ATOL
 

@@ -23,6 +23,7 @@ from deeperspeed_tpu.runtime.packing import (
     PAD_SEGMENT_ID, PackedDataset, count_effective_targets,
     mask_cross_document_labels, pack_documents, packed_batch_token_stats,
     segment_relative_positions, synthetic_doc_mixture)
+from tests.model.references import jitted
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +274,10 @@ def _sp_case(mesh, mode, balance, causal=True, seed=10):
     seg = make_seg(b=2, s=128, n_docs=3, seed=seed + 1, pad=16)
     sp = SequenceParallel(mesh, axis="seq", mode=mode, causal=causal,
                           balance=balance)
-    out = sp(q, k, v, segment_ids=seg)
+    # under one jit, as every caller in the package holds it: called bare,
+    # each operation of each ring step is dispatched on its own to 8 devices
+    out = jax.jit(lambda q, k, v, seg: sp(q, k, v, segment_ids=seg))(
+        q, k, v, seg)
     ref = reference_segmented(q, k, v, seg, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -301,13 +305,13 @@ def test_ring_sp_segmented_grads(seq_mesh):
     seg = make_seg(b=1, s=128, n_docs=2, seed=51, pad=16)
     sp = SequenceParallel(seq_mesh, axis="seq", mode="ring",
                           causal=True, balance=True)
-    g_sp = jax.grad(
+    g_sp = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(sp(q, k, v, segment_ids=seg) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(
+        argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(reference_segmented(q, k, v, seg,
                                                     True) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_sp, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-3,
@@ -319,8 +323,8 @@ def test_sp_unsegmented_unchanged(seq_mesh):
     from deeperspeed_tpu.parallel.sequence import SequenceParallel
     q, k, v = make_qkv(b=1, s=128, h=8, d=16, seed=60)
     sp = SequenceParallel(seq_mesh, axis="seq", mode="ring", causal=True)
-    out_a = sp(q, k, v)
-    out_b = sp(q, k, v, segment_ids=None)
+    out_a = jax.jit(sp)(q, k, v)
+    out_b = jax.jit(lambda q, k, v: sp(q, k, v, segment_ids=None))(q, k, v)
     np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))
 
 
@@ -348,7 +352,7 @@ def test_packed_vs_padded_loss_pin():
 
     tok_p, seg_p = pack_documents(docs, S)
     assert tok_p.shape[0] == 1      # all three fit one row
-    packed_loss = model.loss_fn(
+    packed_loss = jitted(model.loss_fn)(
         params, (jnp.asarray(tok_p), jnp.asarray(tok_p),
                  jnp.asarray(seg_p)))
 
@@ -358,7 +362,7 @@ def test_packed_vs_padded_loss_pin():
     for i, d in enumerate(docs):
         tok_d[i, :d.size] = d
         seg_d[i, :d.size] = 1
-    padded_loss = model.loss_fn(
+    padded_loss = jitted(model.loss_fn)(
         params, (jnp.asarray(tok_d), jnp.asarray(tok_d),
                  jnp.asarray(seg_d)))
 
@@ -408,7 +412,7 @@ def test_gpt2_packed_vs_padded_loss_pin():
     docs = [rng.integers(1, 97, n, dtype=np.int32) for n in (30, 25)]
 
     tok_p, seg_p = pack_documents(docs, S)
-    packed_loss = model.loss_fn(
+    packed_loss = jitted(model.loss_fn)(
         params, (jnp.asarray(tok_p), jnp.asarray(tok_p),
                  jnp.asarray(seg_p)))
     tok_d = np.zeros((2, S), np.int32)
@@ -416,7 +420,7 @@ def test_gpt2_packed_vs_padded_loss_pin():
     for i, d in enumerate(docs):
         tok_d[i, :d.size] = d
         seg_d[i, :d.size] = 1
-    padded_loss = model.loss_fn(
+    padded_loss = jitted(model.loss_fn)(
         params, (jnp.asarray(tok_d), jnp.asarray(tok_d),
                  jnp.asarray(seg_d)))
     np.testing.assert_allclose(float(packed_loss), float(padded_loss),

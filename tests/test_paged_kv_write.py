@@ -12,8 +12,9 @@
 - The engine under a model-parallel mesh with the kernels forced: the
   write and the attention run per head shard, tokens unchanged.
 
-CPU, tiny sizes: results, never a time. `tests/test_tpu_compile.py`
-compiles the same kernels, and the engine's decode program, for a v5e.
+CPU, tiny sizes: results, never a time. `tests/test_tpu_compile_kernels.py`
+compiles the same kernels, and `tests/test_tpu_compile_serving.py` the
+engine's decode program, for a v5e.
 """
 
 import numpy as np

@@ -11,10 +11,6 @@ import jax.numpy as jnp
 from deeperspeed_tpu.ops.pallas.optimizer import (adam_flat_reference,
                                                   fused_adam_flat)
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def _rand_state(n, p_dtype=jnp.float32, seed=0):
     rng = np.random.default_rng(seed)

@@ -30,6 +30,7 @@ from deeperspeed_tpu.models import gpt_neox as neox
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig, LayerSpec
 from deeperspeed_tpu.ops.pallas import ssm as ssm_ops
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import jitted, reference_rows
 
 VOCAB, WINDOW, PAGE = 128, 16, 8
 # float32 rounding through eight layers on logits of size ~0.5; a dropped
@@ -108,11 +109,11 @@ def shortfall(c, params, requests):
     reference's best, teacher-forced over prompt + served tokens."""
     worst = 0.0
     for r in requests:
-        row = jnp.asarray(list(r.prompt) + list(r.generated))[None]
-        lg = reference.logits(c, params, row)[0]
+        lg = reference_rows(reference, c, params,
+                            list(r.prompt) + list(r.generated), 512)
         at = len(r.prompt) - 1 + np.arange(len(r.generated))
         got = lg[at, np.asarray(r.generated)]
-        worst = max(worst, float(jnp.max(lg[at].max(-1) - got)))
+        worst = max(worst, float(np.max(lg[at].max(-1) - got)))
     return worst
 
 
@@ -171,8 +172,8 @@ def test_logits_agree_with_the_reference(setup, use_pallas):
     c, model, params, tokens = setup
     run = GPTNeoX(model.config, use_pallas=use_pallas)
     with jax.default_matmul_precision("highest"):
-        got = run.apply(params, tokens)
-    want = reference.logits(c, params, tokens)
+        got = jitted(run.apply)(params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
     np.testing.assert_allclose(got, want, atol=LOGITS_ATOL, rtol=0)
 
 
@@ -199,9 +200,9 @@ WRONG = {
 @pytest.mark.parametrize("stack,leaf,fn", WRONG.values(), ids=WRONG.keys())
 def test_the_tolerance_refuses_a_wrong_fact(setup, stack, leaf, fn):
     c, model, params, tokens = setup
-    want = reference.logits(c, params, tokens)
+    want = jitted(reference.logits, c)(params, tokens)
     with jax.default_matmul_precision("highest"):
-        got = model.apply(_edit(params, stack, leaf, fn), tokens)
+        got = jitted(model.apply)(_edit(params, stack, leaf, fn), tokens)
     assert float(jnp.max(jnp.abs(got - want))) > 10 * LOGITS_ATOL
 
 

@@ -18,8 +18,8 @@ from tests.simple_model import (LinearLayer, mse_loss, random_batches,
                                 simple_pipeline_module,
                                 tied_pipeline_module)
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
+# passes and fits tier-1's rule (`pyproject.toml`, `slow`); `slow` for the
+# whole run's budget alone, with the mechanisms no cell runs (ROADMAP D19)
 pytestmark = pytest.mark.slow
 
 DIM = 16

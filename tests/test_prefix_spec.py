@@ -26,6 +26,7 @@ from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.models.gpt_neox import forward as neox_forward
 from deeperspeed_tpu.runtime.config import parse_inference_block
 from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
+from tests.model.references import teacher_forced
 
 pytestmark = pytest.mark.serving
 
@@ -45,16 +46,6 @@ def _engine_config(**kw):
     return {"inference": block}
 
 
-def _teacher_forced(cfg, params, forward_fn, prompt, n):
-    toks = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = forward_fn(cfg, params, jnp.asarray([toks], jnp.int32),
-                            use_pallas=False)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
 
 
 def _shared_prefix_prompts(vocab, seed=0, n=6, prefix_len=32, share=0.8):
@@ -502,7 +493,7 @@ class TestEngineSpeculative:
                    for n in (5, 17, 30)]
         outs = spec.generate(prompts, max_new_tokens=8)
         for p, o in zip(prompts, outs):
-            assert o == _teacher_forced(cfg, params, neox_forward, p, 8)
+            assert o == teacher_forced(cfg, params, neox_forward, p, 8)
         assert spec.stats["spec_steps"] > 0
         assert spec.stats["spec_proposed"] > 0
         # a random draft disagrees with a random target somewhere: the
@@ -519,7 +510,7 @@ class TestEngineSpeculative:
                    for n in (7, 21)]
         outs = spec.generate(prompts, max_new_tokens=7)
         for p, o in zip(prompts, outs):
-            assert o == _teacher_forced(cfg, params, gpt2_forward, p, 7)
+            assert o == teacher_forced(cfg, params, gpt2_forward, p, 7)
 
     @pytest.mark.slow
     def test_greedy_parity_int8_cache(self):

@@ -512,10 +512,10 @@ class TestCompressedComm:
                                                    WORLD)
             return out[None], new_e[None]
 
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("data"), P("data")),
-                      out_specs=(P("data"), P("data")),
-                      check_vma=False)
+        f = jax.jit(shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data")),
+                              out_specs=(P("data"), P("data")),
+                              check_vma=False))
         out, new_e = f(jnp.asarray(np.stack(xs)),
                        jnp.asarray(np.stack(errs)))
         ref_outs, ref_errs = compressed_reduce_scatter_host(xs, errs)
@@ -580,10 +580,10 @@ class TestCompressedComm:
                 x[0], we[0], se[0], "data", WORLD, n_valid=n_valid)
             return out[None], nwe[None], nse[None]
 
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("data"), P("data"), P("data")),
-                      out_specs=(P("data"), P("data"), P("data")),
-                      check_vma=False)
+        f = jax.jit(shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data"), P("data")),
+                              out_specs=(P("data"), P("data"), P("data")),
+                              check_vma=False))
         out, nwe, nse = f(jnp.asarray(xs), jnp.asarray(werr),
                           jnp.asarray(serr))
         # server errors are per-rank CHUNKS in the packed transport;
@@ -650,10 +650,10 @@ class TestEfGather:
             row_bar, new_err = jax.grad(f, argnums=(0, 1))(row[0], werr)
             return row_bar[None], new_err
 
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("data"), P("data"), P("data")),
-                      out_specs=(P("data"), P("data")),
-                      check_vma=False)
+        f = jax.jit(shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data"), P("data")),
+                              out_specs=(P("data"), P("data")),
+                              check_vma=False))
         row_bar, new_err = f(rows, werr, cots)
         dead = 1.0 - np.asarray(mask)
         # pad lanes of the compressed grad AND the error buffer: zero
@@ -693,10 +693,10 @@ class TestEfGather:
             row_bar, new_err = jax.grad(f, argnums=(0, 1))(row[0], werr)
             return row_bar[None], new_err
 
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("data"), P("data"), P("data")),
-                      out_specs=(P("data"), P("data")),
-                      check_vma=False)
+        f = jax.jit(shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data"), P("data")),
+                              out_specs=(P("data"), P("data")),
+                              check_vma=False))
         row_bar, new_err = f(rows, werr, cots)
         ref_outs, ref_errs = compressed_reduce_scatter_host(
             [cots[r] for r in range(WORLD)],

@@ -542,6 +542,9 @@ def _read_log(path):
 
 
 class TestDeterministicResume:
+    # three worker processes one after another, each with its own start-up
+    # and compiles: 75 s under the driver's six workers (PR 60)
+    @pytest.mark.slow
     def test_mid_iteration_kill_resumes_bit_exact(self, tmp_path):
         ref_dir = tmp_path / "ref"
         kill_dir = tmp_path / "kill"

@@ -190,37 +190,54 @@ def serve_texts(kernel, cfg=None):
     return prefill, decode
 
 
-def lower_all():
-    texts = {"train_xla": train_text(128, use_pallas=False),
-             "train_single_block": train_text(128, use_pallas=True)}
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("DS_FLASH_BLOCKS", "128,128")
-        mp.setenv("DS_FLASH_BWD_BLOCKS", "128,128")
-        texts["train"] = train_text(256, use_pallas=True)
-        mp.setattr(autotune, "_FLASH_DQ_SLAB_BUDGET", 0)
-        texts["train_two_kernels"] = train_text(256, use_pallas=True)
-    texts["prefill"], texts["decode"] = serve_texts("pallas")
-    texts["decode_xla"] = serve_texts("xla")[1]
-    texts["moe_prefill"], texts["moe_decode"] = serve_texts("pallas", MOE_CFG)
-    texts["plan_prefill"], texts["plan_decode"] = serve_texts("pallas",
-                                                              PLAN_CFG)
-    texts["latent_prefill"], texts["latent_decode"] = serve_texts(
-        "pallas", LATENT_CFG)
-    texts["eva_prefill"], texts["eva_decode"] = serve_texts("pallas",
-                                                            EVA_CFG)
-    return texts
+SERVED = {"": None, "moe_": MOE_CFG, "plan_": PLAN_CFG,
+          "latent_": LATENT_CFG, "eva_": EVA_CFG}
+
+
+def lower(program):
+    """{program: compiled text} of the one lowering that yields `program`
+    (an engine yields its prefill and its decode together)."""
+    if program in ("train_xla", "train_single_block"):
+        return {program: train_text(128, program == "train_single_block")}
+    if program in ("train", "train_two_kernels"):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("DS_FLASH_BLOCKS", "128,128")
+            mp.setenv("DS_FLASH_BWD_BLOCKS", "128,128")
+            if program == "train_two_kernels":
+                mp.setattr(autotune, "_FLASH_DQ_SLAB_BUDGET", 0)
+            return {program: train_text(256, use_pallas=True)}
+    if program == "decode_xla":
+        return {program: serve_texts("xla")[1]}
+    model = program[:program.rindex("_") + 1] if "_" in program else ""
+    prefill, decode = serve_texts("pallas", SERVED[model])
+    return {model + "prefill": prefill, model + "decode": decode}
+
+
+class Texts(dict):
+    """program -> compiled text, lowered when a test first reads it: a
+    case pays for its own program, not the first case for all fifteen."""
+
+    def __init__(self, scoped):
+        super().__init__()
+        self.scoped = scoped
+
+    def __missing__(self, program):
+        with pytest.MonkeyPatch.context() as mp:
+            if not self.scoped:
+                mp.setattr(scopes, "scope",
+                           lambda name: contextlib.nullcontext())
+            self.update(lower(program))
+        return self[program]
 
 
 @pytest.fixture(scope="module")
 def texts():
-    return lower_all()
+    return Texts(scoped=True)
 
 
 @pytest.fixture(scope="module")
 def texts_without_scopes():
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(scopes, "scope", lambda name: contextlib.nullcontext())
-        return lower_all()
+    return Texts(scoped=False)
 
 
 @pytest.mark.parametrize("program,name", [

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from deeperspeed_tpu.inference import InferenceEngine
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+from tests.model.references import model_rows
 
 pytestmark = pytest.mark.serving
 
@@ -108,8 +109,8 @@ def _assert_reference_tokens(model, params, log, temperature):
 
     def row_logits(req, g):
         if id(req) not in logits:
-            ctx = jnp.asarray([list(req.prompt) + list(req.generated)])
-            logits[id(req)] = np.asarray(model.apply(params, ctx))[0]
+            logits[id(req)] = model_rows(
+                model, params, list(req.prompt) + list(req.generated))
         return logits[id(req)][len(req.prompt) + g - 1]
 
     checked = 0

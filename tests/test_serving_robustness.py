@@ -10,6 +10,7 @@ Run the robustness subset alone with ``-m chaos``.
 """
 
 import contextlib
+import functools
 import json
 import time
 
@@ -33,6 +34,7 @@ from deeperspeed_tpu.runtime.config_utils import DeepSpeedConfigError
 from deeperspeed_tpu.runtime.fault_injection import (InjectedServingFault,
                                                      validate_fault_spec)
 from deeperspeed_tpu.utils.kv_retry import RetryingKVTransport
+from tests.model.references import teacher_forced
 
 pytestmark = [pytest.mark.serving, pytest.mark.chaos]
 
@@ -80,26 +82,21 @@ def _engine_config(**kw):
     return {"inference": block}
 
 
+@functools.lru_cache(maxsize=None)
+def _tiny_model():
+    """The module's one tiny NeoX and its params; an engine a test, since
+    most tests here break theirs on purpose."""
+    model = GPTNeoX(config=GPTNeoXConfig.tiny(), use_pallas=False)
+    return model, model.init_params(jax.random.PRNGKey(1))
+
+
 def _tiny_engine(monitor=None, **kw):
-    cfg = GPTNeoXConfig.tiny()
-    model = GPTNeoX(config=cfg, use_pallas=False)
-    params = model.init_params(jax.random.PRNGKey(1))
+    model, params = _tiny_model()
     eng = InferenceEngine(model, config=_engine_config(**kw),
                           params=params, monitor=monitor)
-    return eng, cfg, params
+    return eng, model.config, params
 
 
-def _teacher_forced(cfg, params, prompt, n):
-    toks = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = neox_forward(cfg, params,
-                              jnp.asarray([toks], jnp.int32),
-                              use_pallas=False)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -440,7 +437,8 @@ class TestQuarantineRetry:
         eng.run()
         (done,) = eng.scheduler.pop_finished()
         assert done.request_id == rid
-        assert done.generated == _teacher_forced(cfg, params, p, 6)
+        assert done.generated == teacher_forced(
+            cfg, params, neox_forward, p, 6)
         assert eng.stats["retries"] == 1
         assert eng.stats["requests_failed"] == 0
         assert eng.stats["lookahead_discarded"] == 0
@@ -474,7 +472,8 @@ class TestQuarantineRetry:
         eng.run()
         done = {r.request_id: r for r in eng.scheduler.pop_finished()}
         for rid, p, n in zip(ids, prompts, (8, 4)):
-            assert done[rid].generated == _teacher_forced(cfg, params, p, n)
+            assert done[rid].generated == teacher_forced(
+                cfg, params, neox_forward, p, n)
         assert eng.stats["requests_failed"] == 0
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
@@ -543,7 +542,7 @@ class TestQuarantineRetry:
         # max_attempts=2) yet completed exactly — the counter reset on
         # their next successful step kept them off the poison edge
         for p, o in zip(prompts, outs):
-            assert o == _teacher_forced(cfg, params, p, 8)
+            assert o == teacher_forced(cfg, params, neox_forward, p, 8)
         assert eng.stats["requests_failed"] == 0
 
     @pytest.mark.parametrize("in_flight", [0, 1], ids=[
@@ -585,7 +584,7 @@ class TestQuarantineRetry:
         done = {r.request_id: r for r in eng.scheduler.pop_finished()}
         outs = [list(done[i].generated) for i in sorted(done)]
         for p, o in zip(prompts, outs):
-            assert o == _teacher_forced(cfg, params, p, 6)
+            assert o == teacher_forced(cfg, params, neox_forward, p, 6)
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
     def test_mid_execution_prefill_death_skips_stale_decode(self):
@@ -626,7 +625,7 @@ class TestQuarantineRetry:
         for p, rid in ((p1, 0), (p2, 1)):
             assert done[rid].status == "ok"
             assert list(done[rid].generated) == \
-                _teacher_forced(cfg, params, p, 6)
+                teacher_forced(cfg, params, neox_forward, p, 6)
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
     def test_page_pool_pressure_forces_evictions(self):
@@ -643,7 +642,7 @@ class TestQuarantineRetry:
         outs = eng.generate(prompts, max_new_tokens=6)
         assert eng.stats["evictions"] >= 1
         for p, o in zip(prompts, outs):
-            assert o == _teacher_forced(cfg, params, p, 6)
+            assert o == teacher_forced(cfg, params, neox_forward, p, 6)
         # seized pages all returned
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
@@ -800,7 +799,8 @@ class TestProgramInFlight:
         assert victim.generated[:-1] == seen[1]
         done = self._finish(eng)
         for rid, p in enumerate(prompts):
-            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+            assert done[rid].generated == teacher_forced(
+                cfg, params, neox_forward, p, 8)
         assert eng.stats["decode_tokens"] + eng.stats["prefill_requests"] \
             == 16
         assert eng.cache.num_free == eng.cache.num_pages - 1
@@ -814,8 +814,8 @@ class TestProgramInFlight:
         assert reqs[0].generated == seen and reqs[0].pending == 0
         assert eng.stats["lookahead_discarded"] == 1
         done = self._finish(eng)
-        assert done[1].generated == _teacher_forced(cfg, params,
-                                                    prompts[1], 8)
+        assert done[1].generated == teacher_forced(
+            cfg, params, neox_forward, prompts[1], 8)
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
     def test_drain_finishes_what_is_in_flight(self):
@@ -827,7 +827,8 @@ class TestProgramInFlight:
         done = {r.request_id: r for r in eng.scheduler.pop_finished()}
         for rid, p in enumerate(prompts):
             assert done[rid].status == "ok"
-            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+            assert done[rid].generated == teacher_forced(
+                cfg, params, neox_forward, p, 8)
 
     def test_hot_swap_between_steps_settles_first(self):
         eng, cfg, params, prompts, reqs = self._midstream()
@@ -839,7 +840,8 @@ class TestProgramInFlight:
         assert all(r.pending == 0 for r in reqs)
         done = self._finish(eng)
         for rid, p in enumerate(prompts):
-            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+            assert done[rid].generated == teacher_forced(
+                cfg, params, neox_forward, p, 8)
         assert eng.compile_count() == len(eng._compiled)
 
 
@@ -882,7 +884,8 @@ class TestProgramInFlight:
         done = self._finish(eng)
         for rid, p in enumerate(prompts):
             assert done[rid].status == "ok"
-            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+            assert done[rid].generated == teacher_forced(
+                cfg, params, neox_forward, p, 8)
         assert eng.stats["quarantines"] == 1
         assert eng.stats["requests_failed"] == 0
         assert eng.cache.num_free == eng.cache.num_pages - 1
@@ -897,7 +900,8 @@ class TestProgramInFlight:
         assert (req.failures, req.pending, req.generated) == (1, 0, [])
         assert eng.stats["quarantines"] == 1 and not eng._inflight
         done = self._finish(eng)
-        assert done[0].generated == _teacher_forced(cfg, params, p, 4)
+        assert done[0].generated == teacher_forced(
+            cfg, params, neox_forward, p, 4)
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
     def test_dispatch_error_over_a_failing_readback_is_one_fault(self):
@@ -914,7 +918,8 @@ class TestProgramInFlight:
         assert all((r.failures, r.evictions) == (1, 1) for r in reqs)
         done = self._finish(eng)
         for rid, p in enumerate(prompts):
-            assert done[rid].generated == _teacher_forced(cfg, params, p, 8)
+            assert done[rid].generated == teacher_forced(
+                cfg, params, neox_forward, p, 8)
         assert eng.cache.num_free == eng.cache.num_pages - 1
 
     def test_failure_path_counts_each_phase_once(self):
@@ -1316,7 +1321,7 @@ class TestPreemptionInterleavings:
         for p, rid in zip(prompts, ids):
             assert done[rid].status == "ok"
             assert list(done[rid].generated) == \
-                _teacher_forced(cfg, params, p, 6)
+                teacher_forced(cfg, params, neox_forward, p, 6)
         assert eng.stats["quarantines"] >= 1
         assert eng.cache.num_free == eng.cache.num_pages - 1
         assert sorted(eng.cache._free) == \

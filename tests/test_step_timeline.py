@@ -637,21 +637,25 @@ def test_throughput_timer_is_a_rate_of_training(trained):
 
 
 def test_injected_train_stall_is_never_device_wait(monkeypatch, devices):
+    """One-sided, as the serve side's stall test is: a sleep returns late
+    under six workers (0.062 s for 0.05 s in a PR 46 run), never early."""
+    stall = 0.05
     monkeypatch.setenv(fi.ENV_VAR, json.dumps({"faults": [
-        {"kind": "stall", "step": 14, "seconds": 0.05}]}))
+        {"kind": "stall", "step": 14, "seconds": stall}]}))
     engine = train_engine(hidden=16)
     for batch in stacked(20, hidden=16):
         float(engine.train_batch(batch=batch))
-    stalled = [s for s in engine.timeline.slow if s["excess"] > 0.04]
+    stalled = [s for s in engine.timeline.slow
+               if s["excess"] > 0.8 * stall]
     assert [s["serial"] for s in stalled] == [15]
     slow = stalled[0]
     assert slow["key"] == "gas 1 fault"
-    assert slow["excess"] == pytest.approx(0.05, rel=0.2)
+    assert 0.8 * stall <= slow["excess"] < 3 * stall
     assert "device_wait" not in slow["held_by"]
     # the injector sleeps inside train_batch, under no span
     held = slow["held_by"]
     assert max(held, key=held.get) == "other"
-    assert held["other"] == pytest.approx(0.05, rel=0.2)
+    assert 0.8 * stall <= held["other"] <= slow["excess"]
 
 
 def test_with_a_block_the_train_spans_write_into_the_record(tmp_path,

@@ -20,10 +20,6 @@ from deeperspeed_tpu.runtime.zero.stage1 import (flat_sub_partitions,
                                                  get_group_alignment_padding,
                                                  sub_partition_sizes)
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 STAGES = {1: FP16_DeepSpeedZeroOptimizer_Stage1,
           2: FP16_DeepSpeedZeroOptimizer_Stage2,
           3: FP16_DeepSpeedZeroOptimizer_Stage3}

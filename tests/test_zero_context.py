@@ -13,12 +13,6 @@ from deeperspeed_tpu.runtime.zero.partition_parameters import (
     current_init_context, register_external_parameter,
     unregister_external_parameter)
 
-import pytest
-
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 def data_mesh():
     return Mesh(np.asarray(jax.devices()[:8]), ("data",))

@@ -11,10 +11,6 @@ import jax
 import deeperspeed_tpu
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 STEPS = 5
 
 

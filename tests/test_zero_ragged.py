@@ -18,10 +18,6 @@ import deeperspeed_tpu
 from deeperspeed_tpu.runtime.zero.partition_parameters import (
     FlatPad, ZeroShardingRules, flat_pad, flat_unpad)
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 # 1003 is not divisible by 2/4/8 in any dim; 7 neither.
 RAGGED_SHAPE = (1003, 7)
 DIM = RAGGED_SHAPE[1]

@@ -12,10 +12,6 @@ from deeperspeed_tpu.runtime.zero import (ContiguousMemoryAllocator,
                                           TiledLinear,
                                           memory_efficient_linear)
 
-# heavy jit/training integration file: excluded from the <3-min fast lane
-# (run the full suite, or -m slow, to include it)
-pytestmark = pytest.mark.slow
-
 
 @pytest.mark.parametrize("in_f,out_f,in_splits,out_splits", [
     (32, 48, 1, 1),
