@@ -29,7 +29,13 @@ def dispatch_report():
     [B*H, S, D] copy of every operand and result
     (`flash_attention.heads_in_place`: a training call at a head dim of
     whole lane tiles, or of 64 with an even number of heads, is in place;
-    a serving prefill moves them)}; ``attention``: {"attention" / "sparse_attention":
+    a serving prefill moves them), "k_turns": {"once_a_head": n,
+    "every_step": n}, the tiled forwards on heads in place traced in this
+    process by how often they turn a k^T block into the k the score
+    matmuls read: once a block and head, the head's turned k kept in VMEM
+    (`autotune.flash_k_slab_admitted`: a causal call whose [S, D] fits,
+    every train cell's), or every grid step (a dense grid, a sequence
+    over the budget)}; ``attention``: {"attention" / "sparse_attention":
     backend} of the model-side dispatchers, and "head_projection":
     {"plain": n, "folded": n, "split": n}, the attention projections
     traced in this process by the form their reshape to heads took
@@ -76,8 +82,8 @@ def dispatch_report():
     from .pallas.decode_attention import _LAST_BACKEND
     from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
     from .pallas.flash_attention import (_BODY_BUILDS, _HEAD_PROJECTIONS,
-                                         _HEADS, _LAST_BLOCKS, _LAST_MASKED,
-                                         _XLA_NOTED)
+                                         _HEADS, _K_TURNS, _LAST_BLOCKS,
+                                         _LAST_MASKED, _XLA_NOTED)
     from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
     from .pallas.grouped_matmul import _PLANS_TRACED
     from .pallas.quant_matmul import _LAST_BACKEND as _QMM_BACKEND
@@ -85,7 +91,8 @@ def dispatch_report():
     return {"flash": dict(_LAST_BLOCKS, masked_tiles=dict(_LAST_MASKED),
                           bodies_built={k: (n, round(t, 3)) for k, (n, t)
                                         in _BODY_BUILDS.items()},
-                          heads={k: dict(v) for k, v in _HEADS.items()}),
+                          heads={k: dict(v) for k, v in _HEADS.items()},
+                          k_turns=dict(_K_TURNS)),
             "attention": dict(_ATTN_BACKEND,
                               head_projection=dict(_HEAD_PROJECTIONS)),
             "decode_attention": dict(_LAST_BACKEND),

@@ -167,6 +167,29 @@ def flash_bwd_vmem_limit(s, d):
     return (16 << 20) + s * d * 4
 
 
+# The tiled forward on heads in place (`flash_attention.heads_in_place`)
+# reads k^T blocks and its score matmuls want k: it turns a block on the
+# block's first visit within the head and keeps the head's whole k, [S, D]
+# in the input's dtype, in VMEM while the row walk crosses it. VMEM lays a
+# minor dim under 128 out as a whole lane tile, so head dim 64 costs what
+# 128 does: 4 MiB at 16k tokens of bfloat16, 8 MiB at 32k, which a
+# described v5e compiles at the compiler's default limit at head dim 64
+# and 128 (tests/test_tpu_compile.py compiles both sides of the line);
+# 64k tokens turn each k block once a grid step, as every call did before.
+_FLASH_K_SLAB_BUDGET = 8 << 20
+
+
+def flash_k_slab_admitted(s, d, itemsize, causal):
+    """Does the by-rows forward keep a head's turned k in VMEM (one
+    transpose a k block and head) or turn its k block every grid step? A
+    fact of the call's shape and of nothing else. Causal calls alone: the
+    causal grid's second dimension is sequential, so a head's row walk
+    meets a block first where it turns it; the dense grid declares its q
+    rows `parallel`, and a core of a two-core chip could meet a block its
+    own rows never turned."""
+    return causal and s * max(d, 128) * itemsize <= _FLASH_K_SLAB_BUDGET
+
+
 def _flash_fit(blocks, s):
     """`blocks` fitted to sequence `s`, or None where no 128-multiple
     under a requested size divides it."""

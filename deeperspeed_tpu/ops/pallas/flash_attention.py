@@ -57,7 +57,7 @@ from ... import scopes
 from ...compat import CompilerParams
 from ..autotune import FLASH_BLOCK_K as BLOCK_K, FLASH_BLOCK_Q as BLOCK_Q, \
     fit_block as _fit_block, flash_blocks, flash_bwd_vmem_limit, \
-    flash_dq_slab_admitted
+    flash_dq_slab_admitted, flash_k_slab_admitted
 
 LANES = 128  # TPU minor-dim tile
 NEG_INF = -1e30
@@ -92,6 +92,12 @@ _HEAD_PROJECTIONS = {"plain": 0, "folded": 0, "split": 0}
 # ["flash"]["heads"]` reads it.
 _HEADS = {"fwd": {"in_place": 0, "moved": 0},
           "bwd": {"in_place": 0, "moved": 0}}
+# Tiled forwards on heads in place traced in this process by how often
+# they turn a k^T block into the k their score matmuls read: "once_a_head"
+# keeps the head's turned k in VMEM (`autotune.flash_k_slab_admitted`),
+# "every_step" turns the step's block again. `ops.dispatch_report()
+# ["flash"]["k_turns"]` reads it.
+_K_TURNS = {"once_a_head": 0, "every_step": 0}
 
 
 def heads_in_place(h, g, d):
@@ -109,7 +115,11 @@ def heads_in_place(h, g, d):
     `transpose(0, 2, 3, 1)` in front of them is a bitcast of that
     layout, a head is the block of D ROWS at row block `head`, and the
     tile bodies, which hold their tiles transposed already, lose their
-    own transposes (`_fwd_kernel`, `_bwd_dkv_kernel`: `by_rows`)."""
+    own transposes (`_fwd_kernel`, `_bwd_dkv_kernel`: `by_rows`). What
+    is left is k, which the forward's score matmuls want turned: once a
+    k block and head where the head's k fits VMEM
+    (`autotune.flash_k_slab_admitted`), as the fused backward turns k and
+    v once a column."""
     return g == h and d % 16 == 0
 
 
@@ -922,13 +932,23 @@ def masked_tile_count(n_q, n_k, block_q, block_k, causal, window=None,
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
                 use_mask=False, use_bias=False, dropout_rate=0.0,
-                compact=False, window=None, unroll=True, by_rows=False):
+                compact=False, window=None, unroll=True, by_rows=False,
+                k_slab=False):
     """`by_rows`: the blocks are a head's TRANSPOSED tensors
     (`heads_in_place`): q^T [D, block_q], k^T and v^T [D, block_k] in,
-    out^T [D, block_q] out. The tile body is the one every call runs; v^T and out^T are the
-    operands it wanted (it multiplies v^T by P^T into out^T), q^T is the
-    k q^T matmul's right operand as it lies, and k alone is transposed,
-    once a grid step."""
+    out^T [D, block_q] out. The tile body is the one every call runs; v^T
+    and out^T are the operands it wanted (it multiplies v^T by P^T into
+    out^T), q^T is the k q^T matmul's right operand as it lies, and k
+    alone is transposed, into a scratch the score matmuls read.
+
+    `k_slab` (`autotune.flash_k_slab_admitted`; by rows and causal): the
+    scratch is the head's whole k, [S, D], and a step turns its k^T block
+    into rows ki * block_k.. of it only on the block's FIRST visit within
+    the head, which in the row-ordered causal schedule is the step of row
+    (ki * block_k) // block_q. The grid's flat dimension is sequential and
+    a head's first visit of a block precedes its later ones, so a step
+    reads nothing this head has not written. Without it the scratch is
+    one block, turned every grid step."""
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -939,8 +959,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     o_ref, lse_ref = next(it), next(it)
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
     kbias_scr = next(it) if use_bias else None
-    if by_rows:
-        k_scr = next(it)
+    k_scr = next(it) if by_rows else None
+    if by_rows and not k_slab:
         k_scr[...] = k_ref[0].T                                # [BK, D]
     if compact:
         # flat trapezoidal schedule: (qi, ki) from the prefetched LUTs;
@@ -953,6 +973,14 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         qi = pl.program_id(1)
         ki = pl.program_id(2)
         last_k = pl.num_programs(2) - 1
+
+    k_row = 0          # the scratch row that holds the block's first key
+    if k_slab:
+        k_row = pl.multiple_of(ki * block_k, block_k)
+
+        @pl.when(qi == (ki * block_k) // block_q)
+        def _turn():
+            k_scr[pl.ds(k_row, block_k), :] = k_ref[0].T
 
     # a window's rows start at their band's first tile (compact only)
     @pl.when(ki == _first_k(qi, block_q, block_k, window))
@@ -977,7 +1005,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     def scores(r0, g0):
         # raw, transposed: keys r0.. x queries g0..  [ck, gw]
         if by_rows:
-            return _dot(k_scr[pl.ds(r0, ck), :],
+            return _dot(k_scr[pl.ds(k_row + r0, ck), :],
                         q_ref[0, :, pl.ds(g0, gw)], _NN)
         return _dot(k_ref[0, pl.ds(r0, ck), :], q_ref[0, pl.ds(g0, gw), :],
                     _NT)
@@ -1329,7 +1357,7 @@ def _head_spec(ix, by_rows, h, d, block, which, row_of=lambda bh: bh):
 @functools.cache
 def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
               use_mask, use_bias, dropout_rate, segmented, window,
-              interpret, mask_block=0, by_rows=False):
+              interpret, mask_block=0, by_rows=False, k_slab=False):
     """The tiled forward at one call signature: (the function of its
     inputs, its grid, its (masked, launched) tiles), built once a process
     (`_BODY_BUILDS`). Inputs in order: q, k, v as [B*H | B*G, S, D], then
@@ -1337,11 +1365,13 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
 
     `by_rows` (`heads_in_place`): q^T, k^T, v^T in and out^T out, each
     [B, H*D, S]; a BlockSpec picks a head's (1, D, block) at row block
-    `head` of row `batch`, on the same grid."""
+    `head` of row `batch`, on the same grid. `k_slab`: its scratch for
+    the turned k is the head's [S, D] and not a block's (`_fwd_kernel`)."""
     n_q, n_k = s // block_q, s // block_k
     if by_rows:
         assert g == h and not (use_mask or use_bias or segmented) and \
             dropout_rate == 0.0 and window is None
+    assert not k_slab or (by_rows and causal)
 
     def kv_of(bh):
         """The [B*G, S, D] row that holds query row `bh`'s KV head."""
@@ -1364,7 +1394,8 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
                                    compact=compact, window=window,
                                    # the interpreter gains nothing from a
                                    # body written out, and compiles it
-                                   unroll=not interpret, by_rows=by_rows)
+                                   unroll=not interpret, by_rows=by_rows,
+                                   k_slab=k_slab)
     if compact:
         maps = causal_grid_maps(n_q, n_k, block_q, block_k, "row", window)
         grid = (b * h, len(maps[0]))
@@ -1397,7 +1428,7 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
         pltpu.VMEM((1, block_q), jnp.float32),       # running denom
         pltpu.VMEM((d, block_q), jnp.float32),       # out accumulator^T
     ] + _key_column_scratch(block_k, False, use_bias) \
-        + [pltpu.VMEM((block_k, d), dtype)] * by_rows  # k of a k^T block
+        + [pltpu.VMEM((s if k_slab else block_k, d), dtype)] * by_rows  # k
     masked = masked_tile_count(
         n_q, n_k, block_q, block_k, causal, window,
         always=use_mask or use_bias or dropout_rate > 0.0)
@@ -1479,13 +1510,17 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
         return _from_bh(out, h), (*heads, out, lse.reshape(b * h, s))
 
     _HEADS["fwd"]["in_place" if in_place else "moved"] += 1
+    k_slab = in_place and flash_k_slab_admitted(s, d, q.dtype.itemsize,
+                                                causal)
+    if in_place:
+        _K_TURNS["once_a_head" if k_slab else "every_step"] += 1
     _LAST_BLOCKS["fwd"] = (block_q, block_k)
     _LAST_BLOCKS["fwd_variant"] = "trapezoid" if causal else "dense"
     _log_first_dispatch()
     run, _LAST_GRIDS["fwd"], _LAST_MASKED["fwd"] = _fwd_call(
         b, s, h, g, d, q.dtype, block_q, block_k, causal, sm_scale,
         layout is not None, kbias is not None, dropout_rate,
-        seg is not None, window, _interpret(), mask_block, in_place)
+        seg is not None, window, _interpret(), mask_block, in_place, k_slab)
     out, lse = run(*heads, *_optional_inputs(seg, layout, kbias, seed,
                                              dropout_rate))
     out, lse = _tag_residuals(out, lse)

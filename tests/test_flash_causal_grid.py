@@ -239,6 +239,71 @@ def test_heads_in_place_match_the_moved_heads(shape, blocks, bwd_blocks,
                                    err_msg=f"d{name}")
 
 
+def _k_turns():
+    return dict(importlib.import_module(
+        "deeperspeed_tpu.ops").dispatch_report()["flash"]["k_turns"])
+
+
+def _k_turned_since(before):
+    return {rule: n - before[rule] for rule, n in _k_turns().items()}
+
+
+K_SLAB_CASES = [
+    # [B, S, H, D], forward blocks, causal: four q rows each; the heads'
+    # k all differ, so a block left from the head before would show
+    ((1, 1024, 16, 64), (256, 128), True),
+    ((1, 1024, 16, 128), (256, 128), True),
+    ((2, 512, 2, 64), (128, 256), True),
+    ((3, 512, 1, 64), (128, 128), True),
+    # the dense grid's q rows are `parallel`: it turns k every step
+    ((2, 512, 2, 64), (256, 128), False),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,blocks,causal", K_SLAB_CASES,
+    ids=["h16-d64-blocks-2-to-1", "h16-d128-blocks-2-to-1", "blocks-1-to-2",
+         "equal-blocks", "dense-turns-every-step"])
+def test_a_heads_k_is_turned_once_and_reads_as_turned_every_step(
+        shape, blocks, causal, monkeypatch):
+    """The by-rows forward that keeps a head's turned k in VMEM
+    (`autotune.flash_k_slab_admitted`) against the same forward with the
+    slab refused (the budget taken to nothing: the rule is a function of
+    the shape): out, LSE and all three gradients BIT-equal, and
+    `dispatch_report()["flash"]["k_turns"]` says which rule each call
+    ran. A dense call turns k every step under either budget, and is
+    held to the moved heads' forward."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, w = (jax.random.normal(kk, shape, jnp.bfloat16) * 0.5
+                  for kk in ks)
+    scale = 1.0 / math.sqrt(shape[-1])
+
+    def fwd_and_grads():
+        before = _k_turns()
+        out, res = fa._fwd(q, k, v, causal, scale, *blocks, in_place=True)
+        grads = jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+            *a, causal, None, *blocks).astype(jnp.float32)
+            * w.astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+        return (out, res[-1], *grads), _k_turned_since(before)
+
+    kept, counted = fwd_and_grads()
+    rule = "once_a_head" if causal else "every_step"
+    assert counted == {"once_a_head": 0, "every_step": 0, rule: 2}
+    monkeypatch.setattr(autotune, "_FLASH_K_SLAB_BUDGET", 0)
+    refused, counted = fwd_and_grads()
+    assert counted == {"once_a_head": 0, "every_step": 2}
+    for got, want, name in zip(kept, refused, ("out", "lse", "dq", "dk",
+                                               "dv")):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32),
+                                      err_msg=name)
+    if not causal:
+        moved, _ = fa._fwd(q, k, v, causal, scale, *blocks)
+        np.testing.assert_allclose(np.asarray(kept[0], np.float32),
+                                   np.asarray(moved, np.float32),
+                                   atol=1e-2, rtol=1e-2)
+
+
 def test_a_backward_over_the_slab_budget_moves_the_heads(monkeypatch):
     """The forward's residuals lie by rows; the two kernels of a sequence
     over the slab's budget read [B*H, S, D], so that backward moves the
@@ -500,9 +565,12 @@ def _equations(jaxpr):
 # its five groups, and dq's init and store; as two kernels dkv 199, dq
 # 182). Those are the bodies on MOVED heads ([B*H, S, D] blocks); where
 # the cells' calls read the heads in place (`heads_in_place`: transposed
-# blocks) the forward counts 845 / 842 / 842 (k's transpose and its
-# store) and the fused backward 258 (no transpose in a group, k's and v's
-# once a column). A body that grows past it is set-up every run pays:
+# blocks) the forward counts 875 / 872 / 872 (the first-visit predicate,
+# k's transpose and its store into the head's slab under it, and the
+# block's row of the slab added to every score matmul's offset; 845 /
+# 842 / 842 where the slab is refused and k is turned every step) and the
+# fused backward 258 (no transpose in a group, k's and v's once a
+# column). A body that grows past it is set-up every run pays:
 # shrink it, or let its unrolling adapt to the shape
 # (docs/long-context.md, "What a body costs the host").
 BACKWARD_BUDGET = {"ds.flash_bwd": 340, "ds.flash_bwd_dkv": 260,
@@ -550,34 +618,59 @@ def _built_since(before):
             for kind, (n, _) in fa._BODY_BUILDS.items()}
 
 
-def test_a_train_step_reads_the_heads_in_place_and_a_prefill_moves_them():
-    """`dispatch_report()["flash"]["heads"]`: a traced train step of a tiny
-    Pythia (two layers, two heads of 64, 2,048 tokens: two blocks) counts
-    2 x layers tiled calls in place, a forward and a backward a layer, and
-    none through the copies; a serving prefill's attention (the segmented
-    forward, as `InferenceEngine._prefill_fn` calls it) counts the
-    reverse."""
-    from deeperspeed_tpu.models.gpt_neox import (GPTNeoX, GPTNeoXConfig,
-                                                  causal_attention)
-    layers = 2
-    cfg = GPTNeoXConfig(vocab_size=256, hidden_size=128, num_layers=layers,
-                        num_heads=2, max_seq_len=2048, rotary_pct=0.25)
+TINY_LAYERS = 2
+TINY_TOKENS = jax.ShapeDtypeStruct((1, 2048), jnp.int32)
+
+
+def _trace_tiny_train_step():
+    """Loss and gradients of a tiny Pythia (two layers, two heads of 64,
+    2,048 tokens: two blocks), traced."""
+    from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+    cfg = GPTNeoXConfig(vocab_size=256, hidden_size=128,
+                        num_layers=TINY_LAYERS, num_heads=2,
+                        max_seq_len=2048, rotary_pct=0.25)
     model = GPTNeoX(cfg, use_pallas=True)
     params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32)
-    before = _head_counts()
-    jax.make_jaxpr(jax.value_and_grad(model.loss_fn))(params,
-                                                      (tokens, tokens))
-    assert _counted_since(before) == {
-        "fwd": {"in_place": layers, "moved": 0},
-        "bwd": {"in_place": layers, "moved": 0}}
+    jax.make_jaxpr(jax.value_and_grad(model.loss_fn))(
+        params, (TINY_TOKENS, TINY_TOKENS))
+
+
+def _trace_prefill_attention():
+    """A serving prefill's attention (the segmented forward, as
+    `InferenceEngine._prefill_fn` calls it), traced."""
+    from deeperspeed_tpu.models.gpt_neox import causal_attention
     q = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16)
-    before = _head_counts()
     jax.make_jaxpr(lambda q, k, v, seg: causal_attention(
-        q, k, v, use_pallas=True, segment_ids=seg))(q, q, q, tokens)
+        q, k, v, use_pallas=True, segment_ids=seg))(q, q, q, TINY_TOKENS)
+
+
+def test_a_train_step_reads_the_heads_in_place_and_a_prefill_moves_them():
+    """`dispatch_report()["flash"]["heads"]`: a traced train step counts
+    2 x layers tiled calls in place, a forward and a backward a layer, and
+    none through the copies; a serving prefill's attention counts the
+    reverse."""
+    before = _head_counts()
+    _trace_tiny_train_step()
+    assert _counted_since(before) == {
+        "fwd": {"in_place": TINY_LAYERS, "moved": 0},
+        "bwd": {"in_place": TINY_LAYERS, "moved": 0}}
+    before = _head_counts()
+    _trace_prefill_attention()
     assert _counted_since(before) == {
         "fwd": {"in_place": 0, "moved": 1},
         "bwd": {"in_place": 0, "moved": 0}}
+
+
+def test_a_train_step_turns_a_heads_k_once():
+    """`dispatch_report()["flash"]["k_turns"]`: the same traced train
+    step counts one forward a layer that keeps the head's turned k
+    (`once_a_head`) and none that turns it every grid step; a serving
+    prefill (moved heads: no turn at all) counts under neither."""
+    before = _k_turns()
+    _trace_tiny_train_step()
+    _trace_prefill_attention()
+    assert _k_turned_since(before) == {"once_a_head": TINY_LAYERS,
+                                       "every_step": 0}
 
 
 def _transposes(jaxpr):
@@ -606,7 +699,7 @@ def test_the_training_call_never_moves_a_head(shape):
     assert found == {(0, 2, 3, 1), (0, 3, 1, 2)}, found
 
 
-@pytest.mark.parametrize("heads", ["in_place", "moved"])
+@pytest.mark.parametrize("heads", ["in_place", "moved", "k_every_step"])
 @pytest.mark.parametrize("shape,budget", SETUP_CASES,
                          ids=["train_16k", "train_2k", "zero3_shard"])
 def test_bodies_are_built_once_and_stay_small(shape, budget, backward,
@@ -615,10 +708,14 @@ def test_bodies_are_built_once_and_stay_small(shape, budget, backward,
     process, build each kernel body ONCE: two bodies, the forward's and
     the fused backward's (three where the backward is two kernels); and
     each body stays under its written budget of equations, on the heads
-    in place (what the cells run) and on moved heads (a shape the rule
-    does not admit; every masked, biased or segmented call)."""
+    in place (what the cells run), on moved heads (a shape the rule
+    does not admit; every masked, biased or segmented call) and in place
+    with k turned every grid step (a sequence whose k is over the slab's
+    budget, `autotune.flash_k_slab_admitted`)."""
     if heads == "moved":
         monkeypatch.setattr(fa, "heads_in_place", lambda h, g, d: False)
+    if heads == "k_every_step":
+        monkeypatch.setattr(autotune, "_FLASH_K_SLAB_BUDGET", 0)
     spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     kinds = ("fwd", *backward)
     before = _fresh_account()

@@ -309,6 +309,39 @@ def test_flash_backward_compiles_on_both_sides_of_the_slab_budget(
         ("fused-trapezoid" if fused else "trapezoid")
 
 
+# Both sides of `ops.autotune.flash_k_slab_admitted`: the by-rows forward
+# keeps a head's turned k in VMEM ([S, D] of the input's dtype, a 64-wide
+# minor dim laid out as a whole lane tile) at the compiler's DEFAULT limit
+# at the train cells' shapes and up to the largest slabs the rule admits
+# (8 MiB: 32k tokens of bfloat16 at head dim 64 and 128), and the next
+# sequence up compiles the form that turns its k block every grid step.
+# (name, [B, S, H, D], forward blocks or None = `flash_blocks`' own, kept)
+K_SLAB_SHAPES = [
+    ("train_16k", (1, 16384, 16, 64), (1024, 512), True),
+    ("train_zero3_4c", (4, 2048, 16, 128), None, True),
+    ("largest_slab_d64", (1, 32768, 16, 64), None, True),
+    ("largest_slab_d128", (1, 32768, 16, 128), None, True),
+    ("over_budget_d64", (1, 65536, 16, 64), None, False),
+]
+
+
+@pytest.mark.parametrize("name,shape,blocks,kept", K_SLAB_SHAPES,
+                         ids=[c[0] for c in K_SLAB_SHAPES])
+def test_flash_forward_compiles_on_both_sides_of_the_k_slab_budget(
+        on_chip, name, shape, blocks, kept):
+    from deeperspeed_tpu.ops.autotune import (flash_blocks,
+                                              flash_k_slab_admitted)
+    assert flash_k_slab_admitted(shape[1], shape[3], 2, True) is kept
+    bq, bk = blocks or flash_blocks(shape, True, "TPU v5 lite")[0]
+    before = dict(dispatch_report()["flash"]["k_turns"])
+    text = on_chip(lambda q, k, v: fa.flash_attention(
+        q, k, v, True, None, bq, bk), *qkv(*shape))
+    assert kernel_names(text) == {"ds.flash_fwd"}
+    assert {rule: n - before[rule] for rule, n in
+            dispatch_report()["flash"]["k_turns"].items()} == {
+        "once_a_head": int(kept), "every_step": int(not kept)}
+
+
 def test_segmented_prefill_compiles_at_the_serve_cells_bucket(on_chip):
     """The Pythia and OLMoE cells' largest prefill bucket: one row of
     1,536 tokens, 16 heads of 128, pad rows masked through segment ids."""
