@@ -139,6 +139,11 @@ def _pow2_ladder(lo, hi):
     return sorted(set(out))
 
 
+# what a layer adds to a program's count of held pairs and of held
+# experts touched where it routes nothing, or holds every expert
+_NO_HELD = np.zeros((2,), np.float32)
+
+
 class _Family:
     """The model-family seams the serving loop needs: token embedding,
     position stream, LM head. Everything between (the block body) is
@@ -496,16 +501,14 @@ class InferenceEngine:
             # the names the scheduler and a benchmark's probes read
             self.cache = self.caches[primary]
             self.window_cache = self.caches.get("window")
-            # the cache kind WITHOUT pages (a model with state-space
-            # layers): one slot of recurrent state a running sequence, +
-            # the trash slot
+            # the cache kind WITHOUT pages (a model with state-space or
+            # delta-rule layers): one slot of recurrent state a running
+            # sequence, + the trash slot, in the shapes the model names
             self.state_cache = None
             if fam.cache_layers("state"):
                 self.state_cache = StateCache(
-                    num_layers=fam.cache_layers("state"),
-                    num_slots=self.max_batch_size + 1, inner=cfg.ssm_inner,
-                    state=cfg.ssm_state, conv=cfg.ssm_conv,
-                    dtype=self.compute_dtype)
+                    fam.cache_layers("state"), self.max_batch_size + 1,
+                    *cfg.state_shapes, dtype=self.compute_dtype)
         # -- prefix/radix cache + speculative decoding (both default-off:
         #    without their config sub-blocks the engine is bit-identical
         #    to the plain PR 8 serving loop) --------------------------------
@@ -617,6 +620,8 @@ class InferenceEngine:
         # one more entry behind every program's tokens, read back with
         # them
         self._counts_held = bool(self.family.moe_held)
+        self._gdn_layers = sum(n for spec, _, _, n in self.family.runs()
+                               if spec.attn == "gdn")
         self._carry = self._zero_carry()
         # a block model's passes as they are read back, where a list is
         # put here (None: not kept): one dict a live row-pass, the block
@@ -735,6 +740,14 @@ class InferenceEngine:
                       # layers, and those that fell on an expert held
                       # here (all of them unless the model holds a share)
                       "moe_rows_routed": 0, "moe_rows_held": 0,
+                      # held experts that got at least one row, summed over
+                      # the decode steps' routing layers (0 unless the
+                      # model holds a share of its experts)
+                      "moe_experts_touched": 0,
+                      # delta-rule states a decode step updated (rows x gdn
+                      # layers) and real tokens a prefill put through the
+                      # chunked delta rule (padding not counted), summed
+                      "gdn_state_updates": 0, "gdn_prefill_tokens": 0,
                       # (token, expert) rows the MoE layers routed, all
                       # layers together, and the rows their buffers held
                       # with padding (0 for a dense model)
@@ -1156,16 +1169,29 @@ class InferenceEngine:
         """What a layer loop over layers [at, at + n) of one kind's
         `stack` takes: (the leaves a layer is sliced out of, the function
         that makes layer i's block params). The experts stay whole: the
-        grouped-matmul kernel indexes the layer (`LayerOf`)."""
+        grouped-matmul kernel indexes the layer (`LayerOf`). A run of
+        several layers that is only a PART of its stack (a kind whose
+        layers another kind interrupts) takes no leaves at all: its loop
+        indexes the whole stack at `at + i`, where slicing the run out
+        would copy the run's weights at every call (Qwen3-Next's two runs
+        of gdn layers: 0.37 GB a decode step)."""
         from ..ops.pallas.grouped_matmul import LayerOf
         whole = {k: v for k, v in stack["mlp"].items()
                  if k in ("w_in", "w_out")}
         sliced = dict(stack, mlp={k: v for k, v in stack["mlp"].items()
                                   if k not in whole})
-        if (at, n) != (0, jax.tree_util.tree_leaves(sliced)[0].shape[0]):
+        layers = jax.tree_util.tree_leaves(sliced)[0].shape[0]
+        indexed = None
+        if n > 1 and n < layers:
+            indexed, sliced = sliced, {}
+        elif n < layers:
             sliced = jax.tree_util.tree_map(lambda a: a[at:at + n], sliced)
 
         def layer_of(bp, i):
+            if indexed is not None:
+                bp = jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, at + i, keepdims=False), indexed)
             experts = {k: LayerOf(v, jnp.asarray(at + i, jnp.int32))
                        for k, v in whole.items()}
             return dict(bp, mlp=dict(bp["mlp"], **experts))
@@ -1188,7 +1214,7 @@ class InferenceEngine:
         cache_at = {kind: loop_pass * (fam.cache_layers(kind) //
                                        fam.loop_steps)
                     for kind in ("full", "window", "latent", "eva")}
-        cache_at["ssm"] = 0
+        cache_at.update(ssm=0, gdn=0)
         out = []
         for spec, first, at, n in fam.runs():
             shared = spec.attn in ("cross", "gmu")
@@ -1219,12 +1245,15 @@ class InferenceEngine:
 
     @staticmethod
     def _held_rows(fam, block_out):
-        """(hidden states, the (token, choice) pairs of this layer that
-        fell on a held expert) of a block's return."""
+        """(hidden states, [the (token, choice) pairs of this layer that
+        fell on a held expert, the held experts that got at least one])
+        of a block's return."""
         if isinstance(block_out, tuple) and fam.moe_held:
             lo, hi = fam.moe_held
-            return block_out[0], jnp.sum(block_out[1][2, lo:hi])
-        return neox.block_hidden(block_out), jnp.zeros((), jnp.float32)
+            pairs = block_out[1][2, lo:hi]
+            return block_out[0], jnp.stack(
+                [jnp.sum(pairs), jnp.sum(pairs > 0, dtype=pairs.dtype)])
+        return neox.block_hidden(block_out), _NO_HELD
 
     def _plan_token_layers(self, fam, stacks, x, pos, pools, tables,
                            lengths, loop_pass=0, layers=None, mem=None,
@@ -1392,12 +1421,18 @@ class InferenceEngine:
             return attn_rows(attn), kv
 
         def state_mixer(x, pools, mem, bp, spec, cache_layer):
-            """An ssm layer's step on its rows' states, or a gmu layer's
-            gate on the memory: (the mixer's output, pools, memory)."""
+            """An ssm or gdn layer's step on its rows' states, or a gmu
+            layer's gate on the memory: (the mixer's output, pools,
+            memory)."""
             with scopes.scope("ds.attn"):
                 a = neox.norm(cfg, bp["ln_attn"], x)
             if spec.attn == "gmu":
                 return neox.gmu_mixer(bp["attn"], a, mem), pools, mem
+            if spec.attn == "gdn":
+                mixed, state = neox.gdn_token(
+                    cfg, bp["attn"], a, pools["state"], tables["state"],
+                    cache_layer, lengths > 0)
+                return mixed, dict(pools, state=state), mem
             mixed, state, mem = neox.ssm_token(
                 cfg, bp["attn"], a, pools["state"], tables["state"],
                 cache_layer, lengths > 0, backend=self._attn_backend)
@@ -1406,13 +1441,14 @@ class InferenceEngine:
         @scopes.scoped("ds.block")
         def layer(carry, bp, spec, cache_layer):
             x, pools, held, aux = carry
-            if spec.attn in ("ssm", "gmu"):
+            if spec.attn in ("ssm", "gmu", "gdn"):
                 mixed, pools, mem = state_mixer(
-                    x, pools, aux["mem"], bp, spec, cache_layer)
-                out = neox._block_post_attn(
+                    x, pools, aux.get("mem"), bp, spec, cache_layer)
+                out, rows = self._held_rows(fam, neox._block_post_attn(
                     cfg, bp, x, mixed, reduce_fn=lambda t: t,
-                    token_mask=active, projected=True)
-                return (out, pools, held, {"mem": mem}), None
+                    token_mask=active, projected=True))
+                return (out, pools, held + rows,
+                        {"mem": mem} if fam.shares else aux), None
             kind = cache_kind(spec)
             attend = latent_attn if spec.attn == "latent" else paged_attn
             attn, kv = attend(x, pools[kind], bp, spec, cache_layer)
@@ -1426,7 +1462,7 @@ class InferenceEngine:
                 (B, R, cfg.ssm_inner), jnp.float32)
         with scopes.scope("ds.layers"):
             carry, _ = self._plan_layers(
-                fam, stacks, (x, pools, jnp.zeros((), jnp.float32), aux),
+                fam, stacks, (x, pools, _NO_HELD, aux),
                 layer, loop_pass, layers)
         return carry[:3]
 
@@ -1462,14 +1498,15 @@ class InferenceEngine:
     def _with_held(self, tokens, held, exit_pass=None):
         """A program's tokens, and behind them each row's exit pass where
         the model loops (padded to the tokens' length), then the count of
-        held pairs where the model holds a share of its experts: one
+        held pairs and of held experts touched (`_held_rows`) where the
+        model holds a share of its experts: one
         array, one read-back."""
         parts = [tokens]
         if exit_pass is not None:
             parts.append(jnp.pad(
                 exit_pass, (0, tokens.shape[0] - exit_pass.shape[0])))
         if self._counts_held:
-            parts.append(held.astype(jnp.int32)[None])
+            parts.append(held.astype(jnp.int32))
         return jnp.concatenate(parts) if len(parts) > 1 else tokens
 
     def _prefill_fn(self, batch, seqlen):
@@ -1625,14 +1662,14 @@ class InferenceEngine:
                             fam, stacks, x, keys_values, loop_pass,
                             (split, split + 1))
                         runs += full
-                of_kind = {"state": "ssm"}
 
                 def made(kind, i):
                     """Entry i of what the layers of cache kind `kind`
                     returned, in order: [L_kind, B, rows, ...]."""
+                    mixers = neox.STATE_MIXERS if kind == "state" \
+                        else (kind,)
                     return jnp.concatenate(
-                        [kv[i] for spec, kv in runs
-                         if spec.attn == of_kind.get(kind, kind)])
+                        [kv[i] for spec, kv in runs if spec.attn in mixers])
 
                 with scopes.scope("ds.kv_write"):
                     pools = {kind: tuple(
@@ -1663,11 +1700,11 @@ class InferenceEngine:
                 # the host reads back is a [B] of zeros that waits on the
                 # last layer, so a device error still surfaces there
                 x, (pools, _) = one_pass(
-                    x, (pools, jnp.zeros((), jnp.float32)), 0)
+                    x, (pools, _NO_HELD), 0)
                 done = (last_rows(x, lengths)[:, 0] * 0).astype(jnp.int32)
                 return done, pools
             h, (pools, held), exit_pass = self._loop(
-                params, x, (pools, jnp.zeros((), jnp.float32)),
+                params, x, (pools, _NO_HELD),
                 one_pass, (lambda x: last_rows(x, lengths)) if split == L
                 else (lambda x: x[:, 0]))
             logits = fam.head(params, h)
@@ -1710,7 +1747,7 @@ class InferenceEngine:
                 return x, (pools, held + rows)
 
             h, (pools, held), exit_pass = self._loop(
-                params, x, (pools, jnp.zeros((), jnp.float32)),
+                params, x, (pools, _NO_HELD),
                 one_pass, lambda x: x[:, 0])
             # one shape for every bucket's tokens: what the next decode
             # (of any bucket) gathers from
@@ -2810,6 +2847,8 @@ class InferenceEngine:
                     tokens[i, :len(ctx)] = ctx
                     lengths[i] = len(ctx)
                 self._count_moe_rows("prefill", int(lengths.sum()), B * S)
+                self.stats["gdn_prefill_tokens"] += int(lengths.sum()) * \
+                    self._gdn_layers
                 if self.eva_window:
                     self.stats["eva_prefill_pairs"] += sum(
                         self._eva_pairs(int(n)) for n in lengths)
@@ -2891,6 +2930,8 @@ class InferenceEngine:
                 self.stats["state_slot_steps"] += len(plan.decodes)
                 self.stats["state_byte_steps"] += len(plan.decodes) * \
                     self.stats["state_bytes"]
+                self.stats["gdn_state_updates"] += len(plan.decodes) * \
+                    self._gdn_layers
             self._count_moe_rows("decode", len(plan.decodes), B)
             args = [jnp.asarray(tokens), jnp.asarray(lengths),
                     jax.device_put(tables)]
@@ -3029,14 +3070,14 @@ class InferenceEngine:
 
     def _zero_carry(self):
         # a decode program's output (`_with_held`): the tokens, a looped
-        # model's exit passes behind them, the held count; a block model's
-        # is its rows' block state (`planned_block_decode`)
+        # model's exit passes behind them, the two held counts; a block
+        # model's is its rows' block state (`planned_block_decode`)
         if self.block:
             return jnp.asarray(np.zeros(
                 (self._carry_width, 4 * self.block + 1), np.int32))
         return jnp.asarray(np.zeros(
             (self._carry_width * (2 if self.loop_steps > 1 else 1) +
-             int(self._counts_held),), np.int32))
+             2 * int(self._counts_held),), np.int32))
 
     def _pools(self):
         """{cache kind: (K, V) pools | (latent pool,)} as the programs
@@ -3152,8 +3193,12 @@ class InferenceEngine:
                 if self._counts_held:
                     # the program's count of pairs on a held expert rides
                     # behind its tokens: the same read-back
-                    self.stats["moe_rows_held"] += int(nxt[-1])
-                    nxt = nxt[:-1]
+                    # (and of held experts that got a row, which says
+                    # what share of the experts a DECODE step streams)
+                    self.stats["moe_rows_held"] += int(nxt[-2])
+                    if rec.phase == "decode":
+                        self.stats["moe_experts_touched"] += int(nxt[-1])
+                    nxt = nxt[:-2]
                 if self.loop_steps > 1:
                     # and so does each row's exit pass, behind the tokens
                     exits = nxt[len(nxt) // 2:]

@@ -54,11 +54,15 @@ innermost, and every kernel call would copy the pool). The allocator,
 the reference counts and the page tables are the ones every kind
 shares.
 
-**Recurrent state** (`StateCache`; a model with state-space layers): a
-cache kind WITHOUT pages. A sequence owns one fixed SLOT for its life,
-which holds, for every state-space layer, the scan's state (float32,
-`[N, sub, lanes]` as `ops.pallas.ssm.state_tile` lays the channels out)
-and the last K - 1 rows of the convolution's input. A prefill writes the
+**Recurrent state** (`StateCache`; a model with state-space or
+delta-rule layers): a cache kind WITHOUT pages. A sequence owns one fixed
+SLOT for its life, which holds, for every such layer, the recurrent state
+(float32) and the last K - 1 rows of the convolution's input, in the
+shapes the MODEL names (`GPTNeoXConfig.state_shapes`): a Mamba layer's
+scan state `[N, sub, lanes]` as `ops.pallas.ssm.state_tile` lays the
+channels out (358,400 B a layer a sequence at 16 x 5,120), a Gated
+DeltaNet layer's matrix a value head `[n_v, d_k, d_v]` beside rows of its
+`[q | k | v]` channels (2,146,304 B at 32 x 128 x 128). A prefill writes the
 slot with the state after the prompt's last real token, computed from a
 zero state (a slot's old content is never read: a slot handed on starts
 from zero), every decode step updates it in place, the scheduler gives
@@ -296,21 +300,22 @@ class PagedKVCache:
 
 class StateCache:
     """The recurrent-state pools and their slot allocator: `conv`
-    [layers, slots, K - 1, sub, lanes] in `dtype` and `ssm` [layers,
-    slots, N, sub, lanes] float32, `num_slots` including the reserved
-    trash slot 0."""
+    [layers, slots, *conv_shape] in `dtype` (the convolution's last rows)
+    and `ssm` [layers, slots, *state_shape] float32 (the recurrent state
+    of whatever kind: a scan's, a delta rule's), the two shapes a layer a
+    sequence as the model names them (`GPTNeoXConfig.state_shapes`),
+    `num_slots` including the reserved trash slot 0."""
 
-    def __init__(self, num_layers, num_slots, inner, state, conv, dtype):
-        from ..ops.pallas.ssm import state_tile
+    def __init__(self, num_layers, num_slots, conv_shape, state_shape,
+                 dtype):
         if num_slots < 2:
             raise ValueError(f"num_slots must be >= 2 (slot 0 is the "
                              f"reserved trash slot), got {num_slots}")
         self.num_layers, self.num_slots = int(num_layers), int(num_slots)
         self.dtype = dtype
-        self._conv_shape = (self.num_layers, self.num_slots, int(conv) - 1,
-                            *state_tile(int(inner)))
-        self._ssm_shape = (self.num_layers, self.num_slots, int(state),
-                           *state_tile(int(inner)))
+        lead = (self.num_layers, self.num_slots)
+        self._conv_shape = lead + tuple(int(d) for d in conv_shape)
+        self._ssm_shape = lead + tuple(int(d) for d in state_shape)
         self.reset_pools()
         self._free = list(range(self.num_slots - 1, 0, -1))
         self._held = set()

@@ -57,6 +57,12 @@ class LayerSpec:
     layer's own queries over the K and V of the model's ONE `full` layer,
     which lies before it. `heads` is 0 for `ssm` and `gmu`.
 
+    `gdn` (a Gated DeltaNet layer, arXiv:2412.06464;
+    `GPTNeoXConfig.gdn_*`): linear attention by the gated delta rule, whose
+    cache is a MATRIX state a value head and the last rows of a causal
+    convolution's input (the `state` cache kind too); it reads no other
+    layer. `heads` is 0: its key and value heads are the model's facts.
+
     `eva` (EVA attention, arXiv:2302.04542, as a byte model uses it): exact
     causal attention inside the query's own window of
     `GPTNeoXConfig.eva_window` positions and, in the same softmax, ONE
@@ -171,7 +177,9 @@ class GPTNeoXConfig:
     attn_window: int = 0
     # a gate on the attention output before the output projection
     # ("none" | "per-head": sigmoid(a Wg), one scalar a head and token,
-    # from the normed input `a`),
+    # from the normed input `a` | "elementwise": the same with one gate a
+    # FEATURE of every head, `gate_w` [h, heads x head_dim]; published as
+    # the second half of each head's query projection),
     attn_gate: str = "none"
     # how the router scores, in float32 ("softmax": over all experts;
     # "sigmoid": each expert alone, with a learned correction bias that
@@ -183,9 +191,12 @@ class GPTNeoXConfig:
     moe_topk_group: int = 1
     # the width of ONE expert where `ffn_width` is a dense layer's (0:
     # `intermediate_size`), a shared expert's width (0: none; it is not
-    # gated by the router), the factor on the routed experts' sum,
+    # gated by the router; with `moe_shared_gate` by a sigmoid of its own,
+    # one scalar a token from a leaf `shared_gate` [h, 1]), the factor on
+    # the routed experts' sum,
     moe_expert_width: int = 0
     moe_shared_width: int = 0
+    moe_shared_gate: bool = False
     moe_routing_scale: float = 1.0
     # and WHICH experts are held here: (first, past-the-last) of the
     # `moe_num_experts` the router scores; () holds them all. The layer
@@ -258,12 +269,23 @@ class GPTNeoXConfig:
     ssm_state: int = 0
     ssm_conv: int = 0
     ssm_dt_rank: int = 0
+    # a Gated DeltaNet (`gdn`) layer's facts: key heads, value heads (a
+    # multiple of them: a key head serves consecutive value heads), the
+    # width of a head's key and of its value, the taps of the causal
+    # depthwise convolution over [q | k | v] (0: the plan has no such
+    # layer),
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 0
     # an `eva` layer's facts: the window inside which attention is exact
     # and the chunk whose rows ONE pooled key and value stand for once
     # their window has ended (0: the plan has no such layer),
     eva_window: int = 0
     eva_chunk: int = 0
-    # an RMS norm whose scale is 1 + w (the leaf holds w, zero at init),
+    # an RMS norm whose scale is 1 + w (the leaf holds w, zero at init;
+    # the norms on each head of q and k, `qk_norm="head"`, follow it),
     norm_unit_offset: bool = False
     # and a head of `num_pred_heads` x `vocab_size` logits a position:
     # head m is the distribution of token t + 1 + m. Greedy decoding
@@ -301,6 +323,28 @@ class GPTNeoXConfig:
                 for s in self.layer_plan[full[0] + 1:]):
             return full[0]
         return self.num_layers
+
+    @property
+    def gdn_channels(self):
+        """Channels a gdn layer's convolution runs over: [q | k | v]."""
+        return 2 * self.gdn_key_heads * self.gdn_key_dim + \
+            self.gdn_value_heads * self.gdn_value_dim
+
+    @property
+    def state_shapes(self):
+        """What ONE layer of the `state` cache kind keeps a sequence: (the
+        convolution's last rows, the recurrent state), as the kernels
+        read them. A gdn layer: K - 1 rows of its channels and a [d_k,
+        d_v] matrix a value head; an ssm layer: K - 1 rows and N state
+        rows of its inner channels; a row of channels as
+        `ops.pallas.ssm.state_tile` lays them out."""
+        from ..ops.pallas.ssm import state_tile
+        if self.gdn_value_heads:
+            return ((self.gdn_conv - 1, *state_tile(self.gdn_channels)),
+                    (self.gdn_value_heads, self.gdn_key_dim,
+                     self.gdn_value_dim))
+        tile = state_tile(self.ssm_inner)
+        return ((self.ssm_conv - 1, *tile), (self.ssm_state, *tile))
 
     @property
     def latent_width(self):
@@ -350,14 +394,14 @@ class GPTNeoXConfig:
     def cache_layers(self, attn):
         """How many cache layers of kind `attn` ("full" | "window" |
         "latent": pages; "state": a recurrent state a sequence) the model
-        keeps: one a layer of that kind (a `state` layer is an `ssm`
-        layer; a `cross` or `gmu` layer keeps none) and pass of the loop
-        (`loop_steps`); a homogeneous model's are all "full"."""
+        keeps: one a layer of that kind (a `state` layer is an `ssm` or a
+        `gdn` layer; a `cross` or `gmu` layer keeps none) and pass of the
+        loop (`loop_steps`); a homogeneous model's are all "full"."""
         if not self.layer_plan:
             return self.num_layers if attn == "full" else 0
-        kind = "ssm" if attn == "state" else attn
+        kinds = STATE_MIXERS if attn == "state" else (attn,)
         return self.loop_steps * sum(1 for s in self.layer_plan
-                                     if s.attn == kind)
+                                     if s.attn in kinds)
 
     def _planned_params(self, held):
         """Parameters of a planned model by layer kind; `held` counts the
@@ -382,6 +426,14 @@ class GPTNeoXConfig:
                     di * (R + 2 * N) + (R + 1) * di + di * N + di + di * h
             elif spec.attn == "gmu":
                 attn = 2 * h * self.ssm_inner
+            elif spec.attn == "gdn":
+                nv, wide = self.gdn_value_heads, \
+                    self.gdn_value_heads * self.gdn_value_dim
+                ch = self.gdn_channels
+                # [q | k | v | z] and [b | a], the convolution, A_log and
+                # dt_bias, the output norm's scale, the out-projection
+                attn = h * (ch + wide) + h * 2 * nv + self.gdn_conv * ch + \
+                    2 * nv + self.gdn_value_dim + wide * h
             elif spec.attn == "cross":
                 attn = 2 * h * spec.heads * d + \
                     bias * (spec.heads * d + h) + diff
@@ -392,13 +444,16 @@ class GPTNeoXConfig:
                     attn += 2 * d
                 if spec.attn == "eva":
                     attn += 2 * spec.heads * d      # phi and mu
-            if self.attn_gate == "per-head":
-                attn += h * spec.heads
+            if self.attn_gate != "none":
+                attn += h * spec.heads * (
+                    d if self.attn_gate == "elementwise" else 1)
             if spec.ffn == "dense":
                 ffn = 3 * h * self.intermediate_size
             else:
                 ffn = h * self.moe_num_experts + \
                     3 * h * (E * self.expert_width + self.moe_shared_width)
+                if self.moe_shared_gate:
+                    ffn += h
                 if self.moe_router_score == "sigmoid":
                     ffn += self.moe_num_experts     # the correction bias
             return attn + ffn + (4 if self.sublayer_out_norm else 2) * ln
@@ -490,14 +545,16 @@ class GPTNeoXConfig:
             f"{k}={getattr(self, k)!r}" for k, plain in
             (("attn_head_dim", 0), ("num_kv_heads", 0), ("attn_window", 0),
              ("attn_gate", "none"), ("moe_expert_width", 0),
-             ("moe_shared_width", 0), ("moe_routing_scale", 1.0),
-             ("moe_held", ()), ("moe_router_score", "softmax"),
+             ("moe_shared_width", 0), ("moe_shared_gate", False),
+             ("moe_routing_scale", 1.0), ("moe_held", ()), ("moe_router_score", "softmax"),
              ("mla_q_rank", 0), ("mla_kv_rank", 0), ("mla_nope_dim", 0),
              ("mla_rope_dim", 0), ("mla_v_dim", 0), ("mtp_layers", 0),
              ("sublayer_out_norm", False), ("loop_steps", 1),
              ("loop_exit_threshold", 1.0), ("generation_block", 0),
              ("attn_diff", False), ("ssm_inner", 0), ("ssm_state", 0),
-             ("ssm_conv", 0), ("ssm_dt_rank", 0), ("eva_window", 0),
+             ("ssm_conv", 0), ("ssm_dt_rank", 0), ("gdn_key_heads", 0),
+             ("gdn_value_heads", 0), ("gdn_key_dim", 0),
+             ("gdn_value_dim", 0), ("gdn_conv", 0), ("eva_window", 0),
              ("eva_chunk", 0), ("norm_unit_offset", False),
              ("num_pred_heads", 1))
             if getattr(self, k) != plain]
@@ -524,12 +581,12 @@ class GPTNeoXConfig:
             raise NotImplementedError(
                 f"mtp_layers={self.mtp_layers}: one next-token-prediction "
                 f"block (or none) is computed")
-        if self.attn_gate not in ("none", "per-head"):
+        if self.attn_gate not in ("none", "per-head", "elementwise"):
             raise NotImplementedError(
-                f"attn_gate {self.attn_gate!r}: 'none', or 'per-head' "
+                f"attn_gate {self.attn_gate!r}: 'none', 'per-head' "
                 f"(sigmoid(a Wg) a head and token on the attention output "
-                f"before the output projection); an elementwise gate is "
-                f"not computed")
+                f"before the output projection) or 'elementwise' (a "
+                f"feature of a head and token)")
         if not plan:
             if planned_only:
                 raise NotImplementedError(
@@ -541,9 +598,9 @@ class GPTNeoXConfig:
                     f"next-token-prediction block, no norm on a "
                     f"sublayer's output, no loop, one token a step "
                     f"under the causal mask, no differential attention, "
-                    f"no state-space layer, no chunk-pooled (eva) "
-                    f"attention, a norm scale without a unit offset and "
-                    f"one prediction head")
+                    f"no state-space or delta-rule layer, no chunk-pooled "
+                    f"(eva) attention, a norm scale without a unit offset "
+                    f"and one prediction head")
             return
         if len(plan) != self.num_layers:
             raise ValueError(f"layer_plan names {len(plan)} layers, "
@@ -570,10 +627,10 @@ class GPTNeoXConfig:
                 raise NotImplementedError(
                     f"layer {i}: attention {spec.attn!r} / FFN "
                     f"{spec.ffn!r}; the kinds are full | window | latent | "
-                    f"ssm | gmu | cross | eva and dense | experts")
+                    f"ssm | gmu | cross | eva | gdn and dense | experts")
             if spec.attn == "latent":
                 self._check_latent(i, spec)
-            elif spec.attn in ("ssm", "gmu"):
+            elif spec.attn in ("ssm", "gmu", "gdn"):
                 if spec.heads:
                     raise ValueError(f"layer {i}: an {spec.attn} layer has "
                                      f"no heads, got {spec.heads}")
@@ -599,6 +656,10 @@ class GPTNeoXConfig:
         self._check_generation_block()
         self._check_shared()
         self._check_eva()
+        self._check_gdn()
+        if self.moe_shared_gate and not self.moe_shared_width:
+            raise ValueError("moe_shared_gate without a shared expert "
+                             "(moe_shared_width 0)")
         if self.moe_held:
             lo, hi = self.moe_held
             if not 0 <= lo < hi <= self.moe_num_experts:
@@ -658,6 +719,36 @@ class GPTNeoXConfig:
                 f"attention, with {', '.join(held) or 'an experts layer'}: "
                 f"such a plan is computed run once, a token a step, with "
                 f"dense FFNs")
+
+    def _check_gdn(self):
+        """A `gdn` layer's facts, each refused by name where the code has
+        no path."""
+        kinds = [s.attn for s in self.layer_plan]
+        dims = {k: getattr(self, k) for k in
+                ("gdn_key_heads", "gdn_value_heads", "gdn_key_dim",
+                 "gdn_value_dim", "gdn_conv")}
+        if "gdn" not in kinds:
+            if any(dims.values()):
+                raise ValueError(f"{dims} without a gdn layer in the plan")
+            return
+        if min(dims.values()) < 1 or self.gdn_conv < 2 or \
+                self.gdn_value_heads % self.gdn_key_heads:
+            raise ValueError(
+                f"a gdn layer needs every one of {dims} positive, a "
+                f"convolution of at least 2 taps and value heads that are "
+                f"a multiple of the key heads")
+        held = [f"{k}={getattr(self, k)!r}" for k, plain in
+                (("loop_steps", 1), ("mtp_layers", 0),
+                 ("generation_block", 0), ("sublayer_out_norm", False),
+                 ("attn_diff", False), ("use_bias", False))
+                if getattr(self, k) != plain]
+        other = sorted(set(kinds) - {"gdn", "full"})
+        if held or other:
+            raise NotImplementedError(
+                f"a gdn layer with {', '.join(held + other)}: the delta "
+                f"rule is computed in a plan of gdn and full layers run "
+                f"once, a token a step, without biases (an ssm layer "
+                f"beside it would need a second shape of state a slot)")
 
     def _check_eva(self):
         """An `eva` layer's facts, the unit-offset norm and the prediction
@@ -814,7 +905,9 @@ class GPTNeoXConfig:
 
 
 # what takes the attention's place in a planned layer (`LayerSpec.attn`)
-MIXERS = ("full", "window", "latent", "ssm", "gmu", "cross", "eva")
+MIXERS = ("full", "window", "latent", "ssm", "gmu", "cross", "eva", "gdn")
+# those whose cache is a slot of the `state` kind
+STATE_MIXERS = ("ssm", "gdn")
 
 
 def diff_lambda_init(layer):
@@ -933,6 +1026,33 @@ def _init_ssm_params(cfg, n, ks, out_scale):
             "out_w": _stack_init(ks[2], (n,), (di, h), dt, out_scale)}
 
 
+def _init_gdn_params(cfg, n, ks, out_scale):
+    """A `gdn` layer's leaves: `in_w` [h, channels + n_v d_v] ([q | k | v
+    | z]: the convolution's channels first, q and k a key head at a time,
+    v and z a value head at a time), `ba_w` [h, 2 n_v] ([b | a]),
+    `conv_w` [K, channels] (tap k meets row t - (K - 1) + k; no bias),
+    `A_log`, `dt_bias` [n_v], `norm` [d_v] (the output norm's plain scale),
+    `out_w` [n_v d_v, h]. The decay's leaves are drawn so that heads
+    forget at rates from a few tokens to thousands: `A_log` = log(U(0,
+    16)), `dt_bias` the inverse softplus of a step drawn log-uniformly
+    from [0.001, 0.1]; `conv_w` uniform at K ** -0.5."""
+    h, dt = cfg.hidden_size, cfg.param_dtype
+    nv, K, ch = cfg.gdn_value_heads, cfg.gdn_conv, cfg.gdn_channels
+    wide = nv * cfg.gdn_value_dim
+    step = jnp.exp(jax.random.uniform(ks[3], (n, nv)) *
+                   (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return {"in_w": _stack_init(ks[0], (n,), (h, ch + wide), dt),
+            "ba_w": _stack_init(ks[1], (n,), (h, 2 * nv), dt),
+            "conv_w": jax.random.uniform(
+                ks[9], (n, K, ch), minval=-K ** -0.5,
+                maxval=K ** -0.5).astype(dt),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[10], (n, nv), minval=1e-3, maxval=16.0)).astype(dt),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+            "norm": jnp.ones((n, cfg.gdn_value_dim), dt),
+            "out_w": _stack_init(ks[2], (n,), (wide, h), dt, out_scale)}
+
+
 def init_stack_params(cfg, spec, n, key, layers=None):
     """The parameter stack of `n` layers of kind `spec`, every leaf with
     the leading dim `n` (`layers`: their indices in the model, which
@@ -944,11 +1064,13 @@ def init_stack_params(cfg, spec, n, key, layers=None):
     (`diff_lambda_init`; no parameter, stored so that a layer's slice of
     the stack carries it). A `cross` layer has `q_w`, `out_w` and those
     alone; an `ssm` layer `_init_ssm_params`' leaves; a `gmu` layer
-    `in_w` [h, d_i] and `out_w` [d_i, h]. Attention: `q_w` [h, H*d], `kv_w`
+    `in_w` [h, d_i] and `out_w` [d_i, h]; a `gdn` layer
+    `_init_gdn_params`' leaves. Attention: `q_w` [h, H*d], `kv_w`
     [h, 2*G*d] ([K | V], each G heads of d), `out_w` [H*d, h], with
     `qk_norm='head'` the scales `q_norm`, `k_norm` [d] of the norm on each
-    head of q and k, and with
-    a per-head gate `gate_w` [h, H]; an `eva` layer's pooling leaves
+    head of q and k (zeros, w of 1 + w, with `norm_unit_offset`), and with
+    a per-head gate `gate_w` [h, H], with an elementwise one `gate_w`
+    [h, H*d]; an `eva` layer's pooling leaves
     `eva_phi`, `eva_mu` [H, d]; a latent layer's seven leaves:
     `q_a` [h, q_rank], `q_a_norm` [q_rank], `q_b` [q_rank, H*(nope+rope)]
     (a head's [nope | rope]), `kv_a` [h, kv_rank+rope] ([c_kv | k_r]),
@@ -959,9 +1081,9 @@ def init_stack_params(cfg, spec, n, key, layers=None):
     non-zero at a quarter of the spread of the scores, so that a bias
     that is dropped, or leaks into the weights, shows), `w_in` [E held, h, 2w],
     `w_out` [E held, w, h], and a shared expert's `shared_in` [h, 2s],
-    `shared_out` [s, h]. Norms: `ln_attn`, `ln_mlp` on the sublayers'
-    inputs, and with `sublayer_out_norm` `ln_attn_out`, `ln_mlp_out` on
-    their outputs."""
+    `shared_out` [s, h] (and `shared_gate` [h, 1] where it is gated).
+    Norms: `ln_attn`, `ln_mlp` on the sublayers' inputs, and with
+    `sublayer_out_norm` `ln_attn_out`, `ln_mlp_out` on their outputs."""
     h, d, dt = cfg.hidden_size, cfg.head_dim, cfg.param_dtype
     H, G = spec.heads, cfg.kv_heads
     out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
@@ -983,6 +1105,8 @@ def init_stack_params(cfg, spec, n, key, layers=None):
         attn = {"in_w": _stack_init(ks[0], (n,), (h, cfg.ssm_inner), dt),
                 "out_w": _stack_init(ks[2], (n,), (cfg.ssm_inner, h), dt,
                                      out_scale)}
+    elif spec.attn == "gdn":
+        attn = _init_gdn_params(cfg, n, ks, out_scale)
     else:
         attn = {"q_w": _stack_init(ks[0], (n,), (h, H * d), dt),
                 "kv_w": _stack_init(ks[1], (n,), (h, 2 * G * d), dt),
@@ -994,7 +1118,9 @@ def init_stack_params(cfg, spec, n, key, layers=None):
         if spec.attn == "cross":        # another layer's K and V
             attn = {k: v for k, v in attn.items() if not k.startswith("kv")}
         if cfg.qk_norm == "head":
-            attn.update(q_norm=jnp.ones((n, d), dt), k_norm=jnp.ones((n, d), dt))
+            one = jnp.zeros((n, d), dt) if cfg.norm_unit_offset \
+                else jnp.ones((n, d), dt)
+            attn.update(q_norm=one, k_norm=one)
         if spec.attn == "eva":
             # phi at the keys' own spread, so that the pooling softmax is
             # no plain mean; mu a fifth of it, so that a mu left out shows
@@ -1008,8 +1134,10 @@ def init_stack_params(cfg, spec, n, key, layers=None):
             attn["subln"] = jnp.ones((n, d), dt)
             attn["lam0"] = jnp.asarray(
                 [diff_lambda_init(i) for i in layers], jnp.float32)
-    if cfg.attn_gate == "per-head":
-        attn["gate_w"] = _stack_init(ks[3], (n,), (h, H), dt)
+    if cfg.attn_gate != "none" and H:
+        attn["gate_w"] = _stack_init(
+            ks[3], (n,), (h, H * d if cfg.attn_gate == "elementwise" else H),
+            dt)
     if spec.ffn == "dense":
         i = cfg.intermediate_size
         mlp = {"in_w": _stack_init(ks[4], (n,), (h, 2 * i), dt),
@@ -1038,6 +1166,9 @@ def init_stack_params(cfg, spec, n, key, layers=None):
             mlp["shared_in"] = _stack_init(ks[7], (n,), (h, 2 * sw), dt)
             mlp["shared_out"] = _stack_init(ks[8], (n,), (sw, h), dt,
                                             out_scale)
+            if cfg.moe_shared_gate:
+                mlp["shared_gate"] = _stack_init(
+                    jax.random.fold_in(ks[7], 1), (n,), (h, 1), dt)
     ln = {"scale": jnp.zeros((n, h), dt) if cfg.norm_unit_offset
           else jnp.ones((n, h), dt)}
     if cfg.norm == "layernorm":
@@ -1163,13 +1294,17 @@ def rms_norm(x, scale, eps):
     return (out * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def _rms_scale(cfg, w):
+    """An RMS norm's scale from its leaf: 1 + w with a unit offset."""
+    if getattr(cfg, "norm_unit_offset", False):
+        return 1.0 + w.astype(jnp.float32)
+    return w
+
+
 def norm(cfg, p, x):
     """The model's own norm (`cfg.norm`) with the leaves `p`."""
     if getattr(cfg, "norm", "layernorm") == "rmsnorm":
-        scale = p["scale"]
-        if getattr(cfg, "norm_unit_offset", False):
-            scale = 1.0 + scale.astype(jnp.float32)
-        return rms_norm(x, scale, cfg.layernorm_eps)
+        return rms_norm(x, _rms_scale(cfg, p["scale"]), cfg.layernorm_eps)
     return layer_norm(x, p["scale"], p["bias"], cfg.layernorm_eps)
 
 
@@ -1249,7 +1384,7 @@ def plan_rotary(cfg, seq_len):
     does)."""
     return {spec.attn: _rotary_cache(cfg, seq_len, spec=spec)
             for spec in reversed(cfg.layer_plan)
-            if spec.attn not in ("ssm", "gmu")}
+            if spec.attn not in ("ssm", "gmu", "gdn")}
 
 
 def _rotate_half(x):
@@ -1480,8 +1615,8 @@ def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local, split=False):
         k, v = kv[:, :, 0], kv[:, :, 1]
         if cfg.qk_norm == "head":
             # over the features of each head, one scale for all heads
-            q = rms_norm(q, params["attn"]["q_norm"], cfg.layernorm_eps)
-            k = rms_norm(k, params["attn"]["k_norm"], cfg.layernorm_eps)
+            q = rms_norm(q, _rms_scale(cfg, a["q_norm"]), cfg.layernorm_eps)
+            k = rms_norm(k, _rms_scale(cfg, a["k_norm"]), cfg.layernorm_eps)
         q, k = apply_rotary(q, k, cos, sin, rot_dim)
         return q, k, v
     if split:
@@ -1765,6 +1900,111 @@ def ssm_token(cfg, p, a, state, slots, layer, active, backend=None):
     return _ssm_out(p, s, z)[:, None], state, s[:, None]
 
 
+def _gdn_project(cfg, p, a):
+    """A gdn layer's projections of the normed input `a` [..., h]: ([q | k
+    | v] before the convolution [..., channels], z [..., n_v d_v], b, a
+    [..., n_v])."""
+    ch, nv = cfg.gdn_channels, cfg.gdn_value_heads
+    proj, ba = _wmat(a, p["in_w"]), _wmat(a, p["ba_w"])
+    return proj[..., :ch], proj[..., ch:], ba[..., :nv], ba[..., nv:]
+
+
+def _gdn_conv(p, taps):
+    """silu(sum_k conv_w[k] * taps[k]) in float32: `taps` are rows t - K +
+    1 .. t of [q | k | v], each [..., channels]."""
+    w = p["conv_w"].astype(jnp.float32)
+    return jax.nn.silu(sum(w[k] * tap.astype(jnp.float32)
+                           for k, tap in enumerate(taps)))
+
+
+def _gdn_operands(cfg, p, c, b, a, real):
+    """The delta rule's operands from the convolution's output `c` [...,
+    channels] (float32): q = l2norm(q) / sqrt(d_k) and k = l2norm(k) [...,
+    n_k, d_k], v [..., n_v, d_v], the decay g = -exp(A_log) softplus(a +
+    dt_bias) and the step beta = sigmoid(b) [..., n_v], both zero where
+    `real` is False: float32."""
+    f32 = jnp.float32
+    nk, nv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    lead = c.shape[:-1]
+
+    def unit(t):
+        return t * jax.lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+    q = unit(c[..., :nk * dk].reshape(*lead, nk, dk)) * dk ** -0.5
+    k = unit(c[..., nk * dk:2 * nk * dk].reshape(*lead, nk, dk))
+    v = c[..., 2 * nk * dk:].reshape(*lead, nv, dv)
+    beta = jax.nn.sigmoid(b.astype(f32))
+    g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+        a.astype(f32) + p["dt_bias"].astype(f32))
+    if real is not None:
+        beta = jnp.where(real[..., None], beta, 0.0)
+        g = jnp.where(real[..., None], g, 0.0)
+    return q, k, v, g, beta
+
+
+@scopes.scoped("ds.gdn_out")
+def _gdn_out(cfg, p, o, z):
+    """(o / rms(o) * w_n) * silu(z) a value head, in float32, then W_o:
+    `o` [..., n_v, d_v] float32, `z` [..., n_v d_v]."""
+    f32 = jnp.float32
+    normed = o * jax.lax.rsqrt(
+        jnp.mean(jnp.square(o), axis=-1, keepdims=True) +
+        cfg.layernorm_eps) * p["norm"].astype(f32)
+    gated = normed.reshape(z.shape) * jax.nn.silu(z.astype(f32))
+    return _wmat(gated.astype(z.dtype), p["out_w"])
+
+
+def gdn_mixer(cfg, p, a, real=None):
+    """A Gated DeltaNet layer over whole sequences from a zero state: `a`
+    [B, S, h] the normed input, `real` [B, S] marks the real rows (a
+    prefill bucket's padding moves no state: its decay and its step are
+    0 and its input to the convolution is 0). Returns (the mixer's output
+    [B, S, h], (the last K - 1 real rows of [q | k | v] before the
+    convolution [B, K - 1, sub, lanes] as the pool lays the channels out,
+    the state after the last real row [B, n_v, d_k, d_v] float32))."""
+    from ..ops.pallas.gdn import gdn_chunk
+    B, S, _ = a.shape
+    K = cfg.gdn_conv
+    with scopes.scope("ds.gdn_in"):
+        qkv, z, b, a_ = _gdn_project(cfg, p, a)
+        if real is not None:
+            qkv = jnp.where(real[..., None], qkv, 0)
+        padded = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+        c = _gdn_conv(p, [padded[:, k:k + S] for k in range(K)])
+        operands = _gdn_operands(cfg, p, c, b, a_, real)
+        # rows n - K + 1 .. n - 1 of a row of n real tokens: padded[n + j]
+        n = jnp.sum(real, axis=1) if real is not None else \
+            jnp.full((B,), S, jnp.int32)
+        tail = jnp.take_along_axis(
+            padded, (n[:, None] + jnp.arange(K - 1))[..., None], axis=1)
+    o, state = gdn_chunk(*operands)
+    return _gdn_out(cfg, p, o, z), (
+        tail.reshape(B, *cfg.state_shapes[0]), state)
+
+
+def gdn_token(cfg, p, a, state, slots, layer, active):
+    """The same layer for ONE token a row: `a` [B, 1, h]; `state` the
+    stacked (convolution rows [L, slots, K - 1, sub, lanes], matrix states
+    [L, slots, n_v, d_k, d_v] float32) pools, row b's at `slots[b]` of
+    layer `layer`, updated in place; an inactive row (`active` False)
+    moves nothing. Returns (output [B, 1, h], the pools)."""
+    from ..ops.pallas.gdn import gdn_step
+    conv = state[0]
+    B, K = a.shape[0], cfg.gdn_conv
+    with scopes.scope("ds.gdn_in"):
+        qkv, z, b, a_ = _gdn_project(cfg, p, a[:, 0])
+        rows = jnp.concatenate(
+            [conv[layer, slots].reshape(B, K - 1, -1),
+             qkv[:, None].astype(conv.dtype)], axis=1)
+        c = _gdn_conv(p, [rows[:, k] for k in range(K)])
+        tail = jnp.where(active[:, None, None], rows[:, 1:], rows[:, :-1])
+        operands = _gdn_operands(cfg, p, c, b, a_, active)
+    o, state = gdn_step(state, tail, slots, layer, *operands)
+    return _gdn_out(cfg, p, o, z)[:, None], state
+
+
 @scopes.scoped("ds.gmu")
 def gmu_mixer(p, a, mem):
     """A gated memory unit: (mem * silu(a W_1)) W_2 with `mem` the memory
@@ -1791,8 +2031,9 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
     out_b = params["attn"]["out_b"].astype(x.dtype) \
         if "out_b" in params["attn"] else 0
     if "gate_w" in params["attn"]:
-        # per-head gate: sigmoid(a Wg), a scalar a head and token, from
-        # the normed input `a` (the norm `_block_qkv` took: one value)
+        # the gate: sigmoid(a Wg), a scalar a head and token or one a
+        # feature (its width says which), from the normed input `a` (the
+        # norm `_block_qkv` took: one value)
         with scopes.scope("ds.attn_gate"):
             B, S, _ = attn_flat.shape
             a = norm(cfg, params["ln_attn"], x)
@@ -1835,8 +2076,13 @@ def _block_post_attn(cfg, params, x, attn_flat, reduce_fn, rng=None,
             if "shared_in" in params["mlp"]:
                 # the shared expert: every token, no router weight
                 with scopes.scope("ds.moe_shared"):
-                    y = y + _gated_mlp(ln2, params["mlp"]["shared_in"],
-                                       params["mlp"]["shared_out"], act)
+                    shared = _gated_mlp(ln2, params["mlp"]["shared_in"],
+                                        params["mlp"]["shared_out"], act)
+                    if "shared_gate" in params["mlp"]:
+                        shared = shared * jax.nn.sigmoid(_wmat(
+                            ln2, params["mlp"]["shared_gate"]).astype(
+                                jnp.float32)).astype(shared.dtype)
+                    y = y + shared
         if cfg.use_parallel_residual:
             return x + reduce_fn(attn_partial) + out_b + y, stats
         return ln2_in + y, stats
@@ -1937,16 +2183,19 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
     `GPTNeoXConfig.plan_shares`): {"mem": the memory of the nearest ssm
     layer before this one, "kv": the K and V of the plan's full layer}.
     An ssm layer's `return_kv` is `ssm_mixer`'s (convolution state, scan
-    state, memory); a gmu's or a cross layer's is ()."""
+    state, memory); a gmu's or a cross layer's is (). A gdn layer's is
+    `gdn_mixer`'s (convolution rows, matrix states)."""
     B, S, h = x.shape
     kind = spec.attn if spec is not None else "full"
-    if kind in ("ssm", "gmu"):
+    if kind in ("ssm", "gmu", "gdn"):
         token_mask = None if segment_ids is None else segment_ids > 0
         with scopes.scope("ds.attn"):
             a = norm(cfg, params["ln_attn"], x)
         if kind == "ssm":
             mixed, kv = ssm_mixer(cfg, params["attn"], a, token_mask,
                                   use_pallas)
+        elif kind == "gdn":
+            mixed, kv = gdn_mixer(cfg, params["attn"], a, token_mask)
         else:
             mixed, kv = gmu_mixer(params["attn"], a, shared["mem"]), ()
         out = _block_post_attn(cfg, params, x, mixed, reduce_fn,
@@ -2342,11 +2591,12 @@ def _forward_hidden_planned(cfg, params, tokens, use_pallas, segment_ids,
     layer's hidden states, before the final norm (what the
     next-token-prediction block reads)."""
     S = tokens.shape[1]
-    if segment_ids is not None and cfg.plan_shares:
+    if segment_ids is not None and (cfg.plan_shares or cfg.gdn_conv):
         raise NotImplementedError(
-            "packed rows (segment_ids) through a plan with an ssm, gmu or "
-            "cross layer: the scan and the convolution do not start anew "
-            "at a document's edge; one prompt a row is computed")
+            "packed rows (segment_ids) through a plan with an ssm, gmu, "
+            "cross or gdn layer: the scan, the delta rule and the "
+            "convolution do not start anew at a document's edge; one "
+            "prompt a row is computed")
     if segment_ids is not None and cfg.eva_window:
         raise NotImplementedError(
             "packed rows (segment_ids) through an eva layer: its windows "

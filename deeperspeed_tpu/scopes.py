@@ -65,6 +65,14 @@ SCOPES = {
                               "batch: each row's recurrent state read "
                               "from its slot, updated and written back "
                               "in place"),
+    "ds.gdn_chunk": ("kernel", "a Gated DeltaNet layer's delta rule over "
+                               "a prompt, 64 rows at a time as matmuls: a "
+                               "group of heads' matrix states resident "
+                               "over the chunk walk, float32"),
+    "ds.gdn_step": ("kernel", "the delta rule's one-token step of a "
+                              "decode batch: each row's matrix states "
+                              "read from its slot, decayed, corrected "
+                              "and written back in place"),
     "ds.eva_summarize": ("kernel", "an eva layer's pooling: a chunk's rows "
                                    "to ONE key and ONE value under a "
                                    "softmax over the chunk, in float32. A "
@@ -90,9 +98,10 @@ SCOPES = {
     "ds.moe_combine": ("region", "the experts' rows gathered back, "
                                  "weighted and summed over a token's "
                                  "experts"),
-    "ds.attn_gate": ("region", "the per-head sigmoid gate on the "
-                               "attention output: its projection from "
-                               "the normed input, and the product"),
+    "ds.attn_gate": ("region", "the sigmoid gate on the attention "
+                               "output (a scalar a head, or elementwise): "
+                               "its projection from the normed input, "
+                               "and the product"),
     "ds.mla_q": ("region", "a latent layer's query: the low-rank "
                            "projection, its norm, the projection to the "
                            "heads, the rotary of their rope parts"),
@@ -112,6 +121,14 @@ SCOPES = {
                             "B and C, the step's softplus"),
     "ds.ssm_out": ("region", "a state-space layer after its scan: the "
                              "gate s * silu(z) and the out-projection"),
+    "ds.gdn_in": ("region", "a Gated DeltaNet layer before its delta "
+                            "rule: the projections to [q | k | v | z] and "
+                            "[b | a], the causal depthwise convolution of "
+                            "q | k | v with its slot's rows, the l2 norms "
+                            "of q and k, the step beta and the decay g"),
+    "ds.gdn_out": ("region", "a Gated DeltaNet layer after its delta "
+                             "rule: the RMS norm over each head's output "
+                             "gated by silu(z), and the out-projection"),
     "ds.gmu": ("region", "a gated memory unit: the memory state-space "
                          "layer's scan output of the same token, gated "
                          "by silu of a projection of the normed input, "
@@ -128,7 +145,8 @@ SCOPES = {
                                  "them (`ds.flash_fwd`, inside), the "
                                  "window's rows taken back out"),
     "ds.moe_shared": ("region", "the shared expert every token passes "
-                                "through, beside the routed ones"),
+                                "through, beside the routed ones, and "
+                                "its own sigmoid gate where it has one"),
     "ds.attn_xla": ("region", "the XLA fallback of attention"),
     "ds.paged_decode_xla": ("region", "the XLA fallback of paged decode"),
     "ds.embed": ("region", "token (and position) embedding gather"),

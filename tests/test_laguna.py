@@ -408,7 +408,7 @@ def _plan_config(**over):
 
 REFUSED_BLOCK = {
     "tanh router": (dict(moe_router_score="tanh"), "moe_router_score"),
-    "elementwise gate": (dict(attn_gate="elementwise"), "attn_gate"),
+    "per-feature gate": (dict(attn_gate="per-feature"), "attn_gate"),
     "qk norm": (dict(qk_norm=True), "qk_norm"),
     "parallel residual": (dict(use_parallel_residual=True),
                           "use_parallel_residual"),

@@ -312,8 +312,7 @@ def test_decodes_memory_and_state_equal_the_full_forwards(setup):
     a = jax.random.normal(jax.random.PRNGKey(5), (1, 20, 64))
     with jax.default_matmul_precision("highest"):
         out, (tail, h, mem) = neox.ssm_mixer(cfg, p, a, use_pallas=False)
-        cache = StateCache(3, 4, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv,
-                           jnp.float32)
+        cache = StateCache(3, 4, *cfg.state_shapes, jnp.float32)
         state, slots = (cache.conv, cache.ssm), jnp.asarray([3])
         for t in range(20):
             o_t, state, m_t = neox.ssm_token(
@@ -455,7 +454,7 @@ def test_a_slot_handed_on_starts_from_zero(setup):
 def test_the_allocators_give_a_request_every_kind_or_none():
     full = PagedKVCache(1, 20, 2, PAGE, 16)
     window = PagedKVCache(2, 13, 2, PAGE, 16)
-    state = StateCache(3, 3, 128, 16, 4, jnp.float32)
+    state = StateCache(3, 3, (3, 1, 128), (16, 1, 128), jnp.float32)
     sched = ContinuousBatchingScheduler(
         full, max_seq_len=64, token_budget=64, max_batch_size=4,
         prefill_lengths=[16], prefill_batch_sizes=[1],
@@ -613,7 +612,7 @@ def test_a_dry_pool_raises_and_never_drops_a_state():
         full, max_seq_len=64, token_budget=64, max_batch_size=4,
         prefill_lengths=[16], prefill_batch_sizes=[1],
         decode_batch_sizes=[4],
-        state_cache=StateCache(3, 5, 128, 16, 4, jnp.float32))
+        state_cache=StateCache(3, 5, (3, 1, 128), (16, 1, 128), jnp.float32))
     req = Request(prompt=[1] * 16, max_new_tokens=30)
     sched.add_request(req)
     sched.complete_prefill(sched.schedule().prefills[0], 1)
@@ -628,7 +627,7 @@ def test_a_dry_pool_raises_and_never_drops_a_state():
                 PagedKVCache(1, 9, 2, PAGE, 16), max_seq_len=64,
                 token_budget=64, max_batch_size=4, prefill_lengths=[16],
                 prefill_batch_sizes=[1], decode_batch_sizes=[4],
-                state_cache=StateCache(3, 5, 128, 16, 4, jnp.float32),
+                state_cache=StateCache(3, 5, (3, 1, 128), (16, 1, 128), jnp.float32),
                 **bad)
 
 

@@ -310,8 +310,8 @@ def pallas_calls():
 CALLS = pallas_calls()
 
 
-def test_all_nineteen_sites_are_found():
-    assert len(CALLS) == 19
+def test_all_twenty_one_sites_are_found():
+    assert len(CALLS) == 21
 
 
 @pytest.mark.parametrize("where,name,fn,tree", CALLS,

@@ -1,7 +1,8 @@
 """The cache kinds beside pages of K and V, compiled for a described TPU
 v5e (`tests/tpu_compile_common.py` says how): the recurrent state
-(phi4flash), the latent row (GLM-4.7-Flash) and the chunk-pooled page
-(EvaByte), each kind's kernels and its serving programs.
+(phi4flash's scan state, qwen3_next's delta-rule matrices), the latent row
+(GLM-4.7-Flash) and the chunk-pooled page (EvaByte), each kind's kernels
+and its serving programs.
 """
 
 import functools
@@ -15,8 +16,8 @@ import jax.numpy as jnp
 
 from deeperspeed_tpu.ops import dispatch_report
 from tests.tpu_compile_common import (  # noqa: F401 (fixtures)
-    assert_kernel, BF16, decode_attention, eva, INSTRUCTION, kernel_names,
-    on_chip, pool_shaped_moves, ssm, stacked, v5e_2x2)
+    assert_kernel, BF16, decode_attention, eva, gdn, INSTRUCTION,
+    kernel_names, on_chip, pool_shaped_moves, ssm, stacked, v5e_2x2)
 
 # ---------------------------------------------------------------------------
 # a recurrent-state cache kind (phi4flash) at its published widths
@@ -122,6 +123,119 @@ def test_state_kind_serving_programs_compile_and_carry_every_pool(
         assert not pool_shaped_moves(text, engine.state_cache.ssm.shape,
                                      "f32")
         assert not pool_shaped_moves(text, engine.state_cache.conv.shape)
+
+
+# ---------------------------------------------------------------------------
+# a matrix state a head (qwen3_next's Gated DeltaNet) at its published widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows", [1024, 100])
+def test_gdn_chunk_compiles(on_chip, rows):
+    f32 = jnp.float32
+    text = on_chip(
+        gdn.gdn_chunk, ((1, rows, 16, 128), f32), ((1, rows, 16, 128), f32),
+        ((1, rows, 32, 128), f32), ((1, rows, 32), f32),
+        ((1, rows, 32), f32))
+    assert kernel_names(text) == {"ds.gdn_chunk"}
+
+
+def test_gdn_step_compiles(on_chip):
+    f32 = jnp.float32
+    text = on_chip(
+        lambda conv, pool, tail, slots, *a: gdn.gdn_step(
+            (conv, pool), tail, slots, 2, *a),
+        ((5, 65, 3, 8, 1024), BF16), ((5, 65, 32, 128, 128), f32),
+        ((64, 3, 8192), BF16), ((64,), jnp.int32), ((64, 16, 128), f32),
+        ((64, 16, 128), f32), ((64, 32, 128), f32), ((64, 32), f32),
+        ((64, 32), f32))
+    assert kernel_names(text) == {"ds.gdn_step"}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_gdn_serving_programs_compile_and_carry_every_pool(
+        on_chip, v5e_2x2, program):
+    """The engine's decode and prefill programs for qwen3_next's block at
+    the published widths (hidden 2048, 16 query heads over 2 KV heads of
+    256, 16 key and 32 value heads of 128, experts of width 512 with a
+    gated shared one; 16 of 32 experts and a small vocabulary), six layers
+    in the published order (gdn, gdn, gdn, full, gdn, gdn), compiled for
+    the described v5e from shapes alone: every kernel runs under its own
+    name, nothing falls to XLA, and no instruction's result has the shape
+    of the page pool or of a state pool (a decode step moves rows and
+    states, never a pool)."""
+    from jax.sharding import SingleDeviceSharding
+    from benchmarks.families import qwen3_next as family
+    from deeperspeed_tpu.inference import InferenceEngine
+    conf = {"decoder_sparse_step": 1, "full_attention_interval": 4,
+            "head_dim": 256, "hidden_act": "silu", "hidden_size": 2048,
+            "intermediate_size": 5120, "linear_conv_kernel_dim": 4,
+            "linear_key_head_dim": 128, "linear_num_key_heads": 16,
+            "linear_num_value_heads": 32, "linear_value_head_dim": 128,
+            "max_position_embeddings": 262144, "mlp_only_layers": [],
+            "moe_intermediate_size": 512, "norm_topk_prob": True,
+            "num_attention_heads": 16, "num_experts": 16,
+            "num_experts_published": 32, "held_experts": "0-15",
+            "num_experts_per_tok": 10, "num_hidden_layers": 6,
+            "num_key_value_heads": 2, "partial_rotary_factor": 0.25,
+            "rms_norm_eps": 1e-6, "rope_scaling": None,
+            "rope_theta": 10000000, "shared_expert_intermediate_size": 512,
+            "tie_word_embeddings": False, "use_sliding_window": False,
+            "vocab_size": 1024}
+    model = family.build_model(conf, "bfloat16",
+                               {"use_pallas": True, "max_seq_len": 9216})
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    batch, page, seqlen = 8, 64, 1024
+    engine = InferenceEngine(model, params=params, config={"inference": {
+        "enabled": True, "page_size": page, "num_pages": batch * 144 + 17,
+        "max_seq_len": 9216, "max_batch_size": batch,
+        "token_budget": seqlen + batch, "prefill_lengths": [seqlen],
+        "prefill_batch_sizes": [1], "decode_batch_sizes": [batch]}})
+    assert engine.cache.k.shape == (1, batch * 144 + 17, 2, page, 256)
+    assert engine.state_cache.ssm.shape == (5, batch + 1, 32, 128, 128)
+    assert engine.state_cache.conv.shape == (5, batch + 1, 3, 8, 1024)
+    one_chip = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape_of(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def ints(*shape):
+        return shape_of(np.zeros(shape, np.int32))
+
+    def tables(rows, width):
+        return {"full": ints(rows, width), "state": ints(rows)}
+
+    shapes = functools.partial(jax.tree_util.tree_map, shape_of)
+    carry = ()
+    if program == "decode":
+        fn = engine._decode_fn(batch)
+        inputs = (ints(batch), ints(batch),
+                  tables(batch, engine.n_pages_max))
+        carry = (ints(batch + 2), ints(batch))
+        kernels = {"ds.paged_decode", "ds.kv_write", "ds.gdn_step",
+                   "ds.grouped_matmul"}
+    else:
+        fn = engine._prefill_fn(1, seqlen)
+        inputs = (ints(1, seqlen), ints(1), tables(1, seqlen // page))
+        kernels = {"ds.flash_fwd", "ds.gdn_chunk", "ds.grouped_matmul"}
+    text = fn.lower(
+        shapes(engine.params), shapes(engine.params_stacked), *inputs,
+        shapes(engine._pools()), shape_of(jax.random.PRNGKey(0)),
+        *carry).compile().as_text()
+    assert kernel_names(text) == kernels
+    assert "ds.attn_xla" not in text and "ds.paged_decode_xla" not in text
+    for name in ("ds.gdn_in", "ds.gdn_out", "ds.attn_gate",
+                 "ds.moe_shared"):
+        assert name in text, name
+    if program == "decode":
+        assert not pool_shaped_moves(text, engine.cache.k.shape)
+        assert not pool_shaped_moves(text, engine.state_cache.ssm.shape,
+                                     "f32")
+        # (the convolution rows' pool is small, 16 MB at 65 slots, and the
+        # compiler may stage it in on-chip memory round a layer loop: an
+        # async copy each way, which this check would name)
 
 
 # ---------------------------------------------------------------------------

@@ -40,11 +40,11 @@ import jax.numpy as jnp  # noqa: E402
 # `ops.pallas` re-exports several functions under their module's own
 # name (`flash_attention`, `grouped_matmul`, ...): fetch modules by path
 (fa, decode_attention, block_sparse_attention, grouped_matmul, quant_matmul,
- optimizer, ssm, eva) = KERNEL_MODULES = tuple(
+ optimizer, ssm, eva, gdn) = KERNEL_MODULES = tuple(
     importlib.import_module(f"deeperspeed_tpu.ops.pallas.{name}")
     for name in ("flash_attention", "decode_attention",
                  "block_sparse_attention", "grouped_matmul", "quant_matmul",
-                 "optimizer", "ssm", "eva"))
+                 "optimizer", "ssm", "eva", "gdn"))
 
 BF16 = jnp.bfloat16
 
