@@ -1409,7 +1409,13 @@ def apply_rotary(q, k, cos, sin, rot_dim):
     cos/sin are [S, rot] (shared position stream) or [B, S, rot]
     (per-batch positions — packed batches gather the cache at each
     token's INTRA-document position, so a packed document sees the same
-    rotary stream as the same document padded alone)."""
+    rotary stream as the same document padded alone).
+
+    Passes over q and k in XLA: a training call the flash kernels admit
+    rotates inside them instead (`_rotary_in_kernel`);
+    `ops.dispatch_report()["flash"]["rotary"]` counts both."""
+    from ..ops.pallas.flash_attention import _ROTARY
+    _ROTARY["xla"] += 1
     return tuple(
         jnp.concatenate([_rotary_rows(x[..., :rot_dim], cos, sin),
                          x[..., rot_dim:]], axis=-1) for x in (q, k))
@@ -1432,7 +1438,7 @@ def _flash_route(shape, kv_heads, use_pallas=True, segment_ids=None,
 
 
 def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
-                     window=None, block=0, sm_scale=None):
+                     window=None, block=0, sm_scale=None, rotary=None):
     """Causal MHA core on [B, S, H, D]; fp32 softmax accumulation.
     `k` / `v` may hold fewer (KV) heads than `q`: query head h reads KV
     head h // (H / G); `window` keeps the keys less than `window`
@@ -1442,7 +1448,9 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
     mask BLOCK-causal: query i sees key j wherever j // block <= i //
     block, all of its own block included. `sm_scale`: the softmax scale
     where it is not 1 / sqrt(D) (differential attention's, on the grouped
-    forward and the fallback).
+    forward and the fallback). `rotary` = (cos, sin, rot_dim): q and k are
+    NOT rotated yet and the training flash kernels rotate them; only for
+    a call `_rotary_in_kernel` admitted.
 
     Uses the Pallas flash-attention kernel on TPU when shapes allow;
     XLA-fused fallback otherwise (the fallback still fuses well — softmax
@@ -1465,6 +1473,7 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
         note_xla_on_tpu)
     route = _flash_route(q.shape, k.shape[2], use_pallas, segment_ids,
                          window, block, sm_scale)
+    assert rotary is None or route == "training", route
     if route:
         _LAST_BACKEND["attention"] = "pallas"
 
@@ -1474,7 +1483,8 @@ def causal_attention(q, k, v, use_pallas=True, segment_ids=None,
                                                  sm_scale=sm_scale,
                                                  window=window,
                                                  mask_block=block)
-            return flash_attention(q, k, v, True)
+            # the tables ride the closure: every shard's are the whole
+            return flash_attention(q, k, v, True, rotary=rotary)
 
         if route == "segmented" and segment_ids is None:
             # one kernel path for a window or grouped KV heads: the
@@ -1587,6 +1597,26 @@ def _qkv_split(attn, shape, attn_fn, **call):
         and tiled_in_place(shape, heads))
 
 
+def _rotary_in_kernel(attn, shape, dtype, cos, rot_dim, attn_fn, return_kv,
+                      **call):
+    """Does a block whose attention is `causal_attention(q [shape], k, v,
+    **call)` leave the rotary of q and k to the flash kernels? Where that
+    call is the training flash call and its kernels take it
+    (`flash_attention.rotates_in_kernel`, of q's `shape` and `dtype`:
+    forward and fused backward on heads in place, `rot_dim` whole sublane
+    tiles), every row reads ONE position stream (`cos` [S, rot]; a packed
+    batch's are per row), the projection is the plain block's fused one
+    (as many KV heads as query heads; a planned layer's is `q_w` and
+    `kv_w`), nothing stands in the attention's place (`attn_fn`) and the
+    caller does not want the rotated k (`return_kv`: a prefill writes it
+    to its pages). Every other block rotates in XLA (`apply_rotary`)."""
+    from ..ops.pallas.flash_attention import rotates_in_kernel
+    return attn_fn is None and not return_kv and "qkv_w" in attn and \
+        cos.ndim == 2 and \
+        _flash_route(shape, shape[2], **call) == "training" and \
+        rotates_in_kernel(shape, shape[2], rot_dim, dtype)
+
+
 def _gated_mlp(x, w_in, w_out, act):
     """(act(x Wgate) * (x Wup)) Wdown with `w_in` = [Wgate | Wup]."""
     hmid = _wmat(x, w_in)
@@ -1595,10 +1625,13 @@ def _gated_mlp(x, w_in, w_out, act):
 
 
 @scopes.scoped("ds.attn")
-def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local, split=False):
+def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local, split=False,
+               rotate=True):
     """ln1 + QKV projection + rotary; shared by training and decode.
     `split` (`_block_core`'s to say: `_qkv_split`): the fused projection
-    as three dots, for the tiled flash kernels' reading in place."""
+    as three dots, for the tiled flash kernels' reading in place.
+    `rotate` False (`_rotary_in_kernel`; the plain block's): q and k as
+    projected, for kernels that rotate them."""
     B, S, _ = x.shape
     ln1 = norm(cfg, params["ln_attn"], x)
     if "q_w" in params["attn"]:
@@ -1634,7 +1667,8 @@ def _block_qkv(cfg, params, x, cos, sin, rot_dim, nh_local, split=False):
                             cfg.layernorm_eps).reshape(t.shape)
         q = all_features(q, params["attn"]["q_norm"])
         k = all_features(k, params["attn"]["k_norm"])
-    q, k = apply_rotary(q, k, cos, sin, rot_dim)
+    if rotate:
+        q, k = apply_rotary(q, k, cos, sin, rot_dim)
     return q, k, v
 
 
@@ -2216,11 +2250,16 @@ def _block_core(cfg, params, x, cos_sin, use_pallas, mp, reduce_fn,
         k, v = _latent_expand(cfg, params, latent, heads)
         kv = (latent,)
     else:
+        shape = (B, S, heads // mp, cfg.head_dim)
+        in_kernel = _rotary_in_kernel(
+            params["attn"], shape, x.dtype, cos, rot_dim, attn_fn, return_kv,
+            **call)
         q, k, v = _block_qkv(
             cfg, params, x, cos, sin, rot_dim, heads // mp,
-            split=_qkv_split(
-                params["attn"], (B, S, heads // mp, cfg.head_dim),
-                attn_fn, **call))
+            split=_qkv_split(params["attn"], shape, attn_fn, **call),
+            rotate=not in_kernel)
+        if in_kernel:
+            call["rotary"] = cos_sin
         kv = (k, v)
         if kind == "cross":
             (k, v), kv = shared["kv"], ()

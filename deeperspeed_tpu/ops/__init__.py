@@ -35,7 +35,19 @@ def dispatch_report():
     matmuls read: once a block and head, the head's turned k kept in VMEM
     (`autotune.flash_k_slab_admitted`: a causal call whose [S, D] fits,
     every train cell's), or every grid step (a dense grid, a sequence
-    over the budget)}; ``attention``: {"attention" / "sparse_attention":
+    over the budget), "rotary": {"in_kernel": n, "xla": n}, the
+    rotate-half rotaries of a q, k pair traced in this process by where
+    they run: in_kernel, the tiled training forwards that took the
+    UN-rotated projections and the tables, whose kernels rotate each q^T
+    and k^T block they load and rotate dq and dk back where they store
+    them (`flash_attention.rotates_in_kernel` and
+    `gpt_neox._rotary_in_kernel`: the training call on heads in place
+    with the fused backward, one position stream, `rot_dim` a multiple of
+    16, nobody needing the rotated k; every train cell's); xla, the
+    `gpt_neox.apply_rotary` calls, passes over [B, S, H, D] in front of
+    the attention (a serving prefill, which writes the rotated k to its
+    pages, a decode step, a packed or windowed batch, grouped KV heads,
+    an `attn_fn`, a call of one block)}; ``attention``: {"attention" / "sparse_attention":
     backend} of the model-side dispatchers, and "head_projection":
     {"plain": n, "folded": n, "split": n}, the attention projections
     traced in this process by the form their reshape to heads took
@@ -83,7 +95,7 @@ def dispatch_report():
     from .pallas.flash_attention import _LAST_BACKEND as _ATTN_BACKEND
     from .pallas.flash_attention import (_BODY_BUILDS, _HEAD_PROJECTIONS,
                                          _HEADS, _K_TURNS, _LAST_BLOCKS,
-                                         _LAST_MASKED, _XLA_NOTED)
+                                         _LAST_MASKED, _ROTARY, _XLA_NOTED)
     from .pallas.grouped_matmul import _LAST_BACKEND as _GMM_BACKEND
     from .pallas.grouped_matmul import _PLANS_TRACED
     from .pallas.quant_matmul import _LAST_BACKEND as _QMM_BACKEND
@@ -92,7 +104,7 @@ def dispatch_report():
                           bodies_built={k: (n, round(t, 3)) for k, (n, t)
                                         in _BODY_BUILDS.items()},
                           heads={k: dict(v) for k, v in _HEADS.items()},
-                          k_turns=dict(_K_TURNS)),
+                          k_turns=dict(_K_TURNS), rotary=dict(_ROTARY)),
             "attention": dict(_ATTN_BACKEND,
                               head_projection=dict(_HEAD_PROJECTIONS)),
             "decode_attention": dict(_LAST_BACKEND),

@@ -19,7 +19,10 @@ window, grouped KV heads, a layout, a key bias, dropout; a single block)
 runs on [B*H, S, D], a COPY of each operand and result. Forward saves the
 per-row logsumexp as a compact [BH, S] row-vector (not a lane-broadcast
 [.., 128] tile — 128x less residual HBM traffic); backward recomputes
-probabilities blockwise (no SxS residual).
+probabilities blockwise (no SxS residual). The training call can take the
+rotate-half rotary of q and k into its kernels (`rotates_in_kernel`): it
+is then handed the projections as projected, and no pass over q, k, dq or
+dk stands between the projections' matmuls and the kernels.
 
 Block sizes default to 1024x1024, auto-fitted down to the largest
 128-multiple dividing the sequence length (`ops/autotune.py`): a fat
@@ -98,6 +101,15 @@ _HEADS = {"fwd": {"in_place": 0, "moved": 0},
 # "every_step" turns the step's block again. `ops.dispatch_report()
 # ["flash"]["k_turns"]` reads it.
 _K_TURNS = {"once_a_head": 0, "every_step": 0}
+# Rotate-half rotaries of a q, k pair traced in this process by where they
+# run: "in_kernel" the tiled training forwards handed the UN-rotated
+# projections and the tables (`rotates_in_kernel`: the forward and the
+# fused backward rotate the blocks they load and un-rotate dq and dk where
+# they store them), "xla" the passes over [B, S, H, D] in front of an
+# attention (`models/gpt_neox.py::apply_rotary`: a serving prefill, a
+# decode step, every call the rule does not admit).
+# `ops.dispatch_report()["flash"]["rotary"]` reads it.
+_ROTARY = {"in_kernel": 0, "xla": 0}
 
 
 def heads_in_place(h, g, d):
@@ -109,9 +121,10 @@ def heads_in_place(h, g, d):
     WHERE that is, is XLA's choice, not the model's source: a
     `[B, S, H, D]` tensor of head dim 64 is laid out with the SEQUENCE
     minor (`{1,3,2,0}`: physically [B, H, D, S]; D minor would leave
-    half of every lane tile empty), the rotary fusions write it so and
-    read its gradient so. The kernels therefore take q^T, k^T, v^T (and
-    dO^T) and give out^T (dq^T, dk^T, dv^T) as [B, H*D, S]: the
+    half of every lane tile empty), the projections' fusions write it so
+    and the weight-gradient dots read its gradient so. The kernels
+    therefore take q^T, k^T, v^T (and dO^T) and give out^T (dq^T, dk^T,
+    dv^T) as [B, H*D, S]: the
     `transpose(0, 2, 3, 1)` in front of them is a bitcast of that
     layout, a head is the block of D ROWS at row block `head`, and the
     tile bodies, which hold their tiles transposed already, lose their
@@ -139,6 +152,47 @@ def tiled_in_place(shape, g, causal=True):
 def _one_block(s, block_q, block_k):
     """Is a sequence of `s` one block of the forward, at fitted blocks?"""
     return s // block_q == 1 and s // block_k == 1
+
+
+def _rotates(s, h, g, d, itemsize, causal, rot_dim, fwd_blocks, bwd_blocks):
+    """Can a `flash_attention` call at these (fitted) blocks rotate q and
+    k inside its kernels? Both kernels on the heads in place: a tiled
+    forward, the ONE tiled backward (its dq slab admitted, not a call of
+    one block); `rot_dim` features that are whole packed sublane tiles of
+    the transposed blocks (16 rows of bfloat16; the halves are then whole
+    float32 tiles, and swapping them moves no data); and a forward that
+    turns a k block once, in the query row that covers the block's own
+    positions (causal, the head's k kept, `block_q` a multiple of
+    `block_k`), as the backward's column walk starts a k column at the
+    query row that covers it: that row's columns of the table are then
+    the ONE more operand a kernel takes, for its q block and for the k
+    blocks alike (an operand costs a grid step some 45 cycles of the
+    pipeline's bookkeeping whether or not its block changes: PERF.md
+    section 6, PR 63)."""
+    return heads_in_place(h, g, d) and flash_dq_slab_admitted(s, d) and \
+        flash_k_slab_admitted(s, d, itemsize, causal) and \
+        not _one_block(s, *fwd_blocks) and not _one_block(s, *bwd_blocks) \
+        and fwd_blocks[0] % fwd_blocks[1] == 0 \
+        and bwd_blocks[0] % bwd_blocks[1] == 0 \
+        and 0 < rot_dim <= d and rot_dim % 16 == 0
+
+
+def rotates_in_kernel(shape, g, rot_dim, dtype=jnp.bfloat16, causal=True):
+    """Will `flash_attention(..., rotary=(cos, sin, rot_dim))` on q
+    `shape` [B, S, H, D] of `dtype` and `g` KV heads, at the blocks
+    `ops.autotune.flash_blocks` gives it, take the rotate-half rotary of
+    q and k INTO its kernels (`_rotates`)? The caller then hands the
+    projections un-rotated and runs no rotary of its own; where this says
+    no it keeps its XLA passes and calls without `rotary`. A fact of the
+    shape and of nothing else; what the caller alone knows (per-row
+    positions, a caller that needs the rotated k) is the caller's to add
+    (`models/gpt_neox.py::_rotary_in_kernel`)."""
+    _, s, h, d = shape
+    if not flash_attention_supported(shape):
+        return False
+    fwd, bwd = _resolve_blocks(shape, causal, None, None, None)
+    return _rotates(s, h, g, d, jnp.dtype(dtype).itemsize, causal, rot_dim,
+                    fwd, bwd)
 
 
 def note_xla_on_tpu(op, why):
@@ -698,6 +752,31 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+def _rotated(x, table, inverse=False):
+    """The rotate-half rotary of a head's TRANSPOSED block: rows
+    0 .. rot of x [D, n] (a feature a row) against `table` [2 x rot, n]
+    float32 (cos over sin, the columns of the block's positions), in
+    float32, the other rows as they are; rounded once, to x's dtype.
+    y = x cos + R(x) sin with R [x1, x2] = [-x2, x1] over the halves of
+    the rot rows; `inverse` its transpose, dx = dy cos + R^T(dy sin) with
+    R^T [z1, z2] = [z2, -z1]: what takes the gradient of a rotated block
+    back to the block's. A half is rot / 2 rows, whole float32 sublane
+    tiles (`_rotates`), so the halves' swap is a choice of registers."""
+    rot = table.shape[0] // 2
+    half = rot // 2
+    cos, sin = table[:rot], table[rot:]
+    x1 = x[:half].astype(jnp.float32)
+    x2 = x[half:rot].astype(jnp.float32)
+    c1, c2, s1, s2 = cos[:half], cos[half:], sin[:half], sin[half:]
+    if inverse:
+        y1, y2 = x1 * c1 + x2 * s2, x2 * c2 - x1 * s1
+    else:
+        y1, y2 = x1 * c1 - x2 * s1, x2 * c2 + x1 * s2
+    y = jnp.concatenate([y1, y2], axis=0).astype(x.dtype)
+    return y if rot == x.shape[0] else \
+        jnp.concatenate([y, x[rot:]], axis=0)
+
+
 def _when(cond, fn):
     """`pl.when` that also takes a python bool (a trace-time fact)."""
     if cond is True:
@@ -933,7 +1012,7 @@ def masked_tile_count(n_q, n_k, block_q, block_k, causal, window=None,
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
                 use_mask=False, use_bias=False, dropout_rate=0.0,
                 compact=False, window=None, unroll=True, by_rows=False,
-                k_slab=False):
+                k_slab=False, rotary=False):
     """`by_rows`: the blocks are a head's TRANSPOSED tensors
     (`heads_in_place`): q^T [D, block_q], k^T and v^T [D, block_k] in,
     out^T [D, block_q] out. The tile body is the one every call runs; v^T
@@ -948,7 +1027,14 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     (ki * block_k) // block_q. The grid's flat dimension is sequential and
     a head's first visit of a block precedes its later ones, so a step
     reads nothing this head has not written. Without it the scratch is
-    one block, turned every grid step."""
+    one block, turned every grid step.
+
+    `rotary` (with `k_slab`; `_rotates`): q^T and k^T come UN-rotated,
+    with the table's columns of the q block's positions ([2 x rot,
+    block_q] float32, cos over sin). A query row's q^T block is rotated
+    on the row's first tile, into a scratch the score matmuls read in
+    `q_ref`'s place; a k^T block where it is turned, which is in the row
+    whose q block covers its positions: from the same columns."""
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -956,10 +1042,12 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     m_ref = next(it) if use_mask else None
     b_ref = next(it) if use_bias else None
     seed_ref = next(it) if dropout_rate > 0.0 else None
+    rot_ref = next(it) if rotary else None
     o_ref, lse_ref = next(it), next(it)
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
     kbias_scr = next(it) if use_bias else None
     k_scr = next(it) if by_rows else None
+    q_scr = next(it) if rotary else None
     if by_rows and not k_slab:
         k_scr[...] = k_ref[0].T                                # [BK, D]
     if compact:
@@ -980,7 +1068,12 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 
         @pl.when(qi == (ki * block_k) // block_q)
         def _turn():
-            k_scr[pl.ds(k_row, block_k), :] = k_ref[0].T
+            kT = k_ref[0]
+            if rotary:
+                # the block's columns of its row's table
+                first = pl.multiple_of(k_row - qi * block_q, block_k)
+                kT = _rotated(kT, rot_ref[:, pl.ds(first, block_k)])
+            k_scr[pl.ds(k_row, block_k), :] = kT.T
 
     # a window's rows start at their band's first tile (compact only)
     @pl.when(ki == _first_k(qi, block_q, block_k, window))
@@ -988,6 +1081,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        if rotary:
+            q_scr[...] = _rotated(q_ref[0], rot_ref[...])
 
     tile = _Tile(qi, ki, block_q, block_k, sm_scale=sm_scale,
                  causal=causal, window=window, m_ref=m_ref, b_ref=b_ref,
@@ -1005,8 +1100,10 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
     def scores(r0, g0):
         # raw, transposed: keys r0.. x queries g0..  [ck, gw]
         if by_rows:
+            cols = pl.ds(g0, gw)
             return _dot(k_scr[pl.ds(k_row + r0, ck), :],
-                        q_ref[0, :, pl.ds(g0, gw)], _NN)
+                        q_scr[:, cols] if rotary else q_ref[0, :, cols],
+                        _NN)
         return _dot(k_ref[0, pl.ds(r0, ck), :], q_ref[0, pl.ds(g0, gw), :],
                     _NT)
 
@@ -1319,6 +1416,19 @@ def _optional_inputs(seg, layout, kbias, seed, dropout_rate):
         ([seed] if dropout_rate > 0.0 else [])
 
 
+def _rotary_specs(ix, rot, block_q):
+    """BlockSpec of the rotary's table [2 x rot, S] (cos over sin) as a
+    rotating kernel takes it after `_optional_inputs`: its columns of the
+    q block's positions."""
+    return [pl.BlockSpec((2 * rot, block_q),
+                         ix(lambda bh, qi, ki: (0, qi)))] * bool(rot)
+
+
+def _rot_dim(table):
+    """The features a rotary's table [2 x rot, S] rotates; 0 of None."""
+    return 0 if table is None else table.shape[0] // 2
+
+
 def _mask_spec(h, n_fine_q, n_fine_k, ix=lambda f: f):
     """BlockSpec for the [H, S/128, S/128] layout mask: the WHOLE
     per-head map as one SMEM block (Mosaic requires trailing block dims
@@ -1357,21 +1467,24 @@ def _head_spec(ix, by_rows, h, d, block, which, row_of=lambda bh: bh):
 @functools.cache
 def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
               use_mask, use_bias, dropout_rate, segmented, window,
-              interpret, mask_block=0, by_rows=False, k_slab=False):
+              interpret, mask_block=0, by_rows=False, k_slab=False, rot=0):
     """The tiled forward at one call signature: (the function of its
     inputs, its grid, its (masked, launched) tiles), built once a process
     (`_BODY_BUILDS`). Inputs in order: q, k, v as [B*H | B*G, S, D], then
-    `_optional_inputs`.
+    `_optional_inputs`, then with `rot` the rotary's table.
 
     `by_rows` (`heads_in_place`): q^T, k^T, v^T in and out^T out, each
     [B, H*D, S]; a BlockSpec picks a head's (1, D, block) at row block
     `head` of row `batch`, on the same grid. `k_slab`: its scratch for
-    the turned k is the head's [S, D] and not a block's (`_fwd_kernel`)."""
+    the turned k is the head's [S, D] and not a block's (`_fwd_kernel`).
+    `rot` (with `k_slab`): the kernel rotates the first `rot` features of
+    q and k itself, from the table [2 x rot, S] (`_fwd_kernel`)."""
     n_q, n_k = s // block_q, s // block_k
     if by_rows:
         assert g == h and not (use_mask or use_bias or segmented) and \
             dropout_rate == 0.0 and window is None
     assert not k_slab or (by_rows and causal)
+    assert not rot or (k_slab and block_q % block_k == 0)
 
     def kv_of(bh):
         """The [B*G, S, D] row that holds query row `bh`'s KV head."""
@@ -1395,7 +1508,7 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
                                    # the interpreter gains nothing from a
                                    # body written out, and compiles it
                                    unroll=not interpret, by_rows=by_rows,
-                                   k_slab=k_slab)
+                                   k_slab=k_slab, rotary=bool(rot))
     if compact:
         maps = causal_grid_maps(n_q, n_k, block_q, block_k, "row", window)
         grid = (b * h, len(maps[0]))
@@ -1414,6 +1527,7 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
     ]
     in_specs += _optional_specs(ix, h, s, block_q, block_k, segmented,
                                 use_mask, use_bias, dropout_rate)
+    in_specs += _rotary_specs(ix, rot, block_q)
     out_shape = [
         jax.ShapeDtypeStruct((b, h * d, s) if by_rows else (b * h, s, d),
                              dtype),
@@ -1428,7 +1542,8 @@ def _fwd_call(b, s, h, g, d, dtype, block_q, block_k, causal, sm_scale,
         pltpu.VMEM((1, block_q), jnp.float32),       # running denom
         pltpu.VMEM((d, block_q), jnp.float32),       # out accumulator^T
     ] + _key_column_scratch(block_k, False, use_bias) \
-        + [pltpu.VMEM((s if k_slab else block_k, d), dtype)] * by_rows  # k
+        + [pltpu.VMEM((s if k_slab else block_k, d), dtype)] * by_rows \
+        + [pltpu.VMEM((d, block_q), dtype)] * bool(rot)   # k; rotated q^T
     masked = masked_tile_count(
         n_q, n_k, block_q, block_k, causal, window,
         always=use_mask or use_bias or dropout_rate > 0.0)
@@ -1468,7 +1583,7 @@ def _from_rows(x, h):
 
 def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
          layout=None, kbias=None, dropout_rate=0.0, seed=None, seg=None,
-         window=None, mask_block=0, in_place=False):
+         window=None, mask_block=0, in_place=False, table=None):
     """`k` / `v` may hold fewer heads than `q` (G under H: query head i
     reads KV head ``i // (H / G)``, through the K and V index maps), and
     a `window` (causal only) keeps keys less than `window` positions
@@ -1485,13 +1600,18 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
     [B, H, D, S]. Every other call (a layout, a key bias, dropout,
     segments, a window, grouped KV heads; a single block) MOVES the
     heads: a `[B, S, H, D] -> [B*H, S, D]` copy of each operand and of
-    out, residuals [B*H, S, D]."""
+    out, residuals [B*H, S, D].
+
+    `table` (an `in_place` call `_rotates` admits): the rotary's,
+    [2 x rot, S] float32, cos over sin; q and k are the UN-rotated
+    projections, the kernel rotates them, and so are the residuals."""
     b, s, h, d = q.shape
     g = k.shape[2]
     block_q, block_k = _fit_block(block_q, s), _fit_block(block_k, s)
     single = _one_block(s, block_q, block_k) and layout is None \
         and seg is None and window is None and g == h
     in_place = in_place and not single and heads_in_place(h, g, d)
+    assert in_place or table is None
     # [B, S, H, D] → [B, H*D, S] where it lies so, else → [B*H, S, D]
     # for contiguous per-head tiles
     heads = tuple((_to_rows if in_place else _to_bh)(x) for x in (q, k, v))
@@ -1514,15 +1634,19 @@ def _fwd(q, k, v, causal, sm_scale, block_q=BLOCK_Q, block_k=BLOCK_K,
                                                 causal)
     if in_place:
         _K_TURNS["once_a_head" if k_slab else "every_step"] += 1
+    if table is not None:
+        _ROTARY["in_kernel"] += 1
     _LAST_BLOCKS["fwd"] = (block_q, block_k)
     _LAST_BLOCKS["fwd_variant"] = "trapezoid" if causal else "dense"
     _log_first_dispatch()
     run, _LAST_GRIDS["fwd"], _LAST_MASKED["fwd"] = _fwd_call(
         b, s, h, g, d, q.dtype, block_q, block_k, causal, sm_scale,
         layout is not None, kbias is not None, dropout_rate,
-        seg is not None, window, _interpret(), mask_block, in_place, k_slab)
+        seg is not None, window, _interpret(), mask_block, in_place, k_slab,
+        _rot_dim(table))
     out, lse = run(*heads, *_optional_inputs(seg, layout, kbias, seed,
-                                             dropout_rate))
+                                             dropout_rate),
+                   *[table] * (table is not None))
     out, lse = _tag_residuals(out, lse)
     if in_place:
         return _from_rows(out, h), (
@@ -1801,7 +1925,7 @@ def _dq_block(qi, ki, n_k, block_q, block_k, causal):
 def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
                     n_k=None, use_seg=False, use_mask=False,
                     use_bias=False, dropout_rate=0.0, compact=False,
-                    fused=False, by_rows=False):
+                    fused=False, by_rows=False, rotary=False):
     """dk and dv of a key column, and with `fused` dq as well.
 
     `by_rows` (fused only): the blocks are a head's TRANSPOSED tensors
@@ -1810,7 +1934,18 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
     operands the three matmuls on the tile's weights wanted, k^T is
     dq^T's, the accumulators are the out blocks' layout, and k and v for
     the two score matmuls are transposed once a key COLUMN: a step
-    transposes nothing."""
+    transposes nothing.
+
+    `rotary` (by rows and causal; `_rotates`): q^T and k^T come
+    UN-rotated, with the table's columns of the q block's positions
+    ([2 x rot, block_q] float32, cos over sin); a column's first tile is
+    the row that covers the k block's positions, so the k block's columns
+    are among them and are kept for the column's end. k^T is rotated once
+    a column, where k and v are turned, into a scratch dq's matmul reads
+    in `k_ref`'s place; q^T once a step, into a scratch the groups read in
+    `q_ref`'s place; dk and dq are rotated BACK where they are stored, in
+    float32 before the cast, so they are the gradients of the
+    projections."""
     it = iter(refs)
     if compact:
         qmap_ref, kmap_ref = next(it), next(it)
@@ -1821,6 +1956,7 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
     m_ref = next(it) if use_mask else None
     b_ref = next(it) if use_bias else None
     seed_ref = next(it) if dropout_rate > 0.0 else None
+    rot_q = next(it) if rotary else None
     dk_ref, dv_ref = next(it), next(it)
     dq_ref = next(it) if fused else None
     dk_scr, dv_scr = next(it), next(it)
@@ -1842,12 +1978,20 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
 
     if by_rows:
         k_scr, v_scr = next(it), next(it)
+    if rotary:
+        kT_scr, rot_k, q_scr = next(it), next(it), next(it)
+        q_scr[...] = _rotated(q_ref[0], rot_q[...])
 
     @pl.when(qi == first_q)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
-        if by_rows:
+        if rotary:
+            first = pl.multiple_of(ki * block_k - qi * block_q, block_k)
+            rot_k[...] = rot_q[:, pl.ds(first, block_k)]
+            kT_scr[...] = _rotated(k_ref[0], rot_k[...])
+            k_scr[...], v_scr[...] = kT_scr[...].T, v_ref[0].T
+        elif by_rows:
             k_scr[...], v_scr[...] = k_ref[0].T, v_ref[0].T    # [BK, D]
 
     if fused:
@@ -1868,6 +2012,8 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         cols = slice(g0, g0 + gw)
         if by_rows:
             q, do = q_ref[0, :, cols], do_ref[0, :, cols]      # [D, gw]
+            if rotary:
+                q = q_scr[:, cols]
             sT = _dot(k_scr[:rows, :], q, _NN)                 # [rows, gw]
         else:
             q, do = q_ref[0, cols, :], do_ref[0, cols, :]      # [gw, D]
@@ -1893,7 +2039,8 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
             dv_scr[:, :rows] += _dot(do, pT_v, _NT)
             dk_scr[:, :rows] += _dot(q, dsT, _NT)
             dq_scr[qi, :, cols] += _dot(
-                k_ref[0, :, :rows], dsT.astype(k_ref.dtype), _NN)
+                kT_scr[:, :rows] if rotary else k_ref[0, :, :rows],
+                dsT.astype(k_ref.dtype), _NN)
         elif fused:
             # the tile as the weights of all three: [D, rows] += dO^T P,
             # [D, rows] += q^T dS, [D, gw] += k^T dS^T
@@ -1925,6 +2072,8 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         dk, dv = dk_scr[...] * sm_scale, dv_scr[...]
         if fused and not by_rows:
             dk, dv = dk.T, dv.T
+        if rotary:
+            dk = _rotated(dk, rot_k[...], inverse=True)
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -1932,6 +2081,8 @@ def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, n_q=None,
         @pl.when(ki == _last_k(qi, n_k, block_q, block_k, causal))
         def _finalize_dq():
             dq = dq_scr[qi] * sm_scale
+            if rotary:
+                dq = _rotated(dq, rot_q[...], inverse=True)
             dq_ref[0] = (dq if by_rows else dq.T).astype(dq_ref.dtype)
 
 
@@ -2007,7 +2158,7 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, n_k=None,
 @functools.cache
 def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
                use_seg, use_mask, use_bias, dropout_rate, fused, interpret,
-               by_rows=False):
+               by_rows=False, rot=0):
     """The tiled backward at one call signature: (the function of its
     inputs that returns (dq, dk, dv), the grid of each kernel it runs by
     kind, their (masked, launched) tiles), built once a process as
@@ -2018,7 +2169,10 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
 
     `by_rows` (`heads_in_place`; the fused kernel's): q^T, k^T, v^T and dO^T
     in, dq^T, dk^T and dv^T out, each [B, H*D, S], a head's (1, D, block)
-    at row block `head` of row `batch`; lse and delta as ever."""
+    at row block `head` of row `batch`; lse and delta as ever. `rot` (by
+    rows): q^T and k^T are un-rotated, the rotary's table follows, and
+    dq^T and dk^T are the un-rotated tensors' gradients
+    (`_bwd_dkv_kernel`)."""
     n_q, n_k = s // block_q, s // block_k
     compact = causal   # mirror the forward's trapezoidal schedule
     flags = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
@@ -2027,6 +2181,7 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
                  dropout_rate=dropout_rate, compact=compact)
 
     assert fused or not by_rows
+    assert not rot or (by_rows and causal and block_q % block_k == 0)
 
     def spec(ix, block, which):
         return _head_spec(ix, by_rows, h, d, block, which)
@@ -2039,7 +2194,8 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
         kv_spec = spec(ix, block_k, lambda qi, ki: ki)
         return [q_spec, kv_spec, kv_spec, q_spec, row, row] + \
             _optional_specs(ix, h, s, block_q, block_k, use_seg, use_mask,
-                            use_bias, dropout_rate)
+                            use_bias, dropout_rate) + \
+            _rotary_specs(ix, rot, block_q)
 
     # the two schedules launch the same tiles in another order
     masked = masked_tile_count(
@@ -2065,14 +2221,18 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
         run = _tiled_call(
             "bwd", "ds.flash_bwd",
             functools.partial(_bwd_dkv_kernel, n_q=n_q, fused=True,
-                              by_rows=by_rows, **flags),
+                              by_rows=by_rows, rotary=bool(rot), **flags),
             compact, dkv_grid, specs(ixc),
             [kv_spec, kv_spec, spec(ixc, block_q, lambda qi, ki: _dq_block(
                 qi, ki, n_k, block_q, block_k, causal))],
             [acc, acc, pltpu.VMEM((n_q, d, block_q), jnp.float32)]
             + _key_column_scratch(block_k, use_seg, use_bias)
-            # k and v of a column's k^T and v^T blocks
-            + [pltpu.VMEM((block_k, d), dtypes[i]) for i in (1, 2)] * by_rows,
+            # k and v of a column's k^T and v^T blocks; its rotated k^T,
+            # its columns of the rotary's table, the step's rotated q^T
+            + [pltpu.VMEM((block_k, d), dtypes[i]) for i in (1, 2)] * by_rows
+            + [pltpu.VMEM((d, block_k), dtypes[1]),
+               pltpu.VMEM((2 * rot, block_k), jnp.float32),
+               pltpu.VMEM((d, block_q), dtypes[0])] * bool(rot),
             dkv_shapes + [dq_shape], dkv_maps, interpret,
             vmem_limit=flash_bwd_vmem_limit(s, d))
 
@@ -2112,7 +2272,7 @@ def _bwd_calls(bh, s, h, d, dtypes, block_q, block_k, causal, sm_scale,
 
 
 def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
-         kbias=None, dropout_rate=0.0, seed=None, seg=None):
+         kbias=None, dropout_rate=0.0, seed=None, seg=None, table=None):
     """(dq, dk, dv) [B, S, H, D] from a forward's residuals and the
     cotangent `g` of its out. The residuals of a forward that took the
     heads in place ([B, H, D, S]: `_fwd`) go to the fused kernel as they
@@ -2120,7 +2280,8 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     `_to_rows`). A backward of one block, or of the two kernels of a
     sequence over the slab's budget, moves them to [B*H, S, D] first, as
     every other forward's residuals are, with dO, and moves dq, dk and dv
-    back."""
+    back. `table`: as `_fwd` took it; the residuals' q and k are
+    un-rotated and so are what dq and dk are the gradients of."""
     qb, kb, vb, out, lse = res
     bdim, s, h, d = g.shape
     bh = bdim * h
@@ -2132,6 +2293,7 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
         and seg is None
     fused = flash_dq_slab_admitted(s, d)
     in_place = qb.ndim == 4 and fused and not single
+    assert in_place or table is None
     if in_place:
         do = g.transpose(0, 2, 3, 1)                           # [B, H, D, S]
         # summed where dO and out lie: over D, the rows of a head's block
@@ -2168,7 +2330,8 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     run, grids, masked = _bwd_calls(
         bh, s, h, d, (qb.dtype, kb.dtype, vb.dtype), block_q, block_k,
         causal, sm_scale, seg is not None, layout is not None,
-        kbias is not None, dropout_rate, fused, _interpret(), in_place)
+        kbias is not None, dropout_rate, fused, _interpret(), in_place,
+        _rot_dim(table))
     # what the most recent backward launched, and nothing an earlier one did
     for kind in ("bwd", "dkv", "dq"):
         _LAST_GRIDS.pop(kind, None)
@@ -2176,7 +2339,8 @@ def _bwd(causal, sm_scale_arg, block_q, block_k, res, g, layout=None,
     _LAST_GRIDS.update(grids)
     _LAST_MASKED.update(dict.fromkeys(grids, masked))
     dq, dk, dv = run(qb, kb, vb, do, lse, delta, *_optional_inputs(
-        seg, layout, kbias, seed, dropout_rate))
+        seg, layout, kbias, seed, dropout_rate),
+        *[table] * (table is not None))
     return back(dq), back(dk), back(dv)
 
 
@@ -2192,7 +2356,7 @@ def _resolve_blocks(shape, causal, block_q, block_k, bwd_blocks):
 
 
 def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
-                    block_k=None, bwd_blocks=None):
+                    block_k=None, bwd_blocks=None, rotary=None):
     """Tiled online-softmax attention on [B, S, H, D].
 
     Block geometry comes from `ops.autotune.flash_blocks` at the call's
@@ -2213,10 +2377,38 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     single-block kernels), fewer KV heads than query heads, and a
     backward whose dq slab is over the budget (the two kernels; it moves
     the residuals it was left). No option chooses: the shape does.
-    `ops.dispatch_report()["flash"]["heads"]` counts both."""
+    `ops.dispatch_report()["flash"]["heads"]` counts both.
+
+    `rotary` = (cos, sin, rot_dim), cos and sin [S, rot_dim] of the one
+    position stream every row shares: q and k are the projections as they
+    are, and the KERNELS apply the rotate-half rotary to their first
+    `rot_dim` features (`models/gpt_neox.py::apply_rotary`'s arithmetic, in
+    float32, rounded once) where they load a block, and take it back out
+    of dq and dk where they store them: no pass over q, k, dq or dk in
+    front of or behind the call, and the residuals are the un-rotated
+    projections. Only where `rotates_in_kernel` admits the call (ask it
+    first and keep the XLA rotary where it says no: a call it does not
+    admit raises). `ops.dispatch_report()["flash"]["rotary"]` counts."""
     (bq, bk), bwd = _resolve_blocks(q.shape, causal, block_q, block_k,
                                     bwd_blocks)
-    return _flash_attention(q, k, v, causal, sm_scale, bq, bk, bwd)
+    if rotary is None:
+        return _flash_attention(q, k, v, causal, sm_scale, bq, bk, bwd)
+    cos, sin, rot_dim = rotary
+    _, s, h, d = q.shape
+    if not (flash_attention_supported(q.shape) and cos.ndim == 2 and
+            _rotates(s, h, k.shape[2], d, q.dtype.itemsize, causal, rot_dim,
+                     (_fit_block(bq, s), _fit_block(bk, s)),
+                     tuple(_fit_block(x, s) for x in bwd))):
+        raise ValueError(
+            f"flash_attention cannot rotate q {tuple(q.shape)} / k "
+            f"{tuple(k.shape)} by {rot_dim} features of tables "
+            f"{tuple(cos.shape)} in its kernels (`rotates_in_kernel`): "
+            f"rotate them first and call without `rotary`")
+    # a feature a row, a position a column (the blocks' own orientation),
+    # cos over sin
+    table = jnp.concatenate([t[:, :rot_dim].astype(jnp.float32).T
+                             for t in (cos, sin)])
+    return _flash_rotating(q, k, v, table, causal, sm_scale, bq, bk, bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -2238,6 +2430,34 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, bwd_blocks, res, g):
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_rotating(q, k, v, table, causal, sm_scale, block_q, block_k,
+                    bwd_blocks):
+    """`_flash_attention` of the UN-rotated q and k and the rotary's
+    table [2 x rot, S]: the kernels rotate."""
+    return _flash_rotating_fwd(q, k, v, table, causal, sm_scale, block_q,
+                               block_k, bwd_blocks)[0]
+
+
+def _flash_rotating_fwd(q, k, v, table, causal, sm_scale, block_q, block_k,
+                        bwd_blocks):
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    out, res = _fwd(q, k, v, causal, scale, block_q, block_k, in_place=True,
+                    table=table)
+    return out, (res, table)
+
+
+def _flash_rotating_bwd(causal, sm_scale, block_q, block_k, bwd_blocks,
+                        res_table, g):
+    res, table = res_table
+    dq, dk, dv = _bwd(causal, sm_scale, *bwd_blocks, res, g, table=table)
+    # the table is the step's constant: positions are not learned
+    return dq, dk, dv, jnp.zeros_like(table)
+
+
+_flash_rotating.defvjp(_flash_rotating_fwd, _flash_rotating_bwd)
 
 
 def flash_attention_segmented(q, k, v, segment_ids, causal=True,

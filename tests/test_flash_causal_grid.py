@@ -13,6 +13,7 @@ slab budget, the masked-tile counts and the segmented forward's bodies
 `tests/test_flash_masked_tiles.py`; what they share
 `tests/flash_grid_common.py`."""
 
+import functools
 import importlib
 import math
 
@@ -584,6 +585,18 @@ SETUP_CASES = [
     ((4, 2048, 16, 128), {"ds.flash_fwd": 1000, **BACKWARD_BUDGET}),
 ]
 
+# What the bodies may grow to where the kernels rotate q and k themselves
+# (`flash_attention(..., rotary=...)`, the train cells' call since PR 63):
+# this tree counts the forward 922 / 919 / 919 (+47: the table's slice and
+# a rotation of the k block under the first-visit predicate, one of the q
+# block and its store into the scratch under the row's first tile) and the
+# fused backward 351 (+93: q rotated once a step into its scratch, k once
+# a column with its table columns kept, dk's and dq's rotation back; with
+# q rotated in each of its five groups it was 428 and built in twice the
+# host time). Every row above stays as it is: with `rotary=None` the
+# kernels trace the parent's jaxprs.
+ROTATING_GROWTH = {"ds.flash_fwd": 60, "ds.flash_bwd": 120}
+
 
 KERNEL_OF = {"fwd": "ds.flash_fwd", "bwd": "ds.flash_bwd",
              "dkv": "ds.flash_bwd_dkv", "dq": "ds.flash_bwd_dq"}
@@ -592,19 +605,25 @@ KERNEL_OF = {"fwd": "ds.flash_fwd", "bwd": "ds.flash_bwd",
 LAYERS = 3      # unrolled, as a model without remat calls the attention
 
 
-def _layers(q, k, v):
+def _layers(q, k, v, rotating=False):
     """At the blocks the rule gives the chip the cells run on (the CPU
-    has no row of its own for 16k)."""
+    has no row of its own for 16k). `rotating`: the kernels rotate a
+    quarter of each head's features, as Pythia's do."""
+    from deeperspeed_tpu.models import gpt_neox
     from deeperspeed_tpu.ops.autotune import flash_blocks
     (bq, bk), bwd = flash_blocks(q.shape, True, device_kind="TPU v5 lite")
+    rotary = gpt_neox._rotary_table(
+        *gpt_neox.rope_inv_freq(q.shape[3], 0.25, 10000.0), q.shape[1],
+        jnp.float32) if rotating else None
     x = q
     for _ in range(LAYERS):
-        x = fa.flash_attention(x, k, v, True, None, bq, bk, bwd)
+        x = fa.flash_attention(x, k, v, True, None, bq, bk, bwd,
+                               rotary=rotary)
     return x.astype(jnp.float32)
 
 
-def _layers_loss(q, k, v):
-    return _layers(q, k, v).sum()
+def _layers_loss(q, k, v, rotating=False):
+    return _layers(q, k, v, rotating).sum()
 
 
 def _fresh_account():
@@ -699,7 +718,8 @@ def test_the_training_call_never_moves_a_head(shape):
     assert found == {(0, 2, 3, 1), (0, 3, 1, 2)}, found
 
 
-@pytest.mark.parametrize("heads", ["in_place", "moved", "k_every_step"])
+@pytest.mark.parametrize("heads", ["in_place", "moved", "k_every_step",
+                                   "rotating"])
 @pytest.mark.parametrize("shape,budget", SETUP_CASES,
                          ids=["train_16k", "train_2k", "zero3_shard"])
 def test_bodies_are_built_once_and_stay_small(shape, budget, backward,
@@ -711,15 +731,28 @@ def test_bodies_are_built_once_and_stay_small(shape, budget, backward,
     in place (what the cells run), on moved heads (a shape the rule
     does not admit; every masked, biased or segmented call) and in place
     with k turned every grid step (a sequence whose k is over the slab's
-    budget, `autotune.flash_k_slab_admitted`)."""
+    budget, `autotune.flash_k_slab_admitted`); and in place with the
+    rotary of q and k inside the kernels (what the cells' models call
+    since PR 63), each body a written number of equations over its row."""
     if heads == "moved":
         monkeypatch.setattr(fa, "heads_in_place", lambda h, g, d: False)
     if heads == "k_every_step":
         monkeypatch.setattr(autotune, "_FLASH_K_SLAB_BUDGET", 0)
+    if heads == "rotating":
+        budget = {name: n + ROTATING_GROWTH.get(name, 0)
+                  for name, n in budget.items()}
     spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     kinds = ("fwd", *backward)
     before = _fresh_account()
-    grad = jax.grad(_layers_loss, argnums=(0, 1, 2))
+    grad = jax.grad(functools.partial(_layers_loss,
+                                      rotating=heads == "rotating"),
+                    argnums=(0, 1, 2))
+    if heads == "rotating" and backward != ("bwd",):
+        # the two kernels of a sequence over the slab's budget do not
+        # rotate: the call is refused, and a model keeps its XLA rotary
+        with pytest.raises(ValueError, match="rotates_in_kernel"):
+            jax.make_jaxpr(grad)(spec, spec, spec)
+        return
     first = jax.make_jaxpr(grad)(spec, spec, spec)
     jax.make_jaxpr(grad)(spec, spec, spec)
     assert _built_since(before) == {

@@ -231,6 +231,72 @@ def _one_layer_step(batch, seq, heads, hidden, remat):
                   *((leaf.shape, leaf.dtype) for leaf in leaves)]
 
 
+ENTRY_LINE = re.compile(
+    r"^\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<type>.*?) "
+    r"(?P<op>[a-z][a-z\-]*)\((?P<args>[^)]*)\)")
+# what hands a tensor on as it lies
+PASSES_ON = {"bitcast", "get-tuple-element", "copy-start", "copy-done"}
+
+
+def _entry(text):
+    """{name: (op, operand names, the last part of its `op_name`, elements
+    of its first array)} of a compiled program's ENTRY computation."""
+    found = {}
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = ENTRY_LINE.match(line)
+        if not m:
+            continue
+        dims = re.search(r"\w+\[([0-9,]*)\]", m.group("type"))
+        scope = re.search(r'op_name="([^"]*)"', line)
+        found[m.group("name")] = (
+            m.group("op"), re.findall(r"%([\w.\-]+)", m.group("args")),
+            scope.group(1).rsplit("/", 1)[-1] if scope else "",
+            int(np.prod([int(n) for n in dims.group(1).split(",")]))
+            if dims and dims.group(1) else 1)
+    return found
+
+
+def _flash_neighbours(text, elements):
+    """What a compiled train step's flash kernels read their q, k, v (and
+    dO) FROM and what reads the backward's dq, dk, dv: [(kernel, side,
+    the neighbour's op, its `op_name`'s last part)] over every operand and
+    result of `elements` elements, through whatever passes a tensor on as
+    it lies; the kernels' own results among their operands (out) left
+    out."""
+    entry = _entry(text)
+    users = {}
+    for name, (_, operands, _, _) in entry.items():
+        for operand in operands:
+            users.setdefault(operand, []).append(name)
+
+    def source(name):
+        op, operands, _, _ = entry[name]
+        return source(operands[0]) if op in PASSES_ON else name
+
+    def readers(name):
+        for user in users.get(name, []):
+            if entry[user][0] in PASSES_ON:
+                yield from readers(user)
+            else:
+                yield user
+
+    found = []
+    for name, (op, operands, scope, _) in entry.items():
+        if op != "custom-call" or scope != "pallas_call":
+            continue
+        kernel = name.rsplit(".", 1)[0]
+        for operand in operands:
+            if entry[operand][3] == elements:
+                src = source(operand)
+                if entry[src][0] != "custom-call":
+                    found.append((kernel, "reads", *entry[src][::2]))
+        if kernel == "ds.flash_bwd":
+            found += [(kernel, "read_by", *entry[user][::2])
+                      for user in readers(name)
+                      if entry[user][3] != 1]
+    return found
+
+
 def _heads_moved():
     return {kind: n["moved"] for kind, n in
             dispatch_report()["flash"]["heads"].items()}
@@ -264,9 +330,21 @@ def test_train_step_copies_no_attention_tensor(on_chip, name, batch, seq,
     heads of 128 (`train_zero3_4c`'s shard of Pythia-1.4b, no remat) XLA
     lays a head dim of a whole lane tile the other way, three dots would
     cost six copies of `B*S*hidden`, and the rule keeps the ONE fused dot
-    and its one copy."""
+    and its one copy.
+
+    And at the two one-chip shapes NO ELEMENTWISE PASS stands between the
+    projections and the kernels (PERF.md section 6, PR 63): the kernels
+    rotate q and k themselves (`flash_attention.rotates_in_kernel`), so
+    the forward's q, k and v, and the recomputed ones the backward reads,
+    are the projections' dot fusions' own results, dO is the output
+    projection's gradient dot's, and what reads dq, dk and dv is the
+    weight-gradient dots and the bias gradient's reductions, in the
+    forward, the recomputation and the backward alike. The rotary's
+    `concatenate` / `neg` / `slice` / `add_any` fusions of the parent
+    would each be named here."""
     step, args = _one_layer_step(batch, seq, heads, hidden, remat)
     moved_before, projections = _heads_moved(), _projections()
+    rotary = dict(dispatch_report()["flash"]["rotary"])
     text = on_chip(step, *args)
     assert kernel_names(text) >= {"ds.flash_fwd", "ds.flash_bwd"}
     moved = _copies(text, batch * seq * hidden)
@@ -275,6 +353,20 @@ def test_train_step_copies_no_attention_tensor(on_chip, name, batch, seq,
     assert len(whole) == (0 if form == "split" else 1), whole
     assert moved_before == _heads_moved()
     assert _projections() == {**projections, form: projections[form] + 1}
+    counted = dispatch_report()["flash"]["rotary"]
+    assert counted["xla"] == rotary["xla"]
+    assert counted["in_kernel"] > rotary["in_kernel"]
+    if form == "split":
+        around = _flash_neighbours(text, batch * seq * hidden)
+        assert {row[:2] for row in around} == {
+            ("ds.flash_fwd", "reads"), ("ds.flash_bwd", "reads"),
+            ("ds.flash_bwd", "read_by")}, around
+        # q, k, v; q, k, v recomputed and dO; of each of dq, dk, dv the
+        # weight's and the input's gradient dots and the bias's reduction
+        assert len(around) == 3 + 4 + 9, around
+        passes = [row for row in around if (row[2], row[3]) not in {
+            ("fusion", "dot_general"), ("reduce", "reduce_sum")}]
+        assert not passes, passes
 
 
 # Both sides of `ops.autotune.flash_dq_slab_admitted`, at the blocks the
@@ -340,6 +432,42 @@ def test_flash_forward_compiles_on_both_sides_of_the_k_slab_budget(
     assert {rule: n - before[rule] for rule, n in
             dispatch_report()["flash"]["k_turns"].items()} == {
         "once_a_head": int(kept), "every_step": int(not kept)}
+
+
+# The kernels that rotate q and k themselves (`flash_attention(...,
+# rotary=...)`) at the largest the rule admits (the train cells' shapes
+# compile inside `test_train_step_copies_no_attention_tensor`'s steps):
+# the longest head (its k slab and dq slab both at their budgets) with the
+# smallest and the widest table, the whole head rotated.
+# (name, [B, S, H, D], rot_dim)
+ROTATING_SHAPES = [
+    ("largest_slabs_d64", (1, 32768, 16, 64), 16),
+    ("largest_slabs_d64_whole_head", (1, 32768, 16, 64), 64),
+    ("largest_dq_slab_d128_whole_head", (1, 16384, 16, 128), 128),
+]
+
+
+@pytest.mark.parametrize("name,shape,rot", ROTATING_SHAPES,
+                         ids=[c[0] for c in ROTATING_SHAPES])
+def test_rotating_kernels_compile(on_chip, name, shape, rot):
+    """Forward and fused backward with the rotary inside, at the blocks
+    the rule gives a v5e: two Mosaic kernels, one table operand
+    `[2 x rot, S]` each."""
+    from deeperspeed_tpu.models import gpt_neox
+    from deeperspeed_tpu.ops.autotune import flash_blocks
+    assert fa.rotates_in_kernel(shape, shape[2], rot)
+    (bq, bk), bwd = flash_blocks(shape, True, "TPU v5 lite")
+    b, s, h, d = shape
+    rotary = gpt_neox._rotary_table(
+        *gpt_neox.rope_inv_freq(d, rot / d, 10000.0), s, jnp.float32)
+
+    def attn(q, k, v):
+        return fa.flash_attention(q, k, v, True, None, bq, bk, bwd,
+                                  rotary=rotary)
+
+    text = on_chip(jax.grad(loss_of(attn), argnums=(0, 1, 2)), *qkv(*shape))
+    assert kernel_names(text) == {"ds.flash_fwd", "ds.flash_bwd"}
+    assert text.count(f"f32[{2 * rot},{s}]{{1,0}}") >= 2
 
 
 def test_segmented_prefill_compiles_at_the_serve_cells_bucket(on_chip):
