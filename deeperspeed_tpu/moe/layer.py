@@ -555,9 +555,11 @@ def dropless_plan(pair_expert, n_experts, block_m, rows):
     """The ragged layout's plan, COUNTED: the pairs are never sorted by
     expert.
 
-    pair_expert [P] int32: the held expert of pair p = t * k + j, or the
-    sentinel `n_experts` for a pair that owns no row (a padded token's,
-    an absent expert's). Returns (counts [E], tile_expert, tile_rows,
+    pair_expert [P] int32: the held expert of pair p, or the sentinel
+    `n_experts` for a pair that owns no row (a padded token's, an absent
+    expert's). The caller numbers the pairs; the layer does so
+    choice-major, p = j * T + t for token t's j-th choice
+    (`moe_ffn_dropless`). Returns (counts [E], tile_expert, tile_rows,
     starts [E] as `ragged_tile_maps` gives them, pair_row [P]: the
     buffer row of each pair (`rows` for a sentinel pair), src [rows]:
     the pair of each buffer row (P for a padding row)).
@@ -631,7 +633,14 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
     buffer, a group's rows in pair order (`dropless_plan`: counted, not
     sorted), whose groups have their real lengths, each padded to a whole
     row tile (`ops.pallas.grouped_matmul`, ragged layout): no capacity,
-    no `capacity_factor`. `token_mask` [T] marks real tokens: a padded
+    no `capacity_factor`. The pairs are numbered CHOICE-MAJOR, p = j * T
+    + t for token t's j-th choice, so each routed row moves once each
+    way: the fill is one gather of x's rows (a padding row names one
+    zero row appended to x: no pass that zeroes the buffer), and the
+    combine's gather `out[pair_row]` IS [top_k, T, H], summed over its
+    major axis where it lies (token-major, [T, top_k, H] puts top_k on
+    the tiled second-minor dimension: a copy of every gathered row at
+    top_k = 10 and 4). `token_mask` [T] marks real tokens: a padded
     row (a prefill bucket's tail, an inactive decode row) is routed to no
     expert, takes no buffer row and no part in the statistics, and comes
     out zero. So a token's result does not depend on its batch
@@ -663,7 +672,7 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
     mean of s / sum_e s.
     """
     from .. import scopes
-    from ..ops.pallas.grouped_matmul import ragged_matmul
+    from ..ops.pallas.grouped_matmul import _PLANS_TRACED, ragged_matmul
     T, H = x.shape
     E_all = params["gate"].shape[1]          # the experts the router scores
     lo, hi = held if held is not None else (0, E_all)
@@ -708,15 +717,19 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                             axis=0) / n_live                  # P [E]
 
     with scopes.scope("ds.moe_dispatch"):
-        # pair p = t*k + j; a padded token's pairs go to the sentinel E,
+        # pair p = j*T + t; a padded token's pairs go to the sentinel E,
         # which owns no buffer row
         experts = experts.astype(jnp.int32)
         here = live[:, None] & (experts >= lo) & (experts < hi)
-        pair_expert = jnp.where(here, experts - lo, E).reshape(T * k)
+        pair_expert = jnp.where(here, experts - lo, E).T.reshape(k * T)
         counts, tile_expert, tile_rows, _, pair_row, src = dropless_plan(
             pair_expert, E, bm, R)
-        buf = jnp.where((src < T * k)[:, None],
-                        x[jnp.minimum(src, T * k - 1) // k], 0)
+        _PLANS_TRACED["choice_major"] = \
+            _PLANS_TRACED.get("choice_major", 0) + 1
+        # one gather: a padding row (src == T * k) names x's zero row
+        zero_row = jnp.zeros((1, H), x.dtype)
+        buf = jnp.concatenate([x, zero_row])[
+            jnp.where(src < T * k, src % T, T)]
         if held is None:
             stats = jnp.stack([counts.astype(jnp.float32) /
                                jnp.maximum(jnp.sum(counts), 1), mean_prob])
@@ -737,9 +750,9 @@ def moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                         tile_rows, bm, backend=gmm_backend)   # [R, H]
 
     with scopes.scope("ds.moe_combine"):
-        rows = out[jnp.minimum(pair_row, R - 1)].reshape(T, k, H)
-        w = jnp.where(here, weights, 0.0).astype(dt)
-        y = jnp.sum(w[:, :, None] * rows, axis=1)
+        rows = out[jnp.minimum(pair_row, R - 1)].reshape(k, T, H)
+        w = jnp.where(here, weights, 0.0).astype(dt).T        # [k, T]
+        y = jnp.sum(w[:, :, None] * rows, axis=0)
     return y, stats
 
 

@@ -74,15 +74,18 @@ def dispatch_report():
     "kv_write_latent" / "kv_write_latent_slots": the same of a latent
     layer's row write}; ``quant_matmul`` / ``grouped_matmul``:
     {name: backend}. A backend is "pallas" (the kernel, interpreted off
-    a TPU) or "xla". ``moe``: {"plan": {"counted": n}}, the traces of
-    the dropless MoE layer in this process by the form the ragged
-    layout's plan took (counted: each pair's row from its rank among its
-    expert's pairs, never a sort by expert; the buffer row -> pair map
-    by one sort of the rows: `moe.layer.dropless_plan`); empty until
-    that layer is traced. ``ssm``: {"scan" / "step": backend} of a
-    state-space layer's selective scan and its one-token step
-    (`ops.pallas.ssm`). ``ce_head``: {"loss_and_grads": n, "loss_only":
-    n}, the calls of `models.gpt_neox.fused_lm_head_loss` traced in this
+    a TPU) or "xla". ``moe``: {"plan": {"counted": n, "choice_major":
+    n}}, the traces of the dropless MoE layer in this process by the
+    form the ragged layout's plan took (counted: each pair's row from
+    its rank among its expert's pairs, never a sort by expert; the
+    buffer row -> pair map by one sort of the rows:
+    `moe.layer.dropless_plan`) and by the pairs' numbering
+    (choice_major: pair p = j * T + t, the fill one gather and the
+    combine summed over the gathered rows' major axis:
+    `moe.layer.moe_ffn_dropless`); empty until that layer is traced.
+    ``ssm``: {"scan" / "step": backend} of a state-space layer's
+    selective scan and its one-token step (`ops.pallas.ssm`).
+    ``ce_head``: {"loss_and_grads": n, "loss_only": n}, the calls of `models.gpt_neox.fused_lm_head_loss` traced in this
     process by the rule that ran: its `custom_vjp`'s forward rule (the
     loss and both gradients from one logits tile a chunk: a train step's)
     or its primal (the loss alone: evaluation; also traced, and thrown

@@ -759,7 +759,8 @@ class TestDispatchReport:
         assert set(report["ssm"]) <= {"scan", "step"}
         # the dropless MoE layers traced so far, by the form of their plan
         assert set(report["moe"]) == {"plan"}
-        assert set(report["moe"]["plan"]) <= {"counted"}
+        # and by how the layer numbered the pairs it planned
+        assert set(report["moe"]["plan"]) <= {"counted", "choice_major"}
         assert isinstance(report["flash"], dict)
         # the attention projections traced so far, by their form
         assert set(report["attention"]["head_projection"]) == \

@@ -2,10 +2,15 @@
 against the plain form kept here (a stable argsort and scatters, the
 lines the layer ran until PR 45) every integer of the plan is the same,
 at the serving cells' own shapes and at the edges; the layer's outputs
-and gradients are bit-equal to a copy of that layer; and the traced
+and gradients are bit-equal to a copy of that layer (which numbers the
+pairs token-major, as the layer did until PR 64, or choice-major, as it
+does since: `pair_major`); the fill leaves exact zeros in the buffer's
+padding rows; and the traced
 program holds ONE sort (of the buffer's rows, for `src`) and no scatter
 or scatter-add, so that a later edit cannot bring the others back
 unseen."""
+
+import importlib
 
 import numpy as np
 import pytest
@@ -55,8 +60,18 @@ def plain_plan(pair_expert, E, bm, R):
 def plain_moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                            activation=jax.nn.silu, token_mask=None,
                            gmm_backend=None, held=None, scale=1.0,
-                           score="softmax"):
-    """`moe_ffn_dropless` as PR 44 left it."""
+                           score="softmax", pair_major="token"):
+    """`moe_ffn_dropless` as PR 44 left it: a stable sort by expert, a
+    `where` over the buffer, the combine over `[T, k, H]`.
+
+    `pair_major="choice"` moves the rows as the layer does since PR 64,
+    under the same plain plan: the pairs numbered p = j * T + t before
+    the stable sort (the same rows in the same groups, a group's rows in
+    (choice, token) order, which is the order `dw` sums them in), and
+    the fill one gather from x with a zero row appended (`dx` is then
+    the slice of a scatter-add, to which XLA adds the router's part
+    AFTER the rows' and not before: the last digit of `dx` follows)."""
+    choice_major = pair_major == "choice"
     T, H = x.shape
     E_all = params["gate"].shape[1]
     lo, hi = held if held is not None else (0, E_all)
@@ -86,11 +101,17 @@ def plain_moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
                         axis=0) / n_live
     experts = experts.astype(jnp.int32)
     here = live[:, None] & (experts >= lo) & (experts < hi)
-    pair_expert = jnp.where(here, experts - lo, E).reshape(T * k)
+    pair_expert = jnp.where(here, experts - lo, E)
+    pair_expert = (pair_expert.T if choice_major else pair_expert).reshape(
+        T * k)
     counts, tile_expert, tile_rows, _, pair_row, src = plain_plan(
         pair_expert, E, bm, R)
-    buf = jnp.where((src < T * k)[:, None],
-                    x[jnp.minimum(src, T * k - 1) // k], 0)
+    if choice_major:
+        buf = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])[
+            jnp.where(src < T * k, src % T, T)]
+    else:
+        buf = jnp.where((src < T * k)[:, None],
+                        x[jnp.minimum(src, T * k - 1) // k], 0)
     if held is None:
         stats = jnp.stack([counts.astype(jnp.float32) /
                            jnp.maximum(jnp.sum(counts), 1), mean_prob])
@@ -107,6 +128,8 @@ def plain_moe_ffn_dropless(params, x, top_k, norm_topk_prob=False,
     h = activation(h[:, :inter]) * h[:, inter:]
     out = ragged_matmul(h, params["w_out"].astype(dt), tile_expert,
                         tile_rows, bm, backend=gmm_backend)
+    if choice_major:
+        pair_row = pair_row.reshape(k, T).T.reshape(T * k)
     rows = out[jnp.minimum(pair_row, R - 1)].reshape(T, k, H)
     w = jnp.where(here, weights, 0.0).astype(dt)
     return jnp.sum(w[:, :, None] * rows, axis=1), stats
@@ -209,6 +232,20 @@ LAYERS = {
     "sigmoid-biased": dict(T=29, H=16, inter=8, E_all=8, k=2, mask=True,
                            kw=dict(score="sigmoid", norm_topk_prob=True,
                                    scale=1.8)),
+    # the serving cells' top_k: 4 (GLM), 8 (OLMoE, SDAR), 10 (Laguna,
+    # Qwen3-Next: the k the token-major combine copied)
+    "sigmoid-k4-masked": dict(T=45, H=16, inter=8, E_all=16, k=4, mask=True,
+                              kw=dict(score="sigmoid", norm_topk_prob=True,
+                                      scale=1.8)),
+    "softmax-k8-masked": dict(T=40, H=16, inter=8, E_all=16, k=8, mask=True,
+                              kw={}),
+    "softmax-k8-held": dict(T=24, H=16, inter=8, E_all=32, k=8, mask=False,
+                            kw=dict(held=(8, 24))),
+    "softmax-k10-masked": dict(T=33, H=16, inter=8, E_all=32, k=10,
+                               mask=True, kw=dict(norm_topk_prob=True)),
+    "held-k10-masked-bf16": dict(T=52, H=16, inter=8, E_all=32, k=10,
+                                 mask=True, dtype=jnp.bfloat16,
+                                 kw=dict(held=(0, 16), norm_topk_prob=True)),
 }
 
 
@@ -218,42 +255,108 @@ def _layer_case(case):
     params = layer_params(7, spec["H"], spec["inter"], spec["E_all"],
                           held=kw.get("held"),
                           sigmoid=kw.get("score") == "sigmoid")
+    dtype = spec.get("dtype", jnp.float32)
+    params = {name: leaf if name.startswith("gate") else leaf.astype(dtype)
+              for name, leaf in params.items()}
     x = jax.random.normal(jax.random.PRNGKey(11), (spec["T"], spec["H"]),
-                          jnp.float32)
+                          jnp.float32).astype(dtype)
     mask = (jnp.arange(spec["T"]) % 5 != 3) if spec["mask"] else None
     return params, x, spec["k"], dict(kw, token_mask=mask)
 
 
 @pytest.mark.parametrize("case", sorted(LAYERS))
 def test_layer_outputs_are_the_plain_layers_bit_for_bit(case):
+    """Against the plain layer under BOTH pair numberings: the
+    token-major one is the layer every PR up to 63 ran, so the result of
+    a token did not change with the order of its group's rows."""
     params, x, k, kw = _layer_case(case)
     y, stats = jax.jit(lambda p, v: moe_ffn_dropless(p, v, k, **kw))(
         params, x)
-    y0, stats0 = jax.jit(
-        lambda p, v: plain_moe_ffn_dropless(p, v, k, **kw))(params, x)
     assert stats.shape == (3 if "held" in kw else 2, params["gate"].shape[1])
-    assert (np.asarray(y) == np.asarray(y0)).all()
-    assert (np.asarray(stats) == np.asarray(stats0)).all()
     assert np.asarray(y).any()
+    for pair_major in ("token", "choice"):
+        y0, stats0 = jax.jit(lambda p, v: plain_moe_ffn_dropless(
+            p, v, k, pair_major=pair_major, **kw))(params, x)
+        assert y.dtype == y0.dtype
+        assert (np.asarray(y) == np.asarray(y0)).all(), pair_major
+        assert (np.asarray(stats) == np.asarray(stats0)).all(), pair_major
 
 
-@pytest.mark.parametrize("case", ["softmax-masked", "held-share-scaled"])
+@pytest.mark.parametrize("case", ["softmax-masked", "held-share-scaled",
+                                  "softmax-k10-masked"])
 def test_layer_gradients_are_the_plain_layers_bit_for_bit(case):
+    """`dw` sums a group's rows in the buffer's order, so the plain layer
+    is given the layer's pair numbering (choice-major) before its stable
+    sort, and the layer's fill: every gradient is then the same float.
+    Against the token-major layer of PR 44 the router's gradient is the
+    same float too; `dx` and `dw` are the same sums in another order."""
     params, x, k, kw = _layer_case(case)
     probe = jax.random.normal(jax.random.PRNGKey(3), x.shape, jnp.float32)
 
-    def loss(fn):
+    def loss(fn, **more):
         def scalar(p, v):
-            y, stats = fn(p, v, k, **kw)
+            y, stats = fn(p, v, k, **kw, **more)
             return jnp.sum(y * probe) + jnp.sum(stats[1] * stats[0])
         return jax.jit(jax.grad(scalar, argnums=(0, 1)))
 
     (gp, gx) = loss(moe_ffn_dropless)(params, x)
-    (gp0, gx0) = loss(plain_moe_ffn_dropless)(params, x)
+    (gp0, gx0) = loss(plain_moe_ffn_dropless, pair_major="choice")(params, x)
     assert (np.asarray(gx) == np.asarray(gx0)).all() and np.asarray(gx).any()
     for name in ("w_in", "w_out", "gate"):
         assert (np.asarray(gp[name]) == np.asarray(gp0[name])).all(), name
         assert np.asarray(gp[name]).any(), name
+    (gp1, gx1) = loss(plain_moe_ffn_dropless)(params, x)
+    assert (np.asarray(gp["gate"]) == np.asarray(gp1["gate"])).all()
+    for got, want in ((gx, gx1), (gp["w_in"], gp1["w_in"]),
+                      (gp["w_out"], gp1["w_out"])):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+# --- the fill: one gather, padding rows exact zeros ------------------------
+
+@pytest.mark.parametrize("case", ["softmax-masked", "held-share-scaled",
+                                  "held-k10-masked-bf16"])
+def test_the_fill_leaves_exact_zeros_in_the_padding_rows(monkeypatch, case):
+    """The buffer the first grouped matmul is handed: a padding row (past
+    its tile's live rows) names the zero row appended to x, a live row
+    holds its token's x, in (choice, token) order inside a group. No
+    `where` zeroes the buffer afterwards: the rows are zeros as they are
+    gathered."""
+    # (`ops.pallas` re-exports a function under the module's own name)
+    gmm = importlib.import_module("deeperspeed_tpu.ops.pallas.grouped_matmul")
+    params, x, k, kw = _layer_case(case)
+    seen = []
+    real = gmm.ragged_matmul
+
+    def watched(buf, w, tile_expert, tile_rows, block_m, **more):
+        seen.append((np.asarray(buf.astype(jnp.float32)),
+                     np.asarray(tile_expert), np.asarray(tile_rows),
+                     block_m))
+        return real(buf, w, tile_expert, tile_rows, block_m, **more)
+
+    monkeypatch.setattr(gmm, "ragged_matmul", watched)
+    moe_ffn_dropless(params, x, k, **kw)                # eager: concrete
+    buf, tile_expert, tile_rows, bm = seen[0]
+    T, E = x.shape[0], params["w_in"].shape[0]
+    assert buf.shape[0] == dropless_geometry(T, k, E)[0]
+    lane = np.arange(bm)[None, :]
+    padding = (lane >= tile_rows[:, None]).reshape(-1)
+    assert padding.any() and not padding.all()
+    assert (buf[padding] == 0).all() and not np.signbit(buf[padding]).any()
+    # the live rows: each expert's tokens, choice by choice
+    logits = np.asarray(x, np.float32) @ np.asarray(params["gate"])
+    lo = kw["held"][0] if "held" in kw else 0
+    chosen = np.asarray(jax.lax.top_k(jnp.asarray(logits), k)[1]) - lo
+    live = np.ones(T, bool) if kw["token_mask"] is None \
+        else np.asarray(kw["token_mask"])
+    xs = np.asarray(x.astype(jnp.float32))
+    tile_of = np.repeat(np.arange(tile_rows.size), bm)
+    for e in range(E):
+        want = [t for j in range(k) for t in range(T)
+                if live[t] and chosen[t, j] == e]
+        got = buf[~padding & (tile_expert[tile_of] == e)]
+        assert (got == xs[want]).all(), e
 
 
 # --- the traced program's budget -------------------------------------------
@@ -285,5 +388,7 @@ def test_a_trace_is_counted_by_the_form_of_its_plan():
     params, x, k, kw = _layer_case("softmax-masked")
     before = ops.dispatch_report()["moe"]["plan"]
     jax.make_jaxpr(lambda p, v: moe_ffn_dropless(p, v, k, **kw))(params, x)
+    # the plan's form and the pairs' numbering, one count each a trace
     assert ops.dispatch_report()["moe"]["plan"] == \
-        {"counted": before.get("counted", 0) + 1}
+        {"counted": before.get("counted", 0) + 1,
+         "choice_major": before.get("choice_major", 0) + 1}

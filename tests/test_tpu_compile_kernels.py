@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from deeperspeed_tpu.ops import dispatch_report
 from tests.tpu_compile_common import (  # noqa: F401 (fixtures)
     assert_kernel, BF16, block_sparse_attention, decode_attention, fa,
-    grouped_matmul, kernel_names, loss_of, on_chip, optimizer,
+    grouped_matmul, INSTRUCTION, kernel_names, loss_of, on_chip, optimizer,
     pool_shaped_moves, qkv, quant_matmul, stacked, v5e_2x2)
 
 # ---------------------------------------------------------------------------
@@ -298,40 +298,108 @@ def test_ragged_grouped_matmul_compiles_at_olmoe_shapes(on_chip, tokens):
         assert_kernel(on_chip(grad, *args), at_least=5)
 
 
-@pytest.mark.parametrize(
-    "tokens,k,e_all,held,h,inter",
-    [(32, 8, 64, None, 2048, 1024), (8192, 10, 256, (0, 128), 3072, 1024),
-     (16384, 4, 64, None, 2048, 1536)],
-    ids=["olmoe_decode_32", "laguna_prefill_8192", "glm_prefill_16384"])
-def test_dropless_layer_moves_integers_by_index_once(on_chip, tokens, k,
-                                                     e_all, held, h, inter):
-    """The whole dropless layer at a decode step's rows and at the two
-    largest prefills of the serving cells (81,920 and 65,536 pairs): the
-    ragged layout's plan is counted (`moe.layer.dropless_plan`), so
-    beyond the router's `top_k` the program the chip's compiler emits
-    holds ONE sort (the buffer's rows, for `src`), no scatter, and the
-    two grouped matmuls."""
-    from deeperspeed_tpu.moe.layer import moe_ffn_dropless
-    E = held[1] - held[0] if held else e_all
+DROPLESS_SHAPES = {          # tokens, k, the router's experts, held, H, I
+    "olmoe_decode_32": (32, 8, 64, None, 2048, 1024),
+    "laguna_prefill_8192": (8192, 10, 256, (0, 128), 3072, 1024),
+    "glm_prefill_16384": (16384, 4, 64, None, 2048, 1536),
+    "qwen3next_prefill_4096": (4096, 10, 512, (0, 256), 2048, 512)}
+_DROPLESS_TEXTS = {}        # one compile a shape for the two guards below
 
-    def count(op, text):
-        return len(re.findall(rf" {op}\(", text))
+
+def _dropless_layer_text(on_chip, shape):
+    """The whole dropless layer at one of `DROPLESS_SHAPES`, compiled
+    for the described v5e: (text, the inputs of x and the router)."""
+    from deeperspeed_tpu.moe.layer import moe_ffn_dropless
+    tokens, k, e_all, held, h, inter = DROPLESS_SHAPES[shape]
+    E = held[1] - held[0] if held else e_all
 
     def layer(x, gate, w_in, w_out, mask):
         return moe_ffn_dropless({"gate": gate, "w_in": w_in, "w_out": w_out},
                                 x, k, norm_topk_prob=True, token_mask=mask,
                                 gmm_backend="pallas", held=held)
 
+    args = [((tokens, h), BF16), ((h, e_all), jnp.float32)]
+    if shape not in _DROPLESS_TEXTS:
+        _DROPLESS_TEXTS[shape] = on_chip(
+            layer, *args, ((E, h, 2 * inter), BF16), ((E, inter, h), BF16),
+            ((tokens,), jnp.bool_))
+    assert_kernel(_DROPLESS_TEXTS[shape], at_least=2)
+    return _DROPLESS_TEXTS[shape], args
+
+
+@pytest.mark.parametrize("shape", ["olmoe_decode_32", "laguna_prefill_8192",
+                                   "glm_prefill_16384"])
+def test_dropless_layer_moves_integers_by_index_once(on_chip, shape):
+    """The whole dropless layer at a decode step's rows and at the two
+    largest prefills of the serving cells (81,920 and 65,536 pairs): the
+    ragged layout's plan is counted (`moe.layer.dropless_plan`), so
+    beyond the router's `top_k` the program the chip's compiler emits
+    holds ONE sort (the buffer's rows, for `src`), no scatter, and the
+    two grouped matmuls."""
+    k = DROPLESS_SHAPES[shape][1]
+
+    def count(op, text):
+        return len(re.findall(rf" {op}\(", text))
+
     def routed(x, gate):                 # what `top_k` alone compiles to
         return jax.lax.top_k(jax.nn.softmax(
             x.astype(jnp.float32) @ gate, axis=-1), k)
 
-    args = [((tokens, h), BF16), ((h, e_all), jnp.float32)]
-    text = on_chip(layer, *args, ((E, h, 2 * inter), BF16),
-                   ((E, inter, h), BF16), ((tokens,), jnp.bool_))
-    assert_kernel(text, at_least=2)
+    text, args = _dropless_layer_text(on_chip, shape)
     sorts = count("sort", text) - count("sort", on_chip(routed, *args))
     assert (sorts, count("scatter", text)) == (1, 0)
+
+
+@pytest.mark.parametrize("shape", sorted(DROPLESS_SHAPES))
+def test_dropless_layer_moves_each_routed_row_once_each_way(on_chip, shape):
+    """Between the router and the result the compiled layer holds, at
+    the buffer's size `[R, H]` and the pairs' `[T * k, H]`, exactly TWO
+    gathers (the fill from x and its zero row, the combine's
+    `out[pair_row]`) and ONE reduce (the weighted sum over the choices):
+    no `select` over the buffer (the grouped matmul masks a tile's
+    padding rows itself, and the fill gathers them as zeros), and no
+    `copy` or `reshape` of the gathered rows to `[T, k, H]` (token-major
+    pairs put k on the tiled second-minor dimension: a relayout of every
+    row at k = 10 and k = 4; choice-major, `[k, T, H]` is a bitcast)."""
+    from deeperspeed_tpu.moe.layer import dropless_geometry
+    tokens, k, e_all, held, h, _ = DROPLESS_SHAPES[shape]
+    R, _ = dropless_geometry(tokens, k, held[1] - held[0] if held else e_all)
+    text, _ = _dropless_layer_text(on_chip, shape)
+    assert _row_moves(text, tokens, k, R, h) == {
+        "gathers": sorted([f"bf16[{R},{h}]", f"bf16[{tokens * k},{h}]"]),
+        "reduces": 1, "passes": []}
+
+
+def _row_moves(text, tokens, k, rows, h):
+    """What moves the routed rows in a compiled dropless layer: the
+    gathers whose result has the buffer's size `[R, H]` or the pairs'
+    `[T * k, H]`, the reduces to `[T, H]`, and the PASSES that should
+    not be there: a `select` or `copy` of that size or of the gathered
+    rows as `[T, k, H]` / `[k, T, H]` anywhere, and a `reshape` of them
+    that stands alone in the entry computation (inside a gather's fusion
+    a reshape re-types the rows in place; alone it relays them out)."""
+    sized = re.compile(rf"bf16\[(?:{rows},{h}|{tokens * k},{h}|"
+                       rf"{tokens},{k},{h}|{k},{tokens},{h})\]")
+    gathers, reduces, passes, in_entry = [], 0, [], False
+    for line in text.splitlines():
+        if line.startswith("ENTRY "):
+            in_entry = True
+        elif line.startswith("}"):
+            in_entry = False
+        m = INSTRUCTION.match(line)
+        if not m:
+            continue
+        shape = sized.match(m["type"])
+        if m["op"] == "reduce" and m["type"].startswith(f"bf16[{tokens},{h}]"):
+            reduces += 1
+        if not shape:
+            continue
+        if m["op"] == "gather":
+            gathers.append(shape[0])
+        elif m["op"] in ("select", "copy") or \
+                (m["op"] == "reshape" and in_entry):
+            passes.append((m["op"], shape[0]))
+    return {"gathers": sorted(gathers), "reduces": reduces, "passes": passes}
 
 
 @pytest.mark.parametrize("m,k,n", [(8, 768, 3072), (256, 768, 3072),
